@@ -1,0 +1,552 @@
+"""Does the system still start on the chip?  `python chip_smoke.py`
+
+Drives the two main paths once, through the entry points a user calls,
+at the full width of models the repo ships (depth and weights as
+configured; weights random from ``--seed``), in ONE process:
+
+1. **paged kernels** — the Pallas ragged paged decode and prefill
+   bodies (fp32 pages, bf16 pages, int8 pages) against their ``lax_fn``
+   on the chip at GPT-2 widths, within each kernel contract's tolerance;
+2. **trainer** — BERT-base, batch 48 x sequence 512, ``bf16`` policy,
+   ``make_train_state`` / ``build_train_step`` / ``jax.jit``: five
+   optimizer steps on one fixed batch, attention dropout off (hidden
+   dropout as configured, a fresh key each step). Every loss finite,
+   the fifth below the first, attention on the flash kernel;
+3. **serving** — GPT-2 small through ``inference.make_serving_engine``
+   -> ``warmup()`` -> ``submit()`` x5 -> ``step()`` until idle. Every
+   request finishes, greedy tokens equal ``model.generate(...,
+   use_cache=True)`` on the same chip, 0 compiles after warmup, paged
+   attention on the Pallas bodies.
+
+``--chips 4`` runs INSTEAD (no one-chip phase): BERT-base under
+``shard_train_step`` on a dp2 x tp2 mesh against the same steps on one
+device of that host, and ``ServingEngine(tp=4)`` against a ``tp=1``
+engine, each asserting that parameters and page pools are really spread
+over all four devices.
+
+Any failed condition raises: no phase is wrapped in a ``try`` that lets
+the run go on. Without a TPU the script exits non-zero before any phase.
+The LAST line of stdout is the result object and nothing else.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import math
+import sys
+import time
+
+import numpy as np
+
+#: a greedy-token mismatch against ``model.generate`` is tolerated only
+#: where the reference itself is a near-tie: reference top-1 logit minus
+#: the reference logit of the engine's token, below this (logit units).
+#: Random GPT-2 logits have a typical top-2 gap ~0.1; a broken kernel
+#: picks tokens whole logits away, matmul rounding only near-ties.
+TIE_MARGIN = 0.05
+#: dp2 x tp2 vs one device: same math, different reduction order, bf16
+#: activations — relative tolerance on each step's loss
+MESH_LOSS_RTOL = 2e-2
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """What the phases run at. ``real()`` is the chip run; ``tiny()``
+    is the CPU rehearsal (Pallas bodies through the interpreter)."""
+    bert: dict
+    bert_batch: int
+    bert_seq: int
+    gpt: dict
+    num_slots: int
+    page_size: int
+    prefill_chunk: int
+    max_tokens_per_slot: int
+    prompt_lens: tuple          # request i has prompt_lens[i] tokens
+    shared_prefix: int          # the LAST request opens with this many
+    #                             tokens of request 0, and is submitted
+    #                             once request 0's prompt is in the cache
+    new_tokens: int
+    interpret: bool = False
+
+    @classmethod
+    def real(cls):
+        # prompts cross a page (16) and a prefill-chunk (32) boundary;
+        # five requests over four slots, so the last waits for a slot
+        return cls(bert={}, bert_batch=48, bert_seq=512, gpt={},
+                   num_slots=4, page_size=16, prefill_chunk=32,
+                   max_tokens_per_slot=128, prompt_lens=(40, 13, 70, 40, 40),
+                   shared_prefix=32, new_tokens=12)
+
+    @classmethod
+    def tiny(cls):
+        return cls(bert=dict(vocab_size=128, hidden_size=32, num_layers=2,
+                             num_heads=2, ffn_size=64, max_position=64),
+                   bert_batch=2, bert_seq=32,
+                   gpt=dict(vocab_size=128, hidden_size=32, num_layers=2,
+                            num_heads=4, ffn_size=64, max_position=64),
+                   num_slots=2, page_size=4, prefill_chunk=8,
+                   max_tokens_per_slot=16, prompt_lens=(10, 3, 9, 10),
+                   shared_prefix=8, new_tokens=5, interpret=True)
+
+    @property
+    def prefill_steps(self):
+        """engine steps until request 0's prompt is wholly cached (one
+        chunk per admitted request per step)."""
+        return -(-self.prompt_lens[0] // self.prefill_chunk)
+
+    @property
+    def kernel_impl(self):
+        return "pallas_interpret" if self.interpret else "pallas"
+
+
+def log(msg):
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def _dispatched(kernel, impl):
+    """How often ``kernel`` resolved to ``impl`` so far (trace-time
+    counter of ``kernels.dispatch``)."""
+    from paddle_tpu.observability import registry
+    return registry.counter("kernel_dispatch_total").value(
+        kernel=kernel, impl=impl)
+
+
+# ---------------------------------------------------------------------------
+# phase 1: paged kernels against their lax_fn, on the device
+# ---------------------------------------------------------------------------
+
+def phase_paged_kernels(sizes, seed):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import kernels
+    from paddle_tpu.models.gpt import GPTConfig
+    from paddle_tpu.serving.paged_cache import quantize_kv
+
+    cfg = GPTConfig(**sizes.gpt)
+    h, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    s, ps, c = sizes.num_slots, sizes.page_size, sizes.prefill_chunk
+    mp = -(-sizes.max_tokens_per_slot // ps)
+    num_pages = s * mp + 1
+    rng = np.random.default_rng(seed)
+    k32 = jnp.asarray(rng.standard_normal((num_pages, ps, h, dh)),
+                      jnp.float32)
+    v32 = jnp.asarray(rng.standard_normal((num_pages, ps, h, dh)),
+                      jnp.float32)
+    bt = jnp.asarray(rng.permutation(num_pages - 1)[:s * mp].reshape(s, mp)
+                     + 1, jnp.int32)
+    # slot 0 inactive, slot 1 mid-page, the rest anywhere up to full
+    lengths = rng.integers(1, mp * ps + 1, s)
+    lengths[0], lengths[1 % s] = 0, ps + 3
+    lengths = jnp.asarray(lengths, jnp.int32)
+    starts = jnp.asarray(rng.integers(0, (mp - 1) * ps - c + 1, s),
+                         jnp.int32)
+    n_valid = rng.integers(1, c + 1, s)
+    n_valid[0], n_valid[-1] = 0, c
+    n_valid = jnp.asarray(n_valid, jnp.int32)
+    q_dec = jnp.asarray(rng.standard_normal((s, h, dh)), jnp.float32)
+    q_pre = jnp.asarray(rng.standard_normal((s, c, h, dh)), jnp.float32)
+    kq, ks = quantize_kv(k32, (2, 3))
+    vq, vs = quantize_kv(v32, (2, 3))
+    pools = {
+        "f32": (k32, v32),
+        "bf16": (k32.astype(jnp.bfloat16), v32.astype(jnp.bfloat16)),
+        "int8": (kq, vq, ks, vs),
+    }
+    errs = {}
+    for label, pool in pools.items():
+        suffix = "_int8" if label == "int8" else ""
+        for name, q, geo in (
+                ("ragged_paged_decode" + suffix, q_dec, (lengths,)),
+                ("ragged_paged_prefill" + suffix, q_pre,
+                 (starts, n_valid))):
+            args = (q, *pool, bt, *geo)
+            contract = kernels.get(name).contract
+            out = jax.jit(lambda *a, _n=name: kernels.dispatch(
+                _n, *a, impl=sizes.kernel_impl))(*args)
+            # the reference in true fp32: the backend's default matmul
+            # precision is bf16-class and would drown the comparison
+            with jax.default_matmul_precision("highest"):
+                ref = jax.jit(lambda *a, _n=name: kernels.dispatch(
+                    _n, *a, impl="lax"))(*args)
+            out, ref = np.asarray(out), np.asarray(ref)
+            assert out.shape == ref.shape and np.isfinite(out).all(), name
+            err = float(np.max(np.abs(out - ref)))
+            errs[f"{name}[{label}]"] = err
+            np.testing.assert_allclose(
+                out, ref, atol=contract.atol, rtol=contract.rtol,
+                err_msg=f"{name}[{label}] {sizes.kernel_impl} vs lax")
+    log("paged kernels vs lax max|err|: " + json.dumps(errs))
+
+
+# ---------------------------------------------------------------------------
+# phase 2: the trainer
+# ---------------------------------------------------------------------------
+
+def _bert_setup(sizes, seed, **overrides):
+    """BERT-base with attention dropout off (the flash kernel has no
+    dropout path) and everything else as configured, unless
+    ``overrides`` (``BertConfig`` fields) says otherwise."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import optimizer as opt
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.models.bert import BertConfig, BertForPretraining
+    from paddle_tpu.train import build_train_step, make_train_state
+
+    kw = dict(sizes.bert, attn_dropout=0.0, **overrides)
+    if sizes.interpret:
+        kw["attn_impl"] = "flash_interpret"
+    cfg = BertConfig.base(**kw)
+    model = BertForPretraining(cfg)
+    optimizer = opt.AdamW(learning_rate=1e-4)
+    state = make_train_state(model, optimizer, jax.random.PRNGKey(seed))
+
+    def loss_fn(params, **batch):
+        return model.loss(params, training=True, **batch)
+
+    step = build_train_step(loss_fn, optimizer,
+                            policy=dtypes.get_policy("bf16"))
+    b, seq = sizes.bert_batch, sizes.bert_seq
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    batch = dict(
+        input_ids=jax.random.randint(k1, (b, seq), 0, cfg.vocab_size,
+                                     jnp.int32),
+        token_type_ids=jnp.zeros((b, seq), jnp.int32),
+        attention_mask=jnp.ones((b, seq), bool),
+        mlm_labels=jax.random.randint(k2, (b, seq), 0, cfg.vocab_size,
+                                      jnp.int32),
+        mlm_mask=(jax.random.uniform(k3, (b, seq)) < 0.15
+                  ).astype(jnp.float32),
+        nsp_labels=jnp.zeros((b,), jnp.int32),
+    )
+    return model, state, step, batch
+
+
+def _run_steps(run, state, batch, n, dropout_key=None):
+    """``n`` optimizer steps on ``batch``; with ``dropout_key``, step i
+    draws its dropout masks from ``fold_in(dropout_key, i)``."""
+    import jax
+    losses = []
+    for i in range(n):
+        if dropout_key is not None:
+            batch = dict(batch, key=jax.random.fold_in(dropout_key, i))
+        state, metrics = run(state, **batch)
+        losses.append(float(metrics["loss"]))
+    return state, losses
+
+
+def phase_trainer(sizes, seed):
+    import jax
+    flash_before = _dispatched("flash_attention", sizes.kernel_impl)
+    model, state, step, batch = _bert_setup(sizes, seed)
+    assert model.cfg.dropout > 0.0      # the dropout/RNG path runs too
+    step = jax.jit(step, donate_argnums=(0,))
+    t0 = time.perf_counter()
+    state, losses = _run_steps(step, state, batch, 5,
+                               dropout_key=jax.random.PRNGKey(seed + 3))
+    jax.block_until_ready(state)
+    dt = time.perf_counter() - t0
+    flash = _dispatched("flash_attention", sizes.kernel_impl) - flash_before
+    log(f"trainer: losses={[round(x, 4) for x in losses]} "
+        f"flash_attention[{sizes.kernel_impl}] dispatches={int(flash)} "
+        f"seconds={dt:.1f} (compile included)")
+    assert all(math.isfinite(x) for x in losses), losses
+    assert losses[4] < losses[0], f"loss did not fall: {losses}"
+    assert flash > 0, "BERT attention did not resolve to the flash kernel"
+
+
+# ---------------------------------------------------------------------------
+# phase 3: paged serving
+# ---------------------------------------------------------------------------
+
+def _gpt_setup(sizes, seed):
+    import jax
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    model = GPT(GPTConfig(**sizes.gpt))
+    params = model.init(jax.random.PRNGKey(seed))
+    return model, params
+
+
+def _prompts(sizes, vocab, seed):
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, vocab, n).astype(np.int32)
+               for n in sizes.prompt_lens]
+    prompts[-1][:sizes.shared_prefix] = prompts[0][:sizes.shared_prefix]
+    return prompts
+
+
+def _engine_kwargs(sizes):
+    kw = dict(num_slots=sizes.num_slots, page_size=sizes.page_size,
+              prefill_chunk=sizes.prefill_chunk,
+              max_tokens_per_slot=sizes.max_tokens_per_slot)
+    if sizes.interpret:
+        kw["attn_impl"] = "pallas_interpret"
+    return kw
+
+
+def _serve(eng, prompts, n, prefill_steps):
+    """Submit all but the last prompt, step until request 0's prompt is
+    prefilled (``prefill_steps``), submit the last (it shares request
+    0's prefix), step to idle; -> {index: tokens}."""
+    rids = {eng.submit(p, n): i for i, p in enumerate(prompts[:-1])}
+    got = {}
+    for _ in range(prefill_steps):
+        got.update(eng.step())
+    rids[eng.submit(prompts[-1], n)] = len(prompts) - 1
+    steps = 0
+    while not eng.scheduler.idle():
+        got.update(eng.step())
+        steps += 1
+        assert steps < 10_000, "engine never went idle"
+    assert set(got) == set(rids), f"unfinished requests: {set(rids) - set(got)}"
+    return {rids[r]: np.asarray(t) for r, t in got.items()}
+
+
+def _check_tokens(model, params, prompts, served, n, what):
+    """Engine tokens vs ``model.generate`` on the same device. A
+    mismatch is admitted only at a reference near-tie (TIE_MARGIN);
+    tokens after it are not compared (the sequences have forked)."""
+    import jax
+    gen = jax.jit(lambda p, ids: model.generate(
+        p, ids, max_new_tokens=n, use_cache=True))
+    fwd = jax.jit(model.forward)
+    exact = 0
+    for i, prompt in enumerate(prompts):
+        ref = np.asarray(gen(params, prompt[None]))[0, len(prompt):]
+        out = served[i]
+        assert out.shape == ref.shape, (i, out.shape, ref.shape)
+        diff = np.nonzero(out != ref)[0]
+        if diff.size == 0:
+            exact += 1
+            continue
+        pos = int(diff[0])
+        ids = np.concatenate([prompt, ref[:pos]]).astype(np.int32)
+        logits = np.asarray(fwd(params, ids[None]))[0, -1].astype(np.float64)
+        top = np.sort(logits)[-2:]
+        margin = float(logits.max() - logits[out[pos]])
+        log(f"{what}: request {i} first differs at new-token {pos}: "
+            f"engine={int(out[pos])} reference={int(ref[pos])} "
+            f"reference top-2 margin={float(top[1] - top[0]):.3e} "
+            f"top-1 minus engine's token={margin:.3e} "
+            f"(tolerance {TIE_MARGIN})")
+        assert margin < TIE_MARGIN, (
+            f"{what}: request {i} token {pos} is not a near-tie")
+    log(f"{what}: {exact}/{len(prompts)} requests token-exact vs "
+        "model.generate")
+
+
+def phase_serving(sizes, seed):
+    from paddle_tpu import inference
+    from paddle_tpu import observability as obs
+
+    model, params = _gpt_setup(sizes, seed)
+    prompts = _prompts(sizes, model.cfg.vocab_size, seed + 2)
+    n = sizes.new_tokens
+    impl = sizes.kernel_impl
+    names = ("ragged_paged_decode", "ragged_paged_prefill")
+    before = {(k, i): _dispatched(k, i) for k in names
+              for i in (impl, "lax")}
+    reg = obs.MetricsRegistry()
+    eng = inference.make_serving_engine(model, params, registry=reg,
+                                        **_engine_kwargs(sizes))
+    stats0 = dict(COMPILE_STATS)
+    t0 = time.perf_counter()
+    eng.warmup()
+    t_warm = time.perf_counter() - t0
+    log(f"serving: warmup {t_warm:.1f}s, of which JAX reports "
+        + json.dumps(_compile_stats_since(stats0)))
+    det = obs.RecompileDetector("chip_smoke_serving", warmup=0,
+                                registry=reg)
+    t0 = time.perf_counter()
+    served = _serve(eng, prompts, n, sizes.prefill_steps)
+    t_serve = time.perf_counter() - t0
+    det.check()
+    ran = {k: _dispatched(*k) - v for k, v in before.items()}
+    log(f"serving: warmup {len(eng.warmed_signatures)} signatures in "
+        f"{t_warm:.1f}s, {len(prompts)} requests in {t_serve:.2f}s, "
+        f"compiles after warmup={det.recompiles}, dispatches="
+        + json.dumps({f"{k}[{i}]": int(c) for (k, i), c in ran.items()}))
+    assert det.recompiles == 0, "serving compiled after warmup"
+    for k in names:
+        assert ran[(k, impl)] > 0, f"{k} never resolved to {impl}"
+        assert ran[(k, "lax")] == 0, f"{k} fell back to lax"
+    shared = reg.counter("serving_prompt_tokens_total").value() \
+        - reg.counter("serving_prefill_tokens_total").value()
+    assert shared > 0, "the shared prefix was prefilled twice"
+    _check_tokens(model, params, prompts, served, n, "serving")
+
+
+# ---------------------------------------------------------------------------
+# --chips 4: the sharded paths and what they are compared with
+# ---------------------------------------------------------------------------
+
+def _assert_spread(tree, devices, what):
+    """Some array of ``tree`` is sharded; every sharded one has shards
+    on ALL ``devices`` and no device holds the whole of it."""
+    import jax
+    sharded = 0
+    for x in jax.tree_util.tree_leaves(tree):
+        if x.sharding.is_fully_replicated:
+            continue
+        sharded += 1
+        held = {s.device for s in x.addressable_shards}
+        assert held == set(devices), (what, x.shape, held)
+        for s in x.addressable_shards:
+            assert math.prod(s.data.shape) < math.prod(x.shape), (
+                f"{what}: {s.device} holds all of a sharded {x.shape}")
+    assert sharded > 0, f"{what}: nothing is sharded"
+    return sharded
+
+
+def phase_sharded_trainer(sizes, seed, devices):
+    import jax
+    from paddle_tpu.core.mesh import MeshConfig, make_mesh, mesh_context
+    from paddle_tpu.parallel import api as papi, plan as plan_lib
+
+    # no dropout at all: the two runs must be the same function
+    model, state, step, batch = _bert_setup(sizes, seed, dropout=0.0)
+    mesh = make_mesh(MeshConfig(dp=2, tp=2), devices=devices)
+    with mesh_context(mesh):
+        run, state = papi.shard_train_step(
+            step, mesh, state, plan=plan_lib.megatron_plan(),
+            hints=model.sharding_specs(state["params"]),
+            batch_spec=papi.batch_specs(batch))
+        n = _assert_spread(state["params"], devices, "BERT params")
+        state, losses = _run_steps(run, state, batch, 3)
+        _assert_spread(state["params"], devices, "BERT params after steps")
+    # the same three steps from the same seed on ONE device of this host
+    # (a fresh state: placement may alias the buffers the steps donated)
+    _, state, step, batch = _bert_setup(sizes, seed, dropout=0.0)
+    assert {d for x in jax.tree_util.tree_leaves(state)
+            for d in x.devices()} == {devices[0]}
+    state, ref_losses = _run_steps(jax.jit(step, donate_argnums=(0,)),
+                                   state, batch, 3)
+    log(f"sharded trainer: dp2 x tp2 losses={[round(x, 4) for x in losses]}"
+        f" one-device losses={[round(x, 4) for x in ref_losses]} "
+        f"sharded param arrays={n}")
+    assert all(math.isfinite(x) for x in losses + ref_losses)
+    np.testing.assert_allclose(losses, ref_losses, rtol=MESH_LOSS_RTOL)
+
+
+def phase_tp_serving(sizes, seed, devices):
+    from paddle_tpu import inference
+
+    model, params = _gpt_setup(sizes, seed)
+    prompts = _prompts(sizes, model.cfg.vocab_size, seed + 2)
+    n = sizes.new_tokens
+    kw = _engine_kwargs(sizes)
+    served = {}
+    for tp in (1, 4):       # tp=4 takes the host's first four devices
+        eng = inference.make_serving_engine(model, params, tp=tp, **kw)
+        eng.warmup()
+        if tp > 1:
+            _assert_spread(eng.cache.pages, devices, "tp=4 page pool")
+            _assert_spread(eng._step_params, devices, "tp=4 step params")
+        served[tp] = _serve(eng, prompts, n, sizes.prefill_steps)
+    same = sum(bool(np.array_equal(served[1][i], served[4][i]))
+               for i in range(len(prompts)))
+    log(f"tp serving: {same}/{len(prompts)} requests token-equal, "
+        "tp=4 vs tp=1")
+    assert same == len(prompts), "tp=4 tokens differ from tp=1"
+
+
+# ---------------------------------------------------------------------------
+# entry points
+# ---------------------------------------------------------------------------
+
+def run_one_chip(sizes, seed=0):
+    """The one-chip phases, in order; raises on the first failure."""
+    phase_paged_kernels(sizes, seed)
+    phase_trainer(sizes, seed)
+    phase_serving(sizes, seed)
+
+
+def run_four_chips(sizes, seed=0, devices=None):
+    """The ``--chips 4`` phases and nothing of the one-chip run."""
+    import jax
+    devices = list(devices or jax.devices())[:4]
+    assert len(devices) == 4, f"need 4 devices, have {len(devices)}"
+    phase_sharded_trainer(sizes, seed, devices)
+    phase_tp_serving(sizes, seed, devices)
+
+
+#: what JAX itself reports while it compiles (``jax.monitoring``), summed
+#: over the process once :func:`_watch_compiles` is on: persistent-cache
+#: hits and misses, and seconds spent tracing, lowering to MLIR, in the
+#: backend compile (on a cache hit that is the read) and reading the
+#: cache. A jit traced inside another is counted in both.
+COMPILE_STATS = {"hits": 0, "misses": 0, "trace_s": 0.0, "lower_s": 0.0,
+                 "backend_compile_s": 0.0, "cache_read_s": 0.0}
+_EVENT_KEYS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+}
+
+
+def _watch_compiles():
+    import jax.monitoring
+
+    def on_event(event, **kw):
+        if event in _EVENT_KEYS:
+            COMPILE_STATS[_EVENT_KEYS[event]] += 1
+
+    def on_duration(event, seconds, **kw):
+        if event in _EVENT_KEYS:
+            COMPILE_STATS[_EVENT_KEYS[event]] += seconds
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def _compile_stats_since(before):
+    return {k: round(v - before[k], 1) for k, v in COMPILE_STATS.items()}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    import jax
+    found = jax.devices()
+    if found[0].platform != "tpu":
+        print(f"chip_smoke: needs a TPU, JAX found "
+              f"{found[0].platform!r}", file=sys.stderr)
+        return 1
+    if len(found) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} but JAX found "
+              f"{len(found)} device(s)", file=sys.stderr)
+        return 1
+    # the chips this run uses: the one-chip phases live on the default
+    # device (the first), --chips 4 on the first four
+    devices = found[:args.chips]
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    _watch_compiles()
+    log(f"device={devices[0].device_kind} count={len(devices)} "
+        f"(JAX found {len(found)}) compile cache={cache_dir}")
+    t0 = time.perf_counter()
+    if args.chips == 4:
+        run_four_chips(Sizes.real(), args.seed, devices)
+    else:
+        run_one_chip(Sizes.real(), args.seed)
+    log(f"all phases passed in {time.perf_counter() - t0:.1f}s; compile "
+        f"cache hits={COMPILE_STATS['hits']} "
+        f"misses={COMPILE_STATS['misses']}; peak_bytes_in_use="
+        f"{(devices[0].memory_stats() or {}).get('peak_bytes_in_use')}")
+    print(json.dumps({"ok": True, "device": {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices)}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -21,8 +21,8 @@ StableHLO module's operations to produce a :class:`CostReport`:
   ``collective_broadcast`` op is recorded with its payload bytes and
   replica-group shape, attributed to a mesh axis when ``mesh_axes``
   (``{axis_name: size}``) disambiguates the group size;
-- **resharding chains** — ``custom_call @Sharding`` sites whose result
-  flows (through elementwise ops) into another ``@Sharding`` site with
+- **resharding chains** — ``sdy.sharding_constraint`` sites whose result
+  flows (through elementwise ops) into another constraint site with
   a *different* sharding: the implicit transpose/all-to-all churn the
   ``resharding-churn`` lint rule reports.
 
@@ -170,8 +170,8 @@ class Collective:
 class ReshardSite:
     """A value resharded between two explicit sharding annotations."""
     bytes: int
-    src: str                  # mhlo.sharding of the producer
-    dst: str                  # mhlo.sharding of the consumer
+    src: str                  # sdy sharding of the producer
+    dst: str                  # sdy sharding of the consumer
     location: str = ""
 
     def as_dict(self) -> dict:
@@ -451,9 +451,7 @@ class _Walker:
                     _axis_for(gsize, self.mesh_axes), _short_loc(o)))
 
             # ---- sharding annotations (for churn chains) ----
-            if kind == "custom_call" and "call_target_name" in o.attributes \
-                    and str(o.attributes["call_target_name"]).strip('"') \
-                    == "Sharding" and "mhlo.sharding" in o.attributes:
+            if _constraint_sharding(o) is not None:
                 self._shard_ops.append(o)
             for v in o.operands:
                 self._users.setdefault(v, []).append(o)
@@ -500,15 +498,12 @@ class _Walker:
 
     # -- resharding chains --------------------------------------------------
     def _resharding_chains(self, report: CostReport) -> None:
-        """For every @Sharding site, follow its result forward through
-        elementwise ops; a different @Sharding downstream on a large
-        tensor is a resharding-churn site."""
-        def sharding_of(o) -> str:
-            return str(o.attributes["mhlo.sharding"]).strip('"')
-
+        """For every sharding-constraint site, follow its result forward
+        through elementwise ops; a different constraint downstream on a
+        large tensor is a resharding-churn site."""
         for src_op in self._shard_ops:
-            src = sharding_of(src_op)
-            if src in ("{manual}", "{replicated}"):
+            src = _constraint_sharding(src_op)
+            if _REPLICATED_SDY.fullmatch(src):
                 continue
             nb = _value_bytes(src_op.results[0])
             if nb < self.resharding_min_bytes:
@@ -524,13 +519,9 @@ class _Walker:
                         if user in seen:
                             continue
                         seen.add(user)
-                        if kind == "custom_call" and \
-                                "mhlo.sharding" in user.attributes and \
-                                "call_target_name" in user.attributes and \
-                                str(user.attributes["call_target_name"]
-                                    ).strip('"') == "Sharding":
-                            dst = sharding_of(user)
-                            if dst not in (src, "{manual}"):
+                        dst = _constraint_sharding(user)
+                        if dst is not None:
+                            if dst != src:
                                 report.resharding.append(ReshardSite(
                                     nb, src, dst, _short_loc(user)))
                             continue            # chain ends at a reshard
@@ -538,6 +529,19 @@ class _Walker:
                             nxt.extend(user.results)
                 frontier = nxt
                 depth += 1
+
+
+def _constraint_sharding(op) -> Optional[str]:
+    """The sharding an ``sdy.sharding_constraint`` op pins (how
+    ``with_sharding_constraint`` lowers under the Shardy partitioner),
+    as text; None for any other op."""
+    if op.name != "sdy.sharding_constraint":
+        return None
+    return str(op.attributes["sharding"])
+
+
+#: a constraint that shards no dimension: ``<@mesh, [{}, {}]>``
+_REPLICATED_SDY = re.compile(r"#sdy\.sharding<@\w+, \[(\{\??\}(, )?)*\]>")
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,7 @@ rules fire on hazards only visible in the *lowered* program —
   implicit cross-device sync the sharding specs accidentally created.
 - **resharding-churn** — adjacent sharding annotations that disagree on
   a large value's layout, forcing an implicit transpose/all-to-all
-  between them (detected as ``@Sharding``→``@Sharding`` chains by the
+  between them (detected as constraint→constraint chains by the
   cost walker).
 - **peak-hbm-budget** — the liveness-based peak-HBM estimate exceeds
   the preset's declared budget.

@@ -35,11 +35,14 @@ import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax.extend.core import Literal
 
 from paddle_tpu.analysis.findings import Finding, RULES
 
 HOST_CALLBACK_PRIMS = {"pure_callback", "io_callback"}
-DEBUG_CALLBACK_PRIMS = {"debug_callback"}
+# jax.debug.callback binds `debug_callback`, jax.debug.print its own
+# `debug_print`
+DEBUG_CALLBACK_PRIMS = {"debug_callback", "debug_print"}
 # primitives that DRAW from a key (consume its stream)
 KEY_DRAW_PRIMS = {"random_bits", "threefry2x32"}
 # primitives that DERIVE fresh independent keys (consuming is fine)
@@ -83,7 +86,7 @@ def _is_key_like(aval) -> bool:
 def _src(eqn) -> str:
     """User-frame source location of an equation, best effort."""
     try:
-        from jax._src import source_info_util
+        from jax.extend import source_info_util
         return source_info_util.summarize(eqn.source_info)
     except Exception:
         return ""
@@ -157,7 +160,7 @@ def analyze_jaxpr(
         nonlocal f64_seen
 
         def origin(v):
-            if isinstance(v, jax.core.Literal) or not hasattr(v, "aval"):
+            if isinstance(v, Literal) or not hasattr(v, "aval"):
                 return None
             if v in env:
                 return env[v]
@@ -184,7 +187,7 @@ def analyze_jaxpr(
             elif prim in DEBUG_CALLBACK_PRIMS:
                 findings.append(Finding(
                     "debug-callback", RULES["debug-callback"][0],
-                    "`debug_callback` (jax.debug.print/callback) in the "
+                    f"`{prim}` (jax.debug.print/callback) in the "
                     "traced step",
                     location=_where(prefix, i, eqn),
                     fix="strip jax.debug.* calls from production steps or "
@@ -233,7 +236,7 @@ def analyze_jaxpr(
         prim = eqn.primitive.name
         params = eqn.params
         tag = f"{prefix}eqn[{i}]:{prim}/"
-        if prim == "pjit" or prim in ("closed_call", "core_call", "call",
+        if prim == "jit" or prim in ("closed_call", "core_call", "call",
                                       "remat", "remat2", "checkpoint",
                                       "custom_jvp_call", "custom_vjp_call",
                                       "custom_vjp_call_jaxpr"):

@@ -1,8 +1,7 @@
-"""Version-compat shims over moving JAX APIs.
+"""Thin wrappers over the installed JAX's public spellings.
 
-The repo targets the newest public spellings; older jaxlibs (like the
-pinned 0.4.x here) keep working through these fallbacks so the same code
-runs on both sides of a JAX upgrade.
+There is one installation (the same JAX here and on the chip machine),
+so these are not version shims: they pin the defaults this repo wants.
 """
 
 from __future__ import annotations
@@ -11,26 +10,15 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` (new) / ``jax.experimental.shard_map`` (old).
-
-    ``check_vma=False`` (new name) == ``check_rep=False`` (old name):
-    these wrappers take logically-replicated inputs whose axis-invariance
-    the varying-axes checker cannot prove.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` with ``check_vma=False`` by default: the
+    callers take logically-replicated inputs whose axis-invariance the
+    varying-axes checker cannot prove."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis) -> int:
-    """``jax.lax.axis_size`` (new) / ``jax.core.axis_frame`` (old): the
-    STATIC size of a mapped mesh axis from inside shard_map/pmap —
+    """The STATIC size of a mapped mesh axis from inside shard_map —
     callers use it in Python control flow (``range(n)``), so it must be
     a concrete int, not a traced psum."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    frame = jax.core.axis_frame(axis)
-    return frame if isinstance(frame, int) else frame.size
+    return jax.lax.axis_size(axis)

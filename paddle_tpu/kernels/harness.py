@@ -8,7 +8,9 @@ ring, decode, and prefill kernels each carried. One entry point:
 
 ``impl`` is canonical across every kernel:
 
-- ``"auto"``    — Pallas on TPU, lax fallback elsewhere;
+- ``"auto"``    — Pallas on a TPU backend, lax on any other (that is
+  how the CPU tier-1 suite runs; an entry point that must be on the
+  chip asserts what :func:`resolve_impl` gave it);
 - ``"pallas"``  — the compiled Pallas body (TPU);
 - ``"pallas_interpret"`` — the SAME Pallas body run by the interpreter
   (CPU tier-1 tests exercise the real kernel logic);
@@ -34,21 +36,22 @@ import numpy as np
 
 from paddle_tpu.kernels import autotune as _autotune
 from paddle_tpu.kernels import registry as _registry
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from paddle_tpu.observability import registry as _obs_registry
 
 IMPLS = ("auto", "pallas", "pallas_interpret", "lax")
 
+# what "auto" became is otherwise invisible: counted per trace (host
+# code — a compiled steady-state step never comes back through here)
+_DISPATCHED = _obs_registry.counter(
+    "kernel_dispatch_total",
+    "kernel dispatches by resolved impl, counted at trace time")
+
 
 def on_tpu() -> bool:
-    """THE TPU probe (was private in four modules)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    """THE TPU probe (was private in four modules). A backend that
+    fails to initialise raises here — "no chip" is never read as "use
+    the lax path"."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def resolve_impl(impl: str) -> str:
@@ -57,10 +60,7 @@ def resolve_impl(impl: str) -> str:
         raise ValueError(f"unknown impl {impl!r} (expected "
                          f"{'|'.join(IMPLS)})")
     if impl == "auto":
-        return "pallas" if (pltpu is not None and on_tpu()) else "lax"
-    if impl in ("pallas", "pallas_interpret") and pltpu is None:
-        raise RuntimeError("Pallas TPU backend unavailable in this jax "
-                           "install; use impl='lax'")
+        return "pallas" if on_tpu() else "lax"
     return impl
 
 
@@ -73,6 +73,7 @@ def dispatch(name: str, *args, impl: str = "auto",
     overrides the process-wide cache (tests)."""
     spec = _registry.get(name)
     concrete = resolve_impl(impl)
+    _DISPATCHED.inc(kernel=name, impl=concrete)
     if concrete == "lax":
         return spec.lax_fn(*args, **kwargs)
     if block_sizes is None:

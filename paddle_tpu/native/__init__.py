@@ -8,6 +8,7 @@ as C-ABI shared libraries bound via ctypes (no pybind11 in this image).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,16 +19,33 @@ _LOCK = threading.Lock()
 _LIBS = {}
 
 
+def _sources_digest(srcs, extra_flags) -> str:
+    h = hashlib.sha256(" ".join(extra_flags).encode())
+    for s in srcs:
+        with open(s, "rb") as f:
+            h.update(hashlib.sha256(f.read()).digest())
+    return h.hexdigest()
+
+
 def build_library(name: str, sources, extra_flags=()) -> str:
-    """Compile sources into _build/lib<name>.so if stale; returns path."""
+    """Compile sources into _build/lib<name>.so if stale; returns path.
+
+    Stale means the sources' CONTENT (and flags) differ from what the
+    library was built from — a sha256 stamp beside the .so, not mtimes:
+    ``_build/`` is ignored by git but copied between machines as it lies
+    on disk, and a copy rewrites every mtime."""
     os.makedirs(_BUILD_DIR, exist_ok=True)
     out = os.path.join(_BUILD_DIR, f"lib{name}.so")
+    stamp = out + ".sha256"
     srcs = [os.path.join(_DIR, s) for s in sources]
-    if os.path.exists(out) and all(
-            os.path.getmtime(out) >= os.path.getmtime(s) for s in srcs):
-        return out
+    digest = _sources_digest(srcs, extra_flags)
+    if os.path.exists(out) and os.path.exists(stamp):
+        with open(stamp) as f:
+            if f.read().strip() == digest:
+                return out
     # compile to a temp name, then atomic-rename: a concurrent process must
-    # never dlopen a half-written .so
+    # never dlopen a half-written .so (the stamp lands after the library,
+    # so a reader that sees the new stamp sees the new library)
     tmp = f"{out}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
            *extra_flags, *srcs, "-o", tmp]
@@ -36,6 +54,9 @@ def build_library(name: str, sources, extra_flags=()) -> str:
         raise RuntimeError(f"native build failed: {' '.join(cmd)}\n"
                            f"{proc.stderr}")
     os.replace(tmp, out)
+    with open(tmp, "w") as f:
+        f.write(digest + "\n")
+    os.replace(tmp, stamp)
     return out
 
 

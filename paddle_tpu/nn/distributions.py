@@ -43,10 +43,8 @@ _default_stream = iter(range(1 << 62))
 
 
 def _tracing() -> bool:
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:   # renamed/removed in some jax versions
-        return False
+    """Inside any jax trace (jit/grad/vmap/scan)?"""
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def _key(key, seed):

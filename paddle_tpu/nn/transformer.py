@@ -12,6 +12,8 @@ psum at the block output (inserted by GSPMD).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -34,6 +36,60 @@ def _constrain(x, spec):
     except (ValueError, RuntimeError):
         # outside a mesh context (single-device eager) constraints are moot
         return x
+
+
+def _kernel_spec(mesh, shape):
+    """``HEADS_SPEC`` cut to this mesh and this (B, H, S, Dh) shape: an
+    axis stays only if the mesh has it, and a dimension is split only
+    where its axes divide it — what does not divide stays whole on
+    every shard, as ``_constrain`` leaves it."""
+    entries = []
+    for dim, axes in zip(shape, HEADS_SPEC):
+        axes = (axes,) if isinstance(axes, str) else (axes or ())
+        axes = tuple(a for a in axes if a in mesh.shape)
+        n = math.prod(mesh.shape[a] for a in axes)
+        entries.append(axes if n > 1 and dim % n == 0 else None)
+    return P(*entries)
+
+
+def _attend(q, k, v, bias, *, causal, dropout_rate, dropout_key, impl):
+    """:func:`ops.attention.dot_product_attention` for (B, H, S, Dh)
+    heads laid out by ``HEADS_SPEC``. The flash kernel is a Mosaic
+    custom call, which the SPMD partitioner cannot split ("Mosaic
+    kernels cannot be automatically partitioned"): where the
+    partitioner would see it — a multi-device mesh is current and no
+    enclosing ``shard_map`` (a pipeline stage body) has made the axes
+    manual already — it runs per (batch, head) shard inside
+    ``shard_map``. Batches and heads are independent, so there is no
+    collective and each shard's output is what the unsharded kernel
+    gives for its slice. The composed XLA path partitions by itself."""
+    from paddle_tpu.core import mesh as mesh_lib
+    impl = ops_attn.resolve_attention_impl(impl, dropout_rate)
+    if impl == "xla":
+        return ops_attn.scaled_dot_product_attention(
+            q, k, v, bias=bias, causal=causal, dropout_rate=dropout_rate,
+            dropout_key=dropout_key)
+
+    def kernel(q, k, v, bias=None):
+        return ops_attn.flash_attention_dispatch(
+            q, k, v, bias, impl=impl, causal=causal)
+
+    mesh = mesh_lib.current_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return kernel(q, k, v, bias)
+    from paddle_tpu.core.compat import shard_map
+    spec = _kernel_spec(mesh, q.shape)
+    args, specs = (q, k, v), (spec,) * 3
+    if bias is not None:
+        if bias.ndim < 4:   # accept broadcastable ranks, like the kernel
+            bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
+        # a broadcast (size-1) batch or head dim stays whole on every shard
+        specs += (P(*(ax if bias.shape[d] != 1 else None
+                      for d, ax in enumerate(spec))),)
+        args += (bias,)
+    return shard_map(kernel, mesh=mesh, in_specs=specs,
+                     out_specs=spec)(*args)
 
 
 class MultiHeadAttention(Layer):
@@ -166,9 +222,9 @@ class MultiHeadAttention(Layer):
             from paddle_tpu.parallel.ring_attention import ring_attention
             out = ring_attention(q, k, v, bias=bias, causal=self.causal)
         else:
-            out = ops_attn.dot_product_attention(
-                q, k, v, bias=bias, causal=self.causal,
-                dropout_rate=drop_rate, dropout_key=key, impl=self.attn_impl)
+            out = _attend(q, k, v, bias, causal=self.causal,
+                          dropout_rate=drop_rate, dropout_key=key,
+                          impl=self.attn_impl)
         out = self._merge_heads(out)
         out = self.out_proj(params["out_proj"], out)
         out = _constrain(out, ACT_SPEC)

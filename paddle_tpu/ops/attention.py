@@ -26,11 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pallas backend; present in jax>=0.4 installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps softmax NaN-free
@@ -296,7 +292,7 @@ def _flash_fwd(q, k, v, bias, *, scale, causal, block_q, block_k, interpret,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ) if (pltpu is not None and not interpret) else None,
+        ) if not interpret else None,
         interpret=interpret,
     )(*args)
     if return_lse:
@@ -516,7 +512,7 @@ def _flash_bwd(q, k, v, bias, out, lse, g, *, scale, causal,
                   seq_q=sq, seq_k=sk)
     cparams = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
-    ) if (pltpu is not None and not interpret) else None
+    ) if not interpret else None
 
     # dk/dv: grid (bh, nk, nq) — i = kv block, j = q block
     dkv_kernel = functools.partial(
@@ -611,9 +607,6 @@ def flash_attention(q, k, v, bias=None, causal=False,
     p from the forward's logsumexp). Key-padding biases (Sq dim == 1) are
     treated as constants (zero cotangent); full (Sq,Sk) biases take the
     XLA recompute path so trainable relative-position biases get grads."""
-    if pltpu is None:
-        raise RuntimeError("Pallas TPU backend unavailable in this jax "
-                           "install; use impl='xla'")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if bias is not None and bias.ndim < 4:  # accept broadcastable ranks
@@ -623,9 +616,6 @@ def flash_attention(q, k, v, bias=None, causal=False,
 
 
 def _flash_vjp_fwd(q, k, v, bias, causal, scale, block_q, block_k, interpret):
-    if pltpu is None:
-        raise RuntimeError("Pallas TPU backend unavailable in this jax "
-                           "install; use impl='xla'")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     bias4 = bias
@@ -670,9 +660,36 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # dispatch
 # ---------------------------------------------------------------------------
 
-def _on_tpu() -> bool:
-    from paddle_tpu.kernels import harness
-    return harness.on_tpu()
+_dropout_exit_logged = False
+
+
+def _note_dropout_exit(impl: str, dropout_rate: float):
+    """Attention dropout has no kernel path: the composed XLA attention
+    runs instead. Say so, once, so a trainer with attention dropout on
+    does not pass for a kernel run (``kernel_dispatch_total`` then
+    counts no ``flash_attention``)."""
+    global _dropout_exit_logged
+    if not _dropout_exit_logged:
+        _dropout_exit_logged = True
+        import logging
+        logging.getLogger("paddle_tpu").warning(
+            "attention: dropout_rate=%g has no flash-kernel path; "
+            "impl=%r runs the composed XLA attention instead",
+            dropout_rate, impl)
+
+
+def resolve_attention_impl(impl: str, dropout_rate: float = 0.0) -> str:
+    """What :func:`dot_product_attention` will run for ``impl``:
+    ``"xla"`` (the composed path), ``"flash"`` or ``"flash_interpret"``.
+    ``"auto"`` is flash on a TPU backend and xla on any other; attention
+    dropout always takes the composed path (and says so)."""
+    if impl == "auto":
+        from paddle_tpu import kernels
+        impl = "flash" if kernels.on_tpu() else "xla"
+    if impl != "xla" and dropout_rate > 0.0:
+        _note_dropout_exit(impl, dropout_rate)
+        return "xla"
+    return impl
 
 
 def dot_product_attention(q, k, v, *, bias=None, causal=False,
@@ -680,18 +697,26 @@ def dot_product_attention(q, k, v, *, bias=None, causal=False,
                           impl: str = "auto"):
     """Attention entry point used by nn layers.
 
-    impl: "auto" (flash on TPU, xla elsewhere), "flash", "xla",
-    "flash_interpret" (tests). The flash impls dispatch through the
-    shared kernel registry (:mod:`paddle_tpu.kernels`): block sizes
-    resolve from the autotuner cache at trace time.
+    impl: "auto" (flash on a TPU backend, xla on any other), "flash",
+    "xla", "flash_interpret" (tests). The flash impls dispatch through
+    the shared kernel registry (:mod:`paddle_tpu.kernels`): block sizes
+    resolve from the autotuner cache at trace time, and
+    ``kernel_dispatch_total`` counts what ran. Attention dropout always
+    takes the composed path (see :func:`_note_dropout_exit`).
     """
-    if impl == "auto":
-        impl = "flash" if (pltpu is not None and _on_tpu()
-                           and dropout_rate == 0.0) else "xla"
-    if impl == "xla" or dropout_rate > 0.0:
+    impl = resolve_attention_impl(impl, dropout_rate)
+    if impl == "xla":
         return scaled_dot_product_attention(
             q, k, v, bias=bias, causal=causal, scale=scale,
             dropout_rate=dropout_rate, dropout_key=dropout_key)
+    return flash_attention_dispatch(q, k, v, bias, impl=impl,
+                                    causal=causal, scale=scale)
+
+
+def flash_attention_dispatch(q, k, v, bias=None, *, impl, causal=False,
+                             scale=None):
+    """The flash kernel through the kernel registry, for an ``impl``
+    already resolved to ``"flash"`` or ``"flash_interpret"``."""
     from paddle_tpu import kernels
     return kernels.dispatch(
         "flash_attention", q, k, v, bias,

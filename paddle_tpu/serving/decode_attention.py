@@ -27,9 +27,10 @@ Each has two implementations with identical numerics:
 
 - ``impl="lax"``: XLA gather + masked softmax (CPU/debug reference).
 - ``impl="pallas"`` / ``"pallas_interpret"``: a Pallas kernel, grid
-  ``(S, H, cdiv(max_pages, pages_per_block))``, that scalar-prefetches
+  ``(S, cdiv(max_pages, pages_per_block))``, that scalar-prefetches
   the block table so each kv block's HBM address is known before the
-  body runs (the PrefetchScalarGridSpec pattern), does online-softmax
+  body runs (the PrefetchScalarGridSpec pattern), streams WHOLE pages
+  (every head; the head loop is inside the body), does online-softmax
   accumulation over pages, and skips pages past the slot's live extent
   entirely. The interpret path runs the REAL kernel on CPU, so tier-1
   tests exercise it.
@@ -77,18 +78,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU pallas backend (interpret mode still works without a TPU)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.attention import NEG_INF
-
-
-def _on_tpu() -> bool:
-    from paddle_tpu.kernels import harness
-    return harness.on_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -119,55 +111,87 @@ def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (S, H, max_pages), block-table scalar prefetch
+# Pallas kernels: grid (S, cdiv(max_pages, pb)), block-table scalar prefetch
 # ---------------------------------------------------------------------------
+#
+# Block shapes the TPU compiler accepts (the last two dims of every block
+# equal the array's own, or are (8k, 128k)-aligned), with the stored page
+# layout (P, ps, H, Dh) left alone:
+#
+#   pages    block (1, ps, H, Dh)   one WHOLE page, every head — the HBM
+#                                   tiles of a page move to VMEM as they
+#                                   lie, no relayout of the pool
+#   q / out  block (1, H, R, Dh)    head-major, R = 1 (decode) or C
+#                                   (prefill); the wrappers transpose the
+#                                   small activations, never the pool
+#   scales   block (8, ps)          the 8-row group holding the page's
+#                                   scale row; the body picks row
+#                                   ``page % 8`` (a (1, ps) block over
+#                                   (P, ps) is refused: 1 is neither 8-
+#                                   aligned nor the full P). Where P is
+#                                   no multiple of 8 the last group runs
+#                                   past the array (with P < 8, the only
+#                                   group does): those rows are padding
+#                                   no page number ever picks
+#
+# The head loop is inside the body: one grid step folds ``pb`` pages into
+# EVERY head's (m, l, acc) state, kept per head in (H, R, .) scratch.
 
-def _online_softmax_page_fold(q, k_ref, v_ref, mask, m_scr, l_scr,
-                              acc_scr, k_scale=None, v_scale=None):
-    """Fold ONE (ps, H-sliced) kv page into the running (m, l, acc)
-    online-softmax state. ``mask`` (rows, ps) marks live score entries;
-    masked entries go to NEG_INF and contribute exact zeros. Shared by
-    the decode and prefill kernels — the accumulation order here IS the
-    byte-parity contract, so it must not diverge between them.
+_SCALE_ROWS = 8     # f32 sublane tile: scale rows stream in groups of 8
 
-    ``k_scale``/``v_scale`` (ps,) are the int8 page pool's per-token-row
-    dequant scales (None on the fp path): the scale broadcast is fused
-    INTO the QK and PV products — the int8 page goes straight into the
-    dot and the per-token scale multiplies the (rows, ps) score/weight
-    matrix, so no dequantized fp page is ever materialized (the TPP
-    fused-microkernel shape). The m/l/acc update sequence is identical
-    either way, so the int8 kernels inherit the same per-page
-    accumulation-order contract."""
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (ps, Dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)          # (ps, Dh)
+#: the fold's dots run in true fp32. Mosaic's DEFAULT for fp32 operands
+#: is a single bf16 pass (~3e-3 abs error at GPT-2 widths, measured on a
+#: v5e), which is outside every paged contract's tolerance — the
+#: interpreter never shows it.
+_FP32_DOT = jax.lax.Precision.HIGHEST
+
+
+def _online_softmax_page_fold(q, k, v, mask, m_scr, l_scr, acc_scr, h,
+                              k_scale=None, v_scale=None):
+    """Fold ONE head's (ps, Dh) slice of a kv page into head ``h``'s
+    running (m, l, acc) online-softmax state. ``mask`` (rows, ps) marks
+    live score entries; masked entries go to NEG_INF and contribute
+    exact zeros. Shared by the decode and prefill kernels — the
+    accumulation order here IS the byte-parity contract, so it must not
+    diverge between them.
+
+    ``k_scale``/``v_scale`` (1, ps) are the int8 page pool's
+    per-token-row dequant scales (None on the fp path): the scale
+    broadcast is fused INTO the QK and PV products — the int8 page goes
+    straight into the dot and the per-token scale multiplies the
+    (rows, ps) score/weight matrix, so no dequantized fp page is ever
+    materialized (the TPP fused-microkernel shape). The m/l/acc update
+    sequence is identical either way, so the int8 kernels inherit the
+    same per-page accumulation-order contract."""
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q, k, (((1,), (1,)), ((), ())), precision=_FP32_DOT,
         preferred_element_type=jnp.float32)            # (rows, ps)
     if k_scale is not None:
-        s = s * k_scale[None, :]
+        s = s * k_scale
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[...]                                # (rows, 128)
-    l_prev = l_scr[...]
+    m_prev = m_scr[h]                                  # (rows, 128)
+    l_prev = l_scr[h]
     m_cur = jnp.max(s, axis=1, keepdims=True)          # (rows, 1)
     m_next = jnp.maximum(m_prev, m_cur)                # lanes broadcast
     alpha = jnp.exp(m_prev - m_next)
     p = jnp.exp(s - m_next[:, :1])                     # (rows, ps)
-    l_scr[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_scr[...] = m_next
+    l_scr[h] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[h] = m_next
     if v_scale is not None:
-        p = p * v_scale[None, :]
+        p = p * v_scale
     pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        p, v, (((1,), (0,)), ((), ())), precision=_FP32_DOT,
         preferred_element_type=jnp.float32)            # (rows, Dh)
-    acc_scr[...] = acc_scr[...] * alpha[:, :1] + pv
+    acc_scr[h] = acc_scr[h] * alpha[:, :1] + pv
 
 
 def _split_kv_refs(rest, pb, quantized):
     """Unpack a paged kernel's trailing refs: ``pb`` k blocks, ``pb`` v
-    blocks, (quantized only) ``pb`` k-scale + ``pb`` v-scale rows, then
-    the output ref and the three online-softmax scratch buffers. ONE
-    unpacking convention for the fp and int8 variants of both kernels."""
+    blocks, (quantized only) ``pb`` k-scale + ``pb`` v-scale row groups,
+    then the output ref and the three online-softmax scratch buffers.
+    ONE unpacking convention for the fp and int8 variants of both
+    kernels."""
     k_refs = rest[:pb]
     v_refs = rest[pb:2 * pb]
     if quantized:
@@ -182,22 +206,37 @@ def _split_kv_refs(rest, pb, quantized):
     return k_refs, v_refs, ks_refs, vs_refs, o_ref, m_scr, l_scr, acc_scr
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
-                         pages_per_block, quantized=False):
-    """Online-softmax over a slot's pages, ``pages_per_block`` pages per
-    grid step (the shared autotuner's tunable: fewer grid iterations,
-    deeper DMA pipelining; the per-page accumulation ORDER is identical
-    to pages_per_block=1, so outputs are bit-equal for any setting).
-    ``quantized`` is ONE static flag, not a second kernel: the int8
-    page blocks ride with their per-token scale rows and the scales
-    fuse into the shared fold — grid, ragged skip, and finish logic
-    cannot diverge between the fp and dequant-attend variants."""
+def _paged_attend_kernel(bt_ref, *refs, page_size, pages_per_block,
+                         chunked, quantized=False):
+    """THE paged-attention body: online-softmax over a slot's pages,
+    ``pages_per_block`` pages per grid step (the shared autotuner's
+    tunable: fewer grid iterations, deeper DMA pipelining; the per-page
+    accumulation ORDER is identical to pages_per_block=1, so outputs
+    are bit-equal for any setting), every head of the page folded in
+    turn. Decode and chunked prefill are ONE body with a static
+    ``chunked`` flag — they differ only in their scalar-prefetch
+    geometry and the mask built from it:
+
+    - decode (``len_ref``): one query row per head, key ``tok`` live
+      iff ``tok < lengths[s]``;
+    - prefill (``start_ref``, ``nv_ref``): C query rows per head, row
+      ``r`` live iff ``r < n_valid[s]``, attending causally to
+      ``tok <= chunk_starts[s] + r``.
+
+    ``quantized`` is likewise ONE static flag, not a second kernel: the
+    int8 page blocks ride with their per-token scale rows and the
+    scales fuse into the shared fold — grid, ragged skip, and finish
+    logic cannot diverge between the fp and dequant-attend variants."""
     pb = pages_per_block
+    n_geo = 2 if chunked else 1
+    geo, q_ref, rest = refs[:n_geo], refs[n_geo], refs[n_geo + 1:]
     (k_refs, v_refs, ks_refs, vs_refs, o_ref, m_scr, l_scr,
      acc_scr) = _split_kv_refs(rest, pb, quantized)
     sl = pl.program_id(0)
-    pj = pl.program_id(2)
-    npg = pl.num_programs(2)
+    pj = pl.program_id(1)
+    npg = pl.num_programs(1)
+    n_heads, rows = q_ref.shape[1], q_ref.shape[2]
+    mp = bt_ref.shape[1]
 
     @pl.when(pj == 0)
     def _init():
@@ -205,114 +244,153 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[sl]
+    if chunked:
+        start, nv = geo[0][sl], geo[1][sl]
+        extent = start + nv                  # tokens this chunk can see
+        has_work = (nv > 0) & (pj * pb * page_size < extent)
+    else:
+        extent = geo[0][sl]
+        has_work = pj * pb * page_size < extent
 
     def _body():
-        q = q_ref[0].astype(jnp.float32)               # (1, Dh)
         for t in range(pb):
-            # tokens at/after the slot's length (incl. whole tail pages
-            # of this block, and the clamped duplicate page when pb does
-            # not divide max_pages) mask to NEG_INF -> exact-zero
-            # contributions to l and acc
+            # tokens at/after the slot's live extent (incl. whole tail
+            # pages of this block, and the clamped duplicate page when
+            # pb does not divide max_pages) mask to NEG_INF ->
+            # exact-zero contributions to l and acc
             tok = (pj * pb + t) * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (1, page_size), 1)
-            _online_softmax_page_fold(
-                q, k_refs[t], v_refs[t], tok < length, m_scr, l_scr,
-                acc_scr,
-                k_scale=ks_refs[t][0, :] if quantized else None,
-                v_scale=vs_refs[t][0, :] if quantized else None)
+                jnp.int32, (rows, page_size), 1)
+            if chunked:
+                row = jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, page_size), 0)
+                ok = (tok <= start + row) & (row < nv)  # causal + live
+            else:
+                ok = tok < extent
+            k_scale = v_scale = None
+            if quantized:
+                # the page's scale row inside its streamed 8-row group
+                page = bt_ref[sl, jnp.minimum(pj * pb + t, mp - 1)]
+                r = page % _SCALE_ROWS
+                k_scale = ks_refs[t][pl.ds(r, 1), :]    # (1, ps)
+                v_scale = vs_refs[t][pl.ds(r, 1), :]
+            for h in range(n_heads):
+                _online_softmax_page_fold(
+                    q_ref[0, h].astype(jnp.float32),            # (R, Dh)
+                    k_refs[t][0, :, h, :].astype(jnp.float32),  # (ps, Dh)
+                    v_refs[t][0, :, h, :].astype(jnp.float32),
+                    ok, m_scr, l_scr, acc_scr, h,
+                    k_scale=k_scale, v_scale=v_scale)
 
-    # ragged skip: blocks wholly at/after the slot's length do nothing
-    pl.when(pj * pb * page_size < length)(_body)
+    # ragged skip: blocks wholly past the slot's live extent do nothing
+    pl.when(has_work)(_body)
 
     @pl.when(pj == npg - 1)
     def _finish():
-        denom = l_scr[...][:, :1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        alive = m_scr[...][:, :1] > NEG_INF / 2
-        o_ref[0] = jnp.where(alive, acc_scr[...] / denom, 0.0).astype(
-            o_ref.dtype)
+        for h in range(n_heads):
+            denom = l_scr[h][:, :1]
+            denom = jnp.where(denom == 0.0, 1.0, denom)
+            alive = m_scr[h][:, :1] > NEG_INF / 2
+            o_ref[0, h] = jnp.where(
+                alive, acc_scr[h] / denom, 0.0).astype(o_ref.dtype)
 
 
-def _paged_kv_specs(ps, dh, mp, pb):
-    """``pb`` (k, v) BlockSpec pairs per grid step: page ``j*pb + t`` of
-    the slot's block table (clamped to the last page — the clamped
-    duplicate is fully masked by the token test in the kernel body).
-    The index maps take the scalar-prefetch refs after the grid ids;
-    the block table is always the first of them."""
+def _paged_kv_specs(ps, h, dh, mp, pb):
+    """``pb`` (k, v) BlockSpec pairs per grid step: the WHOLE page
+    ``j*pb + t`` of the slot's block table, all heads (clamped to the
+    last page — the clamped duplicate is fully masked by the token test
+    in the kernel body). The index maps take the scalar-prefetch refs
+    after the grid ids; the block table is always the first of them."""
     def kv_spec(t):
-        def index(s, hh, j, bt, *_rest):
-            return (bt[s, jnp.minimum(j * pb + t, mp - 1)], 0, hh, 0)
-        return pl.BlockSpec((1, ps, 1, dh), index)
+        def index(s, j, bt, *_rest):
+            return (bt[s, jnp.minimum(j * pb + t, mp - 1)], 0, 0, 0)
+        return pl.BlockSpec((1, ps, h, dh), index)
     ks = [kv_spec(t) for t in range(pb)]
     vs = [kv_spec(t) for t in range(pb)]
     return ks, vs
 
 
 def _paged_scale_specs(ps, mp, pb):
-    """``pb`` (k_scale, v_scale) BlockSpec pairs — one (1, ps) scale row
-    per streamed page, indexed by the SAME block-table entry as the page
-    itself, so a page and its dequant scales always arrive together."""
+    """``pb`` (k_scale, v_scale) BlockSpec pairs — the 8-row group that
+    holds the streamed page's (ps,) scale row, indexed by the SAME
+    block-table entry as the page itself, so a page and its dequant
+    scales always arrive together (the body reads row ``page % 8``)."""
     def sc_spec(t):
-        def index(s, hh, j, bt, *_rest):
-            return (bt[s, jnp.minimum(j * pb + t, mp - 1)], 0)
-        return pl.BlockSpec((1, ps), index)
+        def index(s, j, bt, *_rest):
+            return (bt[s, jnp.minimum(j * pb + t, mp - 1)]
+                    // _SCALE_ROWS, 0)
+        return pl.BlockSpec((_SCALE_ROWS, ps), index)
     ks = [sc_spec(t) for t in range(pb)]
     vs = [sc_spec(t) for t in range(pb)]
     return ks, vs
 
 
-def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
-                         interpret, pages_per_block=1, k_scales=None,
-                         v_scales=None):
-    """``k_scales``/``v_scales`` given = the dequant-attend variant:
-    same grid and BlockSpecs plus one (1, ps) scale row per streamed
-    page, fused into the shared fold inside the ONE kernel body."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("Pallas TPU backend unavailable; use impl='lax'")
+def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
+                         interpret, pages_per_block, k_scales, v_scales):
+    """The one ``pallas_call`` behind all four paged kernels. ``q`` is
+    head-major ``(S, H, R, Dh)`` and already scaled; ``geometry`` is the
+    scalar-prefetch tail after the block table — ``(lengths,)`` for
+    decode, ``(chunk_starts, n_valid)`` for chunked prefill.
+    ``k_scales``/``v_scales`` given = the dequant-attend variant."""
     quantized = k_scales is not None
-    s_slots, h, dh = q.shape
+    chunked = len(geometry) == 2
+    s_slots, h, rows, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
     pb = max(1, min(int(pages_per_block), mp))
-    qs = (q * jnp.asarray(scale, q.dtype))
-    k_specs, v_specs = _paged_kv_specs(ps, dh, mp, pb)
+    k_specs, v_specs = _paged_kv_specs(ps, h, dh, mp, pb)
     sc_specs, sc_args = [], []
     if quantized:
         ks_specs, vs_specs = _paged_scale_specs(ps, mp, pb)
         sc_specs = [*ks_specs, *vs_specs]
         sc_args = [*([k_scales] * pb), *([v_scales] * pb)]
 
+    def q_index(s, j, *_prefetch):
+        return (s, 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, lengths
-        grid=(s_slots, h, pl.cdiv(mp, pb)),
+        num_scalar_prefetch=1 + len(geometry),
+        grid=(s_slots, pl.cdiv(mp, pb)),
         in_specs=[
-            pl.BlockSpec((1, 1, dh), lambda s, hh, j, bt, ln: (s, hh, 0)),
+            pl.BlockSpec((1, h, rows, dh), q_index),
             *k_specs,
             *v_specs,
             *sc_specs,
         ],
-        out_specs=pl.BlockSpec((1, 1, dh),
-                               lambda s, hh, j, bt, ln: (s, hh, 0)),
+        out_specs=pl.BlockSpec((1, h, rows, dh), q_index),
         scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, dh), jnp.float32),
+            pltpu.VMEM((h, rows, 128), jnp.float32),
+            pltpu.VMEM((h, rows, 128), jnp.float32),
+            pltpu.VMEM((h, rows, dh), jnp.float32),
         ],
     )
-    kernel = functools.partial(_paged_decode_kernel, page_size=ps,
-                               pages_per_block=pb, quantized=quantized)
-    out = pl.pallas_call(
+    kernel = functools.partial(_paged_attend_kernel, page_size=ps,
+                               pages_per_block=pb, chunked=chunked,
+                               quantized=quantized)
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_slots, h, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qs, *([k_pages] * pb), *([v_pages] * pb), *sc_args)
-    return out
+        name="ragged_paged_prefill" if chunked else "ragged_paged_decode",
+    )(block_tables.astype(jnp.int32),
+      *(g.astype(jnp.int32) for g in geometry),
+      q, *([k_pages] * pb), *([v_pages] * pb), *sc_args)
+
+
+def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
+                         interpret, pages_per_block=1, k_scales=None,
+                         v_scales=None):
+    """``k_scales``/``v_scales`` given = the dequant-attend variant:
+    same grid and BlockSpecs plus one scale-row group per streamed
+    page, fused into the shared fold inside the ONE kernel body."""
+    qs = (q * jnp.asarray(scale, q.dtype))[:, :, None, :]   # (S,H,1,Dh)
+    out = _paged_attend_pallas(qs, k_pages, v_pages, block_tables,
+                               (lengths,), interpret, pages_per_block,
+                               k_scales, v_scales)
+    return out[:, :, 0, :]
 
 
 # ---------------------------------------------------------------------------
@@ -393,105 +471,19 @@ def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
     return out.astype(q.dtype)
 
 
-def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
-                          page_size, pages_per_block, quantized=False):
-    """Chunked-prefill analog of :func:`_paged_decode_kernel`: same
-    ``pages_per_block`` tunable, same bit-equal accumulation order, and
-    the same single ``quantized`` flag for the dequant-attend variant
-    (scale rows fused into the shared fold)."""
-    pb = pages_per_block
-    (k_refs, v_refs, ks_refs, vs_refs, o_ref, m_scr, l_scr,
-     acc_scr) = _split_kv_refs(rest, pb, quantized)
-    sl = pl.program_id(0)
-    pj = pl.program_id(2)
-    npg = pl.num_programs(2)
-
-    @pl.when(pj == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    start = start_ref[sl]
-    nv = nv_ref[sl]
-
-    def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)      # (C, Dh)
-        cc = q.shape[0]
-        for t in range(pb):
-            tok = (pj * pb + t) * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (cc, page_size), 1)
-            row = jax.lax.broadcasted_iota(jnp.int32, (cc, page_size), 0)
-            ok = (tok <= start + row) & (row < nv)     # causal + live lane
-            _online_softmax_page_fold(
-                q, k_refs[t], v_refs[t], ok, m_scr, l_scr, acc_scr,
-                k_scale=ks_refs[t][0, :] if quantized else None,
-                v_scale=vs_refs[t][0, :] if quantized else None)
-
-    # ragged skip: blocks wholly past the chunk's live extent do nothing
-    pl.when((nv > 0) & (pj * pb * page_size < start + nv))(_body)
-
-    @pl.when(pj == npg - 1)
-    def _finish():
-        denom = l_scr[...][:, :1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        alive = m_scr[...][:, :1] > NEG_INF / 2
-        o_ref[0, :, 0, :] = jnp.where(
-            alive, acc_scr[...] / denom, 0.0).astype(o_ref.dtype)
-
-
 def _paged_prefill_pallas(q, k_pages, v_pages, block_tables, chunk_starts,
                           n_valid, scale, interpret, pages_per_block=1,
                           k_scales=None, v_scales=None):
-    """``k_scales``/``v_scales`` given = the dequant-attend variant
-    (same convention as :func:`_paged_decode_pallas`)."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("Pallas TPU backend unavailable; use impl='lax'")
-    quantized = k_scales is not None
-    s_slots, c, h, dh = q.shape
-    mp = block_tables.shape[1]
-    ps = k_pages.shape[1]
-    pb = max(1, min(int(pages_per_block), mp))
-    qs = (q * jnp.asarray(scale, q.dtype))
-    k_specs, v_specs = _paged_kv_specs(ps, dh, mp, pb)
-    sc_specs, sc_args = [], []
-    if quantized:
-        ks_specs, vs_specs = _paged_scale_specs(ps, mp, pb)
-        sc_specs = [*ks_specs, *vs_specs]
-        sc_args = [*([k_scales] * pb), *([v_scales] * pb)]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # block_tables, chunk_starts, n_valid
-        grid=(s_slots, h, pl.cdiv(mp, pb)),
-        in_specs=[
-            pl.BlockSpec((1, c, 1, dh),
-                         lambda s, hh, j, bt, st, nv: (s, 0, hh, 0)),
-            *k_specs,
-            *v_specs,
-            *sc_specs,
-        ],
-        out_specs=pl.BlockSpec((1, c, 1, dh),
-                               lambda s, hh, j, bt, st, nv: (s, 0, hh, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((c, 128), jnp.float32),
-            pltpu.VMEM((c, 128), jnp.float32),
-            pltpu.VMEM((c, dh), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_paged_prefill_kernel, page_size=ps,
-                               pages_per_block=pb, quantized=quantized)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_slots, c, h, dh), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ) if not interpret else None,
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), chunk_starts.astype(jnp.int32),
-      n_valid.astype(jnp.int32), qs, *([k_pages] * pb), *([v_pages] * pb),
-      *sc_args)
-    return out
+    """Chunked-prefill analog of :func:`_paged_decode_pallas`: the SAME
+    kernel body (``chunked=True``), same ``pages_per_block`` tunable and
+    bit-equal accumulation order. ``q`` (S, C, H, Dh) is handed to the
+    kernel head-major; the engine holds it head-major already, so XLA
+    cancels this transpose against the caller's."""
+    qs = (q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3)
+    out = _paged_attend_pallas(qs, k_pages, v_pages, block_tables,
+                               (chunk_starts, n_valid), interpret,
+                               pages_per_block, k_scales, v_scales)
+    return out.transpose(0, 2, 1, 3)                        # (S,C,H,Dh)
 
 
 # ---------------------------------------------------------------------------
@@ -801,13 +793,30 @@ def _paged_tune_signature(args, kwargs):
 
 
 def _paged_vmem_estimate(args, kwargs, blocks):
+    """VMEM working set of one grid step, as the TPU lays it out: the
+    streamed blocks are whole pages (every head), tiles pad the last two
+    dims to (32/itemsize, 128), and the pipeline double-buffers every
+    in/out block. One estimate for the fp and int8 kernels — the page
+    dtype and the scale rows are read off the arguments."""
     q, k_pages = args[0], args[1]
-    ps, dh = k_pages.shape[1], k_pages.shape[-1]
-    c = q.shape[1] if q.ndim == 4 else 1
+    ps, h, dh = k_pages.shape[1:]
+    rows = q.shape[1] if q.ndim == 4 else 1
     pb = blocks.get("pages_per_block", 1)
-    # fp32 working set: pb (k, v) page pairs + q/acc + m/l lane scratch
-    return 4 * (2 * pb * ps * dh + 2 * c * dh + 2 * c * 128
-                + 2 * c * ps)
+
+    def tiled(lead, sub, lane, itemsize):
+        tile = 32 // itemsize
+        return (lead * -(-sub // tile) * tile * -(-lane // 128) * 128
+                * itemsize)
+
+    page = tiled(ps, h, dh, k_pages.dtype.itemsize)
+    qo = tiled(h, rows, dh, q.dtype.itemsize)
+    streamed = 2 * pb * page + 2 * qo
+    if k_pages.dtype.itemsize == 1:              # int8: + scale groups
+        streamed += 2 * pb * tiled(1, _SCALE_ROWS, ps, 4)
+    scratch = 2 * tiled(h, rows, 128, 4) + tiled(h, rows, dh, 4)
+    # fp32 temporaries of one head fold: k, v, scores, weights
+    fold = 2 * tiled(1, ps, dh, 4) + 2 * tiled(1, rows, ps, 4)
+    return 2 * streamed + scratch + fold
 
 
 def _decode_donation_probe():
@@ -987,17 +996,6 @@ def _paged_int8_tune_signature(args, kwargs):
     return tuple(sig)
 
 
-def _paged_int8_vmem_estimate(args, kwargs, blocks):
-    q, k_pages = args[0], args[1]
-    ps, dh = k_pages.shape[1], k_pages.shape[-1]
-    c = q.shape[1] if q.ndim == 4 else 1
-    pb = blocks.get("pages_per_block", 1)
-    # int8 working set: pb (k, v) page pairs at 1 byte + their fp32
-    # scale rows + fp32 q/acc + m/l lane scratch + the score block
-    return (2 * pb * ps * dh + 4 * (2 * pb * ps + 2 * c * dh
-                                    + 2 * c * 128 + 2 * c * ps))
-
-
 def _decode_int8_donation_probe():
     (q, k_pages, v_pages, k_scales, v_scales, block_tables, lengths), _ \
         = _make_paged_int8_sample(0, chunked=False)
@@ -1058,7 +1056,8 @@ def _register_paged_kernels():
                          "lengths": "(S,) i32"},
             out_layout="(S,H,Dh)",
             donatable=("k_pages", "v_pages"),
-            grid="(S, H, cdiv(mp,pages_per_block)) block-table scalar "
+            grid="(S, cdiv(mp,pages_per_block)) whole-page blocks, head "
+                 "loop in the body, block-table scalar "
                  "prefetch, dead-page skip",
             block_candidates=pb_candidates,
             atol=2e-5, rtol=2e-5),
@@ -1067,7 +1066,7 @@ def _register_paged_kernels():
         reference_fn=_decode_kernel_reference,
         sample_inputs=lambda seed: _make_paged_sample(seed, chunked=False),
         pallas_sites=(
-            "paddle_tpu.serving.decode_attention:_paged_decode_pallas",),
+            "paddle_tpu.serving.decode_attention:_paged_attend_pallas",),
         tune_signature=_paged_tune_signature,
         vmem_estimate=_paged_vmem_estimate,
         donation_probe=_decode_donation_probe,
@@ -1087,7 +1086,8 @@ def _register_paged_kernels():
                          "n_valid": "(S,) i32"},
             out_layout="(S,C,H,Dh)",
             donatable=("k_pages", "v_pages"),
-            grid="(S, H, cdiv(mp,pages_per_block)) block-table scalar "
+            grid="(S, cdiv(mp,pages_per_block)) whole-page blocks, head "
+                 "loop in the body, block-table scalar "
                  "prefetch, causal + live-lane mask",
             block_candidates=pb_candidates,
             atol=2e-5, rtol=2e-5),
@@ -1096,7 +1096,7 @@ def _register_paged_kernels():
         reference_fn=_prefill_kernel_reference,
         sample_inputs=lambda seed: _make_paged_sample(seed, chunked=True),
         pallas_sites=(
-            "paddle_tpu.serving.decode_attention:_paged_prefill_pallas",),
+            "paddle_tpu.serving.decode_attention:_paged_attend_pallas",),
         tune_signature=_paged_tune_signature,
         vmem_estimate=_paged_vmem_estimate,
         donation_probe=_prefill_donation_probe,
@@ -1115,7 +1115,8 @@ def _register_paged_kernels():
                          "lengths": "(S,) i32"},
             out_layout="(S,H,Dh)",
             donatable=("k_pages", "v_pages", "k_scales", "v_scales"),
-            grid="(S, H, cdiv(mp,pages_per_block)) block-table scalar "
+            grid="(S, cdiv(mp,pages_per_block)) whole-page blocks, head "
+                 "loop in the body, block-table scalar "
                  "prefetch, dead-page skip, scales fused into QK/PV",
             block_candidates=pb_candidates,
             atol=5e-5, rtol=5e-5),
@@ -1124,12 +1125,12 @@ def _register_paged_kernels():
         reference_fn=_decode_int8_kernel_reference,
         sample_inputs=lambda seed: _make_paged_int8_sample(seed,
                                                            chunked=False),
-        # the int8 variant runs THROUGH the fp kernel's pallas_call site
-        # (one body, quantized=True) — no site of its own
+        # all four paged kernels run THROUGH the one pallas_call site
+        # (one body, static chunked/quantized flags)
         pallas_sites=(
-            "paddle_tpu.serving.decode_attention:_paged_decode_pallas",),
+            "paddle_tpu.serving.decode_attention:_paged_attend_pallas",),
         tune_signature=_paged_int8_tune_signature,
-        vmem_estimate=_paged_int8_vmem_estimate,
+        vmem_estimate=_paged_vmem_estimate,
         donation_probe=_decode_int8_donation_probe,
         tune_sample_variants=(
             lambda s: _tp_local_sample(s, tp=2, chunked=False,
@@ -1149,7 +1150,8 @@ def _register_paged_kernels():
                          "n_valid": "(S,) i32"},
             out_layout="(S,C,H,Dh)",
             donatable=("k_pages", "v_pages", "k_scales", "v_scales"),
-            grid="(S, H, cdiv(mp,pages_per_block)) block-table scalar "
+            grid="(S, cdiv(mp,pages_per_block)) whole-page blocks, head "
+                 "loop in the body, block-table scalar "
                  "prefetch, causal + live-lane mask, scales fused into "
                  "QK/PV",
             block_candidates=pb_candidates,
@@ -1160,9 +1162,9 @@ def _register_paged_kernels():
         sample_inputs=lambda seed: _make_paged_int8_sample(seed,
                                                            chunked=True),
         pallas_sites=(
-            "paddle_tpu.serving.decode_attention:_paged_prefill_pallas",),
+            "paddle_tpu.serving.decode_attention:_paged_attend_pallas",),
         tune_signature=_paged_int8_tune_signature,
-        vmem_estimate=_paged_int8_vmem_estimate,
+        vmem_estimate=_paged_vmem_estimate,
         donation_probe=_prefill_int8_donation_probe,
         tune_sample_variants=(
             lambda s: _tp_local_sample(s, tp=2, chunked=True,

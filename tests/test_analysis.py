@@ -61,7 +61,7 @@ class TestHostCallbackRule:
 
 class TestF64Rule:
     def test_fires_under_x64(self):
-        from jax.experimental import enable_x64
+        from jax import enable_x64
         with enable_x64():
             rep = lint_fn(lambda x: x * np.float64(2.0),
                           jnp.ones((4,), jnp.float64), registry=False)

@@ -1,0 +1,191 @@
+"""Compile the main path's Pallas kernels for a DESCRIBED TPU v5e.
+
+No chip is attached here: the installed TPU compiler lowers and compiles
+for a `v5e:2x2` topology description, which refuses what the Pallas
+interpreter never sees (block shapes off the (8, 128) tiling, too much
+VMEM, a kernel that cannot be partitioned). Nothing runs, so these
+tests say nothing about results or times — ``chip_smoke.py`` does that
+on the chip.
+
+Everything that touches ``jax.experimental.topologies`` lives in the
+module-scoped fixture below, never at import: only the one xdist worker
+that is handed this file loads the TPU library, and every worker
+collects the same tests. The kernels are compiled directly (not through
+``kernels.dispatch``), with the block sizes the tuner's static prior
+picks for these shapes — the tuner's cache key asks ``jax.devices()``
+for the device kind, which is the CPU here.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu import kernels
+from paddle_tpu.kernels import autotune
+
+# GPT-2 small serving widths (GPTConfig() defaults) at the smoke's geometry
+S, H, DH, PS, MP, P, C = 32, 12, 64, 16, 8, 257, 32
+# BERT-base training shapes (BertConfig.base(), batch 48 x seq 512)
+B, BERT_H, SEQ, BERT_DH = 48, 12, 512, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_kernel(name, args, one_chip, fn=None, **kwargs):
+    """Compile kernel ``name``'s Pallas body for the described chip at
+    the static prior's block sizes; return the compiled executable."""
+    spec = kernels.get(name)
+    blocks = autotune.static_prior(spec, args, kwargs)
+    assert all(blocks[b] in cands for b, cands in
+               spec.contract.block_candidates.items())
+    args = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 if a is not None else None for a in args)
+    body = functools.partial(spec.pallas_fn, block_sizes=blocks,
+                             interpret=False, **kwargs)
+    compiled = jax.jit(fn(body) if fn else body).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _paged_args(name, page_dtype, slots=S, max_pages=MP, num_pages=P):
+    sds = jax.ShapeDtypeStruct
+    chunked = "prefill" in name
+    quantized = name.endswith("int8")
+    q_dtype = jnp.float32 if quantized else page_dtype
+    q = sds((slots, C, H, DH) if chunked else (slots, H, DH), q_dtype)
+    pages = sds((num_pages, PS, H, DH),
+                jnp.int8 if quantized else page_dtype)
+    scales = (sds((num_pages, PS), jnp.float32),) * 2 if quantized else ()
+    i32 = sds((slots,), jnp.int32)
+    geometry = (i32, i32) if chunked else (i32,)
+    return (q, pages, pages, *scales,
+            sds((slots, max_pages), jnp.int32), *geometry)
+
+
+@pytest.mark.parametrize("page_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("name", ["ragged_paged_decode",
+                                  "ragged_paged_prefill"])
+def test_paged_kernel_compiles_for_v5e(name, page_dtype, one_chip):
+    _compile_kernel(name, _paged_args(name, page_dtype), one_chip)
+
+
+@pytest.mark.parametrize("pool", [{}, dict(slots=1, max_pages=4,
+                                           num_pages=5)],
+                         ids=["P257", "P5"])
+@pytest.mark.parametrize("name", ["ragged_paged_decode_int8",
+                                  "ragged_paged_prefill_int8"])
+def test_paged_int8_kernel_compiles_for_v5e(name, pool, one_chip):
+    """P5: a pool smaller than one 8-row group of scale rows."""
+    _compile_kernel(name, _paged_args(name, jnp.int8, **pool), one_chip)
+
+
+def _flash_args(dtype, key_bias):
+    sds = jax.ShapeDtypeStruct
+    qkv = sds((B, BERT_H, SEQ, BERT_DH), dtype)
+    bias = sds((B, 1, 1, SEQ), jnp.float32) if key_bias else None
+    return (qkv, qkv, qkv, bias)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("variant", ["key_bias", "causal"])
+def test_flash_forward_compiles_for_v5e(variant, dtype, one_chip):
+    key_bias = variant == "key_bias"
+    _compile_kernel("flash_attention", _flash_args(dtype, key_bias),
+                    one_chip, causal=not key_bias)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("variant", ["key_bias", "causal"])
+def test_flash_backward_compiles_for_v5e(variant, dtype, one_chip):
+    key_bias = variant == "key_bias"
+
+    def grads(body):
+        def loss(q, k, v, bias):
+            return jnp.sum(body(q, k, v, bias).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    compiled = _compile_kernel("flash_attention",
+                               _flash_args(dtype, key_bias), one_chip,
+                               fn=grads, causal=not key_bias)
+    # forward (residuals) + the dkv and dq backward kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("mesh_axes, model_kw", [
+    pytest.param(dict(dp=2, tp=2), {}, id="dp2xtp2"),
+    pytest.param(dict(dp=2, pp=2), dict(pipeline=True, pp_microbatches=2,
+                                        stacked_layers=False),
+                 id="inside-pipeline-stage"),
+])
+def test_flash_under_a_mesh_compiles_for_v5e(mesh_axes, model_kw, topo):
+    """The partitioner refuses a Mosaic kernel ("cannot be automatically
+    partitioned"), and the interpreter never meets the partitioner: a
+    BERT loss+grad with the compiled flash kernel under a mesh of the
+    described chips — per shard in a shard_map, or inside a pipeline
+    stage body that already is one."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from paddle_tpu.core.mesh import (BATCH_AXES, MeshConfig, make_mesh,
+                                      mesh_context)
+    from paddle_tpu.models.bert import BertConfig, BertForPretraining
+
+    mesh = make_mesh(MeshConfig(**mesh_axes), devices=topo.devices)
+    model = BertForPretraining(BertConfig.tiny(
+        vocab_size=512, hidden_size=256, num_layers=2, num_heads=4,
+        ffn_size=512, max_position=128, dropout=0.0, attn_dropout=0.0,
+        attn_impl="flash", **model_kw))
+    b, s = 8, 128
+    replicated = NamedSharding(mesh, PartitionSpec())
+    by_batch = NamedSharding(mesh, PartitionSpec(BATCH_AXES))
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=replicated),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=by_batch)
+
+    batch = dict(input_ids=sds((b, s), jnp.int32),
+                 token_type_ids=sds((b, s), jnp.int32),
+                 attention_mask=sds((b, s), jnp.bool_),
+                 mlm_labels=sds((b, s), jnp.int32),
+                 mlm_mask=sds((b, s), jnp.float32),
+                 nsp_labels=sds((b,), jnp.int32))
+
+    def loss(p, batch):
+        return model.loss(p, training=True, **batch)[0]
+
+    with mesh_context(mesh):
+        compiled = jax.jit(jax.value_and_grad(loss)).lower(
+            params, batch).compile()
+    # forward + the dkv and dq backward kernels, in every layer
+    assert compiled.as_text().count("tpu_custom_call") >= 3

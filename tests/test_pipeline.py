@@ -234,7 +234,7 @@ class TestGPipe:
 
     def test_bubble_fraction_beats_gpipe(self):
         """The interleaved schedule's structural bubble is strictly below
-        GPipe's for every v > 1 (VERDICT round-3 item 4)."""
+        GPipe's for every v > 1."""
         for n, M in [(2, 8), (4, 8), (4, 16)]:
             g = pipeline_bubble_fraction(n, M, 1)
             for v in (2, 4):

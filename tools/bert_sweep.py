@@ -54,11 +54,11 @@ def one(batch_size, attn_impl, remat=False, stacked=False, seq=512,
         nsp_labels=jnp.zeros((batch_size,), jnp.int32))
     for _ in range(2):
         state, m = step(state, **batch)
-        float(m["loss"])
+    jax.block_until_ready(state)
     t0 = time.perf_counter()
     for _ in range(steps):
         state, m = step(state, **batch)
-    float(m["loss"])
+    jax.block_until_ready(state)
     dt = time.perf_counter() - t0
 
     n_params = count_params(state["params"])
@@ -75,6 +75,8 @@ def one(batch_size, attn_impl, remat=False, stacked=False, seq=512,
 
 
 def main():
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     grid = [
         dict(batch_size=48, attn_impl="xla"),
@@ -86,14 +88,17 @@ def main():
     ]
     if quick:
         grid = grid[:2]
+    failed = 0
     for cfg in grid:
-        try:
+        try:     # a variant may not fit the chip: record it, sweep on
             print(json.dumps(one(**cfg)), flush=True)
         except Exception as e:
+            failed += 1
             print(json.dumps({"variant": str(cfg),
                               "error": f"{type(e).__name__}: {e}"}),
                   flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

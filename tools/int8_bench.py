@@ -45,8 +45,10 @@ def main():
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu import slim
+    from paddle_tpu.core.compile_cache import enable_compile_cache
 
     dev = jax.devices()[0]
+    enable_compile_cache()
     rng = np.random.RandomState(0)
     params = {f"l{i}": {"w": rng.randn(args.dim, args.dim)
                         .astype(np.float32) * 0.03}

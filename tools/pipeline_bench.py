@@ -14,9 +14,13 @@ real mesh. Writes tools/PIPELINE_TIMING.json and prints a table.
 """
 import argparse
 import json
+import os
 import statistics
 import sys
 import time
+
+# runnable as `python tools/<name>.py` from anywhere: repo root on path
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main():
@@ -36,9 +40,11 @@ def main():
         jax.config.update("jax_num_cpu_devices", 8)
     import jax.numpy as jnp
     from paddle_tpu.core.mesh import MeshConfig, make_mesh, mesh_context
+    from paddle_tpu.core.compile_cache import enable_compile_cache
     from paddle_tpu.parallel import pipeline as pl
 
     dev = jax.devices()[0]
+    enable_compile_cache()
     results = {"device": str(dev), "dim": args.dim, "mb": args.mb,
                "M": args.M, "layers": args.layers,
                "circuits": args.circuits, "configs": []}
