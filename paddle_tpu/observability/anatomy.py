@@ -3,9 +3,12 @@
 The tracing ring (PR 8) shows request lifecycles; the registry shows
 aggregate latencies. Neither answers the scheduling question ROADMAP
 items 1/3/5 block on: per *step*, how much time is host gap between
-device steps, how much is device busy split by phase (prefill / decode /
-draft / verify), how much is host assembly, and how much of the busy
-time is *collective-exposed* (the tp tax you could hide or shard away).
+device steps, how much is call wall time (uploads, dispatch + sync of
+the jitted call, which holds the device's work but is read on the host's
+clock)
+split by phase (prefill / decode / draft / verify), how much is the
+host remainder, and how much of the call time is *collective-exposed*
+(the tp tax you could hide or shard away).
 
 :class:`StepAnatomy` is the host-side accumulator the engine drives
 around its fixed-shape calls — nothing here touches jitted code, so the
@@ -13,9 +16,9 @@ zero-steady-state-recompile invariant is untouched:
 
 - ``begin_step()`` stamps the step start and the host gap since the
   previous step ended;
-- ``add_phase(phase, start, end)`` records one timed device interval
-  (the engine already holds these stamps around every jitted call —
-  no extra clock reads on the hot path);
+- ``add_phase(phase, start, end)`` records one call interval, dispatch
+  through sync (the engine's ``tracer.phase`` spans already hold these
+  stamps — no extra clock reads on the hot path);
 - ``set_collective(real_s, probe_s)`` lands a sampled collectives-
   elided probe measurement (the ``tp_probe`` discipline: same shapes,
   psum elided, delta = exposed collective time);
@@ -94,7 +97,7 @@ class StepAnatomy:
             "host gap between consecutive steps")
         self._h_phase = r.histogram(
             "anatomy_phase_seconds",
-            "device-busy time per step by phase")
+            "call wall time (uploads + dispatch + sync) per step by phase")
         self._h_coll = r.histogram(
             "anatomy_collective_exposed_seconds",
             "sampled exposed collective time per probed step")
@@ -129,8 +132,8 @@ class StepAnatomy:
                      "intervals": [], "collective": None}
 
     def add_phase(self, phase: str, start: float, end: float) -> None:
-        """Attribute one device interval (tracer-clock stamps the engine
-        already took around the jitted call) to ``phase``."""
+        """Attribute one call interval, dispatch through sync (tracer-clock
+        stamps the engine's phase spans already took), to ``phase``."""
         cur = self._cur
         if cur is None:
             return

@@ -84,7 +84,7 @@ def report(reg: Optional[_registry.MetricsRegistry] = None,
 
 def _anatomy_lines(reg: _registry.MetricsRegistry) -> List[str]:
     """Step-anatomy digest, when a StepAnatomy fed this registry: the
-    per-phase device-busy split, host-gap/host fractions, sampled
+    per-phase call-wall-time split, host-gap/host fractions, sampled
     collective-exposed time, and the resource-headroom snapshot."""
     out: List[str] = []
     phase_h = reg.get("anatomy_phase_seconds")

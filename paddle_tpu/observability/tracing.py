@@ -21,6 +21,15 @@ The design follows the serving discipline everywhere else in the repo:
   stacks; explicit ``parent=`` crosses threads when the caller *wants*
   a background span under a foreground one.
 
+**Phases** (:meth:`Tracer.phase`) are the one context the serving
+engine opens at every layer boundary. A phase is always a
+``jax.profiler.TraceAnnotation`` of the same name — so whenever a
+profiler session is running, whoever started it, the span lands in the
+profiler's trace on the device's clock — plus one clock-read pair that
+feeds the always-on work counters, and a ring span with thread-local
+parentage when the tracer is enabled. Disabled and unprofiled it
+allocates no ``Span`` and records nothing.
+
 Two exporters share the buffer: crash-safe JSONL (one span per line,
 flushed per record — the runlog discipline, validated by
 :func:`validate_trace_log` / ``tools/check_metrics_log.py --trace``)
@@ -39,6 +48,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 TRACE_SCHEMA_VERSION = 1
 
@@ -134,6 +145,53 @@ class Span:
                  **({"attrs": _jsonable_dict(a)} if a else {})}
                 for t, n, a in self.events]
         return rec
+
+
+class Phase:
+    """One layer boundary, entered once: a profiler annotation (inert
+    unless a profiler session runs), the clock-read pair ``start`` /
+    ``end`` on the tracer's clock, an optional bound counter child that
+    is credited the seconds in between, and — tracer enabled — a ring
+    span parented from the thread-local stack (``span``, else None).
+    ``stamp`` writes ``t_mono_ns`` (the tracer clock at entry) into the
+    annotation, so a reader joins ring spans and anatomy records to the
+    device timeline by id, with no clock arithmetic."""
+
+    __slots__ = ("_tracer", "_ann", "_counter", "span", "start", "end")
+
+    def __init__(self, tracer: "Tracer", name: str, counter, stamp: bool,
+                 attrs: Dict[str, Any]):
+        self._tracer = tracer
+        self._counter = counter
+        self.end: Optional[float] = None
+        self.start = tracer.now()
+        self.span = (tracer._make(name, None, attrs, start=self.start)
+                     if tracer.enabled else None)
+        if stamp:       # the ring span holds ``start`` itself
+            attrs = dict(attrs, t_mono_ns=int(self.start * 1e9))
+        self._ann = _Annotation(name, **attrs)
+
+    @property
+    def span_id(self) -> int:
+        return self.span.span_id if self.span is not None else _NO_ID
+
+    def __enter__(self) -> "Phase":
+        self._ann.__enter__()
+        if self.span is not None:
+            self._tracer._push(self.span)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end = self._tracer.now()
+        if self._counter is not None:
+            self._counter.inc(self.end - self.start)
+        sp = self.span
+        if sp is not None:
+            self._tracer._pop(sp)
+            sp.finish(status="error" if exc_type is not None else None,
+                      end=self.end)
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
 
 
 class _NoopSpan:
@@ -232,6 +290,14 @@ class Tracer:
         if not self.enabled:
             return NOOP_SPAN
         return self._make(name, parent, attrs)
+
+    def phase(self, name: str, counter=None, stamp: bool = False,
+              **attrs) -> Phase:
+        """The context for one layer boundary (see :class:`Phase`):
+        ``with tracer.phase("serving.decode.sync", counter=child):``.
+        Works the same enabled or disabled — only the ring span is
+        conditional."""
+        return Phase(self, name, counter, stamp, attrs)
 
     def start_span(self, name: str, parent: Optional[Span] = None,
                    trace_id: Optional[int] = None, **attrs):

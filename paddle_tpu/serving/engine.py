@@ -142,6 +142,18 @@ _LATENCY_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.2, 0.35,
                     0.5, 0.75, 1.0, 1.5, 2.0, 3.0, 4.0, 5.0, 7.5, 10.0,
                     15.0, 30.0, 60.0)
 
+# decode-block / prefill-call wall times: geometric, 1 ms to 2 s in
+# steps of sqrt(2), fine enough that an operator can take a median
+_STEP_BUCKETS = tuple(round(1e-3 * 2 ** (k / 2), 6) for k in range(23))
+
+# serving_step_part_seconds_total{phase,part}: the leaf spans of one
+# engine step, by the work they time (PERF.md section 3)
+_STEP_PARTS = (("prefill", "assemble"), ("prefill", "cow_copy"),
+               ("prefill", "dispatch"), ("prefill", "sync"),
+               ("prefill", "book"), ("decode", "assemble"),
+               ("decode", "dispatch"), ("decode", "sync"),
+               ("decode", "book"), ("sched", "book"), ("observe", "book"))
+
 MIGRATION_FORMAT = "paddle_tpu.serving.slot-migration-v1"
 
 # fleet-global prefix reuse (ISSUE 20): committed prefix pages travel
@@ -396,6 +408,7 @@ class ServingEngine:
         if not self.tp_spmd:
             self.anatomy_probe_every = 0
         self._decode_rounds = 0
+        self._bind_step_metrics()
 
         # step-side params: tp re-lays the attention projections out
         # head-major (qkv (D,3,H,Dh) col-sharded, out (H,Dh,D)
@@ -530,6 +543,101 @@ class ServingEngine:
         self._health_snap: Dict[str, object] = {}
         self._refresh_health()
 
+    def _bind_step_metrics(self):
+        """Every series ``step()`` touches, bound once: a step then pays
+        one lock acquisition per update and no look-up by name."""
+        r = self._reg
+        part = r.counter(
+            "serving_step_part_seconds_total",
+            "seconds inside step() by phase (prefill/decode/sched/observe) "
+            "and part (assemble/cow_copy/dispatch/sync/book): the same "
+            "clock reads as the serving.* phase spans")
+        self._c_part = {(ph, pt): part.child(phase=ph, part=pt)
+                        for ph, pt in _STEP_PARTS}
+        self._c_step_seconds = r.counter(
+            "serving_step_seconds_total",
+            "wall seconds inside step(), steps that did work only").child()
+        self._c_prefill_calls = r.counter(
+            "serving_prefill_calls_total",
+            "batched prefill calls (one per serving.prefill_call)").child()
+        self._c_decode_rounds = r.counter(
+            "serving_decode_rounds_total",
+            "decode rounds (one per serving.decode_round)").child()
+        kv = r.counter(
+            "serving_decode_kv_bytes_total",
+            "K/V bytes per decode round: kind=live is what the live "
+            "tokens hold (token steps x live lengths x 2 x H x Dh x "
+            "itemsize x layers), kind=gathered what the kernel's (slots, "
+            "pages) grid visits (token steps x slots x gather width x "
+            "page bytes x layers)")
+        self._c_kv_live = kv.child(kind="live")
+        self._c_kv_gathered = kv.child(kind="gathered")
+        c = self.cache.config
+        self._kv_token_bytes = (2 * c.num_heads * c.head_dim
+                                * np.dtype(c.dtype).itemsize * c.num_layers)
+        self._h_decode_step = r.histogram(
+            "serving_decode_step_seconds",
+            "wall time per decode block (sync included)",
+            buckets=_STEP_BUCKETS).child()
+        self._h_prefill_step = r.histogram(
+            "serving_prefill_step_seconds",
+            "wall time per batched prefill call (sync included)",
+            buckets=_STEP_BUCKETS).child()
+        self._h_ttft = r.histogram(
+            "serving_ttft_seconds", "submit -> first token latency",
+            buckets=_LATENCY_BUCKETS).child()
+        self._h_admit_to_first = r.histogram(
+            "serving_admit_to_first_token_seconds",
+            "admit -> first token (prefill cost, net of queue wait)",
+            buckets=_LATENCY_BUCKETS).child()
+        self._c_tokens = r.counter("serving_tokens_total",
+                                   "decode tokens produced").child()
+        self._c_steps = r.counter("serving_steps_total").child()
+        self._c_prefill_tokens = r.counter(
+            "serving_prefill_tokens_total",
+            "prompt tokens actually computed by prefill (shared "
+            "prefix tokens are skipped)").child()
+        self._c_prefix_shared = r.counter(
+            "serving_prefix_shared_tokens_total",
+            "prompt tokens skipped via shared prefix pages").child()
+        self._c_cow = r.counter(
+            "serving_prefix_cow_total",
+            "copy-on-write page copies for shared tails").child()
+        self._g_occupancy = r.gauge(
+            "serving_slot_occupancy",
+            "fraction of decode slots live").child()
+        self._g_page_util = r.gauge(
+            "serving_page_utilization",
+            "live tokens / page-pool capacity").child()
+        head = r.gauge(
+            "serving_headroom",
+            "spare capacity per resource (1 = idle, 0 = saturated)")
+        self._g_headroom = {
+            res: head.child(resource=res)
+            for res in ("flops", "pages", "slots", "hbm", "spill")}
+        self._g_spill_pages = r.gauge(
+            "serving_spill_pages",
+            "published KV pages resident in the host spill pool").child()
+        self._g_spill_bytes = r.gauge(
+            "serving_spill_bytes",
+            "bytes of KV (incl. int8 scale rows) in the host spill pool"
+        ).child()
+        self._g_flops_util = r.gauge(
+            "serving_flops_utilization",
+            "retired static flops per busy second / best observed rate"
+        ).child()
+        self._g_prefix_saved = r.gauge(
+            "serving_prefix_saved_per_token",
+            "prefill tokens skipped via prefix sharing per served token"
+        ).child()
+        warm = r.counter(
+            "serving_warmup_seconds_total",
+            "seconds of warmup() by part: cost_gauges (the static cost "
+            "model's second trace + lowering of each bucket) and "
+            "first_call (trace, lower, compile or cache load, run)")
+        self._c_warm_cost = warm.child(part="cost_gauges")
+        self._c_warm_first = warm.child(part="first_call")
+
     # -- request surface --------------------------------------------------
 
     def _can_admit(self, req) -> bool:
@@ -649,8 +757,7 @@ class ServingEngine:
             "free_slots": len(self.scheduler.free_slots()),
             "recompiles": self.recompile_detector.recompiles,
             "requests_in_flight": len(self.scheduler.active_slots()),
-            "steps": int(self._reg.counter(
-                "serving_steps_total").value()),
+            "steps": int(self._c_steps.value()),
             # mesh shape (ISSUE 15): the autoscaler and /healthz must
             # distinguish a 4-chip tp replica from a 1-chip one. The
             # chip count is the TP degree, not the raw mesh size — a
@@ -693,9 +800,8 @@ class ServingEngine:
             flops_util = min(
                 (self._flops_done / self._busy_s)
                 / self._flops_rate_peak, 1.0)
-        tokens = self._reg.counter("serving_tokens_total").value()
-        saved = self._reg.counter(
-            "serving_prefix_shared_tokens_total").value()
+        tokens = self._c_tokens.value()
+        saved = self._c_prefix_shared.value()
         head = {
             "flops_utilization": round(flops_util, 6),
             "flops": round(1.0 - flops_util, 6),
@@ -724,27 +830,12 @@ class ServingEngine:
                 max(1.0 - len(pool) / pool.capacity, 0.0), 6)
             head["spill_pages"] = len(pool)
             head["spill_bytes"] = int(pool.spilled_bytes())
-        g = self._reg.gauge(
-            "serving_headroom",
-            "spare capacity per resource (1 = idle, 0 = saturated)")
-        for res in ("flops", "pages", "slots", "hbm", "spill"):
-            g.set(head[res], resource=res)
-        self._reg.gauge(
-            "serving_spill_pages",
-            "published KV pages resident in the host spill pool"
-        ).set(head["spill_pages"])
-        self._reg.gauge(
-            "serving_spill_bytes",
-            "bytes of KV (incl. int8 scale rows) in the host spill pool"
-        ).set(head["spill_bytes"])
-        self._reg.gauge(
-            "serving_flops_utilization",
-            "retired static flops per busy second / best observed rate"
-        ).set(flops_util)
-        self._reg.gauge(
-            "serving_prefix_saved_per_token",
-            "prefill tokens skipped via prefix sharing per served token"
-        ).set(head["prefix_saved_per_token"])
+        for res, g in self._g_headroom.items():
+            g.set(head[res])
+        self._g_spill_pages.set(head["spill_pages"])
+        self._g_spill_bytes.set(head["spill_bytes"])
+        self._g_flops_util.set(flops_util)
+        self._g_prefix_saved.set(head["prefix_saved_per_token"])
         return head
 
     def _note_busy(self, sigs, dur: float):
@@ -795,159 +886,201 @@ class ServingEngine:
         admit into free slots, advance every admitted request's prefill
         under the interleaving budget, advance every decoding slot one
         block, evict finished sequences. Returns ``{rid: generated
-        tokens}`` for requests that finished now."""
+        tokens}`` for requests that finished now.
+
+        Every layer boundary inside is one ``tracer.phase`` (names and
+        parents: PERF.md section 3): a profiler annotation, a ring span
+        when the tracer is on, and the seconds credited to
+        ``serving_step_part_seconds_total``."""
         finished: Dict[int, np.ndarray] = {}
         self._anat_steps += 1
-        self.anatomy.begin_step(self._anat_steps)
-        step_tokens = 0
-        if isinstance(self.scheduler, SLOScheduler):
-            for req in self.scheduler.shed_expired():
-                rej = Reject("deadline_expired", req.lane,
-                             self.scheduler.queue_depth(),
-                             self.scheduler.est_ttft_s(), 0.001)
-                self._rejects[req.rid] = rej
-                while len(self._rejects) > self._results_cap:
-                    self._rejects.popitem(last=False)
-                self._reg.counter("serving_rejected_total",
-                                  "requests load-shed instead of queued"
-                                  ).inc(reason=rej.reason)
-                self._phase_acc.pop(req.rid, None)
-                self._ext_trace.pop(req.rid, None)
-                root = self._req_spans.pop(req.rid, None)
-                if root is not None:
-                    root.add_event("shed", reason=rej.reason,
-                                   deadline_s=req.ttft_deadline_s)
-                    root.finish(status="shed")
-        budget = self.prefill_budget
-        prefilled_any = False
-        while True:  # admissions can cascade as early-EOS slots free up
-            # pages are reserved inside the admit callback, so each
-            # can_admit check sees the pool net of earlier admissions
-            # in the same call (no over-commit on a down-sized pool)
-            admitted = self.scheduler.admit(on_admit=self._on_admit)
-            done = self._prefill_round(budget,
-                                       allow_liveness=not prefilled_any)
-            prefilled_any = prefilled_any or done > 0
-            budget -= done
-            finished.update(self._evict())
-            if (not admitted and done == 0) or budget <= 0:
-                break
+        phase = self.tracer.phase
+        sched = self._c_part["sched", "book"]
+        with phase("serving.step", stamp=True,
+                   step=self._anat_steps) as step_ph:
+            self.anatomy.begin_step(self._anat_steps)
+            step_tokens = 0
+            if isinstance(self.scheduler, SLOScheduler):
+                with phase("serving.shed", sched):
+                    self._shed_expired()
+            budget = self.prefill_budget
+            prefilled_any = False
+            while True:  # admissions cascade as early-EOS slots free up
+                # pages are reserved inside the admit callback, so each
+                # can_admit check sees the pool net of earlier admissions
+                # in the same call (no over-commit on a down-sized pool)
+                with phase("serving.admit", sched):
+                    admitted = self.scheduler.admit(on_admit=self._on_admit)
+                done = self._prefill_round(
+                    budget, allow_liveness=not prefilled_any)
+                prefilled_any = prefilled_any or done > 0
+                budget -= done
+                finished.update(self._evict())
+                if (not admitted and done == 0) or budget <= 0:
+                    break
 
-        dslots = self.scheduler.decode_slots()
-        if self.tier == "prefill":
-            # prefill-done slots PARK for handoff (the replica handle
-            # drains them via poll_handoffs); only the handoff-fallback
-            # slots explicitly flagged decode-in-place decode here
-            dslots = [i for i in dslots if i in self._decode_in_place]
-        if dslots:
-            # occupancy/utilization of the batch the decode step
-            # actually runs with (recorded before eviction, which
-            # empties finished slots' lengths)
-            self._reg.gauge("serving_slot_occupancy",
-                            "fraction of decode slots live").set(
-                                len(dslots) / self.scheduler.num_slots)
-            self._reg.gauge("serving_page_utilization",
-                            "live tokens / page-pool capacity").set(
-                                self.cache.utilization())
-            if self.speculative:
-                kept = self._speculative_round(dslots)
-            else:
-                kept = self._decode_round(dslots)
-            step_tokens += kept
-            self._reg.counter("serving_tokens_total",
-                              "decode tokens produced").inc(kept)
-            self._reg.counter("serving_steps_total").inc()
-            self.recompile_detector.check()
-            finished.update(self._evict())
-            if self.snapshot_every_blocks is not None:
-                self._take_micro_snapshots()
+            dslots = self.scheduler.decode_slots()
+            if self.tier == "prefill":
+                # prefill-done slots PARK for handoff (the replica handle
+                # drains them via poll_handoffs); only the handoff-fallback
+                # slots explicitly flagged decode-in-place decode here
+                dslots = [i for i in dslots if i in self._decode_in_place]
+            if dslots:
+                # occupancy/utilization of the batch the decode step
+                # actually runs with (recorded before eviction, which
+                # empties finished slots' lengths)
+                self._g_occupancy.set(len(dslots) / self.scheduler.num_slots)
+                self._g_page_util.set(self.cache.utilization())
+                if self.speculative:
+                    kept = self._speculative_round(dslots)
+                else:
+                    kept = self._decode_round(dslots)
+                step_tokens += kept
+                self._c_tokens.inc(kept)
+                self._c_steps.inc()
+                finished.update(self._evict())
+                if self.snapshot_every_blocks is not None:
+                    self._take_micro_snapshots()
 
-        if self.slo_monitor is not None:
-            self.slo_monitor.check()
+            # what the observability itself costs each step
+            with phase("serving.observe", self._c_part["observe", "book"]):
+                if dslots:
+                    self.recompile_detector.check()
+                if self.slo_monitor is not None:
+                    self.slo_monitor.check()
+                if prefilled_any or dslots:
+                    self.anatomy.end_step(tokens=step_tokens)
+                else:
+                    # an idle tick is not a serving step: recording it
+                    # would count queue-empty waiting as "host gap"
+                    self.anatomy.cancel_step()
+                self._refresh_health()
+                with self._health_lock:
+                    snap = self._health_snap
+                self.flight.note(snap)
         if prefilled_any or dslots:
-            self.anatomy.end_step(tokens=step_tokens)
-        else:
-            # an idle tick is not a serving step: recording it would
-            # count queue-empty waiting as "host gap"
-            self.anatomy.cancel_step()
-        self._refresh_health()
-        with self._health_lock:
-            snap = self._health_snap
-        self.flight.note(snap)
+            self._c_step_seconds.inc(step_ph.end - step_ph.start)
         return finished
+
+    def _shed_expired(self):
+        """Deadline shedding: queued requests whose TTFT deadline passed
+        leave with a structured reject."""
+        for req in self.scheduler.shed_expired():
+            rej = Reject("deadline_expired", req.lane,
+                         self.scheduler.queue_depth(),
+                         self.scheduler.est_ttft_s(), 0.001)
+            self._rejects[req.rid] = rej
+            while len(self._rejects) > self._results_cap:
+                self._rejects.popitem(last=False)
+            self._reg.counter("serving_rejected_total",
+                              "requests load-shed instead of queued"
+                              ).inc(reason=rej.reason)
+            self._phase_acc.pop(req.rid, None)
+            self._ext_trace.pop(req.rid, None)
+            root = self._req_spans.pop(req.rid, None)
+            if root is not None:
+                root.add_event("shed", reason=rej.reason,
+                               deadline_s=req.ttft_deadline_s)
+                root.finish(status="shed")
+
+    def _decode_width(self, dslots, n: int) -> int:
+        """Gather width of a decode round: the pow2 page count that
+        covers the longest live slot after ``n`` more tokens."""
+        return self._pow2_width(self.cache.config.pages_for(
+            int(self.cache.lengths[dslots].max()) + n))
+
+    def _count_kv_bytes(self, dslots, n: int, w: int):
+        """``serving_decode_kv_bytes_total`` for one round of ``n`` token
+        steps at gather width ``w``, from the lengths BEFORE the round:
+        useful work (live) over attempted work (gathered), counted where
+        it happens. Token step j of a slot holding L tokens attends over
+        L + j + 1 (the formula ``benchmark/flops.paged_decode_bytes``
+        applies from outside); the kernel's grid visits every slot of the
+        batch, live or not, at ``w`` whole pages."""
+        live = n * int(self.cache.lengths[dslots].sum()) \
+            + len(dslots) * n * (n + 1) // 2
+        self._c_kv_live.inc(live * self._kv_token_bytes)
+        self._c_kv_gathered.inc(
+            n * self.scheduler.num_slots * w * self.cache.config.page_size
+            * self._kv_token_bytes)
 
     def _decode_round(self, dslots) -> int:
         """Advance every decoding slot one block of ``decode_block``
         tokens through the jitted decode step; returns tokens kept."""
         n = self.decode_block
         s_tot = self.scheduler.num_slots
-        tokens = np.zeros((s_tot,), np.int32)
-        active = np.zeros((s_tot,), np.int32)
-        for i in dslots:
-            tokens[i] = self.scheduler.slots[i].generated[-1]
-            active[i] = 1
-        w = self._pow2_width(max(
-            self.cache.config.pages_for(
-                int(self.cache.lengths[i]) + n) for i in dslots))
-        t0 = time.monotonic()
-        out, self.cache.pages = self.decode_step(
-            self._step_params, self.cache.pages,
-            jnp.asarray(self.cache.block_tables[:, :w]),
-            jnp.asarray(self.cache.lengths), jnp.asarray(tokens),
-            jnp.asarray(active))
-        out = np.asarray(out)                    # (S, decode_block)
-        t1 = time.monotonic()
-        self._reg.histogram(
-            "serving_decode_step_seconds",
-            "wall time per decode block (sync included)").observe(
-                t1 - t0)
-        self.anatomy.add_phase("decode", t0, t1)
-        self._note_busy((("decode", w),), t1 - t0)
-        self._decode_rounds += 1
-        if self.anatomy_probe_every and self._probe_pages is not None \
-                and self._decode_rounds % self.anatomy_probe_every == 0:
-            # collective-exposed sample: the SAME decode shapes through
-            # the collectives-elided probe twin (zero probe pool, shard
-            # 0's params); every shape below is a warmed
-            # ("decode_probe", w) bucket, so steady state compiles
-            # nothing — the RecompileDetector asserts it
-            p0 = time.monotonic()
-            pout, self._probe_pages = self.decode_probe_step(
-                self._probe_params, self._probe_pages,
-                jnp.asarray(self.cache.block_tables[:, :w]),
-                jnp.asarray(self.cache.lengths), jnp.asarray(tokens),
-                jnp.asarray(active))
-            np.asarray(pout)                     # sync the probe wall
-            p1 = time.monotonic()
-            self.anatomy.set_collective(t1 - t0, p1 - p0)
-        tr_on = self.tracer.enabled
-        kept = 0
-        for i in dslots:
-            st = self.scheduler.slots[i]
-            req = st.request
-            budget_i = req.max_new_tokens - len(st.generated)
-            kept_i = 0
-            for j in range(min(n, budget_i)):
-                tok = int(out[i, j])
-                st.generated.append(tok)
-                kept_i += 1
-                if req.eos_id is not None and tok == req.eos_id:
-                    break
-            kept += kept_i
-            if not st.finished():
-                # device advanced this slot the full block
-                self.cache.lengths[i] += n
-            acc = self._phase_acc.get(req.rid)
-            if acc is not None:
-                acc["decode_s"] += t1 - t0
-                acc["decode_blocks"] += 1
-            if tr_on:
-                # lanes run in the same batched call, so the spans
-                # share the interval — a parallel track per request
-                self.tracer.record_span(
-                    "serving.decode_block", start=t0, end=t1,
-                    parent=self._req_spans.get(req.rid),
-                    slot=i, tokens=kept_i)
+        w = self._decode_width(dslots, n)
+        phase, part = self.tracer.phase, self._c_part
+        with phase("serving.decode_round", width=w,
+                   slots_live=len(dslots)) as rnd:
+            with phase("serving.decode.assemble",
+                       part["decode", "assemble"]) as asm:
+                tokens = np.zeros((s_tot,), np.int32)
+                active = np.zeros((s_tot,), np.int32)
+                for i in dslots:
+                    tokens[i] = self.scheduler.slots[i].generated[-1]
+                    active[i] = 1
+                self._count_kv_bytes(dslots, n, w)
+                args = (jnp.asarray(self.cache.block_tables[:, :w]),
+                        jnp.asarray(self.cache.lengths),
+                        jnp.asarray(tokens), jnp.asarray(active))
+            with phase("serving.decode.dispatch",
+                       part["decode", "dispatch"]):
+                out, self.cache.pages = self.decode_step(
+                    self._step_params, self.cache.pages, *args)
+            with phase("serving.decode.sync", part["decode", "sync"]) as sync:
+                out = np.asarray(out)                # (S, decode_block)
+            # the call's wall time as it has always been taken: uploads,
+            # dispatch and sync, from the phases' own clock reads
+            t0, t1 = asm.start, sync.end
+            self._h_decode_step.observe(t1 - t0)
+            self.anatomy.add_phase("decode", t0, t1)
+            self._note_busy((("decode", w),), t1 - t0)
+            self._decode_rounds += 1
+            self._c_decode_rounds.inc()
+            if self.anatomy_probe_every and self._probe_pages is not None \
+                    and self._decode_rounds % self.anatomy_probe_every == 0:
+                # collective-exposed sample: the SAME decode shapes through
+                # the collectives-elided probe twin (zero probe pool, shard
+                # 0's params); every shape below is a warmed
+                # ("decode_probe", w) bucket, so steady state compiles
+                # nothing — the RecompileDetector asserts it
+                p0 = time.monotonic()
+                pout, self._probe_pages = self.decode_probe_step(
+                    self._probe_params, self._probe_pages, *args)
+                np.asarray(pout)                     # sync the probe wall
+                p1 = time.monotonic()
+                self.anatomy.set_collective(t1 - t0, p1 - p0)
+            with phase("serving.decode.book", part["decode", "book"]):
+                tr_on = self.tracer.enabled
+                kept = 0
+                for i in dslots:
+                    st = self.scheduler.slots[i]
+                    req = st.request
+                    budget_i = req.max_new_tokens - len(st.generated)
+                    kept_i = 0
+                    for j in range(min(n, budget_i)):
+                        tok = int(out[i, j])
+                        st.generated.append(tok)
+                        kept_i += 1
+                        if req.eos_id is not None and tok == req.eos_id:
+                            break
+                    kept += kept_i
+                    if not st.finished():
+                        # device advanced this slot the full block
+                        self.cache.lengths[i] += n
+                    acc = self._phase_acc.get(req.rid)
+                    if acc is not None:
+                        acc["decode_s"] += t1 - t0
+                        acc["decode_blocks"] += 1
+                    if tr_on:
+                        # lanes run in the same batched call, so the spans
+                        # share the interval — a parallel track per
+                        # request; ``call`` names the round that caused it
+                        self.tracer.record_span(
+                            "serving.decode_block", start=t0, end=t1,
+                            parent=self._req_spans.get(req.rid),
+                            slot=i, tokens=kept_i, call=rnd.span_id)
         return kept
 
     def _speculative_round(self, dslots) -> int:
@@ -969,49 +1102,65 @@ class ServingEngine:
         nothing leaks. Returns tokens kept."""
         n = self.spec_k
         s_tot = self.scheduler.num_slots
-        tokens = np.zeros((s_tot,), np.int32)
-        active = np.zeros((s_tot,), np.int32)
-        nv = np.zeros((s_tot,), np.int32)
-        for i in dslots:
-            st = self.scheduler.slots[i]
-            tokens[i] = st.generated[-1]
-            active[i] = 1
-            # never write past the slot's reservation: the chunk is
-            # capped at the remaining generation budget
-            nv[i] = min(n, st.request.max_new_tokens - len(st.generated))
-        w = self._pow2_width(max(
-            self.cache.config.pages_for(
-                int(self.cache.lengths[i]) + n) for i in dslots))
-        t0 = time.monotonic()
-        nv_dev = jnp.asarray(nv)
-        props_dev, self.draft_cache.pages = self.draft_propose_step(
-            self.draft_params, self.draft_cache.pages,
-            jnp.asarray(self.draft_cache.block_tables[:, :w]),
-            jnp.asarray(self.draft_cache.lengths), jnp.asarray(tokens),
-            jnp.asarray(active), nv_dev)
-        # verify dispatches on the UN-materialized proposals (the chunk
-        # is assembled inside the jitted step), so the draft->verify
-        # chain never blocks on a host round-trip; the props transfer
-        # below overlaps the verify compute
-        ver, self.cache.pages = self.verify_step(
-            self._step_params, self.cache.pages,
-            jnp.asarray(self.cache.block_tables[:, :w]),
-            jnp.asarray(self.cache.lengths), jnp.asarray(tokens),
-            props_dev, nv_dev)
-        props = np.asarray(props_dev)          # (S, spec_k) proposals
-        # the props transfer completes when the draft chain has; the
-        # clock read between the two materializations splits the round
-        # into draft/verify anatomy without changing dispatch overlap
-        t_mid = time.monotonic()
-        ver = np.asarray(ver)                  # (S, spec_k) target greedy
-        t1 = time.monotonic()
-        self._reg.histogram(
-            "serving_decode_step_seconds",
-            "wall time per decode block (sync included)").observe(
-                t1 - t0)
-        self.anatomy.add_phase("draft", t0, t_mid)
-        self.anatomy.add_phase("verify", t_mid, t1)
-        self._note_busy((("draft", w), ("verify", w)), t1 - t0)
+        w = self._decode_width(dslots, n)
+        phase, part = self.tracer.phase, self._c_part
+        with phase("serving.decode_round", width=w,
+                   slots_live=len(dslots)) as rnd:
+            with phase("serving.decode.assemble",
+                       part["decode", "assemble"]) as asm:
+                tokens = np.zeros((s_tot,), np.int32)
+                active = np.zeros((s_tot,), np.int32)
+                nv = np.zeros((s_tot,), np.int32)
+                for i in dslots:
+                    st = self.scheduler.slots[i]
+                    tokens[i] = st.generated[-1]
+                    active[i] = 1
+                    # never write past the slot's reservation: the chunk
+                    # is capped at the remaining generation budget
+                    nv[i] = min(n, st.request.max_new_tokens
+                                - len(st.generated))
+                self._count_kv_bytes(dslots, n, w)
+                nv_dev = jnp.asarray(nv)
+                tok_dev = jnp.asarray(tokens)
+                draft_args = (
+                    jnp.asarray(self.draft_cache.block_tables[:, :w]),
+                    jnp.asarray(self.draft_cache.lengths), tok_dev,
+                    jnp.asarray(active), nv_dev)
+                bt_dev = jnp.asarray(self.cache.block_tables[:, :w])
+                len_dev = jnp.asarray(self.cache.lengths)
+            with phase("serving.decode.dispatch",
+                       part["decode", "dispatch"]):
+                props_dev, self.draft_cache.pages = self.draft_propose_step(
+                    self.draft_params, self.draft_cache.pages, *draft_args)
+                # verify dispatches on the UN-materialized proposals (the
+                # chunk is assembled inside the jitted step), so the
+                # draft->verify chain never blocks on a host round-trip;
+                # the props transfer below overlaps the verify compute
+                ver, self.cache.pages = self.verify_step(
+                    self._step_params, self.cache.pages, bt_dev, len_dev,
+                    tok_dev, props_dev, nv_dev)
+            with phase("serving.decode.sync", part["decode", "sync"]) as sync:
+                props = np.asarray(props_dev)      # (S, spec_k) proposals
+                # the props transfer completes when the draft chain has;
+                # the clock read between the two materializations splits
+                # the round into draft/verify anatomy without changing
+                # dispatch overlap
+                t_mid = self.tracer.now()
+                ver = np.asarray(ver)              # (S, spec_k) target greedy
+            t0, t1 = asm.start, sync.end
+            self._h_decode_step.observe(t1 - t0)
+            self.anatomy.add_phase("draft", t0, t_mid)
+            self.anatomy.add_phase("verify", t_mid, t1)
+            self._note_busy((("draft", w), ("verify", w)), t1 - t0)
+            self._c_decode_rounds.inc()
+            with phase("serving.decode.book", part["decode", "book"]):
+                kept = self._book_speculative(dslots, props, ver, nv,
+                                              t0, t1, rnd.span_id)
+        return kept
+
+    def _book_speculative(self, dslots, props, ver, nv, t0, t1,
+                          call: int) -> int:
+        """The accept loop of one speculative round, per slot."""
         tr_on = self.tracer.enabled
         kept = 0
         for i in dslots:
@@ -1059,7 +1208,8 @@ class ServingEngine:
                 self.tracer.record_span(
                     "serving.verify_block", start=t0, end=t1,
                     parent=self._req_spans.get(req.rid), slot=i,
-                    tokens=kept_i, proposed=proposed, accepted=accepted)
+                    tokens=kept_i, proposed=proposed, accepted=accepted,
+                    call=call)
         return kept
 
     def generate_many(self, prompts: Sequence, max_new_tokens: int = 32,
@@ -1080,6 +1230,10 @@ class ServingEngine:
         return [collected[r] for r in rids]
 
     def _evict(self) -> Dict[int, np.ndarray]:
+        with self.tracer.phase("serving.evict", self._c_part["sched", "book"]):
+            return self._evict_finished()
+
+    def _evict_finished(self) -> Dict[int, np.ndarray]:
         out = {}
         for slot, st in self.scheduler.evict_finished().items():
             self.cache.free_slot(slot)
@@ -1214,9 +1368,7 @@ class ServingEngine:
         st = self.scheduler.slots[slot]
         st.prefilled = shared
         if shared:
-            self._reg.counter(
-                "serving_prefix_shared_tokens_total",
-                "prompt tokens skipped via shared prefix pages").inc(shared)
+            self._c_prefix_shared.inc(shared)
         self._reg.histogram(
             "serving_queue_wait_seconds",
             "submit -> slot admission wait",
@@ -1250,145 +1402,151 @@ class ServingEngine:
         prefill_chunk)`` prompt tokens."""
         consumed = 0
         c = self.prefill_chunk
-        cfgc = self.cache.config
-        while budget - consumed > 0:
-            pslots = [i for i in self.scheduler.active_slots()
-                      if not self.scheduler.slots[i].prefill_done]
-            if not pslots:
-                break
-            lane_cap = (budget - consumed) // c
-            if lane_cap == 0:
-                if consumed > 0 or not allow_liveness:
+        with self.tracer.phase("serving.prefill_round"):
+            while budget - consumed > 0:
+                pslots = [i for i in self.scheduler.active_slots()
+                          if not self.scheduler.slots[i].prefill_done]
+                if not pslots:
                     break
-                lane_cap = 1    # the once-per-step liveness lane
-            # when lanes must wait, run the slots closest to their first
-            # token: that closes TTFTs soonest, and each completion
-            # shrinks the set so no admitted slot waits forever
-            if len(pslots) > lane_cap:
-                pslots.sort(key=lambda i: int(
-                    self.scheduler.slots[i].request.prompt.shape[0])
-                    - self.scheduler.slots[i].prefilled)
-                pslots = pslots[:lane_cap]
-            # compact batch: pow2-bucketed over the number of slots
-            # actually prefilling (a lone late admission does not pay
-            # for num_slots lanes of attention); padding lanes are
-            # inert (n_valid 0, null-page block tables)
-            sb = self._pow2_count(len(pslots))
-            tokens = np.zeros((sb, c), np.int32)
-            starts = np.zeros((sb,), np.int32)
-            nv = np.zeros((sb,), np.int32)
-            bt_rows = np.zeros((sb, cfgc.max_pages_per_slot), np.int32)
-            dbt_rows = np.zeros_like(bt_rows) if self.speculative else None
-            for j, i in enumerate(pslots):
-                st = self.scheduler.slots[i]
-                pc = self.cache.pending_copy(i)
-                if pc is not None:
-                    # copy-on-write of a borrowed tail page, owed before
-                    # this slot's first write lands in it
-                    src, dst = pc
-                    self.cache.pages = self.copy_page_step(
-                        self.cache.pages, jnp.asarray(src, jnp.int32),
-                        jnp.asarray(dst, jnp.int32))
-                    self.cache.copy_done(i)
-                    self._reg.counter(
-                        "serving_prefix_cow_total",
-                        "copy-on-write page copies for shared tails"
-                    ).inc()
-                    root = self._req_spans.get(st.request.rid)
-                    if root is not None:
-                        root.add_event("cow_copy", src_page=int(src),
-                                       dst_page=int(dst))
-                prompt = st.request.prompt
-                lo = st.prefilled
-                # borrower write isolation: the page this chunk starts
-                # writing into must be slot-owned (a shared tail page
-                # must have been CoW-resolved above, never written)
-                assert self.cache.writable(i, lo // cfgc.page_size), \
-                    f"slot {i} would write a borrowed page"
-                n = min(c, int(prompt.shape[0]) - lo)
-                tokens[j, :n] = prompt[lo:lo + n]
-                starts[j] = lo
-                nv[j] = n
-                bt_rows[j] = self.cache.block_tables[i]
+                lane_cap = (budget - consumed) // c
+                if lane_cap == 0:
+                    if consumed > 0 or not allow_liveness:
+                        break
+                    lane_cap = 1    # the once-per-step liveness lane
+                # when lanes must wait, run the slots closest to their
+                # first token: that closes TTFTs soonest, and each
+                # completion shrinks the set so no admitted slot waits
+                # forever
+                if len(pslots) > lane_cap:
+                    pslots.sort(key=lambda i: int(
+                        self.scheduler.slots[i].request.prompt.shape[0])
+                        - self.scheduler.slots[i].prefilled)
+                    pslots = pslots[:lane_cap]
+                consumed += self._prefill_call(pslots)
+        return consumed
+
+    def _prefill_call(self, pslots) -> int:
+        """One batched fixed-shape prefill call over ``pslots``' next
+        chunks; returns the prompt tokens it computed."""
+        c = self.prefill_chunk
+        cfgc = self.cache.config
+        slots = self.scheduler.slots
+        # compact batch: pow2-bucketed over the number of slots actually
+        # prefilling (a lone late admission does not pay for num_slots
+        # lanes of attention); padding lanes are inert (n_valid 0,
+        # null-page block tables)
+        sb = self._pow2_count(len(pslots))
+        los = [slots[i].prefilled for i in pslots]
+        ns = [min(c, int(slots[i].request.prompt.shape[0]) - lo)
+              for i, lo in zip(pslots, los)]
+        call_tokens = sum(ns)
+        w = self._pow2_width(max(cfgc.pages_for(lo + n)
+                                 for lo, n in zip(los, ns)))
+        phase, part = self.tracer.phase, self._c_part
+        with phase("serving.prefill_call", lanes=sb, width=w,
+                   tokens=call_tokens) as call:
+            pend = [(i, pc) for i in pslots
+                    if (pc := self.cache.pending_copy(i)) is not None]
+            if pend:
+                with phase("serving.prefill.cow_copy",
+                           part["prefill", "cow_copy"]):
+                    for i, (src, dst) in pend:
+                        # copy-on-write of a borrowed tail page, owed
+                        # before this slot's first write lands in it
+                        self.cache.pages = self.copy_page_step(
+                            self.cache.pages, jnp.asarray(src, jnp.int32),
+                            jnp.asarray(dst, jnp.int32))
+                        self.cache.copy_done(i)
+                        self._c_cow.inc()
+                        root = self._req_spans.get(slots[i].request.rid)
+                        if root is not None:
+                            root.add_event("cow_copy", src_page=int(src),
+                                           dst_page=int(dst))
+            with phase("serving.prefill.assemble",
+                       part["prefill", "assemble"]) as asm:
+                tokens = np.zeros((sb, c), np.int32)
+                starts = np.zeros((sb,), np.int32)
+                nv = np.zeros((sb,), np.int32)
+                bt_rows = np.zeros((sb, cfgc.max_pages_per_slot), np.int32)
+                dbt_rows = np.zeros_like(bt_rows) if self.speculative \
+                    else None
+                for j, (i, lo, n) in enumerate(zip(pslots, los, ns)):
+                    prompt = slots[i].request.prompt
+                    # borrower write isolation: the page this chunk starts
+                    # writing into must be slot-owned (a shared tail page
+                    # must have been CoW-resolved above, never written)
+                    assert self.cache.writable(i, lo // cfgc.page_size), \
+                        f"slot {i} would write a borrowed page"
+                    tokens[j, :n] = prompt[lo:lo + n]
+                    starts[j] = lo
+                    nv[j] = n
+                    bt_rows[j] = self.cache.block_tables[i]
+                    if self.speculative:
+                        dbt_rows[j] = self.draft_cache.block_tables[i]
+                args = (jnp.asarray(starts), jnp.asarray(tokens),
+                        jnp.asarray(nv))
+                bt_dev = jnp.asarray(bt_rows[:, :w])
+                dbt_dev = jnp.asarray(dbt_rows[:, :w]) if self.speculative \
+                    else None
+            with phase("serving.prefill.dispatch",
+                       part["prefill", "dispatch"]):
+                nxt, self.cache.pages = self.prefill_step(
+                    self._step_params, self.cache.pages, bt_dev, *args)
                 if self.speculative:
-                    dbt_rows[j] = self.draft_cache.block_tables[i]
-            w = self._pow2_width(max(
-                cfgc.pages_for(int(starts[j]) + int(nv[j]))
-                for j in range(len(pslots))))
-            t0 = time.monotonic()
-            nxt, self.cache.pages = self.prefill_step(
-                self._step_params, self.cache.pages,
-                jnp.asarray(bt_rows[:, :w]),
-                jnp.asarray(starts), jnp.asarray(tokens), jnp.asarray(nv))
-            if self.speculative:
-                # the draft cache ingests the SAME chunks so its pages
-                # mirror the target's committed prefix (its next-token
-                # output is discarded — proposals start at decode time)
-                _, self.draft_cache.pages = self.draft_prefill_step(
-                    self.draft_params, self.draft_cache.pages,
-                    jnp.asarray(dbt_rows[:, :w]),
-                    jnp.asarray(starts), jnp.asarray(tokens),
-                    jnp.asarray(nv))
-            nxt = np.asarray(nxt)
-            now = time.monotonic()
-            self._reg.histogram(
-                "serving_prefill_step_seconds",
-                "wall time per batched prefill call (sync included)"
-            ).observe(now - t0)
+                    # the draft cache ingests the SAME chunks so its pages
+                    # mirror the target's committed prefix (its next-token
+                    # output is discarded — proposals start at decode
+                    # time)
+                    _, self.draft_cache.pages = self.draft_prefill_step(
+                        self.draft_params, self.draft_cache.pages, dbt_dev,
+                        *args)
+            with phase("serving.prefill.sync",
+                       part["prefill", "sync"]) as sync:
+                nxt = np.asarray(nxt)
+            t0, now = asm.start, sync.end
+            self._h_prefill_step.observe(now - t0)
             self.anatomy.add_phase("prefill", t0, now)
             self._note_busy((("prefill", w, sb),)
                             + ((("draft_prefill", w, sb),)
                                if self.speculative else ()), now - t0)
-            call_tokens = 0
-            tr_on = self.tracer.enabled
-            for j, i in enumerate(pslots):
-                st = self.scheduler.slots[i]
-                rid = st.request.rid
-                n = int(nv[j])
-                st.prefilled += n
-                self.cache.lengths[i] += n
-                if self.speculative:
-                    self.draft_cache.lengths[i] += n
-                call_tokens += n
-                self.cache.publish_prefix(i, st.request.prompt,
-                                          st.prefilled)
-                acc = self._phase_acc.get(rid)
-                if acc is not None:
-                    acc["prefill_s"] += now - t0
-                    acc["prefill_chunks"] += 1
-                if tr_on:
-                    self.tracer.record_span(
-                        "serving.prefill_chunk", start=t0, end=now,
-                        parent=self._req_spans.get(rid), slot=i,
-                        tokens=n, start_pos=st.prefilled - n)
-                if st.prefill_done:
-                    st.generated.append(int(nxt[j]))
-                    st.first_token_at = now
+            self._c_prefill_calls.inc()
+            with phase("serving.prefill.book", part["prefill", "book"]):
+                tr_on = self.tracer.enabled
+                for j, (i, n) in enumerate(zip(pslots, ns)):
+                    st = slots[i]
+                    rid = st.request.rid
+                    st.prefilled += n
+                    self.cache.lengths[i] += n
+                    if self.speculative:
+                        self.draft_cache.lengths[i] += n
+                    self.cache.publish_prefix(i, st.request.prompt,
+                                              st.prefilled)
+                    acc = self._phase_acc.get(rid)
                     if acc is not None:
-                        acc["prefill_done_s"] = now
-                    ttft = now - st.request.submitted_at
-                    self._reg.histogram(
-                        "serving_ttft_seconds",
-                        "submit -> first token latency",
-                        buckets=_LATENCY_BUCKETS).observe(ttft)
-                    self._reg.histogram(
-                        "serving_admit_to_first_token_seconds",
-                        "admit -> first token (prefill cost, net of "
-                        "queue wait)",
-                        buckets=_LATENCY_BUCKETS).observe(
-                            now - st.admitted_at)
-                    self._reg.counter("serving_tokens_total").inc()
-                    self.scheduler.note_ttft(ttft)
-                    root = self._req_spans.get(rid)
-                    if root is not None:
-                        root.add_event("first_token",
-                                       ttft_s=round(ttft, 6))
-            consumed += call_tokens
-            self._reg.counter(
-                "serving_prefill_tokens_total",
-                "prompt tokens actually computed by prefill (shared "
-                "prefix tokens are skipped)").inc(call_tokens)
-        return consumed
+                        acc["prefill_s"] += now - t0
+                        acc["prefill_chunks"] += 1
+                    if tr_on:
+                        self.tracer.record_span(
+                            "serving.prefill_chunk", start=t0, end=now,
+                            parent=self._req_spans.get(rid), slot=i,
+                            tokens=n, start_pos=st.prefilled - n,
+                            call=call.span_id)
+                    if st.prefill_done:
+                        st.generated.append(int(nxt[j]))
+                        st.first_token_at = now
+                        if acc is not None:
+                            acc["prefill_done_s"] = now
+                        ttft = now - st.request.submitted_at
+                        self._h_ttft.observe(ttft)
+                        self._h_admit_to_first.observe(now - st.admitted_at)
+                        self._c_tokens.inc()
+                        self.scheduler.note_ttft(ttft)
+                        root = self._req_spans.get(rid)
+                        if root is not None:
+                            root.add_event("first_token",
+                                           ttft_s=round(ttft, 6))
+                self._c_prefill_tokens.inc(call_tokens)
+        return call_tokens
 
     def _pow2_width(self, need: int) -> int:
         """Pow2 page count covering ``need`` pages — the gathers (and
@@ -1505,9 +1663,14 @@ class ServingEngine:
         Records the compiled set in :attr:`warmed_signatures`.
 
         ``cost_gauges`` additionally lowers each bucket through the
-        static cost model (tracing only — cheap next to the compile the
-        bucket already pays) and publishes per-bucket flops / peak-HBM
-        into ``serving_bucket_cost_flops`` /
+        static cost model before its first call. That lowering pays the
+        bucket's trace (seconds a signature, most of a warm-cache
+        warm-up) and the first call then finds it in jit's cache, so the
+        gauges move time from ``part="first_call"`` to
+        ``part="cost_gauges"`` of ``serving_warmup_seconds_total`` and add
+        little of their own: about 3 s of 67 on the chip's host (PERF.md
+        section 6, PR 25). It publishes per-bucket flops / peak-HBM into
+        ``serving_bucket_cost_flops`` /
         ``serving_bucket_cost_peak_hbm_bytes`` gauges (labels: phase,
         width, lanes), with the full reports kept in
         :attr:`bucket_costs` for budget audits."""
@@ -1515,7 +1678,9 @@ class ServingEngine:
         zeros = jnp.zeros((s_tot,), jnp.int32)
         self.warmed_signatures = set()
         self.bucket_costs = {}
+        clock = self.tracer.now
         for sig in self.warmup_plan():
+            t_sig, cost0 = clock(), self._c_warm_cost.value()
             if sig[0] == "decode":
                 w = sig[1]
                 args = (self._step_params, self.cache.pages,
@@ -1595,13 +1760,17 @@ class ServingEngine:
                     self.cache.pages, jnp.asarray(0, jnp.int32),
                     jnp.asarray(0, jnp.int32))
             self.warmed_signatures.add(sig)
+            self._c_warm_first.inc(clock() - t_sig - (
+                self._c_warm_cost.value() - cost0))
 
     def _bucket_cost_gauges(self, sig, step_fn, args):
         """Static cost of one warmup bucket -> observability gauges
         (lower-only; donation must not consume the live cache pages, so
-        the lowering runs on abstracted args)."""
+        the lowering runs on abstracted args). Its seconds go to
+        ``serving_warmup_seconds_total{part="cost_gauges"}``."""
         from paddle_tpu.analysis import cost_model
 
+        t0 = self.tracer.now()
         phase, width = sig[0], sig[1]
         lanes = sig[2] if len(sig) > 2 else self.scheduler.num_slots
         abstract = jax.tree_util.tree_map(
@@ -1618,6 +1787,7 @@ class ServingEngine:
             "serving_bucket_cost_peak_hbm_bytes",
             "static peak-HBM estimate per compiled bucket").set(
                 cost.peak_hbm_bytes, **labels)
+        self._c_warm_cost.inc(self.tracer.now() - t0)
 
     # -- live migration (fleet drain) -------------------------------------
 

@@ -63,7 +63,8 @@ def build_train_step(
             params = policy.cast_to_compute(params)
             batch = policy.cast_to_compute(batch)  # activations too: conv/dot
             # require matching operand dtypes
-        with capture_state() as tape:
+        # backward inherits the scope as transpose(jvp(forward))
+        with capture_state() as tape, jax.named_scope("forward"):
             out = loss_fn(params, **batch)
         if isinstance(out, tuple):
             loss, aux = out
@@ -100,8 +101,9 @@ def build_train_step(
             loss, updates, aux, grads = accum_step(state, batch)
         else:
             loss, updates, aux, grads = single_step(state, batch)
-        params, opt_state = optimizer.update(
-            grads, state["opt"], state["params"], mask=trainable_mask)
+        with jax.named_scope("optimizer"):
+            params, opt_state = optimizer.update(
+                grads, state["opt"], state["params"], mask=trainable_mask)
         params = apply_state_updates(params, updates)
         new_state = dict(state)
         new_state.update(params=params, opt=opt_state, step=state["step"] + 1)

@@ -16,6 +16,7 @@ picks for these shapes — the tuner's cache key asks ``jax.devices()``
 for the device kind, which is the CPU here.
 """
 
+import contextlib
 import functools
 import os
 
@@ -139,6 +140,36 @@ def test_flash_backward_compiles_for_v5e(variant, dtype, one_chip):
                                fn=grads, causal=not key_bias)
     # forward (residuals) + the dkv and dq backward kernels
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_flash_kernels_are_named_after_the_innermost_scope(one_chip):
+    """The flash ``pallas_call``s carry no ``name=``, and XLA names a
+    Pallas custom call after the innermost entry of JAX's name stack:
+    under ``build_train_step``'s ``forward`` scope the forward kernel is
+    ``jvp_forward_.N`` and the two backward kernels
+    ``transpose_jvp_forward__.N``. Readers of a device trace find the
+    kernels by these names, so a scope opened between the train step and
+    the kernel (one per module, say) renames all three after itself."""
+    import re
+
+    def compiled_names(*scopes):
+        def grads(body):
+            def loss(q, k, v, bias):
+                with contextlib.ExitStack() as stack:
+                    for name in scopes:
+                        stack.enter_context(jax.named_scope(name))
+                    return jnp.sum(body(q, k, v, bias).astype(jnp.float32))
+            return jax.grad(loss, argnums=(0, 1, 2))
+        text = _compile_kernel(
+            "flash_attention", _flash_args(jnp.bfloat16, True), one_chip,
+            fn=grads, causal=False).as_text()
+        return sorted(re.sub(r"\.\d+$", "", c) for c in re.findall(
+            r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text))
+
+    assert compiled_names("forward") == [
+        "jvp_forward_", "transpose_jvp_forward__", "transpose_jvp_forward__"]
+    assert compiled_names("forward", "attn") == ["attn"] * 3
 
 
 @pytest.mark.parametrize("mesh_axes, model_kw", [

@@ -3,7 +3,7 @@
 Three surfaces under test:
 
 - :class:`StepAnatomy`: per-jitted-step wall-time decomposition (host
-  gap / phase-split device busy / host assembly / sampled
+  gap / phase-split call wall time / host remainder / sampled
   collective-exposed time) with a bounded ring, schema validators, and
   the metrics/trace fan-out;
 - the resource-headroom plane: ``engine.health()["headroom"]`` (flops /
@@ -352,6 +352,46 @@ class TestEngineAnatomy:
         eng.generate_many(short, 12, eos_id=None)
         d_dec = delta(base2)
         assert d_dec["decode"] > d_dec.get("prefill", 0.0)
+
+    def test_phase_seconds_are_the_call_wall_time_of_the_phase_spans(
+            self, model_params):
+        """ISSUE 25: ``anatomy_phase_seconds`` is the wall time of the
+        jitted call (uploads, dispatch, sync), taken from the SAME clock reads as
+        the ``serving.*`` phase spans and their counters, not a second
+        pair beside them; the host figure is what is left of the step."""
+        tracer = obs.Tracer(enabled=True, capacity=4096)
+        eng = _engine(model_params, tracer=tracer)
+        rng = np.random.default_rng(3)
+        eng.generate_many([rng.integers(1, VOCAB, n).astype(np.int32)
+                           for n in (5, 7)], 4, eos_id=None)
+        snap = eng._reg.snapshot()
+
+        def parts(phase, *names):
+            return sum(snap['serving_step_part_seconds_total{part="%s",'
+                            'phase="%s"}' % (n, phase)] for n in names)
+        for phase in ("prefill", "decode"):
+            # assemble.start .. sync.end, the interval these series had
+            # before the phases: the three parts plus the few microseconds
+            # between one phase's exit and the next's entry
+            call_s = parts(phase, "assemble", "dispatch", "sync")
+            got = eng.anatomy.summary()["phase_s"][phase]
+            assert call_s <= got + 1e-6 and got <= call_s + 5e-3
+            assert snap['anatomy_phase_seconds_sum{phase="%s"}' % phase] \
+                == pytest.approx(got, abs=1e-6)
+        assert "dispatch + sync" in eng._reg.get("anatomy_phase_seconds").help
+        # the spans in the ring carry the same stamps
+        asm = tracer.spans(name="serving.decode.assemble")
+        sync = tracer.spans(name="serving.decode.sync")
+        calls = tracer.spans(name="anatomy.decode")
+        assert len(asm) == len(sync) == len(calls) >= 1
+        assert [(a.start, s.end) for a, s in zip(asm, sync)] == \
+            [(c.start, c.end) for c in calls]
+        # the step's wall time bounds its parts, sync included
+        step_s = snap["serving_step_seconds_total"]
+        assert 0 < sum(v for k, v in snap.items() if k.startswith(
+            "serving_step_part_seconds_total")) <= step_s
+        assert step_s <= sum(r["wall_s"] for r in eng.anatomy.records()) \
+            + 1e-3
 
 
 # ---------------------------------------------------------------------------

@@ -877,3 +877,241 @@ class TestReportIntegration:
         text = obs.report(reg, tracer=tracing.Tracer(capacity=4))
         assert "-- trace spans --" not in text
         assert "-- slo --" not in text
+
+
+# ---------------------------------------------------------------------------
+#: ISSUE 25: every phase span of one engine step and its parent
+STEP_TREE = {
+    "serving.step": None,
+    "serving.shed": "serving.step",
+    "serving.admit": "serving.step",
+    "serving.evict": "serving.step",
+    "serving.observe": "serving.step",
+    "serving.prefill_round": "serving.step",
+    "serving.prefill_call": "serving.prefill_round",
+    "serving.prefill.assemble": "serving.prefill_call",
+    "serving.prefill.cow_copy": "serving.prefill_call",
+    "serving.prefill.dispatch": "serving.prefill_call",
+    "serving.prefill.sync": "serving.prefill_call",
+    "serving.prefill.book": "serving.prefill_call",
+    "serving.decode_round": "serving.step",
+    "serving.decode.assemble": "serving.decode_round",
+    "serving.decode.dispatch": "serving.decode_round",
+    "serving.decode.sync": "serving.decode_round",
+    "serving.decode.book": "serving.decode_round",
+}
+#: tiny engine: 2 layers x 2 heads x 8 x float32, keys and values
+TOKEN_BYTES = 2 * 2 * 8 * 4 * 2
+
+
+def _drain(eng):
+    while not eng.scheduler.idle():
+        eng.step()
+
+
+def _part_seconds(reg):
+    return {k: v for k, v in reg.snapshot().items()
+            if k.startswith("serving_step_part_seconds_total")}
+
+
+class TestEngineStepPhases:
+    def _traced_run(self):
+        reg = obs.MetricsRegistry()
+        tr = tracing.Tracer(capacity=8192)
+        eng = _tiny_engine(registry=reg, tracer=tr)
+        eng.warmup()
+        tr.clear()
+        det = obs.RecompileDetector("phase_test", warmup=0, registry=reg)
+        prompt = np.arange(1, 11, dtype=np.int32)   # 2 full pages + tail
+        eng.submit(prompt.copy(), 6)
+        _drain(eng)
+        # the same prompt again maps the published tail page: a CoW copy
+        eng.submit(prompt.copy(), 6)
+        eng.submit(np.arange(20, 27, dtype=np.int32), 5)
+        _drain(eng)
+        det.check()
+        return eng, reg, tr, det
+
+    def test_span_tree_of_a_step_is_the_table(self):
+        eng, reg, tr, det = self._traced_run()
+        assert det.recompiles == 0          # spans never touch jit
+        spans = tr.spans()
+        by_id = {s.span_id: s for s in spans}
+        seen = set()
+        for s in spans:
+            if s.name not in STEP_TREE:
+                continue
+            seen.add(s.name)
+            want = STEP_TREE[s.name]
+            if want is None:
+                assert s.parent_id == 0, s.name
+            else:
+                assert by_id[s.parent_id].name == want, s.name
+        assert seen == set(STEP_TREE)
+        # nothing else of the engine's step is in the ring under another name
+        others = {s.name for s in spans if s.name.startswith("serving.")}
+        assert others - set(STEP_TREE) == {
+            "serving.request", "serving.prefill_chunk",
+            "serving.decode_block"}
+        steps = [s.attrs["step"] for s in spans if s.name == "serving.step"]
+        assert len(steps) >= 2 and steps == sorted(set(steps))
+        call = next(s for s in spans if s.name == "serving.prefill_call")
+        assert {"lanes", "width", "tokens"} <= set(call.attrs)
+        rnd = next(s for s in spans if s.name == "serving.decode_round")
+        assert {"width", "slots_live"} <= set(rnd.attrs)
+
+    def test_request_children_name_the_call_that_caused_them(self):
+        eng, reg, tr, det = self._traced_run()
+        spans = tr.spans()
+        calls = {s.span_id for s in spans if s.name == "serving.prefill_call"}
+        rounds = {s.span_id for s in spans
+                  if s.name == "serving.decode_round"}
+        chunks = [s for s in spans if s.name == "serving.prefill_chunk"]
+        blocks = [s for s in spans if s.name == "serving.decode_block"]
+        assert chunks and blocks
+        assert all(s.attrs["call"] in calls for s in chunks)
+        assert all(s.attrs["call"] in rounds for s in blocks)
+
+    def test_disabled_and_unprofiled_step_allocates_no_span(self,
+                                                            monkeypatch):
+        reg = obs.MetricsRegistry()
+        tr = tracing.Tracer(capacity=8, enabled=False)
+        eng = _tiny_engine(registry=reg, tracer=tr)
+        eng.warmup()
+        made = []
+        init = tracing.Span.__init__
+        monkeypatch.setattr(
+            tracing.Span, "__init__",
+            lambda self, *a, **k: (made.append(1), init(self, *a, **k))[1])
+        eng.submit(np.arange(1, 6, dtype=np.int32), 4)
+        _drain(eng)
+        assert made == [] and tr.spans() == []
+        # the counters at the same boundaries are on all the same
+        assert sum(_part_seconds(reg).values()) > 0
+
+    def test_parts_sum_to_at_most_the_step_seconds(self):
+        eng, reg, tr, det = self._traced_run()
+        snap = reg.snapshot()
+        parts = _part_seconds(reg)
+        assert len(parts) == 11 and all(v >= 0 for v in parts.values())
+        for key in ('phase="prefill"', 'phase="decode"',
+                    'phase="sched"', 'phase="observe"'):
+            assert sum(v for k, v in parts.items() if key in k) > 0, key
+        assert 0 < sum(parts.values()) <= snap["serving_step_seconds_total"]
+        assert snap["serving_prefill_calls_total"] == len(
+            tr.spans(name="serving.prefill_call"))
+        assert snap["serving_decode_rounds_total"] == len(
+            tr.spans(name="serving.decode_round"))
+        # an idle tick is no step that did work
+        before = snap["serving_step_seconds_total"]
+        eng.step()
+        assert reg.snapshot()["serving_step_seconds_total"] == before
+
+    def test_kv_bytes_of_a_decode_round_match_a_hand_count(self):
+        reg = obs.MetricsRegistry()
+        eng = _tiny_engine(registry=reg, decode_block=4,
+                           tracer=tracing.Tracer(enabled=False))
+        eng.warmup()
+        eng.submit(np.arange(1, 6, dtype=np.int32), 3)      # 5 tokens
+        eng.submit(np.arange(1, 8, dtype=np.int32), 3)      # 7 tokens
+        _drain(eng)
+        snap = reg.snapshot()
+        assert snap["serving_decode_rounds_total"] == 1
+        # 4 token steps; slot lengths 5 and 7 before the round: token
+        # step j attends over L + j + 1 tokens -> 4 * 12 + 2 * (1+2+3+4)
+        live = (4 * (5 + 7) + 2 * 10) * TOKEN_BYTES
+        # the grid visits 2 slots x 4 pages (11 tokens -> 3 pages of 4,
+        # gathered at the pow2 width 4)
+        gathered = 4 * 2 * 4 * 4 * TOKEN_BYTES
+        assert snap['serving_decode_kv_bytes_total{kind="live"}'] == live
+        assert snap['serving_decode_kv_bytes_total{kind="gathered"}'] \
+            == gathered
+        assert gathered >= live > 0
+
+    def test_a_profiler_session_sees_the_step_and_its_children(self,
+                                                               tmp_path):
+        import glob
+
+        import jax.profiler
+        eng = _tiny_engine(registry=obs.MetricsRegistry(),
+                           tracer=tracing.Tracer(enabled=False))
+        eng.warmup()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            eng.submit(np.arange(1, 11, dtype=np.int32), 5)
+            _drain(eng)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        events = [ev for plane in
+                  jax.profiler.ProfileData.from_file(path).planes
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith("serving.")]
+        # attributes come back as stats, or inside the name after a "#"
+        assert {ev.name.split("#")[0] for ev in events} >= set(
+            STEP_TREE) - {"serving.prefill.cow_copy"}
+        step = next(ev for ev in events
+                    if ev.name.split("#")[0] == "serving.step")
+        attrs = {k for k, _ in step.stats} | set(
+            kv.split("=")[0] for kv in step.name.partition("#")[2]
+            .strip("#").split(",") if kv)
+        assert {"step", "t_mono_ns"} <= attrs
+
+    def test_step_histograms_have_buckets_a_median_can_be_taken_from(self):
+        reg = obs.MetricsRegistry()
+        _tiny_engine(registry=reg)
+        for name in ("serving_decode_step_seconds",
+                     "serving_prefill_step_seconds"):
+            b = reg.get(name).buckets
+            assert b[0] == 1e-3 and 2.0 <= b[-1] < 2.1
+            assert all(1.4 < hi / lo < 1.43 for lo, hi in zip(b, b[1:]))
+
+    def test_warmup_seconds_split_by_part(self):
+        reg = obs.MetricsRegistry()
+        eng = _tiny_engine(registry=reg)
+        eng.warmup(cost_gauges=False)
+        snap = reg.snapshot()
+        first = snap['serving_warmup_seconds_total{part="first_call"}']
+        assert first > 0
+        assert snap['serving_warmup_seconds_total{part="cost_gauges"}'] == 0
+        eng.warmup()
+        snap = reg.snapshot()
+        assert snap['serving_warmup_seconds_total{part="cost_gauges"}'] > 0
+        assert snap['serving_warmup_seconds_total{part="first_call"}'] \
+            > first
+
+
+class TestPhasePrimitive:
+    def test_phase_feeds_ring_counter_and_clock_from_one_read_pair(self):
+        reg = obs.MetricsRegistry()
+        child = reg.counter("t_part_seconds_total").child(part="x")
+        tr = tracing.Tracer(capacity=16)
+        with tr.phase("outer", step=3) as outer:
+            with tr.phase("inner", child, lanes=2) as inner:
+                pass
+        o, i = tr.spans(name="outer")[0], tr.spans(name="inner")[0]
+        assert i.parent_id == o.span_id and o.parent_id == 0
+        assert (i.start, i.end) == (inner.start, inner.end)
+        assert inner.span_id == i.span_id and i.attrs == {"lanes": 2}
+        assert child.value() == inner.end - inner.start
+        assert outer.start <= inner.start <= inner.end <= outer.end
+
+    def test_disabled_phase_still_times_and_counts(self):
+        reg = obs.MetricsRegistry()
+        child = reg.counter("t_part_seconds_total").child(part="x")
+        tr = tracing.Tracer(capacity=16, enabled=False)
+        with tr.phase("p", child, stamp=True) as ph:
+            pass
+        assert ph.span is None and ph.span_id == 0 and tr.spans() == []
+        assert child.value() == ph.end - ph.start >= 0
+
+    def test_phase_marks_the_ring_span_on_error(self):
+        tr = tracing.Tracer(capacity=16)
+        with pytest.raises(RuntimeError):
+            with tr.phase("boom"):
+                raise RuntimeError("x")
+        assert tr.spans(name="boom")[0].status == "error"
+        assert tr.current() is None
