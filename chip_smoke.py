@@ -130,9 +130,10 @@ def phase_paged_kernels(sizes, seed):
     mp = -(-sizes.max_tokens_per_slot // ps)
     num_pages = s * mp + 1
     rng = np.random.default_rng(seed)
-    k32 = jnp.asarray(rng.standard_normal((num_pages, ps, h, dh)),
+    # the pool as the engine stores it: a token's heads folded head-major
+    k32 = jnp.asarray(rng.standard_normal((num_pages, ps, h * dh)),
                       jnp.float32)
-    v32 = jnp.asarray(rng.standard_normal((num_pages, ps, h, dh)),
+    v32 = jnp.asarray(rng.standard_normal((num_pages, ps, h * dh)),
                       jnp.float32)
     bt = jnp.asarray(rng.permutation(num_pages - 1)[:s * mp].reshape(s, mp)
                      + 1, jnp.int32)
@@ -147,8 +148,8 @@ def phase_paged_kernels(sizes, seed):
     n_valid = jnp.asarray(n_valid, jnp.int32)
     q_dec = jnp.asarray(rng.standard_normal((s, h, dh)), jnp.float32)
     q_pre = jnp.asarray(rng.standard_normal((s, c, h, dh)), jnp.float32)
-    kq, ks = quantize_kv(k32, (2, 3))
-    vq, vs = quantize_kv(v32, (2, 3))
+    kq, ks = quantize_kv(k32, (2,))
+    vq, vs = quantize_kv(v32, (2,))
     pools = {
         "f32": (k32, v32),
         "bf16": (k32.astype(jnp.bfloat16), v32.astype(jnp.bfloat16)),

@@ -41,7 +41,8 @@ DEFAULT_ALLOWLIST = os.path.join(os.path.dirname(_PKG_ROOT), "tools",
 
 
 def _layout_rank(layout: str) -> Optional[int]:
-    """``"(P,ps,H,Dh)" -> 4``; None when the layout is not dimensioned."""
+    """``"(P,ps,H*Dh) i8" -> 3`` (dims are comma-separated; ``H*Dh`` is
+    one folded dim); None when the layout is not dimensioned."""
     if "(" not in layout:
         return None
     body = layout[layout.index("(") + 1:layout.index(")")]

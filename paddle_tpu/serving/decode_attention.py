@@ -11,7 +11,10 @@ a fourth bespoke module):
 
 ``ragged_paged_decode_attention`` — one query token per slot:
   q            (S, H, Dh)        one query token per decode slot
-  k/v pages    (P, ps, H, Dh)    fixed-size pages, token-major
+  k/v pages    (P, ps, H*Dh)     fixed-size pages, token-major; a
+                                 token's heads folded into the lane
+                                 axis, head-major (head ``h`` is
+                                 lanes ``h*Dh .. (h+1)*Dh``)
   block_tables (S, max_pages)    page ids per slot (page 0 = null page)
   lengths      (S,)              live tokens per slot (0 = inactive slot)
 
@@ -30,7 +33,8 @@ Each has two implementations with identical numerics:
   ``(S, cdiv(max_pages, pages_per_block))``, that scalar-prefetches
   the block table so each kv block's HBM address is known before the
   body runs (the PrefetchScalarGridSpec pattern), streams WHOLE pages
-  (every head; the head loop is inside the body), does online-softmax
+  (every head; the head loop is inside the body and takes head ``h`` as
+  a static lane slice of the page block), does online-softmax
   accumulation over pages, and skips pages past the slot's live extent
   entirely. The interpret path runs the REAL kernel on CPU, so tier-1
   tests exercise it.
@@ -59,7 +63,9 @@ contracts with donation-safe pages AND scales, and the shared
 **Tensor-parallel variants** (ISSUE 15):
 ``ragged_paged_{decode,prefill}[_int8]_tp_attention`` run the
 single-device kernels per head shard under ``shard_map`` — pages and
-queries sharded ``H/tp`` over the mesh's "tp" axis, block-table
+queries sharded ``H/tp`` over the mesh's "tp" axis (head-major folding
+keeps a shard's heads contiguous: each holds ``(P, ps, (H/tp)*Dh)``),
+block-table
 geometry (and int8 scale rows) replicated. Heads are independent, so
 each shard's output is BIT-identical to the tp=1 kernel; the one
 attention-output collective lives at the caller's row-sharded output
@@ -87,6 +93,14 @@ from paddle_tpu.ops.attention import NEG_INF
 # lax reference path
 # ---------------------------------------------------------------------------
 
+def _gather_pages(pages, block_tables, h, dh):
+    """A slot batch's pages out of the folded pool, heads unfolded:
+    ``(S, mp, ps, H, Dh)`` (row-major, so the reshape moves nothing)."""
+    g = pages[block_tables]
+    s, mp, ps, _hd = g.shape                       # a 3-D pool only
+    return g.reshape(s, mp, ps, h, dh)
+
+
 def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale):
     s_slots, h, dh = q.shape
     mp = block_tables.shape[1]
@@ -94,8 +108,8 @@ def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale):
     # contract straight against the gathered 5-D (S, mp, ps, H, Dh)
     # layout — reshaping the gather to token-major would materialize a
     # full extra copy of every slot's K and V per call
-    kg = k_pages[block_tables]
-    vg = v_pages[block_tables]
+    kg = _gather_pages(k_pages, block_tables, h, dh)
+    vg = _gather_pages(v_pages, block_tables, h, dh)
     scores = jnp.einsum("shd,smthd->shmt", q.astype(jnp.float32),
                         kg.astype(jnp.float32)) * scale
     scores = scores.reshape(s_slots, h, mp * ps)
@@ -116,11 +130,16 @@ def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale):
 #
 # Block shapes the TPU compiler accepts (the last two dims of every block
 # equal the array's own, or are (8k, 128k)-aligned), with the stored page
-# layout (P, ps, H, Dh) left alone:
+# layout (P, ps, H*Dh) left alone. The pool is stored the way the kernels
+# read it: with (ps, H*Dh) as the two minor dims XLA keeps an entry
+# parameter row-major, so no step relays the pool out around the kernel
+# (with (H, Dh) minor it keeps the pool page_size-minor, and every call
+# copies it whole, padded (H, Dh) -> (16, 128), in and out):
 #
-#   pages    block (1, ps, H, Dh)   one WHOLE page, every head — the HBM
+#   pages    block (1, ps, H*Dh)    one WHOLE page, every head — the HBM
 #                                   tiles of a page move to VMEM as they
-#                                   lie, no relayout of the pool
+#                                   lie; head h is the static lane slice
+#                                   [h*Dh, (h+1)*Dh) of the block
 #   q / out  block (1, H, R, Dh)    head-major, R = 1 (decode) or C
 #                                   (prefill); the wrappers transpose the
 #                                   small activations, never the pool
@@ -235,7 +254,7 @@ def _paged_attend_kernel(bt_ref, *refs, page_size, pages_per_block,
     sl = pl.program_id(0)
     pj = pl.program_id(1)
     npg = pl.num_programs(1)
-    n_heads, rows = q_ref.shape[1], q_ref.shape[2]
+    n_heads, rows, dh = q_ref.shape[1:]
     mp = bt_ref.shape[1]
 
     @pl.when(pj == 0)
@@ -276,8 +295,10 @@ def _paged_attend_kernel(bt_ref, *refs, page_size, pages_per_block,
             for h in range(n_heads):
                 _online_softmax_page_fold(
                     q_ref[0, h].astype(jnp.float32),            # (R, Dh)
-                    k_refs[t][0, :, h, :].astype(jnp.float32),  # (ps, Dh)
-                    v_refs[t][0, :, h, :].astype(jnp.float32),
+                    k_refs[t][0, :, h * dh:(h + 1) * dh].astype(
+                        jnp.float32),                           # (ps, Dh)
+                    v_refs[t][0, :, h * dh:(h + 1) * dh].astype(
+                        jnp.float32),
                     ok, m_scr, l_scr, acc_scr, h,
                     k_scale=k_scale, v_scale=v_scale)
 
@@ -294,16 +315,17 @@ def _paged_attend_kernel(bt_ref, *refs, page_size, pages_per_block,
                 alive, acc_scr[h] / denom, 0.0).astype(o_ref.dtype)
 
 
-def _paged_kv_specs(ps, h, dh, mp, pb):
+def _paged_kv_specs(ps, hd, mp, pb):
     """``pb`` (k, v) BlockSpec pairs per grid step: the WHOLE page
-    ``j*pb + t`` of the slot's block table, all heads (clamped to the
+    ``j*pb + t`` of the slot's block table, all heads folded into its
+    ``hd = H*Dh`` lanes (clamped to the
     last page — the clamped duplicate is fully masked by the token test
     in the kernel body). The index maps take the scalar-prefetch refs
     after the grid ids; the block table is always the first of them."""
     def kv_spec(t):
         def index(s, j, bt, *_rest):
-            return (bt[s, jnp.minimum(j * pb + t, mp - 1)], 0, 0, 0)
-        return pl.BlockSpec((1, ps, h, dh), index)
+            return (bt[s, jnp.minimum(j * pb + t, mp - 1)], 0, 0)
+        return pl.BlockSpec((1, ps, hd), index)
     ks = [kv_spec(t) for t in range(pb)]
     vs = [kv_spec(t) for t in range(pb)]
     return ks, vs
@@ -337,7 +359,7 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
     pb = max(1, min(int(pages_per_block), mp))
-    k_specs, v_specs = _paged_kv_specs(ps, h, dh, mp, pb)
+    k_specs, v_specs = _paged_kv_specs(ps, h * dh, mp, pb)
     sc_specs, sc_args = [], []
     if quantized:
         ks_specs, vs_specs = _paged_scale_specs(ps, mp, pb)
@@ -412,8 +434,8 @@ def _paged_decode_int8_lax(q, k_pages, v_pages, k_scales, v_scales,
     s_slots, h, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
-    kg = k_pages[block_tables]                  # (S, mp, ps, H, Dh) int8
-    vg = v_pages[block_tables]
+    kg = _gather_pages(k_pages, block_tables, h, dh)    # int8
+    vg = _gather_pages(v_pages, block_tables, h, dh)
     ksg = k_scales[block_tables]                # (S, mp, ps) f32
     vsg = v_scales[block_tables]
     scores = jnp.einsum("shd,smthd->shmt", q.astype(jnp.float32),
@@ -453,8 +475,8 @@ def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
     s_slots, c, h, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
-    kg = k_pages[block_tables]                     # (S, mp, ps, H, Dh)
-    vg = v_pages[block_tables]
+    kg = _gather_pages(k_pages, block_tables, h, dh)
+    vg = _gather_pages(v_pages, block_tables, h, dh)
     scores = jnp.einsum("schd,smthd->shcmt", q.astype(jnp.float32),
                         kg.astype(jnp.float32)) * scale
     scores = scores.reshape(s_slots, h, c, mp * ps)
@@ -501,8 +523,8 @@ def _paged_prefill_int8_lax(q, k_pages, v_pages, k_scales, v_scales,
     s_slots, c, h, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
-    kg = k_pages[block_tables]                  # (S, mp, ps, H, Dh) int8
-    vg = v_pages[block_tables]
+    kg = _gather_pages(k_pages, block_tables, h, dh)    # int8
+    vg = _gather_pages(v_pages, block_tables, h, dh)
     ksg = k_scales[block_tables]                # (S, mp, ps) f32
     vsg = v_scales[block_tables]
     scores = jnp.einsum("schd,smthd->shcmt", q.astype(jnp.float32),
@@ -543,7 +565,8 @@ def ragged_paged_decode_attention(q, k_pages, v_pages, block_tables,
                                   impl: str = "auto"):
     """One decode step of attention for every slot at once.
 
-    ``q`` (S, H, Dh); ``k_pages``/``v_pages`` (P, page_size, H, Dh);
+    ``q`` (S, H, Dh); ``k_pages``/``v_pages`` (P, page_size, H*Dh),
+    a token's heads folded head-major into the last axis;
     ``block_tables`` (S, max_pages) int32; ``lengths`` (S,) int32 valid
     tokens per slot. Returns (S, H, Dh). ``impl``: "auto" (pallas on
     TPU, lax elsewhere), "lax", "pallas", "pallas_interpret".
@@ -617,7 +640,8 @@ def ragged_paged_decode_tp_attention(q, k_pages, v_pages, block_tables,
                                      impl: str = "auto", mesh=None):
     """Tensor-parallel ragged paged decode (ISSUE 15): same contract as
     :func:`ragged_paged_decode_attention` with ``q`` (S, H, Dh) and the
-    page pool sharded ``H/tp`` over the mesh's "tp" axis, block tables
+    page pool sharded ``H/tp`` over the mesh's "tp" axis (the folded
+    ``H*Dh`` axis cut into ``tp`` runs of whole heads), block tables
     and lengths replicated. Runs the single-device kernel per head
     shard under ``shard_map`` — heads are independent, so the sharded
     output is BIT-identical to the tp=1 kernel on the same pages; the
@@ -763,10 +787,14 @@ def _make_paged_sample(seed, *, chunked):
     c = ps  # prefill chunk = one page of queries
     num_pages = s_slots * mp + 1
     rng = np.random.default_rng(seed)
+    # drawn per (page, token, head, dim) and folded: the same numbers
+    # the 4-D pool held, in the layout the pool stores
     k_pages = jnp.asarray(
-        rng.standard_normal((num_pages, ps, h, dh)), jnp.float32)
+        rng.standard_normal((num_pages, ps, h, dh)), jnp.float32
+    ).reshape(num_pages, ps, h * dh)
     v_pages = jnp.asarray(
-        rng.standard_normal((num_pages, ps, h, dh)), jnp.float32)
+        rng.standard_normal((num_pages, ps, h, dh)), jnp.float32
+    ).reshape(num_pages, ps, h * dh)
     perm = rng.permutation(num_pages - 1)[:s_slots * mp] + 1
     block_tables = jnp.asarray(perm.reshape(s_slots, mp), jnp.int32)
     if not chunked:
@@ -782,9 +810,10 @@ def _make_paged_sample(seed, *, chunked):
     return (q, k_pages, v_pages, block_tables, starts, n_valid), {}
 
 
-def _paged_tune_signature(args, kwargs):
-    q, k_pages, _v, bt = args[0], args[1], args[2], args[3]
-    sig = [("s", q.shape[0]), ("h", k_pages.shape[2]),
+def _paged_sig(q, k_pages, bt):
+    """Tune-key dims of one paged call; the head count comes from ``q``
+    (the folded pool does not carry it)."""
+    sig = [("s", q.shape[0]), ("h", q.shape[-2]),
            ("d", q.shape[-1]), ("ps", k_pages.shape[1]),
            ("mp", bt.shape[1])]
     if q.ndim == 4:                      # prefill: chunk width matters
@@ -792,14 +821,20 @@ def _paged_tune_signature(args, kwargs):
     return tuple(sig)
 
 
+def _paged_tune_signature(args, kwargs):
+    return _paged_sig(args[0], args[1], args[3])
+
+
 def _paged_vmem_estimate(args, kwargs, blocks):
     """VMEM working set of one grid step, as the TPU lays it out: the
-    streamed blocks are whole pages (every head), tiles pad the last two
-    dims to (32/itemsize, 128), and the pipeline double-buffers every
-    in/out block. One estimate for the fp and int8 kernels — the page
-    dtype and the scale rows are read off the arguments."""
+    streamed blocks are whole pages (every head, folded into the lane
+    axis: ``(ps, H*Dh)`` tiles with no per-head padding), tiles pad the
+    last two dims to (32/itemsize, 128), and the pipeline double-buffers
+    every in/out block. One estimate for the fp and int8 kernels — the
+    page dtype and the scale rows are read off the arguments."""
     q, k_pages = args[0], args[1]
-    ps, h, dh = k_pages.shape[1:]
+    ps = k_pages.shape[1]
+    h, dh = q.shape[-2:]
     rows = q.shape[1] if q.ndim == 4 else 1
     pb = blocks.get("pages_per_block", 1)
 
@@ -808,7 +843,7 @@ def _paged_vmem_estimate(args, kwargs, blocks):
         return (lead * -(-sub // tile) * tile * -(-lane // 128) * 128
                 * itemsize)
 
-    page = tiled(ps, h, dh, k_pages.dtype.itemsize)
+    page = tiled(1, ps, h * dh, k_pages.dtype.itemsize)
     qo = tiled(h, rows, dh, q.dtype.itemsize)
     streamed = 2 * pb * page + 2 * qo
     if k_pages.dtype.itemsize == 1:              # int8: + scale groups
@@ -828,8 +863,8 @@ def _decode_donation_probe():
         # the pages, attend THROUGH THE PALLAS BODY (interpret lowering
         # — the structure XLA aliases, incl. the pages-passed-
         # pages_per_block-times operand shape), hand the pages back
-        kp = kp.at[1, 0].set(q[0])
-        vp = vp.at[1, 0].set(q[0])
+        kp = kp.at[1, 0].set(q[0].reshape(-1))
+        vp = vp.at[1, 0].set(q[0].reshape(-1))
         out = _decode_kernel_pallas(
             q, kp, vp, bt, lens,
             block_sizes={"pages_per_block": 4}, interpret=True)
@@ -891,8 +926,8 @@ def _prefill_donation_probe():
         _make_paged_sample(0, chunked=True)
 
     def step(kp, vp, q, bt, st, nv):
-        kp = kp.at[1, 0].set(q[0, 0])
-        vp = vp.at[1, 0].set(q[0, 0])
+        kp = kp.at[1, 0].set(q[0, 0].reshape(-1))
+        vp = vp.at[1, 0].set(q[0, 0].reshape(-1))
         out = _prefill_kernel_pallas(
             q, kp, vp, bt, st, nv,
             block_sizes={"pages_per_block": 4}, interpret=True)
@@ -930,9 +965,9 @@ def _dequant_pages_np(k_pages, v_pages, k_scales, v_scales):
     fused in-kernel path (the parity battery's whole point)."""
     import numpy as np
     kf = np.asarray(k_pages, np.float32) \
-        * np.asarray(k_scales, np.float32)[:, :, None, None]
+        * np.asarray(k_scales, np.float32)[:, :, None]
     vf = np.asarray(v_pages, np.float32) \
-        * np.asarray(v_scales, np.float32)[:, :, None, None]
+        * np.asarray(v_scales, np.float32)[:, :, None]
     return jnp.asarray(kf), jnp.asarray(vf)
 
 
@@ -981,19 +1016,13 @@ def _make_paged_int8_sample(seed, *, chunked):
     args, kwargs = _make_paged_sample(seed, chunked=chunked)
     q, k_pages, v_pages = args[0], args[1], args[2]
     rest = args[3:]
-    kq, ks = quantize_kv(k_pages, (2, 3))          # scales (P, ps)
-    vq, vs = quantize_kv(v_pages, (2, 3))
+    kq, ks = quantize_kv(k_pages, (2,))            # scales (P, ps)
+    vq, vs = quantize_kv(v_pages, (2,))
     return (q, kq, vq, ks, vs) + rest, kwargs
 
 
 def _paged_int8_tune_signature(args, kwargs):
-    q, k_pages, bt = args[0], args[1], args[5]
-    sig = [("s", q.shape[0]), ("h", k_pages.shape[2]),
-           ("d", q.shape[-1]), ("ps", k_pages.shape[1]),
-           ("mp", bt.shape[1])]
-    if q.ndim == 4:                      # prefill: chunk width matters
-        sig.insert(1, ("c", q.shape[1]))
-    return tuple(sig)
+    return _paged_sig(args[0], args[1], args[5])
 
 
 def _decode_int8_donation_probe():
@@ -1005,7 +1034,7 @@ def _decode_int8_donation_probe():
         # the int8 pages + scale rows, attend THROUGH THE PALLAS BODY,
         # hand all four buffers back (pages AND scales must alias)
         from paddle_tpu.serving.paged_cache import quantize_kv
-        kq, ksc = quantize_kv(q[:1], (1, 2))
+        kq, ksc = quantize_kv(q[:1].reshape(1, -1), (1,))
         kp = kp.at[1, 0].set(kq[0])
         vp = vp.at[1, 0].set(kq[0])
         ks = ks.at[1, 0].set(ksc[0])
@@ -1027,7 +1056,7 @@ def _prefill_int8_donation_probe():
 
     def step(kp, vp, ks, vs, q, bt, st, nv):
         from paddle_tpu.serving.paged_cache import quantize_kv
-        kq, ksc = quantize_kv(q[:1, 0], (1, 2))
+        kq, ksc = quantize_kv(q[:1, 0].reshape(1, -1), (1,))
         kp = kp.at[1, 0].set(kq[0])
         vp = vp.at[1, 0].set(kq[0])
         ks = ks.at[1, 0].set(ksc[0])
@@ -1050,8 +1079,8 @@ def _register_paged_kernels():
         name="ragged_paged_decode",
         contract=kernels.KernelContract(
             version=1,
-            arg_layouts={"q": "(S,H,Dh)", "k_pages": "(P,ps,H,Dh)",
-                         "v_pages": "(P,ps,H,Dh)",
+            arg_layouts={"q": "(S,H,Dh)", "k_pages": "(P,ps,H*Dh)",
+                         "v_pages": "(P,ps,H*Dh)",
                          "block_tables": "(S,mp) i32",
                          "lengths": "(S,) i32"},
             out_layout="(S,H,Dh)",
@@ -1079,8 +1108,8 @@ def _register_paged_kernels():
         name="ragged_paged_prefill",
         contract=kernels.KernelContract(
             version=1,
-            arg_layouts={"q": "(S,C,H,Dh)", "k_pages": "(P,ps,H,Dh)",
-                         "v_pages": "(P,ps,H,Dh)",
+            arg_layouts={"q": "(S,C,H,Dh)", "k_pages": "(P,ps,H*Dh)",
+                         "v_pages": "(P,ps,H*Dh)",
                          "block_tables": "(S,mp) i32",
                          "chunk_starts": "(S,) i32",
                          "n_valid": "(S,) i32"},
@@ -1107,8 +1136,8 @@ def _register_paged_kernels():
         name="ragged_paged_decode_int8",
         contract=kernels.KernelContract(
             version=1,
-            arg_layouts={"q": "(S,H,Dh)", "k_pages": "(P,ps,H,Dh) i8",
-                         "v_pages": "(P,ps,H,Dh) i8",
+            arg_layouts={"q": "(S,H,Dh)", "k_pages": "(P,ps,H*Dh) i8",
+                         "v_pages": "(P,ps,H*Dh) i8",
                          "k_scales": "(P,ps) f32",
                          "v_scales": "(P,ps) f32",
                          "block_tables": "(S,mp) i32",
@@ -1141,8 +1170,8 @@ def _register_paged_kernels():
         name="ragged_paged_prefill_int8",
         contract=kernels.KernelContract(
             version=1,
-            arg_layouts={"q": "(S,C,H,Dh)", "k_pages": "(P,ps,H,Dh) i8",
-                         "v_pages": "(P,ps,H,Dh) i8",
+            arg_layouts={"q": "(S,C,H,Dh)", "k_pages": "(P,ps,H*Dh) i8",
+                         "v_pages": "(P,ps,H*Dh) i8",
                          "k_scales": "(P,ps) f32",
                          "v_scales": "(P,ps) f32",
                          "block_tables": "(S,mp) i32",
@@ -1182,9 +1211,10 @@ _register_paged_kernels()
 
 from jax.sharding import PartitionSpec as _P  # noqa: E402
 
-#: the canonical tp specs: pages/queries sharded on the HEAD axis,
-#: block-table geometry (and int8 scale rows) replicated
-_TP_KV_SPEC = _P(None, None, "tp", None)          # (P, ps, H, Dh)
+#: the canonical tp specs: pages/queries sharded on the HEAD axis (the
+#: pool's folded H*Dh axis is head-major, so a shard's slice is its own
+#: whole heads), block-table geometry (and int8 scale rows) replicated
+_TP_KV_SPEC = _P(None, None, "tp")                # (P, ps, H*Dh)
 _TP_Q_DECODE = _P(None, "tp", None)               # (S, H, Dh)
 _TP_Q_PREFILL = _P(None, None, "tp", None)        # (S, C, H, Dh)
 
@@ -1317,12 +1347,13 @@ def _tp_local_sample(seed, *, tp, chunked, quantized=False):
     maker = _make_paged_int8_sample if quantized else _make_paged_sample
     args, kwargs = maker(seed, chunked=chunked)
     q, k_pages, v_pages = args[0], args[1], args[2]
-    h = k_pages.shape[2]
+    h, dh = q.shape[-2:]
     if h % tp:
         return None
     hl = h // tp
     q = q[:, :, :hl] if q.ndim == 4 else q[:, :hl]
-    return (q, k_pages[:, :, :hl], v_pages[:, :, :hl]) + args[3:], kwargs
+    return (q, k_pages[:, :, :hl * dh],
+            v_pages[:, :, :hl * dh]) + args[3:], kwargs
 
 
 def _tp_donation_probe(*, chunked, quantized):
@@ -1344,7 +1375,7 @@ def _tp_donation_probe(*, chunked, quantized):
             0, chunked=chunked)
     else:
         (q, kp, vp, *rest), _ = _make_paged_sample(0, chunked=chunked)
-    h, dh = kp.shape[2], kp.shape[3]
+    h, dh = q.shape[-2:]
     d_model = h * dh
     wo = jnp.zeros((h, dh, d_model), jnp.float32)
     inner = ("ragged_paged_prefill" if chunked else "ragged_paged_decode")
@@ -1356,8 +1387,8 @@ def _tp_donation_probe(*, chunked, quantized):
         def local(kp, vp, ks, vs, q, wo, *geo):
             from paddle_tpu import kernels
             from paddle_tpu.serving.paged_cache import quantize_kv
-            tok = q[:1, 0] if chunked else q[:1]
-            kq, ksc = quantize_kv(tok, (1, 2), psum_axis="tp")
+            tok = (q[:1, 0] if chunked else q[:1]).reshape(1, -1)
+            kq, ksc = quantize_kv(tok, (1,), psum_axis="tp")
             kp = kp.at[1, 0].set(kq[0])
             vp = vp.at[1, 0].set(kq[0])
             ks = ks.at[1, 0].set(ksc[0])
@@ -1380,7 +1411,7 @@ def _tp_donation_probe(*, chunked, quantized):
     else:
         def local(kp, vp, q, wo, *geo):
             from paddle_tpu import kernels
-            tok = q[0, 0] if chunked else q[0]
+            tok = (q[0, 0] if chunked else q[0]).reshape(-1)
             kp = kp.at[1, 0].set(tok)
             vp = vp.at[1, 0].set(tok)
             att = kernels.dispatch(inner, q, kp, vp, *geo, impl="lax")
@@ -1408,26 +1439,26 @@ def _register_tp_kernels():
            "row-sharded projection"
     defs = (
         ("ragged_paged_decode_tp", "ragged_paged_decode", False, False,
-         {"q": "(S,H,Dh) H/tp", "k_pages": "(P,ps,H,Dh) H/tp",
-          "v_pages": "(P,ps,H,Dh) H/tp", "block_tables": "(S,mp) i32",
+         {"q": "(S,H,Dh) H/tp", "k_pages": "(P,ps,H*Dh) H/tp",
+          "v_pages": "(P,ps,H*Dh) H/tp", "block_tables": "(S,mp) i32",
           "lengths": "(S,) i32"}, "(S,H,Dh) H/tp"),
         ("ragged_paged_prefill_tp", "ragged_paged_prefill", True, False,
-         {"q": "(S,C,H,Dh) H/tp", "k_pages": "(P,ps,H,Dh) H/tp",
-          "v_pages": "(P,ps,H,Dh) H/tp", "block_tables": "(S,mp) i32",
+         {"q": "(S,C,H,Dh) H/tp", "k_pages": "(P,ps,H*Dh) H/tp",
+          "v_pages": "(P,ps,H*Dh) H/tp", "block_tables": "(S,mp) i32",
           "chunk_starts": "(S,) i32", "n_valid": "(S,) i32"},
          "(S,C,H,Dh) H/tp"),
         ("ragged_paged_decode_int8_tp", "ragged_paged_decode_int8",
          False, True,
-         {"q": "(S,H,Dh) H/tp", "k_pages": "(P,ps,H,Dh) i8 H/tp",
-          "v_pages": "(P,ps,H,Dh) i8 H/tp",
+         {"q": "(S,H,Dh) H/tp", "k_pages": "(P,ps,H*Dh) i8 H/tp",
+          "v_pages": "(P,ps,H*Dh) i8 H/tp",
           "k_scales": "(P,ps) f32 replicated",
           "v_scales": "(P,ps) f32 replicated",
           "block_tables": "(S,mp) i32", "lengths": "(S,) i32"},
          "(S,H,Dh) H/tp"),
         ("ragged_paged_prefill_int8_tp", "ragged_paged_prefill_int8",
          True, True,
-         {"q": "(S,C,H,Dh) H/tp", "k_pages": "(P,ps,H,Dh) i8 H/tp",
-          "v_pages": "(P,ps,H,Dh) i8 H/tp",
+         {"q": "(S,C,H,Dh) H/tp", "k_pages": "(P,ps,H*Dh) i8 H/tp",
+          "v_pages": "(P,ps,H*Dh) i8 H/tp",
           "k_scales": "(P,ps) f32 replicated",
           "v_scales": "(P,ps) f32 replicated",
           "block_tables": "(S,mp) i32", "chunk_starts": "(S,) i32",

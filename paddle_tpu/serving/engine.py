@@ -2455,13 +2455,14 @@ class ServingEngine:
                 else:
                     q, k, v = block.attn.qkv_heads(bp["attn"],
                                                    h)      # (S,H,1,Dh)
+                # the token's heads folded the way the pool stores them
+                k_tok = k[:, :, 0, :].reshape(s_tot, -1)   # (S,H*Dh)
+                v_tok = v[:, :, 0, :].reshape(s_tot, -1)
                 if quantized:
                     kp, vp, ksc, vsc = pages[i]
                     psa = "tp" if (tp > 1 and spmd) else None
-                    kq, k_s = quantize_kv(k[:, :, 0, :], (1, 2),
-                                          psum_axis=psa)
-                    vq, v_s = quantize_kv(v[:, :, 0, :], (1, 2),
-                                          psum_axis=psa)
+                    kq, k_s = quantize_kv(k_tok, (1,), psum_axis=psa)
+                    vq, v_s = quantize_kv(v_tok, (1,), psum_axis=psa)
                     kp = kp.at[page_idx, off].set(kq)
                     vp = vp.at[page_idx, off].set(vq)
                     ksc = ksc.at[page_idx, off].set(k_s)
@@ -2472,10 +2473,8 @@ class ServingEngine:
                     new_pages.append((kp, vp, ksc, vsc))
                 else:
                     kp, vp = pages[i]
-                    kp = kp.at[page_idx, off].set(
-                        k[:, :, 0, :].astype(kp.dtype))
-                    vp = vp.at[page_idx, off].set(
-                        v[:, :, 0, :].astype(vp.dtype))
+                    kp = kp.at[page_idx, off].set(k_tok.astype(kp.dtype))
+                    vp = vp.at[page_idx, off].set(v_tok.astype(vp.dtype))
                     att = DA.ragged_paged_decode_attention(
                         q[:, :, 0, :], kp, vp, block_tables, lengths + 1,
                         impl=self.attn_impl)                    # (S,H,Dh)
@@ -2529,7 +2528,7 @@ class ServingEngine:
         content does not matter for timing (shapes are fixed); a zero
         pool keeps the probe from ever touching live KV."""
         c = self.cache.config
-        shape = (c.num_pages, c.page_size, self._tp_heads, c.head_dim)
+        shape = (c.num_pages, c.page_size, self._tp_heads * c.head_dim)
         pool = []
         for _ in range(c.num_layers):
             if self.quantized:
@@ -2608,14 +2607,15 @@ class ServingEngine:
             else:
                 q, k, v = block.attn.qkv_heads(bp["attn"],
                                                h)               # (S,H,C,Dh)
-            k_tok = k.transpose(0, 2, 1, 3)                     # (S,C,H,Dh)
-            v_tok = v.transpose(0, 2, 1, 3)
+            # token-major, heads folded the way the pool stores them
+            k_tok = k.transpose(0, 2, 1, 3).reshape(s_tot, c, -1)
+            v_tok = v.transpose(0, 2, 1, 3).reshape(s_tot, c, -1)
             if quantized:
                 kp, vp, ksc, vsc = pages[i]
                 psa = "tp" if (tp > 1 and spmd) else None
-                kq, k_s = quantize_kv(k_tok, (2, 3),
+                kq, k_s = quantize_kv(k_tok, (2,),
                                       psum_axis=psa)            # (S,C)
-                vq, v_s = quantize_kv(v_tok, (2, 3),
+                vq, v_s = quantize_kv(v_tok, (2,),
                                       psum_axis=psa)
                 kp = kp.at[page_idx, off].set(kq)
                 vp = vp.at[page_idx, off].set(vq)
@@ -2707,13 +2707,20 @@ class ServingEngine:
 
     def _read_page_impl(self, pages, src):
         """One page's K/V across every layer, stacked (2, L, page_size,
-        H, Dh) — the migration shard unit; a quantized pool also
+        H, Dh) — the migration shard unit and its WIRE format, which
+        names the head axis whatever the pool's stored shape: the
+        pool's folded ``(page_size, H*Dh)`` rows are the same row-major
+        bytes, so the unfold moves nothing and a payload's sha256 does
+        not depend on how the pool is stored. A quantized pool also
         returns the page's scale rows (2, L, page_size), carried in the
-        same shard. ``src`` is a traced scalar: one compile covers
-        every page ever snapshotted."""
+        same shard.
+        ``src`` is a traced scalar: one compile covers every page ever
+        snapshotted."""
+        c = self.cache.config
         ks = jnp.stack([ent[0][src] for ent in pages])
         vs = jnp.stack([ent[1][src] for ent in pages])
-        kv = jnp.stack([ks, vs])
+        kv = jnp.stack([ks, vs]).reshape(
+            2, len(pages), c.page_size, c.num_heads, c.head_dim)
         if self.quantized:
             ksc = jnp.stack([ent[2][src] for ent in pages])
             vsc = jnp.stack([ent[3][src] for ent in pages])
@@ -2722,10 +2729,12 @@ class ServingEngine:
 
     def _write_page_impl(self, pages, dst, kv, sc=None):
         """Install one migration shard (the :meth:`_read_page_impl`
-        layout) into page ``dst`` of every layer — quantized shards
+        layout) into page ``dst`` of every layer, folded back to the
+        pool's ``(page_size, H*Dh)`` rows — quantized shards
         carry ``sc`` and restore the scale rows alongside the int8
         page; pages donated, dst a traced scalar — one compile covers
         every restore."""
+        kv = kv.reshape(kv.shape[:3] + (-1,))
         out = []
         for i, ent in enumerate(pages):
             if self.quantized:

@@ -8,7 +8,9 @@ grow and reclaims them the step a sequence finishes, so HBM scales with
 **live tokens** (plus one page of rounding per slot).
 
 Device state (threaded through the jitted step, donated):
-  pages[layer] = (k_pages, v_pages), each (num_pages, page_size, H, Dh)
+  pages[layer] = (k_pages, v_pages), each (num_pages, page_size, H*Dh)
+    — a token's heads folded head-major into the last axis, the shape
+    the paged kernels stream (see :class:`PagedKVCache`)
 
 Host state (plain numpy, mutated by the allocator):
   block_tables (num_slots, max_pages_per_slot) int32 — page ids, row-
@@ -59,8 +61,9 @@ PV products — no fp page is ever materialized.
 
 Tensor parallel (ISSUE 15): pass ``mesh=`` (a mesh with a ``tp`` axis
 of size > 1) and the page pool becomes **per-shard**: the K/V page
-arrays are placed sharded over ``tp`` on the HEAD axis (each mesh shard
-holds every page's slice of its own ``H/tp`` heads), while the block
+arrays are placed sharded over ``tp`` on the folded HEAD axis (each mesh
+shard holds every page's slice of its own ``H/tp`` whole heads), while
+the block
 tables, lengths, allocator books, and — for int8 pools — the per-token
 scale rows stay replicated (a token's quantization scale is computed
 over ALL heads, so it is shard-independent; see
@@ -129,8 +132,8 @@ def quantize_kv(x, reduce_axes: Tuple[int, ...], psum_axis=None):
     """Symmetric per-token int8 quantization of a K/V slab.
 
     ``x`` carries one K (or V) vector per token over its TRAILING
-    ``reduce_axes`` (decode writes ``(S, H, Dh)`` with axes ``(1, 2)``;
-    prefill writes ``(S, C, H, Dh)`` with axes ``(2, 3)``). Returns
+    ``reduce_axes`` (decode writes ``(S, H*Dh)`` with axes ``(1,)``;
+    prefill writes ``(S, C, H*Dh)`` with axes ``(2,)``). Returns
     ``(q int8, scale f32)`` with ``scale = max(|x|) / 127`` per token —
     the row the page pool stores next to the page so dequantization is
     ``q * scale`` inside the attend kernel. Per-token granularity keeps
@@ -324,10 +327,21 @@ class PagedKVCache:
     """Device pages + host-side page allocator, block tables, and the
     refcounted prefix-sharing index.
 
+    Each layer's K and V pool is ``(num_pages, page_size, num_heads *
+    head_dim)``: a token's heads folded head-major into the last axis
+    (head ``h`` is lanes ``h*Dh .. (h+1)*Dh``), the shape the paged
+    kernels stream page blocks of. With ``(page_size, H*Dh)`` as the
+    minor dims the TPU keeps the arrays row-major and unpadded as long
+    as ``H*Dh`` (per shard under tp) is a multiple of 128 lanes; with
+    ``(H, Dh)`` minor, or a lane width it has to pad, it keeps a pool
+    page_size-minor and every step that calls the kernels copies it
+    whole, in and out.
+
     ``mesh=`` (tp > 1): the K/V page arrays are placed sharded over the
-    mesh's ``tp`` axis on the head dimension — per-shard page pools —
-    while int8 scale rows stay replicated (per-token scales are
-    head-global). Allocator/index state is host-side and unaffected."""
+    mesh's ``tp`` axis on the folded head axis — per-shard page pools of
+    ``(H/tp)*Dh`` lanes, each shard's own whole heads — while int8 scale
+    rows stay replicated (per-token scales are head-global).
+    Allocator/index state is host-side and unaffected."""
 
     def __init__(self, config: PagedCacheConfig, mesh=None,
                  host_spill_pages: int = 0):
@@ -338,7 +352,7 @@ class PagedKVCache:
         if self.mesh is not None and c.num_heads % int(mesh.shape["tp"]):
             raise ValueError(
                 f"tp={mesh.shape['tp']} must divide num_heads={c.num_heads}")
-        shape = (c.num_pages, c.page_size, c.num_heads, c.head_dim)
+        shape = (c.num_pages, c.page_size, c.num_heads * c.head_dim)
         if c.quantized:
             # int8 pages + fp32 per-token-row scales, one (k, v, ks, vs)
             # tuple per layer so scales thread/donate with their pages
@@ -356,7 +370,7 @@ class PagedKVCache:
         if self.mesh is not None:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
-            kv_s = NamedSharding(self.mesh, P(None, None, "tp", None))
+            kv_s = NamedSharding(self.mesh, P(None, None, "tp"))
             rep = NamedSharding(self.mesh, P())
             self.pages = [
                 tuple(jax.device_put(a, kv_s if i < 2 else rep)

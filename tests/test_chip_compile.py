@@ -81,7 +81,7 @@ def _paged_args(name, page_dtype, slots=S, max_pages=MP, num_pages=P):
     quantized = name.endswith("int8")
     q_dtype = jnp.float32 if quantized else page_dtype
     q = sds((slots, C, H, DH) if chunked else (slots, H, DH), q_dtype)
-    pages = sds((num_pages, PS, H, DH),
+    pages = sds((num_pages, PS, H * DH),
                 jnp.int8 if quantized else page_dtype)
     scales = (sds((num_pages, PS), jnp.float32),) * 2 if quantized else ()
     i32 = sds((slots,), jnp.int32)
@@ -106,6 +106,130 @@ def test_paged_kernel_compiles_for_v5e(name, page_dtype, one_chip):
 def test_paged_int8_kernel_compiles_for_v5e(name, pool, one_chip):
     """P5: a pool smaller than one 8-row group of scale rows."""
     _compile_kernel(name, _paged_args(name, jnp.int8, **pool), one_chip)
+
+
+# the serving cell's geometry (benchmark/configs/gpt2_small.json: 64 slots
+# x 1024 tokens in pages of 128, prefill chunk 32, decode block 8)
+CELL_SLOTS, CELL_PS, CELL_PAGES, CELL_CHUNK = 64, 128, 513, 32
+_POOL_TEMP_BOUND = 128 << 20
+
+
+def _cell_engine(variant):
+    """A ServingEngine at the cell's widths whose jitted steps are only
+    ever lowered: bf16 is the cell itself (whole GPT-2 small, abstract
+    weights), int8 and tp2 are two layers of it (what is asserted is
+    per layer). The engine's own pool is nine pages; the steps are
+    lowered on a 513-page pool of the same page shape."""
+    from paddle_tpu import inference
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    kw = dict(num_slots=CELL_SLOTS, page_size=CELL_PS, num_pages=9,
+              max_tokens_per_slot=1024, prefill_chunk=CELL_CHUNK,
+              decode_block=8, attn_impl="pallas",
+              cache_dtype=jnp.int8 if variant == "int8" else jnp.bfloat16)
+    if variant == "tp2":
+        # the sharded engine re-lays its weights out and places them on
+        # its mesh, so they are real here: two layers, a small vocabulary
+        model = GPT(GPTConfig(num_layers=2, vocab_size=512))
+        params = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16),
+            model.init(jax.random.PRNGKey(0)))
+        return inference.make_serving_engine(model, params, tp=2, **kw)
+    model = GPT(GPTConfig(num_layers=12 if variant == "bf16" else 2))
+    params = jax.eval_shape(
+        lambda k: jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16), model.init(k)),
+        jax.random.PRNGKey(0))
+    return inference.make_serving_engine(model, params, **kw)
+
+
+@pytest.fixture(scope="module", params=["bf16", "int8", "tp2"])
+def cell_steps(request, topo):
+    """(variant, lower(step, lanes, width) -> compiled) for one engine:
+    its decode and prefill steps compiled for the described chip — for
+    tp2 the same step bodies under ``shard_map`` over two of the
+    described chips, arguments sharded as the engine shards them."""
+    from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+    variant = request.param
+    eng = _cell_engine(variant)
+    steps = {"decode": eng.decode_step, "prefill": eng.prefill_step}
+    if variant == "tp2":
+        from paddle_tpu.core.compat import shard_map
+        from paddle_tpu.core.mesh import MeshConfig, make_mesh
+        mesh = make_mesh(MeshConfig(tp=2), devices=topo.devices[:2])
+        rep = PartitionSpec()
+        specs = (eng._param_specs, eng._page_specs, rep, rep, rep, rep)
+        steps = {
+            name: jax.jit(shard_map(
+                impl, mesh=mesh, in_specs=specs,
+                out_specs=(rep, eng._page_specs), check_vma=False),
+                donate_argnums=(1,))
+            for name, impl in (("decode", eng._decode_step_impl),
+                               ("prefill", eng._prefill_step_impl))}
+
+        def place(spec):
+            return NamedSharding(mesh, spec)
+        param_s = jax.tree_util.tree_map(
+            place, eng._param_specs,
+            is_leaf=lambda x: isinstance(x, PartitionSpec))
+        page_s = jax.tree_util.tree_map(
+            place, eng._page_specs,
+            is_leaf=lambda x: isinstance(x, PartitionSpec))
+        rep_s = place(rep)
+    else:
+        rep_s = SingleDeviceSharding(topo.devices[0])
+        param_s = jax.tree_util.tree_map(lambda _: rep_s, eng._step_params)
+        page_s = jax.tree_util.tree_map(lambda _: rep_s, eng.cache.pages)
+
+    sds = jax.ShapeDtypeStruct
+    params = jax.tree_util.tree_map(
+        lambda a, sh: sds(a.shape, a.dtype, sharding=sh),
+        eng._step_params, param_s)
+    pages = jax.tree_util.tree_map(
+        lambda a, sh: sds((CELL_PAGES,) + a.shape[1:], a.dtype,
+                          sharding=sh),
+        eng.cache.pages, page_s)
+
+    def i32(*shape):
+        return sds(shape, jnp.int32, sharding=rep_s)
+
+    def lower(step, lanes, width):
+        if step == "decode":       # block tables, lengths, tokens, active
+            args = (i32(lanes, width), i32(lanes), i32(lanes), i32(lanes))
+        else:                      # block tables, starts, tokens, n_valid
+            args = (i32(lanes, width), i32(lanes),
+                    i32(lanes, CELL_CHUNK), i32(lanes))
+        return steps[step].lower(params, pages, *args).compile()
+
+    return variant, lower
+
+
+@pytest.mark.parametrize("step, lanes, width", [
+    ("decode", CELL_SLOTS, 1), ("decode", CELL_SLOTS, 8),
+    ("prefill", 1, 1), ("prefill", 32, 8)],
+    ids=["decode-w1", "decode-w8", "prefill-1lane-w1", "prefill-32lanes-w8"])
+def test_serving_step_keeps_the_pool_where_it_lies(step, lanes, width,
+                                                   cell_steps):
+    """The page pool is stored the way the paged kernels read it, so a
+    compiled serving step (a) copies no pool-shaped array, (b) takes the
+    pool in as a row-major entry parameter, (c) needs temporaries far
+    under one pool array (a relayout of a 4-D pool took 2.67x the pool,
+    in and out of every call)."""
+    import re
+    variant, lower = cell_steps
+    compiled = lower(step, lanes, width)
+    text = compiled.as_text()
+    lanes_wide = H * DH // (2 if variant == "tp2" else 1)
+    pool = (f"{'s8' if variant == 'int8' else 'bf16'}"
+            f"[{CELL_PAGES},{CELL_PS},{lanes_wide}]")
+    assert "tpu_custom_call" in text
+    copies = [line for line in text.splitlines()
+              if re.search(r"= " + re.escape(pool) + r"\S* copy\(", line)]
+    assert not copies, copies[:2]
+    entry = text[text.index("ENTRY "):]
+    layouts = set(re.findall(
+        re.escape(pool) + r"(\{[\d,]+)[^ ]* parameter\(", entry))
+    assert layouts == {"{2,1,0"}, layouts
+    assert compiled.memory_analysis().temp_size_in_bytes < _POOL_TEMP_BOUND
 
 
 def _flash_args(dtype, key_bias):

@@ -389,6 +389,147 @@ class TestRegistryLint:
 
 
 # ---------------------------------------------------------------------------
+# the folded page pool (P, ps, H*Dh): a relayout, never a change of result
+# ---------------------------------------------------------------------------
+
+PAGED = ("ragged_paged_decode", "ragged_paged_prefill",
+         "ragged_paged_decode_int8", "ragged_paged_prefill_int8")
+
+
+def _lax_on_the_unfolded_pool(name, args):
+    """What the lax path computed when the pool was stored (P, ps, H,
+    Dh): the 5-D gather contracted head by head, the decode and the
+    prefill contraction each as it was, written out here so the folded
+    path is held to something that never saw a fold."""
+    from paddle_tpu.ops.attention import NEG_INF
+    quantized, chunked = name.endswith("int8"), "prefill" in name
+    q, kp, vp = args[:3]
+    ks, vs = args[3:5] if quantized else (None, None)
+    bt, *geo = args[5:] if quantized else args[3:]
+    h, dh = q.shape[-2:]
+    scale = 1.0 / np.sqrt(dh)
+    p, ps = kp.shape[:2]
+    kg = kp.reshape(p, ps, h, dh)[bt].astype(jnp.float32)
+    vg = vp.reshape(p, ps, h, dh)[bt].astype(jnp.float32)
+    s_slots, mp = bt.shape
+    tok = jnp.arange(mp * ps, dtype=jnp.int32)
+    qf = q.astype(jnp.float32)
+    if chunked:
+        starts, n_valid = geo
+        c = q.shape[1]
+        lead = (s_slots, h, c)
+        scores = jnp.einsum("schd,smthd->shcmt", qf, kg) * scale
+        if quantized:
+            scores = scores * ks[bt][:, None, None]
+        pos = starts[:, None] + jnp.arange(c, dtype=jnp.int32)
+        live = (tok[None, None, None, :] <= pos[:, None, :, None]) & \
+            (jnp.arange(c) < n_valid[:, None])[:, None, :, None]
+    else:
+        lead = (s_slots, h)
+        scores = jnp.einsum("shd,smthd->shmt", qf, kg) * scale
+        if quantized:
+            scores = scores * ks[bt][:, None]
+        live = tok[None, None, :] < geo[0][:, None, None]
+    scores = jnp.where(live, scores.reshape(lead + (mp * ps,)), NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1)
+    alive = jnp.max(scores, axis=-1, keepdims=True) > NEG_INF / 2
+    w = jnp.where(alive, w, 0.0).reshape(lead + (mp, ps))
+    if quantized:
+        w = w * (vs[bt][:, None, None] if chunked else vs[bt][:, None])
+    out = jnp.einsum("shcmt,smthd->schd" if chunked
+                     else "shmt,smthd->shd", w, vg)
+    return out.astype(q.dtype)
+
+
+class TestFoldedPagePool:
+    @pytest.mark.parametrize("name", PAGED)
+    def test_samples_and_contracts_declare_the_folded_pool(self, name):
+        spec = kernels.get(name)
+        args, _ = spec.sample_inputs(2)
+        q, kp, vp = args[:3]
+        h, dh = q.shape[-2:]
+        assert kp.ndim == vp.ndim == 3 and kp.shape[2] == h * dh
+        for arg in ("k_pages", "v_pages"):
+            layout = spec.contract.arg_layouts[arg]
+            assert layout.startswith("(P,ps,H*Dh)"), layout
+            assert lint._layout_rank(layout) == 3
+        assert lint.contract_findings(spec) == []
+
+    @pytest.mark.parametrize("name", PAGED)
+    def test_lax_path_bit_equal_to_the_unfolded_pool(self, name):
+        """Folding the heads into the lane axis is a reshape of
+        row-major bytes: on the lax path it changes no bit."""
+        spec = kernels.get(name)
+        for seed in (0, 1, 2):
+            args, _ = spec.sample_inputs(seed)
+            folded = np.asarray(kernels.dispatch(name, *args, impl="lax"))
+            np.testing.assert_array_equal(
+                folded, np.asarray(_lax_on_the_unfolded_pool(name, args)))
+
+    @pytest.mark.parametrize("name", PAGED)
+    def test_kernel_takes_head_h_from_lanes_h_dh(self, name):
+        """Head ``h`` is lanes ``[h*Dh, (h+1)*Dh)`` of a page block and
+        nothing else: with the heads of the query and of the folded pool
+        reordered alike, the Pallas body (interpreted) gives the same
+        heads, reordered, bit for bit — and stays inside the contract's
+        tolerance of the lax path fed the same folded pool."""
+        spec = kernels.get(name)
+        args, _ = spec.sample_inputs(2)
+        q, kp, vp = args[:3]
+        h, dh = q.shape[-2:]
+        perm = np.random.default_rng(0).permutation(h)
+
+        def reorder(pool):
+            p, ps, _ = pool.shape
+            return pool.reshape(p, ps, h, dh)[:, :, perm].reshape(p, ps, -1)
+
+        out = np.asarray(kernels.dispatch(name, *args,
+                                          impl="pallas_interpret"))
+        moved = np.asarray(kernels.dispatch(
+            name, q[..., perm, :], reorder(kp), reorder(vp), *args[3:],
+            impl="pallas_interpret"))
+        np.testing.assert_array_equal(moved, out[..., perm, :])
+        np.testing.assert_allclose(
+            out, np.asarray(kernels.dispatch(name, *args, impl="lax")),
+            atol=spec.contract.atol, rtol=spec.contract.rtol)
+
+    @pytest.mark.parametrize("name", PAGED)
+    def test_tune_keys_still_hit_the_committed_manifest(self, name):
+        """The head count in a tune key comes from ``q`` (the folded
+        pool does not carry it); every bucket the offline seeding visits
+        (the samples and their per-shard tp twins) must resolve from
+        tools/kernel_tune.json — an entry, never a fresh static prior."""
+        spec = kernels.get(name)
+        tuner = kernels.KernelTuner(kernels.DEFAULT_CACHE_PATH)
+        committed = set(tuner.entries)
+        samples = [spec.sample_inputs(seed) for seed in (0, 1, 2)]
+        samples += [v(seed) for v in spec.tune_sample_variants
+                    for seed in (0, 1, 2)]
+        for args, kw in filter(None, samples):
+            assert kernels.tune_key(spec, args, kw) in committed
+            tuner.get(spec, args, kw)
+        assert tuner.misses == 0 and tuner.hits > 0
+
+    @pytest.mark.parametrize("name", PAGED)
+    def test_vmem_estimate_prices_a_page_block_as_it_is_tiled(self, name):
+        """At the serving cell's widths (12 heads of 64, pages of 128) a
+        page block is (128, 768): whole tiles in bf16 and in int8, no
+        padding of heads."""
+        spec = kernels.get(name)
+        sds = jax.ShapeDtypeStruct
+        quantized, chunked = name.endswith("int8"), "prefill" in name
+        q = sds((64, 32, 12, 64) if chunked else (64, 12, 64), jnp.bfloat16)
+        pool = sds((513, 128, 768), jnp.int8 if quantized else jnp.bfloat16)
+        one = spec.vmem_estimate((q, pool), {}, {"pages_per_block": 1})
+        two = spec.vmem_estimate((q, pool), {}, {"pages_per_block": 2})
+        page_blocks = 128 * 768 * pool.dtype.itemsize      # unpadded
+        scale_rows = 8 * 128 * 4 if quantized else 0
+        # one more page a step = a K and a V block (+ scale groups),
+        # double-buffered by the pipeline and once more by the estimate
+        assert two - one == 2 * 2 * (page_blocks + scale_rows)
+
+
+# ---------------------------------------------------------------------------
 # bench artifact
 # ---------------------------------------------------------------------------
 

@@ -99,8 +99,11 @@ class TestRaggedPagedDecodeAttention:
     def _setup(self, seed=0, s=4, h=2, dh=8, ps=4, mp=4, p=16):
         rng = np.random.default_rng(seed)
         q = jnp.asarray(rng.standard_normal((s, h, dh)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((p, ps, h, dh)), jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((p, ps, h, dh)), jnp.float32)
+        # the pool's stored layout: a token's heads folded head-major
+        kp = jnp.asarray(rng.standard_normal((p, ps, h, dh)),
+                         jnp.float32).reshape(p, ps, h * dh)
+        vp = jnp.asarray(rng.standard_normal((p, ps, h, dh)),
+                         jnp.float32).reshape(p, ps, h * dh)
         bt = jnp.asarray(rng.integers(1, p, (s, mp)), jnp.int32)
         lens = jnp.asarray(rng.integers(0, mp * ps + 1, (s,)), jnp.int32)
         return q, kp, vp, bt, lens
@@ -115,8 +118,8 @@ class TestRaggedPagedDecodeAttention:
             if n == 0:
                 np.testing.assert_array_equal(np.asarray(out[s]), 0.0)
                 continue
-            k = kp[bt[s]].reshape(-1, *kp.shape[2:])[:n]
-            v = vp[bt[s]].reshape(-1, *vp.shape[2:])[:n]
+            k = kp[bt[s]].reshape(-1, *q.shape[1:])[:n]
+            v = vp[bt[s]].reshape(-1, *q.shape[1:])[:n]
             sc = jnp.einsum("hd,thd->ht", q[s], k) / np.sqrt(dh)
             ref = jnp.einsum("ht,thd->hd", jax.nn.softmax(sc, -1), v)
             np.testing.assert_allclose(np.asarray(out[s]), np.asarray(ref),
@@ -166,8 +169,10 @@ class TestRaggedPagedPrefillAttention:
     def _setup(self, seed=0, s=3, c=4, h=2, dh=8, ps=4, mp=4, p=12):
         rng = np.random.default_rng(seed)
         q = jnp.asarray(rng.standard_normal((s, c, h, dh)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((p, ps, h, dh)), jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((p, ps, h, dh)), jnp.float32)
+        kp = jnp.asarray(rng.standard_normal((p, ps, h, dh)),
+                         jnp.float32).reshape(p, ps, h * dh)
+        vp = jnp.asarray(rng.standard_normal((p, ps, h, dh)),
+                         jnp.float32).reshape(p, ps, h * dh)
         bt = jnp.asarray(rng.integers(1, p, (s, mp)), jnp.int32)
         return q, kp, vp, bt
 
@@ -179,8 +184,8 @@ class TestRaggedPagedPrefillAttention:
             q, kp, vp, bt, starts, nv, impl="lax")
         dh = q.shape[-1]
         for s in range(q.shape[0]):
-            k = kp[bt[s]].reshape(-1, *kp.shape[2:])
-            v = vp[bt[s]].reshape(-1, *vp.shape[2:])
+            k = kp[bt[s]].reshape(-1, *q.shape[2:])
+            v = vp[bt[s]].reshape(-1, *q.shape[2:])
             for c in range(int(nv[s])):
                 n = int(starts[s]) + c + 1        # causal horizon
                 sc = jnp.einsum("hd,thd->ht", q[s, c], k[:n]) / np.sqrt(dh)
@@ -280,6 +285,91 @@ class TestPagedVsDense:
         for p, o in zip(prompts, outs):
             np.testing.assert_array_equal(
                 o, _dense_reference(model, params, p, 4))
+
+
+class TestFoldedPoolWireFormat:
+    """The pool stores a token's heads folded into the last axis, (P,
+    ps, H*Dh); a page on the wire (migration shards, spilled payloads,
+    prefix bundles) stays (2, L, ps, H, Dh). Both are the same row-major
+    bytes, so payloads and their sha256 digests are what the 4-D pool
+    gave. The digests below were recorded from the engine of the parent
+    commit (4-D pool) for the same payload."""
+
+    RECORDED = {
+        "float32": "bffdbf5b5bd3b915f43ddbed1db74aeb"
+                   "5c2815074d0b9d13db97bb5efd5211a2",
+        "int8": "73df36a3e4484bce7fae70c5b312922c"
+                "8fea986496cd442a07f457bf47efa163",
+    }
+
+    @pytest.mark.parametrize("dtype", ["float32", "int8"])
+    def test_page_payload_and_digest_are_the_4d_pools(self, dtype):
+        model, params = _model()
+        eng = serving.ServingEngine(
+            model, params, num_slots=2, page_size=4, attn_impl="lax",
+            cache_dtype=jnp.int8 if dtype == "int8" else None)
+        c = eng.cache.config
+        shape = (2, c.num_layers, c.page_size, c.num_heads, c.head_dim)
+        ramp = (np.arange(int(np.prod(shape))) * 7) % 251 - 125
+        pid = jnp.asarray(3, jnp.int32)
+        if eng.quantized:
+            kv = ramp.astype(np.int8).reshape(shape)
+            sc = ((np.arange(2 * c.num_layers * c.page_size) % 13 + 1)
+                  / 16).astype(np.float32).reshape(shape[:3])
+            eng.cache.pages = eng.write_page_step(
+                eng.cache.pages, pid, jnp.asarray(kv), jnp.asarray(sc))
+            page = eng.read_page_step(eng.cache.pages, pid)
+            shard = (np.asarray(page[0]), np.asarray(page[1]))
+            assert shard[1].tobytes() == sc.tobytes()
+            kv_out = shard[0]
+        else:
+            kv = (ramp / 4).astype(np.float32).reshape(shape)
+            eng.cache.pages = eng.write_page_step(
+                eng.cache.pages, pid, jnp.asarray(kv))
+            shard = kv_out = np.asarray(
+                eng.read_page_step(eng.cache.pages, pid))
+        # stored: each token row holds its heads one after the other
+        for layer, ent in enumerate(eng.cache.pages):
+            assert ent[0].shape == (c.num_pages, c.page_size,
+                                    c.num_heads * c.head_dim)
+            for side in (0, 1):
+                np.testing.assert_array_equal(
+                    np.asarray(ent[side][3]),
+                    kv[side, layer].reshape(c.page_size, -1))
+        assert kv_out.shape == shape and kv_out.tobytes() == kv.tobytes()
+        assert eng._shard_digest(shard) == self.RECORDED[dtype]
+
+    @pytest.mark.parametrize("impl", ["lax", "pallas_interpret"])
+    def test_served_pages_read_back_as_the_unfolded_pool(self, impl):
+        """Pages the engine itself wrote (prefill chunks and decode
+        tokens): what ``read_page_step`` hands the wire is the stored
+        page with its last axis unfolded, and a snapshot's manifest
+        digests are those of exactly these arrays."""
+        model, params = _model(seed=3)
+        eng = serving.ServingEngine(model, params, num_slots=2,
+                                    page_size=4, prefill_chunk=8,
+                                    attn_impl=impl)
+        rng = np.random.default_rng(11)
+        for p in _prompts(rng, [10, 7]):
+            eng.submit(p, 24)
+        eng.step()
+        eng.step()
+        c = eng.cache.config
+        slot = next(s for s in range(2) if eng.cache.lengths[s] > 0)
+        n_live = c.pages_for(int(eng.cache.lengths[slot]))
+        assert n_live >= 3
+        snap = eng.snapshot_slot(slot)
+        for k, pid in enumerate(eng.cache.block_tables[slot, :n_live]):
+            page = np.asarray(eng.read_page_step(
+                eng.cache.pages, jnp.asarray(int(pid), jnp.int32)))
+            for layer, (kp, vp) in enumerate(eng.cache.pages):
+                for side, pool in enumerate((kp, vp)):
+                    np.testing.assert_array_equal(
+                        page[side, layer],
+                        np.asarray(pool[int(pid)]).reshape(
+                            c.page_size, c.num_heads, c.head_dim))
+            assert snap["manifest"][k]["sha256"] == eng._shard_digest(page)
+            assert page.any()
 
 
 class TestSchedulerProperty:

@@ -63,6 +63,9 @@ class TestQuantizeKV:
         assert c.config.quantized
         kp, vp, ks, vs = c.pages[0]
         assert kp.dtype == jnp.int8 and vp.dtype == jnp.int8
+        # heads folded into the last axis, like the fp pool; scale rows
+        # stay one per token
+        assert kp.shape == vp.shape == (6, 4, 2 * 4)
         assert ks.shape == (6, 4) and ks.dtype == jnp.float32
         # allocator state is dtype-agnostic: invariants hold untouched
         c.reserve(0, 9)

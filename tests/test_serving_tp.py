@@ -160,6 +160,26 @@ class TestTpSteadyState:
         assert warmed_tp_engine.warmed_signatures == set(
             warmed_tp_engine.warmup_plan())
 
+    def test_pool_is_cut_between_heads_of_the_folded_axis(
+            self, warmed_tp_engine, tiny_model):
+        """The pool's folded H*Dh axis is head-major, so sharding it
+        over tp hands each shard its own whole heads: shard ``t`` holds
+        lanes ``[t*(H/tp)*Dh, (t+1)*(H/tp)*Dh)`` of every page."""
+        from jax.sharding import PartitionSpec
+        eng = warmed_tp_engine
+        cfg = tiny_model[0].cfg
+        c = eng.cache.config
+        per_shard = (cfg.num_heads // 2) * c.head_dim
+        for kp, vp in eng.cache.pages:
+            for pool in (kp, vp):
+                assert pool.shape == (c.num_pages, c.page_size,
+                                      cfg.num_heads * c.head_dim)
+                assert pool.sharding.spec == PartitionSpec(None, None, "tp")
+                assert {s.data.shape for s in pool.addressable_shards} == {
+                    (c.num_pages, c.page_size, per_shard)}
+                for s in pool.addressable_shards:
+                    assert s.index[2].start % per_shard == 0
+
 
 # ---------------------------------------------------------------------------
 # per-shard live migration

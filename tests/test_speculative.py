@@ -61,6 +61,10 @@ class TestSpeculativeParity:
         if eng.speculative:
             eng.draft_cache.check_invariants()
             assert eng.draft_cache.pages_in_use == 0
+            # the draft's pool is folded like the target's, at its widths
+            dc = eng.draft_cache.config
+            assert eng.draft_cache.pages[0][0].shape == (
+                dc.num_pages, dc.page_size, dc.num_heads * dc.head_dim)
         return outs
 
     def test_self_draft_bit_exact_long_accepts(self):
