@@ -16,7 +16,15 @@ configured; weights random from ``--seed``), in ONE process:
    -> ``warmup()`` -> ``submit()`` x5 -> ``step()`` until idle. Every
    request finishes, greedy tokens equal ``model.generate(...,
    use_cache=True)`` on the same chip, 0 compiles after warmup, paged
-   attention on the Pallas bodies.
+   attention on the Pallas bodies;
+4. **sparse family** — ``models/sparse_moe_lm.py`` at its published
+   widths (32 query heads over 4 KV heads of 128, 16 indexer heads of 64,
+   top-2048, 128 experts of 768, the whole vocabulary; ONE layer): the
+   indexer, the sparse paged kernels and the grouped expert kernel
+   against their ``lax_fn``, then one request of 2304 prompt tokens
+   through the engine (dense kernels up to 2048 cached tokens, selection
+   past them), every chosen token within ``SPARSE_TIE_MARGIN`` of the plain
+   float32 reference's best (``benchmark/families/keye_vl2.py``).
 
 ``--chips 4`` runs INSTEAD (no one-chip phase): BERT-base under
 ``shard_train_step`` on a dp2 x tp2 mesh against the same steps on one
@@ -46,6 +54,12 @@ import numpy as np
 #: Random GPT-2 logits have a typical top-2 gap ~0.1; a broken kernel
 #: picks tokens whole logits away, matmul rounding only near-ties.
 TIE_MARGIN = 0.05
+#: the sparse-attention / sparse-expert family in bf16 against its float32
+#: reference: a routed model crosses a routing or selection threshold now
+#: and then where the reference does not, and the token after it may fall
+#: this far short (benchmark/configs/keye_vl2_30b_a3b.json, tie_margin:
+#: largest seen over ten seeds 0.36, with the selection off 0.78)
+SPARSE_TIE_MARGIN = 0.6
 #: dp2 x tp2 vs one device: same math, different reduction order, bf16
 #: activations — relative tolerance on each step's loss
 MESH_LOSS_RTOL = 2e-2
@@ -68,6 +82,10 @@ class Sizes:
     #                             tokens of request 0, and is submitted
     #                             once request 0's prompt is in the cache
     new_tokens: int
+    sparse: dict                # SparseMoELMConfig overrides
+    sparse_page_size: int
+    sparse_chunk: int
+    sparse_prompt: int          # past topk, so decode selects
     interpret: bool = False
 
     @classmethod
@@ -77,7 +95,9 @@ class Sizes:
         return cls(bert={}, bert_batch=48, bert_seq=512, gpt={},
                    num_slots=4, page_size=16, prefill_chunk=32,
                    max_tokens_per_slot=128, prompt_lens=(40, 13, 70, 40, 40),
-                   shared_prefix=32, new_tokens=12)
+                   shared_prefix=32, new_tokens=12,
+                   sparse=dict(num_hidden_layers=1), sparse_page_size=128,
+                   sparse_chunk=64, sparse_prompt=2304)
 
     @classmethod
     def tiny(cls):
@@ -88,7 +108,17 @@ class Sizes:
                             num_heads=4, ffn_size=64, max_position=64),
                    num_slots=2, page_size=4, prefill_chunk=8,
                    max_tokens_per_slot=16, prompt_lens=(10, 3, 9, 10),
-                   shared_prefix=8, new_tokens=5, interpret=True)
+                   shared_prefix=8, new_tokens=5,
+                   sparse=dict(vocab_size=96, hidden_size=64,
+                               num_hidden_layers=1, num_attention_heads=4,
+                               num_key_value_heads=2, head_dim=16,
+                               max_position_embeddings=256, num_experts=8,
+                               num_experts_per_tok=2,
+                               moe_intermediate_size=32,
+                               indexer_num_heads=2, indexer_head_dim=8,
+                               indexer_topk=16),
+                   sparse_page_size=4, sparse_chunk=8, sparse_prompt=22,
+                   interpret=True)
 
     @property
     def prefill_steps(self):
@@ -380,6 +410,143 @@ def phase_serving(sizes, seed):
 
 
 # ---------------------------------------------------------------------------
+# phase 4: the sparse-attention / sparse-expert family
+# ---------------------------------------------------------------------------
+
+def phase_sparse_family(sizes, seed):
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import inference, kernels
+    from paddle_tpu.models.sparse_moe_lm import (SparseMoELM,
+                                                 SparseMoELMConfig)
+
+    impl = sizes.kernel_impl
+    cfg = SparseMoELMConfig(kernel_impl=impl, **sizes.sparse)
+    names = ("lightning_indexer", "sparse_paged_decode",
+             "sparse_paged_prefill", "moe_grouped_ffn")
+    # -- the new kernels against their lax forms, at this model's widths
+    errs = {}
+    for name in names:
+        spec = kernels.get(name)
+        args, kw = _sparse_kernel_args(name, cfg, sizes, seed)
+        out = jax.jit(lambda *a, _n=name: kernels.dispatch(
+            _n, *a, impl=impl, **kw))(*args)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a, _n=name: kernels.dispatch(
+                _n, *a, impl="lax", **kw))(*args)
+        out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+        assert out.shape == ref.shape and np.isfinite(out).all(), name
+        errs[name] = float(np.max(np.abs(out - ref)))
+        np.testing.assert_allclose(
+            out, ref, atol=spec.contract.atol, rtol=spec.contract.rtol,
+            err_msg=f"{name} {impl} vs lax")
+    log("sparse family kernels vs lax max|err|: " + json.dumps(errs))
+
+    # -- a short serve through the engine, against the plain reference
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "benchmark"))
+    from families import keye_vl2
+    model = SparseMoELM(cfg)
+    params = jax.jit(lambda k: model.init(k, dtype=jnp.bfloat16))(
+        jax.random.PRNGKey(seed))
+    before = {(k, i): _dispatched(k, i) for k in names
+              for i in (impl, "lax")}
+    n0, n = sizes.sparse_prompt, sizes.new_tokens
+    pages = -(-(n0 + n + 8) // sizes.sparse_page_size)
+    kw = dict(num_slots=2, page_size=sizes.sparse_page_size,
+              prefill_chunk=sizes.sparse_chunk, attn_impl=impl,
+              max_tokens_per_slot=pages * sizes.sparse_page_size)
+    eng = inference.make_serving_engine(model, params, **kw)
+    prompt = np.random.default_rng(seed + 3).integers(
+        0, cfg.vocab_size, n0).astype(np.int32)
+    t0 = time.perf_counter()
+    rid = eng.submit(prompt, n)
+    while not eng.scheduler.idle():
+        eng.step()
+    out = np.asarray(eng.result(rid))
+    t_serve = time.perf_counter() - t0
+    for k in names:
+        assert _dispatched(k, impl) > before[(k, impl)], \
+            f"{k} never resolved to {impl}"
+        assert _dispatched(k, "lax") == before[(k, "lax")], \
+            f"{k} fell back to lax"
+    published = keye_vl2.sizes_of(cfg)
+    ids = np.concatenate([prompt, out]).astype(np.int32)[None]
+    with jax.default_matmul_precision("highest"):
+        logits = np.asarray(jax.jit(
+            lambda p, i: keye_vl2.reference_logits(
+                p, i, published, n0 - 1, n, query_block=128))(
+            params, jnp.asarray(ids)))[0].astype(np.float64)
+    gaps = logits.max(-1) - logits[np.arange(n), out]
+    log(f"sparse family: {n0}-token prompt + {n} tokens in {t_serve:.1f}s "
+        f"(compiles included); {int((gaps == 0).sum())}/{n} tokens are the "
+        f"float32 reference's argmax, largest shortfall {gaps.max():.3e} "
+        f"logits (tolerance {SPARSE_TIE_MARGIN})")
+    assert gaps.max() < SPARSE_TIE_MARGIN, \
+        "sparse family left the reference"
+
+
+def _sparse_kernel_args(name, cfg, sizes, seed):
+    """One call's arguments of kernel ``name`` at ``cfg``'s widths: bf16
+    pools of 2 slots x 20 pages under float32 queries (as phase 1: the
+    outputs are float32, so a difference is the kernel's and not one
+    rounding to bf16), the selection from random scores; the expert
+    kernel in float32 throughout (its hidden activations round to the
+    weights' type inside the body)."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import grouped_ffn
+    from paddle_tpu.serving import sparse_attention as SA
+    rng = np.random.default_rng(seed)
+    dt = jnp.float32 if sizes.interpret else jnp.bfloat16
+    s, ps, c = 2, sizes.sparse_page_size, sizes.sparse_chunk
+    topk = cfg.indexer_topk
+    mp = topk // ps + 4
+    h, kv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                 cfg.head_dim)
+    j, di = cfg.indexer_num_heads, cfg.indexer_head_dim
+    num_pages = s * mp + 1
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32).astype(dt)
+
+    bt = jnp.asarray(rng.permutation(num_pages - 1)[:s * mp].reshape(s, mp)
+                     + 1, jnp.int32)
+    lengths = jnp.asarray([mp * ps - 3, topk + ps + 1], jnp.int32)
+    if name == "moe_grouped_ffn":
+        e, f, d = (cfg.num_experts, cfg.moe_intermediate_size,
+                   cfg.hidden_size)
+        t, k = 32, cfg.num_experts_per_tok
+        ids = jnp.asarray(np.stack([rng.permutation(e)[:k]
+                                    for _ in range(t)]), jnp.int32)
+        tm = grouped_ffn.tile_rows(t * k, e)
+        src, _dest, tile_expert, n_used, _ = grouped_ffn.route_tiles(
+            ids, jnp.ones((t,), bool), e, tm)
+        x = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
+        x_pad = jnp.where((src >= 0)[:, None], x[jnp.maximum(src, 0)], 0)
+        w = [jnp.asarray(rng.standard_normal((e, f, d)), jnp.float32)
+             * d ** -0.5 for _ in range(3)]
+        return (x_pad, tile_expert, n_used, *w), {}
+    if name == "lightning_indexer":
+        return (normal(s, c, j, di), jnp.asarray(rng.standard_normal(
+            (s, c, j)), jnp.float32), normal(num_pages, di, ps), bt,
+            lengths), {}
+    kp, vp = normal(num_pages, ps, kv * dh), normal(num_pages, ps, kv * dh)
+    if name == "sparse_paged_decode":
+        scores = jnp.asarray(rng.standard_normal((s, mp * ps)), jnp.float32)
+        idx, n_sel = SA.select_decode(scores, lengths, topk)
+        return (jnp.asarray(rng.standard_normal((s, h, dh)), jnp.float32),
+                kp, vp, bt, idx, n_sel), {}
+    starts = lengths - c
+    n_valid = jnp.asarray([c, c - 3], jnp.int32)
+    scores = jnp.asarray(rng.standard_normal((s, c, mp * ps)), jnp.float32)
+    selected = SA.select_prefill(scores, starts, n_valid, topk)
+    return (jnp.asarray(rng.standard_normal((s, c, h, dh)), jnp.float32),
+            kp, vp, bt, starts, n_valid, selected), {}
+
+
+# ---------------------------------------------------------------------------
 # --chips 4: the sharded paths and what they are compared with
 # ---------------------------------------------------------------------------
 
@@ -462,6 +629,7 @@ def run_one_chip(sizes, seed=0):
     phase_paged_kernels(sizes, seed)
     phase_trainer(sizes, seed)
     phase_serving(sizes, seed)
+    phase_sparse_family(sizes, seed)
 
 
 def run_four_chips(sizes, seed=0, devices=None):

@@ -133,6 +133,8 @@ _REGISTRY: Dict[str, KernelSpec] = {}
 _HOME_MODULES = (
     "paddle_tpu.ops.attention",
     "paddle_tpu.serving.decode_attention",
+    "paddle_tpu.serving.sparse_attention",
+    "paddle_tpu.ops.grouped_ffn",
     "paddle_tpu.parallel.ring_attention",
 )
 

@@ -27,10 +27,12 @@ from paddle_tpu.models.yolov3 import YOLOv3, YOLOv3Config
 from paddle_tpu.models.ocr import CRNN
 from paddle_tpu.models.gan import (DCGANDiscriminator, DCGANGenerator,
                                    gan_step)
+from paddle_tpu.models.sparse_moe_lm import SparseMoELM, SparseMoELMConfig
 
 __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "ResNet", "ResNet50", "DeepFM", "Transformer",
            "TransformerConfig", "GPT", "GPTConfig", "LinearRegression",
            "MachineTranslation", "RNNLanguageModel", "SentimentCNN", "SentimentLSTM", "SkipGramNS", "Word2Vec", "RecommenderSystem",
            "MobileNetV1", "MobileNetV2", "VGG", "VGG16", "SEResNeXt",
-           "SEResNeXt50", "AlexNet", "DarkNet53", "DenseNet121", "GoogLeNet", "ShuffleNetV2", "SqueezeNet", "SSD", "SSDConfig", "FasterRCNN", "FasterRCNNConfig", "MaskRCNN", "C3D", "TSN", "YOLOv3", "YOLOv3Config", "CRNN", "DCGANGenerator", "DCGANDiscriminator", "gan_step"]
+           "SEResNeXt50", "AlexNet", "DarkNet53", "DenseNet121", "GoogLeNet", "ShuffleNetV2", "SqueezeNet", "SSD", "SSDConfig", "FasterRCNN", "FasterRCNNConfig", "MaskRCNN", "C3D", "TSN", "YOLOv3", "YOLOv3Config", "CRNN", "DCGANGenerator", "DCGANDiscriminator", "gan_step",
+           "SparseMoELM", "SparseMoELMConfig"]

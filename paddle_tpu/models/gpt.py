@@ -114,6 +114,12 @@ class GPT(Layer):
                                      for _ in range(cfg.num_layers)])
         self.ln_f = LayerNorm(cfg.hidden_size)
 
+    def serving(self, *, tp: int = 1, spmd: bool = False,
+                mlp_sharded: bool = False) -> "GPTServing":
+        """This model's block as the paged serving engine runs it (see
+        :mod:`paddle_tpu.serving.program`)."""
+        return GPTServing(self, tp=tp, spmd=spmd, mlp_sharded=mlp_sharded)
+
     def forward(self, params, ids, *, key=None, training=False):
         cfg = self.cfg
         keys = [None] * (cfg.num_layers + 1)
@@ -347,3 +353,94 @@ class GPT(Layer):
         return jnp.asarray(np.concatenate(
             [prompt_host.astype(np.int32),
              np.asarray(gen)[:, :max_new_tokens]], axis=1))
+
+
+class GPTServing:
+    """GPT's serving program (:mod:`paddle_tpu.serving.program`): learned
+    positions added at the embedding, pre-LN blocks, every head with its
+    own K and V, the word table as the output head.
+
+    ``tp > 1``: the body is one head shard's — qkv from the head-major
+    TP slice of the projections (``ServingEngine._make_tp_params`` lays
+    them out), the row-sharded output projection closed by ONE psum a
+    layer; ``spmd=False`` is the probe engine (the same local math, the
+    collectives elided). ``mlp_sharded`` also splits the MLP the Megatron
+    way (the prefill tier), closed by the layer's second psum."""
+
+    def __init__(self, model: GPT, *, tp: int = 1, spmd: bool = False,
+                 mlp_sharded: bool = False):
+        from paddle_tpu.serving.program import ServingSpec
+        cfg = model.cfg
+        if cfg.pipeline or cfg.stacked_layers:
+            raise ValueError(
+                "ServingEngine needs the LayerList GPT layout; convert "
+                "stacked/pipeline checkpoints for serving first")
+        self.model = model
+        self.tp, self.spmd, self.mlp_sharded = tp, spmd, mlp_sharded
+        self.spec = ServingSpec(
+            num_layers=cfg.num_layers, num_heads=cfg.num_heads,
+            kv_heads=cfg.num_heads,
+            head_dim=cfg.hidden_size // cfg.num_heads,
+            vocab_size=cfg.vocab_size, max_position=cfg.max_position)
+
+    def param_dtype(self, params):
+        return params["wte"]["weight"].dtype
+
+    def embed(self, params, tokens, positions):
+        m = self.model
+        return (m.wte(params["wte"], tokens)
+                + m.wpe(params["wpe"], positions))              # (S,C,D)
+
+    def attn_in(self, params, i, x, positions):
+        block, bp = self.model.blocks[i], params["blocks"][str(i)]
+        h = block.ln1(bp["ln1"], x)
+        if self.tp > 1:
+            # per-shard heads (S,Hl,C,Dh) from the head-major projection
+            # slice: the col-parallel half of the Megatron split
+            ap = bp["attn"]
+            qkv = jnp.einsum("scd,dthk->tshck", h, ap["qkv_tp"]["weight"])
+            b = ap["qkv_tp"].get("bias")
+            if b is not None:
+                qkv = qkv + b[:, None, :, None, :]
+            q, k, v = qkv[0], qkv[1], qkv[2]
+        else:
+            q, k, v = block.attn.qkv_heads(bp["attn"], h)       # (S,H,C,Dh)
+        s_tot, _, c, _ = k.shape
+        # token-major, heads folded the way the pool stores them
+        k_tok = k.transpose(0, 2, 1, 3).reshape(s_tot, c, -1)
+        v_tok = v.transpose(0, 2, 1, 3).reshape(s_tot, c, -1)
+        return q, (k_tok, v_tok), None
+
+    def attn_out(self, params, i, x, att):
+        block, bp = self.model.blocks[i], params["blocks"][str(i)]
+        if self.tp == 1:
+            return x + block.attn.proj_out(bp["attn"],
+                                           att.transpose(0, 2, 1, 3))
+        # row-sharded output projection + THE one attention-output
+        # collective: local heads (S,C,H/tp,Dh) -> (S,C,D) replicated;
+        # the probe engine elides the psum (one shard's partial sum is
+        # one chip's share of the work)
+        ap = bp["attn"]
+        part = jnp.einsum("schk,hkd->scd", att, ap["out_tp"]["weight"])
+        if self.spmd:
+            part = jax.lax.psum(part, "tp")
+        b = ap["out_tp"].get("bias")
+        return x + (part + b if b is not None else part)
+
+    def ffn(self, params, i, x, valid):
+        block, bp = self.model.blocks[i], params["blocks"][str(i)]
+        if not self.mlp_sharded:
+            return x + block.mlp(bp["mlp"], block.ln2(bp["ln2"], x)), None
+        # Megatron MLP shard (prefill tier): fc1 column-split over "tp",
+        # fc2 row-split, closed by the layer's SECOND psum; the fc2 bias
+        # is added once AFTER the reduce
+        mp = bp["mlp"]
+        h = block.ln2(bp["ln2"], x)
+        h = block.mlp.act(jnp.matmul(h, mp["fc1"]["weight"])
+                          + mp["fc1"]["bias"])
+        part = jax.lax.psum(jnp.matmul(h, mp["fc2"]["weight"]), "tp")
+        return x + (part + mp["fc2"]["bias"]), None
+
+    def head(self, params, x):
+        x = self.model.ln_f(params["ln_f"], x)
+        return jnp.einsum("...d,vd->...v", x, params["wte"]["weight"])

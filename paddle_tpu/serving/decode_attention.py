@@ -95,10 +95,15 @@ from paddle_tpu.ops.attention import NEG_INF
 
 def _gather_pages(pages, block_tables, h, dh):
     """A slot batch's pages out of the folded pool, heads unfolded:
-    ``(S, mp, ps, H, Dh)`` (row-major, so the reshape moves nothing)."""
+    ``(S, mp, ps, H, Dh)`` (row-major, so the reshape moves nothing).
+    A pool of fewer KV heads than the ``h`` query heads (grouped-query
+    attention: query head ``i`` reads KV head ``i // (h / kv)``) is
+    repeated up to ``h`` here, on the lax path only."""
     g = pages[block_tables]
-    s, mp, ps, _hd = g.shape                       # a 3-D pool only
-    return g.reshape(s, mp, ps, h, dh)
+    s, mp, ps, hd = g.shape                        # a 3-D pool only
+    kv = hd // dh
+    g = g.reshape(s, mp, ps, kv, dh)
+    return g if kv == h else jnp.repeat(g, h // kv, axis=3)
 
 
 def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale):
@@ -157,6 +162,7 @@ def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale):
 # EVERY head's (m, l, acc) state, kept per head in (H, R, .) scratch.
 
 _SCALE_ROWS = 8     # f32 sublane tile: scale rows stream in groups of 8
+_WIDE_VMEM_LIMIT = 64 << 20
 
 #: the fold's dots run in true fp32. Mosaic's DEFAULT for fp32 operands
 #: is a single bf16 pass (~3e-3 abs error at GPT-2 widths, measured on a
@@ -226,7 +232,7 @@ def _split_kv_refs(rest, pb, quantized):
 
 
 def _paged_attend_kernel(bt_ref, *refs, page_size, pages_per_block,
-                         chunked, quantized=False):
+                         chunked, quantized=False, selected=False):
     """THE paged-attention body: online-softmax over a slot's pages,
     ``pages_per_block`` pages per grid step (the shared autotuner's
     tunable: fewer grid iterations, deeper DMA pipelining; the per-page
@@ -245,16 +251,31 @@ def _paged_attend_kernel(bt_ref, *refs, page_size, pages_per_block,
     ``quantized`` is likewise ONE static flag, not a second kernel: the
     int8 page blocks ride with their per-token scale rows and the
     scales fuse into the shared fold — grid, ragged skip, and finish
-    logic cannot diverge between the fp and dequant-attend variants."""
+    logic cannot diverge between the fp and dequant-attend variants.
+
+    Grouped-query heads: a page block holds ``kv`` heads of ``Dh`` lanes
+    and the query block ``n_heads``, a multiple of it; query head ``h``
+    folds KV head ``h // (n_heads / kv)``. (Decode hands a group's
+    queries in as the ROWS of its KV head instead, so there ``n_heads``
+    is ``kv`` and a fold is one ``(group, Dh) x (Dh, ps)`` product.)
+
+    ``selected`` (chunked only): one more input, ``(1, C, pb * ps)``
+    marks per query row which cache positions it may attend to beside
+    the causal test: sparse attention's per-query selection applied
+    inside the streamed fold."""
     pb = pages_per_block
     n_geo = 2 if chunked else 1
     geo, q_ref, rest = refs[:n_geo], refs[n_geo], refs[n_geo + 1:]
+    sel_ref = None
+    if selected:
+        sel_ref, rest = rest[0], rest[1:]
     (k_refs, v_refs, ks_refs, vs_refs, o_ref, m_scr, l_scr,
      acc_scr) = _split_kv_refs(rest, pb, quantized)
     sl = pl.program_id(0)
     pj = pl.program_id(1)
     npg = pl.num_programs(1)
     n_heads, rows, dh = q_ref.shape[1:]
+    group = n_heads * dh // k_refs[0].shape[-1]    # query heads a KV head
     mp = bt_ref.shape[1]
 
     @pl.when(pj == 0)
@@ -283,6 +304,9 @@ def _paged_attend_kernel(bt_ref, *refs, page_size, pages_per_block,
                 row = jax.lax.broadcasted_iota(
                     jnp.int32, (rows, page_size), 0)
                 ok = (tok <= start + row) & (row < nv)  # causal + live
+                if selected:
+                    ok = ok & (sel_ref[0, :, t * page_size:
+                                       (t + 1) * page_size] > 0)
             else:
                 ok = tok < extent
             k_scale = v_scale = None
@@ -293,11 +317,12 @@ def _paged_attend_kernel(bt_ref, *refs, page_size, pages_per_block,
                 k_scale = ks_refs[t][pl.ds(r, 1), :]    # (1, ps)
                 v_scale = vs_refs[t][pl.ds(r, 1), :]
             for h in range(n_heads):
+                g = h // group
                 _online_softmax_page_fold(
                     q_ref[0, h].astype(jnp.float32),            # (R, Dh)
-                    k_refs[t][0, :, h * dh:(h + 1) * dh].astype(
+                    k_refs[t][0, :, g * dh:(g + 1) * dh].astype(
                         jnp.float32),                           # (ps, Dh)
-                    v_refs[t][0, :, h * dh:(h + 1) * dh].astype(
+                    v_refs[t][0, :, g * dh:(g + 1) * dh].astype(
                         jnp.float32),
                     ok, m_scr, l_scr, acc_scr, h,
                     k_scale=k_scale, v_scale=v_scale)
@@ -346,20 +371,35 @@ def _paged_scale_specs(ps, mp, pb):
     return ks, vs
 
 
+@functools.partial(jax.jit, static_argnums=(5, 6), static_argnames=("name",))
 def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
-                         interpret, pages_per_block, k_scales, v_scales):
-    """The one ``pallas_call`` behind all four paged kernels. ``q`` is
+                         interpret, pages_per_block, k_scales, v_scales,
+                         selected=None, name=None):
+    """The one ``pallas_call`` behind all four paged kernels. Jitted, so
+    that a step program traces and lowers the kernel body once and calls
+    it from every layer (same shapes, same static arguments: JAX reuses
+    the inner function's jaxpr and XLA inlines the calls), where each of
+    a model's layers used to trace its own copy: most of a signature's
+    warm-up time. ``q`` is
     head-major ``(S, H, R, Dh)`` and already scaled; ``geometry`` is the
     scalar-prefetch tail after the block table — ``(lengths,)`` for
     decode, ``(chunk_starts, n_valid)`` for chunked prefill.
-    ``k_scales``/``v_scales`` given = the dequant-attend variant."""
+    ``k_scales``/``v_scales`` given = the dequant-attend variant;
+    ``selected`` (S, C, mp*ps) given = chunked prefill under a per-query
+    selection; ``name`` renames the call for a device trace (the sparse
+    entries run this body under their own names)."""
     quantized = k_scales is not None
     chunked = len(geometry) == 2
     s_slots, h, rows, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
     pb = max(1, min(int(pages_per_block), mp))
-    k_specs, v_specs = _paged_kv_specs(ps, h * dh, mp, pb)
+    k_specs, v_specs = _paged_kv_specs(ps, k_pages.shape[-1], mp, pb)
+    sel_specs, sel_args = [], []
+    if selected is not None:
+        sel_specs = [pl.BlockSpec(
+            (1, rows, pb * ps), lambda s, j, *_prefetch: (s, 0, j))]
+        sel_args = [selected]
     sc_specs, sc_args = [], []
     if quantized:
         ks_specs, vs_specs = _paged_scale_specs(ps, mp, pb)
@@ -374,6 +414,7 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
         grid=(s_slots, pl.cdiv(mp, pb)),
         in_specs=[
             pl.BlockSpec((1, h, rows, dh), q_index),
+            *sel_specs,
             *k_specs,
             *v_specs,
             *sc_specs,
@@ -387,32 +428,46 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
     )
     kernel = functools.partial(_paged_attend_kernel, page_size=ps,
                                pages_per_block=pb, chunked=chunked,
-                               quantized=quantized)
+                               quantized=quantized,
+                               selected=selected is not None)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            # a chunk of grouped-query heads unrolls (heads x pages)
+            # folds whose fp32 temporaries the compiler stacks side by
+            # side: 33 MB at 32 heads x 64 queries x 4 pages against the
+            # default scoped limit of 16 MB (v5e has 128 MB of VMEM)
+            vmem_limit_bytes=_WIDE_VMEM_LIMIT if chunked and (
+                selected is not None or h * dh != k_pages.shape[-1])
+            else None,
         ) if not interpret else None,
         interpret=interpret,
-        name="ragged_paged_prefill" if chunked else "ragged_paged_decode",
+        name=name or ("ragged_paged_prefill" if chunked
+                      else "ragged_paged_decode"),
     )(block_tables.astype(jnp.int32),
       *(g.astype(jnp.int32) for g in geometry),
-      q, *([k_pages] * pb), *([v_pages] * pb), *sc_args)
+      q, *sel_args, *([k_pages] * pb), *([v_pages] * pb), *sc_args)
 
 
 def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
                          interpret, pages_per_block=1, k_scales=None,
-                         v_scales=None):
+                         v_scales=None, name=None):
     """``k_scales``/``v_scales`` given = the dequant-attend variant:
     same grid and BlockSpecs plus one scale-row group per streamed
-    page, fused into the shared fold inside the ONE kernel body."""
-    qs = (q * jnp.asarray(scale, q.dtype))[:, :, None, :]   # (S,H,1,Dh)
+    page, fused into the shared fold inside the ONE kernel body.
+    Grouped-query heads: the ``H / kv`` queries of a KV head go in as
+    that head's rows, ``(S, kv, H/kv, Dh)`` (one query row when every
+    head has its own K and V)."""
+    s_slots, h, dh = q.shape
+    kv = k_pages.shape[-1] // dh
+    qs = (q * jnp.asarray(scale, q.dtype)).reshape(s_slots, kv, h // kv, dh)
     out = _paged_attend_pallas(qs, k_pages, v_pages, block_tables,
                                (lengths,), interpret, pages_per_block,
-                               k_scales, v_scales)
-    return out[:, :, 0, :]
+                               k_scales, v_scales, name=name)
+    return out.reshape(s_slots, h, dh)
 
 
 # ---------------------------------------------------------------------------
@@ -471,7 +526,10 @@ def _paged_decode_int8_pallas(q, k_pages, v_pages, k_scales, v_scales,
 # ---------------------------------------------------------------------------
 
 def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
-                       n_valid, scale):
+                       n_valid, scale, selected=None):
+    """``selected`` (S, C, mp*ps), where given, marks the cache positions
+    each query may attend to beside the causal test (sparse attention:
+    the indexer's choice)."""
     s_slots, c, h, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
@@ -484,7 +542,10 @@ def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
     pos = chunk_starts[:, None] + jnp.arange(c, dtype=jnp.int32)  # (S, C)
     causal = tok[None, None, None, :] <= pos[:, None, :, None]
     row_ok = (jnp.arange(c) < n_valid[:, None])[:, None, :, None]
-    scores = jnp.where(causal & row_ok, scores, NEG_INF)
+    ok = causal & row_ok
+    if selected is not None:
+        ok = ok & (selected[:, None] > 0)
+    scores = jnp.where(ok, scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     # masked rows (padding lanes / inactive slots) emit exact zeros
     alive = jnp.max(scores, axis=-1, keepdims=True) > NEG_INF / 2
@@ -495,7 +556,8 @@ def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
 
 def _paged_prefill_pallas(q, k_pages, v_pages, block_tables, chunk_starts,
                           n_valid, scale, interpret, pages_per_block=1,
-                          k_scales=None, v_scales=None):
+                          k_scales=None, v_scales=None, selected=None,
+                          name=None):
     """Chunked-prefill analog of :func:`_paged_decode_pallas`: the SAME
     kernel body (``chunked=True``), same ``pages_per_block`` tunable and
     bit-equal accumulation order. ``q`` (S, C, H, Dh) is handed to the
@@ -504,7 +566,8 @@ def _paged_prefill_pallas(q, k_pages, v_pages, block_tables, chunk_starts,
     qs = (q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3)
     out = _paged_attend_pallas(qs, k_pages, v_pages, block_tables,
                                (chunk_starts, n_valid), interpret,
-                               pages_per_block, k_scales, v_scales)
+                               pages_per_block, k_scales, v_scales,
+                               selected=selected, name=name)
     return out.transpose(0, 2, 1, 3)                        # (S,C,H,Dh)
 
 
@@ -818,6 +881,9 @@ def _paged_sig(q, k_pages, bt):
            ("mp", bt.shape[1])]
     if q.ndim == 4:                      # prefill: chunk width matters
         sig.insert(1, ("c", q.shape[1]))
+    kv = k_pages.shape[-1] // q.shape[-1]
+    if kv != q.shape[-2]:                # grouped-query heads
+        sig.append(("kv", kv))
     return tuple(sig)
 
 
@@ -843,7 +909,7 @@ def _paged_vmem_estimate(args, kwargs, blocks):
         return (lead * -(-sub // tile) * tile * -(-lane // 128) * 128
                 * itemsize)
 
-    page = tiled(1, ps, h * dh, k_pages.dtype.itemsize)
+    page = tiled(1, ps, k_pages.shape[-1], k_pages.dtype.itemsize)
     qo = tiled(h, rows, dh, q.dtype.itemsize)
     streamed = 2 * pb * page + 2 * qo
     if k_pages.dtype.itemsize == 1:              # int8: + scale groups

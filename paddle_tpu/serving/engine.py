@@ -125,6 +125,7 @@ import numpy as np
 
 from paddle_tpu.analysis.concurrency import guarded_by
 from paddle_tpu.serving import decode_attention as DA
+from paddle_tpu.serving import sparse_attention as SA
 from paddle_tpu.serving.paged_cache import (_ROOT_KEY, _chain,
                                             PagedCacheConfig, PagedKVCache,
                                             payload_digest, quantize_kv)
@@ -148,6 +149,23 @@ _STEP_BUCKETS = tuple(round(1e-3 * 2 ** (k / 2), 6) for k in range(23))
 
 # serving_step_part_seconds_total{phase,part}: the leaf spans of one
 # engine step, by the work they time (PERF.md section 3)
+#: what the step programs count on the device and hand back with the
+#: tokens (``ServingSpec.stats`` plus attention's own)
+_STEP_STAT_HELP = {
+    "moe_assignments": "token-expert pairs computed",
+    "moe_experts_touched": "experts with at least one token, summed "
+                           "over layers and token steps",
+    "moe_expert_slots": "experts a layer has, summed over layers and "
+                        "token steps (the denominator of touched)",
+    "moe_max_expert_tokens": "the fullest expert's load, summed over "
+                             "layers and token steps",
+    "attn_context_tokens": "cached tokens a query could attend to (the "
+                           "indexer scores them once past topk), summed "
+                           "over queries and layers",
+    "attn_selected_tokens": "tokens attended after selection, summed "
+                            "over queries and layers",
+}
+
 _STEP_PARTS = (("prefill", "assemble"), ("prefill", "cow_copy"),
                ("prefill", "dispatch"), ("prefill", "sync"),
                ("prefill", "book"), ("decode", "assemble"),
@@ -203,11 +221,17 @@ class ServingEngine:
                  anatomy_probe_every: Optional[int] = None,
                  tier: str = "colocated",
                  host_spill_pages: int = 0):
-        cfg = model.cfg
-        if cfg.pipeline or cfg.stacked_layers:
+        # the model's block as the engine runs it (serving/program.py);
+        # what that program cannot do yet is refused here, by name
+        base = model.serving()
+        cfg = spec = base.spec
+        self.draft_program = draft_model.serving() \
+            if draft_model is not None else None
+        if spec.select_topk is not None and spec.select_topk % page_size:
             raise ValueError(
-                "ServingEngine needs the LayerList GPT layout; convert "
-                "stacked/pipeline checkpoints for serving first")
+                f"select_topk={spec.select_topk} must be a multiple of "
+                f"page_size={page_size}: the selected tokens are folded "
+                "as whole pages")
         # -- disaggregation tier (ISSUE 19): a "prefill" engine runs
         # only the batched chunked prefill step and PARKS prefill-done
         # slots for handoff (poll_handoffs snapshots + releases them); a
@@ -249,6 +273,25 @@ class ServingEngine:
         if mesh is not None:
             tp = mesh_tp
         tp = int(tp or 1)
+        wants = {
+            "tp": tp > 1 or tp_probe,
+            "int8_pages": cache_dtype is not None
+            and jnp.dtype(cache_dtype) == jnp.dtype(jnp.int8),
+            "draft": draft_model is not None,
+            "host_spill": host_spill_pages > 0,
+            "migration": snapshot_every_blocks is not None,
+            "tiers": tier != "colocated",
+        }
+        for feature, wanted in wants.items():
+            if wanted and feature not in spec.supports:
+                raise ValueError(
+                    f"{type(model).__name__} does not serve with "
+                    f"{feature!r} yet (its serving program supports "
+                    f"{sorted(spec.supports) or 'none of the options'})")
+        if draft_model is not None \
+                and "draft" not in self.draft_program.spec.supports:
+            raise ValueError(f"{type(draft_model).__name__} cannot be a "
+                             "draft model yet ('draft')")
         if tp_probe:
             if tp < 2:
                 raise ValueError("tp_probe needs tp >= 2")
@@ -292,10 +335,11 @@ class ServingEngine:
         if self.speculative:
             if draft_params is None:
                 raise ValueError("draft_model needs draft_params")
-            if draft_model.cfg.vocab_size != cfg.vocab_size:
+            dspec = self.draft_program.spec
+            if dspec.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     "draft and target models must share a vocabulary "
-                    f"({draft_model.cfg.vocab_size} != {cfg.vocab_size})")
+                    f"({dspec.vocab_size} != {cfg.vocab_size})")
             if self.spec_k < 2:
                 raise ValueError("spec_k must be >= 2 (spec_k=1 is "
                                  "plain decoding — drop the draft)")
@@ -320,31 +364,31 @@ class ServingEngine:
         # cache_dtype=jnp.int8 stores quantized pages with per-token-row
         # fp32 scales and attends through the dequant-attend kernels —
         # HBM per live token roughly halves AGAIN vs bf16
-        dtype = cache_dtype or params["wte"]["weight"].dtype
+        dtype = cache_dtype or base.param_dtype(params)
         # a probe engine's pool holds ONE shard's head slice; an spmd
         # engine's pool is globally shaped but placed sharded H/tp
         self.cache = PagedKVCache(PagedCacheConfig(
             num_layers=cfg.num_layers,
-            num_heads=self._tp_heads if self.tp_probe else cfg.num_heads,
-            head_dim=cfg.hidden_size // cfg.num_heads,
+            num_heads=self._tp_heads if self.tp_probe else cfg.kv_heads,
+            head_dim=cfg.head_dim,
             num_slots=num_slots, page_size=page_size, num_pages=num_pages,
             max_pages_per_slot=max_pages_per_slot, dtype=dtype,
-            share_prefix=prefix_sharing),
+            share_prefix=prefix_sharing, extra_rows=spec.extra_rows),
             mesh=mesh if self.tp_spmd else None,
             host_spill_pages=host_spill_pages)
         self.quantized = self.cache.config.quantized
         self.draft_cache = None
         self._draft_quantized = False
         if self.speculative:
-            dcfg = draft_model.cfg
+            dcfg = self.draft_program.spec
             ddtype = draft_cache_dtype or cache_dtype or \
-                draft_params["wte"]["weight"].dtype
+                self.draft_program.param_dtype(draft_params)
             # same slot/page geometry as the target cache: allocations
             # run in lockstep (reserve/free the same slots for the same
             # token counts), so target admission implies draft admission
             self.draft_cache = PagedKVCache(PagedCacheConfig(
-                num_layers=dcfg.num_layers, num_heads=dcfg.num_heads,
-                head_dim=dcfg.hidden_size // dcfg.num_heads,
+                num_layers=dcfg.num_layers, num_heads=dcfg.kv_heads,
+                head_dim=dcfg.head_dim,
                 num_slots=num_slots, page_size=page_size,
                 num_pages=num_pages,
                 max_pages_per_slot=max_pages_per_slot, dtype=ddtype,
@@ -408,6 +452,15 @@ class ServingEngine:
         if not self.tp_spmd:
             self.anatomy_probe_every = 0
         self._decode_rounds = 0
+        # the programs the jitted steps run: this engine's (one head
+        # shard's under tp) and, for the collective probe, its twin with
+        # the collectives elided
+        self.program = base if self.tp == 1 else model.serving(
+            tp=self.tp, spmd=self.tp_spmd, mlp_sharded=self._mlp_sharded)
+        self._probe_program = model.serving(tp=self.tp, spmd=False) \
+            if self.tp > 1 else None
+        #: counts the steps hand back beside the tokens, in order
+        self._step_stats = self._stat_names(spec)
         self._bind_step_metrics()
 
         # step-side params: tp re-lays the attention projections out
@@ -626,6 +679,10 @@ class ServingEngine:
             "serving_flops_utilization",
             "retired static flops per busy second / best observed rate"
         ).child()
+        self._c_step_stats = [
+            r.counter(f"serving_{name}_total", _STEP_STAT_HELP.get(
+                name, "a count the step program hands back")).child()
+            for name in self._step_stats]
         self._g_prefix_saved = r.gauge(
             "serving_prefix_saved_per_token",
             "prefill tokens skipped via prefix sharing per served token"
@@ -637,6 +694,14 @@ class ServingEngine:
             "first_call (trace, lower, compile or cache load, run)")
         self._c_warm_cost = warm.child(part="cost_gauges")
         self._c_warm_first = warm.child(part="first_call")
+
+    def _require(self, feature: str, what: str):
+        """Refuse, by name, a call the model's serving program does not
+        carry yet (its pool entries hold rows this path would lose)."""
+        if feature not in self.program.spec.supports:
+            raise ValueError(
+                f"{what}: {type(self.model).__name__} does not serve "
+                f"with {feature!r} yet")
 
     # -- request surface --------------------------------------------------
 
@@ -667,7 +732,7 @@ class ServingEngine:
                 "(restore_slot), not fresh prompts")
         total = len(np.asarray(prompt).reshape(-1)) + max_new_tokens
         limit = min(self.cache.config.max_tokens_per_slot,
-                    self.model.cfg.max_position)
+                    self.program.spec.max_position)
         if total > limit:
             raise ValueError(f"request needs {total} tokens > per-slot "
                              f"limit {limit}")
@@ -1004,6 +1069,19 @@ class ServingEngine:
             n * self.scheduler.num_slots * w * self.cache.config.page_size
             * self._kv_token_bytes)
 
+    def _note_step_stats(self, phase, counts):
+        """Feed one call's device-side counts (``self._step_stats``
+        order) to their counters, and the round's span its
+        ``experts_touched`` / ``selected`` attributes."""
+        for child, n in zip(self._c_step_stats, counts):
+            child.inc(int(n))
+        if phase.span is not None:
+            got = dict(zip(self._step_stats, counts))
+            phase.span.set_attrs(**{
+                attr: int(got[name]) for attr, name in (
+                    ("experts_touched", "moe_experts_touched"),
+                    ("selected", "attn_selected_tokens")) if name in got})
+
     def _decode_round(self, dslots) -> int:
         """Advance every decoding slot one block of ``decode_block``
         tokens through the jitted decode step; returns tokens kept."""
@@ -1029,7 +1107,12 @@ class ServingEngine:
                 out, self.cache.pages = self.decode_step(
                     self._step_params, self.cache.pages, *args)
             with phase("serving.decode.sync", part["decode", "sync"]) as sync:
+                if self._step_stats:     # counts ride the tokens' sync
+                    out, counts = out
+                    counts = np.asarray(counts)
                 out = np.asarray(out)                # (S, decode_block)
+            if self._step_stats:
+                self._note_step_stats(rnd, counts)
             # the call's wall time as it has always been taken: uploads,
             # dispatch and sync, from the phases' own clock reads
             t0, t1 = asm.start, sync.end
@@ -1502,7 +1585,12 @@ class ServingEngine:
                         *args)
             with phase("serving.prefill.sync",
                        part["prefill", "sync"]) as sync:
+                if self._step_stats:     # counts ride the tokens' sync
+                    nxt, counts = nxt
+                    counts = np.asarray(counts)
                 nxt = np.asarray(nxt)
+            if self._step_stats:
+                self._note_step_stats(call, counts)
             t0, now = asm.start, sync.end
             self._h_prefill_step.observe(now - t0)
             self.anatomy.add_phase("prefill", t0, now)
@@ -1621,6 +1709,9 @@ class ServingEngine:
         bucket-coverage proof (plan == reachable per tier). Page IO and
         the CoW copy stay on both tiers: handoff reads pages on the
         prefill side and writes them on the decode side."""
+        if sig[0] in ("page_read", "page_write") \
+                and "migration" not in self.program.spec.supports:
+            return False        # pages never leave this engine
         if self.tier == "prefill" and sig[0] in ("decode", "decode_probe"):
             return False
         if self.tier == "decode" and sig[0] == "prefill":
@@ -1803,6 +1894,7 @@ class ServingEngine:
         logical KV content. An int8 cache's shards carry the pages'
         scale rows alongside the int8 KV — ONE shard, one hash over
         both, so a transfer can never split a page from its scales."""
+        self._require("migration", "snapshot_slot")
         if self.speculative:
             raise SlotMigrationError(
                 "speculative engines do not migrate slots (the draft "
@@ -2007,6 +2099,7 @@ class ServingEngine:
         root span adopts the snapshot's ``trace_id`` (under
         ``parent_span`` when given), keeping one timeline across the
         migration."""
+        self._require("migration", "restore_slot")
         if self.speculative:
             raise SlotMigrationError(
                 "speculative engines do not migrate slots (the draft "
@@ -2146,6 +2239,7 @@ class ServingEngine:
         cache no longer holds: later pages could not chain onto a
         missing parent on the importer anyway. Returns None when
         nothing is exportable — the router degrades to re-prefill."""
+        self._require("prefix_export", "export_prefix_pages")
         if not self.cache.config.share_prefix:
             return None
         cfgc = self.cache.config
@@ -2225,6 +2319,7 @@ class ServingEngine:
         (never evicting), through the warmed ``("page_write",)``
         signature. Returns pages installed (0 when everything was
         already held — not an error)."""
+        self._require("prefix_export", "import_prefix_pages")
         if bundle is None or not self.cache.config.share_prefix:
             return 0
         if bundle.get("format") != PREFIX_BUNDLE_FORMAT:
@@ -2361,83 +2456,156 @@ class ServingEngine:
         out["blocks"] = blocks
         return out
 
-    def _qkv_tp(self, ap, x):
-        """``(S, C, D)`` -> per-shard q, k, v heads ``(S, H/tp, C,
-        Dh)`` from the head-major projection slice (the col-parallel
-        half of the Megatron split)."""
-        qkv = jnp.einsum("scd,dthk->tshck", x, ap["qkv_tp"]["weight"])
-        b = ap["qkv_tp"].get("bias")
-        if b is not None:
-            qkv = qkv + b[:, None, :, None, :]
-        return qkv[0], qkv[1], qkv[2]
-
-    def _proj_tp(self, ap, att, spmd):
-        """Row-sharded output projection + THE one attention-output
-        collective: local heads ``(S, H/tp, Dh)`` (decode) or ``(S, C,
-        H/tp, Dh)`` (prefill) -> ``(S, C, D)`` replicated. ``spmd=False``
-        (the probe engine) elides the psum — one shard's partial sum
-        stands in, which is exactly one chip's share of the work."""
-        wo = ap["out_tp"]["weight"]
-        if att.ndim == 3:
-            part = jnp.einsum("shk,hkd->sd", att, wo)[:, None, :]
-        else:
-            part = jnp.einsum("schk,hkd->scd", att, wo)
-        if spmd:
-            part = jax.lax.psum(part, "tp")
-        b = ap["out_tp"].get("bias")
-        return part + b if b is not None else part
-
-    def _mlp_tp(self, block, bp, x):
-        """Megatron MLP shard (prefill tier, ISSUE 19): fc1
-        column-split over "tp" (the local ``(D, F/tp)`` slice produces
-        local hidden activations), fc2 row-split (``(F/tp, D)`` partial
-        products) closed by the layer's SECOND psum, with the fc2 bias
-        added exactly once AFTER the reduce (the replicated
-        ``block.mlp`` adds it inside ``Linear``, which under a row
-        shard would add it ``tp`` times). Mathematically the replicated
-        MLP with the hidden-dim reduction reassociated at the shard
-        boundary."""
-        mp = bp["mlp"]
-        h = block.ln2(bp["ln2"], x)
-        h = block.mlp.act(jnp.matmul(h, mp["fc1"]["weight"])
-                          + mp["fc1"]["bias"])
-        part = jax.lax.psum(jnp.matmul(h, mp["fc2"]["weight"]), "tp")
-        return part + mp["fc2"]["bias"]
-
     # -- jitted step bodies ----------------------------------------------
 
+    def _write_rows(self, ent, rows, page_idx, off, quantized, psum_axis):
+        """Land one call's rows in a layer's pool entry: K and V (int8
+        rows + per-token scales for a quantized pool), then the
+        program's extra rows, each where ``page_idx`` / ``off`` say
+        (one index a token: ``(S,)`` for decode, ``(S, C)`` for a
+        chunk). Extra rows are kept ``(P, width, page_size)``, tokens
+        along the lanes, and written a page tile at a time
+        (:meth:`_write_lane_rows`)."""
+        k_tok, v_tok = rows[0], rows[1]
+        if quantized:
+            kp, vp, ksc, vsc = ent
+            ax = (k_tok.ndim - 1,)
+            kq, k_s = quantize_kv(k_tok, ax, psum_axis=psum_axis)
+            vq, v_s = quantize_kv(v_tok, ax, psum_axis=psum_axis)
+            return (kp.at[page_idx, off].set(kq),
+                    vp.at[page_idx, off].set(vq),
+                    ksc.at[page_idx, off].set(k_s),
+                    vsc.at[page_idx, off].set(v_s))
+        kp, vp = ent[0], ent[1]
+        out = [kp.at[page_idx, off].set(k_tok.astype(kp.dtype)),
+               vp.at[page_idx, off].set(v_tok.astype(vp.dtype))]
+        for pool, row in zip(ent[2:], rows[2:]):
+            out.append(self._write_lane_rows(pool, row, page_idx, off))
+        return tuple(out)
+
+    @staticmethod
+    def _write_lane_rows(pool, row, page_idx, off):
+        """Tokens into a ``(P, width, page_size)`` pool, whose lanes are
+        the tokens of a page: every page a call touches is read, the
+        call's tokens placed in their lanes, and the tile written back
+        whole. (A scatter along the lane axis makes the chip's compiler
+        re-lay the whole pool out and back around it.) ``row`` (S, C,
+        width) or (S, width) with one ``page_idx`` / ``off`` a token;
+        ``page_idx`` 0 marks a token that is not written. A lane's
+        tokens are consecutive positions, so they touch at most
+        ``(C - 1) // page_size + 2`` pages, in order."""
+        s = row.shape[0]
+        width, ps = pool.shape[1], pool.shape[2]
+        row = row.reshape(s, -1, width).astype(pool.dtype)      # (S,C,W)
+        page_idx, off = page_idx.reshape(s, -1), off.reshape(s, -1)
+        c = row.shape[1]
+        live = page_idx > 0
+        # which of the lane's touched pages a token lands in: it moves
+        # on where ``off`` wraps
+        nth = jnp.cumsum(jnp.concatenate(
+            [jnp.zeros((s, 1), jnp.int32),
+             (off[:, 1:] < off[:, :-1]).astype(jnp.int32)], axis=1), axis=1)
+        lanes = jnp.arange(ps, dtype=jnp.int32)
+        for k in range((c - 1) // ps + 2 if c > 1 else 1):
+            here = live & (nth == k)                            # (S,C)
+            page = jnp.max(jnp.where(here, page_idx, 0), axis=1)  # (S,)
+            put = (here[:, :, None]
+                   & (off[:, :, None] == lanes)).astype(pool.dtype)  # (S,C,ps)
+            tile = jnp.einsum("scw,scp->swp", row, put)     # one-hot: exact
+            written = jnp.any(put > 0, axis=1)[:, None, :]      # (S,1,ps)
+            pool = pool.at[page].set(
+                jnp.where(written, tile, pool[page]))
+        return pool
+
+    def _selects(self, spec, block_tables) -> bool:
+        """Whether a call at this gather width runs the program's token
+        selection: a static fact of the bucket. Up to ``select_topk``
+        cached tokens every query attends to all it sees, so narrower
+        buckets take the dense kernels."""
+        return spec.select_topk is not None and block_tables.shape[1] \
+            * self.cache.config.page_size > spec.select_topk
+
+    def _attend_decode(self, spec, q, ent, block_tables, lengths, index,
+                       quantized):
+        """One decode token a slot, ``q`` (S, H, Dh), over the pool entry
+        ``ent`` as just written; ``lengths`` counts this token. Returns
+        (heads (S, H, Dh), tokens attended a slot (S,))."""
+        if quantized:
+            return DA.ragged_paged_decode_int8_attention(
+                q, *ent, block_tables, lengths, impl=self.attn_impl), lengths
+        if self._selects(spec, block_tables):
+            return SA.indexed_decode_attention(
+                q, *ent, block_tables, lengths, index[0][:, 0],
+                index[1][:, 0], spec.select_topk, impl=self.attn_impl)
+        return DA.ragged_paged_decode_attention(
+            q, ent[0], ent[1], block_tables, lengths,
+            impl=self.attn_impl), lengths
+
+    def _attend_prefill(self, spec, q, ent, block_tables, starts, n_valid,
+                        index, quantized):
+        """A chunk of queries a slot, ``q`` (S, C, H, Dh), causally over
+        the pool entry ``ent`` as just written. Returns heads (S, C, H,
+        Dh)."""
+        if quantized:
+            return DA.ragged_paged_prefill_int8_attention(
+                q, *ent, block_tables, starts, n_valid, impl=self.attn_impl)
+        if self._selects(spec, block_tables):
+            return SA.indexed_prefill_attention(
+                q, *ent, block_tables, starts, n_valid, index[0], index[1],
+                spec.select_topk, impl=self.attn_impl)
+        return DA.ragged_paged_prefill_attention(
+            q, ent[0], ent[1], block_tables, starts, n_valid,
+            impl=self.attn_impl)
+
+    @staticmethod
+    def _stat_names(spec):
+        """The counts a step of this program hands back beside the
+        tokens: the program's own, then attention's where it selects."""
+        return tuple(spec.stats) + (
+            ("attn_context_tokens", "attn_selected_tokens")
+            if spec.select_topk is not None else ())
+
+    def _step_stat_vector(self, spec, ffn_stats, context, selected):
+        """One layer-call's counts in :meth:`_stat_names` order."""
+        vals = [ffn_stats[name] for name in spec.stats]
+        if spec.select_topk is not None:
+            vals += [context, selected]
+        return jnp.stack([jnp.asarray(v).astype(jnp.int32) for v in vals])
+
     def _decode_loop(self, params, pages, block_tables, lengths, tokens,
-                     active, n_valid=None, *, model=None, quantized=False,
-                     n_steps=1, tp=1, spmd=False, mlp_sharded=False):
+                     active, n_valid=None, *, program=None, quantized=False,
+                     n_steps=1, psum_axis=None):
         """The shared greedy token loop behind the decode step AND the
-        draft-proposal step: ``n_steps`` inner iterations, each entering
-        every slot's current token at position ``lengths[s]``, landing
-        its K/V in the slot's current page (quantized caches store the
+        draft-proposal step, written against what a model supplies
+        (``program``, see :mod:`paddle_tpu.serving.program`): ``n_steps``
+        inner iterations, each entering every slot's current token at
+        position ``lengths[s]``, landing the rows the program wants
+        cached in the slot's current page (quantized caches store the
         int8 rows + per-token scales and attend through the
         dequant-attend kernel), and attending ragged-paged over live
-        pages only. ``n_valid`` (draft proposing) additionally masks
-        writes of iterations ``j >= n_valid[s]`` to the null page — a
-        chunk capped below ``n_steps`` must not write past the slot's
-        reservation. ``tp > 1``: the body is per-shard — qkv from the
-        head-major TP slice, K/V landing in the per-shard pages, the
-        ragged kernel over ``H/tp`` local heads, and the row-sharded
-        output projection with ONE psum per layer (``spmd=False`` is
-        the probe engine: same local math, collectives elided; int8
-        scales complete their abs-max with a pmax so quantization stays
-        bit-identical to tp=1). The keyword-only args are static config
-        (default-marked so the AST host-sync lint, which runs on THIS
-        body via the graph_lint preset, seeds only the array args as
-        tracers). Returns (tokens (S, n_steps), pages)."""
-        cfg = model.cfg
+        pages only — or, where the program selects and the bucket is
+        wide enough (:meth:`_selects`), over the selected tokens only.
+        ``n_valid`` (draft proposing) additionally masks writes of
+        iterations ``j >= n_valid[s]`` to the null page — a chunk capped
+        below ``n_steps`` must not write past the slot's reservation.
+        Under tp the program's body is one head shard's; ``psum_axis``
+        completes the int8 scales' abs-max over the shards so
+        quantization stays bit-identical to tp=1. The keyword-only args
+        are static config (default-marked so the AST host-sync lint,
+        which runs on THIS body via the graph_lint preset, seeds only
+        the array args as tracers). Returns (tokens (S, n_steps), pages),
+        or ((tokens, counts), pages) where the program counts
+        (``self._step_stats`` order)."""
+        spec = program.spec
         ps = self.cache.config.page_size
         s_tot = tokens.shape[0]
         w = block_tables.shape[1]
         slot_ids = jnp.arange(s_tot)
+        n_stats = len(self._stat_names(spec))
 
         def one_token(j, pages, lengths, tokens):
-            pos = jnp.minimum(lengths, cfg.max_position - 1)
-            x = (model.wte(params["wte"], tokens[:, None])
-                 + model.wpe(params["wpe"], pos[:, None]))      # (S,1,D)
+            pos = jnp.minimum(lengths, spec.max_position - 1)
+            x = program.embed(params, tokens[:, None], pos[:, None])
             writable = active > 0
             if n_valid is not None:
                 writable = writable & (j < n_valid)
@@ -2446,63 +2614,40 @@ class ServingEngine:
                 block_tables[slot_ids, jnp.minimum(lengths // ps, w - 1)],
                 0)
             off = lengths % ps
-            new_pages = []
-            for i, block in enumerate(model.blocks):
-                bp = params["blocks"][str(i)]
-                h = block.ln1(bp["ln1"], x)
-                if tp > 1:
-                    q, k, v = self._qkv_tp(bp["attn"], h)  # (S,Hl,1,Dh)
-                else:
-                    q, k, v = block.attn.qkv_heads(bp["attn"],
-                                                   h)      # (S,H,1,Dh)
-                # the token's heads folded the way the pool stores them
-                k_tok = k[:, :, 0, :].reshape(s_tot, -1)   # (S,H*Dh)
-                v_tok = v[:, :, 0, :].reshape(s_tot, -1)
-                if quantized:
-                    kp, vp, ksc, vsc = pages[i]
-                    psa = "tp" if (tp > 1 and spmd) else None
-                    kq, k_s = quantize_kv(k_tok, (1,), psum_axis=psa)
-                    vq, v_s = quantize_kv(v_tok, (1,), psum_axis=psa)
-                    kp = kp.at[page_idx, off].set(kq)
-                    vp = vp.at[page_idx, off].set(vq)
-                    ksc = ksc.at[page_idx, off].set(k_s)
-                    vsc = vsc.at[page_idx, off].set(v_s)
-                    att = DA.ragged_paged_decode_int8_attention(
-                        q[:, :, 0, :], kp, vp, ksc, vsc, block_tables,
-                        lengths + 1, impl=self.attn_impl)       # (S,H,Dh)
-                    new_pages.append((kp, vp, ksc, vsc))
-                else:
-                    kp, vp = pages[i]
-                    kp = kp.at[page_idx, off].set(k_tok.astype(kp.dtype))
-                    vp = vp.at[page_idx, off].set(v_tok.astype(vp.dtype))
-                    att = DA.ragged_paged_decode_attention(
-                        q[:, :, 0, :], kp, vp, block_tables, lengths + 1,
-                        impl=self.attn_impl)                    # (S,H,Dh)
-                    new_pages.append((kp, vp))
-                if tp > 1:
-                    x = x + self._proj_tp(bp["attn"], att, spmd)
-                else:
-                    x = x + block.attn.proj_out(bp["attn"],
-                                                att[:, :, None, :])
-                if mlp_sharded:
-                    x = x + self._mlp_tp(block, bp, x)
-                else:
-                    x = x + block.mlp(bp["mlp"], block.ln2(bp["ln2"], x))
-            x = model.ln_f(params["ln_f"], x)
-            logits = jnp.einsum("bd,vd->bv", x[:, 0],
-                                params["wte"]["weight"])
-            return new_pages, jnp.argmax(logits, -1).astype(jnp.int32)
+            seen = jnp.where(writable, lengths + 1, 0).sum()
+            new_pages, counts = [], 0
+            for i in range(spec.num_layers):
+                q, rows, index = program.attn_in(params, i, x, pos[:, None])
+                ent = self._write_rows(
+                    pages[i], tuple(r[:, 0] for r in rows), page_idx, off,
+                    quantized, psum_axis)
+                att, attended = self._attend_decode(
+                    spec, q[:, :, 0, :], ent, block_tables, lengths + 1,
+                    index, quantized)                           # (S,H,Dh)
+                new_pages.append(ent)
+                x = program.attn_out(params, i, x, att[:, None])
+                x, ffn_stats = program.ffn(params, i, x, writable[:, None])
+                if n_stats:
+                    counts = counts + self._step_stat_vector(
+                        spec, ffn_stats, seen,
+                        jnp.where(writable, attended, 0).sum())
+            logits = program.head(params, x[:, 0])
+            return (new_pages, jnp.argmax(logits, -1).astype(jnp.int32),
+                    counts)
 
         out = jnp.zeros((s_tot, n_steps), jnp.int32)
+        totals = jnp.zeros((n_stats,), jnp.int32) if n_stats else ()
 
         def body(j, carry):
-            pages, lengths, tokens, out = carry
-            pages, nxt = one_token(j, pages, lengths, tokens)
-            return pages, lengths + 1, nxt, out.at[:, j].set(nxt)
+            pages, lengths, tokens, out, totals = carry
+            pages, nxt, counts = one_token(j, pages, lengths, tokens)
+            if n_stats:
+                totals = totals + counts
+            return pages, lengths + 1, nxt, out.at[:, j].set(nxt), totals
 
-        pages, _, _, out = jax.lax.fori_loop(
-            0, n_steps, body, (pages, lengths, tokens, out))
-        return out, pages
+        pages, _, _, out, totals = jax.lax.fori_loop(
+            0, n_steps, body, (pages, lengths, tokens, out, totals))
+        return ((out, totals) if n_stats else out), pages
 
     def _decode_step_impl(self, params, pages, block_tables, lengths,
                           tokens, active):
@@ -2515,11 +2660,10 @@ class ServingEngine:
         discarded garbage (the host keeps only in-budget, pre-EOS
         tokens). Returns (tokens (S, decode_block), pages)."""
         return self._decode_loop(params, pages, block_tables, lengths,
-                                 tokens, active, model=self.model,
+                                 tokens, active, program=self.program,
                                  quantized=self.quantized,
                                  n_steps=self.decode_block,
-                                 tp=self.tp, spmd=self.tp_spmd,
-                                 mlp_sharded=self._mlp_sharded)
+                                 psum_axis="tp" if self.tp_spmd else None)
 
     def _make_probe_pool(self):
         """Zero page pool for the collective probe: the real pool's
@@ -2547,10 +2691,10 @@ class ServingEngine:
         shapes and math minus the per-layer psum, so ``real - probe``
         wall time is the step's exposed collective cost."""
         return self._decode_loop(params, pages, block_tables, lengths,
-                                 tokens, active, model=self.model,
+                                 tokens, active,
+                                 program=self._probe_program,
                                  quantized=self.quantized,
-                                 n_steps=self.decode_block,
-                                 tp=self.tp, spmd=False)
+                                 n_steps=self.decode_block)
 
     def _draft_propose_step_impl(self, params, pages, block_tables,
                                  lengths, tokens, active, n_valid):
@@ -2561,36 +2705,37 @@ class ServingEngine:
         discarded lanes. Returns (proposals (S, spec_k), pages)."""
         return self._decode_loop(params, pages, block_tables, lengths,
                                  tokens, active, n_valid,
-                                 model=self.draft_model,
+                                 program=self.draft_program,
                                  quantized=self._draft_quantized,
                                  n_steps=self.spec_k)
 
     def _prefill_loop(self, params, pages, block_tables, starts, tokens,
-                      n_valid, *, model=None, quantized=False,
-                      all_positions=False, tp=1, spmd=False,
-                      mlp_sharded=False):
+                      n_valid, *, program=None, quantized=False,
+                      all_positions=False, psum_axis=None):
         """The shared chunk-forward behind the batched prefill step, the
-        draft prefill step, and the speculative VERIFY step: ``tokens``
-        (S, C) enter at absolute positions ``starts[s]..starts[s]+C-1``
-        (first ``n_valid[s]`` real, rest pad to the null page), K/V land
-        in each slot's pages (quantized: int8 + scale rows), and every
-        live lane attends causally over everything cached.
+        draft prefill step, and the speculative VERIFY step, written
+        against what a model supplies (``program``): ``tokens`` (S, C)
+        enter at absolute positions ``starts[s]..starts[s]+C-1`` (first
+        ``n_valid[s]`` real, rest pad to the null page), the rows the
+        program wants cached land in each slot's pages (quantized: int8 +
+        scale rows), and every live lane attends causally over everything
+        cached — each query under its own selection where the program
+        selects and the bucket is wide enough (:meth:`_selects`).
         ``all_positions=False`` returns the greedy next token after each
         slot's LAST valid position (prefill's first generated token);
         ``all_positions=True`` returns the greedy argmax after EVERY
         chunk position (S, C) — the speculative verifier's per-candidate
-        target tokens. ``tp``/``spmd`` shard the body per head group
-        exactly as in :meth:`_decode_loop`. Keyword-only args are static
-        config (the AST host-sync lint runs on this body — see
-        :meth:`_decode_loop`). Returns (tokens, pages)."""
-        cfg = model.cfg
+        target tokens. ``psum_axis`` as in :meth:`_decode_loop`.
+        Keyword-only args are static config (the AST host-sync lint runs
+        on this body — see :meth:`_decode_loop`). Returns (tokens,
+        pages), or ((tokens, counts), pages) where the program counts."""
+        spec = program.spec
         ps = self.cache.config.page_size
         s_tot, c = tokens.shape
         w = block_tables.shape[1]
         positions = starts[:, None] + jnp.arange(c, dtype=jnp.int32)
-        pos_e = jnp.minimum(positions, cfg.max_position - 1)
-        x = (model.wte(params["wte"], tokens)
-             + model.wpe(params["wpe"], pos_e))                 # (S,C,D)
+        pos_e = jnp.minimum(positions, spec.max_position - 1)
+        x = program.embed(params, tokens, pos_e)                # (S,C,D)
         valid = jnp.arange(c)[None, :] < n_valid[:, None]
         slot_ids = jnp.arange(s_tot)[:, None]
         page_idx = jnp.where(
@@ -2598,60 +2743,34 @@ class ServingEngine:
             block_tables[slot_ids, jnp.minimum(positions // ps, w - 1)],
             0)
         off = positions % ps
-        new_pages = []
-        for i, block in enumerate(model.blocks):
-            bp = params["blocks"][str(i)]
-            h = block.ln1(bp["ln1"], x)
-            if tp > 1:
-                q, k, v = self._qkv_tp(bp["attn"], h)           # (S,Hl,C,Dh)
-            else:
-                q, k, v = block.attn.qkv_heads(bp["attn"],
-                                               h)               # (S,H,C,Dh)
-            # token-major, heads folded the way the pool stores them
-            k_tok = k.transpose(0, 2, 1, 3).reshape(s_tot, c, -1)
-            v_tok = v.transpose(0, 2, 1, 3).reshape(s_tot, c, -1)
-            if quantized:
-                kp, vp, ksc, vsc = pages[i]
-                psa = "tp" if (tp > 1 and spmd) else None
-                kq, k_s = quantize_kv(k_tok, (2,),
-                                      psum_axis=psa)            # (S,C)
-                vq, v_s = quantize_kv(v_tok, (2,),
-                                      psum_axis=psa)
-                kp = kp.at[page_idx, off].set(kq)
-                vp = vp.at[page_idx, off].set(vq)
-                ksc = ksc.at[page_idx, off].set(k_s)
-                vsc = vsc.at[page_idx, off].set(v_s)
-                att = DA.ragged_paged_prefill_int8_attention(
-                    q.transpose(0, 2, 1, 3), kp, vp, ksc, vsc,
-                    block_tables, starts, n_valid,
-                    impl=self.attn_impl)                        # (S,C,H,Dh)
-                new_pages.append((kp, vp, ksc, vsc))
-            else:
-                kp, vp = pages[i]
-                kp = kp.at[page_idx, off].set(k_tok.astype(kp.dtype))
-                vp = vp.at[page_idx, off].set(v_tok.astype(vp.dtype))
-                att = DA.ragged_paged_prefill_attention(
-                    q.transpose(0, 2, 1, 3), kp, vp, block_tables,
-                    starts, n_valid, impl=self.attn_impl)       # (S,C,H,Dh)
-                new_pages.append((kp, vp))
-            if tp > 1:
-                x = x + self._proj_tp(bp["attn"], att, spmd)
-            else:
-                x = x + block.attn.proj_out(bp["attn"],
-                                            att.transpose(0, 2, 1, 3))
-            if mlp_sharded:
-                x = x + self._mlp_tp(block, bp, x)
-            else:
-                x = x + block.mlp(bp["mlp"], block.ln2(bp["ln2"], x))
-        x = model.ln_f(params["ln_f"], x)
+        counting = bool(self._stat_names(spec))
+        seen = positions + 1                 # tokens a query can attend to
+        attended = jnp.minimum(seen, spec.select_topk) \
+            if self._selects(spec, block_tables) else seen
+        seen, attended = (jnp.where(valid, a, 0).sum()
+                          for a in (seen, attended))
+        new_pages, counts = [], 0
+        for i in range(spec.num_layers):
+            q, rows, index = program.attn_in(params, i, x, pos_e)
+            ent = self._write_rows(pages[i], rows, page_idx, off, quantized,
+                                   psum_axis)
+            att = self._attend_prefill(
+                spec, q.transpose(0, 2, 1, 3), ent, block_tables, starts,
+                n_valid, index, quantized)                      # (S,C,H,Dh)
+            new_pages.append(ent)
+            x = program.attn_out(params, i, x, att)
+            x, ffn_stats = program.ffn(params, i, x, valid)
+            if counting:
+                counts = counts + self._step_stat_vector(
+                    spec, ffn_stats, seen, attended)
         if all_positions:
-            logits = jnp.einsum("scd,vd->scv", x,
-                                params["wte"]["weight"])        # (S,C,V)
-            return jnp.argmax(logits, -1).astype(jnp.int32), new_pages
-        last = jnp.take_along_axis(
-            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
-        logits = last @ params["wte"]["weight"].T               # (S, V)
-        return jnp.argmax(logits, -1).astype(jnp.int32), new_pages
+            logits = program.head(params, x)                    # (S,C,V)
+        else:
+            last = jnp.take_along_axis(
+                x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
+            logits = program.head(params, last)                 # (S, V)
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        return ((nxt, counts) if counting else nxt), new_pages
 
     def _prefill_step_impl(self, params, pages, block_tables, starts,
                            tokens, n_valid):
@@ -2660,10 +2779,9 @@ class ServingEngine:
         :meth:`_prefill_loop`). Returns (greedy next token after each
         slot's last valid position (S,), pages)."""
         return self._prefill_loop(params, pages, block_tables, starts,
-                                  tokens, n_valid, model=self.model,
+                                  tokens, n_valid, program=self.program,
                                   quantized=self.quantized,
-                                  tp=self.tp, spmd=self.tp_spmd,
-                                  mlp_sharded=self._mlp_sharded)
+                                  psum_axis="tp" if self.tp_spmd else None)
 
     def _draft_prefill_step_impl(self, params, pages, block_tables,
                                  starts, tokens, n_valid):
@@ -2672,7 +2790,7 @@ class ServingEngine:
         target's so proposals condition on identical context."""
         return self._prefill_loop(params, pages, block_tables, starts,
                                   tokens, n_valid,
-                                  model=self.draft_model,
+                                  program=self.draft_program,
                                   quantized=self._draft_quantized)
 
     def _verify_step_impl(self, params, pages, block_tables, starts,
@@ -2690,7 +2808,7 @@ class ServingEngine:
         chunk = jnp.concatenate(
             [tokens[:, None], props[:, :self.spec_k - 1]], axis=1)
         return self._prefill_loop(params, pages, block_tables, starts,
-                                  chunk, n_valid, model=self.model,
+                                  chunk, n_valid, program=self.program,
                                   quantized=self.quantized,
                                   all_positions=True)
 

@@ -98,6 +98,11 @@ class PagedCacheConfig:
     max_pages_per_slot: int = 16
     dtype: object = jnp.float32
     share_prefix: bool = True
+    #: further rows cached per token and layer beside K and V, ``(name,
+    #: width)`` each: one more pool array a layer, ``(num_pages, width,
+    #: page_size)`` (tokens along the lanes), allocated, shared, copied
+    #: on write and freed with its page
+    extra_rows: Tuple[Tuple[str, int], ...] = ()
 
     def __post_init__(self):
         if self.page_size < 1 or self.num_pages < 2:
@@ -105,6 +110,8 @@ class PagedCacheConfig:
                              "(page 0 is the reserved null page)")
         if self.max_pages_per_slot < 1:
             raise ValueError("max_pages_per_slot must be >= 1")
+        if self.extra_rows and self.quantized:
+            raise ValueError("an int8 pool carries no extra rows yet")
 
     @property
     def max_tokens_per_slot(self) -> int:
@@ -364,10 +371,15 @@ class PagedKVCache:
                  jnp.zeros(sshape, jnp.float32))
                 for _ in range(c.num_layers)]
         else:
-            self.pages: List[Tuple[jnp.ndarray, jnp.ndarray]] = [
-                (jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype))
+            self.pages: List[Tuple[jnp.ndarray, ...]] = [
+                (jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype),
+                 *(jnp.zeros((c.num_pages, width, c.page_size), c.dtype)
+                   for _name, width in c.extra_rows))
                 for _ in range(c.num_layers)]
         if self.mesh is not None:
+            if c.extra_rows:
+                raise ValueError("a tp-sharded pool carries no extra "
+                                 "rows yet")
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
             kv_s = NamedSharding(self.mesh, P(None, None, "tp"))

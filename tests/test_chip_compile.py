@@ -344,3 +344,87 @@ def test_flash_under_a_mesh_compiles_for_v5e(mesh_axes, model_kw, topo):
             params, batch).compile()
     # forward + the dkv and dq backward kernels, in every layer
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+# the long-document cell's geometry (benchmark/configs/
+# keye_vl2_30b_a3b.json): 32 slots x 16384 tokens in pages of 128, prefill
+# chunk 64 at 4 lanes, decode block 8, a pool of 1280 pages; published
+# widths, all 128 experts, the whole vocabulary, TWO of the six layers
+# (what is asserted is per layer, and two layers compile in a third of
+# the time)
+DOC_SLOTS, DOC_PS, DOC_PAGES, DOC_CHUNK, DOC_LAYERS = 32, 128, 1280, 64, 2
+
+
+@pytest.fixture(scope="module")
+def doc_steps(topo):
+    """lower(step, lanes, width) -> compiled, for the sparse-attention /
+    sparse-expert family at its published widths: abstract bf16 weights,
+    an engine with a nine-page pool, the steps lowered on the cell's
+    pool of shapes."""
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import inference
+    from paddle_tpu.models.sparse_moe_lm import (SparseMoELM,
+                                                 SparseMoELMConfig)
+    model = SparseMoELM(SparseMoELMConfig(num_hidden_layers=DOC_LAYERS,
+                                          kernel_impl="pallas"))
+    params = jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    eng = inference.make_serving_engine(
+        model, params, num_slots=DOC_SLOTS, page_size=DOC_PS, num_pages=9,
+        max_tokens_per_slot=16384, prefill_chunk=DOC_CHUNK, decode_block=8,
+        attn_impl="pallas", cache_dtype=jnp.bfloat16)
+    dev = SingleDeviceSharding(topo.devices[0])
+    sds = jax.ShapeDtypeStruct
+    weights = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, sharding=dev), params)
+    pages = jax.tree_util.tree_map(
+        lambda a: sds((DOC_PAGES,) + a.shape[1:], a.dtype, sharding=dev),
+        eng.cache.pages)
+
+    def i32(*shape):
+        return sds(shape, jnp.int32, sharding=dev)
+
+    def lower(step, lanes, width):
+        if step == "decode":
+            return eng.decode_step.lower(
+                weights, pages, i32(lanes, width), i32(lanes), i32(lanes),
+                i32(lanes)).compile()
+        return eng.prefill_step.lower(
+            weights, pages, i32(lanes, width), i32(lanes),
+            i32(lanes, DOC_CHUNK), i32(lanes)).compile()
+
+    return lower
+
+
+@pytest.mark.parametrize("step, lanes, width, kernels_in", [
+    ("decode", DOC_SLOTS, 128,
+     {"lightning_indexer", "sparse_paged_decode", "moe_grouped_ffn"}),
+    ("prefill", 4, 128,
+     {"lightning_indexer", "sparse_paged_prefill", "moe_grouped_ffn"}),
+    ("decode", DOC_SLOTS, 16, {"ragged_paged_decode", "moe_grouped_ffn"}),
+    ("prefill", 4, 2, {"ragged_paged_prefill", "moe_grouped_ffn"})],
+    ids=["decode-w128-selects", "prefill-4lanes-w128-selects",
+         "decode-w16-dense", "prefill-4lanes-w2-dense"])
+def test_sparse_family_steps_compile_and_keep_the_pools(
+        step, lanes, width, kernels_in, doc_steps):
+    """The family's decode block and prefill step compile for the chip
+    at the cell's geometry: grouped-query heads in the paged kernels, the
+    indexer, the sparse kernels past ``topk`` cached tokens (the dense
+    ones up to it), the grouped expert kernel at 128 experts x 768 x
+    2048. No step copies a K, V or indexer-key pool; each pool comes in
+    row-major; temporaries stay far under one K pool (the gathered rows
+    of 2048 selected tokens a slot are 134 MB of them)."""
+    import re
+    compiled = doc_steps(step, lanes, width)
+    text = compiled.as_text()
+    names = {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
+    assert names == kernels_in
+    pool = rf"bf16\[{DOC_PAGES},(?:{DOC_PS},512|64,{DOC_PS})\]"
+    copies = [line for line in text.splitlines()
+              if re.search(r"= " + pool + r"\S* copy\(", line)]
+    assert not copies, copies[:2]
+    entry = text[text.index("ENTRY "):]
+    layouts = set(re.findall(pool + r"(\{[\d,]+)[^ ]* parameter\(", entry))
+    assert layouts == {"{2,1,0"}, layouts
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20

@@ -35,6 +35,8 @@ def test_one_chip_phases_at_tiny_size(chip_smoke, capsys):
     assert "flash_attention[pallas_interpret]" in out
     assert "compiles after warmup=0" in out
     assert "token-exact vs model.generate" in out
+    assert "sparse family kernels vs lax" in out
+    assert "tokens are the float32 reference's argmax" in out
 
 
 def test_four_chip_phases_on_virtual_devices(chip_smoke, capsys):

@@ -548,3 +548,83 @@ class TestBenchArtifact:
                                      "ragged_paged_prefill"}
         for buckets in r["kernels"].values():
             assert len(buckets) == 3
+
+
+# ---------------------------------------------------------------------------
+# grouped-query heads in the paged kernels (query head h reads KV head
+# h // (H / KV)): the Pallas body against the lax form and a dense NumPy
+# reference that repeats nothing
+# ---------------------------------------------------------------------------
+
+def _gqa_sample(seed, chunked):
+    s, h, kv, dh, ps, mp = ((3, 8, 2, 16, 8, 4), (4, 4, 1, 32, 4, 6))[seed]
+    rng = np.random.default_rng(seed)
+    num_pages = s * mp + 1
+    kp = jnp.asarray(rng.standard_normal((num_pages, ps, kv * dh)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((num_pages, ps, kv * dh)),
+                     jnp.float32)
+    bt = jnp.asarray((rng.permutation(num_pages - 1)[:s * mp] + 1)
+                     .reshape(s, mp), jnp.int32)
+    if not chunked:
+        q = jnp.asarray(rng.standard_normal((s, h, dh)), jnp.float32)
+        lengths = jnp.asarray(rng.integers(0, mp * ps + 1, s), jnp.int32)
+        return (q, kp, vp, bt, lengths)
+    c = ps
+    q = jnp.asarray(rng.standard_normal((s, c, h, dh)), jnp.float32)
+    starts = jnp.asarray(rng.integers(0, (mp - 1) * ps, s), jnp.int32)
+    n_valid = jnp.asarray(rng.integers(0, c + 1, s), jnp.int32)
+    return (q, kp, vp, bt, starts, n_valid)
+
+
+def _gqa_reference(q, kp, vp, bt, *geometry):
+    q, kp, vp, bt = (np.asarray(a, np.float64) for a in (q, kp, vp, bt))
+    bt = bt.astype(int)
+    chunked = len(geometry) == 2
+    if not chunked:
+        q = q[:, None]                                  # (S, 1, H, Dh)
+    s, c, h, dh = q.shape
+    kv = kp.shape[-1] // dh
+    ps = kp.shape[1]
+    out = np.zeros_like(q)
+    for sl in range(s):
+        k = kp[bt[sl]].reshape(-1, kv, dh)
+        v = vp[bt[sl]].reshape(-1, kv, dh)
+        for r in range(c):
+            if chunked:
+                if r >= int(geometry[1][sl]):
+                    continue
+                limit = int(geometry[0][sl]) + r + 1
+            else:
+                limit = int(geometry[0][sl])
+            if limit == 0:
+                continue
+            for hh in range(h):
+                g = hh // (h // kv)
+                sc = k[:limit, g] @ q[sl, r, hh] / np.sqrt(dh)
+                p = np.exp(sc - sc.max())
+                out[sl, r, hh] = (p / p.sum()) @ v[:limit, g]
+    del ps
+    return out if chunked else out[:, 0]
+
+
+class TestGroupedQueryHeads:
+    @pytest.mark.parametrize("seed", [0, 1])
+    @pytest.mark.parametrize("name", ["ragged_paged_decode",
+                                      "ragged_paged_prefill"])
+    @pytest.mark.parametrize("impl", ["lax", "pallas_interpret"])
+    def test_against_dense_reference(self, name, seed, impl):
+        args = _gqa_sample(seed, chunked=name.endswith("prefill"))
+        want = _gqa_reference(*args)
+        got = np.asarray(kernels.dispatch(name, *args, impl=impl))
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("name", ["ragged_paged_decode",
+                                      "ragged_paged_prefill"])
+    def test_pages_per_block_bit_exact(self, name):
+        args = _gqa_sample(0, chunked=name.endswith("prefill"))
+        outs = [np.asarray(kernels.dispatch(
+            name, *args, impl="pallas_interpret",
+            block_sizes={"pages_per_block": pb})) for pb in (1, 2, 4)]
+        for o in outs[1:]:
+            np.testing.assert_array_equal(outs[0], o)
