@@ -22,16 +22,6 @@ import os
 import sys
 import time
 
-# the serving_tp bench shards over virtual CPU devices. Gate the flag on
-# that model: the other benches' committed numbers were measured on the
-# default single-device CPU topology, and a global 8-virtual-device
-# split would silently change what they run on
-if "serving_tp" in sys.argv and \
-        "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
-
 import jax
 import jax.numpy as jnp
 
@@ -2273,241 +2263,6 @@ KERNELS_SCHEMA = ("metric", "value", "unit", "vs_baseline", "kernels",
                   "committed_cache_stale", "device", "dryrun")
 
 
-def serving_tp_json_path(dryrun: bool) -> str:
-    import os
-    if dryrun:  # CI smoke must not dirty the checkout
-        return os.environ.get("PADDLE_TPU_BENCH_SERVING_TP",
-                              "/tmp/BENCH_SERVING_TP.json")
-    return os.environ.get(
-        "PADDLE_TPU_BENCH_SERVING_TP",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "BENCH_SERVING_TP.json"))
-
-
-def run_bench_serving_tp(dev, dryrun=False):
-    """Tensor-parallel paged decode scaling (ISSUE 15 acceptance) on a
-    simulated tp=1/2/4 mesh of virtual CPU devices.
-
-    Two legs per tp degree:
-
-    - **Correctness leg** — the REAL sharded engine (``mesh=`` over tp
-      devices, shard_map steps, per-shard page pools): greedy tokens
-      must be IDENTICAL to the tp=1 engine on the same workload, zero
-      recompiles after warmup, and the decode step's collective bytes
-      come from the static CostReport (one psum per layer at the
-      attention output — the allowlisted kind).
-    - **Busy-time leg** — per-chip decode tokens/s via the probe engine
-      (``tp_probe=True``: ONE shard's local computation on one device,
-      collectives elided). Shards are symmetric, so one shard's wall
-      time IS the per-chip critical path — the same honest accounting
-      BENCH_ROUTER uses (max over replicas ≙ any shard); the elided
-      collective payload is reported alongside from the CostReport so
-      the omission is visible. tokens/s(tp) = decode tokens / the probe
-      registry's ``serving_decode_step_seconds`` sum; best of 2 passes.
-
-    The model is attention-heavy on purpose (long live contexts, small
-    MLP): decode throughput at scale is bounded by per-chip KV
-    bandwidth, which is exactly the term tp divides. Emits
-    BENCH_SERVING_TP.json (schema self-validated; the >=1.6x tp=2 gate
-    is asserted non-dryrun) next to this file (dryrun: /tmp)."""
-    import numpy as np
-
-    from paddle_tpu import observability as obs
-    from paddle_tpu import serving
-    from paddle_tpu.models.gpt import GPT, GPTConfig
-
-    devs = jax.devices()
-    if len(devs) < 4:
-        raise RuntimeError(
-            "serving_tp bench needs >= 4 devices (CI runs it on the "
-            "virtual 8-device CPU mesh; set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
-    if dryrun:
-        cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
-                        num_heads=8, ffn_size=64, max_position=320,
-                        dropout=0.0, attn_impl="xla")
-        n_req, num_slots, page_size, chunk, cap = 6, 4, 16, 32, 16
-        len_set = (80, 144, 208)
-        max_tokens = 240
-    else:
-        cfg = GPTConfig(vocab_size=512, hidden_size=64, num_layers=3,
-                        num_heads=8, ffn_size=64, max_position=640,
-                        dropout=0.0, attn_impl="xla")
-        n_req, num_slots, page_size, chunk, cap = 16, 8, 16, 32, 32
-        len_set = (96, 160, 224, 320, 448)
-        max_tokens = 480
-    model = GPT(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    lens = rng.choice(len_set, n_req)
-    prompts = [rng.integers(1, cfg.vocab_size, int(n)).astype(np.int32)
-               for n in lens]
-
-    # anatomy probe cadence for the REAL sharded engines: every Nth
-    # decode round replays on the collective-elided probe jit, so the
-    # bench reports MEASURED exposed-collective time (not just the
-    # CostReport's static payload)
-    probe_every = 2 if dryrun else 8
-
-    def make_engine(tp, probe=False):
-        reg = obs.MetricsRegistry()
-        eng = serving.ServingEngine(
-            model, params, num_slots=num_slots, page_size=page_size,
-            max_tokens_per_slot=max_tokens, prefill_chunk=chunk,
-            attn_impl="lax", registry=reg,
-            **({} if tp == 1 else
-               {"tp": tp, "tp_probe": True} if probe else
-               {"tp": tp, "anatomy_probe_every": probe_every}))
-        eng.warmup(cost_gauges=False)
-        return eng, reg
-
-    def run_pass(eng):
-        # eos=None: fixed work per request, so every tp degree (and
-        # every probe) executes the identical step schedule
-        return [np.asarray(t) for t in
-                eng.generate_many(prompts, cap, eos_id=None)]
-
-    def decode_busy(reg):
-        return float(reg.histogram(
-            "serving_decode_step_seconds").summary()["sum"])
-
-    def decode_collective_bytes(eng):
-        from paddle_tpu.analysis import cost_model
-        c = eng.cache.config
-        s_tot = eng.scheduler.num_slots
-        w = eng._pow2_width(c.max_pages_per_slot)
-        zeros = jnp.zeros((s_tot,), jnp.int32)
-        args = (eng._step_params, eng.cache.pages,
-                jnp.zeros((s_tot, w), jnp.int32), zeros, zeros, zeros)
-        abstract = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
-        cost = cost_model.estimate_cost(eng.decode_step, *abstract,
-                                        name=f"decode_tp{eng.tp}")
-        # the psum sits inside the decode fori_loop BODY, so the
-        # CostReport counts it once per loop iteration = once per
-        # decode token per slot
-        return int(cost.summary()["collective_bytes"])
-
-    # Each engine's WHOLE lifecycle runs contiguously: the compile
-    # listener is process-wide, so another engine's warmup compiles
-    # would land in this engine's next recompile check otherwise.
-    t_bench0 = time.perf_counter()
-    decode_tokens = float(n_req * cap)
-
-    def busy_leg(eng, reg):
-        """Best-of-2 per-chip decode tokens/s (histogram-sum delta)."""
-        best = 0.0
-        for _ in range(2):
-            before = decode_busy(reg)
-            run_pass(eng)
-            best = max(best, decode_tokens
-                       / max(decode_busy(reg) - before, 1e-9))
-        return round(best, 2)
-
-    # --- tp=1: the baseline tokens AND the tp=1 busy time
-    base_eng, base_reg = make_engine(1)
-    baseline = run_pass(base_eng)
-    tokps = {"1": busy_leg(base_eng, base_reg)}
-    if base_eng.recompile_detector.recompiles:
-        raise RuntimeError("tp=1 engine recompiled after warmup")
-    tp_info = {"1": {
-        "greedy_identical": True, "recompiles": 0,
-        "collective_bytes_per_decode_body": 0,
-        "collective_bytes_per_token": 0.0, "mesh_devices": 1,
-    }}
-
-    for tp in (2, 4):
-        # correctness leg: the REAL sharded engine
-        eng, _reg = make_engine(tp)
-        outs = run_pass(eng)
-        if not all(np.array_equal(a, b) for a, b in zip(baseline, outs)):
-            raise RuntimeError(
-                f"tp={tp} greedy tokens diverged from the tp=1 engine")
-        cbytes = decode_collective_bytes(eng)    # lowering only
-        if eng.recompile_detector.recompiles:
-            raise RuntimeError(f"tp={tp} engine recompiled in steady "
-                               "state after warmup")
-        # step anatomy (ISSUE 16): measured collective-exposed time per
-        # decode step (real wall minus the collective-elided probe's
-        # wall, sampled), host-gap fraction, and the headroom plane
-        asum = eng.anatomy.summary()
-        health = eng.health()
-        tp_info[str(tp)] = {
-            "greedy_identical": True,
-            "recompiles": eng.recompile_detector.recompiles,
-            "collective_bytes_per_decode_body": cbytes,
-            "collective_bytes_per_token": round(cbytes / num_slots, 1),
-            "mesh_devices": health["mesh_devices"],
-            "collective_exposed_s": round(
-                float(asum.get("collective_exposed_s", 0.0)), 6),
-            "collective_exposed_frac": round(
-                float(asum.get("collective_exposed_frac", 0.0)), 4),
-            "probe_samples": int(asum.get("probe_samples", 0)),
-            "host_gap_frac": round(float(asum["host_gap_frac"]), 4),
-            "headroom": health["headroom"],
-        }
-        del eng
-        # busy-time leg: the per-chip probe
-        peng, preg = make_engine(tp, probe=True)
-        tokps[str(tp)] = busy_leg(peng, preg)
-        if peng.recompile_detector.recompiles:
-            raise RuntimeError(
-                f"tp={tp} probe engine recompiled after warmup")
-        del peng
-    scaling_2x = tokps["2"] / max(tokps["1"], 1e-9)
-    scaling_4x = tokps["4"] / max(tokps["1"], 1e-9)
-    if not dryrun and scaling_2x < 1.6:
-        raise RuntimeError(
-            f"tp=2 decode scaling {scaling_2x:.2f}x < the 1.6x "
-            "acceptance floor")
-
-    result = {
-        "metric": "serving_tp_decode_scaling_2x",
-        "value": round(scaling_2x, 3),
-        "unit": "x vs tp=1 (busy-time accounting)",
-        "vs_baseline": round(scaling_2x / 1.6, 3),
-        "decode_tokens_per_s": tokps,
-        "scaling_2x": round(scaling_2x, 3),
-        "scaling_4x": round(scaling_4x, 3),
-        "tp": tp_info,
-        "greedy_identical_all_tp": True,
-        "recompiles_after_warmup": 0,
-        "requests": n_req,
-        "decode_cap": cap,
-        "prompt_lens": sorted(set(int(n) for n in lens)),
-        "model": {"hidden": cfg.hidden_size, "heads": cfg.num_heads,
-                  "layers": cfg.num_layers, "ffn": cfg.ffn_size,
-                  "vocab": cfg.vocab_size},
-        "bench_wall_s": round(time.perf_counter() - t_bench0, 1),
-        "device": str(dev.device_kind if hasattr(dev, "device_kind")
-                      else dev.platform),
-        "dryrun": bool(dryrun),
-    }
-    # schema self-check before the file lands
-    for k in ("decode_tokens_per_s", "scaling_2x", "scaling_4x", "tp",
-              "greedy_identical_all_tp", "recompiles_after_warmup"):
-        assert k in result, f"BENCH_SERVING_TP missing {k}"
-    assert set(result["decode_tokens_per_s"]) == {"1", "2", "4"}
-    for tp, info in result["tp"].items():
-        assert info["recompiles"] == 0, (tp, info)
-        assert info["greedy_identical"] is True
-    assert result["tp"]["2"]["collective_bytes_per_decode_body"] > 0, \
-        "tp=2 step lowered no collective — the psum is missing"
-    for tp in ("2", "4"):
-        info = result["tp"][tp]
-        assert info["probe_samples"] >= 1, \
-            f"tp={tp} anatomy probe never sampled a decode round"
-        assert info["collective_exposed_s"] >= 0.0, (tp, info)
-        assert 0.0 <= info["host_gap_frac"] <= 1.0, (tp, info)
-        assert set(info["headroom"]) >= {"flops", "pages", "slots",
-                                         "hbm"}, (tp, info)
-    path = serving_tp_json_path(dryrun)
-    with open(path, "w") as f:
-        json.dump(result, f, indent=1)
-        f.write("\n")
-    return result
-
-
 def kernels_json_path(dryrun: bool) -> str:
     import os
     if dryrun:  # CI smoke must not dirty the checkout
@@ -3178,8 +2933,6 @@ _BENCHES = {
                "tokens/s"),
     "kernels": (run_bench_kernels, "kernels_autotune_speedup_geomean",
                 "x vs default blocks"),
-    "serving_tp": (run_bench_serving_tp, "serving_tp_decode_scaling_2x",
-                   "x vs tp=1 (busy-time accounting)"),
     "net_router": (run_bench_net_router, "net_router_tokens_per_sec",
                    "tokens/s"),
     "disagg": (run_bench_disagg, "serving_disagg_ttft_p99_improvement",
@@ -3206,7 +2959,7 @@ def main() -> int:
     enable_compile_cache()
     obs.install_compile_listener()  # compiles_cum covers the warmup
     if which in ("serving", "embedding_serving", "router", "kernels",
-                 "serving_tp", "net_router", "disagg", "prefix_fleet"):
+                 "net_router", "disagg", "prefix_fleet"):
         # CI smoke: tiny sizes + schema self-check
         result = _BENCHES[which][0](dev, dryrun="--dryrun" in sys.argv)
     else:

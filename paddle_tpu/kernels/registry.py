@@ -64,12 +64,6 @@ class KernelContract:
     #: display/reference only.
     block_candidates: Mapping[str, Tuple[int, ...]] = \
         dataclasses.field(default_factory=dict)
-    #: collective kinds the kernel's lowering may emit (mesh kernels —
-    #: e.g. the tp-sharded serving wrappers declare ("all_reduce",),
-    #: the one attention-output collective). The kernel-contract lint
-    #: lowers the donation probe and asserts EXACTLY these kinds
-    #: appear; () keeps the single-device zero-collective contract.
-    collectives: Tuple[str, ...] = ()
     #: parity-battery tolerances (pallas-interpret vs lax vs reference)
     atol: float = 1e-5
     rtol: float = 1e-5
@@ -109,10 +103,9 @@ class KernelSpec:
     #: the static prior picks the largest candidate that fits budget
     vmem_estimate: Optional[Callable[..., int]] = None
     #: optional ``() -> (fn, args, donate_argnums)`` probe lowered by the
-    #: lint rule to verify the donation contract in real HLO (and, for
-    #: mesh kernels, that exactly the contract's declared ``collectives``
-    #: lower). A mesh kernel's probe may return None when the box cannot
-    #: host the mesh (single-device CI) — the check is skipped, not failed
+    #: lint rule to verify the donation contract in real HLO. A probe
+    #: may return None when the box cannot host it — the check is
+    #: skipped, not failed
     donation_probe: Optional[Callable[[], Optional[Tuple[
         Callable, tuple, Tuple[int, ...]]]]] = None
     #: extra ``seed -> (args, kwargs) | None`` sample factories the

@@ -114,11 +114,11 @@ class GPT(Layer):
                                      for _ in range(cfg.num_layers)])
         self.ln_f = LayerNorm(cfg.hidden_size)
 
-    def serving(self, *, tp: int = 1, spmd: bool = False,
+    def serving(self, *, tp: int = 1,
                 mlp_sharded: bool = False) -> "GPTServing":
         """This model's block as the paged serving engine runs it (see
         :mod:`paddle_tpu.serving.program`)."""
-        return GPTServing(self, tp=tp, spmd=spmd, mlp_sharded=mlp_sharded)
+        return GPTServing(self, tp=tp, mlp_sharded=mlp_sharded)
 
     def forward(self, params, ids, *, key=None, training=False):
         cfg = self.cfg
@@ -361,13 +361,12 @@ class GPTServing:
     own K and V, the word table as the output head.
 
     ``tp > 1``: the body is one head shard's — qkv from the head-major
-    TP slice of the projections (``ServingEngine._make_tp_params`` lays
-    them out), the row-sharded output projection closed by ONE psum a
-    layer; ``spmd=False`` is the probe engine (the same local math, the
-    collectives elided). ``mlp_sharded`` also splits the MLP the Megatron
-    way (the prefill tier), closed by the layer's second psum."""
+    TP slice of the projections (:meth:`tp_params` lays them out,
+    :meth:`tp_plan` shards them), the row-sharded output projection
+    closed by ONE psum a layer. ``mlp_sharded`` also splits the MLP the
+    Megatron way (the prefill tier), closed by the layer's second psum."""
 
-    def __init__(self, model: GPT, *, tp: int = 1, spmd: bool = False,
+    def __init__(self, model: GPT, *, tp: int = 1,
                  mlp_sharded: bool = False):
         from paddle_tpu.serving.program import ServingSpec
         cfg = model.cfg
@@ -376,7 +375,7 @@ class GPTServing:
                 "ServingEngine needs the LayerList GPT layout; convert "
                 "stacked/pipeline checkpoints for serving first")
         self.model = model
-        self.tp, self.spmd, self.mlp_sharded = tp, spmd, mlp_sharded
+        self.tp, self.mlp_sharded = tp, mlp_sharded
         self.spec = ServingSpec(
             num_layers=cfg.num_layers, num_heads=cfg.num_heads,
             kv_heads=cfg.num_heads,
@@ -385,6 +384,42 @@ class GPTServing:
 
     def param_dtype(self, params):
         return params["wte"]["weight"].dtype
+
+    def tp_params(self, params):
+        """Head-major TP re-layout of the attention projections: fused
+        qkv weight ``(D, 3D)`` -> ``(D, 3, H, Dh)`` (bias ``(3D,)`` ->
+        ``(3, H, Dh)``), out_proj weight ``(D, D)`` -> ``(H, Dh, D)``.
+        Sharding the RAW fused columns over tp would hand each shard a
+        slice straddling the q/k/v boundaries; head-major, the "tp"
+        shard boundary IS a head boundary — which is exactly what the
+        per-shard page pools need. Everything else passes through
+        untouched (replicated under ``serving_tp_plan``)."""
+        cfg = self.model.cfg
+        d, h = cfg.hidden_size, cfg.num_heads
+        dh = d // h
+        out = dict(params)
+        blocks = {}
+        for name, bp in params["blocks"].items():
+            bp = dict(bp)
+            qkv, op = bp["attn"]["qkv_proj"], bp["attn"]["out_proj"]
+            attn = {
+                "qkv_tp": {"weight": qkv["weight"].reshape(d, 3, h, dh)},
+                "out_tp": {"weight": op["weight"].reshape(h, dh, d)},
+            }
+            if "bias" in qkv:
+                attn["qkv_tp"]["bias"] = qkv["bias"].reshape(3, h, dh)
+            if "bias" in op:
+                attn["out_tp"]["bias"] = op["bias"]
+            bp["attn"] = attn
+            blocks[name] = bp
+        out["blocks"] = blocks
+        return out
+
+    def tp_plan(self):
+        """The sharding of :meth:`tp_params`' tree over the "tp" axis."""
+        from paddle_tpu.parallel import plan
+        return (plan.serving_prefill_tp_plan() if self.mlp_sharded
+                else plan.serving_tp_plan())
 
     def embed(self, params, tokens, positions):
         m = self.model
@@ -417,13 +452,10 @@ class GPTServing:
             return x + block.attn.proj_out(bp["attn"],
                                            att.transpose(0, 2, 1, 3))
         # row-sharded output projection + THE one attention-output
-        # collective: local heads (S,C,H/tp,Dh) -> (S,C,D) replicated;
-        # the probe engine elides the psum (one shard's partial sum is
-        # one chip's share of the work)
+        # collective: local heads (S,C,H/tp,Dh) -> (S,C,D) replicated
         ap = bp["attn"]
-        part = jnp.einsum("schk,hkd->scd", att, ap["out_tp"]["weight"])
-        if self.spmd:
-            part = jax.lax.psum(part, "tp")
+        part = jax.lax.psum(
+            jnp.einsum("schk,hkd->scd", att, ap["out_tp"]["weight"]), "tp")
         b = ap["out_tp"].get("bias")
         return x + (part + b if b is not None else part)
 

@@ -22,9 +22,8 @@ first-class telemetry):
    monitor over the latency histograms (``slo_burn_rate`` gauge,
    edge-triggered ``slo_alerts_total`` alerts into metrics AND trace).
 4. **Step anatomy + crash flight recorder** (`anatomy.py`, `flight.py`):
-   per-jitted-step wall-time decomposition (host gap, phase-split device
-   busy, host assembly, sampled collective-exposed time via the
-   ``tp_probe`` discipline) feeding histograms/gauges AND trace spans;
+   per-jitted-step wall-time decomposition (host gap, phase-split call
+   wall time, host assembly) feeding histograms/gauges AND trace spans;
    a bounded :class:`FlightRecorder` black box per replica that dumps
    schema-validated postmortem bundles (anatomy JSONL + Chrome trace +
    health trajectory) on eject / breaker-open / shed spikes, served

@@ -6,9 +6,9 @@ items 1/3/5 block on: per *step*, how much time is host gap between
 device steps, how much is call wall time (uploads, dispatch + sync of
 the jitted call, which holds the device's work but is read on the host's
 clock)
-split by phase (prefill / decode / draft / verify), how much is the
-host remainder, and how much of the call time is *collective-exposed*
-(the tp tax you could hide or shard away).
+split by phase (prefill / decode / draft / verify), and how much is the
+host remainder. (What a tp engine's collectives leave exposed is a
+device-trace reading, ``device.collective_exposed_pct``, not a host one.)
 
 :class:`StepAnatomy` is the host-side accumulator the engine drives
 around its fixed-shape calls — nothing here touches jitted code, so the
@@ -19,9 +19,6 @@ zero-steady-state-recompile invariant is untouched:
 - ``add_phase(phase, start, end)`` records one call interval, dispatch
   through sync (the engine's ``tracer.phase`` spans already hold these
   stamps — no extra clock reads on the hot path);
-- ``set_collective(real_s, probe_s)`` lands a sampled collectives-
-  elided probe measurement (the ``tp_probe`` discipline: same shapes,
-  psum elided, delta = exposed collective time);
 - ``end_step(tokens=...)`` closes the record, pushes it into a bounded
   ring, publishes registry histograms/gauges, and emits trace spans so
   one Perfetto export shows anatomy alongside ``serving.request``.
@@ -84,9 +81,7 @@ class StepAnatomy:
         # totals for summary() — cheap running sums, not ring-derived,
         # so the summary reflects the whole run even after ring wrap
         self._tot = {"steps": 0, "wall_s": 0.0, "gap_s": 0.0,
-                     "host_s": 0.0, "tokens": 0,
-                     "probe_samples": 0, "collective_exposed_s": 0.0,
-                     "probed_wall_s": 0.0}
+                     "host_s": 0.0, "tokens": 0}
         self._tot_phase: Dict[str, float] = {}
         r = self.registry
         self._h_wall = r.histogram(
@@ -98,23 +93,14 @@ class StepAnatomy:
         self._h_phase = r.histogram(
             "anatomy_phase_seconds",
             "call wall time (uploads + dispatch + sync) per step by phase")
-        self._h_coll = r.histogram(
-            "anatomy_collective_exposed_seconds",
-            "sampled exposed collective time per probed step")
         self._g_gap_frac = r.gauge(
             "anatomy_host_gap_frac",
             "fraction of timeline spent in host gaps between steps")
         self._g_host_frac = r.gauge(
             "anatomy_host_frac",
             "fraction of step wall spent in host assembly/data wait")
-        self._g_coll_frac = r.gauge(
-            "anatomy_collective_exposed_frac",
-            "exposed collective time / wall on probed steps")
         self._c_steps = r.counter(
             "anatomy_steps_total", "anatomy records closed").child()
-        self._c_probes = r.counter(
-            "anatomy_probe_samples_total",
-            "collective probe samples taken").child()
         self._phase_children: Dict[str, object] = {}
 
     def to_wall(self, t: float) -> float:
@@ -129,7 +115,7 @@ class StepAnatomy:
         self._step_seq = step_id + 1
         self._cur = {"step": int(step_id), "t0": t0,
                      "gap_s": max(gap, 0.0), "phases": {},
-                     "intervals": [], "collective": None}
+                     "intervals": []}
 
     def add_phase(self, phase: str, start: float, end: float) -> None:
         """Attribute one call interval, dispatch through sync (tracer-clock
@@ -148,15 +134,6 @@ class StepAnatomy:
         if self._cur is not None:
             self._cur = None
             self._last_end = self.now()
-
-    def set_collective(self, real_s: float, probe_s: float) -> None:
-        """Land a sampled collectives-elided probe: ``real_s`` is the
-        full spmd step, ``probe_s`` the same shapes with the psum
-        elided; the positive delta is the exposed collective time."""
-        cur = self._cur
-        if cur is None:
-            return
-        cur["collective"] = (float(real_s), float(probe_s))
 
     def end_step(self, tokens: int = 0) -> Optional[Dict[str, Any]]:
         cur = self._cur
@@ -179,11 +156,6 @@ class StepAnatomy:
             "phases": phases,
             "tokens": int(tokens),
         }
-        if cur["collective"] is not None:
-            real_s, probe_s = cur["collective"]
-            exposed = max(real_s - probe_s, 0.0)
-            rec["probe_wall_s"] = round(probe_s, 9)
-            rec["collective_exposed_s"] = round(exposed, 9)
         self._publish(rec, cur, t1)
         self._last_end = t1
         with self._lock:
@@ -215,21 +187,10 @@ class StepAnatomy:
             self._g_gap_frac.set(t["gap_s"] / timeline)
         if t["wall_s"] > 0:
             self._g_host_frac.set(t["host_s"] / t["wall_s"])
-        if "collective_exposed_s" in rec:
-            self._c_probes.inc()
-            self._h_coll.observe(rec["collective_exposed_s"])
-            t["probe_samples"] += 1
-            t["collective_exposed_s"] += rec["collective_exposed_s"]
-            t["probed_wall_s"] += wall
-            if t["probed_wall_s"] > 0:
-                self._g_coll_frac.set(
-                    t["collective_exposed_s"] / t["probed_wall_s"])
         tracer = self.tracer
         if tracer.enabled:
             attrs = {"step": rec["step"], "host_gap_s": rec["host_gap_s"],
                      "host_s": rec["host_s"], "tokens": rec["tokens"]}
-            if "collective_exposed_s" in rec:
-                attrs["collective_exposed_s"] = rec["collective_exposed_s"]
             sp = tracer.record_span("anatomy.step", start=cur["t0"],
                                     end=t1, **attrs)
             for phase, s0, s1 in cur["intervals"]:
@@ -254,13 +215,13 @@ class StepAnatomy:
             return len(self._ring)
 
     def summary(self) -> Dict[str, Any]:
-        """Whole-run aggregate (survives ring wrap): phase split,
-        host-gap fraction, and the sampled collective economics."""
+        """Whole-run aggregate (survives ring wrap): phase split and
+        host-gap fraction."""
         t = dict(self._tot)
         steps = t["steps"]
         wall = t["wall_s"]
         timeline = wall + t["gap_s"]
-        out: Dict[str, Any] = {
+        return {
             "steps": steps,
             "wall_s": wall,
             "tokens": t["tokens"],
@@ -269,15 +230,7 @@ class StepAnatomy:
             "phase_s": dict(self._tot_phase),
             "phase_frac": {p: (s / wall if wall else 0.0)
                            for p, s in self._tot_phase.items()},
-            "probe_samples": t["probe_samples"],
         }
-        if t["probe_samples"]:
-            out["collective_exposed_s"] = (
-                t["collective_exposed_s"] / t["probe_samples"])
-            out["collective_exposed_frac"] = (
-                t["collective_exposed_s"] / t["probed_wall_s"]
-                if t["probed_wall_s"] else 0.0)
-        return out
 
     def export_jsonl(self, path: str) -> int:
         """Append the ring to a JSONL file (one flushed line per record
@@ -329,10 +282,6 @@ def validate_anatomy_record(rec: Dict[str, Any], *, index: int = 0,
     if sum(phases.values()) > rec["wall_s"] + _EPS:
         fail(f"phase sum {sum(phases.values()):.9f} exceeds wall "
              f"{rec['wall_s']:.9f}")
-    if "collective_exposed_s" in rec:
-        v = rec["collective_exposed_s"]
-        if not isinstance(v, (int, float)) or isinstance(v, bool) or v < 0:
-            fail(f"bad collective_exposed_s {v!r}")
     tok = rec.get("tokens", 0)
     if not isinstance(tok, int) or isinstance(tok, bool) or tok < 0:
         fail(f"bad tokens {tok!r}")

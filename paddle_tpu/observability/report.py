@@ -84,8 +84,8 @@ def report(reg: Optional[_registry.MetricsRegistry] = None,
 
 def _anatomy_lines(reg: _registry.MetricsRegistry) -> List[str]:
     """Step-anatomy digest, when a StepAnatomy fed this registry: the
-    per-phase call-wall-time split, host-gap/host fractions, sampled
-    collective-exposed time, and the resource-headroom snapshot."""
+    per-phase call-wall-time split, host-gap/host fractions, and the
+    resource-headroom snapshot."""
     out: List[str] = []
     phase_h = reg.get("anatomy_phase_seconds")
     if isinstance(phase_h, Histogram):
@@ -101,18 +101,10 @@ def _anatomy_lines(reg: _registry.MetricsRegistry) -> List[str]:
                                                 key=lambda kv: -kv[1]))
             out.append(f"phase_split {split} (busy={busy:.4g}s)")
     for gname, label in (("anatomy_host_gap_frac", "host_gap_frac"),
-                         ("anatomy_host_frac", "host_frac"),
-                         ("anatomy_collective_exposed_frac",
-                          "collective_exposed_frac")):
+                         ("anatomy_host_frac", "host_frac")):
         g = reg.get(gname)
         if isinstance(g, Gauge) and g.labels_seen():
             out.append(f"{label} {g.value():.4g}")
-    coll = reg.get("anatomy_collective_exposed_seconds")
-    if isinstance(coll, Histogram):
-        s = coll.summary()
-        if s["count"]:
-            out.append(f"collective_exposed mean={s['mean']:.6g}s "
-                       f"samples={s['count']}")
     head = reg.get("serving_headroom")
     if isinstance(head, Gauge):
         parts = []

@@ -38,13 +38,8 @@ from paddle_tpu.serving.paged_cache import (PagedCacheConfig, PagedKVCache,
                                             prompt_prefix_digests,
                                             quantize_kv)
 from paddle_tpu.serving.decode_attention import (
-    paged_prefill_attention, ragged_paged_decode_attention,
-    ragged_paged_decode_int8_attention,
-    ragged_paged_decode_int8_tp_attention,
-    ragged_paged_decode_tp_attention, ragged_paged_prefill_attention,
-    ragged_paged_prefill_int8_attention,
-    ragged_paged_prefill_int8_tp_attention,
-    ragged_paged_prefill_tp_attention)
+    ragged_paged_decode_attention, ragged_paged_decode_int8_attention,
+    ragged_paged_prefill_attention, ragged_paged_prefill_int8_attention)
 from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                           LoadShedError, Reject, Request,
                                           SLOScheduler, SlotState)
@@ -53,15 +48,11 @@ from paddle_tpu.serving import fleet
 
 __all__ = [
     "PagedCacheConfig", "PagedKVCache", "PageOverflowError",
-    "paged_prefill_attention", "ragged_paged_decode_attention",
+    "ragged_paged_decode_attention",
     "ragged_paged_decode_int8_attention",
-    "ragged_paged_decode_int8_tp_attention",
-    "ragged_paged_decode_tp_attention",
     "ragged_paged_prefill_attention",
     "ragged_paged_prefill_int8_attention",
-    "ragged_paged_prefill_int8_tp_attention",
-    "ragged_paged_prefill_tp_attention", "prompt_prefix_digests",
-    "quantize_kv",
+    "prompt_prefix_digests", "quantize_kv",
     "ContinuousBatchingScheduler", "SLOScheduler", "LoadShedError",
     "Reject", "Request", "SlotState",
     "ServingEngine", "SlotMigrationError", "fleet",

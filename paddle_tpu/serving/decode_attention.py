@@ -78,19 +78,14 @@ identical scale-after-dot numerics, independent dense references,
 contracts with donation-safe pages AND scales, and the shared
 ``pages_per_block`` tunable.
 
-**Tensor-parallel variants** (ISSUE 15):
-``ragged_paged_{decode,prefill}[_int8]_tp_attention`` run the
-single-device kernels per head shard under ``shard_map`` — pages and
-queries sharded ``H/tp`` over the mesh's "tp" axis (head-major folding
-keeps a shard's heads contiguous: each holds ``(P, ps, (H/tp)*Dh)``),
-block-table
-geometry (and int8 scale rows) replicated. Heads are independent, so
-each shard's output is BIT-identical to the tp=1 kernel; the one
-attention-output collective lives at the caller's row-sharded output
-projection, not in the kernel. Registered as mesh contracts
-(``requires_mesh``) with their own parity battery and engine-shaped
-donation probes that the kernel-contract lint lowers to verify
-per-shard aliasing AND the declared ``("all_reduce",)`` collective set.
+**Tensor parallel** (ISSUE 15): there is no tp kernel. The engine
+shards its whole step over the mesh's "tp" axis and each shard calls
+these kernels on its own heads: the pool's lanes are head-major, so a
+shard's ``(P, ps, (H/tp)*Dh)`` slice is its own whole heads, and heads
+are independent, so a head shard through the plain kernel equals those
+heads of the whole call (``tests/test_kernels.py``,
+``TestHeadShardsAreIndependent``). The one attention-output collective
+lives at the model's row-sharded output projection.
 """
 
 from __future__ import annotations
@@ -916,103 +911,6 @@ def ragged_paged_prefill_int8_attention(q, k_pages, v_pages, k_scales,
                             chunk_starts, n_valid, impl=impl, scale=scale)
 
 
-def ragged_paged_decode_tp_attention(q, k_pages, v_pages, block_tables,
-                                     lengths, *,
-                                     scale: Optional[float] = None,
-                                     impl: str = "auto", mesh=None):
-    """Tensor-parallel ragged paged decode (ISSUE 15): same contract as
-    :func:`ragged_paged_decode_attention` with ``q`` (S, H, Dh) and the
-    page pool sharded ``H/tp`` over the mesh's "tp" axis (the folded
-    ``H*Dh`` axis cut into ``tp`` runs of whole heads), block tables
-    and lengths replicated. Runs the single-device kernel per head
-    shard under ``shard_map`` — heads are independent, so the sharded
-    output is BIT-identical to the tp=1 kernel on the same pages; the
-    attention-output collective lives at the caller's row-sharded
-    output projection, not here. Returns (S, H, Dh) sharded like
-    ``q``. Must run under a mesh (``mesh_context`` or ``mesh=``)."""
-    from paddle_tpu import kernels
-    return kernels.dispatch("ragged_paged_decode_tp", q, k_pages,
-                            v_pages, block_tables, lengths, impl=impl,
-                            scale=scale, mesh=mesh)
-
-
-def ragged_paged_prefill_tp_attention(q, k_pages, v_pages, block_tables,
-                                      chunk_starts, n_valid, *,
-                                      scale: Optional[float] = None,
-                                      impl: str = "auto", mesh=None):
-    """Tensor-parallel batched chunked prefill — the tp twin of
-    :func:`ragged_paged_prefill_attention` (``q`` (S, C, H, Dh) and the
-    pages sharded ``H/tp``, chunk geometry replicated). Same
-    head-independence argument as the decode variant: bit-identical to
-    tp=1 per head shard, zero collectives inside the kernel."""
-    from paddle_tpu import kernels
-    return kernels.dispatch("ragged_paged_prefill_tp", q, k_pages,
-                            v_pages, block_tables, chunk_starts, n_valid,
-                            impl=impl, scale=scale, mesh=mesh)
-
-
-def ragged_paged_decode_int8_tp_attention(q, k_pages, v_pages, k_scales,
-                                          v_scales, block_tables,
-                                          lengths, *,
-                                          scale: Optional[float] = None,
-                                          impl: str = "auto", mesh=None):
-    """Tensor-parallel dequant-attend decode: int8 pages sharded
-    ``H/tp``, per-token-row fp32 scales REPLICATED (a token's scale is
-    computed over all heads — see ``quantize_kv``'s ``psum_axis`` — so
-    every shard dequantizes its head slice with the same row)."""
-    from paddle_tpu import kernels
-    return kernels.dispatch("ragged_paged_decode_int8_tp", q, k_pages,
-                            v_pages, k_scales, v_scales, block_tables,
-                            lengths, impl=impl, scale=scale, mesh=mesh)
-
-
-def ragged_paged_prefill_int8_tp_attention(q, k_pages, v_pages, k_scales,
-                                           v_scales, block_tables,
-                                           chunk_starts, n_valid, *,
-                                           scale: Optional[float] = None,
-                                           impl: str = "auto",
-                                           mesh=None):
-    """Tensor-parallel dequant-attend batched chunked prefill (the int8
-    twin of :func:`ragged_paged_prefill_tp_attention`)."""
-    from paddle_tpu import kernels
-    return kernels.dispatch("ragged_paged_prefill_int8_tp", q, k_pages,
-                            v_pages, k_scales, v_scales, block_tables,
-                            chunk_starts, n_valid, impl=impl, scale=scale,
-                            mesh=mesh)
-
-
-def paged_prefill_attention(q, k_pages, v_pages, block_table_row,
-                            positions, *, scale: Optional[float] = None):
-    """Chunked-prefill attention for ONE slot.
-
-    ``q`` (C, H, Dh) — a chunk of query tokens at absolute ``positions``
-    (C,) int32; keys/values are read from the slot's pages via
-    ``block_table_row`` (max_pages,). Each query attends causally to all
-    cache positions ``<= positions[c]`` (earlier chunks + the causal
-    prefix of this chunk, whose K/V the caller has already written).
-    Padded queries (positions past the chunk's valid length) produce
-    garbage rows the caller discards. XLA-composed: prefill is a few
-    calls per request, the per-step hot path is the decode kernel.
-    """
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    mp = block_table_row.shape[0]
-    ps = k_pages.shape[1]
-    h, dh = q.shape[1], q.shape[2]
-    k = k_pages[block_table_row].reshape(mp * ps, h, dh)
-    v = v_pages[block_table_row].reshape(mp * ps, h, dh)
-    scores = jnp.einsum("chd,thd->hct", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    tok = jnp.arange(mp * ps, dtype=jnp.int32)
-    causal = tok[None, None, :] <= positions[None, :, None]
-    scores = jnp.where(causal, scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    alive = jnp.max(scores, axis=-1, keepdims=True) > NEG_INF / 2
-    p = jnp.where(alive, p, 0.0)
-    out = jnp.einsum("hct,thd->chd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
 # ---------------------------------------------------------------------------
 # kernel-registry entries (paddle_tpu.kernels)
 # ---------------------------------------------------------------------------
@@ -1375,6 +1273,24 @@ def _prefill_int8_donation_probe():
     return step, args, (0, 1, 2, 3)
 
 
+def _tp_local_sample(seed, *, tp, chunked, quantized=False):
+    """The fp/int8 sample with its head axis cut to ONE tp shard's
+    slice — the per-shard shapes a tp engine's sharded step dispatches
+    the kernel at. ``--seed`` tunes these buckets so a tp mesh resolves
+    from the committed manifest instead of a cold prior. None when this
+    seed's head count is not divisible by ``tp``."""
+    maker = _make_paged_int8_sample if quantized else _make_paged_sample
+    args, kwargs = maker(seed, chunked=chunked)
+    q, k_pages, v_pages = args[0], args[1], args[2]
+    h, dh = q.shape[-2:]
+    if h % tp:
+        return None
+    hl = h // tp
+    q = q[:, :, :hl] if q.ndim == 4 else q[:, :hl]
+    return (q, k_pages[:, :, :hl * dh],
+            v_pages[:, :, :hl * dh]) + args[3:], kwargs
+
+
 def _register_paged_kernels():
     from paddle_tpu import kernels
     pb_candidates = {"pages_per_block": (1, 2, 4)}
@@ -1407,8 +1323,7 @@ def _register_paged_kernels():
         tune_signature=_paged_tune_signature,
         vmem_estimate=_paged_vmem_estimate,
         donation_probe=_decode_donation_probe,
-        # per-shard (H/tp) buckets the tp wrappers dispatch this kernel
-        # at — lambdas so the late-defined helper resolves at call time
+        # per-shard (H/tp) buckets a tp engine dispatches this kernel at
         tune_sample_variants=(
             lambda s: _tp_local_sample(s, tp=2, chunked=False),
             lambda s: _tp_local_sample(s, tp=4, chunked=False))))
@@ -1513,303 +1428,3 @@ def _register_paged_kernels():
 
 
 _register_paged_kernels()
-
-
-# ---------------------------------------------------------------------------
-# tensor-parallel wrappers (ISSUE 15): heads sharded H/tp over "tp"
-# ---------------------------------------------------------------------------
-
-from jax.sharding import PartitionSpec as _P  # noqa: E402
-
-#: the canonical tp specs: pages/queries sharded on the HEAD axis (the
-#: pool's folded H*Dh axis is head-major, so a shard's slice is its own
-#: whole heads), block-table geometry (and int8 scale rows) replicated
-_TP_KV_SPEC = _P(None, None, "tp")                # (P, ps, H*Dh)
-_TP_Q_DECODE = _P(None, "tp", None)               # (S, H, Dh)
-_TP_Q_PREFILL = _P(None, None, "tp", None)        # (S, C, H, Dh)
-
-
-def _tp_mesh(mesh):
-    from paddle_tpu.core import mesh as mesh_lib
-    mesh = mesh or mesh_lib.current_mesh()
-    if mesh is None:
-        raise ValueError("tp paged attention requires a mesh "
-                         "(use mesh_context or pass mesh=)")
-    return mesh
-
-
-def _tp_run(inner_name, args, specs, *, inner_impl, block_sizes,
-            scale, mesh):
-    """Run the single-device kernel ``inner_name`` per head shard under
-    shard_map. The inner dispatch resolves its block sizes from the
-    shared autotuner at the LOCAL (H/tp) shapes — trace-time host code,
-    so the tp wrappers stay recompile-safe; ``--seed`` keeps the
-    committed manifest covering those buckets (tune_sample_variants)."""
-    mesh = _tp_mesh(mesh)
-    from paddle_tpu.core.compat import shard_map
-
-    def body(*local):
-        from paddle_tpu import kernels
-        return kernels.dispatch(inner_name, *local, impl=inner_impl,
-                                block_sizes=block_sizes or None,
-                                scale=scale)
-
-    out_spec = specs[0]       # output sharded like q
-    return shard_map(body, mesh=mesh, in_specs=specs,
-                     out_specs=out_spec, check_vma=False)(*args)
-
-
-def _make_tp_fns(inner_name, specs):
-    """(pallas_fn, lax_fn) pair for one tp wrapper spec."""
-    def pallas_fn(*args, block_sizes, interpret, scale=None, mesh=None):
-        if scale is None:
-            scale = 1.0 / math.sqrt(args[0].shape[-1])
-        impl = "pallas_interpret" if interpret else "pallas"
-        return _tp_run(inner_name, args, specs, inner_impl=impl,
-                       block_sizes=block_sizes, scale=scale, mesh=mesh)
-
-    def lax_fn(*args, scale=None, mesh=None):
-        if scale is None:
-            scale = 1.0 / math.sqrt(args[0].shape[-1])
-        return _tp_run(inner_name, args, specs, inner_impl="lax",
-                       block_sizes=None, scale=scale, mesh=mesh)
-
-    return pallas_fn, lax_fn
-
-
-def _tp_parity_mesh():
-    """Largest dp×(tp=2) mesh covering every device — tp=2 divides all
-    sample head counts; None when the box cannot host one."""
-    n = len(jax.devices())
-    if n < 2 or n % 2:
-        return None
-    from paddle_tpu.core.mesh import MeshConfig, make_mesh
-    return make_mesh(MeshConfig(dp=n // 2, tp=2))
-
-
-def _make_tp_parity_fn(name, inner_name, sample_fn, reference_fn,
-                       quantized=False):
-    """Mesh-orchestrated battery for one tp wrapper: lax and
-    pallas-interpret through the sharded dispatch vs the dense
-    reference, PLUS the bit-equality pin — the tp lax path must equal
-    the single-device lax kernel exactly (heads are independent). The
-    int8 variants pin to 1e-6 instead: XLA's codegen for the fused
-    cast-dequant dot reassociates differently at different head counts,
-    so the per-shard dequant einsum can drift a last ulp from the
-    full-head one (the engine-level acceptance — greedy tokens
-    identical to tp=1 — is pinned exactly in tests/test_serving_tp.py
-    and the serving_tp bench)."""
-    def parity(seed):
-        import numpy as np
-        mesh = _tp_parity_mesh()
-        if mesh is None:
-            return {}
-        args, kwargs = sample_fn(seed)
-        from paddle_tpu import kernels
-        contract = kernels.get(name).contract
-        ref = np.asarray(reference_fn(*args, **kwargs), np.float32)
-        from paddle_tpu.core.mesh import mesh_context
-        errs = {}
-        with mesh_context(mesh):
-            for impl in ("lax", "pallas_interpret"):
-                out = np.asarray(jax.jit(
-                    lambda *a, _i=impl: kernels.dispatch(
-                        name, *a, impl=_i, mesh=mesh, **kwargs))(*args),
-                    np.float32)
-                np.testing.assert_allclose(
-                    out, ref, atol=contract.atol, rtol=contract.rtol,
-                    err_msg=f"{name}[{impl}] diverged from the dense "
-                            "reference")
-                errs[impl] = float(np.max(np.abs(out - ref)))
-            tp_lax = np.asarray(jax.jit(
-                lambda *a: kernels.dispatch(
-                    name, *a, impl="lax", mesh=mesh, **kwargs))(*args))
-            tp1 = np.asarray(kernels.dispatch(inner_name, *args,
-                                              impl="lax", **kwargs))
-            if quantized:
-                np.testing.assert_allclose(
-                    tp_lax, tp1, rtol=1e-6, atol=1e-6,
-                    err_msg=f"{name} tp output drifted from the "
-                            f"single-device {inner_name} kernel")
-            else:
-                np.testing.assert_array_equal(
-                    tp_lax, tp1,
-                    err_msg=f"{name} tp output is not bit-identical to "
-                            f"the single-device {inner_name} kernel")
-        return errs
-    return parity
-
-
-def _tp_probe_mesh():
-    devs = jax.devices()
-    if len(devs) < 2:
-        return None
-    from paddle_tpu.core.mesh import MeshConfig, make_mesh
-    return make_mesh(MeshConfig(tp=2), devices=devs[:2])
-
-
-def _tp_local_sample(seed, *, tp, chunked, quantized=False):
-    """The fp/int8 sample with its head axis cut to ONE tp shard's
-    slice — the per-shard shapes the tp wrappers dispatch the inner
-    kernel at. ``--seed`` tunes these buckets so a tp mesh resolves
-    from the committed manifest instead of a cold prior. None when this
-    seed's head count is not divisible by ``tp``."""
-    maker = _make_paged_int8_sample if quantized else _make_paged_sample
-    args, kwargs = maker(seed, chunked=chunked)
-    q, k_pages, v_pages = args[0], args[1], args[2]
-    h, dh = q.shape[-2:]
-    if h % tp:
-        return None
-    hl = h // tp
-    q = q[:, :, :hl] if q.ndim == 4 else q[:, :hl]
-    return (q, k_pages[:, :, :hl * dh],
-            v_pages[:, :, :hl * dh]) + args[3:], kwargs
-
-
-def _tp_donation_probe(*, chunked, quantized):
-    """Engine-shaped donation probe for one tp wrapper: write this
-    step's K/V into the PER-SHARD pages (quantized: int8 rows + the
-    replicated scale rows, with the pmax-completed global scale), attend
-    through the sharded kernel, then the row-sharded output projection
-    with THE one attention-output psum — and hand every pool buffer
-    back. Lowered by the kernel-contract lint: per-shard aliasing
-    (``jax.buffer_donor`` under SPMD) and exactly the contract's
-    ``("all_reduce",)`` collective kind. None when the box cannot host
-    a tp=2 mesh."""
-    mesh = _tp_probe_mesh()
-    if mesh is None:
-        return None
-    from paddle_tpu.core.compat import shard_map
-    if quantized:
-        (q, kp, vp, ks, vs, *rest), _ = _make_paged_int8_sample(
-            0, chunked=chunked)
-    else:
-        (q, kp, vp, *rest), _ = _make_paged_sample(0, chunked=chunked)
-    h, dh = q.shape[-2:]
-    d_model = h * dh
-    wo = jnp.zeros((h, dh, d_model), jnp.float32)
-    inner = ("ragged_paged_prefill" if chunked else "ragged_paged_decode")
-    inner += "_int8" if quantized else ""
-    q_spec = _TP_Q_PREFILL if chunked else _TP_Q_DECODE
-    geo_specs = tuple(_P() for _ in rest)
-
-    if quantized:
-        def local(kp, vp, ks, vs, q, wo, *geo):
-            from paddle_tpu import kernels
-            from paddle_tpu.serving.paged_cache import quantize_kv
-            tok = (q[:1, 0] if chunked else q[:1]).reshape(1, -1)
-            kq, ksc = quantize_kv(tok, (1,), psum_axis="tp")
-            kp = kp.at[1, 0].set(kq[0])
-            vp = vp.at[1, 0].set(kq[0])
-            ks = ks.at[1, 0].set(ksc[0])
-            vs = vs.at[1, 0].set(ksc[0])
-            att = kernels.dispatch(inner, q, kp, vp, ks, vs, *geo,
-                                   impl="lax")
-            part = (jnp.einsum("schk,hkd->scd", att, wo) if chunked
-                    else jnp.einsum("shk,hkd->sd", att, wo))
-            out = jax.lax.psum(part, "tp")
-            return out, kp, vp, ks, vs
-
-        fn = shard_map(
-            local, mesh=mesh,
-            in_specs=(_TP_KV_SPEC, _TP_KV_SPEC, _P(), _P(), q_spec,
-                      _P("tp", None, None)) + geo_specs,
-            out_specs=(_P(), _TP_KV_SPEC, _TP_KV_SPEC, _P(), _P()),
-            check_vma=False)
-        arrs = (kp, vp, ks, vs, q, wo) + tuple(rest)
-        donate = (0, 1, 2, 3)
-    else:
-        def local(kp, vp, q, wo, *geo):
-            from paddle_tpu import kernels
-            tok = (q[0, 0] if chunked else q[0]).reshape(-1)
-            kp = kp.at[1, 0].set(tok)
-            vp = vp.at[1, 0].set(tok)
-            att = kernels.dispatch(inner, q, kp, vp, *geo, impl="lax")
-            part = (jnp.einsum("schk,hkd->scd", att, wo) if chunked
-                    else jnp.einsum("shk,hkd->sd", att, wo))
-            out = jax.lax.psum(part, "tp")
-            return out, kp, vp
-
-        fn = shard_map(
-            local, mesh=mesh,
-            in_specs=(_TP_KV_SPEC, _TP_KV_SPEC, q_spec,
-                      _P("tp", None, None)) + geo_specs,
-            out_specs=(_P(), _TP_KV_SPEC, _TP_KV_SPEC),
-            check_vma=False)
-        arrs = (kp, vp, q, wo) + tuple(rest)
-        donate = (0, 1)
-    args = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrs)
-    return fn, args, donate
-
-
-def _register_tp_kernels():
-    from paddle_tpu import kernels
-    grid = "shard_map over tp: inner kernel per H/tp head shard; the " \
-           "attention-output collective lives at the caller's " \
-           "row-sharded projection"
-    defs = (
-        ("ragged_paged_decode_tp", "ragged_paged_decode", False, False,
-         {"q": "(S,H,Dh) H/tp", "k_pages": "(P,ps,H*Dh) H/tp",
-          "v_pages": "(P,ps,H*Dh) H/tp", "block_tables": "(S,mp) i32",
-          "lengths": "(S,) i32"}, "(S,H,Dh) H/tp"),
-        ("ragged_paged_prefill_tp", "ragged_paged_prefill", True, False,
-         {"q": "(S,C,H,Dh) H/tp", "k_pages": "(P,ps,H*Dh) H/tp",
-          "v_pages": "(P,ps,H*Dh) H/tp", "block_tables": "(S,mp) i32",
-          "chunk_starts": "(S,) i32", "n_valid": "(S,) i32"},
-         "(S,C,H,Dh) H/tp"),
-        ("ragged_paged_decode_int8_tp", "ragged_paged_decode_int8",
-         False, True,
-         {"q": "(S,H,Dh) H/tp", "k_pages": "(P,ps,H*Dh) i8 H/tp",
-          "v_pages": "(P,ps,H*Dh) i8 H/tp",
-          "k_scales": "(P,ps) f32 replicated",
-          "v_scales": "(P,ps) f32 replicated",
-          "block_tables": "(S,mp) i32", "lengths": "(S,) i32"},
-         "(S,H,Dh) H/tp"),
-        ("ragged_paged_prefill_int8_tp", "ragged_paged_prefill_int8",
-         True, True,
-         {"q": "(S,C,H,Dh) H/tp", "k_pages": "(P,ps,H*Dh) i8 H/tp",
-          "v_pages": "(P,ps,H*Dh) i8 H/tp",
-          "k_scales": "(P,ps) f32 replicated",
-          "v_scales": "(P,ps) f32 replicated",
-          "block_tables": "(S,mp) i32", "chunk_starts": "(S,) i32",
-          "n_valid": "(S,) i32"}, "(S,C,H,Dh) H/tp"),
-    )
-    for name, inner, chunked, quantized, layouts, out_layout in defs:
-        q_spec = _TP_Q_PREFILL if chunked else _TP_Q_DECODE
-        n_geo = len(layouts) - (5 if quantized else 3)
-        specs = (q_spec, _TP_KV_SPEC, _TP_KV_SPEC)
-        if quantized:
-            specs += (_P(), _P())             # scale rows replicated
-        specs += tuple(_P() for _ in range(n_geo))
-        pallas_fn, lax_fn = _make_tp_fns(inner, specs)
-        sample_fn = (
-            (lambda s, _c=chunked: _make_paged_int8_sample(s, chunked=_c))
-            if quantized else
-            (lambda s, _c=chunked: _make_paged_sample(s, chunked=_c)))
-        inner_spec = kernels.get(inner)
-        kernels.register(kernels.KernelSpec(
-            name=name,
-            contract=kernels.KernelContract(
-                version=1,
-                arg_layouts=layouts,
-                out_layout=out_layout,
-                donatable=inner_spec.contract.donatable,
-                grid=grid,
-                collectives=("all_reduce",),
-                atol=inner_spec.contract.atol,
-                rtol=inner_spec.contract.rtol),
-            pallas_fn=pallas_fn,
-            lax_fn=lax_fn,
-            reference_fn=None,        # parity_fn orchestrates the mesh
-            sample_inputs=sample_fn,
-            pallas_sites=(),          # reuses the inner kernel's sites
-            requires_mesh=True,
-            parity_fn=_make_tp_parity_fn(name, inner, sample_fn,
-                                         inner_spec.reference_fn,
-                                         quantized=quantized),
-            donation_probe=functools.partial(
-                _tp_donation_probe, chunked=chunked,
-                quantized=quantized)))
-
-
-_register_tp_kernels()

@@ -67,17 +67,19 @@ migration (the draft cache is not carried in snapshots).
 Tensor parallel (ISSUE 15): ``mesh=`` (or the shorthand ``tp=N``)
 shards the whole paged stack over the mesh's ``tp`` axis — the page
 pools hold per-shard head slices (``H/tp``), both fixed-shape steps run
-under ``shard_map`` with head-major Megatron param slices
-(``parallel/plan.serving_tp_plan``) and ONE ``psum`` per layer at the
-attention output (the only collective: MLP/embeddings stay replicated —
-decode is KV-bandwidth-bound, and the KV term is what tp divides).
+under ``shard_map`` on the parameter tree the model's serving program
+lays out and shards for it (``serving/program.py``: ``tp_params``,
+``tp_plan``; GPT's: head-major Megatron slices and ONE ``psum`` per layer
+at the attention output, MLP/embeddings replicated — decode is
+KV-bandwidth-bound, and the KV term is what tp divides).
 Greedy tokens are identical to the tp=1 engine (int8 pools pmax each
 token's abs-max so quantization matches bit-for-bit), slot migration
 moves one sha256 shard per (page, tp shard), ``health()`` reports the
 mesh shape, and ``warmup()`` covers the same bucket plan — zero
-steady-state recompiles with tp on. ``tp_probe=True`` builds the
-bench's busy-time vehicle: ONE shard's local computation on one device,
-collectives elided.
+steady-state recompiles with tp on. A tp engine holds one page pool and
+one parameter tree; the share of device time its collectives leave
+exposed is read off the device trace (``device.collective_exposed_pct``),
+not off the host's clock.
 
 Scheduling is SLO-aware by default (``scheduler_policy="slo"``):
 priority lanes, TTFT deadlines with earliest-deadline-first boosting,
@@ -212,13 +214,9 @@ class ServingEngine:
                  starvation_skips: int = 64,
                  registry=None, tracer=None,
                  ttft_budget_s: Optional[float] = None,
-                 slo_windows=(60.0, 300.0),
                  draft_model=None, draft_params=None, spec_k: int = 4,
-                 draft_cache_dtype=None,
                  snapshot_every_blocks: Optional[int] = None,
                  mesh=None, tp: Optional[int] = None,
-                 tp_probe: bool = False,
-                 anatomy_probe_every: Optional[int] = None,
                  tier: str = "colocated",
                  host_spill_pages: int = 0):
         # the model's block as the engine runs it (serving/program.py);
@@ -260,10 +258,7 @@ class ServingEngine:
         # under shard_map with ONE psum at each layer's attention
         # output (the MLP/embeddings stay replicated: decode is
         # KV-bandwidth-bound, and that is what holds the sharded step
-        # to a single collective kind). ``tp_probe=True`` instead runs
-        # ONE shard's local computation on a single device with the
-        # collectives elided — the bench's per-chip busy-time vehicle
-        # (its outputs lack the other shards' head contributions).
+        # to a single collective kind).
         from paddle_tpu.core import mesh as mesh_lib
         mesh_tp = int(dict(mesh.shape).get("tp", 1)) if mesh is not None \
             else None
@@ -274,7 +269,7 @@ class ServingEngine:
             tp = mesh_tp
         tp = int(tp or 1)
         wants = {
-            "tp": tp > 1 or tp_probe,
+            "tp": tp > 1,
             "int8_pages": cache_dtype is not None
             and jnp.dtype(cache_dtype) == jnp.dtype(jnp.int8),
             "draft": draft_model is not None,
@@ -292,11 +287,7 @@ class ServingEngine:
                 and "draft" not in self.draft_program.spec.supports:
             raise ValueError(f"{type(draft_model).__name__} cannot be a "
                              "draft model yet ('draft')")
-        if tp_probe:
-            if tp < 2:
-                raise ValueError("tp_probe needs tp >= 2")
-            mesh = None            # one shard's work, one device
-        elif tp > 1 and mesh is None:
+        if tp > 1 and mesh is None:
             devs = jax.devices()
             if len(devs) < tp:
                 raise ValueError(
@@ -316,15 +307,13 @@ class ServingEngine:
         # (dp etc.) as serving capacity
         self.mesh = mesh if tp > 1 else None
         self.tp = tp
-        self.tp_probe = bool(tp_probe)
-        self.tp_spmd = self.mesh is not None and tp > 1
         self._tp_heads = cfg.num_heads // tp
-        # prefill tier + spmd tp: shard the MLP too (Megatron ffn_up
+        # prefill tier + tp: shard the MLP too (Megatron ffn_up
         # column / down row split) — prefill is flops-bound, so the MLP
         # matmuls are worth the second psum per layer. Gated to the
         # prefill tier so the colocated/decode step HLO (and every
         # pre-existing cost surface) stays byte-identical.
-        self._mlp_sharded = self.tier == "prefill" and self.tp_spmd
+        self._mlp_sharded = self.tier == "prefill" and tp > 1
         # -- speculative decoding (ISSUE 13): a draft model proposes
         # spec_k tokens per slot per round; the target verifies them all
         # in ONE fixed-shape batched-prefill-shaped step
@@ -365,23 +354,21 @@ class ServingEngine:
         # fp32 scales and attends through the dequant-attend kernels —
         # HBM per live token roughly halves AGAIN vs bf16
         dtype = cache_dtype or base.param_dtype(params)
-        # a probe engine's pool holds ONE shard's head slice; an spmd
-        # engine's pool is globally shaped but placed sharded H/tp
+        # a tp engine's pool is globally shaped but placed sharded H/tp
         self.cache = PagedKVCache(PagedCacheConfig(
-            num_layers=cfg.num_layers,
-            num_heads=self._tp_heads if self.tp_probe else cfg.kv_heads,
+            num_layers=cfg.num_layers, num_heads=cfg.kv_heads,
             head_dim=cfg.head_dim,
             num_slots=num_slots, page_size=page_size, num_pages=num_pages,
             max_pages_per_slot=max_pages_per_slot, dtype=dtype,
             share_prefix=prefix_sharing, extra_rows=spec.extra_rows),
-            mesh=mesh if self.tp_spmd else None,
+            mesh=self.mesh,
             host_spill_pages=host_spill_pages)
         self.quantized = self.cache.config.quantized
         self.draft_cache = None
         self._draft_quantized = False
         if self.speculative:
             dcfg = self.draft_program.spec
-            ddtype = draft_cache_dtype or cache_dtype or \
+            ddtype = cache_dtype or \
                 self.draft_program.param_dtype(draft_params)
             # same slot/page geometry as the target cache: allocations
             # run in lockstep (reserve/free the same slots for the same
@@ -428,79 +415,43 @@ class ServingEngine:
         if ttft_budget_s is not None:
             self.slo_monitor = obs.BurnRateMonitor(
                 "serving_ttft_seconds", ttft_budget_s,
-                windows=slo_windows, registry=self._reg,
+                windows=(60.0, 300.0), registry=self._reg,
                 tracer=self.tracer)
         # step-time anatomy (ISSUE 16): host gap / phase-split device
-        # busy / host assembly per step, plus the sampled collective-
-        # exposed probe below; the flight recorder rides along as the
-        # replica's crash black box (the router dumps it on eject)
+        # busy / host assembly per step; the flight recorder rides along
+        # as the replica's crash black box (the router dumps it on eject)
         self.anatomy = obs.StepAnatomy(registry=self._reg,
                                        tracer=self.tracer)
         self.flight = obs.FlightRecorder(
             "engine", anatomy=self.anatomy, registry=self._reg,
             tracer=self.tracer)
-        if anatomy_probe_every is not None and anatomy_probe_every < 0:
-            raise ValueError("anatomy_probe_every must be >= 0")
-        # collective-exposed sampling: every N decode rounds an spmd
-        # engine re-runs the SAME decode shapes through a collectives-
-        # elided probe twin (the tp_probe discipline, in-engine); the
-        # wall delta is the exposed collective time. 0 disables; the
-        # default arms it only where there ARE collectives to expose.
-        self.anatomy_probe_every = (
-            anatomy_probe_every if anatomy_probe_every is not None
-            else (64 if self.tp_spmd else 0))
-        if not self.tp_spmd:
-            self.anatomy_probe_every = 0
-        self._decode_rounds = 0
-        # the programs the jitted steps run: this engine's (one head
-        # shard's under tp) and, for the collective probe, its twin with
-        # the collectives elided
+        # the program the jitted steps run (one head shard's under tp)
         self.program = base if self.tp == 1 else model.serving(
-            tp=self.tp, spmd=self.tp_spmd, mlp_sharded=self._mlp_sharded)
-        self._probe_program = model.serving(tp=self.tp, spmd=False) \
-            if self.tp > 1 else None
+            tp=self.tp, mlp_sharded=self._mlp_sharded)
         #: counts the steps hand back beside the tokens, in order
         self._step_stats = self._stat_names(spec)
         self._bind_step_metrics()
 
-        # step-side params: tp re-lays the attention projections out
-        # head-major (qkv (D,3,H,Dh) col-sharded, out (H,Dh,D)
-        # row-sharded — parallel/plan.serving_tp_plan, the SpecLayout
-        # Megatron split at head granularity); tp=1 uses the model's
-        # own tree untouched
-        self._probe_params = None
-        self._probe_pages = None
+        # step-side params: under tp the program re-lays its tree out
+        # so that a "tp" shard boundary is a head boundary and says how
+        # to shard it (serving/program.py); tp=1 uses the model's own
+        # tree untouched
         if self.tp > 1:
+            from jax.sharding import PartitionSpec as PSpec
+
+            from paddle_tpu.core.compat import shard_map
             from paddle_tpu.parallel import plan as plan_lib
-            tp_params = self._make_tp_params(params)
-            if self.tp_spmd:
-                if self.anatomy_probe_every:
-                    # the collective probe's params: shard 0's local
-                    # slice, taken host-side BEFORE the sharded
-                    # device_put consumes the tree
-                    self._probe_params = self._tp_shard_slice(
-                        tp_params, 0)
-                tp_plan = (plan_lib.serving_prefill_tp_plan()
-                           if self._mlp_sharded
-                           else plan_lib.serving_tp_plan())
-                self._param_specs = tp_plan.params_specs(tp_params)
-                self._step_params = jax.device_put(
-                    tp_params,
-                    plan_lib.named_shardings(mesh, self._param_specs))
-            else:                  # probe: shard 0's local slice
-                self._step_params = self._tp_shard_slice(tp_params, 0)
+            tp_params = self.program.tp_params(params)
+            self._param_specs = self.program.tp_plan().params_specs(
+                tp_params)
+            self._step_params = jax.device_put(
+                tp_params,
+                plan_lib.named_shardings(mesh, self._param_specs))
             # don't pin the caller's unsharded attention projections
             # for the engine's lifetime next to their sharded copies:
             # under tp, self.params IS the step-side (re-laid-out,
             # sharded) tree
             self.params = self._step_params
-        else:
-            self._step_params = params
-        if self.tp_spmd:
-            from jax.sharding import PartitionSpec as PSpec
-
-            from paddle_tpu.core.compat import shard_map
-            from paddle_tpu.parallel import plan as plan_lib
             rep = PSpec()
             self._page_specs = plan_lib.paged_pool_specs(self.cache.pages)
             step_specs = (self._param_specs, self._page_specs,
@@ -514,18 +465,11 @@ class ServingEngine:
                 out_specs=(rep, self._page_specs), check_vma=False),
                 donate_argnums=(1,))
         else:
+            self._step_params = params
             self.decode_step = jax.jit(self._decode_step_impl,
                                        donate_argnums=(1,))
             self.prefill_step = jax.jit(self._prefill_step_impl,
                                         donate_argnums=(1,))
-        if self._probe_params is not None:
-            # collectives-elided decode twin: ONE shard's local math on
-            # one device against a dedicated zero page pool with the
-            # per-shard head slice — same shapes per width bucket, so
-            # warmup covers it and sampling stays zero-recompile
-            self._probe_pages = self._make_probe_pool()
-            self.decode_probe_step = jax.jit(
-                self._decode_probe_step_impl, donate_argnums=(1,))
         if self.speculative:
             # draft pages donate into their own steps; the verify step
             # donates the TARGET pages exactly like prefill does
@@ -828,8 +772,7 @@ class ServingEngine:
             # chip count is the TP degree, not the raw mesh size — a
             # dp axis only replicates this engine's work
             "tp": self.tp,
-            "mesh_devices": self.tp if self.tp_spmd else 1,
-            "tp_probe": self.tp_probe,
+            "mesh_devices": self.tp,
             # disaggregation tier: the two-tier router and the
             # autoscaler key placement/scaling decisions off this
             "tier": self.tier,
@@ -1119,21 +1062,7 @@ class ServingEngine:
             self._h_decode_step.observe(t1 - t0)
             self.anatomy.add_phase("decode", t0, t1)
             self._note_busy((("decode", w),), t1 - t0)
-            self._decode_rounds += 1
             self._c_decode_rounds.inc()
-            if self.anatomy_probe_every and self._probe_pages is not None \
-                    and self._decode_rounds % self.anatomy_probe_every == 0:
-                # collective-exposed sample: the SAME decode shapes through
-                # the collectives-elided probe twin (zero probe pool, shard
-                # 0's params); every shape below is a warmed
-                # ("decode_probe", w) bucket, so steady state compiles
-                # nothing — the RecompileDetector asserts it
-                p0 = time.monotonic()
-                pout, self._probe_pages = self.decode_probe_step(
-                    self._probe_params, self._probe_pages, *args)
-                np.asarray(pout)                     # sync the probe wall
-                p1 = time.monotonic()
-                self.anatomy.set_collective(t1 - t0, p1 - p0)
             with phase("serving.decode.book", part["decode", "book"]):
                 tr_on = self.tracer.enabled
                 kept = 0
@@ -1686,11 +1615,6 @@ class ServingEngine:
                 plan.append(("verify", w))
             else:
                 plan.append(("decode", w))
-                if self._probe_params is not None:
-                    # the collective probe twin samples the same width
-                    # buckets; precompiling them keeps sampling
-                    # zero-recompile in steady state
-                    plan.append(("decode_probe", w))
             for sb in counts:
                 plan.append(("prefill", w, sb))
                 if self.speculative:
@@ -1712,7 +1636,7 @@ class ServingEngine:
         if sig[0] in ("page_read", "page_write") \
                 and "migration" not in self.program.spec.supports:
             return False        # pages never leave this engine
-        if self.tier == "prefill" and sig[0] in ("decode", "decode_probe"):
+        if self.tier == "prefill" and sig[0] == "decode":
             return False
         if self.tier == "decode" and sig[0] == "prefill":
             return False
@@ -1738,8 +1662,6 @@ class ServingEngine:
                      for w in widths for sb in counts}
         else:
             sigs = {("decode", w) for w in widths}
-            if self._probe_params is not None:
-                sigs |= {("decode_probe", w) for w in widths}
         sigs |= {("prefill", w, sb) for w in widths for sb in counts}
         sigs.add(("copy_page",))
         sigs.add(("page_read",))
@@ -1780,15 +1702,6 @@ class ServingEngine:
                 if cost_gauges:
                     self._bucket_cost_gauges(sig, self.decode_step, args)
                 _, self.cache.pages = self.decode_step(*args)
-            elif sig[0] == "decode_probe":
-                w = sig[1]
-                args = (self._probe_params, self._probe_pages,
-                        jnp.zeros((s_tot, w), jnp.int32), zeros, zeros,
-                        zeros)
-                if cost_gauges:
-                    self._bucket_cost_gauges(sig, self.decode_probe_step,
-                                             args)
-                _, self._probe_pages = self.decode_probe_step(*args)
             elif sig[0] == "draft":
                 w = sig[1]
                 args = (self.draft_params, self.draft_cache.pages,
@@ -1926,7 +1839,7 @@ class ServingEngine:
             # verifies independently (an int8 shard carries the
             # replicated scale rows alongside — one hash over both, as
             # before)
-            for t in range(self.tp if self.tp_spmd else 1):
+            for t in range(self.tp):
                 kv_t = kv_all[..., t * hl:(t + 1) * hl, :]
                 shard = (kv_t, sc_all) if self.quantized else kv_t
                 shards.append(shard)
@@ -1947,7 +1860,7 @@ class ServingEngine:
                          "head_dim": cfgc.head_dim,
                          "page_size": cfgc.page_size,
                          "dtype": str(jnp.dtype(cfgc.dtype)),
-                         "tp": self.tp if self.tp_spmd else 1},
+                         "tp": self.tp},
             "request": {"prompt": np.asarray(req.prompt, np.int32),
                         "max_new_tokens": req.max_new_tokens,
                         "eos_id": req.eos_id, "lane": req.lane,
@@ -2112,7 +2025,7 @@ class ServingEngine:
         mine = {"num_layers": cfgc.num_layers, "num_heads": cfgc.num_heads,
                 "head_dim": cfgc.head_dim, "page_size": cfgc.page_size,
                 "dtype": str(jnp.dtype(cfgc.dtype)),
-                "tp": self.tp if self.tp_spmd else 1}
+                "tp": self.tp}
         if geo != mine:
             # cross-tp restore is refused like any other geometry
             # mismatch: the shard layout IS part of the transfer format
@@ -2141,7 +2054,7 @@ class ServingEngine:
         # null page other live requests gather from
         length = int(snap["state"]["length"])
         n_live = cfgc.pages_for(length) if length > 0 else 0
-        tp_shards = self.tp if self.tp_spmd else 1
+        tp_shards = self.tp
         if length < 0 or length > total or \
                 len(shards) != n_live * tp_shards:
             raise SlotMigrationError(
@@ -2244,7 +2157,7 @@ class ServingEngine:
             return None
         cfgc = self.cache.config
         hl = self._tp_heads
-        tp_shards = self.tp if self.tp_spmd else 1
+        tp_shards = self.tp
         pages, total_bytes = [], 0
         for key in digests:
             key = int(key)
@@ -2326,7 +2239,7 @@ class ServingEngine:
             raise SlotMigrationError(
                 f"unknown prefix bundle format {bundle.get('format')!r}")
         cfgc = self.cache.config
-        tp_shards = self.tp if self.tp_spmd else 1
+        tp_shards = self.tp
         mine = {"num_layers": cfgc.num_layers, "num_heads": cfgc.num_heads,
                 "head_dim": cfgc.head_dim, "page_size": cfgc.page_size,
                 "dtype": str(jnp.dtype(cfgc.dtype)),
@@ -2399,62 +2312,6 @@ class ServingEngine:
         ).inc(nbytes)
         self._refresh_health()
         return len(install)
-
-    # -- tensor parallel helpers ------------------------------------------
-
-    def _make_tp_params(self, params):
-        """Head-major TP re-layout of the attention projections: fused
-        qkv weight ``(D, 3D)`` -> ``(D, 3, H, Dh)`` (bias ``(3D,)`` ->
-        ``(3, H, Dh)``), out_proj weight ``(D, D)`` -> ``(H, Dh, D)``.
-        Sharding the RAW fused columns over tp would hand each shard a
-        slice straddling the q/k/v boundaries; head-major, the "tp"
-        shard boundary IS a head boundary — which is exactly what the
-        per-shard page pools need. Everything else passes through
-        untouched (replicated under ``serving_tp_plan``)."""
-        cfg = self.model.cfg
-        d, h = cfg.hidden_size, cfg.num_heads
-        dh = d // h
-        out = dict(params)
-        blocks = {}
-        for name, bp in params["blocks"].items():
-            bp = dict(bp)
-            qkv, op = bp["attn"]["qkv_proj"], bp["attn"]["out_proj"]
-            attn = {
-                "qkv_tp": {"weight": qkv["weight"].reshape(d, 3, h, dh)},
-                "out_tp": {"weight": op["weight"].reshape(h, dh, d)},
-            }
-            if "bias" in qkv:
-                attn["qkv_tp"]["bias"] = qkv["bias"].reshape(3, h, dh)
-            if "bias" in op:
-                attn["out_tp"]["bias"] = op["bias"]
-            bp["attn"] = attn
-            blocks[name] = bp
-        out["blocks"] = blocks
-        return out
-
-    def _tp_shard_slice(self, tp_params, shard: int):
-        """One shard's local slice of the head-major TP tree — the
-        probe engine's params (what shard_map would hand shard
-        ``shard``)."""
-        hl = self._tp_heads
-        lo = shard * hl
-        out = dict(tp_params)
-        blocks = {}
-        for name, bp in tp_params["blocks"].items():
-            bp = dict(bp)
-            attn = dict(bp["attn"])
-            qkv = {"weight": attn["qkv_tp"]["weight"][:, :, lo:lo + hl]}
-            if "bias" in attn["qkv_tp"]:
-                qkv["bias"] = attn["qkv_tp"]["bias"][:, lo:lo + hl]
-            attn["qkv_tp"] = qkv
-            op = {"weight": attn["out_tp"]["weight"][lo:lo + hl]}
-            if "bias" in attn["out_tp"]:
-                op["bias"] = attn["out_tp"]["bias"]
-            attn["out_tp"] = op
-            bp["attn"] = attn
-            blocks[name] = bp
-        out["blocks"] = blocks
-        return out
 
     # -- jitted step bodies ----------------------------------------------
 
@@ -2663,38 +2520,7 @@ class ServingEngine:
                                  tokens, active, program=self.program,
                                  quantized=self.quantized,
                                  n_steps=self.decode_block,
-                                 psum_axis="tp" if self.tp_spmd else None)
-
-    def _make_probe_pool(self):
-        """Zero page pool for the collective probe: the real pool's
-        geometry with ONE shard's head slice (``H/tp``) on a single
-        device — what shard_map hands each shard, minus the psum. Page
-        content does not matter for timing (shapes are fixed); a zero
-        pool keeps the probe from ever touching live KV."""
-        c = self.cache.config
-        shape = (c.num_pages, c.page_size, self._tp_heads * c.head_dim)
-        pool = []
-        for _ in range(c.num_layers):
-            if self.quantized:
-                sc = jnp.zeros((c.num_pages, c.page_size), jnp.float32)
-                pool.append((jnp.zeros(shape, jnp.int8),
-                             jnp.zeros(shape, jnp.int8), sc, sc))
-            else:
-                pool.append((jnp.zeros(shape, c.dtype),
-                             jnp.zeros(shape, c.dtype)))
-        return pool
-
-    def _decode_probe_step_impl(self, params, pages, block_tables,
-                                lengths, tokens, active):
-        """The decode step's collectives-elided twin (ISSUE 16): one
-        shard's local computation with ``spmd=False`` — identical
-        shapes and math minus the per-layer psum, so ``real - probe``
-        wall time is the step's exposed collective cost."""
-        return self._decode_loop(params, pages, block_tables, lengths,
-                                 tokens, active,
-                                 program=self._probe_program,
-                                 quantized=self.quantized,
-                                 n_steps=self.decode_block)
+                                 psum_axis="tp" if self.tp > 1 else None)
 
     def _draft_propose_step_impl(self, params, pages, block_tables,
                                  lengths, tokens, active, n_valid):
@@ -2781,7 +2607,7 @@ class ServingEngine:
         return self._prefill_loop(params, pages, block_tables, starts,
                                   tokens, n_valid, program=self.program,
                                   quantized=self.quantized,
-                                  psum_axis="tp" if self.tp_spmd else None)
+                                  psum_axis="tp" if self.tp > 1 else None)
 
     def _draft_prefill_step_impl(self, params, pages, block_tables,
                                  starts, tokens, n_valid):

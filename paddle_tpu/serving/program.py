@@ -26,6 +26,15 @@ their block added. ``ffn`` may return a dict of scalar counts (names from
 
 What a program cannot do yet it leaves out of ``spec.supports``; the
 engine refuses, by name, an option that needs it.
+
+A program that lists ``"tp"`` is built for its degree
+(``model.serving(tp=N, ...)``: the functions above are then one head
+shard's, run under the engine's ``shard_map`` with the program's own
+collectives on the mesh's ``"tp"`` axis) and adds the layout that goes
+with it; the engine names no parameter of any model::
+
+    tp_params(params)  -> the tree the sharded steps take
+    tp_plan()          -> parallel.plan.ShardingPlan of that tree
 """
 
 from __future__ import annotations

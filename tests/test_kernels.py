@@ -43,6 +43,55 @@ class TestParityBattery:
 
 
 # ---------------------------------------------------------------------------
+# head shards — what tensor-parallel serving leans on
+# ---------------------------------------------------------------------------
+
+class TestHeadShardsAreIndependent:
+    """A tp engine shards its whole step and calls the plain paged
+    kernels on each shard's heads (there is no tp kernel). That is sound
+    because heads are independent and the pool's lanes are head-major: a
+    head shard of the folded pool through the plain kernel is those
+    heads of the whole call."""
+
+    @pytest.mark.parametrize("name", [
+        "ragged_paged_decode", "ragged_paged_prefill",
+        "ragged_paged_decode_int8", "ragged_paged_prefill_int8"])
+    @pytest.mark.parametrize("seed", [0, 1])
+    def test_shard_equals_those_heads_of_the_whole_call(self, name, seed):
+        spec = kernels.get(name)
+        args, kw = spec.sample_inputs(seed)
+        q, k_pages, v_pages, rest = args[0], args[1], args[2], args[3:]
+        h, dh = q.shape[-2:]
+        hl = h // 2                 # the two shards of tp=2
+        whole = {impl: np.asarray(kernels.dispatch(
+            name, *args, impl=impl, **kw))
+            for impl in ("lax", "pallas_interpret")}
+        for lo, hi in ((0, hl), (hl, h)):
+            # scale rows (int8) and the block-table geometry go whole:
+            # a token's scale is taken over all its heads
+            shard = (q[..., lo:hi, :], k_pages[..., lo * dh:hi * dh],
+                     v_pages[..., lo * dh:hi * dh]) + rest
+            lax_out = np.asarray(kernels.dispatch(
+                name, *shard, impl="lax", **kw))
+            want = whole["lax"][..., lo:hi, :]
+            if name.endswith("_int8"):
+                # XLA's codegen for the fused cast-dequant dot
+                # reassociates differently at different head counts, so
+                # the per-shard dequant einsum can drift a last ulp from
+                # the full-head one (greedy tokens identical to tp=1 are
+                # pinned exactly in tests/test_serving_tp.py)
+                np.testing.assert_allclose(lax_out, want,
+                                           rtol=1e-6, atol=1e-6)
+            else:
+                np.testing.assert_array_equal(lax_out, want)
+            np.testing.assert_allclose(
+                np.asarray(kernels.dispatch(
+                    name, *shard, impl="pallas_interpret", **kw)),
+                whole["pallas_interpret"][..., lo:hi, :],
+                atol=spec.contract.atol, rtol=spec.contract.rtol)
+
+
+# ---------------------------------------------------------------------------
 # byte parity vs the pre-refactor call paths
 # ---------------------------------------------------------------------------
 

@@ -76,13 +76,11 @@ class TestStepAnatomy:
         t = a.now()
         a.add_phase("prefill", t - 0.004, t - 0.003)
         a.add_phase("decode", t - 0.002, t - 0.0005)
-        a.set_collective(0.0015, 0.0009)
         time.sleep(0.005)       # wall must cover the claimed phases
         rec = a.end_step(tokens=3)
         anat.validate_anatomy_record(rec)
         assert rec["step"] == 1 and rec["tokens"] == 3
         assert rec["phases"]["decode"] == pytest.approx(0.0015)
-        assert rec["collective_exposed_s"] == pytest.approx(0.0006)
         assert reg.counter("anatomy_steps_total").value() == 1
         assert reg.histogram("anatomy_phase_seconds").summary(
             phase="decode")["count"] == 1
@@ -392,41 +390,6 @@ class TestEngineAnatomy:
             "serving_step_part_seconds_total")) <= step_s
         assert step_s <= sum(r["wall_s"] for r in eng.anatomy.records()) \
             + 1e-3
-
-
-# ---------------------------------------------------------------------------
-# tp=2: the collective-exposed probe (zero-recompile discipline)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.skipif(len(jax.devices()) < 4,
-                    reason="tp tests need >= 4 (virtual) devices")
-class TestTpCollectiveProbe:
-    def test_probe_samples_without_recompiles(self, model_params):
-        eng = _engine(model_params, tp=2, anatomy_probe_every=2)
-        # the probe signatures are first-class citizens of the warmup
-        # contract: planned AND reachable (the set-equality invariant)
-        plan = set(eng.warmup_plan())
-        assert plan == set(eng.reachable_signatures())
-        assert any(sig[0] == "decode_probe" for sig in plan)
-        eng.warmup()
-        rng = np.random.default_rng(3)
-        prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
-                   for n in (5, 9)]
-        outs = eng.generate_many(prompts, 6, eos_id=None)
-        assert all(len(np.asarray(o)) == 6 for o in outs)
-        s = eng.anatomy.summary()
-        assert s["probe_samples"] >= 1
-        assert s["collective_exposed_s"] >= 0.0
-        assert 0.0 <= s["collective_exposed_frac"] <= 1.0
-        assert eng.recompile_detector.recompiles == 0
-        h = eng.health()["headroom"]
-        assert set(h) >= {"flops", "pages", "slots", "hbm"}
-
-    def test_probe_off_for_unsharded_engines(self, model_params):
-        eng = _engine(model_params)
-        assert eng.anatomy_probe_every == 0
-        assert not any(sig[0] == "decode_probe"
-                       for sig in eng.warmup_plan())
 
 
 # ---------------------------------------------------------------------------

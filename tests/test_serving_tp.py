@@ -5,9 +5,9 @@ IDENTICAL to the tp=1 engine (fp + int8, prefix sharing on/off), zero
 steady-state recompiles with tp on, bucket-coverage proof for the
 sharded warmup plan, per-shard migration byte-parity through a
 mid-decode drain, and the mesh shape surfacing through ``health()`` and
-the fleet router. The tp KERNEL wrappers' parity battery lives in
-``test_kernels.py`` (they register like any other kernel and the
-registry-wide battery picks them up).
+the fleet router. The sharded step runs the plain paged kernels on each
+shard's heads; that a head shard through them equals those heads of the
+whole call is ``test_kernels.py::TestHeadShardsAreIndependent``.
 """
 
 import jax
@@ -154,7 +154,23 @@ class TestTpSteadyState:
         h = warmed_tp_engine.health()
         assert h["tp"] == 2
         assert h["mesh_devices"] == 2
-        assert h["tp_probe"] is False
+
+    def test_decode_step_lowers_one_all_reduce_a_layer(
+            self, warmed_tp_engine, tiny_model):
+        """The sharded decode step's whole collective set: the
+        attention-output psum over the tp axis, once a layer (embedding
+        and MLP are replicated and emit none)."""
+        from paddle_tpu import analysis
+        eng = warmed_tp_engine
+        s_tot = eng.scheduler.num_slots
+        zeros = jnp.zeros((s_tot,), jnp.int32)
+        w = min(sig[1] for sig in eng.warmup_plan() if sig[0] == "decode")
+        cost = analysis.estimate_cost(
+            eng.decode_step, eng._step_params, eng.cache.pages,
+            jnp.zeros((s_tot, w), jnp.int32), zeros, zeros, zeros,
+            name="serving_decode_tp")
+        assert [(c.kind, c.group_size) for c in cost.collectives] == [
+            ("all_reduce", 2)] * tiny_model[0].cfg.num_layers
 
     def test_warmed_signatures_match_plan(self, warmed_tp_engine):
         assert warmed_tp_engine.warmed_signatures == set(
@@ -272,17 +288,72 @@ class TestTpConfig:
         with pytest.raises(ValueError, match="disagrees"):
             make_engine(tiny_model, mesh=mesh, tp=4)
 
-    @pytest.mark.slow
-    def test_probe_engine_is_local(self, tiny_model, prompts):
-        eng = make_engine(tiny_model, tp=2, tp_probe=True)
-        h = eng.health()
-        assert h["tp"] == 2 and h["tp_probe"] is True
-        assert h["mesh_devices"] == 1
-        # one shard's work: the probe runs the full engine loop (its
-        # tokens lack the other shard's head contributions — it is a
-        # busy-time vehicle, not a correctness one)
-        outs = run_all(eng, prompts[:2], eos=None)
-        assert all(len(t) == 16 for t in outs)
+    def test_tp_plan_adds_no_signature_kind(self, tiny_model):
+        plain = {sig[0] for sig in make_engine(tiny_model).warmup_plan()}
+        eng = make_engine(tiny_model, tp=2)
+        assert {sig[0] for sig in eng.warmup_plan()} <= plain
+        assert set(eng.warmup_plan()) == eng.reachable_signatures()
+
+    def test_one_pool_and_one_param_tree(self, tiny_model):
+        """Every device array the tp engine holds is a leaf of its
+        page pool or of its (sharded) parameter tree: no second pool,
+        no second copy of a shard's weights."""
+        eng = make_engine(tiny_model, tp=2)
+        assert eng.params is eng._step_params
+        own = {id(x) for x in jax.tree_util.tree_leaves(
+            (eng._step_params, eng.cache.pages))}
+        extra = [(name, x.shape) for name, v in vars(eng).items()
+                 if name != "cache"
+                 for x in jax.tree_util.tree_leaves(v)
+                 if isinstance(x, jax.Array) and id(x) not in own]
+        assert extra == []
+
+    def test_tp_params_round_trip(self, tiny_model):
+        model, params = tiny_model
+        program = model.serving(tp=2)
+        tree = program.tp_params(params)
+        d = model.cfg.hidden_size
+        for name, bp in params["blocks"].items():
+            got, want = tree["blocks"][name]["attn"], bp["attn"]
+            assert set(got) == {"qkv_tp", "out_tp"}
+            np.testing.assert_array_equal(
+                np.asarray(got["qkv_tp"]["weight"]).reshape(d, 3 * d),
+                np.asarray(want["qkv_proj"]["weight"]))
+            np.testing.assert_array_equal(
+                np.asarray(got["qkv_tp"]["bias"]).reshape(3 * d),
+                np.asarray(want["qkv_proj"]["bias"]))
+            np.testing.assert_array_equal(
+                np.asarray(got["out_tp"]["weight"]).reshape(d, d),
+                np.asarray(want["out_proj"]["weight"]))
+            np.testing.assert_array_equal(
+                np.asarray(got["out_tp"]["bias"]),
+                np.asarray(want["out_proj"]["bias"]))
+            assert tree["blocks"][name]["mlp"] is bp["mlp"]
+        # every leaf of the tree has a spec under the program's plan
+        specs = program.tp_plan().params_specs(tree)
+        assert jax.tree_util.tree_structure(specs) == \
+            jax.tree_util.tree_structure(tree)
+
+    def test_program_without_tp_refused_before_layout(self, tiny_model):
+        """``supports`` decides, by name, before the engine asks the
+        program for a tp layout it does not have."""
+        import dataclasses
+        model, params = tiny_model
+
+        class NoTp:
+            def __init__(self, prog):
+                self.spec = dataclasses.replace(
+                    prog.spec, supports=prog.spec.supports - {"tp"})
+
+            def tp_params(self, params):
+                raise AssertionError("asked for a tp layout")
+
+        class Model:
+            def serving(self, **kw):
+                return NoTp(model.serving(**kw))
+
+        with pytest.raises(ValueError, match="does not serve with 'tp'"):
+            serving.ServingEngine(Model(), params, tp=2)
 
     def test_quantize_kv_psum_axis_matches_global(self):
         from paddle_tpu.core.compat import shard_map

@@ -81,11 +81,6 @@ def render(bundle: dict, tail: int = 8) -> str:
                      f"wall={summary.get('wall_s', 0.0):.4g}s "
                      f"host_gap_frac={summary.get('host_gap_frac', 0.0):.3f}"
                      + (f" | {split}" if split else ""))
-        if "collective_exposed_frac" in summary:
-            lines.append(
-                "  collective exposed: "
-                f"frac={summary['collective_exposed_frac']:.4f} "
-                f"({summary.get('probe_samples', 0)} probe samples)")
     recs = bundle.get("anatomy") or []
     if recs:
         lines.append(f"  last {min(tail, len(recs))} of {len(recs)} "
