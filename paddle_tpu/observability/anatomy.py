@@ -107,8 +107,13 @@ class StepAnatomy:
         return self._wall0 + (t - self._mono0)
 
     # -- step lifecycle ---------------------------------------------------
-    def begin_step(self, step_id: Optional[int] = None) -> None:
-        t0 = self.now()
+    def begin_step(self, step_id: Optional[int] = None,
+                   t0: Optional[float] = None) -> None:
+        """``t0`` / ``end_step``'s ``t1``: clock reads the caller already
+        took around the step (the engine's ``serving.step`` phase), so
+        that the record's wall is that span's, to the digit."""
+        if t0 is None:
+            t0 = self.now()
         gap = (t0 - self._last_end) if self._last_end is not None else 0.0
         if step_id is None:
             step_id = self._step_seq
@@ -135,12 +140,14 @@ class StepAnatomy:
             self._cur = None
             self._last_end = self.now()
 
-    def end_step(self, tokens: int = 0) -> Optional[Dict[str, Any]]:
+    def end_step(self, tokens: int = 0,
+                 t1: Optional[float] = None) -> Optional[Dict[str, Any]]:
         cur = self._cur
         if cur is None:
             return None
         self._cur = None
-        t1 = self.now()
+        if t1 is None:
+            t1 = self.now()
         wall = max(t1 - cur["t0"], 0.0)
         phases = {p: round(s, 9) for p, s in cur["phases"].items()}
         busy = sum(phases.values())

@@ -29,6 +29,19 @@ running the decode block — the chunk floor is a single liveness lane
 for budgets below one chunk — so a burst of long prompts cannot starve
 in-flight decodes and vice versa.
 
+The host waits for the device **once a step** (ISSUE 31): every call of
+a step is ordered on the device by the donated page pool it threads, so
+a prefill call is dispatched and not read. A finished prompt's first
+token stays on the device and is merged into the decode block's input
+tokens there (``first_token_step``, one tiny program per lane count),
+and the block's read-back brings it over together with the block's
+tokens and the programs' pending counts; TTFT is stamped then, when the
+host learns the token. Only a finishing request the step must judge at
+once reads back in its prefill call: one with an ``eos_id`` or a budget
+of one token (the admission cascade evicts on it), a speculative engine
+(its round reads the token on the host), a prefill tier (the slot parks
+for handoff). ``serving_device_readbacks_total{phase}`` counts the waits.
+
 Prefix sharing: admission maps published prompt-prefix pages straight
 into the new slot's block table (refcount bump, prefill skipped for the
 shared tokens — see ``paged_cache``) and the engine performs the single
@@ -94,7 +107,8 @@ Metrics (observability registry): ``serving_requests_total``,
 prefix tokens are skipped and show up in
 ``serving_prefix_shared_tokens_total`` instead),
 ``serving_prompt_tokens_total`` (tokens submitted),
-``serving_prefix_cow_total``, ``serving_steps_total``, and the latency
+``serving_prefix_cow_total``, ``serving_steps_total``,
+``serving_device_readbacks_total``, and the latency
 split: ``serving_queue_wait_seconds`` (submit → admit),
 ``serving_admit_to_first_token_seconds`` (admit → first token: the pure
 prefill cost), ``serving_ttft_seconds`` (their end-to-end sum), plus
@@ -481,6 +495,24 @@ class ServingEngine:
                                        donate_argnums=(1,))
         self.copy_page_step = jax.jit(self._copy_page_impl,
                                       donate_argnums=(0,))
+        # a finished prompt's first token travels from its prefill call
+        # to the decode block of the same step on the device. A compiled
+        # program is keyed by where its operands are committed too, so
+        # the token vectors the host uploads are placed like the ones the
+        # steps hand back: under tp committed to the whole mesh
+        self._upload = jnp.asarray
+        placed = {}
+        if self.tp > 1:
+            whole = jax.sharding.NamedSharding(self.mesh, PSpec())
+            self._upload = lambda a: jax.device_put(a, whole)
+            placed = dict(in_shardings=whole, out_shardings=whole)
+        self.first_token_step = jax.jit(self._first_token_impl, **placed)
+        #: first tokens the host has not read yet: ``(the finishing
+        #: call's tokens on the device, [(lane, slot), ...])``; empty
+        #: whenever ``step()`` returns
+        self._owed: List[tuple] = []
+        #: program counts not read yet: ``(the call's phase, handle)``
+        self._unread_counts: List[tuple] = []
         # migration page IO (fleet drain): src/dst are traced scalars,
         # so ONE compile each covers every page ever moved
         self.read_page_step = jax.jit(self._read_page_impl)
@@ -529,6 +561,9 @@ class ServingEngine:
         # per-call rate as the utilization ceiling (the high-water mark
         # this hardware + bucket set actually demonstrated)
         self._busy_s = 0.0
+        # calls dispatched and not waited for yet (_note_busy)
+        self._unwaited_sigs: tuple = ()
+        self._unwaited_s = 0.0
         self._flops_done = 0.0
         self._flops_rate_peak = 0.0
         self._anat_steps = 0
@@ -560,6 +595,14 @@ class ServingEngine:
         self._c_decode_rounds = r.counter(
             "serving_decode_rounds_total",
             "decode rounds (one per serving.decode_round)").child()
+        back = r.counter(
+            "serving_device_readbacks_total",
+            "times the host waited for a device value inside step(), by "
+            "the phase that waited: the tokens of a batched call (prefill, "
+            "decode) or a page on its way to the host (page_read: spill, "
+            "micro-checkpoints)")
+        self._c_readbacks = {ph: back.child(phase=ph)
+                             for ph in ("prefill", "decode", "page_read")}
         kv = r.counter(
             "serving_decode_kv_bytes_total",
             "K/V bytes per decode round: kind=live is what the live "
@@ -846,10 +889,18 @@ class ServingEngine:
         self._g_prefix_saved.set(head["prefix_saved_per_token"])
         return head
 
-    def _note_busy(self, sigs, dur: float):
+    def _note_busy(self, sigs, dur: float, waited: bool = True):
         """Headroom accounting for one jitted call: busy seconds plus
         the static flops of the bucket(s) it retired (when warmup
-        priced them)."""
+        priced them). A call the host did not wait for is held until the
+        read-back that covers it, so a rate is flops over the wall time
+        of the calls that retired them, never over a bare dispatch."""
+        self._unwaited_sigs += tuple(sigs)
+        self._unwaited_s += dur
+        if not waited:
+            return
+        sigs, dur = self._unwaited_sigs, self._unwaited_s
+        self._unwaited_sigs, self._unwaited_s = (), 0.0
         self._busy_s += dur
         flops = 0.0
         for sig in sigs:
@@ -906,7 +957,7 @@ class ServingEngine:
         sched = self._c_part["sched", "book"]
         with phase("serving.step", stamp=True,
                    step=self._anat_steps) as step_ph:
-            self.anatomy.begin_step(self._anat_steps)
+            self.anatomy.begin_step(self._anat_steps, t0=step_ph.start)
             step_tokens = 0
             if isinstance(self.scheduler, SLOScheduler):
                 with phase("serving.shed", sched):
@@ -949,6 +1000,9 @@ class ServingEngine:
                 finished.update(self._evict())
                 if self.snapshot_every_blocks is not None:
                     self._take_micro_snapshots()
+            # a slot that owes its first token decodes in this step, and
+            # the decode round's read-back settles it
+            assert not self._owed, "a first token outlived its step"
 
             # what the observability itself costs each step
             with phase("serving.observe", self._c_part["observe", "book"]):
@@ -956,18 +1010,19 @@ class ServingEngine:
                     self.recompile_detector.check()
                 if self.slo_monitor is not None:
                     self.slo_monitor.check()
-                if prefilled_any or dslots:
-                    self.anatomy.end_step(tokens=step_tokens)
-                else:
-                    # an idle tick is not a serving step: recording it
-                    # would count queue-empty waiting as "host gap"
-                    self.anatomy.cancel_step()
                 self._refresh_health()
                 with self._health_lock:
                     snap = self._health_snap
                 self.flight.note(snap)
         if prefilled_any or dslots:
+            # the step's seconds and its anatomy record's wall are the
+            # step phase's one clock-read pair
             self._c_step_seconds.inc(step_ph.end - step_ph.start)
+            self.anatomy.end_step(tokens=step_tokens, t1=step_ph.end)
+        else:
+            # an idle tick is not a serving step: recording it would
+            # count queue-empty waiting as "host gap"
+            self.anatomy.cancel_step()
         return finished
 
     def _shed_expired(self):
@@ -1025,13 +1080,50 @@ class ServingEngine:
                     ("experts_touched", "moe_experts_touched"),
                     ("selected", "attn_selected_tokens")) if name in got})
 
+    def _read_back(self, phase: str, *handles):
+        """The host waits for the device: ``handles`` and every program
+        count dispatched since the last wait come over in one transfer
+        (the copies start together), and the counts go to their
+        counters. Inside ``step()`` nothing else reads a device value."""
+        unread, self._unread_counts = self._unread_counts, []
+        got, counts = jax.device_get((handles, [c for _, c in unread]))
+        self._c_readbacks[phase].inc()
+        for (call, _), c in zip(unread, counts):
+            self._note_step_stats(call, c)
+        return got
+
+    def _book_first_token(self, st, tok: int, now: float):
+        """The first generated token of a slot whose prompt is done,
+        stamped when the host learned it: closes the admit -> first
+        token half of the TTFT split."""
+        req = st.request
+        st.generated.append(tok)
+        st.first_token_at = now
+        acc = self._phase_acc.get(req.rid)
+        if acc is not None:
+            acc["prefill_done_s"] = now
+        ttft = now - req.submitted_at
+        self._h_ttft.observe(ttft)
+        self._h_admit_to_first.observe(now - st.admitted_at)
+        self._c_tokens.inc()
+        self.scheduler.note_ttft(ttft)
+        root = self._req_spans.get(req.rid)
+        if root is not None:
+            root.add_event("first_token", ttft_s=round(ttft, 6))
+
     def _decode_round(self, dslots) -> int:
         """Advance every decoding slot one block of ``decode_block``
-        tokens through the jitted decode step; returns tokens kept."""
+        tokens through the jitted decode step; returns tokens kept. A
+        slot whose prompt finished in this step and whose first token is
+        still on the device (``self._owed``) takes its input from there,
+        and the block's read-back brings that token over with the
+        block's own: the step's one wait."""
         n = self.decode_block
         s_tot = self.scheduler.num_slots
+        slots = self.scheduler.slots
         w = self._decode_width(dslots, n)
         phase, part = self.tracer.phase, self._c_part
+        owed, self._owed = self._owed, []
         with phase("serving.decode_round", width=w,
                    slots_live=len(dslots)) as rnd:
             with phase("serving.decode.assemble",
@@ -1039,23 +1131,33 @@ class ServingEngine:
                 tokens = np.zeros((s_tot,), np.int32)
                 active = np.zeros((s_tot,), np.int32)
                 for i in dslots:
-                    tokens[i] = self.scheduler.slots[i].generated[-1]
+                    if slots[i].generated:      # else owed: coded below
+                        tokens[i] = slots[i].generated[-1]
                     active[i] = 1
+                # an owed slot's entry says where its token is: lane j of
+                # the k-th owing call, as -(1 + j) - k * S (a token id is
+                # never negative), so the codes ride the tokens' upload
+                for k, (_, lanes) in enumerate(owed):
+                    for j, i in lanes:
+                        tokens[i] = -(1 + j) - k * s_tot
                 self._count_kv_bytes(dslots, n, w)
+                tok_dev = self._upload(tokens)
+                for nxt, _ in owed:
+                    tok_dev = self.first_token_step(tok_dev, nxt)
                 args = (jnp.asarray(self.cache.block_tables[:, :w]),
                         jnp.asarray(self.cache.lengths),
-                        jnp.asarray(tokens), jnp.asarray(active))
+                        tok_dev, jnp.asarray(active))
             with phase("serving.decode.dispatch",
                        part["decode", "dispatch"]):
                 out, self.cache.pages = self.decode_step(
                     self._step_params, self.cache.pages, *args)
             with phase("serving.decode.sync", part["decode", "sync"]) as sync:
-                if self._step_stats:     # counts ride the tokens' sync
+                if self._step_stats:
                     out, counts = out
-                    counts = np.asarray(counts)
-                out = np.asarray(out)                # (S, decode_block)
-            if self._step_stats:
-                self._note_step_stats(rnd, counts)
+                    self._unread_counts.append((rnd, counts))
+                # (S, decode_block), and the tokens the block started from
+                out, first = self._read_back(
+                    "decode", out, tok_dev if owed else None)
             # the call's wall time as it has always been taken: uploads,
             # dispatch and sync, from the phases' own clock reads
             t0, t1 = asm.start, sync.end
@@ -1066,6 +1168,9 @@ class ServingEngine:
             with phase("serving.decode.book", part["decode", "book"]):
                 tr_on = self.tracer.enabled
                 kept = 0
+                for _, lanes in owed:
+                    for _, i in lanes:
+                        self._book_first_token(slots[i], int(first[i]), t1)
                 for i in dslots:
                     st = self.scheduler.slots[i]
                     req = st.request
@@ -1159,6 +1264,7 @@ class ServingEngine:
                 # dispatch overlap
                 t_mid = self.tracer.now()
                 ver = np.asarray(ver)              # (S, spec_k) target greedy
+                self._c_readbacks["decode"].inc(2)
             t0, t1 = asm.start, sync.end
             self._h_decode_step.observe(t1 - t0)
             self.anatomy.add_phase("draft", t0, t_mid)
@@ -1309,6 +1415,7 @@ class ServingEngine:
         quantized, so int8 scale rows always travel with their page."""
         page = self.read_page_step(self.cache.pages,
                                    jnp.asarray(pid, jnp.int32))
+        self._c_readbacks["page_read"].inc()
         if self.quantized:
             return (np.asarray(page[0]), np.asarray(page[1]))
         return (np.asarray(page),)
@@ -1400,9 +1507,10 @@ class ServingEngine:
                        allow_liveness: bool = True) -> int:
         """Advance in-prefill slots' next prompt chunks through the
         batched fixed-shape prefill step, spending at most ``budget``
-        prompt tokens. Returns tokens computed. Slots whose prompt
-        completes get their first generated token from the same call
-        (closing the admit→first-token half of the TTFT split).
+        prompt tokens. Returns tokens computed. A slot whose prompt
+        completes has its first generated token computed by the same
+        call; the host learns it there only where the step needs it at
+        once, else at the decode round's read-back (:meth:`_prefill_call`).
 
         Each batched call computes up to ``lanes × prefill_chunk``
         tokens, so the lane count is capped by the budget left; when
@@ -1437,9 +1545,24 @@ class ServingEngine:
                 consumed += self._prefill_call(pslots)
         return consumed
 
+    def _reads_first_token_at_once(self, st) -> bool:
+        """Whether the step must know a finishing prompt's first token
+        before its decode round: the admission cascade evicts on it (an
+        ``eos_id``, a budget of one token), a speculative round reads
+        it on the host, and a prefill tier parks the slot for handoff
+        instead of decoding it. Facts of the request and of how the
+        engine was built; everything else waits for the block."""
+        req = st.request
+        return (req.eos_id is not None or req.max_new_tokens == 1
+                or self.speculative or self.tier != "colocated")
+
     def _prefill_call(self, pslots) -> int:
         """One batched fixed-shape prefill call over ``pslots``' next
-        chunks; returns the prompt tokens it computed."""
+        chunks; returns the prompt tokens it computed. The host waits
+        for the call only where a prompt ends in it whose first token
+        the step needs at once (:meth:`_reads_first_token_at_once`);
+        otherwise the tokens stay on the device, a finished prompt's as
+        ``self._owed`` for the decode round of this step."""
         c = self.prefill_chunk
         cfgc = self.cache.config
         slots = self.scheduler.slots
@@ -1454,6 +1577,12 @@ class ServingEngine:
         call_tokens = sum(ns)
         w = self._pow2_width(max(cfgc.pages_for(lo + n)
                                  for lo, n in zip(los, ns)))
+        # lanes whose prompt ends in this chunk: the only ones whose
+        # token anybody reads
+        ends = [(j, i) for j, (i, lo, n) in enumerate(zip(pslots, los, ns))
+                if lo + n >= int(slots[i].request.prompt.shape[0])]
+        wait = any(self._reads_first_token_at_once(slots[i])
+                   for _, i in ends)
         phase, part = self.tracer.phase, self._c_part
         with phase("serving.prefill_call", lanes=sb, width=w,
                    tokens=call_tokens) as call:
@@ -1501,7 +1630,7 @@ class ServingEngine:
                 dbt_dev = jnp.asarray(dbt_rows[:, :w]) if self.speculative \
                     else None
             with phase("serving.prefill.dispatch",
-                       part["prefill", "dispatch"]):
+                       part["prefill", "dispatch"]) as disp:
                 nxt, self.cache.pages = self.prefill_step(
                     self._step_params, self.cache.pages, bt_dev, *args)
                 if self.speculative:
@@ -1512,20 +1641,27 @@ class ServingEngine:
                     _, self.draft_cache.pages = self.draft_prefill_step(
                         self.draft_params, self.draft_cache.pages, dbt_dev,
                         *args)
-            with phase("serving.prefill.sync",
-                       part["prefill", "sync"]) as sync:
-                if self._step_stats:     # counts ride the tokens' sync
-                    nxt, counts = nxt
-                    counts = np.asarray(counts)
-                nxt = np.asarray(nxt)
-            if self._step_stats:
-                self._note_step_stats(call, counts)
-            t0, now = asm.start, sync.end
+            if self._step_stats:     # counts ride the next read-back
+                nxt, counts = nxt
+                self._unread_counts.append((call, counts))
+            if wait:
+                with phase("serving.prefill.sync",
+                           part["prefill", "sync"]) as sync:
+                    nxt, = self._read_back("prefill", nxt)
+                now = sync.end
+            else:
+                now = disp.end
+                if ends:
+                    self._owed.append((nxt, ends))
+            # the call's wall time: uploads, dispatch and what the call
+            # waited for, from the phases' own clock reads
+            t0 = asm.start
             self._h_prefill_step.observe(now - t0)
             self.anatomy.add_phase("prefill", t0, now)
             self._note_busy((("prefill", w, sb),)
                             + ((("draft_prefill", w, sb),)
-                               if self.speculative else ()), now - t0)
+                               if self.speculative else ()), now - t0,
+                            waited=wait)
             self._c_prefill_calls.inc()
             with phase("serving.prefill.book", part["prefill", "book"]):
                 tr_on = self.tracer.enabled
@@ -1548,20 +1684,8 @@ class ServingEngine:
                             parent=self._req_spans.get(rid), slot=i,
                             tokens=n, start_pos=st.prefilled - n,
                             call=call.span_id)
-                    if st.prefill_done:
-                        st.generated.append(int(nxt[j]))
-                        st.first_token_at = now
-                        if acc is not None:
-                            acc["prefill_done_s"] = now
-                        ttft = now - st.request.submitted_at
-                        self._h_ttft.observe(ttft)
-                        self._h_admit_to_first.observe(now - st.admitted_at)
-                        self._c_tokens.inc()
-                        self.scheduler.note_ttft(ttft)
-                        root = self._req_spans.get(rid)
-                        if root is not None:
-                            root.add_event("first_token",
-                                           ttft_s=round(ttft, 6))
+                    if wait and st.prefill_done:
+                        self._book_first_token(st, int(nxt[j]), now)
                 self._c_prefill_tokens.inc(call_tokens)
         return call_tokens
 
@@ -1620,6 +1744,8 @@ class ServingEngine:
                 if self.speculative:
                     plan.append(("draft_prefill", w, sb))
         plan.append(("copy_page",))
+        # a finishing call's tokens merged into the decode block's input
+        plan += [("first_token", sb) for sb in counts]
         # migration page IO: scalar-indexed, so one signature each
         # covers every page a fleet drain ever reads or writes
         plan.append(("page_read",))
@@ -1636,6 +1762,9 @@ class ServingEngine:
         if sig[0] in ("page_read", "page_write") \
                 and "migration" not in self.program.spec.supports:
             return False        # pages never leave this engine
+        if sig[0] == "first_token":
+            # where a finishing call is read at once nothing is merged
+            return self.tier == "colocated" and not self.speculative
         if self.tier == "prefill" and sig[0] == "decode":
             return False
         if self.tier == "decode" and sig[0] == "prefill":
@@ -1663,6 +1792,7 @@ class ServingEngine:
         else:
             sigs = {("decode", w) for w in widths}
         sigs |= {("prefill", w, sb) for w in widths for sb in counts}
+        sigs |= {("first_token", sb) for sb in counts}
         sigs.add(("copy_page",))
         sigs.add(("page_read",))
         sigs.add(("page_write",))
@@ -1689,6 +1819,7 @@ class ServingEngine:
         :attr:`bucket_costs` for budget audits."""
         s_tot = self.scheduler.num_slots
         zeros = jnp.zeros((s_tot,), jnp.int32)
+        tok0 = self._upload(np.zeros((s_tot,), np.int32))
         self.warmed_signatures = set()
         self.bucket_costs = {}
         clock = self.tracer.now
@@ -1697,7 +1828,7 @@ class ServingEngine:
             if sig[0] == "decode":
                 w = sig[1]
                 args = (self._step_params, self.cache.pages,
-                        jnp.zeros((s_tot, w), jnp.int32), zeros, zeros,
+                        jnp.zeros((s_tot, w), jnp.int32), zeros, tok0,
                         zeros)
                 if cost_gauges:
                     self._bucket_cost_gauges(sig, self.decode_step, args)
@@ -1741,6 +1872,9 @@ class ServingEngine:
                     self._bucket_cost_gauges(sig, self.draft_prefill_step,
                                              args)
                 _, self.draft_cache.pages = self.draft_prefill_step(*args)
+            elif sig[0] == "first_token":
+                self.first_token_step(
+                    tok0, self._upload(np.zeros((sig[1],), np.int32)))
             elif sig[0] == "page_read":
                 jax.block_until_ready(self.read_page_step(
                     self.cache.pages, jnp.asarray(0, jnp.int32)))
@@ -1890,8 +2024,10 @@ class ServingEngine:
             blocks = int(acc["decode_blocks"]) if acc else 0
             if blocks and blocks % k == 0 \
                     and self._last_snap_blocks.get(rid) != blocks:
-                self._micro_snaps[rid] = self.snapshot_slot(i)
+                snap = self._micro_snaps[rid] = self.snapshot_slot(i)
                 self._last_snap_blocks[rid] = blocks
+                self._c_readbacks["page_read"].inc(
+                    len(snap["shards"]) // self.tp)   # one wait a page
 
     def poll_micro_snapshots(self) -> Dict[int, Dict]:
         """Drain the micro-checkpoint outbox (``{rid: snapshot}``,
@@ -2637,6 +2773,19 @@ class ServingEngine:
                                   chunk, n_valid, program=self.program,
                                   quantized=self.quantized,
                                   all_positions=True)
+
+    def _first_token_impl(self, tokens, nxt):
+        """The decode block's input tokens ``(S,)`` with the first
+        tokens of the prompts one prefill call finished. An entry
+        ``-(1 + j)`` takes lane ``j`` of that call's ``nxt``; one that
+        codes a later call (``- k * S`` more) moves up a call; a token
+        stays. Fixed shape for a lane count, whatever number finished."""
+        s_tot = tokens.shape[0]
+        lane = -1 - tokens
+        return jnp.where(
+            (lane >= 0) & (lane < s_tot),
+            nxt[jnp.clip(lane, 0, nxt.shape[0] - 1)],
+            jnp.where(lane >= s_tot, tokens + s_tot, tokens))
 
     def _copy_page_impl(self, pages, src, dst):
         """Device-side page copy (CoW of a borrowed shared tail page):

@@ -128,3 +128,36 @@ def test_engine_has_what_the_runner_reaches(runner, tiny_engine):
     assert not missing, (
         f"benchmark/runners/{runner} reaches {missing} on the engine; a "
         "PR that may not edit benchmark/ must keep them")
+
+
+LAYER_METRICS = os.path.join(os.path.dirname(RUNNERS), "layer_metrics")
+
+
+def _series_names(name):
+    """The registry series a counter-ratio metric file names."""
+    import json
+    with open(os.path.join(LAYER_METRICS, name)) as f:
+        spec = json.load(f)
+    if not spec["reader"].startswith("registry_counter"):
+        return set()
+    sides = (spec["params"]["numerator"], spec["params"]["denominator"])
+    return {side["name"] if isinstance(side, dict) else side.split("{")[0]
+            for side in sides}
+
+
+#: the serving engine's metrics that read its registry by series name
+COUNTER_METRICS = sorted(n for n in os.listdir(LAYER_METRICS)
+                         if n.startswith("engine.") and _series_names(n))
+
+
+@pytest.mark.parametrize("name", COUNTER_METRICS)
+def test_engine_feeds_the_series_a_layer_metric_reads(name, tiny_engine):
+    """A metric file names the engine's counters as data; a counter
+    renamed in the engine would read as a metric gone quiet on the chip."""
+    import numpy as np
+    if not tiny_engine._reg.snapshot().get("serving_steps_total"):
+        tiny_engine.generate_many([np.arange(1, 12, dtype=np.int32)], 4)
+    have = {k.split("{")[0] for k in tiny_engine._reg.snapshot()}
+    wanted = _series_names(name)
+    assert wanted <= have, sorted(wanted - have)
+    assert "engine.readbacks_per_step.json" in COUNTER_METRICS

@@ -368,9 +368,10 @@ class TestEngineAnatomy:
             return sum(snap['serving_step_part_seconds_total{part="%s",'
                             'phase="%s"}' % (n, phase)] for n in names)
         for phase in ("prefill", "decode"):
-            # assemble.start .. sync.end, the interval these series had
-            # before the phases: the three parts plus the few microseconds
-            # between one phase's exit and the next's entry
+            # assemble.start .. the end of what the call waited for (its
+            # sync, or its dispatch where it read nothing back): the
+            # three parts plus the few microseconds between one phase's
+            # exit and the next's entry
             call_s = parts(phase, "assemble", "dispatch", "sync")
             got = eng.anatomy.summary()["phase_s"][phase]
             assert call_s <= got + 1e-6 and got <= call_s + 5e-3
@@ -384,12 +385,28 @@ class TestEngineAnatomy:
         assert len(asm) == len(sync) == len(calls) >= 1
         assert [(a.start, s.end) for a, s in zip(asm, sync)] == \
             [(c.start, c.end) for c in calls]
+        # ISSUE 31: no request here makes a prefill call read back, so a
+        # call's interval ends where its dispatch returned
+        assert tracer.spans(name="serving.prefill.sync") == []
+        asm = tracer.spans(name="serving.prefill.assemble")
+        disp = tracer.spans(name="serving.prefill.dispatch")
+        calls = tracer.spans(name="anatomy.prefill")
+        assert len(asm) == len(disp) == len(calls) >= 1
+        assert sorted((a.start, d.end) for a, d in zip(asm, disp)) == \
+            sorted((c.start, c.end) for c in calls)
         # the step's wall time bounds its parts, sync included
         step_s = snap["serving_step_seconds_total"]
         assert 0 < sum(v for k, v in snap.items() if k.startswith(
             "serving_step_part_seconds_total")) <= step_s
-        assert step_s <= sum(r["wall_s"] for r in eng.anatomy.records()) \
-            + 1e-3
+        # and is the anatomy records' wall, from the same clock reads
+        # (a record rounds to the nanosecond)
+        steps = tracer.spans(name="serving.step")
+        recs = eng.anatomy.records()
+        assert step_s == pytest.approx(sum(r["wall_s"] for r in recs),
+                                       abs=1e-8 * len(recs))
+        assert [r["wall_s"] for r in recs] == [
+            round(s.end - s.start, 9) for s in steps
+            if s.attrs["step"] in {r["step"] for r in recs}]
 
 
 # ---------------------------------------------------------------------------

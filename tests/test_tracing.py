@@ -929,6 +929,10 @@ class TestEngineStepPhases:
         eng.submit(prompt.copy(), 6)
         eng.submit(np.arange(20, 27, dtype=np.int32), 5)
         _drain(eng)
+        # a budget of one token: the step evicts on the first token, so
+        # this prompt's last prefill call is one that reads back
+        eng.submit(np.arange(30, 36, dtype=np.int32), 1)
+        _drain(eng)
         det.check()
         return eng, reg, tr, det
 
@@ -957,6 +961,16 @@ class TestEngineStepPhases:
         assert len(steps) >= 2 and steps == sorted(set(steps))
         call = next(s for s in spans if s.name == "serving.prefill_call")
         assert {"lanes", "width", "tokens"} <= set(call.attrs)
+        # ISSUE 31: only a call whose first token the step needs at once
+        # waits; every other call has no sync span, and a step waits once
+        calls = [s for s in spans if s.name == "serving.prefill_call"]
+        syncs = [s for s in spans if s.name == "serving.prefill.sync"]
+        assert len(calls) >= 4 and len(syncs) == 1
+        snap = reg.snapshot()
+        assert snap['serving_device_readbacks_total{phase="prefill"}'] == 1
+        assert snap['serving_device_readbacks_total{phase="decode"}'] == len(
+            [s for s in spans if s.name == "serving.decode.sync"]) \
+            == snap["serving_decode_rounds_total"]
         rnd = next(s for s in spans if s.name == "serving.decode_round")
         assert {"width", "slots_live"} <= set(rnd.attrs)
 
@@ -1041,6 +1055,7 @@ class TestEngineStepPhases:
         jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
         try:
             eng.submit(np.arange(1, 11, dtype=np.int32), 5)
+            eng.submit(np.arange(30, 36, dtype=np.int32), 1)   # reads back
             _drain(eng)
         finally:
             jax.profiler.stop_trace()
