@@ -24,7 +24,17 @@ configured; weights random from ``--seed``), in ONE process:
    against their ``lax_fn``, then one request of 2304 prompt tokens
    through the engine (dense kernels up to 2048 cached tokens, selection
    past them), every chosen token within ``SPARSE_TIE_MARGIN`` of the plain
-   float32 reference's best (``benchmark/families/keye_vl2.py``).
+   float32 reference's best (``benchmark/families/keye_vl2.py``);
+5. **hybrid family** — ``models/hybrid_ssm_lm.py`` at its published
+   widths (20 query heads over 4 KV heads of 128, 32 mixer heads of 128
+   in 2 groups, state 256, MLP 21504; ONE layer, a vocabulary of 8192
+   rows): the chunked scan and the one-token state update against their
+   ``lax_fn`` on float32 state tiles of ``(256, 128)``, then one request
+   of 300 prompt tokens (three chunks of 128, the last ragged) and 12
+   new ones through the engine, every chosen token within
+   ``HYBRID_TIE_MARGIN`` of the plain float32 reference's best
+   (``benchmark/families/falcon_h1.py``), and the slot's state row
+   changed while its neighbours' stayed zero.
 
 ``--chips 4`` runs INSTEAD (no one-chip phase): BERT-base under
 ``shard_train_step`` on a dp2 x tp2 mesh against the same steps on one
@@ -60,6 +70,10 @@ TIE_MARGIN = 0.05
 #: this far short (benchmark/configs/keye_vl2_30b_a3b.json, tie_margin:
 #: largest seen over ten seeds 0.36, with the selection off 0.78)
 SPARSE_TIE_MARGIN = 0.6
+#: the attention + state-space hybrid in bf16 against its float32
+#: reference, in logits that spread by about 0.01 under the published
+#: multipliers (benchmark/configs/falcon_h1_34b.json, tie_margin)
+HYBRID_TIE_MARGIN = 5e-4
 #: dp2 x tp2 vs one device: same math, different reduction order, bf16
 #: activations — relative tolerance on each step's loss
 MESH_LOSS_RTOL = 2e-2
@@ -86,6 +100,10 @@ class Sizes:
     sparse_page_size: int
     sparse_chunk: int
     sparse_prompt: int          # past topk, so decode selects
+    hybrid: dict                # HybridSSMLMConfig overrides
+    hybrid_page_size: int
+    hybrid_chunk: int
+    hybrid_prompt: int          # whole chunks and a ragged one
     interpret: bool = False
 
     @classmethod
@@ -97,7 +115,9 @@ class Sizes:
                    max_tokens_per_slot=128, prompt_lens=(40, 13, 70, 40, 40),
                    shared_prefix=32, new_tokens=12,
                    sparse=dict(num_hidden_layers=1), sparse_page_size=128,
-                   sparse_chunk=64, sparse_prompt=2304)
+                   sparse_chunk=64, sparse_prompt=2304,
+                   hybrid=dict(num_hidden_layers=1, vocab_size=8192),
+                   hybrid_page_size=128, hybrid_chunk=128, hybrid_prompt=300)
 
     @classmethod
     def tiny(cls):
@@ -118,6 +138,14 @@ class Sizes:
                                indexer_num_heads=2, indexer_head_dim=8,
                                indexer_topk=16),
                    sparse_page_size=4, sparse_chunk=8, sparse_prompt=22,
+                   hybrid=dict(vocab_size=96, hidden_size=64,
+                               num_hidden_layers=1, num_attention_heads=4,
+                               num_key_value_heads=2, head_dim=16,
+                               intermediate_size=96,
+                               max_position_embeddings=256, mamba_d_ssm=64,
+                               mamba_n_heads=4, mamba_d_head=16,
+                               mamba_n_groups=2, mamba_d_state=16),
+                   hybrid_page_size=4, hybrid_chunk=8, hybrid_prompt=19,
                    interpret=True)
 
     @property
@@ -488,6 +516,113 @@ def phase_sparse_family(sizes, seed):
         "sparse family left the reference"
 
 
+# ---------------------------------------------------------------------------
+# phase 5: the attention + state-space hybrid
+# ---------------------------------------------------------------------------
+
+def phase_hybrid_family(sizes, seed):
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import inference, kernels
+    from paddle_tpu.models.hybrid_ssm_lm import (HybridSSMLM,
+                                                 HybridSSMLMConfig)
+
+    impl = sizes.kernel_impl
+    cfg = HybridSSMLMConfig(kernel_impl=impl, **sizes.hybrid)
+    names = ("ssd_chunk_scan", "ssm_decode_update")
+    # -- the two kernels against their lax forms, at this model's widths:
+    # four lanes (one fresh, one ragged, one a pad lane on the null row)
+    rng = np.random.default_rng(seed)
+    h, p, g, n = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups,
+                  cfg.mamba_d_state)
+    s, c = 4, sizes.hybrid_chunk
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    dt = np.log1p(np.exp(rng.standard_normal((s, c, h)))).astype(np.float32)
+    dt[2, c // 2:] = 0.0
+    dt[3] = 0.0
+    scan = (normal(s, c, h * p), jnp.asarray(0.1 * dt),
+            -jnp.exp(0.5 * normal(h)), normal(s, c, g * n),
+            normal(s, c, g * n), normal(s + 2, h, n, p),
+            jnp.asarray([2, 5, 3, 0], jnp.int32),
+            jnp.asarray([0, 1, 0, 0], jnp.int32))
+    one = tuple(a[:, 0] for a in scan[:2]) + (scan[2],) + tuple(
+        a[:, 0] for a in scan[3:5]) + scan[5:7]
+    errs = {}
+    for name, args in (("ssd_chunk_scan", scan), ("ssm_decode_update", one)):
+        spec = kernels.get(name)
+        kw = dict(n_groups=g)
+        out = jax.jit(lambda *a, _n=name: kernels.dispatch(
+            _n, *a, impl=impl, **kw))(*args)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a, _n=name: kernels.dispatch(
+                _n, *a, impl="lax", **kw))(*args)
+        for got, want in zip(out, ref):
+            got, want = np.asarray(got), np.asarray(want)
+            assert got.shape == want.shape and np.isfinite(got).all(), name
+            np.testing.assert_allclose(
+                got, want, atol=spec.contract.atol * max(
+                    1.0, float(np.abs(want).max())),
+                rtol=spec.contract.rtol, err_msg=f"{name} {impl} vs lax")
+            errs[name] = max(errs.get(name, 0.0),
+                             float(np.max(np.abs(got - want))))
+        idle = [r for r in range(1, s + 2) if r not in (2, 5, 3)]
+        assert (np.asarray(out[1])[idle] == np.asarray(args[5])[idle]).all(), \
+            f"{name} touched a row no lane holds"
+    log("hybrid family kernels vs lax max|err|: " + json.dumps(errs))
+
+    # -- a short serve through the engine, against the plain reference
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "benchmark"))
+    from families import falcon_h1
+    model = HybridSSMLM(cfg)
+    params = jax.jit(lambda k: model.init(k, dtype=jnp.bfloat16))(
+        jax.random.PRNGKey(seed))
+    before = {(k, i): _dispatched(k, i) for k in names
+              for i in (impl, "lax")}
+    n0, n_new = sizes.hybrid_prompt, sizes.new_tokens
+    pages = -(-(n0 + n_new + 8) // sizes.hybrid_page_size)
+    eng = inference.make_serving_engine(
+        model, params, num_slots=2, page_size=sizes.hybrid_page_size,
+        prefill_chunk=sizes.hybrid_chunk, attn_impl=impl,
+        max_tokens_per_slot=pages * sizes.hybrid_page_size)
+    prompt = np.random.default_rng(seed + 5).integers(
+        0, cfg.vocab_size, n0).astype(np.int32)
+    t0 = time.perf_counter()
+    rid = eng.submit(prompt, n_new)
+    while not eng.scheduler.idle():
+        eng.step()
+    out = np.asarray(eng.result(rid))
+    t_serve = time.perf_counter() - t0
+    for k in names:
+        assert _dispatched(k, impl) > before[(k, impl)], \
+            f"{k} never resolved to {impl}"
+        assert _dispatched(k, "lax") == before[(k, "lax")], \
+            f"{k} fell back to lax"
+    state = np.asarray(eng.cache.pages[0][-1])
+    assert state.dtype == np.float32 and state[1].any(), \
+        "the slot's state row never changed"
+    assert not state[2].any(), "a neighbour's state row changed"
+    ids = np.concatenate([prompt, out]).astype(np.int32)[None]
+    with jax.default_matmul_precision("highest"):
+        logits = np.asarray(jax.jit(
+            lambda pr, i: falcon_h1.reference_logits(
+                pr, i, falcon_h1.sizes_of(cfg), n0 - 1, n_new))(
+            params, jnp.asarray(ids)))[0].astype(np.float64)
+    gaps = logits.max(-1) - logits[np.arange(n_new), out]
+    log(f"hybrid family: {n0}-token prompt + {n_new} tokens in "
+        f"{t_serve:.1f}s (compiles included); {int((gaps == 0).sum())}/"
+        f"{n_new} tokens are the float32 reference's argmax, largest "
+        f"shortfall {gaps.max():.3e} logits of a spread of "
+        f"{logits.std():.3e} (tolerance {HYBRID_TIE_MARGIN})")
+    assert gaps.max() < HYBRID_TIE_MARGIN, \
+        "hybrid family left the reference"
+
+
 def _sparse_kernel_args(name, cfg, sizes, seed):
     """One call's arguments of kernel ``name`` at ``cfg``'s widths: bf16
     pools of 2 slots x 20 pages under float32 queries (as phase 1: the
@@ -630,6 +765,7 @@ def run_one_chip(sizes, seed=0):
     phase_trainer(sizes, seed)
     phase_serving(sizes, seed)
     phase_sparse_family(sizes, seed)
+    phase_hybrid_family(sizes, seed)
 
 
 def run_four_chips(sizes, seed=0, devices=None):

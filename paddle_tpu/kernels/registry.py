@@ -128,6 +128,7 @@ _HOME_MODULES = (
     "paddle_tpu.serving.decode_attention",
     "paddle_tpu.serving.sparse_attention",
     "paddle_tpu.ops.grouped_ffn",
+    "paddle_tpu.ops.ssm_scan",
     "paddle_tpu.parallel.ring_attention",
 )
 

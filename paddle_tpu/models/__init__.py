@@ -28,6 +28,7 @@ from paddle_tpu.models.ocr import CRNN
 from paddle_tpu.models.gan import (DCGANDiscriminator, DCGANGenerator,
                                    gan_step)
 from paddle_tpu.models.sparse_moe_lm import SparseMoELM, SparseMoELMConfig
+from paddle_tpu.models.hybrid_ssm_lm import HybridSSMLM, HybridSSMLMConfig
 
 __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "ResNet", "ResNet50", "DeepFM", "Transformer",
@@ -35,4 +36,5 @@ __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "MachineTranslation", "RNNLanguageModel", "SentimentCNN", "SentimentLSTM", "SkipGramNS", "Word2Vec", "RecommenderSystem",
            "MobileNetV1", "MobileNetV2", "VGG", "VGG16", "SEResNeXt",
            "SEResNeXt50", "AlexNet", "DarkNet53", "DenseNet121", "GoogLeNet", "ShuffleNetV2", "SqueezeNet", "SSD", "SSDConfig", "FasterRCNN", "FasterRCNNConfig", "MaskRCNN", "C3D", "TSN", "YOLOv3", "YOLOv3Config", "CRNN", "DCGANGenerator", "DCGANDiscriminator", "gan_step",
-           "SparseMoELM", "SparseMoELMConfig"]
+           "SparseMoELM", "SparseMoELMConfig", "HybridSSMLM",
+           "HybridSSMLMConfig"]

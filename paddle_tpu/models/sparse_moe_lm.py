@@ -37,6 +37,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.models.common import rms_norm as _rms_norm
+from paddle_tpu.models.common import rope as _rope
 from paddle_tpu.ops.attention import NEG_INF
 from paddle_tpu.ops.grouped_ffn import grouped_expert_ffn
 from paddle_tpu.serving.program import ServingSpec
@@ -79,12 +81,6 @@ class SparseMoELMConfig:
         return cls(**kw)
 
 
-def _rms_norm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
 def _layer_norm(x, p, eps=1e-6):
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
@@ -92,22 +88,6 @@ def _layer_norm(x, p, eps=1e-6):
     y = (x32 - mu) * jax.lax.rsqrt(var + eps)
     return (y * p["scale"].astype(jnp.float32)
             + p["bias"].astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, positions, theta):
-    """Rotary positions over the whole last axis, rotate-half pairing
-    ``(i, i + d/2)``. ``x`` (S, C, heads, d) or (S, C, d); ``positions``
-    (S, C)."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[..., None] * inv       # (S,C,d/2)
-    if x.ndim == 4:
-        ang = ang[:, :, None, :]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    a, b = x32[..., :d // 2], x32[..., d // 2:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           -1).astype(x.dtype)
 
 
 class SparseMoELM:
@@ -259,8 +239,8 @@ class SparseMoELM:
 
     def serving(self, **unsupported):
         """This model's block as the paged serving engine runs it. It
-        takes none of the engine's sharding options yet (``spec.supports``
-        is empty, so the engine refuses them before asking)."""
+        shares prompt prefixes and takes none of the engine's other options
+        yet (``spec.supports``, so the engine refuses them before asking)."""
         if unsupported:
             raise ValueError(f"SparseMoELM.serving() takes no options yet, "
                              f"got {sorted(unsupported)}")
@@ -288,7 +268,7 @@ class SparseMoEServing:
             select_topk=c.indexer_topk,
             stats=("moe_assignments", "moe_experts_touched",
                    "moe_expert_slots", "moe_max_expert_tokens"),
-            supports=frozenset())
+            supports=frozenset({"prefix_sharing"}))
 
     def param_dtype(self, params):
         return params["embed"]["weight"].dtype

@@ -221,7 +221,7 @@ class ServingEngine:
                  prefill_chunk: int = 32, decode_block: int = 8,
                  prefill_budget: Optional[int] = None,
                  attn_impl: str = "auto", cache_dtype=None,
-                 prefix_sharing: bool = True,
+                 prefix_sharing: Optional[bool] = None,
                  scheduler_policy: str = "slo",
                  lanes: Sequence[str] = ("interactive", "default", "batch"),
                  max_queue_depth: Optional[int] = None,
@@ -290,13 +290,14 @@ class ServingEngine:
             "host_spill": host_spill_pages > 0,
             "migration": snapshot_every_blocks is not None,
             "tiers": tier != "colocated",
+            "prefix_sharing": bool(prefix_sharing),
         }
         for feature, wanted in wants.items():
-            if wanted and feature not in spec.supports:
-                raise ValueError(
-                    f"{type(model).__name__} does not serve with "
-                    f"{feature!r} yet (its serving program supports "
-                    f"{sorted(spec.supports) or 'none of the options'})")
+            if wanted:
+                self._require(feature, "ServingEngine()", spec)
+        # left unsaid, prompts share their prefixes where the program can
+        if prefix_sharing is None:
+            prefix_sharing = "prefix_sharing" in spec.supports
         if draft_model is not None \
                 and "draft" not in self.draft_program.spec.supports:
             raise ValueError(f"{type(draft_model).__name__} cannot be a "
@@ -374,7 +375,9 @@ class ServingEngine:
             head_dim=cfg.head_dim,
             num_slots=num_slots, page_size=page_size, num_pages=num_pages,
             max_pages_per_slot=max_pages_per_slot, dtype=dtype,
-            share_prefix=prefix_sharing, extra_rows=spec.extra_rows),
+            share_prefix=prefix_sharing, extra_rows=spec.extra_rows,
+            slot_state=spec.slot_state,
+            slot_state_dtype=jnp.dtype(spec.slot_state_dtype)),
             mesh=self.mesh,
             host_spill_pages=host_spill_pages)
         self.quantized = self.cache.config.quantized
@@ -666,6 +669,7 @@ class ServingEngine:
             "serving_flops_utilization",
             "retired static flops per busy second / best observed rate"
         ).child()
+        self._bind_state_metrics(r)
         self._c_step_stats = [
             r.counter(f"serving_{name}_total", _STEP_STAT_HELP.get(
                 name, "a count the step program hands back")).child()
@@ -682,13 +686,62 @@ class ServingEngine:
         self._c_warm_cost = warm.child(part="cost_gauges")
         self._c_warm_first = warm.child(part="first_call")
 
-    def _require(self, feature: str, what: str):
-        """Refuse, by name, a call the model's serving program does not
-        carry yet (its pool entries hold rows this path would lose)."""
-        if feature not in self.program.spec.supports:
+    def _bind_state_metrics(self, r):
+        """The series of a program that keeps state a slot
+        (``spec.slot_state``), fed from counts the host already holds;
+        a program without keeps none of them."""
+        self._state_slot_bytes = self.cache.state_bytes_per_slot()
+        if not self._state_slot_bytes:
+            return
+        self._c_ssm_decode = r.counter(
+            "serving_ssm_decode_slot_steps_total",
+            "live slots x token steps x layers through the one-token "
+            "state update").child()
+        self._c_ssm_prefill = r.counter(
+            "serving_ssm_prefill_tokens_total",
+            "valid prompt tokens x layers through the chunked scan").child()
+        moved = r.counter(
+            "serving_ssm_state_bytes_total",
+            "slot-state bytes (conv window + head states, every layer) "
+            "the steps read and wrote: a decode token step reads and "
+            "writes each live slot's; a prefill call writes each lane's "
+            "and reads it unless the lane's prompt starts there")
+        self._c_ssm_read = moved.child(kind="read")
+        self._c_ssm_written = moved.child(kind="written")
+        self._c_ssm_resets = r.counter(
+            "serving_ssm_state_resets_total",
+            "prompts started from a zero state (one an admission)").child()
+        r.gauge("serving_ssm_state_pool_bytes",
+                "bytes of the slot-state pool, null row included").set(
+                    self._state_slot_bytes * (self.scheduler.num_slots + 1))
+
+    def _count_state(self, span, decoding: int = 0, token_steps: int = 0,
+                     lanes: int = 0, fresh: int = 0, tokens: int = 0):
+        """One decode round's or prefill call's slot-state work, and the
+        span's ``state_slots``."""
+        if not self._state_slot_bytes:
+            return
+        layers = self.cache.config.num_layers
+        self._c_ssm_decode.inc(decoding * token_steps * layers)
+        self._c_ssm_prefill.inc(tokens * layers)
+        self._c_ssm_resets.inc(fresh)
+        b = self._state_slot_bytes
+        self._c_ssm_read.inc(b * (decoding * token_steps + lanes - fresh))
+        self._c_ssm_written.inc(b * (decoding * token_steps + lanes))
+        if span is not None:
+            span.set_attrs(state_slots=decoding + lanes)
+
+    def _require(self, feature: str, what: str, spec=None):
+        """The one refusal of an option or call the model's serving
+        program does not carry yet (its pool entries hold rows or state
+        this path would lose): the model's class and the feature, by
+        name. ``spec``: the program's, while the engine is being built."""
+        spec = spec or self.program.spec
+        if feature not in spec.supports:
             raise ValueError(
                 f"{what}: {type(self.model).__name__} does not serve "
-                f"with {feature!r} yet")
+                f"with {feature!r} yet (its serving program supports "
+                f"{sorted(spec.supports) or 'none of the options'})")
 
     # -- request surface --------------------------------------------------
 
@@ -1141,6 +1194,8 @@ class ServingEngine:
                     for j, i in lanes:
                         tokens[i] = -(1 + j) - k * s_tot
                 self._count_kv_bytes(dslots, n, w)
+                self._count_state(rnd.span, decoding=len(dslots),
+                                  token_steps=n)
                 tok_dev = self._upload(tokens)
                 for nxt, _ in owed:
                     tok_dev = self.first_token_step(tok_dev, nxt)
@@ -1626,7 +1681,17 @@ class ServingEngine:
                         dbt_rows[j] = self.draft_cache.block_tables[i]
                 args = (jnp.asarray(starts), jnp.asarray(tokens),
                         jnp.asarray(nv))
-                bt_dev = jnp.asarray(bt_rows[:, :w])
+                bt_rows = bt_rows[:, :w]
+                if self._state_slot_bytes:
+                    # a lane says whose state it holds, pool row slot + 1,
+                    # in one more column of its table (a pad lane: row 0)
+                    state_rows = np.zeros((sb, 1), np.int32)
+                    state_rows[:len(pslots), 0] = np.asarray(pslots) + 1
+                    bt_rows = np.concatenate([bt_rows, state_rows], axis=1)
+                bt_dev = jnp.asarray(bt_rows)
+                self._count_state(call.span, lanes=len(pslots),
+                                  fresh=sum(lo == 0 for lo in los),
+                                  tokens=call_tokens)
                 dbt_dev = jnp.asarray(dbt_rows[:, :w]) if self.speculative \
                     else None
             with phase("serving.prefill.dispatch",
@@ -1855,7 +1920,8 @@ class ServingEngine:
                 w, sb = sig[1], sig[2]
                 zb = jnp.zeros((sb,), jnp.int32)
                 args = (self._step_params, self.cache.pages,
-                        jnp.zeros((sb, w), jnp.int32), zb,
+                        jnp.zeros((sb, w + bool(self._state_slot_bytes)),
+                                  jnp.int32), zb,
                         jnp.zeros((sb, self.prefill_chunk), jnp.int32),
                         zb)
                 if cost_gauges:
@@ -2595,6 +2661,7 @@ class ServingEngine:
         w = block_tables.shape[1]
         slot_ids = jnp.arange(s_tot)
         n_stats = len(self._stat_names(spec))
+        n_paged = len(pages[0]) - len(spec.slot_state)
 
         def one_token(j, pages, lengths, tokens):
             pos = jnp.minimum(lengths, spec.max_position - 1)
@@ -2608,17 +2675,27 @@ class ServingEngine:
                 0)
             off = lengths % ps
             seen = jnp.where(writable, lengths + 1, 0).sum()
+            if spec.slot_state:
+                # a decoding slot's state is pool row slot + 1; any other
+                # slot's (free, or mid-prefill and owning live state) is
+                # not this block's to touch: the null row
+                state_rows = jnp.where(writable, slot_ids + 1, 0)
             new_pages, counts = [], 0
             for i in range(spec.num_layers):
                 q, rows, index = program.attn_in(params, i, x, pos[:, None])
                 ent = self._write_rows(
-                    pages[i], tuple(r[:, 0] for r in rows), page_idx, off,
-                    quantized, psum_axis)
+                    pages[i][:n_paged], tuple(r[:, 0] for r in rows),
+                    page_idx, off, quantized, psum_axis)
                 att, attended = self._attend_decode(
                     spec, q[:, :, 0, :], ent, block_tables, lengths + 1,
                     index, quantized)                           # (S,H,Dh)
+                x_in, x = x, program.attn_out(params, i, x, att[:, None])
+                if spec.slot_state:
+                    mixed, state = program.mixer(
+                        params, i, x_in, pages[i][n_paged:], state_rows,
+                        jnp.zeros_like(state_rows), writable[:, None])
+                    ent, x = ent + tuple(state), x + mixed
                 new_pages.append(ent)
-                x = program.attn_out(params, i, x, att[:, None])
                 x, ffn_stats = program.ffn(params, i, x, writable[:, None])
                 if n_stats:
                     counts = counts + self._step_stat_vector(
@@ -2694,6 +2771,14 @@ class ServingEngine:
         spec = program.spec
         ps = self.cache.config.page_size
         s_tot, c = tokens.shape
+        n_paged = len(pages[0]) - len(spec.slot_state)
+        if spec.slot_state:
+            # the lanes' state rows ride the tables' last column; a lane
+            # whose prompt starts here starts from zeros, its slot's
+            # reset at admission
+            state_rows, block_tables = block_tables[:, -1], \
+                block_tables[:, :-1]
+            fresh = (starts == 0).astype(jnp.int32)
         w = block_tables.shape[1]
         positions = starts[:, None] + jnp.arange(c, dtype=jnp.int32)
         pos_e = jnp.minimum(positions, spec.max_position - 1)
@@ -2714,13 +2799,18 @@ class ServingEngine:
         new_pages, counts = [], 0
         for i in range(spec.num_layers):
             q, rows, index = program.attn_in(params, i, x, pos_e)
-            ent = self._write_rows(pages[i], rows, page_idx, off, quantized,
-                                   psum_axis)
+            ent = self._write_rows(pages[i][:n_paged], rows, page_idx, off,
+                                   quantized, psum_axis)
             att = self._attend_prefill(
                 spec, q.transpose(0, 2, 1, 3), ent, block_tables, starts,
                 n_valid, index, quantized)                      # (S,C,H,Dh)
+            x_in, x = x, program.attn_out(params, i, x, att)
+            if spec.slot_state:
+                mixed, state = program.mixer(
+                    params, i, x_in, pages[i][n_paged:], state_rows, fresh,
+                    valid)
+                ent, x = ent + tuple(state), x + mixed
             new_pages.append(ent)
-            x = program.attn_out(params, i, x, att)
             x, ffn_stats = program.ffn(params, i, x, valid)
             if counting:
                 counts = counts + self._step_stat_vector(
@@ -2793,10 +2883,9 @@ class ServingEngine:
         including the scale rows of a quantized pool, which travel with
         their page. Fixed shape — src/dst are traced scalars, so one
         compile covers every copy."""
-        out = []
-        for ent in pages:
-            out.append(tuple(a.at[dst].set(a[src]) for a in ent))
-        return out
+        n_paged = self.cache.config.paged_entries
+        return [tuple(a.at[dst].set(a[src]) for a in ent[:n_paged])
+                + tuple(ent[n_paged:]) for ent in pages]
 
     def _read_page_impl(self, pages, src):
         """One page's K/V across every layer, stacked (2, L, page_size,

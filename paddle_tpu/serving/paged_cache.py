@@ -59,6 +59,20 @@ stays exact). Dequantization happens INSIDE the dequant-attend kernels
 (:mod:`~paddle_tpu.serving.decode_attention`), fused into the QK and
 PV products — no fp page is ever materialized.
 
+Per-slot state (ISSUE 32): a program whose layers carry a recurrence
+declares ``slot_state`` and the one manager then holds a second kind of
+cache: per layer and entry an array ``(num_slots + 1,) + shape`` beside
+the layer's page pools, in the same ``pages[layer]`` tuple (so it threads
+through, and is donated into, the same jitted steps). It is indexed by
+SLOT, not by page: row ``slot + 1`` is the slot's, row 0 the null row that
+pad lanes and non-decoding slots point at. Fixed size whatever the
+sequence length; never shared, copied on write, published, spilled or
+shipped (``copy_page_step`` / ``read_page`` / ``write_page`` leave the
+entries alone), so a pool with slot state has prefix sharing off. A row
+holds whatever its last request left: the step that starts a prompt
+starts from zeros (``fresh`` in :mod:`~paddle_tpu.serving.program`), which
+is the reset at admission with no program of its own.
+
 Tensor parallel (ISSUE 15): pass ``mesh=`` (a mesh with a ``tp`` axis
 of size > 1) and the page pool becomes **per-shard**: the K/V page
 arrays are placed sharded over ``tp`` on the folded HEAD axis (each mesh
@@ -103,6 +117,11 @@ class PagedCacheConfig:
     #: page_size)`` (tokens along the lanes), allocated, shared, copied
     #: on write and freed with its page
     extra_rows: Tuple[Tuple[str, int], ...] = ()
+    #: state kept per SLOT and layer, ``(name, shape)`` each: one more
+    #: array a layer, ``(num_slots + 1,) + shape`` of ``slot_state_dtype``
+    #: (row 0 the null row), beside the page pools and not paged
+    slot_state: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
+    slot_state_dtype: object = jnp.float32
 
     def __post_init__(self):
         if self.page_size < 1 or self.num_pages < 2:
@@ -112,10 +131,22 @@ class PagedCacheConfig:
             raise ValueError("max_pages_per_slot must be >= 1")
         if self.extra_rows and self.quantized:
             raise ValueError("an int8 pool carries no extra rows yet")
+        if self.slot_state and self.quantized:
+            raise ValueError("an int8 pool carries no slot state yet")
+        if self.slot_state and self.share_prefix:
+            raise ValueError(
+                "a pool with slot state cannot share prefixes: a prefix "
+                "hit would skip the tokens that built the state")
 
     @property
     def max_tokens_per_slot(self) -> int:
         return self.max_pages_per_slot * self.page_size
+
+    @property
+    def paged_entries(self) -> int:
+        """Arrays of a layer's entry that are page pools; the slot-state
+        arrays follow them."""
+        return 4 if self.quantized else 2 + len(self.extra_rows)
 
     @property
     def quantized(self) -> bool:
@@ -374,12 +405,15 @@ class PagedKVCache:
             self.pages: List[Tuple[jnp.ndarray, ...]] = [
                 (jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype),
                  *(jnp.zeros((c.num_pages, width, c.page_size), c.dtype)
-                   for _name, width in c.extra_rows))
+                   for _name, width in c.extra_rows),
+                 *(jnp.zeros((c.num_slots + 1,) + tuple(shape),
+                             c.slot_state_dtype)
+                   for _name, shape in c.slot_state))
                 for _ in range(c.num_layers)]
         if self.mesh is not None:
-            if c.extra_rows:
+            if c.extra_rows or c.slot_state:
                 raise ValueError("a tp-sharded pool carries no extra "
-                                 "rows yet")
+                                 "rows and no slot state yet")
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
             kv_s = NamedSharding(self.mesh, P(None, None, "tp"))
@@ -458,9 +492,16 @@ class PagedKVCache:
         sharding (each shard holds its head slice of the same page)."""
         total = 0
         for layer in self.pages:
-            for arr in layer:
+            for arr in layer[:self.config.paged_entries]:
                 total += arr.nbytes
         return total // self.config.num_pages
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of slot state one slot holds across every layer (0 for
+        a pool without)."""
+        return sum(arr.nbytes for layer in self.pages
+                   for arr in layer[self.config.paged_entries:]) \
+            // (self.config.num_slots + 1)
 
     def capacity_bytes(self) -> int:
         """HBM bytes of the allocatable pool (null page excluded)."""

@@ -12,6 +12,8 @@ over the parameter tree, each called inside the engine's jitted steps on
     attn_out(params, i, x, att (S,C,H,Dh))                -> x
     ffn(params, i, x, valid (S,C) bool)                   -> x, stats
     head(params, x (..., D))                              -> logits (..., V)
+    mixer(params, i, x, state, rows (S,), fresh (S,), valid (S,C))
+                                                          -> y (S,C,D), state
 
 ``attn_in`` returns the layer's queries ``q`` (S, H, C, Dh), the ``rows``
 to cache for every token, a tuple of (S, C, lanes) arrays: K and V with
@@ -23,6 +25,19 @@ table says, runs attention over the pool, and hands the heads back to
 ``attn_out``. ``attn_out`` and ``ffn`` return the residual stream with
 their block added. ``ffn`` may return a dict of scalar counts (names from
 ``spec.stats``) about the tokens ``valid`` marks, or None.
+
+``mixer`` is called only for a program that declares ``spec.slot_state``:
+state of a fixed size that a layer keeps a SLOT, not a token (a
+recurrence's state, a conv window). The engine keeps one pool array a
+layer and entry, ``(num_slots + 1,) + shape``, row 0 the null row, beside
+the layer's K and V pools, and calls ``mixer`` with the layer's input
+``x`` (the one ``attn_in`` gets), the pools as ``state``, the pool row of
+every lane (0 for a pad lane or a slot that is not decoding), ``fresh``
+where a lane's prompt starts in this call (its row's content is another
+request's: start from zeros) and ``valid`` marking a lane's real tokens,
+which come first. It returns what the block adds to the residual stream
+beside ``attn_out``'s and the pools with every lane's row advanced past
+its valid tokens; rows of other slots stay as they were, bit for bit.
 
 What a program cannot do yet it leaves out of ``spec.supports``; the
 engine refuses, by name, an option that needs it.
@@ -51,6 +66,7 @@ FEATURES = frozenset({
     "migration",        # slot snapshot / restore, micro-checkpoints
     "tiers",            # prefill / decode disaggregation with handoff
     "prefix_export",    # published prefix pages shipped between replicas
+    "prefix_sharing",   # published prompt pages mapped instead of prefilled
 })
 
 
@@ -69,4 +85,9 @@ class ServingSpec:
     select_topk: Optional[int] = None
     #: counts ``ffn`` hands back, summed into ``serving_<name>_total``
     stats: Tuple[str, ...] = ()
+    #: state kept per SLOT and layer beside the pages, ``(name, shape)``
+    #: each: one pool array a layer, ``(num_slots + 1,) + shape``, read
+    #: and written by ``mixer``; never shared, copied on write or shipped
+    slot_state: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
+    slot_state_dtype: str = "float32"
     supports: FrozenSet[str] = FEATURES

@@ -467,3 +467,107 @@ def test_sparse_decode_reads_its_gathered_rows_from_hbm(doc_steps):
     gathered = re.findall(
         rf"bf16\[{DOC_SLOTS * 16},{DOC_PS},512\]\{{[^}}]*\}}", text)
     assert gathered and not [g for g in gathered if "S(1)" in g], gathered[:2]
+
+
+# -- the attention + state-space hybrid at the chat cell's geometry -----------
+
+H1_SLOTS, H1_PS, H1_PAGES, H1_CHUNK, H1_LAYERS = 64, 128, 1025, 128, 2
+H1_HEADS, H1_P, H1_GROUPS, H1_STATE = 32, 128, 2, 256
+
+
+@pytest.mark.parametrize("name, lanes, chunk", [
+    ("ssd_chunk_scan", 4, 128), ("ssd_chunk_scan", 1, 256),
+    ("ssm_decode_update", H1_SLOTS, None)],
+    ids=["scan-4lanes-c128", "scan-1lane-2tiles", "decode-64slots"])
+def test_state_space_kernel_compiles_for_v5e(name, lanes, chunk, one_chip):
+    """The two state-space kernels at the published tile sizes: 32 heads,
+    state tiles of (256, 128) float32 (the published (128, 256),
+    state-major), a pool of 65 rows (that a step which donates it gets
+    it back in place is read off the whole steps below)."""
+    sds = jax.ShapeDtypeStruct
+    tok = (lanes,) if chunk is None else (lanes, chunk)
+    args = (sds(tok + (H1_HEADS * H1_P,), jnp.float32),
+            sds(tok + (H1_HEADS,), jnp.float32),
+            sds((H1_HEADS,), jnp.float32),
+            sds(tok + (H1_GROUPS * H1_STATE,), jnp.float32),
+            sds(tok + (H1_GROUPS * H1_STATE,), jnp.float32),
+            sds((H1_SLOTS + 1, H1_HEADS, H1_STATE, H1_P), jnp.float32),
+            sds((lanes,), jnp.int32))
+    if chunk is not None:
+        args += (sds((lanes,), jnp.int32),)
+    _compile_kernel(name, args, one_chip, n_groups=H1_GROUPS)
+
+
+@pytest.fixture(scope="module")
+def h1_steps(topo):
+    """lower(step, lanes, width) -> compiled, for the hybrid family at its
+    published widths, two layers: abstract bf16 weights, an engine with a
+    nine-page pool and two slots, the steps lowered on the cell's pools of
+    shapes (1025 pages, 65 state rows)."""
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import inference
+    from paddle_tpu.models.hybrid_ssm_lm import (HybridSSMLM,
+                                                 HybridSSMLMConfig)
+    model = HybridSSMLM(HybridSSMLMConfig(num_hidden_layers=H1_LAYERS,
+                                          kernel_impl="pallas"))
+    params = jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    eng = inference.make_serving_engine(
+        model, params, num_slots=2, page_size=H1_PS, num_pages=9,
+        max_tokens_per_slot=2048, prefill_chunk=H1_CHUNK, decode_block=8,
+        attn_impl="pallas", cache_dtype=jnp.bfloat16)
+    dev = SingleDeviceSharding(topo.devices[0])
+    sds = jax.ShapeDtypeStruct
+    weights = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, sharding=dev), params)
+    paged = eng.cache.config.paged_entries
+    pages = [tuple(sds(((H1_PAGES if k < paged else H1_SLOTS + 1),)
+                       + a.shape[1:], a.dtype, sharding=dev)
+                   for k, a in enumerate(ent)) for ent in eng.cache.pages]
+
+    def i32(*shape):
+        return sds(shape, jnp.int32, sharding=dev)
+
+    def lower(step, lanes, width):
+        if step == "decode":
+            return eng.decode_step.lower(
+                weights, pages, i32(lanes, width), i32(lanes), i32(lanes),
+                i32(lanes)).compile()
+        # a prefill lane's table carries its state row in one more column
+        return eng.prefill_step.lower(
+            weights, pages, i32(lanes, width + 1), i32(lanes),
+            i32(lanes, H1_CHUNK), i32(lanes)).compile()
+
+    return lower
+
+
+@pytest.mark.parametrize("step, lanes, width, kernels_in", [
+    ("decode", H1_SLOTS, 16, {"ragged_paged_decode", "ssm_decode_update"}),
+    ("prefill", 4, 8, {"ragged_paged_prefill", "ssd_chunk_scan"})],
+    ids=["decode-w16", "prefill-4lanes-w8"])
+def test_hybrid_family_steps_compile_and_keep_the_pools(
+        step, lanes, width, kernels_in, h1_steps):
+    """The hybrid's decode block and prefill step compile for the chip at
+    the chat cell's geometry: 20 query heads over 4 KV heads in the dense
+    paged kernels (a group of 5), chunks of 128, the state update over 64
+    slots inside the 8-token loop. No step copies a K or V pool, a state
+    pool or a conv-window pool; each comes in row-major; temporaries stay
+    under one layer's state pool (272 MB)."""
+    import re
+    compiled = h1_steps(step, lanes, width)
+    text = compiled.as_text()
+    names = {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
+    assert names == kernels_in
+    pools = (rf"bf16\[{H1_PAGES},{H1_PS},512\]",
+             rf"f32\[{H1_SLOTS + 1},{H1_HEADS},{H1_STATE},{H1_P}\]",
+             rf"f32\[{H1_SLOTS + 1},15360\]")
+    entry = text[text.index("ENTRY "):]
+    for pool, layout in zip(pools, ("{2,1,0", "{3,2,1,0", "{1,0")):
+        copies = [line for line in text.splitlines()
+                  if re.search(r"= " + pool + r"\S* copy\(", line)]
+        assert not copies, copies[:2]
+        layouts = set(re.findall(pool + r"(\{[\d,]+)[^ ]* parameter\(",
+                                 entry))
+        assert layouts == {layout}, (pool, layouts)
+    assert compiled.memory_analysis().temp_size_in_bytes < 272 << 20
