@@ -42,6 +42,16 @@ of one token (the admission cascade evicts on it), a speculative engine
 (its round reads the token on the host), a prefill tier (the slot parks
 for handoff). ``serving_device_readbacks_total{phase}`` counts the waits.
 
+And the block a step reads is the one the step BEFORE dispatched
+(ISSUE 34): ``step()`` dispatches block k, then settles block k-1, so at
+most one decode block is in flight across calls and the host's per-step
+work (book, evict, observe, the caller's submits, admit, the prefill and
+decode uploads and dispatches) runs beside the device, not between two
+of its blocks. What is decided at dispatch, what is learned at settle
+and which engines and calls settle at once: :meth:`ServingEngine.step`.
+``serving_decode_blocks_overlapped_total`` counts the blocks sent while
+the one before was unread.
+
 Prefix sharing: admission maps published prompt-prefix pages straight
 into the new slot's block table (refcount bump, prefill skipped for the
 shared tokens — see ``paged_cache``) and the engine performs the single
@@ -129,6 +139,7 @@ histogram (``slo_burn_rate`` gauge + edge-triggered
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import threading
 import time
@@ -197,6 +208,27 @@ MIGRATION_FORMAT = "paddle_tpu.serving.slot-migration-v1"
 PREFIX_BUNDLE_FORMAT = "paddle_tpu.serving.prefix-pages-v1"
 
 
+@dataclasses.dataclass
+class _Block:
+    """A decode block between its dispatch and its settle: what the
+    device holds, and what the host promised on its strength."""
+    out: object             # (S, decode_block) tokens, on the device
+    started_from: object    # its input tokens on the device where a row
+                            # owes its first token, else None
+    counts: list            # program counts that come over with it:
+                            # ``(the call's phase, handle)``
+    rows: Dict[int, tuple]  # slot -> (rid, tokens to keep, owes its first)
+    width: int
+    rnd: object             # the round's phase (its span names the block)
+    t0: float               # assemble.start
+    t1: float               # dispatch.end
+
+    def promised(self, slot: int, rid: int) -> int:
+        """Tokens the settle will hand ``rid`` in ``slot``."""
+        row = self.rows.get(slot)
+        return row[1] + row[2] if row is not None and row[0] == rid else 0
+
+
 class SlotMigrationError(RuntimeError):
     """A slot snapshot cannot be restored: corrupt shard (sha256
     mismatch), incompatible cache geometry, or no free slot/pages on
@@ -209,8 +241,9 @@ class ServingEngine:
 
     ``submit()`` enqueues a request (optionally tagging an SLO lane and
     a TTFT deadline), ``step()`` advances the engine one iteration
-    (admit + budgeted batched prefill + one decode block + evict), and
-    ``generate_many()`` drives the loop to completion. Decoding is
+    (admit + budgeted batched prefill + dispatch one decode block +
+    settle the one before + evict), and ``generate_many()`` drives the
+    loop to completion. Decoding is
     greedy — the deterministic serving mode the paged-vs-dense parity
     tests pin down.
     """
@@ -511,11 +544,17 @@ class ServingEngine:
             placed = dict(in_shardings=whole, out_shardings=whole)
         self.first_token_step = jax.jit(self._first_token_impl, **placed)
         #: first tokens the host has not read yet: ``(the finishing
-        #: call's tokens on the device, [(lane, slot), ...])``; empty
-        #: whenever ``step()`` returns
+        #: call's tokens on the device, [(lane, slot), ...])``; the
+        #: step's decode block takes them over, so empty whenever
+        #: ``step()`` returns
         self._owed: List[tuple] = []
-        #: program counts not read yet: ``(the call's phase, handle)``
+        #: program counts of calls no block has taken over yet: ``(the
+        #: call's phase, handle)``
         self._unread_counts: List[tuple] = []
+        #: the decode block in flight: dispatched, not read (``step()``)
+        self._pending: Optional[_Block] = None
+        self._last_settle_end = 0.0
+        self._left_step_at = 0.0
         # migration page IO (fleet drain): src/dst are traced scalars,
         # so ONE compile each covers every page ever moved
         self.read_page_step = jax.jit(self._read_page_impl)
@@ -552,6 +591,13 @@ class ServingEngine:
             if snapshot_every_blocks < 1:
                 raise ValueError("snapshot_every_blocks must be >= 1")
         self.snapshot_every_blocks = snapshot_every_blocks
+        # where something reads a slot's tokens or pages between two
+        # blocks, a block is settled in the step that dispatched it: a
+        # speculative round reads ``generated`` on the host, a tier
+        # hands slots over after every step, micro-checkpoints read
+        # pages after every block. Facts of how the engine was built
+        self._settles_at_once = (self.speculative or tier != "colocated"
+                                 or snapshot_every_blocks is not None)
         self._micro_snaps: Dict[int, Dict] = {}
         self._last_snap_blocks: Dict[int, int] = {}
         # externally-minted trace ids (router propagation) so
@@ -597,7 +643,18 @@ class ServingEngine:
             "batched prefill calls (one per serving.prefill_call)").child()
         self._c_decode_rounds = r.counter(
             "serving_decode_rounds_total",
-            "decode rounds (one per serving.decode_round)").child()
+            "decode blocks dispatched (one per serving.decode_round that "
+            "had a slot to advance)").child()
+        self._c_overlapped = r.counter(
+            "serving_decode_blocks_overlapped_total",
+            "decode blocks dispatched while the block before was still "
+            "unread: the host's work of a step ran beside the device"
+        ).child()
+        self._c_discarded = r.counter(
+            "serving_decode_discarded_tokens_total",
+            "tokens a decode block computed for a request that had "
+            "ended by then (an eos_id inside the block, or inside the "
+            "block before while this one was in flight)").child()
         back = r.counter(
             "serving_device_readbacks_total",
             "times the host waited for a device value inside step(), by "
@@ -620,7 +677,10 @@ class ServingEngine:
                                 * np.dtype(c.dtype).itemsize * c.num_layers)
         self._h_decode_step = r.histogram(
             "serving_decode_step_seconds",
-            "wall time per decode block (sync included)",
+            "wall time per decode block: assemble.start to the end of "
+            "its read-back, or from the end of the read-back before "
+            "where the block was dispatched ahead of it, less what the "
+            "caller spent between the two step() calls",
             buckets=_STEP_BUCKETS).child()
         self._h_prefill_step = r.histogram(
             "serving_prefill_step_seconds",
@@ -996,9 +1056,39 @@ class ServingEngine:
     def step(self) -> Dict[int, np.ndarray]:
         """One engine iteration: shed expired-deadline queue entries,
         admit into free slots, advance every admitted request's prefill
-        under the interleaving budget, advance every decoding slot one
-        block, evict finished sequences. Returns ``{rid: generated
-        tokens}`` for requests that finished now.
+        under the interleaving budget, **dispatch** the next decode block
+        for every slot with budget left, **settle** the block before it
+        (the step's one read-back), evict finished sequences. Returns
+        ``{rid: generated tokens}`` for requests the host learned to be
+        finished now.
+
+        At most one decode block is in flight across calls
+        (``self._pending``): block k is dispatched before block k-1 is
+        read, so the device goes from k-1 straight into this step's
+        prefill calls and block k while the host books, evicts, observes
+        and assembles the next step beside it.
+
+        - *Known at dispatch* (:meth:`_dispatch_block`): which slots
+          advance and how many of the block's tokens each keeps (its
+          budget net of what is in flight), ``cache.lengths`` advanced
+          by that, the input tokens as device values (the block in
+          flight's last column, a prefill call's first token, or a token
+          the host has).
+        - *Known at settle* (:meth:`_settle_block`): the tokens, so first
+          tokens (TTFT), ``generated``, an ``eos_id`` hit, and who is
+          finished. A request is therefore evicted, and its successor
+          admitted, one block later than it was computed.
+        - *Settled in the step that dispatched it* (the synchronous
+          engine is the same two calls back to back): a speculative
+          engine, a prefill or decode tier, ``snapshot_every_blocks``
+          (``self._settles_at_once``). *Settled on entry*
+          (:meth:`_settle_pending`): ``snapshot_slot``, ``release_slot``,
+          ``restore_slot``, ``cancel_queued``, ``export_prefix_pages``,
+          ``import_prefix_pages``, ``poll_handoffs``,
+          ``poll_micro_snapshots`` and a spill's page read.
+
+        A step with nothing to dispatch settles what is pending, so a
+        loop to ``scheduler.idle()`` ends with every request returned.
 
         Every layer boundary inside is one ``tracer.phase`` (names and
         parents: PERF.md section 3): a profiler annotation, a ring span
@@ -1011,6 +1101,12 @@ class ServingEngine:
         with phase("serving.step", stamp=True,
                    step=self._anat_steps) as step_ph:
             self.anatomy.begin_step(self._anat_steps, t0=step_ph.start)
+            if self._pending is not None:
+                # what the caller did between two steps is no part of
+                # the interval of the block in flight
+                away = step_ph.start - self._left_step_at
+                self._pending.t0 += away
+                self._last_settle_end += away
             step_tokens = 0
             if isinstance(self.scheduler, SLOScheduler):
                 with phase("serving.shed", sched):
@@ -1037,29 +1133,33 @@ class ServingEngine:
                 # drains them via poll_handoffs); only the handoff-fallback
                 # slots explicitly flagged decode-in-place decode here
                 dslots = [i for i in dslots if i in self._decode_in_place]
-            if dslots:
-                # occupancy/utilization of the batch the decode step
-                # actually runs with (recorded before eviction, which
-                # empties finished slots' lengths)
-                self._g_occupancy.set(len(dslots) / self.scheduler.num_slots)
-                self._g_page_util.set(self.cache.utilization())
+            # a slot whose budget the block in flight exhausts is not
+            # dispatched again: its settle finishes it
+            dslots = [i for i in dslots if self._budget_left(i) > 0]
+            decoded = bool(dslots) or self._pending is not None
+            if decoded:
+                if dslots:
+                    # occupancy/utilization of the batch the decode step
+                    # actually runs with (recorded before eviction, which
+                    # empties finished slots' lengths)
+                    self._g_occupancy.set(
+                        len(dslots) / self.scheduler.num_slots)
+                    self._g_page_util.set(self.cache.utilization())
                 if self.speculative:
-                    kept = self._speculative_round(dslots)
+                    step_tokens += self._speculative_round(dslots)
                 else:
-                    kept = self._decode_round(dslots)
-                step_tokens += kept
-                self._c_tokens.inc(kept)
+                    step_tokens += self._decode_round(dslots)
                 self._c_steps.inc()
                 finished.update(self._evict())
                 if self.snapshot_every_blocks is not None:
                     self._take_micro_snapshots()
             # a slot that owes its first token decodes in this step, and
-            # the decode round's read-back settles it
+            # its block carries the debt to its settle
             assert not self._owed, "a first token outlived its step"
 
             # what the observability itself costs each step
             with phase("serving.observe", self._c_part["observe", "book"]):
-                if dslots:
+                if decoded:
                     self.recompile_detector.check()
                 if self.slo_monitor is not None:
                     self.slo_monitor.check()
@@ -1067,7 +1167,7 @@ class ServingEngine:
                 with self._health_lock:
                     snap = self._health_snap
                 self.flight.note(snap)
-        if prefilled_any or dslots:
+        if prefilled_any or decoded:
             # the step's seconds and its anatomy record's wall are the
             # step phase's one clock-read pair
             self._c_step_seconds.inc(step_ph.end - step_ph.start)
@@ -1076,7 +1176,23 @@ class ServingEngine:
             # an idle tick is not a serving step: recording it would
             # count queue-empty waiting as "host gap"
             self.anatomy.cancel_step()
+        self._left_step_at = step_ph.end
         return finished
+
+    def _budget_left(self, slot: int) -> int:
+        """Tokens ``slot``'s request may still be dispatched for: its
+        ``max_new_tokens`` net of what the host holds and of what the
+        block in flight will hand it."""
+        st = self.scheduler.slots[slot]
+        return (st.request.max_new_tokens - len(st.generated)
+                - self._flying(slot))
+
+    def _flying(self, slot: int) -> int:
+        """Tokens the block in flight will hand ``slot``'s request."""
+        if self._pending is None:
+            return 0
+        return self._pending.promised(
+            slot, self.scheduler.slots[slot].request.rid)
 
     def _shed_expired(self):
         """Deadline shedding: queued requests whose TTFT deadline passed
@@ -1133,12 +1249,16 @@ class ServingEngine:
                     ("experts_touched", "moe_experts_touched"),
                     ("selected", "attn_selected_tokens")) if name in got})
 
-    def _read_back(self, phase: str, *handles):
-        """The host waits for the device: ``handles`` and every program
-        count dispatched since the last wait come over in one transfer
-        (the copies start together), and the counts go to their
-        counters. Inside ``step()`` nothing else reads a device value."""
-        unread, self._unread_counts = self._unread_counts, []
+    def _read_back(self, phase: str, unread, *handles):
+        """The host waits for the device: ``handles`` and the program
+        counts ``unread`` (``(the call's phase, handle)``, those of the
+        calls queued before what is read) come over in one transfer (the
+        copies start together), and the counts go to their counters.
+        Inside ``step()`` nothing else reads a device value, and a step
+        calls this once: to settle the decode block dispatched by the
+        step before (by itself where the engine settles at once). More
+        only where a finishing prompt's first token is needed at once
+        (:meth:`_reads_first_token_at_once`)."""
         got, counts = jax.device_get((handles, [c for _, c in unread]))
         self._c_readbacks[phase].inc()
         for (call, _), c in zip(unread, counts):
@@ -1165,95 +1285,182 @@ class ServingEngine:
             root.add_event("first_token", ttft_s=round(ttft, 6))
 
     def _decode_round(self, dslots) -> int:
-        """Advance every decoding slot one block of ``decode_block``
-        tokens through the jitted decode step; returns tokens kept. A
-        slot whose prompt finished in this step and whose first token is
-        still on the device (``self._owed``) takes its input from there,
-        and the block's read-back brings that token over with the
-        block's own: the step's one wait."""
+        """One decode round: dispatch a block of ``decode_block`` tokens
+        for ``dslots`` (:meth:`_dispatch_block`) and settle a block
+        (:meth:`_settle_block`): the one dispatched by the round before,
+        so that the new one stays in flight beside the host's work until
+        the next round; or, where the engine settles at once
+        (``self._settles_at_once``) and nothing is ever in flight, the
+        new one itself. With no ``dslots`` it settles what is pending.
+        Returns tokens kept."""
+        w = self._decode_width(dslots, self.decode_block) if dslots else 0
+        with self.tracer.phase("serving.decode_round", width=w,
+                               slots_live=len(dslots)) as rnd:
+            if not dslots:
+                return self._settle_pending()
+            due = self._pending
+            new = self._pending = self._dispatch_block(dslots, w, rnd)
+            if self._settles_at_once:
+                due, self._pending = new, None
+            if due is None:         # the first block of a run: no wait
+                self.anatomy.add_phase("decode", new.t0, new.t1)
+                return 0
+            return self._settle_block(due, since=new.t0)
+
+    def _dispatch_block(self, dslots, w: int, rnd) -> _Block:
+        """Queue one block of ``decode_block`` tokens for ``dslots`` at
+        gather width ``w`` behind whatever the device holds, and return
+        its record without waiting. Decided here, from what the host
+        knows: how many of the block's tokens each slot keeps (its
+        budget net of the block in flight, ``self._pending``), and
+        ``cache.lengths`` advanced by that (the pages were reserved at
+        admission). A slot's input token is a device value wherever the
+        host has not read it: the last column of the block in flight, or
+        lane j of a prefill call of this step (``self._owed``); both are
+        coded as negative entries of the uploaded token vector and
+        merged there by ``first_token_step``."""
         n = self.decode_block
         s_tot = self.scheduler.num_slots
         slots = self.scheduler.slots
-        w = self._decode_width(dslots, n)
         phase, part = self.tracer.phase, self._c_part
+        prev = self._pending
         owed, self._owed = self._owed, []
-        with phase("serving.decode_round", width=w,
-                   slots_live=len(dslots)) as rnd:
-            with phase("serving.decode.assemble",
-                       part["decode", "assemble"]) as asm:
-                tokens = np.zeros((s_tot,), np.int32)
-                active = np.zeros((s_tot,), np.int32)
-                for i in dslots:
-                    if slots[i].generated:      # else owed: coded below
-                        tokens[i] = slots[i].generated[-1]
-                    active[i] = 1
-                # an owed slot's entry says where its token is: lane j of
-                # the k-th owing call, as -(1 + j) - k * S (a token id is
-                # never negative), so the codes ride the tokens' upload
-                for k, (_, lanes) in enumerate(owed):
-                    for j, i in lanes:
-                        tokens[i] = -(1 + j) - k * s_tot
-                self._count_kv_bytes(dslots, n, w)
-                self._count_state(rnd.span, decoding=len(dslots),
-                                  token_steps=n)
-                tok_dev = self._upload(tokens)
-                for nxt, _ in owed:
-                    tok_dev = self.first_token_step(tok_dev, nxt)
-                args = (jnp.asarray(self.cache.block_tables[:, :w]),
-                        jnp.asarray(self.cache.lengths),
-                        tok_dev, jnp.asarray(active))
-            with phase("serving.decode.dispatch",
-                       part["decode", "dispatch"]):
-                out, self.cache.pages = self.decode_step(
-                    self._step_params, self.cache.pages, *args)
-            with phase("serving.decode.sync", part["decode", "sync"]) as sync:
-                if self._step_stats:
-                    out, counts = out
-                    self._unread_counts.append((rnd, counts))
-                # (S, decode_block), and the tokens the block started from
-                out, first = self._read_back(
-                    "decode", out, tok_dev if owed else None)
-            # the call's wall time as it has always been taken: uploads,
-            # dispatch and sync, from the phases' own clock reads
-            t0, t1 = asm.start, sync.end
-            self._h_decode_step.observe(t1 - t0)
-            self.anatomy.add_phase("decode", t0, t1)
-            self._note_busy((("decode", w),), t1 - t0)
-            self._c_decode_rounds.inc()
-            with phase("serving.decode.book", part["decode", "book"]):
-                tr_on = self.tracer.enabled
-                kept = 0
-                for _, lanes in owed:
-                    for _, i in lanes:
-                        self._book_first_token(slots[i], int(first[i]), t1)
-                for i in dslots:
-                    st = self.scheduler.slots[i]
-                    req = st.request
-                    budget_i = req.max_new_tokens - len(st.generated)
-                    kept_i = 0
-                    for j in range(min(n, budget_i)):
-                        tok = int(out[i, j])
-                        st.generated.append(tok)
-                        kept_i += 1
-                        if req.eos_id is not None and tok == req.eos_id:
-                            break
-                    kept += kept_i
-                    if not st.finished():
-                        # device advanced this slot the full block
-                        self.cache.lengths[i] += n
-                    acc = self._phase_acc.get(req.rid)
-                    if acc is not None:
-                        acc["decode_s"] += t1 - t0
-                        acc["decode_blocks"] += 1
-                    if tr_on:
-                        # lanes run in the same batched call, so the spans
-                        # share the interval — a parallel track per
-                        # request; ``call`` names the round that caused it
-                        self.tracer.record_span(
-                            "serving.decode_block", start=t0, end=t1,
-                            parent=self._req_spans.get(req.rid),
-                            slot=i, tokens=kept_i, call=rnd.span_id)
+        owing = {i for _, lanes in owed for _, i in lanes}
+        with phase("serving.decode.assemble",
+                   part["decode", "assemble"]) as asm:
+            tokens = np.zeros((s_tot,), np.int32)
+            active = np.zeros((s_tot,), np.int32)
+            rows: Dict[int, tuple] = {}
+            carried = []
+            for i in dslots:
+                st = slots[i]
+                owes = i in owing
+                rows[i] = (st.request.rid,
+                           min(n, self._budget_left(i) - owes), owes)
+                active[i] = 1
+                if self._flying(i):
+                    carried.append(i)
+                elif not owes:
+                    tokens[i] = st.generated[-1]
+            # an entry says where its token is on the device: lane j of
+            # the k-th call to merge, as -(1 + j) - k * S (a token id is
+            # never negative), so the codes ride the tokens' upload. The
+            # block in flight, where a slot goes on from it, is call 0
+            # and a slot's lane there is the slot
+            calls = ([(prev.out, [(i, i) for i in carried])]
+                     if carried else []) + owed
+            for k, (_, lanes) in enumerate(calls):
+                for j, i in lanes:
+                    tokens[i] = -(1 + j) - k * s_tot
+            self._count_kv_bytes(dslots, n, w)
+            self._count_state(rnd.span, decoding=len(dslots), token_steps=n)
+            tok_dev = self._upload(tokens)
+            for nxt, _ in calls:
+                tok_dev = self.first_token_step(tok_dev, nxt)
+            # copies: an upload may read its host array after it returns,
+            # and these two change under a block in flight (the lengths
+            # just below, a table when its slot is freed)
+            args = (jnp.asarray(self.cache.block_tables[:, :w].copy()),
+                    jnp.asarray(self.cache.lengths.copy()),
+                    tok_dev, jnp.asarray(active))
+            for i, (_, keep, _) in rows.items():
+                self.cache.lengths[i] += keep
+        with phase("serving.decode.dispatch",
+                   part["decode", "dispatch"]) as disp:
+            out, self.cache.pages = self.decode_step(
+                self._step_params, self.cache.pages, *args)
+        # the program's counts, and those of the step's prefill calls
+        # queued before it, come over when this block is read
+        counts, self._unread_counts = self._unread_counts, []
+        if self._step_stats:
+            out, own = out
+            counts.append((rnd, own))
+        self._c_decode_rounds.inc()
+        if prev is not None:
+            self._c_overlapped.inc()
+        return _Block(out=out, started_from=tok_dev if owed else None,
+                      counts=counts, rows=rows, width=w, rnd=rnd,
+                      t0=asm.start, t1=disp.end)
+
+    def _settle_block(self, blk: _Block, since: Optional[float] = None
+                      ) -> int:
+        """Read one dispatched block and book it; returns tokens kept
+        (counted in ``serving_tokens_total`` here). ``since``: where the
+        round's decode interval began, if with a dispatch before this.
+        The read-back brings the block's tokens, the first tokens it
+        started from and every count pending with it; first tokens are
+        booked (TTFT stamped at ``sync.end``, when the host learned
+        them), then each row's tokens up to what dispatch promised or an
+        ``eos_id``. A row whose request left its slot since (it ended in
+        the block before, while this one was in flight) is dropped:
+        ``serving_decode_discarded_tokens_total``. The spans carry
+        ``block``, the span id of the round that dispatched it."""
+        phase, part = self.tracer.phase, self._c_part
+        slots = self.scheduler.slots
+        rnd = blk.rnd
+        with phase("serving.decode.sync", part["decode", "sync"],
+                   block=rnd.span_id) as sync:
+            # (S, decode_block), and the tokens the block started from
+            out, first = self._read_back("decode", blk.counts, blk.out,
+                                         blk.started_from)
+        # the block's wall time: uploads, dispatch and sync; where it
+        # was dispatched before the last read-back, since that one (the
+        # cadence at which a client is handed blocks, net of the
+        # caller's own time between steps: ``step()`` shifts both)
+        t0, t1 = max(blk.t0, self._last_settle_end), sync.end
+        self._last_settle_end = t1
+        self._h_decode_step.observe(t1 - t0)
+        # the step's anatomy holds what the host spent on the round:
+        # the new block's uploads and dispatch, then this wait
+        self.anatomy.add_phase(
+            "decode", sync.start if since is None else since, t1)
+        self._note_busy((("decode", blk.width),), t1 - t0)
+        with phase("serving.decode.book", part["decode", "book"],
+                   block=rnd.span_id):
+            tr_on = self.tracer.enabled
+            kept = discarded = 0
+            for i, (rid, keep, owes) in blk.rows.items():
+                st = slots[i]
+                if st is None or st.request.rid != rid:
+                    discarded += keep
+                    continue
+                req = st.request
+                if owes:
+                    self._book_first_token(st, int(first[i]), t1)
+                kept_i = 0
+                for j in range(keep):
+                    tok = int(out[i, j])
+                    st.generated.append(tok)
+                    kept_i += 1
+                    if req.eos_id is not None and tok == req.eos_id:
+                        break
+                kept += kept_i
+                discarded += keep - kept_i
+                acc = self._phase_acc.get(rid)
+                if acc is not None:
+                    acc["decode_s"] += t1 - t0
+                    acc["decode_blocks"] += 1
+                if tr_on:
+                    # lanes run in the same batched call, so the spans
+                    # share the interval — a parallel track per
+                    # request; ``call`` names the round that caused it
+                    self.tracer.record_span(
+                        "serving.decode_block", start=t0, end=t1,
+                        parent=self._req_spans.get(rid),
+                        slot=i, tokens=kept_i, call=rnd.span_id)
+            if discarded:
+                self._c_discarded.inc(discarded)
+        self._c_tokens.inc(kept)
         return kept
+
+    def _settle_pending(self) -> int:
+        """Settle the block in flight, if any, with no dispatch before
+        it: a step with nothing to dispatch, and the entry of every call
+        that reads or moves a slot's tokens or pages between two steps.
+        A request this finishes outside ``step()`` stays in its slot for
+        the next ``step()`` to evict and return. Returns tokens kept."""
+        blk, self._pending = self._pending, None
+        return self._settle_block(blk) if blk is not None else 0
 
     def _speculative_round(self, dslots) -> int:
         """One speculative decode round (ISSUE 13): the draft model
@@ -1329,6 +1536,7 @@ class ServingEngine:
             with phase("serving.decode.book", part["decode", "book"]):
                 kept = self._book_speculative(dslots, props, ver, nv,
                                               t0, t1, rnd.span_id)
+        self._c_tokens.inc(kept)
         return kept
 
     def _book_speculative(self, dslots, props, ver, nv, t0, t1,
@@ -1467,7 +1675,10 @@ class ServingEngine:
         """Cache spill callback: read one page to host through the
         warmed ``("page_read",)`` signature. Returns the host arrays
         the spill pool stores — ``(kv,)`` or ``(kv, scales)`` when
-        quantized, so int8 scale rows always travel with their page."""
+        quantized, so int8 scale rows always travel with their page.
+        The host waits for the page, so the block in flight is settled
+        first: the step's wait stays the block's."""
+        self._settle_pending()
         page = self.read_page_step(self.cache.pages,
                                    jnp.asarray(pid, jnp.int32))
         self._c_readbacks["page_read"].inc()
@@ -1712,7 +1923,8 @@ class ServingEngine:
             if wait:
                 with phase("serving.prefill.sync",
                            part["prefill", "sync"]) as sync:
-                    nxt, = self._read_back("prefill", nxt)
+                    unread, self._unread_counts = self._unread_counts, []
+                    nxt, = self._read_back("prefill", unread, nxt)
                 now = sync.end
             else:
                 now = disp.end
@@ -1809,8 +2021,10 @@ class ServingEngine:
                 if self.speculative:
                     plan.append(("draft_prefill", w, sb))
         plan.append(("copy_page",))
-        # a finishing call's tokens merged into the decode block's input
+        # a finishing call's tokens merged into the decode block's
+        # input, and the last tokens of the block in flight
         plan += [("first_token", sb) for sb in counts]
+        plan.append(("last_token",))
         # migration page IO: scalar-indexed, so one signature each
         # covers every page a fleet drain ever reads or writes
         plan.append(("page_read",))
@@ -1830,6 +2044,9 @@ class ServingEngine:
         if sig[0] == "first_token":
             # where a finishing call is read at once nothing is merged
             return self.tier == "colocated" and not self.speculative
+        if sig[0] == "last_token":
+            # where a block is settled at once none is gone on from
+            return not self._settles_at_once
         if self.tier == "prefill" and sig[0] == "decode":
             return False
         if self.tier == "decode" and sig[0] == "prefill":
@@ -1858,6 +2075,7 @@ class ServingEngine:
             sigs = {("decode", w) for w in widths}
         sigs |= {("prefill", w, sb) for w in widths for sb in counts}
         sigs |= {("first_token", sb) for sb in counts}
+        sigs.add(("last_token",))
         sigs.add(("copy_page",))
         sigs.add(("page_read",))
         sigs.add(("page_write",))
@@ -1941,6 +2159,9 @@ class ServingEngine:
             elif sig[0] == "first_token":
                 self.first_token_step(
                     tok0, self._upload(np.zeros((sig[1],), np.int32)))
+            elif sig[0] == "last_token":
+                self.first_token_step(tok0, self._upload(
+                    np.zeros((s_tot, self.decode_block), np.int32)))
             elif sig[0] == "page_read":
                 jax.block_until_ready(self.read_page_step(
                     self.cache.pages, jnp.asarray(0, jnp.int32)))
@@ -2008,6 +2229,7 @@ class ServingEngine:
         scale rows alongside the int8 KV — ONE shard, one hash over
         both, so a transfer can never split a page from its scales."""
         self._require("migration", "snapshot_slot")
+        self._settle_pending()
         if self.speculative:
             raise SlotMigrationError(
                 "speculative engines do not migrate slots (the draft "
@@ -2100,6 +2322,7 @@ class ServingEngine:
         newest per request). The fleet replica handle forwards these to
         the router, which keeps the latest as the warm-restore seed
         bounding re-decode work after a crash."""
+        self._settle_pending()
         out, self._micro_snaps = self._micro_snaps, {}
         return out
 
@@ -2113,6 +2336,7 @@ class ServingEngine:
         snapshot), ...]``; the router streams each snapshot to a
         decode-tier replica's :meth:`restore_slot`. Empty on
         non-prefill tiers (and on an idle prefill tier)."""
+        self._settle_pending()
         if self.tier != "prefill":
             return []
         out = []
@@ -2157,6 +2381,7 @@ class ServingEngine:
         trace), and the phase/trace maps are cleaned so nothing leaks.
         Returns the popped :class:`~paddle_tpu.serving.Request`s in
         queue order."""
+        self._settle_pending()
         out: List[Request] = []
         sched = self.scheduler
         while sched.queue:
@@ -2177,6 +2402,7 @@ class ServingEngine:
         popped :class:`~paddle_tpu.serving.SlotState` (the drain path's
         receipt). The request lives on wherever its snapshot was
         restored."""
+        self._settle_pending()
         st = self.scheduler.slots[slot]
         if st is None:
             raise SlotMigrationError(f"slot {slot} is empty")
@@ -2215,6 +2441,7 @@ class ServingEngine:
         ``parent_span`` when given), keeping one timeline across the
         migration."""
         self._require("migration", "restore_slot")
+        self._settle_pending()
         if self.speculative:
             raise SlotMigrationError(
                 "speculative engines do not migrate slots (the draft "
@@ -2355,6 +2582,7 @@ class ServingEngine:
         missing parent on the importer anyway. Returns None when
         nothing is exportable — the router degrades to re-prefill."""
         self._require("prefix_export", "export_prefix_pages")
+        self._settle_pending()
         if not self.cache.config.share_prefix:
             return None
         cfgc = self.cache.config
@@ -2435,6 +2663,7 @@ class ServingEngine:
         signature. Returns pages installed (0 when everything was
         already held — not an error)."""
         self._require("prefix_export", "import_prefix_pages")
+        self._settle_pending()
         if bundle is None or not self.cache.config.share_prefix:
             return 0
         if bundle.get("format") != PREFIX_BUNDLE_FORMAT:
@@ -2865,11 +3094,16 @@ class ServingEngine:
                                   all_positions=True)
 
     def _first_token_impl(self, tokens, nxt):
-        """The decode block's input tokens ``(S,)`` with the first
-        tokens of the prompts one prefill call finished. An entry
-        ``-(1 + j)`` takes lane ``j`` of that call's ``nxt``; one that
-        codes a later call (``- k * S`` more) moves up a call; a token
-        stays. Fixed shape for a lane count, whatever number finished."""
+        """The decode block's input tokens ``(S,)`` with the tokens one
+        earlier call left on the device: the first tokens of the prompts
+        a prefill call finished (``nxt`` (lanes,)), or the last column of
+        the decode block in flight (``nxt`` (S, decode_block)), where a
+        slot goes on from the token it ended on. An entry ``-(1 + j)``
+        takes lane ``j`` of that call's ``nxt``; one that codes a later
+        call (``- k * S`` more) moves up a call; a token stays. Fixed
+        shape for a lane count, whatever number of lanes is taken."""
+        if nxt.ndim == 2:
+            nxt = nxt[:, -1]
         s_tot = tokens.shape[0]
         lane = -1 - tokens
         return jnp.where(

@@ -289,7 +289,9 @@ def test_step_counters_and_span_attributes(model_and_params):
     assert snap["serving_attn_context_tokens_total"] == seen * layers
     sel = sum(min(p, TOPK) for p in range(1, 47))
     assert snap["serving_attn_selected_tokens_total"] == sel * layers
-    rounds = [s for s in tracer.spans() if s.name == "serving.decode_round"]
+    # the rounds that dispatched a block (the last only settles one)
+    rounds = [s for s in tracer.spans() if s.name == "serving.decode_round"
+              and s.attrs["slots_live"]]
     assert rounds and all(
         s.attrs["selected"] == 2 * TOPK * layers
         and s.attrs["experts_touched"] > 0 for s in rounds)
