@@ -34,7 +34,16 @@ configured; weights random from ``--seed``), in ONE process:
    new ones through the engine, every chosen token within
    ``HYBRID_TIE_MARGIN`` of the plain float32 reference's best
    (``benchmark/families/falcon_h1.py``), and the slot's state row
-   changed while its neighbours' stayed zero.
+   changed while its neighbours' stayed zero;
+6. **latent family** — ``models/latent_conv_moe_lm.py`` at its published
+   widths (8 query heads over 2 KV heads of 128 in a latent of 1024 + 256
+   + 256, 16 experts of 2048 with one a token, router hidden 256; ONE
+   layer, a vocabulary of 8192 rows): the grouped expert kernel on ``(T,
+   1)`` ids against its ``lax_fn``, then one request of 300 prompt tokens
+   (three chunks of 128, the last ragged) and 12 new ones through the
+   engine, every chosen token within ``LATENT_TIE_MARGIN`` of the plain
+   float32 reference's best (``benchmark/families/zaya.py``), and the
+   slot's three tails changed while its neighbours' stayed zero.
 
 ``--chips 4`` runs INSTEAD (no one-chip phase): BERT-base under
 ``shard_train_step`` on a dp2 x tp2 mesh against the same steps on one
@@ -74,6 +83,15 @@ SPARSE_TIE_MARGIN = 0.6
 #: reference, in logits that spread by about 0.01 under the published
 #: multipliers (benchmark/configs/falcon_h1_34b.json, tie_margin)
 HYBRID_TIE_MARGIN = 5e-4
+#: the latent-conv attention + top-1 expert model in bf16 against its
+#: float32 reference at ONE layer, 12 chosen tokens, in logits that spread
+#: by about 0.9. The reference follows the program's expert at a routing
+#: tie and keeps a switch that leaves the token at most 0.1 short
+#: (benchmark/families/zaya.py, ROUTING_EXPLAINED), so a followed token
+#: may read up to 0.1; one it could not follow reads 0.3 to 4. Six seeds
+#: at this phase's own size read 0, 0, 0, 0, 1.5e-3, 0 (my chip run, PR 35,
+#: chiprun_out/logs/ninth_smoke6.log)
+LATENT_TIE_MARGIN = 0.15
 #: dp2 x tp2 vs one device: same math, different reduction order, bf16
 #: activations — relative tolerance on each step's loss
 MESH_LOSS_RTOL = 2e-2
@@ -104,6 +122,8 @@ class Sizes:
     hybrid_page_size: int
     hybrid_chunk: int
     hybrid_prompt: int          # whole chunks and a ragged one
+    latent: dict                # LatentConvMoELMConfig overrides; page,
+    #                             chunk and prompt are the hybrid's
     interpret: bool = False
 
     @classmethod
@@ -117,7 +137,8 @@ class Sizes:
                    sparse=dict(num_hidden_layers=1), sparse_page_size=128,
                    sparse_chunk=64, sparse_prompt=2304,
                    hybrid=dict(num_hidden_layers=1, vocab_size=8192),
-                   hybrid_page_size=128, hybrid_chunk=128, hybrid_prompt=300)
+                   hybrid_page_size=128, hybrid_chunk=128, hybrid_prompt=300,
+                   latent=dict(num_hidden_layers=1, vocab_size=8192))
 
     @classmethod
     def tiny(cls):
@@ -146,6 +167,12 @@ class Sizes:
                                mamba_n_heads=4, mamba_d_head=16,
                                mamba_n_groups=2, mamba_d_state=16),
                    hybrid_page_size=4, hybrid_chunk=8, hybrid_prompt=19,
+                   latent=dict(vocab_size=96, hidden_size=64,
+                               num_hidden_layers=1, num_attention_heads=4,
+                               num_key_value_heads=2, head_dim=16,
+                               max_position_embeddings=256, num_experts=8,
+                               moe_intermediate_size=32,
+                               router_hidden_size=16),
                    interpret=True)
 
     @property
@@ -516,6 +543,63 @@ def phase_sparse_family(sizes, seed):
         "sparse family left the reference"
 
 
+def _serve_slot_state_family(what, model, family, kernel_names, sizes, seed,
+                             state_entries, margin):
+    """One request of ``sizes.hybrid_prompt`` tokens (whole chunks and a
+    ragged one) and ``sizes.new_tokens`` new ones through a two-slot
+    engine of a family that keeps state a slot: its kernels ran on
+    ``sizes.kernel_impl`` and never on ``lax``, the slot's row of each of
+    the last ``state_entries`` pool arrays changed while its neighbour's
+    stayed zero, and every chosen token lies within ``margin`` logits of
+    the best of ``family.reference_logits`` (plain float32)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import inference
+
+    impl, cfg = sizes.kernel_impl, model.cfg
+    params = jax.jit(lambda k: model.init(k, dtype=jnp.bfloat16))(
+        jax.random.PRNGKey(seed))
+    before = {(k, i): _dispatched(k, i) for k in kernel_names
+              for i in (impl, "lax")}
+    n0, n_new = sizes.hybrid_prompt, sizes.new_tokens
+    pages = -(-(n0 + n_new + 8) // sizes.hybrid_page_size)
+    eng = inference.make_serving_engine(
+        model, params, num_slots=2, page_size=sizes.hybrid_page_size,
+        prefill_chunk=sizes.hybrid_chunk, attn_impl=impl,
+        max_tokens_per_slot=pages * sizes.hybrid_page_size)
+    prompt = np.random.default_rng(seed + 5).integers(
+        0, cfg.vocab_size, n0).astype(np.int32)
+    t0 = time.perf_counter()
+    rid = eng.submit(prompt, n_new)
+    while not eng.scheduler.idle():
+        eng.step()
+    out = np.asarray(eng.result(rid))
+    t_serve = time.perf_counter() - t0
+    for k in kernel_names:
+        assert _dispatched(k, impl) > before[(k, impl)], \
+            f"{k} never resolved to {impl}"
+        assert _dispatched(k, "lax") == before[(k, "lax")], \
+            f"{k} fell back to lax"
+    for state in eng.cache.pages[0][-state_entries:]:
+        state = np.asarray(state)
+        assert state.dtype == np.float32 and state[1].any(), \
+            "the slot's state row never changed"
+        assert not state[2].any(), "a neighbour's state row changed"
+    ids = np.concatenate([prompt, out]).astype(np.int32)[None]
+    with jax.default_matmul_precision("highest"):
+        logits = np.asarray(jax.jit(
+            lambda pr, i: family.reference_logits(
+                pr, i, family.sizes_of(cfg), n0 - 1, n_new))(
+            params, jnp.asarray(ids)))[0].astype(np.float64)
+    gaps = logits.max(-1) - logits[np.arange(n_new), out]
+    log(f"{what} family: {n0}-token prompt + {n_new} tokens in "
+        f"{t_serve:.1f}s (compiles included); {int((gaps == 0).sum())}/"
+        f"{n_new} tokens are the float32 reference's argmax, largest "
+        f"shortfall {gaps.max():.3e} logits of a spread of "
+        f"{logits.std():.3e} (tolerance {margin})")
+    assert gaps.max() < margin, f"{what} family left the reference"
+
+
 # ---------------------------------------------------------------------------
 # phase 5: the attention + state-space hybrid
 # ---------------------------------------------------------------------------
@@ -525,7 +609,7 @@ def phase_hybrid_family(sizes, seed):
 
     import jax
     import jax.numpy as jnp
-    from paddle_tpu import inference, kernels
+    from paddle_tpu import kernels
     from paddle_tpu.models.hybrid_ssm_lm import (HybridSSMLM,
                                                  HybridSSMLMConfig)
 
@@ -579,48 +663,60 @@ def phase_hybrid_family(sizes, seed):
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
         __file__)), "benchmark"))
     from families import falcon_h1
-    model = HybridSSMLM(cfg)
-    params = jax.jit(lambda k: model.init(k, dtype=jnp.bfloat16))(
-        jax.random.PRNGKey(seed))
-    before = {(k, i): _dispatched(k, i) for k in names
-              for i in (impl, "lax")}
-    n0, n_new = sizes.hybrid_prompt, sizes.new_tokens
-    pages = -(-(n0 + n_new + 8) // sizes.hybrid_page_size)
-    eng = inference.make_serving_engine(
-        model, params, num_slots=2, page_size=sizes.hybrid_page_size,
-        prefill_chunk=sizes.hybrid_chunk, attn_impl=impl,
-        max_tokens_per_slot=pages * sizes.hybrid_page_size)
-    prompt = np.random.default_rng(seed + 5).integers(
-        0, cfg.vocab_size, n0).astype(np.int32)
-    t0 = time.perf_counter()
-    rid = eng.submit(prompt, n_new)
-    while not eng.scheduler.idle():
-        eng.step()
-    out = np.asarray(eng.result(rid))
-    t_serve = time.perf_counter() - t0
-    for k in names:
-        assert _dispatched(k, impl) > before[(k, impl)], \
-            f"{k} never resolved to {impl}"
-        assert _dispatched(k, "lax") == before[(k, "lax")], \
-            f"{k} fell back to lax"
-    state = np.asarray(eng.cache.pages[0][-1])
-    assert state.dtype == np.float32 and state[1].any(), \
-        "the slot's state row never changed"
-    assert not state[2].any(), "a neighbour's state row changed"
-    ids = np.concatenate([prompt, out]).astype(np.int32)[None]
-    with jax.default_matmul_precision("highest"):
-        logits = np.asarray(jax.jit(
-            lambda pr, i: falcon_h1.reference_logits(
-                pr, i, falcon_h1.sizes_of(cfg), n0 - 1, n_new))(
-            params, jnp.asarray(ids)))[0].astype(np.float64)
-    gaps = logits.max(-1) - logits[np.arange(n_new), out]
-    log(f"hybrid family: {n0}-token prompt + {n_new} tokens in "
-        f"{t_serve:.1f}s (compiles included); {int((gaps == 0).sum())}/"
-        f"{n_new} tokens are the float32 reference's argmax, largest "
-        f"shortfall {gaps.max():.3e} logits of a spread of "
-        f"{logits.std():.3e} (tolerance {HYBRID_TIE_MARGIN})")
-    assert gaps.max() < HYBRID_TIE_MARGIN, \
-        "hybrid family left the reference"
+    _serve_slot_state_family("hybrid", HybridSSMLM(cfg), falcon_h1, names,
+                             sizes, seed, 1, HYBRID_TIE_MARGIN)
+
+
+# ---------------------------------------------------------------------------
+# phase 6: latent conv attention + top-1 experts with a carried router
+# ---------------------------------------------------------------------------
+
+def phase_latent_family(sizes, seed):
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import kernels
+    from paddle_tpu.models.latent_conv_moe_lm import (LatentConvMoELM,
+                                                      LatentConvMoELMConfig)
+    from paddle_tpu.ops import grouped_ffn
+
+    impl = sizes.kernel_impl
+    cfg = LatentConvMoELMConfig(kernel_impl=impl, **sizes.latent)
+    name = "moe_grouped_ffn"
+    # -- the grouped expert kernel at one expert a token, at this model's
+    # widths: a decode batch (tiles of 16 rows) and a prefill call (32)
+    rng = np.random.default_rng(seed)
+    e, f, d = cfg.num_experts, cfg.moe_intermediate_size, cfg.hidden_size
+    w = [jnp.asarray(rng.standard_normal((e, f, d)) * d ** -0.5,
+                     jnp.bfloat16) for _ in range(3)]
+    spec, errs = kernels.get(name), {}
+    for tokens in (4 * e, 40 * e):
+        ids = jnp.asarray(rng.integers(0, e, (tokens, 1)), jnp.int32)
+        valid = jnp.asarray(rng.uniform(size=tokens) < 0.9)
+        src, _dest, tile_expert, n_used, _ = grouped_ffn.route_tiles(
+            ids, valid, e, grouped_ffn.tile_rows(tokens, e))
+        x = jnp.asarray(rng.standard_normal((tokens, d)), jnp.bfloat16)
+        args = (jnp.where((src >= 0)[:, None], x[jnp.maximum(src, 0)], 0),
+                tile_expert, n_used, *w)
+        got = np.asarray(jax.jit(lambda *a: kernels.dispatch(
+            name, *a, impl=impl))(*args), np.float32)
+        want = np.asarray(jax.jit(lambda *a: kernels.dispatch(
+            name, *a, impl="lax"))(*args), np.float32)
+        assert got.shape == want.shape and np.isfinite(got).all(), name
+        # bf16 operands and a bf16 result on both sides: one rounding
+        np.testing.assert_allclose(
+            got, want, atol=2 ** -7 * max(1.0, float(np.abs(want).max())),
+            rtol=spec.contract.rtol, err_msg=f"{name} {impl} vs lax")
+        errs[f"{tokens} tokens"] = float(np.max(np.abs(got - want)))
+    log("latent family kernels vs lax max|err|: " + json.dumps(errs))
+
+    # -- a short serve through the engine, against the plain reference
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "benchmark"))
+    from families import zaya
+    _serve_slot_state_family("latent", LatentConvMoELM(cfg), zaya, (name,),
+                             sizes, seed, 3, LATENT_TIE_MARGIN)
 
 
 def _sparse_kernel_args(name, cfg, sizes, seed):
@@ -766,6 +862,7 @@ def run_one_chip(sizes, seed=0):
     phase_serving(sizes, seed)
     phase_sparse_family(sizes, seed)
     phase_hybrid_family(sizes, seed)
+    phase_latent_family(sizes, seed)
 
 
 def run_four_chips(sizes, seed=0, devices=None):

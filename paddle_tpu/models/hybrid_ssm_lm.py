@@ -43,27 +43,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddle_tpu.models.common import rms_norm, rope
+from paddle_tpu.models.common import matmul_precision as _precision
+from paddle_tpu.models.common import normal_init as _normal
+from paddle_tpu.models.common import project as _project, rms_norm, rope
 from paddle_tpu.ops.attention import NEG_INF
 from paddle_tpu.ops.ssm_scan import (SCAN_TILE, ssd_chunk_scan,
                                      ssm_decode_update)
 from paddle_tpu.serving.program import ServingSpec
 
 _HI = jax.lax.Precision.HIGHEST
-#: elements of one piece when a large matrix is drawn (the float32 draw of
-#: a whole embedding would not fit beside the model)
-_INIT_PIECE = 1 << 25
-
-
-def _precision(dtype):
-    return _HI if jnp.dtype(dtype) == jnp.float32 \
-        else jax.lax.Precision.DEFAULT
-
-
-def _project(x, w):
-    """``x @ w`` with operands of the weight's type, summed in float32."""
-    return jnp.matmul(x.astype(w.dtype), w, precision=_precision(w.dtype),
-                      preferred_element_type=jnp.float32)
 
 
 @dataclasses.dataclass
@@ -135,24 +123,6 @@ class HybridSSMLMConfig:
                          mamba_d_state=16).items():
             kw.setdefault(k, v)
         return cls(**kw)
-
-
-def _normal(key, shape, dtype, std=0.02):
-    """``std * N(0, 1)`` made in ``dtype``; a large matrix a piece of its
-    leading axis at a time."""
-    lead, size = shape[0], 1
-    for n in shape:
-        size *= n
-    pieces = next(n for n in range(1, lead + 1)
-                  if lead % n == 0 and size // n <= _INIT_PIECE)
-    if pieces == 1:
-        return (std * jax.random.normal(key, shape, jnp.float32)
-                ).astype(dtype)
-    piece = (lead // pieces,) + tuple(shape[1:])
-    out = jax.lax.map(
-        lambda k: (std * jax.random.normal(k, piece, jnp.float32)
-                   ).astype(dtype), jax.random.split(key, pieces))
-    return out.reshape(shape)
 
 
 class HybridSSMLM:

@@ -186,6 +186,9 @@ _STEP_STAT_HELP = {
                         "token steps (the denominator of touched)",
     "moe_max_expert_tokens": "the fullest expert's load, summed over "
                              "layers and token steps",
+    "moe_tile_rows": "rows of the row tiles the grouped expert kernel "
+                     "walked (tiles in use x rows a tile; the pairs "
+                     "over it is the tiles' fill)",
     "attn_context_tokens": "cached tokens a query could attend to (the "
                            "indexer scores them once past topk), summed "
                            "over queries and layers",
@@ -755,17 +758,22 @@ class ServingEngine:
             return
         self._c_ssm_decode = r.counter(
             "serving_ssm_decode_slot_steps_total",
-            "live slots x token steps x layers through the one-token "
-            "state update").child()
+            "live slots x token steps x layers whose slot state a "
+            "decode token step advanced by one token (a recurrence's "
+            "one-token update, or the attention projections' conv "
+            "tails)").child()
         self._c_ssm_prefill = r.counter(
             "serving_ssm_prefill_tokens_total",
-            "valid prompt tokens x layers through the chunked scan").child()
+            "valid prompt tokens x layers whose slot state a prefill "
+            "call advanced (the chunked scan, or the tails)").child()
         moved = r.counter(
             "serving_ssm_state_bytes_total",
-            "slot-state bytes (conv window + head states, every layer) "
-            "the steps read and wrote: a decode token step reads and "
-            "writes each live slot's; a prefill call writes each lane's "
-            "and reads it unless the lane's prompt starts there")
+            "slot-state bytes (every entry of the program's slot_state, "
+            "every layer: a mixer's conv window + head states, or the "
+            "attention projections' tails) the steps read and wrote: a "
+            "decode token step reads and writes each live slot's; a "
+            "prefill call writes each lane's and reads it unless the "
+            "lane's prompt starts there")
         self._c_ssm_read = moved.child(kind="read")
         self._c_ssm_written = moved.child(kind="written")
         self._c_ssm_resets = r.counter(
@@ -2860,6 +2868,39 @@ class ServingEngine:
             vals += [context, selected]
         return jnp.stack([jnp.asarray(v).astype(jnp.int32) for v in vals])
 
+    @staticmethod
+    def _attn_in(program, params, i, x, positions, state, rows, fresh,
+                 valid):
+        """Layer ``i``'s ``attn_in``: -> (q, rows to cache, index, the
+        slot-state pools it advanced or None). A program whose attention
+        projections own the slot state (``spec.slot_state_reader``)
+        takes what ``mixer`` would and hands the pools back. A decode
+        step gives ``fresh`` None (no lane's prompt starts there) and
+        ``valid`` (S,), expanded here so that a program without such
+        state traces to the step it had."""
+        if program.spec.slot_state_reader == "attn_in":
+            if fresh is None:
+                fresh, valid = jnp.zeros_like(rows), valid[:, None]
+            return program.attn_in(params, i, x, positions, state, rows,
+                                   fresh, valid)
+        return program.attn_in(params, i, x, positions) + (None,)
+
+    @staticmethod
+    def _ffn(program, params, i, x, valid, carry):
+        """Layer ``i``'s ``ffn``: -> (x, stats, carry), the carry passed
+        through the layer where the program declares one
+        (``spec.layer_carry``)."""
+        if program.spec.layer_carry:
+            return program.ffn(params, i, x, valid, carry)
+        return program.ffn(params, i, x, valid) + (carry,)
+
+    @staticmethod
+    def _carry_start(spec, lanes, tokens):
+        """What a token carries into the first layer beside the residual
+        stream: zeros, one array an entry of ``spec.layer_carry``."""
+        return tuple(jnp.zeros((lanes, tokens, width), jnp.float32)
+                     for _name, width in spec.layer_carry)
+
     def _decode_loop(self, params, pages, block_tables, lengths, tokens,
                      active, n_valid=None, *, program=None, quantized=False,
                      n_steps=1, psum_axis=None):
@@ -2891,6 +2932,7 @@ class ServingEngine:
         slot_ids = jnp.arange(s_tot)
         n_stats = len(self._stat_names(spec))
         n_paged = len(pages[0]) - len(spec.slot_state)
+        mixes = bool(spec.slot_state) and spec.slot_state_reader == "mixer"
 
         def one_token(j, pages, lengths, tokens):
             pos = jnp.minimum(lengths, spec.max_position - 1)
@@ -2904,14 +2946,17 @@ class ServingEngine:
                 0)
             off = lengths % ps
             seen = jnp.where(writable, lengths + 1, 0).sum()
-            if spec.slot_state:
-                # a decoding slot's state is pool row slot + 1; any other
-                # slot's (free, or mid-prefill and owning live state) is
-                # not this block's to touch: the null row
-                state_rows = jnp.where(writable, slot_ids + 1, 0)
+            # a decoding slot's state is pool row slot + 1; any other
+            # slot's (free, or mid-prefill and owning live state) is
+            # not this block's to touch: the null row
+            state_rows = jnp.where(writable, slot_ids + 1, 0) \
+                if spec.slot_state else None
             new_pages, counts = [], 0
+            carry = self._carry_start(spec, s_tot, 1)
             for i in range(spec.num_layers):
-                q, rows, index = program.attn_in(params, i, x, pos[:, None])
+                q, rows, index, state = self._attn_in(
+                    program, params, i, x, pos[:, None], pages[i][n_paged:],
+                    state_rows, None, writable)
                 ent = self._write_rows(
                     pages[i][:n_paged], tuple(r[:, 0] for r in rows),
                     page_idx, off, quantized, psum_axis)
@@ -2919,13 +2964,16 @@ class ServingEngine:
                     spec, q[:, :, 0, :], ent, block_tables, lengths + 1,
                     index, quantized)                           # (S,H,Dh)
                 x_in, x = x, program.attn_out(params, i, x, att[:, None])
-                if spec.slot_state:
+                if mixes:
                     mixed, state = program.mixer(
                         params, i, x_in, pages[i][n_paged:], state_rows,
                         jnp.zeros_like(state_rows), writable[:, None])
-                    ent, x = ent + tuple(state), x + mixed
+                    x = x + mixed
+                if spec.slot_state:
+                    ent = ent + tuple(state)
                 new_pages.append(ent)
-                x, ffn_stats = program.ffn(params, i, x, writable[:, None])
+                x, ffn_stats, carry = self._ffn(
+                    program, params, i, x, writable[:, None], carry)
                 if n_stats:
                     counts = counts + self._step_stat_vector(
                         spec, ffn_stats, seen,
@@ -3001,6 +3049,8 @@ class ServingEngine:
         ps = self.cache.config.page_size
         s_tot, c = tokens.shape
         n_paged = len(pages[0]) - len(spec.slot_state)
+        mixes = bool(spec.slot_state) and spec.slot_state_reader == "mixer"
+        state_rows = fresh = None
         if spec.slot_state:
             # the lanes' state rows ride the tables' last column; a lane
             # whose prompt starts here starts from zeros, its slot's
@@ -3026,21 +3076,27 @@ class ServingEngine:
         seen, attended = (jnp.where(valid, a, 0).sum()
                           for a in (seen, attended))
         new_pages, counts = [], 0
+        carry = self._carry_start(spec, s_tot, c)
         for i in range(spec.num_layers):
-            q, rows, index = program.attn_in(params, i, x, pos_e)
+            q, rows, index, state = self._attn_in(
+                program, params, i, x, pos_e, pages[i][n_paged:],
+                state_rows, fresh, valid)
             ent = self._write_rows(pages[i][:n_paged], rows, page_idx, off,
                                    quantized, psum_axis)
             att = self._attend_prefill(
                 spec, q.transpose(0, 2, 1, 3), ent, block_tables, starts,
                 n_valid, index, quantized)                      # (S,C,H,Dh)
             x_in, x = x, program.attn_out(params, i, x, att)
-            if spec.slot_state:
+            if mixes:
                 mixed, state = program.mixer(
                     params, i, x_in, pages[i][n_paged:], state_rows, fresh,
                     valid)
-                ent, x = ent + tuple(state), x + mixed
+                x = x + mixed
+            if spec.slot_state:
+                ent = ent + tuple(state)
             new_pages.append(ent)
-            x, ffn_stats = program.ffn(params, i, x, valid)
+            x, ffn_stats, carry = self._ffn(program, params, i, x, valid,
+                                            carry)
             if counting:
                 counts = counts + self._step_stat_vector(
                     spec, ffn_stats, seen, attended)

@@ -10,7 +10,7 @@ over the parameter tree, each called inside the engine's jitted steps on
     embed(params, tokens (S,C), positions (S,C))          -> x (S,C,D)
     attn_in(params, i, x, positions)                      -> q, rows, index
     attn_out(params, i, x, att (S,C,H,Dh))                -> x
-    ffn(params, i, x, valid (S,C) bool)                   -> x, stats
+    ffn(params, i, x, valid (S,C) bool[, carry])          -> x, stats[, carry]
     head(params, x (..., D))                              -> logits (..., V)
     mixer(params, i, x, state, rows (S,), fresh (S,), valid (S,C))
                                                           -> y (S,C,D), state
@@ -38,6 +38,22 @@ request's: start from zeros) and ``valid`` marking a lane's real tokens,
 which come first. It returns what the block adds to the residual stream
 beside ``attn_out``'s and the pools with every lane's row advanced past
 its valid tokens; rows of other slots stay as they were, bit for bit.
+
+Where the state is the attention projections' own (a query, key or value
+that reads the tokens before it), the program declares
+``spec.slot_state_reader = "attn_in"``: it has no ``mixer``, and the
+engine hands ``attn_in`` those same four arguments after ``positions``
+and takes the pools back as a fourth result::
+
+    attn_in(params, i, x, positions, state, rows (S,), fresh (S,),
+            valid (S,C))                        -> q, rows, index, state
+
+A program that declares ``spec.layer_carry`` passes arrays from one
+layer's ``ffn`` to the next layer's, per token, beside the residual
+stream: the engine starts a pass through the layers with a tuple of
+zeros ``(S, C, width)`` float32, one for each entry, hands it to ``ffn``
+as a fifth argument, takes it back as a third result, and drops it after
+the last layer. It is neither cached nor state: it lives for one pass.
 
 What a program cannot do yet it leaves out of ``spec.supports``; the
 engine refuses, by name, an option that needs it.
@@ -90,4 +106,17 @@ class ServingSpec:
     #: and written by ``mixer``; never shared, copied on write or shipped
     slot_state: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
     slot_state_dtype: str = "float32"
+    #: which function reads and advances ``slot_state``: ``"mixer"``, a
+    #: branch beside attention, or ``"attn_in"``, the attention
+    #: projections themselves (such a program has no ``mixer``)
+    slot_state_reader: str = "mixer"
+    #: arrays a token carries from layer to layer beside the residual
+    #: stream, ``(name, width)`` each, float32, through ``ffn``
+    layer_carry: Tuple[Tuple[str, int], ...] = ()
     supports: FrozenSet[str] = FEATURES
+
+    def __post_init__(self):
+        if self.slot_state_reader not in ("mixer", "attn_in"):
+            raise ValueError(
+                f"slot_state_reader={self.slot_state_reader!r}: the engine "
+                "hands slot state to 'mixer' or to 'attn_in'")

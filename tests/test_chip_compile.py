@@ -498,30 +498,26 @@ def test_state_space_kernel_compiles_for_v5e(name, lanes, chunk, one_chip):
     _compile_kernel(name, args, one_chip, n_groups=H1_GROUPS)
 
 
-@pytest.fixture(scope="module")
-def h1_steps(topo):
-    """lower(step, lanes, width) -> compiled, for the hybrid family at its
-    published widths, two layers: abstract bf16 weights, an engine with a
-    nine-page pool and two slots, the steps lowered on the cell's pools of
-    shapes (1025 pages, 65 state rows)."""
+def _slot_state_steps(topo, model, slots, page_size, num_pages, chunk,
+                      max_tokens):
+    """lower(step, lanes, width) -> compiled, for a family that keeps
+    state a slot: abstract bf16 weights, an engine with a nine-page pool
+    and two slots, the steps lowered on the cell's pools of shapes
+    (``num_pages`` pages, ``slots`` + 1 state rows)."""
     from jax.sharding import SingleDeviceSharding
     from paddle_tpu import inference
-    from paddle_tpu.models.hybrid_ssm_lm import (HybridSSMLM,
-                                                 HybridSSMLMConfig)
-    model = HybridSSMLM(HybridSSMLMConfig(num_hidden_layers=H1_LAYERS,
-                                          kernel_impl="pallas"))
     params = jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
                             jax.random.PRNGKey(0))
     eng = inference.make_serving_engine(
-        model, params, num_slots=2, page_size=H1_PS, num_pages=9,
-        max_tokens_per_slot=2048, prefill_chunk=H1_CHUNK, decode_block=8,
+        model, params, num_slots=2, page_size=page_size, num_pages=9,
+        max_tokens_per_slot=max_tokens, prefill_chunk=chunk, decode_block=8,
         attn_impl="pallas", cache_dtype=jnp.bfloat16)
     dev = SingleDeviceSharding(topo.devices[0])
     sds = jax.ShapeDtypeStruct
     weights = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype, sharding=dev), params)
     paged = eng.cache.config.paged_entries
-    pages = [tuple(sds(((H1_PAGES if k < paged else H1_SLOTS + 1),)
+    pages = [tuple(sds(((num_pages if k < paged else slots + 1),)
                        + a.shape[1:], a.dtype, sharding=dev)
                    for k, a in enumerate(ent)) for ent in eng.cache.pages]
 
@@ -536,9 +532,41 @@ def h1_steps(topo):
         # a prefill lane's table carries its state row in one more column
         return eng.prefill_step.lower(
             weights, pages, i32(lanes, width + 1), i32(lanes),
-            i32(lanes, H1_CHUNK), i32(lanes)).compile()
+            i32(lanes, chunk), i32(lanes)).compile()
 
     return lower
+
+
+def _assert_step_keeps_its_pools(compiled, kernels_in, pools, temp_limit):
+    """The compiled step holds exactly the Pallas calls ``kernels_in``,
+    copies none of ``pools`` (shape pattern -> entry layout), takes each in
+    that layout, and keeps its temporaries under ``temp_limit`` bytes."""
+    import re
+    text = compiled.as_text()
+    names = {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
+    assert names == kernels_in
+    entry = text[text.index("ENTRY "):]
+    for pool, layout in pools.items():
+        copies = [line for line in text.splitlines()
+                  if re.search(r"= " + pool + r"\S* copy\(", line)]
+        assert not copies, copies[:2]
+        layouts = set(re.findall(pool + r"(\{[\d,]+)[^ ]* parameter\(",
+                                 entry))
+        assert layouts == {layout}, (pool, layouts)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+
+
+@pytest.fixture(scope="module")
+def h1_steps(topo):
+    """The hybrid family at its published widths, two layers, on the chat
+    cell's pools (1025 pages, 65 state rows)."""
+    from paddle_tpu.models.hybrid_ssm_lm import (HybridSSMLM,
+                                                 HybridSSMLMConfig)
+    model = HybridSSMLM(HybridSSMLMConfig(num_hidden_layers=H1_LAYERS,
+                                          kernel_impl="pallas"))
+    return _slot_state_steps(topo, model, H1_SLOTS, H1_PS, H1_PAGES,
+                             H1_CHUNK, 2048)
 
 
 @pytest.mark.parametrize("step, lanes, width, kernels_in", [
@@ -553,21 +581,44 @@ def test_hybrid_family_steps_compile_and_keep_the_pools(
     slots inside the 8-token loop. No step copies a K or V pool, a state
     pool or a conv-window pool; each comes in row-major; temporaries stay
     under one layer's state pool (272 MB)."""
-    import re
-    compiled = h1_steps(step, lanes, width)
-    text = compiled.as_text()
-    names = {re.sub(r"\.\d+$", "", n) for n in re.findall(
-        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
-    assert names == kernels_in
-    pools = (rf"bf16\[{H1_PAGES},{H1_PS},512\]",
-             rf"f32\[{H1_SLOTS + 1},{H1_HEADS},{H1_STATE},{H1_P}\]",
-             rf"f32\[{H1_SLOTS + 1},15360\]")
-    entry = text[text.index("ENTRY "):]
-    for pool, layout in zip(pools, ("{2,1,0", "{3,2,1,0", "{1,0")):
-        copies = [line for line in text.splitlines()
-                  if re.search(r"= " + pool + r"\S* copy\(", line)]
-        assert not copies, copies[:2]
-        layouts = set(re.findall(pool + r"(\{[\d,]+)[^ ]* parameter\(",
-                                 entry))
-        assert layouts == {layout}, (pool, layouts)
-    assert compiled.memory_analysis().temp_size_in_bytes < 272 << 20
+    _assert_step_keeps_its_pools(h1_steps(step, lanes, width), kernels_in, {
+        rf"bf16\[{H1_PAGES},{H1_PS},512\]": "{2,1,0",
+        rf"f32\[{H1_SLOTS + 1},{H1_HEADS},{H1_STATE},{H1_P}\]": "{3,2,1,0",
+        rf"f32\[{H1_SLOTS + 1},15360\]": "{1,0"}, 272 << 20)
+
+
+# -- latent conv attention + top-1 experts at the reasoning cell's geometry ---
+
+ZY_SLOTS, ZY_PS, ZY_PAGES, ZY_CHUNK, ZY_LAYERS = 256, 128, 3585, 128, 10
+
+
+@pytest.fixture(scope="module")
+def zaya_steps(topo):
+    """The latent-conv family at its published widths and the cell's TEN
+    layers, on the reasoning cell's pools (3585 pages, 257 tail rows)."""
+    from paddle_tpu.models.latent_conv_moe_lm import (LatentConvMoELM,
+                                                      LatentConvMoELMConfig)
+    model = LatentConvMoELM(LatentConvMoELMConfig(
+        num_hidden_layers=ZY_LAYERS, kernel_impl="pallas"))
+    return _slot_state_steps(topo, model, ZY_SLOTS, ZY_PS, ZY_PAGES,
+                             ZY_CHUNK, 3072)
+
+
+@pytest.mark.parametrize("step, lanes, width, kernels_in", [
+    ("decode", ZY_SLOTS, 32, {"ragged_paged_decode", "moe_grouped_ffn"}),
+    ("prefill", 16, 16, {"ragged_paged_prefill", "moe_grouped_ffn"})],
+    ids=["decode-w32", "prefill-16lanes-w16"])
+def test_latent_family_steps_compile_and_keep_the_pools(
+        step, lanes, width, kernels_in, zaya_steps):
+    """The ten-layer decode block and prefill step compile for the chip at
+    the reasoning cell's geometry: 8 query heads over 2 KV heads in the
+    dense paged kernels (a page row of 256 lanes), 256 slots inside the
+    8-token loop, 16 lanes of 128 queries, the grouped expert kernel at
+    16 experts of 2048 x 2048 with one a token. No step copies a K or V
+    pool or a tail pool; each comes in row-major; temporaries stay under
+    the decode logits' 269 MB (the head's matmul and argmax fuse)."""
+    _assert_step_keeps_its_pools(
+        zaya_steps(step, lanes, width), kernels_in, {
+            rf"bf16\[{ZY_PAGES},{ZY_PS},256\]": "{2,1,0",
+            rf"f32\[{ZY_SLOTS + 1},1280\]": "{1,0",
+            rf"f32\[{ZY_SLOTS + 1},128\]": "{1,0"}, 269 << 20)

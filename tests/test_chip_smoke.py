@@ -39,6 +39,8 @@ def test_one_chip_phases_at_tiny_size(chip_smoke, capsys):
     assert "tokens are the float32 reference's argmax" in out
     assert "hybrid family kernels vs lax" in out
     assert "hybrid family: 19-token prompt" in out
+    assert "latent family kernels vs lax" in out
+    assert "latent family: 19-token prompt" in out
 
 
 def test_four_chip_phases_on_virtual_devices(chip_smoke, capsys):
