@@ -325,21 +325,31 @@ def _run_steps(run, state, batch, n, dropout_key=None):
 
 def phase_trainer(sizes, seed):
     import jax
+    from paddle_tpu.core import dtypes
     flash_before = _dispatched("flash_attention", sizes.kernel_impl)
     model, state, step, batch = _bert_setup(sizes, seed)
     assert model.cfg.dropout > 0.0      # the dropout/RNG path runs too
+    # the batch's loss without dropout, before and after the steps: a
+    # training loss swings with the masks it happened to draw by more
+    # than five steps move it
+    policy = dtypes.get_policy("bf16")
+    eval_loss = jax.jit(lambda p: model.loss(
+        policy.cast_to_compute(p), training=False, **batch)[0])
+    before = float(eval_loss(state["params"]))
     step = jax.jit(step, donate_argnums=(0,))
     t0 = time.perf_counter()
     state, losses = _run_steps(step, state, batch, 5,
                                dropout_key=jax.random.PRNGKey(seed + 3))
     jax.block_until_ready(state)
     dt = time.perf_counter() - t0
+    after = float(eval_loss(state["params"]))
     flash = _dispatched("flash_attention", sizes.kernel_impl) - flash_before
-    log(f"trainer: losses={[round(x, 4) for x in losses]} "
+    log(f"trainer: losses={[round(x, 4) for x in losses]} evaluation-mode "
+        f"loss {before:.4f} -> {after:.4f} "
         f"flash_attention[{sizes.kernel_impl}] dispatches={int(flash)} "
         f"seconds={dt:.1f} (compile included)")
     assert all(math.isfinite(x) for x in losses), losses
-    assert losses[4] < losses[0], f"loss did not fall: {losses}"
+    assert after < before, f"loss did not fall: {before} -> {after}"
     assert flash > 0, "BERT attention did not resolve to the flash kernel"
 
 

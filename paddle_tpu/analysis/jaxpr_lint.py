@@ -44,7 +44,7 @@ HOST_CALLBACK_PRIMS = {"pure_callback", "io_callback"}
 # `debug_print`
 DEBUG_CALLBACK_PRIMS = {"debug_callback", "debug_print"}
 # primitives that DRAW from a key (consume its stream)
-KEY_DRAW_PRIMS = {"random_bits", "threefry2x32"}
+KEY_DRAW_PRIMS = {"random_bits", "threefry2x32", "rng_bit_generator"}
 # primitives that DERIVE fresh independent keys (consuming is fine)
 KEY_DERIVE_PRIMS = {"random_split", "random_fold_in", "random_seed",
                     "random_clone"}
@@ -223,6 +223,12 @@ def analyze_jaxpr(
                         env[ov] = o
             elif prim in KEY_DERIVE_PRIMS:
                 pass                      # outputs are fresh origins
+            elif prim == "concatenate":
+                # one key repeated to fill a wider generator state
+                # (ops.nn.keep_mask) is still that key
+                origins = {origin(v) for v in eqn.invars}
+                if len(origins) == 1 and None not in origins:
+                    env[eqn.outvars[0]] = origins.pop()
             elif prim in KEY_DRAW_PRIMS:
                 for v in eqn.invars:
                     o = origin(v)

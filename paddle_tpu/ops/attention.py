@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops.nn import keep_mask
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps softmax NaN-free
                  # for fully-masked rows (padded queries)
@@ -63,7 +64,7 @@ def scaled_dot_product_attention(q, k, v, *, bias=None, causal=False,
     alive = jnp.max(s, axis=-1, keepdims=True) > NEG_INF / 2
     p = jnp.where(alive, p, 0.0)
     if dropout_rate > 0.0 and dropout_key is not None:
-        keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_rate, p.shape)
+        keep = keep_mask(dropout_key, 1.0 - dropout_rate, p.shape)
         p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 

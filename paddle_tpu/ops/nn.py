@@ -225,13 +225,39 @@ def batch_norm(x, scale, bias, mean, variance, epsilon=1e-5, momentum=0.9,
     return out, new_mean, new_var
 
 
+def keep_mask(key, keep, shape):
+    """Boolean mask of ``shape``: each element True independently with
+    probability ``keep``, compared at 32 bits. The package's one way to
+    draw a dropout mask.
+
+    The bits come from XLA's ``RngBitGenerator``, which a TPU serves from
+    its hardware generator; Threefry would hash every element in integer
+    arithmetic on the VPU. The generator's 128-bit state is ``key``'s
+    words repeated, as ``jax.random``'s own ``rbg`` seeding does; ``key``
+    is a typed key or raw ``uint32`` key data, and splitting and
+    ``fold_in`` upstream stay the caller's (Threefry). Same key and shape
+    give the same mask on one backend; the bits differ between backends
+    (a CPU expands Philox in XLA) and from ``jax.random.bernoulli``'s.
+    Under ``vmap`` over keys JAX draws the whole batch from the first
+    key's stream: rows stay independent of each other.
+    """
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    state = jnp.concatenate([key] * (4 // key.shape[0]))
+    _, bits = jax.lax.rng_bit_generator(state, shape, dtype=jnp.uint32)
+    return bits < np.uint32(min(int(keep * 2 ** 32), 2 ** 32 - 1))
+
+
 @register_op("dropout")
 def dropout(x, key, rate=0.5, training=True):
-    """Dropout with explicit PRNG key (fluid dropout_op; upscale_in_train)."""
+    """Dropout with explicit PRNG key (fluid dropout_op; upscale_in_train).
+
+    The mask is :func:`keep_mask`'s: the chip's bit generator, seeded from
+    ``key``."""
     if not training or rate == 0.0:
         return x
     keep = 1.0 - rate
-    mask = jax.random.bernoulli(key, keep, x.shape)
+    mask = keep_mask(key, keep, x.shape)
     return jnp.where(mask, x / keep, 0.0).astype(x.dtype)
 
 

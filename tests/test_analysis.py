@@ -287,17 +287,30 @@ class TestPrngSurfaces:
                       registry=False)
         assert _rules(rep) == []
 
-    def test_shared_dropout_key_trips(self):
+    @pytest.mark.parametrize("make_key", [jax.random.PRNGKey, jax.random.key],
+                             ids=["raw", "typed"])
+    def test_shared_dropout_key_trips(self, make_key):
         """The anti-pattern ImgConvGroup avoids: one key for every layer's
-        dropout correlates the masks — the rule must catch it."""
+        dropout correlates the masks — the rule must catch it, through the
+        bit generator's state that dropout widens the key into."""
         from paddle_tpu.ops import nn as F
         def fwd(key, x):
             h = F.dropout(x, key, rate=0.3, training=True)
             h = F.dropout(h, key, rate=0.3, training=True)
             return h.sum()
-        rep = lint_fn(fwd, jax.random.PRNGKey(0),
+        rep = lint_fn(fwd, make_key(0),
                       jnp.ones((2, 8, 8, 3)), registry=False)
         assert "prng-key-reuse" in _rules(rep)
+
+    def test_dropout_per_site_fold_in_clean(self):
+        from paddle_tpu.ops import nn as F
+        def fwd(key, x):
+            for i in range(3):
+                x = F.dropout(x, jax.random.fold_in(key, i), rate=0.3)
+            return x.sum()
+        rep = lint_fn(fwd, jax.random.PRNGKey(0),
+                      jnp.ones((2, 8, 8, 3)), registry=False)
+        assert "prng-key-reuse" not in _rules(rep)
 
 
 # ---------------------------------------------------------------------------

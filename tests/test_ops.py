@@ -209,6 +209,71 @@ def test_dropout_statistics():
         np.asarray(x))
 
 
+# one dropout site of the BERT cells: batch 48 x 512 tokens x hidden 768
+_SITE = (48, 512, 768)
+_SITE_N = int(np.prod(_SITE))
+
+
+def _kept(key, rate, shape=_SITE):
+    return np.asarray(nn.dropout(jnp.ones(shape), key, rate=rate)) != 0
+
+
+@pytest.mark.parametrize("rate", [0.1, 0.25, 0.5])
+def test_dropout_keeps_one_minus_rate_and_scales_by_its_inverse(rate):
+    keep = 1.0 - rate
+    x = jnp.asarray(RNG.rand(*_SITE).astype(np.float32) + 0.5)
+    out = np.asarray(nn.dropout(x, jax.random.PRNGKey(7), rate=rate))
+    kept = out != 0
+    sigma = np.sqrt(keep * rate / _SITE_N)
+    assert abs(kept.mean() - keep) < 3 * sigma
+    # the keep threshold is not rounded to a narrow draw (a uint8 compare at
+    # rate 0.1 keeps 230/256, which is 22 sigma off at this size)
+    np.testing.assert_array_equal(out[kept], np.asarray(x / keep)[kept])
+
+
+def test_dropout_same_key_same_mask_typed_or_raw():
+    a = _kept(jax.random.PRNGKey(3), 0.1)
+    np.testing.assert_array_equal(a, _kept(jax.random.PRNGKey(3), 0.1))
+    np.testing.assert_array_equal(a, _kept(jax.random.key(3), 0.1))
+    assert (a != _kept(jax.random.PRNGKey(4), 0.1)).any()
+
+
+@pytest.mark.parametrize("derive", ["fold_in", "split"])
+def test_dropout_masks_of_derived_keys_are_independent(derive):
+    """No two sites, steps or microbatches share a stream: masks under
+    fold_in(key, i) and under split(key, 4) agree as often as independent
+    draws do (keep^2 + rate^2), within 3 sigma."""
+    key = jax.random.PRNGKey(11)
+    keys = ([jax.random.fold_in(key, i) for i in range(4)]
+            if derive == "fold_in" else list(jax.random.split(key, 4)))
+    rate = 0.1
+    masks = [_kept(k, rate) for k in keys]
+    agree = (1 - rate) ** 2 + rate ** 2
+    sigma = np.sqrt(agree * (1 - agree) / _SITE_N)
+    for i in range(4):
+        for j in range(i + 1, 4):
+            assert abs((masks[i] == masks[j]).mean() - agree) < 3 * sigma, (i, j)
+
+
+def test_dropout_gradient_is_the_mask_over_keep():
+    x = jnp.asarray(randn(64, 768))
+    key = jax.random.key(5)
+    g = jax.grad(lambda v: nn.dropout(v, key, rate=0.1).sum())(x)
+    np.testing.assert_array_equal(
+        np.asarray(g), _kept(key, 0.1, x.shape).astype(np.float32) / np.float32(0.9))
+
+
+@pytest.mark.parametrize("make_key", [jax.random.PRNGKey, jax.random.key],
+                         ids=["raw", "typed"])
+def test_dropout_draws_from_the_bit_generator_not_threefry(make_key):
+    # the printed jaxpr holds the equations of every nested call too
+    text = str(jax.make_jaxpr(lambda k, v: nn.dropout(v, k, rate=0.1))(
+        make_key(0), jnp.ones(_SITE)))
+    assert text.count("rng_bit_generator[") == 1
+    assert "shape=(48, 512, 768)" in text
+    assert "threefry2x32" not in text and "random_bits" not in text
+
+
 def test_top_k():
     x = np.array([[1.0, 5.0, 3.0], [9.0, 2.0, 4.0]], np.float32)
     vals, idx = tensor.top_k(x, 2)
