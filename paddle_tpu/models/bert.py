@@ -25,6 +25,12 @@ from paddle_tpu.nn.transformer import ACT_SPEC, TransformerEncoderLayer, _constr
 from paddle_tpu.ops import activation as ops_act
 from paddle_tpu.ops import attention as ops_attn
 
+#: the ``jax.named_scope`` names this model opens beside the blocks' own
+#: (``nn.transformer.BLOCK_SCOPES``); ``mlm_head`` covers the transform,
+#: LayerNorm and decoder matmul AND the float32 log-softmax and masked
+#: sum of :meth:`BertForPretraining.loss` (PERF.md section 3)
+MODEL_SCOPES = ("embeddings", "pooler", "mlm_head", "nsp_head")
+
 
 @dataclasses.dataclass
 class BertConfig:
@@ -119,15 +125,16 @@ class BertEmbeddings(Layer):
 
     def forward(self, params, input_ids, token_type_ids=None, *,
                 key=None, training=False):
-        s = input_ids.shape[1]
-        pos = jnp.arange(s, dtype=jnp.int32)[None, :]
-        x = self.word(params["word"], input_ids)
-        x = x + self.position(params["position"], pos)
-        if token_type_ids is None:
-            token_type_ids = jnp.zeros_like(input_ids)
-        x = x + self.token_type(params["token_type"], token_type_ids)
-        x = self.ln(params["ln"], x)
-        return self.drop(None, x, key=key, training=training)
+        with jax.named_scope("embeddings"):
+            s = input_ids.shape[1]
+            pos = jnp.arange(s, dtype=jnp.int32)[None, :]
+            x = self.word(params["word"], input_ids)
+            x = x + self.position(params["position"], pos)
+            if token_type_ids is None:
+                token_type_ids = jnp.zeros_like(input_ids)
+            x = x + self.token_type(params["token_type"], token_type_ids)
+            x = self.ln(params["ln"], x)
+            return self.drop(None, x, key=key, training=training)
 
 
 class BertModel(Layer):
@@ -172,7 +179,8 @@ class BertModel(Layer):
             for i, layer in enumerate(self.encoder):
                 x = layer(params["encoder"][str(i)], x, bias=bias,
                           key=keys[i + 1], training=training)
-        pooled = jnp.tanh(self.pooler(params["pooler"], x[:, 0]))
+        with jax.named_scope("pooler"):
+            pooled = jnp.tanh(self.pooler(params["pooler"], x[:, 0]))
         return x, pooled
 
     def _encoder_pipelined(self, params, x, bias, layer_keys, training):
@@ -219,11 +227,14 @@ class BertPretrainingHeads(Layer):
         self.nsp = Linear(cfg.hidden_size, 2, sharding=None)
 
     def forward(self, params, sequence_output, pooled_output, word_table):
-        h = ops_act.gelu(self.transform(params["transform"], sequence_output))
-        h = self.ln(params["ln"], h)
-        mlm_logits = jnp.einsum("bsd,vd->bsv", h, word_table) \
-            + params["decoder_bias"]
-        nsp_logits = self.nsp(params["nsp"], pooled_output)
+        with jax.named_scope("mlm_head"):
+            h = ops_act.gelu(
+                self.transform(params["transform"], sequence_output))
+            h = self.ln(params["ln"], h)
+            mlm_logits = jnp.einsum("bsd,vd->bsv", h, word_table) \
+                + params["decoder_bias"]
+        with jax.named_scope("nsp_head"):
+            nsp_logits = self.nsp(params["nsp"], pooled_output)
         return mlm_logits, nsp_logits
 
 
@@ -250,13 +261,17 @@ class BertForPretraining(Layer):
         mlm_logits, nsp_logits = self.forward(
             params, input_ids, token_type_ids, attention_mask,
             key=key, training=training)
-        mlm_lp = jax.nn.log_softmax(mlm_logits.astype(jnp.float32), axis=-1)
-        mlm_nll = -jnp.take_along_axis(
-            mlm_lp, mlm_labels[..., None], axis=-1)[..., 0]
-        denom = jnp.maximum(mlm_mask.sum(), 1.0)
-        mlm_loss = (mlm_nll * mlm_mask).sum() / denom
-        nsp_lp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32), axis=-1)
-        nsp_loss = -jnp.take_along_axis(
-            nsp_lp, nsp_labels[:, None], axis=-1).mean()
+        with jax.named_scope("mlm_head"):
+            mlm_lp = jax.nn.log_softmax(mlm_logits.astype(jnp.float32),
+                                        axis=-1)
+            mlm_nll = -jnp.take_along_axis(
+                mlm_lp, mlm_labels[..., None], axis=-1)[..., 0]
+            denom = jnp.maximum(mlm_mask.sum(), 1.0)
+            mlm_loss = (mlm_nll * mlm_mask).sum() / denom
+        with jax.named_scope("nsp_head"):
+            nsp_lp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32),
+                                        axis=-1)
+            nsp_loss = -jnp.take_along_axis(
+                nsp_lp, nsp_labels[:, None], axis=-1).mean()
         loss = mlm_loss + nsp_loss
         return loss, {"mlm_loss": mlm_loss, "nsp_loss": nsp_loss}

@@ -29,6 +29,17 @@ ACT_SPEC = P(("dp", "fsdp"), "sp", None)
 HEADS_SPEC = P(("dp", "fsdp"), "tp", None, None)       # (B, H, S, Dh)
 RING_HEADS_SPEC = P(("dp", "fsdp"), "tp", "sp", None)  # seq stays sharded
 
+#: the ``jax.named_scope`` names these blocks open, flat (no layer index,
+#: no nesting under a layer's own name): they reach the compiled step's
+#: ``op_name`` metadata, where ``observability.scopes`` books device time
+#: by them (PERF.md section 3). The flash kernel call stays OUTSIDE every
+#: one of them: its Pallas calls are unnamed, XLA names them after the
+#: innermost enclosing scope (``jvp_forward_.N``), and the accepted
+#: ``kernel.flash_attn_*`` metrics select them by that name. So
+#: ``attn_qkv`` and ``attn_out`` are siblings of the kernel call, and
+#: ``attn_core`` wraps the composed (non-flash) attention only.
+BLOCK_SCOPES = ("attn_qkv", "attn_core", "attn_out", "ffn", "add_norm")
+
 
 def _constrain(x, spec):
     try:
@@ -66,9 +77,10 @@ def _attend(q, k, v, bias, *, causal, dropout_rate, dropout_key, impl):
     from paddle_tpu.core import mesh as mesh_lib
     impl = ops_attn.resolve_attention_impl(impl, dropout_rate)
     if impl == "xla":
-        return ops_attn.scaled_dot_product_attention(
-            q, k, v, bias=bias, causal=causal, dropout_rate=dropout_rate,
-            dropout_key=dropout_key)
+        with jax.named_scope("attn_core"):
+            return ops_attn.scaled_dot_product_attention(
+                q, k, v, bias=bias, causal=causal, dropout_rate=dropout_rate,
+                dropout_key=dropout_key)
 
     def kernel(q, k, v, bias=None):
         return ops_attn.flash_attention_dispatch(
@@ -135,16 +147,19 @@ class MultiHeadAttention(Layer):
         b, h, s, d = x.shape
         return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
-    def qkv_heads(self, params, x):
+    def qkv_heads(self, params, x, key_value=None):
         """(B, S, D) -> (q, k, v) heads, each (B, H, S, Dh) — the serving
         engine's hook: it owns the attention itself (ragged paged decode
-        over the shared page pool) and only needs the projections."""
+        over the shared page pool) and only needs the projections.
+        ``key_value`` (B, Sk, D): what cross-attention projects k and v
+        from (``x`` itself when left out)."""
         if self.self_attention:
             qkv = self.qkv_proj(params["qkv_proj"], x)
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
             q = self.q_proj(params["q_proj"], x)
-            kv = self.kv_proj(params["kv_proj"], x)
+            kv = self.kv_proj(params["kv_proj"],
+                              x if key_value is None else key_value)
             k, v = jnp.split(kv, 2, axis=-1)
         return tuple(self._split_heads(t) for t in (q, k, v))
 
@@ -178,44 +193,40 @@ class MultiHeadAttention(Layer):
         heads (see :meth:`cross_kv`) — skips the kv projection entirely
         (cross-attention decode)."""
         if static_kv is not None:
-            q = self._split_heads(self.q_proj(params["q_proj"], query))
+            with jax.named_scope("attn_qkv"):
+                q = self._split_heads(self.q_proj(params["q_proj"], query))
             k, v = static_kv
-            out = ops_attn.dot_product_attention(
-                q, k, v, bias=bias, causal=False, impl="xla")
-            out = self._merge_heads(out)
-            return self.out_proj(params["out_proj"], out)
-        if self.self_attention:
-            qkv = self.qkv_proj(params["qkv_proj"], query)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-        else:
-            q = self.q_proj(params["q_proj"], query)
-            kv = self.kv_proj(params["kv_proj"],
-                              query if key_value is None else key_value)
-            k, v = jnp.split(kv, 2, axis=-1)
-        q, k, v = (self._split_heads(t) for t in (q, k, v))
+            with jax.named_scope("attn_core"):
+                out = ops_attn.dot_product_attention(
+                    q, k, v, bias=bias, causal=False, impl="xla")
+            with jax.named_scope("attn_out"):
+                return self.proj_out(params, out)
+        with jax.named_scope("attn_qkv"):
+            q, k, v = self.qkv_heads(params, query, key_value)
 
         if cache is not None:
-            ck, cv = cache
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, 0, cache_pos, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, 0, cache_pos, 0))
-            # static shapes: attend over the whole cache, mask the unfilled
-            # tail (positions > cache_pos)
-            smax = ck.shape[2]
-            mask = jnp.arange(smax)[None, None, None, :] <= cache_pos
-            step_bias = jnp.where(mask, 0.0, -1e30).astype(q.dtype)
-            if bias is not None:
-                step_bias = step_bias + bias
-            out = ops_attn.dot_product_attention(
-                q, ck, cv, bias=step_bias, causal=False, impl="xla")
-            out = self._merge_heads(out)
-            out = self.out_proj(params["out_proj"], out)
-            return out, (ck, cv)
+            with jax.named_scope("attn_core"):
+                ck, cv = cache
+                ck = jax.lax.dynamic_update_slice(
+                    ck, k.astype(ck.dtype), (0, 0, cache_pos, 0))
+                cv = jax.lax.dynamic_update_slice(
+                    cv, v.astype(cv.dtype), (0, 0, cache_pos, 0))
+                # static shapes: attend over the whole cache, mask the
+                # unfilled tail (positions > cache_pos)
+                smax = ck.shape[2]
+                mask = jnp.arange(smax)[None, None, None, :] <= cache_pos
+                step_bias = jnp.where(mask, 0.0, -1e30).astype(q.dtype)
+                if bias is not None:
+                    step_bias = step_bias + bias
+                out = ops_attn.dot_product_attention(
+                    q, ck, cv, bias=step_bias, causal=False, impl="xla")
+            with jax.named_scope("attn_out"):
+                return self.proj_out(params, out), (ck, cv)
         spec = RING_HEADS_SPEC if self.attn_impl == "ring" else HEADS_SPEC
-        q = _constrain(q, spec)
-        k = _constrain(k, spec)
-        v = _constrain(v, spec)
+        with jax.named_scope("attn_qkv"):
+            q = _constrain(q, spec)
+            k = _constrain(k, spec)
+            v = _constrain(v, spec)
         drop_rate = self.dropout_rate if training else 0.0
         if self.attn_impl == "ring":
             # sequence-parallel path: S sharded over "sp", k/v ride the ring
@@ -225,9 +236,8 @@ class MultiHeadAttention(Layer):
             out = _attend(q, k, v, bias, causal=self.causal,
                           dropout_rate=drop_rate, dropout_key=key,
                           impl=self.attn_impl)
-        out = self._merge_heads(out)
-        out = self.out_proj(params["out_proj"], out)
-        out = _constrain(out, ACT_SPEC)
+        with jax.named_scope("attn_out"):
+            out = _constrain(self.proj_out(params, out), ACT_SPEC)
         if return_kv:
             return out, (k, v)
         return out
@@ -244,9 +254,10 @@ class FeedForward(Layer):
         self.drop = Dropout(dropout)
 
     def forward(self, params, x, key=None, training=False):
-        h = self.act(self.fc1(params["fc1"], x))
-        h = self.drop(None, h, key=key, training=training)
-        return _constrain(self.fc2(params["fc2"], h), ACT_SPEC)
+        with jax.named_scope("ffn"):
+            h = self.act(self.fc1(params["fc1"], x))
+            h = self.drop(None, h, key=key, training=training)
+            return _constrain(self.fc2(params["fc2"], h), ACT_SPEC)
 
 
 class TransformerEncoderLayer(Layer):
@@ -269,25 +280,34 @@ class TransformerEncoderLayer(Layer):
     def forward(self, params, x, *, bias=None, key=None, training=False):
         k1 = k2 = k3 = None
         if key is not None:
-            k1, k2, k3 = jax.random.split(key, 3)
+            with jax.named_scope("add_norm"):   # the dropouts' keys
+                k1, k2, k3 = jax.random.split(key, 3)
+        # ``add_norm``: the residual, its dropout and the LayerNorm, as
+        # siblings of the attention and ffn calls (never around them)
         if self.pre_ln:
-            h = self.attn(params["attn"], self.ln1(params["ln1"], x),
-                          bias=bias, key=k1, training=training)
-            x = x + self.drop(None, h, key=k2, training=training)
-            h = self.ffn(params["ffn"], self.ln2(params["ln2"], x),
-                         key=k3, training=training)
-            if key is not None:
-                h = self.drop(None, h, key=jax.random.fold_in(k3, 1),
-                              training=training)
-            return x + h
+            with jax.named_scope("add_norm"):
+                h = self.ln1(params["ln1"], x)
+            h = self.attn(params["attn"], h, bias=bias, key=k1,
+                          training=training)
+            with jax.named_scope("add_norm"):
+                x = x + self.drop(None, h, key=k2, training=training)
+                h = self.ln2(params["ln2"], x)
+            h = self.ffn(params["ffn"], h, key=k3, training=training)
+            with jax.named_scope("add_norm"):
+                if key is not None:
+                    h = self.drop(None, h, key=jax.random.fold_in(k3, 1),
+                                  training=training)
+                return x + h
         h = self.attn(params["attn"], x, bias=bias, key=k1, training=training)
-        x = self.ln1(params["ln1"],
-                     x + self.drop(None, h, key=k2, training=training))
+        with jax.named_scope("add_norm"):
+            x = self.ln1(params["ln1"],
+                         x + self.drop(None, h, key=k2, training=training))
         h = self.ffn(params["ffn"], x, key=k3, training=training)
-        if key is not None:
-            k4 = jax.random.fold_in(k3, 1)
-            h = self.drop(None, h, key=k4, training=training)
-        return self.ln2(params["ln2"], x + h)
+        with jax.named_scope("add_norm"):
+            if key is not None:
+                k4 = jax.random.fold_in(k3, 1)
+                h = self.drop(None, h, key=k4, training=training)
+            return self.ln2(params["ln2"], x + h)
 
 
 class TransformerDecoderLayer(Layer):
@@ -318,17 +338,24 @@ class TransformerDecoderLayer(Layer):
                 key=None, training=False):
         ks = [None] * 3
         if key is not None:
-            ks = list(jax.random.split(key, 3))
+            with jax.named_scope("add_norm"):   # the dropouts' keys
+                ks = list(jax.random.split(key, 3))
 
         def sub(x, ln_name, fn, drop_key):
             ln = getattr(self, ln_name)
-            dk = (jax.random.fold_in(drop_key, 1)
-                  if drop_key is not None else None)
+            with jax.named_scope("add_norm"):
+                dk = (jax.random.fold_in(drop_key, 1)
+                      if drop_key is not None else None)
             if self.pre_ln:
-                h = fn(ln(params[ln_name], x))
-                return x + self.drop(None, h, key=dk, training=training)
-            h = self.drop(None, fn(x), key=dk, training=training)
-            return ln(params[ln_name], x + h)
+                with jax.named_scope("add_norm"):
+                    h = ln(params[ln_name], x)
+                h = fn(h)
+                with jax.named_scope("add_norm"):
+                    return x + self.drop(None, h, key=dk, training=training)
+            h = fn(x)
+            with jax.named_scope("add_norm"):
+                h = self.drop(None, h, key=dk, training=training)
+                return ln(params[ln_name], x + h)
 
         x = sub(x, "ln1",
                 lambda h: self.self_attn(params["self_attn"], h,
@@ -352,8 +379,14 @@ class TransformerDecoderLayer(Layer):
         def sub(x, ln_name, fn):
             ln = getattr(self, ln_name)
             if self.pre_ln:
-                return x + fn(ln(params[ln_name], x))
-            return ln(params[ln_name], x + fn(x))
+                with jax.named_scope("add_norm"):
+                    h = ln(params[ln_name], x)
+                h = fn(h)
+                with jax.named_scope("add_norm"):
+                    return x + h
+            h = fn(x)
+            with jax.named_scope("add_norm"):
+                return ln(params[ln_name], x + h)
 
         box = {}
 

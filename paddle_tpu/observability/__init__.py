@@ -32,7 +32,8 @@ first-class telemetry):
 One :func:`report` call dumps a unified summary across all four.
 """
 
-from paddle_tpu.observability import anatomy, exposition, flight, slo, tracing
+from paddle_tpu.observability import (anatomy, exposition, flight, recompile,
+                                      scopes, slo, tracing)
 from paddle_tpu.observability.anatomy import (StepAnatomy,
                                               validate_anatomy_log,
                                               validate_anatomy_record,
@@ -51,6 +52,7 @@ from paddle_tpu.observability.runlog import (RunLogWriter, read_run_log,
 from paddle_tpu.observability.recompile import (RecompileDetector,
                                                 compile_count,
                                                 install_compile_listener,
+                                                loaded_programs,
                                                 shape_signature)
 from paddle_tpu.observability.aggregate import aggregate, format_aggregate
 from paddle_tpu.observability.telemetry import (StepTelemetry,
@@ -99,7 +101,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "counter",
     "default", "gauge", "histogram", "RunLogWriter", "read_run_log",
     "validate_record", "validate_run_log", "RecompileDetector",
-    "compile_count", "install_compile_listener", "shape_signature",
+    "compile_count", "install_compile_listener", "loaded_programs",
+    "shape_signature",
     "aggregate", "format_aggregate", "StepTelemetry",
     "device_memory_stats", "record_memory_gauges", "SPAN_METRIC",
     "report", "render_prometheus", "snapshot", "observe_span",
@@ -109,5 +112,5 @@ __all__ = [
     "validate_anatomy_log", "FlightRecorder", "POSTMORTEM_SCHEMA",
     "validate_postmortem_bundle", "validate_postmortem_file",
     "write_bundle",
-    "tracing", "exposition", "slo", "anatomy", "flight",
+    "tracing", "exposition", "slo", "anatomy", "flight", "scopes",
 ]

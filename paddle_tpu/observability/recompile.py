@@ -9,25 +9,152 @@ once per backend compile, cache hits excluded), keeps a process-wide
 count, and lets the Trainer snapshot it per step: a count increase after
 warmup is a retrace, logged as a structured warning with the function
 name and the offending batch's arg-shape signature.
+
+The same listener keeps the process's CATALOGUE of loaded programs: at
+every compile event, and at every program read from the persistent
+compile cache, it looks at the handles the backend lists
+(``live_executables()``) and holds the ones it has not seen, the last
+``_RECENT`` of them within ``_HELD_CODE_BYTES`` of generated code.
+Handles only: no HLO text is produced or parsed
+until ``observability.scopes.tables()`` asks, so with tracing off the
+cost is one list call an event. A held handle keeps its program loaded
+after the caller dropped it; what that costs on the chip is in PERF.md
+section 6 (PR 38).
 """
 
 from __future__ import annotations
 
+import collections
+import dataclasses
+import itertools
 import threading
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional
 
 from paddle_tpu.observability import registry as _registry
 
 # any of these firing == one backend compile happened in-process
 _COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",)
+# a program came from the persistent cache instead (JAX's own event for
+# the read): no compile, but a program the backend has loaded
+_CACHE_READ_EVENTS = ("/jax/compilation_cache/cache_retrieval_time_sec",)
 
 _lock = threading.Lock()
 _installed = False
 _count = 0
 
 
+#: the programs the listener saw last, held so that one the caller has
+#: dropped since can still be asked for its table. Bounded in number (a
+#: loaded program keeps its code mapped, and a process that compiles
+#: thousands, a test suite or a retracing trainer, must not keep them
+#: all) and in bytes: a held program's generated code stays in device
+#: memory (the BERT cell's two reference programs, 34 and 70 MB, raised
+#: ``memory_peak_bytes`` by exactly that on a chip that has 0.3 GB to
+#: spare: PERF.md section 6, PR 38), so a program larger than the budget
+#: is not held at all. A serving engine lifts the budget
+#: (:func:`hold_step_programs`): its step programs are 10-60 MB of code
+#: each and loaded for as long as it serves, so holding them costs
+#: nothing until it is dropped, and a trace of its steps is read after
+_RECENT = 64
+_HELD_CODE_BYTES = 32 << 20
+_held_code_bytes: Optional[int] = _HELD_CODE_BYTES
+
+
+def hold_step_programs() -> None:
+    """Hold the programs the listener sees whatever their size (the last
+    ``_RECENT`` still): for a process whose large programs are its own
+    step programs, alive while it runs (``ServingEngine.__init__``)."""
+    global _held_code_bytes
+    install_compile_listener()
+    _held_code_bytes = None
+_recent: Deque["LoadedProgram"] = collections.deque(maxlen=_RECENT)
+_seen: Dict[int, int] = {}      # id(handle) -> seq, of the LIVE programs
+_tables: Dict[int, Any] = {}    # seq -> scope table, of the live programs
+_seq = itertools.count(1)
+
+
+@dataclasses.dataclass
+class LoadedProgram:
+    """One loaded program: the backend's handle, the order in which the
+    listener first saw it, and (``table``) its scope table once
+    ``scopes.tables()`` has built it, kept while the program is loaded."""
+    handle: Any
+    seq: int
+    code_bytes: int = 0     # its generated code, as loaded on the device
+
+    @property
+    def module(self) -> str:
+        return self.handle.hlo_modules()[0].name
+
+    @property
+    def table(self) -> Any:
+        return _tables.get(self.seq)
+
+    @table.setter
+    def table(self, value: Any) -> None:
+        _tables[self.seq] = value
+
+
+def _look(collect: bool = False) -> List[LoadedProgram]:
+    """Look at what the backend lists: programs not seen before go into
+    ``_recent``; what died is forgotten (an ``id`` may come back as
+    another program's: a handle is the backend's own object, the same
+    one every call, so ``id`` is a key only while its program lives).
+    With ``collect`` -> a record a live program."""
+    try:
+        import jax.extend.backend
+        live = jax.extend.backend.get_backend().live_executables()
+    except Exception:       # telemetry must never take a compile down
+        return []
+    out = []
+    with _lock:
+        seqs = {}
+        for handle in live:
+            seq = _seen.get(id(handle))
+            if seq is None:
+                seq = next(_seq)
+                _hold(LoadedProgram(handle, seq, _code_bytes(handle)))
+            seqs[id(handle)] = seq
+            if collect:
+                out.append(LoadedProgram(handle, seq))
+        _seen.clear()
+        _seen.update(seqs)
+        for gone in set(_tables).difference(seqs.values()):
+            del _tables[gone]
+    return out
+
+
+def _code_bytes(handle) -> int:
+    try:
+        return int(handle.get_compiled_memory_stats()
+                   .generated_code_size_in_bytes)
+    except Exception:       # a backend that does not say: count it free
+        return 0
+
+
+def _hold(rec: LoadedProgram) -> None:
+    """``rec`` into ``_recent``, the oldest out until the held programs'
+    code fits the budget, where there is one (with ``_lock`` held)."""
+    budget = _held_code_bytes
+    if budget is not None and rec.code_bytes > budget:
+        return
+    _recent.append(rec)
+    while budget is not None \
+            and sum(r.code_bytes for r in _recent) > budget:
+        _recent.popleft()
+
+
+def loaded_programs() -> List[LoadedProgram]:
+    """Every program the backend has loaded now, oldest first: what the
+    process holds itself, and the last ``_RECENT`` the compile listener
+    saw (:func:`install_compile_listener`), whoever dropped them since."""
+    return sorted(_look(collect=True), key=lambda rec: rec.seq)
+
+
 def _on_duration(event: str, duration: float, **kw):
     global _count
+    if event in _COMPILE_EVENTS or event in _CACHE_READ_EVENTS:
+        _look()
     if event in _COMPILE_EVENTS:
         with _lock:
             _count += 1
@@ -109,6 +236,7 @@ class RecompileDetector:
         self._log = log_fn if log_fn is not None else _warn
         self._baseline = compile_count()
         self._last = self._baseline
+        self._seen_seq = next(_seq)
         self._checks = 0
         self.compiles_cum = 0     # compiles since construction
         self.recompiles = 0       # compiles after warmup (true retraces)
@@ -122,6 +250,11 @@ class RecompileDetector:
         self._last = now
         self._checks += 1
         self.compiles_cum = now - self._baseline
+        appeared = []
+        if new:         # a step without a compile event does no new work
+            with _lock:
+                appeared = [p for p in _recent if p.seq > self._seen_seq]
+            self._seen_seq = next(_seq)
         if new and self._checks > self.warmup:
             self.recompiles += new
             self._reg.counter(
@@ -130,7 +263,8 @@ class RecompileDetector:
             at = f" step={step}" if step is not None else ""
             self._log(
                 f"[observability] RECOMPILATION: fn={self.name}{at} "
-                f"new_compiles={new} total_retraces={self.recompiles} — "
+                f"new_compiles={new} total_retraces={self.recompiles} "
+                f"programs={[p.module for p in appeared]} — "
                 f"arg signature: {shape_signature(feeds)} (a mid-training "
                 "retrace usually means input shape/dtype drift; pad or "
                 "bucket the batch)")

@@ -24,6 +24,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core import mesh as mesh_lib
+from paddle_tpu.observability.recompile import install_compile_listener
 from paddle_tpu.parallel import plan as plan_lib
 
 
@@ -68,6 +69,7 @@ def shard_train_step(
     ``BCastParamsToDevices``, ``parallel_executor.cc:630`` — except sharded
     placement, not N full copies).
     """
+    install_compile_listener()     # catalogue the program the caller loads
     plan = plan or plan_lib.replicated_plan()
     state_specs = plan.state_specs(state, hints)
     state_sh = plan_lib.named_shardings(mesh, state_specs)

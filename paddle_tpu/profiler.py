@@ -6,8 +6,10 @@ Reference mapping (SURVEY.md §5.1): RAII ``RecordEvent`` wrapping every op
 ``fluid.profiler.profiler`` context managers (python/paddle/fluid/
 profiler.py). TPU-native: ``jax.profiler`` (XPlane → TensorBoard/Perfetto)
 carries the device side; ``record_event``/named_scope annotate traced
-regions so XLA ops correlate back to model code; a lightweight host-side
-event table reproduces the sorted per-op summary report.
+regions so XLA ops correlate back to model code
+(:func:`device_time_by_scope` books a session's device time by those
+names, from the loaded programs' own ``op_name`` metadata); a lightweight
+host-side event table reproduces the sorted per-op summary report.
 """
 
 from __future__ import annotations
@@ -75,7 +77,9 @@ def profiler(output_dir: Optional[str] = None, *, summary: bool = True):
     """Profile a region. With ``output_dir``, captures a jax.profiler trace
     viewable in TensorBoard/XProf (device timeline ≙ CUPTI tracer + Chrome
     trace). Always collects host record_event stats; prints the sorted
-    summary table on exit (EnableProfiler/DisableProfiler parity)."""
+    summary table on exit (EnableProfiler/DisableProfiler parity), and
+    under it the session's device time by named scope
+    (:func:`device_time_by_scope`) when it traced a device."""
     if output_dir:
         jax.profiler.start_trace(output_dir)
     res = []
@@ -88,6 +92,34 @@ def profiler(output_dir: Optional[str] = None, *, summary: bool = True):
         events, wall = res[0]
         if summary and events:
             print(format_summary(events, wall))
+        if summary and output_dir:
+            by_scope = device_time_by_scope(output_dir)
+            if by_scope:                # the session traced a device
+                print(format_by_scope(by_scope))
+
+
+def device_time_by_scope(logdir: str) -> Dict[str, float]:
+    """Device self seconds of the profiler session under ``logdir`` by
+    the ``jax.named_scope`` each instruction was traced under:
+    ``{"forward/ffn": 0.41, "backward/ffn": 0.83, "optimizer/": ...,
+    "attend": ..., "unattributed": ...}`` (``phase/scope``; the bare
+    scope where a step has no phases; ``unattributed``: events no loaded
+    program's table could key). The session's ``.xplane.pb`` is joined
+    with :func:`paddle_tpu.observability.scopes.tables` by (module,
+    instruction): the programs must still be loaded, or have been
+    catalogued by the compile listener (``build_train_step``,
+    ``shard_train_step`` and the serving engine install it)."""
+    return _obs.scopes.device_time_by_scope(logdir)
+
+
+def format_by_scope(by_scope: Dict[str, float]) -> str:
+    """The by-scope table of :func:`device_time_by_scope`, sorted."""
+    total = sum(by_scope.values())
+    lines = [f"{'Scope (device self time)':<32}{'Total(s)':>12}{'Ratio':>8}"]
+    for key, t in sorted(by_scope.items(), key=lambda kv: -kv[1]):
+        lines.append(f"{key or '(no scope)':<32}{t:>12.4f}"
+                     f"{t / max(total, 1e-12):>8.2%}")
+    return "\n".join(lines)
 
 
 def format_summary(events, wall: float) -> str:

@@ -202,6 +202,15 @@ _STEP_PARTS = (("prefill", "assemble"), ("prefill", "cow_copy"),
                ("decode", "dispatch"), ("decode", "sync"),
                ("decode", "book"), ("sched", "book"), ("observe", "book"))
 
+#: the ``jax.named_scope`` names the two loops open, one around each
+#: program hook (``_decode_loop`` / ``_prefill_loop``). They reach the
+#: compiled programs' ``op_name`` metadata, where
+#: ``observability.scopes`` books device time by them: a contract like
+#: the ``serving.*`` span names (PERF.md section 3). No layer index: the
+#: layers of a program are one scope.
+STEP_SCOPES = ("embed", "attn_in", "write_rows", "attend", "attn_out",
+               "mixer", "ffn", "head", "stats")
+
 MIGRATION_FORMAT = "paddle_tpu.serving.slot-migration-v1"
 
 # fleet-global prefix reuse (ISSUE 20): committed prefix pages travel
@@ -451,6 +460,9 @@ class ServingEngine:
         self._reg = registry or obs.default()
         self.recompile_detector = obs.RecompileDetector(
             "serving_decode", warmup=1, registry=self._reg)
+        # the step programs stay catalogued after this engine is dropped,
+        # so that a trace of its steps can be booked by scope afterwards
+        obs.recompile.hold_step_programs()
         # request-lifecycle tracing: one root span per request, children
         # per prefill chunk / decode block, scheduler verdicts as events.
         # All host-side — nothing below touches jitted code, so tracing
@@ -2936,7 +2948,8 @@ class ServingEngine:
 
         def one_token(j, pages, lengths, tokens):
             pos = jnp.minimum(lengths, spec.max_position - 1)
-            x = program.embed(params, tokens[:, None], pos[:, None])
+            with jax.named_scope("embed"):
+                x = program.embed(params, tokens[:, None], pos[:, None])
             writable = active > 0
             if n_valid is not None:
                 writable = writable & (j < n_valid)
@@ -2945,7 +2958,8 @@ class ServingEngine:
                 block_tables[slot_ids, jnp.minimum(lengths // ps, w - 1)],
                 0)
             off = lengths % ps
-            seen = jnp.where(writable, lengths + 1, 0).sum()
+            with jax.named_scope("stats"):
+                seen = jnp.where(writable, lengths + 1, 0).sum()
             # a decoding slot's state is pool row slot + 1; any other
             # slot's (free, or mid-prefill and owning live state) is
             # not this block's to touch: the null row
@@ -2954,33 +2968,41 @@ class ServingEngine:
             new_pages, counts = [], 0
             carry = self._carry_start(spec, s_tot, 1)
             for i in range(spec.num_layers):
-                q, rows, index, state = self._attn_in(
-                    program, params, i, x, pos[:, None], pages[i][n_paged:],
-                    state_rows, None, writable)
-                ent = self._write_rows(
-                    pages[i][:n_paged], tuple(r[:, 0] for r in rows),
-                    page_idx, off, quantized, psum_axis)
-                att, attended = self._attend_decode(
-                    spec, q[:, :, 0, :], ent, block_tables, lengths + 1,
-                    index, quantized)                           # (S,H,Dh)
-                x_in, x = x, program.attn_out(params, i, x, att[:, None])
+                with jax.named_scope("attn_in"):
+                    q, rows, index, state = self._attn_in(
+                        program, params, i, x, pos[:, None],
+                        pages[i][n_paged:], state_rows, None, writable)
+                with jax.named_scope("write_rows"):
+                    ent = self._write_rows(
+                        pages[i][:n_paged], tuple(r[:, 0] for r in rows),
+                        page_idx, off, quantized, psum_axis)
+                with jax.named_scope("attend"):
+                    att, attended = self._attend_decode(
+                        spec, q[:, :, 0, :], ent, block_tables, lengths + 1,
+                        index, quantized)                       # (S,H,Dh)
+                with jax.named_scope("attn_out"):
+                    x_in, x = x, program.attn_out(params, i, x, att[:, None])
                 if mixes:
-                    mixed, state = program.mixer(
-                        params, i, x_in, pages[i][n_paged:], state_rows,
-                        jnp.zeros_like(state_rows), writable[:, None])
-                    x = x + mixed
+                    with jax.named_scope("mixer"):
+                        mixed, state = program.mixer(
+                            params, i, x_in, pages[i][n_paged:], state_rows,
+                            jnp.zeros_like(state_rows), writable[:, None])
+                        x = x + mixed
                 if spec.slot_state:
                     ent = ent + tuple(state)
                 new_pages.append(ent)
-                x, ffn_stats, carry = self._ffn(
-                    program, params, i, x, writable[:, None], carry)
+                with jax.named_scope("ffn"):
+                    x, ffn_stats, carry = self._ffn(
+                        program, params, i, x, writable[:, None], carry)
                 if n_stats:
-                    counts = counts + self._step_stat_vector(
-                        spec, ffn_stats, seen,
-                        jnp.where(writable, attended, 0).sum())
-            logits = program.head(params, x[:, 0])
-            return (new_pages, jnp.argmax(logits, -1).astype(jnp.int32),
-                    counts)
+                    with jax.named_scope("stats"):
+                        counts = counts + self._step_stat_vector(
+                            spec, ffn_stats, seen,
+                            jnp.where(writable, attended, 0).sum())
+            with jax.named_scope("head"):
+                logits = program.head(params, x[:, 0])
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            return new_pages, nxt, counts
 
         out = jnp.zeros((s_tot, n_steps), jnp.int32)
         totals = jnp.zeros((n_stats,), jnp.int32) if n_stats else ()
@@ -3061,7 +3083,8 @@ class ServingEngine:
         w = block_tables.shape[1]
         positions = starts[:, None] + jnp.arange(c, dtype=jnp.int32)
         pos_e = jnp.minimum(positions, spec.max_position - 1)
-        x = program.embed(params, tokens, pos_e)                # (S,C,D)
+        with jax.named_scope("embed"):
+            x = program.embed(params, tokens, pos_e)            # (S,C,D)
         valid = jnp.arange(c)[None, :] < n_valid[:, None]
         slot_ids = jnp.arange(s_tot)[:, None]
         page_idx = jnp.where(
@@ -3070,43 +3093,53 @@ class ServingEngine:
             0)
         off = positions % ps
         counting = bool(self._stat_names(spec))
-        seen = positions + 1                 # tokens a query can attend to
-        attended = jnp.minimum(seen, spec.select_topk) \
-            if self._selects(spec, block_tables) else seen
-        seen, attended = (jnp.where(valid, a, 0).sum()
-                          for a in (seen, attended))
+        with jax.named_scope("stats"):
+            seen = positions + 1             # tokens a query can attend to
+            attended = jnp.minimum(seen, spec.select_topk) \
+                if self._selects(spec, block_tables) else seen
+            seen, attended = (jnp.where(valid, a, 0).sum()
+                              for a in (seen, attended))
         new_pages, counts = [], 0
         carry = self._carry_start(spec, s_tot, c)
         for i in range(spec.num_layers):
-            q, rows, index, state = self._attn_in(
-                program, params, i, x, pos_e, pages[i][n_paged:],
-                state_rows, fresh, valid)
-            ent = self._write_rows(pages[i][:n_paged], rows, page_idx, off,
-                                   quantized, psum_axis)
-            att = self._attend_prefill(
-                spec, q.transpose(0, 2, 1, 3), ent, block_tables, starts,
-                n_valid, index, quantized)                      # (S,C,H,Dh)
-            x_in, x = x, program.attn_out(params, i, x, att)
+            with jax.named_scope("attn_in"):
+                q, rows, index, state = self._attn_in(
+                    program, params, i, x, pos_e, pages[i][n_paged:],
+                    state_rows, fresh, valid)
+            with jax.named_scope("write_rows"):
+                ent = self._write_rows(pages[i][:n_paged], rows, page_idx,
+                                       off, quantized, psum_axis)
+            with jax.named_scope("attend"):
+                att = self._attend_prefill(
+                    spec, q.transpose(0, 2, 1, 3), ent, block_tables, starts,
+                    n_valid, index, quantized)                  # (S,C,H,Dh)
+            with jax.named_scope("attn_out"):
+                x_in, x = x, program.attn_out(params, i, x, att)
             if mixes:
-                mixed, state = program.mixer(
-                    params, i, x_in, pages[i][n_paged:], state_rows, fresh,
-                    valid)
-                x = x + mixed
+                with jax.named_scope("mixer"):
+                    mixed, state = program.mixer(
+                        params, i, x_in, pages[i][n_paged:], state_rows,
+                        fresh, valid)
+                    x = x + mixed
             if spec.slot_state:
                 ent = ent + tuple(state)
             new_pages.append(ent)
-            x, ffn_stats, carry = self._ffn(program, params, i, x, valid,
-                                            carry)
+            with jax.named_scope("ffn"):
+                x, ffn_stats, carry = self._ffn(program, params, i, x, valid,
+                                                carry)
             if counting:
-                counts = counts + self._step_stat_vector(
-                    spec, ffn_stats, seen, attended)
-        if all_positions:
-            logits = program.head(params, x)                    # (S,C,V)
-        else:
-            last = jnp.take_along_axis(
-                x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
-            logits = program.head(params, last)                 # (S, V)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                with jax.named_scope("stats"):
+                    counts = counts + self._step_stat_vector(
+                        spec, ffn_stats, seen, attended)
+        with jax.named_scope("head"):
+            if all_positions:
+                logits = program.head(params, x)                # (S,C,V)
+            else:
+                last = jnp.take_along_axis(
+                    x, jnp.maximum(n_valid - 1, 0)[:, None, None],
+                    axis=1)[:, 0]
+                logits = program.head(params, last)             # (S, V)
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         return ((nxt, counts) if counting else nxt), new_pages
 
     def _prefill_step_impl(self, params, pages, block_tables, starts,

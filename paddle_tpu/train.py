@@ -21,6 +21,12 @@ import jax.numpy as jnp
 
 from paddle_tpu.core import dtypes
 from paddle_tpu.nn.module import apply_state_updates, capture_state
+from paddle_tpu.observability.recompile import install_compile_listener
+
+#: the ``jax.named_scope`` names :func:`build_train_step` opens: the
+#: phases ``observability.scopes`` splits a step's device time by (the
+#: backward inherits ``forward`` as ``transpose(jvp(forward))``)
+PHASE_SCOPES = ("forward", "optimizer")
 
 
 def make_train_state(model, optimizer, rng_key, sample_extra=None):
@@ -55,6 +61,9 @@ def build_train_step(
     ir/multi_batch_merge_pass.h:34).
     """
 
+    # the step's compile is the caller's: catalogue whatever it loads, so
+    # that observability.scopes can book device time by the scopes below
+    install_compile_listener()
     if remat:
         loss_fn = jax.checkpoint(loss_fn)
 

@@ -169,12 +169,15 @@ def _cell_engine(variant):
 
 @pytest.fixture(scope="module", params=["bf16", "int8", "tp2"])
 def cell_steps(request, topo):
+    return _cell_steps(request.param, topo)
+
+
+def _cell_steps(variant, topo):
     """(variant, lower(step, lanes, width) -> compiled) for one engine:
     its decode and prefill steps compiled for the described chip — for
     tp2 the same step bodies under ``shard_map`` over two of the
     described chips, arguments sharded as the engine shards them."""
     from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
-    variant = request.param
     eng = _cell_engine(variant)
     steps = {"decode": eng.decode_step, "prefill": eng.prefill_step}
     if variant == "tp2":
@@ -524,6 +527,7 @@ def _slot_state_steps(topo, model, slots, page_size, num_pages, chunk,
     def i32(*shape):
         return sds(shape, jnp.int32, sharding=dev)
 
+    @functools.lru_cache(maxsize=None)
     def lower(step, lanes, width):
         if step == "decode":
             return eng.decode_step.lower(
@@ -622,3 +626,104 @@ def test_latent_family_steps_compile_and_keep_the_pools(
             rf"bf16\[{ZY_PAGES},{ZY_PS},256\]": "{2,1,0",
             rf"f32\[{ZY_SLOTS + 1},1280\]": "{1,0",
             rf"f32\[{ZY_SLOTS + 1},128\]": "{1,0"}, 269 << 20)
+
+
+# -- the named scopes of PR 38 leave every name a metric selects as it was ------
+
+def _kernel_counts(text):
+    """Pallas custom calls of a compiled program by name, and its
+    ``rng-bit-generator`` instructions."""
+    import collections
+    import re
+    counts = collections.Counter(
+        re.sub(r"\.\d+$", "", n) for n in re.findall(
+            r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text))
+    counts["rng-bit-generator"] = len(re.findall(r" rng-bit-generator\(",
+                                                 text))
+    return dict(counts)
+
+
+def _scope_keys(text):
+    """Every ``phase/scope`` the compiled program's instructions are
+    booked to."""
+    from paddle_tpu.observability import scopes
+    return {sc.key for sc in scopes.parse_hlo(text).scopes.values()}
+
+
+def test_bert_base_step_keeps_the_names_the_metrics_select(one_chip):
+    """The BERT-base cell's train step (batch 48 x 512, bf16 policy, flash
+    kernel, a pool of batches) compiled for the chip WITH the model's
+    named scopes: the flash kernels are still the 12 ``jvp_forward_`` and
+    24 ``transpose_jvp_forward__`` custom calls ``kernel.flash_attn_*``
+    select (a scope that ENCLOSED the kernel call would have renamed
+    them), the dropout draws still 37 ``rng-bit-generator`` instructions
+    (``kernel.dropout_bits_time_pct.train``), and every scope reaches
+    the compiled program's metadata in the forward and the backward."""
+    from paddle_tpu import optimizer as opt
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.models import bert
+    from paddle_tpu.nn import transformer
+    from paddle_tpu.train import build_train_step, make_train_state
+    model = bert.BertForPretraining(bert.BertConfig(
+        attn_dropout=0.0, attn_impl="flash"))
+    optimizer = opt.AdamW(learning_rate=1e-4)
+    step = build_train_step(
+        lambda params, **batch: model.loss(params, training=True, **batch),
+        optimizer, policy=dtypes.get_policy("bf16"))
+    sds = jax.ShapeDtypeStruct
+    state = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype, sharding=one_chip), jax.eval_shape(
+            lambda k: make_train_state(model, optimizer, k),
+            jax.random.PRNGKey(0)))
+
+    def arr(dtype, *shape):
+        return sds(shape, dtype, sharding=one_chip)
+    batch = dict(
+        input_ids=arr(jnp.int32, B, SEQ), token_type_ids=arr(jnp.int32, B, SEQ),
+        attention_mask=arr(bool, B, SEQ), mlm_labels=arr(jnp.int32, B, SEQ),
+        mlm_mask=arr(jnp.float32, B, SEQ), nsp_labels=arr(jnp.int32, B),
+        key=arr(jnp.uint32, 2))
+    text = jax.jit(lambda st, b: step(st, **b), donate_argnums=(0,)).lower(
+        state, batch).compile().as_text()
+    assert _kernel_counts(text) == {
+        "jvp_forward_": 12, "transpose_jvp_forward__": 24,
+        "rng-bit-generator": 37}
+    keys = _scope_keys(text)
+    model_scopes = set(transformer.BLOCK_SCOPES + bert.MODEL_SCOPES) \
+        - {"attn_core"}                 # the composed attention: not here
+    for phase in ("forward", "backward"):
+        assert {f"{phase}/{name}" for name in model_scopes} <= keys
+    assert "optimizer/" in keys
+
+
+@pytest.mark.parametrize("family, step_args, kernels_in", [
+    ("gpt2", ("decode", 64, 8), {"ragged_paged_decode": 12}),
+    ("sparse", ("decode", 32, 128),
+     {"lightning_indexer": 2, "sparse_paged_decode": 2,
+      "moe_grouped_ffn": 2}),
+    ("hybrid", ("decode", 64, 16),
+     {"ragged_paged_decode": 2, "ssm_decode_update": 2}),
+    ("latent", ("decode", 256, 32),
+     {"ragged_paged_decode": 10, "moe_grouped_ffn": 10})],
+    ids=["gpt2", "sparse", "hybrid", "latent"])
+def test_serving_decode_steps_keep_the_names_the_metrics_select(
+        family, step_args, kernels_in, request):
+    """One decode block of each serving family compiled for the chip
+    WITH the engine's scopes around the program's hooks: every Pallas
+    kernel keeps its own name and count (a named ``pallas_call`` is not
+    renamed by an enclosing scope; ``kernel.*_time_pct.*`` and the
+    rooflines select them by these names), and the hooks' scopes are in
+    the compiled program's metadata."""
+    if family == "gpt2":
+        _variant, lower = _cell_steps("bf16", request.getfixturevalue("topo"))
+    else:
+        lower = request.getfixturevalue(
+            {"sparse": "doc_steps", "hybrid": "h1_steps",
+             "latent": "zaya_steps"}[family])
+    text = lower(*step_args).as_text()
+    assert _kernel_counts(text) == {**kernels_in, "rng-bit-generator": 0}
+    keys = _scope_keys(text)
+    assert {"embed", "attn_in", "write_rows", "attend", "attn_out", "ffn",
+            "head"} <= keys
+    assert ("mixer" in keys) == (family == "hybrid")
