@@ -28,15 +28,19 @@ zeros.
 Each has two implementations with identical numerics:
 
 - ``impl="lax"``: XLA gather + masked softmax (CPU/debug reference).
-- ``impl="pallas"`` / ``"pallas_interpret"``: a Pallas kernel, grid
-  ``(S, cdiv(max_pages, pages_per_block))``, that scalar-prefetches
-  the block table so each kv block's HBM address is known before the
-  body runs (the PrefetchScalarGridSpec pattern), streams WHOLE pages
-  (every head), does online-softmax accumulation over pages, and skips
-  pages past the slot's live extent entirely. The interpret path runs
-  the REAL kernel on CPU, so tier-1 tests exercise it.
+- ``impl="pallas"`` / ``"pallas_interpret"``: a Pallas kernel that
+  scalar-prefetches the block table so each kv block's HBM address is
+  known before it is needed (the PrefetchScalarGridSpec pattern), moves
+  WHOLE pages (every head), does online-softmax accumulation over
+  pages, and skips pages past the slot's live extent entirely. Chunked
+  prefill, the int8 twins and sparse decode lay a grid ``(S,
+  cdiv(max_pages, pages_per_block))`` over the block table's width and
+  let the ``BlockSpec`` pipeline stream the pages; dense decode
+  (``ragged_paged_decode``) has grid ``(S,)`` and copies a slot's live
+  pages itself (below). The interpret path runs the REAL kernel on CPU,
+  so tier-1 tests exercise it.
 
-The two bodies differ in how a page meets the queries:
+The bodies differ in how a page meets the queries:
 
 - **chunked prefill** (``_paged_prefill_kernel``): a chunk fills the
   MXU's rows with the C queries of ONE head, so the body loops over the
@@ -53,17 +57,33 @@ The two bodies differ in how a page meets the queries:
   the caller's are) goes in as its three bf16 terms, so nothing is
   rounded that ``HIGHEST`` would not round; a float32 pool multiplies
   at ``HIGHEST``. Only live pages are moved: a page operand past the
-  slot's extent repeats its last index. No test holds the two bodies
-  to bit equality with each other, only each to its reference and to
-  itself across ``pages_per_block``.
+  slot's extent repeats its last index. This is the int8 twin's body
+  and sparse decode's (rows gathered side by side, nothing ragged);
+- **dense decode** (``_paged_decode_walk_kernel``, PR 39): the same
+  all-heads products on the same operands, but the body walks the
+  slot's live pages itself: the pools stay in HBM, one grid step a
+  slot copies ``pages_per_block`` live pages side by side into one of
+  two VMEM buffers while the other is folded, and a block is ONE
+  softmax update (one score product, one maximum, one exponential, one
+  product with ``V``) where the pipelined body makes one a page. No
+  grid step, index map or DMA check exists for a page that does not
+  move, and a slot's first block is on its way while the slot before
+  still folds. Exactly the pages that were copied are folded, so
+  nothing a buffer held before reaches an output.
 
-Both kernels register with the shared kernel layer
+No test holds the bodies to bit equality with each other, only each to
+its reference; the pipelined bodies also to themselves across
+``pages_per_block`` (the per-page accumulation order is identical),
+the dense decode body at every setting to the float64 reference within
+2e-5 (a block is one update, so the order of the sums follows the
+setting).
+
+The kernels register with the shared kernel layer
 (:mod:`paddle_tpu.kernels`): the public entry points dispatch through
 the registry, the ``pages_per_block`` tunable (how many of a slot's
-pages one grid step streams — bit-equal output for any setting, the
-per-page accumulation order is identical) resolves from the shared
-autotuner at trace time, and the registry's parity battery +
-graph-lint contract rule cover both.
+pages one grid step streams, or one buffer of the dense decode body
+holds) resolves from the shared autotuner at trace time, and the
+registry's parity battery + graph-lint contract rule cover all.
 
 **Dequant-attend int8 variants** (ISSUE 13):
 ``ragged_paged_decode_int8_attention`` and
@@ -396,6 +416,22 @@ def _all_heads_page_dot(x, page, contract_page_dim):
     return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
 
 
+def _decode_finish(o_ref, m_scr, l_scr, acc_scr, group):
+    """A slot's output from its finished ``m / l / acc`` state (both
+    decode bodies): ``acc / l``, row ``i`` keeping the lanes of its own
+    KV head ``i // group``; a slot that folded nothing gives zeros."""
+    rows, dh = o_ref.shape[1:]
+    denom = l_scr[...][:, :1]
+    denom = jnp.where(denom == 0.0, 1.0, denom)
+    alive = m_scr[...][:, :1] > NEG_INF / 2
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, dh), 0)
+    out = jnp.zeros((rows, dh), jnp.float32)
+    for g in range(acc_scr.shape[1] // dh):
+        mine = (row >= g * group) & (row < (g + 1) * group)
+        out = out + jnp.where(mine, acc_scr[:, g * dh:(g + 1) * dh], 0.0)
+    o_ref[0] = jnp.where(alive, out / denom, 0.0).astype(o_ref.dtype)
+
+
 def _decode_page(bt, lens, s, j, t, *, page_size, pages_per_block):
     """The pool page that page operand ``t`` of grid step ``(s, j)``
     holds: page ``j*pb + t`` of the slot while that page is live, then
@@ -482,19 +518,8 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
     for n_pages in range(1, pb + 1):
         pl.when(live_here == n_pages)(functools.partial(_fold, n_pages))
 
-    @pl.when(pj == npg - 1)
-    def _finish():
-        denom = l_scr[...][:, :1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        alive = m_scr[...][:, :1] > NEG_INF / 2
-        # row i keeps the lanes of its own KV head i // group
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, dh), 0)
-        out = jnp.zeros((rows, dh), jnp.float32)
-        for g in range(kv):
-            mine = (row >= g * group) & (row < (g + 1) * group)
-            out = out + jnp.where(
-                mine, acc_scr[:, g * dh:(g + 1) * dh], 0.0)
-        o_ref[0] = jnp.where(alive, out / denom, 0.0).astype(o_ref.dtype)
+    pl.when(pj == npg - 1)(functools.partial(
+        _decode_finish, o_ref, m_scr, l_scr, acc_scr, group))
 
 
 def _paged_page_index(ps, mp, pb, t, decode):
@@ -567,7 +592,8 @@ def _block_structured_queries(q, kv):
 def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
                          interpret, pages_per_block, k_scales, v_scales,
                          selected=None, name=None):
-    """The one ``pallas_call`` behind all four paged kernels. Jitted, so
+    """The one ``pallas_call`` behind the pipelined paged kernels (all
+    but dense decode). Jitted, so
     that a step program traces and lowers the kernel body once and calls
     it from every layer (same shapes, same static arguments: JAX reuses
     the inner function's jaxpr and XLA inlines the calls), where each of
@@ -596,7 +622,8 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
     else:
         # the queries of a slot as ONE matrix over the page's lanes
         q = _block_structured_queries(q, hd // dh)
-        # a page pool is an HBM array. Said here because a pool of
+        # a page pool is an HBM array. Said here (the pipelined decode
+        # path: sparse decode and the int8 twin) because a pool of
         # gathered rows (sparse decode) may be small enough for XLA to
         # keep it in VMEM (it kept the 67 MB of gathered K rows there
         # and V in HBM): the kernel then outruns the HBM roofline that
@@ -673,15 +700,187 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
 def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
                          interpret, pages_per_block=1, k_scales=None,
                          v_scales=None, name=None):
-    """``k_scales``/``v_scales`` given = the dequant-attend variant:
-    same grid and BlockSpecs plus one scale-row group per streamed
-    page, multiplied into the all-heads scores and weights inside the
-    ONE decode body. Grouped-query heads are read off the shapes (the
-    page's lanes hold ``kv <= H`` heads of ``Dh``)."""
+    """The pipelined decode call: sparse decode (``name``) and, with
+    ``k_scales``/``v_scales`` given, the dequant-attend variant: same
+    grid and BlockSpecs plus one scale-row group per streamed page,
+    multiplied into the all-heads scores and weights inside the one
+    body. Grouped-query heads are read off the shapes (the page's lanes
+    hold ``kv <= H`` heads of ``Dh``). Dense decode has a body of its
+    own: :func:`_paged_decode_walk_pallas`."""
     return _paged_attend_pallas(
         q * jnp.asarray(scale, q.dtype), k_pages, v_pages, block_tables,
         (lengths,), interpret, pages_per_block, k_scales, v_scales,
         name=name)
+
+
+# ---------------------------------------------------------------------------
+# dense decode: the kernel walks a slot's live pages itself
+# ---------------------------------------------------------------------------
+#
+# A ``BlockSpec`` pipeline lays a fixed grid over the block table's
+# width: every grid step runs the index maps and DMA checks of its page
+# operands whether or not a page moves, and the body meets a page at a
+# time, one softmax chain each. A slot of the serving cells holds 3 to 9
+# live pages of 64 to 192 KB, so those fixed costs were most of the
+# call (1.4 us of bytes in 5.75 us a slot at a page row of 256 lanes:
+# PERF.md section 6, PR 39). Here the grid is ``(S,)``, the pools stay
+# in HBM, and the body copies the slot's LIVE pages side by side into
+# one of two VMEM buffers (``pages_per_block`` pages each) while it
+# folds the other, so a block of pages is ONE product against ``Q``,
+# one softmax update, one product with ``V``. The last fold of a slot
+# runs beside the copies of the next slot's first block. Exactly the
+# fetched pages are folded (one region a count of pages, as in the
+# pipelined body), so nothing a buffer held before reaches an output.
+# ``sparse_paged_decode`` (rows gathered side by side, nothing ragged)
+# and the int8 twin keep the pipelined body above.
+
+def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                              k_buf, v_buf, sems, first_buf, m_scr, l_scr,
+                              acc_scr, *, page_size, pages_per_block,
+                              n_heads):
+    """The dense decode body: grid ``(S,)``, one step a slot. ``q_ref``,
+    ``o_ref`` and the ``m / l / acc`` state are
+    :func:`_paged_decode_kernel`'s; ``k_hbm`` / ``v_hbm`` are the whole
+    pools in HBM, ``k_buf`` / ``v_buf`` ``(2, pb*ps, kv*Dh)`` the two
+    blocks in VMEM, ``sems`` ``(2, 2)`` one DMA semaphore a (pool,
+    buffer), ``first_buf`` the buffer that holds the slot's first block
+    (it alternates block by block across slots, so a slot's first block
+    can be on its way while the slot before still folds)."""
+    ps, pb = page_size, pages_per_block
+    sl = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    rows = q_ref.shape[1]
+    group = n_heads * o_ref.shape[-1] // q_ref.shape[2]  # heads a KV head
+
+    def live_pages(slot):
+        return (len_ref[slot] + ps - 1) // ps
+
+    extent = len_ref[sl]
+    n_live = live_pages(sl)
+    n_blocks = (n_live + pb - 1) // pb
+
+    def copies(slot, block, buf, start):
+        """Start, or wait for, the copies of one block of a slot: its
+        live page ``block*pb + t`` out of each pool into rows ``t*ps ..``
+        of that pool's buffer ``buf``, all of a buffer's on its one DMA
+        semaphore. A wait rebuilds the descriptors its start was made
+        from."""
+        n = live_pages(slot)
+        for t in range(pb):
+            p = block * pb + t
+            # a dead page's table entry is never read past the table
+            page = bt_ref[slot, jnp.minimum(p, bt_ref.shape[1] - 1)]
+
+            @pl.when(p < n)
+            def _live_page():
+                for i, (pool, vmem) in enumerate(((k_hbm, k_buf),
+                                                  (v_hbm, v_buf))):
+                    copy = pltpu.make_async_copy(
+                        pool.at[page], vmem.at[buf, pl.ds(t * ps, ps)],
+                        sems.at[i, buf])
+                    copy.start() if start else copy.wait()
+
+    @pl.when(sl == 0)
+    def _first_slot():
+        first_buf[0] = 0
+
+    # the slot before started this slot's first block beside its own
+    # last fold; an empty one folded nothing, and nobody precedes slot 0
+    @pl.when((sl == 0) | (live_pages(jnp.maximum(sl - 1, 0)) == 0))
+    def _own_first_block():
+        copies(sl, 0, first_buf[0], start=True)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def fold(block, buf, n_pages):
+        """Block ``block``'s ``n_pages`` fetched pages as ONE update."""
+        width = n_pages * ps
+        q = q_ref[0]
+        s = _all_heads_page_dot(q, k_buf[buf, :width], 1)   # (rows, width)
+        tok = block * (pb * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, width), 1)
+        s = jnp.where(tok < extent, s, NEG_INF)
+        m = m_scr[...]
+        m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_next)                          # (rows, 128)
+        p = jnp.exp(s - m_next[:, :1])                       # (rows, width)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[...] = m_next
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _all_heads_page_dot(
+            p, v_buf[buf, :width], 0)                        # (rows, kv*Dh)
+
+    def walk(block, _):
+        buf = (first_buf[0] + block) % 2
+        last = block == n_blocks - 1
+        # what arrives while this block is folded: the slot's next block,
+        # or after its last the first block of the slot that follows
+        nxt_slot = jnp.where(last, jnp.minimum(sl + 1, n_slots - 1), sl)
+
+        @pl.when(~last | (sl + 1 < n_slots))
+        def _next_block():
+            copies(nxt_slot, jnp.where(last, 0, block + 1), 1 - buf,
+                   start=True)
+
+        copies(sl, block, buf, start=False)
+        here = jnp.minimum(n_live - block * pb, pb)
+        for n_pages in range(1, pb + 1):
+            pl.when(here == n_pages)(
+                functools.partial(fold, block, buf, n_pages))
+
+    jax.lax.fori_loop(0, n_blocks, walk, None)
+    first_buf[0] = (first_buf[0] + n_blocks) % 2
+    _decode_finish(o_ref, m_scr, l_scr, acc_scr, group)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _paged_decode_walk_pallas(q, k_pages, v_pages, block_tables, lengths,
+                              interpret, pages_per_block):
+    """The ``pallas_call`` of the dense decode entry
+    (``ragged_paged_decode``), jitted like :func:`_paged_attend_pallas`
+    so that a step program traces and lowers the body once. ``q`` is
+    already scaled."""
+    s_slots, h, dh = q.shape
+    ps, hd = k_pages.shape[1:]
+    pb = max(1, min(int(pages_per_block), block_tables.shape[1]))
+    q = _block_structured_queries(q, hd // dh)
+    rows = q.shape[1]
+
+    def slot_block(shape):
+        return pl.BlockSpec(shape, lambda s, *_prefetch: (s, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s_slots,),
+        in_specs=[slot_block((1, rows, hd)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=slot_block((1, rows, dh)),
+        scratch_shapes=[
+            pltpu.VMEM((2, pb * ps, hd), k_pages.dtype),
+            pltpu.VMEM((2, pb * ps, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_decode_walk_kernel, page_size=ps,
+                          pages_per_block=pb, n_heads=h),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_slots, rows, dh), q.dtype),
+        # one slot after the other: a slot's last fold runs beside the
+        # copies it started for the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)) if not interpret else None,
+        interpret=interpret,
+        name="ragged_paged_decode",
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
+      k_pages, v_pages)
+    return out[:, :h]
 
 
 # ---------------------------------------------------------------------------
@@ -919,9 +1118,20 @@ def _decode_kernel_pallas(q, k_pages, v_pages, block_tables, lengths, *,
                           block_sizes, interpret, scale=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _paged_decode_pallas(
-        q, k_pages, v_pages, block_tables, lengths, scale, interpret,
-        pages_per_block=block_sizes.get("pages_per_block", 1))
+    pb = block_sizes.get("pages_per_block", 1)
+    ps, hd = k_pages.shape[1:]
+    # the body that walks the pages copies a page out of the pool as it
+    # lies: the chip's compiler takes that slice only where a page is
+    # whole tiles (rows of whole 128-lane tiles, whole sublane tiles of
+    # them). A pool that is not (a tp = 4 shard of GPT-2's heads is 192
+    # lanes) goes through the pipelined body, whose blocks span the row
+    if hd % 128 or ps % (32 // k_pages.dtype.itemsize):
+        return _paged_decode_pallas(q, k_pages, v_pages, block_tables,
+                                    lengths, scale, interpret,
+                                    pages_per_block=pb)
+    return _paged_decode_walk_pallas(
+        q * jnp.asarray(scale, q.dtype), k_pages, v_pages, block_tables,
+        lengths, interpret, pb)
 
 
 def _decode_kernel_lax(q, k_pages, v_pages, block_tables, lengths, *,
@@ -1008,16 +1218,20 @@ def _paged_tune_signature(args, kwargs):
     return _paged_sig(args[0], args[1], args[3])
 
 
-def _paged_vmem_estimate(args, kwargs, blocks):
+def _paged_vmem_estimate(args, kwargs, blocks, walk=False):
     """VMEM working set of one grid step, as the TPU lays it out: the
     streamed blocks are whole pages (every head, folded into the lane
     axis: ``(ps, H*Dh)`` tiles with no per-head padding), tiles pad the
     last two dims to (32/itemsize, 128), and the pipeline double-buffers
     every in/out block. One estimate for the fp and int8 kernels — the
     page dtype and the scale rows are read off the arguments — and for
-    both bodies: chunked prefill (4-D ``q``) holds per-head state and
-    the temporaries of one head fold, decode the all-heads state over
-    the page's lanes and the temporaries of one page fold."""
+    the three bodies: chunked prefill (4-D ``q``) holds per-head state
+    and the temporaries of one head fold, pipelined decode the all-heads
+    state over the page's lanes and the temporaries of one page fold,
+    and the dense decode body that walks the pages itself (``walk``)
+    its own two buffers of ``pb`` pages each for K and V (what the
+    pipeline's double-buffered page blocks came to) with the
+    temporaries of a fold ``pb`` pages wide."""
     q, k_pages = args[0], args[1]
     ps, hd = k_pages.shape[1:]
     h, dh = q.shape[-2:]
@@ -1043,15 +1257,17 @@ def _paged_vmem_estimate(args, kwargs, blocks):
         streamed += (tiled(1, rows, hd, q.dtype.itemsize)
                      + tiled(1, rows, dh, q.dtype.itemsize))
         scratch = 2 * tiled(1, rows, 128, 4) + tiled(1, rows, hd, 4)
-        # one page fold: fp32 scores and weights, the weights' three
-        # bf16 terms and their products with V; a page cast to bf16
-        # (int8 pools) and the terms of fp32 queries where those apply
-        fold = (2 * tiled(1, rows, ps, 4) + tiled(1, 3 * rows, ps, 2)
+        # one softmax update (a page; the walk's is a block of pages):
+        # fp32 scores and weights, the weights' three bf16 terms and
+        # their products with V; a page cast to bf16 (int8 pools) and
+        # the terms of fp32 queries where those apply
+        width = pb * ps if walk else ps
+        fold = (2 * tiled(1, rows, width, 4) + tiled(1, 3 * rows, width, 2)
                 + tiled(1, 3 * rows, hd, 4))
         if k_pages.dtype.itemsize == 1:
             fold += 2 * tiled(1, ps, hd, 2)
         if q.dtype.itemsize == 4:
-            fold += tiled(1, 3 * rows, hd, 2) + tiled(1, 3 * rows, ps, 4)
+            fold += tiled(1, 3 * rows, hd, 2) + tiled(1, 3 * rows, width, 4)
     return 2 * streamed + scratch + fold
 
 
@@ -1294,9 +1510,9 @@ def _tp_local_sample(seed, *, tp, chunked, quantized=False):
 def _register_paged_kernels():
     from paddle_tpu import kernels
     pb_candidates = {"pages_per_block": (1, 2, 4)}
-    # decode: a slot's whole gather width in ONE grid step, so that the
-    # next slot's pages arrive while this one's are folded (a second
-    # step with no live page gives the prefetch nothing to hide behind)
+    # decode: the most pages a softmax update (dense), or a slot's whole
+    # width in ONE grid step so that the next slot's pages arrive while
+    # this one's are folded (pipelined: int8)
     decode_pb_candidates = {"pages_per_block": (1, 2, 4, 8)}
     kernels.register(kernels.KernelSpec(
         name="ragged_paged_decode",
@@ -1308,10 +1524,13 @@ def _register_paged_kernels():
                          "lengths": "(S,) i32"},
             out_layout="(S,H,Dh)",
             donatable=("k_pages", "v_pages"),
-            grid="(S, cdiv(mp,pages_per_block)) whole-page blocks, a page "
-                 "folded once for all heads (block-structured Q over the "
-                 "page's lanes), block-table + lengths scalar prefetch, "
-                 "only live pages moved and folded",
+            grid="(S,) one step a slot, pools left in HBM: the body copies "
+                 "the slot's live pages itself, pages_per_block side by "
+                 "side into one of two VMEM buffers while it folds the "
+                 "other; a block folded once for all heads (block-"
+                 "structured Q over the page's lanes) as one softmax "
+                 "update, block-table + lengths scalar prefetch, only "
+                 "live pages copied and folded",
             block_candidates=decode_pb_candidates,
             atol=2e-5, rtol=2e-5),
         pallas_fn=_decode_kernel_pallas,
@@ -1319,9 +1538,12 @@ def _register_paged_kernels():
         reference_fn=_decode_kernel_reference,
         sample_inputs=lambda seed: _make_paged_sample(seed, chunked=False),
         pallas_sites=(
-            "paddle_tpu.serving.decode_attention:_paged_attend_pallas",),
+            "paddle_tpu.serving.decode_attention:"
+            "_paged_decode_walk_pallas",
+            # a pool whose pages are not whole tiles
+            "paddle_tpu.serving.decode_attention:_paged_attend_pallas"),
         tune_signature=_paged_tune_signature,
-        vmem_estimate=_paged_vmem_estimate,
+        vmem_estimate=functools.partial(_paged_vmem_estimate, walk=True),
         donation_probe=_decode_donation_probe,
         # per-shard (H/tp) buckets a tp engine dispatches this kernel at
         tune_sample_variants=(
@@ -1379,8 +1601,8 @@ def _register_paged_kernels():
         reference_fn=_decode_int8_kernel_reference,
         sample_inputs=lambda seed: _make_paged_int8_sample(seed,
                                                            chunked=False),
-        # all four paged kernels run THROUGH the one pallas_call site
-        # (one body, static chunked/quantized flags)
+        # the pipelined paged kernels run THROUGH the one pallas_call
+        # site (static chunked/quantized flags)
         pallas_sites=(
             "paddle_tpu.serving.decode_attention:_paged_attend_pallas",),
         tune_signature=_paged_int8_tune_signature,

@@ -1247,8 +1247,13 @@ class ServingEngine:
         useful work (live) over attempted work (gathered), counted where
         it happens. Token step j of a slot holding L tokens attends over
         L + j + 1 (the formula ``benchmark/flops.paged_decode_bytes``
-        applies from outside); the kernel's grid visits every slot of the
-        batch, live or not, at ``w`` whole pages."""
+        applies from outside). "Gathered" is every slot of the batch,
+        live or not, at ``w`` whole pages: the block table's width, which
+        the pipelined decode bodies (int8 pages, sparse rows) lay their
+        grid over. The dense decode kernel walks a slot's live pages
+        itself since PR 39 and lays out no width, so for it the ratio
+        says how wide the table's bucket is for what the slots hold, not
+        what the kernel spent."""
         live = n * int(self.cache.lengths[dslots].sum()) \
             + len(dslots) * n * (n + 1) // 2
         self._c_kv_live.inc(live * self._kv_token_bytes)

@@ -99,14 +99,19 @@ def _decode_cell_args(slots, heads, kv_heads, dh, width):
             sds((slots, width), jnp.int32), sds((slots,), jnp.int32))
 
 
-# the decode call at the benchmark's two geometries: the backlog cell's
-# 64 slots of 12 heads x 64 at each gather width it warms, and the docs
-# cell's 32 slots of 32 query heads over 4 KV heads x 128 on the 16
-# pages of gathered rows
+# the dense decode call at the benchmark's geometries: the backlog
+# cell's 64 slots of 12 heads x 64 at each table width it warms, the
+# reasoning cell's 256 slots of 8 query heads over 2 KV heads x 128, the
+# hybrid cell's 64 slots of 20 over 4 x 128 (groups of 5), and the docs
+# cell's heads (32 over 4 x 128 on 16 pages; that cell's own decode call
+# is `sparse_paged_decode`, compiled with its whole step below)
 _DECODE_CELLS = {
     **{f"backlog-w{w}": _decode_cell_args(64, 12, 12, 64, w)
        for w in (1, 2, 4, 8)},
-    "docs-gathered": _decode_cell_args(32, 32, 4, 128, 16)}
+    "docs-gathered": _decode_cell_args(32, 32, 4, 128, 16),
+    **{f"reasoning-w{w}": _decode_cell_args(256, 8, 2, 128, w)
+       for w in (8, 16, 24)},
+    "hybrid-w16": _decode_cell_args(64, 20, 4, 128, 16)}
 
 
 @pytest.mark.parametrize("name, args", [

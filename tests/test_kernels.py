@@ -23,6 +23,10 @@ from paddle_tpu import kernels
 from paddle_tpu.kernels import autotune, lint, registry
 
 KERNEL_NAMES = kernels.load_all()
+# the entries whose body folds a block of pages as ONE softmax update:
+# bit-equal across ``pages_per_block`` no more, each setting held to the
+# reference instead
+ONE_UPDATE_A_BLOCK = ("ragged_paged_decode",)
 
 
 # ---------------------------------------------------------------------------
@@ -112,15 +116,23 @@ class TestByteParity:
     @pytest.mark.parametrize("name", ["ragged_paged_decode",
                                       "ragged_paged_prefill"])
     def test_pages_per_block_bit_exact(self, name):
-        """The autotuner's pages_per_block tunable keeps the per-page
-        accumulation ORDER identical, so every setting is bit-equal —
-        tuning can never change serving outputs (greedy argmax included)."""
+        """The pipelined bodies keep the per-page accumulation ORDER
+        whatever ``pages_per_block``, so every setting is bit-equal. The
+        dense decode body folds a block of pages as one softmax update:
+        there every setting is within the contract's tolerance of the
+        reference."""
         spec = kernels.get(name)
         args, kw = spec.sample_inputs(1)
         outs = [np.asarray(kernels.dispatch(
             name, *args, impl="pallas_interpret",
             block_sizes={"pages_per_block": pb}, **kw))
             for pb in (1, 2, 4)]
+        if name in ONE_UPDATE_A_BLOCK:
+            want = np.asarray(spec.reference_fn(*args, **kw))
+            for o in outs:
+                np.testing.assert_allclose(o, want, atol=spec.contract.atol,
+                                           rtol=spec.contract.rtol)
+            return
         for o in outs[1:]:
             np.testing.assert_array_equal(outs[0], o)
 
@@ -357,6 +369,36 @@ class TestTraceTimeResolution:
                 o, _dense_reference(model, params, p, 4))
 
 
+    def test_engine_decodes_through_the_body_that_walks_pages(
+            self, monkeypatch):
+        """The dense decode entry walks a slot's live pages itself where
+        a pool page is whole tiles (128 lanes a row here, float32 pages
+        of 8 rows) and only elsewhere falls back to the pipelined body:
+        an engine at such widths never reaches the fallback, and its
+        greedy tokens are the dense cached path's, over slots of one
+        and of several pages."""
+        from test_serving import _dense_reference, _prompts
+        from paddle_tpu import serving
+        from paddle_tpu.models.gpt import GPT, GPTConfig
+        from paddle_tpu.serving import decode_attention as DA
+
+        def no_fallback(*_a, **_k):
+            raise AssertionError("the pipelined decode body was traced")
+        monkeypatch.setattr(DA, "_paged_decode_pallas", no_fallback)
+        model = GPT(GPTConfig.tiny(
+            vocab_size=64, hidden_size=128, num_heads=2, ffn_size=64,
+            max_position=64, dropout=0.0, attn_impl="xla"))
+        params = model.init(jax.random.PRNGKey(3))
+        prompts = _prompts(np.random.default_rng(11), [4, 19, 9])
+        eng = serving.ServingEngine(model, params, num_slots=2,
+                                    page_size=8, prefill_chunk=8,
+                                    attn_impl="pallas_interpret")
+        outs = eng.generate_many(prompts, max_new_tokens=6, max_steps=100)
+        for p, o in zip(prompts, outs):
+            np.testing.assert_array_equal(
+                o, _dense_reference(model, params, p, 6))
+
+
 # ---------------------------------------------------------------------------
 # registry + lint
 # ---------------------------------------------------------------------------
@@ -576,8 +618,13 @@ class TestFoldedPagePool:
         page_blocks = 128 * 768 * pool.dtype.itemsize      # unpadded
         scale_rows = 8 * 128 * 4 if quantized else 0
         # one more page a step = a K and a V block (+ scale groups),
-        # double-buffered by the pipeline and once more by the estimate
-        assert two - one == 2 * 2 * (page_blocks + scale_rows)
+        # double-buffered by the pipeline and once more by the estimate;
+        # the dense decode body's own two buffers each come to the same,
+        # and its one softmax update grows a page wider: 16 rows of
+        # float32 scores and weights, the weights' three bf16 terms
+        wider_fold = (2 * 16 * 128 * 4 + 48 * 128 * 2
+                      if name in ONE_UPDATE_A_BLOCK else 0)
+        assert two - one == 2 * 2 * (page_blocks + scale_rows) + wider_fold
 
 
 # ---------------------------------------------------------------------------
@@ -677,6 +724,11 @@ class TestGroupedQueryHeads:
         outs = [np.asarray(kernels.dispatch(
             name, *args, impl="pallas_interpret",
             block_sizes={"pages_per_block": pb})) for pb in (1, 2, 4)]
+        if name in ONE_UPDATE_A_BLOCK:
+            for o in outs:
+                np.testing.assert_allclose(o, _gqa_reference(*args),
+                                           atol=2e-5, rtol=2e-5)
+            return
         for o in outs[1:]:
             np.testing.assert_array_equal(outs[0], o)
 
@@ -690,14 +742,19 @@ class TestGroupedQueryHeads:
 # ---------------------------------------------------------------------------
 
 _FOLD_SHAPES = {"mha-12x64": (12, 12, 64), "gqa-32-over-4x128": (32, 4, 128)}
-_FOLD_PS, _FOLD_MP = 16, 4
-# empty, one token, a page boundary, one past it, the full width, ragged
-_FOLD_LENGTHS = (0, 1, _FOLD_PS, _FOLD_PS + 1, _FOLD_PS * _FOLD_MP, 37)
+_FOLD_PS, _FOLD_MP = 16, 8
+# empty, one token, a page boundary, one past it, the full width, three
+# live pages (no multiple of 2, 4 or 8), an empty slot between two live
+# ones, six live pages (two blocks of 4, the second half full)
+_FOLD_LENGTHS = (0, 1, _FOLD_PS, _FOLD_PS + 1, _FOLD_PS * _FOLD_MP, 37, 0,
+                 5 * _FOLD_PS + 3)
+_FOLD_PB = (1, 2, 4, 8)
 
 
 def _fold_sample(shape, pool):
     """float32 queries holding bf16 values (so the output is float32)
-    over a bf16 or int8 pool; returns (kernel name, args, float64 K, V)."""
+    over a bf16, float32 or int8 pool; returns (kernel name, args,
+    float64 K, V)."""
     h, kv, dh = _FOLD_SHAPES[shape]
     s = len(_FOLD_LENGTHS)
     rng = np.random.default_rng(h)
@@ -710,9 +767,11 @@ def _fold_sample(shape, pool):
     bt = jnp.asarray((rng.permutation(num_pages - 1)[:s * _FOLD_MP] + 1)
                      .reshape(s, _FOLD_MP), jnp.int32)
     lengths = jnp.asarray(_FOLD_LENGTHS, jnp.int32)
-    if pool == "bf16":
+    if pool in ("bf16", "f32"):
         k64, v64 = (np.asarray(p.astype(jnp.float32), np.float64)
                     for p in (kp, vp))
+        if pool == "f32":       # the same values, multiplied at HIGHEST
+            kp, vp = kp.astype(jnp.float32), vp.astype(jnp.float32)
         return "ragged_paged_decode", (q, kp, vp, bt, lengths), k64, v64
     from paddle_tpu.serving.paged_cache import quantize_kv
     kq, ks = quantize_kv(kp.astype(jnp.float32), (2,))
@@ -724,6 +783,13 @@ def _fold_sample(shape, pool):
             k64, v64)
 
 
+def _live_pages(bt, lengths, ps):
+    """The pool pages that some slot's live extent covers."""
+    bt, lengths = np.asarray(bt), np.asarray(lengths)
+    return {int(p) for row, n in zip(bt, lengths)
+            for p in row[:-(-int(n) // ps)]}
+
+
 class TestDecodeFoldsAPageForAllHeads:
     TOL = 2e-5
 
@@ -732,24 +798,87 @@ class TestDecodeFoldsAPageForAllHeads:
             name, *args, impl="pallas_interpret", scale=0.125,
             block_sizes={"pages_per_block": pb}))
 
-    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    @pytest.mark.parametrize("pb", _FOLD_PB)
+    @pytest.mark.parametrize("pool", ["bf16", "f32", "int8"])
     @pytest.mark.parametrize("shape", _FOLD_SHAPES)
-    def test_against_float64_on_the_stored_values(self, shape, pool):
+    def test_against_float64_on_the_stored_values(self, shape, pool, pb):
         name, args, k64, v64 = _fold_sample(shape, pool)
         want = _gqa_reference(args[0], k64, v64, args[-2], args[-1],
                               scale=0.125)
-        got = self._run(name, args)
+        got = self._run(name, args, pb)
         assert got.dtype == np.float32
         np.testing.assert_allclose(got, want, atol=self.TOL, rtol=self.TOL)
-        assert not got[0].any()                  # the empty slot: zeros
+        # the empty slots: zeros, the first and the one between two live
+        assert not got[0].any() and not got[6].any()
 
     @pytest.mark.parametrize("pool", ["bf16", "int8"])
     @pytest.mark.parametrize("shape", _FOLD_SHAPES)
     def test_pages_per_block_bit_equal(self, shape, pool):
-        name, args, _k, _v = _fold_sample(shape, pool)
+        """The int8 entry keeps the body that folds page by page: bit-
+        equal for any setting. The dense entry's body folds a block as
+        one update: every setting within the tolerance of float64."""
+        name, args, k64, v64 = _fold_sample(shape, pool)
         outs = [self._run(name, args, pb) for pb in (1, 2, 4)]
+        if name in ONE_UPDATE_A_BLOCK:
+            want = _gqa_reference(args[0], k64, v64, args[-2], args[-1],
+                                  scale=0.125)
+            for o in outs:
+                np.testing.assert_allclose(o, want, atol=self.TOL,
+                                           rtol=self.TOL)
+            return
         for o in outs[1:]:
             np.testing.assert_array_equal(outs[0], o)
+
+    @pytest.mark.parametrize("pool", ["bf16", "f32"])
+    @pytest.mark.parametrize("pb", _FOLD_PB)
+    def test_only_live_pages_are_read(self, pb, pool):
+        """The dense body copies a slot's live pages itself: with every
+        pool page that no slot's live extent covers filled with NaN
+        (the null page 0 among them) the outputs are the clean pool's."""
+        name, args, _k, _v = _fold_sample("gqa-32-over-4x128", pool)
+        q, kp, vp, bt, lengths = args
+        dead = np.asarray(sorted(
+            set(range(kp.shape[0])) - _live_pages(bt, lengths, _FOLD_PS)))
+        assert 0 in dead and len(dead) > len(_FOLD_LENGTHS)
+        poisoned = tuple(p.at[dead].set(jnp.nan) for p in (kp, vp))
+        got = self._run(name, (q, *poisoned, bt, lengths), pb)
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, self._run(name, args, pb))
+
+    @pytest.mark.parametrize("pb", _FOLD_PB)
+    def test_nothing_stale_is_folded(self, pb):
+        """What a buffer held before reaches no output: a full-length
+        slot of huge values (whose weights times a small value would
+        still be seen), then a one-token slot, then a slot whose last
+        block holds one live page, its dead table entries pointing at a
+        NaN page."""
+        h, kv, dh, ps, mp = 8, 2, 128, _FOLD_PS, 8
+        rng = np.random.default_rng(pb)
+        lengths = (mp * ps, 1, (pb if pb < mp else pb // 2) * ps + 1)
+        num_pages = len(lengths) * mp + 2
+        q = jnp.asarray(rng.standard_normal((len(lengths), h, dh)),
+                        jnp.bfloat16).astype(jnp.float32)
+        kp, vp = (jnp.asarray(
+            rng.standard_normal((num_pages, ps, kv * dh)), jnp.bfloat16)
+            for _ in range(2))
+        bt = np.arange(1, 1 + len(lengths) * mp).reshape(len(lengths), mp)
+        huge = jnp.asarray(3e38, jnp.bfloat16)
+        kp = kp.at[bt[0]].multiply(huge)
+        vp = vp.at[bt[0]].set(huge)
+        nan_page = num_pages - 1
+        for sl, n in enumerate(lengths):
+            bt[sl, -(-n // ps):] = nan_page
+        kp, vp = kp.at[nan_page].set(jnp.nan), vp.at[nan_page].set(jnp.nan)
+        args = (q, kp, vp, jnp.asarray(bt, jnp.int32),
+                jnp.asarray(lengths, jnp.int32))
+        k64, v64 = (np.asarray(p.astype(jnp.float32), np.float64)
+                    for p in (kp, vp))
+        with np.errstate(all="ignore"):     # slot 0's own inf and NaN
+            want = _gqa_reference(args[0], k64, v64, args[-2], args[-1],
+                                  scale=0.125)
+        got = self._run("ragged_paged_decode", args, pb)
+        np.testing.assert_allclose(got[1:], want[1:], atol=self.TOL,
+                                   rtol=self.TOL)
 
     @pytest.mark.parametrize("shape", _FOLD_SHAPES)
     def test_one_bf16_term_of_p_is_seen_and_fails(self, shape, monkeypatch):
@@ -766,16 +895,17 @@ class TestDecodeFoldsAPageForAllHeads:
         split_err = np.abs(self._run(name, args) - want).max()
         monkeypatch.setattr(DA, "_bf16_terms",
                             lambda x: x.astype(jnp.bfloat16))
-        DA._paged_attend_pallas.clear_cache()    # traced with the split
+        DA._paged_decode_walk_pallas.clear_cache()   # traced with the split
         try:
             rounded_err = np.abs(self._run(name, args) - want).max()
         finally:
-            DA._paged_attend_pallas.clear_cache()
+            DA._paged_decode_walk_pallas.clear_cache()
         assert split_err < self.TOL / 20
         assert rounded_err > self.TOL * 50
 
     def test_only_live_pages_move(self):
-        """Page operand ``t`` of a grid step holds page ``j*pb + t``
+        """The pipelined body (the int8 and sparse entries): page
+        operand ``t`` of a grid step holds page ``j*pb + t``
         while the slot has it, then its own last live page again (a
         repeated index moves nothing), and pool page 0 where the slot
         never gives it a live page."""
@@ -793,6 +923,34 @@ class TestDecodeFoldsAPageForAllHeads:
                                         pages_per_block=pb))
                     for t in range(pb)] for j in range(4)]
             assert got == want, (n_tokens, got)
+
+
+def test_sparse_decode_traces_to_the_call_it_was():
+    """``sparse_paged_decode`` keeps the pipelined decode body: at the
+    docs cell's geometry (32 slots, 32 query heads over 4 KV heads of
+    128, 2048 selected rows of a 1280-page pool) its Pallas path traces
+    to the jaxpr of the commit before the dense entry got a body of its
+    own (sha256 taken there by these lines under this suite's conftest,
+    source positions stripped; a change that means to alter the sparse
+    decode call takes it anew)."""
+    import functools
+    import hashlib
+    import re
+    spec = kernels.get("sparse_paged_decode")
+    sds = jax.ShapeDtypeStruct
+    pages = sds((1280, 128, 4 * 128), jnp.bfloat16)
+    args = (sds((32, 32, 128), jnp.bfloat16), pages, pages,
+            sds((32, 128), jnp.int32), sds((32, 2048), jnp.int32),
+            sds((32,), jnp.int32))
+    blocks = autotune.static_prior(spec, args, {})
+    assert blocks == {"pages_per_block": 8}
+    text = str(jax.make_jaxpr(functools.partial(
+        spec.pallas_fn, block_sizes=blocks, interpret=False))(*args))
+    assert "sparse_paged_decode" in text
+    text = re.sub(r" at [^\s\]]+:\d+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "4730570f2e6a181f82cc56b35278e6ad"
+        "4c904820aad7325e45ce39a8e4d3ad2d")
 
 
 # ---------------------------------------------------------------------------
