@@ -31,6 +31,7 @@ from paddle_tpu.models.sparse_moe_lm import SparseMoELM, SparseMoELMConfig
 from paddle_tpu.models.hybrid_ssm_lm import HybridSSMLM, HybridSSMLMConfig
 from paddle_tpu.models.latent_conv_moe_lm import (LatentConvMoELM,
                                                   LatentConvMoELMConfig)
+from paddle_tpu.models.window_moe_lm import WindowMoELM, WindowMoELMConfig
 
 __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "ResNet", "ResNet50", "DeepFM", "Transformer",
@@ -39,4 +40,5 @@ __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "MobileNetV1", "MobileNetV2", "VGG", "VGG16", "SEResNeXt",
            "SEResNeXt50", "AlexNet", "DarkNet53", "DenseNet121", "GoogLeNet", "ShuffleNetV2", "SqueezeNet", "SSD", "SSDConfig", "FasterRCNN", "FasterRCNNConfig", "MaskRCNN", "C3D", "TSN", "YOLOv3", "YOLOv3Config", "CRNN", "DCGANGenerator", "DCGANDiscriminator", "gan_step",
            "SparseMoELM", "SparseMoELMConfig", "HybridSSMLM",
-           "HybridSSMLMConfig", "LatentConvMoELM", "LatentConvMoELMConfig"]
+           "HybridSSMLMConfig", "LatentConvMoELM", "LatentConvMoELMConfig",
+           "WindowMoELM", "WindowMoELMConfig"]

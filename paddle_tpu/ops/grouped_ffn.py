@@ -15,6 +15,14 @@ index (no DMA) and skip the arithmetic. The three weights are stored
 ``(E, F, D)`` so that a block of ``tf`` hidden units is one contiguous
 piece of each. Routing, the sort and the gather / weighted sum around the
 kernel are plain XLA.
+
+A chip that holds a SHARE of a layer's experts (``held_offset``: experts
+``held_offset .. held_offset + E`` of those the router picks from, the
+weights ``(E, F, D)``) lays out the pairs of its own experts
+only: a pair whose expert is held elsewhere gets no row and adds nothing
+here, the tile table is sized for the held experts and the pairs that can
+land on them, and ``sizes`` counts the held experts. Nothing stands in
+for the other chips: their part of the sum is theirs.
 """
 
 from __future__ import annotations
@@ -49,6 +57,13 @@ def tile_rows(n_pairs: int, n_experts: int) -> int:
     return 16 if n_pairs <= 8 * n_experts else 32
 
 
+def held_pairs(n_pairs: int, n_experts: int, held=None) -> int:
+    """The pairs an even router sends to the ``n_experts`` held here of
+    ``held = (offset, n_routed)``; all of them where every expert is
+    held (None)."""
+    return n_pairs if held is None else n_pairs * n_experts // held[1]
+
+
 def _block_f(f: int, d: int, itemsize: int) -> int:
     for tf in _BLOCK_F:
         if f % tf == 0 and 2 * 3 * tf * d * itemsize <= _WEIGHT_VMEM_BUDGET:
@@ -56,19 +71,33 @@ def _block_f(f: int, d: int, itemsize: int) -> int:
     return f
 
 
-def route_tiles(expert_ids, valid, n_experts: int, tm: int):
+def route_tiles(expert_ids, valid, n_experts: int, tm: int,
+                held_offset=None):
     """The tile layout of a batch of token-expert pairs.
 
     ``expert_ids`` (T, K) int32, ``valid`` (T,) bool (pairs of an invalid
     token get no row). Returns ``(src (Mp,) token of every padded row or
-    -1, dest (T, K) padded row of every pair (0 for an invalid token's),
-    tile_expert (n_tiles,), n_used (1,) tiles in use, sizes (E,) pairs an
-    expert)`` with ``Mp = n_tiles * tm`` and ``n_tiles = E + T*K // tm``,
-    the most that any routing can need."""
+    -1, dest (T, K) padded row of every pair (0 for a pair without a
+    row), tile_expert (n_tiles,), n_used (1,) tiles in use, sizes (E,)
+    pairs an expert)`` with ``Mp = n_tiles * tm`` and ``n_tiles = E + T*K
+    // tm``, the most that any routing can need.
+
+    ``held_offset``: ``expert_ids`` name experts of a wider router, of
+    which ``held_offset .. held_offset + n_experts`` are held here:
+    validity is then a PAIR's (a pair of an expert held elsewhere gets no
+    row), ``tile_expert`` and ``sizes`` index the held experts from 0,
+    and the table is sized for the pairs that can land here, ``n_tiles =
+    E + T * min(K, E) // tm`` (a token's experts are distinct)."""
     t, k = expert_ids.shape
     m = t * k
-    n_tiles = n_experts + m // tm
-    flat = jnp.where(valid[:, None], expert_ids, n_experts).reshape(m)
+    live_pair = valid[:, None]
+    if held_offset is None:
+        n_tiles = n_experts + m // tm
+    else:
+        expert_ids = expert_ids - held_offset
+        live_pair = live_pair & (expert_ids >= 0) & (expert_ids < n_experts)
+        n_tiles = n_experts + t * min(k, n_experts) // tm
+    flat = jnp.where(live_pair, expert_ids, n_experts).reshape(m)
     order = jnp.argsort(flat, stable=True)                  # pairs by expert
     sizes_all = jnp.zeros((n_experts + 1,), jnp.int32).at[flat].add(1)
     sizes = sizes_all[:n_experts]
@@ -215,25 +244,35 @@ def _make_grouped_sample(seed):
 
 
 def grouped_expert_ffn(x, expert_ids, coef, valid, w_gate, w_up, w_down, *,
-                       impl: str = "auto"):
+                       impl: str = "auto", held=None):
     """Every token through every one of its experts, nothing dropped.
 
     ``x`` (T, D); ``expert_ids`` / ``coef`` (T, K) the experts of a token
     and their weights; ``valid`` (T,) tokens that count (the others get
     zeros and touch no expert); weights ``(E, F, D)``. Returns ``(y (T,
-    D) float32, sizes (E,) int32 pairs computed an expert)``."""
+    D) float32, sizes (E,) int32 pairs computed an expert)``.
+
+    ``held=(offset, n_routed)``: the weights are those of experts
+    ``offset .. offset + E`` of the ``n_routed`` the router picks from; a
+    pair of another expert adds nothing here (:func:`route_tiles`,
+    ``held_offset``), ``y`` is this chip's part of the sum, and a tile's rows go
+    by the pairs an even router sends here (:func:`held_pairs`)."""
     from paddle_tpu import kernels
     t, k = expert_ids.shape
     e = w_gate.shape[0]
-    tm = tile_rows(t * k, e)
+    tm = tile_rows(held_pairs(t * k, e, held), e)
+    offset = None if held is None else held[0]
     src, dest, tile_expert, n_used, sizes = route_tiles(
-        expert_ids, valid, e, tm)
+        expert_ids, valid, e, tm, held_offset=offset)
     x_pad = jnp.where((src >= 0)[:, None], x[jnp.maximum(src, 0)],
                       jnp.zeros((), x.dtype))
     y_pad = kernels.dispatch("moe_grouped_ffn", x_pad, tile_expert, n_used,
                              w_gate, w_up, w_down, impl=impl)
     picked = y_pad[dest].astype(jnp.float32)                 # (T, K, D)
     c = jnp.where(valid[:, None], coef, 0.0).astype(jnp.float32)
+    if held is not None:        # a pair held elsewhere points at row 0
+        c = jnp.where((expert_ids >= offset) & (expert_ids < offset + e),
+                      c, 0.0)
     y = jnp.einsum("tk,tkd->td", c, picked, precision=_FP32_DOT)
     return y, sizes
 

@@ -139,7 +139,8 @@ def _gather_pages(pages, block_tables, h, dh):
     return g if kv == h else jnp.repeat(g, h // kv, axis=3)
 
 
-def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale):
+def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale,
+                      window=None):
     s_slots, h, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
@@ -153,6 +154,9 @@ def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale):
     scores = scores.reshape(s_slots, h, mp * ps)
     tok = jnp.arange(mp * ps, dtype=jnp.int32)
     valid = tok[None, None, :] < lengths[:, None, None]
+    if window is not None:      # the last ``window`` tokens only
+        valid = valid & (tok[None, None, :]
+                         >= lengths[:, None, None] - window)
     scores = jnp.where(valid, scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     # length-0 slots: every key masked -> emit 0, not a uniform mean of v
@@ -249,6 +253,48 @@ def _online_softmax_page_fold(q, k, v, mask, m_scr, l_scr, acc_scr, h,
     acc_scr[h] = acc_scr[h] * alpha[:, :1] + pv
 
 
+#: heads x queries of a chunk from which the chunked-prefill body folds a
+#: page once a KV head, for the whole group of query heads that read it
+#: (:func:`_online_softmax_group_fold`), and not once a query head
+_GROUP_FOLD_MIN_ROWS = 4096
+
+
+def _online_softmax_group_fold(q, k, v, mask, m_scr, l_scr, acc_scr, heads):
+    """:func:`_online_softmax_page_fold` for ALL the query heads of one KV
+    head at once: ``q`` ``(group * rows, Dh)`` holds the group's heads one
+    after the other, ``heads`` is their slice of the ``(H, rows, .)``
+    state, ``mask`` ``(group * rows, ps)``. The same ``m / l / acc``
+    sequence a row, so a row's sums are the per-head fold's; what changes
+    is the unrolling: the body LOOPS over the KV heads (``lax.fori_loop``,
+    the group's lanes a dynamic slice of whole 128-lane tiles) where the
+    per-head body unrolls a fold a query head. 64 query heads over 8 KV
+    heads of 128 queries: a call compiles in 2 s for the chip and lowers
+    in 0.3 s where the per-head body took 11 s and 8 s, in every one of
+    a cell's 35 prefill programs (PERF.md section 6, PR 40)."""
+    group, rows = m_scr[heads].shape[:2]
+
+    def flat(ref):
+        a = ref[heads]
+        return a.reshape(group * rows, a.shape[-1])
+
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), precision=_FP32_DOT,
+        preferred_element_type=jnp.float32)            # (G*rows, ps)
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev, l_prev = flat(m_scr), flat(l_scr)
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - m_next[:, :1])
+    l_scr[heads] = (l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+                    ).reshape(group, rows, -1)
+    m_scr[heads] = m_next.reshape(group, rows, -1)
+    pv = jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), precision=_FP32_DOT,
+        preferred_element_type=jnp.float32)            # (G*rows, Dh)
+    acc_scr[heads] = (flat(acc_scr) * alpha[:, :1] + pv).reshape(
+        group, rows, -1)
+
+
 def _split_kv_refs(rest, pb, quantized):
     """Unpack a paged kernel's trailing refs: ``pb`` k blocks, ``pb`` v
     blocks, (quantized only) ``pb`` k-scale + ``pb`` v-scale row groups,
@@ -271,7 +317,7 @@ def _split_kv_refs(rest, pb, quantized):
 
 def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
                           page_size, pages_per_block, quantized=False,
-                          selected=False):
+                          selected=False, window=None):
     """The chunked-prefill body: online-softmax over a slot's pages,
     ``pages_per_block`` pages per grid step (the shared autotuner's
     tunable: fewer grid iterations, deeper DMA pipelining; the per-page
@@ -292,7 +338,16 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
     ``selected``: one more input, ``(1, C, pb * ps)`` marks per query
     row which cache positions it may attend to beside the causal test:
     sparse attention's per-query selection applied inside the streamed
-    fold."""
+    fold.
+
+    ``window``: a query attends to the last ``window`` tokens only, itself
+    counted (``tok > chunk_starts[s] + r - window``), masked in float32
+    like the causal test; a block wholly behind the window of the chunk's
+    first query does nothing, and its page operands stay at the window's
+    first page (:func:`_paged_page_index`). A row may meet a page that
+    holds none of its tokens before one that does: its ``m`` is still
+    ``NEG_INF`` there, what it sums is wiped by ``alpha = 0`` at the first
+    page that holds one, and a live row's own token always is one."""
     pb = pages_per_block
     sel_ref = None
     if selected:
@@ -305,6 +360,9 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
     n_heads, rows, dh = q_ref.shape[1:]
     group = n_heads * dh // k_refs[0].shape[-1]    # query heads a KV head
     mp = bt_ref.shape[1]
+    # a wide chunk of grouped-query heads folds a page once a KV head
+    by_group = (group > 1 and n_heads * rows >= _GROUP_FOLD_MIN_ROWS
+                and not quantized and not selected)
 
     @pl.when(pj == 0)
     def _init():
@@ -315,6 +373,30 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
     start, nv = start_ref[sl], nv_ref[sl]
     extent = start + nv                      # tokens this chunk can see
     has_work = (nv > 0) & (pj * pb * page_size < extent)
+    if window is not None:
+        has_work = has_work & ((pj + 1) * pb * page_size > start - window + 1)
+
+    def _body_by_group():
+        shape = (group * rows, page_size)
+        row = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+                          rows)
+        for t in range(pb):
+            tok = (pj * pb + t) * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 1)
+            ok = (tok <= start + row) & (row < nv)      # causal + live
+            if window is not None:
+                ok = ok & (tok > start + row - window)
+            def one_kv_head(g, _, t=t, ok=ok):
+                heads = pl.ds(g * group, group)
+                lanes = pl.ds(pl.multiple_of(g * dh, dh), dh)
+                _online_softmax_group_fold(
+                    q_ref[0, heads].reshape(group * rows, dh).astype(
+                        jnp.float32),
+                    k_refs[t][0, :, lanes].astype(jnp.float32),
+                    v_refs[t][0, :, lanes].astype(jnp.float32),
+                    ok, m_scr, l_scr, acc_scr, heads)
+
+            jax.lax.fori_loop(0, n_heads // group, one_kv_head, None)
 
     def _body():
         for t in range(pb):
@@ -327,6 +409,8 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
             row = jax.lax.broadcasted_iota(
                 jnp.int32, (rows, page_size), 0)
             ok = (tok <= start + row) & (row < nv)      # causal + live
+            if window is not None:
+                ok = ok & (tok > start + row - window)
             if selected:
                 ok = ok & (sel_ref[0, :, t * page_size:
                                    (t + 1) * page_size] > 0)
@@ -349,10 +433,17 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
                     k_scale=k_scale, v_scale=v_scale)
 
     # ragged skip: blocks wholly past the slot's live extent do nothing
-    pl.when(has_work)(_body)
+    pl.when(has_work)(_body_by_group if by_group else _body)
 
     @pl.when(pj == npg - 1)
     def _finish():
+        if by_group:                         # every head in one pass
+            denom = l_scr[...][:, :, :1]
+            denom = jnp.where(denom == 0.0, 1.0, denom)
+            alive = m_scr[...][:, :, :1] > NEG_INF / 2
+            o_ref[0] = jnp.where(alive, acc_scr[...] / denom, 0.0).astype(
+                o_ref.dtype)
+            return
         for h in range(n_heads):
             denom = l_scr[h][:, :1]
             denom = jnp.where(denom == 0.0, 1.0, denom)
@@ -447,7 +538,8 @@ def _decode_page(bt, lens, s, j, t, *, page_size, pages_per_block):
 
 
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
-                         pages_per_block, n_heads, quantized=False):
+                         pages_per_block, n_heads, quantized=False,
+                         window=None):
     """The decode body: online softmax over a slot's live pages, a page
     folded once for all heads (above). ``q_ref`` ``(1, rows, kv*Dh)`` is
     the block-structured query matrix (``rows`` = ``n_heads`` padded to
@@ -465,7 +557,13 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
     row maximum, exponentials, weighted values) is a string of latencies,
     and inside one region the scheduler runs the chains of neighbouring
     pages beside each other; a region a page read 0.70 us a page at the
-    docs cell's geometry, this 0.42 (my chip runs, PR 29)."""
+    docs cell's geometry, this 0.42 (my chip runs, PR 29).
+
+    ``window``: the last ``window`` tokens only, by the mask alone (this
+    body serves dense decode only where a pool's pages are not whole
+    tiles, sizes of a test: the pages behind the window still move; a
+    page wholly behind it sums under ``m = NEG_INF`` what the first page
+    with a live token wipes with ``alpha = 0``)."""
     pb = pages_per_block
     (k_refs, v_refs, ks_refs, vs_refs, o_ref, m_scr, l_scr,
      acc_scr) = _split_kv_refs(rest, pb, quantized)
@@ -499,7 +597,10 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
                 s = s * ks_refs[t][pl.ds(r, 1), :]
             tok = (pj * pb + t) * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, page_size), 1)
-            s = jnp.where(tok < extent, s, NEG_INF)
+            live = tok < extent
+            if window is not None:
+                live = live & (tok >= extent - window)
+            s = jnp.where(live, s, NEG_INF)
             m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_next)                      # (rows, 128)
             p = jnp.exp(s - m_next[:, :1])                   # (rows, ps)
@@ -522,28 +623,35 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
         _decode_finish, o_ref, m_scr, l_scr, acc_scr, group))
 
 
-def _paged_page_index(ps, mp, pb, t, decode):
+def _paged_page_index(ps, mp, pb, t, decode, window=None):
     """Page operand ``t``'s pool page at grid step ``(s, j)``, as a
     function of the grid ids and the scalar-prefetch refs (the block
     table first). Chunked prefill: page ``j*pb + t`` of the slot,
     clamped to its last (the clamped duplicate is fully masked by the
-    token test in the body). Decode: :func:`_decode_page`."""
+    token test in the body) and, under a ``window``, to the page that
+    holds the first token of the chunk's first window (pages behind it
+    do not move). Decode: :func:`_decode_page`."""
     if decode:
         def page(s, j, bt, lens):
             return _decode_page(bt, lens, s, j, t, page_size=ps,
                                 pages_per_block=pb)
+    elif window is not None:
+        def page(s, j, bt, starts, *_rest):
+            first = jnp.maximum(starts[s] - window + 1, 0) // ps
+            return bt[s, jnp.clip(j * pb + t, jnp.minimum(first, mp - 1),
+                                  mp - 1)]
     else:
         def page(s, j, bt, *_rest):
             return bt[s, jnp.minimum(j * pb + t, mp - 1)]
     return page
 
 
-def _paged_kv_specs(ps, hd, mp, pb, decode=False):
+def _paged_kv_specs(ps, hd, mp, pb, decode=False, window=None):
     """``pb`` (k, v) BlockSpec pairs per grid step: WHOLE pages of the
     slot's block table, all heads folded into their ``hd = H*Dh``
     lanes."""
     def kv_spec(t):
-        page = _paged_page_index(ps, mp, pb, t, decode)
+        page = _paged_page_index(ps, mp, pb, t, decode, window)
         return pl.BlockSpec((1, ps, hd),
                             lambda *ids_and_refs: (page(*ids_and_refs), 0, 0))
     ks = [kv_spec(t) for t in range(pb)]
@@ -588,10 +696,11 @@ def _block_structured_queries(q, kv):
     return qb.reshape(s_slots, kv_rows * group, kv * dh)
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6), static_argnames=("name",))
+@functools.partial(jax.jit, static_argnums=(5, 6),
+                   static_argnames=("name", "window"))
 def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
                          interpret, pages_per_block, k_scales, v_scales,
-                         selected=None, name=None):
+                         selected=None, name=None, window=None):
     """The one ``pallas_call`` behind the pipelined paged kernels (all
     but dense decode). Jitted, so
     that a step program traces and lowers the kernel body once and calls
@@ -619,6 +728,8 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
         state, acc = (h, rows, 128), (h, rows, dh)
         kernel = functools.partial(_paged_prefill_kernel,
                                    selected=selected is not None)
+        if window is not None:
+            kernel = functools.partial(kernel, window=window)
     else:
         # the queries of a slot as ONE matrix over the page's lanes
         q = _block_structured_queries(q, hd // dh)
@@ -638,8 +749,11 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
         q_block, out_block = (1, rows, hd), (1, rows, dh)
         state, acc = (rows, 128), (rows, hd)
         kernel = functools.partial(_paged_decode_kernel, n_heads=h)
+        if window is not None:
+            kernel = functools.partial(kernel, window=window)
 
-    k_specs, v_specs = _paged_kv_specs(ps, hd, mp, pb, decode=not chunked)
+    k_specs, v_specs = _paged_kv_specs(ps, hd, mp, pb, decode=not chunked,
+                                       window=window)
     sel_specs, sel_args = [], []
     if selected is not None:
         sel_specs = [pl.BlockSpec(
@@ -699,7 +813,7 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
 
 def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
                          interpret, pages_per_block=1, k_scales=None,
-                         v_scales=None, name=None):
+                         v_scales=None, name=None, window=None):
     """The pipelined decode call: sparse decode (``name``) and, with
     ``k_scales``/``v_scales`` given, the dequant-attend variant: same
     grid and BlockSpecs plus one scale-row group per streamed page,
@@ -710,7 +824,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
     return _paged_attend_pallas(
         q * jnp.asarray(scale, q.dtype), k_pages, v_pages, block_tables,
         (lengths,), interpret, pages_per_block, k_scales, v_scales,
-        name=name)
+        name=name, window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -737,7 +851,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
 def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                               k_buf, v_buf, sems, first_buf, m_scr, l_scr,
                               acc_scr, *, page_size, pages_per_block,
-                              n_heads):
+                              n_heads, window=None):
     """The dense decode body: grid ``(S,)``, one step a slot. ``q_ref``,
     ``o_ref`` and the ``m / l / acc`` state are
     :func:`_paged_decode_kernel`'s; ``k_hbm`` / ``v_hbm`` are the whole
@@ -745,7 +859,14 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     blocks in VMEM, ``sems`` ``(2, 2)`` one DMA semaphore a (pool,
     buffer), ``first_buf`` the buffer that holds the slot's first block
     (it alternates block by block across slots, so a slot's first block
-    can be on its way while the slot before still folds)."""
+    can be on its way while the slot before still folds).
+
+    ``window``: a query attends to the slot's last ``window`` tokens only.
+    The walk then starts at the page that holds the first of them (block
+    ``b`` of a slot is its pages ``first + b*pb ..``), so it copies and
+    folds ``pages_for(window) + 1`` pages at most whatever the length,
+    and the rows of that page before the window are masked in float32
+    as the rows past the extent are."""
     ps, pb = page_size, pages_per_block
     sl = pl.program_id(0)
     n_slots = pl.num_programs(0)
@@ -755,8 +876,17 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     def live_pages(slot):
         return (len_ref[slot] + ps - 1) // ps
 
+    def walked(slot, page):
+        """Page ``page`` of the slot's walk as a page of its table: the
+        walk starts at page 0, or at the window's first page."""
+        if window is None:
+            return page
+        return jnp.maximum(len_ref[slot] - window, 0) // ps + page
+
     extent = len_ref[sl]
-    n_live = live_pages(sl)
+    # the live pages from the walk's first on
+    n_live = live_pages(sl) if window is None \
+        else live_pages(sl) - walked(sl, 0)
     n_blocks = (n_live + pb - 1) // pb
 
     def copies(slot, block, buf, start):
@@ -767,7 +897,7 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         from."""
         n = live_pages(slot)
         for t in range(pb):
-            p = block * pb + t
+            p = walked(slot, block * pb + t)
             # a dead page's table entry is never read past the table
             page = bt_ref[slot, jnp.minimum(p, bt_ref.shape[1] - 1)]
 
@@ -799,9 +929,14 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         width = n_pages * ps
         q = q_ref[0]
         s = _all_heads_page_dot(q, k_buf[buf, :width], 1)   # (rows, width)
-        tok = block * (pb * ps) + jax.lax.broadcasted_iota(
+        start = block * (pb * ps) if window is None \
+            else walked(sl, block * pb) * ps
+        tok = start + jax.lax.broadcasted_iota(
             jnp.int32, (rows, width), 1)
-        s = jnp.where(tok < extent, s, NEG_INF)
+        live = tok < extent
+        if window is not None:
+            live = live & (tok >= extent - window)
+        s = jnp.where(live, s, NEG_INF)
         m = m_scr[...]
         m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_next)                          # (rows, 128)
@@ -834,9 +969,10 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     _decode_finish(o_ref, m_scr, l_scr, acc_scr, group)
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6))
+@functools.partial(jax.jit, static_argnums=(5, 6),
+                   static_argnames=("window",))
 def _paged_decode_walk_pallas(q, k_pages, v_pages, block_tables, lengths,
-                              interpret, pages_per_block):
+                              interpret, pages_per_block, window=None):
     """The ``pallas_call`` of the dense decode entry
     (``ragged_paged_decode``), jitted like :func:`_paged_attend_pallas`
     so that a step program traces and lowers the body once. ``q`` is
@@ -869,7 +1005,8 @@ def _paged_decode_walk_pallas(q, k_pages, v_pages, block_tables, lengths,
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_walk_kernel, page_size=ps,
-                          pages_per_block=pb, n_heads=h),
+                          pages_per_block=pb, n_heads=h,
+                          **({} if window is None else {"window": window})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, rows, dh), q.dtype),
         # one slot after the other: a slot's last fold runs beside the
@@ -939,7 +1076,7 @@ def _paged_decode_int8_pallas(q, k_pages, v_pages, k_scales, v_scales,
 # ---------------------------------------------------------------------------
 
 def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
-                       n_valid, scale, selected=None):
+                       n_valid, scale, selected=None, window=None):
     """``selected`` (S, C, mp*ps), where given, marks the cache positions
     each query may attend to beside the causal test (sparse attention:
     the indexer's choice)."""
@@ -956,6 +1093,8 @@ def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
     causal = tok[None, None, None, :] <= pos[:, None, :, None]
     row_ok = (jnp.arange(c) < n_valid[:, None])[:, None, :, None]
     ok = causal & row_ok
+    if window is not None:      # the last ``window`` tokens, itself counted
+        ok = ok & (tok[None, None, None, :] > pos[:, None, :, None] - window)
     if selected is not None:
         ok = ok & (selected[:, None] > 0)
     scores = jnp.where(ok, scores, NEG_INF)
@@ -970,7 +1109,7 @@ def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
 def _paged_prefill_pallas(q, k_pages, v_pages, block_tables, chunk_starts,
                           n_valid, scale, interpret, pages_per_block=1,
                           k_scales=None, v_scales=None, selected=None,
-                          name=None):
+                          name=None, window=None):
     """Chunked-prefill analog of :func:`_paged_decode_pallas`: the same
     call site with the chunked body, same ``pages_per_block`` tunable,
     outputs bit-equal for any setting of it. ``q`` (S, C, H, Dh) is handed to the
@@ -980,7 +1119,7 @@ def _paged_prefill_pallas(q, k_pages, v_pages, block_tables, chunk_starts,
     out = _paged_attend_pallas(qs, k_pages, v_pages, block_tables,
                                (chunk_starts, n_valid), interpret,
                                pages_per_block, k_scales, v_scales,
-                               selected=selected, name=name)
+                               selected=selected, name=name, window=window)
     return out.transpose(0, 2, 1, 3)                        # (S,C,H,Dh)
 
 
@@ -1038,24 +1177,30 @@ def _paged_prefill_int8_pallas(q, k_pages, v_pages, k_scales, v_scales,
 
 def ragged_paged_decode_attention(q, k_pages, v_pages, block_tables,
                                   lengths, *, scale: Optional[float] = None,
-                                  impl: str = "auto"):
+                                  impl: str = "auto",
+                                  window: Optional[int] = None):
     """One decode step of attention for every slot at once.
 
     ``q`` (S, H, Dh); ``k_pages``/``v_pages`` (P, page_size, H*Dh),
     a token's heads folded head-major into the last axis;
     ``block_tables`` (S, max_pages) int32; ``lengths`` (S,) int32 valid
     tokens per slot. Returns (S, H, Dh). ``impl``: "auto" (pallas on
-    TPU, lax elsewhere), "lax", "pallas", "pallas_interpret".
+    TPU, lax elsewhere), "lax", "pallas", "pallas_interpret". ``window``
+    (static): the query attends to the slot's last ``window`` tokens
+    only, itself counted; None: to all of them.
     """
     from paddle_tpu import kernels
+    kw = {} if window is None else {"window": int(window)}
     return kernels.dispatch("ragged_paged_decode", q, k_pages, v_pages,
-                            block_tables, lengths, impl=impl, scale=scale)
+                            block_tables, lengths, impl=impl, scale=scale,
+                            **kw)
 
 
 def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
                                    chunk_starts, n_valid, *,
                                    scale: Optional[float] = None,
-                                   impl: str = "auto"):
+                                   impl: str = "auto",
+                                   window: Optional[int] = None):
     """One batched chunked-prefill step of attention for every slot.
 
     ``q`` (S, C, H, Dh) — a chunk of C query tokens per slot, the first
@@ -1067,12 +1212,14 @@ def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
     K/V the caller has already written). Padding lanes and inactive
     slots (``n_valid == 0``) emit exact zeros. Returns (S, C, H, Dh).
     ``impl``: "auto" (pallas on TPU, lax elsewhere), "lax", "pallas",
-    "pallas_interpret".
+    "pallas_interpret". ``window`` (static): each query attends to the
+    last ``window`` cache positions up to its own only; None: to all.
     """
     from paddle_tpu import kernels
+    kw = {} if window is None else {"window": int(window)}
     return kernels.dispatch("ragged_paged_prefill", q, k_pages, v_pages,
                             block_tables, chunk_starts, n_valid,
-                            impl=impl, scale=scale)
+                            impl=impl, scale=scale, **kw)
 
 
 def ragged_paged_decode_int8_attention(q, k_pages, v_pages, k_scales,
@@ -1115,7 +1262,7 @@ def ragged_paged_prefill_int8_attention(q, k_pages, v_pages, k_scales,
 # ---------------------------------------------------------------------------
 
 def _decode_kernel_pallas(q, k_pages, v_pages, block_tables, lengths, *,
-                          block_sizes, interpret, scale=None):
+                          block_sizes, interpret, scale=None, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     pb = block_sizes.get("pages_per_block", 1)
@@ -1128,22 +1275,22 @@ def _decode_kernel_pallas(q, k_pages, v_pages, block_tables, lengths, *,
     if hd % 128 or ps % (32 // k_pages.dtype.itemsize):
         return _paged_decode_pallas(q, k_pages, v_pages, block_tables,
                                     lengths, scale, interpret,
-                                    pages_per_block=pb)
+                                    pages_per_block=pb, window=window)
     return _paged_decode_walk_pallas(
         q * jnp.asarray(scale, q.dtype), k_pages, v_pages, block_tables,
-        lengths, interpret, pb)
+        lengths, interpret, pb, window=window)
 
 
 def _decode_kernel_lax(q, k_pages, v_pages, block_tables, lengths, *,
-                       scale=None):
+                       scale=None, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths,
-                             scale)
+                             scale, window)
 
 
 def _decode_kernel_reference(q, k_pages, v_pages, block_tables, lengths,
-                             *, scale=None):
+                             *, scale=None, window=None):
     """NumPy per-slot dense attention — independent of both impls."""
     import numpy as np
     s_slots, h, dh = q.shape
@@ -1160,8 +1307,9 @@ def _decode_kernel_reference(q, k_pages, v_pages, block_tables, lengths,
         n = int(ln[sl])
         if n == 0:
             continue
-        k = kp[bt[sl]].reshape(mp * ps, h, dh)[:n]
-        v = vp[bt[sl]].reshape(mp * ps, h, dh)[:n]
+        lo = 0 if window is None else max(n - window, 0)
+        k = kp[bt[sl]].reshape(mp * ps, h, dh)[lo:n]
+        v = vp[bt[sl]].reshape(mp * ps, h, dh)[lo:n]
         s = np.einsum("hd,thd->ht", qn[sl], k) * scale
         s = s - s.max(-1, keepdims=True)
         p = np.exp(s)
@@ -1171,7 +1319,10 @@ def _decode_kernel_reference(q, k_pages, v_pages, block_tables, lengths,
 
 
 def _make_paged_sample(seed, *, chunked):
+    """Seeds 0-2: three shapes; seeds 3-5: the same shapes under a window
+    that is no multiple of the page (``{"window": ...}``)."""
     import numpy as np
+    kwargs = {"window": (11, 21, 40)[seed % 3]} if seed % 6 >= 3 else {}
     s_slots, h, dh, ps, mp = (
         (4, 2, 16, 8, 3), (6, 4, 32, 16, 4), (8, 4, 64, 16, 6))[seed % 3]
     c = ps  # prefill chunk = one page of queries
@@ -1192,12 +1343,12 @@ def _make_paged_sample(seed, *, chunked):
                         jnp.float32)
         lengths = jnp.asarray(
             rng.integers(0, mp * ps + 1, s_slots), jnp.int32)
-        return (q, k_pages, v_pages, block_tables, lengths), {}
+        return (q, k_pages, v_pages, block_tables, lengths), kwargs
     q = jnp.asarray(rng.standard_normal((s_slots, c, h, dh)), jnp.float32)
     starts = jnp.asarray(
         rng.integers(0, (mp - 1) * ps, s_slots), jnp.int32)
     n_valid = jnp.asarray(rng.integers(0, c + 1, s_slots), jnp.int32)
-    return (q, k_pages, v_pages, block_tables, starts, n_valid), {}
+    return (q, k_pages, v_pages, block_tables, starts, n_valid), kwargs
 
 
 def _paged_sig(q, k_pages, bt):
@@ -1294,24 +1445,26 @@ def _decode_donation_probe():
 
 def _prefill_kernel_pallas(q, k_pages, v_pages, block_tables,
                            chunk_starts, n_valid, *, block_sizes,
-                           interpret, scale=None):
+                           interpret, scale=None, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _paged_prefill_pallas(
         q, k_pages, v_pages, block_tables, chunk_starts, n_valid, scale,
-        interpret, pages_per_block=block_sizes.get("pages_per_block", 1))
+        interpret, pages_per_block=block_sizes.get("pages_per_block", 1),
+        window=window)
 
 
 def _prefill_kernel_lax(q, k_pages, v_pages, block_tables, chunk_starts,
-                        n_valid, *, scale=None):
+                        n_valid, *, scale=None, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _paged_prefill_lax(q, k_pages, v_pages, block_tables,
-                              chunk_starts, n_valid, scale)
+                              chunk_starts, n_valid, scale, window=window)
 
 
 def _prefill_kernel_reference(q, k_pages, v_pages, block_tables,
-                              chunk_starts, n_valid, *, scale=None):
+                              chunk_starts, n_valid, *, scale=None,
+                              window=None):
     """NumPy per-slot, per-row causal attention over the slot's pages."""
     import numpy as np
     s_slots, c, h, dh = q.shape
@@ -1330,11 +1483,12 @@ def _prefill_kernel_reference(q, k_pages, v_pages, block_tables,
         v = vp[bt[sl]].reshape(mp * ps, h, dh)
         for r in range(int(nv[sl])):
             limit = int(st[sl]) + r + 1          # causal horizon
-            s = np.einsum("hd,thd->ht", qn[sl, r], k[:limit]) * scale
+            lo = 0 if window is None else max(limit - window, 0)
+            s = np.einsum("hd,thd->ht", qn[sl, r], k[lo:limit]) * scale
             s = s - s.max(-1, keepdims=True)
             p = np.exp(s)
             p = p / p.sum(-1, keepdims=True)
-            outs[sl, r] = np.einsum("ht,thd->hd", p, v[:limit])
+            outs[sl, r] = np.einsum("ht,thd->hd", p, v[lo:limit])
     return jnp.asarray(outs).astype(q.dtype)
 
 

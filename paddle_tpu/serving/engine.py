@@ -186,6 +186,10 @@ _STEP_STAT_HELP = {
                         "token steps (the denominator of touched)",
     "moe_max_expert_tokens": "the fullest expert's load, summed over "
                              "layers and token steps",
+    "moe_routed_pairs": "token-expert pairs the router made, those of "
+                        "experts held on other chips included (the "
+                        "denominator of assignments where a chip holds a "
+                        "share of a layer's experts)",
     "moe_tile_rows": "rows of the row tiles the grouped expert kernel "
                      "walked (tiles in use x rows a tile; the pairs "
                      "over it is the tiles' fill)",
@@ -414,6 +418,13 @@ class ServingEngine:
         # fp32 scales and attends through the dequant-attend kernels —
         # HBM per live token roughly halves AGAIN vs bf16
         dtype = cache_dtype or base.param_dtype(params)
+        if any(w is not None for w in spec.layer_windows) \
+                and self.prefill_chunk > page_size:
+            raise ValueError(
+                f"prefill_chunk={self.prefill_chunk} > page_size="
+                f"{page_size}: a window layer's ring holds its window and "
+                "one page more, so a call writes at most a page of tokens "
+                "a slot before it attends")
         # a tp engine's pool is globally shaped but placed sharded H/tp
         self.cache = PagedKVCache(PagedCacheConfig(
             num_layers=cfg.num_layers, num_heads=cfg.kv_heads,
@@ -422,7 +433,8 @@ class ServingEngine:
             max_pages_per_slot=max_pages_per_slot, dtype=dtype,
             share_prefix=prefix_sharing, extra_rows=spec.extra_rows,
             slot_state=spec.slot_state,
-            slot_state_dtype=jnp.dtype(spec.slot_state_dtype)),
+            slot_state_dtype=jnp.dtype(spec.slot_state_dtype),
+            layer_windows=spec.layer_windows),
             mesh=self.mesh,
             host_spill_pages=host_spill_pages)
         self.quantized = self.cache.config.quantized
@@ -688,8 +700,12 @@ class ServingEngine:
         self._c_kv_live = kv.child(kind="live")
         self._c_kv_gathered = kv.child(kind="gathered")
         c = self.cache.config
-        self._kv_token_bytes = (2 * c.num_heads * c.head_dim
-                                * np.dtype(c.dtype).itemsize * c.num_layers)
+        # K and V of one token in one layer; a window layer reads its
+        # window's tokens at most
+        self._kv_layer_token_bytes = (2 * c.num_heads * c.head_dim
+                                      * np.dtype(c.dtype).itemsize)
+        self._kv_token_bytes = self._kv_layer_token_bytes * (
+            c.num_layers - len(c.window_layers))
         self._h_decode_step = r.histogram(
             "serving_decode_step_seconds",
             "wall time per decode block: assemble.start to the end of "
@@ -745,6 +761,7 @@ class ServingEngine:
             "retired static flops per busy second / best observed rate"
         ).child()
         self._bind_state_metrics(r)
+        self._bind_window_metrics(r)
         self._c_step_stats = [
             r.counter(f"serving_{name}_total", _STEP_STAT_HELP.get(
                 name, "a count the step program hands back")).child()
@@ -794,6 +811,57 @@ class ServingEngine:
         r.gauge("serving_ssm_state_pool_bytes",
                 "bytes of the slot-state pool, null row included").set(
                     self._state_slot_bytes * (self.scheduler.num_slots + 1))
+
+    def _bind_window_metrics(self, r):
+        """The series of a program with layers of two kinds
+        (``spec.layer_windows``), fed from the lengths the host holds; a
+        program whose layers are all full binds none of them."""
+        c = self.cache.config
+        #: ``{window: layers}``; empty where every layer is full
+        self._window_layers = c.window_layer_counts
+        #: the slots' table lanes carry their slot beside the pages (a
+        #: state row, a ring)
+        self._lane_slot_column = bool(self._state_slot_bytes
+                                      or self._window_layers)
+        if not self._window_layers:
+            return
+        resident = r.counter(
+            "serving_kv_resident_bytes_total",
+            "K/V bytes the slots of a decode round or prefill call hold "
+            "when it is dispatched, whole pages, by layer kind: a full "
+            "layer every page of the slot's tokens, a window layer its "
+            "ring's pages at most")
+        self._c_resident = {kind: resident.child(layers=kind)
+                            for kind in ("window", "full")}
+        self._c_recycled = r.counter(
+            "serving_window_pages_recycled_total",
+            "ring pages of window layers written over as slots advanced "
+            "past them (pages x window layers)").child()
+        pool = r.gauge("serving_kv_pool_bytes",
+                       "bytes of the K/V pools by layer kind, null pages "
+                       "included")
+        full = [i for i in range(c.num_layers) if i not in c.window_layers]
+        for kind, layers in (("window", c.window_layers), ("full", full)):
+            pool.set(sum(a.nbytes for i in layers
+                         for a in self.cache.pages[i][:c.paged_entries]),
+                     layers=kind)
+
+    def _count_window(self, span, before, after):
+        """One round's or call's K/V by layer kind, from the lengths the
+        host holds: what its slots hold going in (``before`` tokens each)
+        and the ring pages written over on the way to ``after``."""
+        c = self.cache.config
+        page = c.page_size * self._kv_layer_token_bytes
+        pages = -(-np.asarray(before, np.int64) // c.page_size)
+        self._c_resident["full"].inc(int(pages.sum()) * page * (
+            c.num_layers - len(c.window_layers)))
+        self._c_resident["window"].inc(page * int(sum(
+            n * np.minimum(pages, c.ring_pages(win)).sum()
+            for win, n in self._window_layers.items())))
+        recycled = self.cache.recycled_pages(before, after)
+        self._c_recycled.inc(recycled)
+        if span is not None:
+            span.set_attrs(window_pages=recycled)
 
     def _count_state(self, span, decoding: int = 0, token_steps: int = 0,
                      lanes: int = 0, fresh: int = 0, tokens: int = 0):
@@ -1254,12 +1322,22 @@ class ServingEngine:
         itself since PR 39 and lays out no width, so for it the ratio
         says how wide the table's bucket is for what the slots hold, not
         what the kernel spent."""
-        live = n * int(self.cache.lengths[dslots].sum()) \
-            + len(dslots) * n * (n + 1) // 2
-        self._c_kv_live.inc(live * self._kv_token_bytes)
+        c = self.cache.config
+        lens = self.cache.lengths[dslots]
+        live = n * int(lens.sum()) + len(dslots) * n * (n + 1) // 2
+        live_b = live * self._kv_token_bytes
+        gathered = w * self._kv_token_bytes
+        for win, layers in self._window_layers.items():
+            # a window layer's read is its window's: token step j of a
+            # slot holding L tokens attends over min(L + j + 1, window),
+            # from a table as wide as its ring
+            live_b += layers * self._kv_layer_token_bytes * int(sum(
+                np.minimum(lens + j + 1, win).sum() for j in range(n)))
+            gathered += layers * c.ring_pages(win) \
+                * self._kv_layer_token_bytes
+        self._c_kv_live.inc(live_b)
         self._c_kv_gathered.inc(
-            n * self.scheduler.num_slots * w * self.cache.config.page_size
-            * self._kv_token_bytes)
+            n * self.scheduler.num_slots * c.page_size * gathered)
 
     def _note_step_stats(self, phase, counts):
         """Feed one call's device-side counts (``self._step_stats``
@@ -1269,10 +1347,12 @@ class ServingEngine:
             child.inc(int(n))
         if phase.span is not None:
             got = dict(zip(self._step_stats, counts))
-            phase.span.set_attrs(**{
-                attr: int(got[name]) for attr, name in (
-                    ("experts_touched", "moe_experts_touched"),
-                    ("selected", "attn_selected_tokens")) if name in got})
+            attrs = [("experts_touched", "moe_experts_touched"),
+                     ("selected", "attn_selected_tokens")]
+            if "moe_routed_pairs" in got:   # a chip's share of the experts
+                attrs.append(("pairs_held", "moe_assignments"))
+            phase.span.set_attrs(**{attr: int(got[name])
+                                    for attr, name in attrs if name in got})
 
     def _read_back(self, phase: str, unread, *handles):
         """The host waits for the device: ``handles`` and the program
@@ -1379,6 +1459,10 @@ class ServingEngine:
                     tokens[i] = -(1 + j) - k * s_tot
             self._count_kv_bytes(dslots, n, w)
             self._count_state(rnd.span, decoding=len(dslots), token_steps=n)
+            if self._window_layers:
+                lens = self.cache.lengths[dslots]
+                self._count_window(rnd.span, lens, lens + np.asarray(
+                    [rows[i][1] for i in dslots]))
             tok_dev = self._upload(tokens)
             for nxt, _ in calls:
                 tok_dev = self.first_token_step(tok_dev, nxt)
@@ -1918,9 +2002,10 @@ class ServingEngine:
                 args = (jnp.asarray(starts), jnp.asarray(tokens),
                         jnp.asarray(nv))
                 bt_rows = bt_rows[:, :w]
-                if self._state_slot_bytes:
-                    # a lane says whose state it holds, pool row slot + 1,
-                    # in one more column of its table (a pad lane: row 0)
+                if self._lane_slot_column:
+                    # a lane says whose state or ring it holds, pool row
+                    # slot + 1, in one more column of its table (a pad
+                    # lane: row 0)
                     state_rows = np.zeros((sb, 1), np.int32)
                     state_rows[:len(pslots), 0] = np.asarray(pslots) + 1
                     bt_rows = np.concatenate([bt_rows, state_rows], axis=1)
@@ -1928,6 +2013,9 @@ class ServingEngine:
                 self._count_state(call.span, lanes=len(pslots),
                                   fresh=sum(lo == 0 for lo in los),
                                   tokens=call_tokens)
+                if self._window_layers:
+                    self._count_window(call.span, np.asarray(los),
+                                       np.asarray(los) + np.asarray(ns))
                 dbt_dev = jnp.asarray(dbt_rows[:, :w]) if self.speculative \
                     else None
             with phase("serving.prefill.dispatch",
@@ -2163,7 +2251,7 @@ class ServingEngine:
                 w, sb = sig[1], sig[2]
                 zb = jnp.zeros((sb,), jnp.int32)
                 args = (self._step_params, self.cache.pages,
-                        jnp.zeros((sb, w + bool(self._state_slot_bytes)),
+                        jnp.zeros((sb, w + self._lane_slot_column),
                                   jnp.int32), zb,
                         jnp.zeros((sb, self.prefill_chunk), jnp.int32),
                         zb)
@@ -2830,6 +2918,47 @@ class ServingEngine:
                 jnp.where(written, tile, pool[page]))
         return pool
 
+    def _ring(self, window, slots, first_page, width):
+        """Window layers keep a slot's K and V in a ring of pages, found
+        by the slot and the position alone: -> (pages (S,) or (S, C) of
+        the tokens of sequence page ``first_page`` (same shape), the
+        table (S, ``width``) of the pages ``first_page[s] ..`` as the
+        paged kernels take one)."""
+        ring = self.cache.config.ring_pages(window)
+        base = 1 + slots * ring
+
+        def pages(seq_page):
+            return base.reshape(base.shape + (1,) * (seq_page.ndim - 1)) \
+                + seq_page % ring
+        return pages, pages(first_page[:, None]
+                            + jnp.arange(width, dtype=jnp.int32))
+
+    def _window_decode(self, window, slots, lengths, writable):
+        """What a window layer's decode token needs, from the slot and
+        its ``lengths`` (the token being written not counted): (the ring
+        page it is written to (S,), the table of the pages its window
+        spans, the tokens in them up to and with this one)."""
+        ps = self.cache.config.page_size
+        first = jnp.maximum(lengths + 1 - window, 0) // ps
+        pages, table = self._ring(window, slots, first,
+                                  self.cache.config.ring_pages(window))
+        return (jnp.where(writable, pages(lengths // ps), 0), table,
+                lengths + 1 - first * ps)
+
+    def _window_prefill(self, window, slots, starts, positions, valid):
+        """A window layer's chunk: (the ring pages its tokens are written
+        to (S, C), the table of the pages the chunk's windows span, the
+        chunk's start in them). A chunk of at most a page of tokens spans
+        the ring and, where it starts inside a page, that page's next
+        lap: one column more than the ring, the stale rows of either lap
+        outside every query's window or past it."""
+        ps = self.cache.config.page_size
+        first = jnp.maximum(starts - window + 1, 0) // ps
+        pages, table = self._ring(window, slots, first,
+                                  self.cache.config.ring_pages(window) + 1)
+        return (jnp.where(valid, pages(positions // ps), 0), table,
+                starts - first * ps)
+
     def _selects(self, spec, block_tables) -> bool:
         """Whether a call at this gather width runs the program's token
         selection: a static fact of the bucket. Up to ``select_topk``
@@ -2950,6 +3079,7 @@ class ServingEngine:
         n_stats = len(self._stat_names(spec))
         n_paged = len(pages[0]) - len(spec.slot_state)
         mixes = bool(spec.slot_state) and spec.slot_state_reader == "mixer"
+        windows = spec.layer_windows or (None,) * spec.num_layers
 
         def one_token(j, pages, lengths, tokens):
             pos = jnp.minimum(lengths, spec.max_position - 1)
@@ -2970,9 +3100,13 @@ class ServingEngine:
             # not this block's to touch: the null row
             state_rows = jnp.where(writable, slot_ids + 1, 0) \
                 if spec.slot_state else None
+            ringed = {win: self._window_decode(win, slot_ids, lengths,
+                                               writable)
+                      for win in set(windows) - {None}}
             new_pages, counts = [], 0
             carry = self._carry_start(spec, s_tot, 1)
             for i in range(spec.num_layers):
+                win = windows[i]
                 with jax.named_scope("attn_in"):
                     q, rows, index, state = self._attn_in(
                         program, params, i, x, pos[:, None],
@@ -2980,11 +3114,19 @@ class ServingEngine:
                 with jax.named_scope("write_rows"):
                     ent = self._write_rows(
                         pages[i][:n_paged], tuple(r[:, 0] for r in rows),
-                        page_idx, off, quantized, psum_axis)
+                        page_idx if win is None else ringed[win][0], off,
+                        quantized, psum_axis)
                 with jax.named_scope("attend"):
-                    att, attended = self._attend_decode(
-                        spec, q[:, :, 0, :], ent, block_tables, lengths + 1,
-                        index, quantized)                       # (S,H,Dh)
+                    if win is None:
+                        att, attended = self._attend_decode(
+                            spec, q[:, :, 0, :], ent, block_tables,
+                            lengths + 1, index, quantized)      # (S,H,Dh)
+                    else:
+                        _, table, held = ringed[win]
+                        att = DA.ragged_paged_decode_attention(
+                            q[:, :, 0, :], ent[0], ent[1], table, held,
+                            impl=self.attn_impl, window=win)
+                        attended = jnp.minimum(lengths + 1, win)
                 with jax.named_scope("attn_out"):
                     x_in, x = x, program.attn_out(params, i, x, att[:, None])
                 if mixes:
@@ -3077,8 +3219,9 @@ class ServingEngine:
         s_tot, c = tokens.shape
         n_paged = len(pages[0]) - len(spec.slot_state)
         mixes = bool(spec.slot_state) and spec.slot_state_reader == "mixer"
+        windows = spec.layer_windows or (None,) * spec.num_layers
         state_rows = fresh = None
-        if spec.slot_state:
+        if spec.slot_state or spec.layer_windows:
             # the lanes' state rows ride the tables' last column; a lane
             # whose prompt starts here starts from zeros, its slot's
             # reset at admission
@@ -3104,20 +3247,34 @@ class ServingEngine:
                 if self._selects(spec, block_tables) else seen
             seen, attended = (jnp.where(valid, a, 0).sum()
                               for a in (seen, attended))
+        # a lane's ring is its slot's: pool row less one (a pad lane
+        # writes nothing and attends to nothing)
+        ringed = {win: self._window_prefill(
+            win, jnp.maximum(state_rows - 1, 0), starts, positions, valid)
+            for win in set(windows) - {None}}
         new_pages, counts = [], 0
         carry = self._carry_start(spec, s_tot, c)
         for i in range(spec.num_layers):
+            win = windows[i]
             with jax.named_scope("attn_in"):
                 q, rows, index, state = self._attn_in(
                     program, params, i, x, pos_e, pages[i][n_paged:],
                     state_rows, fresh, valid)
             with jax.named_scope("write_rows"):
-                ent = self._write_rows(pages[i][:n_paged], rows, page_idx,
-                                       off, quantized, psum_axis)
+                ent = self._write_rows(
+                    pages[i][:n_paged], rows,
+                    page_idx if win is None else ringed[win][0], off,
+                    quantized, psum_axis)
             with jax.named_scope("attend"):
-                att = self._attend_prefill(
-                    spec, q.transpose(0, 2, 1, 3), ent, block_tables, starts,
-                    n_valid, index, quantized)                  # (S,C,H,Dh)
+                if win is None:
+                    att = self._attend_prefill(
+                        spec, q.transpose(0, 2, 1, 3), ent, block_tables,
+                        starts, n_valid, index, quantized)      # (S,C,H,Dh)
+                else:
+                    _, table, start_in = ringed[win]
+                    att = DA.ragged_paged_prefill_attention(
+                        q.transpose(0, 2, 1, 3), ent[0], ent[1], table,
+                        start_in, n_valid, impl=self.attn_impl, window=win)
             with jax.named_scope("attn_out"):
                 x_in, x = x, program.attn_out(params, i, x, att)
             if mixes:
@@ -3211,9 +3368,11 @@ class ServingEngine:
         including the scale rows of a quantized pool, which travel with
         their page. Fixed shape — src/dst are traced scalars, so one
         compile covers every copy."""
-        n_paged = self.cache.config.paged_entries
-        return [tuple(a.at[dst].set(a[src]) for a in ent[:n_paged])
-                + tuple(ent[n_paged:]) for ent in pages]
+        c = self.cache.config
+        n_paged = c.paged_entries
+        return [ent if c.window_of(i) is not None else
+                tuple(a.at[dst].set(a[src]) for a in ent[:n_paged])
+                + tuple(ent[n_paged:]) for i, ent in enumerate(pages)]
 
     def _read_page_impl(self, pages, src):
         """One page's K/V across every layer, stacked (2, L, page_size,

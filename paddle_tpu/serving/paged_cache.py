@@ -73,6 +73,31 @@ holds whatever its last request left: the step that starts a prompt
 starts from zeros (``fresh`` in :mod:`~paddle_tpu.serving.program`), which
 is the reset at admission with no program of its own.
 
+Window layers (ISSUE 40): a program whose layers are not all of one kind
+declares ``layer_windows`` (None, or the tokens a query of that layer
+attends to, itself counted) and the one manager then holds a third kind of
+cache beside pages and slot state. A FULL layer's K and V stay as above:
+pages of the shared pool, mapped by the block table, reserved at
+admission. A WINDOW layer's are a **ring** of ``ring_pages(window)`` =
+``pages_for(window) + 1`` pages a slot, in a pool of its own,
+``(num_slots * ring + 1, page_size, lanes)`` (page 0 the null page): token
+``t`` of slot ``s`` lives in ring page ``1 + s * ring + (t // page_size) %
+ring``, row ``t % page_size``, so the page a slot writes next is the one
+whose tokens have all fallen behind the window (**recycled**, counted by
+``recycled_pages``), whatever the slot's length. No table, no allocation,
+no free: the ring is the slot's, as a slot-state row is; a call that
+writes at most ``page_size`` tokens a slot before it attends (the engine
+holds ``prefill_chunk`` to that) never writes over a token a query of the
+same call still reads. A window layer so holds at most ``window +
+page_size`` tokens of a slot where the page divides the window (``ring *
+page_size`` in general), and admission reckons with the full layers
+alone: ``can_reserve`` / ``reserve`` stay all-or-nothing over both kinds
+because the window kind can never refuse. Never shared, copied on write,
+published, spilled or shipped: a pool with window layers has prefix
+sharing off (a borrower would need the window layers' last tokens of the
+prefix). ``bytes_per_page`` is a page row of the full layers;
+``capacity_bytes`` / ``live_bytes`` count both kinds.
+
 Tensor parallel (ISSUE 15): pass ``mesh=`` (a mesh with a ``tp`` axis
 of size > 1) and the page pool becomes **per-shard**: the K/V page
 arrays are placed sharded over ``tp`` on the folded HEAD axis (each mesh
@@ -122,8 +147,20 @@ class PagedCacheConfig:
     #: (row 0 the null row), beside the page pools and not paged
     slot_state: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
     slot_state_dtype: object = jnp.float32
+    #: a layer's kind of attention, one entry a layer: None (pages of the
+    #: shared pool, every token) or a window (a ring of pages a slot);
+    #: empty: every layer is full
+    layer_windows: Tuple[Optional[int], ...] = ()
 
     def __post_init__(self):
+        if self.layer_windows:
+            if len(self.layer_windows) != self.num_layers:
+                raise ValueError("layer_windows names every layer or none")
+            if self.quantized or self.share_prefix:
+                raise ValueError(
+                    "a pool with window layers is not quantized and shares "
+                    "no prefixes yet: a borrower would need the window "
+                    "layers' last tokens of the prefix")
         if self.page_size < 1 or self.num_pages < 2:
             raise ValueError("need page_size >= 1 and num_pages >= 2 "
                              "(page 0 is the reserved null page)")
@@ -155,6 +192,28 @@ class PagedCacheConfig:
 
     def pages_for(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
+
+    def window_of(self, layer: int) -> Optional[int]:
+        return self.layer_windows[layer] if self.layer_windows else None
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, w in enumerate(self.layer_windows)
+                     if w is not None)
+
+    @property
+    def window_layer_counts(self) -> Dict[int, int]:
+        """``{window: layers of that window}`` over the window layers."""
+        counts: Dict[int, int] = {}
+        for i in self.window_layers:
+            w = self.layer_windows[i]
+            counts[w] = counts.get(w, 0) + 1
+        return counts
+
+    def ring_pages(self, window: int) -> int:
+        """Pages of a window layer's ring a slot: the window's own and
+        one more, the page being written."""
+        return self.pages_for(window) + 1
 
 
 class PageOverflowError(RuntimeError):
@@ -402,18 +461,25 @@ class PagedKVCache:
                  jnp.zeros(sshape, jnp.float32))
                 for _ in range(c.num_layers)]
         else:
+            def kv_shape(layer):
+                w = c.window_of(layer)
+                return shape if w is None else (
+                    c.num_slots * c.ring_pages(w) + 1,) + shape[1:]
+
             self.pages: List[Tuple[jnp.ndarray, ...]] = [
-                (jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype),
+                (jnp.zeros(kv_shape(i), c.dtype),
+                 jnp.zeros(kv_shape(i), c.dtype),
                  *(jnp.zeros((c.num_pages, width, c.page_size), c.dtype)
                    for _name, width in c.extra_rows),
                  *(jnp.zeros((c.num_slots + 1,) + tuple(shape),
                              c.slot_state_dtype)
                    for _name, shape in c.slot_state))
-                for _ in range(c.num_layers)]
+                for i in range(c.num_layers)]
         if self.mesh is not None:
-            if c.extra_rows or c.slot_state:
+            if c.extra_rows or c.slot_state or c.layer_windows:
                 raise ValueError("a tp-sharded pool carries no extra "
-                                 "rows and no slot state yet")
+                                 "rows, no slot state and no window "
+                                 "layers yet")
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
             kv_s = NamedSharding(self.mesh, P(None, None, "tp"))
@@ -422,6 +488,9 @@ class PagedKVCache:
                 tuple(jax.device_put(a, kv_s if i < 2 else rep)
                       for i, a in enumerate(ent))
                 for ent in self.pages]
+        #: the window layers, fixed with the pools: a pool without any (every
+        #: program the engine ran before them) pays nothing for the kind
+        self._window_layers = frozenset(c.window_layers)
         self.block_tables = np.zeros((c.num_slots, c.max_pages_per_slot),
                                      np.int32)
         self.lengths = np.zeros((c.num_slots,), np.int32)
@@ -491,10 +560,44 @@ class PagedKVCache:
         pools (+ scale rows when quantized) — global bytes under tp
         sharding (each shard holds its head slice of the same page)."""
         total = 0
-        for layer in self.pages:
-            for arr in layer[:self.config.paged_entries]:
-                total += arr.nbytes
+        for i, layer in enumerate(self.pages):
+            if i not in self._window_layers:        # a ring is no page row
+                for arr in layer[:self.config.paged_entries]:
+                    total += arr.nbytes
         return total // self.config.num_pages
+
+    def window_bytes_per_slot(self) -> int:
+        """Bytes of the window layers' rings one slot holds (0 for a
+        pool without window layers)."""
+        c = self.config
+        return sum(arr.nbytes // arr.shape[0] * c.ring_pages(c.window_of(i))
+                   for i in self._window_layers
+                   for arr in self.pages[i][:c.paged_entries])
+
+    def window_tokens_held(self, slot: int, layer: int) -> int:
+        """Tokens of ``slot`` that window layer ``layer`` still holds."""
+        c = self.config
+        return min(int(self.lengths[slot]),
+                   c.ring_pages(c.window_of(layer)) * c.page_size)
+
+    def window_page(self, slot: int, layer: int, logical_page: int) -> int:
+        """The ring page of window layer ``layer`` that holds page
+        ``logical_page`` of ``slot``'s sequence (while it is held)."""
+        ring = self.config.ring_pages(self.config.window_of(layer))
+        return 1 + slot * ring + logical_page % ring
+
+    def recycled_pages(self, before, after) -> int:
+        """Ring pages written over, summed over the window layers, as
+        slots advance from ``before`` to ``after`` tokens (arrays, one
+        entry a slot): a page is recycled when the slot enters a page of
+        its sequence past the ring's first lap."""
+        c = self.config
+        before, after = (-(-np.asarray(a, np.int64) // c.page_size)
+                         for a in (before, after))
+        return int(sum(
+            n * (np.maximum(after - c.ring_pages(w), 0)
+                 - np.maximum(before - c.ring_pages(w), 0)).sum()
+            for w, n in c.window_layer_counts.items()))
 
     def state_bytes_per_slot(self) -> int:
         """Bytes of slot state one slot holds across every layer (0 for
@@ -504,14 +607,23 @@ class PagedKVCache:
             // (self.config.num_slots + 1)
 
     def capacity_bytes(self) -> int:
-        """HBM bytes of the allocatable pool (null page excluded)."""
-        return self.bytes_per_page() * (self.config.num_pages - 1)
+        """HBM bytes of the allocatable pool (null page excluded) and of
+        every slot's window rings."""
+        full = self.bytes_per_page() * (self.config.num_pages - 1)
+        if not self._window_layers:
+            return full
+        return full + self.window_bytes_per_slot() * self.config.num_slots
 
     def live_bytes(self) -> int:
         """HBM bytes committed to allocated pages right now (page
         granularity — reservations count the moment they are made,
-        which is what admission headroom must see)."""
-        return self.bytes_per_page() * self.pages_in_use
+        which is what admission headroom must see), and the window rings
+        of the slots that hold a reservation."""
+        live = self.bytes_per_page() * self.pages_in_use
+        if not self._window_layers:
+            return live
+        return live + self.window_bytes_per_slot() * sum(
+            1 for sp in self._slot_pages if sp)
 
     def _alloc_page(self) -> int:
         if self._free:
@@ -923,6 +1035,24 @@ class PagedKVCache:
             assert pid in self._page_tokens, "published page lost tokens"
         for owned, sp in zip(self._owned, self._slot_pages):
             assert owned <= set(sp), "owned page not mapped"
+        for i in c.window_layers:
+            ring = c.ring_pages(c.window_of(i))
+            assert self.pages[i][0].shape[0] == c.num_slots * ring + 1, \
+                "a window layer's pool is not a ring a slot"
+            for slot in range(c.num_slots):
+                held = self.window_tokens_held(slot, i)
+                assert held <= ring * c.page_size, \
+                    "a window layer holds more than its ring"
+                # the pages that hold the slot's window are distinct
+                # pages of the slot's own ring
+                last = max(int(self.lengths[slot]) - 1, 0) // c.page_size
+                first = max(int(self.lengths[slot]) - c.window_of(i), 0) \
+                    // c.page_size
+                mine = [self.window_page(slot, i, p)
+                        for p in range(first, last + 1)]
+                assert len(set(mine)) == len(mine) and all(
+                    slot * ring < p <= (slot + 1) * ring for p in mine), \
+                    "a window's pages collide or leave the slot's ring"
         if self.spill_pool is not None:
             spilled = self.spill_pool.keys()
             assert len(self.spill_pool) <= self.spill_pool.capacity, \

@@ -55,6 +55,17 @@ zeros ``(S, C, width)`` float32, one for each entry, hands it to ``ffn``
 as a fifth argument, takes it back as a third result, and drops it after
 the last layer. It is neither cached nor state: it lives for one pass.
 
+A program whose layers are not all of one kind of attention declares
+``spec.layer_windows``, one entry a layer: None where a token attends to
+every token before it, ``w`` where it attends to the last ``w`` (itself
+counted: ``t - w < s <= t``). The cache then keeps a window layer's K and
+V in a ring of pages a slot (:mod:`~paddle_tpu.serving.paged_cache`: the
+pages behind the window are written over as the slot advances, whatever
+its length), the loops address it by the slot and the position alone (no
+table) and hand the window to the paged kernels, and the program's own
+functions stay as above: ``attn_in`` of layer ``i`` returns that layer's
+rows whatever its kind.
+
 What a program cannot do yet it leaves out of ``spec.supports``; the
 engine refuses, by name, an option that needs it.
 
@@ -113,9 +124,21 @@ class ServingSpec:
     #: arrays a token carries from layer to layer beside the residual
     #: stream, ``(name, width)`` each, float32, through ``ffn``
     layer_carry: Tuple[Tuple[str, int], ...] = ()
+    #: a layer's kind of attention, one entry a layer: None (every token
+    #: before) or the window, the tokens a query attends to with itself
+    #: counted; empty: every layer is full
+    layer_windows: Tuple[Optional[int], ...] = ()
     supports: FrozenSet[str] = FEATURES
 
     def __post_init__(self):
+        if self.layer_windows and all(w is None for w in self.layer_windows):
+            object.__setattr__(self, "layer_windows", ())    # all full
+        if self.layer_windows and (
+                len(self.layer_windows) != self.num_layers or any(
+                    w is not None and w < 1 for w in self.layer_windows)):
+            raise ValueError(
+                f"layer_windows={self.layer_windows!r}: one entry a layer "
+                f"({self.num_layers}), None or a window of at least 1")
         if self.slot_state_reader not in ("mixer", "attn_in"):
             raise ValueError(
                 f"slot_state_reader={self.slot_state_reader!r}: the engine "

@@ -633,6 +633,80 @@ def test_latent_family_steps_compile_and_keep_the_pools(
             rf"f32\[{ZY_SLOTS + 1},128\]": "{1,0"}, 269 << 20)
 
 
+# -- window and full attention layers + an expert share at the mixed cell's geometry
+
+KX_SLOTS, KX_PS, KX_PAGES, KX_CHUNK = 128, 128, 3073, 128
+
+
+@pytest.fixture(scope="module")
+def k_exaone_steps(topo):
+    """The window / full family at its published widths and the cell's
+    five layers (window, window, window, full, window; 16 of 128 experts
+    held), on the mixed cell's pools: 3073 pages of the full layer, a
+    ring of 2 pages a slot (257 pages) for each window layer."""
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import inference
+    from paddle_tpu.models.window_moe_lm import (WindowMoELM,
+                                                 WindowMoELMConfig)
+    model = WindowMoELM(WindowMoELMConfig(
+        num_hidden_layers=5, vocab_size=19200, num_experts=16,
+        num_routed_experts=128, kernel_impl="pallas"))
+    params = jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    eng = inference.make_serving_engine(
+        model, params, num_slots=2, page_size=KX_PS, num_pages=9,
+        max_tokens_per_slot=9216, prefill_chunk=KX_CHUNK, decode_block=8,
+        attn_impl="pallas", cache_dtype=jnp.bfloat16)
+    dev = SingleDeviceSharding(topo.devices[0])
+    sds = jax.ShapeDtypeStruct
+    weights = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, sharding=dev), params)
+    c = eng.cache.config
+    pages = [tuple(sds(((KX_PAGES if c.window_of(i) is None else
+                         KX_SLOTS * c.ring_pages(c.window_of(i)) + 1),)
+                       + a.shape[1:], a.dtype, sharding=dev) for a in ent)
+             for i, ent in enumerate(eng.cache.pages)]
+
+    def i32(*shape):
+        return sds(shape, jnp.int32, sharding=dev)
+
+    @functools.lru_cache(maxsize=None)
+    def lower(step, lanes, width):
+        if step == "decode":
+            return eng.decode_step.lower(
+                weights, pages, i32(lanes, width), i32(lanes), i32(lanes),
+                i32(lanes)).compile()
+        # a prefill lane's table carries its slot in one more column
+        return eng.prefill_step.lower(
+            weights, pages, i32(lanes, width + 1), i32(lanes),
+            i32(lanes, KX_CHUNK), i32(lanes)).compile()
+
+    return lower
+
+
+@pytest.mark.parametrize("step, lanes, width, kernels_in", [
+    ("decode", KX_SLOTS, 72, {"ragged_paged_decode", "moe_grouped_ffn"}),
+    ("prefill", 16, 64, {"ragged_paged_prefill", "moe_grouped_ffn"})],
+    ids=["decode-w72", "prefill-16lanes-w64"])
+def test_window_family_steps_compile_and_keep_the_pools(
+        step, lanes, width, kernels_in, k_exaone_steps):
+    """The five-layer decode block and prefill step compile for the chip
+    at the mixed cell's geometry: 64 query heads over 8 KV heads (a page
+    row of 1024 lanes, a group of 8: the widest the dense kernels run),
+    the window layers' calls over tables of 2 and 3 ring pages beside the
+    full layer's 72 and 64, 16 lanes of 128 queries at one page a grid
+    step, the grouped expert kernel at 16 held experts of 2048 x 6144 in
+    128-wide hidden blocks. No step copies the full layer's pool or a
+    ring pool; each comes in row-major; temporaries stay under 640 MB
+    (the expert layer's rows of a 2048-token call: every pair may land
+    on the held experts, so the table is sized for all of them)."""
+    _assert_step_keeps_its_pools(
+        k_exaone_steps(step, lanes, width), kernels_in, {
+            rf"bf16\[{KX_PAGES},{KX_PS},1024\]": "{2,1,0",
+            rf"bf16\[{KX_SLOTS * 2 + 1},{KX_PS},1024\]": "{2,1,0"},
+        640 << 20)
+
+
 # -- the named scopes of PR 38 leave every name a metric selects as it was ------
 
 def _kernel_counts(text):

@@ -330,26 +330,43 @@ class TestEngineAnatomy:
         """Prefill-heavy traffic (long prompts, 1 new token) moves the
         phase split toward prefill; decode-heavy traffic (short prompt,
         long generation) moves it toward decode — the anatomy must make
-        the two regimes distinguishable from the totals alone."""
+        the two regimes distinguishable from its records alone. Held by
+        what the records count (the steps a phase took part in, and the
+        tokens the phases' counters saw), not by two sums of wall-clock
+        time: under a loaded host one pause in either phase outweighs
+        the few milliseconds this tiny engine computes."""
         rng = np.random.default_rng(2)
-        base = dict(eng.anatomy.summary()["phase_s"])
 
-        def delta(prev):
-            cur = eng.anatomy.summary()["phase_s"]
-            return {p: cur.get(p, 0.0) - prev.get(p, 0.0) for p in cur}
+        def mark():
+            snap = eng._reg.snapshot()
+            return (eng.anatomy.summary()["steps"],
+                    snap.get("serving_prefill_tokens_total", 0.0),
+                    snap.get("serving_tokens_total", 0.0))
 
+        def since(before):
+            steps, pre_tok, dec_tok = (a - b for a, b in
+                                       zip(mark(), before))
+            recs = eng.anatomy.records()[-int(steps):]
+            took_part = {ph: sum(1 for r in recs if r["phases"].get(ph, 0) > 0)
+                         for ph in ("prefill", "decode")}
+            assert all(s >= 0.0 for r in recs for s in r["phases"].values())
+            return took_part, pre_tok, dec_tok
+
+        before = mark()
         long_prompts = [rng.integers(1, VOCAB, 12).astype(np.int32)
                         for _ in range(4)]
         eng.generate_many(long_prompts, 1, eos_id=None)
-        d_pre = delta(base)
-        assert d_pre["prefill"] > d_pre.get("decode", 0.0)
+        steps, pre_tok, dec_tok = since(before)
+        assert steps["prefill"] > steps["decode"]
+        assert pre_tok == 4 * 12 and pre_tok > dec_tok
 
-        base2 = dict(eng.anatomy.summary()["phase_s"])
+        before = mark()
         short = [rng.integers(1, VOCAB, 4).astype(np.int32)
                  for _ in range(2)]
         eng.generate_many(short, 12, eos_id=None)
-        d_dec = delta(base2)
-        assert d_dec["decode"] > d_dec.get("prefill", 0.0)
+        steps, pre_tok, dec_tok = since(before)
+        assert steps["decode"] > steps["prefill"]
+        assert dec_tok == 2 * 12 and dec_tok > pre_tok
 
     def test_phase_seconds_are_the_call_wall_time_of_the_phase_spans(
             self, model_params):
