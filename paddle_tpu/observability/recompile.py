@@ -54,10 +54,28 @@ _count = 0
 #: is not held at all. A serving engine lifts the budget
 #: (:func:`hold_step_programs`): its step programs are 10-60 MB of code
 #: each and loaded for as long as it serves, so holding them costs
-#: nothing until it is dropped, and a trace of its steps is read after
+#: nothing until it is dropped, and a trace of its steps is read after.
+#: A trainer's step program is told apart from the one-off programs
+#: around it where it is traced (:func:`expect_step_program`) and never
+#: makes room for one of those: the BERT cell's evaluation program is
+#: within a megabyte of the budget on either side from one PR to the
+#: next (33.9 MB, then 32.9: PERF.md section 6, PR 41) and, once under
+#: it, pushed the 29 MB step program out and stayed loaded itself.
 _RECENT = 64
 _HELD_CODE_BYTES = 32 << 20
 _held_code_bytes: Optional[int] = _HELD_CODE_BYTES
+
+
+_step_traced = False
+
+
+def expect_step_program() -> None:
+    """The program being traced is a step program: the next programs
+    the listener meets are held in preference to any other (called by
+    ``train.build_train_step``'s step while it is traced; nothing runs
+    a step)."""
+    global _step_traced
+    _step_traced = True
 
 
 def hold_step_programs() -> None:
@@ -81,6 +99,7 @@ class LoadedProgram:
     handle: Any
     seq: int
     code_bytes: int = 0     # its generated code, as loaded on the device
+    step: bool = False      # traced from a train step: the last to go
 
     @property
     def module(self) -> str:
@@ -106,14 +125,17 @@ def _look(collect: bool = False) -> List[LoadedProgram]:
         live = jax.extend.backend.get_backend().live_executables()
     except Exception:       # telemetry must never take a compile down
         return []
+    global _step_traced
     out = []
     with _lock:
         seqs = {}
+        step = _step_traced
         for handle in live:
             seq = _seen.get(id(handle))
             if seq is None:
                 seq = next(_seq)
-                _hold(LoadedProgram(handle, seq, _code_bytes(handle)))
+                _hold(LoadedProgram(handle, seq, _code_bytes(handle), step))
+                _step_traced = False    # the trace's programs are met
             seqs[id(handle)] = seq
             if collect:
                 out.append(LoadedProgram(handle, seq))
@@ -134,14 +156,16 @@ def _code_bytes(handle) -> int:
 
 def _hold(rec: LoadedProgram) -> None:
     """``rec`` into ``_recent``, the oldest out until the held programs'
-    code fits the budget, where there is one (with ``_lock`` held)."""
+    code fits the budget, where there is one (with ``_lock`` held):
+    the programs that are no step's first, ``rec`` among them, so a
+    one-off program never pushes a step program out."""
     budget = _held_code_bytes
     if budget is not None and rec.code_bytes > budget:
         return
     _recent.append(rec)
     while budget is not None \
             and sum(r.code_bytes for r in _recent) > budget:
-        _recent.popleft()
+        _recent.remove(next((r for r in _recent if not r.step), _recent[0]))
 
 
 def loaded_programs() -> List[LoadedProgram]:

@@ -11,14 +11,22 @@ Layout convention: (batch, num_heads, seq, head_dim) — "BHSD".
 
 Dispatch: :func:`dot_product_attention` picks the Pallas kernel on TPU and
 the XLA-composed path elsewhere (CPU tests run the kernel in interpret
-mode). Both forward and backward are Pallas kernels (FlashAttention-2
-style: the backward recomputes p from the forward's logsumexp in two
-kernels, dkv and dq); full (Sq,Sk) biases fall back to the XLA backward so
-trainable position biases get gradients.
+mode). The forward and the backward are ONE Pallas kernel each. The
+backward recomputes p from the forward's logsumexp once a block pair and
+takes dV, dK and dQ from it: five products. A body emits only the work
+the call's static shape needs (see "Pallas flash-attention kernels"
+below): keys in one block take a whole softmax and not an online one, a
+head in one block pair writes its gradients without scratch, masks and
+iotas exist only where a sequence does not divide into its blocks or the
+call is causal, and the matrix unit takes the operands in the dtype they
+come in. ``flash_attention_lowerings_total`` counts which body a trace
+took. Full (Sq,Sk) biases fall back to the XLA backward so trainable
+position biases get gradients.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Optional
@@ -28,6 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.observability import registry as _obs_registry
 from paddle_tpu.ops.nn import keep_mask
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps softmax NaN-free
@@ -122,7 +131,7 @@ def _lax_flash_fwd(q, k, v, bias=None, *, scale=None, causal=False,
 def _lax_flash_block_bwd(q, k, v, bias, out, lse, g, *, scale, causal):
     """XLA-composed FlashAttention-2 block backward against a GLOBAL
     logsumexp: recompute p = exp(s - lse), then ds = p(dp - delta)scale.
-    Mirrors :func:`_flash_bwd`'s two Pallas kernels, so ring attention's
+    Mirrors :func:`_flash_bwd`'s Pallas kernel, so ring attention's
     backward merge is backend-independent (grads accumulate across ring
     blocks against the merged forward's lse on either path)."""
     s = _masked_scores(q, k, bias, scale=scale, causal=causal)
@@ -140,12 +149,173 @@ def _lax_flash_block_bwd(q, k, v, bias, out, lse, g, *, scale, causal):
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash-attention forward kernel
+# Pallas flash-attention kernels
 # ---------------------------------------------------------------------------
+#
+# One algorithm, four bodies. What picks a body, and every mask inside
+# it, is static when the call is traced: the sequence lengths against
+# the blocks, ``causal``, the bias mode and the operands' dtype. A body
+# holds only the work its shape needs:
+#
+# - the matrix unit takes q, k, v and dO in the dtype they come in
+#   (bf16 under the trainer's policy) and accumulates in float32; p and
+#   dS are rounded to that dtype for their products, as the composed
+#   path does. Scores, softmax statistics and accumulators are float32.
+# - the softmax scale is folded into q, a (bq, Dh) multiply where the
+#   scores would take a (bq, bk) one.
+# - key rows are zeroed and columns masked only where ``sk % bk != 0``,
+#   query rows only where ``sq % bq != 0``, and an iota exists only
+#   where one of those or ``causal`` needs it.
+# - rows that must add nothing to a gradient (every key masked, or past
+#   the end of q) get p = 0 through their logsumexp, on a (bq, 1)
+#   column: exp(s - 1e30) is 0.0 exactly.
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                      m_scr, l_scr, acc_scr, *,
-                      scale, causal, block_q, block_k, seq_q, seq_k):
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+_LOWERINGS = _obs_registry.counter(
+    "flash_attention_lowerings_total",
+    "flash kernel bodies chosen, by pass, body and masks; counted where "
+    "the shape decides, at trace time")
+
+
+def _dot(a, b, dims):
+    """float32 accumulation of operands as they are. Operands narrower
+    than float32 are what the matrix unit takes whole, so they say so:
+    under a process-wide ``highest`` (the tests' conftest) Mosaic would
+    refuse them; float32 operands follow the process's default."""
+    narrow = a.dtype.itemsize < 4
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.DEFAULT if narrow else None)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Shape:
+    """What a flash body may branch on: all of it static."""
+    seq_q: int
+    seq_k: int
+    block_q: int
+    block_k: int
+    causal: bool
+
+    @classmethod
+    def of(cls, q, k, block_q, block_k, causal):
+        sq, sk = q.shape[2], k.shape[2]
+        return cls(sq, sk, min(block_q, sq), min(block_k, sk), bool(causal))
+
+    @property
+    def nq(self):
+        return pl.cdiv(self.seq_q, self.block_q)
+
+    @property
+    def nk(self):
+        return pl.cdiv(self.seq_k, self.block_k)
+
+    @property
+    def ragged_q(self):
+        return self.seq_q % self.block_q != 0
+
+    @property
+    def ragged_k(self):
+        return self.seq_k % self.block_k != 0
+
+    @property
+    def masks(self):
+        if self.causal:
+            return "causal"
+        return "ragged" if self.ragged_q or self.ragged_k else "none"
+
+    def below_diagonal(self, qi, ki):
+        """False for a key block wholly above the causal diagonal."""
+        return (ki * self.block_k <= qi * self.block_q + (self.block_q - 1)
+                + (self.seq_k - self.seq_q))
+
+    def count(self, which, single):
+        _LOWERINGS.inc(**{"pass": which, "masks": self.masks,
+                          "body": "single_block" if single else "blocked"})
+
+
+def _valid(block, seq, i):
+    """(block, 1) bool: which rows of block ``i`` lie inside ``seq``."""
+    return i * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, 1), 0) < seq
+
+
+def _scaled(q, scale):
+    """The scale folded into q, in float32 and rounded once to q's own
+    dtype (exact for a power of two: 1/8 at a head width of 64)."""
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
+def _keys(k_ref, v_ref, sh, ki):
+    """k and v as they lie; rows past ``seq_k`` zeroed (a block past the
+    end is padded with garbage, and 0 * NaN would poison p @ v)."""
+    k, v = k_ref[0], v_ref[0]
+    if sh.ragged_k:
+        valid = _valid(sh.block_k, sh.seq_k, ki)
+        k = jnp.where(valid, k, jnp.zeros_like(k))
+        v = jnp.where(valid, v, jnp.zeros_like(v))
+    return k, v
+
+
+def _bias_block(bias_ref, bias_mode):
+    """float32 (1, bk) for a key bias (row 0 of its sublane-padded
+    block), (bq, bk) for a full one, None for none."""
+    if bias_mode is None:
+        return None
+    blk = bias_ref[0][0:1, :] if bias_mode == "key" else bias_ref[0]
+    return blk.astype(jnp.float32)
+
+
+def _scores(q, k, bias, sh, qi, ki):
+    """float32 (bq, bk) scores of one block pair; ``q`` carries the
+    scale. Masked to NEG_INF above the causal diagonal and past
+    ``seq_k``, where the shape has either."""
+    s = _dot(q, k, _NT)
+    if bias is not None:
+        s = s + bias
+    if sh.causal or sh.ragged_k:
+        shape = (sh.block_q, sh.block_k)
+        col = ki * sh.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        keep = col < sh.seq_k if sh.ragged_k else None
+        if sh.causal:
+            row = qi * sh.block_q + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 0)
+            seen = col <= row + (sh.seq_k - sh.seq_q)
+            keep = seen if keep is None else keep & seen
+        s = jnp.where(keep, s, NEG_INF)
+    return s
+
+
+def _write_rows(o_ref, lse_ref, acc, m, l):
+    """acc / l and the logsumexp, from (bq, 1) columns m and l. A row
+    with every key masked has m ~ NEG_INF and p = 1 a column, the
+    uniform mean of v: it is zeroed, so that the forward agrees with the
+    backward, which drops such a row by the same test of its lse."""
+    alive = m > NEG_INF / 2
+    o_ref[0] = jnp.where(alive, acc / l, 0.0).astype(o_ref.dtype)
+    if lse_ref is not None:
+        lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
+
+
+def _flash_fwd_single_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
+                             *, sh, scale, bias_mode):
+    """Grid (BH, nq, 1): the keys are ONE block, so the softmax is
+    whole: max, exp, sum, one product, one divide. No scratch."""
+    qi = pl.program_id(1)
+    k, v = _keys(k_ref, v_ref, sh, 0)
+    s = _scores(_scaled(q_ref[0], scale), k,
+                _bias_block(bias_ref, bias_mode), sh, qi, 0)
+    m = jnp.max(s, axis=1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=1, keepdims=True)       # >= 1: the max is in it
+    _write_rows(o_ref, lse_ref, _dot(p.astype(v.dtype), v, _NN), m, l)
+
+
+def _flash_fwd_blocked_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
+                              m_scr, l_scr, acc_scr, *, sh, scale, bias_mode):
     """Grid (BH, nq, nk); online-softmax accumulation over kv blocks.
 
     Scratch: m (bq,128) running max, l (bq,128) running denom (values
@@ -153,7 +323,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -162,62 +331,29 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _body():
-        q = q_ref[0].astype(jnp.float32)           # (bq, D)
-        k = k_ref[0].astype(jnp.float32)           # (bk, D)
-        # zero padded kv rows (pallas pads out-of-bounds blocks with
-        # garbage/NaN; 0*NaN would poison the p@v contraction)
-        kv_valid = (ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)) < seq_k
-        k = jnp.where(kv_valid, k, 0.0)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
-        if bias_ref is not None:
-            s = s + bias_ref[0].astype(jnp.float32)
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        if causal:
-            s = jnp.where(col <= row + (seq_k - seq_q), s, NEG_INF)
-        # mask out padding blocks past the true seq end (grid is padded up)
-        s = jnp.where(col < seq_k, s, NEG_INF)
-
+        k, v = _keys(k_ref, v_ref, sh, ki)
+        s = _scores(_scaled(q_ref[0], scale), k,
+                    _bias_block(bias_ref, bias_mode), sh, qi, ki)
         m_prev = m_scr[...]                        # (bq, 128)
-        l_prev = l_scr[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
-        m_next = jnp.maximum(m_prev, m_cur)        # broadcast over lanes
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)           # (bq, 128)
         p = jnp.exp(s - m_next[:, :1])             # (bq, bk)
-        l_next = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_scr[...] = m_next
-        l_scr[...] = l_next
-        v = jnp.where(kv_valid, v_ref[0].astype(jnp.float32), 0.0)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # (bq, D)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + pv
+        acc_scr[...] = (acc_scr[...] * alpha[:, :1]
+                        + _dot(p.astype(v.dtype), v, _NN))
 
-    if causal:
-        # skip kv blocks fully above the diagonal
-        below = ki * block_k <= qi * block_q + (block_q - 1) + (seq_k - seq_q)
-        pl.when(below)(_body)
+    if sh.causal:
+        pl.when(sh.below_diagonal(qi, ki))(_body)
     else:
         _body()
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == sh.nk - 1)
     def _finish():
-        denom = l_scr[...][:, :1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        # fully-masked rows (every key at NEG_INF bias): m never rises above
-        # ~NEG_INF, p=exp(s-m)=1 and the naive result would be a uniform mean
-        # of v. Zero them so the forward matches the backward, which drops
-        # those rows' cotangents via the same lse <= NEG_INF/2 test.
-        alive = m_scr[...][:, :1] > NEG_INF / 2
-        o_ref[0] = jnp.where(alive, acc_scr[...] / denom, 0.0).astype(
-            o_ref.dtype)
-        if lse_ref is not None:  # logsumexp row stats for the backward
-            lse_ref[0, 0] = (m_scr[...][:, 0] + jnp.log(denom[:, 0]))
+        l = l_scr[...][:, :1]
+        # a row whose every block was skipped has summed nothing
+        _write_rows(o_ref, lse_ref, acc_scr[...], m_scr[...][:, :1],
+                    jnp.where(l == 0.0, 1.0, l))
 
 
 def _prep_bias(bias, b, h, sq, sk):
@@ -233,43 +369,56 @@ def _prep_bias(bias, b, h, sq, sk):
     return "full", bias.reshape(bh, sq, sk)
 
 
+def _bias_operand(bias, q, k, sh, key_map, full_map):
+    """(mode, specs, arrays) of a call's bias operand: nothing for no
+    bias, else its block under the index map of its mode."""
+    if bias is None:
+        return None, [], []
+    b, h, sq, _ = q.shape
+    mode, br = _prep_bias(bias, b, h, sq, k.shape[2])
+    spec = (pl.BlockSpec((1, 8, sh.block_k), key_map) if mode == "key"
+            else pl.BlockSpec((1, sh.block_q, sh.block_k), full_map))
+    return mode, [spec], [br]
+
+
+def _optional_refs(body, n_in, has_bias, n_out):
+    """pallas hands a kernel its refs in one flat list: ``n_in`` inputs,
+    the bias block if the call has one, ``n_out`` outputs of the
+    ``len(n_out)`` the body names (a False is handed over as None), then
+    the scratch."""
+    def kernel(*refs):
+        refs = list(refs)
+        ins = [refs.pop(0) for _ in range(n_in)]
+        bias_ref = refs.pop(0) if has_bias else None
+        outs = [refs.pop(0) if there else None for there in n_out]
+        body(*ins, bias_ref, *outs, *refs)
+    return kernel
+
+
 def _flash_fwd(q, k, v, bias, *, scale, causal, block_q, block_k, interpret,
                return_lse=False):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    nq = pl.cdiv(sq, bq)
-    nk = pl.cdiv(sk, bk)
+    sh = _Shape.of(q, k, block_q, block_k, causal)
+    bq, bk = sh.block_q, sh.block_k
+    single = sh.nk == 1
+    sh.count("fwd", single)
     bh = b * h
-    qr = q.reshape(bh, sq, d)
-    kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, d)
 
+    bias_mode, bias_specs, bias_args = _bias_operand(
+        bias, q, k, sh, lambda g, i, j: (g, 0, j), lambda g, i, j: (g, i, j))
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
         pl.BlockSpec((1, bk, d), lambda g, i, j: (g, j, 0)),
         pl.BlockSpec((1, bk, d), lambda g, i, j: (g, j, 0)),
-    ]
-    args = [qr, kr, vr]
-    if bias is not None:
-        bias_mode, br = _prep_bias(bias, b, h, sq, sk)
-        if bias_mode == "key":
-            in_specs.append(
-                pl.BlockSpec((1, 8, bk), lambda g, i, j: (g, 0, j)))
-        else:
-            in_specs.append(
-                pl.BlockSpec((1, bq, bk), lambda g, i, j: (g, i, j)))
-        args.append(br)
-    else:
-        bias_mode = None
+        *bias_specs]
+    args = [q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, d),
+            *bias_args]
 
-    kernel = functools.partial(
-        _flash_kernel_dispatch, bias_mode=bias_mode, with_lse=return_lse,
-        scale=scale, causal=causal, block_q=bq, block_k=bk,
-        seq_q=sq, seq_k=sk)
-
-    scratch = [
+    body = functools.partial(
+        _flash_fwd_single_kernel if single else _flash_fwd_blocked_kernel,
+        sh=sh, scale=scale, bias_mode=bias_mode)
+    scratch = [] if single else [
         pltpu.VMEM((bq, 128), jnp.float32),
         pltpu.VMEM((bq, 128), jnp.float32),
         pltpu.VMEM((bq, d), jnp.float32),
@@ -283,10 +432,9 @@ def _flash_fwd(q, k, v, bias, *, scale, causal, block_q, block_k, interpret,
                      pl.BlockSpec((1, 1, bq), lambda g, i, j: (g, 0, i))]
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32)]
-    grid = (bh, nq, nk)
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
+        _optional_refs(body, 3, bias_mode is not None, (True, return_lse)),
+        grid=(bh, sh.nq, sh.nk),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -302,294 +450,161 @@ def _flash_fwd(q, k, v, bias, *, scale, causal, block_q, block_k, interpret,
     return out.reshape(b, h, sq, d)
 
 
-
-
-def _flash_kernel_dispatch(*refs, bias_mode, with_lse, **kw):
-    refs = list(refs)
-    q_ref, k_ref, v_ref = refs[:3]
-    i = 3
-    b_ref = None
-    if bias_mode is not None:
-        b_ref = refs[i]
-        i += 1
-        if bias_mode == "key":
-            b_ref = _KeyBias(b_ref)
-    o_ref = refs[i]
-    i += 1
-    lse_ref = refs[i] if with_lse else None
-    if with_lse:
-        i += 1
-    m, l, acc = refs[i:]
-    _flash_fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
-                      m, l, acc, **kw)
-
-
-class _KeyBias:
-    """Adapts a (1, 8, bk) key-bias block to the (bq, bk) read the kernel
-    does: row 0 broadcast over queries."""
-
-    def __init__(self, ref):
-        self._ref = ref
-
-    def __getitem__(self, idx):
-        return self._ref[0][0:1, :]  # (1, bk), broadcasts against (bq, bk)
-
-    def astype(self, dt):  # pragma: no cover - not used
-        raise TypeError
-
-
 # ---------------------------------------------------------------------------
-# Pallas flash-attention backward (FlashAttention-2 style two-kernel split)
+# Pallas flash-attention backward: ONE kernel, five products a block pair
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q, k, bias_blk, lse, ki, qi, *, scale, causal,
-                 block_q, block_k, seq_q, seq_k):
-    """Recompute the probability block p = exp(s - lse) with the SAME
-    masking as the forward (so p matches bit-for-bit up to fp assoc)."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if bias_blk is not None:
-        s = s + bias_blk
-    row = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    col = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    if causal:
-        s = jnp.where(col <= row + (seq_k - seq_q), s, NEG_INF)
-    s = jnp.where(col < seq_k, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
-    # zero padded q rows (their lse/do are garbage)
-    p = jnp.where(row < seq_q, p, 0.0)
-    # fully-masked rows: lse sits at ~NEG_INF (log-denominator cancelled by
-    # fp rounding), so exp(s - lse) would come out 1 per column instead of
-    # 1/seq_k — inflating dk/dv for every key by seq_k. Zero such rows.
-    p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0, p)
-    return p
+def _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
+              sh, scale, bias_mode, qi, ki):
+    """One block pair of the backward against a given logsumexp: p
+    ONCE (the forward's masking, so p matches it up to fp association),
+    dP = dO V^T ONCE, dS = p (dP - delta), and the three products they
+    feed. Returns this pair's float32 (dq, dk, dv)."""
+    q = _scaled(q_ref[0], scale)
+    do = do_ref[0]
+    k, v = _keys(k_ref, v_ref, sh, ki)
+    bias = _bias_block(bias_ref, bias_mode)
+    lse = lse_ref[0, 0][:, None]                   # (bq, 1)
+    delta = delta_ref[0, 0][:, None]
+    # a row with every key masked has lse ~ NEG_INF (the log-denominator
+    # lost to rounding): exp(s - lse) would be 1 a column, not 1/seq_k,
+    # inflating dk and dv of every key. Such a row gets p = 0.
+    dead = lse <= NEG_INF / 2
+    if sh.ragged_q:                # rows past seq_q hold garbage
+        valid = _valid(sh.block_q, sh.seq_q, qi)
+        q = jnp.where(valid, q, jnp.zeros_like(q))
+        do = jnp.where(valid, do, jnp.zeros_like(do))
+        delta = jnp.where(valid, delta, 0.0)
+        if bias_mode == "full":
+            bias = jnp.where(valid, bias, 0.0)
+        dead = dead | ~valid
+    lse = jnp.where(dead, -NEG_INF, lse)
+    p = jnp.exp(_scores(q, k, bias, sh, qi, ki) - lse)
+    dp = _dot(do, v, _NT)
+    ds = (p * (dp - delta)).astype(q.dtype)
+    dv = _dot(p.astype(do.dtype), do, _TN)
+    dk = _dot(ds, q, _TN)                          # q carries the scale
+    dq = _dot(ds, k, _NN) * scale
+    return dq, dk, dv
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          bias_ref, dk_ref, dv_ref,
-                          dk_scr, dv_scr, *,
-                          scale, causal, block_q, block_k, seq_q, seq_k):
-    """Grid (BH, nk, nq): for a fixed kv block, stream q blocks and
-    accumulate dk = sum ds^T q, dv = sum p^T do."""
+def _flash_bwd_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                             bias_ref, dq_ref, dk_ref, dv_ref,
+                             *, sh, scale, bias_mode):
+    """Grid (BH, 1, 1): a head's sequence is one block pair, so nothing
+    accumulates: the three gradients go straight to the outputs."""
+    dq, dk, dv = _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                           bias_ref, sh, scale, bias_mode, 0, 0)
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _flash_bwd_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                              delta_ref, bias_ref, dq_ref, dk_ref, dv_ref,
+                              dq_scr, dk_scr, dv_scr,
+                              *, sh, scale, bias_mode):
+    """Grid (BH, nk, nq): for a fixed kv block, stream the q blocks and
+    accumulate dk += ds^T q, dv += p^T do in (bk, D) float32 scratch,
+    and dq += ds k in a float32 scratch that holds dq for the WHOLE
+    sequence (nq * bq, D) and is written out at a head's last step."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    rows = pl.ds(pl.multiple_of(qi * sh.block_q, sh.block_q), sh.block_q)
+
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_scr[rows, :] = jnp.zeros((sh.block_q, dq_scr.shape[1]),
+                                    jnp.float32)
 
     @pl.when(qi == 0)
-    def _init():
+    def _init_dkv():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def _body():
-        row_valid = (qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)) < seq_q
-        kv_valid = (ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)) < seq_k
-        q = jnp.where(row_valid, q_ref[0].astype(jnp.float32), 0.0)
-        k = jnp.where(kv_valid, k_ref[0].astype(jnp.float32), 0.0)
-        v = jnp.where(kv_valid, v_ref[0].astype(jnp.float32), 0.0)
-        do = jnp.where(row_valid, do_ref[0].astype(jnp.float32), 0.0)
-        lse = jnp.where(row_valid[:, 0], lse_ref[0, 0], 0.0)
-        delta = jnp.where(row_valid[:, 0], delta_ref[0, 0], 0.0)
-        bias_blk = (bias_ref[0].astype(jnp.float32)
-                    if bias_ref is not None else None)
+        dq, dk, dv = _bwd_pair(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                               delta_ref, bias_ref, sh, scale, bias_mode,
+                               qi, ki)
+        dq_scr[rows, :] += dq
+        dk_scr[...] += dk
+        dv_scr[...] += dv
 
-        p = _recompute_p(q, k, bias_blk, lse, ki, qi, scale=scale,
-                         causal=causal, block_q=block_q, block_k=block_k,
-                         seq_q=seq_q, seq_k=seq_k)
-        # dv += p^T do   (contract over q rows)
-        dv_scr[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dp = do v^T ; ds = p * (dp - delta) * scale
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        # dk += ds^T q
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        below = ki * block_k <= qi * block_q + (block_q - 1) + (seq_k - seq_q)
-        pl.when(below)(_body)
+    if sh.causal:
+        pl.when(sh.below_diagonal(qi, ki))(_body)
     else:
         _body()
 
-    @pl.when(qi == nq - 1)
-    def _finish():
+    @pl.when(qi == sh.nq - 1)
+    def _finish_dkv():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
-
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         bias_ref, dq_ref, dq_scr, *,
-                         scale, causal, block_q, block_k, seq_q, seq_k):
-    """Grid (BH, nq, nk): for a fixed q block, stream kv blocks and
-    accumulate dq = sum ds k."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    def _body():
-        row_valid = (qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)) < seq_q
-        kv_valid = (ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)) < seq_k
-        q = jnp.where(row_valid, q_ref[0].astype(jnp.float32), 0.0)
-        k = jnp.where(kv_valid, k_ref[0].astype(jnp.float32), 0.0)
-        v = jnp.where(kv_valid, v_ref[0].astype(jnp.float32), 0.0)
-        do = jnp.where(row_valid, do_ref[0].astype(jnp.float32), 0.0)
-        lse = jnp.where(row_valid[:, 0], lse_ref[0, 0], 0.0)
-        delta = jnp.where(row_valid[:, 0], delta_ref[0, 0], 0.0)
-        bias_blk = (bias_ref[0].astype(jnp.float32)
-                    if bias_ref is not None else None)
-
-        p = _recompute_p(q, k, bias_blk, lse, ki, qi, scale=scale,
-                         causal=causal, block_q=block_q, block_k=block_k,
-                         seq_q=seq_q, seq_k=seq_k)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        below = ki * block_k <= qi * block_q + (block_q - 1) + (seq_k - seq_q)
-        pl.when(below)(_body)
-    else:
-        _body()
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+    @pl.when((qi == sh.nq - 1) & (ki == sh.nk - 1))
+    def _finish_dq():
+        dq_ref[0] = dq_scr[0:sh.seq_q, :].astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, bias, out, lse, g, *, scale, causal,
                block_q, block_k, interpret):
-    """Pallas backward: returns (dq, dk, dv). Bias grads are not computed
-    here (callers with trainable biases use the XLA path)."""
+    """Pallas backward against the given ``lse`` and ``out`` (ring
+    attention hands the merged forward's): returns (dq, dk, dv). Bias
+    grads are not computed here (callers with trainable biases use the
+    XLA path)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    nq = pl.cdiv(sq, bq)
-    nk = pl.cdiv(sk, bk)
+    sh = _Shape.of(q, k, block_q, block_k, causal)
+    bq, bk = sh.block_q, sh.block_k
+    single = sh.nq == 1 and sh.nk == 1
+    sh.count("bwd", single)
     bh = b * h
-    qr = q.reshape(bh, sq, d)
-    kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, d)
-    dor = g.reshape(bh, sq, d)
-    lser = lse.reshape(bh, 1, sq)
-    # delta = rowsum(do * o) — cheap elementwise, let XLA fuse it
+    # delta = rowsum(do * o) stays XLA's: its fusion reads ``out`` in
+    # whatever layout the caller keeps it, where a kernel operand would
+    # cost a copy of it a layer (PERF.md section 6, PR 41)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, sq)
+    bias_mode, bias_specs, bias_args = _bias_operand(
+        bias, q, k, sh, lambda g_, i, j: (g_, 0, i),
+        lambda g_, i, j: (g_, j, i))
+    args = [q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, d),
+            g.reshape(bh, sq, d), lse.reshape(bh, 1, sq), delta, *bias_args]
 
-    bias_mode = None
-    bias_args = []
-    if bias is not None:
-        bias_mode, br = _prep_bias(bias, b, h, sq, sk)
-        bias_args = [br]
+    # grid (bh, nk, nq): i = kv block, j = q block
+    q_rows = pl.BlockSpec((1, bq, d), lambda g_, i, j: (g_, j, 0))
+    k_rows = pl.BlockSpec((1, bk, d), lambda g_, i, j: (g_, i, 0))
+    q_stat = pl.BlockSpec((1, 1, bq), lambda g_, i, j: (g_, 0, j))
+    in_specs = [q_rows, k_rows, k_rows, q_rows, q_stat, q_stat, *bias_specs]
 
-    def bias_spec(for_dkv):
-        if bias_mode == "key":
-            return [pl.BlockSpec((1, 8, bk),
-                                 (lambda g_, i, j: (g_, 0, i)) if for_dkv
-                                 else (lambda g_, i, j: (g_, 0, j)))]
-        if bias_mode == "full":
-            return [pl.BlockSpec((1, bq, bk),
-                                 (lambda g_, i, j: (g_, j, i)) if for_dkv
-                                 else (lambda g_, i, j: (g_, i, j)))]
-        return []
-
-    common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
-                  seq_q=sq, seq_k=sk)
-    cparams = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    ) if not interpret else None
-
-    # dk/dv: grid (bh, nk, nq) — i = kv block, j = q block
-    dkv_kernel = functools.partial(
-        _bwd_dispatch, which="dkv", has_bias=bias_mode is not None,
-        mode=bias_mode, **common)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda g_, i, j: (g_, j, 0)),   # q
-            pl.BlockSpec((1, bk, d), lambda g_, i, j: (g_, i, 0)),   # k
-            pl.BlockSpec((1, bk, d), lambda g_, i, j: (g_, i, 0)),   # v
-            pl.BlockSpec((1, bq, d), lambda g_, i, j: (g_, j, 0)),   # do
-            pl.BlockSpec((1, 1, bq), lambda g_, i, j: (g_, 0, j)),   # lse
-            pl.BlockSpec((1, 1, bq), lambda g_, i, j: (g_, 0, j)),   # delta
-            *bias_spec(for_dkv=True),
-        ],
+    body = functools.partial(
+        _flash_bwd_single_kernel if single else _flash_bwd_blocked_kernel,
+        sh=sh, scale=scale, bias_mode=bias_mode)
+    scratch = [] if single else [
+        pltpu.VMEM((sh.nq * bq, d), jnp.float32),
+        pltpu.VMEM((bk, d), jnp.float32),
+        pltpu.VMEM((bk, d), jnp.float32),
+    ]
+    dq, dk, dv = pl.pallas_call(
+        _optional_refs(body, 6, bias_mode is not None, (True,) * 3),
+        grid=(bh, sh.nk, sh.nq),
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda g_, i, j: (g_, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda g_, i, j: (g_, i, 0)),
+            # dq for the whole sequence: resident while a head's blocks run
+            pl.BlockSpec((1, sq, d), lambda g_, i, j: (g_, 0, 0)),
+            k_rows, k_rows,
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), q.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), q.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=cparams,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        ) if not interpret else None,
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, delta, *bias_args)
-
-    dq_kernel = functools.partial(
-        _bwd_dispatch, which="dq", has_bias=bias_mode is not None,
-        mode=bias_mode, **common)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda g_, i, j: (g_, i, 0)),   # q
-            pl.BlockSpec((1, bk, d), lambda g_, i, j: (g_, j, 0)),   # k
-            pl.BlockSpec((1, bk, d), lambda g_, i, j: (g_, j, 0)),   # v
-            pl.BlockSpec((1, bq, d), lambda g_, i, j: (g_, i, 0)),   # do
-            pl.BlockSpec((1, 1, bq), lambda g_, i, j: (g_, 0, i)),   # lse
-            pl.BlockSpec((1, 1, bq), lambda g_, i, j: (g_, 0, i)),   # delta
-            *bias_spec(for_dkv=False),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda g_, i, j: (g_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=cparams,
-        interpret=interpret,
-    )(qr, kr, vr, dor, lser, delta, *bias_args)
-
-    shape4 = (b, h, sq, d)
-    return (dq.reshape(shape4), dk.reshape(b, h, sk, d),
+    )(*args)
+    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
-
-
-def _bwd_dispatch(*refs, which, has_bias, mode, **kw):
-    refs = list(refs)
-    ins, rest = refs[:6], refs[6:]
-    if has_bias:
-        b_ref, rest = rest[0], rest[1:]
-        if mode == "key":
-            b_ref = _KeyBias(b_ref)
-    else:
-        b_ref = None
-    if which == "dkv":
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
-        _flash_bwd_dkv_kernel(*ins, b_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                              **kw)
-    else:
-        dq_ref, dq_scr = rest
-        _flash_bwd_dq_kernel(*ins, b_ref, dq_ref, dq_scr, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -604,8 +619,8 @@ def flash_attention(q, k, v, bias=None, causal=False,
     """Flash attention (Pallas fwd + bwd). q,k,v: (B,H,S,D); bias additive,
     broadcastable to (B,H,Sq,Sk).
 
-    Backward: FlashAttention-2-style Pallas kernels (dkv + dq, recomputing
-    p from the forward's logsumexp). Key-padding biases (Sq dim == 1) are
+    Backward: one Pallas kernel (:func:`_flash_bwd`, recomputing p from
+    the forward's logsumexp). Key-padding biases (Sq dim == 1) are
     treated as constants (zero cotangent); full (Sq,Sk) biases take the
     XLA recompute path so trainable relative-position biases get grads."""
     if scale is None:
@@ -763,11 +778,26 @@ def _flash_tune_signature(args, kwargs):
 
 
 def _flash_vmem_estimate(args, kwargs, blocks):
-    d = args[0].shape[-1]
-    bq = blocks.get("block_q", 512)
-    bk = blocks.get("block_k", 512)
-    # fp32 working set: q + acc, k + v, s + p, m/l lane scratch
-    return 4 * (2 * bq * d + 2 * bk * d + 2 * bq * bk + 2 * bq * 128)
+    """The backward's working set, the larger of the two passes': s, p,
+    dP and dS in float32 and their two roundings for the products; the
+    operand blocks (q, dO; k, v) and dk's and dv's, (block, Dh) each and
+    twice for the pipeline's two buffers; dq for the whole sequence as
+    an output, twice, and where a head is more than one block pair once
+    more as float32 scratch beside dk's and dv's. A row is 128 lanes at
+    least."""
+    q, k = args[0], args[1]
+    sh = _Shape.of(q, k, blocks.get("block_q", 512),
+                   blocks.get("block_k", 512), False)
+    bq, bk = sh.block_q, sh.block_k
+    row = max(q.shape[-1], 128)
+    item = jnp.dtype(q.dtype).itemsize
+    scores = (4 * 4 + 2 * item) * bq * bk
+    moved = 2 * item * row * (2 * bq + 4 * bk)
+    if sh.nq == 1 and sh.nk == 1:
+        return scores + moved + 2 * item * row * bq
+    rows_q = sh.nq * bq
+    return scores + moved + 2 * item * row * rows_q + 4 * row * (rows_q
+                                                                  + 2 * bk)
 
 
 def _register_flash_kernel():
@@ -780,8 +810,10 @@ def _register_flash_kernel():
                          "v": "(B,H,Sk,D)",
                          "bias": "(B,H,Sq,Sk) additive, optional"},
             out_layout="(B,H,Sq,D)",
-            grid="(B*H, cdiv(Sq,block_q), cdiv(Sk,block_k)) "
-                 "kv-arbitrary online softmax",
+            grid="fwd (B*H, nq, nk): online softmax over kv blocks, a "
+                 "whole softmax where nk == 1; bwd (B*H, nk, nq): one "
+                 "kernel, dk/dv a kv block and dq a whole sequence in "
+                 "float32 scratch, none where nq == nk == 1",
             block_candidates={"block_q": (512, 256, 128),
                               "block_k": (512, 256, 128)},
             atol=2e-5, rtol=2e-5),

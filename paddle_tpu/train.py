@@ -21,7 +21,8 @@ import jax.numpy as jnp
 
 from paddle_tpu.core import dtypes
 from paddle_tpu.nn.module import apply_state_updates, capture_state
-from paddle_tpu.observability.recompile import install_compile_listener
+from paddle_tpu.observability.recompile import (expect_step_program,
+                                                install_compile_listener)
 
 #: the ``jax.named_scope`` names :func:`build_train_step` opens: the
 #: phases ``observability.scopes`` splits a step's device time by (the
@@ -106,6 +107,7 @@ def build_train_step(
         return loss, updates, aux, grads
 
     def step(state, **batch):
+        expect_step_program()       # while traced: nothing runs a step
         if grad_accum_steps > 1:
             loss, updates, aux, grads = accum_step(state, batch)
         else:

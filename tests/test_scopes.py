@@ -207,6 +207,55 @@ def test_held_programs_fit_a_budget_of_device_code(monkeypatch):
     assert [r.seq for r in recompile._recent] == [2, 4, 5]
 
 
+def test_a_one_off_program_never_pushes_a_step_program_out(monkeypatch):
+    """The BERT cell's order of programs: set-up, the train step, then an
+    evaluation program just under the budget, run once and dropped. The
+    step program is the one whose table is asked for after the run."""
+    import collections
+    monkeypatch.setattr(recompile, "_recent", collections.deque(maxlen=64))
+    monkeypatch.setattr(recompile, "_held_code_bytes",
+                        recompile._HELD_CODE_BYTES)
+    mb = 1 << 20
+    for seq, (size, step) in enumerate(
+            [(10 * mb, False), (29 * mb, True), (31 * mb, False),
+             (2 * mb, False)], 1):
+        recompile._hold(recompile.LoadedProgram(object(), seq, size, step))
+    assert [r.seq for r in recompile._recent] == [2, 4]
+    # among step programs the oldest goes first
+    recompile._hold(recompile.LoadedProgram(object(), 5, 20 * mb, True))
+    assert [r.seq for r in recompile._recent] == [5]
+
+
+def test_a_traced_train_step_marks_the_program_compiled_from_it():
+    """In a process of its own: this one's catalogue is shared with every
+    test that ran before, and its ids are reused as their programs die."""
+    import os
+    import subprocess
+    import sys
+    script = """
+import jax, jax.numpy as jnp
+from paddle_tpu import optimizer as opt
+from paddle_tpu.observability import recompile
+from paddle_tpu.train import build_train_step
+sgd = opt.SGD(0.1)
+step = build_train_step(lambda params: jnp.sum(params * params), sgd)
+params = jnp.ones((2,))
+state = {"params": params, "opt": sgd.init(params),
+         "step": jnp.zeros((), jnp.int32)}
+compiled = jax.jit(step).lower(state).compile()
+other = jax.jit(lambda x: x * 2.0 + 1.0).lower(params).compile()
+marks = {id(r.handle): r.step for r in recompile._recent}
+print("MARKS", marks[id(compiled.runtime_executable())],
+      marks[id(other.runtime_executable())])
+"""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True,
+        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                          "PYTHONPATH": root})
+    assert "MARKS True False" in out.stdout, out.stdout + out.stderr[-2000:]
+
+
 def test_no_text_is_produced_unless_a_table_is_asked_for(monkeypatch):
     """Compiling, running and checking for recompiles read no HLO text:
     only ``scopes.tables()`` does, once a program."""
