@@ -32,6 +32,7 @@ from paddle_tpu.models.hybrid_ssm_lm import HybridSSMLM, HybridSSMLMConfig
 from paddle_tpu.models.latent_conv_moe_lm import (LatentConvMoELM,
                                                   LatentConvMoELMConfig)
 from paddle_tpu.models.window_moe_lm import WindowMoELM, WindowMoELMConfig
+from paddle_tpu.models.mla_moe_lm import MLAMoELM, MLAMoELMConfig
 
 __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "ResNet", "ResNet50", "DeepFM", "Transformer",
@@ -41,4 +42,4 @@ __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "SEResNeXt50", "AlexNet", "DarkNet53", "DenseNet121", "GoogLeNet", "ShuffleNetV2", "SqueezeNet", "SSD", "SSDConfig", "FasterRCNN", "FasterRCNNConfig", "MaskRCNN", "C3D", "TSN", "YOLOv3", "YOLOv3Config", "CRNN", "DCGANGenerator", "DCGANDiscriminator", "gan_step",
            "SparseMoELM", "SparseMoELMConfig", "HybridSSMLM",
            "HybridSSMLMConfig", "LatentConvMoELM", "LatentConvMoELMConfig",
-           "WindowMoELM", "WindowMoELMConfig"]
+           "WindowMoELM", "WindowMoELMConfig", "MLAMoELM", "MLAMoELMConfig"]

@@ -28,7 +28,7 @@ import collections
 import dataclasses
 import itertools
 import threading
-from typing import Any, Callable, Deque, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from paddle_tpu.observability import registry as _registry
 
@@ -86,7 +86,8 @@ def hold_step_programs() -> None:
     install_compile_listener()
     _held_code_bytes = None
 _recent: Deque["LoadedProgram"] = collections.deque(maxlen=_RECENT)
-_seen: Dict[int, int] = {}      # id(handle) -> seq, of the LIVE programs
+#: (id(handle), its fingerprint) -> seq, of the LIVE programs
+_seen: Dict[Tuple[int, Any], int] = {}
 _tables: Dict[int, Any] = {}    # seq -> scope table, of the live programs
 _seq = itertools.count(1)
 
@@ -116,10 +117,15 @@ class LoadedProgram:
 
 def _look(collect: bool = False) -> List[LoadedProgram]:
     """Look at what the backend lists: programs not seen before go into
-    ``_recent``; what died is forgotten (an ``id`` may come back as
-    another program's: a handle is the backend's own object, the same
-    one every call, so ``id`` is a key only while its program lives).
-    With ``collect`` -> a record a live program."""
+    ``_recent``; what died is forgotten. A handle is the backend's own
+    object, the same one every call, and takes no weak reference; its
+    ``id`` may come back as another program's BEFORE a look has noticed
+    the death (programs die and compile between two looks), so a program
+    is keyed by its ``id`` and its fingerprint together: an ``id`` that
+    comes back under another fingerprint is a program not seen before,
+    and under the same one the same program compiled again, whose table
+    is the dead one's word for word. With ``collect`` -> a record a live
+    program."""
     try:
         import jax.extend.backend
         live = jax.extend.backend.get_backend().live_executables()
@@ -131,12 +137,13 @@ def _look(collect: bool = False) -> List[LoadedProgram]:
         seqs = {}
         step = _step_traced
         for handle in live:
-            seq = _seen.get(id(handle))
+            key = (id(handle), _fingerprint(handle))
+            seq = _seen.get(key)
             if seq is None:
                 seq = next(_seq)
                 _hold(LoadedProgram(handle, seq, _code_bytes(handle), step))
                 _step_traced = False    # the trace's programs are met
-            seqs[id(handle)] = seq
+            seqs[key] = seq
             if collect:
                 out.append(LoadedProgram(handle, seq))
         _seen.clear()
@@ -144,6 +151,13 @@ def _look(collect: bool = False) -> List[LoadedProgram]:
         for gone in set(_tables).difference(seqs.values()):
             del _tables[gone]
     return out
+
+
+def _fingerprint(handle) -> Any:
+    try:
+        return handle.fingerprint
+    except Exception:       # a backend that gives none: the id alone
+        return None
 
 
 def _code_bytes(handle) -> int:

@@ -1175,6 +1175,368 @@ def _paged_prefill_int8_pallas(q, k_pages, v_pages, k_scales, v_scales,
 # public entry points
 # ---------------------------------------------------------------------------
 
+# ---------------------------------------------------------------------------
+# latent rows: every head reads ONE cached row a token, values are part of it
+# ---------------------------------------------------------------------------
+#
+# Multi-head latent attention in its absorbed form: a token caches one
+# row ``[c (Dl) | k_rope (Dr)]`` a layer, every head's query ``(Dl + Dr)``
+# scores against that row, and what a head sums under its softmax weights
+# is the row's first ``Dl`` values (the up-projections are folded into the
+# queries and into the output projection by the model). The pool keeps the
+# two parts where each is read without a relayout and in whole tiles:
+#
+#   c_pages  (P, ps, Dl)   token-major, the shape the dense pools have:
+#                          ``q_c c^T`` contracts the lanes, ``P c`` the rows
+#   r_pages  (P, Dr, ps)   the rotary key with the tokens along the lanes
+#                          (as ``extra_rows`` are kept): ``q_r k_rope^T``
+#                          is a plain product, and 64 rows of 128 tokens
+#                          are whole tiles where ``(ps, 64)`` would be half
+#
+# so a row is ``(Dl + Dr) * itemsize`` bytes and nothing is padded. The
+# page of ``c`` is read ONCE for both products. Queries come already
+# scaled. Decode walks a slot's live pages itself, as dense decode does
+# (:func:`_paged_decode_walk_kernel`): one grid step a slot, two VMEM
+# buffers of ``pages_per_block`` pages, a block one softmax update for all
+# heads. Chunked prefill stacks ``q_rows`` (heads x queries) of a lane as
+# the rows of one matrix and streams the lane's pages past it through the
+# ``BlockSpec`` pipeline, grid ``(S, query tiles, page blocks)``.
+
+def _latent_gather(c_pages, r_pages, block_tables):
+    """A slot batch's rows out of the two pools, token-major:
+    ``(S, mp*ps, Dl)`` and ``(S, mp*ps, Dr)`` float32 (lax path only)."""
+    s, mp = block_tables.shape
+    cg = c_pages[block_tables].astype(jnp.float32)         # (S,mp,ps,Dl)
+    rg = r_pages[block_tables].astype(jnp.float32)         # (S,mp,Dr,ps)
+    return (cg.reshape(s, mp * cg.shape[2], -1),
+            rg.transpose(0, 1, 3, 2).reshape(s, mp * cg.shape[2], -1))
+
+
+def _latent_softmax(scores, ok):
+    """Masked float32 softmax over the last axis; a row with nothing to
+    attend to gives zeros."""
+    scores = jnp.where(ok, scores, NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1)
+    alive = jnp.max(scores, axis=-1, keepdims=True) > NEG_INF / 2
+    return jnp.where(alive, p, 0.0)
+
+
+def _latent_decode_lax(q, c_pages, r_pages, block_tables, lengths):
+    dl = c_pages.shape[-1]
+    cg, rg = _latent_gather(c_pages, r_pages, block_tables)
+    qf = q.astype(jnp.float32)
+    scores = jnp.einsum("shd,std->sht", qf[..., :dl], cg,
+                        precision=_FP32_DOT) \
+        + jnp.einsum("shd,std->sht", qf[..., dl:], rg, precision=_FP32_DOT)
+    tok = jnp.arange(cg.shape[1], dtype=jnp.int32)
+    p = _latent_softmax(scores, tok[None, None, :] < lengths[:, None, None])
+    return jnp.einsum("sht,std->shd", p, cg,
+                      precision=_FP32_DOT).astype(q.dtype)
+
+
+def _latent_prefill_lax(q, c_pages, r_pages, block_tables, chunk_starts,
+                        n_valid):
+    dl, c = c_pages.shape[-1], q.shape[1]
+    cg, rg = _latent_gather(c_pages, r_pages, block_tables)
+    qf = q.astype(jnp.float32)
+    scores = jnp.einsum("schd,std->shct", qf[..., :dl], cg,
+                        precision=_FP32_DOT) \
+        + jnp.einsum("schd,std->shct", qf[..., dl:], rg, precision=_FP32_DOT)
+    tok = jnp.arange(cg.shape[1], dtype=jnp.int32)
+    pos = chunk_starts[:, None] + jnp.arange(c, dtype=jnp.int32)  # (S, C)
+    ok = (tok[None, None, None, :] <= pos[:, None, :, None]) \
+        & (jnp.arange(c) < n_valid[:, None])[:, None, :, None]
+    p = _latent_softmax(scores, ok)
+    return jnp.einsum("shct,std->schd", p, cg,
+                      precision=_FP32_DOT).astype(q.dtype)
+
+
+def _pool_dot(x, page, contract_page_dim):
+    """``x (rows, .)`` times a page block with both operands in the
+    pool's type, float32 out: one pass over a bf16 pool (``x`` ROUNDED to
+    it, where :func:`_all_heads_page_dot` would split it into three
+    terms), ``HIGHEST`` over a float32 one (the CPU tests)."""
+    return _all_heads_page_dot(x.astype(page.dtype), page,
+                               contract_page_dim)
+
+
+def _latent_decode_walk_kernel(bt_ref, len_ref, qc_ref, qr_ref, c_hbm, r_hbm,
+                               o_ref, c_buf, r_buf, sems, first_buf, m_scr,
+                               l_scr, acc_scr, *, page_size,
+                               pages_per_block):
+    """The latent decode body: grid ``(S,)``, one step a slot, laid out as
+    :func:`_paged_decode_walk_kernel` is. ``qc_ref`` ``(1, rows, Dl)`` and
+    ``qr_ref`` ``(1, rows, Dr)`` are the slot's absorbed queries, a head a
+    row; ``c_hbm`` / ``r_hbm`` the whole pools in HBM; ``c_buf`` ``(2,
+    pb*ps, Dl)`` and ``r_buf`` ``(2, Dr, pb*ps)`` the two blocks in VMEM,
+    a page's rotary keys landing in the lanes of its tokens; ``sems``
+    ``(2, 2)`` one DMA semaphore a (pool, buffer). A block is ONE update:
+    ``S = q_c C^T + q_r R``, one maximum, one exponential, ``A += P C``
+    with the ``C`` the scores were taken from."""
+    ps, pb = page_size, pages_per_block
+    sl = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    rows = qc_ref.shape[1]
+    dr = r_buf.shape[1]
+
+    def live_pages(slot):
+        return (len_ref[slot] + ps - 1) // ps
+
+    extent = len_ref[sl]
+    n_live = live_pages(sl)
+    n_blocks = (n_live + pb - 1) // pb
+
+    def copies(slot, block, buf, start):
+        """Start, or wait for, the copies of one block of a slot: its live
+        page ``block*pb + t`` out of each pool into place ``t`` of that
+        pool's buffer ``buf``."""
+        n = live_pages(slot)
+        for t in range(pb):
+            p = block * pb + t
+            page = bt_ref[slot, jnp.minimum(p, bt_ref.shape[1] - 1)]
+
+            @pl.when(p < n)
+            def _live_page():
+                for i, (src, dst) in enumerate((
+                        (c_hbm.at[page],
+                         c_buf.at[buf, pl.ds(t * ps, ps)]),
+                        (r_hbm.at[page],
+                         r_buf.at[buf, pl.ds(0, dr), pl.ds(t * ps, ps)]))):
+                    copy = pltpu.make_async_copy(src, dst, sems.at[i, buf])
+                    copy.start() if start else copy.wait()
+
+    @pl.when(sl == 0)
+    def _first_slot():
+        first_buf[0] = 0
+
+    @pl.when((sl == 0) | (live_pages(jnp.maximum(sl - 1, 0)) == 0))
+    def _own_first_block():
+        copies(sl, 0, first_buf[0], start=True)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def fold(block, buf, n_pages):
+        width = n_pages * ps
+        c = c_buf[buf, :width]                               # (width, Dl)
+        s = _all_heads_page_dot(qc_ref[0], c, 1) \
+            + _all_heads_page_dot(qr_ref[0], r_buf[buf, :, :width], 0)
+        tok = block * (pb * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, width), 1)
+        s = jnp.where(tok < extent, s, NEG_INF)
+        m = m_scr[...]
+        m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_next)                          # (rows, 128)
+        p = jnp.exp(s - m_next[:, :1])                       # (rows, width)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[...] = m_next
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] \
+            + _all_heads_page_dot(p, c, 0)                   # (rows, Dl)
+
+    def walk(block, _):
+        buf = (first_buf[0] + block) % 2
+        last = block == n_blocks - 1
+        nxt_slot = jnp.where(last, jnp.minimum(sl + 1, n_slots - 1), sl)
+
+        @pl.when(~last | (sl + 1 < n_slots))
+        def _next_block():
+            copies(nxt_slot, jnp.where(last, 0, block + 1), 1 - buf,
+                   start=True)
+
+        copies(sl, block, buf, start=False)
+        here = jnp.minimum(n_live - block * pb, pb)
+        for n_pages in range(1, pb + 1):
+            pl.when(here == n_pages)(
+                functools.partial(fold, block, buf, n_pages))
+
+    jax.lax.fori_loop(0, n_blocks, walk, None)
+    first_buf[0] = (first_buf[0] + n_blocks) % 2
+    denom = l_scr[...][:, :1]
+    denom = jnp.where(denom == 0.0, 1.0, denom)
+    alive = m_scr[...][:, :1] > NEG_INF / 2
+    o_ref[0] = jnp.where(alive, acc_scr[...] / denom, 0.0).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _latent_decode_pallas(q, c_pages, r_pages, block_tables, lengths,
+                          interpret, pages_per_block):
+    """The ``pallas_call`` of ``latent_paged_decode``."""
+    s_slots, h, _ = q.shape
+    ps, dl = c_pages.shape[1:]
+    dr = r_pages.shape[1]
+    # the body copies a page out of each pool as it lies, which the chip's
+    # compiler does only where a page is whole tiles
+    if not interpret and (dl % 128 or ps % 128
+                          or dr % (32 // r_pages.dtype.itemsize)):
+        raise ValueError(
+            f"latent_paged_decode copies whole pages out of the pools: "
+            f"pages of {ps} tokens, rows of {dl} + {dr} are not whole tiles")
+    pb = max(1, min(int(pages_per_block), block_tables.shape[1]))
+    rows = h + -h % _HEAD_ROWS
+    q = q.astype(c_pages.dtype)
+    if rows != h:
+        q = jnp.pad(q, ((0, 0), (0, rows - h), (0, 0)))
+
+    def slot_block(width):
+        return pl.BlockSpec((1, rows, width), lambda s, *_prefetch: (s, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s_slots,),
+        in_specs=[slot_block(dl), slot_block(dr),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=slot_block(dl),
+        scratch_shapes=[
+            pltpu.VMEM((2, pb * ps, dl), c_pages.dtype),
+            pltpu.VMEM((2, dr, pb * ps), r_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, dl), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_decode_walk_kernel, page_size=ps,
+                          pages_per_block=pb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_slots, rows, dl), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)) if not interpret else None,
+        interpret=interpret,
+        name="latent_paged_decode",
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      q[..., :dl], q[..., dl:], c_pages, r_pages)
+    return out[:, :h]
+
+
+def _latent_prefill_kernel(bt_ref, start_ref, nv_ref, qc_ref, qr_ref, *rest,
+                           page_size, pages_per_block, n_heads):
+    """The latent chunked-prefill body: grid ``(S, query tiles, page
+    blocks)``. A tile's rows are ``n_heads`` rows a query, queries in
+    order (row ``r`` is query ``r // n_heads`` of the tile); ``rest``:
+    ``pb`` pages of ``c``, ``pb`` of the rotary keys, the output tile,
+    the ``m / l / acc`` state of the tile. Row ``r`` attends causally to
+    ``tok <= chunk_starts[s] + query``, rows of queries past ``n_valid``
+    give zeros, and a block wholly past the tile's last live query does
+    nothing (its page operands stay where they were)."""
+    ps, pb = page_size, pages_per_block
+    c_refs, r_refs = rest[:pb], rest[pb:2 * pb]
+    o_ref, m_scr, l_scr, acc_scr = rest[2 * pb:]
+    sl, qi, pj = (pl.program_id(a) for a in range(3))
+    rows = qc_ref.shape[1]
+    per_tile = rows // n_heads
+
+    @pl.when(pj == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    start, nv = start_ref[sl], nv_ref[sl]
+    first = qi * per_tile                       # the tile's first query
+    # tokens the tile's last live query can see
+    extent = start + jnp.minimum(first + per_tile, nv)
+    has_work = (first < nv) & (pj * pb * ps < extent)
+
+    @pl.when(has_work)
+    def _body():
+        shape = (rows, ps)
+        query = first + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0) // n_heads
+        for t in range(pb):
+            tok = (pj * pb + t) * ps + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 1)
+            ok = (tok <= start + query) & (query < nv)
+            c = c_refs[t][0]                                 # (ps, Dl)
+            s = _pool_dot(qc_ref[0], c, 1) \
+                + _pool_dot(qr_ref[0], r_refs[t][0], 0)      # (rows, ps)
+            s = jnp.where(ok, s, NEG_INF)
+            m = m_scr[...]
+            m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_next)
+            p = jnp.exp(s - m_next[:, :1])
+            l_scr[...] = l_scr[...] * alpha \
+                + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[...] = m_next
+            acc_scr[...] = acc_scr[...] * alpha[:, :1] + _pool_dot(p, c, 0)
+
+    @pl.when(pj == pl.num_programs(2) - 1)
+    def _finish():
+        denom = l_scr[...][:, :1]
+        denom = jnp.where(denom == 0.0, 1.0, denom)
+        alive = m_scr[...][:, :1] > NEG_INF / 2
+        o_ref[0] = jnp.where(alive, acc_scr[...] / denom,
+                             0.0).astype(o_ref.dtype)
+
+
+def _latent_queries_a_tile(c, h, q_rows):
+    """Queries a tile of at most ``q_rows`` rows holds: the most that
+    divide the chunk."""
+    return max(n for n in range(1, c + 1)
+               if c % n == 0 and (n * h <= q_rows or n == 1))
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _latent_prefill_pallas(q, c_pages, r_pages, block_tables, chunk_starts,
+                           n_valid, interpret, pages_per_block, q_rows):
+    """The ``pallas_call`` of ``latent_paged_prefill``: ``q`` (S, C, H, Dl
+    + Dr) goes in as ``(S, C*H, .)``, a query's heads side by side, which
+    is the order it comes in: no transpose either way."""
+    s_slots, c, h, _ = q.shape
+    mp = block_tables.shape[1]
+    ps, dl = c_pages.shape[1:]
+    dr = r_pages.shape[1]
+    pb = max(1, min(int(pages_per_block), mp))
+    per_tile = _latent_queries_a_tile(c, h, q_rows)
+    rows = per_tile * h
+    q = q.astype(c_pages.dtype).reshape(s_slots, c * h, dl + dr)
+
+    def tile_block(width):
+        return pl.BlockSpec((1, rows, width),
+                            lambda s, i, j, *_prefetch: (s, i, 0))
+
+    def page_spec(t, block):
+        def page(s, i, j, bt, starts, nv):
+            # the last page the tile's last live query sees: later blocks
+            # repeat it and move nothing
+            seen = starts[s] + jnp.clip(
+                jnp.minimum((i + 1) * per_tile, nv[s]), 1, None)
+            last = jnp.minimum((seen - 1) // ps, mp - 1)
+            return bt[s, jnp.minimum(j * pb + t, last)]
+        return pl.BlockSpec(block, lambda *a: (page(*a), 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s_slots, c // per_tile, pl.cdiv(mp, pb)),
+        in_specs=[tile_block(dl), tile_block(dr),
+                  *(page_spec(t, (1, ps, dl)) for t in range(pb)),
+                  *(page_spec(t, (1, dr, ps)) for t in range(pb))],
+        out_specs=tile_block(dl),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, dl), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, page_size=ps,
+                          pages_per_block=pb, n_heads=h),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_slots, c * h, dl), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_WIDE_VMEM_LIMIT) if not interpret else None,
+        interpret=interpret,
+        name="latent_paged_prefill",
+    )(block_tables.astype(jnp.int32), chunk_starts.astype(jnp.int32),
+      n_valid.astype(jnp.int32), q[..., :dl], q[..., dl:],
+      *([c_pages] * pb), *([r_pages] * pb))
+    return out.reshape(s_slots, c, h, dl)
+
+
 def ragged_paged_decode_attention(q, k_pages, v_pages, block_tables,
                                   lengths, *, scale: Optional[float] = None,
                                   impl: str = "auto",
@@ -1255,6 +1617,38 @@ def ragged_paged_prefill_int8_attention(q, k_pages, v_pages, k_scales,
     return kernels.dispatch("ragged_paged_prefill_int8", q, k_pages,
                             v_pages, k_scales, v_scales, block_tables,
                             chunk_starts, n_valid, impl=impl, scale=scale)
+
+
+def latent_paged_decode_attention(q, c_pages, r_pages, block_tables,
+                                  lengths, *, impl: str = "auto"):
+    """One decode step of attention over latent rows, every slot at once.
+
+    ``q`` (S, H, Dl + Dr): each head's absorbed query, already scaled;
+    ``c_pages`` (P, page_size, Dl) the cached latents, which are also the
+    values; ``r_pages`` (P, Dr, page_size) the shared rotary keys, tokens
+    along the lanes; ``block_tables`` (S, max_pages) int32; ``lengths``
+    (S,) int32 live tokens a slot. Every head scores the same row
+    ``[c | k_rope]`` of a token and sums its ``c``. Returns (S, H, Dl).
+    """
+    from paddle_tpu import kernels
+    return kernels.dispatch("latent_paged_decode", q, c_pages, r_pages,
+                            block_tables, lengths, impl=impl)
+
+
+def latent_paged_prefill_attention(q, c_pages, r_pages, block_tables,
+                                   chunk_starts, n_valid, *,
+                                   impl: str = "auto"):
+    """One batched chunked-prefill step of attention over latent rows.
+
+    ``q`` (S, C, H, Dl + Dr), the first ``n_valid[s]`` queries of a lane
+    real, at positions ``chunk_starts[s] + c``, each attending causally
+    to every row cached up to its own (the caller has written the
+    chunk's). Pools as :func:`latent_paged_decode_attention`. Padding
+    rows and lanes give zeros. Returns (S, C, H, Dl).
+    """
+    from paddle_tpu import kernels
+    return kernels.dispatch("latent_paged_prefill", q, c_pages, r_pages,
+                            block_tables, chunk_starts, n_valid, impl=impl)
 
 
 # ---------------------------------------------------------------------------
@@ -1661,6 +2055,175 @@ def _tp_local_sample(seed, *, tp, chunked, quantized=False):
             v_pages[:, :, :hl * dh]) + args[3:], kwargs
 
 
+# -- latent rows: registry plumbing ------------------------------------------
+
+#: rows (heads x queries) of a chunked-prefill tile
+_LATENT_Q_ROWS = (256, 1024)
+
+
+def _latent_decode_kernel_pallas(q, c_pages, r_pages, block_tables, lengths,
+                                 *, block_sizes, interpret):
+    return _latent_decode_pallas(
+        q, c_pages, r_pages, block_tables, lengths, interpret,
+        block_sizes.get("pages_per_block", 1))
+
+
+def _latent_prefill_kernel_pallas(q, c_pages, r_pages, block_tables,
+                                  chunk_starts, n_valid, *, block_sizes,
+                                  interpret):
+    return _latent_prefill_pallas(
+        q, c_pages, r_pages, block_tables, chunk_starts, n_valid, interpret,
+        block_sizes.get("pages_per_block", 1),
+        block_sizes.get("q_rows", _LATENT_Q_ROWS[-1]))
+
+
+def _latent_rows_np(c_pages, r_pages, table):
+    """A slot's cached rows ``[c | k_rope]`` in token order (NumPy)."""
+    import numpy as np
+    c = np.asarray(c_pages, np.float32)[table]              # (mp, ps, Dl)
+    r = np.asarray(r_pages, np.float32)[table]              # (mp, Dr, ps)
+    return np.concatenate(
+        [c.reshape(-1, c.shape[-1]),
+         r.transpose(0, 2, 1).reshape(-1, r.shape[1])], axis=1)
+
+
+def _latent_attend_np(q, rows, dl):
+    """``q`` (H, Dl + Dr) over ``rows`` (T, Dl + Dr): softmax of the
+    scores against the whole row, weighted sum of its first ``dl``."""
+    import numpy as np
+    s = q @ rows.T
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return (p / p.sum(-1, keepdims=True)) @ rows[:, :dl]
+
+
+def _latent_decode_reference(q, c_pages, r_pages, block_tables, lengths):
+    """NumPy per-slot attention over the rows: independent of both impls."""
+    import numpy as np
+    dl = c_pages.shape[-1]
+    qn, bt = np.asarray(q, np.float32), np.asarray(block_tables)
+    out = np.zeros(q.shape[:2] + (dl,), np.float32)
+    for sl, n in enumerate(np.asarray(lengths)):
+        if n:
+            out[sl] = _latent_attend_np(
+                qn[sl], _latent_rows_np(c_pages, r_pages, bt[sl])[:n], dl)
+    return jnp.asarray(out).astype(q.dtype)
+
+
+def _latent_prefill_reference(q, c_pages, r_pages, block_tables,
+                              chunk_starts, n_valid):
+    import numpy as np
+    dl = c_pages.shape[-1]
+    qn, bt = np.asarray(q, np.float32), np.asarray(block_tables)
+    st, nv = np.asarray(chunk_starts), np.asarray(n_valid)
+    out = np.zeros(q.shape[:3] + (dl,), np.float32)
+    for sl in range(q.shape[0]):
+        rows = _latent_rows_np(c_pages, r_pages, bt[sl])
+        for r in range(int(nv[sl])):
+            out[sl, r] = _latent_attend_np(
+                qn[sl, r], rows[:int(st[sl]) + r + 1], dl)
+    return jnp.asarray(out).astype(q.dtype)
+
+
+def _make_latent_sample(seed, *, chunked):
+    """Three shapes by ``seed % 3``: float32 pools of pages scattered
+    over the pool, slots of every length from empty to full."""
+    import numpy as np
+    s_slots, h, dl, dr, ps, mp = (
+        (4, 2, 16, 8, 8, 3), (6, 4, 32, 8, 16, 4), (8, 4, 64, 16, 16, 6)
+    )[seed % 3]
+    c = ps
+    num_pages = s_slots * mp + 1
+    rng = np.random.default_rng(seed)
+    c_pages = jnp.asarray(rng.standard_normal((num_pages, ps, dl)),
+                          jnp.float32)
+    r_pages = jnp.asarray(rng.standard_normal((num_pages, dr, ps)),
+                          jnp.float32)
+    perm = rng.permutation(num_pages - 1)[:s_slots * mp] + 1
+    block_tables = jnp.asarray(perm.reshape(s_slots, mp), jnp.int32)
+    scale = (dl + dr) ** -0.5           # the caller's: queries come scaled
+    if not chunked:
+        q = jnp.asarray(scale * rng.standard_normal((s_slots, h, dl + dr)),
+                        jnp.float32)
+        lengths = jnp.asarray(
+            rng.integers(0, mp * ps + 1, s_slots), jnp.int32)
+        return (q, c_pages, r_pages, block_tables, lengths), {}
+    q = jnp.asarray(scale * rng.standard_normal((s_slots, c, h, dl + dr)),
+                    jnp.float32)
+    starts = jnp.asarray(rng.integers(0, (mp - 1) * ps, s_slots), jnp.int32)
+    n_valid = jnp.asarray(rng.integers(0, c + 1, s_slots), jnp.int32)
+    return (q, c_pages, r_pages, block_tables, starts, n_valid), {}
+
+
+def _latent_tune_signature(args, kwargs):
+    q, c_pages, r_pages, bt = args[:4]
+    sig = [("s", q.shape[0]), ("h", q.shape[-2]), ("dl", c_pages.shape[-1]),
+           ("dr", r_pages.shape[1]), ("ps", c_pages.shape[1]),
+           ("mp", bt.shape[1])]
+    if q.ndim == 4:
+        sig.insert(1, ("c", q.shape[1]))
+    return tuple(sig)
+
+
+def _latent_vmem_estimate(args, kwargs, blocks):
+    """VMEM working set of one grid step of the latent bodies, tiles
+    padded as the chip lays them out. Decode: its own two buffers of
+    ``pb`` pages of each pool, the queries and output double-buffered,
+    the state, and one update ``pb`` pages wide (float32 scores and
+    weights, the weights' three bf16 terms and their product). Chunked
+    prefill: ``pb`` pages of each pool, the query tile and the output
+    tile double-buffered by the pipeline, the tile's state, and one
+    page's scores and weights."""
+    q, c_pages, r_pages = args[:3]
+    ps, dl = c_pages.shape[1:]
+    dr = r_pages.shape[1]
+    isz = c_pages.dtype.itemsize
+    pb = blocks.get("pages_per_block", 1)
+
+    def tiled(sub, lane, itemsize):
+        tile = 32 // itemsize
+        return -(-sub // tile) * tile * -(-lane // 128) * 128 * itemsize
+
+    pages = pb * (tiled(ps, dl, isz) + tiled(dr, ps, isz))
+    if q.ndim == 3:
+        rows = q.shape[1] + -q.shape[1] % _HEAD_ROWS
+        width = pb * ps
+        io = 2 * (2 * tiled(rows, dl, isz) + tiled(rows, dr, isz))
+        state = 2 * tiled(rows, 128, 4) + tiled(rows, dl, 4)
+        fold = (2 * tiled(rows, width, 4) + tiled(3 * rows, width, 2)
+                + tiled(3 * rows, dl, 4))
+        return 2 * pages + io + state + fold
+    c, h = q.shape[1:3]
+    rows = h * _latent_queries_a_tile(
+        c, h, blocks.get("q_rows", _LATENT_Q_ROWS[-1]))
+    io = 2 * (2 * tiled(rows, dl, isz) + tiled(rows, dr, isz))
+    state = 2 * tiled(rows, 128, 4) + tiled(rows, dl, 4)
+    fold = 3 * tiled(rows, ps, 4) + tiled(rows, dl, 4)
+    return 2 * pages + io + state + fold
+
+
+def _latent_donation_probe(chunked):
+    def probe():
+        args, _ = _make_latent_sample(0, chunked=chunked)
+        q, c_pages, r_pages = args[:3]
+        kernel = _latent_prefill_kernel_pallas if chunked \
+            else _latent_decode_kernel_pallas
+
+        def step(cp, rp, q, *geometry):
+            # the engine's pattern: a token's row written, then attended
+            # through the Pallas body, the pools handed back
+            one = q[0, 0] if chunked else q[0]
+            cp = cp.at[1, 0].set(one[0, :cp.shape[-1]])
+            rp = rp.at[1, :, 0].set(one[0, cp.shape[-1]:])
+            out = kernel(q, cp, rp, *geometry,
+                         block_sizes={"pages_per_block": 2}, interpret=True)
+            return out, cp, rp
+
+        shapes = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
+                       for a in (c_pages, r_pages, q) + args[3:])
+        return step, shapes, (0, 1)
+    return probe
+
+
 def _register_paged_kernels():
     from paddle_tpu import kernels
     pb_candidates = {"pages_per_block": (1, 2, 4)}
@@ -1802,5 +2365,55 @@ def _register_paged_kernels():
             lambda s: _tp_local_sample(s, tp=4, chunked=True,
                                        quantized=True))))
 
+    latent_layouts = {"c_pages": "(P,ps,Dl)", "r_pages": "(P,Dr,ps)",
+                      "block_tables": "(S,mp) i32"}
+    kernels.register(kernels.KernelSpec(
+        name="latent_paged_decode",
+        contract=kernels.KernelContract(
+            version=1,
+            arg_layouts={"q": "(S,H,Dl+Dr)", **latent_layouts,
+                         "lengths": "(S,) i32"},
+            out_layout="(S,H,Dl)",
+            donatable=("c_pages", "r_pages"),
+            grid="(S,) one step a slot, pools left in HBM: the body copies "
+                 "the slot's live pages of both pools itself, "
+                 "pages_per_block side by side into one of two VMEM "
+                 "buffers while it folds the other; a block one softmax "
+                 "update for all heads, the page of c read once for the "
+                 "scores and the weighted sum",
+            block_candidates=decode_pb_candidates,
+            atol=2e-5, rtol=2e-5),
+        pallas_fn=_latent_decode_kernel_pallas,
+        lax_fn=_latent_decode_lax,
+        reference_fn=_latent_decode_reference,
+        sample_inputs=lambda seed: _make_latent_sample(seed, chunked=False),
+        pallas_sites=(
+            "paddle_tpu.serving.decode_attention:_latent_decode_pallas",),
+        tune_signature=_latent_tune_signature,
+        vmem_estimate=_latent_vmem_estimate,
+        donation_probe=_latent_donation_probe(False)))
+    kernels.register(kernels.KernelSpec(
+        name="latent_paged_prefill",
+        contract=kernels.KernelContract(
+            version=1,
+            arg_layouts={"q": "(S,C,H,Dl+Dr)", **latent_layouts,
+                         "chunk_starts": "(S,) i32", "n_valid": "(S,) i32"},
+            out_layout="(S,C,H,Dl)",
+            donatable=("c_pages", "r_pages"),
+            grid="(S, C*H/rows, cdiv(mp,pages_per_block)): a tile of "
+                 "q_rows heads x queries against whole-page blocks of both "
+                 "pools, block-table scalar prefetch, causal + live-row "
+                 "mask, blocks past a tile's last query not moved",
+            block_candidates={**pb_candidates, "q_rows": _LATENT_Q_ROWS},
+            atol=2e-5, rtol=2e-5),
+        pallas_fn=_latent_prefill_kernel_pallas,
+        lax_fn=_latent_prefill_lax,
+        reference_fn=_latent_prefill_reference,
+        sample_inputs=lambda seed: _make_latent_sample(seed, chunked=True),
+        pallas_sites=(
+            "paddle_tpu.serving.decode_attention:_latent_prefill_pallas",),
+        tune_signature=_latent_tune_signature,
+        vmem_estimate=_latent_vmem_estimate,
+        donation_probe=_latent_donation_probe(True)))
 
 _register_paged_kernels()

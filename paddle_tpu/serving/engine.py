@@ -200,6 +200,9 @@ _STEP_STAT_HELP = {
                             "over queries and layers",
 }
 
+_KV_POOL_HELP = ("bytes of the K/V pools by layer kind, null pages "
+                 "included")
+
 _STEP_PARTS = (("prefill", "assemble"), ("prefill", "cow_copy"),
                ("prefill", "dispatch"), ("prefill", "sync"),
                ("prefill", "book"), ("decode", "assemble"),
@@ -434,7 +437,7 @@ class ServingEngine:
             share_prefix=prefix_sharing, extra_rows=spec.extra_rows,
             slot_state=spec.slot_state,
             slot_state_dtype=jnp.dtype(spec.slot_state_dtype),
-            layer_windows=spec.layer_windows),
+            layer_windows=spec.layer_windows, latent_row=spec.latent_row),
             mesh=self.mesh,
             host_spill_pages=host_spill_pages)
         self.quantized = self.cache.config.quantized
@@ -700,10 +703,12 @@ class ServingEngine:
         self._c_kv_live = kv.child(kind="live")
         self._c_kv_gathered = kv.child(kind="gathered")
         c = self.cache.config
-        # K and V of one token in one layer; a window layer reads its
-        # window's tokens at most
-        self._kv_layer_token_bytes = (2 * c.num_heads * c.head_dim
-                                      * np.dtype(c.dtype).itemsize)
+        # K and V of one token in one layer (a latent row is cached once:
+        # its values are a part of it); a window layer reads its window's
+        # tokens at most
+        self._kv_layer_token_bytes = (
+            (1 if c.latent_row else 2) * c.num_heads * c.head_dim
+            * np.dtype(c.dtype).itemsize)
         self._kv_token_bytes = self._kv_layer_token_bytes * (
             c.num_layers - len(c.window_layers))
         self._h_decode_step = r.histogram(
@@ -762,6 +767,7 @@ class ServingEngine:
         ).child()
         self._bind_state_metrics(r)
         self._bind_window_metrics(r)
+        self._bind_latent_metrics(r)
         self._c_step_stats = [
             r.counter(f"serving_{name}_total", _STEP_STAT_HELP.get(
                 name, "a count the step program hands back")).child()
@@ -837,14 +843,48 @@ class ServingEngine:
             "serving_window_pages_recycled_total",
             "ring pages of window layers written over as slots advanced "
             "past them (pages x window layers)").child()
-        pool = r.gauge("serving_kv_pool_bytes",
-                       "bytes of the K/V pools by layer kind, null pages "
-                       "included")
+        pool = r.gauge("serving_kv_pool_bytes", _KV_POOL_HELP)
         full = [i for i in range(c.num_layers) if i not in c.window_layers]
         for kind, layers in (("window", c.window_layers), ("full", full)):
             pool.set(sum(a.nbytes for i in layers
                          for a in self.cache.pages[i][:c.paged_entries]),
                      layers=kind)
+
+    def _bind_latent_metrics(self, r):
+        """The series of a program whose layers cache one latent row a
+        token (``spec.latent_row``), fed from the lengths the host holds;
+        any other program binds none of them."""
+        #: fixed with the pools: the steps of a program without latent
+        #: rows never ask again
+        self._latent = self.cache.config.latent_row is not None
+        if not self._latent:
+            return
+        rows = r.counter(
+            "serving_latent_rows_read_total",
+            "cached latent rows x layers the latent kernels had to read: "
+            "a decode token step every live token of each decoding slot, "
+            "a prefill call each lane's context and chunk")
+        pairs = r.counter(
+            "serving_latent_pairs_total",
+            "(query token, cached row) pairs x layers the latent kernels "
+            "scored, every head each: a decode token step one query a "
+            "slot (equal to the rows), a prefill call each chunk token "
+            "against the rows up to its own")
+        self._c_latent = {ph: (rows.child(phase=ph), pairs.child(phase=ph))
+                          for ph in ("decode", "prefill")}
+        r.gauge("serving_kv_pool_bytes", _KV_POOL_HELP).set(
+            sum(a.nbytes for ent in self.cache.pages for a in ent),
+            layers="latent")
+
+    def _count_latent(self, span, phase, rows: int, pairs: int):
+        """One round's or call's latent rows and pairs (``rows``,
+        ``pairs``: of ONE layer), and the span's ``latent_rows``."""
+        layers = self.cache.config.num_layers
+        c_rows, c_pairs = self._c_latent[phase]
+        c_rows.inc(rows * layers)
+        c_pairs.inc(pairs * layers)
+        if span is not None:
+            span.set_attrs(latent_rows=rows * layers)
 
     def _count_window(self, span, before, after):
         """One round's or call's K/V by layer kind, from the lengths the
@@ -1321,7 +1361,8 @@ class ServingEngine:
         grid over. The dense decode kernel walks a slot's live pages
         itself since PR 39 and lays out no width, so for it the ratio
         says how wide the table's bucket is for what the slots hold, not
-        what the kernel spent."""
+        what the kernel spent. Returns the live tokens attended over, a
+        layer."""
         c = self.cache.config
         lens = self.cache.lengths[dslots]
         live = n * int(lens.sum()) + len(dslots) * n * (n + 1) // 2
@@ -1338,6 +1379,7 @@ class ServingEngine:
         self._c_kv_live.inc(live_b)
         self._c_kv_gathered.inc(
             n * self.scheduler.num_slots * c.page_size * gathered)
+        return live
 
     def _note_step_stats(self, phase, counts):
         """Feed one call's device-side counts (``self._step_stats``
@@ -1457,8 +1499,10 @@ class ServingEngine:
             for k, (_, lanes) in enumerate(calls):
                 for j, i in lanes:
                     tokens[i] = -(1 + j) - k * s_tot
-            self._count_kv_bytes(dslots, n, w)
+            live = self._count_kv_bytes(dslots, n, w)
             self._count_state(rnd.span, decoding=len(dslots), token_steps=n)
+            if self._latent:
+                self._count_latent(rnd.span, "decode", live, live)
             if self._window_layers:
                 lens = self.cache.lengths[dslots]
                 self._count_window(rnd.span, lens, lens + np.asarray(
@@ -2016,6 +2060,13 @@ class ServingEngine:
                 if self._window_layers:
                     self._count_window(call.span, np.asarray(los),
                                        np.asarray(los) + np.asarray(ns))
+                if self._latent:
+                    # chunk token j of a lane sees its context and the
+                    # chunk's tokens up to itself
+                    self._count_latent(
+                        call.span, "prefill", sum(los) + call_tokens,
+                        sum(lo * n + n * (n + 1) // 2
+                            for lo, n in zip(los, ns)))
                 dbt_dev = jnp.asarray(dbt_rows[:, :w]) if self.speculative \
                     else None
             with phase("serving.prefill.dispatch",
@@ -2861,7 +2912,8 @@ class ServingEngine:
 
     def _write_rows(self, ent, rows, page_idx, off, quantized, psum_axis):
         """Land one call's rows in a layer's pool entry: K and V (int8
-        rows + per-token scales for a quantized pool), then the
+        rows + per-token scales for a quantized pool; a latent row's two
+        parts where the program caches one), then the
         program's extra rows, each where ``page_idx`` / ``off`` say
         (one index a token: ``(S,)`` for decode, ``(S, C)`` for a
         chunk). Extra rows are kept ``(P, width, page_size)``, tokens
@@ -2877,10 +2929,12 @@ class ServingEngine:
                     vp.at[page_idx, off].set(vq),
                     ksc.at[page_idx, off].set(k_s),
                     vsc.at[page_idx, off].set(v_s))
-        kp, vp = ent[0], ent[1]
-        out = [kp.at[page_idx, off].set(k_tok.astype(kp.dtype)),
-               vp.at[page_idx, off].set(v_tok.astype(vp.dtype))]
-        for pool, row in zip(ent[2:], rows[2:]):
+        # token-major pools first: K and V, or a latent row's latent alone
+        # (its shared rotary key is kept as an extra row is)
+        major = 1 if self._latent else 2
+        out = [pool.at[page_idx, off].set(row.astype(pool.dtype))
+               for pool, row in zip(ent[:major], rows[:major])]
+        for pool, row in zip(ent[major:], rows[major:]):
             out.append(self._write_lane_rows(pool, row, page_idx, off))
         return tuple(out)
 
@@ -2971,7 +3025,12 @@ class ServingEngine:
                        quantized):
         """One decode token a slot, ``q`` (S, H, Dh), over the pool entry
         ``ent`` as just written; ``lengths`` counts this token. Returns
-        (heads (S, H, Dh), tokens attended a slot (S,))."""
+        (heads (S, H, Dh), tokens attended a slot (S,)); a program of
+        latent rows: ``q`` against the whole row, heads (S, H, latent)."""
+        if spec.latent_row is not None:
+            return DA.latent_paged_decode_attention(
+                q, ent[0], ent[1], block_tables, lengths,
+                impl=self.attn_impl), lengths
         if quantized:
             return DA.ragged_paged_decode_int8_attention(
                 q, *ent, block_tables, lengths, impl=self.attn_impl), lengths
@@ -2987,7 +3046,11 @@ class ServingEngine:
                         index, quantized):
         """A chunk of queries a slot, ``q`` (S, C, H, Dh), causally over
         the pool entry ``ent`` as just written. Returns heads (S, C, H,
-        Dh)."""
+        Dh); a program of latent rows: (S, C, H, latent)."""
+        if spec.latent_row is not None:
+            return DA.latent_paged_prefill_attention(
+                q, ent[0], ent[1], block_tables, starts, n_valid,
+                impl=self.attn_impl)
         if quantized:
             return DA.ragged_paged_prefill_int8_attention(
                 q, *ent, block_tables, starts, n_valid, impl=self.attn_impl)

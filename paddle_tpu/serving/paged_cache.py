@@ -98,6 +98,19 @@ sharing off (a borrower would need the window layers' last tokens of the
 prefix). ``bytes_per_page`` is a page row of the full layers;
 ``capacity_bytes`` / ``live_bytes`` count both kinds.
 
+Latent rows (ISSUE 42): a program whose heads all read one cached row a
+token declares ``latent_row = (latent_dim, rope_dim)`` and a layer's entry
+is then ``(c_pages (num_pages, page_size, latent_dim), r_pages (num_pages,
+rope_dim, page_size))``: the latent, token-major as K is, and the shared
+rotary key with the tokens along the lanes, as ``extra_rows`` are kept.
+There is no V pool (attention sums the latents themselves), so a page row
+is ``page_size * (latent_dim + rope_dim) * itemsize`` bytes a layer, whole
+tiles of both arrays and nothing padded. Both are page pools like any
+other: allocated, refcounted, published, copied on write and freed under
+one page id, and ``bytes_per_page`` / ``capacity_bytes`` / ``live_bytes``
+count them as they count K and V. Not quantized, sharded, spilled or
+shipped yet.
+
 Tensor parallel (ISSUE 15): pass ``mesh=`` (a mesh with a ``tp`` axis
 of size > 1) and the page pool becomes **per-shard**: the K/V page
 arrays are placed sharded over ``tp`` on the folded HEAD axis (each mesh
@@ -151,8 +164,18 @@ class PagedCacheConfig:
     #: shared pool, every token) or a window (a ring of pages a slot);
     #: empty: every layer is full
     layer_windows: Tuple[Optional[int], ...] = ()
+    #: ``(latent_dim, rope_dim)``: a layer's entry is one row a token, the
+    #: latent ``(num_pages, page_size, latent_dim)`` and the shared rotary
+    #: key ``(num_pages, rope_dim, page_size)``, and no V pool; None: K, V
+    latent_row: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
+        if self.latent_row is not None and (
+                self.quantized or self.extra_rows or self.slot_state
+                or self.layer_windows):
+            raise ValueError(
+                "a pool of latent rows is not quantized and carries no "
+                "extra rows, slot state or window layers yet")
         if self.layer_windows:
             if len(self.layer_windows) != self.num_layers:
                 raise ValueError("layer_windows names every layer or none")
@@ -450,7 +473,13 @@ class PagedKVCache:
             raise ValueError(
                 f"tp={mesh.shape['tp']} must divide num_heads={c.num_heads}")
         shape = (c.num_pages, c.page_size, c.num_heads * c.head_dim)
-        if c.quantized:
+        if c.latent_row is not None:
+            latent, rope = c.latent_row
+            self.pages = [
+                (jnp.zeros(shape[:2] + (latent,), c.dtype),
+                 jnp.zeros((c.num_pages, rope, c.page_size), c.dtype))
+                for _ in range(c.num_layers)]
+        elif c.quantized:
             # int8 pages + fp32 per-token-row scales, one (k, v, ks, vs)
             # tuple per layer so scales thread/donate with their pages
             # through every jitted step as ONE pytree
@@ -476,10 +505,11 @@ class PagedKVCache:
                    for _name, shape in c.slot_state))
                 for i in range(c.num_layers)]
         if self.mesh is not None:
-            if c.extra_rows or c.slot_state or c.layer_windows:
+            if c.extra_rows or c.slot_state or c.layer_windows \
+                    or c.latent_row:
                 raise ValueError("a tp-sharded pool carries no extra "
-                                 "rows, no slot state and no window "
-                                 "layers yet")
+                                 "rows, no slot state, no window layers "
+                                 "and no latent rows yet")
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
             kv_s = NamedSharding(self.mesh, P(None, None, "tp"))
@@ -1035,6 +1065,13 @@ class PagedKVCache:
             assert pid in self._page_tokens, "published page lost tokens"
         for owned, sp in zip(self._owned, self._slot_pages):
             assert owned <= set(sp), "owned page not mapped"
+        if c.latent_row is not None:
+            latent, rope = c.latent_row
+            for ent in self.pages:
+                assert [a.shape for a in ent] == [
+                    (c.num_pages, c.page_size, latent),
+                    (c.num_pages, rope, c.page_size)], \
+                    "a latent layer's entry is not one row a token"
         for i in c.window_layers:
             ring = c.ring_pages(c.window_of(i))
             assert self.pages[i][0].shape[0] == c.num_slots * ring + 1, \

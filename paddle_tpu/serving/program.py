@@ -66,6 +66,21 @@ table) and hand the window to the paged kernels, and the program's own
 functions stay as above: ``attn_in`` of layer ``i`` returns that layer's
 rows whatever its kind.
 
+A program whose heads all read ONE cached row a token declares
+``spec.latent_row = (latent_dim, rope_dim)`` (multi-head latent attention
+in its absorbed form). ``attn_in`` then returns ``q`` (S, H, C,
+latent_dim + rope_dim), every head's query against the whole row, already
+scaled, and ``rows = (c (S, C, latent_dim), k_rope (S, C, rope_dim))``: the
+token's latent, which is also what attention sums, and the rotary key the
+heads share. The cache keeps the two as one page pool each and nothing
+else (``latent_dim + rope_dim`` values a token and layer; there is no V
+pool: the values are the row's first ``latent_dim``), the engine attends
+through the latent kernels (:mod:`~paddle_tpu.serving.decode_attention`),
+and ``attn_out`` gets heads ``(S, C, H, latent_dim)``: the weighted sum of
+latents, to which the program applies its value up-projection and its
+output projection. Such a spec says ``kv_heads = 1`` and ``head_dim =
+latent_dim + rope_dim``: one row, as wide as a query.
+
 What a program cannot do yet it leaves out of ``spec.supports``; the
 engine refuses, by name, an option that needs it.
 
@@ -128,9 +143,25 @@ class ServingSpec:
     #: before) or the window, the tokens a query attends to with itself
     #: counted; empty: every layer is full
     layer_windows: Tuple[Optional[int], ...] = ()
+    #: ``(latent_dim, rope_dim)`` where a layer caches ONE row a token that
+    #: every head reads, its first ``latent_dim`` values also the values
+    #: attention sums; None: K and V heads
+    latent_row: Optional[Tuple[int, int]] = None
     supports: FrozenSet[str] = FEATURES
 
     def __post_init__(self):
+        if self.latent_row is not None:
+            if (self.kv_heads, self.head_dim) != (1, sum(self.latent_row)):
+                raise ValueError(
+                    f"latent_row={self.latent_row!r}: one row a token as "
+                    "wide as a query (kv_heads 1, head_dim latent_dim + "
+                    "rope_dim)")
+            mixed = [name for name in ("extra_rows", "select_topk",
+                                       "slot_state", "layer_windows")
+                     if getattr(self, name)]
+            if mixed:
+                raise ValueError(f"a latent row is cached alone, without "
+                                 f"{mixed}")
         if self.layer_windows and all(w is None for w in self.layer_windows):
             object.__setattr__(self, "layer_windows", ())    # all full
         if self.layer_windows and (

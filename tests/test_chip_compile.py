@@ -713,6 +713,92 @@ def test_window_family_steps_compile_and_keep_the_pools(
         640 << 20)
 
 
+# -- latent rows + an expert share at the shared-context cell's geometry ---------
+
+MS_SLOTS, MS_PS, MS_PAGES, MS_CHUNK, MS_WIDTH = 128, 128, 4993, 256, 134
+
+
+@pytest.fixture(scope="module")
+def mistral4_steps(topo):
+    """The latent-row family at its published widths and the cell's six
+    layers (16 of 128 experts held, 16384 rows of the vocabulary), on the
+    sessions cell's pools: 4993 pages of (128, 256) latents and of (64,
+    128) rotary keys a layer."""
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import inference
+    from paddle_tpu.models.mla_moe_lm import MLAMoELM, MLAMoELMConfig
+    model = MLAMoELM(MLAMoELMConfig(
+        num_hidden_layers=6, vocab_size=16384, n_routed_experts=16,
+        num_routed_experts=128, kernel_impl="pallas"))
+    params = jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 2_872_634_880
+    eng = inference.make_serving_engine(
+        model, params, num_slots=2, page_size=MS_PS, num_pages=9,
+        max_tokens_per_slot=17152, prefill_chunk=MS_CHUNK, decode_block=8,
+        attn_impl="pallas", cache_dtype=jnp.bfloat16)
+    dev = SingleDeviceSharding(topo.devices[0])
+    sds = jax.ShapeDtypeStruct
+    weights = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, sharding=dev), params)
+    pages = [tuple(sds((MS_PAGES,) + a.shape[1:], a.dtype, sharding=dev)
+                   for a in ent) for ent in eng.cache.pages]
+
+    def i32(*shape):
+        return sds(shape, jnp.int32, sharding=dev)
+
+    @functools.lru_cache(maxsize=None)
+    def lower(step, lanes, width):
+        if step == "decode":
+            return eng.decode_step.lower(
+                weights, pages, i32(lanes, width), i32(lanes), i32(lanes),
+                i32(lanes)).compile()
+        return eng.prefill_step.lower(
+            weights, pages, i32(lanes, width), i32(lanes),
+            i32(lanes, MS_CHUNK), i32(lanes)).compile()
+
+    return lower
+
+
+@pytest.mark.parametrize("step, lanes, kernels_in", [
+    ("decode", MS_SLOTS, {"latent_paged_decode", "moe_grouped_ffn"}),
+    ("prefill", 8, {"latent_paged_prefill", "moe_grouped_ffn"})],
+    ids=["decode-w134", "prefill-8lanes-w134"])
+def test_latent_row_family_steps_compile_and_keep_the_pools(
+        step, lanes, kernels_in, mistral4_steps):
+    """The six-layer decode block and prefill step compile for the chip at
+    the sessions cell's geometry: 32 absorbed queries of 320 a slot over
+    tables of 134 pages, 128 slots inside the 8-token loop; 8 lanes of 256
+    queries x 32 heads as tiles of 1024 rows; the grouped expert kernel at
+    16 held experts of 2048 x 4096. No step copies a latent pool or a
+    rotary-key pool; each comes in row-major, whole tiles, nothing padded;
+    temporaries stay under 512 MB."""
+    _assert_step_keeps_its_pools(
+        mistral4_steps(step, lanes, MS_WIDTH), kernels_in, {
+            rf"bf16\[{MS_PAGES},{MS_PS},256\]": "{2,1,0",
+            rf"bf16\[{MS_PAGES},64,{MS_PS}\]": "{2,1,0"}, 512 << 20)
+
+
+@pytest.mark.parametrize("name, q_shape, geometry", [
+    ("latent_paged_decode", (MS_SLOTS, 32, 320), 1),
+    ("latent_paged_prefill", (8, MS_CHUNK, 32, 320), 2)])
+def test_latent_kernels_vmem_estimates_hold_for_v5e(name, q_shape, geometry,
+                                                    one_chip):
+    """Each latent kernel alone at the cell's shapes and the static
+    prior's block sizes: it compiles for the chip, and the estimate that
+    chose the blocks is not under what the compiler scoped."""
+    sds = jax.ShapeDtypeStruct
+    args = (sds(q_shape, jnp.bfloat16),
+            sds((MS_PAGES, MS_PS, 256), jnp.bfloat16),
+            sds((MS_PAGES, 64, MS_PS), jnp.bfloat16),
+            sds((q_shape[0], MS_WIDTH), jnp.int32)) + tuple(
+                sds((q_shape[0],), jnp.int32) for _ in range(geometry))
+    spec = kernels.get(name)
+    blocks = autotune.static_prior(spec, args, {})
+    assert spec.vmem_estimate(args, {}, blocks) <= autotune.VMEM_BUDGET_BYTES
+    _compile_kernel(name, args, one_chip)
+
+
 # -- the named scopes of PR 38 leave every name a metric selects as it was ------
 
 def _kernel_counts(text):

@@ -74,6 +74,22 @@ def _engine(params, impl="lax", slots=2, control=None, **kw):
     return eng, sink, reg
 
 
+@pytest.fixture(scope="module")
+def engines(model_and_params):
+    """``impl -> (engine, its head calls' logits, registry)``: ONE engine
+    an ``impl`` for the cases that serve a request alone at this geometry
+    (a case then compiles only the widths no earlier one met); a slot's
+    state holds what its last request left, and the step that starts a
+    prompt starts from zeros, as in a serving process."""
+    built = {}
+
+    def get(impl):
+        if impl not in built:
+            built[impl] = _engine(model_and_params[1], impl)
+        return built[impl]
+    return get
+
+
 def _serve(eng, sink, prompt, n_new):
     """One request alone in the engine: its tokens and the logits of
     positions ``len(prompt) - 1 .. len(prompt) + n_new - 2``."""
@@ -140,11 +156,11 @@ CASES = {
     ("ends_inside_a_chunk", "lax"),
     ("ends_inside_a_chunk", "pallas_interpret")])
 def test_prefill_then_decode_logits_match_the_reference(
-        case, impl, model_and_params):
+        case, impl, model_and_params, engines):
     model, params = model_and_params
     n0, n_new = CASES[case]
     prompt = _prompt(n0)
-    eng, sink, _ = _engine(params, impl)
+    eng, sink, _ = engines(impl)
     out, got = _serve(eng, sink, prompt, n_new)
     want = _reference_rows(model, params, prompt, out)
     _assert_close(got, want)

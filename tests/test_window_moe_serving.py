@@ -9,6 +9,10 @@ window 8, page 4, chunk 4: a window layer's ring is 3 pages a slot.
 Weights are seeded float32 as ``init`` draws them, so what separates the
 engine from the reference is the order of float32 sums (the paged
 kernels' page folds, the grouped expert kernel's tiles) and nothing else.
+ONE engine an ``impl`` serves the cases that take this geometry
+(``engines``, module-scoped: a case then compiles only the widths no
+earlier one met), a request at a time; a slot's rings hold what its last
+request left, as they do in a serving process.
 """
 
 import dataclasses
@@ -90,6 +94,19 @@ def _engine(params, impl="lax", slots=2, **kw):
     return eng, sink, reg
 
 
+@pytest.fixture(scope="module")
+def engines(model_and_params):
+    """``impl -> (engine, the logits its head calls made, registry)``,
+    built at first use and kept for the module."""
+    built = {}
+
+    def get(impl):
+        if impl not in built:
+            built[impl] = _engine(model_and_params[1], impl)
+        return built[impl]
+    return get
+
+
 def _serve(eng, sink, prompt, n_new):
     """One request alone in the engine: its tokens and the logits of
     positions ``len(prompt) - 1 .. len(prompt) + n_new - 2``."""
@@ -158,26 +175,33 @@ CASES = {
     ("prompt_laps_the_ring", "pallas_interpret"),
     ("prompt_laps_the_ring", "lax"), ("ends_on_a_page_edge", "lax")])
 def test_prefill_then_decode_through_the_cache_gives_the_references_logits(
-        case, impl, model_and_params):
+        case, impl, model_and_params, engines):
     model, params = model_and_params
     n_prompt, n_new = CASES[case]
-    eng, sink, _ = _engine(params, impl)
+    eng, sink, _ = engines(impl)
     prompt = _prompt(n_prompt)
     out, logits = _serve(eng, sink, prompt, n_new)
     assert len(out) == n_new
     _assert_close(logits, _reference_rows(model, params, prompt, out))
 
 
+@pytest.fixture(scope="module")
+def served(engines):
+    """One request served once for the controls: (prompt, tokens,
+    logits)."""
+    eng, sink, _ = engines("lax")
+    prompt = _prompt(21)
+    return (prompt,) + _serve(eng, sink, prompt, 7)
+
+
 @pytest.mark.parametrize("control", ["window_one_longer", "every_layer_full",
                                      "no_shared_expert"])
 def test_the_tolerance_tells_a_wrong_window_or_a_missing_expert(
-        control, model_and_params):
+        control, model_and_params, served):
     """What ``LOGIT_RTOL`` must refuse: the reference with the window a
     token longer, with every layer full, or without the shared expert."""
     model, params = model_and_params
-    eng, sink, _ = _engine(params)
-    prompt = _prompt(21)
-    out, logits = _serve(eng, sink, prompt, 7)
+    prompt, out, logits = served
     over, tree = {}, params
     if control == "window_one_longer":
         over = {"sliding_window": WINDOW + 1}
@@ -196,11 +220,11 @@ def test_the_tolerance_tells_a_wrong_window_or_a_missing_expert(
 
 
 def test_two_requests_side_by_side_keep_to_their_own_rings(
-        model_and_params):
+        model_and_params, engines):
     """Two slots of different lengths in one batch: each slot's window
     layers read its own ring (a slot's pages are found by the slot)."""
     model, params = model_and_params
-    eng, _, _ = _engine(params, "pallas_interpret")
+    eng = engines("pallas_interpret")[0]
     prompts = [_prompt(17), _prompt(6)]
     outs = eng.generate_many(prompts, max_new_tokens=9)
     for prompt, out in zip(prompts, outs):
@@ -504,7 +528,7 @@ def test_a_program_of_one_layer_kind_binds_none_of_the_new_series():
     eng.generate_many([_prompt(5)], max_new_tokens=4)
     assert not [k for k in reg.snapshot()
                 if "window" in k or "resident" in k or "pool_bytes" in k
-                or "routed_pairs" in k]
+                or "routed_pairs" in k or "latent" in k]
     assert eng.cache.window_bytes_per_slot() == 0
     assert eng.cache.capacity_bytes() \
         == eng.cache.bytes_per_page() * (eng.cache.config.num_pages - 1)
