@@ -1195,10 +1195,16 @@ def _paged_prefill_int8_pallas(q, k_pages, v_pages, k_scales, v_scales,
 #
 # so a row is ``(Dl + Dr) * itemsize`` bytes and nothing is padded. The
 # page of ``c`` is read ONCE for both products. Queries come already
-# scaled. Decode walks a slot's live pages itself, as dense decode does
-# (:func:`_paged_decode_walk_kernel`): one grid step a slot, two VMEM
-# buffers of ``pages_per_block`` pages, a block one softmax update for all
-# heads. Chunked prefill stacks ``q_rows`` (heads x queries) of a lane as
+# scaled. Decode walks live pages itself, as dense decode does
+# (:func:`_paged_decode_walk_kernel`): two VMEM buffers of
+# ``pages_per_block`` pages, a block one softmax update for all rows. It
+# is two calls: the pages that the tables of several decoding slots open
+# with are walked ONCE a group of those slots, their queries stacked
+# against one copy of each page (Part A, one grid step a group), and each
+# slot's own pages a slot from the state Part A handed it (Part B, one
+# grid step a slot); a slot that shares nothing is Part B's alone. Who
+# shares what comes from the host, which holds the tables
+# (:func:`latent_decode_groups`). Chunked prefill stacks ``q_rows`` (heads x queries) of a lane as
 # the rows of one matrix and streams the lane's pages past it through the
 # ``BlockSpec`` pipeline, grid ``(S, query tiles, page blocks)``.
 
@@ -1221,7 +1227,9 @@ def _latent_softmax(scores, ok):
     return jnp.where(alive, p, 0.0)
 
 
-def _latent_decode_lax(q, c_pages, r_pages, block_tables, lengths):
+def _latent_decode_lax(q, c_pages, r_pages, block_tables, lengths,
+                       *_groups):
+    """Attention a slot; which slots share pages changes no result."""
     dl = c_pages.shape[-1]
     cg, rg = _latent_gather(c_pages, r_pages, block_tables)
     qf = q.astype(jnp.float32)
@@ -1260,40 +1268,76 @@ def _pool_dot(x, page, contract_page_dim):
                                contract_page_dim)
 
 
-def _latent_decode_walk_kernel(bt_ref, len_ref, qc_ref, qr_ref, c_hbm, r_hbm,
-                               o_ref, c_buf, r_buf, sems, first_buf, m_scr,
-                               l_scr, acc_scr, *, page_size,
-                               pages_per_block):
-    """The latent decode body: grid ``(S,)``, one step a slot, laid out as
-    :func:`_paged_decode_walk_kernel` is. ``qc_ref`` ``(1, rows, Dl)`` and
-    ``qr_ref`` ``(1, rows, Dr)`` are the slot's absorbed queries, a head a
-    row; ``c_hbm`` / ``r_hbm`` the whole pools in HBM; ``c_buf`` ``(2,
-    pb*ps, Dl)`` and ``r_buf`` ``(2, Dr, pb*ps)`` the two blocks in VMEM,
-    a page's rotary keys landing in the lanes of its tokens; ``sems``
-    ``(2, 2)`` one DMA semaphore a (pool, buffer). A block is ONE update:
-    ``S = q_c C^T + q_r R``, one maximum, one exponential, ``A += P C``
-    with the ``C`` the scores were taken from."""
+#: slots of one group: how many decoding slots whose tables open with the
+#: same pages are stacked against ONE copy of those pages. A block's cost
+#: on the chip is its bytes once and its products a member (PERF.md
+#: section 6, PR 43): 8 folds nearly every document of the sessions cell
+#: into one walk, and a group pays for the members it has
+LATENT_GROUP = 8
+
+#: a group's shared pages come in multiples of this many: every
+#: ``pages_per_block`` the kernel may be given divides it, so Part A
+#: folds whole blocks only and the pages left over are each slot's own
+LATENT_SHARED_PAGES = 8
+
+#: lanes of a slot's unnormalised state behind its accumulator: the
+#: running maximum and the running sum, a lane tile each
+_STATE_LANES = 256
+
+
+def _latent_fold(qc_ref, qr_ref, c_buf, r_buf, buf, m_scr, l_scr, acc_scr,
+                 *, width, first_token=None, extent=None):
+    """One softmax update of the rows' state (``m_scr / l_scr / acc_scr``)
+    with the first ``width`` tokens of block ``buf``: ``S = q_c C^T + q_r
+    R`` for every row at once, one maximum, one exponential, ``A += P C``
+    with the ``C`` the scores were taken from. ``qc_ref`` ``(rows, Dl)``
+    and ``qr_ref`` ``(rows, Dr)`` are absorbed queries, a head (of a
+    slot) a row. Tokens from ``extent`` on are masked, the block's first
+    being token ``first_token``; no ``extent``: every token is live."""
+    c = c_buf[buf, :width]                                   # (width, Dl)
+    s = _all_heads_page_dot(qc_ref[...], c, 1) \
+        + _all_heads_page_dot(qr_ref[...], r_buf[buf, :, :width], 0)
+    if extent is not None:
+        tok = first_token + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(tok < extent, s, NEG_INF)
+    m = m_scr[...]
+    m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_next)                              # (rows, 128)
+    p = jnp.exp(s - m_next[:, :1])                           # (rows, width)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[...] = m_next
+    acc_scr[...] = acc_scr[...] * alpha[:, :1] \
+        + _all_heads_page_dot(p, c, 0)                       # (rows, Dl)
+
+
+def _latent_walk(walk_of, fold, bt_ref, c_hbm, r_hbm, c_buf, r_buf, sems,
+                 first_buf, *, page_size, pages_per_block):
+    """One grid step's walk of the latent decode, laid out as
+    :func:`_paged_decode_walk_kernel` is: ``walk_of(step)`` gives (table
+    row, first page of the row, pages) of a step's walk; the pages are
+    copied ``pages_per_block`` side by side out of both pools into one of
+    two VMEM buffers (``c_buf`` ``(2, pb*ps, Dl)``, ``r_buf`` ``(2, Dr,
+    pb*ps)``: a page's rotary keys land in the lanes of its tokens;
+    ``sems`` ``(2, 2)`` one DMA semaphore a (pool, buffer)) while the
+    other is folded, a step's first block started by the step before.
+    ``fold(block, buf, pages)`` folds block ``block`` of the step's walk,
+    which lies in buffer ``buf`` and holds ``pages`` pages."""
     ps, pb = page_size, pages_per_block
-    sl = pl.program_id(0)
-    n_slots = pl.num_programs(0)
-    rows = qc_ref.shape[1]
+    step = pl.program_id(0)
+    n_steps = pl.num_programs(0)
     dr = r_buf.shape[1]
-
-    def live_pages(slot):
-        return (len_ref[slot] + ps - 1) // ps
-
-    extent = len_ref[sl]
-    n_live = live_pages(sl)
+    n_live = walk_of(step)[2]
     n_blocks = (n_live + pb - 1) // pb
 
-    def copies(slot, block, buf, start):
-        """Start, or wait for, the copies of one block of a slot: its live
-        page ``block*pb + t`` out of each pool into place ``t`` of that
-        pool's buffer ``buf``."""
-        n = live_pages(slot)
+    def copies(of, block, buf, start):
+        """Start, or wait for, the copies of one block of a step's walk:
+        its page ``block*pb + t`` out of each pool into place ``t`` of
+        that pool's buffer ``buf``."""
+        row, first_page, n = walk_of(of)
         for t in range(pb):
             p = block * pb + t
-            page = bt_ref[slot, jnp.minimum(p, bt_ref.shape[1] - 1)]
+            page = bt_ref[row, jnp.minimum(first_page + p,
+                                           bt_ref.shape[1] - 1)]
 
             @pl.when(p < n)
             def _live_page():
@@ -1305,66 +1349,143 @@ def _latent_decode_walk_kernel(bt_ref, len_ref, qc_ref, qr_ref, c_hbm, r_hbm,
                     copy = pltpu.make_async_copy(src, dst, sems.at[i, buf])
                     copy.start() if start else copy.wait()
 
-    @pl.when(sl == 0)
-    def _first_slot():
+    @pl.when(step == 0)
+    def _first_step():
         first_buf[0] = 0
 
-    @pl.when((sl == 0) | (live_pages(jnp.maximum(sl - 1, 0)) == 0))
+    @pl.when((step == 0) | (walk_of(jnp.maximum(step - 1, 0))[2] == 0))
     def _own_first_block():
-        copies(sl, 0, first_buf[0], start=True)
-
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def fold(block, buf, n_pages):
-        width = n_pages * ps
-        c = c_buf[buf, :width]                               # (width, Dl)
-        s = _all_heads_page_dot(qc_ref[0], c, 1) \
-            + _all_heads_page_dot(qr_ref[0], r_buf[buf, :, :width], 0)
-        tok = block * (pb * ps) + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, width), 1)
-        s = jnp.where(tok < extent, s, NEG_INF)
-        m = m_scr[...]
-        m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_next)                          # (rows, 128)
-        p = jnp.exp(s - m_next[:, :1])                       # (rows, width)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[...] = m_next
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] \
-            + _all_heads_page_dot(p, c, 0)                   # (rows, Dl)
+        copies(step, 0, first_buf[0], start=True)
 
     def walk(block, _):
         buf = (first_buf[0] + block) % 2
         last = block == n_blocks - 1
-        nxt_slot = jnp.where(last, jnp.minimum(sl + 1, n_slots - 1), sl)
+        nxt = jnp.where(last, jnp.minimum(step + 1, n_steps - 1), step)
 
-        @pl.when(~last | (sl + 1 < n_slots))
+        @pl.when(~last | (step + 1 < n_steps))
         def _next_block():
-            copies(nxt_slot, jnp.where(last, 0, block + 1), 1 - buf,
-                   start=True)
+            copies(nxt, jnp.where(last, 0, block + 1), 1 - buf, start=True)
 
-        copies(sl, block, buf, start=False)
-        here = jnp.minimum(n_live - block * pb, pb)
-        for n_pages in range(1, pb + 1):
-            pl.when(here == n_pages)(
-                functools.partial(fold, block, buf, n_pages))
+        copies(step, block, buf, start=False)
+        fold(block, buf, jnp.minimum(n_live - block * pb, pb))
 
     jax.lax.fori_loop(0, n_blocks, walk, None)
     first_buf[0] = (first_buf[0] + n_blocks) % 2
+
+
+def _latent_decode_shared_kernel(bt_ref, gs_ref, gp_ref, *refs, page_size,
+                                 pages_per_block, members):
+    """Part A of the latent decode: grid ``(groups,)``, one step a group
+    of up to ``members`` slots whose tables open with the same
+    ``gp_ref[g]`` pages, whole blocks of them. The members' queries
+    (``members`` blocks ``(1, rows, Dl)`` then as many ``(1, rows, Dr)``,
+    found by ``gs_ref``, a group's members first) are stacked as the rows
+    of one matrix against ONE copy of each shared page
+    (:func:`_latent_walk` over the first member's table; every row of a
+    shared page is live for every member, so nothing is masked; a block
+    is folded for the rows of the members the group has), and the
+    members' unnormalised float32 states ``[acc | m | l]`` leave as the
+    group's block ``(1, members*rows, Dl + 256)``. A group without pages
+    does nothing."""
+    g, ps, pb = members, page_size, pages_per_block
+    qc_refs, qr_refs = refs[:g], refs[g:2 * g]
+    (c_hbm, r_hbm, st_ref, c_buf, r_buf, sems, first_buf, qc_scr, qr_scr,
+     m_scr, l_scr, acc_scr) = refs[2 * g:]
+    rows, dl = qc_refs[0].shape[1:]
+    grp = pl.program_id(0)
+    held = sum((gs_ref[grp * g + j] >= 0).astype(jnp.int32)
+               for j in range(g))
+
+    def walk_of(of):
+        return jnp.maximum(gs_ref[of * g], 0), 0, gp_ref[of]
+
+    def fold(_block, buf, _pages):
+        for n in range(2, g + 1):       # a group of one is folded as two
+            head = pl.ds(0, n * rows)
+            pl.when(jnp.maximum(held, 2) == n)(functools.partial(
+                _latent_fold, qc_scr.at[head], qr_scr.at[head], c_buf,
+                r_buf, buf, m_scr.at[head], l_scr.at[head],
+                acc_scr.at[head], width=pb * ps))
+
+    @pl.when(gp_ref[grp] > 0)
+    def _stack():
+        for j in range(g):
+            qc_scr[j * rows:(j + 1) * rows] = qc_refs[j][0]
+            qr_scr[j * rows:(j + 1) * rows] = qr_refs[j][0]
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    _latent_walk(walk_of, fold, bt_ref, c_hbm, r_hbm, c_buf, r_buf, sems,
+                 first_buf, page_size=ps, pages_per_block=pb)
+
+    @pl.when(gp_ref[grp] > 0)
+    def _hand_over():
+        st_ref[0, :, :dl] = acc_scr[...]
+        st_ref[0, :, dl:dl + 128] = m_scr[...]
+        st_ref[0, :, dl + 128:] = l_scr[...]
+
+
+def _latent_decode_own_kernel(bt_ref, len_ref, sp_ref, row_ref, qc_ref,
+                              qr_ref, st_ref, c_hbm, r_hbm, o_ref, c_buf,
+                              r_buf, sems, first_buf, m_scr, l_scr, acc_scr,
+                              *, page_size, pages_per_block, spare):
+    """Part B of the latent decode: grid ``(S,)``, one step a slot. The
+    slot's state starts from what Part A left it (``st_ref`` ``(1, rows,
+    Dl + 256)``, block ``row_ref[slot]`` of the states) where
+    ``sp_ref[slot]`` of its pages were folded with its group's, empty
+    where its block is the ``spare`` one; :func:`_latent_walk` goes over
+    its own live pages from there on, and the state is normalised into
+    ``o_ref``."""
+    ps, pb = page_size, pages_per_block
+    sl = pl.program_id(0)
+    dl = o_ref.shape[2]
+
+    def walk_of(of):
+        n = (len_ref[of] + ps - 1) // ps
+        shared = jnp.minimum(sp_ref[of], n)
+        return of, shared, n - shared
+
+    def fold(block, buf, pages):
+        for n_pages in range(1, pb + 1):
+            pl.when(pages == n_pages)(functools.partial(
+                _latent_fold, qc_ref.at[0], qr_ref.at[0], c_buf, r_buf, buf,
+                m_scr, l_scr, acc_scr, width=n_pages * ps,
+                first_token=(walk_of(sl)[1] + block * pb) * ps,
+                extent=len_ref[sl]))
+
+    folded = row_ref[sl] != spare
+
+    @pl.when(folded)
+    def _from_the_group():
+        acc_scr[...] = st_ref[0, :, :dl]
+        m_scr[...] = st_ref[0, :, dl:dl + 128]
+        l_scr[...] = st_ref[0, :, dl + 128:]
+
+    @pl.when(~folded)
+    def _from_nothing():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    _latent_walk(walk_of, fold, bt_ref, c_hbm, r_hbm, c_buf, r_buf, sems,
+                 first_buf, page_size=ps, pages_per_block=pb)
     denom = l_scr[...][:, :1]
     denom = jnp.where(denom == 0.0, 1.0, denom)
     alive = m_scr[...][:, :1] > NEG_INF / 2
     o_ref[0] = jnp.where(alive, acc_scr[...] / denom, 0.0).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6))
+@functools.partial(jax.jit, static_argnums=(8, 9))
 def _latent_decode_pallas(q, c_pages, r_pages, block_tables, lengths,
+                          group_slots, group_pages, shared_pages,
                           interpret, pages_per_block):
-    """The ``pallas_call`` of ``latent_paged_decode``."""
+    """The two ``pallas_call``s of ``latent_paged_decode``, Part A a
+    group and Part B a slot, both under the kernel's one name."""
     s_slots, h, _ = q.shape
     ps, dl = c_pages.shape[1:]
     dr = r_pages.shape[1]
+    n_groups, g = group_slots.shape
     # the body copies a page out of each pool as it lies, which the chip's
     # compiler does only where a page is whole tiles
     if not interpret and (dl % 128 or ps % 128
@@ -1377,38 +1498,95 @@ def _latent_decode_pallas(q, c_pages, r_pages, block_tables, lengths,
     q = q.astype(c_pages.dtype)
     if rows != h:
         q = jnp.pad(q, ((0, 0), (0, rows - h), (0, 0)))
+    qc, qr = q[..., :dl], q[..., dl:]
+    block_tables = block_tables.astype(jnp.int32)
+    group_slots = group_slots.astype(jnp.int32).reshape(-1)
+    # Part A folds whole blocks: what is left of a group's pages is walked
+    # a slot (nothing, where the groups are :func:`latent_decode_groups`')
+    group_pages = group_pages.astype(jnp.int32) // pb * pb
+    shared_pages = shared_pages.astype(jnp.int32) // pb * pb
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",)) if not interpret else None
+
+    def buffers(n_rows):
+        return [pltpu.VMEM((2, pb * ps, dl), c_pages.dtype),
+                pltpu.VMEM((2, dr, pb * ps), r_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32)], [
+                pltpu.VMEM((n_rows, 128), jnp.float32),
+                pltpu.VMEM((n_rows, 128), jnp.float32),
+                pltpu.VMEM((n_rows, dl), jnp.float32)]
+
+    pools = [pl.BlockSpec(memory_space=pl.ANY),
+             pl.BlockSpec(memory_space=pl.ANY)]
+
+    # Part A. A member's queries come from its slot's block (a member a
+    # group lacks reads slot 0's); a group's states go to the group's
+    # block, those of every group without pages to one spare block
+    def member(j, width):
+        return pl.BlockSpec(
+            (1, rows, width),
+            lambda grp, _bt, gs, _gp: (jnp.maximum(gs[grp * g + j], 0), 0, 0))
+
+    walk, state = buffers(g * rows)
+    width = dl + _STATE_LANES
+    states = pl.pallas_call(
+        functools.partial(_latent_decode_shared_kernel, page_size=ps,
+                          pages_per_block=pb, members=g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_groups,),
+            in_specs=[member(j, dl) for j in range(g)]
+            + [member(j, dr) for j in range(g)] + pools,
+            out_specs=pl.BlockSpec(
+                (1, g * rows, width),
+                lambda grp, _bt, _gs, gp: (
+                    jnp.where(gp[grp] > 0, grp, n_groups), 0, 0)),
+            scratch_shapes=walk + [
+                pltpu.VMEM((g * rows, dl), c_pages.dtype),
+                pltpu.VMEM((g * rows, dr), c_pages.dtype)] + state),
+        out_shape=jax.ShapeDtypeStruct((n_groups + 1, g * rows, width),
+                                       jnp.float32),
+        compiler_params=params,
+        interpret=interpret,
+        name="latent_paged_decode",
+    )(block_tables, group_slots, group_pages, *[qc] * g, *[qr] * g,
+      c_pages, r_pages)
+
+    # Part B. Where a slot's state lies: member j of group grp at block
+    # grp * g + j of the states seen a member a block; a slot that is in
+    # no group, had no whole block folded or is dead at the spare group's
+    # first
+    spare = n_groups * g
+    lengths = lengths.astype(jnp.int32)
+    mine = group_slots[None, :] == jnp.arange(s_slots)[:, None]
+    state_rows = jnp.where(
+        mine.any(1) & (shared_pages > 0) & (lengths > 0),
+        jnp.argmax(mine, 1), spare).astype(jnp.int32)
 
     def slot_block(width):
         return pl.BlockSpec((1, rows, width), lambda s, *_prefetch: (s, 0, 0))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_slots,),
-        in_specs=[slot_block(dl), slot_block(dr),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=slot_block(dl),
-        scratch_shapes=[
-            pltpu.VMEM((2, pb * ps, dl), c_pages.dtype),
-            pltpu.VMEM((2, dr, pb * ps), r_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, dl), jnp.float32),
-        ],
-    )
+    walk, state = buffers(rows)
     out = pl.pallas_call(
-        functools.partial(_latent_decode_walk_kernel, page_size=ps,
-                          pages_per_block=pb),
-        grid_spec=grid_spec,
+        functools.partial(_latent_decode_own_kernel, page_size=ps,
+                          pages_per_block=pb, spare=spare),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(s_slots,),
+            in_specs=[slot_block(dl), slot_block(dr),
+                      pl.BlockSpec(
+                          (1, rows, width),
+                          lambda s, _bt, _len, _sp, row: (row[s], 0, 0))]
+            + pools,
+            out_specs=slot_block(dl),
+            scratch_shapes=walk + state),
         out_shape=jax.ShapeDtypeStruct((s_slots, rows, dl), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)) if not interpret else None,
+        compiler_params=params,
         interpret=interpret,
         name="latent_paged_decode",
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q[..., :dl], q[..., dl:], c_pages, r_pages)
+    )(block_tables, lengths, shared_pages, state_rows, qc, qr,
+      states.reshape(spare + g, rows, width), c_pages, r_pages)
     return out[:, :h]
 
 
@@ -1619,8 +1797,57 @@ def ragged_paged_prefill_int8_attention(q, k_pages, v_pages, k_scales,
                             chunk_starts, n_valid, impl=impl, scale=scale)
 
 
+def latent_decode_groups(block_tables, lengths, slots, page_size,
+                         members=LATENT_GROUP):
+    """The groups ``latent_paged_decode`` folds, made on the host (NumPy)
+    from what the tables say and nothing else: among ``slots`` (the
+    decoding ones) those whose tables open with the same page ids, a set
+    cut into groups of at most ``members``, a group's shared pages the
+    longest run that is common to its members and whole in each
+    (``lengths // page_size``: shared pages are read-only, a slot grows in
+    pages of its own, so a group holds while its members' tables do), in
+    whole multiples of ``LATENT_SHARED_PAGES``.
+    Fixed shapes whatever is shared: ``group_slots`` (S // 2, members)
+    int32, -1 where a group has no such member; ``group_pages`` (S // 2,)
+    int32, 0 for a group that is none; ``shared_pages`` (S,) int32, the
+    leading pages of a slot that its group's walk covers, 0 for a slot
+    walked alone."""
+    import numpy as np
+    n_slots = np.shape(block_tables)[0]
+    group_slots = np.full((max(n_slots // 2, 1), members), -1, np.int32)
+    group_pages = np.zeros((group_slots.shape[0],), np.int32)
+    shared_pages = np.zeros((n_slots,), np.int32)
+    slots = np.asarray(slots, np.int64)
+    if len(slots) < 2:
+        return group_slots, group_pages, shared_pages
+    bt = np.asarray(block_tables)
+    slots = slots[np.argsort(bt[slots, 0], kind="stable")]
+    rows = bt[slots]
+    at = np.arange(len(slots))
+    # a set is a run of one first page, a group `members` of a set in a
+    # row; ``head``: where a slot's group begins
+    begins = np.r_[True, rows[1:, 0] != rows[:-1, 0]]
+    seat = (at - np.maximum.accumulate(np.where(begins, at, 0))) % members
+    head = at - seat
+    starts = np.flatnonzero(seat == 0)
+    sizes = np.diff(np.r_[starts, len(slots)])
+    common = (rows == rows[head]).cumprod(1).sum(1)
+    pages = np.minimum.reduceat(
+        np.minimum(common, np.asarray(lengths)[slots] // page_size), starts)
+    pages -= pages % LATENT_SHARED_PAGES
+    # the groups kept, in order, and each slot's place among them
+    kept = (sizes >= 2) & (pages > 0)
+    place = np.repeat(np.where(kept, np.cumsum(kept) - 1, -1), sizes)
+    held = place >= 0
+    group_slots[place[held], seat[held]] = slots[held]
+    group_pages[:kept.sum()] = pages[kept]
+    shared_pages[slots[held]] = group_pages[place[held]]
+    return group_slots, group_pages, shared_pages
+
+
 def latent_paged_decode_attention(q, c_pages, r_pages, block_tables,
-                                  lengths, *, impl: str = "auto"):
+                                  lengths, groups=None, *,
+                                  impl: str = "auto"):
     """One decode step of attention over latent rows, every slot at once.
 
     ``q`` (S, H, Dl + Dr): each head's absorbed query, already scaled;
@@ -1628,11 +1855,18 @@ def latent_paged_decode_attention(q, c_pages, r_pages, block_tables,
     values; ``r_pages`` (P, Dr, page_size) the shared rotary keys, tokens
     along the lanes; ``block_tables`` (S, max_pages) int32; ``lengths``
     (S,) int32 live tokens a slot. Every head scores the same row
-    ``[c | k_rope]`` of a token and sums its ``c``. Returns (S, H, Dl).
+    ``[c | k_rope]`` of a token and sums its ``c``. ``groups``:
+    ``(group_slots, group_pages, shared_pages)`` as
+    :func:`latent_decode_groups` makes them, the slots whose tables open
+    with the same pages: the kernel reads those pages once a group, the
+    members' queries stacked against them; None: every slot walked alone.
+    The result is attention a slot either way. Returns (S, H, Dl).
     """
     from paddle_tpu import kernels
+    if groups is None:      # the shapes of a table that groups no slot
+        groups = latent_decode_groups(block_tables, lengths, (), 1)
     return kernels.dispatch("latent_paged_decode", q, c_pages, r_pages,
-                            block_tables, lengths, impl=impl)
+                            block_tables, lengths, *groups, impl=impl)
 
 
 def latent_paged_prefill_attention(q, c_pages, r_pages, block_tables,
@@ -2062,9 +2296,11 @@ _LATENT_Q_ROWS = (256, 1024)
 
 
 def _latent_decode_kernel_pallas(q, c_pages, r_pages, block_tables, lengths,
-                                 *, block_sizes, interpret):
+                                 group_slots, group_pages, shared_pages, *,
+                                 block_sizes, interpret):
     return _latent_decode_pallas(
-        q, c_pages, r_pages, block_tables, lengths, interpret,
+        q, c_pages, r_pages, block_tables, lengths, group_slots,
+        group_pages, shared_pages, interpret,
         block_sizes.get("pages_per_block", 1))
 
 
@@ -2096,8 +2332,10 @@ def _latent_attend_np(q, rows, dl):
     return (p / p.sum(-1, keepdims=True)) @ rows[:, :dl]
 
 
-def _latent_decode_reference(q, c_pages, r_pages, block_tables, lengths):
-    """NumPy per-slot attention over the rows: independent of both impls."""
+def _latent_decode_reference(q, c_pages, r_pages, block_tables, lengths,
+                             *_groups):
+    """NumPy per-slot attention over the rows: independent of both impls
+    and of which slots share pages."""
     import numpy as np
     dl = c_pages.shape[-1]
     qn, bt = np.asarray(q, np.float32), np.asarray(block_tables)
@@ -2126,10 +2364,15 @@ def _latent_prefill_reference(q, c_pages, r_pages, block_tables,
 
 def _make_latent_sample(seed, *, chunked):
     """Three shapes by ``seed % 3``: float32 pools of pages scattered
-    over the pool, slots of every length from empty to full."""
+    over the pool, slots of every length from empty to full. Decode:
+    tables long enough for some slots' to open with the same pages (a
+    pair; ten, which is a whole group and a pair; three and two), grouped
+    as the engine groups them."""
     import numpy as np
     s_slots, h, dl, dr, ps, mp = (
         (4, 2, 16, 8, 8, 3), (6, 4, 32, 8, 16, 4), (8, 4, 64, 16, 16, 6)
+    )[seed % 3] if chunked else (
+        (4, 2, 16, 8, 8, 10), (12, 4, 32, 8, 8, 11), (8, 4, 64, 16, 16, 12)
     )[seed % 3]
     c = ps
     num_pages = s_slots * mp + 1
@@ -2139,14 +2382,22 @@ def _make_latent_sample(seed, *, chunked):
     r_pages = jnp.asarray(rng.standard_normal((num_pages, dr, ps)),
                           jnp.float32)
     perm = rng.permutation(num_pages - 1)[:s_slots * mp] + 1
-    block_tables = jnp.asarray(perm.reshape(s_slots, mp), jnp.int32)
+    tables = perm.reshape(s_slots, mp).astype(np.int32)
     scale = (dl + dr) ** -0.5           # the caller's: queries come scaled
     if not chunked:
         q = jnp.asarray(scale * rng.standard_normal((s_slots, h, dl + dr)),
                         jnp.float32)
-        lengths = jnp.asarray(
-            rng.integers(0, mp * ps + 1, s_slots), jnp.int32)
-        return (q, c_pages, r_pages, block_tables, lengths), {}
+        lengths = rng.integers(0, mp * ps + 1, s_slots).astype(np.int32)
+        for sharers, k in ((([0, 1], 8),), ((list(range(10)), 9),),
+                           (([0, 1, 2], 10), ([3, 4], 8)))[seed % 3]:
+            tables[sharers, :k] = tables[sharers[0], :k]
+            lengths[sharers] = rng.integers(k * ps, mp * ps + 1,
+                                            len(sharers))
+        groups = latent_decode_groups(tables, lengths, np.arange(s_slots),
+                                      ps)
+        return (q, c_pages, r_pages, jnp.asarray(tables),
+                jnp.asarray(lengths)) + tuple(map(jnp.asarray, groups)), {}
+    block_tables = jnp.asarray(tables)
     q = jnp.asarray(scale * rng.standard_normal((s_slots, c, h, dl + dr)),
                     jnp.float32)
     starts = jnp.asarray(rng.integers(0, (mp - 1) * ps, s_slots), jnp.int32)
@@ -2161,18 +2412,21 @@ def _latent_tune_signature(args, kwargs):
            ("mp", bt.shape[1])]
     if q.ndim == 4:
         sig.insert(1, ("c", q.shape[1]))
+    else:
+        sig.append(("g", args[5].shape[1]))
     return tuple(sig)
 
 
 def _latent_vmem_estimate(args, kwargs, blocks):
     """VMEM working set of one grid step of the latent bodies, tiles
-    padded as the chip lays them out. Decode: its own two buffers of
-    ``pb`` pages of each pool, the queries and output double-buffered,
-    the state, and one update ``pb`` pages wide (float32 scores and
-    weights, the weights' three bf16 terms and their product). Chunked
-    prefill: ``pb`` pages of each pool, the query tile and the output
-    tile double-buffered by the pipeline, the tile's state, and one
-    page's scores and weights."""
+    padded as the chip lays them out. Decode, the larger of its two
+    parts, a group's: its own two buffers of ``pb`` pages of each pool,
+    the members' queries double-buffered and stacked, the group's state
+    block double-buffered, the state, and one update ``pb`` pages wide
+    for every member's rows (float32 scores and weights, the weights'
+    three bf16 terms and their product). Chunked prefill: ``pb`` pages
+    of each pool, the query tile and the output tile double-buffered by
+    the pipeline, the tile's state, and one page's scores and weights."""
     q, c_pages, r_pages = args[:3]
     ps, dl = c_pages.shape[1:]
     dr = r_pages.shape[1]
@@ -2185,9 +2439,10 @@ def _latent_vmem_estimate(args, kwargs, blocks):
 
     pages = pb * (tiled(ps, dl, isz) + tiled(dr, ps, isz))
     if q.ndim == 3:
-        rows = q.shape[1] + -q.shape[1] % _HEAD_ROWS
+        rows = (q.shape[1] + -q.shape[1] % _HEAD_ROWS) * args[5].shape[1]
         width = pb * ps
-        io = 2 * (2 * tiled(rows, dl, isz) + tiled(rows, dr, isz))
+        io = 3 * (tiled(rows, dl, isz) + tiled(rows, dr, isz)) \
+            + 2 * tiled(rows, dl + _STATE_LANES, 4)
         state = 2 * tiled(rows, 128, 4) + tiled(rows, dl, 4)
         fold = (2 * tiled(rows, width, 4) + tiled(3 * rows, width, 2)
                 + tiled(3 * rows, dl, 4))
@@ -2370,16 +2625,26 @@ def _register_paged_kernels():
     kernels.register(kernels.KernelSpec(
         name="latent_paged_decode",
         contract=kernels.KernelContract(
-            version=1,
+            version=2,
             arg_layouts={"q": "(S,H,Dl+Dr)", **latent_layouts,
-                         "lengths": "(S,) i32"},
+                         "lengths": "(S,) i32",
+                         "group_slots": "(S//2,G) i32",
+                         "group_pages": "(S//2,) i32",
+                         "shared_pages": "(S,) i32"},
             out_layout="(S,H,Dl)",
             donatable=("c_pages", "r_pages"),
-            grid="(S,) one step a slot, pools left in HBM: the body copies "
-                 "the slot's live pages of both pools itself, "
+            grid="two calls, pools left in HBM. (S//2,) one step a group "
+                 "of up to G slots whose tables open with the same "
+                 "group_pages pages (group_slots, -1 for no member): the "
+                 "members' queries stacked against ONE copy of each "
+                 "shared page, their float32 states [acc|m|l] handed on; "
+                 "then (S,) one step a slot, from that state over its own "
+                 "pages from shared_pages on (shared_pages * ps <= "
+                 "lengths; 0: the slot is walked alone). Either body "
+                 "copies the live pages of both pools itself, "
                  "pages_per_block side by side into one of two VMEM "
                  "buffers while it folds the other; a block one softmax "
-                 "update for all heads, the page of c read once for the "
+                 "update for all rows, the page of c read once for the "
                  "scores and the weighted sum",
             block_candidates=decode_pb_candidates,
             atol=2e-5, rtol=2e-5),

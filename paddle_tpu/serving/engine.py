@@ -861,30 +861,86 @@ class ServingEngine:
             return
         rows = r.counter(
             "serving_latent_rows_read_total",
-            "cached latent rows x layers the latent kernels had to read: "
-            "a decode token step every live token of each decoding slot, "
-            "a prefill call each lane's context and chunk")
+            "cached latent rows x layers the latent kernels HAD to read, "
+            "the least any kernel could: a decode token step each "
+            "DISTINCT live row of the decoding slots once (a page that "
+            "several slots' tables hold counts once), a prefill call "
+            "each lane's context and chunk")
+        fetched = r.counter(
+            "serving_latent_rows_fetched_total",
+            "cached latent rows x layers the latent kernels' walks "
+            "copied: a decode token step a group's shared rows once a "
+            "group and every slot's own rows, a prefill call what it "
+            "has to read")
         pairs = r.counter(
             "serving_latent_pairs_total",
             "(query token, cached row) pairs x layers the latent kernels "
             "scored, every head each: a decode token step one query a "
             "slot (equal to the rows), a prefill call each chunk token "
             "against the rows up to its own")
-        self._c_latent = {ph: (rows.child(phase=ph), pairs.child(phase=ph))
+        self._c_latent = {ph: tuple(c.child(phase=ph)
+                                    for c in (rows, pairs, fetched))
                           for ph in ("decode", "prefill")}
+        #: the groups of the last decode block, kept while the decoding
+        #: slots and their tables stay what they were: (slots, their
+        #: table rows, the three arrays on the device, rows a token step
+        #: that a second slot holds too, rows the groups' walks spare)
+        self._latent_groups = None
         r.gauge("serving_kv_pool_bytes", _KV_POOL_HELP).set(
             sum(a.nbytes for ent in self.cache.pages for a in ent),
             layers="latent")
 
-    def _count_latent(self, span, phase, rows: int, pairs: int):
-        """One round's or call's latent rows and pairs (``rows``,
-        ``pairs``: of ONE layer), and the span's ``latent_rows``."""
+    def _count_latent(self, span, phase, rows: int, pairs: int,
+                      fetched: int):
+        """One round's or call's latent rows, pairs and rows fetched
+        (each of ONE layer), and the span's ``latent_rows``."""
         layers = self.cache.config.num_layers
-        c_rows, c_pairs = self._c_latent[phase]
-        c_rows.inc(rows * layers)
-        c_pairs.inc(pairs * layers)
+        for child, n in zip(self._c_latent[phase], (rows, pairs, fetched)):
+            child.inc(n * layers)
         if span is not None:
             span.set_attrs(latent_rows=rows * layers)
+
+    def _group_latent_decode(self, dslots):
+        """Who shares what in a decode block of a program of latent rows,
+        from the decoding slots' tables alone: (the three arrays
+        :func:`decode_attention.latent_decode_groups` makes, uploaded; the
+        live rows a token step that a second decoding slot holds too,
+        which no kernel has to read twice; the rows the groups' walks do
+        not copy twice). Pages that several tables hold are whole and
+        read-only and a slot grows in pages of its own, so all three hold
+        for every token step of the block, and from block to block while
+        the decoding slots and their tables stay what they were."""
+        cache, ps = self.cache, self.cache.config.page_size
+        tables = cache.block_tables[dslots]
+        lens = cache.lengths[dslots]
+        kept = self._latent_groups
+        if kept is not None and np.array_equal(kept[0], dslots) \
+                and np.array_equal(kept[1], tables):
+            return kept[2:]
+        groups = DA.latent_decode_groups(cache.block_tables, cache.lengths,
+                                         dslots, ps)
+        spared = int(((groups[0] >= 0).sum(1) - 1).clip(0)
+                     @ groups[1].astype(np.int64)) * ps
+        # the distinct live rows: a page some slot holds whole counts
+        # whole, however many hold it; the page a slot is filling counts
+        # as far as the longest of its holders goes
+        whole = lens // ps
+        held = np.bincount(
+            tables[np.arange(tables.shape[1])[None, :] < whole[:, None]],
+            minlength=cache.config.num_pages) > 0
+        filling = tables[np.arange(len(lens)),
+                         np.minimum(whole, tables.shape[1] - 1)]
+        part = (lens > whole * ps) & ~held[filling]
+        ids, rows = filling[part], (lens - whole * ps)[part]
+        order = np.argsort(ids, kind="stable")
+        distinct = ps * int(held.sum()) + (int(np.maximum.reduceat(
+            rows[order], np.flatnonzero(np.diff(ids[order], prepend=-1))
+        ).sum()) if len(ids) else 0)
+        twice = int(lens.sum()) - distinct
+        self._latent_groups = (np.asarray(dslots).copy(), tables,
+                               tuple(jnp.asarray(a) for a in groups),
+                               twice, spared)
+        return self._latent_groups[2:]
 
     def _count_window(self, span, before, after):
         """One round's or call's K/V by layer kind, from the lengths the
@@ -1501,8 +1557,12 @@ class ServingEngine:
                     tokens[i] = -(1 + j) - k * s_tot
             live = self._count_kv_bytes(dslots, n, w)
             self._count_state(rnd.span, decoding=len(dslots), token_steps=n)
+            groups = ()
             if self._latent:
-                self._count_latent(rnd.span, "decode", live, live)
+                shared, twice, spared = self._group_latent_decode(dslots)
+                groups = (shared,)
+                self._count_latent(rnd.span, "decode", live - n * twice,
+                                   live, live - n * spared)
             if self._window_layers:
                 lens = self.cache.lengths[dslots]
                 self._count_window(rnd.span, lens, lens + np.asarray(
@@ -1515,7 +1575,7 @@ class ServingEngine:
             # just below, a table when its slot is freed)
             args = (jnp.asarray(self.cache.block_tables[:, :w].copy()),
                     jnp.asarray(self.cache.lengths.copy()),
-                    tok_dev, jnp.asarray(active))
+                    tok_dev, jnp.asarray(active)) + groups
             for i, (_, keep, _) in rows.items():
                 self.cache.lengths[i] += keep
         with phase("serving.decode.dispatch",
@@ -2066,7 +2126,8 @@ class ServingEngine:
                     self._count_latent(
                         call.span, "prefill", sum(los) + call_tokens,
                         sum(lo * n + n * (n + 1) // 2
-                            for lo, n in zip(los, ns)))
+                            for lo, n in zip(los, ns)),
+                        sum(los) + call_tokens)
                 dbt_dev = jnp.asarray(dbt_rows[:, :w]) if self.speculative \
                     else None
             with phase("serving.prefill.dispatch",
@@ -2277,6 +2338,8 @@ class ServingEngine:
                 args = (self._step_params, self.cache.pages,
                         jnp.zeros((s_tot, w), jnp.int32), zeros, tok0,
                         zeros)
+                if self._latent:    # no slot shares a page with another
+                    args += (self._group_latent_decode([])[0],)
                 if cost_gauges:
                     self._bucket_cost_gauges(sig, self.decode_step, args)
                 _, self.cache.pages = self.decode_step(*args)
@@ -3022,14 +3085,16 @@ class ServingEngine:
             * self.cache.config.page_size > spec.select_topk
 
     def _attend_decode(self, spec, q, ent, block_tables, lengths, index,
-                       quantized):
+                       quantized, groups=None):
         """One decode token a slot, ``q`` (S, H, Dh), over the pool entry
         ``ent`` as just written; ``lengths`` counts this token. Returns
         (heads (S, H, Dh), tokens attended a slot (S,)); a program of
-        latent rows: ``q`` against the whole row, heads (S, H, latent)."""
+        latent rows: ``q`` against the whole row, heads (S, H, latent),
+        the slots of a group of ``groups`` over ONE copy of the pages
+        their tables open with."""
         if spec.latent_row is not None:
             return DA.latent_paged_decode_attention(
-                q, ent[0], ent[1], block_tables, lengths,
+                q, ent[0], ent[1], block_tables, lengths, groups,
                 impl=self.attn_impl), lengths
         if quantized:
             return DA.ragged_paged_decode_int8_attention(
@@ -3111,8 +3176,8 @@ class ServingEngine:
                      for _name, width in spec.layer_carry)
 
     def _decode_loop(self, params, pages, block_tables, lengths, tokens,
-                     active, n_valid=None, *, program=None, quantized=False,
-                     n_steps=1, psum_axis=None):
+                     active, n_valid=None, groups=None, *, program=None,
+                     quantized=False, n_steps=1, psum_axis=None):
         """The shared greedy token loop behind the decode step AND the
         draft-proposal step, written against what a model supplies
         (``program``, see :mod:`paddle_tpu.serving.program`): ``n_steps``
@@ -3128,8 +3193,11 @@ class ServingEngine:
         below ``n_steps`` must not write past the slot's reservation.
         Under tp the program's body is one head shard's; ``psum_axis``
         completes the int8 scales' abs-max over the shards so
-        quantization stays bit-identical to tp=1. The keyword-only args
-        are static config (default-marked so the AST host-sync lint,
+        quantization stays bit-identical to tp=1. ``groups`` (a program
+        of latent rows): which decoding slots' tables open with the same
+        pages, fixed for the block
+        (:func:`decode_attention.latent_decode_groups`). The keyword-only
+        args are static config (default-marked so the AST host-sync lint,
         which runs on THIS body via the graph_lint preset, seeds only
         the array args as tracers). Returns (tokens (S, n_steps), pages),
         or ((tokens, counts), pages) where the program counts
@@ -3183,7 +3251,8 @@ class ServingEngine:
                     if win is None:
                         att, attended = self._attend_decode(
                             spec, q[:, :, 0, :], ent, block_tables,
-                            lengths + 1, index, quantized)      # (S,H,Dh)
+                            lengths + 1, index, quantized,
+                            groups)                             # (S,H,Dh)
                     else:
                         _, table, held = ringed[win]
                         att = DA.ragged_paged_decode_attention(
@@ -3229,7 +3298,7 @@ class ServingEngine:
         return ((out, totals) if n_stats else out), pages
 
     def _decode_step_impl(self, params, pages, block_tables, lengths,
-                          tokens, active):
+                          tokens, active, groups=None):
         """Fixed-shape batched decode of ONE BLOCK of ``decode_block``
         tokens per slot — one host round-trip per block instead of per
         token. Non-decoding lanes (``active == 0``: free slots AND
@@ -3237,9 +3306,12 @@ class ServingEngine:
         not corrupt) write to the null page; post-EOS/post-cap lanes
         write past their reservation into the null page and produce
         discarded garbage (the host keeps only in-budget, pre-EOS
-        tokens). Returns (tokens (S, decode_block), pages)."""
+        tokens). ``groups``: only a program of latent rows is handed
+        them (None there: every slot walked alone). Returns (tokens (S,
+        decode_block), pages)."""
         return self._decode_loop(params, pages, block_tables, lengths,
-                                 tokens, active, program=self.program,
+                                 tokens, active, groups=groups,
+                                 program=self.program,
                                  quantized=self.quantized,
                                  n_steps=self.decode_block,
                                  psum_axis="tp" if self.tp > 1 else None)

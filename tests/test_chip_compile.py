@@ -787,12 +787,16 @@ def test_latent_kernels_vmem_estimates_hold_for_v5e(name, q_shape, geometry,
     """Each latent kernel alone at the cell's shapes and the static
     prior's block sizes: it compiles for the chip, and the estimate that
     chose the blocks is not under what the compiler scoped."""
+    from paddle_tpu.serving.decode_attention import LATENT_GROUP
     sds = jax.ShapeDtypeStruct
     args = (sds(q_shape, jnp.bfloat16),
             sds((MS_PAGES, MS_PS, 256), jnp.bfloat16),
             sds((MS_PAGES, 64, MS_PS), jnp.bfloat16),
             sds((q_shape[0], MS_WIDTH), jnp.int32)) + tuple(
                 sds((q_shape[0],), jnp.int32) for _ in range(geometry))
+    if name == "latent_paged_decode":       # the groups: who shares what
+        args += (sds((MS_SLOTS // 2, LATENT_GROUP), jnp.int32),
+                 sds((MS_SLOTS // 2,), jnp.int32), sds((MS_SLOTS,), jnp.int32))
     spec = kernels.get(name)
     blocks = autotune.static_prior(spec, args, {})
     assert spec.vmem_estimate(args, {}, blocks) <= autotune.VMEM_BUDGET_BYTES

@@ -26,6 +26,7 @@ import pytest
 from paddle_tpu import inference, kernels
 from paddle_tpu import observability as obs
 from paddle_tpu.models.mla_moe_lm import MLAMoELM, MLAMoELMConfig
+from paddle_tpu.serving import decode_attention as DA
 from paddle_tpu.serving.paged_cache import PagedCacheConfig, PagedKVCache
 from paddle_tpu.serving.program import FEATURES, ServingSpec
 
@@ -264,10 +265,9 @@ def test_absorbed_decode_is_expanded_attention(impl):
     lengths = np.asarray([mp * ps, 11, 0], np.int32)
     qt = np.concatenate([np.einsum("shd,lhd->shl", q_nope, w_uk), q_rope],
                         -1)
-    u = np.asarray(kernels.dispatch(
-        "latent_paged_decode", jnp.asarray(qt), jnp.asarray(c_pages),
-        jnp.asarray(r_pages), jnp.asarray(table), jnp.asarray(lengths),
-        impl=impl))
+    u = np.asarray(DA.latent_paged_decode_attention(
+        jnp.asarray(qt), jnp.asarray(c_pages), jnp.asarray(r_pages),
+        jnp.asarray(table), jnp.asarray(lengths), impl=impl))
     got = np.einsum("shl,lhv->shv", u, w_uv)
     for sl, n in enumerate(lengths):
         c = c_pages[table[sl]].reshape(-1, dc)[:n]
@@ -301,21 +301,102 @@ def test_latent_kernels_at_every_block_size(name, blocks):
         atol=spec.contract.atol, rtol=spec.contract.rtol)
 
 
+@pytest.fixture(scope="module")
+def shared_pool():
+    """Twelve slots over one float32 pool, pages of 8 tokens: slots 0-9
+    open with the same ten pages, slots 10 and 11 share nothing."""
+    rng = np.random.default_rng(43)
+    s, h, dl, dr, ps, mp = 12, 2, 16, 8, 8, 12
+    f = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa
+    tables = (1 + rng.permutation(s * mp)).reshape(s, mp).astype(np.int32)
+    tables[:10, :10] = tables[0, :10]
+    return dict(q=0.2 * f(s, h, dl + dr), c_pages=f(s * mp + 1, ps, dl),
+                r_pages=f(s * mp + 1, dr, ps), tables=tables, ps=ps)
+
+
+def _groups_by_hand(members, pages, slots=12):
+    """One group, written out: what the engine's grouping would never
+    make (a group of one, a dead member, pages that are no whole block)."""
+    group_slots = np.full((slots // 2, DA.LATENT_GROUP), -1, np.int32)
+    group_slots[0, :len(members)] = members
+    group_pages = np.zeros((slots // 2,), np.int32)
+    group_pages[0] = pages
+    shared_pages = np.zeros((slots,), np.int32)
+    shared_pages[list(members)] = pages
+    return group_slots, group_pages, shared_pages
+
+
+FOLDS = {
+    # (lengths of slots 0-11, the groups: None = the engine's own)
+    "a_full_group": ([96, 90, 81, 96, 70, 88, 65, 93, 3, 0, 40, 96], None),
+    "a_set_cut_in_two": ([96, 90, 81, 96, 70, 88, 65, 93, 77, 80, 40, 9],
+                         None),
+    "own_parts_of_every_length": ([64, 65, 72, 73, 96, 0, 0, 0, 0, 0, 1, 0],
+                                  None),
+    "a_group_of_one": ([96, 90, 81, 96, 70, 88, 65, 93, 77, 80, 40, 9],
+                       _groups_by_hand([3], 8)),
+    "a_dead_slot_in_a_group": ([96, 0, 81, 0, 70, 88, 65, 93, 77, 80, 40, 9],
+                               _groups_by_hand([0, 1, 2, 3], 8)),
+    "pages_left_over_a_block": ([96, 90, 81, 96, 85, 88, 85, 93, 83, 80, 40,
+                                 9], _groups_by_hand([0, 1, 2, 3, 4], 10)),
+    "nothing_shared": ([96, 90, 81, 96, 70, 88, 65, 93, 77, 80, 40, 9],
+                       _groups_by_hand([], 0)),
+}
+
+
+@pytest.mark.parametrize("case", list(FOLDS))
+def test_slots_folded_over_shared_pages_attend_as_each_would_alone(
+        case, shared_pool):
+    """``latent_paged_decode`` with the members of a group stacked against
+    one copy of their shared pages, against per-slot attention in NumPy:
+    eight sharers; ten, cut into eight and two; own parts from nothing (a
+    slot that ends on the shared pages' edge) to four pages; and groups
+    only a caller could write: of one, with a dead slot inside, over pages
+    that are no whole block (the kernel rounds them down and walks the
+    rest a slot). With nothing grouped every slot is walked alone, to the
+    same tolerance (the groups left out:
+    ``test_absorbed_decode_is_expanded_attention``)."""
+    pool = shared_pool
+    lengths = np.asarray(FOLDS[case][0], np.int32)
+    groups = FOLDS[case][1]
+    if groups is None:
+        groups = DA.latent_decode_groups(
+            pool["tables"], lengths, np.flatnonzero(lengths), pool["ps"])
+        members = (groups[0] >= 0).sum(1)
+        assert sorted(members[members > 0]) == {
+            "a_full_group": [8], "a_set_cut_in_two": [2, 8],
+            "own_parts_of_every_length": [5]}[case]
+        assert set(groups[1][members > 0]) == {8}
+    spec = kernels.get("latent_paged_decode")
+    args = tuple(jnp.asarray(pool[k]) for k in (
+        "q", "c_pages", "r_pages", "tables")) + (jnp.asarray(lengths),)
+    got = np.asarray(kernels.dispatch(
+        "latent_paged_decode", *args, *map(jnp.asarray, groups),
+        impl="pallas_interpret", block_sizes={"pages_per_block": 4}))
+    want = np.asarray(spec.reference_fn(*args))
+    np.testing.assert_allclose(got, want, atol=spec.contract.atol,
+                               rtol=spec.contract.rtol)
+    assert not got[lengths == 0].any()
+
+
 def test_vmem_estimates_at_the_published_widths():
     """128 slots of 32 heads over rows of 256 + 64 in pages of 128: the
-    decode body's buffers of 8 pages a pool and the prefill body's tile of
-    1024 rows both fit the chip's 16 MiB default scope several times."""
+    decode body's buffers of 8 pages a pool beside one update for a
+    group's 8 x 32 stacked rows fit the chip's 16 MiB default scope
+    twice, the prefill body's tile of 1024 rows fits it."""
     sds = jax.ShapeDtypeStruct
     pools = (sds((4993, 128, 256), jnp.bfloat16),
              sds((4993, 64, 128), jnp.bfloat16))
     decode = kernels.get("latent_paged_decode").vmem_estimate(
-        (sds((128, 32, 320), jnp.bfloat16),) + pools, {},
+        (sds((128, 32, 320), jnp.bfloat16),) + pools
+        + (sds((128, 134), jnp.int32), sds((128,), jnp.int32),
+           sds((64, DA.LATENT_GROUP), jnp.int32)), {},
         {"pages_per_block": 8})
     prefill = kernels.get("latent_paged_prefill").vmem_estimate(
         (sds((8, 256, 32, 320), jnp.bfloat16),) + pools, {},
         {"pages_per_block": 4, "q_rows": 1024})
     page = 128 * 320 * 2                    # nothing padded: whole tiles
-    assert 2 * 8 * page < decode < 6 << 20
+    assert 2 * 8 * page < decode < 8 << 20
     assert 2 * 4 * page < prefill < 16 << 20
 
 
@@ -486,6 +567,108 @@ def test_counters_and_spans_of_the_latent_rows(engines):
     assert sum(s.attrs["latent_rows"] for s in rounds) == rows
     assert [s.attrs["latent_rows"] for s in calls] == [8 * LAYERS,
                                                        13 * LAYERS]
+
+
+def _decode_blocks_of(eng, run):
+    """``run()`` with every decode block's decoding slots, tables and
+    lengths as dispatch found them and the groups it made: [(slots,
+    tables, lengths, (group_slots, group_pages, shared_pages))]."""
+    seen, dispatch = [], eng._dispatch_block
+
+    def watched(dslots, w, rnd):
+        before = (list(dslots), eng.cache.block_tables.copy(),
+                  eng.cache.lengths.copy())
+        blk = dispatch(dslots, w, rnd)
+        seen.append(before + (tuple(
+            np.asarray(a) for a in eng._latent_groups[2]),))
+        return blk
+    eng._dispatch_block = watched
+    try:
+        run()
+    finally:
+        del eng._dispatch_block
+    return seen
+
+
+def _rows_by_hand(blocks, n_steps):
+    """What the three decode counters of one layer must add up to over
+    ``blocks``: (the distinct (page, row) the decoding slots hold, token
+    step by token step; every slot's rows; what the walks copy: a group's
+    shared pages once, a slot's rows behind its shared pages)."""
+    distinct = pairs = fetched = 0
+    for slots, tables, lengths, (_, group_pages, shared_pages) in blocks:
+        for j in range(1, n_steps + 1):
+            held = set()
+            for i in slots:
+                n = int(lengths[i]) + j
+                held |= {(int(tables[i, t // PAGE]), t % PAGE)
+                         for t in range(n)}
+                pairs += n
+                fetched += n - int(shared_pages[i]) * PAGE
+            distinct += len(held)
+            fetched += int(group_pages.sum()) * PAGE
+    return distinct, pairs, fetched
+
+
+def test_requests_over_a_published_prefix_decode_folded(engines):
+    """Two requests that open with the 64 tokens a third published are
+    decoded as one group over ONE copy of those eight pages (the Pallas
+    body, interpreted), and emit the tokens they emit when the cache
+    shares nothing (the same engine, its cache told not to share: the
+    step programs are the ones already compiled). The decode counters:
+    rows read are the distinct (page, row) of the decoding slots by
+    brute force, rows fetched the walks' own sum, pairs every slot's
+    rows. Two that share only two pages are no group (a group's pages
+    are whole blocks of eight): each walk copies its own, and the rows
+    that HAD to be read are still fewer. With nothing shared all three
+    are the same number."""
+    eng, _sink, reg, _ = engines("pallas_interpret")
+    n_new, n_steps = 5, eng.decode_block
+    names = [f'serving_latent_{kind}_total{{phase="decode"}}'
+             for kind in ("rows_read", "pairs", "rows_fetched")]
+
+    def serve(asks, sharing=True):
+        shares = eng.cache.config
+        eng.cache.config = dataclasses.replace(shares, share_prefix=sharing)
+        before, outs = reg.snapshot(), []
+        try:
+            blocks = _decode_blocks_of(eng, lambda: outs.extend(
+                eng.generate_many(asks, max_new_tokens=n_new)))
+        finally:
+            eng.cache.config = shares
+        snap = reg.snapshot()
+        counts = [int(snap[k] - before.get(k, 0)) // LAYERS for k in names]
+        assert tuple(counts) == _rows_by_hand(blocks, n_steps)
+        return ([list(o) for o in outs], counts,
+                [b[3] for b in blocks if len(b[0]) == 2])
+
+    def asks_over(n_prefix, seed):
+        """Two requests over one prefix, after a third published it."""
+        prefix = _prompt(n_prefix, seed=seed)
+        eng.generate_many([np.concatenate([prefix, _prompt(2, seed=seed)])],
+                          max_new_tokens=2)
+        return [np.concatenate([prefix, _prompt(n, seed=seed + n)])
+                for n in (3, 6)]
+
+    asks = asks_over(64, 640)
+    shared0 = eng.cache.shared_tokens_total
+    folded, (rows, pairs, fetched), both = serve(asks)
+    assert eng.cache.shared_tokens_total - shared0 == 2 * 64
+    assert both and all(
+        pages.tolist() == [8] and shared.tolist() == [8, 8]
+        and sorted(slots[0][:2]) == [0, 1] for slots, pages, shared in both)
+    # a group of two copies the prefix once: 64 rows a token step spared
+    assert rows == fetched == pairs - 64 * n_steps * len(both)
+
+    alone, (rows, pairs, fetched), both = serve(asks, sharing=False)
+    assert alone == folded
+    assert both and not any(pages.any() or shared.any()
+                            for _, pages, shared in both)
+    assert rows == pairs == fetched
+
+    _, (rows, pairs, fetched), both = serve(asks_over(16, 160))
+    assert both and not any(pages.any() for _, pages, _ in both)
+    assert rows == pairs - 16 * n_steps * len(both) and fetched == pairs
 
 
 # -- the benchmark's copy ---------------------------------------------------------
