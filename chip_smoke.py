@@ -775,10 +775,17 @@ def _sparse_kernel_args(name, cfg, sizes, seed):
             lengths), {}
     kp, vp = normal(num_pages, ps, kv * dh), normal(num_pages, ps, kv * dh)
     if name == "sparse_paged_decode":
+        # the two slots open with the same eight pages: one group
+        from paddle_tpu.serving import decode_attention as DA
+        tables = np.asarray(bt).copy()
+        tables[1, :8] = tables[0, :8]
+        groups = DA.decode_groups(tables, np.asarray(lengths),
+                                         np.arange(s), ps)
         scores = jnp.asarray(rng.standard_normal((s, mp * ps)), jnp.float32)
-        idx, n_sel = SA.select_decode(scores, lengths, topk)
+        selected = SA.select_decode_mask(scores, lengths, topk)
         return (jnp.asarray(rng.standard_normal((s, h, dh)), jnp.float32),
-                kp, vp, bt, idx, n_sel), {}
+                kp, vp, jnp.asarray(tables), selected, lengths,
+                *map(jnp.asarray, groups)), {}
     starts = lengths - c
     n_valid = jnp.asarray([c, c - 3], jnp.int32)
     scores = jnp.asarray(rng.standard_normal((s, c, mp * ps)), jnp.float32)

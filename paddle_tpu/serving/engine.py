@@ -768,6 +768,7 @@ class ServingEngine:
         self._bind_state_metrics(r)
         self._bind_window_metrics(r)
         self._bind_latent_metrics(r)
+        self._bind_sparse_metrics(r)
         self._c_step_stats = [
             r.counter(f"serving_{name}_total", _STEP_STAT_HELP.get(
                 name, "a count the step program hands back")).child()
@@ -857,6 +858,16 @@ class ServingEngine:
         #: fixed with the pools: the steps of a program without latent
         #: rows never ask again
         self._latent = self.cache.config.latent_row is not None
+        #: whether this program's decode folds the pages that several
+        #: slots' tables open with into one walk (latent rows, or a token
+        #: selection): its decode step is handed the groups
+        self._folds = self._latent \
+            or self.program.spec.select_topk is not None
+        #: the groups of the last decode block, kept while the decoding
+        #: slots and their tables stay what they were: (slots, their
+        #: table rows, the three arrays on the device, rows a token step
+        #: that a second slot holds too, rows the groups' walks spare)
+        self._decode_groups = None
         if not self._latent:
             return
         rows = r.counter(
@@ -881,11 +892,6 @@ class ServingEngine:
         self._c_latent = {ph: tuple(c.child(phase=ph)
                                     for c in (rows, pairs, fetched))
                           for ph in ("decode", "prefill")}
-        #: the groups of the last decode block, kept while the decoding
-        #: slots and their tables stay what they were: (slots, their
-        #: table rows, the three arrays on the device, rows a token step
-        #: that a second slot holds too, rows the groups' walks spare)
-        self._latent_groups = None
         r.gauge("serving_kv_pool_bytes", _KV_POOL_HELP).set(
             sum(a.nbytes for ent in self.cache.pages for a in ent),
             layers="latent")
@@ -900,10 +906,28 @@ class ServingEngine:
         if span is not None:
             span.set_attrs(latent_rows=rows * layers)
 
-    def _group_latent_decode(self, dslots):
-        """Who shares what in a decode block of a program of latent rows,
-        from the decoding slots' tables alone: (the three arrays
-        :func:`decode_attention.latent_decode_groups` makes, uploaded; the
+    def _bind_sparse_metrics(self, r):
+        """The two series of a program that selects the tokens it attends
+        to (``spec.select_topk``), fed from the tables and lengths the
+        host holds; any other program binds neither."""
+        if self.program.spec.select_topk is None:
+            return
+        self._c_sparse_fetched = r.counter(
+            "serving_sparse_rows_fetched_total",
+            "cached K/V rows x layers the sparse decode's walks copied, a "
+            "token step of a bucket that selects: a group's shared rows "
+            "once a group, every slot's own rows once").child()
+        self._c_sparse_held = r.counter(
+            "serving_sparse_rows_held_total",
+            "live cached K/V rows x layers of the decoding slots, a slot "
+            "at a time, a token step of a bucket that selects: what the "
+            "walks copy where every slot is walked alone").child()
+
+    def _group_decode(self, dslots):
+        """Who shares what in a decode block of a program whose decode
+        folds shared pages (``self._folds``), from the decoding slots'
+        tables alone: (the three arrays
+        :func:`decode_attention.decode_groups` makes, uploaded; the
         live rows a token step that a second decoding slot holds too,
         which no kernel has to read twice; the rows the groups' walks do
         not copy twice). Pages that several tables hold are whole and
@@ -913,21 +937,31 @@ class ServingEngine:
         cache, ps = self.cache, self.cache.config.page_size
         tables = cache.block_tables[dslots]
         lens = cache.lengths[dslots]
-        kept = self._latent_groups
+        kept = self._decode_groups
         if kept is not None and np.array_equal(kept[0], dslots) \
                 and np.array_equal(kept[1], tables):
             return kept[2:]
-        groups = DA.latent_decode_groups(cache.block_tables, cache.lengths,
-                                         dslots, ps)
+        groups = DA.decode_groups(cache.block_tables, cache.lengths, dslots,
+                                  ps)
         spared = int(((groups[0] >= 0).sum(1) - 1).clip(0)
                      @ groups[1].astype(np.int64)) * ps
-        # the distinct live rows: a page some slot holds whole counts
-        # whole, however many hold it; the page a slot is filling counts
-        # as far as the longest of its holders goes
+        # (only the latent family's series read the second)
+        twice = self._rows_held_twice(tables, lens) if self._latent else 0
+        self._decode_groups = (np.asarray(dslots).copy(), tables,
+                               tuple(jnp.asarray(a) for a in groups),
+                               twice, spared)
+        return self._decode_groups[2:]
+
+    def _rows_held_twice(self, tables, lens):
+        """The live rows of slots with block tables ``tables`` and
+        ``lens`` tokens that a second of them holds too: a page some slot
+        holds whole counts whole, however many hold it; the page a slot
+        is filling counts as far as the longest of its holders goes."""
+        ps = self.cache.config.page_size
         whole = lens // ps
         held = np.bincount(
             tables[np.arange(tables.shape[1])[None, :] < whole[:, None]],
-            minlength=cache.config.num_pages) > 0
+            minlength=self.cache.config.num_pages) > 0
         filling = tables[np.arange(len(lens)),
                          np.minimum(whole, tables.shape[1] - 1)]
         part = (lens > whole * ps) & ~held[filling]
@@ -936,11 +970,7 @@ class ServingEngine:
         distinct = ps * int(held.sum()) + (int(np.maximum.reduceat(
             rows[order], np.flatnonzero(np.diff(ids[order], prepend=-1))
         ).sum()) if len(ids) else 0)
-        twice = int(lens.sum()) - distinct
-        self._latent_groups = (np.asarray(dslots).copy(), tables,
-                               tuple(jnp.asarray(a) for a in groups),
-                               twice, spared)
-        return self._latent_groups[2:]
+        return int(lens.sum()) - distinct
 
     def _count_window(self, span, before, after):
         """One round's or call's K/V by layer kind, from the lengths the
@@ -1413,9 +1443,10 @@ class ServingEngine:
         L + j + 1 (the formula ``benchmark/flops.paged_decode_bytes``
         applies from outside). "Gathered" is every slot of the batch,
         live or not, at ``w`` whole pages: the block table's width, which
-        the pipelined decode bodies (int8 pages, sparse rows) lay their
-        grid over. The dense decode kernel walks a slot's live pages
-        itself since PR 39 and lays out no width, so for it the ratio
+        the pipelined decode body (int8 pages) lays its grid over. The
+        dense decode kernel walks a slot's live pages itself since PR 39
+        (the sparse one since PR 44) and lays out no width, so for it the
+        ratio
         says how wide the table's bucket is for what the slots hold, not
         what the kernel spent. Returns the live tokens attended over, a
         layer."""
@@ -1558,11 +1589,17 @@ class ServingEngine:
             live = self._count_kv_bytes(dslots, n, w)
             self._count_state(rnd.span, decoding=len(dslots), token_steps=n)
             groups = ()
-            if self._latent:
-                shared, twice, spared = self._group_latent_decode(dslots)
+            if self._folds:
+                shared, twice, spared = self._group_decode(dslots)
                 groups = (shared,)
-                self._count_latent(rnd.span, "decode", live - n * twice,
-                                   live, live - n * spared)
+                if self._latent:
+                    self._count_latent(rnd.span, "decode", live - n * twice,
+                                       live, live - n * spared)
+                elif self._selects(self.program.spec,
+                                   self.cache.block_tables[:, :w]):
+                    layers = self.cache.config.num_layers
+                    self._c_sparse_fetched.inc((live - n * spared) * layers)
+                    self._c_sparse_held.inc(live * layers)
             if self._window_layers:
                 lens = self.cache.lengths[dslots]
                 self._count_window(rnd.span, lens, lens + np.asarray(
@@ -2338,8 +2375,8 @@ class ServingEngine:
                 args = (self._step_params, self.cache.pages,
                         jnp.zeros((s_tot, w), jnp.int32), zeros, tok0,
                         zeros)
-                if self._latent:    # no slot shares a page with another
-                    args += (self._group_latent_decode([])[0],)
+                if self._folds:     # no slot shares a page with another
+                    args += (self._group_decode([])[0],)
                 if cost_gauges:
                     self._bucket_cost_gauges(sig, self.decode_step, args)
                 _, self.cache.pages = self.decode_step(*args)
@@ -3089,9 +3126,10 @@ class ServingEngine:
         """One decode token a slot, ``q`` (S, H, Dh), over the pool entry
         ``ent`` as just written; ``lengths`` counts this token. Returns
         (heads (S, H, Dh), tokens attended a slot (S,)); a program of
-        latent rows: ``q`` against the whole row, heads (S, H, latent),
-        the slots of a group of ``groups`` over ONE copy of the pages
-        their tables open with."""
+        latent rows: ``q`` against the whole row, heads (S, H, latent).
+        A program of latent rows, and one that selects in a bucket wide
+        enough, read the pages that the tables of a group of ``groups``
+        open with ONCE for the group's slots."""
         if spec.latent_row is not None:
             return DA.latent_paged_decode_attention(
                 q, ent[0], ent[1], block_tables, lengths, groups,
@@ -3102,7 +3140,8 @@ class ServingEngine:
         if self._selects(spec, block_tables):
             return SA.indexed_decode_attention(
                 q, *ent, block_tables, lengths, index[0][:, 0],
-                index[1][:, 0], spec.select_topk, impl=self.attn_impl)
+                index[1][:, 0], spec.select_topk, groups=groups,
+                impl=self.attn_impl)
         return DA.ragged_paged_decode_attention(
             q, ent[0], ent[1], block_tables, lengths,
             impl=self.attn_impl), lengths
@@ -3194,9 +3233,10 @@ class ServingEngine:
         Under tp the program's body is one head shard's; ``psum_axis``
         completes the int8 scales' abs-max over the shards so
         quantization stays bit-identical to tp=1. ``groups`` (a program
-        of latent rows): which decoding slots' tables open with the same
+        whose decode folds shared pages: latent rows, or a token
+        selection): which decoding slots' tables open with the same
         pages, fixed for the block
-        (:func:`decode_attention.latent_decode_groups`). The keyword-only
+        (:func:`decode_attention.decode_groups`). The keyword-only
         args are static config (default-marked so the AST host-sync lint,
         which runs on THIS body via the graph_lint preset, seeds only
         the array args as tracers). Returns (tokens (S, n_steps), pages),
@@ -3306,9 +3346,9 @@ class ServingEngine:
         not corrupt) write to the null page; post-EOS/post-cap lanes
         write past their reservation into the null page and produce
         discarded garbage (the host keeps only in-budget, pre-EOS
-        tokens). ``groups``: only a program of latent rows is handed
-        them (None there: every slot walked alone). Returns (tokens (S,
-        decode_block), pages)."""
+        tokens). ``groups``: only a program whose decode folds shared
+        pages is handed them (None there: every slot walked alone).
+        Returns (tokens (S, decode_block), pages)."""
         return self._decode_loop(params, pages, block_tables, lengths,
                                  tokens, active, groups=groups,
                                  program=self.program,

@@ -27,19 +27,26 @@ Three kernels register with the shared kernel layer:
   (positions at or past ``extent[s]`` read 0). Pallas: grid ``(S, mp /
   pb)``, block-table scalar prefetch, ``pb`` whole key pages a step.
 ``sparse_paged_decode`` — one query a slot over ``topk`` selected
-  tokens: the selected K and V rows are gathered out of the pool (XLA
-  gather, 2 x ``topk`` rows a slot where dense decode streams every
-  page) into a contiguous run of ``topk / page_size`` pages a slot, and
-  the paged decode body folds those. ``lax.top_k`` returns the selection
-  best first, so the live ones are a prefix and the run needs no mask
-  beyond a length.
+  tokens. The kernel reads the K and V pools themselves: it walks whole
+  pages (a selected row cannot be fetched cheaper than the tile it lies
+  in, and nearly every tile of a long context holds one) and takes the
+  selection as a mask on the scores, ``(S, mp * page_size)`` float32,
+  as the prefill kernel does. No copy of the selected rows is made.
+  Slots whose block tables open with the same pages (requests over one
+  published document) are folded into ONE walk of those pages, their
+  queries stacked against one copy of each, and each slot's own pages
+  are walked from the state that walk left it: two Pallas calls under
+  the kernel's one name, the groups made on the host from the tables
+  (``decode_attention.decode_groups``).
 ``sparse_paged_prefill`` — a chunk of queries, each with its own
   selection: the paged prefill body with the selection as one more
   streamed input, applied beside the causal test inside the fold.
 
-Selection itself is XLA (``lax.top_k``: ties go to the lower position).
-A query at position ``p`` with ``p + 1 <= topk`` attends to everything
-it can see, as the model defines.
+Selection itself is XLA (``lax.top_k``: ties go to the lower position),
+handed on as a mask by one rule for both (:func:`_chosen`: the scores
+against the value of the last of the top-k). A query at position
+``p`` with ``p + 1 <= topk`` attends to everything it can see, as the
+model defines.
 """
 
 from __future__ import annotations
@@ -264,6 +271,31 @@ def select_decode(scores, lengths, topk):
     return idx.astype(jnp.int32), jnp.minimum(lengths, topk)
 
 
+def _chosen(masked, topk, tok):
+    """``masked`` (..., T) scores, ``-inf`` where the query cannot see,
+    ``tok`` the positions ``arange(T)`` -> bool (..., T), the ``topk``
+    best: above the value of the last of the top-k, or at it and no
+    later than the last position ``lax.top_k`` took there (ties go to
+    the lower position)."""
+    vals, idx = jax.lax.top_k(masked, topk)
+    thr = vals[..., -1:]
+    last_tie = jnp.max(jnp.where(vals == thr, idx, -1), axis=-1,
+                       keepdims=True)
+    return (masked > thr) | ((masked == thr) & (tok <= last_tie))
+
+
+def select_decode_mask(scores, lengths, topk):
+    """:func:`select_decode`'s selection as a mask, by the rule chunked
+    prefill has (:func:`select_prefill`), with no scatter of the indices:
+    ``scores`` (S, T) -> (S, T) float32, 1 at the tokens the slot's
+    query attends to, none at or past ``lengths[s]``."""
+    tok = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+    live = tok[None, :] < lengths[:, None]
+    chosen = _chosen(jnp.where(live, scores, -jnp.inf), topk, tok)
+    everything = (lengths <= topk)[:, None]
+    return (live & (everything | chosen)).astype(jnp.float32)
+
+
 def select_prefill(scores, chunk_starts, n_valid, topk):
     """Chunked prefill's selection: ``scores`` (S, C, T), query ``c`` of
     slot ``s`` at position ``chunk_starts[s] + c`` -> (S, C, T) float32,
@@ -274,64 +306,356 @@ def select_prefill(scores, chunk_starts, n_valid, topk):
     tok = jnp.arange(t, dtype=jnp.int32)
     pos = chunk_starts[:, None] + jnp.arange(c, dtype=jnp.int32)   # (S, C)
     seen = tok[None, None, :] <= pos[:, :, None]
-    masked = jnp.where(seen, scores, -jnp.inf)
-    vals, idx = jax.lax.top_k(masked, topk)
-    thr = vals[..., -1:]                                           # (S,C,1)
-    last_tie = jnp.max(jnp.where(vals == thr, idx, -1), axis=-1,
-                       keepdims=True)
-    chosen = (masked > thr) | ((masked == thr)
-                               & (tok[None, None, :] <= last_tie))
+    chosen = _chosen(jnp.where(seen, scores, -jnp.inf), topk, tok)
     everything = (pos + 1 <= topk)[:, :, None]
     return (seen & (everything | chosen)).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
-# sparse decode: gather the selected rows, fold them with the paged body
+# sparse decode: walk whole pages of the pools under the selection
 # ---------------------------------------------------------------------------
+#
+# A row of the pool cannot be fetched cheaper than the tile it lies in,
+# and a bf16 pool's tiles of 16 rows nearly all hold a selected row (2048
+# of 15.7k tokens: 89% of them). So the body copies WHOLE pages out of
+# the K and V pools, as dense decode does (``decode_attention
+# ._paged_decode_walk_kernel``), and the selection is a mask on the
+# scores, as the sparse prefill body takes it. It is two calls under one
+# name, laid out as the latent decode's are: the pages that the tables of
+# several decoding slots open with are walked ONCE a group of those slots
+# (Part A, one grid step a group), and each slot's own pages a slot from
+# the state Part A handed it (Part B, one grid step a slot); a slot in no
+# group is Part B's alone, over all its pages. Who shares what comes from
+# the host (``decode_attention.decode_groups``).
+#
+# The queries meet a block a KV HEAD at a time: the rows of one product
+# are the ``Hq / kv`` query heads of that KV head (padded to 8), of every
+# member of the group one under the other, against the head's 128-lane
+# slice of the block. No row is pushed through another head's lanes, and
+# the slice is whole lane tiles of the buffer as it lies.
 
-def _gather_selected(k_pages, v_pages, block_tables, sel_idx):
-    """The selected tokens' K and V rows as a pool of their own: slot
-    ``s`` owns pages ``s*n .. (s+1)*n`` (``n = topk / page_size``), in
-    selection order. An XLA row gather out of the ``(P*ps, lanes)`` view
-    of the pool (the view moves nothing)."""
-    p, ps, lanes = k_pages.shape
-    s, topk = sel_idx.shape
-    # a token's page out of the block table as a one-hot product (exact
-    # in float32 for any page number below 2**24): the chip does a
-    # scalar gather of 65536 table entries in 0.5 ms a layer
-    onehot = jax.nn.one_hot(sel_idx // ps, block_tables.shape[1],
-                            dtype=jnp.float32)
-    page = jnp.einsum("stp,sp->st", onehot, block_tables.astype(
-        jnp.float32), precision=_FP32_DOT).astype(jnp.int32)
-    rows = (page * ps + sel_idx % ps).reshape(-1)
-    n = topk // ps
-    ks = k_pages.reshape(p * ps, lanes)[rows].reshape(s * n, ps, lanes)
-    vs = v_pages.reshape(p * ps, lanes)[rows].reshape(s * n, ps, lanes)
-    bt = jnp.arange(s * n, dtype=jnp.int32).reshape(s, n)
-    return ks, vs, bt
+#: what a member's rows in a KV head's product of Part A are padded to
+#: (the query heads of one KV head; a float32 sublane tile)
+_MEMBER_ROWS = 8
 
 
-def _sparse_decode_pallas(q, k_pages, v_pages, block_tables, sel_idx, n_sel,
-                          *, block_sizes, interpret, scale=None):
+def _fold_selected(q, k, v, live, m_ref, l_ref, acc_ref):
+    """One softmax update of one KV head's rows with a block of its
+    tokens: ``q`` (rows, Dh), ``k`` / ``v`` (width, Dh) the head's lanes
+    of the block, ``live`` (rows, width) which tokens each row attends
+    to. Scores, maximum, sum and accumulator float32; the weights go into
+    ``P V`` as their three bf16 terms (``_all_heads_page_dot``). A row
+    that has met no live token yet sums under ``m = NEG_INF`` what the
+    first live one wipes with ``alpha = 0``."""
+    s = DA._all_heads_page_dot(q, k, 1)                      # (rows, width)
+    s = jnp.where(live, s, DA.NEG_INF)
+    m = m_ref[...]
+    m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_next)                              # (rows, 128)
+    p = jnp.exp(s - m_next[:, :1])
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = m_next
+    acc_ref[...] = acc_ref[...] * alpha[:, :1] \
+        + DA._all_heads_page_dot(p, v, 0)                    # (rows, Dh)
+
+
+def _fold_block(q_ref, k_buf, v_buf, buf, live, m_scr, l_scr, acc_scr, *,
+                width):
+    """:func:`_fold_selected` of every KV head with the first ``width``
+    tokens of block ``buf``: ``q_ref`` (kv, rows, Dh), the state ``(kv,
+    rows, .)``, a head's lanes a slice of whole lane tiles."""
+    kv, _, dh = q_ref.shape
+
+    def one_kv_head(g, _):
+        lanes = pl.ds(pl.multiple_of(g * dh, dh), dh)
+        _fold_selected(q_ref[g], k_buf[buf, :width, lanes],
+                       v_buf[buf, :width, lanes], live,
+                       m_scr.at[g], l_scr.at[g], acc_scr.at[g])
+
+    jax.lax.fori_loop(0, kv, one_kv_head, None)
+
+
+def _selection_rows(sel_ref, first_page, n_pages, rows):
+    """``sel_ref`` (1, mp, ps) a slot's selection a page a row -> (rows,
+    n_pages * ps) bool: pages ``first_page ..`` side by side, every row
+    the same."""
+    ps = sel_ref.shape[-1]
+    return jnp.concatenate(
+        [jnp.broadcast_to(sel_ref[0, pl.ds(first_page + t, 1), :],
+                          (rows, ps)) for t in range(n_pages)], axis=1) > 0
+
+
+def _kv_moves(k_hbm, v_hbm, k_buf, v_buf, page_size):
+    def moves(page, buf, t):
+        rows = pl.ds(t * page_size, page_size)
+        return ((k_hbm.at[page], k_buf.at[buf, rows]),
+                (v_hbm.at[page], v_buf.at[buf, rows]))
+    return moves
+
+
+def _reset(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, DA.NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _sparse_decode_shared_kernel(bt_ref, gs_ref, gp_ref, q_ref, *refs,
+                                 page_size, pages_per_block, members):
+    """Part A: grid ``(groups,)``, one step a group of up to ``members``
+    slots whose tables open with the same ``gp_ref[g]`` pages, whole
+    blocks of them. ``q_ref`` (1, kv, members*8, Dh) holds the members'
+    queries a KV head, a member's 8 rows under the member's before;
+    ``refs`` opens with the members' selections (``members`` blocks (1,
+    mp, ps), found by ``gs_ref``, as ``q_ref``'s rows were). Every row of
+    a shared page is live for every member, so the mask is the selection
+    alone. A member the group lacks folds some slot's rows, which nobody
+    reads. The members' unnormalised float32 states ``[acc | m | l]``
+    leave as the group's block (1, kv, members*8, Dh + 256)."""
+    g, ps, pb = members, page_size, pages_per_block
+    sel_refs = refs[:g]
+    (k_hbm, v_hbm, st_ref, k_buf, v_buf, sems, first_buf, m_scr, l_scr,
+     acc_scr) = refs[g:]
+    dh = q_ref.shape[-1]
+    member_rows = q_ref.shape[2] // g
+    grp = pl.program_id(0)
+
+    def walk_of(of):
+        return jnp.maximum(gs_ref[of * g], 0), 0, gp_ref[of]
+
+    def fold(block, buf, _pages):
+        live = jnp.concatenate(
+            [_selection_rows(sel, block * pb, pb, member_rows)
+             for sel in sel_refs], axis=0)
+        _fold_block(q_ref.at[0], k_buf, v_buf, buf, live, m_scr, l_scr,
+                    acc_scr, width=pb * ps)
+
+    pl.when(gp_ref[grp] > 0)(
+        functools.partial(_reset, m_scr, l_scr, acc_scr))
+    DA._page_walk(walk_of, fold, bt_ref,
+                  _kv_moves(k_hbm, v_hbm, k_buf, v_buf, ps), sems,
+                  first_buf, pages_per_block=pb)
+
+    @pl.when(gp_ref[grp] > 0)
+    def _hand_over():
+        st_ref[0, :, :, :dh] = acc_scr[...]
+        st_ref[0, :, :, dh:dh + 128] = m_scr[...]
+        st_ref[0, :, :, dh + 128:] = l_scr[...]
+
+
+def _sparse_decode_own_kernel(bt_ref, ext_ref, sp_ref, row_ref, q_ref,
+                              sel_ref, st_ref, k_hbm, v_hbm, o_ref, k_buf,
+                              v_buf, sems, first_buf, m_scr, l_scr, acc_scr,
+                              *, page_size, pages_per_block, spare):
+    """Part B: grid ``(S,)``, one step a slot. ``q_ref`` (1, kv, rows,
+    Dh) the slot's queries a KV head (``rows``: the heads of one, padded
+    to whole bf16 tiles), ``sel_ref`` (1, mp, ps) its selection. The
+    state starts from what Part A left the slot (``st_ref`` (1, kv, 1, 8,
+    Dh + 256), block ``row_ref[slot]`` of the states) where
+    ``sp_ref[slot]`` of its pages were folded with its group's, from
+    nothing where its block is the ``spare`` one; the walk goes over its
+    own pages from there to the one that holds token ``ext_ref[slot] -
+    1``, exactly the fetched pages folded, and the state is normalised
+    into ``o_ref`` (1, kv, rows, Dh). The selection marks no token at or
+    past the slot's extent, so it is the whole mask here too."""
+    ps, pb = page_size, pages_per_block
+    sl = pl.program_id(0)
+    rows, dh = q_ref.shape[2:]
+
+    def walk_of(of):
+        n = (ext_ref[of] + ps - 1) // ps
+        shared = jnp.minimum(sp_ref[of], n)
+        return of, shared, n - shared
+
+    def fold(block, buf, pages):
+        first = walk_of(sl)[1] + block * pb
+
+        def fold_pages(n_pages):
+            _fold_block(q_ref.at[0], k_buf, v_buf, buf,
+                        _selection_rows(sel_ref, first, n_pages, rows),
+                        m_scr, l_scr, acc_scr, width=n_pages * ps)
+
+        for n_pages in range(1, pb + 1):
+            pl.when(pages == n_pages)(
+                functools.partial(fold_pages, n_pages))
+
+    _reset(m_scr, l_scr, acc_scr)
+
+    @pl.when(row_ref[sl] != spare)
+    def _from_the_group():
+        own = slice(0, st_ref.shape[3])
+        acc_scr[:, own] = st_ref[0, :, 0, :, :dh]
+        m_scr[:, own] = st_ref[0, :, 0, :, dh:dh + 128]
+        l_scr[:, own] = st_ref[0, :, 0, :, dh + 128:]
+
+    DA._page_walk(walk_of, fold, bt_ref,
+                  _kv_moves(k_hbm, v_hbm, k_buf, v_buf, ps), sems,
+                  first_buf, pages_per_block=pb)
+    denom = l_scr[...][:, :, :1]
+    denom = jnp.where(denom == 0.0, 1.0, denom)
+    alive = m_scr[...][:, :, :1] > DA.NEG_INF / 2
+    o_ref[0] = jnp.where(alive, acc_scr[...] / denom, 0.0).astype(
+        o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10))
+def _sparse_decode_pallas(q, k_pages, v_pages, block_tables, selected,
+                          extent, group_slots, group_pages, shared_pages,
+                          interpret, pages_per_block):
+    """The two ``pallas_call``s of ``sparse_paged_decode``, Part A a
+    group and Part B a slot, both under the kernel's one name. ``q`` is
+    already scaled. Jitted, so that a step program traces and lowers the
+    bodies once and calls them from every layer."""
+    s_slots, h, dh = q.shape
+    ps, hd = k_pages.shape[1:]
+    mp = block_tables.shape[1]
+    kv = hd // dh
+    heads = h // kv                              # query heads a KV head
+    n_groups, g = group_slots.shape
+    # the bodies copy a page out of each pool as it lies and slice a KV
+    # head's lanes out of the copy, which the chip's compiler does only
+    # where both are whole tiles
+    if not interpret and (dh % 128 or ps % (32 // k_pages.dtype.itemsize)):
+        raise ValueError(
+            f"sparse_paged_decode copies whole pages out of the pools: "
+            f"pages of {ps} tokens, heads of {dh} lanes are not whole tiles")
+    # rows of a KV head's product: a member's in Part A, a slot's in
+    # Part B, which stacks nothing and pads to whole bf16 tiles itself
+    member_rows = heads + -heads % _MEMBER_ROWS
+    rows = heads + -heads % DA._HEAD_ROWS
+    if (g * member_rows) % DA._HEAD_ROWS:
+        raise ValueError(f"sparse_paged_decode stacks whole tiles of rows: "
+                         f"groups of {g} x {member_rows} rows are none")
+    pb = max(1, min(int(pages_per_block), mp))
+    block_tables = block_tables.astype(jnp.int32)
+    extent = extent.astype(jnp.int32)
+    group_slots = group_slots.astype(jnp.int32).reshape(-1)
+    # Part A folds whole blocks: what is left of a group's pages is walked
+    # a slot (nothing, where the groups are ``decode_groups``')
+    group_pages = group_pages.astype(jnp.int32) // pb * pb
+    shared_pages = shared_pages.astype(jnp.int32) // pb * pb
+    selected = selected.astype(jnp.float32).reshape(s_slots, mp, ps)
+    # a slot's queries a KV head: (S, kv, heads, Dh), padded with zero
+    # queries to the rows of each part
+    q = q.reshape(s_slots, kv, heads, dh)
+    q_own = jnp.pad(q, ((0, 0), (0, 0), (0, rows - heads), (0, 0)))
+    members = jnp.maximum(group_slots, 0).reshape(n_groups, g)
+    q_grp = jnp.pad(q, ((0, 0), (0, 0), (0, member_rows - heads), (0, 0))
+                    )[members]                   # (groups, g, kv, 8, Dh)
+    q_grp = q_grp.transpose(0, 2, 1, 3, 4).reshape(
+        n_groups, kv, g * member_rows, dh)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",)) if not interpret else None
+
+    def scratch(n_rows):
+        return [pltpu.VMEM((2, pb * ps, hd), k_pages.dtype),
+                pltpu.VMEM((2, pb * ps, hd), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((kv, n_rows, 128), jnp.float32),
+                pltpu.VMEM((kv, n_rows, 128), jnp.float32),
+                pltpu.VMEM((kv, n_rows, dh), jnp.float32)]
+
+    pools = [pl.BlockSpec(memory_space=pl.ANY),
+             pl.BlockSpec(memory_space=pl.ANY)]
+    width = dh + DA._STATE_LANES
+
+    # Part A. A member's selection comes from its slot's block (a member
+    # a group lacks reads slot 0's); a group's states go to the group's
+    # block, those of every group without pages to one spare block
+    def member_selection(j):
+        return pl.BlockSpec(
+            (1, mp, ps),
+            lambda grp, _bt, gs, _gp: (jnp.maximum(gs[grp * g + j], 0), 0, 0))
+
+    states = pl.pallas_call(
+        functools.partial(_sparse_decode_shared_kernel, page_size=ps,
+                          pages_per_block=pb, members=g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_groups,),
+            in_specs=[pl.BlockSpec((1, kv, g * member_rows, dh),
+                                   lambda grp, *_prefetch: (grp, 0, 0, 0))]
+            + [member_selection(j) for j in range(g)] + pools,
+            out_specs=pl.BlockSpec(
+                (1, kv, g * member_rows, width),
+                lambda grp, _bt, _gs, gp: (
+                    jnp.where(gp[grp] > 0, grp, n_groups), 0, 0, 0)),
+            scratch_shapes=scratch(g * member_rows)),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_groups + 1, kv, g * member_rows, width), jnp.float32),
+        compiler_params=params,
+        interpret=interpret,
+        name="sparse_paged_decode",
+    )(block_tables, group_slots, group_pages, q_grp, *[selected] * g,
+      k_pages, v_pages)
+
+    # Part B, from the state rows Part A left: member j of group grp at
+    # block (grp, j) of the states seen a member a block
+    spare = n_groups * g
+    state_rows = DA._group_state_rows(group_slots, shared_pages, extent,
+                                      spare)
+
+    def slot_block(*shape):
+        return pl.BlockSpec((1,) + shape,
+                            lambda s, *_prefetch: (s,) + (0,) * len(shape))
+
+    out = pl.pallas_call(
+        functools.partial(_sparse_decode_own_kernel, page_size=ps,
+                          pages_per_block=pb, spare=spare),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(s_slots,),
+            in_specs=[slot_block(kv, rows, dh), slot_block(mp, ps),
+                      pl.BlockSpec(
+                          (1, kv, 1, member_rows, width),
+                          lambda s, _bt, _ext, _sp, row: (
+                              row[s] // g, 0, row[s] % g, 0, 0))]
+            + pools,
+            out_specs=slot_block(kv, rows, dh),
+            scratch_shapes=scratch(rows)),
+        out_shape=jax.ShapeDtypeStruct((s_slots, kv, rows, dh), q.dtype),
+        compiler_params=params,
+        interpret=interpret,
+        name="sparse_paged_decode",
+    )(block_tables, extent, shared_pages, state_rows, q_own, selected,
+      states.reshape(n_groups + 1, kv, g, member_rows, width),
+      k_pages, v_pages)
+    return out[:, :, :heads].reshape(s_slots, h, dh)
+
+
+def _sparse_decode_kernel_pallas(q, k_pages, v_pages, block_tables,
+                                 selected, extent, group_slots, group_pages,
+                                 shared_pages, *, block_sizes, interpret,
+                                 scale=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    ks, vs, bt = _gather_selected(k_pages, v_pages, block_tables, sel_idx)
-    return DA._paged_decode_pallas(
-        q, ks, vs, bt, n_sel, scale, interpret,
-        pages_per_block=block_sizes.get("pages_per_block", 1),
-        name="sparse_paged_decode")
+    return _sparse_decode_pallas(
+        q * jnp.asarray(scale, q.dtype), k_pages, v_pages, block_tables,
+        selected, extent, group_slots, group_pages, shared_pages, interpret,
+        block_sizes.get("pages_per_block", 1))
 
 
-def _sparse_decode_lax(q, k_pages, v_pages, block_tables, sel_idx, n_sel, *,
-                       scale=None):
+def _sparse_decode_lax(q, k_pages, v_pages, block_tables, selected, extent,
+                       *_groups, scale=None):
+    """Attention a slot over the tokens its selection marks; which slots
+    share pages changes no result."""
+    s, h, dh = q.shape
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    ks, vs, bt = _gather_selected(k_pages, v_pages, block_tables, sel_idx)
-    return DA._paged_decode_lax(q, ks, vs, bt, n_sel, scale)
+        scale = 1.0 / math.sqrt(dh)
+    kg = DA._gather_pages(k_pages, block_tables, h, dh)
+    vg = DA._gather_pages(v_pages, block_tables, h, dh)
+    scores = jnp.einsum("shd,smthd->shmt", q.astype(jnp.float32),
+                        kg.astype(jnp.float32)) * scale
+    p = DA._latent_softmax(scores.reshape(s, h, -1),
+                           selected[:, None, :] > 0).reshape(scores.shape)
+    return jnp.einsum("shmt,smthd->shd", p,
+                      vg.astype(jnp.float32)).astype(q.dtype)
 
 
-def _sparse_decode_reference(q, k_pages, v_pages, block_tables, sel_idx,
-                             n_sel, *, scale=None):
+def _sparse_decode_reference(q, k_pages, v_pages, block_tables, selected,
+                             extent, *_groups, scale=None):
+    """NumPy, a slot and a head at a time over the slot's selected
+    tokens: independent of both impls and of which slots share pages."""
     import numpy as np
     s, h, dh = q.shape
     if scale is None:
@@ -341,10 +665,10 @@ def _sparse_decode_reference(q, k_pages, v_pages, block_tables, sel_idx,
     qn = np.asarray(q, np.float64)
     kp = np.asarray(k_pages, np.float64).reshape(-1, ps, kv, dh)
     vp = np.asarray(v_pages, np.float64).reshape(-1, ps, kv, dh)
-    bt, idx, ns = (np.asarray(a) for a in (block_tables, sel_idx, n_sel))
+    bt, sel = np.asarray(block_tables), np.asarray(selected) > 0
     out = np.zeros((s, h, dh))
     for sl in range(s):
-        toks = idx[sl, :int(ns[sl])]
+        toks = np.flatnonzero(sel[sl])
         if not len(toks):
             continue
         k = kp[bt[sl, toks // ps], toks % ps]              # (n, kv, dh)
@@ -358,10 +682,15 @@ def _sparse_decode_reference(q, k_pages, v_pages, block_tables, sel_idx,
 
 
 def _make_sparse_decode_sample(seed):
+    """Three shapes by ``seed % 3``: float32 pools of pages scattered
+    over the pool, slots of every length from empty to full, some of
+    them opening with the same pages (a pair; three and a pair; a whole
+    group of eight), grouped as the engine groups them."""
     import numpy as np
-    s, h, kv, dh, ps, mp, topk = ((3, 4, 2, 16, 4, 6, 8),
-                                  (2, 8, 2, 32, 8, 4, 16),
-                                  (4, 2, 2, 16, 4, 8, 8))[seed % 3]
+    s, h, kv, dh, ps, mp, topk, sharers = (
+        (3, 4, 2, 16, 4, 12, 8, (([0, 1], 8),)),
+        (6, 8, 2, 32, 8, 10, 16, (([0, 1, 2], 9), ([3, 5], 8))),
+        (9, 2, 2, 16, 4, 18, 8, ((list(range(8)), 16),)))[seed % 3]
     rng = np.random.default_rng(seed)
     num_pages = s * mp + 1
     q = jnp.asarray(rng.standard_normal((s, h, dh)), jnp.float32)
@@ -369,24 +698,59 @@ def _make_sparse_decode_sample(seed):
                      jnp.float32)
     vp = jnp.asarray(rng.standard_normal((num_pages, ps, kv * dh)),
                      jnp.float32)
-    bt = jnp.asarray((rng.permutation(num_pages - 1)[:s * mp] + 1)
-                     .reshape(s, mp), jnp.int32)
-    lengths = jnp.asarray(rng.integers(0, mp * ps + 1, s), jnp.int32)
+    tables = (rng.permutation(num_pages - 1)[:s * mp] + 1).reshape(
+        s, mp).astype(np.int32)
+    lengths = rng.integers(0, mp * ps + 1, s).astype(np.int32)
+    for slots, k in sharers:
+        tables[slots, :k] = tables[slots[0], :k]
+        lengths[slots] = rng.integers(k * ps, mp * ps + 1, len(slots))
     scores = jnp.asarray(rng.standard_normal((s, mp * ps)), jnp.float32)
-    idx, n = select_decode(scores, lengths, topk)
-    return (q, kp, vp, bt, idx, n), {}
+    selected = select_decode_mask(scores, jnp.asarray(lengths), topk)
+    groups = DA.decode_groups(tables, lengths, np.arange(s), ps)
+    return (q, kp, vp, jnp.asarray(tables), selected, jnp.asarray(lengths)
+            ) + tuple(map(jnp.asarray, groups)), {}
 
 
 def sparse_paged_decode_attention(q, k_pages, v_pages, block_tables,
                                   sel_idx, n_sel, *, scale=None,
-                                  impl: str = "auto"):
-    """One decode step of attention over each slot's SELECTED tokens.
-    ``sel_idx`` (S, topk) cache positions, the first ``n_sel[s]`` live;
-    ``topk`` a multiple of the page size. Returns (S, H, Dh)."""
+                                  impl: str = "auto", groups=None):
+    """One decode step of attention over each slot's SELECTED tokens,
+    for whoever holds a selection as indices (the engine's own path hands
+    the kernel a mask: :func:`indexed_decode_attention`). ``sel_idx`` (S,
+    topk) cache positions, the first ``n_sel[s]`` live. ``groups`` as
+    :func:`selected_decode_attention` takes them. Returns (S, H, Dh)."""
+    t = block_tables.shape[1] * k_pages.shape[1]
+    live = jnp.arange(sel_idx.shape[1])[None, :] < n_sel[:, None]
+    slot = jnp.arange(sel_idx.shape[0])[:, None]
+    # a dead entry lands past the row's end and is dropped
+    selected = jnp.zeros(sel_idx.shape[:1] + (t,), jnp.float32).at[
+        slot, jnp.where(live, sel_idx, t)].set(1.0, mode="drop")
+    extent = jnp.max(jnp.where(live, sel_idx + 1, 0), axis=1)
+    return selected_decode_attention(q, k_pages, v_pages, block_tables,
+                                     selected, extent, groups, scale=scale,
+                                     impl=impl)
+
+
+def selected_decode_attention(q, k_pages, v_pages, block_tables, selected,
+                              extent, groups=None, *, scale=None,
+                              impl: str = "auto"):
+    """One decode step of attention over the tokens ``selected`` (S, mp *
+    page_size) marks, 1 for a token the slot's query attends to and 0 for
+    every other, none at or past ``extent[s]`` (S,): the kernel walks a
+    slot's pages up to the one that holds token ``extent[s] - 1``.
+    ``groups``: ``(group_slots, group_pages, shared_pages)`` as
+    ``decode_attention.decode_groups`` makes them (groups of 8, as the
+    latent decode's: at the docs cell's geometry groups of 4 read 0.64 ms
+    a call for 0.53, PERF.md section 6, PR 44), the slots whose tables
+    open with the same pages: the kernel reads those pages once a group;
+    None: every slot walked alone. The result is attention a slot either
+    way. Returns (S, H, Dh)."""
     from paddle_tpu import kernels
+    if groups is None:      # the shapes of a table that groups no slot
+        groups = DA.decode_groups(block_tables, extent, (), 1)
     return kernels.dispatch("sparse_paged_decode", q, k_pages, v_pages,
-                            block_tables, sel_idx, n_sel, impl=impl,
-                            scale=scale)
+                            block_tables, selected, extent, *groups,
+                            impl=impl, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -482,17 +846,21 @@ def sparse_paged_prefill_attention(q, k_pages, v_pages, block_tables,
 # ---------------------------------------------------------------------------
 
 def indexed_decode_attention(q, k_pages, v_pages, ik_pages, block_tables,
-                             lengths, q_idx, w_idx, topk, *,
+                             lengths, q_idx, w_idx, topk, *, groups=None,
                              impl: str = "auto"):
     """Score, select, attend for one decode token a slot: ``q`` (S, H,
     Dh), ``q_idx`` (S, J, Di), ``w_idx`` (S, J), ``lengths`` the live
-    tokens INCLUDING this one. Returns (attention (S, H, Dh), selected
-    (S,) tokens attended a slot)."""
-    idx, n_sel = indexed_decode_selection(ik_pages, block_tables, lengths,
-                                          q_idx, w_idx, topk, impl=impl)
-    att = sparse_paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                        idx, n_sel, impl=impl)
-    return att, n_sel
+    tokens INCLUDING this one, ``groups`` which slots' tables open with
+    the same pages (:func:`selected_decode_attention`). The selection
+    reaches the kernel as a mask (:func:`select_decode_mask`). Returns
+    (attention (S, H, Dh), selected (S,) tokens attended a slot)."""
+    scores = lightning_index_scores(
+        q_idx[:, None], w_idx[:, None], ik_pages, block_tables, lengths,
+        impl=impl)[:, 0]
+    selected = select_decode_mask(scores, lengths, topk)
+    att = selected_decode_attention(q, k_pages, v_pages, block_tables,
+                                    selected, lengths, groups, impl=impl)
+    return att, jnp.minimum(lengths, topk)
 
 
 def indexed_decode_selection(ik_pages, block_tables, lengths, q_idx, w_idx,
@@ -526,7 +894,38 @@ def indexed_prefill_attention(q, k_pages, v_pages, ik_pages, block_tables,
 
 def _sparse_tune_signature(args, kwargs):
     return DA._paged_sig(args[0], args[1], args[3]) \
-        + (("topk", args[4].shape[-1]),)
+        + (("g", args[6].shape[1]),)
+
+
+def _sparse_decode_vmem_estimate(args, kwargs, blocks):
+    """VMEM working set of one grid step of the sparse decode, tiles
+    padded as the chip lays them out; the larger of its two parts, a
+    group's: two buffers of ``pb`` pages of each pool, the members'
+    queries and selections and the group's state block double-buffered,
+    the state, and one KV head's update ``pb`` pages wide for every
+    member's rows (the selection's rows, float32 scores and weights, the
+    weights' three bf16 terms and their product)."""
+    q, k_pages, bt = args[0], args[1], args[3]
+    ps, hd = k_pages.shape[1:]
+    dh, mp = q.shape[-1], bt.shape[1]
+    kv, g = hd // dh, args[6].shape[1]
+    isz = k_pages.dtype.itemsize
+    pb = blocks.get("pages_per_block", 1)
+
+    def tiled(lead, sub, lane, itemsize):
+        tile = 32 // itemsize
+        return (lead * -(-sub // tile) * tile * -(-lane // 128) * 128
+                * itemsize)
+
+    heads = q.shape[-2] // kv
+    rows, width = g * (heads + -heads % _MEMBER_ROWS), pb * ps
+    pages = 2 * 2 * tiled(1, width, hd, isz)
+    io = 2 * (tiled(kv, rows, dh, q.dtype.itemsize) + g * tiled(1, mp, ps, 4)
+              + tiled(kv, rows, dh + DA._STATE_LANES, 4))
+    state = 2 * tiled(kv, rows, 128, 4) + tiled(kv, rows, dh, 4)
+    fold = (3 * tiled(1, rows, width, 4) + tiled(1, 3 * rows, width, 2)
+            + tiled(1, 3 * rows, dh, 4))
+    return pages + io + state + fold
 
 
 def _register():
@@ -559,23 +958,39 @@ def _register():
     kernels.register(kernels.KernelSpec(
         name="sparse_paged_decode",
         contract=kernels.KernelContract(
-            version=1,
+            version=2,
             arg_layouts={"q": "(S,H,Dh)", "k_pages": "(P,ps,KV*Dh)",
                          "v_pages": "(P,ps,KV*Dh)",
                          "block_tables": "(S,mp) i32",
-                         "sel_idx": "(S,topk) i32", "n_sel": "(S,) i32"},
+                         "selected": "(S,mp*ps) f32",
+                         "extent": "(S,) i32",
+                         "group_slots": "(S//2,G) i32",
+                         "group_pages": "(S//2,) i32",
+                         "shared_pages": "(S,) i32"},
             out_layout="(S,H,Dh)",
-            grid="XLA row gather of the selected tokens, then the paged "
-                 "decode body over (S, topk/ps/pages_per_block)",
+            grid="two calls, pools left in HBM. (S//2,) one step a group "
+                 "of up to G slots whose tables open with the same "
+                 "group_pages pages (group_slots, -1 for no member): the "
+                 "members' queries stacked a KV head against ONE copy of "
+                 "each shared page, each member's rows masked by its "
+                 "selection, their float32 states [acc|m|l] handed on; "
+                 "then (S,) one step a slot, from that state over its own "
+                 "pages from shared_pages on (shared_pages * ps <= "
+                 "extent; 0: the slot is walked alone) under its "
+                 "selection. Either body copies whole pages of both pools "
+                 "itself, pages_per_block side by side into one of two "
+                 "VMEM buffers while it folds the other; a block one "
+                 "softmax update a KV head",
             block_candidates={"pages_per_block": (1, 2, 4, 8)},
             atol=2e-5, rtol=2e-5),
-        pallas_fn=_sparse_decode_pallas,
+        pallas_fn=_sparse_decode_kernel_pallas,
         lax_fn=_sparse_decode_lax,
         reference_fn=_sparse_decode_reference,
         sample_inputs=_make_sparse_decode_sample,
         pallas_sites=(
-            "paddle_tpu.serving.decode_attention:_paged_attend_pallas",),
-        tune_signature=_sparse_tune_signature))
+            "paddle_tpu.serving.sparse_attention:_sparse_decode_pallas",),
+        tune_signature=_sparse_tune_signature,
+        vmem_estimate=_sparse_decode_vmem_estimate))
     kernels.register(kernels.KernelSpec(
         name="sparse_paged_prefill",
         contract=kernels.KernelContract(

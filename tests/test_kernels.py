@@ -925,32 +925,49 @@ class TestDecodeFoldsAPageForAllHeads:
             assert got == want, (n_tokens, got)
 
 
+def _sparse_decode_cell_args():
+    """``sparse_paged_decode`` at the docs cell's geometry: 32 slots, 32
+    query heads over 4 KV heads of 128, tables of 128 pages of a
+    1280-page bf16 pool, the selection as a mask, groups of 8."""
+    sds = jax.ShapeDtypeStruct
+    pages = sds((1280, 128, 4 * 128), jnp.bfloat16)
+    return (sds((32, 32, 128), jnp.bfloat16), pages, pages,
+            sds((32, 128), jnp.int32), sds((32, 128 * 128), jnp.float32),
+            sds((32,), jnp.int32), sds((16, 8), jnp.int32),
+            sds((16,), jnp.int32), sds((32,), jnp.int32))
+
+
 def test_sparse_decode_traces_to_the_call_it_was():
-    """``sparse_paged_decode`` keeps the pipelined decode body: at the
-    docs cell's geometry (32 slots, 32 query heads over 4 KV heads of
-    128, 2048 selected rows of a 1280-page pool) its Pallas path traces
-    to the jaxpr of the commit before the dense entry got a body of its
-    own (sha256 taken there by these lines under this suite's conftest,
-    source positions stripped; a change that means to alter the sparse
-    decode call takes it anew)."""
+    """``sparse_paged_decode`` at the docs cell's geometry traces, on its
+    Pallas path, to the jaxpr it had when the body that walks the pools
+    under the selection was written (PR 44; sha256 taken by these lines
+    under this suite's conftest, source positions stripped; a change that
+    means to alter the sparse decode call takes it anew): two calls
+    under the kernel's one name, blocks of 8 pages."""
     import functools
     import hashlib
     import re
     spec = kernels.get("sparse_paged_decode")
-    sds = jax.ShapeDtypeStruct
-    pages = sds((1280, 128, 4 * 128), jnp.bfloat16)
-    args = (sds((32, 32, 128), jnp.bfloat16), pages, pages,
-            sds((32, 128), jnp.int32), sds((32, 2048), jnp.int32),
-            sds((32,), jnp.int32))
+    args = _sparse_decode_cell_args()
     blocks = autotune.static_prior(spec, args, {})
     assert blocks == {"pages_per_block": 8}
     text = str(jax.make_jaxpr(functools.partial(
         spec.pallas_fn, block_sizes=blocks, interpret=False))(*args))
-    assert "sparse_paged_decode" in text
+    assert text.count("name=sparse_paged_decode") == 2
     text = re.sub(r" at [^\s\]]+:\d+", "", text)
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "4730570f2e6a181f82cc56b35278e6ad"
-        "4c904820aad7325e45ce39a8e4d3ad2d")
+        "8df1db902acdd374b53cf4fe8056cd7d"
+        "f6da3892bab925851d219babfdae37be")
+
+
+def test_sparse_decode_vmem_estimate_at_the_published_widths():
+    """A group's step at 8 members x 8 rows a KV head and blocks of 8
+    pages: more than its four buffers of 8 pages (4 MB), under half the
+    chip's 16 MiB default scope."""
+    spec = kernels.get("sparse_paged_decode")
+    need = spec.vmem_estimate(_sparse_decode_cell_args(), {},
+                              {"pages_per_block": 8})
+    assert 4 * 8 * 128 * 512 * 2 < need < 8 << 20
 
 
 # ---------------------------------------------------------------------------

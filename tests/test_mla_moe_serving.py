@@ -317,7 +317,7 @@ def shared_pool():
 def _groups_by_hand(members, pages, slots=12):
     """One group, written out: what the engine's grouping would never
     make (a group of one, a dead member, pages that are no whole block)."""
-    group_slots = np.full((slots // 2, DA.LATENT_GROUP), -1, np.int32)
+    group_slots = np.full((slots // 2, DA.DECODE_GROUP), -1, np.int32)
     group_slots[0, :len(members)] = members
     group_pages = np.zeros((slots // 2,), np.int32)
     group_pages[0] = pages
@@ -360,7 +360,7 @@ def test_slots_folded_over_shared_pages_attend_as_each_would_alone(
     lengths = np.asarray(FOLDS[case][0], np.int32)
     groups = FOLDS[case][1]
     if groups is None:
-        groups = DA.latent_decode_groups(
+        groups = DA.decode_groups(
             pool["tables"], lengths, np.flatnonzero(lengths), pool["ps"])
         members = (groups[0] >= 0).sum(1)
         assert sorted(members[members > 0]) == {
@@ -390,7 +390,7 @@ def test_vmem_estimates_at_the_published_widths():
     decode = kernels.get("latent_paged_decode").vmem_estimate(
         (sds((128, 32, 320), jnp.bfloat16),) + pools
         + (sds((128, 134), jnp.int32), sds((128,), jnp.int32),
-           sds((64, DA.LATENT_GROUP), jnp.int32)), {},
+           sds((64, DA.DECODE_GROUP), jnp.int32)), {},
         {"pages_per_block": 8})
     prefill = kernels.get("latent_paged_prefill").vmem_estimate(
         (sds((8, 256, 32, 320), jnp.bfloat16),) + pools, {},
@@ -580,7 +580,7 @@ def _decode_blocks_of(eng, run):
                   eng.cache.lengths.copy())
         blk = dispatch(dslots, w, rnd)
         seen.append(before + (tuple(
-            np.asarray(a) for a in eng._latent_groups[2]),))
+            np.asarray(a) for a in eng._decode_groups[2]),))
         return blk
     eng._dispatch_block = watched
     try:

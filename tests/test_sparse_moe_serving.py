@@ -19,7 +19,9 @@ import pytest
 from paddle_tpu import inference
 from paddle_tpu import observability as obs
 from paddle_tpu.models.sparse_moe_lm import SparseMoELM, SparseMoELMConfig
+from paddle_tpu import kernels
 from paddle_tpu.ops.grouped_ffn import grouped_expert_ffn
+from paddle_tpu.serving import decode_attention as DA
 from paddle_tpu.serving import sparse_attention as SA
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -209,6 +211,227 @@ def test_selected_set_is_the_references(model_and_params):
             checked += 1
             assert (chosen[r] == keep[lo + r]).all(), lo + r
     assert checked >= c - 2
+
+
+SELECTIONS = {
+    # (scores of one slot's T = 12 tokens, its length): ties at the
+    # threshold go to the lower position; a slot of at most topk tokens
+    # selects them all; a dead slot nothing
+    "distinct_scores": ([.9, .1, .8, .2, .7, .3, .6, .4, .5, .0, .95, .05], 12),
+    "ties_at_the_threshold": ([.9, .5, .8, .5, .5, .3, .5, .4, .5, .0, .5, .5],
+                              12),
+    "every_score_the_same": ([.5] * 12, 11),
+    "a_tie_past_the_length": ([.9, .1, .8, .2, .7, .3, .6, .9, .9, .9, .9, .9],
+                              7),
+    "exactly_topk_tokens": ([.9, .1, .8, .2, .7, .3, .6, .4, .5, .0, .9, .9],
+                            4),
+    "fewer_than_topk_tokens": ([.9, .1, .8, .2, .7, .3, .6, .4, .5, .0, .9,
+                                .9], 3),
+    "one_token": ([.0] * 12, 1),
+    "a_dead_slot": ([.9, .1, .8, .2, .7, .3, .6, .4, .5, .0, .9, .9], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECTIONS))
+def test_the_mask_from_the_scores_is_the_scatter_of_the_selected_indices(
+        case):
+    """What the decode step hands the kernel (``select_decode_mask``:
+    scores against the value of the last of the top-k, ties to the lower
+    position, by the rule ``select_prefill`` has) marks, element for
+    element, the tokens ``select_decode``'s indices name, and what
+    ``select_prefill`` marks for a query at position ``length - 1``."""
+    topk = 4
+    row, n = SELECTIONS[case]
+    scores = jnp.asarray([row, row[::-1]], jnp.float32)
+    lengths = jnp.asarray([n, n], jnp.int32)
+    idx, n_sel = SA.select_decode(scores, lengths, topk)
+    want = np.zeros(scores.shape, np.float32)
+    for sl in range(2):
+        want[sl, np.asarray(idx[sl, :int(n_sel[sl])])] = 1.0
+    got = np.asarray(SA.select_decode_mask(scores, lengths, topk))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, np.asarray(SA.select_prefill(
+        scores[:, None], lengths - 1, None, topk)[:, 0]))
+    assert want.sum(1).tolist() == [min(n, topk)] * 2
+
+
+# -- the decode body: whole pages of the pools, walked under the selection ---
+
+D_H, D_KV, D_DH, D_PS, D_TOPK = 4, 2, 16, 4, 24
+
+
+def _documents(n_slots, sharers, shared, lengths, pool, mp, seed=0):
+    """``n_slots`` slots over one pool of pages of 4 tokens, the first
+    ``sharers`` of them opening with the same ``shared`` pages; float32
+    queries holding bf16 values, so a bf16 pool's products are exact."""
+    rng = np.random.default_rng(seed)
+    num_pages = n_slots * mp + 1
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    q = jnp.asarray(rng.standard_normal((n_slots, D_H, D_DH)),
+                    jnp.bfloat16).astype(jnp.float32)
+    kp, vp = (jnp.asarray(rng.standard_normal(
+        (num_pages, D_PS, D_KV * D_DH)), jnp.bfloat16).astype(dtype)
+        for _ in range(2))
+    tables = (1 + rng.permutation(num_pages - 1)[:n_slots * mp]).reshape(
+        n_slots, mp).astype(np.int32)
+    tables[:sharers, :shared] = tables[0, :shared]
+    lengths = np.asarray(lengths, np.int32)
+    scores = jnp.asarray(rng.standard_normal((n_slots, mp * D_PS)),
+                         jnp.float32)
+    selected = SA.select_decode_mask(scores, jnp.asarray(lengths), D_TOPK)
+    return (q, kp, vp, jnp.asarray(tables), selected,
+            jnp.asarray(lengths)), tables, lengths
+
+
+def _one_group(members, pages, n_slots):
+    """A group written out, as the engine's grouping would never make it
+    (a group of one, pages that are no whole block, a dead member)."""
+    group_slots = np.full((max(n_slots // 2, 1), DA.DECODE_GROUP), -1,
+                          np.int32)
+    group_slots[0, :len(members)] = members
+    group_pages = np.zeros((group_slots.shape[0],), np.int32)
+    group_pages[0] = pages
+    shared_pages = np.zeros((n_slots,), np.int32)
+    shared_pages[list(members)] = pages
+    return group_slots, group_pages, shared_pages
+
+
+# name: (slots, how many of them open with the same pages, that many
+# pages, lengths, the table's width, the pool, pages a block, a group by
+# hand (members, pages) or None for the engine's grouping, the members a
+# group of the engine's then has)
+WALKS = {
+    "a_group_of_one": (3, 1, 8, [40, 33, 48], 12, "f32", 4, ([0], 8), None),
+    "a_pair": (3, 2, 8, [32, 33, 48], 12, "f32", 4, None, [2]),
+    "four_of_a_document": (5, 4, 8, [32, 33, 48, 41, 17], 12, "f32", 8,
+                           None, [4]),
+    "eight": (9, 8, 8, [32, 33, 48, 41, 37, 45, 36, 44, 48], 12, "f32", 2,
+              None, [8]),
+    # the ninth sharer is a group of one to the engine: walked alone
+    "nine_is_eight_and_one_alone": (
+        9, 9, 8, [32, 33, 48, 41, 37, 45, 36, 44, 39], 12, "f32", 4, None,
+        [8]),
+    "nothing_shared": (3, 0, 0, [32, 33, 48], 12, "f32", 4, None, []),
+    # seven pages are no whole block of the engine's: nothing is grouped
+    "seven_shared_pages_are_walked_alone": (3, 3, 7, [32, 33, 48], 12,
+                                            "f32", 4, None, []),
+    # by hand the kernel folds the whole blocks of 4 among them and walks
+    # the other three a slot
+    "seven_shared_pages_by_hand": (3, 3, 7, [32, 33, 48], 12, "f32", 4,
+                                   ([0, 1, 2], 7), None),
+    "a_dead_member": (4, 4, 8, [40, 0, 48, 33], 12, "f32", 4,
+                      ([0, 1, 2, 3], 8), None),
+    "a_long_document": (3, 3, 120, [480, 481, 496], 124, "f32", 8, None,
+                        [3]),
+    "a_bf16_pool": (5, 4, 8, [32, 33, 48, 41, 17], 12, "bf16", 8, None,
+                    [4]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_sparse_decode_walks_shared_pages_once_a_group(case):
+    """``sparse_paged_decode``'s Pallas bodies (interpreted) against
+    attention a slot over its selected tokens in NumPy: groups of 1, 2,
+    4, 8 and 9 slots of one document, shared runs of 0, 7, 8 and 120
+    pages, lengths at a page's end, one past it and the whole table,
+    float32 and bf16 pools. A folded member's output is the same slot's
+    walked alone within the contract's tolerance, and neither fallback
+    asks who shares what."""
+    (n_slots, sharers, shared, lengths, mp, pool, pb, by_hand,
+     members) = WALKS[case]
+    args, tables, lengths = _documents(n_slots, sharers, shared, lengths,
+                                       pool, mp)
+    spec = kernels.get("sparse_paged_decode")
+    if by_hand is None:
+        groups = DA.decode_groups(tables, lengths,
+                                         np.flatnonzero(lengths), D_PS)
+        held = (groups[0] >= 0).sum(1)
+        assert sorted(held[held > 0]) == members
+        assert set(groups[1][held > 0]) <= {shared}
+    else:
+        groups = _one_group(*by_hand, n_slots)
+    alone = _one_group([], 0, n_slots)
+    run = lambda g: np.asarray(kernels.dispatch(               # noqa: E731
+        "sparse_paged_decode", *args, *map(jnp.asarray, g),
+        impl="pallas_interpret", block_sizes={"pages_per_block": pb}))
+    want = np.asarray(spec.reference_fn(*args))
+    tol = dict(atol=spec.contract.atol, rtol=spec.contract.rtol)
+    folded = run(groups)
+    np.testing.assert_allclose(folded, want, **tol)
+    if np.any(groups[1]):
+        np.testing.assert_allclose(folded, run(alone), **tol)
+    assert not folded[lengths == 0].any()
+    for fn in (spec.lax_fn, spec.reference_fn):
+        np.testing.assert_array_equal(
+            np.asarray(fn(*args, *map(jnp.asarray, groups))),
+            np.asarray(fn(*args, *map(jnp.asarray, alone))))
+    np.testing.assert_allclose(np.asarray(spec.lax_fn(*args)), want, **tol)
+
+
+def test_selection_as_indices_is_the_selection_as_a_mask():
+    """``sparse_paged_decode_attention`` keeps its signature for whoever
+    holds a selection as indices (the benchmark's selection replay): the
+    mask it scatters them into and the extent it reads off them give the
+    attention the mask from the scores gives."""
+    args, _tables, _lengths = _documents(4, 0, 0, [0, 1, 30, 48], "f32", 12)
+    q, kp, vp, bt, _selected, ln = args
+    scores = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (4, 48)), jnp.float32)
+    idx, n_sel = SA.select_decode(scores, ln, D_TOPK)
+    selected = SA.select_decode_mask(scores, ln, D_TOPK)
+    for impl in ("lax", "pallas_interpret"):
+        np.testing.assert_array_equal(
+            np.asarray(SA.sparse_paged_decode_attention(
+                q, kp, vp, bt, idx, n_sel, impl=impl)),
+            np.asarray(SA.selected_decode_attention(
+                q, kp, vp, bt, selected, ln, impl=impl)))
+
+
+def test_requests_over_a_published_document_decode_folded(model_and_params):
+    """Two requests that open with the 40 tokens a third published decode
+    as one group over ONE copy of its first eight pages (the Pallas
+    bodies, interpreted; a group's pages are whole blocks of eight), and
+    emit the tokens they emit when the cache shares nothing (the same
+    engine, its cache told not to share: the step programs are the ones
+    already compiled). The two counters, fed from the tables and lengths
+    the host holds: the walks copy the group's 32 shared rows once a
+    token step and layer where the slots hold them twice; with nothing
+    shared they copy what the slots hold."""
+    import dataclasses
+    model, params = model_and_params
+    eng, _sink, reg = _engine(model, params, "pallas_interpret")
+    rng = np.random.default_rng(44)
+    draw = lambda n: rng.integers(                               # noqa: E731
+        0, model.cfg.vocab_size, n).astype(np.int32)
+    document = draw(40)
+    eng.generate_many([np.concatenate([document, draw(2)])],
+                      max_new_tokens=2)
+    asks = [np.concatenate([document, draw(n)]) for n in (3, 6)]
+    names = ["serving_sparse_rows_fetched_total",
+             "serving_sparse_rows_held_total"]
+
+    def serve(sharing):
+        shares = eng.cache.config
+        eng.cache.config = dataclasses.replace(shares, share_prefix=sharing)
+        before = reg.snapshot()
+        try:
+            outs = eng.generate_many(asks, max_new_tokens=5)
+        finally:
+            eng.cache.config = shares
+        snap = reg.snapshot()
+        return [list(o) for o in outs], [
+            int(snap[k] - before.get(k, 0)) for k in names]
+
+    folded, (fetched, held) = serve(True)
+    _slots, _tables, groups, _twice, spared = eng._decode_groups
+    assert np.asarray(groups[1]).tolist() == [8] and spared == 8 * PAGE
+    assert sorted(np.asarray(groups[0])[0, :2]) == [0, 1]
+    # blocks of 2 token steps, 2 layers: 32 rows spared each
+    assert held > fetched > 0 and (held - fetched) % (32 * 2 * 2) == 0
+    alone, (fetched, held) = serve(False)
+    assert alone == folded
+    assert fetched == held > 0
+    assert not np.asarray(eng._decode_groups[2][1]).any()
 
 
 def test_blocked_benchmark_reference_is_the_plain_one(model_and_params):
