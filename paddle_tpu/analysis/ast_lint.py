@@ -18,7 +18,8 @@ Tracer inference is a deliberate, shallow heuristic: the function's
 parameters seed the tracer set (minus parameters whose defaults are
 plain Python flags — ``training=False``, ``mode="train"``, ``key=None``
 — which are static config by convention), and assignments propagate.
-``x is None``-style comparisons are static and never flagged. The lint
+``x is None``-style comparisons and ``x.shape`` / ``x.ndim`` / ``x.dtype``
+are static and never flagged. The lint
 is per-function — callees are not followed; run it on the function you
 ``jit``.
 """
@@ -39,7 +40,14 @@ _CAST_BUILTINS = {"float", "int", "bool", "complex"}
 
 
 def _names_in(node: ast.AST) -> Set[str]:
-    return {n.id for n in ast.walk(node) if isinstance(n, ast.Name)}
+    """Names ``node`` reads a value of (``x.shape[0]`` reads none of
+    ``x``'s: a branch on it is a branch on a Python int)."""
+    if isinstance(node, ast.Attribute) and node.attr in ("shape", "ndim",
+                                                         "dtype"):
+        return set()
+    if isinstance(node, ast.Name):
+        return {node.id}
+    return set().union(*map(_names_in, ast.iter_child_nodes(node)))
 
 
 def _is_none_compare(test: ast.AST) -> bool:

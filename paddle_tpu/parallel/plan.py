@@ -216,20 +216,3 @@ def serving_prefill_tp_plan() -> ShardingPlan:
         (r"mlp/fc2/weight$", P("tp", None)),
         (r"^", P()),      # everything else (incl. fc2 bias) replicated
     ])
-
-
-def paged_pool_specs(pages) -> list:
-    """PartitionSpec pytree for a :class:`~paddle_tpu.serving
-    .PagedKVCache` page pool under tp: K/V page arrays ``(P, ps,
-    H*Dh)`` sharded over "tp" on the folded, head-major head axis
-    (per-shard pools of whole heads), int8 scale rows replicated
-    (per-token scales are head-global — see ``quantize_kv``'s
-    ``psum_axis``). Mirrors the pool's per-layer tuple structure, so it
-    drops straight into ``shard_map`` in/out specs."""
-    kv = P(None, None, "tp")
-    out = []
-    for ent in pages:
-        specs = [kv, kv]
-        specs.extend(P() for _ in ent[2:])      # int8 scale rows
-        out.append(tuple(specs))
-    return out

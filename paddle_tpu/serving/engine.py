@@ -1,24 +1,23 @@
-"""ServingEngine: continuous-batching GPT inference over a paged KV cache.
+"""ServingEngine: continuous-batching inference over a paged KV cache.
 
-The serving loop is TWO jit-compiled fixed-shape steps:
+The serving loop is TWO jit-compiled fixed-shape steps, written against
+what a model supplies (:mod:`~paddle_tpu.serving.program`) and what its
+layers cache (:mod:`~paddle_tpu.serving.layer_kinds`: one kind a layer,
+built once from the program's spec and the cache's geometry; the loops are
+straight-line code over ``cache.config.kinds[i]``):
 
-- a **batched chunked-prefill step** (ISSUE 6): one call advances EVERY
-  admitted request's next prompt chunk at once — tokens (S, C), ragged
-  per-slot valid counts, causal paged attention via
-  ``decode_attention.ragged_paged_prefill_attention`` — replacing the
-  old one-request-at-a-time chunk loop that made prefill the serving
-  bottleneck (BENCH_SERVING showed it 4× slower than the dense path);
+- a **batched chunked-prefill step**: one call advances EVERY admitted
+  request's next prompt chunk at once — tokens (S, C), ragged per-slot
+  valid counts, causal paged attention;
 - a **decode step**: every slot advances a BLOCK of ``decode_block``
   tokens per call (an on-device ``fori_loop``, amortizing the host
-  round-trip), attending over its own pages via
-  ``decode_attention.ragged_paged_decode_attention``.
+  round-trip), attending over its own pages.
 
 All shapes are static: ``num_slots``, the prefill chunk, and pow2-
 bucketed block-table gather widths that track the LIVE high-water mark
-(so work follows live tokens, not slot capacity, even on the lax
-fallback). The cache pages are **donated** into both steps, and
-:meth:`ServingEngine.warmup` precompiles every bucket — decode AND
-prefill — so steady-state serving triggers zero recompiles and zero
+(work follows live tokens, not slot capacity). The cache pages are
+**donated** into both steps, and :meth:`ServingEngine.warmup` precompiles
+every bucket, so steady-state serving triggers zero recompiles and zero
 cache copies (a :class:`~paddle_tpu.observability.RecompileDetector`
 wired to the step proves it).
 
@@ -29,118 +28,78 @@ running the decode block — the chunk floor is a single liveness lane
 for budgets below one chunk — so a burst of long prompts cannot starve
 in-flight decodes and vice versa.
 
-The host waits for the device **once a step** (ISSUE 31): every call of
-a step is ordered on the device by the donated page pool it threads, so
-a prefill call is dispatched and not read. A finished prompt's first
-token stays on the device and is merged into the decode block's input
-tokens there (``first_token_step``, one tiny program per lane count),
-and the block's read-back brings it over together with the block's
-tokens and the programs' pending counts; TTFT is stamped then, when the
-host learns the token. Only a finishing request the step must judge at
-once reads back in its prefill call: one with an ``eos_id`` or a budget
-of one token (the admission cascade evicts on it), a speculative engine
-(its round reads the token on the host), a prefill tier (the slot parks
-for handoff). ``serving_device_readbacks_total{phase}`` counts the waits.
+The host waits for the device **once a step**: every call of a step is
+ordered on the device by the donated page pool it threads, so a prefill
+call is dispatched and not read. A finished prompt's first token stays on
+the device and is merged into the decode block's input tokens there
+(``first_token_step``, one tiny program per lane count), and the block's
+read-back brings it over together with the block's tokens and the
+programs' pending counts; TTFT is stamped then, when the host learns the
+token. Only a finishing request the step must judge at once reads back in
+its prefill call: one with an ``eos_id`` or a budget of one token (the
+admission cascade evicts on it), a speculative engine (its round reads the
+token on the host), a prefill tier (the slot parks for handoff).
+``serving_device_readbacks_total{phase}`` counts the waits.
 
-And the block a step reads is the one the step BEFORE dispatched
-(ISSUE 34): ``step()`` dispatches block k, then settles block k-1, so at
-most one decode block is in flight across calls and the host's per-step
-work (book, evict, observe, the caller's submits, admit, the prefill and
-decode uploads and dispatches) runs beside the device, not between two
-of its blocks. What is decided at dispatch, what is learned at settle
-and which engines and calls settle at once: :meth:`ServingEngine.step`.
-``serving_decode_blocks_overlapped_total`` counts the blocks sent while
-the one before was unread.
+And the block a step reads is the one the step BEFORE dispatched:
+``step()`` dispatches block k, then settles block k-1, so at most one
+decode block is in flight across calls and the host's per-step work runs
+beside the device, not between two of its blocks. What is decided at
+dispatch, what is learned at settle and which engines and calls settle at
+once: :meth:`ServingEngine.step`.
 
 Prefix sharing: admission maps published prompt-prefix pages straight
 into the new slot's block table (refcount bump, prefill skipped for the
 shared tokens — see ``paged_cache``) and the engine performs the single
 copy-on-write page copy a borrowed *tail* page requires before the
-slot's first write.
+slot's first write. ``cache_dtype=jnp.int8`` selects the int8 kind of
+layer: roughly half the HBM per live token of bf16, and migration shards
+carry page + scales under one hash.
 
-Int8 paged KV (ISSUE 13): ``cache_dtype=jnp.int8`` stores the page
-pool quantized with per-token-row fp32 scales (``paged_cache``) —
-roughly half the HBM per live token of bf16, so the same pool hosts
-~2x the slots — and both fixed-shape steps write int8 rows + scales
-and attend through the **dequant-attend** kernel variants (scales
-fused into the QK/PV products inside the online-softmax page stream;
-no fp page materialized). The PR 7 cost model proves the bytes
-reduction statically (`tools/cost_budgets.json` gates it in CI), and
-migration shards carry page + scales under one hash.
+Speculative decoding: pass ``draft_model``/``draft_params`` (+
+``spec_k``) and the decode phase becomes draft-then-verify: the draft
+proposes ``spec_k`` greedy tokens per slot on its OWN paged cache (same
+slot/page geometry, allocations in lockstep), and the target verifies the
+whole chunk in ONE fixed-shape batched-prefill-shaped step
+(`_verify_step_impl`). Each round accepts the longest draft prefix the
+target agrees with plus the target's next token, so **greedy outputs are
+bit-exact vs non-speculative decoding**; rollback is a host-side cursor
+rewind (rejected tokens' K/V stay masked behind the slot length and are
+overwritten — pages were reserved up front, nothing leaks). Accept quality
+lands in ``serving_spec_*`` and per-request ``request_stats``. Speculation
+disables prefix sharing (the draft must prefill every prompt token) and
+slot migration (the draft cache is not carried in snapshots).
 
-Speculative decoding (ISSUE 13): pass ``draft_model``/``draft_params``
-(+ ``spec_k``) and the decode phase becomes draft-then-verify: the
-draft proposes ``spec_k`` greedy tokens per slot on its OWN paged
-cache (same slot/page geometry, allocations in lockstep), and the
-target verifies the whole chunk in ONE fixed-shape batched-prefill-
-shaped step (`_verify_step_impl` — per-position greedy argmax). Each
-round accepts the longest draft prefix the target agrees with plus the
-target's next token, so **greedy outputs are bit-exact vs
-non-speculative decoding**; rollback is a host-side cursor rewind
-(rejected tokens' K/V stay masked behind the slot length and are
-overwritten — pages were reserved up front, nothing leaks). Accept
-quality lands in ``serving_spec_accept_rate`` /
-``serving_spec_proposed_total`` / ``serving_spec_accepted_total`` and
-per-request ``request_stats``; ``warmup()`` precompiles the draft /
-draft-prefill / verify buckets so steady state still compiles nothing
-(bucket-coverage lint proves it ahead of time). Speculation disables
-prefix sharing (the draft must prefill every prompt token) and slot
-migration (the draft cache is not carried in snapshots).
+Tensor parallel: ``mesh=`` (or the shorthand ``tp=N``) shards the whole
+paged stack over the mesh's ``tp`` axis — the page pools hold per-shard
+head slices (``H/tp``), both fixed-shape steps run under ``shard_map`` on
+the parameter tree the model's serving program lays out and shards for it
+(``tp_params``, ``tp_plan``; GPT's: head-major Megatron slices and ONE
+``psum`` per layer at the attention output). Greedy tokens are identical
+to the tp=1 engine, slot migration moves one sha256 shard per (page, tp
+shard), ``health()`` reports the mesh shape, and ``warmup()`` covers the
+same bucket plan.
 
-Tensor parallel (ISSUE 15): ``mesh=`` (or the shorthand ``tp=N``)
-shards the whole paged stack over the mesh's ``tp`` axis — the page
-pools hold per-shard head slices (``H/tp``), both fixed-shape steps run
-under ``shard_map`` on the parameter tree the model's serving program
-lays out and shards for it (``serving/program.py``: ``tp_params``,
-``tp_plan``; GPT's: head-major Megatron slices and ONE ``psum`` per layer
-at the attention output, MLP/embeddings replicated — decode is
-KV-bandwidth-bound, and the KV term is what tp divides).
-Greedy tokens are identical to the tp=1 engine (int8 pools pmax each
-token's abs-max so quantization matches bit-for-bit), slot migration
-moves one sha256 shard per (page, tp shard), ``health()`` reports the
-mesh shape, and ``warmup()`` covers the same bucket plan — zero
-steady-state recompiles with tp on. A tp engine holds one page pool and
-one parameter tree; the share of device time its collectives leave
-exposed is read off the device trace (``device.collective_exposed_pct``),
-not off the host's clock.
+Scheduling is SLO-aware by default (``scheduler_policy="slo"``): priority
+lanes, TTFT deadlines with earliest-deadline-first boosting, bounded-skip
+anti-starvation, and load shedding via structured
+:class:`~paddle_tpu.serving.LoadShedError` rejects instead of unbounded
+queueing. ``scheduler_policy="fifo"`` is the plain head-blocking FIFO.
 
-Scheduling is SLO-aware by default (``scheduler_policy="slo"``):
-priority lanes, TTFT deadlines with earliest-deadline-first boosting,
-no head-of-line blocking (bounded-skip anti-starvation), and load
-shedding via structured :class:`~paddle_tpu.serving.LoadShedError`
-rejects instead of unbounded queueing. ``scheduler_policy="fifo"``
-restores the plain head-blocking FIFO.
-
-Metrics (observability registry): ``serving_requests_total``,
-``serving_rejected_total``, ``serving_tokens_total``,
-``serving_prefill_tokens_total`` (tokens actually COMPUTED — shared
-prefix tokens are skipped and show up in
-``serving_prefix_shared_tokens_total`` instead),
-``serving_prompt_tokens_total`` (tokens submitted),
-``serving_prefix_cow_total``, ``serving_steps_total``,
-``serving_device_readbacks_total``, and the latency
-split: ``serving_queue_wait_seconds`` (submit → admit),
-``serving_admit_to_first_token_seconds`` (admit → first token: the pure
-prefill cost), ``serving_ttft_seconds`` (their end-to-end sum), plus
-``serving_prefill_step_seconds``, ``serving_decode_step_seconds``,
-``serving_slot_occupancy``, ``serving_page_utilization``, and
-``serving_decode_recompiles_total`` via the detector.
-
-Observability (ISSUE 10): pass ``tracer=`` for request-lifecycle
-tracing — one root span per request with scheduler-decision /
-prefix-share / CoW events, child spans per prefill chunk and decode
-block (all host-side; the zero-recompile invariant holds with tracing
-on); ``ttft_budget_s=`` arms an SLO burn-rate monitor over the TTFT
-histogram (``slo_burn_rate`` gauge + edge-triggered
-``slo_alerts_total`` + ``slo.alert`` trace spans); ``health()`` /
-``start_exposition()`` serve live ``/metrics`` ``/healthz``
-``/traces``.
+Observability: every series the engine feeds is bound, with its meaning,
+in :meth:`ServingEngine._bind_step_metrics` (a layer kind's own in its
+``bind``); the spans of a step are listed in :meth:`ServingEngine.step`
+and PERF.md section 3. Pass ``tracer=`` for request-lifecycle tracing —
+one root span per request with scheduler-decision / prefix-share / CoW
+events, child spans per prefill chunk and decode block (all host-side);
+``ttft_budget_s=`` arms an SLO burn-rate monitor over the TTFT histogram;
+``health()`` / ``start_exposition()`` serve live ``/metrics``
+``/healthz`` ``/traces``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import threading
 import time
 from collections import OrderedDict
@@ -151,11 +110,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.analysis.concurrency import guarded_by
-from paddle_tpu.serving import decode_attention as DA
-from paddle_tpu.serving import sparse_attention as SA
+from paddle_tpu.serving import layer_kinds
 from paddle_tpu.serving.paged_cache import (_ROOT_KEY, _chain,
                                             PagedCacheConfig, PagedKVCache,
-                                            payload_digest, quantize_kv)
+                                            payload_digest)
 from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
                                           Reject, Request, SLOScheduler,
                                           SlotState)
@@ -177,7 +135,7 @@ _STEP_BUCKETS = tuple(round(1e-3 * 2 ** (k / 2), 6) for k in range(23))
 # serving_step_part_seconds_total{phase,part}: the leaf spans of one
 # engine step, by the work they time (PERF.md section 3)
 #: what the step programs count on the device and hand back with the
-#: tokens (``ServingSpec.stats`` plus attention's own)
+#: tokens (``ServingSpec.stats`` plus those of the layers' kinds)
 _STEP_STAT_HELP = {
     "moe_assignments": "token-expert pairs computed",
     "moe_experts_touched": "experts with at least one token, summed "
@@ -199,9 +157,6 @@ _STEP_STAT_HELP = {
     "attn_selected_tokens": "tokens attended after selection, summed "
                             "over queries and layers",
 }
-
-_KV_POOL_HELP = ("bytes of the K/V pools by layer kind, null pages "
-                 "included")
 
 _STEP_PARTS = (("prefill", "assemble"), ("prefill", "cow_copy"),
                ("prefill", "dispatch"), ("prefill", "sync"),
@@ -291,11 +246,6 @@ class ServingEngine:
         cfg = spec = base.spec
         self.draft_program = draft_model.serving() \
             if draft_model is not None else None
-        if spec.select_topk is not None and spec.select_topk % page_size:
-            raise ValueError(
-                f"select_topk={spec.select_topk} must be a multiple of "
-                f"page_size={page_size}: the selected tokens are folded "
-                "as whole pages")
         # -- disaggregation tier (ISSUE 19): a "prefill" engine runs
         # only the batched chunked prefill step and PARKS prefill-done
         # slots for handoff (poll_handoffs snapshots + releases them); a
@@ -416,48 +366,32 @@ class ServingEngine:
             # DOWN to bet on early EOS (that is the paging win)
             num_pages = num_slots * max_pages_per_slot + 1
         # like generate(cache_dtype=...): a bf16 page pool halves KV
-        # gather traffic (softmax still runs fp32 inside the kernel);
-        # cache_dtype=jnp.int8 stores quantized pages with per-token-row
-        # fp32 scales and attends through the dequant-attend kernels —
-        # HBM per live token roughly halves AGAIN vs bf16
+        # gather traffic (softmax still runs fp32 inside the kernel), an
+        # int8 one (its own kind of layer) roughly halves it AGAIN
         dtype = cache_dtype or base.param_dtype(params)
-        if any(w is not None for w in spec.layer_windows) \
-                and self.prefill_chunk > page_size:
-            raise ValueError(
-                f"prefill_chunk={self.prefill_chunk} > page_size="
-                f"{page_size}: a window layer's ring holds its window and "
-                "one page more, so a call writes at most a page of tokens "
-                "a slot before it attends")
         # a tp engine's pool is globally shaped but placed sharded H/tp
-        self.cache = PagedKVCache(PagedCacheConfig(
-            num_layers=cfg.num_layers, num_heads=cfg.kv_heads,
-            head_dim=cfg.head_dim,
-            num_slots=num_slots, page_size=page_size, num_pages=num_pages,
-            max_pages_per_slot=max_pages_per_slot, dtype=dtype,
-            share_prefix=prefix_sharing, extra_rows=spec.extra_rows,
-            slot_state=spec.slot_state,
-            slot_state_dtype=jnp.dtype(spec.slot_state_dtype),
-            layer_windows=spec.layer_windows, latent_row=spec.latent_row),
-            mesh=self.mesh,
-            host_spill_pages=host_spill_pages)
-        self.quantized = self.cache.config.quantized
+        geometry = dict(num_slots=num_slots, page_size=page_size,
+                        num_pages=num_pages)
+        self.cache = self._paged_cache(
+            spec, dtype, prefix_sharing, geometry, max_pages_per_slot,
+            tp=tp, mesh=self.mesh, host_spill_pages=host_spill_pages)
+        #: one object a kind of layer, for the host's counts
+        self._kinds = tuple(dict.fromkeys(self.cache.config.kinds))
+        #: the pages carry scale rows (the page wire format's two shapes)
+        self.quantized = any(kind.quantized for kind in self._kinds)
+        #: the lanes of a prefill call's table carry their slot beside the
+        #: pages (pool row slot + 1): whose state row, whose ring
+        self._lane_slot_column = bool(spec.slot_state) or any(
+            kind.by_slot for kind in self._kinds)
         self.draft_cache = None
-        self._draft_quantized = False
         if self.speculative:
-            dcfg = self.draft_program.spec
-            ddtype = cache_dtype or \
-                self.draft_program.param_dtype(draft_params)
             # same slot/page geometry as the target cache: allocations
             # run in lockstep (reserve/free the same slots for the same
             # token counts), so target admission implies draft admission
-            self.draft_cache = PagedKVCache(PagedCacheConfig(
-                num_layers=dcfg.num_layers, num_heads=dcfg.kv_heads,
-                head_dim=dcfg.head_dim,
-                num_slots=num_slots, page_size=page_size,
-                num_pages=num_pages,
-                max_pages_per_slot=max_pages_per_slot, dtype=ddtype,
-                share_prefix=False))
-            self._draft_quantized = self.draft_cache.config.quantized
+            self.draft_cache = self._paged_cache(
+                self.draft_program.spec,
+                cache_dtype or self.draft_program.param_dtype(draft_params),
+                False, geometry, max_pages_per_slot)
         if scheduler_policy == "slo":
             self.scheduler = SLOScheduler(
                 num_slots, can_admit=self._can_admit, lanes=lanes,
@@ -509,7 +443,7 @@ class ServingEngine:
         self.program = base if self.tp == 1 else model.serving(
             tp=self.tp, mlp_sharded=self._mlp_sharded)
         #: counts the steps hand back beside the tokens, in order
-        self._step_stats = self._stat_names(spec)
+        self._step_stats = self._stat_names(spec, self.cache.config.kinds)
         self._bind_step_metrics()
 
         # step-side params: under tp the program re-lays its tree out
@@ -533,7 +467,7 @@ class ServingEngine:
             # sharded) tree
             self.params = self._step_params
             rep = PSpec()
-            self._page_specs = plan_lib.paged_pool_specs(self.cache.pages)
+            self._page_specs = self.cache.page_specs()
             step_specs = (self._param_specs, self._page_specs,
                           rep, rep, rep, rep)
             self.decode_step = jax.jit(shard_map(
@@ -702,15 +636,6 @@ class ServingEngine:
             "page bytes x layers)")
         self._c_kv_live = kv.child(kind="live")
         self._c_kv_gathered = kv.child(kind="gathered")
-        c = self.cache.config
-        # K and V of one token in one layer (a latent row is cached once:
-        # its values are a part of it); a window layer reads its window's
-        # tokens at most
-        self._kv_layer_token_bytes = (
-            (1 if c.latent_row else 2) * c.num_heads * c.head_dim
-            * np.dtype(c.dtype).itemsize)
-        self._kv_token_bytes = self._kv_layer_token_bytes * (
-            c.num_layers - len(c.window_layers))
         self._h_decode_step = r.histogram(
             "serving_decode_step_seconds",
             "wall time per decode block: assemble.start to the end of "
@@ -766,9 +691,8 @@ class ServingEngine:
             "retired static flops per busy second / best observed rate"
         ).child()
         self._bind_state_metrics(r)
-        self._bind_window_metrics(r)
-        self._bind_latent_metrics(r)
-        self._bind_sparse_metrics(r)
+        for kind in self._kinds:
+            kind.bind(r)
         self._c_step_stats = [
             r.counter(f"serving_{name}_total", _STEP_STAT_HELP.get(
                 name, "a count the step program hands back")).child()
@@ -819,176 +743,6 @@ class ServingEngine:
                 "bytes of the slot-state pool, null row included").set(
                     self._state_slot_bytes * (self.scheduler.num_slots + 1))
 
-    def _bind_window_metrics(self, r):
-        """The series of a program with layers of two kinds
-        (``spec.layer_windows``), fed from the lengths the host holds; a
-        program whose layers are all full binds none of them."""
-        c = self.cache.config
-        #: ``{window: layers}``; empty where every layer is full
-        self._window_layers = c.window_layer_counts
-        #: the slots' table lanes carry their slot beside the pages (a
-        #: state row, a ring)
-        self._lane_slot_column = bool(self._state_slot_bytes
-                                      or self._window_layers)
-        if not self._window_layers:
-            return
-        resident = r.counter(
-            "serving_kv_resident_bytes_total",
-            "K/V bytes the slots of a decode round or prefill call hold "
-            "when it is dispatched, whole pages, by layer kind: a full "
-            "layer every page of the slot's tokens, a window layer its "
-            "ring's pages at most")
-        self._c_resident = {kind: resident.child(layers=kind)
-                            for kind in ("window", "full")}
-        self._c_recycled = r.counter(
-            "serving_window_pages_recycled_total",
-            "ring pages of window layers written over as slots advanced "
-            "past them (pages x window layers)").child()
-        pool = r.gauge("serving_kv_pool_bytes", _KV_POOL_HELP)
-        full = [i for i in range(c.num_layers) if i not in c.window_layers]
-        for kind, layers in (("window", c.window_layers), ("full", full)):
-            pool.set(sum(a.nbytes for i in layers
-                         for a in self.cache.pages[i][:c.paged_entries]),
-                     layers=kind)
-
-    def _bind_latent_metrics(self, r):
-        """The series of a program whose layers cache one latent row a
-        token (``spec.latent_row``), fed from the lengths the host holds;
-        any other program binds none of them."""
-        #: fixed with the pools: the steps of a program without latent
-        #: rows never ask again
-        self._latent = self.cache.config.latent_row is not None
-        #: whether this program's decode folds the pages that several
-        #: slots' tables open with into one walk (latent rows, or a token
-        #: selection): its decode step is handed the groups
-        self._folds = self._latent \
-            or self.program.spec.select_topk is not None
-        #: the groups of the last decode block, kept while the decoding
-        #: slots and their tables stay what they were: (slots, their
-        #: table rows, the three arrays on the device, rows a token step
-        #: that a second slot holds too, rows the groups' walks spare)
-        self._decode_groups = None
-        if not self._latent:
-            return
-        rows = r.counter(
-            "serving_latent_rows_read_total",
-            "cached latent rows x layers the latent kernels HAD to read, "
-            "the least any kernel could: a decode token step each "
-            "DISTINCT live row of the decoding slots once (a page that "
-            "several slots' tables hold counts once), a prefill call "
-            "each lane's context and chunk")
-        fetched = r.counter(
-            "serving_latent_rows_fetched_total",
-            "cached latent rows x layers the latent kernels' walks "
-            "copied: a decode token step a group's shared rows once a "
-            "group and every slot's own rows, a prefill call what it "
-            "has to read")
-        pairs = r.counter(
-            "serving_latent_pairs_total",
-            "(query token, cached row) pairs x layers the latent kernels "
-            "scored, every head each: a decode token step one query a "
-            "slot (equal to the rows), a prefill call each chunk token "
-            "against the rows up to its own")
-        self._c_latent = {ph: tuple(c.child(phase=ph)
-                                    for c in (rows, pairs, fetched))
-                          for ph in ("decode", "prefill")}
-        r.gauge("serving_kv_pool_bytes", _KV_POOL_HELP).set(
-            sum(a.nbytes for ent in self.cache.pages for a in ent),
-            layers="latent")
-
-    def _count_latent(self, span, phase, rows: int, pairs: int,
-                      fetched: int):
-        """One round's or call's latent rows, pairs and rows fetched
-        (each of ONE layer), and the span's ``latent_rows``."""
-        layers = self.cache.config.num_layers
-        for child, n in zip(self._c_latent[phase], (rows, pairs, fetched)):
-            child.inc(n * layers)
-        if span is not None:
-            span.set_attrs(latent_rows=rows * layers)
-
-    def _bind_sparse_metrics(self, r):
-        """The two series of a program that selects the tokens it attends
-        to (``spec.select_topk``), fed from the tables and lengths the
-        host holds; any other program binds neither."""
-        if self.program.spec.select_topk is None:
-            return
-        self._c_sparse_fetched = r.counter(
-            "serving_sparse_rows_fetched_total",
-            "cached K/V rows x layers the sparse decode's walks copied, a "
-            "token step of a bucket that selects: a group's shared rows "
-            "once a group, every slot's own rows once").child()
-        self._c_sparse_held = r.counter(
-            "serving_sparse_rows_held_total",
-            "live cached K/V rows x layers of the decoding slots, a slot "
-            "at a time, a token step of a bucket that selects: what the "
-            "walks copy where every slot is walked alone").child()
-
-    def _group_decode(self, dslots):
-        """Who shares what in a decode block of a program whose decode
-        folds shared pages (``self._folds``), from the decoding slots'
-        tables alone: (the three arrays
-        :func:`decode_attention.decode_groups` makes, uploaded; the
-        live rows a token step that a second decoding slot holds too,
-        which no kernel has to read twice; the rows the groups' walks do
-        not copy twice). Pages that several tables hold are whole and
-        read-only and a slot grows in pages of its own, so all three hold
-        for every token step of the block, and from block to block while
-        the decoding slots and their tables stay what they were."""
-        cache, ps = self.cache, self.cache.config.page_size
-        tables = cache.block_tables[dslots]
-        lens = cache.lengths[dslots]
-        kept = self._decode_groups
-        if kept is not None and np.array_equal(kept[0], dslots) \
-                and np.array_equal(kept[1], tables):
-            return kept[2:]
-        groups = DA.decode_groups(cache.block_tables, cache.lengths, dslots,
-                                  ps)
-        spared = int(((groups[0] >= 0).sum(1) - 1).clip(0)
-                     @ groups[1].astype(np.int64)) * ps
-        # (only the latent family's series read the second)
-        twice = self._rows_held_twice(tables, lens) if self._latent else 0
-        self._decode_groups = (np.asarray(dslots).copy(), tables,
-                               tuple(jnp.asarray(a) for a in groups),
-                               twice, spared)
-        return self._decode_groups[2:]
-
-    def _rows_held_twice(self, tables, lens):
-        """The live rows of slots with block tables ``tables`` and
-        ``lens`` tokens that a second of them holds too: a page some slot
-        holds whole counts whole, however many hold it; the page a slot
-        is filling counts as far as the longest of its holders goes."""
-        ps = self.cache.config.page_size
-        whole = lens // ps
-        held = np.bincount(
-            tables[np.arange(tables.shape[1])[None, :] < whole[:, None]],
-            minlength=self.cache.config.num_pages) > 0
-        filling = tables[np.arange(len(lens)),
-                         np.minimum(whole, tables.shape[1] - 1)]
-        part = (lens > whole * ps) & ~held[filling]
-        ids, rows = filling[part], (lens - whole * ps)[part]
-        order = np.argsort(ids, kind="stable")
-        distinct = ps * int(held.sum()) + (int(np.maximum.reduceat(
-            rows[order], np.flatnonzero(np.diff(ids[order], prepend=-1))
-        ).sum()) if len(ids) else 0)
-        return int(lens.sum()) - distinct
-
-    def _count_window(self, span, before, after):
-        """One round's or call's K/V by layer kind, from the lengths the
-        host holds: what its slots hold going in (``before`` tokens each)
-        and the ring pages written over on the way to ``after``."""
-        c = self.cache.config
-        page = c.page_size * self._kv_layer_token_bytes
-        pages = -(-np.asarray(before, np.int64) // c.page_size)
-        self._c_resident["full"].inc(int(pages.sum()) * page * (
-            c.num_layers - len(c.window_layers)))
-        self._c_resident["window"].inc(page * int(sum(
-            n * np.minimum(pages, c.ring_pages(win)).sum()
-            for win, n in self._window_layers.items())))
-        recycled = self.cache.recycled_pages(before, after)
-        self._c_recycled.inc(recycled)
-        if span is not None:
-            span.set_attrs(window_pages=recycled)
-
     def _count_state(self, span, decoding: int = 0, token_steps: int = 0,
                      lanes: int = 0, fresh: int = 0, tokens: int = 0):
         """One decode round's or prefill call's slot-state work, and the
@@ -1004,6 +758,32 @@ class ServingEngine:
         self._c_ssm_written.inc(b * (decoding * token_steps + lanes))
         if span is not None:
             span.set_attrs(state_slots=decoding + lanes)
+
+    def _paged_cache(self, spec, dtype, share_prefix, geometry,
+                     max_pages_per_slot, tp=1, **placed) -> PagedKVCache:
+        """The page cache of the program ``spec`` describes, its layers'
+        kinds built by the one function that decides them."""
+        kinds = layer_kinds.build(
+            spec, dtype=dtype, share_prefix=share_prefix, tp=tp,
+            impl=self.attn_impl, prefill_chunk=self.prefill_chunk,
+            **geometry)
+        return PagedKVCache(PagedCacheConfig(
+            num_layers=spec.num_layers, num_heads=spec.kv_heads,
+            head_dim=spec.head_dim, max_pages_per_slot=max_pages_per_slot,
+            dtype=dtype, share_prefix=share_prefix,
+            slot_state=spec.slot_state,
+            slot_state_dtype=jnp.dtype(spec.slot_state_dtype),
+            kinds=kinds, **geometry), **placed)
+
+    def _shared_groups(self, dslots) -> tuple:
+        """The decode step's seventh argument as ``(groups,)``, where a
+        layer's kind folds the pages that several of ``dslots``' tables
+        open with into one walk; ``()`` where none does."""
+        groups = ()
+        for kind in self._kinds:
+            groups = groups or kind.decode_groups(
+                self.cache.block_tables, self.cache.lengths, dslots)
+        return groups
 
     def _require(self, feature: str, what: str, spec=None):
         """The one refusal of an option or call the model's serving
@@ -1435,38 +1215,28 @@ class ServingEngine:
         return self._pow2_width(self.cache.config.pages_for(
             int(self.cache.lengths[dslots].max()) + n))
 
-    def _count_kv_bytes(self, dslots, n: int, w: int):
-        """``serving_decode_kv_bytes_total`` for one round of ``n`` token
-        steps at gather width ``w``, from the lengths BEFORE the round:
-        useful work (live) over attempted work (gathered), counted where
-        it happens. Token step j of a slot holding L tokens attends over
-        L + j + 1 (the formula ``benchmark/flops.paged_decode_bytes``
-        applies from outside). "Gathered" is every slot of the batch,
-        live or not, at ``w`` whole pages: the block table's width, which
-        the pipelined decode body (int8 pages) lays its grid over. The
-        dense decode kernel walks a slot's live pages itself since PR 39
-        (the sparse one since PR 44) and lays out no width, so for it the
-        ratio
-        says how wide the table's bucket is for what the slots hold, not
-        what the kernel spent. Returns the live tokens attended over, a
-        layer."""
-        c = self.cache.config
-        lens = self.cache.lengths[dslots]
-        live = n * int(lens.sum()) + len(dslots) * n * (n + 1) // 2
-        live_b = live * self._kv_token_bytes
-        gathered = w * self._kv_token_bytes
-        for win, layers in self._window_layers.items():
-            # a window layer's read is its window's: token step j of a
-            # slot holding L tokens attends over min(L + j + 1, window),
-            # from a table as wide as its ring
-            live_b += layers * self._kv_layer_token_bytes * int(sum(
-                np.minimum(lens + j + 1, win).sum() for j in range(n)))
-            gathered += layers * c.ring_pages(win) \
-                * self._kv_layer_token_bytes
-        self._c_kv_live.inc(live_b)
-        self._c_kv_gathered.inc(
-            n * self.scheduler.num_slots * c.page_size * gathered)
-        return live
+    def _count_attention(self, span, dslots, keeps, n: int, w: int):
+        """One decode round of ``n`` token steps at gather width ``w``,
+        of which slot ``dslots[k]`` keeps ``keeps[k]`` tokens, from the
+        tables and the lengths BEFORE the round: each kind of layer feeds
+        its own series (``span``: the round's) and hands back its share
+        of ``serving_decode_kv_bytes_total``: live, what token step j of
+        a slot holding L tokens attends over (L + j + 1; the formula
+        ``benchmark/flops.paged_decode_bytes`` applies from outside), and
+        gathered, every slot of the batch, live or not, at the whole
+        pages of the table its kernel is handed (a kernel that walks a
+        slot's live pages itself lays out no width: for it the ratio says
+        how wide the bucket is for what the slots hold)."""
+        live = gathered = 0
+        for kind in self._kinds:
+            live_b, wide_b = kind.count_decode(
+                span, self.cache.block_tables, self.cache.lengths, dslots,
+                keeps, n, w)
+            live += live_b
+            gathered += wide_b
+        self._c_kv_live.inc(live)
+        self._c_kv_gathered.inc(n * self.scheduler.num_slots
+                                * self.cache.config.page_size * gathered)
 
     def _note_step_stats(self, phase, counts):
         """Feed one call's device-side counts (``self._step_stats``
@@ -1586,24 +1356,11 @@ class ServingEngine:
             for k, (_, lanes) in enumerate(calls):
                 for j, i in lanes:
                     tokens[i] = -(1 + j) - k * s_tot
-            live = self._count_kv_bytes(dslots, n, w)
+            self._count_attention(
+                rnd.span, dslots, np.asarray([rows[i][1] for i in dslots]),
+                n, w)
             self._count_state(rnd.span, decoding=len(dslots), token_steps=n)
-            groups = ()
-            if self._folds:
-                shared, twice, spared = self._group_decode(dslots)
-                groups = (shared,)
-                if self._latent:
-                    self._count_latent(rnd.span, "decode", live - n * twice,
-                                       live, live - n * spared)
-                elif self._selects(self.program.spec,
-                                   self.cache.block_tables[:, :w]):
-                    layers = self.cache.config.num_layers
-                    self._c_sparse_fetched.inc((live - n * spared) * layers)
-                    self._c_sparse_held.inc(live * layers)
-            if self._window_layers:
-                lens = self.cache.lengths[dslots]
-                self._count_window(rnd.span, lens, lens + np.asarray(
-                    [rows[i][1] for i in dslots]))
+            groups = self._shared_groups(dslots)
             tok_dev = self._upload(tokens)
             for nxt, _ in calls:
                 tok_dev = self.first_token_step(tok_dev, nxt)
@@ -1748,7 +1505,7 @@ class ServingEngine:
                     # is capped at the remaining generation budget
                     nv[i] = min(n, st.request.max_new_tokens
                                 - len(st.generated))
-                self._count_kv_bytes(dslots, n, w)
+                self._count_attention(rnd.span, dslots, nv[dslots], n, w)
                 nv_dev = jnp.asarray(nv)
                 tok_dev = jnp.asarray(tokens)
                 draft_args = (
@@ -1929,12 +1686,20 @@ class ServingEngine:
         The host waits for the page, so the block in flight is settled
         first: the step's wait stays the block's."""
         self._settle_pending()
-        page = self.read_page_step(self.cache.pages,
-                                   jnp.asarray(pid, jnp.int32))
         self._c_readbacks["page_read"].inc()
-        if self.quantized:
-            return (np.asarray(page[0]), np.asarray(page[1]))
-        return (np.asarray(page),)
+        return self._read_page(pid)
+
+    def _spill_rotted(self, ent) -> bool:
+        """Whether a host-spilled page no longer matches its sha256: a
+        rotted copy must neither be restored nor leave this replica, so it
+        is dropped (the advertisement goes stale too) and counted."""
+        if payload_digest(ent.payload) == ent.sha256:
+            return False
+        self.cache.spill_pool.pop(ent.key)
+        self._reg.counter("serving_spill_corrupt_total",
+                          "host-spilled pages refused on restore "
+                          "(sha256 mismatch)").inc()
+        return True
 
     def _restore_spilled(self, prompt, rid: int) -> int:
         """Admission-overlapped restore (the DeviceEmbeddingCache
@@ -1956,12 +1721,7 @@ class ServingEngine:
             return 0
         entries, devs = [], []
         for ent in plan:
-            if payload_digest(ent.payload) != ent.sha256:
-                pool.pop(ent.key)
-                self._reg.counter(
-                    "serving_spill_corrupt_total",
-                    "host-spilled pages refused on restore "
-                    "(sha256 mismatch)").inc()
+            if self._spill_rotted(ent):
                 break
             entries.append(ent)
             devs.append(tuple(jax.device_put(a) for a in ent.payload))
@@ -2154,17 +1914,9 @@ class ServingEngine:
                 self._count_state(call.span, lanes=len(pslots),
                                   fresh=sum(lo == 0 for lo in los),
                                   tokens=call_tokens)
-                if self._window_layers:
-                    self._count_window(call.span, np.asarray(los),
-                                       np.asarray(los) + np.asarray(ns))
-                if self._latent:
-                    # chunk token j of a lane sees its context and the
-                    # chunk's tokens up to itself
-                    self._count_latent(
-                        call.span, "prefill", sum(los) + call_tokens,
-                        sum(lo * n + n * (n + 1) // 2
-                            for lo, n in zip(los, ns)),
-                        sum(los) + call_tokens)
+                for kind in self._kinds:
+                    kind.count_prefill(call.span, starts[:len(pslots)],
+                                       nv[:len(pslots)])
                 dbt_dev = jnp.asarray(dbt_rows[:, :w]) if self.speculative \
                     else None
             with phase("serving.prefill.dispatch",
@@ -2259,18 +2011,14 @@ class ServingEngine:
         proof."""
         c = self.cache.config
         s_tot = self.scheduler.num_slots
-        widths, w = [], 1
-        while w < c.max_pages_per_slot:
-            widths.append(w)
-            w *= 2
-        widths.append(c.max_pages_per_slot)
-        widths = sorted(set(widths))
-        counts, s = [], 1
-        while s < s_tot:
-            counts.append(s)
-            s *= 2
-        counts.append(s_tot)
-        counts = sorted(set(counts))
+
+        def doubling(cap):          # 1, 2, 4, .. below ``cap``, then it
+            out, n = [], 1
+            while n < cap:
+                out.append(n)
+                n *= 2
+            return out + [cap]
+        widths, counts = doubling(c.max_pages_per_slot), doubling(s_tot)
         plan = []
         for w in widths:
             if self.speculative:
@@ -2368,58 +2116,40 @@ class ServingEngine:
         self.warmed_signatures = set()
         self.bucket_costs = {}
         clock = self.tracer.now
+
+        def ints(*shape):
+            return jnp.zeros(shape, jnp.int32)
+
+        def first_call(step, cache, params, *rest):
+            args = (params, cache.pages) + rest
+            if cost_gauges:
+                self._bucket_cost_gauges(sig, step, args)
+            _, cache.pages = step(*args)
+
         for sig in self.warmup_plan():
             t_sig, cost0 = clock(), self._c_warm_cost.value()
+            w, sb = (sig + (None, None))[1:3]
             if sig[0] == "decode":
-                w = sig[1]
-                args = (self._step_params, self.cache.pages,
-                        jnp.zeros((s_tot, w), jnp.int32), zeros, tok0,
-                        zeros)
-                if self._folds:     # no slot shares a page with another
-                    args += (self._group_decode([])[0],)
-                if cost_gauges:
-                    self._bucket_cost_gauges(sig, self.decode_step, args)
-                _, self.cache.pages = self.decode_step(*args)
+                # (the groups of a block in which no slot shares a page)
+                first_call(self.decode_step, self.cache, self._step_params,
+                           ints(s_tot, w), zeros, tok0, zeros,
+                           *self._shared_groups([]))
             elif sig[0] == "draft":
-                w = sig[1]
-                args = (self.draft_params, self.draft_cache.pages,
-                        jnp.zeros((s_tot, w), jnp.int32), zeros, zeros,
-                        zeros, zeros)
-                if cost_gauges:
-                    self._bucket_cost_gauges(sig, self.draft_propose_step,
-                                             args)
-                _, self.draft_cache.pages = self.draft_propose_step(*args)
+                first_call(self.draft_propose_step, self.draft_cache,
+                           self.draft_params, ints(s_tot, w), zeros, zeros,
+                           zeros, zeros)
             elif sig[0] == "verify":
-                w = sig[1]
-                args = (self._step_params, self.cache.pages,
-                        jnp.zeros((s_tot, w), jnp.int32), zeros, zeros,
-                        jnp.zeros((s_tot, self.spec_k), jnp.int32),
-                        zeros)
-                if cost_gauges:
-                    self._bucket_cost_gauges(sig, self.verify_step, args)
-                _, self.cache.pages = self.verify_step(*args)
+                first_call(self.verify_step, self.cache, self._step_params,
+                           ints(s_tot, w), zeros, zeros,
+                           ints(s_tot, self.spec_k), zeros)
             elif sig[0] == "prefill":
-                w, sb = sig[1], sig[2]
-                zb = jnp.zeros((sb,), jnp.int32)
-                args = (self._step_params, self.cache.pages,
-                        jnp.zeros((sb, w + self._lane_slot_column),
-                                  jnp.int32), zb,
-                        jnp.zeros((sb, self.prefill_chunk), jnp.int32),
-                        zb)
-                if cost_gauges:
-                    self._bucket_cost_gauges(sig, self.prefill_step, args)
-                _, self.cache.pages = self.prefill_step(*args)
+                first_call(self.prefill_step, self.cache, self._step_params,
+                           ints(sb, w + self._lane_slot_column), ints(sb),
+                           ints(sb, self.prefill_chunk), ints(sb))
             elif sig[0] == "draft_prefill":
-                w, sb = sig[1], sig[2]
-                zb = jnp.zeros((sb,), jnp.int32)
-                args = (self.draft_params, self.draft_cache.pages,
-                        jnp.zeros((sb, w), jnp.int32), zb,
-                        jnp.zeros((sb, self.prefill_chunk), jnp.int32),
-                        zb)
-                if cost_gauges:
-                    self._bucket_cost_gauges(sig, self.draft_prefill_step,
-                                             args)
-                _, self.draft_cache.pages = self.draft_prefill_step(*args)
+                first_call(self.draft_prefill_step, self.draft_cache,
+                           self.draft_params, ints(sb, w), ints(sb),
+                           ints(sb, self.prefill_chunk), ints(sb))
             elif sig[0] == "first_token":
                 self.first_token_step(
                     tok0, self._upload(np.zeros((sig[1],), np.int32)))
@@ -2430,20 +2160,13 @@ class ServingEngine:
                 jax.block_until_ready(self.read_page_step(
                     self.cache.pages, jnp.asarray(0, jnp.int32)))
             elif sig[0] == "page_write":
-                c = self.cache.config
-                blank = jnp.zeros((2, c.num_layers, c.page_size,
-                                   c.num_heads, c.head_dim),
-                                  jnp.int8 if self.quantized else c.dtype)
-                if self.quantized:
-                    blank_sc = jnp.zeros((2, c.num_layers, c.page_size),
-                                         jnp.float32)
-                    self.cache.pages = self.write_page_step(
-                        self.cache.pages, jnp.asarray(0, jnp.int32),
-                        blank, blank_sc)
-                else:
-                    self.cache.pages = self.write_page_step(
-                        self.cache.pages, jnp.asarray(0, jnp.int32),
-                        blank)
+                # a blank page: what a page read hands back, in zeros
+                null = jnp.asarray(0, jnp.int32)
+                blank = jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+                    lambda a: jnp.zeros(a.shape, a.dtype), jax.eval_shape(
+                        self.read_page_step, self.cache.pages, null)))
+                self.cache.pages = self.write_page_step(
+                    self.cache.pages, null, *blank)
             else:
                 self.cache.pages = self.copy_page_step(
                     self.cache.pages, jnp.asarray(0, jnp.int32),
@@ -2511,42 +2234,17 @@ class ServingEngine:
             src, dst = pc
             pids = [src if p == dst else p for p in pids]
         shards, manifest = [], []
-        hl = self._tp_heads
         for k, pid in enumerate(pids):
-            page = self.read_page_step(self.cache.pages,
-                                       jnp.asarray(pid, jnp.int32))
-            if self.quantized:
-                kv_all, sc_all = np.asarray(page[0]), np.asarray(page[1])
-            else:
-                kv_all, sc_all = np.asarray(page), None
-            # per-shard shards (ISSUE 15): one sha256-digested shard per
-            # (page, tp shard) — the head axis of (2, L, ps, H, Dh) cut
-            # at mesh-shard boundaries, so each shard's KV travels and
-            # verifies independently (an int8 shard carries the
-            # replicated scale rows alongside — one hash over both, as
-            # before)
-            for t in range(self.tp):
-                kv_t = kv_all[..., t * hl:(t + 1) * hl, :]
-                shard = (kv_t, sc_all) if self.quantized else kv_t
-                shards.append(shard)
-                manifest.append({
-                    "index": k,
-                    "tp_shard": t,
-                    "sha256": self._shard_digest(shard),
-                    "bytes": self._shard_bytes(shard),
-                })
+            cut_up = self._page_shards(k, self._read_page(pid))
+            shards += cut_up[0]
+            manifest += cut_up[1]
         root = self._req_spans.get(req.rid)
         trace_id = (root.trace_id if root is not None
                     else self._ext_trace.get(req.rid, 0))
         acc = self._phase_acc.get(req.rid) or {}
         return {
             "format": MIGRATION_FORMAT,
-            "geometry": {"num_layers": cfgc.num_layers,
-                         "num_heads": cfgc.num_heads,
-                         "head_dim": cfgc.head_dim,
-                         "page_size": cfgc.page_size,
-                         "dtype": str(jnp.dtype(cfgc.dtype)),
-                         "tp": self.tp},
+            "geometry": self._geometry(),
             "request": {"prompt": np.asarray(req.prompt, np.int32),
                         "max_new_tokens": req.max_new_tokens,
                         "eos_id": req.eos_id, "lane": req.lane,
@@ -2620,22 +2318,57 @@ class ServingEngine:
         self._refresh_health()
         return out
 
+    def _geometry(self) -> Dict[str, object]:
+        """What a snapshot or a prefix bundle must agree with this engine
+        on: the shard layout IS part of the transfer format."""
+        c = self.cache.config
+        return {"num_layers": c.num_layers, "num_heads": c.num_heads,
+                "head_dim": c.head_dim, "page_size": c.page_size,
+                "dtype": str(jnp.dtype(c.dtype)), "tp": self.tp}
+
+    def _read_page(self, pid: int) -> tuple:
+        """Page ``pid`` on the host as the page read hands it over:
+        ``(kv,)``, or ``(kv, scales)`` where the pages carry scale rows."""
+        page = self.read_page_step(self.cache.pages,
+                                   jnp.asarray(pid, jnp.int32))
+        return tuple(np.asarray(a)
+                     for a in (page if self.quantized else (page,)))
+
+    def _page_shards(self, index: int, payload):
+        """One page's host arrays as its transfer shards: one
+        sha256-digested shard per (page, tp shard), the head axis of (2,
+        L, ps, H, Dh) cut at mesh-shard boundaries, so each shard's KV
+        travels and verifies independently (an int8 shard carries the
+        replicated scale rows alongside: one hash over both). Returns
+        (shards, their manifest entries)."""
+        hl = self._tp_heads
+        shards, manifest = [], []
+        for t in range(self.tp):
+            kv_t = payload[0][..., t * hl:(t + 1) * hl, :]
+            shard = (kv_t, payload[1]) if self.quantized else kv_t
+            shards.append(shard)
+            manifest.append({"index": index, "tp_shard": t,
+                             "sha256": self._shard_digest(shard),
+                             "bytes": sum(int(a.nbytes) for a in (
+                                 shard if self.quantized else (shard,)))})
+        return shards, manifest
+
+    def _install_page(self, dst: int, chunks):
+        """Write page ``dst`` from its tp shards: head-axis chunks back in
+        mesh-shard order (scale rows, in every shard, from the first)."""
+        kv = np.concatenate([np.asarray(c[0] if self.quantized else c)
+                             for c in chunks], axis=3)
+        rest = (jnp.asarray(chunks[0][1]),) if self.quantized else ()
+        self.cache.pages = self.write_page_step(
+            self.cache.pages, jnp.asarray(dst, jnp.int32), jnp.asarray(kv),
+            *rest)
+
     def _shard_digest(self, shard) -> str:
         """sha256 of one migration shard — a quantized shard hashes the
         int8 KV AND its scale rows as one digest (a scale-only
         corruption is as fatal as a KV corruption and must be refused
         the same way)."""
-        if self.quantized:
-            kv, sc = shard
-            h = hashlib.sha256(np.asarray(kv).tobytes())
-            h.update(np.asarray(sc).tobytes())
-            return h.hexdigest()
-        return hashlib.sha256(np.asarray(shard).tobytes()).hexdigest()
-
-    def _shard_bytes(self, shard) -> int:
-        if self.quantized:
-            return int(shard[0].nbytes + shard[1].nbytes)
-        return int(shard.nbytes)
+        return payload_digest(shard if self.quantized else (shard,))
 
     def cancel_queued(self) -> List[Request]:
         """Pop every queued (not yet admitted) request and close its
@@ -2714,11 +2447,7 @@ class ServingEngine:
             raise SlotMigrationError(
                 f"unknown snapshot format {snap.get('format')!r}")
         cfgc = self.cache.config
-        geo = snap["geometry"]
-        mine = {"num_layers": cfgc.num_layers, "num_heads": cfgc.num_heads,
-                "head_dim": cfgc.head_dim, "page_size": cfgc.page_size,
-                "dtype": str(jnp.dtype(cfgc.dtype)),
-                "tp": self.tp}
+        geo, mine = snap["geometry"], self._geometry()
         if geo != mine:
             # cross-tp restore is refused like any other geometry
             # mismatch: the shard layout IS part of the transfer format
@@ -2775,21 +2504,8 @@ class ServingEngine:
         for k in range(n_live):
             # reassemble each page from its tp shards: hash-verified
             # head-axis chunks concatenated back in mesh-shard order
-            chunks = shards[k * tp_shards:(k + 1) * tp_shards]
-            dst = int(self.cache.block_tables[slot, k])
-            if self.quantized:
-                kv = np.concatenate([np.asarray(c[0]) for c in chunks],
-                                    axis=3)
-                sc = chunks[0][1]
-                self.cache.pages = self.write_page_step(
-                    self.cache.pages, jnp.asarray(dst, jnp.int32),
-                    jnp.asarray(kv), jnp.asarray(sc))
-            else:
-                kv = np.concatenate([np.asarray(c) for c in chunks],
-                                    axis=3)
-                self.cache.pages = self.write_page_step(
-                    self.cache.pages, jnp.asarray(dst, jnp.int32),
-                    jnp.asarray(kv))
+            self._install_page(int(self.cache.block_tables[slot, k]),
+                               shards[k * tp_shards:(k + 1) * tp_shards])
         self.cache.lengths[slot] = int(stt["length"])
         rid = next(self.scheduler._ids)     # fresh local rid, no collision
         req = Request(rid, prompt, int(rq["max_new_tokens"]),
@@ -2849,9 +2565,6 @@ class ServingEngine:
         self._settle_pending()
         if not self.cache.config.share_prefix:
             return None
-        cfgc = self.cache.config
-        hl = self._tp_heads
-        tp_shards = self.tp
         pages, total_bytes = [], 0
         for key in digests:
             key = int(key)
@@ -2860,39 +2573,14 @@ class ServingEngine:
                 break
             if hit[0] == "device":
                 _, pid, tokens = hit
-                page = self.read_page_step(self.cache.pages,
-                                           jnp.asarray(pid, jnp.int32))
-                if self.quantized:
-                    kv_all = np.asarray(page[0])
-                    sc_all = np.asarray(page[1])
-                else:
-                    kv_all, sc_all = np.asarray(page), None
+                payload = self._read_page(pid)
             else:
                 ent = hit[1]
-                if payload_digest(ent.payload) != ent.sha256:
-                    # a rotted host copy must never leave this replica;
-                    # drop it so the advertisement goes stale too
-                    self.cache.spill_pool.pop(ent.key)
-                    self._reg.counter(
-                        "serving_spill_corrupt_total",
-                        "host-spilled pages refused on restore "
-                        "(sha256 mismatch)").inc()
+                if self._spill_rotted(ent):
                     break
-                tokens = ent.tokens
-                kv_all = ent.payload[0]
-                sc_all = ent.payload[1] if self.quantized else None
-            shards, manifest = [], []
-            for t in range(tp_shards):
-                kv_t = kv_all[..., t * hl:(t + 1) * hl, :]
-                shard = (kv_t, sc_all) if self.quantized else kv_t
-                shards.append(shard)
-                manifest.append({
-                    "index": len(pages),
-                    "tp_shard": t,
-                    "sha256": self._shard_digest(shard),
-                    "bytes": self._shard_bytes(shard),
-                })
-                total_bytes += manifest[-1]["bytes"]
+                tokens, payload = ent.tokens, ent.payload
+            shards, manifest = self._page_shards(len(pages), payload)
+            total_bytes += sum(rec["bytes"] for rec in manifest)
             pages.append({"key": key,
                           "tokens": np.asarray(tokens, np.int32),
                           "shards": shards, "manifest": manifest})
@@ -2904,12 +2592,7 @@ class ServingEngine:
         ).inc(len(pages))
         return {
             "format": PREFIX_BUNDLE_FORMAT,
-            "geometry": {"num_layers": cfgc.num_layers,
-                         "num_heads": cfgc.num_heads,
-                         "head_dim": cfgc.head_dim,
-                         "page_size": cfgc.page_size,
-                         "dtype": str(jnp.dtype(cfgc.dtype)),
-                         "tp": tp_shards},
+            "geometry": self._geometry(),
             "pages": pages,
             "bytes": int(total_bytes),
         }
@@ -2935,10 +2618,7 @@ class ServingEngine:
                 f"unknown prefix bundle format {bundle.get('format')!r}")
         cfgc = self.cache.config
         tp_shards = self.tp
-        mine = {"num_layers": cfgc.num_layers, "num_heads": cfgc.num_heads,
-                "head_dim": cfgc.head_dim, "page_size": cfgc.page_size,
-                "dtype": str(jnp.dtype(cfgc.dtype)),
-                "tp": tp_shards}
+        mine = self._geometry()
         if bundle.get("geometry") != mine:
             raise SlotMigrationError(
                 f"cache geometry mismatch: bundle "
@@ -2983,20 +2663,7 @@ class ServingEngine:
         for page in install:
             pid = self.cache.adopt_published_page(
                 int(page["key"]), page["tokens"])
-            chunks = page["shards"]
-            if self.quantized:
-                kv = np.concatenate([np.asarray(c[0]) for c in chunks],
-                                    axis=3)
-                sc = chunks[0][1]
-                self.cache.pages = self.write_page_step(
-                    self.cache.pages, jnp.asarray(pid, jnp.int32),
-                    jnp.asarray(kv), jnp.asarray(sc))
-            else:
-                kv = np.concatenate([np.asarray(c) for c in chunks],
-                                    axis=3)
-                self.cache.pages = self.write_page_step(
-                    self.cache.pages, jnp.asarray(pid, jnp.int32),
-                    jnp.asarray(kv))
+            self._install_page(pid, page["shards"])
             nbytes += sum(int(r["bytes"]) for r in page["manifest"])
         self._reg.counter(
             "serving_prefix_fetched_pages_total",
@@ -3010,175 +2677,18 @@ class ServingEngine:
 
     # -- jitted step bodies ----------------------------------------------
 
-    def _write_rows(self, ent, rows, page_idx, off, quantized, psum_axis):
-        """Land one call's rows in a layer's pool entry: K and V (int8
-        rows + per-token scales for a quantized pool; a latent row's two
-        parts where the program caches one), then the
-        program's extra rows, each where ``page_idx`` / ``off`` say
-        (one index a token: ``(S,)`` for decode, ``(S, C)`` for a
-        chunk). Extra rows are kept ``(P, width, page_size)``, tokens
-        along the lanes, and written a page tile at a time
-        (:meth:`_write_lane_rows`)."""
-        k_tok, v_tok = rows[0], rows[1]
-        if quantized:
-            kp, vp, ksc, vsc = ent
-            ax = (k_tok.ndim - 1,)
-            kq, k_s = quantize_kv(k_tok, ax, psum_axis=psum_axis)
-            vq, v_s = quantize_kv(v_tok, ax, psum_axis=psum_axis)
-            return (kp.at[page_idx, off].set(kq),
-                    vp.at[page_idx, off].set(vq),
-                    ksc.at[page_idx, off].set(k_s),
-                    vsc.at[page_idx, off].set(v_s))
-        # token-major pools first: K and V, or a latent row's latent alone
-        # (its shared rotary key is kept as an extra row is)
-        major = 1 if self._latent else 2
-        out = [pool.at[page_idx, off].set(row.astype(pool.dtype))
-               for pool, row in zip(ent[:major], rows[:major])]
-        for pool, row in zip(ent[major:], rows[major:]):
-            out.append(self._write_lane_rows(pool, row, page_idx, off))
-        return tuple(out)
-
     @staticmethod
-    def _write_lane_rows(pool, row, page_idx, off):
-        """Tokens into a ``(P, width, page_size)`` pool, whose lanes are
-        the tokens of a page: every page a call touches is read, the
-        call's tokens placed in their lanes, and the tile written back
-        whole. (A scatter along the lane axis makes the chip's compiler
-        re-lay the whole pool out and back around it.) ``row`` (S, C,
-        width) or (S, width) with one ``page_idx`` / ``off`` a token;
-        ``page_idx`` 0 marks a token that is not written. A lane's
-        tokens are consecutive positions, so they touch at most
-        ``(C - 1) // page_size + 2`` pages, in order."""
-        s = row.shape[0]
-        width, ps = pool.shape[1], pool.shape[2]
-        row = row.reshape(s, -1, width).astype(pool.dtype)      # (S,C,W)
-        page_idx, off = page_idx.reshape(s, -1), off.reshape(s, -1)
-        c = row.shape[1]
-        live = page_idx > 0
-        # which of the lane's touched pages a token lands in: it moves
-        # on where ``off`` wraps
-        nth = jnp.cumsum(jnp.concatenate(
-            [jnp.zeros((s, 1), jnp.int32),
-             (off[:, 1:] < off[:, :-1]).astype(jnp.int32)], axis=1), axis=1)
-        lanes = jnp.arange(ps, dtype=jnp.int32)
-        for k in range((c - 1) // ps + 2 if c > 1 else 1):
-            here = live & (nth == k)                            # (S,C)
-            page = jnp.max(jnp.where(here, page_idx, 0), axis=1)  # (S,)
-            put = (here[:, :, None]
-                   & (off[:, :, None] == lanes)).astype(pool.dtype)  # (S,C,ps)
-            tile = jnp.einsum("scw,scp->swp", row, put)     # one-hot: exact
-            written = jnp.any(put > 0, axis=1)[:, None, :]      # (S,1,ps)
-            pool = pool.at[page].set(
-                jnp.where(written, tile, pool[page]))
-        return pool
-
-    def _ring(self, window, slots, first_page, width):
-        """Window layers keep a slot's K and V in a ring of pages, found
-        by the slot and the position alone: -> (pages (S,) or (S, C) of
-        the tokens of sequence page ``first_page`` (same shape), the
-        table (S, ``width``) of the pages ``first_page[s] ..`` as the
-        paged kernels take one)."""
-        ring = self.cache.config.ring_pages(window)
-        base = 1 + slots * ring
-
-        def pages(seq_page):
-            return base.reshape(base.shape + (1,) * (seq_page.ndim - 1)) \
-                + seq_page % ring
-        return pages, pages(first_page[:, None]
-                            + jnp.arange(width, dtype=jnp.int32))
-
-    def _window_decode(self, window, slots, lengths, writable):
-        """What a window layer's decode token needs, from the slot and
-        its ``lengths`` (the token being written not counted): (the ring
-        page it is written to (S,), the table of the pages its window
-        spans, the tokens in them up to and with this one)."""
-        ps = self.cache.config.page_size
-        first = jnp.maximum(lengths + 1 - window, 0) // ps
-        pages, table = self._ring(window, slots, first,
-                                  self.cache.config.ring_pages(window))
-        return (jnp.where(writable, pages(lengths // ps), 0), table,
-                lengths + 1 - first * ps)
-
-    def _window_prefill(self, window, slots, starts, positions, valid):
-        """A window layer's chunk: (the ring pages its tokens are written
-        to (S, C), the table of the pages the chunk's windows span, the
-        chunk's start in them). A chunk of at most a page of tokens spans
-        the ring and, where it starts inside a page, that page's next
-        lap: one column more than the ring, the stale rows of either lap
-        outside every query's window or past it."""
-        ps = self.cache.config.page_size
-        first = jnp.maximum(starts - window + 1, 0) // ps
-        pages, table = self._ring(window, slots, first,
-                                  self.cache.config.ring_pages(window) + 1)
-        return (jnp.where(valid, pages(positions // ps), 0), table,
-                starts - first * ps)
-
-    def _selects(self, spec, block_tables) -> bool:
-        """Whether a call at this gather width runs the program's token
-        selection: a static fact of the bucket. Up to ``select_topk``
-        cached tokens every query attends to all it sees, so narrower
-        buckets take the dense kernels."""
-        return spec.select_topk is not None and block_tables.shape[1] \
-            * self.cache.config.page_size > spec.select_topk
-
-    def _attend_decode(self, spec, q, ent, block_tables, lengths, index,
-                       quantized, groups=None):
-        """One decode token a slot, ``q`` (S, H, Dh), over the pool entry
-        ``ent`` as just written; ``lengths`` counts this token. Returns
-        (heads (S, H, Dh), tokens attended a slot (S,)); a program of
-        latent rows: ``q`` against the whole row, heads (S, H, latent).
-        A program of latent rows, and one that selects in a bucket wide
-        enough, read the pages that the tables of a group of ``groups``
-        open with ONCE for the group's slots."""
-        if spec.latent_row is not None:
-            return DA.latent_paged_decode_attention(
-                q, ent[0], ent[1], block_tables, lengths, groups,
-                impl=self.attn_impl), lengths
-        if quantized:
-            return DA.ragged_paged_decode_int8_attention(
-                q, *ent, block_tables, lengths, impl=self.attn_impl), lengths
-        if self._selects(spec, block_tables):
-            return SA.indexed_decode_attention(
-                q, *ent, block_tables, lengths, index[0][:, 0],
-                index[1][:, 0], spec.select_topk, groups=groups,
-                impl=self.attn_impl)
-        return DA.ragged_paged_decode_attention(
-            q, ent[0], ent[1], block_tables, lengths,
-            impl=self.attn_impl), lengths
-
-    def _attend_prefill(self, spec, q, ent, block_tables, starts, n_valid,
-                        index, quantized):
-        """A chunk of queries a slot, ``q`` (S, C, H, Dh), causally over
-        the pool entry ``ent`` as just written. Returns heads (S, C, H,
-        Dh); a program of latent rows: (S, C, H, latent)."""
-        if spec.latent_row is not None:
-            return DA.latent_paged_prefill_attention(
-                q, ent[0], ent[1], block_tables, starts, n_valid,
-                impl=self.attn_impl)
-        if quantized:
-            return DA.ragged_paged_prefill_int8_attention(
-                q, *ent, block_tables, starts, n_valid, impl=self.attn_impl)
-        if self._selects(spec, block_tables):
-            return SA.indexed_prefill_attention(
-                q, *ent, block_tables, starts, n_valid, index[0], index[1],
-                spec.select_topk, impl=self.attn_impl)
-        return DA.ragged_paged_prefill_attention(
-            q, ent[0], ent[1], block_tables, starts, n_valid,
-            impl=self.attn_impl)
-
-    @staticmethod
-    def _stat_names(spec):
+    def _stat_names(spec, kinds):
         """The counts a step of this program hands back beside the
-        tokens: the program's own, then attention's where it selects."""
-        return tuple(spec.stats) + (
-            ("attn_context_tokens", "attn_selected_tokens")
-            if spec.select_topk is not None else ())
+        tokens: the program's own, then those of its layers' kinds."""
+        return tuple(spec.stats) + tuple(dict.fromkeys(
+            name for kind in kinds for name in kind.stat_names))
 
-    def _step_stat_vector(self, spec, ffn_stats, context, selected):
+    @staticmethod
+    def _step_stat_vector(spec, kind, ffn_stats, context, selected):
         """One layer-call's counts in :meth:`_stat_names` order."""
         vals = [ffn_stats[name] for name in spec.stats]
-        if spec.select_topk is not None:
-            vals += [context, selected]
+        vals += kind.step_counts(context, selected)
         return jnp.stack([jnp.asarray(v).astype(jnp.int32) for v in vals])
 
     @staticmethod
@@ -3216,41 +2726,33 @@ class ServingEngine:
 
     def _decode_loop(self, params, pages, block_tables, lengths, tokens,
                      active, n_valid=None, groups=None, *, program=None,
-                     quantized=False, n_steps=1, psum_axis=None):
+                     kinds=(), n_steps=1):
         """The shared greedy token loop behind the decode step AND the
         draft-proposal step, written against what a model supplies
-        (``program``, see :mod:`paddle_tpu.serving.program`): ``n_steps``
-        inner iterations, each entering every slot's current token at
-        position ``lengths[s]``, landing the rows the program wants
-        cached in the slot's current page (quantized caches store the
-        int8 rows + per-token scales and attend through the
-        dequant-attend kernel), and attending ragged-paged over live
-        pages only — or, where the program selects and the bucket is
-        wide enough (:meth:`_selects`), over the selected tokens only.
+        (``program``, :mod:`paddle_tpu.serving.program`) and what its
+        cache's layers are (``kinds``, one a layer,
+        :mod:`paddle_tpu.serving.layer_kinds`): ``n_steps`` inner
+        iterations, each entering every slot's current token at position
+        ``lengths[s]``, landing the rows the program wants cached where
+        the layer's kind places them, and attending as the kind does.
         ``n_valid`` (draft proposing) additionally masks writes of
         iterations ``j >= n_valid[s]`` to the null page — a chunk capped
         below ``n_steps`` must not write past the slot's reservation.
-        Under tp the program's body is one head shard's; ``psum_axis``
-        completes the int8 scales' abs-max over the shards so
-        quantization stays bit-identical to tp=1. ``groups`` (a program
-        whose decode folds shared pages: latent rows, or a token
-        selection): which decoding slots' tables open with the same
-        pages, fixed for the block
-        (:func:`decode_attention.decode_groups`). The keyword-only
-        args are static config (default-marked so the AST host-sync lint,
-        which runs on THIS body via the graph_lint preset, seeds only
-        the array args as tracers). Returns (tokens (S, n_steps), pages),
-        or ((tokens, counts), pages) where the program counts
-        (``self._step_stats`` order)."""
+        ``groups`` (a kind whose decode folds shared pages): which
+        decoding slots' tables open with the same pages, fixed for the
+        block. The keyword-only args are static config (default-marked so
+        the AST host-sync lint, which runs on THIS body via the graph_lint
+        preset, seeds only the array args as tracers). Returns (tokens
+        (S, n_steps), pages), or ((tokens, counts), pages) where the
+        program counts (``self._step_stats`` order)."""
         spec = program.spec
         ps = self.cache.config.page_size
         s_tot = tokens.shape[0]
-        w = block_tables.shape[1]
         slot_ids = jnp.arange(s_tot)
-        n_stats = len(self._stat_names(spec))
+        n_stats = len(self._stat_names(spec, kinds))
         n_paged = len(pages[0]) - len(spec.slot_state)
         mixes = bool(spec.slot_state) and spec.slot_state_reader == "mixer"
-        windows = spec.layer_windows or (None,) * spec.num_layers
+        distinct = tuple(dict.fromkeys(kinds))
 
         def one_token(j, pages, lengths, tokens):
             pos = jnp.minimum(lengths, spec.max_position - 1)
@@ -3259,11 +2761,8 @@ class ServingEngine:
             writable = active > 0
             if n_valid is not None:
                 writable = writable & (j < n_valid)
-            page_idx = jnp.where(
-                writable,
-                block_tables[slot_ids, jnp.minimum(lengths // ps, w - 1)],
-                0)
-            off = lengths % ps
+            under = layer_kinds.under_table(
+                block_tables, lengths, writable, slot_ids, ps, lengths)
             with jax.named_scope("stats"):
                 seen = jnp.where(writable, lengths + 1, 0).sum()
             # a decoding slot's state is pool row slot + 1; any other
@@ -3271,34 +2770,23 @@ class ServingEngine:
             # not this block's to touch: the null row
             state_rows = jnp.where(writable, slot_ids + 1, 0) \
                 if spec.slot_state else None
-            ringed = {win: self._window_decode(win, slot_ids, lengths,
-                                               writable)
-                      for win in set(windows) - {None}}
+            places = {kind: kind.place_decode(under, writable, slot_ids)
+                      for kind in distinct}
             new_pages, counts = [], 0
             carry = self._carry_start(spec, s_tot, 1)
-            for i in range(spec.num_layers):
-                win = windows[i]
+            for i, kind in enumerate(kinds):
                 with jax.named_scope("attn_in"):
                     q, rows, index, state = self._attn_in(
                         program, params, i, x, pos[:, None],
                         pages[i][n_paged:], state_rows, None, writable)
                 with jax.named_scope("write_rows"):
-                    ent = self._write_rows(
-                        pages[i][:n_paged], tuple(r[:, 0] for r in rows),
-                        page_idx if win is None else ringed[win][0], off,
-                        quantized, psum_axis)
+                    ent = kind.write(pages[i][:n_paged],
+                                     tuple(r[:, 0] for r in rows),
+                                     places[kind])
                 with jax.named_scope("attend"):
-                    if win is None:
-                        att, attended = self._attend_decode(
-                            spec, q[:, :, 0, :], ent, block_tables,
-                            lengths + 1, index, quantized,
-                            groups)                             # (S,H,Dh)
-                    else:
-                        _, table, held = ringed[win]
-                        att = DA.ragged_paged_decode_attention(
-                            q[:, :, 0, :], ent[0], ent[1], table, held,
-                            impl=self.attn_impl, window=win)
-                        attended = jnp.minimum(lengths + 1, win)
+                    att, attended = kind.attend_decode(
+                        q[:, :, 0, :], ent, places[kind], index,
+                        groups)                                 # (S,H,Dh)
                 with jax.named_scope("attn_out"):
                     x_in, x = x, program.attn_out(params, i, x, att[:, None])
                 if mixes:
@@ -3316,7 +2804,7 @@ class ServingEngine:
                 if n_stats:
                     with jax.named_scope("stats"):
                         counts = counts + self._step_stat_vector(
-                            spec, ffn_stats, seen,
+                            spec, kind, ffn_stats, seen,
                             jnp.where(writable, attended, 0).sum())
             with jax.named_scope("head"):
                 logits = program.head(params, x[:, 0])
@@ -3352,9 +2840,8 @@ class ServingEngine:
         return self._decode_loop(params, pages, block_tables, lengths,
                                  tokens, active, groups=groups,
                                  program=self.program,
-                                 quantized=self.quantized,
-                                 n_steps=self.decode_block,
-                                 psum_axis="tp" if self.tp > 1 else None)
+                                 kinds=self.cache.config.kinds,
+                                 n_steps=self.decode_block)
 
     def _draft_propose_step_impl(self, params, pages, block_tables,
                                  lengths, tokens, active, n_valid):
@@ -3366,90 +2853,74 @@ class ServingEngine:
         return self._decode_loop(params, pages, block_tables, lengths,
                                  tokens, active, n_valid,
                                  program=self.draft_program,
-                                 quantized=self._draft_quantized,
+                                 kinds=self.draft_cache.config.kinds,
                                  n_steps=self.spec_k)
 
     def _prefill_loop(self, params, pages, block_tables, starts, tokens,
-                      n_valid, *, program=None, quantized=False,
-                      all_positions=False, psum_axis=None):
+                      n_valid, *, program=None, kinds=(),
+                      all_positions=False):
         """The shared chunk-forward behind the batched prefill step, the
         draft prefill step, and the speculative VERIFY step, written
-        against what a model supplies (``program``): ``tokens`` (S, C)
-        enter at absolute positions ``starts[s]..starts[s]+C-1`` (first
-        ``n_valid[s]`` real, rest pad to the null page), the rows the
-        program wants cached land in each slot's pages (quantized: int8 +
-        scale rows), and every live lane attends causally over everything
-        cached — each query under its own selection where the program
-        selects and the bucket is wide enough (:meth:`_selects`).
+        against what a model supplies (``program``) and what its cache's
+        layers are (``kinds``): ``tokens`` (S, C) enter at absolute
+        positions ``starts[s]..starts[s]+C-1`` (first ``n_valid[s]`` real,
+        rest pad to the null page), the rows the program wants cached land
+        where each layer's kind places them, and every live lane attends
+        causally over everything cached, as the kind does.
         ``all_positions=False`` returns the greedy next token after each
         slot's LAST valid position (prefill's first generated token);
         ``all_positions=True`` returns the greedy argmax after EVERY
         chunk position (S, C) — the speculative verifier's per-candidate
-        target tokens. ``psum_axis`` as in :meth:`_decode_loop`.
-        Keyword-only args are static config (the AST host-sync lint runs
-        on this body — see :meth:`_decode_loop`). Returns (tokens,
-        pages), or ((tokens, counts), pages) where the program counts."""
+        target tokens. Keyword-only args are static config (the AST
+        host-sync lint runs on this body — see :meth:`_decode_loop`).
+        Returns (tokens, pages), or ((tokens, counts), pages) where the
+        program counts."""
         spec = program.spec
         ps = self.cache.config.page_size
         s_tot, c = tokens.shape
         n_paged = len(pages[0]) - len(spec.slot_state)
         mixes = bool(spec.slot_state) and spec.slot_state_reader == "mixer"
-        windows = spec.layer_windows or (None,) * spec.num_layers
+        distinct = tuple(dict.fromkeys(kinds))
         state_rows = fresh = None
-        if spec.slot_state or spec.layer_windows:
-            # the lanes' state rows ride the tables' last column; a lane
-            # whose prompt starts here starts from zeros, its slot's
-            # reset at admission
+        if spec.slot_state or any(kind.by_slot for kind in distinct):
+            # the lanes' slots (pool row slot + 1) ride the tables' last
+            # column; a lane whose prompt starts here starts from zeros,
+            # its slot's reset at admission
             state_rows, block_tables = block_tables[:, -1], \
                 block_tables[:, :-1]
             fresh = (starts == 0).astype(jnp.int32)
-        w = block_tables.shape[1]
         positions = starts[:, None] + jnp.arange(c, dtype=jnp.int32)
         pos_e = jnp.minimum(positions, spec.max_position - 1)
         with jax.named_scope("embed"):
             x = program.embed(params, tokens, pos_e)            # (S,C,D)
         valid = jnp.arange(c)[None, :] < n_valid[:, None]
         slot_ids = jnp.arange(s_tot)[:, None]
-        page_idx = jnp.where(
-            valid,
-            block_tables[slot_ids, jnp.minimum(positions // ps, w - 1)],
-            0)
-        off = positions % ps
-        counting = bool(self._stat_names(spec))
+        under = layer_kinds.under_table(
+            block_tables, positions, valid, slot_ids, ps, starts)
+        counting = bool(self._stat_names(spec, kinds))
         with jax.named_scope("stats"):
             seen = positions + 1             # tokens a query can attend to
-            attended = jnp.minimum(seen, spec.select_topk) \
-                if self._selects(spec, block_tables) else seen
-            seen, attended = (jnp.where(valid, a, 0).sum()
-                              for a in (seen, attended))
-        # a lane's ring is its slot's: pool row less one (a pad lane
-        # writes nothing and attends to nothing)
-        ringed = {win: self._window_prefill(
-            win, jnp.maximum(state_rows - 1, 0), starts, positions, valid)
-            for win in set(windows) - {None}}
+            attended = {kind: kind.attends_prefill(seen, block_tables)
+                        for kind in distinct}
+            seen = jnp.where(valid, seen, 0).sum()
+            attended = {kind: jnp.where(valid, a, 0).sum()
+                        for kind, a in attended.items()}
+        places = {kind: kind.place_prefill(under, positions, valid,
+                                           state_rows)
+                  for kind in distinct}
         new_pages, counts = [], 0
         carry = self._carry_start(spec, s_tot, c)
-        for i in range(spec.num_layers):
-            win = windows[i]
+        for i, kind in enumerate(kinds):
             with jax.named_scope("attn_in"):
                 q, rows, index, state = self._attn_in(
                     program, params, i, x, pos_e, pages[i][n_paged:],
                     state_rows, fresh, valid)
             with jax.named_scope("write_rows"):
-                ent = self._write_rows(
-                    pages[i][:n_paged], rows,
-                    page_idx if win is None else ringed[win][0], off,
-                    quantized, psum_axis)
+                ent = kind.write(pages[i][:n_paged], rows, places[kind])
             with jax.named_scope("attend"):
-                if win is None:
-                    att = self._attend_prefill(
-                        spec, q.transpose(0, 2, 1, 3), ent, block_tables,
-                        starts, n_valid, index, quantized)      # (S,C,H,Dh)
-                else:
-                    _, table, start_in = ringed[win]
-                    att = DA.ragged_paged_prefill_attention(
-                        q.transpose(0, 2, 1, 3), ent[0], ent[1], table,
-                        start_in, n_valid, impl=self.attn_impl, window=win)
+                att = kind.attend_prefill(
+                    q.transpose(0, 2, 1, 3), ent, places[kind], n_valid,
+                    index)                                      # (S,C,H,Dh)
             with jax.named_scope("attn_out"):
                 x_in, x = x, program.attn_out(params, i, x, att)
             if mixes:
@@ -3467,7 +2938,7 @@ class ServingEngine:
             if counting:
                 with jax.named_scope("stats"):
                     counts = counts + self._step_stat_vector(
-                        spec, ffn_stats, seen, attended)
+                        spec, kind, ffn_stats, seen, attended[kind])
         with jax.named_scope("head"):
             if all_positions:
                 logits = program.head(params, x)                # (S,C,V)
@@ -3487,8 +2958,7 @@ class ServingEngine:
         slot's last valid position (S,), pages)."""
         return self._prefill_loop(params, pages, block_tables, starts,
                                   tokens, n_valid, program=self.program,
-                                  quantized=self.quantized,
-                                  psum_axis="tp" if self.tp > 1 else None)
+                                  kinds=self.cache.config.kinds)
 
     def _draft_prefill_step_impl(self, params, pages, block_tables,
                                  starts, tokens, n_valid):
@@ -3498,7 +2968,7 @@ class ServingEngine:
         return self._prefill_loop(params, pages, block_tables, starts,
                                   tokens, n_valid,
                                   program=self.draft_program,
-                                  quantized=self._draft_quantized)
+                                  kinds=self.draft_cache.config.kinds)
 
     def _verify_step_impl(self, params, pages, block_tables, starts,
                           tokens, props, n_valid):
@@ -3516,7 +2986,7 @@ class ServingEngine:
             [tokens[:, None], props[:, :self.spec_k - 1]], axis=1)
         return self._prefill_loop(params, pages, block_tables, starts,
                                   chunk, n_valid, program=self.program,
-                                  quantized=self.quantized,
+                                  kinds=self.cache.config.kinds,
                                   all_positions=True)
 
     def _first_token_impl(self, tokens, nxt):
@@ -3539,15 +3009,13 @@ class ServingEngine:
 
     def _copy_page_impl(self, pages, src, dst):
         """Device-side page copy (CoW of a borrowed shared tail page):
-        every layer's K and V page ``src`` duplicated into ``dst`` —
-        including the scale rows of a quantized pool, which travel with
-        their page. Fixed shape — src/dst are traced scalars, so one
+        every layer's page ``src`` duplicated into ``dst`` as the layer's
+        kind copies one (whatever arrays its entry holds travel with
+        their page). Fixed shape — src/dst are traced scalars, so one
         compile covers every copy."""
-        c = self.cache.config
-        n_paged = c.paged_entries
-        return [ent if c.window_of(i) is not None else
-                tuple(a.at[dst].set(a[src]) for a in ent[:n_paged])
-                + tuple(ent[n_paged:]) for i, ent in enumerate(pages)]
+        return [kind.copy_page(ent[:len(kind.pools)], src, dst)
+                + tuple(ent[len(kind.pools):])
+                for kind, ent in zip(self.cache.config.kinds, pages)]
 
     def _read_page_impl(self, pages, src):
         """One page's K/V across every layer, stacked (2, L, page_size,
@@ -3561,15 +3029,11 @@ class ServingEngine:
         ``src`` is a traced scalar: one compile covers every page ever
         snapshotted."""
         c = self.cache.config
-        ks = jnp.stack([ent[0][src] for ent in pages])
-        vs = jnp.stack([ent[1][src] for ent in pages])
+        ks, vs, *scales = (jnp.stack([ent[j][src] for ent in pages])
+                           for j in range(4 if self.quantized else 2))
         kv = jnp.stack([ks, vs]).reshape(
             2, len(pages), c.page_size, c.num_heads, c.head_dim)
-        if self.quantized:
-            ksc = jnp.stack([ent[2][src] for ent in pages])
-            vsc = jnp.stack([ent[3][src] for ent in pages])
-            return kv, jnp.stack([ksc, vsc])
-        return kv
+        return (kv, jnp.stack(scales)) if scales else kv
 
     def _write_page_impl(self, pages, dst, kv, sc=None):
         """Install one migration shard (the :meth:`_read_page_impl`
@@ -3579,16 +3043,7 @@ class ServingEngine:
         page; pages donated, dst a traced scalar — one compile covers
         every restore."""
         kv = kv.reshape(kv.shape[:3] + (-1,))
-        out = []
-        for i, ent in enumerate(pages):
-            if self.quantized:
-                kp, vp, ksc, vsc = ent
-                out.append((kp.at[dst].set(kv[0, i].astype(kp.dtype)),
-                            vp.at[dst].set(kv[1, i].astype(vp.dtype)),
-                            ksc.at[dst].set(sc[0, i]),
-                            vsc.at[dst].set(sc[1, i])))
-            else:
-                kp, vp = ent
-                out.append((kp.at[dst].set(kv[0, i].astype(kp.dtype)),
-                            vp.at[dst].set(kv[1, i].astype(vp.dtype))))
-        return out
+        parts = (kv[0], kv[1]) + (() if sc is None else (sc[0], sc[1]))
+        return [tuple(pool.at[dst].set(part[i].astype(pool.dtype))
+                      for pool, part in zip(ent, parts))
+                for i, ent in enumerate(pages)]

@@ -1,0 +1,796 @@
+"""What a layer's kind of attention is, and how its rows are cached.
+
+Decided HERE and nowhere else: :func:`build` reads the program's
+:class:`~paddle_tpu.serving.program.ServingSpec` and the cache's geometry
+once and returns one kind a layer (layers that are alike share one
+object). The page cache asks a kind what the layer's pool entry is; the
+engine's two loops ask it where a call's tokens go, have it write the rows
+and attend; the host has it count. The kinds call the paged kernels
+(:mod:`~paddle_tpu.serving.decode_attention`,
+:mod:`~paddle_tpu.serving.sparse_attention`) and import neither the engine
+nor a model.
+
+The kinds, and what selects each:
+
+==================  =====================================================
+:class:`Paged`      K and V a token, pages of the shared pool under the
+                    slot's block table (the default, and the base class)
+:class:`PagedInt8`  the same in int8 with a scale a token row
+                    (``dtype=int8``)
+:class:`Ring`       K and V in a ring of pages a slot, the window's and
+                    one more (``spec.layer_windows[i]`` is a window)
+:class:`Latent`     one latent row a token that every head reads, in two
+                    pools (``spec.latent_row``)
+:class:`Selecting`  K and V plus index rows, each query attending to its
+                    ``select_topk`` best tokens once it sees more
+                    (``spec.select_topk``, ``spec.extra_rows``)
+==================  =====================================================
+
+What a kind answers is the methods of :class:`Paged`: its pool entry;
+traced, inside the engine's steps: where a call's tokens go and what the
+kernel is handed, the writes, the attention, what its steps count; on the
+host: the groups a folding decode takes, its series, and one counting call
+a decode round and one a prefill call.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from paddle_tpu.serving import decode_attention as DA
+from paddle_tpu.serving import sparse_attention as SA
+
+#: abs-max floor so an all-zero token row gets a harmless tiny scale
+#: instead of a division by zero (dequant of its zero int8 row is 0)
+KV_SCALE_FLOOR = 1e-8
+
+
+def quantize_kv(x, reduce_axes: Tuple[int, ...], psum_axis=None):
+    """Symmetric per-token int8 quantization of a K/V slab.
+
+    ``x`` carries one K (or V) vector per token over its TRAILING
+    ``reduce_axes`` (decode writes ``(S, H*Dh)`` with axes ``(1,)``;
+    prefill writes ``(S, C, H*Dh)`` with axes ``(2,)``). Returns
+    ``(q int8, scale f32)`` with ``scale = max(|x|) / 127`` per token —
+    the row the page pool stores next to the page so dequantization is
+    ``q * scale`` inside the attend kernel. Per-token granularity keeps
+    incremental page writes append-stable: a new token never forces a
+    requantization of rows already stored (a single per-page scalar
+    would), which is what lets shared/published int8 pages stay
+    bit-stable under prefix sharing and CoW.
+
+    ``psum_axis`` (tensor parallel): inside ``shard_map`` each shard
+    holds only its own ``H/tp`` heads of ``x``, so the per-token abs-max
+    is completed with a ``pmax`` over the named mesh axis BEFORE the
+    scale divides — every shard then quantizes its head slice with the
+    all-head scale the tp=1 engine computes (max is exact; deeper layers'
+    inputs carry the psum's last-ulp noise, which the rounding absorbs:
+    greedy parity is pinned at the token level)."""
+    xf = x.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(xf), axis=reduce_axes)
+    if psum_axis is not None:
+        amax = jax.lax.pmax(amax, psum_axis)
+    scale = jnp.maximum(amax, KV_SCALE_FLOOR) / 127.0
+    exp = scale.reshape(scale.shape + (1,) * len(reduce_axes))
+    q = jnp.clip(jnp.round(xf / exp), -127, 127).astype(jnp.int8)
+    return q, scale
+
+
+@dataclasses.dataclass(frozen=True)
+class Geometry:
+    """The cache's side of a kind: what :func:`build` is told."""
+    num_slots: int
+    page_size: int
+    num_pages: int
+    heads: int                  # heads of K and V a token caches
+    head_dim: int
+    dtype: object
+    tp: int = 1
+    impl: str = "auto"          # the engine's ``attn_impl``
+
+
+# -- where tokens fall under the slots' tables (traced) ----------------------
+
+def under_table(block_tables, positions, live, slot_ids, page_size, base):
+    """Where tokens at ``positions`` fall under the slots' tables: decode,
+    one a slot at ``lengths`` (S,); prefill, a chunk a lane (S, C) -> (the
+    page each is written to, 0 where it is not ``live``; its row in the
+    page; the table; ``base``, the lengths or the chunks' starts the
+    kernel is handed with it). Every kind's placement starts from this
+    one."""
+    pages = jnp.where(
+        live,
+        block_tables[slot_ids, jnp.minimum(positions // page_size,
+                                           block_tables.shape[1] - 1)],
+        0)
+    return pages, positions % page_size, block_tables, base
+
+
+def _write_lane_rows(pool, row, page_idx, off):
+    """Tokens into a ``(P, width, page_size)`` pool, whose lanes are
+    the tokens of a page: every page a call touches is read, the
+    call's tokens placed in their lanes, and the tile written back
+    whole. (A scatter along the lane axis makes the chip's compiler
+    re-lay the whole pool out and back around it.) ``row`` (S, C,
+    width) or (S, width) with one ``page_idx`` / ``off`` a token;
+    ``page_idx`` 0 marks a token that is not written. A lane's
+    tokens are consecutive positions, so they touch at most
+    ``(C - 1) // page_size + 2`` pages, in order."""
+    s = row.shape[0]
+    width, ps = pool.shape[1], pool.shape[2]
+    row = row.reshape(s, -1, width).astype(pool.dtype)      # (S,C,W)
+    page_idx, off = page_idx.reshape(s, -1), off.reshape(s, -1)
+    c = row.shape[1]
+    live = page_idx > 0
+    # which of the lane's touched pages a token lands in: it moves
+    # on where ``off`` wraps
+    nth = jnp.cumsum(jnp.concatenate(
+        [jnp.zeros((s, 1), jnp.int32),
+         (off[:, 1:] < off[:, :-1]).astype(jnp.int32)], axis=1), axis=1)
+    lanes = jnp.arange(ps, dtype=jnp.int32)
+    for k in range((c - 1) // ps + 2 if c > 1 else 1):
+        here = live & (nth == k)                            # (S,C)
+        page = jnp.max(jnp.where(here, page_idx, 0), axis=1)  # (S,)
+        put = (here[:, :, None]
+               & (off[:, :, None] == lanes)).astype(pool.dtype)  # (S,C,ps)
+        tile = jnp.einsum("scw,scp->swp", row, put)     # one-hot: exact
+        written = jnp.any(put > 0, axis=1)[:, None, :]      # (S,1,ps)
+        pool = pool.at[page].set(
+            jnp.where(written, tile, pool[page]))
+    return pool
+
+
+def _write_rows(ent, rows, place, major):
+    """Land one call's rows in a float entry, each where ``place`` says
+    (one index a token: ``(S,)`` for decode, ``(S, C)`` for a chunk): the
+    first ``major`` pools are token-major ``(P, page_size, lanes)``, the
+    rest ``(P, width, page_size)``, tokens along the lanes, written a
+    page tile at a time."""
+    pages, off = place[0], place[1]
+    out = [pool.at[pages, off].set(row.astype(pool.dtype))
+           for pool, row in zip(ent[:major], rows[:major])]
+    for pool, row in zip(ent[major:], rows[major:]):
+        out.append(_write_lane_rows(pool, row, pages, off))
+    return tuple(out)
+
+
+# -- who shares what in a decode block (host) --------------------------------
+
+def _rows_held_twice(tables, lens, page_size, num_pages):
+    """The live rows of slots with block tables ``tables`` and
+    ``lens`` tokens that a second of them holds too: a page some slot
+    holds whole counts whole, however many hold it; the page a slot
+    is filling counts as far as the longest of its holders goes."""
+    ps = page_size
+    whole = lens // ps
+    held = np.bincount(
+        tables[np.arange(tables.shape[1])[None, :] < whole[:, None]],
+        minlength=num_pages) > 0
+    filling = tables[np.arange(len(lens)),
+                     np.minimum(whole, tables.shape[1] - 1)]
+    part = (lens > whole * ps) & ~held[filling]
+    ids, rows = filling[part], (lens - whole * ps)[part]
+    order = np.argsort(ids, kind="stable")
+    distinct = ps * int(held.sum()) + (int(np.maximum.reduceat(
+        rows[order], np.flatnonzero(np.diff(ids[order], prepend=-1))
+    ).sum()) if len(ids) else 0)
+    return int(lens.sum()) - distinct
+
+
+class _Groups:
+    """Who shares what in a decode block of a kind whose decode folds the
+    pages that several slots' tables open with, from the decoding slots'
+    tables alone. Shared pages are whole and read-only and a slot grows in
+    pages of its own, so it holds for every token step of the block, and
+    from block to block while the decoding slots and their tables stay:
+    ``kept`` is (slots, their table rows, the three arrays
+    :func:`decode_attention.decode_groups` makes, on the device; the live
+    rows a token step that a second decoding slot holds too; the rows the
+    groups' walks do not copy twice)."""
+
+    def __init__(self, geo: Geometry, counts_twice: bool):
+        self.geo, self.counts_twice = geo, counts_twice
+        self.kept = None
+
+    def of(self, block_tables, lengths, dslots):
+        """-> (the arrays, rows held twice, rows spared)."""
+        ps = self.geo.page_size
+        tables = block_tables[dslots]
+        kept = self.kept
+        if kept is not None and np.array_equal(kept[0], dslots) \
+                and np.array_equal(kept[1], tables):
+            return kept[2:]
+        groups = DA.decode_groups(block_tables, lengths, dslots, ps)
+        spared = int(((groups[0] >= 0).sum(1) - 1).clip(0)
+                     @ groups[1].astype(np.int64)) * ps
+        twice = _rows_held_twice(
+            tables, lengths[dslots], ps,
+            self.geo.num_pages) if self.counts_twice else 0
+        self.kept = (np.asarray(dslots).copy(), tables,
+                     tuple(jnp.asarray(a) for a in groups), twice, spared)
+        return self.kept[2:]
+
+
+def _attended(lens, n):
+    """Tokens ``n`` decode token steps attend over, a layer: step j of a
+    slot holding L tokens attends over L + j + 1."""
+    return n * int(lens.sum()) + len(lens) * n * (n + 1) // 2
+
+
+# -- the kinds ---------------------------------------------------------------
+
+class Paged:
+    """One kind of attention layer, for ``layers`` layers of one program
+    in one cache; this one is the plain kind, and the others subclass it
+    for what they answer differently. K and V a token, each ``(num_pages,
+    page_size, heads * head_dim)`` (a token's heads folded head-major into
+    the lanes), pages of the shared pool mapped by the slot's block table;
+    then one pool ``(num_pages, width, page_size)`` for each of the
+    program's ``extra_rows`` (tokens along the lanes), allocated, shared,
+    copied on write and freed with its page. ``pools``: ``(shape, dtype,
+    the axes a tp mesh shards as a PartitionSpec's entries)`` a pool array
+    (K and V: the folded head axis). ``label``: the kind's name in the
+    series that split a pool by kind, None where the program's layers are
+    all of one kind (:class:`Latent` always names itself)."""
+
+    quantized = False       # pages carry scale rows
+    by_slot = False         # a prefill lane's placement needs its slot
+    stat_names: Tuple[str, ...] = ()    # counts its steps add on the device
+    groups = None           # a :class:`_Groups` where its decode folds
+    _c_resident = None      # bound where the program's pool is split by kind
+
+    def __init__(self, geo: Geometry, layers: int,
+                 label: Optional[str] = None, extra_rows=()):
+        self.geo, self.layers, self.label = geo, layers, label
+        #: K and V of one token in one layer
+        self.token_bytes = 2 * geo.heads * geo.head_dim \
+            * np.dtype(geo.dtype).itemsize
+        kv = ((geo.num_pages, geo.page_size, geo.heads * geo.head_dim),
+              geo.dtype, (None, None, "tp"))
+        self.pools = (kv, kv) + tuple(
+            ((geo.num_pages, width, geo.page_size), geo.dtype, ())
+            for _name, width in extra_rows)
+
+    # -- the pool entry --
+
+    @property
+    def row_bytes(self) -> int:
+        """Bytes of one page (its leading index) across the entry."""
+        return sum(int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
+                   for shape, dtype, _ in self.pools)
+
+    @property
+    def page_bytes(self) -> int:
+        """Bytes one page id of the shared pool commits in a layer."""
+        return self.row_bytes
+
+    slot_bytes = 0          # a layer's bytes a slot holds at any length
+
+    def check(self, ent, lengths):
+        """The kind's part of the cache's self-check (tests): ``ent``,
+        the layer's pool arrays, are what the kind lays out."""
+        assert [(a.shape, a.dtype) for a in ent] == [
+            (shape, jnp.dtype(dtype)) for shape, dtype, _ in self.pools], \
+            f"a layer's entry is not what {type(self).__name__} lays out"
+
+    # -- traced --
+
+    def place_decode(self, under, writable, slot_ids):
+        """Where one decode token a slot goes and what the kernel is
+        handed, from :func:`under_table`'s ``under``."""
+        return under
+
+    def place_prefill(self, under, positions, valid, lane_rows):
+        """The same for a chunk a lane;
+        ``lane_rows``: the lanes' slots + 1 where a kind is ``by_slot``."""
+        return under
+
+    def write(self, ent, rows, place):
+        """Land the rows ``attn_in`` wants cached in the entry ``ent``
+        where ``place`` says; returns the entry."""
+        return _write_rows(ent, rows, place, 2)
+
+    def attend_decode(self, q, ent, place, index, groups):
+        """One decode token a slot, ``q`` (S, H, Dh), over ``ent`` as just
+        written -> (heads, tokens attended a slot (S,))."""
+        lengths = place[3] + 1
+        return DA.ragged_paged_decode_attention(
+            q, ent[0], ent[1], place[2], lengths,
+            impl=self.geo.impl), lengths
+
+    def attend_prefill(self, q, ent, place, n_valid, index):
+        """A chunk of queries a lane, ``q`` (S, C, H, Dh), causally over
+        ``ent`` as just written -> heads."""
+        return DA.ragged_paged_prefill_attention(
+            q, ent[0], ent[1], place[2], place[3], n_valid,
+            impl=self.geo.impl)
+
+    def attends_prefill(self, seen, block_tables):
+        """Tokens each query of a chunk attends to, of the ``seen`` (S, C)
+        it can see, in a bucket as wide as ``block_tables``."""
+        return seen
+
+    def step_counts(self, context, selected):
+        """The values of ``stat_names`` for one layer-call."""
+        return ()
+
+    def copy_page(self, ent, src, dst):
+        """Page ``src`` of the entry duplicated into ``dst`` (the
+        copy-on-write of a borrowed tail page)."""
+        return tuple(a.at[dst].set(a[src]) for a in ent)
+
+    # -- host --
+
+    def decode_groups(self, block_tables, lengths, dslots) -> tuple:
+        """``(groups,)`` for the decode step where the kind's decode
+        folds the pages that several tables open with; ``()``: it walks
+        every slot alone and its step takes none."""
+        if self.groups is None:
+            return ()
+        return (self.groups.of(block_tables, lengths, dslots)[0],)
+
+    def bind(self, reg):
+        """Bind the kind's series in ``reg``, once."""
+        if self.label is not None:
+            self._bind_by_kind(reg)
+
+    def _bind_by_kind(self, reg):
+        self._c_resident = reg.counter(
+            "serving_kv_resident_bytes_total",
+            "K/V bytes the slots of a decode round or prefill call hold "
+            "when it is dispatched, whole pages, by layer kind: a full "
+            "layer every page of the slot's tokens, a window layer its "
+            "ring's pages at most").child(layers=self.label)
+        self._set_pool_bytes(reg)
+
+    def _set_pool_bytes(self, reg):
+        reg.gauge("serving_kv_pool_bytes", "bytes of the K/V pools by layer "
+                  "kind, null pages included").set(self.layers * sum(
+                      int(np.prod(shape)) * np.dtype(dtype).itemsize
+                      for shape, dtype, _ in self.pools), layers=self.label)
+
+    def _count_resident(self, before):
+        """What the slots hold going in (``before`` tokens each), where
+        the program's pool is split by kind."""
+        if self._c_resident is not None:
+            pages = -(-np.asarray(before, np.int64) // self.geo.page_size)
+            self._c_resident.inc(
+                int(self._held(pages).sum()) * self.geo.page_size
+                * self.token_bytes * self.layers)
+
+    def _held(self, pages):
+        return pages
+
+    def _kv_bytes(self, live: int, width: int):
+        each = self.token_bytes * self.layers
+        return live * each, width * each
+
+    def count_decode(self, span, block_tables, lengths, dslots, keeps,
+                     n: int, width: int):
+        """One decode round of ``n`` token steps at gather width ``width``
+        over ``dslots``, from the tables and lengths BEFORE it (``keeps``:
+        the tokens each slot keeps). Feeds the kind's series and the
+        span's attribute; returns its share of
+        ``serving_decode_kv_bytes_total`` (live, a row of its table)."""
+        lens = lengths[dslots]
+        live = _attended(lens, n)
+        self._count_own(span, block_tables, lengths, dslots, live, n, width)
+        self._count_resident(lens)
+        return self._kv_bytes(live, width)
+
+    def _count_own(self, span, block_tables, lengths, dslots, live, n,
+                   width):
+        """What the kind alone counts of a decode round attending over
+        ``live`` tokens a layer."""
+
+    def count_prefill(self, span, starts, ns):
+        """One prefill call: lanes at ``starts`` computing ``ns`` tokens."""
+        self._count_resident(starts)
+
+
+class PagedInt8(Paged):
+    """K and V in int8 with one symmetric abs-max scale a token row
+    (:func:`quantize_kv`): ``(k, v, k_scales, v_scales)``, the scales fp32
+    ``(num_pages, page_size)``, page-major so that scales travel WITH
+    their pages wherever pages go. Dequantization happens inside the
+    dequant-attend kernels. Under tp K and V are sharded as
+    :class:`Paged`'s, the scales replicated (a token's scale is over ALL
+    heads: the abs-max is completed over the shards)."""
+
+    quantized = True
+
+    def __init__(self, geo, layers):
+        super().__init__(geo, layers)
+        kv = ((geo.num_pages, geo.page_size, geo.heads * geo.head_dim),
+              jnp.int8, (None, None, "tp"))
+        sc = ((geo.num_pages, geo.page_size), jnp.float32, ())
+        self.pools = (kv, kv, sc, sc)
+        self.psum_axis = "tp" if geo.tp > 1 else None
+
+    def write(self, ent, rows, place):
+        kp, vp, ksc, vsc = ent
+        k_tok, v_tok = rows
+        pages, off = place[0], place[1]
+        ax = (k_tok.ndim - 1,)
+        kq, k_s = quantize_kv(k_tok, ax, psum_axis=self.psum_axis)
+        vq, v_s = quantize_kv(v_tok, ax, psum_axis=self.psum_axis)
+        return (kp.at[pages, off].set(kq), vp.at[pages, off].set(vq),
+                ksc.at[pages, off].set(k_s), vsc.at[pages, off].set(v_s))
+
+    def attend_decode(self, q, ent, place, index, groups):
+        lengths = place[3] + 1
+        return DA.ragged_paged_decode_int8_attention(
+            q, *ent, place[2], lengths, impl=self.geo.impl), lengths
+
+    def attend_prefill(self, q, ent, place, n_valid, index):
+        return DA.ragged_paged_prefill_int8_attention(
+            q, *ent, place[2], place[3], n_valid, impl=self.geo.impl)
+
+
+class Ring(Paged):
+    """K and V of a layer whose queries attend to the last ``window``
+    tokens (themselves counted): a **ring** of ``ring_pages`` =
+    ``pages_for(window) + 1`` pages a slot, in a pool of its own,
+    ``(num_slots * ring + 1, page_size, lanes)`` (page 0 the null page).
+    Token ``t`` of slot ``s`` lives in ring page ``1 + s * ring + (t //
+    page_size) % ring``, row ``t % page_size``, so the page a slot writes
+    next is the one whose tokens have all fallen behind the window
+    (**recycled**), whatever the slot's length. No table, no allocation,
+    no free: the ring is the slot's, and a call that writes at most a page
+    of tokens a slot before it attends never writes over a token a query
+    of the same call still reads (:func:`build` holds ``prefill_chunk`` to
+    that). Admission reckons without it (``page_bytes`` 0). Never shared,
+    copied on write, published, spilled or shipped."""
+
+    by_slot = True
+
+    def __init__(self, geo, layers, window: int):
+        super().__init__(geo, layers, "window")
+        self.window = window
+        #: the window's own pages and one more, the page being written
+        self.ring_pages = -(-window // geo.page_size) + 1
+        kv = ((geo.num_slots * self.ring_pages + 1, geo.page_size,
+               geo.heads * geo.head_dim), geo.dtype, ())
+        self.pools = (kv, kv)
+
+    page_bytes = 0
+
+    @property
+    def slot_bytes(self) -> int:
+        return self.ring_pages * self.row_bytes
+
+    def page_of(self, slot: int, seq_page: int) -> int:
+        """The ring page that holds page ``seq_page`` of ``slot``'s
+        sequence (while it is held)."""
+        return 1 + slot * self.ring_pages + seq_page % self.ring_pages
+
+    def recycled(self, before, after) -> int:
+        """Ring pages written over, all the kind's layers, as slots
+        advance from ``before`` to ``after`` tokens (arrays, one entry a
+        slot): a page is recycled when the slot enters a page of its
+        sequence past the ring's first lap."""
+        before, after = (-(-np.asarray(a, np.int64) // self.geo.page_size)
+                         for a in (before, after))
+        return self.layers * int(
+            (np.maximum(after - self.ring_pages, 0)
+             - np.maximum(before - self.ring_pages, 0)).sum())
+
+    def check(self, ent, lengths):
+        super().check(ent, lengths)
+        ps, ring = self.geo.page_size, self.ring_pages
+        for slot, length in enumerate(int(n) for n in lengths):
+            # the pages that hold the slot's window are distinct pages of
+            # the slot's own ring
+            mine = [self.page_of(slot, p) for p in range(
+                max(length - self.window, 0) // ps,
+                max(length - 1, 0) // ps + 1)]
+            assert len(set(mine)) == len(mine) and all(
+                slot * ring < p <= (slot + 1) * ring for p in mine), \
+                "a window's pages collide or leave the slot's ring"
+
+    def _pages(self, slots, first_page, width):
+        """-> (pages (S,) or (S, C) of the tokens of sequence page
+        ``first_page`` (same shape), the table (S, ``width``) of the pages
+        ``first_page[s] ..`` as the paged kernels take one)."""
+        ring = self.ring_pages
+        base = 1 + slots * ring
+
+        def pages(seq_page):
+            return base.reshape(base.shape + (1,) * (seq_page.ndim - 1)) \
+                + seq_page % ring
+        return pages, pages(first_page[:, None]
+                            + jnp.arange(width, dtype=jnp.int32))
+
+    def place_decode(self, under, writable, slot_ids):
+        """-> (the ring page the token is written to, its row, the table
+        of the pages its window spans, the tokens in them up to and with
+        this one, the slots' lengths)."""
+        _, off, _, lengths = under
+        ps = self.geo.page_size
+        first = jnp.maximum(lengths + 1 - self.window, 0) // ps
+        pages, table = self._pages(slot_ids, first, self.ring_pages)
+        return (jnp.where(writable, pages(lengths // ps), 0), off, table,
+                lengths + 1 - first * ps, lengths)
+
+    def place_prefill(self, under, positions, valid, lane_rows):
+        """-> (the ring pages the chunk's tokens are written to (S, C),
+        their rows, the table of the pages the chunk's windows span, the
+        chunk's start in them). A chunk of at most a page of tokens spans
+        the ring and, where it starts inside a page, that page's next
+        lap: one column more than the ring, the stale rows of either lap
+        outside every query's window or past it. A lane's ring is its
+        slot's: pool row less one (a pad lane writes nothing and attends
+        to nothing)."""
+        _, off, _, starts = under
+        slots = jnp.maximum(lane_rows - 1, 0)
+        ps = self.geo.page_size
+        first = jnp.maximum(starts - self.window + 1, 0) // ps
+        pages, table = self._pages(slots, first, self.ring_pages + 1)
+        return (jnp.where(valid, pages(positions // ps), 0), off, table,
+                starts - first * ps)
+
+    def attend_decode(self, q, ent, place, index, groups):
+        att = DA.ragged_paged_decode_attention(
+            q, ent[0], ent[1], place[2], place[3], impl=self.geo.impl,
+            window=self.window)
+        return att, jnp.minimum(place[4] + 1, self.window)
+
+    def attend_prefill(self, q, ent, place, n_valid, index):
+        return DA.ragged_paged_prefill_attention(
+            q, ent[0], ent[1], place[2], place[3], n_valid,
+            impl=self.geo.impl, window=self.window)
+
+    def copy_page(self, ent, src, dst):
+        return ent              # no page of a ring is ever shared
+
+    def bind(self, reg):
+        self._bind_by_kind(reg)
+        self._c_recycled = reg.counter(
+            "serving_window_pages_recycled_total",
+            "ring pages of window layers written over as slots advanced "
+            "past them (pages x window layers)").child()
+
+    def _held(self, pages):
+        return np.minimum(pages, self.ring_pages)
+
+    def _count(self, span, before, after):
+        self._count_resident(before)
+        recycled = self.recycled(before, after)
+        self._c_recycled.inc(recycled)
+        if span is not None:
+            span.set_attrs(window_pages=span.attrs.get("window_pages", 0)
+                           + recycled)
+
+    def count_decode(self, span, block_tables, lengths, dslots, keeps,
+                     n, width):
+        # a window layer's read is its window's: token step j of a slot
+        # holding L tokens attends over min(L + j + 1, window), from a
+        # table as wide as its ring
+        lens = lengths[dslots]
+        self._count(span, lens, lens + keeps)
+        return self._kv_bytes(
+            int(sum(np.minimum(lens + j + 1, self.window).sum()
+                    for j in range(n))), self.ring_pages)
+
+    def count_prefill(self, span, starts, ns):
+        self._count(span, starts, starts + ns)
+
+
+class Latent(Paged):
+    """One row a token that every head reads (multi-head latent attention
+    in its absorbed form), ``(latent_dim, rope_dim)`` wide: the entry is
+    ``(c_pages (num_pages, page_size, latent_dim), r_pages (num_pages,
+    rope_dim, page_size))``, the latent, token-major as K is, and the
+    shared rotary key with the tokens along the lanes. There is no V pool
+    (attention sums the latents themselves): whole tiles of both arrays
+    and nothing padded. Both are page pools like any other: allocated,
+    refcounted, published, copied on write and freed under one page id.
+    Its decode reads the pages that a group of slots' tables open with
+    once for the group. Not quantized, sharded, spilled or shipped yet."""
+
+    def __init__(self, geo, layers, latent_row):
+        super().__init__(geo, layers, "latent")
+        latent, rope = latent_row
+        self.pools = (
+            ((geo.num_pages, geo.page_size, latent), geo.dtype, ()),
+            ((geo.num_pages, rope, geo.page_size), geo.dtype, ()))
+        # a latent row is cached once: its values are a part of it
+        self.token_bytes //= 2
+        self.groups = _Groups(geo, counts_twice=True)
+
+    def write(self, ent, rows, place):
+        return _write_rows(ent, rows, place, 1)
+
+    def attend_decode(self, q, ent, place, index, groups):
+        lengths = place[3] + 1
+        return DA.latent_paged_decode_attention(
+            q, ent[0], ent[1], place[2], lengths, groups,
+            impl=self.geo.impl), lengths
+
+    def attend_prefill(self, q, ent, place, n_valid, index):
+        return DA.latent_paged_prefill_attention(
+            q, ent[0], ent[1], place[2], place[3], n_valid,
+            impl=self.geo.impl)
+
+    def bind(self, reg):
+        rows = reg.counter(
+            "serving_latent_rows_read_total",
+            "cached latent rows x layers the latent kernels HAD to read, "
+            "the least any kernel could: a decode token step each "
+            "DISTINCT live row of the decoding slots once (a page that "
+            "several slots' tables hold counts once), a prefill call "
+            "each lane's context and chunk")
+        fetched = reg.counter(
+            "serving_latent_rows_fetched_total",
+            "cached latent rows x layers the latent kernels' walks "
+            "copied: a decode token step a group's shared rows once a "
+            "group and every slot's own rows, a prefill call what it "
+            "has to read")
+        pairs = reg.counter(
+            "serving_latent_pairs_total",
+            "(query token, cached row) pairs x layers the latent kernels "
+            "scored, every head each: a decode token step one query a "
+            "slot (equal to the rows), a prefill call each chunk token "
+            "against the rows up to its own")
+        self._c = {ph: tuple(c.child(phase=ph)
+                             for c in (rows, pairs, fetched))
+                   for ph in ("decode", "prefill")}
+        self._set_pool_bytes(reg)
+
+    def _count(self, span, phase, rows: int, pairs: int, fetched: int):
+        """One round's or call's latent rows, pairs and rows fetched
+        (each of ONE layer), and the span's ``latent_rows``."""
+        for child, n in zip(self._c[phase], (rows, pairs, fetched)):
+            child.inc(n * self.layers)
+        if span is not None:
+            span.set_attrs(latent_rows=rows * self.layers)
+
+    def _count_own(self, span, block_tables, lengths, dslots, live, n,
+                   width):
+        _, twice, spared = self.groups.of(block_tables, lengths, dslots)
+        self._count(span, "decode", live - n * twice, live,
+                    live - n * spared)
+
+    def count_prefill(self, span, starts, ns):
+        # chunk token j of a lane sees its context and the chunk's tokens
+        # up to itself
+        rows = int(starts.sum() + ns.sum())
+        self._count(span, "prefill", rows,
+                    int((starts * ns + ns * (ns + 1) // 2).sum()), rows)
+
+
+class Selecting(Paged):
+    """K and V as :class:`Paged`'s plus the index rows the program caches
+    beside them (``extra_rows``), each query attending to the ``topk``
+    cached tokens its index scores best. Whether a call selects is a
+    static fact of its bucket: up to ``topk`` cached tokens every query
+    attends to all it sees, so a table no wider than that takes the dense
+    kernels. A decode that selects walks whole pages under the selection
+    and reads the pages that a group of slots' tables open with once for
+    the group."""
+
+    stat_names = ("attn_context_tokens", "attn_selected_tokens")
+
+    def __init__(self, geo, layers, label, extra_rows, topk: int):
+        super().__init__(geo, layers, label, extra_rows)
+        self.topk = topk
+        self.groups = _Groups(geo, counts_twice=False)
+
+    def _selects(self, width: int) -> bool:
+        return width * self.geo.page_size > self.topk
+
+    def attend_decode(self, q, ent, place, index, groups):
+        if self._selects(place[2].shape[1]):
+            return SA.indexed_decode_attention(
+                q, *ent, place[2], place[3] + 1, index[0][:, 0],
+                index[1][:, 0], self.topk, groups=groups,
+                impl=self.geo.impl)
+        return super().attend_decode(q, ent, place, index, groups)
+
+    def attend_prefill(self, q, ent, place, n_valid, index):
+        if self._selects(place[2].shape[1]):
+            return SA.indexed_prefill_attention(
+                q, *ent, place[2], place[3], n_valid, index[0], index[1],
+                self.topk, impl=self.geo.impl)
+        return super().attend_prefill(q, ent, place, n_valid, index)
+
+    def attends_prefill(self, seen, block_tables):
+        return jnp.minimum(seen, self.topk) \
+            if self._selects(block_tables.shape[1]) else seen
+
+    def step_counts(self, context, selected):
+        return context, selected
+
+    def bind(self, reg):
+        super().bind(reg)
+        self._c_fetched = reg.counter(
+            "serving_sparse_rows_fetched_total",
+            "cached K/V rows x layers the sparse decode's walks copied, a "
+            "token step of a bucket that selects: a group's shared rows "
+            "once a group, every slot's own rows once").child()
+        self._c_held = reg.counter(
+            "serving_sparse_rows_held_total",
+            "live cached K/V rows x layers of the decoding slots, a slot "
+            "at a time, a token step of a bucket that selects: what the "
+            "walks copy where every slot is walked alone").child()
+
+    def _count_own(self, span, block_tables, lengths, dslots, live, n,
+                   width):
+        if self._selects(width):
+            _, _, spared = self.groups.of(block_tables, lengths, dslots)
+            self._c_fetched.inc((live - n * spared) * self.layers)
+            self._c_held.inc(live * self.layers)
+
+
+# -- the one decision --------------------------------------------------------
+
+def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
+          share_prefix: bool, tp: int = 1, impl: str = "auto",
+          prefill_chunk: Optional[int] = None) -> Tuple[Paged, ...]:
+    """One kind a layer of the program ``spec`` describes, in a cache of
+    this geometry (layers alike share one object). The only
+    reader of ``spec.extra_rows``, ``spec.select_topk``,
+    ``spec.layer_windows``, ``spec.latent_row`` and the cache's dtype, and
+    the one place where what does not combine yet is refused.
+    ``prefill_chunk``: the engine's, where an engine asks."""
+    geo = Geometry(num_slots=num_slots, page_size=page_size,
+                   num_pages=num_pages, heads=spec.kv_heads,
+                   head_dim=spec.head_dim, dtype=dtype, tp=tp, impl=impl)
+    int8 = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
+    windows = spec.layer_windows or (None,) * spec.num_layers
+    ringed = [w for w in windows if w is not None]
+    if spec.select_topk is not None and spec.select_topk % page_size:
+        raise ValueError(
+            f"select_topk={spec.select_topk} must be a multiple of "
+            f"page_size={page_size}: the selected tokens are folded "
+            "as whole pages")
+    if spec.latent_row is not None and int8:
+        raise ValueError(
+            "a pool of latent rows is not quantized and carries no "
+            "extra rows, slot state or window layers yet")
+    if ringed:
+        if int8 or share_prefix:
+            raise ValueError(
+                "a pool with window layers is not quantized and shares "
+                "no prefixes yet: a borrower would need the window "
+                "layers' last tokens of the prefix")
+        if prefill_chunk is not None and prefill_chunk > page_size:
+            raise ValueError(
+                f"prefill_chunk={prefill_chunk} > page_size="
+                f"{page_size}: a window layer's ring holds its window and "
+                "one page more, so a call writes at most a page of tokens "
+                "a slot before it attends")
+    if spec.extra_rows and int8:
+        raise ValueError("an int8 pool carries no extra rows yet")
+    if spec.slot_state and int8:
+        raise ValueError("an int8 pool carries no slot state yet")
+    if spec.slot_state and share_prefix:
+        raise ValueError(
+            "a pool with slot state cannot share prefixes: a prefix "
+            "hit would skip the tokens that built the state")
+    if tp > 1:
+        if spec.kv_heads % tp:
+            raise ValueError(
+                f"tp={tp} must divide num_heads={spec.kv_heads}")
+        if spec.extra_rows or spec.slot_state or ringed or spec.latent_row:
+            raise ValueError("a tp-sharded pool carries no extra "
+                             "rows, no slot state, no window layers "
+                             "and no latent rows yet")
+    full_layers = spec.num_layers - len(ringed)
+    label = "full" if ringed else None
+    if spec.latent_row is not None:
+        full = Latent(geo, full_layers, spec.latent_row)
+    elif int8:
+        full = PagedInt8(geo, full_layers)
+    elif spec.select_topk is not None:
+        full = Selecting(geo, full_layers, label, spec.extra_rows,
+                         spec.select_topk)
+    else:
+        full = Paged(geo, full_layers, label, spec.extra_rows)
+    rings = {w: Ring(geo, ringed.count(w), w) for w in dict.fromkeys(ringed)}
+    return tuple(full if w is None else rings[w] for w in windows)

@@ -44,20 +44,11 @@ by every follower) and skips prefilling them. Rules that keep it exact:
   LRU **cached** pool: reusable by future matches, evicted (and
   unpublished) only when the allocator runs dry.
 
-Int8 pages (ISSUE 13): ``dtype=jnp.int8`` stores K/V pages quantized —
-HBM per live token roughly halves, so the same pool hosts ~2x the
-slots. Each layer entry becomes ``(k_pages, v_pages, k_scales,
-v_scales)`` with the scales fp32 ``(num_pages, page_size)`` — one
-symmetric abs-max scale per *token row* of each page
-(:func:`quantize_kv`), stored page-major so scales always travel WITH
-their pages: publication, copy-on-write, the LRU cached pool, and the
-fleet migration shards all move page and scale rows together under one
-page id (a finer grain than one scalar per page, same page-granular
-management — incremental token writes then never requantize already-
-stored rows, so stored content is append-stable and prefix sharing
-stays exact). Dequantization happens INSIDE the dequant-attend kernels
-(:mod:`~paddle_tpu.serving.decode_attention`), fused into the QK and
-PV products — no fp page is ever materialized.
+What a layer's pool entry holds is its **kind**'s business
+(:mod:`~paddle_tpu.serving.layer_kinds`): ``config.kinds`` names one a
+layer, and this manager asks it for the arrays' shapes, what a page id or
+a slot commits in bytes, and its part of the self-check. The allocator
+below knows pages, slots and refcounts, and no kind by name.
 
 Per-slot state (ISSUE 32): a program whose layers carry a recurrence
 declares ``slot_state`` and the one manager then holds a second kind of
@@ -73,55 +64,14 @@ holds whatever its last request left: the step that starts a prompt
 starts from zeros (``fresh`` in :mod:`~paddle_tpu.serving.program`), which
 is the reset at admission with no program of its own.
 
-Window layers (ISSUE 40): a program whose layers are not all of one kind
-declares ``layer_windows`` (None, or the tokens a query of that layer
-attends to, itself counted) and the one manager then holds a third kind of
-cache beside pages and slot state. A FULL layer's K and V stay as above:
-pages of the shared pool, mapped by the block table, reserved at
-admission. A WINDOW layer's are a **ring** of ``ring_pages(window)`` =
-``pages_for(window) + 1`` pages a slot, in a pool of its own,
-``(num_slots * ring + 1, page_size, lanes)`` (page 0 the null page): token
-``t`` of slot ``s`` lives in ring page ``1 + s * ring + (t // page_size) %
-ring``, row ``t % page_size``, so the page a slot writes next is the one
-whose tokens have all fallen behind the window (**recycled**, counted by
-``recycled_pages``), whatever the slot's length. No table, no allocation,
-no free: the ring is the slot's, as a slot-state row is; a call that
-writes at most ``page_size`` tokens a slot before it attends (the engine
-holds ``prefill_chunk`` to that) never writes over a token a query of the
-same call still reads. A window layer so holds at most ``window +
-page_size`` tokens of a slot where the page divides the window (``ring *
-page_size`` in general), and admission reckons with the full layers
-alone: ``can_reserve`` / ``reserve`` stay all-or-nothing over both kinds
-because the window kind can never refuse. Never shared, copied on write,
-published, spilled or shipped: a pool with window layers has prefix
-sharing off (a borrower would need the window layers' last tokens of the
-prefix). ``bytes_per_page`` is a page row of the full layers;
-``capacity_bytes`` / ``live_bytes`` count both kinds.
-
-Latent rows (ISSUE 42): a program whose heads all read one cached row a
-token declares ``latent_row = (latent_dim, rope_dim)`` and a layer's entry
-is then ``(c_pages (num_pages, page_size, latent_dim), r_pages (num_pages,
-rope_dim, page_size))``: the latent, token-major as K is, and the shared
-rotary key with the tokens along the lanes, as ``extra_rows`` are kept.
-There is no V pool (attention sums the latents themselves), so a page row
-is ``page_size * (latent_dim + rope_dim) * itemsize`` bytes a layer, whole
-tiles of both arrays and nothing padded. Both are page pools like any
-other: allocated, refcounted, published, copied on write and freed under
-one page id, and ``bytes_per_page`` / ``capacity_bytes`` / ``live_bytes``
-count them as they count K and V. Not quantized, sharded, spilled or
-shipped yet.
-
 Tensor parallel (ISSUE 15): pass ``mesh=`` (a mesh with a ``tp`` axis
-of size > 1) and the page pool becomes **per-shard**: the K/V page
-arrays are placed sharded over ``tp`` on the folded HEAD axis (each mesh
-shard holds every page's slice of its own ``H/tp`` whole heads), while
-the block
-tables, lengths, allocator books, and — for int8 pools — the per-token
-scale rows stay replicated (a token's quantization scale is computed
-over ALL heads, so it is shard-independent; see
-:func:`quantize_kv`'s ``psum_axis``). The host-side allocator and the
-prefix-sharing index are untouched: page identity is global, only the
-page *contents* are sharded.
+of size > 1) and the page pool becomes **per-shard**: each pool array is
+placed as its kind says (K and V sharded over ``tp`` on the folded HEAD
+axis, each shard holding every page's slice of its own ``H/tp`` whole
+heads; int8 scale rows replicated), while the block tables, lengths and
+allocator books stay replicated. The host-side allocator and the
+prefix-sharing index are untouched: page identity is global, only the page
+*contents* are sharded.
 """
 
 from __future__ import annotations
@@ -137,6 +87,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.analysis.concurrency import guarded_by
+from paddle_tpu.serving import layer_kinds
+from paddle_tpu.serving.layer_kinds import quantize_kv  # noqa: F401 (public)
+from paddle_tpu.serving.program import ServingSpec
 
 
 @dataclasses.dataclass
@@ -150,135 +103,45 @@ class PagedCacheConfig:
     max_pages_per_slot: int = 16
     dtype: object = jnp.float32
     share_prefix: bool = True
-    #: further rows cached per token and layer beside K and V, ``(name,
-    #: width)`` each: one more pool array a layer, ``(num_pages, width,
-    #: page_size)`` (tokens along the lanes), allocated, shared, copied
-    #: on write and freed with its page
-    extra_rows: Tuple[Tuple[str, int], ...] = ()
     #: state kept per SLOT and layer, ``(name, shape)`` each: one more
     #: array a layer, ``(num_slots + 1,) + shape`` of ``slot_state_dtype``
     #: (row 0 the null row), beside the page pools and not paged
     slot_state: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
     slot_state_dtype: object = jnp.float32
-    #: a layer's kind of attention, one entry a layer: None (pages of the
-    #: shared pool, every token) or a window (a ring of pages a slot);
-    #: empty: every layer is full
-    layer_windows: Tuple[Optional[int], ...] = ()
-    #: ``(latent_dim, rope_dim)``: a layer's entry is one row a token, the
-    #: latent ``(num_pages, page_size, latent_dim)`` and the shared rotary
-    #: key ``(num_pages, rope_dim, page_size)``, and no V pool; None: K, V
-    latent_row: Optional[Tuple[int, int]] = None
+    #: each layer's kind (:func:`layer_kinds.build`), which lays out its
+    #: pool entry; empty: K and V of ``num_heads`` x ``head_dim`` in
+    #: ``dtype`` under the block table, every layer
+    kinds: Tuple[layer_kinds.Paged, ...] = ()
 
     def __post_init__(self):
-        if self.latent_row is not None and (
-                self.quantized or self.extra_rows or self.slot_state
-                or self.layer_windows):
-            raise ValueError(
-                "a pool of latent rows is not quantized and carries no "
-                "extra rows, slot state or window layers yet")
-        if self.layer_windows:
-            if len(self.layer_windows) != self.num_layers:
-                raise ValueError("layer_windows names every layer or none")
-            if self.quantized or self.share_prefix:
-                raise ValueError(
-                    "a pool with window layers is not quantized and shares "
-                    "no prefixes yet: a borrower would need the window "
-                    "layers' last tokens of the prefix")
         if self.page_size < 1 or self.num_pages < 2:
             raise ValueError("need page_size >= 1 and num_pages >= 2 "
                              "(page 0 is the reserved null page)")
         if self.max_pages_per_slot < 1:
             raise ValueError("max_pages_per_slot must be >= 1")
-        if self.extra_rows and self.quantized:
-            raise ValueError("an int8 pool carries no extra rows yet")
-        if self.slot_state and self.quantized:
-            raise ValueError("an int8 pool carries no slot state yet")
-        if self.slot_state and self.share_prefix:
-            raise ValueError(
-                "a pool with slot state cannot share prefixes: a prefix "
-                "hit would skip the tokens that built the state")
+        if not self.kinds:
+            self.kinds = layer_kinds.build(
+                ServingSpec(num_layers=self.num_layers,
+                            num_heads=self.num_heads,
+                            kv_heads=self.num_heads, head_dim=self.head_dim,
+                            vocab_size=0, max_position=0,
+                            slot_state=self.slot_state),
+                num_slots=self.num_slots, page_size=self.page_size,
+                num_pages=self.num_pages, dtype=self.dtype,
+                share_prefix=self.share_prefix)
+        if len(self.kinds) != self.num_layers:
+            raise ValueError("kinds name every layer or none")
 
     @property
     def max_tokens_per_slot(self) -> int:
         return self.max_pages_per_slot * self.page_size
 
-    @property
-    def paged_entries(self) -> int:
-        """Arrays of a layer's entry that are page pools; the slot-state
-        arrays follow them."""
-        return 4 if self.quantized else 2 + len(self.extra_rows)
-
-    @property
-    def quantized(self) -> bool:
-        """Int8 page storage with per-token-row fp32 scales."""
-        return jnp.dtype(self.dtype) == jnp.dtype(jnp.int8)
-
     def pages_for(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
-
-    def window_of(self, layer: int) -> Optional[int]:
-        return self.layer_windows[layer] if self.layer_windows else None
-
-    @property
-    def window_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i, w in enumerate(self.layer_windows)
-                     if w is not None)
-
-    @property
-    def window_layer_counts(self) -> Dict[int, int]:
-        """``{window: layers of that window}`` over the window layers."""
-        counts: Dict[int, int] = {}
-        for i in self.window_layers:
-            w = self.layer_windows[i]
-            counts[w] = counts.get(w, 0) + 1
-        return counts
-
-    def ring_pages(self, window: int) -> int:
-        """Pages of a window layer's ring a slot: the window's own and
-        one more, the page being written."""
-        return self.pages_for(window) + 1
 
 
 class PageOverflowError(RuntimeError):
     """No free pages (or slot capacity exceeded) for a reservation."""
-
-
-#: abs-max floor so an all-zero token row gets a harmless tiny scale
-#: instead of a division by zero (dequant of its zero int8 row is 0)
-KV_SCALE_FLOOR = 1e-8
-
-
-def quantize_kv(x, reduce_axes: Tuple[int, ...], psum_axis=None):
-    """Symmetric per-token int8 quantization of a K/V slab.
-
-    ``x`` carries one K (or V) vector per token over its TRAILING
-    ``reduce_axes`` (decode writes ``(S, H*Dh)`` with axes ``(1,)``;
-    prefill writes ``(S, C, H*Dh)`` with axes ``(2,)``). Returns
-    ``(q int8, scale f32)`` with ``scale = max(|x|) / 127`` per token —
-    the row the page pool stores next to the page so dequantization is
-    ``q * scale`` inside the attend kernel. Per-token granularity keeps
-    incremental page writes append-stable: a new token never forces a
-    requantization of rows already stored (a single per-page scalar
-    would), which is what lets shared/published int8 pages stay
-    bit-stable under prefix sharing and CoW.
-
-    ``psum_axis`` (tensor parallel): inside ``shard_map`` each shard
-    holds only its own ``H/tp`` heads of ``x``, so the per-token abs-max
-    is completed with a ``pmax`` over the named mesh axis BEFORE the
-    scale divides — every shard then quantizes its head slice with the
-    all-head scale the tp=1 engine computes (max is exact, so for
-    bit-identical inputs the quantization is bit-identical; in the
-    sharded engine deeper layers' inputs carry the psum's last-ulp
-    accumulation noise, which the rounding absorbs — greedy parity is
-    pinned at the token level)."""
-    xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=reduce_axes)
-    if psum_axis is not None:
-        amax = jax.lax.pmax(amax, psum_axis)
-    scale = jnp.maximum(amax, KV_SCALE_FLOOR) / 127.0
-    exp = scale.reshape(scale.shape + (1,) * len(reduce_axes))
-    q = jnp.clip(jnp.round(xf / exp), -127, 127).astype(jnp.int8)
-    return q, scale
 
 
 _ROOT_KEY = hash("paddle_tpu.serving.prefix_root")
@@ -447,21 +310,21 @@ class PagedKVCache:
     """Device pages + host-side page allocator, block tables, and the
     refcounted prefix-sharing index.
 
-    Each layer's K and V pool is ``(num_pages, page_size, num_heads *
-    head_dim)``: a token's heads folded head-major into the last axis
-    (head ``h`` is lanes ``h*Dh .. (h+1)*Dh``), the shape the paged
-    kernels stream page blocks of. With ``(page_size, H*Dh)`` as the
-    minor dims the TPU keeps the arrays row-major and unpadded as long
-    as ``H*Dh`` (per shard under tp) is a multiple of 128 lanes; with
-    ``(H, Dh)`` minor, or a lane width it has to pad, it keeps a pool
-    page_size-minor and every step that calls the kernels copies it
-    whole, in and out.
+    ``pages[layer]`` is the arrays the layer's kind lays out
+    (``config.kinds[layer].pools``), then one array ``(num_slots + 1,) +
+    shape`` for each entry of ``config.slot_state``. A pool of K and V is
+    ``(num_pages, page_size, num_heads * head_dim)``: a token's heads
+    folded head-major into the last axis (head ``h`` is lanes ``h*Dh ..
+    (h+1)*Dh``), the shape the paged kernels stream page blocks of. With
+    ``(page_size, H*Dh)`` as the minor dims the TPU keeps the arrays
+    row-major and unpadded as long as ``H*Dh`` (per shard under tp) is a
+    multiple of 128 lanes; with ``(H, Dh)`` minor, or a lane width it has
+    to pad, it keeps a pool page_size-minor and every step that calls the
+    kernels copies it whole, in and out.
 
-    ``mesh=`` (tp > 1): the K/V page arrays are placed sharded over the
-    mesh's ``tp`` axis on the folded head axis — per-shard page pools of
-    ``(H/tp)*Dh`` lanes, each shard's own whole heads — while int8 scale
-    rows stay replicated (per-token scales are head-global).
-    Allocator/index state is host-side and unaffected."""
+    ``mesh=`` (tp > 1): each pool array is placed over the mesh's ``tp``
+    axis as its kind says (:meth:`page_specs`). Allocator/index state is
+    host-side and unaffected."""
 
     def __init__(self, config: PagedCacheConfig, mesh=None,
                  host_spill_pages: int = 0):
@@ -469,58 +332,24 @@ class PagedKVCache:
         self.mesh = mesh if (mesh is not None
                              and int(mesh.shape.get("tp", 1)) > 1) else None
         c = config
-        if self.mesh is not None and c.num_heads % int(mesh.shape["tp"]):
-            raise ValueError(
-                f"tp={mesh.shape['tp']} must divide num_heads={c.num_heads}")
-        shape = (c.num_pages, c.page_size, c.num_heads * c.head_dim)
-        if c.latent_row is not None:
-            latent, rope = c.latent_row
-            self.pages = [
-                (jnp.zeros(shape[:2] + (latent,), c.dtype),
-                 jnp.zeros((c.num_pages, rope, c.page_size), c.dtype))
-                for _ in range(c.num_layers)]
-        elif c.quantized:
-            # int8 pages + fp32 per-token-row scales, one (k, v, ks, vs)
-            # tuple per layer so scales thread/donate with their pages
-            # through every jitted step as ONE pytree
-            sshape = (c.num_pages, c.page_size)
-            self.pages = [
-                (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                 jnp.zeros(sshape, jnp.float32),
-                 jnp.zeros(sshape, jnp.float32))
-                for _ in range(c.num_layers)]
-        else:
-            def kv_shape(layer):
-                w = c.window_of(layer)
-                return shape if w is None else (
-                    c.num_slots * c.ring_pages(w) + 1,) + shape[1:]
-
-            self.pages: List[Tuple[jnp.ndarray, ...]] = [
-                (jnp.zeros(kv_shape(i), c.dtype),
-                 jnp.zeros(kv_shape(i), c.dtype),
-                 *(jnp.zeros((c.num_pages, width, c.page_size), c.dtype)
-                   for _name, width in c.extra_rows),
-                 *(jnp.zeros((c.num_slots + 1,) + tuple(shape),
-                             c.slot_state_dtype)
-                   for _name, shape in c.slot_state))
-                for i in range(c.num_layers)]
+        tp = int(self.mesh.shape["tp"]) if self.mesh is not None else 1
+        if any(kind.geo.tp != tp for kind in c.kinds):
+            raise ValueError(f"the pool's kinds were not built for tp={tp}")
+        self._bytes = (     # a page id's, a slot's: asked every step
+            sum(kind.page_bytes for kind in c.kinds),
+            sum(kind.slot_bytes for kind in c.kinds))
+        self.pages: List[Tuple[jnp.ndarray, ...]] = [
+            (*(jnp.zeros(shape, dtype) for shape, dtype, _ in kind.pools),
+             *(jnp.zeros((c.num_slots + 1,) + tuple(shape),
+                         c.slot_state_dtype)
+               for _name, shape in c.slot_state))
+            for kind in c.kinds]
         if self.mesh is not None:
-            if c.extra_rows or c.slot_state or c.layer_windows \
-                    or c.latent_row:
-                raise ValueError("a tp-sharded pool carries no extra "
-                                 "rows, no slot state, no window layers "
-                                 "and no latent rows yet")
             from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as P
-            kv_s = NamedSharding(self.mesh, P(None, None, "tp"))
-            rep = NamedSharding(self.mesh, P())
             self.pages = [
-                tuple(jax.device_put(a, kv_s if i < 2 else rep)
-                      for i, a in enumerate(ent))
-                for ent in self.pages]
-        #: the window layers, fixed with the pools: a pool without any (every
-        #: program the engine ran before them) pays nothing for the kind
-        self._window_layers = frozenset(c.window_layers)
+                tuple(jax.device_put(a, NamedSharding(self.mesh, spec))
+                      for a, spec in zip(ent, specs))
+                for ent, specs in zip(self.pages, self.page_specs())]
         self.block_tables = np.zeros((c.num_slots, c.max_pages_per_slot),
                                      np.int32)
         self.lengths = np.zeros((c.num_slots,), np.int32)
@@ -585,75 +414,46 @@ class PagedKVCache:
         cap = (self.config.num_pages - 1) * self.config.page_size
         return float(self.lengths.sum()) / cap if cap else 0.0
 
+    def page_specs(self) -> list:
+        """PartitionSpec pytree of ``pages`` under tp, one tuple a layer:
+        each pool array as its kind shards it, slot state replicated.
+        Drops straight into ``shard_map`` in/out specs."""
+        from jax.sharding import PartitionSpec as P
+        state = (P(),) * len(self.config.slot_state)
+        return [tuple(P(*axes) for _, _, axes in kind.pools) + state
+                for kind in self.config.kinds]
+
     def bytes_per_page(self) -> int:
-        """HBM bytes one page row commits across every layer's K + V
-        pools (+ scale rows when quantized) — global bytes under tp
+        """HBM bytes one page id commits across every layer's pools (scale
+        rows included; a ring a slot: none) — global bytes under tp
         sharding (each shard holds its head slice of the same page)."""
-        total = 0
-        for i, layer in enumerate(self.pages):
-            if i not in self._window_layers:        # a ring is no page row
-                for arr in layer[:self.config.paged_entries]:
-                    total += arr.nbytes
-        return total // self.config.num_pages
+        return self._bytes[0]
 
-    def window_bytes_per_slot(self) -> int:
-        """Bytes of the window layers' rings one slot holds (0 for a
-        pool without window layers)."""
-        c = self.config
-        return sum(arr.nbytes // arr.shape[0] * c.ring_pages(c.window_of(i))
-                   for i in self._window_layers
-                   for arr in self.pages[i][:c.paged_entries])
-
-    def window_tokens_held(self, slot: int, layer: int) -> int:
-        """Tokens of ``slot`` that window layer ``layer`` still holds."""
-        c = self.config
-        return min(int(self.lengths[slot]),
-                   c.ring_pages(c.window_of(layer)) * c.page_size)
-
-    def window_page(self, slot: int, layer: int, logical_page: int) -> int:
-        """The ring page of window layer ``layer`` that holds page
-        ``logical_page`` of ``slot``'s sequence (while it is held)."""
-        ring = self.config.ring_pages(self.config.window_of(layer))
-        return 1 + slot * ring + logical_page % ring
-
-    def recycled_pages(self, before, after) -> int:
-        """Ring pages written over, summed over the window layers, as
-        slots advance from ``before`` to ``after`` tokens (arrays, one
-        entry a slot): a page is recycled when the slot enters a page of
-        its sequence past the ring's first lap."""
-        c = self.config
-        before, after = (-(-np.asarray(a, np.int64) // c.page_size)
-                         for a in (before, after))
-        return int(sum(
-            n * (np.maximum(after - c.ring_pages(w), 0)
-                 - np.maximum(before - c.ring_pages(w), 0)).sum()
-            for w, n in c.window_layer_counts.items()))
+    def bytes_per_slot(self) -> int:
+        """HBM bytes a slot holds whatever its length, state not counted."""
+        return self._bytes[1]
 
     def state_bytes_per_slot(self) -> int:
         """Bytes of slot state one slot holds across every layer (0 for
         a pool without)."""
-        return sum(arr.nbytes for layer in self.pages
-                   for arr in layer[self.config.paged_entries:]) \
-            // (self.config.num_slots + 1)
+        c = self.config
+        return c.num_layers * np.dtype(c.slot_state_dtype).itemsize * sum(
+            int(np.prod(shape)) for _name, shape in c.slot_state)
 
     def capacity_bytes(self) -> int:
         """HBM bytes of the allocatable pool (null page excluded) and of
-        every slot's window rings."""
-        full = self.bytes_per_page() * (self.config.num_pages - 1)
-        if not self._window_layers:
-            return full
-        return full + self.window_bytes_per_slot() * self.config.num_slots
+        what every slot holds whatever its length."""
+        return self.bytes_per_page() * (self.config.num_pages - 1) \
+            + self.bytes_per_slot() * self.config.num_slots
 
     def live_bytes(self) -> int:
         """HBM bytes committed to allocated pages right now (page
         granularity — reservations count the moment they are made,
-        which is what admission headroom must see), and the window rings
-        of the slots that hold a reservation."""
-        live = self.bytes_per_page() * self.pages_in_use
-        if not self._window_layers:
-            return live
-        return live + self.window_bytes_per_slot() * sum(
-            1 for sp in self._slot_pages if sp)
+        which is what admission headroom must see), and what the slots
+        that hold a reservation hold whatever their length."""
+        return self.bytes_per_page() * self.pages_in_use \
+            + self.bytes_per_slot() * sum(
+                1 for sp in self._slot_pages if sp)
 
     def _alloc_page(self) -> int:
         if self._free:
@@ -1033,10 +833,6 @@ class PagedKVCache:
 
     # -- device views -----------------------------------------------------
 
-    def device_tables(self):
-        """(block_tables, lengths) as device arrays for the jitted step."""
-        return jnp.asarray(self.block_tables), jnp.asarray(self.lengths)
-
     def check_invariants(self):
         """Allocator self-check (tests): per-page refcount equals the
         number of mappings holding it, free/cached/live partition the
@@ -1065,31 +861,8 @@ class PagedKVCache:
             assert pid in self._page_tokens, "published page lost tokens"
         for owned, sp in zip(self._owned, self._slot_pages):
             assert owned <= set(sp), "owned page not mapped"
-        if c.latent_row is not None:
-            latent, rope = c.latent_row
-            for ent in self.pages:
-                assert [a.shape for a in ent] == [
-                    (c.num_pages, c.page_size, latent),
-                    (c.num_pages, rope, c.page_size)], \
-                    "a latent layer's entry is not one row a token"
-        for i in c.window_layers:
-            ring = c.ring_pages(c.window_of(i))
-            assert self.pages[i][0].shape[0] == c.num_slots * ring + 1, \
-                "a window layer's pool is not a ring a slot"
-            for slot in range(c.num_slots):
-                held = self.window_tokens_held(slot, i)
-                assert held <= ring * c.page_size, \
-                    "a window layer holds more than its ring"
-                # the pages that hold the slot's window are distinct
-                # pages of the slot's own ring
-                last = max(int(self.lengths[slot]) - 1, 0) // c.page_size
-                first = max(int(self.lengths[slot]) - c.window_of(i), 0) \
-                    // c.page_size
-                mine = [self.window_page(slot, i, p)
-                        for p in range(first, last + 1)]
-                assert len(set(mine)) == len(mine) and all(
-                    slot * ring < p <= (slot + 1) * ring for p in mine), \
-                    "a window's pages collide or leave the slot's ring"
+        for kind, ent in zip(c.kinds, self.pages):
+            kind.check(ent[:len(kind.pools)], self.lengths)
         if self.spill_pool is not None:
             spilled = self.spill_pool.keys()
             assert len(self.spill_pool) <= self.spill_pool.capacity, \

@@ -12,7 +12,7 @@ the chip's compiler keeps page_size-minor as an entry parameter, and
 copies the whole key pool to the kernel's layout and back in every call
 (12 pool-sized copies in a 6-layer decode block). Tokens along the lanes
 it keeps as stored; the engine then writes a token by rewriting its
-page's ``(Di, page_size)`` tile whole (``ServingEngine._write_rows``),
+page's ``(Di, page_size)`` tile whole (``layer_kinds._write_lane_rows``),
 because a scatter along the lane axis brings the same copies back
 (PERF.md section 6, PR 28).
 

@@ -431,7 +431,7 @@ def doc_steps(topo):
 
     # the groups of slots whose tables open with the same pages, as the
     # engine hands them to every decode block
-    groups = tuple(i32(*a.shape) for a in eng._group_decode([])[0])
+    groups = tuple(i32(*a.shape) for a in eng._shared_groups([])[0])
 
     @functools.lru_cache(maxsize=None)
     def lower(step, lanes, width):
@@ -541,7 +541,7 @@ def _slot_state_steps(topo, model, slots, page_size, num_pages, chunk,
     sds = jax.ShapeDtypeStruct
     weights = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype, sharding=dev), params)
-    paged = eng.cache.config.paged_entries
+    paged = len(eng.cache.config.kinds[0].pools)
     pages = [tuple(sds(((num_pages if k < paged else slots + 1),)
                        + a.shape[1:], a.dtype, sharding=dev)
                    for k, a in enumerate(ent)) for ent in eng.cache.pages]
@@ -665,6 +665,7 @@ def k_exaone_steps(topo):
     from paddle_tpu import inference
     from paddle_tpu.models.window_moe_lm import (WindowMoELM,
                                                  WindowMoELMConfig)
+    from paddle_tpu.serving import layer_kinds
     model = WindowMoELM(WindowMoELMConfig(
         num_hidden_layers=5, vocab_size=19200, num_experts=16,
         num_routed_experts=128, kernel_impl="pallas"))
@@ -679,10 +680,13 @@ def k_exaone_steps(topo):
     weights = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype, sharding=dev), params)
     c = eng.cache.config
-    pages = [tuple(sds(((KX_PAGES if c.window_of(i) is None else
-                         KX_SLOTS * c.ring_pages(c.window_of(i)) + 1),)
-                       + a.shape[1:], a.dtype, sharding=dev) for a in ent)
-             for i, ent in enumerate(eng.cache.pages)]
+    # the cell's pools: what each layer's kind lays out at its geometry
+    pages = [tuple(sds(shape, dtype, sharding=dev)
+                   for shape, dtype, _ in kind.pools)
+             for kind in layer_kinds.build(
+                 eng.program.spec, num_slots=KX_SLOTS, page_size=KX_PS,
+                 num_pages=KX_PAGES, dtype=c.dtype,
+                 share_prefix=c.share_prefix)]
 
     def i32(*shape):
         return sds(shape, jnp.int32, sharding=dev)

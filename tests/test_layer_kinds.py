@@ -1,0 +1,213 @@
+"""The seam between the serving engine, the page cache and a layer's kind
+of attention (``paddle_tpu/serving/layer_kinds.py``).
+
+A toy kind stands in for the dense one by substituting the ONE function
+that builds kinds; the engine and the cache must then take its shapes, its
+writes, its attention and its counts without naming it. And the arrows
+point one way: the engine imports no attention module, the kinds import
+neither the engine nor a model.
+"""
+
+import ast
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu import observability as obs
+from paddle_tpu import serving
+from paddle_tpu.models.gpt import GPT, GPTConfig
+from paddle_tpu.serving import decode_attention as DA
+from paddle_tpu.serving import layer_kinds
+from paddle_tpu.serving.program import ServingSpec
+
+SERVING = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "paddle_tpu", "serving")
+
+
+class Negated(layer_kinds.Paged):
+    """K and V as the dense kind lays them out but stored NEGATED (and
+    negated back where they are read), beside a tally of the writes a row
+    took: ``(num_pages, page_size)`` int32, a third pool array no other
+    kind has. It counts the tokens its queries attended to on the device
+    and its decode rounds on the host."""
+
+    stat_names = ("toy_attended_tokens",)
+
+    def __init__(self, geo, layers):
+        super().__init__(geo, layers)
+        self.pools += (
+            ((geo.num_pages, geo.page_size), jnp.int32, ()),)
+
+    def write(self, ent, rows, place):
+        k, v = super().write(ent[:2], tuple(-r for r in rows), place)
+        return k, v, ent[2].at[place[0], place[1]].add(1)
+
+    def attend_decode(self, q, ent, place, index, groups):
+        lengths = place[3] + 1
+        return DA.ragged_paged_decode_attention(
+            q, -ent[0], -ent[1], place[2], lengths, impl="lax"), lengths
+
+    def attend_prefill(self, q, ent, place, n_valid, index):
+        return DA.ragged_paged_prefill_attention(
+            q, -ent[0], -ent[1], place[2], place[3], n_valid, impl="lax")
+
+    def step_counts(self, context, selected):
+        return (selected,)
+
+    def bind(self, reg):
+        self._c_rounds = reg.counter(
+            "serving_toy_decode_rounds_total",
+            "decode rounds the toy kind was asked to count").child()
+
+    def count_decode(self, span, block_tables, lengths, dslots, keeps, n,
+                     width):
+        self._c_rounds.inc()
+        return super().count_decode(span, block_tables, lengths, dslots,
+                                    keeps, n, width)
+
+
+def _toy_build(spec, *, num_slots, page_size, num_pages, dtype,
+               share_prefix, tp=1, impl="auto", prefill_chunk=None):
+    geo = layer_kinds.Geometry(num_slots, page_size, num_pages,
+                               spec.kv_heads, spec.head_dim, dtype, tp, impl)
+    return (Negated(geo, spec.num_layers),) * spec.num_layers
+
+
+def _serve(registry):
+    model = GPT(GPTConfig.tiny(num_heads=2, hidden_size=16,
+                               max_position=64))
+    eng = serving.ServingEngine(
+        model, model.init(jax.random.PRNGKey(0)), num_slots=3, page_size=4,
+        prefill_chunk=8, decode_block=2, attn_impl="lax", registry=registry)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 90, n).astype(np.int32) for n in (5, 11, 3)]
+    return eng, prompts, [list(o) for o in
+                          eng.generate_many(prompts, max_new_tokens=6)]
+
+
+def test_a_toy_kind_serves_in_place_of_the_dense_one(monkeypatch):
+    plain, prompts, want = _serve(obs.MetricsRegistry())
+    monkeypatch.setattr(layer_kinds, "build", _toy_build)
+    reg = obs.MetricsRegistry()
+    toy, _, got = _serve(reg)
+    assert got == want                                  # greedy parity
+    kinds = toy.cache.config.kinds
+    assert all(isinstance(k, Negated) for k in kinds) \
+        and len({id(k) for k in kinds}) == 1
+    # shapes: the cache laid out the kind's third array and reckons with it
+    c = toy.cache.config
+    assert all(len(ent) == 3 and ent[2].shape == (c.num_pages, c.page_size)
+               for ent in toy.cache.pages)
+    assert toy.cache.bytes_per_page() == plain.cache.bytes_per_page() \
+        + c.num_layers * c.page_size * 4
+    toy.cache.check_invariants()
+    for mine, theirs in zip(toy.cache.pages, plain.cache.pages):
+        # writes: every row landed where the dense kind's did, negated,
+        # and each live row was written once (the null page takes the
+        # masked lanes' writes)
+        np.testing.assert_array_equal(np.asarray(mine[0]),
+                                      -np.asarray(theirs[0]))
+        np.testing.assert_array_equal(np.asarray(mine[1]),
+                                      -np.asarray(theirs[1]))
+        tally = np.asarray(mine[2])
+        assert set(np.unique(tally[1:])) <= {0, 1}
+        # a prompt's tokens, then three blocks of two (the last block's
+        # second token is past the budget, inside the reservation)
+        assert tally[1:].sum() == sum(len(p) + 6 for p in prompts)
+    # counts: the kind's own series on the host, its count on the device
+    snap = reg.snapshot()
+    assert snap["serving_toy_decode_rounds_total"] \
+        == snap["serving_decode_rounds_total"] > 0
+    attended = snap["serving_toy_attended_tokens_total"]
+    assert attended > 0 and attended % c.num_layers == 0
+    assert not [k for k in obs.MetricsRegistry().snapshot() if "toy" in k]
+
+
+def _imports(path):
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    names = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names |= {a.name for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            names |= {f"{node.module}.{a.name}" for a in node.names}
+            names.add(node.module)
+    return names
+
+
+def test_the_arrows_point_one_way():
+    engine = _imports(os.path.join(SERVING, "engine.py"))
+    assert not [n for n in engine if "decode_attention" in n
+                or "sparse_attention" in n], engine
+    kinds = _imports(os.path.join(SERVING, "layer_kinds.py"))
+    assert not [n for n in kinds if n.startswith("paddle_tpu.models")
+                or n.endswith("serving.engine")
+                or n.endswith("paged_cache")], kinds
+    cache = _imports(os.path.join(SERVING, "paged_cache.py"))
+    assert not [n for n in cache if "attention" in n or "engine" in n], cache
+
+
+@pytest.mark.parametrize("word", [
+    "latent_row", "_latent", "layer_windows", "_window_layers", "_ring",
+    "_selects", "_folds", "select_topk", "extra_rows"])
+def test_the_engine_names_no_family(word):
+    with open(os.path.join(SERVING, "engine.py")) as f:
+        assert word not in f.read()
+
+
+_SPEC = dict(num_layers=2, num_heads=4, vocab_size=8, max_position=64)
+_GEO = dict(num_slots=2, page_size=4, num_pages=9)
+
+
+@pytest.mark.parametrize("fields, dtype, kinds", [
+    (dict(kv_heads=2, head_dim=8), jnp.float32,
+     [layer_kinds.Paged] * 2),
+    (dict(kv_heads=2, head_dim=8), jnp.int8,
+     [layer_kinds.PagedInt8] * 2),
+    (dict(kv_heads=2, head_dim=8, layer_windows=(8, None)), jnp.float32,
+     [layer_kinds.Ring, layer_kinds.Paged]),
+    (dict(kv_heads=1, head_dim=24, latent_row=(16, 8)), jnp.float32,
+     [layer_kinds.Latent] * 2),
+    (dict(kv_heads=2, head_dim=8, extra_rows=(("idx", 4),), select_topk=8),
+     jnp.float32, [layer_kinds.Selecting] * 2),
+], ids=["float", "int8", "window", "latent", "selecting"])
+def test_build_decides_the_kind_of_every_layer(fields, dtype, kinds):
+    built = layer_kinds.build(ServingSpec(**_SPEC, **fields), dtype=dtype,
+                              share_prefix=False, **_GEO)
+    assert [type(k) for k in built] == kinds
+    # layers alike share one object, which knows how many it stands for
+    for kind in set(built):
+        assert kind.layers == built.count(kind)
+    # a pool's geometry is the cache's; the page cache takes the kinds
+    cache = serving.PagedKVCache(serving.PagedCacheConfig(
+        num_layers=2, num_heads=fields["kv_heads"],
+        head_dim=fields["head_dim"], dtype=dtype, share_prefix=False,
+        kinds=built, **_GEO))
+    cache.check_invariants()
+    assert [tuple(a.shape for a in ent) for ent in cache.pages] \
+        == [tuple(shape for shape, _, _ in k.pools) for k in built]
+    assert cache.capacity_bytes() == cache.bytes_per_page() * 8 \
+        + cache.bytes_per_slot() * 2
+
+
+def test_build_refuses_what_does_not_combine():
+    def build(dtype=jnp.float32, share_prefix=False, page_size=4, **fields):
+        spec = ServingSpec(**_SPEC, kv_heads=2, head_dim=8, **fields)
+        return layer_kinds.build(spec, num_slots=2, page_size=page_size,
+                                 num_pages=9, dtype=dtype,
+                                 share_prefix=share_prefix, prefill_chunk=8)
+    with pytest.raises(ValueError, match="multiple of page_size"):
+        build(extra_rows=(("idx", 4),), select_topk=6)
+    with pytest.raises(ValueError, match="no extra rows"):
+        build(dtype=jnp.int8, extra_rows=(("idx", 4),), select_topk=8)
+    with pytest.raises(ValueError, match="no slot state"):
+        build(dtype=jnp.int8, slot_state=(("s", (2,)),))
+    with pytest.raises(ValueError, match="cannot share prefixes"):
+        build(share_prefix=True, slot_state=(("s", (2,)),))
+    with pytest.raises(ValueError, match="prefill_chunk=8 > page_size=4"):
+        build(layer_windows=(8, None))
+    assert build(page_size=8, layer_windows=(8, None))

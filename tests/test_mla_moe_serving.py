@@ -27,6 +27,7 @@ from paddle_tpu import inference, kernels
 from paddle_tpu import observability as obs
 from paddle_tpu.models.mla_moe_lm import MLAMoELM, MLAMoELMConfig
 from paddle_tpu.serving import decode_attention as DA
+from paddle_tpu.serving import layer_kinds
 from paddle_tpu.serving.paged_cache import PagedCacheConfig, PagedKVCache
 from paddle_tpu.serving.program import FEATURES, ServingSpec
 
@@ -423,17 +424,25 @@ def test_a_latent_page_is_one_row_a_token(engines):
 
 
 def test_a_latent_pool_is_alone_in_its_entry():
-    base = dict(num_layers=1, num_heads=1, head_dim=24, num_slots=2,
-                page_size=8, num_pages=5, latent_row=(16, 8))
-    cache = PagedKVCache(PagedCacheConfig(**base))
+    spec = dict(num_layers=1, num_heads=4, vocab_size=8, max_position=8)
+    latent = dict(spec, kv_heads=1, head_dim=24, latent_row=(16, 8))
+    geo = dict(num_slots=2, page_size=8, num_pages=5)
+
+    def kinds(dtype=jnp.float32, share_prefix=True, **more):
+        return layer_kinds.build(ServingSpec(**latent, **more), dtype=dtype,
+                                 share_prefix=share_prefix, **geo)
+    cache = PagedKVCache(PagedCacheConfig(
+        num_layers=1, num_heads=1, head_dim=24, kinds=kinds(), **geo))
     cache.check_invariants()
-    assert cache.config.paged_entries == 2
-    for extra in (dict(dtype=jnp.int8), dict(extra_rows=(("idx", 4),)),
+    assert len(cache.config.kinds[0].pools) == len(cache.pages[0]) == 2
+    with pytest.raises(ValueError, match="latent rows"):
+        kinds(dtype=jnp.int8)
+    # (what a spec may not declare beside a latent row, the spec refuses)
+    for extra in (dict(extra_rows=(("idx", 4),)),
                   dict(slot_state=(("s", (2,)),), share_prefix=False),
                   dict(layer_windows=(8,), share_prefix=False)):
-        with pytest.raises(ValueError, match="latent rows"):
-            PagedCacheConfig(**base, **extra)
-    spec = dict(num_layers=1, num_heads=4, vocab_size=8, max_position=8)
+        with pytest.raises(ValueError, match="cached alone"):
+            kinds(**extra)
     with pytest.raises(ValueError, match="one row a token"):
         ServingSpec(**spec, kv_heads=4, head_dim=24, latent_row=(16, 8))
     with pytest.raises(ValueError, match="cached alone"):
@@ -580,7 +589,8 @@ def _decode_blocks_of(eng, run):
                   eng.cache.lengths.copy())
         blk = dispatch(dslots, w, rnd)
         seen.append(before + (tuple(
-            np.asarray(a) for a in eng._decode_groups[2]),))
+            np.asarray(a) for a in
+            eng.cache.config.kinds[0].groups.kept[2]),))
         return blk
     eng._dispatch_block = watched
     try:

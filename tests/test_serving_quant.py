@@ -60,7 +60,7 @@ class TestQuantizeKV:
             num_layers=2, num_heads=2, head_dim=4, num_slots=2,
             page_size=4, num_pages=6, max_pages_per_slot=3,
             dtype=jnp.int8))
-        assert c.config.quantized
+        assert all(kind.quantized for kind in c.config.kinds)
         kp, vp, ks, vs = c.pages[0]
         assert kp.dtype == jnp.int8 and vp.dtype == jnp.int8
         # heads folded into the last axis, like the fp pool; scale rows

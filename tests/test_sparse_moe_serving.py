@@ -423,7 +423,8 @@ def test_requests_over_a_published_document_decode_folded(model_and_params):
             int(snap[k] - before.get(k, 0)) for k in names]
 
     folded, (fetched, held) = serve(True)
-    _slots, _tables, groups, _twice, spared = eng._decode_groups
+    kept = eng.cache.config.kinds[0].groups
+    _slots, _tables, groups, _twice, spared = kept.kept
     assert np.asarray(groups[1]).tolist() == [8] and spared == 8 * PAGE
     assert sorted(np.asarray(groups[0])[0, :2]) == [0, 1]
     # blocks of 2 token steps, 2 layers: 32 rows spared each
@@ -431,7 +432,7 @@ def test_requests_over_a_published_document_decode_folded(model_and_params):
     alone, (fetched, held) = serve(False)
     assert alone == folded
     assert fetched == held > 0
-    assert not np.asarray(eng._decode_groups[2][1]).any()
+    assert not np.asarray(kept.kept[2][1]).any()
 
 
 def test_blocked_benchmark_reference_is_the_plain_one(model_and_params):

@@ -28,6 +28,7 @@ from paddle_tpu import inference, kernels
 from paddle_tpu import observability as obs
 from paddle_tpu.models import WindowMoELM, WindowMoELMConfig
 from paddle_tpu.ops import grouped_ffn
+from paddle_tpu.serving import layer_kinds
 from paddle_tpu.serving.paged_cache import (PageOverflowError,
                                             PagedCacheConfig, PagedKVCache)
 from paddle_tpu.serving.program import FEATURES, ServingSpec
@@ -300,30 +301,49 @@ def test_route_tiles_gives_no_row_to_a_pair_held_elsewhere(offset):
 
 # -- the cache ----------------------------------------------------------------
 
-def _cache(num_pages=25, slots=3, windows=(8, 8, 8, None, 8), **kw):
+def _kinds(windows, layers=None, slots=3, num_pages=25, dtype=jnp.float32,
+           share_prefix=False):
+    """The kinds of a program with these windows, through the one function
+    that decides them."""
+    spec = ServingSpec(num_layers=layers or len(windows), num_heads=2,
+                       kv_heads=2, head_dim=16, vocab_size=8,
+                       max_position=256, layer_windows=windows)
+    return layer_kinds.build(spec, num_slots=slots, page_size=PAGE,
+                             num_pages=num_pages, dtype=dtype,
+                             share_prefix=share_prefix)
+
+
+def _cache(num_pages=25, slots=3, windows=(8, 8, 8, None, 8)):
     return PagedKVCache(PagedCacheConfig(
         num_layers=len(windows), num_heads=2, head_dim=16, num_slots=slots,
         page_size=PAGE, num_pages=num_pages, max_pages_per_slot=24,
-        share_prefix=False, layer_windows=windows, **kw))
+        share_prefix=False,
+        kinds=_kinds(windows, slots=slots, num_pages=num_pages)))
+
+
+def _rings(cache):
+    """(layer, its kind) of the window layers; one object for them all."""
+    return [(i, k) for i, k in enumerate(cache.config.kinds)
+            if isinstance(k, layer_kinds.Ring)]
 
 
 def test_a_window_layer_holds_a_ring_a_slot_whatever_the_length():
     cache = _cache()
-    ring = cache.config.ring_pages(WINDOW)
-    assert ring == 3
+    ring = _rings(cache)[0][1].ring_pages
+    assert ring == 3 and len({id(k) for _, k in _rings(cache)}) == 1
     page = PAGE * 2 * 16 * 4 * 2                        # K and V, float32
     assert [ent[0].shape[0] for ent in cache.pages] == [10, 10, 10, 25, 10]
     assert cache.bytes_per_page() == page               # the full layer's
-    assert cache.window_bytes_per_slot() == 4 * ring * page
+    assert cache.bytes_per_slot() == 4 * ring * page
     assert cache.capacity_bytes() == 24 * page + 3 * 4 * ring * page
     cache.reserve(1, 80)
     assert cache.live_bytes() == 20 * page + 4 * ring * page
     for n in (0, 3, 8, 12, 13, 57, 80):
         cache.lengths[1] = n
         cache.check_invariants()
-        for layer in cache.config.window_layers:
-            assert cache.window_tokens_held(1, layer) \
-                == min(n, WINDOW + PAGE)
+    for _layer, kind in _rings(cache):      # what a ring holds of a slot
+        assert kind.ring_pages * PAGE == WINDOW + PAGE
+        assert kind.slot_bytes == ring * page and kind.page_bytes == 0
     cache.free_slot(1)
     assert cache.live_bytes() == 0
 
@@ -355,33 +375,32 @@ def test_a_recycled_page_is_never_one_a_live_slot_still_reads():
     """The page a slot writes next holds no token of its own window, and
     is no other slot's."""
     cache = _cache()
-    for layer in cache.config.window_layers:
-        for slot in range(3):
-            for n in range(0, 60):          # tokens held before the write
-                writes = cache.window_page(slot, layer, n // PAGE)
-                still_read = {cache.window_page(slot, layer, t // PAGE)
-                              for t in range(max(n - WINDOW + 1, 0), n)
-                              if t // PAGE != n // PAGE}
-                assert writes not in still_read
-                assert all(cache.window_page(other, layer, p) != writes
-                           for other in range(3) if other != slot
-                           for p in range(3))
-    assert cache.recycled_pages(np.array([0, 11]), np.array([12, 13])) == 4
-    assert cache.recycled_pages(np.array([12]), np.array([21])) == 4 * 3
+    kind = _rings(cache)[0][1]
+    for slot in range(3):
+        for n in range(0, 60):          # tokens held before the write
+            writes = kind.page_of(slot, n // PAGE)
+            still_read = {kind.page_of(slot, t // PAGE)
+                          for t in range(max(n - WINDOW + 1, 0), n)
+                          if t // PAGE != n // PAGE}
+            assert writes not in still_read
+            assert all(kind.page_of(other, p) != writes
+                       for other in range(3) if other != slot
+                       for p in range(3))
+    # (all four window layers: the kind counts for the layers it stands for)
+    assert kind.layers == 4
+    assert kind.recycled(np.array([0, 11]), np.array([12, 13])) == 4
+    assert kind.recycled(np.array([12]), np.array([21])) == 4 * 3
 
 
 def test_a_pool_with_window_layers_shares_no_prefix_and_is_not_quantized():
     with pytest.raises(ValueError, match="window layers"):
-        PagedCacheConfig(num_layers=2, num_heads=2, head_dim=16, num_slots=2,
-                         page_size=4, layer_windows=(8, None))
+        _kinds((8, None), share_prefix=True)
     with pytest.raises(ValueError, match="window layers"):
-        PagedCacheConfig(num_layers=2, num_heads=2, head_dim=16, num_slots=2,
-                         page_size=4, share_prefix=False, dtype=jnp.int8,
-                         layer_windows=(8, None))
+        _kinds((8, None), dtype=jnp.int8)
     with pytest.raises(ValueError, match="every layer or none"):
         PagedCacheConfig(num_layers=3, num_heads=2, head_dim=16, num_slots=2,
                          page_size=4, share_prefix=False,
-                         layer_windows=(8, None))
+                         kinds=_kinds((8, None)))
     with pytest.raises(ValueError, match="one entry a layer"):
         ServingSpec(num_layers=3, num_heads=2, kv_heads=2, head_dim=16,
                     vocab_size=8, max_position=64, layer_windows=(8, None))
@@ -529,7 +548,7 @@ def test_a_program_of_one_layer_kind_binds_none_of_the_new_series():
     assert not [k for k in reg.snapshot()
                 if "window" in k or "resident" in k or "pool_bytes" in k
                 or "routed_pairs" in k or "latent" in k]
-    assert eng.cache.window_bytes_per_slot() == 0
+    assert eng.cache.bytes_per_slot() == 0
     assert eng.cache.capacity_bytes() \
         == eng.cache.bytes_per_page() * (eng.cache.config.num_pages - 1)
 

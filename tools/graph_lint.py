@@ -384,6 +384,37 @@ def lint_serving_prefill_tp_mlp(suppressions, cost=False):
         suppressions=suppressions, cost=cost)
 
 
+#: a layer kind's methods that run inside the engine's jitted steps
+TRACED_KIND_METHODS = ("place_decode", "place_prefill", "write",
+                       "attend_decode", "attend_prefill", "attends_prefill",
+                       "step_counts", "copy_page")
+
+
+def lint_serving_layer_kinds(suppressions, cost=False):
+    """The traced half of ``serving/layer_kinds.py``: where a call's
+    tokens go, the row writes and the attention calls of every kind of
+    layer. They run inside the serving steps the presets above lower (the
+    jaxpr tier sees them there); the AST host-sync lint is per function,
+    so it runs here on each of them as it runs on ``_decode_loop`` /
+    ``_prefill_loop``, the bodies they were lifted out of."""
+    from paddle_tpu.analysis import ast_lint
+    from paddle_tpu.serving import layer_kinds
+
+    report = analysis.Report("serving_layer_kinds",
+                             suppressions=suppressions)
+    lk = layer_kinds
+    traced = [lk.under_table, lk._write_lane_rows, lk._write_rows,
+              lk.quantize_kv, lk.Ring._pages] + [
+        vars(kind)[name]
+        for kind in (lk.Paged, lk.PagedInt8, lk.Ring, lk.Latent,
+                     lk.Selecting)
+        for name in TRACED_KIND_METHODS if name in vars(kind)]
+    for fn in traced:
+        report.extend(ast_lint.lint_callable(fn))
+    report.count_into_registry()
+    return report
+
+
 def lint_embedding_install(suppressions, cost=False):
     """The embedding-serving cache's update step: the device hot-row
     table is DONATED into the bucketed scatter (the engine replaces its
@@ -486,6 +517,7 @@ PRESETS = {
                   lint_serving_prefill, lint_serving_decode_int8,
                   lint_serving_prefill_int8, lint_serving_decode_tp,
                   lint_serving_prefill_tp, lint_serving_prefill_tp_mlp,
+                  lint_serving_layer_kinds,
                   lint_embedding_install,
                   lint_embedding_lookup, lint_kernel_registry],
 }

@@ -66,6 +66,7 @@ def cell_programs(root: str, config: dict, device):
     import jax
     import jax.numpy as jnp
     from paddle_tpu import inference
+    from paddle_tpu.serving import layer_kinds
     family = importlib.import_module(f"families.{config['family']}")
     model = family.build(config["sizes"])
     ekw = dict(config["engine"])
@@ -83,20 +84,18 @@ def cell_programs(root: str, config: dict, device):
     if num_pages is None:
         num_pages = slots * width + 1
     sds = jax.ShapeDtypeStruct
-
-    def pool(layer, k, a):
-        if k >= c.paged_entries:                        # state a slot
-            lead = slots + 1
-        elif c.window_of(layer) is not None:            # a ring a slot
-            lead = slots * c.ring_pages(c.window_of(layer)) + 1
-        else:
-            lead = num_pages
-        return sds((lead,) + a.shape[1:], a.dtype, sharding=device)
-
+    # the cell's pools: what each layer's kind lays out at the cell's
+    # geometry, then the program's state a slot
+    kinds = layer_kinds.build(
+        eng.program.spec, num_slots=slots, page_size=c.page_size,
+        num_pages=num_pages, dtype=c.dtype, share_prefix=c.share_prefix)
     weights = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype, sharding=device), params)
-    pages = [tuple(pool(i, k, a) for k, a in enumerate(ent))
-             for i, ent in enumerate(eng.cache.pages)]
+    pages = [tuple(sds(shape, dtype, sharding=device)
+                   for shape, dtype, _ in kind.pools)
+             + tuple(sds((slots + 1,) + a.shape[1:], a.dtype,
+                         sharding=device) for a in ent[len(kind.pools):])
+             for kind, ent in zip(kinds, eng.cache.pages)]
 
     def i32(*shape):
         return sds(shape, jnp.int32, sharding=device)
