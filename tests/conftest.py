@@ -31,3 +31,33 @@ def mesh8():
 def mesh_dp2_tp4():
     from paddle_tpu.core.mesh import MeshConfig, make_mesh
     return make_mesh(MeshConfig(dp=2, tp=4))
+
+
+def _script(name, *where):
+    """``<root>/<where>/<name>.py`` loaded anew as a module."""
+    import importlib.util
+    import os
+    import sys
+    spec = importlib.util.spec_from_file_location(name, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), *where,
+        name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod             # dataclasses resolve the module
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="session")
+def script():
+    """``script(name, *where)``: a script of the repo as a module."""
+    return _script
+
+
+@pytest.fixture(scope="module")
+def chip_smoke():
+    return _script("chip_smoke")
+
+
+@pytest.fixture
+def graph_lint_cli():
+    return _script("graph_lint", "tools")

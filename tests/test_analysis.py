@@ -482,30 +482,20 @@ class TestExecutorGate:
 # ---------------------------------------------------------------------------
 
 class TestCli:
-    def _cli(self):
-        import importlib.util
-        import os
-        spec = importlib.util.spec_from_file_location(
-            "graph_lint", os.path.join(os.path.dirname(__file__),
-                                       "..", "tools", "graph_lint.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_list_rules(self, capsys):
-        assert self._cli().main(["--list-rules"]) == 0
+    def test_list_rules(self, graph_lint_cli, capsys):
+        assert graph_lint_cli.main(["--list-rules"]) == 0
         out = capsys.readouterr().out
         assert "prng-key-reuse" in out and "host-callback" in out
 
-    def test_lenet_preset_entry_green(self):
-        mod = self._cli()
+    def test_lenet_preset_entry_green(self, graph_lint_cli):
+        mod = graph_lint_cli
         rep = mod.lint_lenet(None)
         assert rep.ok("error"), rep.render_text()
 
     @pytest.mark.slow
-    def test_framework_preset_green(self):
+    def test_framework_preset_green(self, graph_lint_cli):
         """The CI self-lint stage (run_ci.sh) must pass."""
-        assert self._cli().main(["--preset", "framework"]) == 0
+        assert graph_lint_cli.main(["--preset", "framework"]) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -812,18 +802,8 @@ class TestStaleSuppressions:
 
 
 class TestCostCli:
-    def _cli(self):
-        import importlib.util
-        import os
-        spec = importlib.util.spec_from_file_location(
-            "graph_lint", os.path.join(os.path.dirname(__file__),
-                                       "..", "tools", "graph_lint.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_cost_diff_flags_regression(self):
-        mod = self._cli()
+    def test_cost_diff_flags_regression(self, graph_lint_cli):
+        mod = graph_lint_cli
         budgets = {"tolerance": 0.10, "surfaces": {
             "s": {"flops": 100, "peak_hbm_bytes": 1000,
                   "collective_bytes": 0}}}
@@ -836,8 +816,8 @@ class TestCostCli:
         assert mod.cost_diff(bad, budgets, out=sink.append) == 1
         assert any("REGRESSION" in s for s in sink)
 
-    def test_cost_diff_collectives_from_zero_fail(self):
-        mod = self._cli()
+    def test_cost_diff_collectives_from_zero_fail(self, graph_lint_cli):
+        mod = graph_lint_cli
         budgets = {"tolerance": 0.10, "surfaces": {
             "s": {"flops": 100, "peak_hbm_bytes": 1000,
                   "collective_bytes": 0}}}
@@ -845,31 +825,32 @@ class TestCostCli:
                       "collective_bytes": 4096}}
         assert mod.cost_diff(grew, budgets, out=lambda *_: None) == 1
 
-    def test_cost_diff_missing_baseline_fails(self):
-        mod = self._cli()
+    def test_cost_diff_missing_baseline_fails(self, graph_lint_cli):
+        mod = graph_lint_cli
         budgets = {"tolerance": 0.10, "surfaces": {}}
         assert mod.cost_diff(
             {"new": {"flops": 1, "peak_hbm_bytes": 1,
                      "collective_bytes": 0}},
             budgets, out=lambda *_: None) == 1
 
-    def test_bucket_coverage_report_green(self):
-        rep = self._cli().bucket_coverage_report(None)
+    def test_bucket_coverage_report_green(self, graph_lint_cli):
+        rep = graph_lint_cli.bucket_coverage_report(None)
         assert rep.ok("error"), rep.render_text()
 
     @pytest.mark.slow
-    def test_cost_preset_green(self):
+    def test_cost_preset_green(self, graph_lint_cli):
         """The CI cost stage (run_ci.sh): --cost --cost-diff must pass
         against the committed tools/cost_budgets.json."""
-        assert self._cli().main(
+        assert graph_lint_cli.main(
             ["--preset", "framework", "--cost", "--cost-diff"]) == 0
 
     @pytest.mark.slow
-    def test_injected_regression_fails_cost_diff(self, tmp_path):
+    def test_injected_regression_fails_cost_diff(self, graph_lint_cli,
+                                                 tmp_path):
         """ISSUE acceptance: --cost-diff demonstrably fails on an
         injected >10% budget regression."""
         import json
-        mod = self._cli()
+        mod = graph_lint_cli
         with open(mod.DEFAULT_BUDGETS) as f:
             budgets = json.load(f)
         # shrink one committed baseline so the measured value reads as

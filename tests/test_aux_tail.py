@@ -4,7 +4,6 @@ loaders, chrome-trace export (tools/timeline.py parity), program printer
 parity)."""
 
 import json
-import os
 
 import jax
 import jax.numpy as jnp

@@ -11,7 +11,6 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import io, optimizer as opt
-from paddle_tpu.core.mesh import MeshConfig, make_mesh
 from paddle_tpu.data import datasets, reader as rd, DataFeeder, device_iterator
 from paddle_tpu.models import LeNet
 from paddle_tpu.ops import nn as F

@@ -2,53 +2,52 @@
 calls, at a tiny size, Pallas bodies through the interpreter — and the
 script's refusal to produce a result without a TPU."""
 
-import importlib.util
 import json
 import os
-import sys
 
 import jax
 import pytest
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-@pytest.fixture(scope="module")
-def chip_smoke():
-    spec = importlib.util.spec_from_file_location(
-        "chip_smoke", os.path.join(_ROOT, "chip_smoke.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules["chip_smoke"] = mod     # dataclasses resolve the module
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_one_chip_phases_at_tiny_size(chip_smoke, capsys):
-    chip_smoke._watch_compiles()
-    chip_smoke.run_one_chip(chip_smoke.Sizes.tiny())
-    out = capsys.readouterr().out
+#: a phase of ``chip_smoke.run_one_chip`` and what it says when it passed
+ONE_CHIP_PHASES = {
+    "phase_paged_kernels": ["paged kernels vs lax"],
+    "phase_trainer": ["flash_attention[pallas_interpret]"],
     # JAX's own account of where warmup's seconds went
-    assert "of which JAX reports" in out
-    assert chip_smoke.COMPILE_STATS["trace_s"] > 0
-    assert chip_smoke.COMPILE_STATS["backend_compile_s"] > 0
-    assert "paged kernels vs lax" in out
-    assert "flash_attention[pallas_interpret]" in out
-    assert "compiles after warmup=0" in out
-    assert "token-exact vs model.generate" in out
-    assert "sparse family kernels vs lax" in out
-    assert "tokens are the float32 reference's argmax" in out
-    assert "hybrid family kernels vs lax" in out
-    assert "hybrid family: 19-token prompt" in out
-    assert "latent family kernels vs lax" in out
-    assert "latent family: 19-token prompt" in out
+    "phase_serving": ["of which JAX reports", "compiles after warmup=0",
+                      "token-exact vs model.generate"],
+    "phase_sparse_family": ["sparse family kernels vs lax",
+                            "tokens are the float32 reference's argmax"],
+    "phase_hybrid_family": ["hybrid family kernels vs lax",
+                            "hybrid family: 19-token prompt"],
+    "phase_latent_family": ["latent family kernels vs lax",
+                            "latent family: 19-token prompt"],
+}
 
 
-def test_four_chip_phases_on_virtual_devices(chip_smoke, capsys):
-    chip_smoke.run_four_chips(chip_smoke.Sizes.tiny(),
-                              devices=jax.devices()[:4])
+def test_run_one_chip_is_these_phases(chip_smoke, monkeypatch):
+    called = []
+    for name in [n for n in dir(chip_smoke) if n.startswith("phase_")]:
+        monkeypatch.setattr(chip_smoke, name, lambda sizes, seed, name=name:
+                            called.append(name))
+    chip_smoke.run_one_chip(chip_smoke.Sizes.tiny())
+    assert called == list(ONE_CHIP_PHASES)
+
+
+@pytest.mark.parametrize("phase", list(ONE_CHIP_PHASES))
+def test_one_chip_phases_at_tiny_size(chip_smoke, capsys, phase):
+    """Each phase alone, as ``run_one_chip`` calls it: a phase reads the
+    counters it asserts on before and after itself."""
+    if phase == "phase_serving":
+        chip_smoke._watch_compiles()
+    getattr(chip_smoke, phase)(chip_smoke.Sizes.tiny(), 0)
     out = capsys.readouterr().out
-    assert "dp2 x tp2 losses" in out
-    assert "requests token-equal, tp=4 vs tp=1" in out
+    for line in ONE_CHIP_PHASES[phase]:
+        assert line in out
+    if phase == "phase_serving":
+        assert chip_smoke.COMPILE_STATS["trace_s"] > 0
+        assert chip_smoke.COMPILE_STATS["backend_compile_s"] > 0
 
 
 def test_real_sizes_are_full_width(chip_smoke):
@@ -78,12 +77,8 @@ def test_main_fails_without_a_tpu(chip_smoke, capsys, argv):
 # -- the entry points do not hide the device ---------------------------------
 
 @pytest.fixture(scope="module")
-def bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(_ROOT, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def bench(script):
+    return script("bench")
 
 
 class _Dev:
@@ -158,64 +153,3 @@ def test_kernel_dispatch_counts_the_resolved_impl():
     # on a CPU backend "auto" is the lax path — and says so
     assert counter.value(kernel="ragged_paged_decode",
                          impl="lax") == before + 1
-
-
-# -- the flash kernel under a mesh (nn.transformer._attend) ------------------
-# A TPU's "auto" is the flash kernel, which the SPMD partitioner refuses:
-# under a mesh it runs per shard in a shard_map — unless a pipeline stage
-# body already is one. The CPU's "auto" is xla, so these name the kernel.
-
-_TINY_BERT = dict(vocab_size=64, hidden_size=16, num_layers=4, num_heads=2,
-                  ffn_size=32, max_position=32, dropout=0.0,
-                  attn_dropout=0.0)
-
-
-def _bert_batch(b, s=16):
-    import jax.numpy as jnp
-    k1, k2 = jax.random.split(jax.random.PRNGKey(1))
-    mask = jnp.arange(s)[None, :] < jax.random.randint(
-        k2, (b, 1), s // 2, s + 1)               # ragged padding
-    return dict(
-        input_ids=jax.random.randint(k1, (b, s), 0, 64, jnp.int32),
-        token_type_ids=jnp.zeros((b, s), jnp.int32),
-        attention_mask=mask,
-        mlm_labels=jnp.zeros((b, s), jnp.int32),
-        mlm_mask=jnp.ones((b, s), jnp.float32),
-        nsp_labels=jnp.zeros((b,), jnp.int32))
-
-
-@pytest.mark.parametrize("mesh_kw, model_kw, batch_size", [
-    pytest.param(dict(config=dict(dp=2, fsdp=2, pp=2)),
-                 dict(pipeline=True, pp_microbatches=4,
-                      stacked_layers=False), 16, id="inside-pipeline-stage"),
-    pytest.param(dict(axis_names=("dp",), shape=(8,)), {}, 16,
-                 id="mesh-without-fsdp-tp-axes"),
-    pytest.param(dict(config=dict(dp=4, fsdp=2)), {}, 6,
-                 id="batch-not-divisible"),
-    pytest.param(dict(config=dict(dp=2, tp=4)), {}, 16,
-                 id="heads-not-divisible"),
-])
-def test_flash_kernel_under_a_mesh(mesh_kw, model_kw, batch_size):
-    import numpy as np
-    from paddle_tpu.core.mesh import MeshConfig, make_mesh, mesh_context
-    from paddle_tpu.models.bert import BertConfig, BertForPretraining
-
-    mesh_kw = dict(mesh_kw)
-    if "config" in mesh_kw:
-        mesh_kw["config"] = MeshConfig(**mesh_kw["config"])
-    m_ref = BertForPretraining(BertConfig.tiny(**_TINY_BERT,
-                                               attn_impl="xla"))
-    m = BertForPretraining(BertConfig.tiny(
-        **_TINY_BERT, attn_impl="flash_interpret", **model_kw))
-    params = m_ref.init(jax.random.PRNGKey(0))
-    batch = _bert_batch(batch_size)
-    l_ref, g_ref = jax.value_and_grad(
-        lambda p: m_ref.loss(p, training=False, **batch)[0])(params)
-    with mesh_context(make_mesh(**mesh_kw)):
-        loss, grads = jax.jit(jax.value_and_grad(
-            lambda p: m.loss(p, training=False, **batch)[0]))(params)
-    assert float(loss) == pytest.approx(float(l_ref), rel=1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(g_ref)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-4, rtol=1e-3)

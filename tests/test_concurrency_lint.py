@@ -6,13 +6,11 @@ found in the existing serving plane. Every rule gets a fire/clean-twin
 pair; the threaded e2e proves observed ⊆ the committed static graph on
 a real stepping fleet under sanitize()."""
 
-import json
 import os
 import threading
 import time
 
 import numpy as np
-import jax
 import pytest
 
 from paddle_tpu import observability as obs
@@ -22,7 +20,6 @@ from paddle_tpu.analysis import conformance
 from paddle_tpu.analysis.findings import RULES
 from paddle_tpu.serving import fleet
 from paddle_tpu.serving.scheduler import REJECT_REASONS, Reject
-from paddle_tpu.models.gpt import GPT, GPTConfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOCK_ORDER = os.path.join(REPO, "tools", "lock_order.json")
@@ -32,11 +29,8 @@ VOCAB = 64
 
 @pytest.fixture(scope="module")
 def model_params():
-    cfg = GPTConfig.tiny(vocab_size=VOCAB, hidden_size=16, num_layers=2,
-                         num_heads=2, ffn_size=32, max_position=64,
-                         dropout=0.0, attn_impl="xla")
-    model = GPT(cfg)
-    return model, model.init(jax.random.PRNGKey(0))
+    from serving_taps import tiny_gpt
+    return tiny_gpt()
 
 
 def _engine(model_params, **kw):

@@ -7,7 +7,6 @@ locally — zero egress).
 """
 
 import gzip
-import os
 import pickle
 import struct
 import time

@@ -16,17 +16,6 @@ pytestmark = pytest.mark.slow  # excluded from the quick CI gate
 from paddle_tpu.ops import detection as D
 
 
-def np_box_iou(a, b):
-    area1 = (a[:, 2] - a[:, 0]) * (a[:, 3] - a[:, 1])
-    area2 = (b[:, 2] - b[:, 0]) * (b[:, 3] - b[:, 1])
-    lt = np.maximum(a[:, None, :2], b[None, :, :2])
-    rb = np.minimum(a[:, None, 2:], b[None, :, 2:])
-    wh = np.clip(rb - lt, 0.0, None)
-    inter = wh[..., 0] * wh[..., 1]
-    return inter / np.maximum(area1[:, None] + area2[None, :] - inter,
-                              1e-10)
-
-
 def np_bipartite_match(dist, row_mask):
     d = np.where(row_mask[:, None], dist, -1.0).copy()
     g, p = d.shape

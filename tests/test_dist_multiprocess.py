@@ -15,7 +15,6 @@ CPU backend), independent of the in-process 8-device fixture.
 
 import json
 import os
-import signal
 import socket
 import subprocess
 import sys

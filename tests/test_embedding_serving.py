@@ -4,8 +4,6 @@ shedding, persistence, and the zero-steady-state-recompile invariant.
 """
 
 import os
-import threading
-import time
 
 import jax
 import jax.numpy as jnp

@@ -11,16 +11,16 @@ breakers armed."""
 import time
 
 import numpy as np
-import jax
 import pytest
 
 from paddle_tpu import observability as obs
 from paddle_tpu import serving
 from paddle_tpu.serving import fleet
 from paddle_tpu.serving.fleet.faults import BREAKER_GAUGE
-from paddle_tpu.models.gpt import GPT, GPTConfig
 
-VOCAB = 64
+from serving_taps import (fleet_engine, fleet_of, tiny_gpt,
+                          warmed_engines)
+from serving_taps import random_prompts as _prompts
 
 
 class FakeClock:
@@ -36,65 +36,37 @@ class FakeClock:
 
 @pytest.fixture(scope="module")
 def model_params():
-    cfg = GPTConfig.tiny(vocab_size=VOCAB, hidden_size=16, num_layers=2,
-                         num_heads=2, ffn_size=32, max_position=64,
-                         dropout=0.0, attn_impl="xla")
-    model = GPT(cfg)
-    return model, model.init(jax.random.PRNGKey(0))
+    return tiny_gpt()
+
+
+GEOMETRY = dict(num_slots=2, decode_block=2)
 
 
 def _engine(model_params, tracer=None, **kw):
-    model, params = model_params
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("page_size", 4)
-    kw.setdefault("max_tokens_per_slot", 32)
-    kw.setdefault("prefill_chunk", 4)
-    kw.setdefault("decode_block", 2)
-    return serving.ServingEngine(model, params, attn_impl="lax",
-                                 registry=obs.MetricsRegistry(),
-                                 tracer=tracer, **kw)
+    return fleet_engine(model_params, tracer, **{**GEOMETRY, **kw})
 
 
-def _fleet(model_params, n, tracer=None, faults=None, seed=0, clock=None,
-           wrap=None, **kw):
-    """n warmed LocalReplicas behind a FleetRouter; ``wrap`` maps
-    replica index -> ChaosSpec kwargs for replicas to chaos-wrap."""
-    tracer = tracer or obs.Tracer(enabled=False)
-    reps = []
-    for i in range(n):
-        rep = fleet.LocalReplica(_engine(model_params, tracer=tracer,
-                                         **kw), name=f"r{i}").warmup()
-        if wrap and i in wrap:
-            rep = fleet.ChaosReplica(rep, **wrap[i])
-        reps.append(rep)
-    router = fleet.FleetRouter(
-        reps, registry=obs.MetricsRegistry(), tracer=tracer, seed=seed,
-        faults=faults or fleet.FaultPolicy(max_consecutive_failures=1,
-                                           probe_timeout_s=30.0),
-        **({"clock": clock} if clock else {}))
-    return router, reps
+@pytest.fixture(scope="module")
+def warmed(model_params):
+    """``get(peer=0, **options) ->`` engine number ``peer`` of these
+    options, warmed ONCE for the module and idle (``serving_taps.py``): a
+    chaos wrapper's crash, a hang and an ejection are the replica's; what
+    they leave in the engine is served out before the next case has it."""
+    return warmed_engines(model_params, **GEOMETRY)
 
 
-def _prompts(n, rng=None, lo=3, hi=9):
-    rng = rng or np.random.default_rng(0)
-    return [rng.integers(1, VOCAB, int(rng.integers(lo, hi)))
-            .astype(np.int32) for _ in range(n)]
+def _fleet(model_params, n, faults=None, **kw):
+    """``serving_taps.fleet_of`` at this file's geometry, its router quick
+    to eject (one failure) unless given other ``faults``."""
+    return fleet_of(model_params, n, engine=_engine, faults=faults or (
+        fleet.FaultPolicy(max_consecutive_failures=1, probe_timeout_s=30.0)),
+        **kw)
 
 
-_REF_ENGINE = {}
-
-
-def _reference(model_params, prompts, max_new):
-    """Failure-free reference: one clean engine, greedy decode. The
-    engine is warmed once per module and reused (generate_many leaves
-    it idle) — each warmup compiles every bucket and would otherwise
-    dominate the battery's runtime."""
-    eng = _REF_ENGINE.get(id(model_params))
-    if eng is None:
-        eng = _engine(model_params, num_slots=2)
-        eng.warmup()
-        _REF_ENGINE[id(model_params)] = eng
-    return eng.generate_many(prompts, max_new, max_steps=100_000)
+def _reference(warmed, prompts, max_new):
+    """Failure-free reference: one of the module's engines, greedy
+    decode."""
+    return warmed().generate_many(prompts, max_new, max_steps=100_000)
 
 
 def _drain_fleet(router, frids, max_steps=5000):
@@ -257,14 +229,15 @@ class TestFailureDetector:
 
 
 class TestEjectRedrive:
-    def test_crash_mid_burst_zero_lost_bit_identical(self, model_params):
+    def test_crash_mid_burst_zero_lost_bit_identical(self, model_params,
+                                                     warmed):
         """The acceptance battery: kill a replica mid-burst; nothing is
         lost and every redriven output is byte-identical to a
         failure-free run — with zero steady-state recompiles while
         detection + breakers are armed."""
         cap = 10
         prompts = _prompts(6)
-        ref = _reference(model_params, prompts, cap)
+        ref = _reference(warmed, prompts, cap)
         tracer = obs.Tracer()
         router, reps = _fleet(model_params, 3, tracer=tracer,
                               wrap={1: {}})
@@ -293,7 +266,7 @@ class TestEjectRedrive:
         names = {s.name for s in tracer.spans()}
         assert "router.eject" in names and "router.redrive" in names
 
-    def test_redrive_shares_original_trace_id(self, model_params):
+    def test_redrive_shares_original_trace_id(self, model_params, warmed):
         tracer = obs.Tracer()
         router, reps = _fleet(model_params, 2, tracer=tracer,
                               wrap={0: {}})
@@ -309,12 +282,12 @@ class TestEjectRedrive:
         assert all(s.trace_id in req_tids for s in redrives), \
             "redrive spans must ride the request's original trace"
 
-    def test_queued_requests_reroute_on_eject(self, model_params):
+    def test_queued_requests_reroute_on_eject(self, model_params, warmed):
         # more requests than the chaos replica can admit: its queue
         # must re-route (observed empty -> plain resubmit)
-        router, reps = _fleet(model_params, 2, wrap={0: {}})
+        router, reps = _fleet(model_params, 2, wrap={0: {}}, warmed=warmed)
         prompts = _prompts(8, lo=3, hi=5)
-        ref = _reference(model_params, prompts, 6)
+        ref = _reference(warmed, prompts, 6)
         frids = [router.submit(p, 6) for p in prompts]
         reps[0].dead = True                  # dies before a single step
         outs, rejects = _drain_fleet(router, frids)
@@ -323,11 +296,11 @@ class TestEjectRedrive:
             np.testing.assert_array_equal(outs[f], want)
 
     def test_redrive_budget_exhausted_sheds_structured(self,
-                                                       model_params):
+                                                       model_params, warmed):
         router, reps = _fleet(
             model_params, 2,
             faults=fleet.FaultPolicy(max_consecutive_failures=1,
-                                     max_redrives=0))
+                                     max_redrives=0), warmed=warmed)
         frids = [router.submit(p, 6) for p in _prompts(2, lo=3, hi=5)]
         router.step()
         router.eject_replica(reps[0], reason="crashed")
@@ -339,9 +312,9 @@ class TestEjectRedrive:
         assert all(router.reject_reason(f) is None for f in rejects)
 
     def test_expired_deadline_redrive_sheds_structured(self,
-                                                       model_params):
+                                                       model_params, warmed):
         clk = FakeClock()
-        router, reps = _fleet(model_params, 2, clock=clk)
+        router, reps = _fleet(model_params, 2, clock=clk, warmed=warmed)
         # a queued-only request (no token observed) with a TTFT deadline
         frid = router.submit(_prompts(1)[0], 6, ttft_deadline_s=0.5)
         rep = router._where[frid][0]
@@ -353,12 +326,12 @@ class TestEjectRedrive:
         assert reg.counter("fleet_redrive_shed_total").value(
             reason="deadline_expired") == 1
 
-    def test_engine_side_shed_surfaces_at_router(self, model_params):
+    def test_engine_side_shed_surfaces_at_router(self, model_params, warmed):
         """A replica's OWN engine shedding a queued request (TTFT
         deadline expired before admission) must surface as a fleet
         reject — result XOR reject, never silence — and clean the
         replay record."""
-        router, reps = _fleet(model_params, 1)
+        router, reps = _fleet(model_params, 1, warmed=warmed)
         # fill both slots so the probe request has to queue
         busy = [router.submit(p, 16) for p in _prompts(2, lo=3, hi=5)]
         router.step()
@@ -377,11 +350,11 @@ class TestEjectRedrive:
         outs, rejects = _drain_fleet(router, busy)
         assert not rejects and len(outs) == 2
 
-    def test_live_deadline_survives_redrive(self, model_params):
+    def test_live_deadline_survives_redrive(self, model_params, warmed):
         clk = FakeClock()
-        router, reps = _fleet(model_params, 2, clock=clk)
+        router, reps = _fleet(model_params, 2, clock=clk, warmed=warmed)
         prompts = _prompts(1)
-        ref = _reference(model_params, prompts, 6)
+        ref = _reference(warmed, prompts, 6)
         frid = router.submit(prompts[0], 6, ttft_deadline_s=60.0)
         rep = router._where[frid][0]
         router.eject_replica(rep, reason="crashed")
@@ -391,13 +364,13 @@ class TestEjectRedrive:
 
 
 class TestWarmRedrive:
-    def test_micro_checkpoint_restores_on_peer(self, model_params):
+    def test_micro_checkpoint_restores_on_peer(self, model_params, warmed):
         """With snapshot_every_blocks on, a crash redrives WARM: the
         newest checkpoint restores into a peer (bounded re-decode) and
         outputs stay byte-identical."""
         cap = 12
         prompts = _prompts(2, lo=3, hi=5)
-        ref = _reference(model_params, prompts, cap)
+        ref = _reference(warmed, prompts, cap)
         tracer = obs.Tracer()
         router, reps = _fleet(model_params, 2, tracer=tracer,
                               wrap={0: {}}, snapshot_every_blocks=1)
@@ -433,13 +406,13 @@ class TestWarmRedrive:
 
 
 class TestHangDetection:
-    def test_hung_replica_ejected_work_redriven(self, model_params):
+    def test_hung_replica_ejected_work_redriven(self, model_params, warmed):
         prompts = _prompts(4, lo=3, hi=5)
-        ref = _reference(model_params, prompts, 6)
+        ref = _reference(warmed, prompts, 6)
         router, reps = _fleet(
             model_params, 2, wrap={1: {"hang_after_step": 2}},
             faults=fleet.FaultPolicy(max_consecutive_failures=1,
-                                     probe_timeout_s=5.0))
+                                     probe_timeout_s=5.0), warmed=warmed)
         frids = [router.submit(p, 6) for p in prompts]
         outs, rejects = _drain_fleet(router, frids)
         assert not rejects
@@ -451,37 +424,37 @@ class TestHangDetection:
 
 class TestThreadDeathSurfaced:
     def test_background_loop_crash_marks_replica_failed(self,
-                                                        model_params):
+                                                        model_params, warmed):
         """Satellite regression: a raising step() in the background
         loop must not die silently — last_error recorded, failed set,
         health()/running() see it."""
-        rep = fleet.LocalReplica(_engine(model_params), name="t0")
-        rep.warmup()
-        orig_step = rep.engine.step
+        rep = fleet.LocalReplica(warmed(), name="t0")
 
         def boom():
             raise RuntimeError("kaboom in step")
 
         rep.engine.step = boom
-        rep.start(idle_sleep_s=0.001)
-        rep.submit(_prompts(1)[0], 4)
-        for _ in range(200):
-            if rep.failed:
-                break
-            time.sleep(0.01)
-        assert rep.failed and "kaboom" in rep.last_error
-        assert rep.running() is False
-        h = rep.health()
-        assert h["failed"] and "kaboom" in h["last_error"]
-        rep.stop()
-        rep.engine.step = orig_step
+        try:
+            rep.start(idle_sleep_s=0.001)
+            rep.submit(_prompts(1)[0], 4)
+            for _ in range(200):
+                if rep.failed:
+                    break
+                time.sleep(0.01)
+            assert rep.failed and "kaboom" in rep.last_error
+            assert rep.running() is False
+            h = rep.health()
+            assert h["failed"] and "kaboom" in h["last_error"]
+        finally:
+            rep.stop()
+            del rep.engine.step             # the class's own again
         with pytest.raises(RuntimeError):
             rep.start()                      # no zombie restarts
 
-    def test_router_ejects_failed_thread_replica(self, model_params):
+    def test_router_ejects_failed_thread_replica(self, model_params, warmed):
         prompts = _prompts(2, lo=3, hi=5)
-        ref = _reference(model_params, prompts, 6)
-        router, reps = _fleet(model_params, 2)
+        ref = _reference(warmed, prompts, 6)
+        router, reps = _fleet(model_params, 2, warmed=warmed)
         bad = reps[0]
         frids = [router.submit(p, 6) for p in prompts]
         # simulate what the background loop records on a step crash
@@ -498,15 +471,16 @@ class TestThreadDeathSurfaced:
 
 class TestDrainVsCrashRace:
     def test_crash_mid_drain_falls_through_to_redrive(self,
-                                                      model_params):
+                                                      model_params, warmed):
         """A replica that dies after drain_queue but before migration
         completes must not lose its in-flight requests — they fall
         through to the redrive path."""
         cap = 10
         prompts = _prompts(4)
-        ref = _reference(model_params, prompts, cap)
+        ref = _reference(warmed, prompts, cap)
         router, reps = _fleet(model_params, 2,
-                              wrap={1: {"crash_on_snapshot": True}})
+                              wrap={1: {"crash_on_snapshot": True}},
+                              warmed=warmed)
         chaos = reps[1]
         frids = [router.submit(p, cap) for p in prompts]
         for _ in range(500):
@@ -531,7 +505,7 @@ class TestDrainVsCrashRace:
 
 
 class TestBreakerThroughRouter:
-    def test_open_halfopen_closed_visible(self, model_params):
+    def test_open_halfopen_closed_visible(self, model_params, warmed):
         clk = FakeClock()
         tracer = obs.Tracer()
         router, reps = _fleet(
@@ -568,7 +542,8 @@ class TestBreakerThroughRouter:
         assert g.value(replica=name) == BREAKER_GAUGE["closed"]
 
     def test_transient_health_flap_quarantines_not_ejects(self,
-                                                          model_params):
+                                                          model_params,
+                                                          warmed):
         """A transiently flaky health endpoint must trip the breaker
         (quarantine, which also stops the probing) BEFORE the
         consecutive-failure count reaches the death verdict — the
@@ -578,7 +553,7 @@ class TestBreakerThroughRouter:
             model_params, 2, wrap={0: {"health_failures": 3}},
             faults=fleet.FaultPolicy(max_consecutive_failures=5,
                                      breaker_threshold=3,
-                                     breaker_cooldown_s=0.0))
+                                     breaker_cooldown_s=0.0), warmed=warmed)
         name = reps[0].name
         for _ in range(6):               # idle fleet: probes flake
             router.step()
@@ -592,10 +567,10 @@ class TestBreakerThroughRouter:
         assert not rejects
         assert (name, "half_open", "closed") in router.breaker_transitions
 
-    def test_disabled_policy_restores_pr9_behavior(self, model_params):
+    def test_disabled_policy_restores_pr9_behavior(self, model_params, warmed):
         router, reps = _fleet(model_params, 2,
                               faults=fleet.FaultPolicy(enabled=False),
-                              wrap={0: {"crash_on_step": 1}})
+                              wrap={0: {"crash_on_step": 1}}, warmed=warmed)
         # p2c balances, so a few submits guarantee the chaos replica
         # holds work and gets stepped (a lone request may land on the
         # healthy peer and never touch it)
@@ -714,8 +689,8 @@ class TestAutoscalerReplace:
 
 
 class TestHealthzFleetBreakers:
-    def test_degraded_503_while_breaker_open(self, model_params):
-        router, reps = _fleet(model_params, 2)
+    def test_degraded_503_while_breaker_open(self, model_params, warmed):
+        router, reps = _fleet(model_params, 2, warmed=warmed)
         monitor = fleet.FleetMonitor(router,
                                      registry=obs.MetricsRegistry())
         srv = obs.ExpositionServer(registry=monitor.reg,

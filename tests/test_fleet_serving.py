@@ -7,70 +7,35 @@ exit code."""
 import threading
 
 import numpy as np
-import jax
 import pytest
 
 from paddle_tpu import observability as obs
-from paddle_tpu import serving
 from paddle_tpu.serving import fleet
 from paddle_tpu.serving.paged_cache import prompt_prefix_digests
-from paddle_tpu.models.gpt import GPT, GPTConfig
+
+from serving_taps import tiny_gpt, traced, warmed_engines
+from serving_taps import fleet_engine as _engine, fleet_of as _fleet
 
 VOCAB = 64
 
 
 @pytest.fixture(scope="module")
 def model_params():
-    cfg = GPTConfig.tiny(vocab_size=VOCAB, hidden_size=16, num_layers=2,
-                         num_heads=2, ffn_size=32, max_position=64,
-                         dropout=0.0, attn_impl="xla")
-    model = GPT(cfg)
-    return model, model.init(jax.random.PRNGKey(0))
+    return tiny_gpt()
 
 
-def _engine(model_params, tracer=None, **kw):
-    model, params = model_params
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("page_size", 4)
-    kw.setdefault("max_tokens_per_slot", 32)
-    kw.setdefault("prefill_chunk", 4)
-    return serving.ServingEngine(model, params, attn_impl="lax",
-                                 registry=obs.MetricsRegistry(),
-                                 tracer=tracer, **kw)
-
-
-def _step_until_mid_decode(router, rep, cap, max_steps=1000):
-    """Step the fleet until ``rep`` holds a mid-decode request (some
-    tokens generated, more to go) — the deterministic drain window the
-    migration tests need regardless of decode_block/cap timing."""
-    eng = rep.engine
-    for _ in range(max_steps):
-        router.step()
-        mid = [i for i in eng.scheduler.decode_slots()
-               if 0 < len(eng.scheduler.slots[i].generated) < cap]
-        if mid:
-            return
-    raise AssertionError("no mid-decode window reached")
-
-
-def _fleet(model_params, n, tracer=None, policy="affinity", seed=0,
-           autoscaler=None, prefix_fetch=True, **kw):
-    tracer = tracer or obs.Tracer(enabled=False)
-    reps = [fleet.LocalReplica(_engine(model_params, tracer=tracer, **kw),
-                               name=f"r{i}").warmup()
-            for i in range(n)]
-    router = fleet.FleetRouter(reps, policy=policy,
-                               registry=obs.MetricsRegistry(),
-                               tracer=tracer, seed=seed,
-                               autoscaler=autoscaler,
-                               prefix_fetch=prefix_fetch)
-    return router, reps
+@pytest.fixture(scope="module")
+def warmed(model_params):
+    """``get(peer=0, **options) ->`` engine number ``peer`` of these
+    options, warmed ONCE for the module and idle (``tests/serving_taps.py``:
+    the pages earlier cases published stay mapped). A case that hands its
+    engines a tracer builds its own."""
+    return warmed_engines(model_params)
 
 
 class TestPrefixDigests:
-    def test_digests_match_published_index(self, model_params):
-        eng = _engine(model_params)
-        eng.warmup()
+    def test_digests_match_published_index(self, warmed):
+        eng = warmed()
         rng = np.random.default_rng(0)
         prompt = rng.integers(1, VOCAB, 13).astype(np.int32)
         eng.generate_many([prompt], 4, max_steps=10_000)
@@ -90,9 +55,8 @@ class TestPrefixDigests:
         b = prompt_prefix_digests(np.arange(2, 11, dtype=np.int32), 4)
         assert a and b and a[0] != b[0]
 
-    def test_published_digests_memoized_on_index_gen(self, model_params):
-        eng = _engine(model_params)
-        eng.warmup()
+    def test_published_digests_memoized_on_index_gen(self, warmed):
+        eng = warmed()
         d0 = eng.cache.published_digests()
         assert eng.cache.published_digests() is d0   # no per-call build
         rng = np.random.default_rng(2)
@@ -103,23 +67,21 @@ class TestPrefixDigests:
 
 
 class TestExternalTraceId:
-    def test_submit_adopts_router_trace_id(self, model_params):
-        tracer = obs.Tracer(capacity=256)
-        eng = _engine(model_params, tracer=tracer)
-        eng.warmup()
-        rid = eng.submit(np.arange(1, 6, dtype=np.int32), 3,
-                         trace_id=777)
-        assert eng._req_spans[rid].trace_id == 777
-        while not eng.scheduler.idle():
-            eng.step()
+    def test_submit_adopts_router_trace_id(self, warmed):
+        eng = warmed()
+        with traced(eng) as tracer:
+            rid = eng.submit(np.arange(1, 6, dtype=np.int32), 3,
+                             trace_id=777)
+            assert eng._req_spans[rid].trace_id == 777
+            while not eng.scheduler.idle():
+                eng.step()
         st = eng.request_stats(rid)
         assert st["trace_id"] == 777.0
         spans = [s for s in tracer.spans() if s.trace_id == 777]
         assert any(s.name == "serving.request" for s in spans)
 
-    def test_trace_id_carried_with_tracing_off(self, model_params):
-        eng = _engine(model_params)       # disabled default tracer
-        eng.warmup()
+    def test_trace_id_carried_with_tracing_off(self, warmed):
+        eng = warmed()                    # its tracer is off
         rid = eng.submit(np.arange(1, 6, dtype=np.int32), 3,
                          trace_id=555)
         while not eng.scheduler.idle():
@@ -128,12 +90,11 @@ class TestExternalTraceId:
 
 
 class TestConcurrentHealth:
-    def test_health_poll_during_step_loop(self, model_params):
+    def test_health_poll_during_step_loop(self, warmed):
         """Satellite regression: a router thread hammers ``health()``
         while the engine thread runs ``step()`` — snapshot reads must
         never throw or return torn values."""
-        eng = _engine(model_params)
-        eng.warmup()
+        eng = warmed()
         rng = np.random.default_rng(1)
         prompts = [rng.integers(1, VOCAB, int(n)).astype(np.int32)
                    for n in rng.integers(4, 12, 12)]
@@ -163,9 +124,8 @@ class TestConcurrentHealth:
         h = eng.health()
         assert h["requests_in_flight"] == 0 and h["queue_depth"] == 0
 
-    def test_snapshot_updates_on_submit_and_step(self, model_params):
-        eng = _engine(model_params)
-        eng.warmup()
+    def test_snapshot_updates_on_submit_and_step(self, warmed):
+        eng = warmed()
         assert eng.health()["queue_depth"] == 0
         eng.submit(np.arange(1, 6, dtype=np.int32), 2)
         assert eng.health()["queue_depth"] == 1
@@ -187,13 +147,17 @@ class TestRouting:
             "serving_prefix_shared_tokens_total").value())
             for r in router.replicas)
 
-    def _run_shared_traffic(self, model_params, policy):
+    def _run_shared_traffic(self, model_params, policy, warmed, first):
+        """-> the router and the tokens its two replicas (the module's
+        engines ``first`` and ``first + 1``, which have not met this
+        system prompt) shared in the run."""
         rng = np.random.default_rng(7)
         sysp = rng.integers(1, VOCAB, 13).astype(np.int32)
         # fleet prefix fetch would let round-robin import the pages it
         # missed — disable it to compare the ROUTING policies alone
         router, _ = _fleet(model_params, 2, policy=policy, seed=3,
-                           prefix_fetch=False)
+                           prefix_fetch=False, warmed=warmed, first=first)
+        before = self._shared_tokens(router)
         # wave 1 publishes the prefix on ONE replica
         router.submit(_shared_prefix_traffic(rng, sysp, 1)[0], 4)
         router.run_until_idle(max_steps=10_000)
@@ -201,14 +165,14 @@ class TestRouting:
         for p in _shared_prefix_traffic(rng, sysp, 8):
             router.submit(p, 4)
         router.run_until_idle(max_steps=10_000)
-        return router
+        return router, self._shared_tokens(router) - before
 
-    def test_affinity_beats_round_robin_on_shared_prefix(self,
-                                                         model_params):
-        aff = self._run_shared_traffic(model_params, "affinity")
-        rr = self._run_shared_traffic(model_params, "round_robin")
-        got_aff = self._shared_tokens(aff)
-        got_rr = self._shared_tokens(rr)
+    def test_affinity_beats_round_robin_on_shared_prefix(self, model_params,
+                                                         warmed):
+        aff, got_aff = self._run_shared_traffic(model_params, "affinity",
+                                                warmed, 0)
+        _rr, got_rr = self._run_shared_traffic(model_params, "round_robin",
+                                               warmed, 2)
         # affinity keeps every wave-2 request on the publisher: all 8
         # share the 3-page prefix; round-robin spreads them, half land
         # on the replica that never saw the prefix (until its own
@@ -216,8 +180,10 @@ class TestRouting:
         assert got_aff > got_rr, (got_aff, got_rr)
         assert aff.routed_affinity_total >= 8
 
-    def test_p2c_imbalance_bounded_random_arrivals(self, model_params):
-        router, reps = _fleet(model_params, 4, policy="p2c", seed=11)
+    def test_p2c_imbalance_bounded_random_arrivals(self, model_params,
+                                                   warmed):
+        router, reps = _fleet(model_params, 4, policy="p2c", seed=11,
+                              warmed=warmed)
         rng = np.random.default_rng(11)
         counts = {r.name: 0 for r in reps}
         for _ in range(64):
@@ -233,15 +199,17 @@ class TestRouting:
         assert vals.max() / vals.mean() <= 2.0, counts
         router.run_until_idle(max_steps=100_000)
 
-    def test_round_robin_cycles(self, model_params):
-        router, reps = _fleet(model_params, 2, policy="round_robin")
+    def test_round_robin_cycles(self, model_params, warmed):
+        router, reps = _fleet(model_params, 2, policy="round_robin",
+                              warmed=warmed)
         a = router.submit(np.arange(1, 6, dtype=np.int32), 2)
         b = router.submit(np.arange(1, 6, dtype=np.int32), 2)
         assert router._where[a][0] is not router._where[b][0]
         router.run_until_idle(max_steps=10_000)
 
-    def test_fleet_results_and_stats_by_fleet_rid(self, model_params):
-        router, _ = _fleet(model_params, 2)
+    def test_fleet_results_and_stats_by_fleet_rid(self, model_params,
+                                                  warmed):
+        router, _ = _fleet(model_params, 2, warmed=warmed)
         rng = np.random.default_rng(5)
         prompts = [rng.integers(1, VOCAB, 6).astype(np.int32)
                    for _ in range(6)]
@@ -254,245 +222,9 @@ class TestRouting:
             assert st["replica"].startswith("r")
 
 
-class TestMigration:
-    def test_drain_mid_decode_byte_identical(self, model_params):
-        """ISSUE acceptance: greedy tokens through a mid-decode drain
-        are byte-identical to an unmigrated run."""
-        rng = np.random.default_rng(9)
-        prompts = [rng.integers(1, VOCAB, int(n)).astype(np.int32)
-                   for n in (5, 9, 6, 11)]
-        ref_router, _ = _fleet(model_params, 2, seed=1,
-                               decode_block=4)
-        ref_frids = [ref_router.submit(p, 16) for p in prompts]
-        ref_router.run_until_idle(max_steps=10_000)
-        ref = [ref_router.result(f) for f in ref_frids]
-
-        router, reps = _fleet(model_params, 2, seed=1,
-                              decode_block=4)
-        frids = [router.submit(p, 16) for p in prompts]
-        _step_until_mid_decode(router, reps[1], 16)
-        migrated = router.drain_replica(reps[1])
-        assert migrated > 0
-        assert len(router.replicas) == 1
-        router.run_until_idle(max_steps=10_000)
-        got = [router.result(f) for f in frids]
-        for want, have in zip(ref, got):
-            assert have is not None
-            np.testing.assert_array_equal(want, have)
-        assert router.migrations_total == migrated
-
-    def test_excess_shard_refused_before_touching_pages(self,
-                                                        model_params):
-        """A snapshot carrying more shards than its live length
-        explains must be refused: the extra shard would index past the
-        reserved block-table entries and overwrite the null page."""
-        import hashlib
-        eng = _engine(model_params)
-        eng.warmup()
-        eng.submit(np.arange(1, 8, dtype=np.int32), 24)
-        for _ in range(2):
-            eng.step()
-        snap = eng.snapshot_slot(eng.scheduler.active_slots()[0])
-        forged = np.zeros_like(snap["shards"][0])
-        snap["shards"].append(forged)
-        snap["manifest"].append({
-            "index": len(snap["manifest"]),
-            "sha256": hashlib.sha256(forged.tobytes()).hexdigest(),
-            "bytes": forged.nbytes})        # hash-valid, count-invalid
-        target = _engine(model_params)
-        target.warmup()
-        with pytest.raises(serving.SlotMigrationError,
-                           match="inconsistent"):
-            target.restore_slot(snap)
-        assert target.scheduler.active_slots() == []
-        target.cache.check_invariants()
-
-    def test_drain_queue_closes_request_bookkeeping(self, model_params):
-        """Queued requests popped by a drain must not leak engine-side
-        spans/maps: the root span finishes as 'requeued'."""
-        tracer = obs.Tracer(capacity=256)
-        eng = _engine(model_params, tracer=tracer)
-        eng.warmup()
-        rep = fleet.LocalReplica(eng, name="dq")
-        rids = [eng.submit(np.arange(1, 6, dtype=np.int32), 4)
-                for _ in range(3)]          # queued, never stepped
-        assert len(eng._req_spans) == 3
-        popped = rep.drain_queue()
-        assert [t[0] for t in popped] == rids
-        assert eng._req_spans == {} and eng._phase_acc == {}
-        closed = [s for s in tracer.spans()
-                  if s.name == "serving.request"
-                  and s.status == "requeued"]
-        assert len(closed) == 3
-
-    def test_corrupt_shard_refused(self, model_params):
-        eng = _engine(model_params)
-        eng.warmup()
-        eng.submit(np.arange(1, 8, dtype=np.int32), 24)
-        for _ in range(2):
-            eng.step()
-        snap = eng.snapshot_slot(eng.scheduler.active_slots()[0])
-        flat = snap["shards"][0].reshape(-1).copy()
-        flat[0] += 1                       # bit-flip one value
-        snap["shards"][0] = flat.reshape(snap["shards"][0].shape)
-        target = _engine(model_params)
-        target.warmup()
-        with pytest.raises(serving.SlotMigrationError,
-                           match="sha256 mismatch"):
-            target.restore_slot(snap)
-        # target untouched: nothing reserved, no slot installed
-        assert target.scheduler.active_slots() == []
-        target.cache.check_invariants()
-
-    def test_drain_abort_restores_everything(self, model_params):
-        """No peer capacity: the drain aborts, every snapshot goes back
-        into the source, and every request still completes."""
-        router, reps = _fleet(model_params, 2, num_slots=2, seed=2,
-                              decode_block=4)
-        rng = np.random.default_rng(3)
-        # saturate BOTH replicas' slots so nothing can migrate
-        frids = [router.submit(rng.integers(1, VOCAB, 5).astype(np.int32),
-                               16) for _ in range(4)]
-        _step_until_mid_decode(router, reps[1], 16)
-        with pytest.raises(serving.SlotMigrationError, match="aborted"):
-            router.drain_replica(reps[1])
-        assert len(router.replicas) == 2
-        assert not reps[1].draining
-        out = router.run_until_idle(max_steps=10_000)
-        assert set(out) == set(frids)
-
-    def test_migration_trace_continuity(self, model_params):
-        tracer = obs.Tracer(capacity=2048)
-        router, reps = _fleet(model_params, 2, tracer=tracer, seed=4,
-                              decode_block=4)
-        rng = np.random.default_rng(4)
-        frids = [router.submit(rng.integers(1, VOCAB, 6).astype(np.int32),
-                               16) for _ in range(4)]
-        _step_until_mid_decode(router, reps[1], 16)
-        router.drain_replica(reps[1])
-        router.run_until_idle(max_steps=10_000)
-        spans = tracer.spans()
-        req_tids = {s.trace_id for s in spans
-                    if s.name == "serving.request"}
-        route_tids = {s.trace_id for s in spans
-                      if s.name == "router.route"}
-        mig = [s for s in spans if s.name == "router.migrate"]
-        assert mig, "no migrate spans"
-        for s in mig:
-            # the migrate span AND the restored request continuation
-            # live on the original router-minted trace
-            assert s.trace_id in req_tids
-            assert s.trace_id in route_tids
-            assert s.attrs["src"] == "r1"
-            assert s.attrs["dst"] == "r0"
-        migrated_in = [s for s in spans if s.name == "serving.request"
-                       and s.attrs.get("migrated")]
-        assert migrated_in
-        for s in migrated_in:
-            assert s.trace_id in route_tids
-
-    def test_migrated_stats_and_counters(self, model_params):
-        router, reps = _fleet(model_params, 2, seed=6, decode_block=4)
-        rng = np.random.default_rng(6)
-        frids = [router.submit(rng.integers(1, VOCAB, 6).astype(np.int32),
-                               16) for _ in range(4)]
-        _step_until_mid_decode(router, reps[1], 16)
-        n = router.drain_replica(reps[1])
-        assert reps[0].engine.migrated_in_total == n
-        assert reps[1].engine.migrated_out_total == n
-        router.run_until_idle(max_steps=10_000)
-        for f in frids:
-            assert router.result(f) is not None
-
-
-class _QueueFake(fleet.ReplicaHandle):
-    """Interface-level fake: accepts (or sheds) submissions, hands its
-    queue back on drain — lets the requeue paths be tested without
-    engines."""
-
-    def __init__(self, name, shed=False):
-        self.name = name
-        self.shed = shed
-        self.accepted = []
-        self._rids = iter(range(1, 1000))
-
-    def page_size(self):
-        return 4
-
-    def prefix_digests(self):
-        return frozenset()
-
-    def health(self):
-        return {"queue_depth": len(self.accepted),
-                "requests_in_flight": 0, "slot_occupancy": 0.0,
-                "page_utilization": 0.0, "free_slots": 4}
-
-    def idle(self):
-        return True
-
-    def step(self):
-        return {}
-
-    def warmup(self):
-        return self
-
-    def submit(self, prompt, max_new_tokens, eos_id=None, *,
-               lane="default", ttft_deadline_s=None, trace_id=None):
-        if self.shed:
-            from paddle_tpu.serving.scheduler import Reject
-            raise serving.LoadShedError(
-                Reject("queue_full", lane, 99, 1.0, 0.1))
-        rid = next(self._rids)
-        self.accepted.append((rid, prompt, max_new_tokens, eos_id,
-                              lane, ttft_deadline_s))
-        return rid
-
-    def drain_queue(self):
-        out, self.accepted = self.accepted, []
-        return out
-
-    def snapshot_inflight(self):
-        return []
-
-    def close(self):
-        pass
-
-
-class TestDrainRequeue:
-    def test_requeue_retries_every_peer_before_shedding(self):
-        victim = _QueueFake("victim")
-        shedder = _QueueFake("shedder", shed=True)
-        acceptor = _QueueFake("acceptor")
-        # round_robin puts the first submit on the victim; the shedder
-        # (load 0) is the first re-route target, the acceptor must
-        # still get the request
-        router = fleet.FleetRouter([victim, shedder, acceptor],
-                                   policy="round_robin",
-                                   registry=obs.MetricsRegistry())
-        frid = router.submit(np.arange(1, 6, dtype=np.int32), 4)
-        assert router._where[frid][0] is victim
-        router._rr = 0      # pin the re-route's first pick to the shedder
-        router.drain_replica(victim)
-        assert len(acceptor.accepted) == 1, "retry never reached peer"
-        assert router._where[frid][0] is acceptor
-
-    def test_requeue_shed_everywhere_cleans_fleet_maps(self):
-        victim = _QueueFake("victim")
-        s1 = _QueueFake("s1", shed=True)
-        s2 = _QueueFake("s2", shed=True)
-        router = fleet.FleetRouter([victim, s1, s2],
-                                   policy="round_robin",
-                                   registry=obs.MetricsRegistry())
-        frid = router.submit(np.arange(1, 6, dtype=np.int32), 4)
-        router.drain_replica(victim)
-        assert frid not in router._where, "stale mapping leaked"
-        assert frid not in router._trace
-
-
 class TestThreadedReplica:
-    def test_background_loop_serves_and_health_polls(self, model_params):
-        rep = fleet.LocalReplica(_engine(model_params), name="bg")
-        rep.warmup()
+    def test_background_loop_serves_and_health_polls(self, warmed):
+        rep = fleet.LocalReplica(warmed(), name="bg")
         rep.start()
         try:
             rng = np.random.default_rng(8)
@@ -649,7 +381,7 @@ class TestAutoscaler:
         assert aborted, "drain abort never recorded"
         assert len(router.replicas) == 2
 
-    def test_real_fleet_idle_scale_in_migrates(self, model_params):
+    def test_real_fleet_idle_scale_in_migrates(self, model_params, warmed):
         """Integration: a real 2-replica fleet with in-flight work on
         the drain victim — scale-in live-migrates, requests finish."""
         model, params = model_params
@@ -663,7 +395,8 @@ class TestAutoscaler:
                                   cooldown_s=0.0,
                                   registry=obs.MetricsRegistry(),
                                   clock=lambda: clock[0])
-        router, reps = _fleet(model_params, 2, seed=12, autoscaler=a)
+        router, reps = _fleet(model_params, 2, seed=12, autoscaler=a,
+                              warmed=warmed)
         rng = np.random.default_rng(12)
         frids = [router.submit(rng.integers(1, VOCAB, 5).astype(np.int32),
                                12) for _ in range(2)]
@@ -815,6 +548,8 @@ class TestFleetMonitorAndFacade:
             assert rep.engine.warmed_signatures  # facade pre-warmed
 
     def test_fleet_zero_steady_state_recompiles(self, model_params):
+        # (replicas of the case's own, new from warm-up: on the module's,
+        # a bucket that warm-up missed was compiled by an earlier case)
         router, _ = _fleet(model_params, 2, seed=15)
         det = obs.RecompileDetector("fleet_test", warmup=0,
                                     registry=obs.MetricsRegistry())
@@ -829,13 +564,13 @@ class TestFleetMonitorAndFacade:
 
 
 class TestWarmupCoverageWithMigration:
-    def test_page_io_in_plan_and_reachable(self, model_params):
-        eng = _engine(model_params)
+    def test_page_io_in_plan_and_reachable(self, warmed):
+        eng = warmed()
         plan = set(eng.warmup_plan())
         assert ("page_read",) in plan and ("page_write",) in plan
         assert set(eng.reachable_signatures()) == plan
 
-    def test_bucket_coverage_still_clean(self, model_params):
+    def test_bucket_coverage_still_clean(self, warmed):
         from paddle_tpu import analysis
-        eng = _engine(model_params)
+        eng = warmed()
         assert analysis.serving_bucket_coverage(eng) == []

@@ -9,7 +9,6 @@ contrib/slim light_nas sa_controller.
 import os
 import stat
 
-import numpy as np
 import pytest
 
 from paddle_tpu import slim

@@ -6,7 +6,6 @@ crash / scale-in / corruption — never a lost or wrong request), and
 the stale-affinity generation fix."""
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 import pytest
 
@@ -21,18 +20,14 @@ from paddle_tpu.serving.fleet.faults import (ChaosReplica, ChaosSpec,
 from paddle_tpu.serving.paged_cache import (HostPagePool, SpilledPage,
                                             payload_digest,
                                             prompt_prefix_digests)
-from paddle_tpu.models.gpt import GPT, GPTConfig
 
 VOCAB = 64
 
 
 @pytest.fixture(scope="module")
 def model_params():
-    cfg = GPTConfig.tiny(vocab_size=VOCAB, hidden_size=16, num_layers=2,
-                         num_heads=2, ffn_size=32, max_position=64,
-                         dropout=0.0, attn_impl="xla")
-    model = GPT(cfg)
-    return model, model.init(jax.random.PRNGKey(0))
+    from serving_taps import tiny_gpt
+    return tiny_gpt()
 
 
 def _engine(model_params, **kw):

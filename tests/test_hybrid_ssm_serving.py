@@ -9,8 +9,8 @@ against the token-by-token recurrence, the paged kernels' page folds) and
 nothing else.
 """
 
+import functools
 import hashlib
-import json
 import os
 import sys
 
@@ -25,14 +25,21 @@ from paddle_tpu.models.hybrid_ssm_lm import HybridSSMLM, HybridSSMLMConfig
 from paddle_tpu.ops import ssm_scan
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import hybrid_ssm_reference as ref  # noqa: E402
+from serving_taps import (assert_close, assert_refused,  # noqa: E402
+                          benchmark_config, FEATURE_OPTIONS,
+                          moved, reference_rows, serve_alone,
+                          serve_into_a_used_slot_and_alone,
+                          serve_staggered_watching_state_rows,
+                          shared_engines, tapped_engine, traced)
+from serving_taps import prompt as _prompt  # noqa: E402
 
 #: float32 on both sides, sums in another order. With the published
 #: multipliers (``lm_head_multiplier`` 1/128 on weights of std 0.02) the
 #: logits are of magnitude 5e-3, so the bound is 2e-5 OF THE LARGEST
 #: LOGIT: sound runs read 2e-7 of it, a bfloat16 state pool 1e-4
 LOGIT_RTOL = 2e-5
+_assert_close = functools.partial(assert_close, rtol=LOGIT_RTOL)
 
 PAGE, CHUNK = 4, 8
 #: decay per token exp(-A dt) with A dt in 0.002..0.14: a head remembers
@@ -51,94 +58,29 @@ def model_and_params():
     return model, model.init(jax.random.PRNGKey(5))
 
 
-class _Tap:
-    """A serving program whose ``head`` also hands every call's logits
-    to the host, in order."""
-
-    def __init__(self, program, sink):
-        self._p, self._sink = program, sink
-        self.spec = program.spec
-        for name in ("embed", "attn_in", "attn_out", "ffn", "mixer",
-                     "param_dtype"):
-            setattr(self, name, getattr(program, name))
-
-    def head(self, params, x):
-        logits = self._p.head(params, x)
-        jax.debug.callback(lambda a: self._sink.append(np.asarray(a)),
-                           logits, ordered=True)
-        return logits
-
-
 def _engine(params, impl="lax", slots=2, state_dtype="float32", **kw):
     model = HybridSSMLM(HybridSSMLMConfig.tiny(
         kernel_impl=impl, state_dtype=state_dtype, **TIME_SCALES))
-    reg = obs.MetricsRegistry()
-    kw.setdefault("decode_block", 2)
-    eng = inference.make_serving_engine(
-        model, params, num_slots=slots, page_size=PAGE, prefill_chunk=CHUNK,
-        max_tokens_per_slot=96, attn_impl=impl, registry=reg, **kw)
-    sink = []
-    eng.program = _Tap(eng.program, sink)
-    return eng, sink, reg
+    return tapped_engine(model, params, num_slots=slots, page_size=PAGE,
+                         prefill_chunk=CHUNK, attn_impl=impl, **kw)
 
 
 @pytest.fixture(scope="module")
 def engines(model_and_params):
-    """``impl -> (engine, its head calls' logits, registry)``: ONE engine
-    an ``impl`` for the cases that serve a request alone at this geometry
-    (a case then compiles only the widths no earlier one met); a slot's
-    state holds what its last request left, and the step that starts a
-    prompt starts from zeros, as in a serving process."""
-    built = {}
-
-    def get(impl):
-        if impl not in built:
-            built[impl] = _engine(model_and_params[1], impl)
-        return built[impl]
-    return get
+    """``get(impl) -> (engine, its head calls' logits, registry)``, one
+    engine an ``impl`` for the module (``tests/serving_taps.py``). The
+    ``lax`` one has the four slots and the budget (a chunk a step beside
+    the decoding) of the staggered case; the others take it as it is."""
+    return shared_engines(lambda impl: _engine(
+        model_and_params[1], impl,
+        **(dict(slots=4, prefill_budget=3 * CHUNK) if impl == "lax" else {})))
 
 
-def _serve(eng, sink, prompt, n_new):
-    """One request alone in the engine: its tokens and the logits of
-    positions ``len(prompt) - 1 .. len(prompt) + n_new - 2``."""
-    del sink[:]
-    rid = eng.submit(prompt, n_new)
-    slot = None
-    while not eng.scheduler.idle():
-        eng.step()
-        for i in eng.scheduler.active_slots():
-            slot = i
-    jax.effects_barrier()
-    out = eng.result(rid)
-    # prefill calls hand (lanes, V): the lone request is lane 0, and the
-    # call that finished the prompt is the last of them; decode token
-    # steps hand (slots, V)
-    s_tot = eng.scheduler.num_slots
-    calls = list(sink)
-    last_prefill = max(i for i, a in enumerate(calls)
-                       if a.shape[0] != s_tot or i == 0)
-    logits = [calls[last_prefill][0]]
-    logits += [a[slot if slot is not None else 0]
-               for a in calls[last_prefill + 1:]]
-    return out, np.stack(logits[:n_new])
-
-
-def _prompt(n, seed=None):
-    return np.random.default_rng(n if seed is None else seed).integers(
-        0, 96, n).astype(np.int32)
+_rows = reference_rows(ref.reference_logits)
 
 
 def _reference_rows(model, params, prompt, out):
-    ids = jnp.asarray(np.concatenate([prompt, out]))
-    with jax.default_matmul_precision("highest"):
-        logits = np.asarray(ref.reference_logits(params, ids, model.cfg))
-    n0 = len(prompt)
-    return logits[n0 - 1:n0 - 1 + len(out)]
-
-
-def _assert_close(got, want):
-    np.testing.assert_allclose(got, want, rtol=0,
-                               atol=LOGIT_RTOL * np.abs(want).max())
+    return _rows(params, prompt, out, model.cfg)
 
 
 CASES = {
@@ -162,7 +104,7 @@ def test_prefill_then_decode_logits_match_the_reference(
     n0, n_new = CASES[case]
     prompt = _prompt(n0)
     eng, sink, _ = engines(impl)
-    out, got = _serve(eng, sink, prompt, n_new)
+    out, got = serve_alone(eng, sink, prompt, n_new)
     want = _reference_rows(model, params, prompt, out)
     _assert_close(got, want)
     assert (want.argmax(-1) == out).all()
@@ -185,7 +127,7 @@ def test_a_bfloat16_state_fails_the_same_comparison(model_and_params):
     prompt = _prompt(21)
     eng, sink, _ = _engine(params, state_dtype="bfloat16")
     assert eng.cache.pages[0][-1].dtype == jnp.bfloat16
-    out, got = _serve(eng, sink, prompt, 7)
+    out, got = serve_alone(eng, sink, prompt, 7)
     want = _reference_rows(model, params, prompt, out)
     with pytest.raises(AssertionError):
         _assert_close(got, want)
@@ -272,78 +214,27 @@ def test_decode_update_is_one_step_and_leaves_dead_slots_alone(impl):
 
 # -- continuous batching --------------------------------------------------------
 
-def test_a_reused_slot_gives_what_the_request_gives_alone(model_and_params):
+def test_a_reused_slot_gives_what_the_request_gives_alone(model_and_params,
+                                                          engines):
     """Two requests one after the other in slot 0: the second starts from
     zeros, not from what the first left in the slot's row."""
     model, params = model_and_params
-    first, second = _prompt(19), _prompt(13, seed=77)
-    eng, sink, reg = _engine(params)
-    _serve(eng, sink, first, 5)
-    assert eng.cache.pages[0][-1][1].any()      # what the first left
-    out, got = _serve(eng, sink, second, 6)
-    alone, sink2, _ = _engine(params)
-    out2, got2 = _serve(alone, sink2, second, 6)
-    assert (out == out2).all() and (got == got2).all()
+    second = _prompt(13, seed=77)
+    out, got = serve_into_a_used_slot_and_alone(*engines("lax"), _prompt(19),
+                                                second)
     _assert_close(got, _reference_rows(model, params, second, out))
-    assert reg.snapshot()["serving_ssm_state_resets_total"] == 2
 
 
-def _state_rows(pages):
-    """Every layer's slot-state arrays on the host: {(layer, entry):
-    array}."""
-    return {(i, k): np.asarray(a) for i, ent in enumerate(pages)
-            for k, a in enumerate(ent[2:])}
-
-
-def test_a_step_touches_only_the_rows_of_its_own_lanes(model_and_params):
-    """Four slots under staggered traffic, prompts prefilled a chunk a
-    step while others decode: every decode block leaves the rows of
-    slots it does not decode (free, or in mid-prefill and owning live
-    state) bit for bit, every prefill call the rows of slots outside its
-    lanes, pad lanes included; and each request still reads the
-    reference's logits' argmax."""
+def test_a_step_touches_only_the_rows_of_its_own_lanes(model_and_params,
+                                                        engines):
+    """Four slots under staggered traffic: every step leaves the rows of
+    slots outside its lanes bit for bit (``serving_taps.
+    serve_staggered_watching_state_rows``), and each request still reads
+    the reference's logits' argmax."""
     model, params = model_and_params
-    eng, _sink, _ = _engine(params, slots=4, prefill_budget=3 * CHUNK)
-    seen = {"decode_kept": 0, "prefill_kept": 0, "pad_lanes": 0,
-            "mid_prefill_during_decode": 0}
-
-    def watch(step, rows_of, kind):
-        def run(params_, pages, *args):
-            before = _state_rows(pages)
-            touched = set(rows_of(*args)) | {0}
-            out, new_pages = step(params_, pages, *args)
-            for key, was in before.items():
-                now = np.asarray(new_pages[key[0]][2 + key[1]])
-                for r in range(was.shape[0]):
-                    if r not in touched:
-                        assert (now[r] == was[r]).all(), (kind, key, r)
-                        seen[f"{kind}_kept"] += 1
-            return out, new_pages
-        return run
-
-    def decode_rows(_bt, _lengths, _tokens, active):
-        live = np.nonzero(np.asarray(active))[0]
-        busy = set(eng.scheduler.active_slots()) - set(live.tolist())
-        seen["mid_prefill_during_decode"] += len(busy)
-        return (live + 1).tolist()
-
-    def prefill_rows(bt, _starts, _tokens, n_valid):
-        rows = np.asarray(bt)[:, -1]
-        seen["pad_lanes"] += int((np.asarray(n_valid) == 0).sum())
-        assert (rows[np.asarray(n_valid) == 0] == 0).all()
-        return rows.tolist()
-
-    eng.decode_step = watch(eng.decode_step, decode_rows, "decode")
-    eng.prefill_step = watch(eng.prefill_step, prefill_rows, "prefill")
+    eng, _sink, _ = engines("lax")
     prompts = [_prompt(n, seed=n) for n in (9, 30, 21, 27, 14)]
-    rids = [eng.submit(p, 6) for p in prompts[:3]]
-    for _ in range(3):
-        eng.step()
-    rids += [eng.submit(p, 6) for p in prompts[3:]]
-    while not eng.scheduler.idle():
-        eng.step()
-    assert seen["decode_kept"] and seen["prefill_kept"]
-    assert seen["pad_lanes"] and seen["mid_prefill_during_decode"]
+    rids = serve_staggered_watching_state_rows(eng, prompts)
     for rid, prompt in zip(rids, prompts):
         out = eng.result(rid)
         want = _reference_rows(model, params, prompt, out)
@@ -364,20 +255,10 @@ def _hybrid():
     return model, model.init(jax.random.PRNGKey(5))
 
 
-OPTIONS = {
-    "tp": dict(tp=2),
-    "int8_pages": dict(cache_dtype=jnp.int8),
-    "draft": "draft",
-    "host_spill": dict(host_spill_pages=4),
-    "migration": dict(snapshot_every_blocks=2),
-    "tiers": dict(tier="prefill"),
-    "prefix_sharing": dict(prefix_sharing=True),
-    "prefix_export": "call",
-}
 #: what each program that refuses leaves out of ``supports``
-REFUSES = {"SparseMoELM": (_sparse_moe, sorted(set(OPTIONS)
+REFUSES = {"SparseMoELM": (_sparse_moe, sorted(set(FEATURE_OPTIONS)
                                                - {"prefix_sharing"})),
-           "HybridSSMLM": (_hybrid, sorted(OPTIONS))}
+           "HybridSSMLM": (_hybrid, sorted(FEATURE_OPTIONS))}
 
 
 @pytest.mark.parametrize("family, feature", [
@@ -386,26 +267,12 @@ REFUSES = {"SparseMoELM": (_sparse_moe, sorted(set(OPTIONS)
 def test_engine_refuses_an_option_by_class_and_feature(family, feature):
     """One sentence for every option and call a program does not carry:
     the model's class and the feature by name."""
-    model, params = REFUSES[family][0]()
-    kw = OPTIONS[feature]
-    said = rf"{family} does not serve with '{feature}' yet"
-    base = dict(num_slots=2, page_size=4, attn_impl="lax")
-    if kw == "call":
-        eng = inference.make_serving_engine(model, params, **base)
-        for call, arg in ((eng.export_prefix_pages, [1]),
-                          (eng.import_prefix_pages, {})):
-            with pytest.raises(ValueError, match=said):
-                call(arg)
-        return
-    if kw == "draft":
-        kw = dict(draft_model=model, draft_params=params)
-    with pytest.raises(ValueError, match=said):
-        inference.make_serving_engine(model, params, **base, **kw)
+    assert_refused(*REFUSES[family][0](), feature,
+                   rf"{family} does not serve with '{feature}' yet")
 
 
-def test_hybrid_engine_is_built_with_sharing_off(model_and_params):
-    _, params = model_and_params
-    eng, _, _ = _engine(params)
+def test_hybrid_engine_is_built_with_sharing_off(engines):
+    eng, _, _ = engines("lax")
     assert eng.cache.config.share_prefix is False
     assert eng.program.spec.supports == frozenset()
     assert ("page_read",) not in eng.warmup_plan()
@@ -417,14 +284,14 @@ def test_hybrid_engine_is_built_with_sharing_off(model_and_params):
 
 # -- counters and the step programs ---------------------------------------------
 
-def test_counters_are_what_the_traffic_implies(model_and_params):
+def test_counters_are_what_the_traffic_implies(engines):
     """A prompt of 21 tokens (3 chunks), 9 new tokens at 2 a block (the
-    first from prefill, then 4 blocks), 2 layers, one slot live."""
-    _, params = model_and_params
-    tracer = obs.tracing.Tracer(enabled=True)
-    eng, _sink, reg = _engine(params, tracer=tracer)
-    eng.generate_many([_prompt(21)], max_new_tokens=9)
-    snap = reg.snapshot()
+    first from prefill, then 4 blocks), 2 layers, one slot live of 4."""
+    eng, _sink, reg = engines("lax")
+    before = reg.snapshot()
+    with traced(eng) as tracer:
+        eng.generate_many([_prompt(21)], max_new_tokens=9)
+    snap = moved(reg, before)
     layers, blocks, block = 2, 4, 2
     slot_bytes = eng.cache.state_bytes_per_slot()
     assert slot_bytes == layers * 4 * (3 * (64 + 2 * 2 * 16) + 4 * 16 * 16)
@@ -436,7 +303,7 @@ def test_counters_are_what_the_traffic_implies(model_and_params):
         == slot_bytes * (blocks * block + 3)
     assert snap['serving_ssm_state_bytes_total{kind="read"}'] \
         == slot_bytes * (blocks * block + 3 - 1)
-    assert snap["serving_ssm_state_pool_bytes"] == slot_bytes * 3
+    assert reg.snapshot()["serving_ssm_state_pool_bytes"] == slot_bytes * 5
     # one read-back a block, by the step after the one that sent it, none
     # in a prefill call
     assert snap['serving_device_readbacks_total{phase="decode"}'] \
@@ -517,19 +384,4 @@ def test_benchmark_reference_is_the_plain_reference(model_and_params,
 
 
 def test_benchmark_configuration_holds_the_published_keys_twice():
-    """``configs/falcon_h1_34b.json`` carries the catalog's numbers at its
-    top level (where the driver compares them) and under ``sizes`` (where
-    the runner reads them): the same, but for the cut depth; and the
-    program's defaults are those numbers."""
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "falcon_h1_34b.json")) as f:
-        cfg = json.load(f)
-    for key, value in cfg["sizes"].items():
-        assert cfg[key] == value, key
-    assert cfg["reduced"] == ["num_hidden_layers"]
-    assert cfg["num_hidden_layers"] == 6
-    default = HybridSSMLMConfig()
-    for key, value in cfg["sizes"].items():
-        if hasattr(default, key) and key != "num_hidden_layers":
-            got = getattr(default, key)
-            assert (list(got) if isinstance(got, tuple) else got) == value, key
+    benchmark_config("falcon_h1_34b", 6, HybridSSMLMConfig())

@@ -7,10 +7,8 @@ subprocess-resident table; RemoteKVStore is a drop-in HostKVStore, so the
 whole DeepFM sparse pipeline trains against the remote pserver unchanged.
 """
 
-import os
 import subprocess
 import sys
-import time
 
 import jax
 import jax.numpy as jnp

@@ -15,6 +15,7 @@ time.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -23,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu import inference, kernels
+from paddle_tpu import kernels
 from paddle_tpu import observability as obs
 from paddle_tpu.models.mla_moe_lm import MLAMoELM, MLAMoELMConfig
 from paddle_tpu.serving import decode_attention as DA
@@ -32,14 +33,18 @@ from paddle_tpu.serving.paged_cache import PagedCacheConfig, PagedKVCache
 from paddle_tpu.serving.program import FEATURES, ServingSpec
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import mla_moe_reference as ref  # noqa: E402
+from serving_taps import (assert_close, assert_refused,  # noqa: E402
+                          FEATURE_OPTIONS, serve_alone,
+                          shared_engines, tapped_engine)
+from serving_taps import prompt as _prompt  # noqa: E402
 
 #: float32 on both sides, the absorbed sums in another order than the
 #: expanded ones: 2e-5 OF THE LARGEST LOGIT. Sound runs read under 3e-6 of
 #: it; ``a_t`` left at 1, an unrotated key or a float8 row each read over
 #: 1e-3 (the controls below)
 LOGIT_RTOL = 2e-5
+_assert_close = functools.partial(assert_close, rtol=LOGIT_RTOL)
 
 PAGE, CHUNK, ROW, LAYERS = 8, 8, 24, 2
 
@@ -79,71 +84,20 @@ def model_and_params():
     return model, _params(model)
 
 
-class _Tap:
-    """A serving program whose ``head`` also hands every call's logits to
-    the host, in order."""
-
-    def __init__(self, program, sink):
-        self._p, self._sink = program, sink
-        self.spec = program.spec
-        for name in ("embed", "attn_in", "attn_out", "ffn", "param_dtype"):
-            setattr(self, name, getattr(program, name))
-
-    def head(self, params, x):
-        logits = self._p.head(params, x)
-        jax.debug.callback(lambda a: self._sink.append(np.asarray(a)),
-                           logits, ordered=True)
-        return logits
+def _engine(params, impl="lax"):
+    eng, sink, reg = tapped_engine(
+        MLAMoELM(MLAMoELMConfig.tiny(kernel_impl=impl)), params, num_slots=2,
+        page_size=PAGE, prefill_chunk=CHUNK, attn_impl=impl,
+        tracer=obs.Tracer(enabled=True))
+    return eng, sink, reg, eng.tracer
 
 
 @pytest.fixture(scope="module")
 def engines(model_and_params):
-    """``impl -> (engine, the logits its head calls made, registry,
-    tracer)``: built at first use, kept for the module."""
-    _, params = model_and_params
-    built = {}
-
-    def get(impl):
-        if impl not in built:
-            model = MLAMoELM(MLAMoELMConfig.tiny(kernel_impl=impl))
-            reg, tracer = obs.MetricsRegistry(), obs.Tracer(enabled=True)
-            eng = inference.make_serving_engine(
-                model, params, num_slots=2, page_size=PAGE,
-                max_tokens_per_slot=96, prefill_chunk=CHUNK, decode_block=2,
-                attn_impl=impl, registry=reg, tracer=tracer)
-            sink = []
-            eng.program = _Tap(eng.program, sink)
-            built[impl] = (eng, sink, reg, tracer)
-        return built[impl]
-    return get
-
-
-def _serve(eng, sink, prompt, n_new):
-    """One request alone in the engine: its tokens and the logits of
-    positions ``len(prompt) - 1 .. len(prompt) + n_new - 2``."""
-    del sink[:]
-    rid = eng.submit(prompt, n_new)
-    slot = None
-    while not eng.scheduler.idle():
-        eng.step()
-        eng.cache.check_invariants()
-        for i in eng.scheduler.active_slots():
-            slot = i
-    jax.effects_barrier()
-    out = eng.result(rid)
-    s_tot = eng.scheduler.num_slots
-    calls = list(sink)
-    last_prefill = max(i for i, a in enumerate(calls)
-                       if a.shape[0] != s_tot or i == 0)
-    logits = [calls[last_prefill][0]]
-    logits += [a[slot if slot is not None else 0]
-               for a in calls[last_prefill + 1:]]
-    return out, np.stack(logits[:n_new])
-
-
-def _prompt(n, seed=None):
-    return np.random.default_rng(n if seed is None else seed).integers(
-        0, 96, n).astype(np.int32)
+    """``get(impl) -> (engine, its head calls' logits, registry,
+    tracer)``, one engine an ``impl`` for the module: what a case may
+    assume of it is in ``tests/serving_taps.py``."""
+    return shared_engines(lambda impl: _engine(model_and_params[1], impl))
 
 
 _REFERENCE = {}
@@ -171,11 +125,6 @@ def _reference_rows(model, params, prompt, out, **controls):
     return logits[n0 - 1:n0 - 1 + len(out)]
 
 
-def _assert_close(got, want):
-    np.testing.assert_allclose(got, want, rtol=0,
-                               atol=LOGIT_RTOL * np.abs(want).max())
-
-
 CASES = {
     # one chunk, decode crosses the first page edge
     "one_chunk": (5, 6),
@@ -198,7 +147,7 @@ def test_prefill_then_decode_through_the_cache_gives_the_references_logits(
     n_prompt, n_new = CASES[case]
     eng, sink = engines(impl)[:2]
     prompt = _prompt(n_prompt)
-    out, logits = _serve(eng, sink, prompt, n_new)
+    out, logits = serve_alone(eng, sink, prompt, n_new)
     assert len(out) == n_new
     _assert_close(logits, _reference_rows(model, params, prompt, out))
 
@@ -209,7 +158,7 @@ def served(model_and_params, engines):
     controls: (prompt, tokens, logits)."""
     eng, sink = engines("lax")[:2]
     prompt = _prompt(27, seed=77)
-    return (prompt,) + _serve(eng, sink, prompt, 11)
+    return (prompt,) + serve_alone(eng, sink, prompt, 11)
 
 
 @pytest.mark.parametrize("control", [
@@ -467,7 +416,7 @@ def test_a_borrower_of_published_pages_reads_what_a_fresh_prefill_writes(
                            (np.concatenate([first[:18], _prompt(7, 502)]),
                             18)):
         before = eng.cache.shared_tokens_total
-        out, logits = _serve(eng, sink, prompt, 6)
+        out, logits = serve_alone(eng, sink, prompt, 6)
         assert eng.cache.shared_tokens_total - before == shared
         _assert_close(logits, _reference_rows(model, params, prompt, out))
     assert eng.cache.shared_tokens_total - shared0 == 39
@@ -510,23 +459,15 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(impl):
 
 # -- the engine -----------------------------------------------------------------
 
-@pytest.mark.parametrize("feature, option", [
-    ("tp", dict(tp=2)), ("int8_pages", dict(cache_dtype=jnp.int8)),
-    ("draft", dict(draft_model="self")),
-    ("host_spill", dict(host_spill_pages=4)),
-    ("migration", dict(snapshot_every_blocks=2)),
-    ("tiers", dict(tier="prefill"))])
+@pytest.mark.parametrize("feature", sorted(
+    set(FEATURE_OPTIONS) - {"prefix_sharing", "prefix_export"}))
 def test_every_option_the_program_does_not_carry_is_refused_by_name(
-        feature, option, model_and_params):
+        feature, model_and_params):
     model, params = model_and_params
     assert feature in FEATURES
     assert model.serving().spec.supports == {"prefix_sharing"}
-    if option.get("draft_model") == "self":
-        option = dict(draft_model=model, draft_params=params)
-    with pytest.raises(ValueError, match=f"MLAMoELM.*{feature!r}"):
-        inference.make_serving_engine(model, params, num_slots=2,
-                                      page_size=PAGE, prefill_chunk=CHUNK,
-                                      **option)
+    assert_refused(model, params, feature, f"MLAMoELM.*{feature!r}",
+                   page_size=PAGE, prefill_chunk=CHUNK, attn_impl="auto")
 
 
 def test_pages_never_leave_the_engine(engines):

@@ -39,12 +39,10 @@ import threading
 import time
 
 import numpy as np
-import jax
 import pytest
 
 from paddle_tpu import observability as obs
 from paddle_tpu import serving
-from paddle_tpu.models.gpt import GPT, GPTConfig
 from paddle_tpu.resilience.preempt import EXIT_DRAINED
 from paddle_tpu.resilience.retry import RetryPolicy
 from paddle_tpu.serving import fleet
@@ -81,11 +79,8 @@ class FakeClock:
 
 @pytest.fixture(scope="module")
 def model_params():
-    cfg = GPTConfig.tiny(vocab_size=VOCAB, hidden_size=16, num_layers=2,
-                         num_heads=2, ffn_size=32, max_position=64,
-                         dropout=0.0, attn_impl="xla")
-    model = GPT(cfg)
-    return model, model.init(jax.random.PRNGKey(0))
+    from serving_taps import tiny_gpt
+    return tiny_gpt()
 
 
 def _engine(model_params, **kw):
@@ -776,15 +771,8 @@ class TestNetlogValidator:
         with pytest.raises(ValueError, match="required >= 2"):
             net.validate_netlog_file(p, require_requests=2)
 
-    def test_check_metrics_log_cli(self, tmp_path, capsys):
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "check_metrics_log_for_test",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "tools",
-                "check_metrics_log.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+    def test_check_metrics_log_cli(self, script, tmp_path, capsys):
+        mod = script("check_metrics_log", "tools")
         good = _write_log(tmp_path, self._good())
         assert mod.main([good, "--netlog", "--require-requests", "1"]) == 0
         assert mod.main([good, "--netlog", "--require-requests", "9"]) == 1

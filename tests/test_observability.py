@@ -387,17 +387,10 @@ class TestTrainerTelemetry:
 
 
 class TestBenchTelemetry:
-    def test_write_and_check_cli(self, tmp_path, monkeypatch):
+    def test_write_and_check_cli(self, script, tmp_path, monkeypatch):
         """bench.write_bench_telemetry writes the log, the Prometheus
         dump, and passes its own validator CLI."""
-        import importlib.util
-        import os as _os
-        import sys as _sys
-        root = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_mod", _os.path.join(root, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
+        bench = script("bench")
         log = str(tmp_path / "bench.jsonl")
         monkeypatch.setenv("PADDLE_TPU_METRICS_LOG", log)
         result = {"metric": "m", "value": 10.0, "vs_baseline": 1.0,

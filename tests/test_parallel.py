@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-import paddle_tpu as pt
 from paddle_tpu import nn, optimizer, train
 from paddle_tpu.core.mesh import MeshConfig, make_mesh, mesh_context
 from paddle_tpu.parallel import (ShardingPlan, collective, fsdp_plan,

@@ -1,7 +1,6 @@
 """Sequence-op long tail + WMT loader tests (operators/sequence_ops/
 breadth; python/paddle/dataset/wmt16 parse path)."""
 
-import os
 
 import jax.numpy as jnp
 import numpy as np

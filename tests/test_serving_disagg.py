@@ -20,75 +20,52 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu import observability as obs
-from paddle_tpu import serving
 from paddle_tpu.serving import fleet
 from paddle_tpu.serving.engine import SlotMigrationError
-from paddle_tpu.models.gpt import GPT, GPTConfig
 
-VOCAB = 64
+from serving_taps import random_prompts as _prompts
+from serving_taps import tiny_gpt, warmed_engines
+from serving_taps import fleet_engine as _engine
 
 
 @pytest.fixture(scope="module")
 def model_params():
-    cfg = GPTConfig.tiny(vocab_size=VOCAB, hidden_size=16, num_layers=2,
-                         num_heads=2, ffn_size=32, max_position=64,
-                         dropout=0.0, attn_impl="xla")
-    model = GPT(cfg)
-    return model, model.init(jax.random.PRNGKey(0))
+    return tiny_gpt()
 
 
-def _engine(model_params, tracer=None, **kw):
-    model, params = model_params
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("page_size", 4)
-    kw.setdefault("max_tokens_per_slot", 32)
-    kw.setdefault("prefill_chunk", 4)
-    return serving.ServingEngine(model, params, attn_impl="lax",
-                                 registry=obs.MetricsRegistry(),
-                                 tracer=tracer, **kw)
+@pytest.fixture(scope="module")
+def warmed(model_params):
+    """``get(**options) ->`` the engine of these options (``tier=`` one of
+    the two, or colocated), warmed ONCE for the module and idle (``tests/
+    serving_taps.py``); a case that crashes a replica builds its own."""
+    return warmed_engines(model_params)
 
 
-def _disagg_fleet(model_params, tracer=None, faults=None,
-                  pre_kw=None, dec_kw=None, wrap=None, **kw):
-    """1 prefill + 1 decode LocalReplica behind a FleetRouter; ``wrap``
-    maps tier -> ChaosSpec kwargs."""
+def _disagg_fleet(model_params, tracer=None, pre_kw=None, dec_kw=None,
+                  warmed=None, **kw):
+    """1 prefill + 1 decode LocalReplica behind a FleetRouter: over the
+    module's engines with ``warmed``, else over two of their own."""
     tracer = tracer or obs.Tracer(enabled=False)
-    pre = fleet.LocalReplica(
-        _engine(model_params, tracer=tracer, tier="prefill",
-                **dict(kw, **(pre_kw or {}))), name="p0").warmup()
-    dec = fleet.LocalReplica(
-        _engine(model_params, tracer=tracer, tier="decode",
-                **dict(kw, **(dec_kw or {}))), name="d0").warmup()
-    reps = {"prefill": pre, "decode": dec}
-    if wrap:
-        for tier, spec in wrap.items():
-            reps[tier] = fleet.ChaosReplica(reps[tier], **spec)
-    router = fleet.FleetRouter(
-        [reps["prefill"], reps["decode"]], policy="p2c",
-        registry=obs.MetricsRegistry(), tracer=tracer, seed=0,
-        **({"faults": faults} if faults is not None else {}))
-    return router, reps["prefill"], reps["decode"]
+    if warmed is not None:
+        engines = [warmed(tier="prefill", **kw), warmed(tier="decode", **kw)]
+    else:
+        engines = [_engine(model_params, tracer=tracer, tier=tier,
+                           **dict(kw, **(own or {})))
+                   for tier, own in (("prefill", pre_kw), ("decode", dec_kw))]
+        for eng in engines:
+            eng.warmup()
+    pre, dec = (fleet.LocalReplica(eng, name=name)
+                for eng, name in zip(engines, ("p0", "d0")))
+    router = fleet.FleetRouter([pre, dec], policy="p2c", seed=0,
+                               registry=obs.MetricsRegistry(), tracer=tracer)
+    return router, pre, dec
 
 
-def _prompts(n, rng=None, lo=3, hi=9):
-    rng = rng or np.random.default_rng(0)
-    return [rng.integers(1, VOCAB, int(rng.integers(lo, hi)))
-            .astype(np.int32) for _ in range(n)]
-
-
-_REF = {}
-
-
-def _reference(model_params, prompts, max_new, **kw):
-    """Failure-free colocated reference, one engine per config key."""
-    key = (max_new, tuple(sorted(kw.items(), key=lambda x: str(x))),
-           tuple(int(p.sum()) for p in prompts))
-    if key not in _REF:
-        eng = _engine(model_params, **kw)
-        eng.warmup()
-        _REF[key] = [np.asarray(t) for t in
-                     eng.generate_many(prompts, max_new, eos_id=None)]
-    return _REF[key]
+def _reference(warmed, prompts, max_new, **kw):
+    """Failure-free colocated reference, from the module's colocated
+    engine of these options."""
+    return [np.asarray(t) for t in warmed(**kw).generate_many(
+        prompts, max_new, eos_id=None)]
 
 
 def _drain(router, max_steps=3000):
@@ -107,10 +84,11 @@ class TestDisaggParity:
     must be BIT-IDENTICAL to a colocated run — the handoff is the
     hash-verified migration format, so nothing may drift."""
 
-    def test_fp_parity_and_streaming(self, model_params):
+    def test_fp_parity_and_streaming(self, model_params, warmed):
         prompts = _prompts(6)
-        ref = _reference(model_params, prompts, 8)
-        router, pre, dec = _disagg_fleet(model_params)
+        ref = _reference(warmed, prompts, 8)
+        router, pre, dec = _disagg_fleet(model_params, warmed=warmed)
+        came_in = dec.engine.migrated_in_total
         frids = [router.submit(p, 8) for p in prompts]
         _drain(router)
         outs = [router.result(f) for f in frids]
@@ -119,14 +97,14 @@ class TestDisaggParity:
         # every request crossed the tier boundary
         assert router.handoffs_total == len(prompts)
         # decode happened on the decode tier, not in place
-        assert dec.engine.migrated_in_total == len(prompts)
+        assert dec.engine.migrated_in_total - came_in == len(prompts)
 
-    def test_int8_parity(self, model_params):
+    def test_int8_parity(self, model_params, warmed):
         prompts = _prompts(4, rng=np.random.default_rng(7))
-        ref = _reference(model_params, prompts, 6,
+        ref = _reference(warmed, prompts, 6,
                          cache_dtype=jnp.int8, num_pages=65)
         router, _pre, _dec = _disagg_fleet(
-            model_params, cache_dtype=jnp.int8, num_pages=65)
+            model_params, warmed=warmed, cache_dtype=jnp.int8, num_pages=65)
         frids = [router.submit(p, 6) for p in prompts]
         _drain(router)
         outs = [router.result(f) for f in frids]
@@ -135,14 +113,14 @@ class TestDisaggParity:
 
     @pytest.mark.skipif(len(jax.devices()) < 4,
                         reason="tp tests need >= 4 (virtual) devices")
-    def test_tp2_parity_real_shard_manifests(self, model_params):
+    def test_tp2_parity_real_shard_manifests(self, model_params, warmed):
         """tp=2 on both tiers: the prefill tier runs the REAL Megatron
         MLP shard (ffn column/row split, second psum) and the handoff
         carries per-(page, tp-shard) manifests; decode must still be
         bit-identical to the tp=1 colocated reference."""
         from paddle_tpu.core.mesh import MeshConfig, make_mesh
         prompts = _prompts(4, rng=np.random.default_rng(3))
-        ref = _reference(model_params, prompts, 6)
+        ref = _reference(warmed, prompts, 6)
         kw = dict(page_size=8, max_tokens_per_slot=64)
 
         def mesh():
@@ -160,12 +138,11 @@ class TestDisaggParity:
         assert all(np.array_equal(o, r) for o, r in zip(outs, ref))
         assert router.handoffs_total == len(prompts)
 
-    def test_corrupt_shard_refused_all_or_nothing(self, model_params):
+    def test_corrupt_shard_refused_all_or_nothing(self, model_params, warmed):
         """A flipped bit in one page shard must fail the sha256 check
         BEFORE anything is written: the decode engine stays empty and
         the same snapshot restores cleanly elsewhere."""
-        pre = _engine(model_params, tier="prefill")
-        dec = _engine(model_params, tier="decode")
+        pre, dec = warmed(tier="prefill"), warmed(tier="decode")
         pre.submit(_prompts(1)[0], 8)
         handoffs = []
         for _ in range(50):
@@ -188,10 +165,11 @@ class TestDisaggParity:
                         for s in dec.scheduler.active_slots()
                         for st in [dec.scheduler.slots[s]]}
 
-    def test_decode_tier_mid_prefill_restore_refused(self, model_params):
+    def test_decode_tier_mid_prefill_restore_refused(self, model_params,
+                                                     warmed):
         """Decode-tier engines restore only prefill-COMPLETE slots."""
         src = _engine(model_params, prefill_budget=4)
-        dec = _engine(model_params, tier="decode")
+        dec = warmed(tier="decode")
         p = np.arange(1, 17, dtype=np.int32)     # 16 tokens, chunk=4
         src.submit(p, 8)
         slot = None
@@ -209,14 +187,17 @@ class TestDisaggParity:
 
 
 class TestNoLostRequests:
-    def test_decode_capacity_abort_decodes_in_place(self, model_params):
+    def test_decode_capacity_abort_decodes_in_place(self, model_params,
+                                                    warmed):
         """Decode tier too small for the wave: the unplaceable handoff
         restores BACK into the prefill replica with the
         decode-in-place marker — every request still finishes with
         bit-identical tokens, none lost, no Reject needed."""
         prompts = _prompts(6)
-        ref = _reference(model_params, prompts, 8)
+        ref = _reference(warmed, prompts, 8)
         reg = obs.MetricsRegistry()
+        # (decoding in place compiles decode programs on a prefill tier:
+        # not on the module's, which must never compile after warm-up)
         pre = fleet.LocalReplica(
             _engine(model_params, tier="prefill"), name="p0").warmup()
         dec = fleet.LocalReplica(
@@ -234,13 +215,13 @@ class TestNoLostRequests:
             "expected at least one decode-in-place fallback"
 
     def test_prefill_crash_mid_handoff_redrives_bit_identical(
-            self, model_params):
+            self, model_params, warmed):
         """ChaosReplica kills the prefill replica exactly at
         poll_handoffs: in-flight requests redrive from the replay
         records onto the surviving colocated peer, outputs
         bit-identical, 0 lost."""
         prompts = _prompts(4)
-        ref = _reference(model_params, prompts, 8)
+        ref = _reference(warmed, prompts, 8)
         tracer = obs.Tracer(enabled=False)
         pre = fleet.ChaosReplica(
             fleet.LocalReplica(
@@ -248,8 +229,7 @@ class TestNoLostRequests:
                 name="p0").warmup(),
             crash_on_handoff=True)
         # the survivor is colocated so redriven prompts can decode
-        colo = fleet.LocalReplica(
-            _engine(model_params), name="c0").warmup()
+        colo = fleet.LocalReplica(warmed(), name="c0")
         router = fleet.FleetRouter(
             [pre, colo], policy="p2c", registry=obs.MetricsRegistry(),
             tracer=tracer, seed=0,
@@ -272,13 +252,13 @@ class TestNoLostRequests:
         assert done > 0
         assert pre not in router.replicas, "dead prefill not ejected"
 
-    def test_decode_crash_mid_restore_no_lost(self, model_params):
+    def test_decode_crash_mid_restore_no_lost(self, model_params, warmed):
         """ChaosReplica kills the decode replica at restore(): the
         handoff placement fails over (decode-in-place on the source),
         the dead replica is ejected, and every request completes or
         sheds with a structured reason."""
         prompts = _prompts(4)
-        ref = _reference(model_params, prompts, 8)
+        ref = _reference(warmed, prompts, 8)
         pre = fleet.LocalReplica(
             _engine(model_params, tier="prefill"), name="p0").warmup()
         dec = fleet.ChaosReplica(
@@ -308,14 +288,14 @@ class TestNoLostRequests:
 
 
 class TestTierContracts:
-    def test_decode_tier_refuses_fresh_prompts(self, model_params):
-        eng = _engine(model_params, tier="decode")
+    def test_decode_tier_refuses_fresh_prompts(self, model_params, warmed):
+        eng = warmed(tier="decode")
         with pytest.raises(ValueError, match="restored slots"):
             eng.submit(_prompts(1)[0], 4)
 
     def test_router_never_routes_prompts_to_decode_tier(
-            self, model_params):
-        router, pre, dec = _disagg_fleet(model_params)
+            self, model_params, warmed):
+        router, pre, dec = _disagg_fleet(model_params, warmed=warmed)
         for p in _prompts(6):
             router.submit(p, 4)
         # every submit landed on the prefill replica
@@ -326,9 +306,8 @@ class TestTierContracts:
         _drain(router)
 
     def test_decode_only_fleet_has_no_prompt_candidates(
-            self, model_params):
-        dec = fleet.LocalReplica(
-            _engine(model_params, tier="decode"), name="d0").warmup()
+            self, model_params, warmed):
+        dec = fleet.LocalReplica(warmed(tier="decode"), name="d0")
         router = fleet.FleetRouter([dec], policy="p2c",
                                    registry=obs.MetricsRegistry())
         with pytest.raises(SlotMigrationError, match="no routable"):
@@ -343,7 +322,9 @@ class TestTierContracts:
         """Post-warmup steady state compiles NOTHING on either tier,
         and each tier's warmup plan covers exactly its reachable
         signatures (prefill never compiles decode buckets, decode
-        never compiles prefill buckets)."""
+        never compiles prefill buckets). (Engines of the case's own,
+        new from warm-up: on the module's, a bucket that warm-up missed
+        would have been compiled by an earlier case.)"""
         router, pre, dec = _disagg_fleet(model_params)
         for eng, tier in ((pre.engine, "prefill"),
                           (dec.engine, "decode")):
@@ -365,8 +346,8 @@ class TestTierContracts:
 
 
 class TestDisaggObservability:
-    def test_health_tier_and_handoff_counters(self, model_params):
-        router, pre, dec = _disagg_fleet(model_params)
+    def test_health_tier_and_handoff_counters(self, model_params, warmed):
+        router, pre, dec = _disagg_fleet(model_params, warmed=warmed)
         reg = router._reg
         frids = [router.submit(p, 6) for p in _prompts(4)]
         _drain(router)
@@ -379,11 +360,12 @@ class TestDisaggObservability:
         assert reg.counter("fleet_handoff_bytes_total",
                            "x").value(src="p0", dst="d0") > 0
 
-    def test_colocated_health_has_no_tier_surprises(self, model_params):
+    def test_colocated_health_has_no_tier_surprises(self, model_params,
+                                                    warmed):
         """A colocated engine advertises tier="colocated" and the
         monitor's per-replica gauges keep their exact pre-tier label
         sets (no tier label) — dashboards stay byte-identical."""
-        eng = _engine(model_params)
+        eng = warmed()
         assert eng.health()["tier"] == "colocated"
         rep = fleet.LocalReplica(eng, name="m0")
         reg = obs.MetricsRegistry()
@@ -393,8 +375,8 @@ class TestDisaggObservability:
         assert reg.get("fleet_replica_queue_depth") \
             .value(replica="m0") == 0.0
 
-    def test_monitor_tier_labels_on_tiered_fleet(self, model_params):
-        router, _pre, _dec = _disagg_fleet(model_params)
+    def test_monitor_tier_labels_on_tiered_fleet(self, model_params, warmed):
+        router, _pre, _dec = _disagg_fleet(model_params, warmed=warmed)
         reg = obs.MetricsRegistry()
         mon = fleet.FleetMonitor(router, registry=reg)
         mon.collect()

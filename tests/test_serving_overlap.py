@@ -9,14 +9,16 @@ synchronous engine, which is what a speculative engine, a tier and
 ``snapshot_every_blocks`` get, and every call that reads a slot between
 two steps settles on entry.
 
-The GPT tokens are the parent's of ``test_serving_readback.py`` and
-``test_serving_loops_golden.py``; the hybrid and sparse ones were printed
-by ``_battery`` below on the parent commit (fe44bd4), where every block
-was read in the step that dispatched it.
+The GPT cases take their engines from ``engines`` below, one a set of
+options for the module: what a case may assume of such an engine is in
+``tests/serving_taps.py``. The GPT tokens are those of
+``test_serving_readback.py`` (where the cases that serve the seven prompts
+whole live beside their twins of ISSUE 31; the one whose first token
+comes with its block's settle is in ``test_serving_readback_steps.py``);
+the hybrid and sparse ones
+were printed by ``_battery`` below on ISSUE 34's parent commit (fe44bd4),
+where every block was read in the step that dispatched it.
 """
-
-import os
-import sys
 
 import jax
 import numpy as np
@@ -27,11 +29,11 @@ from paddle_tpu import observability as obs
 from paddle_tpu.models.gpt import GPT, GPTConfig
 from paddle_tpu.serving.engine import SlotMigrationError
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from test_serving_loops_golden import PARENT_TOKENS as LOOP_TOKENS  # noqa: E402
-from test_serving_loops_golden import _serve as _serve_loops  # noqa: E402
-from test_serving_readback import (ENGINE_KINDS, PARENT_TOKENS,  # noqa: E402
-                                   _engine, _prompts, _readbacks)
+from serving_taps import moved, shared_engines, traced, wipe  # noqa: E402
+from serving_taps import PARENT_TOKENS, readback_gpt  # noqa: E402
+from serving_taps import drain_counting as _drain  # noqa: E402
+from serving_taps import readback_engine as _engine  # noqa: E402
+from serving_taps import readback_prompts as _prompts  # noqa: E402
 
 #: five requests of 7..15 tokens through three slots, block of 3
 PARENT_HYBRID = [
@@ -53,19 +55,16 @@ PARENT_SPARSE = [
 
 @pytest.fixture(scope="module")
 def model_params():
-    model = GPT(GPTConfig.tiny(num_heads=4, attn_impl="xla"))
-    return model, model.init(jax.random.PRNGKey(5))
+    return readback_gpt()
 
 
-def _drain(eng):
-    """Step to idle; -> ({rid: tokens}, steps, most read-backs a step)."""
-    out, steps, most = {}, 0, 0
-    while not eng.scheduler.idle():
-        before = _readbacks(eng)
-        out.update(eng.step())
-        most = max(most, _readbacks(eng) - before)
-        steps += 1
-    return out, steps, most
+@pytest.fixture(scope="module")
+def engines(model_params):
+    """``get(**options) -> engine``: ``serving_taps.readback_engine`` with
+    these options, once for the module, idle, its tracer its own and off."""
+    return shared_engines(lambda **over: _engine(
+        model_params, tracer=obs.Tracer(capacity=4096, enabled=False),
+        **over))
 
 
 def _battery(family):
@@ -100,29 +99,7 @@ def _battery(family):
     return eng, [np.asarray(out[r]).tolist() for r in rids], most
 
 
-# -- (a) the parent's tokens, every kind of engine -------------------------------
-
-@pytest.mark.parametrize("kind", sorted(ENGINE_KINDS))
-def test_overlapped_tokens_are_the_parents(model_params, kind):
-    if kind == "tp2" and len(jax.devices()) < 2:
-        pytest.skip("needs two devices")
-    eng = _engine(model_params, prefill_budget=64, **ENGINE_KINDS[kind])
-    rids = [eng.submit(p, 10)
-            for p in _prompts(model_params[0].cfg.vocab_size)]
-    out, _, most = _drain(eng)
-    assert [np.asarray(out[r]).tolist() for r in rids] == PARENT_TOKENS
-    snap = eng._reg.snapshot()
-    assert snap["serving_decode_blocks_overlapped_total"] > 0
-    assert snap["serving_decode_discarded_tokens_total"] == 0
-    assert most == 1 and eng._pending is None
-
-
-@pytest.mark.parametrize("kind", ["fp", "int8", "tp2"])
-def test_overlapped_loop_goldens_are_the_parents(kind):
-    if kind == "tp2" and len(jax.devices()) < 2:
-        pytest.skip("needs two devices")
-    assert _serve_loops(kind) == LOOP_TOKENS[kind]
-
+# -- (a) the parent's tokens (GPT's: ``test_serving_readback.py``) ---------------
 
 @pytest.mark.parametrize("family,parent", [("hybrid", PARENT_HYBRID),
                                            ("sparse", PARENT_SPARSE)])
@@ -142,14 +119,15 @@ def test_programs_with_state_or_counts_give_the_parents_tokens(family,
 
 # -- (b) the order of a steady state ---------------------------------------------
 
-def test_block_k_is_dispatched_before_block_k_minus_1_is_read(model_params):
-    tracer = obs.tracing.Tracer(capacity=4096)
-    eng = _engine(model_params, tracer=tracer, prefix_sharing=False)
-    rids = [eng.submit(np.arange(1, 9 + k, dtype=np.int32), 12)
-            for k in range(3)]
-    out, steps, most = _drain(eng)
+def test_block_k_is_dispatched_before_block_k_minus_1_is_read(engines):
+    eng = engines(prefix_sharing=False)
+    before = eng._reg.snapshot()
+    with traced(eng) as tracer:
+        rids = [eng.submit(np.arange(1, 9 + k, dtype=np.int32), 12)
+                for k in range(3)]
+        out, steps, most = _drain(eng)
     assert sorted(out) == sorted(rids) and most == 1
-    snap = eng._reg.snapshot()
+    snap = moved(eng._reg, before)
     rounds = snap["serving_decode_rounds_total"]
     assert rounds >= 4
     # a run that starts idle: every block but the first went out while
@@ -182,51 +160,31 @@ def test_block_k_is_dispatched_before_block_k_minus_1_is_read(model_params):
         == {round(s.end, 9) for s in sync}
 
 
-def test_first_tokens_come_with_the_settle_of_their_block(model_params):
-    """A prompt that ends in step k decodes in block k, and the host
-    learns its first token with that block: TTFT is stamped then."""
-    eng = _engine(model_params, prefill_budget=32)
-    eng.submit(np.arange(1, 30, dtype=np.int32), 7)       # 29 tokens
-    before = _readbacks(eng)
-    assert eng.step() == {}
-    snap = eng._reg.snapshot()
-    assert snap["serving_prefill_calls_total"] == 4
-    assert snap["serving_decode_rounds_total"] == 1
-    (st,) = [s for s in eng.scheduler.slots if s is not None]
-    # four prefill calls and a block went out, nothing was waited for
-    assert _readbacks(eng) == before and eng._owed == []
-    assert st.prefill_done and st.generated == [] \
-        and st.first_token_at is None
-    assert eng._pending is not None and eng._pending.started_from is not None
-    # known at dispatch: the slot's length holds the block already
-    assert eng.cache.lengths[0] == 29 + eng.decode_block
-    assert eng.step() == {}
-    assert _readbacks(eng) - before == 1
-    assert len(st.generated) == 1 + eng.decode_block      # first + block
-    assert st.first_token_at is not None
-
-
 # -- (c) an eos_id inside block k-1 with block k in flight ------------------------
 
-def test_eos_inside_a_block_drops_the_block_in_flight(model_params):
+def test_eos_inside_a_block_drops_the_block_in_flight(model_params, engines):
     p = _prompts(model_params[0].cfg.vocab_size)
     # alone, prompt 1 gives [39, 49, 120, 39, 120, 34, 120, 2, 39, 39]:
     # its first 120 ends block 0 (tokens 1-3 after the first token)
-    ref = _engine(model_params, num_slots=1)
+    ref = engines(num_slots=1)
     alone = {i: ref.generate_many([p[i]], 10)[0].tolist() for i in (1, 2)}
     assert alone[1][:4] == [39, 49, 120, 39]
 
-    eng = _engine(model_params, num_slots=1, prefill_budget=32)
+    eng = engines(num_slots=1, prefill_budget=32)
+    before = eng._reg.snapshot()
     r1 = eng.submit(p[1], 10, eos_id=120)
     r2 = eng.submit(p[2], 10)
     freed = []
     free_slot = eng.cache.free_slot
     eng.cache.free_slot = lambda s: (freed.append(s), free_slot(s))[1]
     came, k = {}, 0
-    while not eng.scheduler.idle():
-        k += 1
-        for rid, toks in eng.step().items():
-            came[rid] = (k, np.asarray(toks).tolist())
+    try:
+        while not eng.scheduler.idle():
+            k += 1
+            for rid, toks in eng.step().items():
+                came[rid] = (k, np.asarray(toks).tolist())
+    finally:
+        del eng.cache.free_slot             # the class's own again
     # an eos_id request's first token is read in its prefill call (39);
     # step 1 dispatches block 0 (49 120 39), step 2 block 1 and settles
     # block 0: the request ends on 120 with block 1 in flight
@@ -234,7 +192,7 @@ def test_eos_inside_a_block_drops_the_block_in_flight(model_params):
     # the newcomer took the freed slot behind the stale block and gives
     # the tokens it gives alone
     assert came[r2][1] == alone[2]
-    snap = eng._reg.snapshot()
+    snap = moved(eng._reg, before)
     # one token of block 0 after the eos, all three of block 1
     assert snap["serving_decode_discarded_tokens_total"] == 1 + 3
     assert freed == [0, 0]          # once a request
@@ -252,23 +210,25 @@ def test_eos_on_a_program_with_slot_state_resets_the_row_once():
         dt_init_range=(0.1, 0.7)))
     params = model.init(jax.random.PRNGKey(5))
 
-    def engine():
-        return inference.make_serving_engine(
-            model, params, num_slots=1, page_size=4, prefill_chunk=8,
-            max_tokens_per_slot=96, decode_block=3, attn_impl="lax",
-            registry=obs.MetricsRegistry())
+    eng = inference.make_serving_engine(
+        model, params, num_slots=1, page_size=4, prefill_chunk=8,
+        max_tokens_per_slot=96, decode_block=3, attn_impl="lax",
+        registry=obs.MetricsRegistry())
     rng = np.random.default_rng(17)
     a, b = (rng.integers(0, 96, n).astype(np.int32) for n in (5, 19))
-    alone_a = engine().generate_many([a], 9)[0].tolist()
-    alone_b = engine().generate_many([b], 9)[0].tolist()
+    # each alone, the slot's rows zeroed in between as a new engine's are
+    alone_a = eng.generate_many([a], 9)[0].tolist()
+    wipe(eng)
+    alone_b = eng.generate_many([b], 9)[0].tolist()
+    wipe(eng)
     eos = alone_a[2]                # ends inside block 0
     assert eos not in alone_a[:2]
-    eng = engine()
+    before = eng._reg.snapshot()
     r1, r2 = eng.submit(a, 9, eos_id=eos), eng.submit(b, 9)
     out, _, _ = _drain(eng)
     assert out[r1].tolist() == alone_a[:3]
     assert out[r2].tolist() == alone_b
-    snap = eng._reg.snapshot()
+    snap = moved(eng._reg, before)
     assert snap["serving_decode_discarded_tokens_total"] == 1 + 3
     assert snap["serving_ssm_state_resets_total"] == 2
 
@@ -277,10 +237,12 @@ def test_eos_on_a_program_with_slot_state_resets_the_row_once():
 
 @pytest.mark.parametrize("budget", [2, 4, 5, 7])
 def test_a_budget_that_ends_mid_block_is_not_dispatched_again(model_params,
+                                                              engines,
                                                               budget):
     """Every finish is known in advance without an ``eos_id``: the slot
     joins exactly the blocks its budget needs, and no row is wasted."""
-    eng = _engine(model_params, num_slots=2)
+    eng = engines(num_slots=2)
+    before = eng._reg.snapshot()
     n = eng.decode_block                                    # 3
     p = _prompts(model_params[0].cfg.vocab_size)
     rid = eng.submit(p[0], budget)
@@ -288,20 +250,25 @@ def test_a_budget_that_ends_mid_block_is_not_dispatched_again(model_params,
     dispatch = eng._dispatch_block
     eng._dispatch_block = lambda dslots, w, rnd: (
         live.append(list(dslots)), dispatch(dslots, w, rnd))[1]
-    out, _, most = _drain(eng)
+    try:
+        out, _, most = _drain(eng)
+    finally:
+        del eng._dispatch_block             # the class's own again
     assert out[rid].tolist() == PARENT_TOKENS[0][:budget]
     # the first token comes from prefill; the rest in blocks of 3
     assert live == [[0]] * -(-(budget - 1) // n)
-    assert eng._reg.snapshot()["serving_decode_discarded_tokens_total"] == 0
+    assert moved(eng._reg, before)[
+        "serving_decode_discarded_tokens_total"] == 0
     assert most == 1 and not eng.cache.lengths.any()
 
 
 # -- (e) who sees no block in flight ----------------------------------------------
 
-def _in_flight(model_params, **over):
-    """An engine three steps into two requests of 20 tokens: both slots
-    hold tokens, and a block of three more each is in flight."""
-    eng = _engine(model_params, prefix_sharing=False, **over)
+def _in_flight(model_params, engines, **over):
+    """The idle engine of these options three steps into two requests of
+    20 tokens: both slots hold tokens, and a block of three more each is
+    in flight."""
+    eng = engines(prefix_sharing=False, **over)
     p = _prompts(model_params[0].cfg.vocab_size)
     rids = [eng.submit(p[0], 20), eng.submit(p[2], 20)]
     for _ in range(3):
@@ -315,8 +282,11 @@ def _in_flight(model_params, **over):
                                   "export_prefix_pages",
                                   "import_prefix_pages", "poll_handoffs",
                                   "poll_micro_snapshots"])
-def test_calls_between_steps_settle_the_block_in_flight(model_params, call):
-    eng, _ = _in_flight(model_params)
+def test_calls_between_steps_settle_the_block_in_flight(model_params,
+                                                        engines, call):
+    """(The restore and the import are refused, or have nothing to do,
+    before anything of theirs lands: the engine serves on.)"""
+    eng, _ = _in_flight(model_params, engines)
     assert eng._pending is not None
     st = eng.scheduler.slots[1]
     args = {"snapshot_slot": (0,), "release_slot": (0,),
@@ -332,14 +302,14 @@ def test_calls_between_steps_settle_the_block_in_flight(model_params, call):
 
 
 def test_a_migrated_slot_carries_the_tokens_of_the_block_in_flight(
-        model_params):
+        model_params, engines):
     """Drain between two steps: the snapshot holds every token the device
-    had computed, and the peer finishes the request bit-identically."""
+    had computed, and the peer (an engine of its own) finishes the
+    request bit-identically."""
     p = _prompts(model_params[0].cfg.vocab_size)
-    whole = _engine(model_params, prefix_sharing=False).generate_many(
-        [p[0], p[2]], 20)
+    whole = engines(prefix_sharing=False).generate_many([p[0], p[2]], 20)
     assert whole[0].tolist()[:10] == PARENT_TOKENS[0]
-    src, _ = _in_flight(model_params)
+    src, _ = _in_flight(model_params, engines)
     dst = _engine(model_params, prefix_sharing=False)
     snap = src.snapshot_slot(0)
     assert snap["state"]["generated"] == whole[0].tolist()[:10]
@@ -352,8 +322,8 @@ def test_a_migrated_slot_carries_the_tokens_of_the_block_in_flight(
     assert [v.tolist() for v in out.values()] == [whole[1].tolist()]
 
 
-def test_a_spill_read_settles_first(model_params):
-    eng, _ = _in_flight(model_params, host_spill_pages=4)
+def test_a_spill_read_settles_first(model_params, engines):
+    eng, _ = _in_flight(model_params, engines, host_spill_pages=4)
     assert eng._pending is not None
     eng._spill_read(1)
     assert eng._pending is None
@@ -362,7 +332,7 @@ def test_a_spill_read_settles_first(model_params):
 @pytest.mark.parametrize("how", ["speculative", "prefill_tier",
                                  "decode_tier", "snapshot_every_blocks"])
 def test_engines_that_settle_at_once_leave_nothing_in_flight(model_params,
-                                                             how):
+                                                             engines, how):
     over = {"prefill_tier": dict(tier="prefill"),
             "decode_tier": dict(tier="decode"),
             "snapshot_every_blocks": dict(snapshot_every_blocks=1)}.get(how)
@@ -379,7 +349,7 @@ def test_engines_that_settle_at_once_leave_nothing_in_flight(model_params,
     if how == "decode_tier":
         # a decode tier takes restored slots only: two steps of a
         # colocated engine, handed over
-        src, _ = _in_flight(model_params)
+        src, _ = _in_flight(model_params, engines)
         snap = src.snapshot_slot(0)
         eng.restore_slot(snap)
         held = len(snap["state"]["generated"])
@@ -406,8 +376,9 @@ def test_engines_that_settle_at_once_leave_nothing_in_flight(model_params,
 
 # -- (f) loops end ----------------------------------------------------------------
 
-def test_generate_many_and_a_drain_return_every_request(model_params):
-    eng = _engine(model_params, prefill_budget=16)
+def test_generate_many_and_a_drain_return_every_request(model_params,
+                                                        engines):
+    eng = engines(prefill_budget=16)    # a prompt's chunks over several steps
     p = _prompts(model_params[0].cfg.vocab_size)
     outs = eng.generate_many(p, 10)
     assert [o.tolist() for o in outs] == PARENT_TOKENS
@@ -423,31 +394,19 @@ def test_generate_many_and_a_drain_return_every_request(model_params):
     assert eng.step() == {}                 # an idle tick reads nothing
 
 
-def test_the_merge_program_is_warmed_and_nothing_recompiles(model_params):
-    from paddle_tpu import analysis
-    eng = _engine(model_params, prefill_budget=64)
-    assert ("last_token",) in eng.warmup_plan()
-    eng.warmup(cost_gauges=False)
-    assert analysis.serving_bucket_coverage(eng) == []
-    det = obs.RecompileDetector("overlap", warmup=0, registry=eng._reg)
-    outs = eng.generate_many(_prompts(model_params[0].cfg.vocab_size), 10)
-    det.check()
-    assert [o.tolist() for o in outs] == PARENT_TOKENS
-    assert det.recompiles == 0
-
-
-def test_decode_block_seconds_are_the_cadence_of_read_backs(model_params):
+def test_decode_block_seconds_are_the_cadence_of_read_backs(engines):
     """``serving_decode_step_seconds`` of an overlapped block is the
     interval between two read-backs: the blocks of a run tile the time
     from the first dispatch to the last read-back, none counted twice,
     and what the caller does between two steps is in none of them."""
     import time
-    tracer = obs.tracing.Tracer(capacity=4096)
-    eng = _engine(model_params, tracer=tracer, num_slots=1)
-    eng.submit(np.arange(1, 9, dtype=np.int32), 13)
-    while not eng.scheduler.idle():
-        eng.step()
-        time.sleep(0.05)        # a caller that pauses (a compile, a trace)
+    eng = engines(num_slots=1)
+    before = eng._reg.snapshot()
+    with traced(eng) as tracer:
+        eng.submit(np.arange(1, 9, dtype=np.int32), 13)
+        while not eng.scheduler.idle():
+            eng.step()
+            time.sleep(0.05)    # a caller that pauses (a compile, a trace)
     spans = tracer.spans()
     sync = [s for s in spans if s.name == "serving.decode.sync"]
     asm = min(s.start for s in spans if s.name == "serving.decode.assemble")
@@ -455,7 +414,7 @@ def test_decode_block_seconds_are_the_cadence_of_read_backs(model_params):
                     and s.end > asm), key=lambda s: s.start)
     away = sum(b.start - a.end for a, b in zip(steps, steps[1:]))
     assert away >= 0.05 * (len(steps) - 1)
-    hist = eng._reg.snapshot()
+    hist = moved(eng._reg, before)
     assert hist["serving_decode_step_seconds_count"] == len(sync) == 4
     assert hist["serving_decode_step_seconds_sum"] == pytest.approx(
         max(s.end for s in sync) - asm - away, rel=1e-6)

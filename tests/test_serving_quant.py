@@ -13,27 +13,35 @@ import pytest
 from paddle_tpu import kernels
 from paddle_tpu import observability as obs
 from paddle_tpu import serving
-from paddle_tpu.models.gpt import GPT, GPTConfig
 from paddle_tpu.serving.paged_cache import (PagedCacheConfig, PagedKVCache,
                                             quantize_kv)
 
-
-def _model(seed=0, **kw):
-    cfg = GPTConfig.tiny(vocab_size=64, hidden_size=16, num_layers=2,
-                         num_heads=2, ffn_size=32, max_position=64,
-                         dropout=0.0, attn_impl="xla", **kw)
-    model = GPT(cfg)
-    return model, model.init(jax.random.PRNGKey(seed))
+from serving_taps import dense_reference as _dense_reference
+from serving_taps import prompts as _prompts, tiny_gpt as _model
+from serving_taps import churn_a_prefix_pool, shared_engines
 
 
-def _prompts(rng, lens):
-    return [rng.integers(1, 64, n).astype(np.int32) for n in lens]
+@pytest.fixture(scope="module")
+def model_params():
+    return _model(seed=5)
 
 
-def _dense_reference(model, params, prompt, max_new):
-    out = model.generate(params, jnp.asarray(prompt)[None],
-                         max_new_tokens=max_new, use_cache=True)
-    return np.asarray(out)[0, len(prompt):]
+@pytest.fixture(scope="module")
+def warmed_int8(model_params):
+    """``get(peer) ->`` one of the int8 engines of one geometry, warmed
+    ONCE for the module and idle (``tests/serving_taps.py``): a migration's
+    source (0) and its peer (1), and the one that must not recompile
+    (``"unserved"``: no other case takes it)."""
+    model, params = model_params
+
+    def build(peer):
+        eng = serving.ServingEngine(
+            model, params, num_slots=2, page_size=4, max_tokens_per_slot=48,
+            attn_impl="lax", cache_dtype=jnp.int8, decode_block=2,
+            registry=obs.MetricsRegistry())
+        eng.warmup()
+        return eng
+    return shared_engines(build)
 
 
 class TestQuantizeKV:
@@ -173,15 +181,13 @@ class TestInt8EngineParity:
             np.testing.assert_array_equal(
                 o, _dense_reference(model, params, p, 5))
 
-    def test_zero_steady_state_recompiles(self):
-        model, params = _model()
+    def test_zero_steady_state_recompiles(self, warmed_int8):
         rng = np.random.default_rng(8)
-        reg = obs.MetricsRegistry()
-        eng = serving.ServingEngine(model, params, num_slots=2,
-                                    page_size=4, attn_impl="lax",
-                                    cache_dtype=jnp.int8, registry=reg)
-        eng.warmup()
-        det = obs.RecompileDetector("int8_steady", warmup=0, registry=reg)
+        eng = warmed_int8("unserved")
+        # new from warm-up: no earlier case compiled what warm-up missed
+        assert eng.health()["steps"] == 0
+        det = obs.RecompileDetector("int8_steady", warmup=0,
+                                    registry=eng._reg)
         eng.generate_many(_prompts(rng, [9, 4, 6]), max_new_tokens=4,
                           max_steps=100)
         det.check()
@@ -250,61 +256,12 @@ class TestInt8PrefixSharing:
         """The allocator property test on a quantized pool — refcounts,
         publication, and the free/cached/live partition are storage-
         dtype independent and must hold identically."""
-        rng = np.random.default_rng(22)
-        c = PagedKVCache(PagedCacheConfig(
-            num_layers=1, num_heads=2, head_dim=4, num_slots=4,
-            page_size=4, num_pages=14, max_pages_per_slot=4,
-            dtype=jnp.int8))
-        pool = [rng.integers(1, 9, n).astype(np.int32)
-                for n in (6, 9, 10, 13, 10)]
-        pool.append(pool[2].copy())
-        live = {}
-        for _step in range(300):
-            op = rng.random()
-            free_slots = [s for s in range(4) if s not in live]
-            if op < 0.5 and free_slots:
-                slot = int(rng.choice(free_slots))
-                prompt = pool[int(rng.integers(len(pool)))]
-                total = len(prompt) + int(rng.integers(1, 4))
-                try:
-                    shared = c.reserve(slot, total, prompt=prompt)
-                except serving.PageOverflowError:
-                    c.check_invariants()
-                    continue
-                assert 0 <= shared < len(prompt)
-                live[slot] = (prompt, shared)
-            elif op < 0.7 and live:
-                slot = int(rng.choice(list(live)))
-                if c.pending_copy(slot) is not None:
-                    c.copy_done(slot)
-                prompt, shared = live[slot]
-                upto = int(rng.integers(shared, len(prompt) + 1))
-                if c.pending_copy(slot) is None:
-                    c.publish_prefix(slot, prompt, upto)
-            elif live:
-                slot = int(rng.choice(list(live)))
-                c.free_slot(slot)
-                del live[slot]
-            c.check_invariants()
-        for slot in list(live):
-            c.free_slot(slot)
-        c.check_invariants()
-        assert c.pages_in_use == 0
+        churn_a_prefix_pool(300, dtype=jnp.int8)
 
 
 class TestInt8Migration:
     """Fleet drain of an int8 slot: shards carry scales, hashes cover
     both, restore is byte-identical."""
-
-    def _engine(self, model_params, **kw):
-        model, params = model_params
-        kw.setdefault("num_slots", 2)
-        kw.setdefault("page_size", 4)
-        kw.setdefault("max_tokens_per_slot", 48)
-        kw.setdefault("attn_impl", "lax")
-        kw.setdefault("cache_dtype", jnp.int8)
-        kw.setdefault("decode_block", 2)
-        return serving.ServingEngine(model, params, **kw)
 
     def _step_to_mid_decode(self, eng, cap, max_steps=50):
         for _ in range(max_steps):
@@ -315,17 +272,13 @@ class TestInt8Migration:
                 return mid[0]
         raise AssertionError("no mid-decode window reached")
 
-    @pytest.fixture(scope="class")
-    def model_params(self):
-        return _model(seed=5)
-
-    def test_mid_decode_migration_byte_identical(self, model_params):
+    def test_mid_decode_migration_byte_identical(self, model_params,
+                                                 warmed_int8):
         model, params = model_params
         prompt = np.arange(1, 8, dtype=np.int32)
         ref = _dense_reference(model, params, prompt, 16)
 
-        src = self._engine(model_params)
-        src.warmup()
+        src = warmed_int8(0)
         src.submit(prompt, 16)
         slot = self._step_to_mid_decode(src, 16)
         snap = src.snapshot_slot(slot)
@@ -334,8 +287,7 @@ class TestInt8Migration:
         assert kv.dtype == np.int8 and sc.dtype == np.float32
         assert snap["geometry"]["dtype"] == "int8"
 
-        dst = self._engine(model_params)
-        dst.warmup()
+        dst = warmed_int8(1)
         rid = dst.restore_slot(snap)
         src.release_slot(slot)
         out = {}
@@ -350,33 +302,33 @@ class TestInt8Migration:
         src.cache.check_invariants()
         dst.cache.check_invariants()
 
-    def test_corrupt_scale_shard_refused(self, model_params):
-        """A bit-flip in the SCALES (not the int8 KV) must be refused:
-        the digest covers both halves of the shard."""
-        src = self._engine(model_params)
-        src.warmup()
+    def test_corrupt_scale_shard_refused(self, warmed_int8):
+        """A bit-flip in the SCALES (not the int8 KV) must be refused,
+        before anything lands: the digest covers both halves of the
+        shard."""
+        src = warmed_int8(0)
         src.submit(np.arange(1, 8, dtype=np.int32), 24)
         snap = src.snapshot_slot(self._step_to_mid_decode(src, 24))
         kv, sc = snap["shards"][0]
         sc = sc.copy()
         sc.reshape(-1)[0] += 0.25
         snap["shards"][0] = (kv, sc)
-        dst = self._engine(model_params)
-        dst.warmup()
+        dst = warmed_int8(1)
         with pytest.raises(serving.SlotMigrationError,
                            match="sha256 mismatch"):
             dst.restore_slot(snap)
         assert dst.scheduler.active_slots() == []
         dst.cache.check_invariants()
 
-    def test_cross_dtype_restore_refused(self, model_params):
+    def test_cross_dtype_restore_refused(self, model_params, warmed_int8):
         """An int8 snapshot cannot restore into a bf16 engine (geometry
         pins the dtype)."""
-        src = self._engine(model_params)
-        src.warmup()
+        src = warmed_int8(0)
         src.submit(np.arange(1, 8, dtype=np.int32), 24)
         snap = src.snapshot_slot(self._step_to_mid_decode(src, 24))
-        dst = self._engine(model_params, cache_dtype=jnp.bfloat16)
+        dst = serving.ServingEngine(
+            *model_params, num_slots=2, page_size=4, max_tokens_per_slot=48,
+            attn_impl="lax", cache_dtype=jnp.bfloat16, decode_block=2)
         with pytest.raises(serving.SlotMigrationError,
                            match="geometry mismatch"):
             dst.restore_slot(snap)
@@ -405,18 +357,8 @@ class TestInt8StaticBytes:
         return analysis.estimate_cost(eng.decode_step, *args,
                                       name=f"decode_{dtype}")
 
-    def _cost_diff(self):
-        import importlib.util
-        import os
-        spec = importlib.util.spec_from_file_location(
-            "graph_lint", os.path.join(os.path.dirname(__file__),
-                                       "..", "tools", "graph_lint.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.cost_diff
-
-    def test_cost_diff_fails_at_bf16_bytes(self):
-        cost_diff = self._cost_diff()
+    def test_cost_diff_fails_at_bf16_bytes(self, graph_lint_cli):
+        cost_diff = graph_lint_cli.cost_diff
         cost8 = self._lower(jnp.int8)
         costb = self._lower(jnp.bfloat16)
         # the real claim: on a KV-dominated pool the int8 step moves

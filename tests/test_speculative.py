@@ -16,13 +16,9 @@ from paddle_tpu import serving
 from paddle_tpu.analysis import hlo_lint
 from paddle_tpu.models.gpt import GPT, GPTConfig
 
-
-def _model(seed=0, **kw):
-    cfg = GPTConfig.tiny(vocab_size=64, hidden_size=16, num_layers=2,
-                         num_heads=2, ffn_size=32, max_position=64,
-                         dropout=0.0, attn_impl="xla", **kw)
-    model = GPT(cfg)
-    return model, model.init(jax.random.PRNGKey(seed))
+from serving_taps import dense_reference as _dense_reference
+from serving_taps import prompts as _prompts, tiny_gpt as _model
+from serving_taps import moved, shared_engines
 
 
 def _draft(seed=9):
@@ -36,24 +32,31 @@ def _draft(seed=9):
     return model, model.init(jax.random.PRNGKey(seed))
 
 
-def _prompts(rng, lens):
-    return [rng.integers(1, 64, n).astype(np.int32) for n in lens]
+@pytest.fixture(scope="module")
+def engines():
+    """``get(self_draft) ->`` the battery's engine of ``_model()`` without
+    a draft, or with itself as the draft (``spec_k`` 4), built once for
+    the module and idle (``tests/serving_taps.py``)."""
+    model, params = _model()
 
-
-def _dense_reference(model, params, prompt, max_new):
-    out = model.generate(params, jnp.asarray(prompt)[None],
-                         max_new_tokens=max_new, use_cache=True)
-    return np.asarray(out)[0, len(prompt):]
+    def build(self_draft):
+        kw = dict(draft_model=model, draft_params=params,
+                  spec_k=4) if self_draft else {}
+        return serving.ServingEngine(
+            model, params, num_slots=3, page_size=4, prefill_chunk=8,
+            attn_impl="lax", registry=obs.MetricsRegistry(), **kw)
+    return shared_engines(build)
 
 
 class TestSpeculativeParity:
     """The acceptance gate: speculative greedy == non-speculative
     greedy, bit for bit, on the serving parity battery."""
 
-    def _run(self, model, params, prompts, max_new, eos_id=None, **kw):
-        eng = serving.ServingEngine(model, params, num_slots=3,
-                                    page_size=4, prefill_chunk=8,
-                                    attn_impl="lax", **kw)
+    def _run(self, model, params, prompts, max_new, eos_id=None, eng=None,
+             **kw):
+        eng = eng or serving.ServingEngine(model, params, num_slots=3,
+                                           page_size=4, prefill_chunk=8,
+                                           attn_impl="lax", **kw)
         outs = eng.generate_many(prompts, max_new_tokens=max_new,
                                  eos_id=eos_id, max_steps=500)
         eng.cache.check_invariants()
@@ -67,26 +70,27 @@ class TestSpeculativeParity:
                 dc.num_pages, dc.page_size, dc.num_heads * dc.head_dim)
         return outs
 
-    def test_self_draft_bit_exact_long_accepts(self):
+    def test_self_draft_bit_exact_long_accepts(self, engines):
         """draft == target: every proposal verifies, rounds accept the
         whole chunk — and outputs still exactly match non-speculative
         greedy AND the dense reference."""
         model, params = _model()
         rng = np.random.default_rng(3)
         prompts = _prompts(rng, [5, 9, 3, 12, 7])
-        reg = obs.MetricsRegistry()
-        base = self._run(model, params, prompts, 7)
-        spec = self._run(model, params, prompts, 7, draft_model=model,
-                         draft_params=params, spec_k=4, registry=reg)
+        reg = engines(True)._reg
+        before = reg.snapshot()
+        base = self._run(model, params, prompts, 7, eng=engines(False))
+        spec = self._run(model, params, prompts, 7, eng=engines(True))
         for p, b, s in zip(prompts, base, spec):
             np.testing.assert_array_equal(s, b)
             np.testing.assert_array_equal(
                 s, _dense_reference(model, params, p, 7))
-        prop = reg.counter("serving_spec_proposed_total").value()
-        acc = reg.counter("serving_spec_accepted_total").value()
+        snap = moved(reg, before)
+        prop = snap["serving_spec_proposed_total"]
+        acc = snap["serving_spec_accepted_total"]
         assert prop > 0 and acc == prop     # perfect draft: all accepted
 
-    def test_weak_draft_bit_exact_constant_rollback(self):
+    def test_weak_draft_bit_exact_constant_rollback(self, engines):
         """A random small draft never matches: every round rolls back
         to the single target token — exactness must survive the rewind
         (stale K/V behind the cursor, overwritten next round)."""
@@ -95,7 +99,7 @@ class TestSpeculativeParity:
         rng = np.random.default_rng(5)
         prompts = _prompts(rng, [6, 11, 4])
         reg = obs.MetricsRegistry()
-        base = self._run(model, params, prompts, 8)
+        base = self._run(model, params, prompts, 8, eng=engines(False))
         spec = self._run(model, params, prompts, 8, draft_model=dmodel,
                          draft_params=dparams, spec_k=4, registry=reg)
         for b, s in zip(base, spec):
@@ -104,7 +108,7 @@ class TestSpeculativeParity:
         acc = reg.counter("serving_spec_accepted_total").value()
         assert prop > 0 and acc < prop      # rollback really happened
 
-    def test_early_eos_truncates_accepted_run(self):
+    def test_early_eos_truncates_accepted_run(self, engines):
         """EOS inside an accepted chunk stops the request exactly where
         sequential decoding would."""
         model, params = _model()
@@ -114,8 +118,7 @@ class TestSpeculativeParity:
         eos = int(full[3])
         stop = int(np.argmax(full == eos)) + 1
         out = self._run(model, params, [prompt], 12, eos_id=eos,
-                        draft_model=model, draft_params=params,
-                        spec_k=4)[0]
+                        eng=engines(True))[0]
         np.testing.assert_array_equal(out, full[:stop])
 
     def test_int8_cache_speculative_matches_int8_plain(self):

@@ -10,7 +10,6 @@ import gc
 import json
 import sys
 import threading
-import time
 import urllib.error
 import urllib.request
 
@@ -915,7 +914,9 @@ def _part_seconds(reg):
 
 
 class TestEngineStepPhases:
-    def _traced_run(self):
+    @pytest.fixture(scope="class")
+    def traced_run(self):
+        """One warmed engine's traced run, read by three tests."""
         reg = obs.MetricsRegistry()
         tr = tracing.Tracer(capacity=8192)
         eng = _tiny_engine(registry=reg, tracer=tr)
@@ -936,8 +937,8 @@ class TestEngineStepPhases:
         det.check()
         return eng, reg, tr, det
 
-    def test_span_tree_of_a_step_is_the_table(self):
-        eng, reg, tr, det = self._traced_run()
+    def test_span_tree_of_a_step_is_the_table(self, traced_run):
+        eng, reg, tr, det = traced_run
         assert det.recompiles == 0          # spans never touch jit
         spans = tr.spans()
         by_id = {s.span_id: s for s in spans}
@@ -974,8 +975,9 @@ class TestEngineStepPhases:
         rnd = next(s for s in spans if s.name == "serving.decode_round")
         assert {"width", "slots_live"} <= set(rnd.attrs)
 
-    def test_request_children_name_the_call_that_caused_them(self):
-        eng, reg, tr, det = self._traced_run()
+    def test_request_children_name_the_call_that_caused_them(self,
+                                                             traced_run):
+        eng, reg, tr, det = traced_run
         spans = tr.spans()
         calls = {s.span_id for s in spans if s.name == "serving.prefill_call"}
         rounds = {s.span_id for s in spans
@@ -1003,8 +1005,8 @@ class TestEngineStepPhases:
         # the counters at the same boundaries are on all the same
         assert sum(_part_seconds(reg).values()) > 0
 
-    def test_parts_sum_to_at_most_the_step_seconds(self):
-        eng, reg, tr, det = self._traced_run()
+    def test_parts_sum_to_at_most_the_step_seconds(self, traced_run):
+        eng, reg, tr, det = traced_run
         snap = reg.snapshot()
         parts = _part_seconds(reg)
         assert len(parts) == 11 and all(v >= 0 for v in parts.values())

@@ -24,6 +24,19 @@ import jax
 import jax.numpy as jnp
 
 
+def sizes_of(cfg, **over):
+    """The published keys the reference reads, from a program config."""
+    sizes = {k: getattr(cfg, k) for k in (
+        "hidden_size", "num_hidden_layers", "num_attention_heads",
+        "num_key_value_heads", "head_dim", "rms_norm_eps", "sliding_window",
+        "num_experts_per_tok", "routed_scaling_factor", "norm_topk_prob")}
+    sizes.update(layer_types=list(cfg.layer_types),
+                 mlp_layer_types=list(cfg.mlp_layer_types),
+                 rope_parameters={"rope_theta": cfg.rope_theta},
+                 expert_offset=cfg.expert_offset, **over)
+    return sizes
+
+
 def _f32(a):
     return a.astype(jnp.float32)
 
