@@ -24,11 +24,14 @@ from paddle_tpu.nn.module import Layer, LayerList, StackedLayers
 from paddle_tpu.nn.transformer import ACT_SPEC, TransformerEncoderLayer, _constrain
 from paddle_tpu.ops import activation as ops_act
 from paddle_tpu.ops import attention as ops_attn
+from paddle_tpu.ops import labelled_nll as ops_nll
 
 #: the ``jax.named_scope`` names this model opens beside the blocks' own
 #: (``nn.transformer.BLOCK_SCOPES``); ``mlm_head`` covers the transform,
-#: LayerNorm and decoder matmul AND the float32 log-softmax and masked
-#: sum of :meth:`BertForPretraining.loss` (PERF.md section 3)
+#: LayerNorm and decoder matmul at every position in
+#: :meth:`BertPretrainingHeads.forward` (inference), and in
+#: :meth:`BertForPretraining.loss` the walk of ``ops.labelled_nll`` over
+#: the labelled positions, forward and backward (PERF.md section 3)
 MODEL_SCOPES = ("embeddings", "pooler", "mlm_head", "nsp_head")
 
 
@@ -214,7 +217,12 @@ class BertModel(Layer):
 
 
 class BertPretrainingHeads(Layer):
-    """MLM head (transform + tied-embedding decoder) + NSP head."""
+    """MLM head (transform + tied-embedding decoder) + NSP head.
+
+    :meth:`forward` gives the MLM logits at EVERY position: the inference
+    surface. Training does not go through it: :meth:`mlm_nll` hands the
+    same transform and decoder to ``ops.labelled_nll``, which computes
+    them at the labelled positions only."""
 
     def __init__(self, cfg: BertConfig):
         super().__init__()
@@ -226,16 +234,33 @@ class BertPretrainingHeads(Layer):
             sharding=P("tp"))
         self.nsp = Linear(cfg.hidden_size, 2, sharding=None)
 
+    def mlm_transform(self, params, rows):
+        """Hidden rows ``(..., D)`` -> the decoder's input rows."""
+        h = ops_act.gelu(self.transform(params["transform"], rows))
+        return self.ln(params["ln"], h)
+
+    def nsp_logits(self, params, pooled_output):
+        with jax.named_scope("nsp_head"):
+            return self.nsp(params["nsp"], pooled_output)
+
     def forward(self, params, sequence_output, pooled_output, word_table):
         with jax.named_scope("mlm_head"):
-            h = ops_act.gelu(
-                self.transform(params["transform"], sequence_output))
-            h = self.ln(params["ln"], h)
+            h = self.mlm_transform(params, sequence_output)
             mlm_logits = jnp.einsum("bsd,vd->bsv", h, word_table) \
                 + params["decoder_bias"]
-        with jax.named_scope("nsp_head"):
-            nsp_logits = self.nsp(params["nsp"], pooled_output)
-        return mlm_logits, nsp_logits
+        return mlm_logits, self.nsp_logits(params, pooled_output)
+
+    def mlm_nll(self, params, sequence_output, word_table, mlm_labels,
+                mlm_mask):
+        """-> (masked sum of the MLM negative log-likelihoods, the mask's
+        count, the share of the ``B x S`` rows the head computed): what
+        :meth:`forward`'s logits would give, from the labelled rows alone
+        (``ops.labelled_nll``)."""
+        with jax.named_scope("mlm_head"):
+            return ops_nll.labelled_nll(
+                sequence_output, word_table, params["decoder_bias"],
+                mlm_labels, mlm_mask, head=self.mlm_transform,
+                head_params={k: params[k] for k in ("transform", "ln")})
 
 
 class BertForPretraining(Layer):
@@ -257,21 +282,28 @@ class BertForPretraining(Layer):
     def loss(self, params, input_ids, token_type_ids, attention_mask,
              mlm_labels, mlm_mask, nsp_labels, *, key=None, training=True):
         """mlm_labels: (B,S) target ids; mlm_mask: (B,S) 1.0 where masked;
-        nsp_labels: (B,). Returns (loss, metrics)."""
-        mlm_logits, nsp_logits = self.forward(
-            params, input_ids, token_type_ids, attention_mask,
-            key=key, training=training)
+        nsp_labels: (B,). Returns (loss, metrics).
+
+        The MLM loss is the mean over the labelled positions of the
+        float32 negative log-likelihood, as :meth:`forward`'s logits at
+        every position would give it masked afterwards; it is computed
+        by :meth:`BertPretrainingHeads.mlm_nll` from the labelled rows
+        alone, for any mask, and the ``(B, S, vocab)`` logits are never
+        formed. ``metrics["mlm_head_rows_share"]`` is the share of the
+        ``B x S`` rows the head computed (a device scalar)."""
+        seq, pooled = self.bert(params["bert"], input_ids, token_type_ids,
+                                attention_mask, key=key, training=training)
+        word_table = params["bert"]["embeddings"]["word"]["weight"]
+        nll_sum, count, rows_share = self.heads.mlm_nll(
+            params["heads"], seq, word_table, mlm_labels, mlm_mask)
         with jax.named_scope("mlm_head"):
-            mlm_lp = jax.nn.log_softmax(mlm_logits.astype(jnp.float32),
-                                        axis=-1)
-            mlm_nll = -jnp.take_along_axis(
-                mlm_lp, mlm_labels[..., None], axis=-1)[..., 0]
-            denom = jnp.maximum(mlm_mask.sum(), 1.0)
-            mlm_loss = (mlm_nll * mlm_mask).sum() / denom
+            mlm_loss = nll_sum / jnp.maximum(count, 1.0)
+        nsp_logits = self.heads.nsp_logits(params["heads"], pooled)
         with jax.named_scope("nsp_head"):
             nsp_lp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32),
                                         axis=-1)
             nsp_loss = -jnp.take_along_axis(
                 nsp_lp, nsp_labels[:, None], axis=-1).mean()
         loss = mlm_loss + nsp_loss
-        return loss, {"mlm_loss": mlm_loss, "nsp_loss": nsp_loss}
+        return loss, {"mlm_loss": mlm_loss, "nsp_loss": nsp_loss,
+                      "mlm_head_rows_share": rows_share}

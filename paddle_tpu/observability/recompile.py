@@ -60,7 +60,11 @@ _count = 0
 #: makes room for one of those: the BERT cell's evaluation program is
 #: within a megabyte of the budget on either side from one PR to the
 #: next (33.9 MB, then 32.9: PERF.md section 6, PR 41) and, once under
-#: it, pushed the 29 MB step program out and stayed loaded itself.
+#: it, pushed the 29 MB step program out and stayed loaded itself. The
+#: NEWEST step program is held whatever its size: it is loaded for as
+#: long as the trainer runs it, and its size is the compiler's choice
+#: (the BERT cell's is 29 MB compiled against a full chip and 133 MB
+#: with 4 GB to spare: PERF.md section 6, PR 47).
 _RECENT = 64
 _HELD_CODE_BYTES = 32 << 20
 _held_code_bytes: Optional[int] = _HELD_CODE_BYTES
@@ -73,8 +77,12 @@ def expect_step_program() -> None:
     """The program being traced is a step program: the next programs
     the listener meets are held in preference to any other (called by
     ``train.build_train_step``'s step while it is traced; nothing runs
-    a step)."""
+    a step). What is loaded already is met first, unmarked: a listener
+    installed after a trainer's set-up programs would otherwise meet
+    them together with the step program and mark them all."""
     global _step_traced
+    _step_traced = False
+    _look()
     _step_traced = True
 
 
@@ -172,14 +180,19 @@ def _hold(rec: LoadedProgram) -> None:
     """``rec`` into ``_recent``, the oldest out until the held programs'
     code fits the budget, where there is one (with ``_lock`` held):
     the programs that are no step's first, ``rec`` among them, so a
-    one-off program never pushes a step program out."""
+    one-off program never pushes a step program out; then the older
+    step programs; never the newest one, whatever its size."""
     budget = _held_code_bytes
-    if budget is not None and rec.code_bytes > budget:
+    if budget is not None and rec.code_bytes > budget and not rec.step:
         return
     _recent.append(rec)
     while budget is not None \
             and sum(r.code_bytes for r in _recent) > budget:
-        _recent.remove(next((r for r in _recent if not r.step), _recent[0]))
+        may_go = [r for r in _recent if not r.step] \
+            or [r for r in _recent if r.step][:-1]
+        if not may_go:
+            break
+        _recent.remove(may_go[0])
 
 
 def loaded_programs() -> List[LoadedProgram]:

@@ -27,6 +27,13 @@ from paddle_tpu.train import build_train_step, make_train_state
      "dot_general"),
     ("jit(step)/transpose(jvp(forward))/mlm_head/jit(inner)/mul", "backward",
      "mlm_head", "jit(inner)/mul"),
+    # the walk over the labelled positions (ops.labelled_nll, PR 47): a
+    # loop under the scope, and in its backward a vjp taken inside the body
+    ("jit(step)/jvp(forward)/mlm_head/while/body/gbcd,vd->gbcv/dot_general",
+     "forward", "mlm_head", "while/body/gbcd,vd->gbcv/dot_general"),
+    ("jit(step)/transpose(jvp(forward))/mlm_head/while/body/"
+     "vmap(transpose(jvp()))/dot_general", "backward", "mlm_head",
+     "while/body/vmap(transpose(jvp()))/dot_general"),
     ("jit(step)/optimizer/mul", "optimizer", "", "mul"),
     ("jit(f)/while/body/attend/jit(_take)/gather", "", "attend",
      "jit(_take)/gather"),
@@ -37,8 +44,9 @@ from paddle_tpu.train import build_train_step, make_train_state
     # the first scope wins; one nested under it is the rest
     ("jit(f)/attn_in/ffn/add", "", "attn_in", "ffn/add"),
     ("", "", "", ""),
-], ids=["forward", "backward", "optimizer", "nested-jit", "jit-named-like-a-"
-        "scope", "phase-only", "first-scope-wins", "no-metadata"])
+], ids=["forward", "backward", "forward-loop", "backward-loop-vjp",
+        "optimizer", "nested-jit", "jit-named-like-a-scope", "phase-only",
+        "first-scope-wins", "no-metadata"])
 def test_an_op_name_splits_into_phase_scope_and_rest(op_name, phase, scope,
                                                      rest):
     got = scopes.split_op_name(op_name)
@@ -224,6 +232,14 @@ def test_a_one_off_program_never_pushes_a_step_program_out(monkeypatch):
     # among step programs the oldest goes first
     recompile._hold(recompile.LoadedProgram(object(), 5, 20 * mb, True))
     assert [r.seq for r in recompile._recent] == [5]
+    # the newest step program is held whatever its size (the BERT cell's
+    # is 133 MB of code once the chip has memory to spare), alone
+    recompile._hold(recompile.LoadedProgram(object(), 6, 133 * mb, True))
+    assert [r.seq for r in recompile._recent] == [6]
+    recompile._hold(recompile.LoadedProgram(object(), 7, 2 * mb, False))
+    assert [r.seq for r in recompile._recent] == [6]
+    recompile._hold(recompile.LoadedProgram(object(), 8, 30 * mb, True))
+    assert [r.seq for r in recompile._recent] == [8]
 
 
 def test_a_traced_train_step_marks_the_program_compiled_from_it():
@@ -238,22 +254,28 @@ from paddle_tpu import optimizer as opt
 from paddle_tpu.observability import recompile
 from paddle_tpu.train import build_train_step
 sgd = opt.SGD(0.1)
-step = build_train_step(lambda params: jnp.sum(params * params), sgd)
 params = jnp.ones((2,))
 state = {"params": params, "opt": sgd.init(params),
          "step": jnp.zeros((), jnp.int32)}
+# a set-up program, loaded before the listener is there and nothing
+# compiled between: met together with the step program, and marked
+# like it, if the trace did not look at what is loaded first
+early = jax.jit(lambda x: x - 3.0).lower(params).compile()
+step = build_train_step(lambda params: jnp.sum(params * params), sgd)
 compiled = jax.jit(step).lower(state).compile()
 other = jax.jit(lambda x: x * 2.0 + 1.0).lower(params).compile()
 marks = {id(r.handle): r.step for r in recompile._recent}
 print("MARKS", marks[id(compiled.runtime_executable())],
-      marks[id(other.runtime_executable())])
+      marks[id(other.runtime_executable())],
+      marks[id(early.runtime_executable())])
 """
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu",
                           "PYTHONPATH": root})
-    assert "MARKS True False" in out.stdout, out.stdout + out.stderr[-2000:]
+    assert "MARKS True False False" in out.stdout, \
+        out.stdout + out.stderr[-2000:]
 
 
 def test_no_text_is_produced_unless_a_table_is_asked_for(monkeypatch):
@@ -308,13 +330,23 @@ def test_a_tiny_bert_step_has_every_scope_forward_and_backward(tmp_path):
     for phase in ("forward", "backward"):
         assert {f"{phase}/{n}" for n in names} <= keys, phase
     assert "optimizer/" in keys
+    # the MLM head is a walk over the labelled positions: both loops'
+    # bodies are keyed to the scope their caller opened, the backward's
+    # (a custom_vjp's, traced at transposition) under ``backward``
+    for phase in ("forward", "backward"):
+        assert any(sc.key == f"{phase}/mlm_head"
+                   and sc.rest.startswith("while/body/")
+                   for sc in table.scopes.values()), phase
     # nothing of the encoder under no scope: what the forward leaves
     # bare is the model's own key split and padding bias and the sum of
-    # the two losses
+    # the two losses; ``jit(<lambda>)`` leads the policy's cast of the
+    # gradients back to float32 (``transpose(jvp())``, outside ``forward``),
+    # which stands alone where it follows the head's backward loop
     bare = {sc.rest.split("/")[0] for sc in table.scopes.values()
             if sc.key in ("forward/", "backward/")}
     assert bare <= {"jit(_threefry_split)", "jit(_where)", "slice",
-                    "squeeze", "broadcast_in_dim", "add", ""}, bare
+                    "squeeze", "broadcast_in_dim", "add", "jit(<lambda>)",
+                    ""}, bare
     # the flash dispatch itself is under none of the blocks' scopes: a
     # scope AROUND it would rename the unnamed kernels after itself
     # (tests/test_chip_compile.py compiles the real one)
@@ -326,7 +358,9 @@ def test_a_tiny_bert_step_has_every_scope_forward_and_backward(tmp_path):
             st, _m = compiled(st, batch)
         jax.block_until_ready(st)
     by_scope = profiler.device_time_by_scope(str(tmp_path))
-    assert by_scope["backward/ffn"] > 0 and by_scope["forward/mlm_head"] > 0
+    assert by_scope["backward/ffn"] > 0
+    assert by_scope["forward/mlm_head"] > 0
+    assert by_scope["backward/mlm_head"] > 0
     assert by_scope.get(scopes.UNATTRIBUTED, 0.0) \
         < 0.05 * sum(by_scope.values())
     assert "backward/ffn" in profiler.format_by_scope(by_scope)
