@@ -21,9 +21,11 @@ configured; weights random from ``--seed``), in ONE process:
    widths (32 query heads over 4 KV heads of 128, 16 indexer heads of 64,
    top-2048, 128 experts of 768, the whole vocabulary; ONE layer): the
    indexer, the sparse paged kernels and the grouped expert kernel
-   against their ``lax_fn``, then one request of 2304 prompt tokens
-   through the engine (dense kernels up to 2048 cached tokens, selection
-   past them), every chosen token within ``SPARSE_TIE_MARGIN`` of the plain
+   against their ``lax_fn``, the selection mask against the ``lax.top_k``
+   statement of its rule at the docs cell's geometry (0 elements may
+   differ) and its kernel timed alone, then one request of 2304 prompt
+   tokens through the engine (dense kernels up to 2048 cached tokens,
+   selection past them), every chosen token within ``SPARSE_TIE_MARGIN`` of the plain
    float32 reference's best (``benchmark/families/keye_vl2.py``);
 5. **hybrid family** — ``models/hybrid_ssm_lm.py`` at its published
    widths (20 query heads over 4 KV heads of 128, 32 mixer heads of 128
@@ -508,6 +510,7 @@ def phase_sparse_family(sizes, seed):
             out, ref, atol=spec.contract.atol, rtol=spec.contract.rtol,
             err_msg=f"{name} {impl} vs lax")
     log("sparse family kernels vs lax max|err|: " + json.dumps(errs))
+    _selection_at_the_cells_geometry(cfg, sizes, seed)
 
     # -- a short serve through the engine, against the plain reference
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
@@ -551,6 +554,90 @@ def phase_sparse_family(sizes, seed):
         f"logits (tolerance {SPARSE_TIE_MARGIN})")
     assert gaps.max() < SPARSE_TIE_MARGIN, \
         "sparse family left the reference"
+
+
+def _selection_at_the_cells_geometry(cfg, sizes, seed):
+    """The engine's selection mask (``topk_selection_mask``) against the
+    ``lax.top_k`` statement of the rule at the docs cell's geometry, a
+    decode call's 32 rows and a prefill call's 4 x 64 of 128 pages, over
+    score sets drawn like index scores (a weighted sum of relu'd
+    products: exact zeros), one of them quantised so that ties cross the
+    threshold: 0 elements may differ. Then the kernel alone beside
+    ``lax.top_k`` on ``(S, T)``: 16 calls in one program, each call's
+    scores made from the mask before it, so that what feeds a call is in
+    the chain and no call is shared; the feeding alone is timed too."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving import sparse_attention as SA
+    impl, topk = sizes.kernel_impl, cfg.indexer_topk
+    slots, lanes, calls = (4, 2, 2) if sizes.interpret else (32, 4, 16)
+    c, t = sizes.sparse_chunk, 128 * sizes.sparse_page_size
+    rng = np.random.default_rng(seed)
+
+    def scores_of(rows, kind):
+        # four heads: a sixteenth of the scores are zeros, of the sign
+        # of the head weights where those agree
+        w = rng.standard_normal((rows, 1, 4)).astype(np.float32)
+        x = (w * np.maximum(rng.standard_normal((rows, t, 4)).astype(
+            np.float32), 0.0)).sum(-1)
+        x = np.round(x * 4) / 4 if kind == "ties" else x
+        return jnp.asarray(x, jnp.float32)
+
+    decode = jax.jit(lambda x, n: SA.select_decode_mask(x, n, topk,
+                                                        impl=impl))
+    prefill = jax.jit(lambda x, st: SA.select_prefill(x, st, None, topk,
+                                                      impl=impl))
+    by_sort = jax.jit(lambda x, n: SA.selected_by_sort(x, n, topk))
+    differing = {}
+    for kind in ("ties", "drawn", "drawn again"):
+        n = rng.integers(topk + 1, t + 1, slots)
+        n[:3] = (t, topk + 1, topk)
+        x, n = scores_of(slots, kind), jnp.asarray(n, jnp.int32)
+        got, want = np.asarray(decode(x, n)), np.asarray(by_sort(x, n))
+        assert want.sum(1).tolist() == [topk] * slots
+        starts = rng.integers(0, t - c + 1, lanes)
+        starts[:2] = (t - c, topk - c // 2)
+        x = scores_of(lanes * c, kind).reshape(lanes, c, t)
+        st = jnp.asarray(starts, jnp.int32)
+        got_p = np.asarray(prefill(x, st))
+        want_p = np.asarray(by_sort(x, st[:, None] + jnp.arange(1, c + 1)))
+        differing[kind] = [int((got != want).sum()), got.size,
+                           int((got_p != want_p).sum()), got_p.size]
+    log("selection mask vs lax.top_k, elements differing [decode, of, "
+        "prefill, of]: " + json.dumps(differing))
+    assert all(d[0] == d[2] == 0 for d in differing.values()), differing
+
+    x0 = scores_of(slots, "drawn")
+    n = jnp.full((slots,), t, jnp.int32)
+
+    def chain(select):
+        def run(x):
+            for _ in range(calls):
+                x = x + 0.5 * select(x)
+            return x
+        return jax.jit(run)
+
+    def top_k_mask(x):
+        vals, _ = jax.lax.top_k(x, topk)
+        return (x >= vals[:, -1:]).astype(jnp.float32)
+
+    per_call = {}
+    for what, select in (
+            ("topk_selection_mask", lambda x: SA.select_decode_mask(
+                x, n, topk, impl=impl)),
+            ("lax.top_k", top_k_mask),
+            ("the feeding alone", lambda x: (x > 0).astype(jnp.float32))):
+        run = chain(select)
+        run(x0).block_until_ready()
+        best = math.inf
+        for _ in range(5):
+            t0 = time.perf_counter()
+            run(x0).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        per_call[what] = round(best / calls * 1e6, 1)
+    log(f"selection alone, ({slots}, {t}) for {topk}, us a call in a chain "
+        f"of {calls}" + (" (interpreted: no device time)" if sizes.interpret
+                         else "") + ": " + json.dumps(per_call))
 
 
 def _serve_slot_state_family(what, model, family, kernel_names, sizes, seed,

@@ -42,7 +42,7 @@ from paddle_tpu.models.common import rope as _rope
 from paddle_tpu.ops.attention import NEG_INF
 from paddle_tpu.ops.grouped_ffn import grouped_expert_ffn
 from paddle_tpu.serving.program import ServingSpec
-from paddle_tpu.serving.sparse_attention import select_prefill
+from paddle_tpu.serving.sparse_attention import selected_by_sort
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -205,13 +205,13 @@ class SparseMoELM:
 
     def forward(self, params, ids):
         """(B, S) ids -> (B, S, V) float32 logits: dense causal scores
-        with each query's selection as a mask, no cache."""
+        with each query's selection as a mask, no cache. The selection
+        is the sort's (``lax.top_k``), not the engine's counting kernel:
+        the pass the engine is judged by shares no code with it."""
         c = self.cfg
         b, n = ids.shape
         pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (b, n))
         x = self.embed(params, ids, pos)
-        zeros = jnp.zeros((b,), jnp.int32)
-        full = jnp.full((b,), n, jnp.int32)
         g = c.num_attention_heads // c.num_key_value_heads
         for i in range(c.num_hidden_layers):
             q, (k, v, k_idx), (q_idx, w_idx) = self.attn_in(params, i, x, pos)
@@ -221,8 +221,8 @@ class SparseMoELM:
             scores = scale * jnp.einsum("bqj,bqjk->bqk", w_idx,
                                         jnp.maximum(dots, 0.0),
                                         precision=_HI)
-            keep = select_prefill(scores, zeros, full,
-                                  min(c.indexer_topk, n)) > 0
+            keep = selected_by_sort(scores, pos + 1,
+                                    min(c.indexer_topk, n)) > 0
             kh = jnp.repeat(k.reshape(b, n, -1, c.head_dim), g, axis=2)
             vh = jnp.repeat(v.reshape(b, n, -1, c.head_dim), g, axis=2)
             att = jnp.einsum("bhqd,bkhd->bhqk", q, kh, precision=_HI,

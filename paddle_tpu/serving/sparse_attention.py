@@ -20,7 +20,7 @@ Index score of query ``t`` against cached token ``s``::
 
     I[t, s] = scale * sum_j w[t, j] * relu(qI[t, j] . kI[s])
 
-Three kernels register with the shared kernel layer:
+Four kernels register with the shared kernel layer:
 
 ``lightning_indexer`` — the scores of ``C`` queries a slot against every
   cached token of the slot's pages, ``(S, C, mp * page_size)`` float32
@@ -42,11 +42,18 @@ Three kernels register with the shared kernel layer:
   selection: the paged prefill body with the selection as one more
   streamed input, applied beside the causal test inside the fold.
 
-Selection itself is XLA (``lax.top_k``: ties go to the lower position),
-handed on as a mask by one rule for both (:func:`_chosen`: the scores
-against the value of the last of the top-k). A query at position
-``p`` with ``p + 1 <= topk`` attends to everything it can see, as the
-model defines.
+``topk_selection_mask`` — the selection, one rule for decode and
+  prefill, as the mask both attention kernels take: ``(R, T)`` scores and
+  how many of them each row sees -> ``(R, T)`` float32, 1 at the
+  ``topk`` best (ties to the lower position), at all it sees where those
+  are no more (a query at position ``p`` with ``p + 1 <= topk`` attends
+  to everything it can see, as the model defines). No sort: a mask wants
+  the VALUE of the ``topk``-th largest score, which 32 compare-and-count
+  passes over a row's order-preserving integer keys give exactly, and 14
+  more the last position taken at it. Pallas: a block of rows whole in
+  VMEM, read once, written once. The reference forward, the selection
+  replay (:func:`select_decode`) and the tests state the same rule with
+  ``lax.top_k`` (:func:`selected_by_sort`), and share no code with it.
 """
 
 from __future__ import annotations
@@ -257,58 +264,230 @@ def _indexer_vmem_estimate(args, kwargs, blocks):
 
 
 # ---------------------------------------------------------------------------
-# selection (XLA)
+# selection
 # ---------------------------------------------------------------------------
+#
+# The rule, for a row of scores of which the first ``n`` are visible: all
+# of them while ``n <= topk``; else the ``topk`` of largest score, ``-0.0``
+# and ``+0.0`` one value, ties to the lower position: exactly ``topk``.
+# It is stated twice. By sorting (``lax.top_k``: :func:`select_decode`,
+# :func:`selected_by_sort`), for the reference forward, the selection
+# replay and the tests. And by counting (:func:`_bisect`), for the engine:
+# the kernels want a mask, never an order, so the value of the
+# ``topk``-th largest score is built a bit at a time from 32 counts over
+# the row, the last position taken at that value from 14 more, and the
+# mask is one comparison with the two. Scores are finite.
+
+_KEY_MIN = jnp.iinfo(jnp.int32).min
+
+
+def _visible_scores(scores, n):
+    """``scores`` (..., T), ``n`` (...,) -> (the positions, which of them
+    each row sees, its scores with ``-inf`` at the others). ``lax.top_k``
+    orders ``-0.0`` below ``+0.0``; here they are one value."""
+    tok = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+    seen = tok < n[..., None]
+    return tok, seen, jnp.where(
+        seen, jnp.where(scores == 0.0, 0.0, scores), -jnp.inf)
+
 
 def select_decode(scores, lengths, topk):
-    """Decode's selection: ``scores`` (S, T) of one query a slot over
-    ``lengths[s]`` live tokens -> (indices (S, topk) best first, n (S,)
-    how many of them are live). A slot of at most ``topk`` tokens selects
-    all of them."""
-    tok = jnp.arange(scores.shape[-1], dtype=jnp.int32)
-    masked = jnp.where(tok[None, :] < lengths[:, None], scores, -jnp.inf)
+    """Decode's selection by sorting: ``scores`` (S, T) of one query a
+    slot over ``lengths[s]`` live tokens -> (indices (S, topk) best
+    first, n (S,) how many of them are live). A slot of at most ``topk``
+    tokens selects all of them."""
+    _tok, _seen, masked = _visible_scores(scores, lengths)
     _vals, idx = jax.lax.top_k(masked, topk)
     return idx.astype(jnp.int32), jnp.minimum(lengths, topk)
 
 
-def _chosen(masked, topk, tok):
-    """``masked`` (..., T) scores, ``-inf`` where the query cannot see,
-    ``tok`` the positions ``arange(T)`` -> bool (..., T), the ``topk``
-    best: above the value of the last of the top-k, or at it and no
-    later than the last position ``lax.top_k`` took there (ties go to
-    the lower position)."""
+def selected_by_sort(scores, n, topk):
+    """The rule by sorting, the reference statement of it: ``scores``
+    (..., T), ``n`` (...,) visible -> (..., T) float32, 1 at the ``topk``
+    positions ``lax.top_k`` returns (above the value of the last of them,
+    or at it and no later than the last position taken there), at every
+    visible position where ``n <= topk``."""
+    tok, seen, masked = _visible_scores(scores, n)
     vals, idx = jax.lax.top_k(masked, topk)
     thr = vals[..., -1:]
     last_tie = jnp.max(jnp.where(vals == thr, idx, -1), axis=-1,
                        keepdims=True)
-    return (masked > thr) | ((masked == thr) & (tok <= last_tie))
+    chosen = (masked > thr) | ((masked == thr) & (tok <= last_tie))
+    return (seen & ((n <= topk)[..., None] | chosen)).astype(jnp.float32)
 
 
-def select_decode_mask(scores, lengths, topk):
-    """:func:`select_decode`'s selection as a mask, by the rule chunked
-    prefill has (:func:`select_prefill`), with no scatter of the indices:
-    ``scores`` (S, T) -> (S, T) float32, 1 at the tokens the slot's
-    query attends to, none at or past ``lengths[s]``."""
-    tok = jnp.arange(scores.shape[-1], dtype=jnp.int32)
-    live = tok[None, :] < lengths[:, None]
-    chosen = _chosen(jnp.where(live, scores, -jnp.inf), topk, tok)
-    everything = (lengths <= topk)[:, None]
-    return (live & (everything | chosen)).astype(jnp.float32)
+def _order_keys(scores, seen):
+    """float32 scores -> int32 keys of the same order: the bit pattern,
+    the sign folded; ``-0.0`` (the one pattern that lands on -1) given
+    ``+0.0``'s key, as IEEE ``==`` has them; below every score where the
+    row cannot see."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    key = bits ^ ((bits >> 31) & 0x7FFFFFFF)
+    return jnp.where(seen, jnp.where(key == -1, 0, key), _KEY_MIN)
 
 
-def select_prefill(scores, chunk_starts, n_valid, topk):
+def _bisect(count, rows, topk, t):
+    """The key of the ``topk``-th largest entry of each of ``rows`` rows
+    and the last position taken at that key, (rows, 1) int32 each.
+    ``count(hit)`` -> (rows, 1) int32, how many of a row's entries
+    ``hit(keys, positions)`` holds for. A row that sees fewer than
+    ``topk`` gets the lowest key."""
+    def key_bit(i, thr):
+        # bit 31 first: the lowest key has it set, and clearing it is
+        # the one step up that a set bit is for the other 31
+        cand = thr ^ (jnp.int32(1) << (31 - i))
+        enough = count(lambda key, pos: key >= cand) >= topk
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, key_bit,
+                            jnp.full((rows, 1), _KEY_MIN, jnp.int32))
+    # what the entries above the threshold leave goes to the lowest
+    # positions that hold it: the largest position with fewer before it
+    need = topk - count(lambda key, pos: key > thr)
+    bits = max(t - 1, 1).bit_length()
+
+    def pos_bit(i, last):
+        cand = last | (jnp.int32(1) << (bits - 1 - i))
+        before = count(lambda key, pos: (key == thr) & (pos < cand))
+        return jnp.where(before < need, cand, last)
+
+    return thr, jax.lax.fori_loop(0, bits, pos_bit, jnp.zeros_like(thr))
+
+
+def _selected(key, pos, n, thr, last, topk):
+    seen = pos < n
+    return (seen & ((n <= topk) | (key > thr)
+                    | ((key == thr) & (pos <= last)))).astype(jnp.float32)
+
+
+def _selection_lax(scores, n, *, topk):
+    t = scores.shape[1]
+    pos = jnp.arange(t, dtype=jnp.int32)[None, :]
+    n = n.astype(jnp.int32)[:, None]
+    key = _order_keys(scores, pos < n)
+
+    def count(hit):
+        return jnp.sum(hit(key, pos), axis=1, keepdims=True,
+                       dtype=jnp.int32)
+
+    thr, last = _bisect(count, scores.shape[0], topk, t)
+    return _selected(key, pos, n, thr, last, topk)
+
+
+def _lane_chunk(t):
+    """The lanes one count adds up at a time: several registers a row
+    block, so that the adds of a pass are no one chain."""
+    return next((w for w in (512, 256, 128) if t % w == 0), t)
+
+
+def _selection_kernel(n_ref, x_ref, o_ref, key_ref, *, topk):
+    """A block of rows, whole: ``x_ref`` (rows, T) scores read once into
+    ``key_ref`` (rows, T) int32 keys, every pass of :func:`_bisect` over
+    those, ``o_ref`` (rows, T) written once. ``n_ref`` (rows, 1)."""
+    rows, t = x_ref.shape
+    w = _lane_chunk(t)
+    n = n_ref[...]
+
+    def chunk(c):
+        lanes = slice(c * w, (c + 1) * w)
+        return lanes, c * w + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, w), 1)
+
+    for c in range(t // w):
+        lanes, pos = chunk(c)
+        key_ref[:, lanes] = _order_keys(x_ref[:, lanes], pos < n)
+
+    def count(hit):
+        acc = jnp.zeros((rows, w), jnp.int32)
+        for c in range(t // w):
+            lanes, pos = chunk(c)
+            acc += hit(key_ref[:, lanes], pos).astype(jnp.int32)
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    thr, last = _bisect(count, rows, topk, t)
+    for c in range(t // w):
+        lanes, pos = chunk(c)
+        o_ref[:, lanes] = _selected(key_ref[:, lanes], pos, n, thr, last,
+                                    topk)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _selection_pallas(scores, n, topk, interpret, rows_per_block):
+    """Jitted, as the sparse decode's call is: a step program traces and
+    lowers the body once and calls it from every layer."""
+    r, t = scores.shape
+    # whole sublane tiles of rows a grid step, no more than there are
+    rows = min(rows_per_block, r + -r % 8)
+    pad = -r % rows
+    scores = jnp.pad(scores.astype(jnp.float32), ((0, pad), (0, 0)))
+    n = jnp.pad(n.astype(jnp.int32), (0, pad))[:, None]
+
+    def block(width):
+        return pl.BlockSpec((rows, width), lambda i: (i, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_selection_kernel, topk=topk),
+        grid=((r + pad) // rows,),
+        in_specs=[block(1), block(t)],
+        out_specs=block(t),
+        out_shape=jax.ShapeDtypeStruct((r + pad, t), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)) if not interpret else None,
+        interpret=interpret, name="topk_selection_mask",
+    )(n, scores)
+    return out[:r]
+
+
+def _selection_kernel_pallas(scores, n, *, block_sizes, interpret, topk):
+    return _selection_pallas(scores, n, topk, interpret,
+                             block_sizes.get("rows_per_block", 8))
+
+
+def _selection_vmem_estimate(args, kwargs, blocks):
+    """A block of scores and one of the mask, double-buffered, and the
+    keys between them."""
+    return 5 * blocks.get("rows_per_block", 8) * args[0].shape[1] * 4
+
+
+def _make_selection_sample(seed):
+    """Three shapes by ``seed % 3``; scores of a few values, so that ties
+    cross the threshold, with zeros of both signs; rows of every kind of
+    ``n``: none, fewer than ``topk``, ``topk``, one more, all, past the
+    row's end."""
+    import numpy as np
+    r, t, topk = ((5, 48, 8), (9, 256, 32), (16, 640, 100))[seed % 3]
+    rng = np.random.default_rng(seed)
+    scores = rng.choice(
+        np.asarray([-0.0, 0.0, 0.25, 0.5, 1.0, -1.0], np.float32), (r, t))
+    scores[::2] += (rng.standard_normal((-(-r // 2), t)) * 0.1).astype(
+        np.float32) * (rng.random((-(-r // 2), t)) < 0.5)
+    n = rng.integers(0, t + 1, r)
+    n[:5] = (0, topk - 1, topk, topk + 1, t + 3)[:r]
+    return (jnp.asarray(scores), jnp.asarray(n, jnp.int32)), {"topk": topk}
+
+
+def select_decode_mask(scores, lengths, topk, *, impl: str = "auto"):
+    """The rule by counting, what the engine's kernels take: ``scores``
+    (S, T) of one query a slot over ``lengths[s]`` live tokens -> (S, T)
+    float32, 1 at the tokens the slot's query attends to, none at or past
+    ``lengths[s]``: element for element what :func:`selected_by_sort`
+    marks."""
+    from paddle_tpu import kernels
+    return kernels.dispatch("topk_selection_mask", scores, lengths,
+                            impl=impl, topk=topk)
+
+
+def select_prefill(scores, chunk_starts, n_valid, topk, *,
+                   impl: str = "auto"):
     """Chunked prefill's selection: ``scores`` (S, C, T), query ``c`` of
-    slot ``s`` at position ``chunk_starts[s] + c`` -> (S, C, T) float32,
-    1 where that query may attend (beside the causal test, which the
-    attention applies again). Ties at the threshold go to the lower
-    position, as ``lax.top_k`` orders them."""
+    slot ``s`` at position ``chunk_starts[s] + c`` sees that many tokens
+    and itself -> (S, C, T) float32, 1 where that query may attend
+    (beside the causal test, which the attention applies again)."""
     s, c, t = scores.shape
-    tok = jnp.arange(t, dtype=jnp.int32)
-    pos = chunk_starts[:, None] + jnp.arange(c, dtype=jnp.int32)   # (S, C)
-    seen = tok[None, None, :] <= pos[:, :, None]
-    chosen = _chosen(jnp.where(seen, scores, -jnp.inf), topk, tok)
-    everything = (pos + 1 <= topk)[:, :, None]
-    return (seen & (everything | chosen)).astype(jnp.float32)
+    n = chunk_starts[:, None] + jnp.arange(1, c + 1, dtype=jnp.int32)
+    return select_decode_mask(scores.reshape(s * c, t), n.reshape(s * c),
+                              topk, impl=impl).reshape(s, c, t)
 
 
 # ---------------------------------------------------------------------------
@@ -857,7 +1036,7 @@ def indexed_decode_attention(q, k_pages, v_pages, ik_pages, block_tables,
     scores = lightning_index_scores(
         q_idx[:, None], w_idx[:, None], ik_pages, block_tables, lengths,
         impl=impl)[:, 0]
-    selected = select_decode_mask(scores, lengths, topk)
+    selected = select_decode_mask(scores, lengths, topk, impl=impl)
     att = selected_decode_attention(q, k_pages, v_pages, block_tables,
                                     selected, lengths, groups, impl=impl)
     return att, jnp.minimum(lengths, topk)
@@ -882,7 +1061,7 @@ def indexed_prefill_attention(q, k_pages, v_pages, ik_pages, block_tables,
     H, Dh)."""
     scores = lightning_index_scores(q_idx, w_idx, ik_pages, block_tables,
                                     chunk_starts + n_valid, impl=impl)
-    selected = select_prefill(scores, chunk_starts, n_valid, topk)
+    selected = select_prefill(scores, chunk_starts, n_valid, topk, impl=impl)
     return sparse_paged_prefill_attention(
         q, k_pages, v_pages, block_tables, chunk_starts, n_valid, selected,
         impl=impl)
@@ -954,6 +1133,27 @@ def _register():
             ("j", args[0].shape[2]), ("d", args[0].shape[3]),
             ("ps", args[2].shape[-1]), ("mp", args[3].shape[1])),
         vmem_estimate=_indexer_vmem_estimate))
+    kernels.register(kernels.KernelSpec(
+        name="topk_selection_mask",
+        contract=kernels.KernelContract(
+            version=1,
+            arg_layouts={"scores": "(R,T) f32", "n": "(R,) i32"},
+            out_layout="(R,T) f32",
+            grid="(R/rows_per_block,) a block of rows whole: the scores "
+                 "read once into int32 keys in VMEM, 32 + log2(T) "
+                 "compare-and-count passes over those, the mask written "
+                 "once",
+            block_candidates={"rows_per_block": (8, 16, 32)},
+            atol=0.0, rtol=0.0),
+        pallas_fn=_selection_kernel_pallas,
+        lax_fn=_selection_lax,
+        reference_fn=selected_by_sort,
+        sample_inputs=_make_selection_sample,
+        pallas_sites=(
+            "paddle_tpu.serving.sparse_attention:_selection_pallas",),
+        tune_signature=lambda args, kwargs: (
+            ("r", args[0].shape[0]), ("t", args[0].shape[1])),
+        vmem_estimate=_selection_vmem_estimate))
     pb_candidates = {"pages_per_block": (1, 2, 4)}
     kernels.register(kernels.KernelSpec(
         name="sparse_paged_decode",

@@ -446,11 +446,28 @@ def doc_steps(topo):
     return lower
 
 
+@pytest.mark.parametrize("rows", [DOC_SLOTS, 4 * DOC_CHUNK],
+                         ids=["decode-32rows", "prefill-4lanes-256rows"])
+def test_selection_kernel_compiles_for_v5e(rows, one_chip):
+    """``topk_selection_mask`` at the docs cell's shapes, 2,048 of
+    16,384 a row for a decode call's 32 rows and a prefill call's 4 x 64,
+    at the static prior's block: rows of whole 16,384 scores, their keys
+    and the mask resident under the default scoped-VMEM limit."""
+    sds = jax.ShapeDtypeStruct
+    args = (sds((rows, 128 * DOC_PS), jnp.float32), sds((rows,), jnp.int32))
+    text = _compile_kernel("topk_selection_mask", args, one_chip,
+                           topk=2048).as_text()
+    assert _custom_calls(text) == ["topk_selection_mask"]
+    assert '"scoped_memory_configs":[],"custom_call_config"' in text
+
+
 @pytest.mark.parametrize("step, lanes, width, kernels_in", [
     ("decode", DOC_SLOTS, 128,
-     {"lightning_indexer", "sparse_paged_decode", "moe_grouped_ffn"}),
+     {"lightning_indexer", "topk_selection_mask", "sparse_paged_decode",
+      "moe_grouped_ffn"}),
     ("prefill", 4, 128,
-     {"lightning_indexer", "sparse_paged_prefill", "moe_grouped_ffn"}),
+     {"lightning_indexer", "topk_selection_mask", "sparse_paged_prefill",
+      "moe_grouped_ffn"}),
     ("decode", DOC_SLOTS, 16, {"ragged_paged_decode", "moe_grouped_ffn"}),
     ("prefill", 4, 2, {"ragged_paged_prefill", "moe_grouped_ffn"})],
     ids=["decode-w128-selects", "prefill-4lanes-w128-selects",
@@ -469,6 +486,12 @@ def test_sparse_family_steps_compile_and_keep_the_pools(
     names = {re.sub(r"\.\d+$", "", n) for n in re.findall(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
     assert names == kernels_in
+    # the selection is counted, never sorted: the sorts left are the
+    # expert layer's (the router's 8 of 128, the pairs by expert)
+    sorts = [line for line in text.splitlines()
+             if re.search(r" (?:sort|topk)\(|custom_call_target=\"TopK\"",
+                          line)]
+    assert sorts and all("/ffn/" in line for line in sorts), sorts[:2]
     pool = rf"bf16\[{DOC_PAGES},(?:{DOC_PS},512|64,{DOC_PS})\]"
     copies = [line for line in text.splitlines()
               if re.search(r"= " + pool + r"\S* copy\(", line)]
@@ -886,7 +909,8 @@ def test_bert_base_step_keeps_the_names_the_metrics_select(one_chip):
 @pytest.mark.parametrize("family, step_args, kernels_in", [
     ("gpt2", ("decode", 64, 8), {"ragged_paged_decode": 12}),
     ("sparse", ("decode", 32, 128),
-     {"lightning_indexer": 2, "sparse_paged_decode": 4,   # two calls a layer
+     {"lightning_indexer": 2, "topk_selection_mask": 2,
+      "sparse_paged_decode": 4,                          # two calls a layer
       "moe_grouped_ffn": 2}),
     ("hybrid", ("decode", 64, 16),
      {"ragged_paged_decode": 2, "ssm_decode_update": 2}),

@@ -18,6 +18,8 @@ ONE_CHIP_PHASES = {
     "phase_serving": ["of which JAX reports", "compiles after warmup=0",
                       "token-exact vs model.generate"],
     "phase_sparse_family": ["sparse family kernels vs lax",
+                            "selection mask vs lax.top_k",
+                            "selection alone, (4, 512) for 16",
                             "tokens are the float32 reference's argmax"],
     "phase_hybrid_family": ["hybrid family kernels vs lax",
                             "hybrid family: 19-token prompt"],

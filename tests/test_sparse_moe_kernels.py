@@ -1,7 +1,8 @@
 """The sparse family's selection and kernels on their own, beside the
 engine cases of ``tests/test_sparse_moe_serving.py`` (a file of its own for
 ``--dist loadfile``): the selected set against the reference's, as a mask
-and as indices, the decode walk over shared pages, the expert layer."""
+by counting, as a mask by sorting and as indices, the decode walk over
+shared pages, the expert layer."""
 
 import jax
 import jax.numpy as jnp
@@ -103,10 +104,11 @@ SELECTIONS = {
 def test_the_mask_from_the_scores_is_the_scatter_of_the_selected_indices(
         case):
     """What the decode step hands the kernel (``select_decode_mask``:
-    scores against the value of the last of the top-k, ties to the lower
-    position, by the rule ``select_prefill`` has) marks, element for
-    element, the tokens ``select_decode``'s indices name, and what
-    ``select_prefill`` marks for a query at position ``length - 1``."""
+    scores against the value of the ``topk``-th largest, found by
+    counting, ties to the lower position, by the rule ``select_prefill``
+    has) marks, element for element, the tokens the sort's indices name
+    (``select_decode``), and what ``select_prefill`` marks for a query at
+    position ``length - 1``."""
     topk = 4
     row, n = SELECTIONS[case]
     scores = jnp.asarray([row, row[::-1]], jnp.float32)
@@ -120,6 +122,62 @@ def test_the_mask_from_the_scores_is_the_scatter_of_the_selected_indices(
     np.testing.assert_array_equal(got, np.asarray(SA.select_prefill(
         scores[:, None], lengths - 1, None, topk)[:, 0]))
     assert want.sum(1).tolist() == [min(n, topk)] * 2
+
+
+def _score_rows(kind, rows, t, seed=0):
+    """Rows of ``t`` index scores: drawn; of a handful of values, so that
+    ties cross the threshold and outnumber the places left; weighted sums
+    of relu'd products, 70% of them exact zeros of either sign; one
+    value."""
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((rows, t)).astype(np.float32)
+    if kind == "a_handful_of_values":
+        x = np.round(x)
+    elif kind == "zeros_of_both_signs":
+        x = np.maximum(x, 0.0) * (rng.random((rows, t)) < 0.3)
+        x = np.where(rng.random((rows, t)) < 0.5, -x, x)
+        assert (x == 0).mean() > 0.6 and np.signbit(x[x == 0]).any() \
+            and not np.signbit(x[x == 0]).all()
+    elif kind == "every_score_the_same":
+        x = np.full_like(x, -0.75)
+    return jnp.asarray(x)
+
+
+@pytest.mark.parametrize("shape", ["decode", "prefill"])
+@pytest.mark.parametrize("kind", [
+    "drawn", "a_handful_of_values", "zeros_of_both_signs",
+    "every_score_the_same"])
+def test_the_mask_by_counting_is_the_mask_by_sorting(kind, shape):
+    """The engine's selection (``topk_selection_mask``: the value of the
+    ``topk``-th score by bisection, its Pallas body interpreted and the
+    same in ``jnp``) marks, element for element, what the ``lax.top_k``
+    statement of the rule marks: rows of one call that see nothing, fewer
+    than ``topk``, ``topk``, one more, the whole row and anything
+    between, as decode's ``(S, T)`` and as prefill's ``(S, C, T)``; a row
+    that sees more than ``topk`` gets exactly ``topk``, zeros of either
+    sign being one value."""
+    topk, t = 24, 200
+    if shape == "decode":
+        n = np.asarray([0, 1, topk - 1, topk, topk + 1, t, 77, 150, t - 1,
+                        t + 5, 31], np.int32)
+        scores = _score_rows(kind, len(n), t)
+        got = {impl: np.asarray(SA.select_decode_mask(
+            scores, jnp.asarray(n), topk, impl=impl))
+            for impl in ("lax", "pallas_interpret")}
+    else:
+        starts = np.asarray([0, topk - 3, t - 6, 90], np.int32)
+        c = 6
+        scores = _score_rows(kind, len(starts) * c, t).reshape(
+            len(starts), c, t)
+        got = {impl: np.asarray(SA.select_prefill(
+            scores, jnp.asarray(starts), None, topk, impl=impl))
+            for impl in ("lax", "pallas_interpret")}
+        n = starts[:, None] + np.arange(1, c + 1)
+    want = np.asarray(SA.selected_by_sort(scores, jnp.asarray(n), topk))
+    for impl, mask in got.items():
+        np.testing.assert_array_equal(mask, want, err_msg=impl)
+    np.testing.assert_array_equal(want.sum(-1), np.minimum(n, topk))
+    assert not want[np.arange(t) >= n[..., None]].any()
 
 
 # -- the decode body: whole pages of the pools, walked under the selection ---
