@@ -45,7 +45,15 @@ configured; weights random from ``--seed``), in ONE process:
    (three chunks of 128, the last ragged) and 12 new ones through the
    engine, every chosen token within ``LATENT_TIE_MARGIN`` of the plain
    float32 reference's best (``benchmark/families/zaya.py``), and the
-   slot's three tails changed while its neighbours' stayed zero.
+   slot's three tails changed while its neighbours' stayed zero;
+7. **keys wider than values** — the dense paged decode and prefill
+   bodies at the widths of a model whose two kinds of layer differ in
+   their KV heads (64 query heads of 192; a full layer's pool 4 KV heads,
+   K 768 lanes beside V 512, query groups of 16; a window layer's 8, K
+   1536 beside V 1024, groups of 8, window 128, a learned sink a head in
+   the softmax) against their ``lax_fn``, bf16 and fp32 pages, within
+   each kernel contract's tolerance. ``--only wide_keys`` runs this phase
+   alone.
 
 ``--chips 4`` runs INSTEAD (no one-chip phase): BERT-base under
 ``shard_train_step`` on a dp2 x tp2 mesh against the same steps on one
@@ -126,6 +134,9 @@ class Sizes:
     hybrid_prompt: int          # whole chunks and a ragged one
     latent: dict                # LatentConvMoELMConfig overrides; page,
     #                             chunk and prompt are the hybrid's
+    #: keys wider than values: (query heads, key width, value width, page
+    #: size, window, (KV heads of a full layer, of a window layer))
+    wide_keys: tuple = (64, 192, 128, 128, 128, (4, 8))
     interpret: bool = False
 
     @classmethod
@@ -175,7 +186,7 @@ class Sizes:
                                max_position_embeddings=256, num_experts=8,
                                moe_intermediate_size=32,
                                router_hidden_size=16),
-                   interpret=True)
+                   wide_keys=(4, 24, 16, 4, 8, (1, 2)), interpret=True)
 
     @property
     def prefill_steps(self):
@@ -266,6 +277,60 @@ def phase_paged_kernels(sizes, seed):
                 out, ref, atol=contract.atol, rtol=contract.rtol,
                 err_msg=f"{name}[{label}] {sizes.kernel_impl} vs lax")
     log("paged kernels vs lax max|err|: " + json.dumps(errs))
+
+
+def phase_wide_key_kernels(sizes, seed):
+    """The dense paged bodies where a KV head's keys are wider than its
+    values and the two kinds of layer differ in KV heads: a full layer's
+    pool without a sink, a window layer's under its window with one."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import kernels
+
+    h, dk, dv, ps, window, kv_heads = sizes.wide_keys
+    s, mp = 4, 6
+    c = ps                              # a chunk is a page of queries
+    num_pages = s * mp + 1
+    rng = np.random.default_rng(seed)
+    bt = jnp.asarray(rng.permutation(num_pages - 1)[:s * mp].reshape(s, mp)
+                     + 1, jnp.int32)
+    lengths = rng.integers(1, mp * ps + 1, s)
+    lengths[0], lengths[1] = 0, ps + 3
+    lengths = jnp.asarray(lengths, jnp.int32)
+    starts = jnp.asarray(rng.integers(0, (mp - 1) * ps - c + 1, s),
+                         jnp.int32)
+    n_valid = rng.integers(1, c + 1, s)
+    n_valid[0], n_valid[-1] = 0, c
+    n_valid = jnp.asarray(n_valid, jnp.int32)
+    errs = {}
+    for kv, kw in ((kv_heads[0], {}), (kv_heads[1], dict(
+            window=window,
+            sinks=jnp.asarray(rng.standard_normal(h) + 2.0, jnp.float32)))):
+        k32, v32 = (jnp.asarray(rng.standard_normal(
+            (num_pages, ps, kv * width)), jnp.float32) for width in (dk, dv))
+        for dtype in (jnp.float32, jnp.bfloat16):
+            pool = (k32.astype(dtype), v32.astype(dtype))
+            for name, q, geo in (
+                    ("ragged_paged_decode", (s, h, dk), (lengths,)),
+                    ("ragged_paged_prefill", (s, c, h, dk),
+                     (starts, n_valid))):
+                q = jnp.asarray(rng.standard_normal(q), jnp.float32)
+                args = (q, *pool, bt, *geo)
+                contract = kernels.get(name).contract
+                out = jax.jit(lambda *a, _n=name: kernels.dispatch(
+                    _n, *a, impl=sizes.kernel_impl, **kw))(*args)
+                with jax.default_matmul_precision("highest"):
+                    ref = jax.jit(lambda *a, _n=name: kernels.dispatch(
+                        _n, *a, impl="lax", **kw))(*args)
+                out, ref = np.asarray(out), np.asarray(ref)
+                label = f"{name}[kv{kv},{jnp.dtype(dtype).name}]"
+                assert out.shape == ref.shape == q.shape[:-1] + (dv,) \
+                    and np.isfinite(out).all(), label
+                errs[label] = float(np.max(np.abs(out - ref)))
+                np.testing.assert_allclose(
+                    out, ref, atol=contract.atol, rtol=contract.rtol,
+                    err_msg=f"{label} {sizes.kernel_impl} vs lax")
+    log("wide-key paged kernels vs lax max|err|: " + json.dumps(errs))
 
 
 # ---------------------------------------------------------------------------
@@ -967,6 +1032,7 @@ def run_one_chip(sizes, seed=0):
     phase_sparse_family(sizes, seed)
     phase_hybrid_family(sizes, seed)
     phase_latent_family(sizes, seed)
+    phase_wide_key_kernels(sizes, seed)
 
 
 def run_four_chips(sizes, seed=0, devices=None):
@@ -1018,6 +1084,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--only", choices=("wide_keys",), default=None,
+                    help="one chip: this phase alone")
     args = ap.parse_args(argv)
 
     import jax
@@ -1041,6 +1109,8 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     if args.chips == 4:
         run_four_chips(Sizes.real(), args.seed, devices)
+    elif args.only:
+        phase_wide_key_kernels(Sizes.real(), args.seed)
     else:
         run_one_chip(Sizes.real(), args.seed)
     log(f"all phases passed in {time.perf_counter() - t0:.1f}s; compile "

@@ -1,6 +1,6 @@
 """Decoder-only language model whose layers mix window and full attention
-over a sigmoid-routed expert layer with a shared expert, of which a chip
-may hold a share, for the paged serving engine.
+over a sigmoid-routed expert layer (with or without a shared expert), of
+which a chip may hold a share, for the paged serving engine.
 
 Layer ``l``, float32 stream ``x`` (``D`` wide; ``H`` query heads over ``G``
 KV heads of ``d``, head ``i`` reads KV head ``i // (H / G)``)::
@@ -13,6 +13,26 @@ KV heads of ``d``, head ``i`` reads KV head ``i // (H / G)``)::
            token t attends s with t - window < s <= t
     full layer ("full_attention"): no rotary embedding; s <= t
     x'   = x + softmax(q k^T / sqrt(d)) v W_o
+
+which the options below turn, each alone, into the family whose two kinds
+of layer differ in more than the mask (MiMo-V2: none of them set gives the
+block above, parameter for parameter):
+
+- ``swa_num_key_value_heads``: the window layers' KV heads where they are
+  not the full layers' (``G_l``: 8 beside 4);
+- ``v_head_dim``: values narrower than keys (``d`` = 192 for q and k, 128
+  for v and the heads that reach ``W_o``);
+- ``partial_rotary_factor`` < 1: the rotary embedding on the first
+  ``int(d x factor)`` entries of a head, the rest as they are;
+  ``full_attention_rope``: on the full layers too, base ``rope_theta``
+  there and ``swa_rope_theta`` on the window layers;
+- ``qk_norm=False``: no per-head norm (and no ``gq``, ``gk``);
+- ``attention_value_scale``: ``v`` multiplied by it before it is cached;
+- ``add_swa_attention_sink_bias`` / ``add_full_attention_sink_bias``: a
+  learned logit ``b_i`` a query head (``sinks``) that joins that kind of
+  layer's softmax denominator and nothing else, ``p_is = exp(a_is) /
+  (exp(b_i) + sum_s' exp(a_is'))``;
+- ``num_shared_experts`` 0: no shared expert (and no ``shared``).
     h2   = rms(x'; g2)
     dense layer:  x'' = x' + (silu(h2 W_g) * (h2 W_u)) W_d
     sparse layer: s   = sigmoid(h2 W_r)           float32, all routed experts
@@ -42,6 +62,7 @@ the cache's ring (``ServingSpec.layer_windows``).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -87,6 +108,20 @@ class WindowMoELMConfig:
     num_shared_experts: int = 1
     routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
+    #: the window layers' KV heads (None: ``num_key_value_heads``)
+    swa_num_key_value_heads: Optional[int] = None
+    #: the width of a head's values (None: ``head_dim``)
+    v_head_dim: Optional[int] = None
+    #: the share of a head's entries, from the first, that are rotated
+    partial_rotary_factor: float = 1.0
+    #: the rotary embedding on the full layers too (base ``rope_theta``)
+    full_attention_rope: bool = False
+    #: the window layers' base (None: ``rope_theta``)
+    swa_rope_theta: Optional[float] = None
+    qk_norm: bool = True
+    attention_value_scale: float = 1.0
+    add_swa_attention_sink_bias: bool = False
+    add_full_attention_sink_bias: bool = False
     #: which body the kernels run: "auto" (Pallas on a TPU, XLA
     #: elsewhere), "pallas", "pallas_interpret", "lax"
     kernel_impl: str = "auto"
@@ -113,13 +148,44 @@ class WindowMoELMConfig:
         if not 0 <= self.expert_offset <= \
                 self.num_routed_experts - self.num_experts:
             raise ValueError("the experts held lie within the router's")
-        if self.num_attention_heads % self.num_key_value_heads:
+        if any(self.num_attention_heads % g for g in self.layer_kv_heads):
             raise ValueError("query heads in whole groups over the KV heads")
 
     @property
     def layer_windows(self):
         return tuple(self.sliding_window if t == "sliding_attention" else None
                      for t in self.layer_types)
+
+    @property
+    def layer_kv_heads(self):
+        """The KV heads of each layer, by its kind."""
+        swa = self.swa_num_key_value_heads or self.num_key_value_heads
+        return tuple(swa if t == "sliding_attention"
+                     else self.num_key_value_heads for t in self.layer_types)
+
+    @property
+    def sink_layers(self):
+        """Which layers' softmax carries a learned sink."""
+        return tuple(self.add_swa_attention_sink_bias
+                     if t == "sliding_attention"
+                     else self.add_full_attention_sink_bias
+                     for t in self.layer_types)
+
+    @property
+    def value_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    def layer_rope_theta(self, i: int) -> Optional[float]:
+        """The rotary base of layer ``i``; None: it has no rotary
+        embedding."""
+        if self.layer_types[i] == "sliding_attention":
+            return self.rope_theta if self.swa_rope_theta is None \
+                else self.swa_rope_theta
+        return self.rope_theta if self.full_attention_rope else None
 
     @property
     def holds_all_experts(self) -> bool:
@@ -162,11 +228,20 @@ class WindowMoELM:
         then spread by ``0.02 sqrt(D)`` (1.57 at 6144), so the sigmoid
         scores lie between 0.05 and 0.95 and the ``K`` largest are no
         ties (neighbours near the ``K``-th of 128 lie about 0.1 apart in
-        the logit)."""
+        the logit).
+
+        A layer's sinks are drawn so that they MATTER: ``b = log(window) +
+        (0.0004 D)^2 / 2 + log(2/3) + 0.3 n``, ``n`` standard normal a
+        head. A window's scores over normal(0.02) projections of a normed
+        stream spread by ``0.0004 D`` (1.64 at 4096), so its ``window``
+        terms ``exp(a)`` sum to about ``window x exp(var / 2)`` (490 at
+        128 and 4096) and the sink then takes about two fifths of the
+        softmax's mass, more where a head's draw is high; a sink at 0
+        would take 0.2% and a program that dropped it would pass every
+        comparison."""
         c = self.cfg
         d, dh, f = c.hidden_size, c.head_dim, c.moe_intermediate_size
-        h, g, e = (c.num_attention_heads, c.num_key_value_heads,
-                   c.num_experts)
+        h, e, dv = c.num_attention_heads, c.num_experts, c.value_dim
         ones = lambda n: {"scale": jnp.ones((n,), dtype)}       # noqa: E731
 
         def mlp(k, width):
@@ -179,15 +254,22 @@ class WindowMoELM:
         layers = {}
         for i in range(c.num_hidden_layers):
             k = jax.random.split(keys[i], 10)
+            g = c.layer_kv_heads[i]
             lp = {
                 "attn_norm": ones(d),
                 "q_proj": {"weight": normal_init(k[0], (d, h * dh), dtype)},
                 "k_proj": {"weight": normal_init(k[1], (d, g * dh), dtype)},
-                "v_proj": {"weight": normal_init(k[2], (d, g * dh), dtype)},
-                "o_proj": {"weight": normal_init(k[3], (h * dh, d), dtype)},
-                "q_norm": ones(dh), "k_norm": ones(dh),
+                "v_proj": {"weight": normal_init(k[2], (d, g * dv), dtype)},
+                "o_proj": {"weight": normal_init(k[3], (h * dv, d), dtype)},
                 "ffn_norm": ones(d),
             }
+            if c.qk_norm:
+                lp.update(q_norm=ones(dh), k_norm=ones(dh))
+            if c.sink_layers[i]:
+                lp["sinks"] = (
+                    math.log(c.sliding_window * 2 / 3) + (0.0004 * d) ** 2 / 2
+                    + 0.3 * jax.random.normal(
+                        jax.random.fold_in(keys[i], 1), (h,), jnp.float32))
             if c.mlp_layer_types[i] == "dense":
                 lp["mlp"] = mlp(k[4], c.intermediate_size)
             else:
@@ -202,7 +284,8 @@ class WindowMoELM:
                     "gate": normal_init(k[6], (e, f, d), dtype),
                     "up": normal_init(k[7], (e, f, d), dtype),
                     "down": normal_init(k[8], (e, f, d), dtype)}
-                lp["shared"] = mlp(k[9], f * c.num_shared_experts)
+                if c.num_shared_experts:
+                    lp["shared"] = mlp(k[9], f * c.num_shared_experts)
             layers[str(i)] = lp
         return {"embed": {"weight": normal_init(
                     keys[-2], (c.vocab_size, d), dtype)},
@@ -217,21 +300,27 @@ class WindowMoELM:
         return _f32(params["embed"]["weight"][tokens])
 
     def attn_in(self, params, i, x, positions):
-        """-> (q (S, H, C, d), (K rows, V rows) (S, C, G d), None)."""
+        """-> (q (S, H, C, d), (K rows (S, C, G d), V rows (S, C, G dv)),
+        the layer's sinks (H,) float32 or None)."""
         c, lp = self.cfg, params["layers"][str(i)]
         s, n, _ = x.shape
-        h, g, dh = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        h, g, dh = c.num_attention_heads, c.layer_kv_heads[i], c.head_dim
         a = rms_norm(x, lp["attn_norm"]["scale"], c.rms_norm_eps)
         q = project(a, lp["q_proj"]["weight"]).reshape(s, n, h, dh)
         k = project(a, lp["k_proj"]["weight"]).reshape(s, n, g, dh)
         v = project(a, lp["v_proj"]["weight"])
-        q = rms_norm(q, lp["q_norm"]["scale"], c.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"]["scale"], c.rms_norm_eps)
-        if c.layer_types[i] == "sliding_attention":
-            q = rope(q, positions, c.rope_theta)
-            k = rope(k, positions, c.rope_theta)
+        if c.qk_norm:
+            q = rms_norm(q, lp["q_norm"]["scale"], c.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"]["scale"], c.rms_norm_eps)
+        theta = c.layer_rope_theta(i)
+        if theta is not None:
+            q = rope(q, positions, theta, c.rotary_dim)
+            k = rope(k, positions, theta, c.rotary_dim)
+        if c.attention_value_scale != 1.0:
+            v = v * c.attention_value_scale
         q = q.astype(lp["q_proj"]["weight"].dtype)
-        return q.transpose(0, 2, 1, 3), (k.reshape(s, n, g * dh), v), None
+        sinks = _f32(lp["sinks"]) if c.sink_layers[i] else None
+        return q.transpose(0, 2, 1, 3), (k.reshape(s, n, g * dh), v), sinks
 
     def attn_out(self, params, i, x, att):
         lp = params["layers"][str(i)]
@@ -277,7 +366,10 @@ class WindowMoELM:
                  "moe_expert_slots": c.num_experts,
                  "moe_max_expert_tokens": sizes.max(),
                  "moe_tile_rows": (-(-sizes // tm)).sum() * tm}
-        return x + y.reshape(s, n, d) + _swiglu(b, lp["shared"]), stats
+        x = x + y.reshape(s, n, d)
+        if c.num_shared_experts:
+            x = x + _swiglu(b, lp["shared"])
+        return x, stats
 
     def head(self, params, x):
         w = params["head"]["weight"]
@@ -296,18 +388,24 @@ class WindowMoELM:
         pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (b, n))
         valid = jnp.ones((b, n), bool)
         x = self.embed(params, ids, pos)
-        group = c.num_attention_heads // c.num_key_value_heads
         t = jnp.arange(n)
         causal = t[None, :] <= t[:, None]
         for i, win in enumerate(c.layer_windows):
-            q, (k, v), _ = self.attn_in(params, i, x, pos)
+            q, (k, v), sinks = self.attn_in(params, i, x, pos)
+            group = c.num_attention_heads // c.layer_kv_heads[i]
             kh = jnp.repeat(k.reshape(b, n, -1, c.head_dim), group, axis=2)
-            vh = jnp.repeat(v.reshape(b, n, -1, c.head_dim), group, axis=2)
+            vh = jnp.repeat(v.reshape(b, n, -1, c.value_dim), group, axis=2)
             seen = causal if win is None else \
                 causal & (t[None, :] > t[:, None] - win)
             att = jnp.einsum("bhqd,bkhd->bhqk", _f32(q), kh, precision=_HI)
-            att = jax.nn.softmax(jnp.where(
-                seen, att * c.head_dim ** -0.5, NEG_INF), axis=-1)
+            att = jnp.where(seen, att * c.head_dim ** -0.5, NEG_INF)
+            if sinks is None:
+                att = jax.nn.softmax(att, axis=-1)
+            else:       # one more column a head, which sums no value
+                att = jax.nn.softmax(jnp.concatenate(
+                    [att, jnp.broadcast_to(sinks[None, :, None, None],
+                                           att.shape[:3] + (1,))], -1),
+                    axis=-1)[..., :-1]
             o = jnp.einsum("bhqk,bkhd->bqhd", att, vh, precision=_HI)
             x = self.attn_out(params, i, x, o)
             x, _ = self.ffn(params, i, x, valid)
@@ -328,7 +426,10 @@ class WindowMoELM:
 class WindowMoEServing:
     """:mod:`paddle_tpu.serving.program` for :class:`WindowMoELM`: K and V
     cached a token and layer, a window layer's in the cache's ring
-    (``layer_windows``), the expert share's counts handed back. No option
+    (``layer_windows``), each kind of layer at its own KV heads and the
+    values at their own width (``layer_kv_heads``, ``value_dim``), the
+    sinks of the layers that have them handed over as ``attn_in``'s third
+    result (``sink_layers``), the expert share's counts handed back. No option
     that shares, snapshots, ships or speculates carries two kinds of layer
     yet (a borrower of a prefix would need the window layers' last tokens
     of it), so ``supports`` is empty."""
@@ -346,7 +447,8 @@ class WindowMoEServing:
             vocab_size=c.vocab_size,
             max_position=c.max_position_embeddings,
             stats=_STATS, layer_windows=c.layer_windows,
-            supports=frozenset())
+            layer_kv_heads=c.layer_kv_heads, value_dim=c.value_dim,
+            sink_layers=c.sink_layers, supports=frozenset())
 
     def param_dtype(self, params):
         return params["embed"]["weight"].dtype

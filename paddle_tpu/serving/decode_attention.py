@@ -140,8 +140,25 @@ def _gather_pages(pages, block_tables, h, dh):
     return g if kv == h else jnp.repeat(g, h // kv, axis=3)
 
 
+def _value_dim(q, k_pages, v_pages):
+    """The width of a head's values: the V pool's lanes over the KV heads
+    the K pool's lanes hold at the queries' width (``Dv``; ``Dk`` where
+    the two pools are alike)."""
+    return v_pages.shape[-1] // (k_pages.shape[-1] // q.shape[-1])
+
+
+def _sink_softmax(scores, sinks):
+    """Softmax over the last axis of masked ``scores`` with one more term
+    in the denominator, ``exp(sinks)`` (broadcast against the rows): a
+    learned sink a head, which takes its share of a row's mass and sums
+    no value. A row with every key masked gives zeros."""
+    m = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sinks)
+    e = jnp.exp(scores - m)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sinks - m))
+
+
 def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale,
-                      window=None):
+                      window=None, sinks=None):
     s_slots, h, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
@@ -149,7 +166,8 @@ def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale,
     # layout — reshaping the gather to token-major would materialize a
     # full extra copy of every slot's K and V per call
     kg = _gather_pages(k_pages, block_tables, h, dh)
-    vg = _gather_pages(v_pages, block_tables, h, dh)
+    vg = _gather_pages(v_pages, block_tables, h,
+                       _value_dim(q, k_pages, v_pages))
     scores = jnp.einsum("shd,smthd->shmt", q.astype(jnp.float32),
                         kg.astype(jnp.float32)) * scale
     scores = scores.reshape(s_slots, h, mp * ps)
@@ -159,10 +177,15 @@ def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale,
         valid = valid & (tok[None, None, :]
                          >= lengths[:, None, None] - window)
     scores = jnp.where(valid, scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    # length-0 slots: every key masked -> emit 0, not a uniform mean of v
-    alive = jnp.max(scores, axis=-1, keepdims=True) > NEG_INF / 2
-    p = jnp.where(alive, p, 0.0).reshape(s_slots, h, mp, ps)
+    if sinks is not None:
+        p = _sink_softmax(scores, sinks.astype(jnp.float32)[None, :, None])
+    else:
+        p = jax.nn.softmax(scores, axis=-1)
+        # length-0 slots: every key masked -> emit 0, not a uniform mean
+        # of v
+        alive = jnp.max(scores, axis=-1, keepdims=True) > NEG_INF / 2
+        p = jnp.where(alive, p, 0.0)
+    p = p.reshape(s_slots, h, mp, ps)
     out = jnp.einsum("shmt,smthd->shd", p, vg.astype(jnp.float32))
     return out.astype(q.dtype)
 
@@ -318,7 +341,7 @@ def _split_kv_refs(rest, pb, quantized):
 
 def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
                           page_size, pages_per_block, quantized=False,
-                          selected=False, window=None):
+                          selected=False, window=None, sink=False):
     """The chunked-prefill body: online-softmax over a slot's pages,
     ``pages_per_block`` pages per grid step (the shared autotuner's
     tunable: fewer grid iterations, deeper DMA pipelining; the per-page
@@ -348,27 +371,52 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
     first page (:func:`_paged_page_index`). A row may meet a page that
     holds none of its tokens before one that does: its ``m`` is still
     ``NEG_INF`` there, what it sums is wiped by ``alpha = 0`` at the first
-    page that holds one, and a live row's own token always is one."""
+    page that holds one, and a live row's own token always is one.
+
+    Keys wider than values: the queries and a KV head's K lanes are
+    ``Dk`` wide (``q_ref``), its V lanes and the output ``Dv``
+    (``o_ref``). Where ``Dk`` is no whole number of 128-lane tiles (192)
+    a KV head's K lanes do not start on a tile boundary, so the group
+    fold loads the fewest KV heads whose lanes together do (a span: two
+    heads, 384 lanes) at a tile boundary and takes each head's lanes as
+    a static slice of what it loaded.
+
+    ``sink``: one more input, ``(H, 1, 128)`` float32, a learned logit a
+    head that joins the softmax's denominator and sums no value: the
+    state starts at ``m = sink, l = 1`` where it starts at ``m = NEG_INF,
+    l = 0`` without one, and nothing else changes (a row that folds
+    nothing still gives ``acc / l = 0``)."""
     pb = pages_per_block
-    sel_ref = None
+    sel_ref = sink_ref = None
     if selected:
         sel_ref, rest = rest[0], rest[1:]
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
     (k_refs, v_refs, ks_refs, vs_refs, o_ref, m_scr, l_scr,
      acc_scr) = _split_kv_refs(rest, pb, quantized)
     sl = pl.program_id(0)
     pj = pl.program_id(1)
     npg = pl.num_programs(1)
     n_heads, rows, dh = q_ref.shape[1:]
-    group = n_heads * dh // k_refs[0].shape[-1]    # query heads a KV head
+    dv = o_ref.shape[-1]
+    kv = k_refs[0].shape[-1] // dh
+    group = n_heads // kv                          # query heads a KV head
     mp = bt_ref.shape[1]
+    # KV heads whose K lanes together are whole tiles
+    span_heads = 128 // math.gcd(dh, 128)
     # a wide chunk of grouped-query heads folds a page once a KV head
     by_group = (group > 1 and n_heads * rows >= _GROUP_FOLD_MIN_ROWS
-                and not quantized and not selected)
+                and not quantized and not selected
+                and (dh == dv or kv % span_heads == 0))
 
     @pl.when(pj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if sink:
+            m_scr[...] = jnp.broadcast_to(sink_ref[...], m_scr.shape)
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     start, nv = start_ref[sl], nv_ref[sl]
@@ -387,17 +435,30 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
             ok = (tok <= start + row) & (row < nv)      # causal + live
             if window is not None:
                 ok = ok & (tok > start + row - window)
-            def one_kv_head(g, _, t=t, ok=ok):
+            def one_kv_head(g, _, t=t, ok=ok, k_span=None, at=0):
                 heads = pl.ds(g * group, group)
-                lanes = pl.ds(pl.multiple_of(g * dh, dh), dh)
+                lanes = pl.ds(pl.multiple_of(g * dv, dv), dv)
                 _online_softmax_group_fold(
                     q_ref[0, heads].reshape(group * rows, dh).astype(
                         jnp.float32),
-                    k_refs[t][0, :, lanes].astype(jnp.float32),
+                    k_refs[t][0, :, lanes].astype(jnp.float32)
+                    if k_span is None else k_span[:, at * dh:(at + 1) * dh],
                     v_refs[t][0, :, lanes].astype(jnp.float32),
                     ok, m_scr, l_scr, acc_scr, heads)
 
-            jax.lax.fori_loop(0, n_heads // group, one_kv_head, None)
+            def one_span(j, _, t=t, ok=ok):
+                width = span_heads * dh
+                k_span = k_refs[t][0, :, pl.ds(
+                    pl.multiple_of(j * width, width), width)].astype(
+                        jnp.float32)
+                for at in range(span_heads):
+                    one_kv_head(j * span_heads + at, None, t, ok, k_span,
+                                at)
+
+            if dh == dv:
+                jax.lax.fori_loop(0, kv, one_kv_head, None)
+            else:
+                jax.lax.fori_loop(0, kv // span_heads, one_span, None)
 
     def _body():
         for t in range(pb):
@@ -425,11 +486,11 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
             for h in range(n_heads):
                 g = h // group
                 _online_softmax_page_fold(
-                    q_ref[0, h].astype(jnp.float32),            # (R, Dh)
+                    q_ref[0, h].astype(jnp.float32),            # (R, Dk)
                     k_refs[t][0, :, g * dh:(g + 1) * dh].astype(
-                        jnp.float32),                           # (ps, Dh)
-                    v_refs[t][0, :, g * dh:(g + 1) * dh].astype(
-                        jnp.float32),
+                        jnp.float32),                           # (ps, Dk)
+                    v_refs[t][0, :, g * dv:(g + 1) * dv].astype(
+                        jnp.float32),                           # (ps, Dv)
                     ok, m_scr, l_scr, acc_scr, h,
                     k_scale=k_scale, v_scale=v_scale)
 
@@ -510,8 +571,9 @@ def _all_heads_page_dot(x, page, contract_page_dim):
 
 def _decode_finish(o_ref, m_scr, l_scr, acc_scr, group):
     """A slot's output from its finished ``m / l / acc`` state (both
-    decode bodies): ``acc / l``, row ``i`` keeping the lanes of its own
-    KV head ``i // group``; a slot that folded nothing gives zeros."""
+    decode bodies): ``acc / l``, row ``i`` keeping the ``Dv`` lanes of its
+    own KV head ``i // group``; a slot that folded nothing gives zeros
+    (under a sink too: ``acc`` is zero over ``l = 1``)."""
     rows, dh = o_ref.shape[1:]
     denom = l_scr[...][:, :1]
     denom = jnp.where(denom == 0.0, 1.0, denom)
@@ -522,6 +584,19 @@ def _decode_finish(o_ref, m_scr, l_scr, acc_scr, group):
         mine = (row >= g * group) & (row < (g + 1) * group)
         out = out + jnp.where(mine, acc_scr[:, g * dh:(g + 1) * dh], 0.0)
     o_ref[0] = jnp.where(alive, out / denom, 0.0).astype(o_ref.dtype)
+
+
+def _decode_start(sink_ref, m_scr, l_scr, acc_scr):
+    """A slot's ``m / l / acc`` state before its first page (both decode
+    bodies): nothing summed, or only the heads' sinks (``m = sink, l =
+    1``: the sink's own term ``exp(sink - m)``)."""
+    if sink_ref is None:
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+    else:
+        m_scr[...] = sink_ref[...]
+        l_scr[...] = jnp.ones_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
 def _decode_page(bt, lens, s, j, t, *, page_size, pages_per_block):
@@ -540,7 +615,7 @@ def _decode_page(bt, lens, s, j, t, *, page_size, pages_per_block):
 
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
                          pages_per_block, n_heads, quantized=False,
-                         window=None):
+                         window=None, sink=False):
     """The decode body: online softmax over a slot's live pages, a page
     folded once for all heads (above). ``q_ref`` ``(1, rows, kv*Dh)`` is
     the block-structured query matrix (``rows`` = ``n_heads`` padded to
@@ -564,24 +639,30 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
     body serves dense decode only where a pool's pages are not whole
     tiles, sizes of a test: the pages behind the window still move; a
     page wholly behind it sums under ``m = NEG_INF`` what the first page
-    with a live token wipes with ``alpha = 0``)."""
+    with a live token wipes with ``alpha = 0``).
+
+    Keys wider than values: ``q_ref`` spans the K page's lanes (``kv*Dk``),
+    ``acc`` the V page's (``kv*Dv``), the output is ``Dv`` wide.
+    ``sink``: one more input, ``(rows, 128)`` float32, a learned logit a
+    query head (row) in the softmax's denominator: the state starts at
+    ``m = sink, l = 1``."""
     pb = pages_per_block
+    sink_ref = None
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
     (k_refs, v_refs, ks_refs, vs_refs, o_ref, m_scr, l_scr,
      acc_scr) = _split_kv_refs(rest, pb, quantized)
     sl = pl.program_id(0)
     pj = pl.program_id(1)
     npg = pl.num_programs(1)
     rows = q_ref.shape[1]
-    dh = o_ref.shape[-1]
-    kv = q_ref.shape[2] // dh
+    kv = acc_scr.shape[-1] // o_ref.shape[-1]
     group = n_heads // kv
     extent = len_ref[sl]
 
     @pl.when(pj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _decode_start(sink_ref, m_scr, l_scr, acc_scr)
 
     def _fold(n_pages):
         m, l, acc = m_scr[...], l_scr[...], acc_scr[...]
@@ -647,16 +728,16 @@ def _paged_page_index(ps, mp, pb, t, decode, window=None):
     return page
 
 
-def _paged_kv_specs(ps, hd, mp, pb, decode=False, window=None):
+def _paged_kv_specs(ps, hd, mp, pb, decode=False, window=None, hdv=None):
     """``pb`` (k, v) BlockSpec pairs per grid step: WHOLE pages of the
     slot's block table, all heads folded into their ``hd = H*Dh``
-    lanes."""
-    def kv_spec(t):
+    lanes (``hdv``: the V pool's, where its heads are not as wide)."""
+    def kv_spec(t, lanes):
         page = _paged_page_index(ps, mp, pb, t, decode, window)
-        return pl.BlockSpec((1, ps, hd),
+        return pl.BlockSpec((1, ps, lanes),
                             lambda *ids_and_refs: (page(*ids_and_refs), 0, 0))
-    ks = [kv_spec(t) for t in range(pb)]
-    vs = [kv_spec(t) for t in range(pb)]
+    ks = [kv_spec(t, hd) for t in range(pb)]
+    vs = [kv_spec(t, hd if hdv is None else hdv) for t in range(pb)]
     return ks, vs
 
 
@@ -697,11 +778,21 @@ def _block_structured_queries(q, kv):
     return qb.reshape(s_slots, kv_rows * group, kv * dh)
 
 
+def _sink_rows(sinks, rows):
+    """``sinks`` (H,) as the decode bodies take them: ``(rows, 128)``
+    float32, row ``i`` head ``i``'s logit along the lanes (the shape of
+    ``m``), the padding rows at 0 (zero queries whose output nobody
+    reads)."""
+    sinks = sinks.astype(jnp.float32)
+    return jnp.broadcast_to(
+        jnp.pad(sinks, (0, rows - sinks.shape[0]))[:, None], (rows, 128))
+
+
 @functools.partial(jax.jit, static_argnums=(5, 6),
                    static_argnames=("name", "window"))
 def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
                          interpret, pages_per_block, k_scales, v_scales,
-                         selected=None, name=None, window=None):
+                         selected=None, name=None, window=None, sinks=None):
     """The one ``pallas_call`` behind the pipelined paged kernels (all
     but dense decode). Jitted, so
     that a step program traces and lowers the kernel body once and calls
@@ -715,39 +806,49 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
     ``k_scales``/``v_scales`` given = the dequant-attend variant;
     ``selected`` (S, C, mp*ps) given = chunked prefill under a per-query
     selection; ``name`` renames the call for a device trace (sparse
-    prefill runs this body under its own name)."""
+    prefill runs this body under its own name); ``sinks`` (H,) given = a
+    learned logit a head in the softmax's denominator. The V pool's heads
+    may be narrower than the K pool's (``Dv``; the output's width)."""
     quantized = k_scales is not None
     chunked = len(geometry) == 2
     s_slots, h = q.shape[:2]
     dh = q.shape[-1]
     mp = block_tables.shape[1]
     ps, hd = k_pages.shape[1:]
+    hdv = v_pages.shape[-1]
+    dv = _value_dim(q, k_pages, v_pages)
     pb = max(1, min(int(pages_per_block), mp))
     if chunked:
         rows = q.shape[2]
-        q_block = out_block = (1, h, rows, dh)
-        state, acc = (h, rows, 128), (h, rows, dh)
+        q_block, out_block = (1, h, rows, dh), (1, h, rows, dv)
+        state, acc = (h, rows, 128), (h, rows, dv)
         kernel = functools.partial(_paged_prefill_kernel,
                                    selected=selected is not None)
-        if window is not None:
-            kernel = functools.partial(kernel, window=window)
     else:
         # the queries of a slot as ONE matrix over the page's lanes
         q = _block_structured_queries(q, hd // dh)
         rows = q.shape[1]
-        q_block, out_block = (1, rows, hd), (1, rows, dh)
-        state, acc = (rows, 128), (rows, hd)
+        q_block, out_block = (1, rows, hd), (1, rows, dv)
+        state, acc = (rows, 128), (rows, hdv)
         kernel = functools.partial(_paged_decode_kernel, n_heads=h)
-        if window is not None:
-            kernel = functools.partial(kernel, window=window)
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
 
     k_specs, v_specs = _paged_kv_specs(ps, hd, mp, pb, decode=not chunked,
-                                       window=window)
+                                       window=window, hdv=hdv)
     sel_specs, sel_args = [], []
     if selected is not None:
         sel_specs = [pl.BlockSpec(
             (1, rows, pb * ps), lambda s, j, *_prefetch: (s, 0, j))]
         sel_args = [selected]
+    if sinks is not None:
+        kernel = functools.partial(kernel, sink=True)
+        sink = jnp.broadcast_to(
+            sinks.astype(jnp.float32)[:, None, None], (h, 1, 128)) \
+            if chunked else _sink_rows(sinks, rows)
+        sel_specs.append(pl.BlockSpec(
+            sink.shape, lambda s, j, *_prefetch: (0,) * sink.ndim))
+        sel_args.append(sink)
     sc_specs, sc_args = [], []
     if quantized:
         ks_specs, vs_specs = _paged_scale_specs(ps, mp, pb,
@@ -802,7 +903,7 @@ def _paged_attend_pallas(q, k_pages, v_pages, block_tables, geometry,
 
 def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
                          interpret, pages_per_block=1, k_scales=None,
-                         v_scales=None, window=None):
+                         v_scales=None, window=None, sinks=None):
     """The pipelined decode call: a pool whose pages are not whole tiles
     and, with ``k_scales``/``v_scales`` given, the dequant-attend
     variant: same grid and BlockSpecs plus one scale-row group per
@@ -814,7 +915,7 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
     return _paged_attend_pallas(
         q * jnp.asarray(scale, q.dtype), k_pages, v_pages, block_tables,
         (lengths,), interpret, pages_per_block, k_scales, v_scales,
-        window=window)
+        window=window, sinks=sinks)
 
 
 # ---------------------------------------------------------------------------
@@ -838,10 +939,9 @@ def _paged_decode_pallas(q, k_pages, v_pages, block_tables, lengths, scale,
 # The int8 twin and a pool whose pages are not whole tiles keep the
 # pipelined body above.
 
-def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                              k_buf, v_buf, sems, first_buf, m_scr, l_scr,
-                              acc_scr, *, page_size, pages_per_block,
-                              n_heads, window=None):
+def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, *refs, page_size,
+                              pages_per_block, n_heads, window=None,
+                              sink=False):
     """The dense decode body: grid ``(S,)``, one step a slot. ``q_ref``,
     ``o_ref`` and the ``m / l / acc`` state are
     :func:`_paged_decode_kernel`'s; ``k_hbm`` / ``v_hbm`` are the whole
@@ -856,12 +956,21 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     ``b`` of a slot is its pages ``first + b*pb ..``), so it copies and
     folds ``pages_for(window) + 1`` pages at most whatever the length,
     and the rows of that page before the window are masked in float32
-    as the rows past the extent are."""
+    as the rows past the extent are.
+
+    ``sink``: ``(rows, 128)`` float32 comes after ``q_ref``, as in
+    :func:`_paged_decode_kernel`; ``k_buf`` spans the K pool's lanes
+    (``kv*Dk``) and ``v_buf`` the V pool's (``kv*Dv``)."""
+    sink_ref = None
+    if sink:
+        sink_ref, refs = refs[0], refs[1:]
+    (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, first_buf, m_scr, l_scr,
+     acc_scr) = refs
     ps, pb = page_size, pages_per_block
     sl = pl.program_id(0)
     n_slots = pl.num_programs(0)
     rows = q_ref.shape[1]
-    group = n_heads * o_ref.shape[-1] // q_ref.shape[2]  # heads a KV head
+    group = n_heads * o_ref.shape[-1] // acc_scr.shape[-1]  # heads a KV head
 
     def live_pages(slot):
         return (len_ref[slot] + ps - 1) // ps
@@ -910,9 +1019,7 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     def _own_first_block():
         copies(sl, 0, first_buf[0], start=True)
 
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
+    _decode_start(sink_ref, m_scr, l_scr, acc_scr)
 
     def fold(block, buf, n_pages):
         """Block ``block``'s ``n_pages`` fetched pages as ONE update."""
@@ -962,16 +1069,25 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 @functools.partial(jax.jit, static_argnums=(5, 6),
                    static_argnames=("window",))
 def _paged_decode_walk_pallas(q, k_pages, v_pages, block_tables, lengths,
-                              interpret, pages_per_block, window=None):
+                              interpret, pages_per_block, window=None,
+                              sinks=None):
     """The ``pallas_call`` of the dense decode entry
     (``ragged_paged_decode``), jitted like :func:`_paged_attend_pallas`
     so that a step program traces and lowers the body once. ``q`` is
     already scaled."""
     s_slots, h, dh = q.shape
     ps, hd = k_pages.shape[1:]
+    hdv = v_pages.shape[-1]
+    dv = _value_dim(q, k_pages, v_pages)
     pb = max(1, min(int(pages_per_block), block_tables.shape[1]))
     q = _block_structured_queries(q, hd // dh)
     rows = q.shape[1]
+    static = {} if window is None else {"window": window}
+    sink_specs, sink_args = [], []
+    if sinks is not None:
+        static["sink"] = True
+        sink_specs = [pl.BlockSpec((rows, 128), lambda s, *_prefetch: (0, 0))]
+        sink_args = [_sink_rows(sinks, rows)]
 
     def slot_block(shape):
         return pl.BlockSpec(shape, lambda s, *_prefetch: (s, 0, 0))
@@ -980,25 +1096,25 @@ def _paged_decode_walk_pallas(q, k_pages, v_pages, block_tables, lengths,
         num_scalar_prefetch=2,
         grid=(s_slots,),
         in_specs=[slot_block((1, rows, hd)),
+                  *sink_specs,
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=slot_block((1, rows, dh)),
+        out_specs=slot_block((1, rows, dv)),
         scratch_shapes=[
             pltpu.VMEM((2, pb * ps, hd), k_pages.dtype),
-            pltpu.VMEM((2, pb * ps, hd), v_pages.dtype),
+            pltpu.VMEM((2, pb * ps, hdv), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, hd), jnp.float32),
+            pltpu.VMEM((rows, hdv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_walk_kernel, page_size=ps,
-                          pages_per_block=pb, n_heads=h,
-                          **({} if window is None else {"window": window})),
+                          pages_per_block=pb, n_heads=h, **static),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_slots, rows, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_slots, rows, dv), q.dtype),
         # one slot after the other: a slot's last fold runs beside the
         # copies it started for the next
         compiler_params=pltpu.CompilerParams(
@@ -1006,7 +1122,7 @@ def _paged_decode_walk_pallas(q, k_pages, v_pages, block_tables, lengths,
         interpret=interpret,
         name="ragged_paged_decode",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
-      k_pages, v_pages)
+      *sink_args, k_pages, v_pages)
     return out[:, :h]
 
 
@@ -1066,15 +1182,18 @@ def _paged_decode_int8_pallas(q, k_pages, v_pages, k_scales, v_scales,
 # ---------------------------------------------------------------------------
 
 def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
-                       n_valid, scale, selected=None, window=None):
+                       n_valid, scale, selected=None, window=None,
+                       sinks=None):
     """``selected`` (S, C, mp*ps), where given, marks the cache positions
     each query may attend to beside the causal test (sparse attention:
-    the indexer's choice)."""
+    the indexer's choice). ``sinks`` (H,): a learned term a head in the
+    softmax's denominator (:func:`_sink_softmax`)."""
     s_slots, c, h, dh = q.shape
     mp = block_tables.shape[1]
     ps = k_pages.shape[1]
     kg = _gather_pages(k_pages, block_tables, h, dh)
-    vg = _gather_pages(v_pages, block_tables, h, dh)
+    vg = _gather_pages(v_pages, block_tables, h,
+                       _value_dim(q, k_pages, v_pages))
     scores = jnp.einsum("schd,smthd->shcmt", q.astype(jnp.float32),
                         kg.astype(jnp.float32)) * scale
     scores = scores.reshape(s_slots, h, c, mp * ps)
@@ -1088,10 +1207,15 @@ def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
     if selected is not None:
         ok = ok & (selected[:, None] > 0)
     scores = jnp.where(ok, scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    # masked rows (padding lanes / inactive slots) emit exact zeros
-    alive = jnp.max(scores, axis=-1, keepdims=True) > NEG_INF / 2
-    p = jnp.where(alive, p, 0.0).reshape(s_slots, h, c, mp, ps)
+    if sinks is not None:
+        p = _sink_softmax(scores,
+                          sinks.astype(jnp.float32)[None, :, None, None])
+    else:
+        p = jax.nn.softmax(scores, axis=-1)
+        # masked rows (padding lanes / inactive slots) emit exact zeros
+        alive = jnp.max(scores, axis=-1, keepdims=True) > NEG_INF / 2
+        p = jnp.where(alive, p, 0.0)
+    p = p.reshape(s_slots, h, c, mp, ps)
     out = jnp.einsum("shcmt,smthd->schd", p, vg.astype(jnp.float32))
     return out.astype(q.dtype)
 
@@ -1099,7 +1223,7 @@ def _paged_prefill_lax(q, k_pages, v_pages, block_tables, chunk_starts,
 def _paged_prefill_pallas(q, k_pages, v_pages, block_tables, chunk_starts,
                           n_valid, scale, interpret, pages_per_block=1,
                           k_scales=None, v_scales=None, selected=None,
-                          name=None, window=None):
+                          name=None, window=None, sinks=None):
     """Chunked-prefill analog of :func:`_paged_decode_pallas`: the same
     call site with the chunked body, same ``pages_per_block`` tunable,
     outputs bit-equal for any setting of it. ``q`` (S, C, H, Dh) is handed to the
@@ -1109,8 +1233,9 @@ def _paged_prefill_pallas(q, k_pages, v_pages, block_tables, chunk_starts,
     out = _paged_attend_pallas(qs, k_pages, v_pages, block_tables,
                                (chunk_starts, n_valid), interpret,
                                pages_per_block, k_scales, v_scales,
-                               selected=selected, name=name, window=window)
-    return out.transpose(0, 2, 1, 3)                        # (S,C,H,Dh)
+                               selected=selected, name=name, window=window,
+                               sinks=sinks)
+    return out.transpose(0, 2, 1, 3)                        # (S,C,H,Dv)
 
 
 # ---------------------------------------------------------------------------
@@ -1726,7 +1851,8 @@ def _latent_prefill_pallas(q, c_pages, r_pages, block_tables, chunk_starts,
 def ragged_paged_decode_attention(q, k_pages, v_pages, block_tables,
                                   lengths, *, scale: Optional[float] = None,
                                   impl: str = "auto",
-                                  window: Optional[int] = None):
+                                  window: Optional[int] = None,
+                                  sinks=None):
     """One decode step of attention for every slot at once.
 
     ``q`` (S, H, Dh); ``k_pages``/``v_pages`` (P, page_size, H*Dh),
@@ -1736,9 +1862,19 @@ def ragged_paged_decode_attention(q, k_pages, v_pages, block_tables,
     TPU, lax elsewhere), "lax", "pallas", "pallas_interpret". ``window``
     (static): the query attends to the slot's last ``window`` tokens
     only, itself counted; None: to all of them.
+
+    The pools may hold ``KV <= H`` heads (query head ``i`` reads KV head
+    ``i // (H / KV)``, any whole group), and values narrower than keys:
+    ``k_pages`` (P, page_size, KV*Dh) with ``Dh`` the queries' width,
+    ``v_pages`` (P, page_size, KV*Dv); the result is then (S, H, Dv).
+    ``sinks`` (H,): a learned logit a head that joins the softmax's
+    denominator and nothing else, ``p = exp(a) / (exp(sink) + sum
+    exp(a))``; None: no such term.
     """
     from paddle_tpu import kernels
     kw = {} if window is None else {"window": int(window)}
+    if sinks is not None:
+        kw["sinks"] = sinks
     return kernels.dispatch("ragged_paged_decode", q, k_pages, v_pages,
                             block_tables, lengths, impl=impl, scale=scale,
                             **kw)
@@ -1748,7 +1884,8 @@ def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
                                    chunk_starts, n_valid, *,
                                    scale: Optional[float] = None,
                                    impl: str = "auto",
-                                   window: Optional[int] = None):
+                                   window: Optional[int] = None,
+                                   sinks=None):
     """One batched chunked-prefill step of attention for every slot.
 
     ``q`` (S, C, H, Dh) — a chunk of C query tokens per slot, the first
@@ -1762,9 +1899,14 @@ def ragged_paged_prefill_attention(q, k_pages, v_pages, block_tables,
     ``impl``: "auto" (pallas on TPU, lax elsewhere), "lax", "pallas",
     "pallas_interpret". ``window`` (static): each query attends to the
     last ``window`` cache positions up to its own only; None: to all.
+    Grouped-query pools, values narrower than keys (the result is then
+    (S, C, H, Dv)) and ``sinks`` (H,) as
+    :func:`ragged_paged_decode_attention` takes them.
     """
     from paddle_tpu import kernels
     kw = {} if window is None else {"window": int(window)}
+    if sinks is not None:
+        kw["sinks"] = sinks
     return kernels.dispatch("ragged_paged_prefill", q, k_pages, v_pages,
                             block_tables, chunk_starts, n_valid,
                             impl=impl, scale=scale, **kw)
@@ -1899,7 +2041,8 @@ def latent_paged_prefill_attention(q, c_pages, r_pages, block_tables,
 # ---------------------------------------------------------------------------
 
 def _decode_kernel_pallas(q, k_pages, v_pages, block_tables, lengths, *,
-                          block_sizes, interpret, scale=None, window=None):
+                          block_sizes, interpret, scale=None, window=None,
+                          sinks=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     pb = block_sizes.get("pages_per_block", 1)
@@ -1909,25 +2052,57 @@ def _decode_kernel_pallas(q, k_pages, v_pages, block_tables, lengths, *,
     # whole tiles (rows of whole 128-lane tiles, whole sublane tiles of
     # them). A pool that is not (a tp = 4 shard of GPT-2's heads is 192
     # lanes) goes through the pipelined body, whose blocks span the row
-    if hd % 128 or ps % (32 // k_pages.dtype.itemsize):
+    if hd % 128 or v_pages.shape[-1] % 128 \
+            or ps % (32 // k_pages.dtype.itemsize):
         return _paged_decode_pallas(q, k_pages, v_pages, block_tables,
                                     lengths, scale, interpret,
-                                    pages_per_block=pb, window=window)
+                                    pages_per_block=pb, window=window,
+                                    sinks=sinks)
     return _paged_decode_walk_pallas(
         q * jnp.asarray(scale, q.dtype), k_pages, v_pages, block_tables,
-        lengths, interpret, pb, window=window)
+        lengths, interpret, pb, window=window, sinks=sinks)
 
 
 def _decode_kernel_lax(q, k_pages, v_pages, block_tables, lengths, *,
-                       scale=None, window=None):
+                       scale=None, window=None, sinks=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths,
-                             scale, window)
+                             scale, window, sinks)
+
+
+def _attend_np(q, k, v, scale, sinks):
+    """NumPy attention of ``q`` (H, Dk) over the tokens ``k`` (T, KV, Dk)
+    and ``v`` (T, KV, Dv), query head ``i`` reading KV head ``i // (H /
+    KV)``; ``sinks`` (H,) or None: one more term a head in the
+    denominator. -> (H, Dv)."""
+    import numpy as np
+    group = q.shape[0] // k.shape[1]
+    k, v = (np.repeat(a, group, axis=1) for a in (k, v))
+    s = np.einsum("hd,thd->ht", q, k) * scale
+    m = s.max(-1, keepdims=True)
+    if sinks is not None:
+        m = np.maximum(m, sinks[:, None])
+    p = np.exp(s - m)
+    denom = p.sum(-1, keepdims=True)
+    if sinks is not None:
+        denom = denom + np.exp(sinks[:, None] - m)
+    return np.einsum("ht,thd->hd", p / denom, v)
+
+
+def _pools_np(q, k_pages, v_pages):
+    """The pools as NumPy ``(P, ps, KV, Dk)`` and ``(P, ps, KV, Dv)``."""
+    import numpy as np
+    dh = q.shape[-1]
+    kv = k_pages.shape[-1] // dh
+    kp = np.asarray(k_pages, np.float32)
+    vp = np.asarray(v_pages, np.float32)
+    return (kp.reshape(kp.shape[:2] + (kv, dh)),
+            vp.reshape(vp.shape[:2] + (kv, -1)))
 
 
 def _decode_kernel_reference(q, k_pages, v_pages, block_tables, lengths,
-                             *, scale=None, window=None):
+                             *, scale=None, window=None, sinks=None):
     """NumPy per-slot dense attention — independent of both impls."""
     import numpy as np
     s_slots, h, dh = q.shape
@@ -1935,23 +2110,19 @@ def _decode_kernel_reference(q, k_pages, v_pages, block_tables, lengths,
         scale = 1.0 / math.sqrt(dh)
     mp, ps = block_tables.shape[1], k_pages.shape[1]
     qn = np.asarray(q, np.float32)
-    kp = np.asarray(k_pages, np.float32)
-    vp = np.asarray(v_pages, np.float32)
+    kp, vp = _pools_np(q, k_pages, v_pages)
+    sinks = None if sinks is None else np.asarray(sinks, np.float32)
     bt = np.asarray(block_tables)
     ln = np.asarray(lengths)
-    outs = np.zeros((s_slots, h, dh), np.float32)
+    outs = np.zeros((s_slots, h, vp.shape[-1]), np.float32)
     for sl in range(s_slots):
         n = int(ln[sl])
         if n == 0:
             continue
         lo = 0 if window is None else max(n - window, 0)
-        k = kp[bt[sl]].reshape(mp * ps, h, dh)[lo:n]
-        v = vp[bt[sl]].reshape(mp * ps, h, dh)[lo:n]
-        s = np.einsum("hd,thd->ht", qn[sl], k) * scale
-        s = s - s.max(-1, keepdims=True)
-        p = np.exp(s)
-        p = p / p.sum(-1, keepdims=True)
-        outs[sl] = np.einsum("ht,thd->hd", p, v)
+        k = kp[bt[sl]].reshape((mp * ps,) + kp.shape[2:])[lo:n]
+        v = vp[bt[sl]].reshape((mp * ps,) + vp.shape[2:])[lo:n]
+        outs[sl] = _attend_np(qn[sl], k, v, scale, sinks)
     return jnp.asarray(outs).astype(q.dtype)
 
 
@@ -2003,7 +2174,13 @@ def _paged_sig(q, k_pages, bt):
 
 
 def _paged_tune_signature(args, kwargs):
-    return _paged_sig(args[0], args[1], args[3])
+    sig = _paged_sig(args[0], args[1], args[3])
+    dv = _value_dim(*args[:3])
+    if dv != args[0].shape[-1]:          # values narrower than keys
+        sig += (("dv", dv),)
+    if kwargs.get("sinks") is not None:
+        sig += (("sink", 1),)
+    return sig
 
 
 def _paged_vmem_estimate(args, kwargs, blocks, walk=False):
@@ -2022,7 +2199,10 @@ def _paged_vmem_estimate(args, kwargs, blocks, walk=False):
     temporaries of a fold ``pb`` pages wide."""
     q, k_pages = args[0], args[1]
     ps, hd = k_pages.shape[1:]
+    v_pages = args[2] if len(args) > 2 else k_pages
+    hdv = v_pages.shape[-1]
     h, dh = q.shape[-2:]
+    dv = _value_dim(q, k_pages, v_pages)
     pb = blocks.get("pages_per_block", 1)
 
     def tiled(lead, sub, lane, itemsize):
@@ -2030,28 +2210,30 @@ def _paged_vmem_estimate(args, kwargs, blocks, walk=False):
         return (lead * -(-sub // tile) * tile * -(-lane // 128) * 128
                 * itemsize)
 
-    page = tiled(1, ps, hd, k_pages.dtype.itemsize)
-    streamed = 2 * pb * page
+    streamed = pb * (tiled(1, ps, hd, k_pages.dtype.itemsize)
+                     + tiled(1, ps, hdv, k_pages.dtype.itemsize))
     if k_pages.dtype.itemsize == 1:              # int8: + scale groups
         streamed += 2 * pb * tiled(1, _SCALE_ROWS, ps, 4)
     if q.ndim == 4:                              # chunked prefill
         rows = q.shape[1]
-        streamed += 2 * tiled(h, rows, dh, q.dtype.itemsize)
-        scratch = 2 * tiled(h, rows, 128, 4) + tiled(h, rows, dh, 4)
+        streamed += (tiled(h, rows, dh, q.dtype.itemsize)
+                     + tiled(h, rows, dv, q.dtype.itemsize))
+        scratch = 2 * tiled(h, rows, 128, 4) + tiled(h, rows, dv, 4)
         # fp32 temporaries of one head fold: k, v, scores, weights
-        fold = 2 * tiled(1, ps, dh, 4) + 2 * tiled(1, rows, ps, 4)
+        fold = (tiled(1, ps, dh, 4) + tiled(1, ps, dv, 4)
+                + 2 * tiled(1, rows, ps, 4))
     else:
         rows = h + -h % _HEAD_ROWS
         streamed += (tiled(1, rows, hd, q.dtype.itemsize)
-                     + tiled(1, rows, dh, q.dtype.itemsize))
-        scratch = 2 * tiled(1, rows, 128, 4) + tiled(1, rows, hd, 4)
+                     + tiled(1, rows, dv, q.dtype.itemsize))
+        scratch = 2 * tiled(1, rows, 128, 4) + tiled(1, rows, hdv, 4)
         # one softmax update (a page; the walk's is a block of pages):
         # fp32 scores and weights, the weights' three bf16 terms and
         # their products with V; a page cast to bf16 (int8 pools) and
         # the terms of fp32 queries where those apply
         width = pb * ps if walk else ps
         fold = (2 * tiled(1, rows, width, 4) + tiled(1, 3 * rows, width, 2)
-                + tiled(1, 3 * rows, hd, 4))
+                + tiled(1, 3 * rows, hdv, 4))
         if k_pages.dtype.itemsize == 1:
             fold += 2 * tiled(1, ps, hd, 2)
         if q.dtype.itemsize == 4:
@@ -2082,26 +2264,27 @@ def _decode_donation_probe():
 
 def _prefill_kernel_pallas(q, k_pages, v_pages, block_tables,
                            chunk_starts, n_valid, *, block_sizes,
-                           interpret, scale=None, window=None):
+                           interpret, scale=None, window=None, sinks=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _paged_prefill_pallas(
         q, k_pages, v_pages, block_tables, chunk_starts, n_valid, scale,
         interpret, pages_per_block=block_sizes.get("pages_per_block", 1),
-        window=window)
+        window=window, sinks=sinks)
 
 
 def _prefill_kernel_lax(q, k_pages, v_pages, block_tables, chunk_starts,
-                        n_valid, *, scale=None, window=None):
+                        n_valid, *, scale=None, window=None, sinks=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _paged_prefill_lax(q, k_pages, v_pages, block_tables,
-                              chunk_starts, n_valid, scale, window=window)
+                              chunk_starts, n_valid, scale, window=window,
+                              sinks=sinks)
 
 
 def _prefill_kernel_reference(q, k_pages, v_pages, block_tables,
                               chunk_starts, n_valid, *, scale=None,
-                              window=None):
+                              window=None, sinks=None):
     """NumPy per-slot, per-row causal attention over the slot's pages."""
     import numpy as np
     s_slots, c, h, dh = q.shape
@@ -2109,23 +2292,20 @@ def _prefill_kernel_reference(q, k_pages, v_pages, block_tables,
         scale = 1.0 / math.sqrt(dh)
     mp, ps = block_tables.shape[1], k_pages.shape[1]
     qn = np.asarray(q, np.float32)
-    kp = np.asarray(k_pages, np.float32)
-    vp = np.asarray(v_pages, np.float32)
+    kp, vp = _pools_np(q[0], k_pages, v_pages)
+    sinks = None if sinks is None else np.asarray(sinks, np.float32)
     bt = np.asarray(block_tables)
     st = np.asarray(chunk_starts)
     nv = np.asarray(n_valid)
-    outs = np.zeros((s_slots, c, h, dh), np.float32)
+    outs = np.zeros((s_slots, c, h, vp.shape[-1]), np.float32)
     for sl in range(s_slots):
-        k = kp[bt[sl]].reshape(mp * ps, h, dh)
-        v = vp[bt[sl]].reshape(mp * ps, h, dh)
+        k = kp[bt[sl]].reshape((mp * ps,) + kp.shape[2:])
+        v = vp[bt[sl]].reshape((mp * ps,) + vp.shape[2:])
         for r in range(int(nv[sl])):
             limit = int(st[sl]) + r + 1          # causal horizon
             lo = 0 if window is None else max(limit - window, 0)
-            s = np.einsum("hd,thd->ht", qn[sl, r], k[lo:limit]) * scale
-            s = s - s.max(-1, keepdims=True)
-            p = np.exp(s)
-            p = p / p.sum(-1, keepdims=True)
-            outs[sl, r] = np.einsum("ht,thd->hd", p, v[lo:limit])
+            outs[sl, r] = _attend_np(qn[sl, r], k[lo:limit], v[lo:limit],
+                                     scale, sinks)
     return jnp.asarray(outs).astype(q.dtype)
 
 

@@ -26,6 +26,17 @@ The kinds, and what selects each:
                     (``spec.select_topk``, ``spec.extra_rows``)
 ==================  =====================================================
 
+A kind's **geometry** is its own: :func:`build` hands each the KV heads of
+ITS layers (``spec.layer_kv_heads``: full layers of 4 KV heads beside
+window layers of 8 are two kinds with pools of their own widths), the
+width of a head's keys and of its values (``spec.head_dim``,
+``spec.value_dim``: a K pool of ``heads * 192`` lanes beside a V pool of
+``heads * 128``, each stored at its own width, nothing padded), and
+whether its layers' softmax carries a learned sink a query head
+(``spec.sink_layers``: the logits come from ``attn_in`` with the queries
+and go to the paged kernels as one more operand). Every byte the host
+counts comes from the kind's two widths.
+
 What a kind answers is the methods of :class:`Paged`: its pool entry;
 traced, inside the engine's steps: where a call's tokens go and what the
 kernel is handed, the writes, the attention, what its steps count; on the
@@ -88,10 +99,23 @@ class Geometry:
     page_size: int
     num_pages: int
     heads: int                  # heads of K and V a token caches
-    head_dim: int
+    head_dim: int               # the width of a head's keys (and queries)
     dtype: object
     tp: int = 1
     impl: str = "auto"          # the engine's ``attn_impl``
+    value_dim: Optional[int] = None     # of its values; None: ``head_dim``
+    #: the program's query heads where some of its layers' softmax carries
+    #: a sink: every kind of such a program counts the query rows it
+    #: attends for; 0: none does
+    query_heads: int = 0
+
+    @property
+    def k_lanes(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def v_lanes(self) -> int:
+        return self.heads * (self.value_dim or self.head_dim)
 
 
 # -- where tokens fall under the slots' tables (traced) ----------------------
@@ -216,6 +240,13 @@ class _Groups:
         return self.kept[2:]
 
 
+def _add_attr(span, name: str, n: int):
+    """``n`` more on the span's attribute ``name`` (several kinds of one
+    program feed the same round's or call's span)."""
+    if span is not None:
+        span.set_attrs(**{name: span.attrs.get(name, 0) + n})
+
+
 def _attended(lens, n):
     """Tokens ``n`` decode token steps attend over, a layer: step j of a
     slot holding L tokens attends over L + j + 1."""
@@ -236,25 +267,39 @@ class Paged:
     the axes a tp mesh shards as a PartitionSpec's entries)`` a pool array
     (K and V: the folded head axis). ``label``: the kind's name in the
     series that split a pool by kind, None where the program's layers are
-    all of one kind (:class:`Latent` always names itself)."""
+    all of one kind (:class:`Latent` always names itself). ``sink``: the
+    layers' softmax carries a learned logit a query head, which ``attn_in``
+    hands over as ``index``."""
 
     quantized = False       # pages carry scale rows
     by_slot = False         # a prefill lane's placement needs its slot
     stat_names: Tuple[str, ...] = ()    # counts its steps add on the device
     groups = None           # a :class:`_Groups` where its decode folds
     _c_resident = None      # bound where the program's pool is split by kind
+    _c_rows = _c_sink_rows = None   # bound where the program has sinks
 
     def __init__(self, geo: Geometry, layers: int,
-                 label: Optional[str] = None, extra_rows=()):
+                 label: Optional[str] = None, extra_rows=(),
+                 sink: bool = False):
         self.geo, self.layers, self.label = geo, layers, label
-        #: K and V of one token in one layer
-        self.token_bytes = 2 * geo.heads * geo.head_dim \
+        self.sink = sink
+        #: K and V of one token in one layer, each at its own width
+        self.token_bytes = (geo.k_lanes + geo.v_lanes) \
             * np.dtype(geo.dtype).itemsize
-        kv = ((geo.num_pages, geo.page_size, geo.heads * geo.head_dim),
-              geo.dtype, (None, None, "tp"))
-        self.pools = (kv, kv) + tuple(
-            ((geo.num_pages, width, geo.page_size), geo.dtype, ())
-            for _name, width in extra_rows)
+        self.pools = self._kv_pools(geo.num_pages, (None, None, "tp")) \
+            + tuple(((geo.num_pages, width, geo.page_size), geo.dtype, ())
+                    for _name, width in extra_rows)
+
+    def _kv_pools(self, pages: int, axes, dtype=None):
+        """The K pool and the V pool of ``pages`` pages: a token's heads
+        folded head-major into the lanes, each pool its own width."""
+        geo = self.geo
+        return tuple(((pages, geo.page_size, lanes), dtype or geo.dtype, axes)
+                     for lanes in (geo.k_lanes, geo.v_lanes))
+
+    def _sinks(self, index) -> dict:
+        """The kernels' ``sinks=`` where the layers' softmax has one."""
+        return {"sinks": index} if self.sink else {}
 
     # -- the pool entry --
 
@@ -301,14 +346,14 @@ class Paged:
         lengths = place[3] + 1
         return DA.ragged_paged_decode_attention(
             q, ent[0], ent[1], place[2], lengths,
-            impl=self.geo.impl), lengths
+            impl=self.geo.impl, **self._sinks(index)), lengths
 
     def attend_prefill(self, q, ent, place, n_valid, index):
         """A chunk of queries a lane, ``q`` (S, C, H, Dh), causally over
         ``ent`` as just written -> heads."""
         return DA.ragged_paged_prefill_attention(
             q, ent[0], ent[1], place[2], place[3], n_valid,
-            impl=self.geo.impl)
+            impl=self.geo.impl, **self._sinks(index))
 
     def attends_prefill(self, seen, block_tables):
         """Tokens each query of a chunk attends to, of the ``seen`` (S, C)
@@ -338,6 +383,8 @@ class Paged:
         """Bind the kind's series in ``reg``, once."""
         if self.label is not None:
             self._bind_by_kind(reg)
+        if self.geo.query_heads:
+            self._bind_rows(reg)
 
     def _bind_by_kind(self, reg):
         self._c_resident = reg.counter(
@@ -346,7 +393,61 @@ class Paged:
             "when it is dispatched, whole pages, by layer kind: a full "
             "layer every page of the slot's tokens, a window layer its "
             "ring's pages at most").child(layers=self.label)
+        self._c_pairs = reg.counter(
+            "serving_prefill_attn_pairs_total",
+            "(query token, key token) pairs x layers the real tokens of "
+            "the prefill calls score, by layer kind: causal, and inside "
+            "the window on a window layer").child(layers=self.label)
+        self._c_prefill_rows = reg.counter(
+            "serving_prefill_kv_rows_total",
+            "cached K/V rows x layers the prefill calls have to read "
+            "once, by layer kind: a lane's context and chunk on a full "
+            "layer, what the chunk's windows reach on a window layer"
+            ).child(layers=self.label)
         self._set_pool_bytes(reg)
+
+    def _bind_rows(self, reg):
+        """The series of a program some of whose layers' softmax carries
+        a sink: the query rows every kind attends for, and of those the
+        rows of the layers with one."""
+        self._c_rows = reg.counter(
+            "serving_attn_rows_total",
+            "query rows x heads x layers attended for, prefill tokens and "
+            "decode token steps (a program with sink layers only)").child()
+        if self.sink:
+            self._c_sink_rows = reg.counter(
+                "serving_attn_sink_rows_total",
+                "query rows x heads x layers whose softmax carried a "
+                "learned sink: prefill tokens and decode token steps of "
+                "the layers that have one").child()
+
+    def _count_rows(self, span, tokens: int):
+        """``tokens`` query tokens attended for in each of the layers."""
+        if self._c_rows is None:
+            return
+        rows = tokens * self.geo.query_heads * self.layers
+        self._c_rows.inc(rows)
+        if self._c_sink_rows is not None:
+            self._c_sink_rows.inc(rows)
+            _add_attr(span, "sink_rows", rows)
+
+    def _seen_prefill(self, starts, ns):
+        """-> ((query, key) pairs, K/V rows read) of one prefill call a
+        layer: chunk token j of a lane at ``start`` sees its context and
+        the chunk's tokens up to itself."""
+        return (int((starts * ns + ns * (ns + 1) // 2).sum()),
+                int((starts + ns)[ns > 0].sum()))
+
+    def _count_prefill_attention(self, span, starts, ns):
+        if self._c_rows is None and self._c_resident is None:
+            return
+        starts, ns = (np.asarray(a, np.int64) for a in (starts, ns))
+        self._count_rows(span, int(ns.sum()))
+        if self._c_resident is not None:
+            pairs, rows = self._seen_prefill(starts, ns)
+            self._c_pairs.inc(pairs * self.layers)
+            self._c_prefill_rows.inc(rows * self.layers)
+            _add_attr(span, "attn_pairs", pairs * self.layers)
 
     def _set_pool_bytes(self, reg):
         reg.gauge("serving_kv_pool_bytes", "bytes of the K/V pools by layer "
@@ -381,6 +482,7 @@ class Paged:
         live = _attended(lens, n)
         self._count_own(span, block_tables, lengths, dslots, live, n, width)
         self._count_resident(lens)
+        self._count_rows(span, n * len(lens))
         return self._kv_bytes(live, width)
 
     def _count_own(self, span, block_tables, lengths, dslots, live, n,
@@ -391,6 +493,7 @@ class Paged:
     def count_prefill(self, span, starts, ns):
         """One prefill call: lanes at ``starts`` computing ``ns`` tokens."""
         self._count_resident(starts)
+        self._count_prefill_attention(span, starts, ns)
 
 
 class PagedInt8(Paged):
@@ -406,10 +509,9 @@ class PagedInt8(Paged):
 
     def __init__(self, geo, layers):
         super().__init__(geo, layers)
-        kv = ((geo.num_pages, geo.page_size, geo.heads * geo.head_dim),
-              jnp.int8, (None, None, "tp"))
         sc = ((geo.num_pages, geo.page_size), jnp.float32, ())
-        self.pools = (kv, kv, sc, sc)
+        self.pools = self._kv_pools(geo.num_pages, (None, None, "tp"),
+                                    jnp.int8) + (sc, sc)
         self.psum_axis = "tp" if geo.tp > 1 else None
 
     def write(self, ent, rows, place):
@@ -449,14 +551,13 @@ class Ring(Paged):
 
     by_slot = True
 
-    def __init__(self, geo, layers, window: int):
-        super().__init__(geo, layers, "window")
+    def __init__(self, geo, layers, window: int, sink: bool = False):
+        super().__init__(geo, layers, "window", sink=sink)
         self.window = window
         #: the window's own pages and one more, the page being written
         self.ring_pages = -(-window // geo.page_size) + 1
-        kv = ((geo.num_slots * self.ring_pages + 1, geo.page_size,
-               geo.heads * geo.head_dim), geo.dtype, ())
-        self.pools = (kv, kv)
+        self.pools = self._kv_pools(
+            geo.num_slots * self.ring_pages + 1, ())
 
     page_bytes = 0
 
@@ -537,19 +638,19 @@ class Ring(Paged):
     def attend_decode(self, q, ent, place, index, groups):
         att = DA.ragged_paged_decode_attention(
             q, ent[0], ent[1], place[2], place[3], impl=self.geo.impl,
-            window=self.window)
+            window=self.window, **self._sinks(index))
         return att, jnp.minimum(place[4] + 1, self.window)
 
     def attend_prefill(self, q, ent, place, n_valid, index):
         return DA.ragged_paged_prefill_attention(
             q, ent[0], ent[1], place[2], place[3], n_valid,
-            impl=self.geo.impl, window=self.window)
+            impl=self.geo.impl, window=self.window, **self._sinks(index))
 
     def copy_page(self, ent, src, dst):
         return ent              # no page of a ring is ever shared
 
     def bind(self, reg):
-        self._bind_by_kind(reg)
+        super().bind(reg)
         self._c_recycled = reg.counter(
             "serving_window_pages_recycled_total",
             "ring pages of window layers written over as slots advanced "
@@ -573,12 +674,23 @@ class Ring(Paged):
         # table as wide as its ring
         lens = lengths[dslots]
         self._count(span, lens, lens + keeps)
+        self._count_rows(span, n * len(lens))
         return self._kv_bytes(
             int(sum(np.minimum(lens + j + 1, self.window).sum()
                     for j in range(n))), self.ring_pages)
 
+    def _seen_prefill(self, starts, ns):
+        # chunk token j of a lane at ``start`` sees the last ``window``
+        # tokens up to itself; the chunk reads from its first window on
+        w = self.window
+        short = np.clip(w - 1 - starts, 0, ns)  # queries that see < window
+        pairs = short * starts + short * (short + 1) // 2 + (ns - short) * w
+        return (int(pairs.sum()), int(
+            (starts + ns - np.maximum(starts - w + 1, 0))[ns > 0].sum()))
+
     def count_prefill(self, span, starts, ns):
         self._count(span, starts, starts + ns)
+        self._count_prefill_attention(span, starts, ns)
 
 
 class Latent(Paged):
@@ -733,17 +845,39 @@ def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
           share_prefix: bool, tp: int = 1, impl: str = "auto",
           prefill_chunk: Optional[int] = None) -> Tuple[Paged, ...]:
     """One kind a layer of the program ``spec`` describes, in a cache of
-    this geometry (layers alike share one object). The only
-    reader of ``spec.extra_rows``, ``spec.select_topk``,
-    ``spec.layer_windows``, ``spec.latent_row`` and the cache's dtype, and
-    the one place where what does not combine yet is refused.
-    ``prefill_chunk``: the engine's, where an engine asks."""
-    geo = Geometry(num_slots=num_slots, page_size=page_size,
-                   num_pages=num_pages, heads=spec.kv_heads,
-                   head_dim=spec.head_dim, dtype=dtype, tp=tp, impl=impl)
+    this geometry (layers alike share one object: the same window, KV
+    heads and sink). The only reader of ``spec.extra_rows``,
+    ``spec.select_topk``, ``spec.layer_windows``, ``spec.latent_row``,
+    ``spec.layer_kv_heads``, ``spec.value_dim``, ``spec.sink_layers`` and
+    the cache's dtype, and the one place where what does not combine yet
+    is refused. ``prefill_chunk``: the engine's, where an engine asks."""
+    def geo(heads):
+        return Geometry(num_slots=num_slots, page_size=page_size,
+                        num_pages=num_pages, heads=heads,
+                        head_dim=spec.head_dim, dtype=dtype, tp=tp,
+                        impl=impl, value_dim=spec.value_dim,
+                        query_heads=spec.num_heads if spec.sink_layers
+                        else 0)
+
     int8 = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
     windows = spec.layer_windows or (None,) * spec.num_layers
     ringed = [w for w in windows if w is not None]
+    own = [name for name in ("layer_kv_heads", "value_dim", "sink_layers")
+           if getattr(spec, name)]
+    if own:
+        other = [what for what, there in (
+            ("int8 pages", int8), (f"tp={tp}", tp > 1),
+            ("prefix sharing", share_prefix),
+            ("select_topk", spec.select_topk is not None),
+            ("extra_rows", bool(spec.extra_rows))) if there]
+        if other:
+            raise ValueError(
+                f"a program that declares {', '.join(own)} (a layer's own "
+                f"KV heads, values narrower than keys, a sink in the "
+                f"softmax) does not combine with {', '.join(other)} yet: "
+                "the int8, sharded and sparse kernels and the prefix, "
+                "spill and migration formats take K and V of one width "
+                "and one head count")
     if spec.select_topk is not None and spec.select_topk % page_size:
         raise ValueError(
             f"select_topk={spec.select_topk} must be a multiple of "
@@ -781,16 +915,23 @@ def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
             raise ValueError("a tp-sharded pool carries no extra "
                              "rows, no slot state, no window layers "
                              "and no latent rows yet")
-    full_layers = spec.num_layers - len(ringed)
     label = "full" if ringed else None
-    if spec.latent_row is not None:
-        full = Latent(geo, full_layers, spec.latent_row)
-    elif int8:
-        full = PagedInt8(geo, full_layers)
-    elif spec.select_topk is not None:
-        full = Selecting(geo, full_layers, label, spec.extra_rows,
-                         spec.select_topk)
-    else:
-        full = Paged(geo, full_layers, label, spec.extra_rows)
-    rings = {w: Ring(geo, ringed.count(w), w) for w in dict.fromkeys(ringed)}
-    return tuple(full if w is None else rings[w] for w in windows)
+
+    def kind(window, heads, sink, layers):
+        if window is not None:
+            return Ring(geo(heads), layers, window, sink)
+        if spec.latent_row is not None:
+            return Latent(geo(heads), layers, spec.latent_row)
+        if int8:
+            return PagedInt8(geo(heads), layers)
+        if spec.select_topk is not None:
+            return Selecting(geo(heads), layers, label, spec.extra_rows,
+                             spec.select_topk)
+        return Paged(geo(heads), layers, label, spec.extra_rows, sink)
+
+    alike = list(zip(
+        windows, spec.layer_kv_heads or (spec.kv_heads,) * spec.num_layers,
+        spec.sink_layers or (False,) * spec.num_layers))
+    kinds = {key: kind(*key, alike.count(key))
+             for key in dict.fromkeys(alike)}
+    return tuple(kinds[key] for key in alike)

@@ -29,6 +29,28 @@ object with a :class:`ServingSpec` as ``spec`` and the hooks below.
                     cached beside K and V: ``Selecting``
 ==================  ======================================================
 
+A layer's **geometry** is its own too. Three fields say where a layer's
+differs from the program's ``kv_heads`` x ``head_dim`` (they go with
+``Paged`` and ``Ring`` layers in a float pool on one chip, without prefix
+sharing: what else they would combine with is refused by name):
+
+==================  ======================================================
+``layer_kv_heads``  one entry a layer: the KV heads that layer caches
+                    (full layers of 4 beside window layers of 8); every
+                    entry divides ``num_heads``. Empty: ``kv_heads``
+``value_dim``       the width of a head's VALUES where keys are wider
+                    (keys ``head_dim`` = 192, values 128): a layer's K
+                    pool is ``heads * head_dim`` lanes and its V pool
+                    ``heads * value_dim``; ``attn_in`` returns V rows of
+                    that width and ``attn_out`` gets heads ``(S, C, H,
+                    value_dim)``. None: ``head_dim``
+``sink_layers``     one bool a layer: its softmax carries a learned sink
+                    a query head, ``p = exp(a) / (exp(b) + sum exp(a))``.
+                    ``attn_in`` hands the layer's sink logits ``b`` (H,)
+                    back as its third result (``index``), which reaches
+                    the kind with the queries. Empty: no layer does
+==================  ======================================================
+
 Beside the kind: ``slot_state`` (below), ``layer_carry`` (arrays a token
 carries from one layer's ``ffn`` to the next, zeros ``(S, C, width)``
 float32 into the first layer, dropped after the last: neither cached nor
@@ -58,8 +80,10 @@ the engine's jitted steps on ``S`` lanes of ``C`` tokens (decode: ``C`` =
 to cache for every token, a tuple of (S, C, lanes) arrays: K and V with
 their KV heads folded head-major into the lanes, then one array for each
 of ``spec.extra_rows``, and ``index``: None, or ``(q_idx (S,C,J,Di),
-w_idx (S,C,J))`` where the model selects; layer ``i``'s rows whatever its
-kind. The kind writes the rows where it places them and attends over the
+w_idx (S,C,J))`` where the model selects, or the layer's sink logits (H,)
+float32 where ``spec.sink_layers`` says it has them; layer ``i``'s rows
+whatever its kind, K at ``layer_kv_heads[i] * head_dim`` lanes and V at
+``layer_kv_heads[i] * value_dim`` where the program declares those. The kind writes the rows where it places them and attends over the
 pool; the engine hands the heads to ``attn_out``. ``attn_out`` and ``ffn``
 return the residual stream with their block added. ``ffn`` may return a
 dict of scalar counts (names from ``spec.stats``) about the tokens
@@ -131,6 +155,12 @@ class ServingSpec:
     layer_windows: Tuple[Optional[int], ...] = ()
     #: ``(latent_dim, rope_dim)``; None: K and V heads
     latent_row: Optional[Tuple[int, int]] = None
+    #: one entry a layer, its KV heads; empty: ``kv_heads`` every layer
+    layer_kv_heads: Tuple[int, ...] = ()
+    #: the width of a head's values; None: ``head_dim``, as its keys
+    value_dim: Optional[int] = None
+    #: one bool a layer, its softmax carries a sink; empty: none does
+    sink_layers: Tuple[bool, ...] = ()
     supports: FrozenSet[str] = FEATURES
 
     def __post_init__(self):
@@ -141,7 +171,9 @@ class ServingSpec:
                     "wide as a query (kv_heads 1, head_dim latent_dim + "
                     "rope_dim)")
             mixed = [name for name in ("extra_rows", "select_topk",
-                                       "slot_state", "layer_windows")
+                                       "slot_state", "layer_windows",
+                                       "layer_kv_heads", "value_dim",
+                                       "sink_layers")
                      if getattr(self, name)]
             if mixed:
                 raise ValueError(f"a latent row is cached alone, without "
@@ -154,6 +186,27 @@ class ServingSpec:
             raise ValueError(
                 f"layer_windows={self.layer_windows!r}: one entry a layer "
                 f"({self.num_layers}), None or a window of at least 1")
+        if self.layer_kv_heads and set(self.layer_kv_heads) == {
+                self.kv_heads}:
+            object.__setattr__(self, "layer_kv_heads", ())   # all alike
+        if self.value_dim == self.head_dim:
+            object.__setattr__(self, "value_dim", None)
+        if self.sink_layers and not any(self.sink_layers):
+            object.__setattr__(self, "sink_layers", ())
+        if self.layer_kv_heads and (
+                len(self.layer_kv_heads) != self.num_layers or any(
+                    g < 1 or self.num_heads % g
+                    for g in self.layer_kv_heads)):
+            raise ValueError(
+                f"layer_kv_heads={self.layer_kv_heads!r}: one entry a "
+                f"layer ({self.num_layers}), each dividing num_heads="
+                f"{self.num_heads}")
+        if self.sink_layers and len(self.sink_layers) != self.num_layers:
+            raise ValueError(
+                f"sink_layers={self.sink_layers!r}: one bool a layer "
+                f"({self.num_layers})")
+        if self.value_dim is not None and self.value_dim < 1:
+            raise ValueError(f"value_dim={self.value_dim!r}: at least 1")
         if self.slot_state_reader not in ("mixer", "attn_in"):
             raise ValueError(
                 f"slot_state_reader={self.slot_state_reader!r}: the engine "

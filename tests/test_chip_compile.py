@@ -130,6 +130,57 @@ def test_paged_kernel_compiles_for_v5e(name, args, one_chip):
         assert '"scoped_memory_configs":[],"custom_call_config"' in text
 
 
+def _wide_key_args(chunked, kv_heads, lanes, width, pool_pages):
+    """A call of the long-prompt cell (MiMo-V2-Flash's widths): 64 query
+    heads of 192 over ``kv_heads`` KV heads, a K pool of ``kv_heads *
+    192`` lanes beside a V pool of ``kv_heads * 128``, pages of 128."""
+    sds = jax.ShapeDtypeStruct
+    bf, i32 = jnp.bfloat16, jnp.int32
+    q = sds((lanes, 128, 64, 192) if chunked else (lanes, 64, 192), bf)
+    geometry = (sds((lanes,), i32),) * (2 if chunked else 1)
+    return (q, sds((pool_pages, 128, kv_heads * 192), bf),
+            sds((pool_pages, 128, kv_heads * 128), bf),
+            sds((lanes, width), i32), *geometry)
+
+
+# a full layer's pool (4 KV heads: K 768 lanes, V 512, groups of 16) at the
+# 64 pages of the widest bucket but one, and a window layer's ring (8 KV
+# heads: K 1536, V 1024, window 128, a sink a head; 2 ring pages a decode
+# and 3 a prefill call)
+@pytest.mark.parametrize("name, args, window", [
+    pytest.param("ragged_paged_decode",
+                 _wide_key_args(False, 4, 64, 64, 4609), None,
+                 id="decode-full"),
+    pytest.param("ragged_paged_decode",
+                 _wide_key_args(False, 8, 64, 2, 129), 128,
+                 id="decode-window-sink"),
+    pytest.param("ragged_paged_prefill",
+                 _wide_key_args(True, 4, 8, 64, 4609), None,
+                 id="prefill-full"),
+    pytest.param("ragged_paged_prefill",
+                 _wide_key_args(True, 8, 8, 3, 129), 128,
+                 id="prefill-window-sink")])
+def test_paged_kernels_with_keys_wider_than_values_compile_for_v5e(
+        name, args, window, one_chip):
+    """192-wide keys are one and a half lane tiles: the prefill body's
+    group fold loads two KV heads' K lanes (384) at a tile boundary and
+    slices a head out of what it loaded, which the interpreter never
+    questions and the chip's compiler has to accept."""
+    if window is None:
+        _compile_kernel(name, args, one_chip)
+        return
+    spec = kernels.get(name)
+    sinks = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip)
+    blocks = autotune.static_prior(spec, args, {"window": window,
+                                                "sinks": sinks})
+    args = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 for a in args)
+    compiled = jax.jit(lambda *a: spec.pallas_fn(
+        *a[:-1], sinks=a[-1], window=window, block_sizes=blocks,
+        interpret=False)).lower(*args, sinks).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("pool", [{}, dict(slots=1, max_pages=4,
                                            num_pages=5)],
                          ids=["P257", "P5"])

@@ -25,6 +25,8 @@ ONE_CHIP_PHASES = {
                             "hybrid family: 19-token prompt"],
     "phase_latent_family": ["latent family kernels vs lax",
                             "latent family: 19-token prompt"],
+    "phase_wide_key_kernels": ["wide-key paged kernels vs lax",
+                               "ragged_paged_prefill[kv2,float32]"],
 }
 
 

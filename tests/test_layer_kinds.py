@@ -194,6 +194,115 @@ def test_build_decides_the_kind_of_every_layer(fields, dtype, kinds):
         + cache.bytes_per_slot() * 2
 
 
+def test_a_kind_has_the_geometry_of_its_own_layers():
+    """Full layers of 1 KV head beside window layers of 2, keys of 24
+    beside values of 16, a sink on the window layers: two kinds, each with
+    pools of its own two widths and bytes counted from them."""
+    spec = ServingSpec(**{**_SPEC, "num_layers": 4}, kv_heads=1, head_dim=24,
+                       layer_windows=(None, 8, 8, None),
+                       layer_kv_heads=(1, 2, 2, 1), value_dim=16,
+                       sink_layers=(False, True, True, False))
+    built = layer_kinds.build(spec, dtype=jnp.bfloat16, share_prefix=False,
+                              prefill_chunk=4, **_GEO)
+    full, ring = built[0], built[1]
+    assert built == (full, ring, ring, full) and full is not ring
+    assert (type(full), type(ring)) == (layer_kinds.Paged, layer_kinds.Ring)
+    assert (full.geo.heads, ring.geo.heads) == (1, 2)
+    assert (full.sink, ring.sink, full.layers, ring.layers) \
+        == (False, True, 2, 2)
+    assert [shape for shape, _, _ in full.pools] == [(9, 4, 24), (9, 4, 16)]
+    assert [shape for shape, _, _ in ring.pools] == [(7, 4, 48), (7, 4, 32)]
+    assert (full.token_bytes, ring.token_bytes) == (80, 160)
+    assert (full.page_bytes, ring.page_bytes) == (4 * 80, 0)
+    assert ring.slot_bytes == 3 * 4 * 160
+    cache = serving.PagedKVCache(serving.PagedCacheConfig(
+        num_layers=4, num_heads=1, head_dim=24, dtype=jnp.bfloat16,
+        share_prefix=False, kinds=built, **_GEO))
+    cache.check_invariants()
+    assert cache.capacity_bytes() == 2 * 8 * 320 + 2 * 2 * 1920
+
+
+@pytest.mark.parametrize("fields", [
+    dict(layer_kv_heads=(1, 2)), dict(value_dim=4),
+    dict(sink_layers=(True, False))], ids=lambda f: next(iter(f)))
+@pytest.mark.parametrize("other, said", [
+    (dict(dtype=jnp.int8), "int8 pages"), (dict(tp=2), "tp=2"),
+    (dict(share_prefix=True), "prefix sharing")],
+    ids=["int8", "tp", "prefix"])
+def test_a_layers_own_geometry_is_refused_where_it_does_not_combine(
+        fields, other, said):
+    spec = ServingSpec(**_SPEC, kv_heads=2, head_dim=8, **fields)
+    kw = {**dict(dtype=jnp.float32, share_prefix=False), **other}
+    with pytest.raises(ValueError, match=f"{next(iter(fields))}.*{said}"):
+        layer_kinds.build(spec, **_GEO, **kw)
+
+
+def test_a_spec_that_says_what_the_program_says_declares_nothing():
+    spec = ServingSpec(**_SPEC, kv_heads=2, head_dim=8,
+                       layer_kv_heads=(2, 2), value_dim=8,
+                       sink_layers=(False, False))
+    assert (spec.layer_kv_heads, spec.value_dim, spec.sink_layers) \
+        == ((), None, ())
+    with pytest.raises(ValueError, match="layer_kv_heads"):
+        ServingSpec(**_SPEC, kv_heads=2, head_dim=8, layer_kv_heads=(2, 3))
+    with pytest.raises(ValueError, match="sink_layers"):
+        ServingSpec(**_SPEC, kv_heads=2, head_dim=8, sink_layers=(True,))
+
+
+@pytest.mark.parametrize("window", [None, 8])
+def test_prefill_pairs_and_rows_are_what_the_masks_admit(window):
+    """``_seen_prefill`` against a count of the mask itself."""
+    spec = ServingSpec(**{**_SPEC, "num_layers": 1}, kv_heads=2, head_dim=8,
+                       layer_windows=(window,))
+    kind = layer_kinds.build(spec, dtype=jnp.float32, share_prefix=False,
+                             num_slots=2, page_size=16, num_pages=9)[0]
+    starts, ns = np.array([0, 3, 7, 20, 5]), np.array([16, 9, 1, 16, 0])
+    pairs = rows = 0
+    for start, n in zip(starts, ns):
+        seen = set()
+        for t in range(start, start + n):
+            lo = 0 if window is None else max(t - window + 1, 0)
+            pairs += t + 1 - lo
+            seen.update(range(lo, t + 1))
+        rows += len(seen)
+    assert kind._seen_prefill(starts, ns) == (pairs, rows)
+
+
+#: sha256 (16 hex digits) of the lowered text of each tiny program's decode
+#: and prefill step AT THE PARENT OF PR 49 (``tests/step_texts.py``, run on
+#: a checkout of 89f4e56): a program that declares none of the fields that
+#: PR added builds the kinds, pools and step programs it built before
+STEPS_BEFORE_A_LAYER_HAD_ITS_OWN_GEOMETRY = {
+    "gpt[lax]": ("934684c9a32f713f",
+                 "bf5381ee66851d70"),
+    "gpt[pallas_interpret]": ("855e0e0dc1357239",
+                              "5674d5e1ccaa6201"),
+    "latent_conv_moe[lax]": ("91b9896893603f16",
+                             "aaab4fb5a3bd25c5"),
+    "latent_conv_moe[pallas_interpret]": ("641e8ce19ee86e1c",
+                                          "8221b111c837b828"),
+    "window_moe[lax]": ("98cbb84e63537930",
+                        "5cf3f1c36adb3285"),
+    "window_moe[pallas_interpret]": ("50b2307f0e9459b2",
+                                     "d326109e034271b5"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(
+    STEPS_BEFORE_A_LAYER_HAD_ITS_OWN_GEOMETRY))
+def test_the_other_programs_lower_to_the_steps_they_had(program):
+    """GPT-2's, ZAYA1's and K-EXAONE's tiny programs: the decode and the
+    prefill step lower to the text they lowered to before the paged
+    kernels took keys wider than values and a sink (the hashes move with
+    the JAX version too: regenerate them on the PARENT commit with
+    ``tests/step_texts.py``, never on the change)."""
+    import step_texts
+    name, impl = program[:-1].split("[")
+    got = step_texts.step_hashes(name, impl)
+    assert (got["decode"], got["prefill"]) \
+        == STEPS_BEFORE_A_LAYER_HAD_ITS_OWN_GEOMETRY[program]
+
+
 def test_build_refuses_what_does_not_combine():
     def build(dtype=jnp.float32, share_prefix=False, page_size=4, **fields):
         spec = ServingSpec(**_SPEC, kv_heads=2, head_dim=8, **fields)
