@@ -203,6 +203,17 @@ def log(msg):
     print(f"[chip_smoke] {msg}", flush=True)
 
 
+def _prefill_forms():
+    """``paged_prefill_lowerings_total`` by label set: the forms the
+    chunked-prefill bodies traced so far took (fold by head or by group,
+    operands as stored or float32, terms stacked or a product each)."""
+    from paddle_tpu.observability import registry as obs
+    counter = obs.counter("paged_prefill_lowerings_total")
+    return {",".join(f"{k}={v}" for k, v in labels):
+            int(counter.value(**dict(labels)))
+            for labels in counter.labels_seen()}
+
+
 def _dispatched(kernel, impl):
     """How often ``kernel`` resolved to ``impl`` so far (trace-time
     counter of ``kernels.dispatch``)."""
@@ -277,6 +288,12 @@ def phase_paged_kernels(sizes, seed):
                 out, ref, atol=contract.atol, rtol=contract.rtol,
                 err_msg=f"{name}[{label}] {sizes.kernel_impl} vs lax")
     log("paged kernels vs lax max|err|: " + json.dumps(errs))
+    forms = _prefill_forms()
+    # the bf16 and int8 pools' operands went to the MXU as stored, the
+    # float32 pool's at HIGHEST
+    assert any("operands=stored" in f for f in forms) \
+        and any("operands=float32" in f for f in forms), forms
+    log("paged prefill lowerings: " + json.dumps(forms))
 
 
 def phase_wide_key_kernels(sizes, seed):
@@ -331,6 +348,10 @@ def phase_wide_key_kernels(sizes, seed):
                     out, ref, atol=contract.atol, rtol=contract.rtol,
                     err_msg=f"{label} {sizes.kernel_impl} vs lax")
     log("wide-key paged kernels vs lax max|err|: " + json.dumps(errs))
+    forms = _prefill_forms()
+    if h * c >= 4096:       # a wide chunk folds a page once a KV head
+        assert "fold=group,operands=stored,terms=each" in forms, forms
+    log("wide-key prefill lowerings: " + json.dumps(forms))
 
 
 # ---------------------------------------------------------------------------

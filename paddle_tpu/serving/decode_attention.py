@@ -45,19 +45,26 @@ The bodies differ in how a page meets the queries:
 
 - **chunked prefill** (``_paged_prefill_kernel``): a chunk fills the
   MXU's rows with the C queries of ONE head, so the body loops over the
-  heads and takes head ``h`` as a static lane slice of the page block,
-  float32 at ``Precision.HIGHEST``;
+  heads and takes head ``h`` as a static lane slice of the page block
+  (a wide chunk of grouped-query heads: over the KV heads, all the
+  query heads of one at once). Its two products follow the rule below
+  (``_exact_page_dot``), a float32 operand's three terms stacked only
+  where the fold's rows leave the MXU room for them, and its row
+  statistics meet a 128-lane row of scores or values as they lie;
+  ``paged_prefill_lowerings_total`` counts which form a trace took
+  (``_prefill_fold_form``);
 - **decode** (``_paged_decode_kernel``): one query a head would leave
   those rows empty, so a page is folded ONCE for every head: the slot's
   queries are one block-structured matrix over the page's whole lane
   width (row ``i`` = query head ``i`` in the lanes of its KV head), the
   scores of all heads are one product, the softmax update one, the
-  weighted values one. The operands go to the MXU in the dtype the page
-  is stored in — a bf16 (or int8) page takes one bf16 pass with float32
-  accumulation, and what is float32 (the softmax weights; queries, if
-  the caller's are) goes in as its three bf16 terms, so nothing is
-  rounded that ``HIGHEST`` would not round; a float32 pool multiplies
-  at ``HIGHEST``. Only live pages are moved: a page operand past the
+  weighted values one. **The product rule of every body here**: the
+  operands go to the MXU in the dtype the page is stored in — a bf16
+  (or int8) page takes one bf16 pass with float32 accumulation, bf16
+  queries as they are, and what is float32 (the softmax weights;
+  queries, if the caller's are) goes in as its three bf16 terms, so
+  nothing is rounded that ``HIGHEST`` would not round; a float32 pool
+  multiplies at ``HIGHEST``. Only live pages are moved: a page operand past the
   slot's extent repeats its last index. This is the int8 twin's body
   and that of a pool whose pages are not whole tiles;
 - **dense decode** (``_paged_decode_walk_kernel``, PR 39): the same
@@ -113,13 +120,14 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.observability import registry as _obs_registry
 from paddle_tpu.ops.attention import NEG_INF
 
 
@@ -230,15 +238,126 @@ def _paged_decode_lax(q, k_pages, v_pages, block_tables, lengths, scale,
 _SCALE_ROWS = 8     # f32 sublane tile: scale rows stream in groups of 8
 _WIDE_VMEM_LIMIT = 64 << 20
 
-#: the fold's dots run in true fp32. Mosaic's DEFAULT for fp32 operands
-#: is a single bf16 pass (~3e-3 abs error at GPT-2 widths, measured on a
-#: v5e), which is outside every paged contract's tolerance — the
-#: interpreter never shows it.
+#: a float32 pool's dots run in true fp32. Mosaic's DEFAULT for fp32
+#: operands is a single bf16 pass (~3e-3 abs error at GPT-2 widths,
+#: measured on a v5e), which is outside every paged contract's tolerance
+#: — the interpreter never shows it.
 _FP32_DOT = jax.lax.Precision.HIGHEST
 
 
+def _bf16_terms(x):
+    """float32 ``x (rows, n)`` as the exact sum of three bfloat16 terms
+    (8 + 8 + 8 significand bits). One bf16 MXU pass a term multiplies
+    every bit of ``x``: what ``Precision.HIGHEST`` does in six passes,
+    three of them on the zero low halves of a bf16 page."""
+    terms, rest = [], x
+    for _ in range(3):
+        term = rest.astype(jnp.bfloat16)
+        terms.append(term)
+        rest = rest - term.astype(jnp.float32)
+    return tuple(terms)
+
+
+def _exact_page_dot(x, page, contract_page_dim, stack=True):
+    """``x (rows, .)`` times a page block (or a head's lanes of one) in
+    the dtype the page is stored in, float32 out, no operand rounded: the
+    one product rule of the decode bodies and of both chunked-prefill
+    folds. A float32 page (the CPU tests) multiplies at ``HIGHEST``; a
+    bf16 page, or an int8 one (int8 -> bf16 is exact), takes ONE bf16
+    pass a term of ``x``: a bf16 ``x`` is its own one term, a float32
+    ``x`` (the softmax weights; queries, if the caller's are) goes in as
+    its three. ``stack``: the terms as ONE product, stacked along the
+    rows, its row blocks summed (decode: a head's few rows leave the
+    MXU's rows to spare); else a product a term, which saves the copy
+    into the stack (a chunked-prefill fold whose rows fill the MXU a
+    term: :func:`_prefill_fold_form`)."""
+    dims = (((1,), (contract_page_dim,)), ((), ()))
+    if page.dtype == jnp.float32:
+        return jax.lax.dot_general(
+            x.astype(jnp.float32), page, dims, precision=_FP32_DOT,
+            preferred_element_type=jnp.float32)
+
+    page = page.astype(jnp.bfloat16)         # once, whatever the terms
+
+    def one_pass(x):
+        return jax.lax.dot_general(
+            x, page, dims, precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32)
+
+    if x.dtype == jnp.bfloat16:
+        return one_pass(x)
+    terms = _bf16_terms(x.astype(jnp.float32))
+    if stack:
+        rows = x.shape[0]
+        out = one_pass(jnp.concatenate(terms, axis=0))
+        blocks = (out[i * rows:(i + 1) * rows] for i in range(len(terms)))
+    else:
+        blocks = (one_pass(term) for term in terms)
+    return functools.reduce(jnp.add, blocks)
+
+
+def _row_values(x, lanes):
+    """A ``(rows, 128)`` statistic of the fold's state, every lane its
+    row's value, against ``lanes`` columns: as it lies where a row of
+    scores or values is those 128 lanes wide (a page of 128 tokens, a
+    head of 128 values: no lane moves), else its first column, which
+    broadcasts. The same values either way; re-broadcasting a column
+    costs the chip's cross-lane unit an operation a row group, and at
+    128 wide those bounded the whole fold (PERF.md section 6, PR 51)."""
+    return x if x.shape[1] == lanes else x[:, :1]
+
+
+#: heads x queries of a chunk from which the chunked-prefill body folds a
+#: page once a KV head, for the whole group of query heads that read it
+#: (:func:`_online_softmax_group_fold`), and not once a query head
+_GROUP_FOLD_MIN_ROWS = 4096
+_MXU_ROWS = 128     # rows of the matrix unit (v5e)
+
+_LOWERINGS = _obs_registry.counter(
+    "paged_prefill_lowerings_total",
+    "chunked-prefill bodies traced, by fold (a page once a query head or "
+    "once a KV head), operands (to the MXU as the pool stores them, or a "
+    "float32 pool at HIGHEST) and terms (a float32 operand's three bf16 "
+    "terms as one stacked product or a product each: the fold's row "
+    "count decides, whether or not the pool's type makes terms); counted "
+    "where the shapes and the dtypes decide, at trace time")
+
+
+class _FoldForm(NamedTuple):
+    """The form a chunked-prefill call's folds take: all of it static,
+    read off the call's shapes and dtypes (:func:`_prefill_fold_form`)."""
+    by_group: bool      # a page folded once a KV head, not once a head
+    stored: bool        # operands to the MXU as the pool stores them
+    stack: bool         # three bf16 terms as one stacked product
+
+    def count(self):
+        _LOWERINGS.inc(fold="group" if self.by_group else "head",
+                       operands="stored" if self.stored else "float32",
+                       terms="stacked" if self.stack else "each")
+
+
+def _prefill_fold_form(n_heads, rows, kv, dh, dv, page_dtype, selected):
+    """What a chunked-prefill call of ``n_heads`` query heads over ``kv``
+    KV heads (``dh`` key lanes, ``dv`` value lanes a head) and ``rows``
+    queries a head does with a page, for the kernel and for its VMEM
+    estimate. A wide chunk of grouped-query heads over a plain pool folds
+    a page once a KV head (keys wider than values only where the KV heads
+    come in whole spans of 128-lane tiles). The three terms of a float32
+    operand stay one stacked product only where three times the fold's
+    rows still fit the MXU's: a chunk that fills them a term saves the
+    copy into the stack (the scheduled code of a 32-row, a 64-row and a
+    2,048-row fold either way: PERF.md section 6, PR 51)."""
+    span_heads = 128 // math.gcd(dh, 128)
+    by_group = (n_heads > kv and n_heads * rows >= _GROUP_FOLD_MIN_ROWS
+                and page_dtype != jnp.int8 and not selected
+                and (dh == dv or kv % span_heads == 0))
+    fold_rows = rows * (n_heads // kv if by_group else 1)
+    return _FoldForm(by_group, page_dtype != jnp.float32,
+                     3 * fold_rows <= _MXU_ROWS)
+
+
 def _online_softmax_page_fold(q, k, v, mask, m_scr, l_scr, acc_scr, h,
-                              k_scale=None, v_scale=None):
+                              stack, k_scale=None, v_scale=None):
     """Fold ONE head's (ps, Dh) slice of a kv page into head ``h``'s
     running (m, l, acc) online-softmax state: the chunked-prefill fold
     (a chunk fills the MXU's rows with the queries of one head; decode,
@@ -246,17 +365,21 @@ def _online_softmax_page_fold(q, k, v, mask, m_scr, l_scr, acc_scr, h,
     :func:`_paged_decode_kernel`). ``mask`` (rows, ps) marks live score
     entries; masked entries go to NEG_INF and contribute exact zeros.
 
+    ``q``, ``k`` and ``v`` come as they are stored and go to the MXU so
+    (:func:`_exact_page_dot`: no page block is cast to float32, and a
+    bf16 query over a bf16 page is one pass), the float32 weights ``p``
+    as their three bf16 terms.
+
     ``k_scale``/``v_scale`` (1, ps) are the int8 page pool's
     per-token-row dequant scales (None on the fp path): the scale
     broadcast is fused INTO the QK and PV products — the int8 page goes
     straight into the dot and the per-token scale multiplies the
-    (rows, ps) score/weight matrix, so no dequantized fp page is ever
+    (rows, ps) score/weight matrix (the weights before they are split
+    into terms), so no dequantized fp page is ever
     materialized (the TPP fused-microkernel shape). The m/l/acc update
     sequence is identical either way, so the int8 kernels inherit the
     same per-page accumulation-order contract."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), precision=_FP32_DOT,
-        preferred_element_type=jnp.float32)            # (rows, ps)
+    s = _exact_page_dot(q, k, 1, stack)                # (rows, ps)
     if k_scale is not None:
         s = s * k_scale
     s = jnp.where(mask, s, NEG_INF)
@@ -266,24 +389,17 @@ def _online_softmax_page_fold(q, k, v, mask, m_scr, l_scr, acc_scr, h,
     m_cur = jnp.max(s, axis=1, keepdims=True)          # (rows, 1)
     m_next = jnp.maximum(m_prev, m_cur)                # lanes broadcast
     alpha = jnp.exp(m_prev - m_next)
-    p = jnp.exp(s - m_next[:, :1])                     # (rows, ps)
+    p = jnp.exp(s - _row_values(m_next, s.shape[1]))   # (rows, ps)
     l_scr[h] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_scr[h] = m_next
     if v_scale is not None:
         p = p * v_scale
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), precision=_FP32_DOT,
-        preferred_element_type=jnp.float32)            # (rows, Dh)
-    acc_scr[h] = acc_scr[h] * alpha[:, :1] + pv
+    pv = _exact_page_dot(p, v, 0, stack)               # (rows, Dh)
+    acc_scr[h] = acc_scr[h] * _row_values(alpha, pv.shape[1]) + pv
 
 
-#: heads x queries of a chunk from which the chunked-prefill body folds a
-#: page once a KV head, for the whole group of query heads that read it
-#: (:func:`_online_softmax_group_fold`), and not once a query head
-_GROUP_FOLD_MIN_ROWS = 4096
-
-
-def _online_softmax_group_fold(q, k, v, mask, m_scr, l_scr, acc_scr, heads):
+def _online_softmax_group_fold(q, k, v, mask, m_scr, l_scr, acc_scr, heads,
+                               stack):
     """:func:`_online_softmax_page_fold` for ALL the query heads of one KV
     head at once: ``q`` ``(group * rows, Dh)`` holds the group's heads one
     after the other, ``heads`` is their slice of the ``(H, rows, .)``
@@ -294,29 +410,27 @@ def _online_softmax_group_fold(q, k, v, mask, m_scr, l_scr, acc_scr, heads):
     per-head body unrolls a fold a query head. 64 query heads over 8 KV
     heads of 128 queries: a call compiles in 2 s for the chip and lowers
     in 0.3 s where the per-head body took 11 s and 8 s, in every one of
-    a cell's 35 prefill programs (PERF.md section 6, PR 40)."""
+    a cell's 35 prefill programs (PERF.md section 6, PR 40). The
+    operands go to the MXU as the per-head fold's do, as they are
+    stored."""
     group, rows = m_scr[heads].shape[:2]
 
     def flat(ref):
         a = ref[heads]
         return a.reshape(group * rows, a.shape[-1])
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), precision=_FP32_DOT,
-        preferred_element_type=jnp.float32)            # (G*rows, ps)
+    s = _exact_page_dot(q, k, 1, stack)                # (G*rows, ps)
     s = jnp.where(mask, s, NEG_INF)
     m_prev, l_prev = flat(m_scr), flat(l_scr)
     m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_next)
-    p = jnp.exp(s - m_next[:, :1])
+    p = jnp.exp(s - _row_values(m_next, s.shape[1]))
     l_scr[heads] = (l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
                     ).reshape(group, rows, -1)
     m_scr[heads] = m_next.reshape(group, rows, -1)
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), precision=_FP32_DOT,
-        preferred_element_type=jnp.float32)            # (G*rows, Dh)
-    acc_scr[heads] = (flat(acc_scr) * alpha[:, :1] + pv).reshape(
-        group, rows, -1)
+    pv = _exact_page_dot(p, v, 0, stack)               # (G*rows, Dh)
+    acc_scr[heads] = (flat(acc_scr) * _row_values(alpha, pv.shape[1])
+                      + pv).reshape(group, rows, -1)
 
 
 def _split_kv_refs(rest, pb, quantized):
@@ -404,10 +518,10 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
     mp = bt_ref.shape[1]
     # KV heads whose K lanes together are whole tiles
     span_heads = 128 // math.gcd(dh, 128)
-    # a wide chunk of grouped-query heads folds a page once a KV head
-    by_group = (group > 1 and n_heads * rows >= _GROUP_FOLD_MIN_ROWS
-                and not quantized and not selected
-                and (dh == dv or kv % span_heads == 0))
+    form = _prefill_fold_form(n_heads, rows, kv, dh, dv, k_refs[0].dtype,
+                              selected)
+    form.count()
+    by_group = form.by_group
 
     @pl.when(pj == 0)
     def _init():
@@ -439,18 +553,16 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
                 heads = pl.ds(g * group, group)
                 lanes = pl.ds(pl.multiple_of(g * dv, dv), dv)
                 _online_softmax_group_fold(
-                    q_ref[0, heads].reshape(group * rows, dh).astype(
-                        jnp.float32),
-                    k_refs[t][0, :, lanes].astype(jnp.float32)
+                    q_ref[0, heads].reshape(group * rows, dh),
+                    k_refs[t][0, :, lanes]
                     if k_span is None else k_span[:, at * dh:(at + 1) * dh],
-                    v_refs[t][0, :, lanes].astype(jnp.float32),
-                    ok, m_scr, l_scr, acc_scr, heads)
+                    v_refs[t][0, :, lanes],
+                    ok, m_scr, l_scr, acc_scr, heads, form.stack)
 
             def one_span(j, _, t=t, ok=ok):
                 width = span_heads * dh
                 k_span = k_refs[t][0, :, pl.ds(
-                    pl.multiple_of(j * width, width), width)].astype(
-                        jnp.float32)
+                    pl.multiple_of(j * width, width), width)]
                 for at in range(span_heads):
                     one_kv_head(j * span_heads + at, None, t, ok, k_span,
                                 at)
@@ -486,12 +598,10 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
             for h in range(n_heads):
                 g = h // group
                 _online_softmax_page_fold(
-                    q_ref[0, h].astype(jnp.float32),            # (R, Dk)
-                    k_refs[t][0, :, g * dh:(g + 1) * dh].astype(
-                        jnp.float32),                           # (ps, Dk)
-                    v_refs[t][0, :, g * dv:(g + 1) * dv].astype(
-                        jnp.float32),                           # (ps, Dv)
-                    ok, m_scr, l_scr, acc_scr, h,
+                    q_ref[0, h],                                # (R, Dk)
+                    k_refs[t][0, :, g * dh:(g + 1) * dh],       # (ps, Dk)
+                    v_refs[t][0, :, g * dv:(g + 1) * dv],       # (ps, Dv)
+                    ok, m_scr, l_scr, acc_scr, h, form.stack,
                     k_scale=k_scale, v_scale=v_scale)
 
     # ragged skip: blocks wholly past the slot's live extent do nothing
@@ -530,43 +640,6 @@ def _paged_prefill_kernel(bt_ref, start_ref, nv_ref, q_ref, *rest,
 # KV head.
 
 _HEAD_ROWS = 16     # Q's rows pad to whole (16, 128) bf16 tiles
-
-
-def _bf16_terms(x):
-    """float32 ``x (rows, n)`` as the exact sum of three bfloat16 terms
-    (8 + 8 + 8 significand bits), stacked along the rows: ``(3*rows,
-    n)``. One bf16 MXU pass over the stack, its three row blocks summed,
-    multiplies every bit of ``x``: what ``Precision.HIGHEST`` does in
-    six passes, three of them on the zero low halves of a bf16 page."""
-    terms, rest = [], x
-    for _ in range(3):
-        term = rest.astype(jnp.bfloat16)
-        terms.append(term)
-        rest = rest - term.astype(jnp.float32)
-    return jnp.concatenate(terms, axis=0)
-
-
-def _all_heads_page_dot(x, page, contract_page_dim):
-    """``x (rows, .)`` times a page block in the dtype the page is
-    stored in, float32 out, no operand rounded: a float32 page (the CPU
-    tests) multiplies at ``HIGHEST``; a bf16 page, or an int8 one (int8
-    -> bf16 is exact), takes ONE bf16 pass, a float32 ``x`` going in as
-    its three bf16 terms."""
-    dims = (((1,), (contract_page_dim,)), ((), ()))
-    if page.dtype == jnp.float32:
-        return jax.lax.dot_general(
-            x.astype(jnp.float32), page, dims, precision=_FP32_DOT,
-            preferred_element_type=jnp.float32)
-    rows = x.shape[0]
-    if x.dtype != jnp.bfloat16:
-        x = _bf16_terms(x.astype(jnp.float32))
-    out = jax.lax.dot_general(
-        x, page.astype(jnp.bfloat16), dims,
-        precision=jax.lax.Precision.DEFAULT,
-        preferred_element_type=jnp.float32)
-    if out.shape[0] == rows:
-        return out
-    return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
 
 
 def _decode_finish(o_ref, m_scr, l_scr, acc_scr, group):
@@ -668,7 +741,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
         m, l, acc = m_scr[...], l_scr[...], acc_scr[...]
         q = q_ref[0]
         # every page's scores first: they depend on no state
-        scores = [_all_heads_page_dot(q, k_refs[t][0], 1)    # (rows, ps)
+        scores = [_exact_page_dot(q, k_refs[t][0], 1)        # (rows, ps)
                   for t in range(n_pages)]
         for t, s in enumerate(scores):
             if quantized:
@@ -690,7 +763,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, *rest, page_size,
             m = m_next
             if quantized:
                 p = p * vs_refs[t][pl.ds(r, 1), :]
-            acc = acc * alpha[:, :1] + _all_heads_page_dot(
+            acc = acc * alpha[:, :1] + _exact_page_dot(
                 p, v_refs[t][0], 0)                          # (rows, kv*Dh)
         m_scr[...], l_scr[...], acc_scr[...] = m, l, acc
 
@@ -1025,7 +1098,7 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, *refs, page_size,
         """Block ``block``'s ``n_pages`` fetched pages as ONE update."""
         width = n_pages * ps
         q = q_ref[0]
-        s = _all_heads_page_dot(q, k_buf[buf, :width], 1)   # (rows, width)
+        s = _exact_page_dot(q, k_buf[buf, :width], 1)       # (rows, width)
         start = block * (pb * ps) if window is None \
             else walked(sl, block * pb) * ps
         tok = start + jax.lax.broadcasted_iota(
@@ -1040,7 +1113,7 @@ def _paged_decode_walk_kernel(bt_ref, len_ref, q_ref, *refs, page_size,
         p = jnp.exp(s - m_next[:, :1])                       # (rows, width)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_scr[...] = m_next
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _all_heads_page_dot(
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + _exact_page_dot(
             p, v_buf[buf, :width], 0)                        # (rows, kv*Dh)
 
     def walk(block, _):
@@ -1378,10 +1451,9 @@ def _latent_prefill_lax(q, c_pages, r_pages, block_tables, chunk_starts,
 def _pool_dot(x, page, contract_page_dim):
     """``x (rows, .)`` times a page block with both operands in the
     pool's type, float32 out: one pass over a bf16 pool (``x`` ROUNDED to
-    it, where :func:`_all_heads_page_dot` would split it into three
+    it, where :func:`_exact_page_dot` would split it into three
     terms), ``HIGHEST`` over a float32 one (the CPU tests)."""
-    return _all_heads_page_dot(x.astype(page.dtype), page,
-                               contract_page_dim)
+    return _exact_page_dot(x.astype(page.dtype), page, contract_page_dim)
 
 
 #: slots of one group: how many decoding slots whose tables open with the
@@ -1411,8 +1483,8 @@ def _latent_fold(qc_ref, qr_ref, c_buf, r_buf, buf, m_scr, l_scr, acc_scr,
     slot) a row. Tokens from ``extent`` on are masked, the block's first
     being token ``first_token``; no ``extent``: every token is live."""
     c = c_buf[buf, :width]                                   # (width, Dl)
-    s = _all_heads_page_dot(qc_ref[...], c, 1) \
-        + _all_heads_page_dot(qr_ref[...], r_buf[buf, :, :width], 0)
+    s = _exact_page_dot(qc_ref[...], c, 1) \
+        + _exact_page_dot(qr_ref[...], r_buf[buf, :, :width], 0)
     if extent is not None:
         tok = first_token + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(tok < extent, s, NEG_INF)
@@ -1423,7 +1495,7 @@ def _latent_fold(qc_ref, qr_ref, c_buf, r_buf, buf, m_scr, l_scr, acc_scr,
     l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_scr[...] = m_next
     acc_scr[...] = acc_scr[...] * alpha[:, :1] \
-        + _all_heads_page_dot(p, c, 0)                       # (rows, Dl)
+        + _exact_page_dot(p, c, 0)                           # (rows, Dl)
 
 
 def _page_walk(walk_of, fold, bt_ref, moves, sems, first_buf, *,
@@ -2219,9 +2291,23 @@ def _paged_vmem_estimate(args, kwargs, blocks, walk=False):
         streamed += (tiled(h, rows, dh, q.dtype.itemsize)
                      + tiled(h, rows, dv, q.dtype.itemsize))
         scratch = 2 * tiled(h, rows, 128, 4) + tiled(h, rows, dv, 4)
-        # fp32 temporaries of one head fold: k, v, scores, weights
-        fold = (tiled(1, ps, dh, 4) + tiled(1, ps, dv, 4)
-                + 2 * tiled(1, rows, ps, 4))
+        # one fold's temporaries (a head's, or a whole group of query
+        # heads' where the chunk is wide; a selecting call, which folds
+        # by head, is priced as the wider): fp32 scores, weights and
+        # product with V; over a pool that is not float32 the weights'
+        # three bf16 terms and a product a term, the head's K and V
+        # lanes cast to bf16 (int8 pools), and the terms of fp32 queries
+        # with a score product each
+        form = _prefill_fold_form(h, rows, hd // dh, dh, dv, k_pages.dtype,
+                                  False)
+        n = rows * (h // (hd // dh) if form.by_group else 1)
+        fold = 2 * tiled(1, n, ps, 4) + tiled(1, n, dv, 4)
+        if form.stored:
+            fold += 3 * tiled(1, n, ps, 2) + 2 * tiled(1, n, dv, 4)
+            if k_pages.dtype.itemsize == 1:
+                fold += tiled(1, ps, dh, 2) + tiled(1, ps, dv, 2)
+            if q.dtype.itemsize == 4:
+                fold += 3 * tiled(1, n, dh, 2) + 3 * tiled(1, n, ps, 4)
     else:
         rows = h + -h % _HEAD_ROWS
         streamed += (tiled(1, rows, hd, q.dtype.itemsize)

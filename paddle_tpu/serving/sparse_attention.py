@@ -523,10 +523,10 @@ def _fold_selected(q, k, v, live, m_ref, l_ref, acc_ref):
     tokens: ``q`` (rows, Dh), ``k`` / ``v`` (width, Dh) the head's lanes
     of the block, ``live`` (rows, width) which tokens each row attends
     to. Scores, maximum, sum and accumulator float32; the weights go into
-    ``P V`` as their three bf16 terms (``_all_heads_page_dot``). A row
+    ``P V`` as their three bf16 terms (``_exact_page_dot``). A row
     that has met no live token yet sums under ``m = NEG_INF`` what the
     first live one wipes with ``alpha = 0``."""
-    s = DA._all_heads_page_dot(q, k, 1)                      # (rows, width)
+    s = DA._exact_page_dot(q, k, 1)                          # (rows, width)
     s = jnp.where(live, s, DA.NEG_INF)
     m = m_ref[...]
     m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -535,7 +535,7 @@ def _fold_selected(q, k, v, live, m_ref, l_ref, acc_ref):
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_ref[...] = m_next
     acc_ref[...] = acc_ref[...] * alpha[:, :1] \
-        + DA._all_heads_page_dot(p, v, 0)                    # (rows, Dh)
+        + DA._exact_page_dot(p, v, 0)                        # (rows, Dh)
 
 
 def _fold_block(q_ref, k_buf, v_buf, buf, live, m_scr, l_scr, acc_scr, *,

@@ -181,6 +181,177 @@ def test_paged_kernels_with_keys_wider_than_values_compile_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, those of the kernels' bodies and of
+    their loops and branches among them."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _float32_work_on_bf16_pages(jaxpr):
+    """What a chunked-prefill call traced on a bf16 pool may not hold: a
+    ``dot_general`` with an operand that is not bf16, and a page block (a
+    load from a 3-D bf16 ref, sliced or not) cast to float32."""
+    found, producers = [], {}
+    for eqn in _eqns(jaxpr):
+        for out in eqn.outvars:
+            producers[out] = eqn
+    for eqn in _eqns(jaxpr):
+        name = eqn.primitive.name
+        if name == "dot_general":
+            dtypes = [str(v.aval.dtype) for v in eqn.invars]
+            if dtypes != ["bfloat16", "bfloat16"]:
+                found.append(("dot_general", dtypes))
+        elif name == "convert_element_type" \
+                and eqn.params["new_dtype"] == jnp.float32:
+            src = producers.get(eqn.invars[0])
+            while src is not None and src.primitive.name in (
+                    "slice", "reshape", "squeeze"):
+                src = producers.get(src.invars[0])
+            if src is not None and src.primitive.name == "get":
+                ref = src.invars[0].aval
+                if ref.dtype == jnp.bfloat16 and len(ref.shape) == 3:
+                    found.append(("page block to float32", ref.shape))
+    return found
+
+
+def _docs_prefill_args():
+    """The docs cell's chunked-prefill call under a selection: 4 lanes of
+    64 queries, 32 heads over 4 KV heads of 128, tables of 128 pages."""
+    sds = jax.ShapeDtypeStruct
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pages = sds((1280, 128, 4 * 128), bf)
+    return (sds((4, 64, 32, 128), bf), pages, pages, sds((4, 128), i32),
+            sds((4,), i32), sds((4,), i32),
+            sds((4, 64, 128 * 128), jnp.float32))
+
+
+def _plain_prefill_args(lanes, chunk, heads, head_dim, width, pool_pages,
+                        pool_dtype):
+    """A chunked-prefill call of one query head a KV head (GPT-2's
+    widths: 12 heads of 64, a chunk of 32) over pages of 128."""
+    sds = jax.ShapeDtypeStruct
+    pages = sds((pool_pages, 128, heads * head_dim), pool_dtype)
+    return (sds((lanes, chunk, heads, head_dim), pool_dtype), pages, pages,
+            sds((lanes, width), jnp.int32), sds((lanes,), jnp.int32),
+            sds((lanes,), jnp.int32))
+
+
+def _prefill_call(name, args, window):
+    """``(call, operands)``: kernel ``name``'s Pallas entry at the static
+    prior's blocks, a window with a sink a head where ``window`` is set."""
+    spec = kernels.get(name)
+    kw = {} if window is None else {
+        "window": window, "sinks": jax.ShapeDtypeStruct((64,), jnp.float32)}
+    blocks = autotune.static_prior(spec, args, kw)
+    sinks = kw.pop("sinks", None)
+
+    def call(*a):
+        extra = {} if sinks is None else {"sinks": a[-1]}
+        return spec.pallas_fn(*a[:len(args)], **kw, **extra,
+                              block_sizes=blocks, interpret=False)
+
+    return call, args if sinks is None else (*args, sinks)
+
+
+def _trace_prefill(name, args, window):
+    """The call's jaxpr, its body traced anew (the jitted call keeps the
+    jaxpr of shapes it has met), and what the trace added to
+    ``paged_prefill_lowerings_total``, by label set."""
+    from paddle_tpu.observability import registry as obs
+    from paddle_tpu.serving import decode_attention as DA
+    counter = obs.counter("paged_prefill_lowerings_total")
+
+    def counts():
+        return {lb: counter.value(**dict(lb)) for lb in counter.labels_seen()}
+
+    call, operands = _prefill_call(name, args, window)
+    DA._paged_attend_pallas.clear_cache()
+    before = counts()
+    jaxpr = jax.make_jaxpr(call)(*operands).jaxpr
+    added = {lb: n - before.get(lb, 0) for lb, n in counts().items()
+             if n != before.get(lb, 0)}
+    return jaxpr, added
+
+
+# the long-prompt cell's two prefill calls (the group fold, spans of two
+# 192-lane heads), the docs cell's (the per-head fold under a selection)
+# and GPT-2's (a chunk of 32 rows: three terms fit the MXU's rows stacked)
+_BF16_PREFILL_CALLS = [
+    pytest.param("ragged_paged_prefill",
+                 _wide_key_args(True, 4, 8, 64, 4609), None,
+                 ("group", "each"), id="long-full"),
+    pytest.param("ragged_paged_prefill",
+                 _wide_key_args(True, 8, 8, 3, 129), 128,
+                 ("group", "each"), id="long-window-sink"),
+    pytest.param("sparse_paged_prefill", _docs_prefill_args(), None,
+                 ("head", "each"), id="docs-selected"),
+    pytest.param("ragged_paged_prefill",
+                 _plain_prefill_args(8, 32, 12, 64, 8, 513, jnp.bfloat16),
+                 None, ("head", "stacked"), id="gpt2")]
+
+
+@pytest.mark.parametrize("name, args, window, form", _BF16_PREFILL_CALLS)
+def test_prefill_folds_take_a_bf16_pool_as_it_is_stored(name, args, window,
+                                                        form):
+    """Traced on a bf16 pool the body holds no product with a float32
+    operand (the softmax weights go in as bf16 terms) and casts no page
+    block to float32, and the trace is counted once, under the form the
+    call's shapes decide. No chip is described: the jaxpr is enough."""
+    jaxpr, added = _trace_prefill(name, args, window)
+    assert sum(e.primitive.name == "dot_general"
+               for e in _eqns(jaxpr)) >= 2
+    assert _float32_work_on_bf16_pages(jaxpr) == []
+    fold, terms = form
+    assert added == {(("fold", fold), ("operands", "stored"),
+                      ("terms", terms)): 1}
+
+
+def test_a_float32_pool_is_counted_as_float32_and_the_detector_sees_it(
+        monkeypatch):
+    """The CPU tests' pools multiply at ``HIGHEST`` and are counted so;
+    and the detector flags the body this kernel had before PR 51 (every
+    operand cast to float32 for a ``HIGHEST`` product) on a bf16 pool."""
+    from paddle_tpu.serving import decode_attention as DA
+    args = _plain_prefill_args(2, 32, 12, 64, 8, 17, jnp.float32)
+    jaxpr, added = _trace_prefill("ragged_paged_prefill", args, None)
+    assert added == {(("fold", "head"), ("operands", "float32"),
+                      ("terms", "stacked")): 1}
+    assert all(str(v.aval.dtype) == "float32" for e in _eqns(jaxpr)
+               if e.primitive.name == "dot_general" for v in e.invars)
+
+    def float32_product(x, page, contract_page_dim, stack=True):
+        return jax.lax.dot_general(
+            x.astype(jnp.float32), page.astype(jnp.float32),
+            (((1,), (contract_page_dim,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    monkeypatch.setattr(DA, "_exact_page_dot", float32_product)
+    try:
+        jaxpr, _ = _trace_prefill(*_BF16_PREFILL_CALLS[3].values[:3])
+    finally:
+        DA._paged_attend_pallas.clear_cache()
+    kinds = {kind for kind, _ in _float32_work_on_bf16_pages(jaxpr)}
+    assert kinds == {"dot_general", "page block to float32"}
+
+
+def test_the_docs_cells_selected_prefill_compiles_for_v5e(one_chip):
+    """The per-head fold under a selection with its operands as they are
+    stored (the long-prompt cell's two calls compile above)."""
+    call, operands = _prefill_call("sparse_paged_prefill",
+                                   _docs_prefill_args(), None)
+    operands = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                     for a in operands)
+    compiled = jax.jit(call).lower(*operands).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("pool", [{}, dict(slots=1, max_pages=4,
                                            num_pages=5)],
                          ids=["P257", "P5"])

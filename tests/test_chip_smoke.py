@@ -12,7 +12,9 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: a phase of ``chip_smoke.run_one_chip`` and what it says when it passed
 ONE_CHIP_PHASES = {
-    "phase_paged_kernels": ["paged kernels vs lax"],
+    "phase_paged_kernels": ["paged kernels vs lax",
+                            "paged prefill lowerings",
+                            "fold=head,operands=stored,terms=stacked"],
     "phase_trainer": ["flash_attention[pallas_interpret]"],
     # JAX's own account of where warmup's seconds went
     "phase_serving": ["of which JAX reports", "compiles after warmup=0",
@@ -26,7 +28,8 @@ ONE_CHIP_PHASES = {
     "phase_latent_family": ["latent family kernels vs lax",
                             "latent family: 19-token prompt"],
     "phase_wide_key_kernels": ["wide-key paged kernels vs lax",
-                               "ragged_paged_prefill[kv2,float32]"],
+                               "ragged_paged_prefill[kv2,float32]",
+                               "wide-key prefill lowerings"],
 }
 
 

@@ -410,7 +410,7 @@ class TestDecodeFoldsAPageForAllHeads:
                               scale=0.125)
         split_err = np.abs(self._run(name, args) - want).max()
         monkeypatch.setattr(DA, "_bf16_terms",
-                            lambda x: x.astype(jnp.bfloat16))
+                            lambda x: (x.astype(jnp.bfloat16),))
         DA._paged_decode_walk_pallas.clear_cache()   # traced with the split
         try:
             rounded_err = np.abs(self._run(name, args) - want).max()
@@ -439,6 +439,205 @@ class TestDecodeFoldsAPageForAllHeads:
                                         pages_per_block=pb))
                     for t in range(pb)] for j in range(4)]
             assert got == want, (n_tokens, got)
+
+
+# ---------------------------------------------------------------------------
+# the chunked-prefill folds take their operands as they are stored too
+# (PR 51): bf16 (or int8) pages and bf16 queries one bf16 pass, float32
+# queries and the float32 softmax weights three bf16 terms, a float32 pool
+# ``HIGHEST``. The decode battery's claims, for the per-head fold, the
+# group fold, keys wider than values under a sink and a window, and the
+# per-head fold under a selection.
+# ---------------------------------------------------------------------------
+
+#: case -> (query heads, KV heads, Dk, Dv, chunk, page, pages a slot,
+#: window, sink, selected): ``mha``, ``selected`` and the first ``page128``
+#: case run the per-head fold, the other three the group fold (heads x
+#: chunk >= 4096), ``wide-keys`` on spans of two 192-lane heads (the
+#: long-prompt cell's ``one_span`` path). A chunk of 32 rows multiplies
+#: its three terms as one stacked product (96 of the MXU's 128 rows), a
+#: chunk of 64 or a group's rows a product a term. The ``page128`` pair,
+#: one a fold, has the benchmark cells' page: a row of scores is the 128
+#: lanes the fold's state is kept in, so the running maximum meets the
+#: scores as it lies (``_row_values``), where a 16-token page takes its
+#: first column
+_PREFILL_FOLDS = {
+    "mha-12x64": (12, 12, 64, 64, 32, 16, 8, None, False, False),
+    "gqa-32-over-4x128": (32, 4, 128, 128, 128, 16, 12, None, False, False),
+    "wide-keys-sink-window": (32, 2, 192, 128, 128, 16, 12, 70, True, False),
+    "selected-8-over-2x64": (8, 2, 64, 64, 64, 16, 8, None, False, True),
+    "gqa-8-over-2x128-page128": (8, 2, 128, 128, 32, 128, 3, None, False,
+                                 False),
+    "gqa-32-over-4x128-page128": (32, 4, 128, 128, 128, 128, 3, None, False,
+                                  False),
+}
+#: the case of the int8 entry (no window, sink, selection or second width
+#: there, and grouped-query heads take the same per-head fold)
+_PREFILL_INT8 = ("mha-12x64",)
+_PREFILL_POOLS = [(case, pool) for case in _PREFILL_FOLDS
+                  for pool in ("bf16", "f32", "int8")
+                  if pool != "int8" or case in _PREFILL_INT8]
+
+
+def _prefill_fold_sample(case, pool, q_dtype):
+    """Queries holding bf16 values, as float32 (so the output is float32)
+    or as bf16, over a bf16, float32 or int8 pool: (kernel name, args,
+    keywords, float64 K, V). Slots: a dead one, a full chunk deep in its
+    context, a chunk of 5 live rows at the context's start, one that ends
+    the table."""
+    h, kv, dk, dv, c, ps, mp, window, sink, selected = _PREFILL_FOLDS[case]
+    s = 4
+    rng = np.random.default_rng(h + dk)
+    num_pages = s * mp + 1
+    q = jnp.asarray(rng.standard_normal((s, c, h, dk)),
+                    jnp.bfloat16).astype(q_dtype)
+    kp, vp = (jnp.asarray(
+        rng.standard_normal((num_pages, ps, kv * d)), jnp.bfloat16)
+        for d in (dk, dv))
+    bt = jnp.asarray((rng.permutation(num_pages - 1)[:s * mp] + 1)
+                     .reshape(s, mp), jnp.int32)
+    starts = jnp.asarray([3, (mp * ps - c) // 2 + 3, 0, mp * ps - c],
+                         jnp.int32)
+    n_valid = jnp.asarray([0, c, 5, c], jnp.int32)
+    kw = {"scale": 0.125}
+    if window is not None:
+        kw["window"] = window
+    if sink:        # from far under the scores to over them
+        kw["sinks"] = jnp.asarray(rng.standard_normal(h) * 4, jnp.float32)
+    tail = (bt, starts, n_valid)
+    if selected:    # about half of the context a row
+        tail += (jnp.asarray(rng.random((s, c, mp * ps)) < 0.5,
+                             jnp.float32),)
+    name = "sparse_paged_prefill" if selected else "ragged_paged_prefill"
+    if pool in ("bf16", "f32"):
+        k64, v64 = (np.asarray(p.astype(jnp.float32), np.float64)
+                    for p in (kp, vp))
+        if pool == "f32":       # the same values, multiplied at HIGHEST
+            kp, vp = kp.astype(jnp.float32), vp.astype(jnp.float32)
+        return name, (q, kp, vp, *tail), kw, k64, v64
+    from paddle_tpu.serving.paged_cache import quantize_kv
+    kq, ks = quantize_kv(kp.astype(jnp.float32), (2,))
+    vq, vs = quantize_kv(vp.astype(jnp.float32), (2,))
+    k64, v64 = (np.asarray(p, np.float64)
+                * np.asarray(sc, np.float64)[:, :, None]
+                for p, sc in ((kq, ks), (vq, vs)))
+    return name + "_int8", (q, kq, vq, ks, vs, *tail), kw, k64, v64
+
+
+def _prefill_reference64(case, args, kw, k64, v64):
+    """The chunk's attention in float64 on the stored values: causal,
+    inside the window, under the selection, a sink's term in the
+    denominator; dead rows zeros."""
+    h, kv, dk, dv, c, ps, mp, window, sink, selected = _PREFILL_FOLDS[case]
+    q = np.asarray(args[0].astype(jnp.float32), np.float64)
+    tail = args[-4:] if selected else args[-3:]
+    bt, starts, n_valid = (np.asarray(a) for a in tail[:3])
+    sel = np.asarray(tail[3]) > 0 if selected else None
+    sinks = np.asarray(kw["sinks"], np.float64) if sink else None
+    out = np.zeros(q.shape[:-1] + (dv,))
+    tok = np.arange(mp * ps)
+    for sl in range(q.shape[0]):
+        k = k64[bt[sl]].reshape(-1, kv, dk)
+        v = v64[bt[sl]].reshape(-1, kv, dv)
+        pos = starts[sl] + np.arange(c)
+        ok = (tok[None] <= pos[:, None]) & (np.arange(c) < n_valid[sl])[:, None]
+        if window is not None:
+            ok &= tok[None] > pos[:, None] - window
+        if selected:
+            ok &= sel[sl]
+        for hh in range(h):
+            g = hh // (h // kv)
+            sc = np.where(ok, q[sl, :, hh] @ k[:, g].T * kw["scale"], -np.inf)
+            top = sc.max(axis=1, keepdims=True)
+            if sink:
+                top = np.maximum(top, sinks[hh])
+            top = np.where(np.isfinite(top), top, 0.0)
+            e = np.exp(sc - top)
+            denom = e.sum(axis=1, keepdims=True)
+            if sink:
+                denom = denom + np.exp(sinks[hh] - top)
+            out[sl, :, hh] = np.where(
+                denom > 0, e / np.where(denom > 0, denom, 1.0), 0.0) @ v[:, g]
+        out[sl, n_valid[sl]:] = 0.0
+    return out
+
+
+class TestPrefillFoldsOperandsAsStored:
+    TOL = 2e-5
+
+    def _run(self, name, args, kw, pb=2):
+        return np.asarray(kernels.dispatch(
+            name, *args, impl="pallas_interpret",
+            block_sizes={"pages_per_block": pb}, **kw).astype(jnp.float32))
+
+    @pytest.mark.parametrize("q_dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case, pool", _PREFILL_POOLS)
+    def test_against_float64_on_the_stored_values(self, case, pool, q_dtype):
+        """float32 queries: the float32 output within 2e-5 of float64.
+        bf16 queries: the bf16 output is the rounding of a value that
+        close (half a unit in its last place, at most 2**-8 of the value)."""
+        name, args, kw, k64, v64 = _prefill_fold_sample(
+            case, pool, jnp.dtype(q_dtype))
+        want = _prefill_reference64(case, args, kw, k64, v64)
+        got = self._run(name, args, kw)
+        assert np.abs(want).max() > 0.1
+        rounding = 0.0 if q_dtype == "float32" else 2.0 ** -8
+        assert (np.abs(got - want)
+                <= self.TOL + (self.TOL + rounding) * np.abs(want)).all()
+        # the dead slot, and the rows past a chunk's live ones: zeros
+        assert not got[0].any() and not got[2, 5:].any()
+
+    @pytest.mark.parametrize("case, pool", [
+        cp for cp in _PREFILL_POOLS if cp[1] != "f32"])
+    def test_pages_per_block_bit_equal(self, case, pool):
+        """A page is one update whatever the block: the order of every
+        sum is the setting's at 1."""
+        name, args, kw, _k, _v = _prefill_fold_sample(case, pool,
+                                                      jnp.float32)
+        outs = [self._run(name, args, kw, pb) for pb in (1, 2, 4)]
+        for o in outs[1:]:
+            np.testing.assert_array_equal(outs[0], o)
+
+    @pytest.mark.parametrize("case", sorted(_PREFILL_FOLDS))
+    def test_one_bf16_term_of_p_is_seen_and_fails(self, case, monkeypatch):
+        """The control of the decode battery, on both prefill folds: `P`
+        (and a float32 `q`) rounded to ONE bf16 term misses the tolerance
+        the three-term fold sits well inside."""
+        from paddle_tpu.serving import decode_attention as DA
+        name, args, kw, k64, v64 = _prefill_fold_sample(case, "bf16",
+                                                        jnp.float32)
+        want = _prefill_reference64(case, args, kw, k64, v64)
+        split_err = np.abs(self._run(name, args, kw) - want).max()
+        monkeypatch.setattr(DA, "_bf16_terms",
+                            lambda x: (x.astype(jnp.bfloat16),))
+        DA._paged_attend_pallas.clear_cache()        # traced with the split
+        try:
+            rounded_err = np.abs(self._run(name, args, kw) - want).max()
+        finally:
+            DA._paged_attend_pallas.clear_cache()
+        assert split_err < self.TOL / 4
+        assert rounded_err > self.TOL * 20
+
+    @pytest.mark.parametrize("case, pool, fold, stack", [
+        ("mha-12x64", "bf16", "head", True),
+        ("mha-12x64", "int8", "head", True),
+        ("mha-12x64", "f32", "head", True),
+        ("selected-8-over-2x64", "bf16", "head", False),
+        ("gqa-32-over-4x128", "bf16", "group", False),
+        ("wide-keys-sink-window", "f32", "group", False)])
+    def test_the_form_is_read_off_shapes_and_dtypes(self, case, pool, fold,
+                                                    stack):
+        """One place decides how a call folds (by head or by KV head),
+        whether its operands go to the MXU as stored, and whether three
+        terms fit the MXU's rows stacked; the battery above runs every
+        combination it names."""
+        from paddle_tpu.serving import decode_attention as DA
+        h, kv, dk, dv, c, _ps, _mp, _w, _sink, selected = _PREFILL_FOLDS[case]
+        dtype = {"bf16": jnp.bfloat16, "int8": jnp.int8,
+                 "f32": jnp.float32}[pool]
+        form = DA._prefill_fold_form(h, c, kv, dk, dv, jnp.dtype(dtype),
+                                     selected)
+        assert form == (fold == "group", pool != "f32", stack)
 
 
 def _sparse_decode_cell_args():
