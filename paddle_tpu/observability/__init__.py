@@ -23,11 +23,44 @@ first-class telemetry):
    edge-triggered ``slo_alerts_total`` alerts into metrics AND trace).
 4. **Step anatomy + crash flight recorder** (`anatomy.py`, `flight.py`):
    per-jitted-step wall-time decomposition (host gap, phase-split call
-   wall time, host assembly) feeding histograms/gauges AND trace spans;
+   wall time, host assembly) feeding histograms/gauges (the intervals
+   themselves are the engine's ``serving.*`` phase spans);
    a bounded :class:`FlightRecorder` black box per replica that dumps
    schema-validated postmortem bundles (anatomy JSONL + Chrome trace +
    health trajectory) on eject / breaker-open / shed spikes, served
    live at ``/debug/postmortem`` and rendered by ``tools/postmortem.py``.
+
+   **The step that took too long.** Each record of the anatomy ring
+   (``eng.anatomy.records()``, always on, no tracer needed) holds that
+   ONE step's ``parts`` (seconds by ``phase.part`` of
+   ``serving_step_part_seconds_total``, with ``caller.gap``, the
+   caller's time before the step, and ``step.other``, what no part
+   names) and its ``prefill_calls`` (``[lanes_live, lanes, width,
+   tokens, seconds]`` each). A fixed rule (``anatomy.SlowStepRule``; its
+   constants are module-level names beside it, nothing can be set) marks
+   a step ``slow`` with the part that made it so (``slow_part``,
+   ``excess_s``) and what the engine knew of it (``slots_live``,
+   ``width``, ``admitted``, ``evicted``, ``traces``, ``gc_s``). A part
+   is held against its own median and against the step before, so a
+   pause is flagged every time it comes and a change that stays (a
+   queue to the device that has filled, a context that grows) once, at
+   its first step. An
+   operator's use: ``serving_slow_steps_total{phase,part}`` and
+   ``serving_slow_step_excess_seconds_total{phase,part}`` on
+   ``/metrics`` say how many such steps a replica met and where (0.0
+   when none: under ``dispatch`` / ``sync`` the host waited for the
+   device, under any other part or ``caller/gap`` the host itself stood
+   still); beside them ``serving_step_traces_total`` (functions traced
+   to a jaxpr inside working steps: anything above 0 after warm-up is a
+   step program traced again, which no compile counter shows) and
+   ``serving_step_gc_seconds_total`` (the collector's seconds inside
+   working steps, to hold against ``serving_step_seconds_total``: the
+   two a stall is first suspected of, ruled in or out without a
+   profiler); ``report(reg, tracer, anatomy=eng.anatomy)`` and
+   ``tools/postmortem.py BUNDLE.json`` print the slow steps first (the
+   count by part, then the newest five records); in a profiler's trace a
+   ``serving.slow_step`` annotation carries the ``step`` of the
+   ``serving.step`` span it judged.
 
 One :func:`report` call dumps a unified summary across all four.
 """

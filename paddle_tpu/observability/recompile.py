@@ -20,14 +20,24 @@ until ``observability.scopes.tables()`` asks, so with tracing off the
 cost is one list call an event. A held handle keeps its program loaded
 after the caller dropped it; what that costs on the chip is in PERF.md
 section 6 (PR 38).
+
+The one listener also counts what a step can wait on that no compile
+event shows (PR 52): every function TRACED to a jaxpr
+(:func:`trace_count`; a program traced again and then found in a cache
+compiles nothing and still costs its tracing), and, through one
+``gc.callbacks`` hook installed with it, the seconds the garbage
+collector ran (:func:`gc_seconds`). The serving engine takes both deltas
+over each working step.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import itertools
 import threading
+import time
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from paddle_tpu.observability import registry as _registry
@@ -37,10 +47,15 @@ _COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",)
 # a program came from the persistent cache instead (JAX's own event for
 # the read): no compile, but a program the backend has loaded
 _CACHE_READ_EVENTS = ("/jax/compilation_cache/cache_retrieval_time_sec",)
+# a function was traced to a jaxpr, whatever became of it afterwards
+_TRACE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",)
 
 _lock = threading.Lock()
 _installed = False
 _count = 0
+_traces = 0
+_gc_seconds = 0.0
+_gc_began: Optional[float] = None
 
 
 #: the programs the listener saw last, held so that one the caller has
@@ -203,7 +218,11 @@ def loaded_programs() -> List[LoadedProgram]:
 
 
 def _on_duration(event: str, duration: float, **kw):
-    global _count
+    global _count, _traces
+    if event in _TRACE_EVENTS:
+        with _lock:
+            _traces += 1
+        return
     if event in _COMPILE_EVENTS or event in _CACHE_READ_EVENTS:
         _look()
     if event in _COMPILE_EVENTS:
@@ -217,8 +236,22 @@ def _on_duration(event: str, duration: float, **kw):
             "backend compile wall time").observe(duration)
 
 
+def _on_gc(phase: str, info: Dict[str, Any]):
+    """A ``gc.callbacks`` hook: sums the seconds of collections. It runs
+    in whichever thread set the collection off, possibly one that holds
+    ``_lock``, so it takes no lock: two floats under the interpreter's
+    own."""
+    global _gc_began, _gc_seconds
+    if phase == "start":
+        _gc_began = time.monotonic()
+    elif _gc_began is not None:
+        _gc_seconds += time.monotonic() - _gc_began
+        _gc_began = None
+
+
 def install_compile_listener():
-    """Idempotently hook jax.monitoring's compile-duration stream.
+    """Idempotently hook jax.monitoring's compile-duration stream, and
+    the garbage collector's callbacks with it.
 
     Degrades gracefully: if this jax has no (or a renamed) monitoring
     API, detection stays silently off (compile_count() == 0 forever)
@@ -229,6 +262,7 @@ def install_compile_listener():
         if _installed:
             return
         _installed = True  # one attempt per process, success or not
+    gc.callbacks.append(_on_gc)
     try:
         import jax.monitoring
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
@@ -244,6 +278,20 @@ def compile_count() -> int:
     installed (0 before :func:`install_compile_listener`)."""
     with _lock:
         return _count
+
+
+def trace_count() -> int:
+    """Functions traced to a jaxpr in this process since the listener was
+    installed: every compile begins with one, and so does a call that
+    misses ``jit``'s own cache and finds its program compiled already."""
+    with _lock:
+        return _traces
+
+
+def gc_seconds() -> float:
+    """Seconds the garbage collector has run since the listener was
+    installed, every generation and thread together."""
+    return _gc_seconds
 
 
 def shape_signature(feeds: Optional[Dict[str, Any]]) -> str:

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+from paddle_tpu.observability import anatomy as _anatomy
 from paddle_tpu.observability import registry as _registry
 from paddle_tpu.observability import tracing as _tracing
 from paddle_tpu.observability.registry import (Counter, Gauge, Histogram,
@@ -19,8 +20,11 @@ SPAN_METRIC = "record_event_span_seconds"
 
 
 def report(reg: Optional[_registry.MetricsRegistry] = None,
-           tracer: Optional[_tracing.Tracer] = None) -> str:
-    """Render the unified observability summary."""
+           tracer: Optional[_tracing.Tracer] = None,
+           anatomy: Optional[_anatomy.StepAnatomy] = None) -> str:
+    """Render the unified observability summary. ``anatomy`` (an
+    engine's ``eng.anatomy``): its newest slow steps are printed under
+    their counts, which the registry alone gives."""
     reg = reg or _registry.default()
     tracer = tracer or _tracing.default()
     scalars: List[str] = []
@@ -69,7 +73,7 @@ def report(reg: Optional[_registry.MetricsRegistry] = None,
                          f"{a['total_s']:>12.4f}")
         if tracer.dropped:
             lines.append(f"(ring dropped {tracer.dropped} older spans)")
-    anatomy_lines = _anatomy_lines(reg)
+    anatomy_lines = _anatomy_lines(reg, anatomy)
     if anatomy_lines:
         lines.append("-- anatomy --")
         lines.extend(anatomy_lines)
@@ -82,11 +86,29 @@ def report(reg: Optional[_registry.MetricsRegistry] = None,
     return "\n".join(lines)
 
 
-def _anatomy_lines(reg: _registry.MetricsRegistry) -> List[str]:
-    """Step-anatomy digest, when a StepAnatomy fed this registry: the
-    per-phase call-wall-time split, host-gap/host fractions, and the
-    resource-headroom snapshot."""
-    out: List[str] = []
+def _anatomy_lines(reg: _registry.MetricsRegistry,
+                   anatomy: Optional[_anatomy.StepAnatomy] = None
+                   ) -> List[str]:
+    """Step-anatomy digest, when a StepAnatomy fed this registry: first
+    the slow steps (``serving_slow_steps_total`` by part, then the ring's
+    newest records) and the traces and collector seconds inside working
+    steps, then the per-phase call-wall-time split,
+    host-gap/host fractions, and the resource-headroom snapshot."""
+    counts = {}
+    slow = reg.get("serving_slow_steps_total")
+    if isinstance(slow, Counter):
+        for key in slow.labels_seen():
+            labels = dict(key)
+            counts[f"{labels.get('phase', '?')}.{labels.get('part', '?')}"] \
+                = slow.value(**labels)
+    out: List[str] = _anatomy.slow_step_lines(
+        counts, anatomy.records() if anatomy is not None else ())
+    traces = reg.get("serving_step_traces_total")
+    gc_s = reg.get("serving_step_gc_seconds_total")
+    if isinstance(traces, Counter) and isinstance(gc_s, Counter):
+        # what a step can wait on that no part names
+        out.append(f"in_working_steps traces={int(traces.value())} "
+                   f"gc={gc_s.value() * 1e3:.2f}ms")
     phase_h = reg.get("anatomy_phase_seconds")
     if isinstance(phase_h, Histogram):
         sums = {}

@@ -108,8 +108,11 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from paddle_tpu.analysis.concurrency import guarded_by
+from paddle_tpu.observability import recompile as _recompile
+from paddle_tpu.observability.anatomy import GAP_PART, OTHER_PART
 from paddle_tpu.serving import layer_kinds
 from paddle_tpu.serving.paged_cache import (_ROOT_KEY, _chain,
                                             PagedCacheConfig, PagedKVCache,
@@ -163,6 +166,14 @@ _STEP_PARTS = (("prefill", "assemble"), ("prefill", "cow_copy"),
                ("prefill", "book"), ("decode", "assemble"),
                ("decode", "dispatch"), ("decode", "sync"),
                ("decode", "book"), ("sched", "book"), ("observe", "book"))
+#: what a step's record holds beside them: the caller's time between two
+#: steps, and what is left of the step's wall (``anatomy.GAP_PART`` /
+#: ``OTHER_PART``). A slow step is named after one of all thirteen
+_SLOW_PARTS = _STEP_PARTS + (tuple(GAP_PART.split(".")),
+                             tuple(OTHER_PART.split(".")))
+#: lanes a prefill call carried; calls a step that made any
+_LANE_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
+_CALL_BUCKETS = (1, 2, 4, 8, 12, 16, 24, 32, 64)
 
 #: the ``jax.named_scope`` names the two loops open, one around each
 #: program hook (``_decode_loop`` / ``_prefill_loop``). They reach the
@@ -180,6 +191,23 @@ MIGRATION_FORMAT = "paddle_tpu.serving.slot-migration-v1"
 # as slot migration, wrapped per published page with its chain key and
 # token content so the importer can re-verify the whole hash chain
 PREFIX_BUNDLE_FORMAT = "paddle_tpu.serving.prefix-pages-v1"
+
+
+class _StepPart:
+    """One part of ``serving_step_part_seconds_total`` as a phase's
+    counter: the bound child, and beside it the seconds credited inside
+    the step in progress (``step()`` zeroes them on entry), so that a
+    step knows its own parts from the clock reads its phases took."""
+
+    __slots__ = ("_child", "in_step")
+
+    def __init__(self, child):
+        self._child = child
+        self.in_step = 0.0
+
+    def inc(self, n: float):
+        self._child.inc(n)
+        self.in_step += n
 
 
 @dataclasses.dataclass
@@ -434,8 +462,7 @@ class ServingEngine:
         # step-time anatomy (ISSUE 16): host gap / phase-split device
         # busy / host assembly per step; the flight recorder rides along
         # as the replica's crash black box (the router dumps it on eject)
-        self.anatomy = obs.StepAnatomy(registry=self._reg,
-                                       tracer=self.tracer)
+        self.anatomy = obs.StepAnatomy(registry=self._reg)
         self.flight = obs.FlightRecorder(
             "engine", anatomy=self.anatomy, registry=self._reg,
             tracer=self.tracer)
@@ -519,6 +546,13 @@ class ServingEngine:
         self._pending: Optional[_Block] = None
         self._last_settle_end = 0.0
         self._left_step_at = 0.0
+        # whether the engine held work when step() last returned: only
+        # then is the caller's time until the next step a part of it
+        self._left_with_work = False
+        #: the step in progress: its prefill calls ``(lanes_live, lanes,
+        #: width, tokens, seconds)`` and what a slow step's record says
+        self._step_calls: Optional[List[tuple]] = None
+        self._step_width = 0
         # migration page IO (fleet drain): src/dst are traced scalars,
         # so ONE compile each covers every page ever moved
         self.read_page_step = jax.jit(self._read_page_impl)
@@ -597,8 +631,42 @@ class ServingEngine:
             "seconds inside step() by phase (prefill/decode/sched/observe) "
             "and part (assemble/cow_copy/dispatch/sync/book): the same "
             "clock reads as the serving.* phase spans")
-        self._c_part = {(ph, pt): part.child(phase=ph, part=pt)
+        self._c_part = {(ph, pt): _StepPart(part.child(phase=ph, part=pt))
                         for ph, pt in _STEP_PARTS}
+        self._step_parts = [(f"{ph}.{pt}", child)
+                            for (ph, pt), child in self._c_part.items()]
+        # the slow steps (observability/anatomy.SlowStepRule): every child
+        # bound here, so a run that met none reads 0.0 and not nothing
+        slow = r.counter(
+            "serving_slow_steps_total",
+            "working steps the slow-step rule flagged, by the part with "
+            "the largest excess (caller/gap: the caller's time between "
+            "two steps; step/other: inside step(), in no named part)")
+        excess = r.counter(
+            "serving_slow_step_excess_seconds_total",
+            "seconds the flagged steps' parts took above three times "
+            "their own medians, by the part that named the step")
+        self._c_slow = {f"{ph}.{pt}": (slow.child(phase=ph, part=pt),
+                                       excess.child(phase=ph, part=pt))
+                        for ph, pt in _SLOW_PARTS}
+        self._c_step_traces = r.counter(
+            "serving_step_traces_total",
+            "functions traced to a jaxpr while a working step ran (the "
+            "compile listener's trace events; warm-up is outside step()): "
+            "a step program traced again costs its tracing even where "
+            "nothing compiles").child()
+        self._c_step_gc = r.counter(
+            "serving_step_gc_seconds_total",
+            "seconds the garbage collector ran inside working steps"
+        ).child()
+        self._h_call_lanes = r.histogram(
+            "serving_prefill_call_lanes",
+            "slots a batched prefill call carried (its lanes less the "
+            "padding up to the bucket)", buckets=_LANE_BUCKETS).child()
+        self._h_step_calls = r.histogram(
+            "serving_step_prefill_calls",
+            "batched prefill calls in a step that made any",
+            buckets=_CALL_BUCKETS).child()
         self._c_step_seconds = r.counter(
             "serving_step_seconds_total",
             "wall seconds inside step(), steps that did work only").child()
@@ -685,10 +753,6 @@ class ServingEngine:
         self._g_spill_bytes = r.gauge(
             "serving_spill_bytes",
             "bytes of KV (incl. int8 scale rows) in the host spill pool"
-        ).child()
-        self._g_flops_util = r.gauge(
-            "serving_flops_utilization",
-            "retired static flops per busy second / best observed rate"
         ).child()
         self._bind_state_metrics(r)
         for kind in self._kinds:
@@ -992,7 +1056,6 @@ class ServingEngine:
             g.set(head[res])
         self._g_spill_pages.set(head["spill_pages"])
         self._g_spill_bytes.set(head["spill_bytes"])
-        self._g_flops_util.set(flops_util)
         self._g_prefix_saved.set(head["prefix_saved_per_token"])
         return head
 
@@ -1087,18 +1150,28 @@ class ServingEngine:
         Every layer boundary inside is one ``tracer.phase`` (names and
         parents: PERF.md section 3): a profiler annotation, a ring span
         when the tracer is on, and the seconds credited to
-        ``serving_step_part_seconds_total``."""
+        ``serving_step_part_seconds_total``. The same seconds, kept for
+        this step alone, are its anatomy record's ``parts``; a step the
+        slow-step rule flags is counted, and marked in a profiler's
+        trace, after its phase has closed (:meth:`_note_slow_step`)."""
         finished: Dict[int, np.ndarray] = {}
         self._anat_steps += 1
         phase = self.tracer.phase
         sched = self._c_part["sched", "book"]
+        for _, part in self._step_parts:
+            part.in_step = 0.0
+        self._step_calls = None
+        self._step_width = 0
+        n_admitted = 0
+        traces0 = _recompile.trace_count()
+        gc0 = _recompile.gc_seconds()
         with phase("serving.step", stamp=True,
                    step=self._anat_steps) as step_ph:
             self.anatomy.begin_step(self._anat_steps, t0=step_ph.start)
+            away = step_ph.start - self._left_step_at
             if self._pending is not None:
                 # what the caller did between two steps is no part of
                 # the interval of the block in flight
-                away = step_ph.start - self._left_step_at
                 self._pending.t0 += away
                 self._last_settle_end += away
             step_tokens = 0
@@ -1113,6 +1186,7 @@ class ServingEngine:
                 # in the same call (no over-commit on a down-sized pool)
                 with phase("serving.admit", sched):
                     admitted = self.scheduler.admit(on_admit=self._on_admit)
+                n_admitted += len(admitted)
                 done = self._prefill_round(
                     budget, allow_liveness=not prefilled_any)
                 prefilled_any = prefilled_any or done > 0
@@ -1164,14 +1238,54 @@ class ServingEngine:
         if prefilled_any or decoded:
             # the step's seconds and its anatomy record's wall are the
             # step phase's one clock-read pair
-            self._c_step_seconds.inc(step_ph.end - step_ph.start)
-            self.anatomy.end_step(tokens=step_tokens, t1=step_ph.end)
+            wall = step_ph.end - step_ph.start
+            self._c_step_seconds.inc(wall)
+            traces = _recompile.trace_count() - traces0
+            gc_s = _recompile.gc_seconds() - gc0
+            if traces:
+                self._c_step_traces.inc(traces)
+            if gc_s:
+                self._c_step_gc.inc(gc_s)
+            calls = self._step_calls
+            if calls:
+                self._h_step_calls.observe(len(calls))
+            # the step's own parts: what its phases credited, what is
+            # left of its wall, and the caller's time before it where
+            # the engine held work meanwhile (waiting on an empty queue
+            # is no part of a step)
+            parts = {name: part.in_step for name, part in self._step_parts}
+            parts[OTHER_PART] = max(wall - sum(parts.values()), 0.0)
+            parts[GAP_PART] = away if self._left_with_work else 0.0
+            rec = self.anatomy.end_step(
+                tokens=step_tokens, t1=step_ph.end, parts=parts,
+                prefill_calls=calls, slow_detail=lambda: dict(
+                    slots_live=len(dslots), width=self._step_width,
+                    admitted=n_admitted, evicted=len(finished),
+                    traces=traces, gc_s=round(gc_s, 9)))
+            if rec.get("slow"):
+                self._note_slow_step(rec)
         else:
             # an idle tick is not a serving step: recording it would
             # count queue-empty waiting as "host gap"
             self.anatomy.cancel_step()
         self._left_step_at = step_ph.end
+        self._left_with_work = (self._pending is not None
+                                or not self.scheduler.idle())
         return finished
+
+    def _note_slow_step(self, rec: Dict[str, object]):
+        """A step the slow-step rule flagged (its anatomy record says
+        which part and by how much): counted under that part, and ONE
+        zero-length annotation whose ``step`` is the ``serving.step``
+        span's, so that a profiler's trace can be joined to the record.
+        Steps that are not slow emit nothing."""
+        steps, excess = self._c_slow[rec["slow_part"]]
+        steps.inc()
+        excess.inc(rec["excess_s"])
+        with TraceAnnotation("serving.slow_step", step=rec["step"],
+                             part=rec["slow_part"],
+                             excess_us=int(rec["excess_s"] * 1e6)):
+            pass
 
     def _budget_left(self, slot: int) -> int:
         """Tokens ``slot``'s request may still be dispatched for: its
@@ -1298,6 +1412,7 @@ class ServingEngine:
         new one itself. With no ``dslots`` it settles what is pending.
         Returns tokens kept."""
         w = self._decode_width(dslots, self.decode_block) if dslots else 0
+        self._step_width = w
         with self.tracer.phase("serving.decode_round", width=w,
                                slots_live=len(dslots)) as rnd:
             if not dslots:
@@ -1488,7 +1603,7 @@ class ServingEngine:
         nothing leaks. Returns tokens kept."""
         n = self.spec_k
         s_tot = self.scheduler.num_slots
-        w = self._decode_width(dslots, n)
+        w = self._step_width = self._decode_width(dslots, n)
         phase, part = self.tracer.phase, self._c_part
         with phase("serving.decode_round", width=w,
                    slots_live=len(dslots)) as rnd:
@@ -1861,7 +1976,7 @@ class ServingEngine:
                    for _, i in ends)
         phase, part = self.tracer.phase, self._c_part
         with phase("serving.prefill_call", lanes=sb, width=w,
-                   tokens=call_tokens) as call:
+                   tokens=call_tokens, lanes_live=len(pslots)) as call:
             pend = [(i, pc) for i in pslots
                     if (pc := self.cache.pending_copy(i)) is not None]
             if pend:
@@ -1954,6 +2069,11 @@ class ServingEngine:
                                if self.speculative else ()), now - t0,
                             waited=wait)
             self._c_prefill_calls.inc()
+            self._h_call_lanes.observe(len(pslots))
+            if self._step_calls is None:
+                self._step_calls = []
+            self._step_calls.append((len(pslots), sb, w, call_tokens,
+                                     now - t0))
             with phase("serving.prefill.book", part["prefill", "book"]):
                 tr_on = self.tracer.enabled
                 for j, (i, n) in enumerate(zip(pslots, ns)):

@@ -69,23 +69,31 @@ def _engine(model_params, tracer=None, **kw):
 
 class TestStepAnatomy:
     def test_record_schema_metrics_and_spans(self):
+        """A record's wall and phases are the ``serving.*`` phase spans'
+        own clock reads; the anatomy records no span of its own."""
         reg = obs.MetricsRegistry()
         tracer = obs.Tracer(enabled=True)
-        a = obs.StepAnatomy(registry=reg, tracer=tracer)
-        a.begin_step(1)
-        t = a.now()
-        a.add_phase("prefill", t - 0.004, t - 0.003)
-        a.add_phase("decode", t - 0.002, t - 0.0005)
-        time.sleep(0.005)       # wall must cover the claimed phases
-        rec = a.end_step(tokens=3)
+        a = obs.StepAnatomy(registry=reg)
+        with tracer.phase("serving.step", step=1) as step_ph:
+            a.begin_step(1, t0=step_ph.start)
+            with tracer.phase("serving.prefill.dispatch") as pre:
+                time.sleep(0.001)
+            a.add_phase("prefill", pre.start, pre.end)
+            with tracer.phase("serving.decode.sync") as dec:
+                time.sleep(0.0015)
+            a.add_phase("decode", dec.start, dec.end)
+        rec = a.end_step(tokens=3, t1=step_ph.end)
         anat.validate_anatomy_record(rec)
         assert rec["step"] == 1 and rec["tokens"] == 3
-        assert rec["phases"]["decode"] == pytest.approx(0.0015)
+        spans = {s.name: s for s in tracer.spans()}
+        assert set(spans) == {"serving.step", "serving.prefill.dispatch",
+                              "serving.decode.sync"}
+        assert rec["phases"]["decode"] == round(
+            spans["serving.decode.sync"].duration_s, 9) >= 0.0015
+        assert rec["wall_s"] == round(spans["serving.step"].duration_s, 9)
         assert reg.counter("anatomy_steps_total").value() == 1
         assert reg.histogram("anatomy_phase_seconds").summary(
             phase="decode")["count"] == 1
-        names = {s.name for s in tracer.spans()}
-        assert "anatomy.step" in names and "anatomy.decode" in names
 
     def test_ring_bounded_under_10k_steps(self):
         """The black-box discipline: 10k steps leave the ring at its
@@ -156,7 +164,7 @@ class TestFlightRecorder:
     def _bundle(self):
         reg = obs.MetricsRegistry()
         tracer = obs.Tracer(enabled=True)
-        a = obs.StepAnatomy(registry=reg, tracer=tracer)
+        a = obs.StepAnatomy(registry=reg)
         fr = obs.FlightRecorder("rX", anatomy=a, registry=reg,
                                 tracer=tracer, snapshot_every=1)
         for i in range(4):
@@ -396,28 +404,36 @@ class TestEngineAnatomy:
                 == pytest.approx(got, abs=1e-6)
         assert "dispatch + sync" in eng._reg.get("anatomy_phase_seconds").help
         # the spans in the ring carry the same stamps
+        recs = eng.anatomy.records()
         asm = tracer.spans(name="serving.decode.assemble")
         sync = tracer.spans(name="serving.decode.sync")
-        calls = tracer.spans(name="anatomy.decode")
         disp = tracer.spans(name="serving.decode.dispatch")
+        rounds = [r["phases"]["decode"] for r in recs
+                  if "decode" in r["phases"]]
         # ISSUE 34: a round's decode interval is what the host spent on
         # it: the new block's uploads and dispatch, then the wait for
         # the block before. The first round only dispatches, the last
         # step only waits
-        assert len(asm) == len(sync) == len(calls) - 1 >= 1
-        assert [(c.start, c.end) for c in calls] == \
-            [(asm[0].start, disp[0].end)] \
-            + [(a.start, s.end) for a, s in zip(asm[1:], sync)] \
-            + [(sync[-1].start, sync[-1].end)]
+        assert len(asm) == len(sync) == len(rounds) - 1 >= 1
+        assert rounds == [round(e - s, 9) for s, e in
+                          [(asm[0].start, disp[0].end)]
+                          + [(a.start, s.end) for a, s in zip(asm[1:], sync)]
+                          + [(sync[-1].start, sync[-1].end)]]
         # ISSUE 31: no request here makes a prefill call read back, so a
-        # call's interval ends where its dispatch returned
+        # call's interval ends where its dispatch returned; ISSUE 52: a
+        # step's record holds each of its calls
         assert tracer.spans(name="serving.prefill.sync") == []
         asm = tracer.spans(name="serving.prefill.assemble")
         disp = tracer.spans(name="serving.prefill.dispatch")
-        calls = tracer.spans(name="anatomy.prefill")
+        calls = [c for r in recs for c in r["prefill_calls"]]
         assert len(asm) == len(disp) == len(calls) >= 1
-        assert sorted((a.start, d.end) for a, d in zip(asm, disp)) == \
-            sorted((c.start, c.end) for c in calls)
+        assert [c[4] for c in calls] == [round(d.end - a.start, 9)
+                                         for a, d in zip(asm, disp)]
+        assert [(c[0], c[1], c[2], c[3]) for c in calls] == [
+            (s.attrs["lanes_live"], s.attrs["lanes"], s.attrs["width"],
+             s.attrs["tokens"])
+            for s in tracer.spans(name="serving.prefill_call")]
+        assert not any(s.name.startswith("anatomy.") for s in tracer.spans())
         # the step's wall time bounds its parts, sync included
         step_s = snap["serving_step_seconds_total"]
         assert 0 < sum(v for k, v in snap.items() if k.startswith(
@@ -425,7 +441,6 @@ class TestEngineAnatomy:
         # and is the anatomy records' wall, from the same clock reads
         # (a record rounds to the nanosecond)
         steps = tracer.spans(name="serving.step")
-        recs = eng.anatomy.records()
         assert step_s == pytest.approx(sum(r["wall_s"] for r in recs),
                                        abs=1e-8 * len(recs))
         assert [r["wall_s"] for r in recs] == [

@@ -5,10 +5,11 @@ The router dumps a bundle (``observability.flight.write_bundle``) on
 every replica eject, breaker-open, and shed spike; this tool is the
 offline half — point it at one bundle or a dump directory and it
 validates the schema, then prints the incident digest: who died, why,
-which requests were on board (trace ids), the health trajectory
-leading up to the failure, the step-anatomy tail, and the headroom
-plane at the moment of capture. ``--trace-out`` extracts the embedded
-Chrome trace for Perfetto.
+the slow steps the replica met (how many by the part that named them,
+then the newest five), which requests were on board (trace ids), the
+health trajectory leading up to the failure, the step-anatomy tail, and
+the headroom plane at the moment of capture. ``--trace-out`` extracts
+the embedded Chrome trace for Perfetto.
 
 Usage:
     python tools/postmortem.py BUNDLE.json [--trace-out trace.json]
@@ -47,10 +48,14 @@ def _headroom_line(health: dict) -> str:
 
 def render(bundle: dict, tail: int = 8) -> str:
     """One bundle -> text digest (validated by the caller)."""
+    from paddle_tpu.observability.anatomy import slow_step_lines
     lines = []
     lines.append(f"== postmortem: {bundle['replica']} "
                  f"reason={bundle['reason']} "
                  f"at {_fmt_ts(bundle['ts'])} ==")
+    lines.extend("  " + ln for ln in slow_step_lines(
+        (bundle.get("anatomy_summary") or {}).get("slow_steps") or {},
+        bundle.get("anatomy") or []))
     extra = bundle.get("extra") or {}
     if extra:
         lines.append("  extra: " + " ".join(
