@@ -36,7 +36,8 @@ first-class telemetry):
    ``serving_step_part_seconds_total``, with ``caller.gap``, the
    caller's time before the step, and ``step.other``, what no part
    names) and its ``prefill_calls`` (``[lanes_live, lanes, width,
-   tokens, seconds]`` each). A fixed rule (``anatomy.SlowStepRule``; its
+   tokens, seconds, run]`` each; ``run``: the most consecutive chunks of
+   one slot among the call's lanes). A fixed rule (``anatomy.SlowStepRule``; its
    constants are module-level names beside it, nothing can be set) marks
    a step ``slow`` with the part that made it so (``slow_part``,
    ``excess_s``) and what the engine knew of it (``slots_live``,

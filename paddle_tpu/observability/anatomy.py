@@ -32,8 +32,10 @@ took anyway: ``parts``, the seconds of each ``phase.part`` of
 :data:`GAP_PART` (what the caller spent between the step before and this
 one, outside the wall) and :data:`OTHER_PART` (what is left of the wall:
 they sum to it), and ``prefill_calls``, one ``[lanes_live, lanes, width,
-tokens, seconds]`` a batched prefill call (the slow records hold the two
-for good, the others while they are among the newest ``PARTS_TAIL``).
+tokens, seconds, run]`` a batched prefill call (``run``, since PR 54: the
+most consecutive chunks of one slot among its lanes; a caller that gives
+five has five kept; the slow records hold the two for good, the others
+while they are among the newest ``PARTS_TAIL``).
 :class:`SlowStepRule` judges every such step against the steps before
 it and marks a slow one (``slow``, ``slow_part``, ``excess_s`` and what
 the engine knows of the step), so the flight recorder's bundle and
@@ -295,7 +297,7 @@ class StepAnatomy:
         """``parts``: this step's seconds by ``phase.part``, ``GAP_PART``
         and ``OTHER_PART`` among them; ``prefill_calls``: its batched
         prefill calls, ``(lanes_live, lanes, width, tokens, seconds)``
-        each. A step that gives its parts is judged by the slow-step
+        each, or with the call's longest run as a sixth. A step that gives its parts is judged by the slow-step
         rule; ``slow_detail()`` is asked only of a slow one, for what
         else its record should say. Returns the ring's record: a slow
         one holds its ``parts`` and ``prefill_calls`` itself, any other
@@ -329,7 +331,9 @@ class StepAnatomy:
                           if s > 0.0 or p == OTHER_PART},
                 "prefill_calls": [
                     [int(live), int(lanes), int(w), int(toks), round(s, 9)]
-                    for live, lanes, w, toks, s in prefill_calls or ()]}
+                    + [int(run) for run in more]
+                    for live, lanes, w, toks, s, *more
+                    in prefill_calls or ()]}
             verdict = self.slow_rule.judge(wall, parts)
             if verdict is not None:
                 part, excess = verdict
@@ -517,10 +521,12 @@ def validate_anatomy_record(rec: Dict[str, Any], *, index: int = 0,
                 OTHER_PART in parts and inside < rec["wall_s"] - _EPS):
             fail(f"parts sum to {inside:.9f}, wall is {rec['wall_s']:.9f}")
     for i, call in enumerate(rec.get("prefill_calls", ())):
-        if not isinstance(call, (list, tuple)) or len(call) != 5 \
-                or not all(seconds(v) for v in call) or call[0] > call[1]:
+        if not isinstance(call, (list, tuple)) or len(call) not in (5, 6) \
+                or not all(seconds(v) for v in call) or call[0] > call[1] \
+                or (len(call) == 6 and not 1 <= call[5] <= call[0]):
             fail(f"prefill_calls[{i}] is {call!r}, want [lanes_live, "
-                 "lanes, width, tokens, seconds]")
+                 "lanes, width, tokens, seconds] or with the longest run "
+                 "as a sixth")
     if rec.get("slow"):
         if not isinstance(rec.get("slow_part"), str) \
                 or not seconds(rec.get("excess_s")):

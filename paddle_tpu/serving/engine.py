@@ -6,16 +6,21 @@ layers cache (:mod:`~paddle_tpu.serving.layer_kinds`: one kind a layer,
 built once from the program's spec and the cache's geometry; the loops are
 straight-line code over ``cache.config.kinds[i]``):
 
-- a **batched chunked-prefill step**: one call advances EVERY admitted
-  request's next prompt chunk at once — tokens (S, C), ragged per-slot
-  valid counts, causal paged attention;
+- a **batched chunked-prefill step**: one call advances the admitted
+  requests' next prompt chunks at once — tokens (lanes, C), ragged
+  per-lane valid counts, causal paged attention. A lane is a (slot,
+  chunk) pair: where the program's layer kinds take it
+  (``layer_kinds.Paged.prefill_run``) consecutive lanes carry a **run** of
+  consecutive chunks of ONE prompt, so that a step's prompt tokens read
+  the weights in one call however few prompts are in prefill;
 - a **decode step**: every slot advances a BLOCK of ``decode_block``
   tokens per call (an on-device ``fori_loop``, amortizing the host
   round-trip), attending over its own pages.
 
-All shapes are static: ``num_slots``, the prefill chunk, and pow2-
-bucketed block-table gather widths that track the LIVE high-water mark
-(work follows live tokens, not slot capacity). The cache pages are
+All shapes are static: ``num_slots``, the prefill chunk, pow2-bucketed
+block-table gather widths that track the LIVE high-water mark (work
+follows live tokens, not slot capacity), and bucketed lane counts (1, 2,
+4, 8, then every multiple of 8 the budget reaches). The cache pages are
 **donated** into both steps, and :meth:`ServingEngine.warmup` precompiles
 every bucket, so steady-state serving triggers zero recompiles and zero
 cache copies (a :class:`~paddle_tpu.observability.RecompileDetector`
@@ -26,7 +31,12 @@ Prefill and decode **interleave** under a per-step token budget
 ``max(prefill_budget, prefill_chunk)`` prompt tokens on prefill before
 running the decode block — the chunk floor is a single liveness lane
 for budgets below one chunk — so a burst of long prompts cannot starve
-in-flight decodes and vice versa.
+in-flight decodes and vice versa. The budget is a ceiling, not a quota:
+where calls carry runs and a slot decodes, a slot gives a step one run,
+and what it could still give leads the next step's call; a further call
+of the step carries only slots the first had no lane for, and only at or
+above the break-even in lanes under which it is mostly a second read of
+the weights (:meth:`ServingEngine._prefill_round`).
 
 The host waits for the device **once a step**: every call of a step is
 ordered on the device by the donated page pool it threads, so a prefill
@@ -174,6 +184,38 @@ _SLOW_PARTS = _STEP_PARTS + (tuple(GAP_PART.split(".")),
 #: lanes a prefill call carried; calls a step that made any
 _LANE_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
 _CALL_BUCKETS = (1, 2, 4, 8, 12, 16, 24, 32, 64)
+#: the one step of a prefill call's lanes: lane buckets are powers of two
+#: up to here, then every multiple of it (:meth:`ServingEngine._pow2_count`),
+#: and a window layer's ring gets this many pages of room (or the lanes
+#: the budget buys, if fewer: ``layer_kinds.build(prefill_room=)``), so
+#: one slot's longest run is one step and whole runs fill a bucket. A
+#: pad lane runs the dense projections and the dense MLP on zeros (only
+#: the attention kernels and the expert layer skip a dead lane), which a
+#: call of a few lanes hides under its weights' read and a wide one does
+#: not: a power-of-two bucket pads 24 live lanes with 8, a multiple of 8
+#: with none, and at most 7 of any count (2.1 ms a pad lane in the
+#: long-prompt cell: PERF.md section 6, PR 54). A page of room is
+#: ``num_slots`` x window layers x a page of K and V: 8 are 1.47 GB over
+#: the one page a ring had in the long-prompt cell (PERF.md section 3)
+_LANE_STEP = 8
+#: what the matrix unit retires while one byte arrives from HBM (TPU
+#: v5e: 197 TFLOP/s in bf16 over 819 GB/s)
+_FLOPS_PER_HBM_BYTE = 240
+
+
+def _break_even_lanes(itemsize: int, chunk: int) -> int:
+    """The lanes of ``chunk`` rows at which a prefill call's arithmetic
+    takes as long as its weights' read, from the configuration's bytes
+    and flops: a weight of ``itemsize`` bytes is read once a call and
+    does 2 flops a row, so the two meet at ``itemsize *
+    _FLOPS_PER_HBM_BYTE / 2`` rows (240 in bf16). A call under it is
+    mostly a read of the stage's weights. Reckoned as if every held
+    weight met every row: for a stage that holds more experts than a row
+    meets it is the least the break-even can be (2 lanes in bf16 at a
+    chunk of 128, where the long-prompt cell's held experts make it
+    about 5), so no call worth its read is held back by it."""
+    return -(-itemsize * _FLOPS_PER_HBM_BYTE // (2 * chunk))
+
 
 #: the ``jax.named_scope`` names the two loops open, one around each
 #: program hook (``_decode_loop`` / ``_prefill_loop``). They reach the
@@ -400,6 +442,12 @@ class ServingEngine:
         # a tp engine's pool is globally shaped but placed sharded H/tp
         geometry = dict(num_slots=num_slots, page_size=page_size,
                         num_pages=num_pages)
+        #: the most lanes one prefill call carries: what the budget buys
+        #: (the liveness lane where it buys none), a lane a slot at least
+        #: (a lane's index codes its first token's place for the decode
+        #: block, under ``num_slots``)
+        self._lane_cap = min(
+            max(self.prefill_budget // self.prefill_chunk, 1), num_slots)
         self.cache = self._paged_cache(
             spec, dtype, prefix_sharing, geometry, max_pages_per_slot,
             tp=tp, mesh=self.mesh, host_spill_pages=host_spill_pages)
@@ -411,6 +459,22 @@ class ServingEngine:
         #: pages (pool row slot + 1): whose state row, whose ring
         self._lane_slot_column = bool(spec.slot_state) or any(
             kind.by_slot for kind in self._kinds)
+        #: consecutive chunks of ONE slot a prefill call may carry: the
+        #: least over what the program is built from. Its layers' kinds
+        #: say theirs; a program that carries state from chunk to chunk
+        #: outside the pages (two lanes of a slot would write one state
+        #: row) and an engine option not shown to take runs yet (a draft
+        #: cache beside the target's, a tier, a sharded pool) answer 1,
+        #: which is the call of one chunk a slot as it always was
+        self._run_limit = 1 if (
+            spec.slot_state or self.speculative or tier != "colocated"
+            or tp > 1) else max(min(
+                [self._lane_cap] + [kind.prefill_run for kind in self._kinds
+                                    if kind.prefill_run is not None]), 1)
+        #: the fewest live lanes a further call of a step is made for
+        #: where calls carry runs (:meth:`_prefill_round`)
+        self._second_call_lanes = _break_even_lanes(
+            jnp.dtype(base.param_dtype(params)).itemsize, self.prefill_chunk)
         self.draft_cache = None
         if self.speculative:
             # same slot/page geometry as the target cache: allocations
@@ -553,6 +617,9 @@ class ServingEngine:
         #: width, tokens, seconds)`` and what a slow step's record says
         self._step_calls: Optional[List[tuple]] = None
         self._step_width = 0
+        #: the slots this step's prefill calls have carried
+        #: (:meth:`_prefill_round`'s rule on further calls)
+        self._step_carried: set = set()
         # migration page IO (fleet drain): src/dst are traced scalars,
         # so ONE compile each covers every page ever moved
         self.read_page_step = jax.jit(self._read_page_impl)
@@ -661,8 +728,22 @@ class ServingEngine:
         ).child()
         self._h_call_lanes = r.histogram(
             "serving_prefill_call_lanes",
-            "slots a batched prefill call carried (its lanes less the "
-            "padding up to the bucket)", buckets=_LANE_BUCKETS).child()
+            "live lanes a batched prefill call carried (its lanes less "
+            "the padding up to the bucket): a lane is one chunk of one "
+            "slot, and a slot may give a call several",
+            buckets=_LANE_BUCKETS).child()
+        self._h_run_chunks = r.histogram(
+            "serving_prefill_run_chunks",
+            "consecutive chunks of one slot in a batched prefill call, "
+            "observed once a slot and call (1: the call carried one chunk "
+            "of the slot)", buckets=_LANE_BUCKETS).child()
+        lanes = r.counter(
+            "serving_prefill_lanes_total",
+            "lanes of the batched prefill calls: kind=live those that "
+            "carried a chunk, kind=bucket those the call's program ran "
+            "(live and the pad up to the bucket)")
+        self._c_lanes_live = lanes.child(kind="live")
+        self._c_lanes_bucket = lanes.child(kind="bucket")
         self._h_step_calls = r.histogram(
             "serving_step_prefill_calls",
             "batched prefill calls in a step that made any",
@@ -830,6 +911,7 @@ class ServingEngine:
         kinds = layer_kinds.build(
             spec, dtype=dtype, share_prefix=share_prefix, tp=tp,
             impl=self.attn_impl, prefill_chunk=self.prefill_chunk,
+            prefill_room=min(self._lane_cap, _LANE_STEP),
             **geometry)
         return PagedKVCache(PagedCacheConfig(
             num_layers=spec.num_layers, num_heads=spec.kv_heads,
@@ -1162,6 +1244,7 @@ class ServingEngine:
             part.in_step = 0.0
         self._step_calls = None
         self._step_width = 0
+        self._step_carried.clear()
         n_admitted = 0
         traces0 = _recompile.trace_count()
         gc0 = _recompile.gc_seconds()
@@ -1877,6 +1960,8 @@ class ServingEngine:
             self.draft_cache.reserve(slot, req.total_tokens)
         st = self.scheduler.slots[slot]
         st.prefilled = shared
+        # (a request new to its slot is one no call of this step carried)
+        self._step_carried.discard(slot)
         if shared:
             self._c_prefix_shared.inc(shared)
         self._reg.histogram(
@@ -1903,38 +1988,102 @@ class ServingEngine:
         call; the host learns it there only where the step needs it at
         once, else at the decode round's read-back (:meth:`_prefill_call`).
 
-        Each batched call computes up to ``lanes × prefill_chunk``
-        tokens, so the lane count is capped by the budget left; when
-        less than one chunk remains the round stops rather than
-        overshoot — except the ``allow_liveness`` single-lane exception
-        (used once per ``step()``), which keeps an admitted slot
-        progressing even with ``prefill_budget < prefill_chunk``. Net
-        per-step contract: at most ``max(prefill_budget,
-        prefill_chunk)`` prompt tokens."""
+        A lane of a call is one chunk of one slot, and each batched call
+        computes up to ``lanes x prefill_chunk`` tokens, so the lane
+        count is capped by the budget left; when less than one chunk
+        remains the round stops rather than overshoot — except the
+        ``allow_liveness`` single-lane exception (used once per
+        ``step()``), which keeps an admitted slot progressing even with
+        ``prefill_budget < prefill_chunk``. Net per-step contract: at
+        most ``max(prefill_budget, prefill_chunk)`` prompt tokens.
+
+        When lanes must wait the slots nearest their first token go
+        first (that closes TTFTs soonest, and each completion shrinks
+        the set so no admitted slot waits forever), and each slot gives
+        the call a **run** of consecutive chunks, a lane each
+        (:meth:`_lanes_of`). Where the engine's run limit is 1 that is
+        the call of one chunk a slot, and the round is the loop it
+        always was.
+
+        Where calls carry runs the budget is a ceiling, not a quota, and
+        a FURTHER call of a step is made by two rules over what the step
+        has issued and the call's own live lanes. While a slot decodes,
+        a slot gives a step one run: a further call carries only slots
+        no call of this step has carried (a burst of more short prompts
+        than a call has lanes), and what a carried slot could still give
+        leads the next step's call, where it rides with whoever was
+        admitted meanwhile; such a call would read the stage's weights
+        again for the same few prompts, between the first call and the
+        decode block every decoding slot waits for (PERF.md section 6,
+        PR 54: 46 ms for 8 lanes behind a call of 24 in the long-prompt
+        cell, the gap between tokens 1.1% longer for 0.5% more tokens).
+        Where no slot decodes nothing waits behind a call, and the
+        budget is spent as it always was: a lone long prompt advances a
+        budget a step, in calls of one run. And a further call is made
+        only for at least ``_second_call_lanes`` live lanes, the
+        break-even under which it is mostly a second read of the
+        weights; fewer wait for the next step."""
         consumed = 0
         c = self.prefill_chunk
+        runs = self._run_limit > 1
         with self.tracer.phase("serving.prefill_round"):
             while budget - consumed > 0:
+                further = runs and bool(self._step_carried)
                 pslots = [i for i in self.scheduler.active_slots()
                           if not self.scheduler.slots[i].prefill_done]
+                if further and self.scheduler.decode_slots():
+                    pslots = [i for i in pslots
+                              if i not in self._step_carried]
                 if not pslots:
                     break
-                lane_cap = (budget - consumed) // c
+                lane_cap = min((budget - consumed) // c, self._lane_cap)
                 if lane_cap == 0:
                     if consumed > 0 or not allow_liveness:
                         break
                     lane_cap = 1    # the once-per-step liveness lane
-                # when lanes must wait, run the slots closest to their
-                # first token: that closes TTFTs soonest, and each
-                # completion shrinks the set so no admitted slot waits
-                # forever
-                if len(pslots) > lane_cap:
-                    pslots.sort(key=lambda i: int(
-                        self.scheduler.slots[i].request.prompt.shape[0])
-                        - self.scheduler.slots[i].prefilled)
-                    pslots = pslots[:lane_cap]
-                consumed += self._prefill_call(pslots)
+                lanes = self._lanes_of(pslots, lane_cap)
+                if further and len(lanes) < self._second_call_lanes:
+                    break
+                consumed += self._prefill_call(lanes)
+                if runs:
+                    self._step_carried.update(lane[0] for lane in lanes)
         return consumed
+
+    def _lanes_of(self, pslots, lane_cap: int) -> List[tuple]:
+        """The lanes of one prefill call over ``pslots``, at most
+        ``lane_cap``: ``(slot, start, tokens)`` each, a slot's run of
+        consecutive chunks as consecutive lanes. When lanes must wait
+        (the slots want more than the call has) the slots nearest their
+        first token go first, else the scheduler's order stands; the
+        lanes go round the slots, a chunk a slot a round, until the call
+        is full or no slot's run can grow: each slot gets of one call
+        what the calls of one chunk a slot gave it of a step, so the
+        prompts in prefill advance side by side as they did (the order
+        decides who decodes when, and with it every request's gap
+        between tokens: PERF.md section 6, PR 54). A run is at most the
+        engine's run limit, which at 1 makes this the call of one chunk
+        a slot; a prompt's last chunk may be partial and a run may end
+        in it; a slot that still owes the copy of a borrowed tail page
+        gives one chunk (its run would start in the page the call
+        copies first)."""
+        slots = self.scheduler.slots
+        c = self.prefill_chunk
+
+        def left(i):
+            return int(slots[i].request.prompt.shape[0]) - slots[i].prefilled
+        most = {i: min(-(-left(i) // c),
+                       1 if self.cache.pending_copy(i) is not None
+                       else self._run_limit) for i in pslots}
+        if sum(most.values()) > lane_cap:
+            pslots = sorted(pslots, key=left)[:lane_cap]
+        runs, free = dict.fromkeys(pslots, 0), lane_cap
+        while free and any(runs[i] < most[i] for i in pslots):
+            for i in pslots:
+                if free and runs[i] < most[i]:
+                    runs[i] += 1
+                    free -= 1
+        return [(i, slots[i].prefilled + k * c, min(c, left(i) - k * c))
+                for i in pslots for k in range(runs[i])]
 
     def _reads_first_token_at_once(self, st) -> bool:
         """Whether the step must know a finishing prompt's first token
@@ -1947,24 +2096,30 @@ class ServingEngine:
         return (req.eos_id is not None or req.max_new_tokens == 1
                 or self.speculative or self.tier != "colocated")
 
-    def _prefill_call(self, pslots) -> int:
-        """One batched fixed-shape prefill call over ``pslots``' next
-        chunks; returns the prompt tokens it computed. The host waits
-        for the call only where a prompt ends in it whose first token
-        the step needs at once (:meth:`_reads_first_token_at_once`);
-        otherwise the tokens stay on the device, a finished prompt's as
-        ``self._owed`` for the decode round of this step."""
+    def _prefill_call(self, lanes) -> int:
+        """One batched fixed-shape prefill call over ``lanes``, ``(slot,
+        start, tokens)`` each, a slot's consecutive chunks as consecutive
+        lanes (:meth:`_lanes_of`); returns the prompt tokens it computed.
+        Every lane's rows of a layer are written before the layer
+        attends, so a lane finds the rows of its slot's earlier lanes
+        like any cached before. The host waits for the call only where a
+        prompt ends in it whose first token the step needs at once
+        (:meth:`_reads_first_token_at_once`); otherwise the tokens stay
+        on the device, a finished prompt's as ``self._owed`` for the
+        decode round of this step: the token of the lane whose chunk
+        ends the prompt."""
         c = self.prefill_chunk
         cfgc = self.cache.config
         slots = self.scheduler.slots
-        # compact batch: pow2-bucketed over the number of slots actually
-        # prefilling (a lone late admission does not pay for num_slots
-        # lanes of attention); padding lanes are inert (n_valid 0,
-        # null-page block tables)
-        sb = self._pow2_count(len(pslots))
-        los = [slots[i].prefilled for i in pslots]
-        ns = [min(c, int(slots[i].request.prompt.shape[0]) - lo)
-              for i, lo in zip(pslots, los)]
+        # compact batch: bucketed over the lanes actually live (a lone
+        # late admission does not pay for num_slots lanes of attention);
+        # padding lanes are inert (n_valid 0, null-page block tables)
+        sb = self._pow2_count(len(lanes))
+        pslots, los, ns = (list(col) for col in zip(*lanes))
+        # which lanes open their slot's run, and the runs' lengths
+        heads = np.asarray([j == 0 or i != pslots[j - 1]
+                            for j, i in enumerate(pslots)])
+        runs = np.diff(np.append(np.flatnonzero(heads), len(lanes)))
         call_tokens = sum(ns)
         w = self._pow2_width(max(cfgc.pages_for(lo + n)
                                  for lo, n in zip(los, ns)))
@@ -1976,8 +2131,9 @@ class ServingEngine:
                    for _, i in ends)
         phase, part = self.tracer.phase, self._c_part
         with phase("serving.prefill_call", lanes=sb, width=w,
-                   tokens=call_tokens, lanes_live=len(pslots)) as call:
-            pend = [(i, pc) for i in pslots
+                   tokens=call_tokens, lanes_live=len(lanes),
+                   slots=len(runs)) as call:
+            pend = [(i, pc) for i in dict.fromkeys(pslots)
                     if (pc := self.cache.pending_copy(i)) is not None]
             if pend:
                 with phase("serving.prefill.cow_copy",
@@ -2026,12 +2182,12 @@ class ServingEngine:
                     state_rows[:len(pslots), 0] = np.asarray(pslots) + 1
                     bt_rows = np.concatenate([bt_rows, state_rows], axis=1)
                 bt_dev = jnp.asarray(bt_rows)
-                self._count_state(call.span, lanes=len(pslots),
+                self._count_state(call.span, lanes=len(lanes),
                                   fresh=sum(lo == 0 for lo in los),
                                   tokens=call_tokens)
                 for kind in self._kinds:
-                    kind.count_prefill(call.span, starts[:len(pslots)],
-                                       nv[:len(pslots)])
+                    kind.count_prefill(call.span, starts[:len(lanes)],
+                                       nv[:len(lanes)], heads)
                 dbt_dev = jnp.asarray(dbt_rows[:, :w]) if self.speculative \
                     else None
             with phase("serving.prefill.dispatch",
@@ -2069,11 +2225,15 @@ class ServingEngine:
                                if self.speculative else ()), now - t0,
                             waited=wait)
             self._c_prefill_calls.inc()
-            self._h_call_lanes.observe(len(pslots))
+            self._h_call_lanes.observe(len(lanes))
+            self._c_lanes_live.inc(len(lanes))
+            self._c_lanes_bucket.inc(sb)
+            for run in runs:
+                self._h_run_chunks.observe(int(run))
             if self._step_calls is None:
                 self._step_calls = []
-            self._step_calls.append((len(pslots), sb, w, call_tokens,
-                                     now - t0))
+            self._step_calls.append((len(lanes), sb, w, call_tokens,
+                                     now - t0, int(runs.max())))
             with phase("serving.prefill.book", part["prefill", "book"]):
                 tr_on = self.tracer.enabled
                 for j, (i, n) in enumerate(zip(pslots, ns)):
@@ -2087,7 +2247,8 @@ class ServingEngine:
                                               st.prefilled)
                     acc = self._phase_acc.get(rid)
                     if acc is not None:
-                        acc["prefill_s"] += now - t0
+                        if heads[j]:    # the call's wall once a slot
+                            acc["prefill_s"] += now - t0
                         acc["prefill_chunks"] += 1
                     if tr_on:
                         self.tracer.record_span(
@@ -2111,10 +2272,15 @@ class ServingEngine:
         return min(w, self.cache.config.max_pages_per_slot)
 
     def _pow2_count(self, need: int) -> int:
-        """Pow2 lane count for the compact prefill batch."""
+        """The lane bucket of a prefill call with ``need`` live lanes: a
+        power of two up to ``_LANE_STEP``, then the next multiple of it,
+        and never more than the slots (the name is older than the
+        multiples)."""
         s = 1
-        while s < need:
+        while s < min(need, _LANE_STEP):
             s *= 2
+        if need > s:
+            s = -(-need // _LANE_STEP) * _LANE_STEP
         return min(s, self.scheduler.num_slots)
 
     def warmup_plan(self):
@@ -2130,15 +2296,19 @@ class ServingEngine:
         the runtime zero-recompile invariant into an ahead-of-time
         proof."""
         c = self.cache.config
-        s_tot = self.scheduler.num_slots
 
-        def doubling(cap):          # 1, 2, 4, .. below ``cap``, then it
+        def covering(cap, limit, step=None):
+            # 1, 2, 4, .. (from ``step`` on in steps of it) up to the
+            # first that covers ``cap``, none above ``limit``
             out, n = [], 1
             while n < cap:
                 out.append(n)
-                n *= 2
-            return out + [cap]
-        widths, counts = doubling(c.max_pages_per_slot), doubling(s_tot)
+                n = n * 2 if step is None or n < step else n + step
+            return out + [min(n, limit)]
+        widths = covering(c.max_pages_per_slot, c.max_pages_per_slot)
+        # up to the lanes the budget buys a call: more are never asked for
+        counts = covering(self._lane_cap, self.scheduler.num_slots,
+                          _LANE_STEP)
         plan = []
         for w in widths:
             if self.speculative:
@@ -2187,15 +2357,15 @@ class ServingEngine:
         """Every bucket signature the steady-state ``step()`` loop can
         request, enumerated from the STEP-side bucketing functions
         (``_pow2_width`` over every possible live page count,
-        ``_pow2_count`` over every in-prefill slot count) — the other
-        half of the bucket-coverage proof. A speculative engine's
+        ``_pow2_count`` over every count of live lanes a call can carry)
+        — the other half of the bucket-coverage proof. A speculative engine's
         decode phase requests draft + verify buckets instead of decode
         buckets, plus the draft-prefill twins."""
         c = self.cache.config
         widths = {self._pow2_width(n)
                   for n in range(1, c.max_pages_per_slot + 1)}
         counts = {self._pow2_count(n)
-                  for n in range(1, self.scheduler.num_slots + 1)}
+                  for n in range(1, self._lane_cap + 1)}
         if self.speculative:
             sigs = {("draft", w) for w in widths}
             sigs |= {("verify", w) for w in widths}
@@ -2982,11 +3152,15 @@ class ServingEngine:
         """The shared chunk-forward behind the batched prefill step, the
         draft prefill step, and the speculative VERIFY step, written
         against what a model supplies (``program``) and what its cache's
-        layers are (``kinds``): ``tokens`` (S, C) enter at absolute
-        positions ``starts[s]..starts[s]+C-1`` (first ``n_valid[s]`` real,
-        rest pad to the null page), the rows the program wants cached land
-        where each layer's kind places them, and every live lane attends
-        causally over everything cached, as the kind does.
+        layers are (``kinds``): ``tokens`` (S, C), a chunk a LANE, enter
+        at absolute positions ``starts[s]..starts[s]+C-1`` (first
+        ``n_valid[s]`` real, rest pad to the null page), the rows the
+        program wants cached land where each layer's kind places them, and
+        every live lane attends causally over everything cached, as the
+        kind does. Consecutive lanes may be consecutive chunks of one slot
+        (same table, the next start): a layer's rows of EVERY lane are
+        written before the layer attends, so a lane finds its slot's
+        earlier lanes' rows among what is cached.
         ``all_positions=False`` returns the greedy next token after each
         slot's LAST valid position (prefill's first generated token);
         ``all_positions=True`` returns the greedy argmax after EVERY
@@ -3072,10 +3246,10 @@ class ServingEngine:
 
     def _prefill_step_impl(self, params, pages, block_tables, starts,
                            tokens, n_valid):
-        """Fixed-shape BATCHED chunked prefill: one call advances EVERY
-        admitted request's next prompt chunk (see
+        """Fixed-shape BATCHED chunked prefill: one call advances the
+        admitted requests' next prompt chunks, a lane each (see
         :meth:`_prefill_loop`). Returns (greedy next token after each
-        slot's last valid position (S,), pages)."""
+        lane's last valid position (S,), pages)."""
         return self._prefill_loop(params, pages, block_tables, starts,
                                   tokens, n_valid, program=self.program,
                                   kinds=self.cache.config.kinds)

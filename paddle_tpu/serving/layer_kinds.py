@@ -18,7 +18,8 @@ The kinds, and what selects each:
 :class:`PagedInt8`  the same in int8 with a scale a token row
                     (``dtype=int8``)
 :class:`Ring`       K and V in a ring of pages a slot, the window's and
-                    one more (``spec.layer_windows[i]`` is a window)
+                    room for the run of chunks one prefill call may carry
+                    (``spec.layer_windows[i]`` is a window)
 :class:`Latent`     one latent row a token that every head reads, in two
                     pools (``spec.latent_row``)
 :class:`Selecting`  K and V plus index rows, each query attending to its
@@ -42,6 +43,15 @@ traced, inside the engine's steps: where a call's tokens go and what the
 kernel is handed, the writes, the attention, what its steps count; on the
 host: the groups a folding decode takes, its series, and one counting call
 a decode round and one a prefill call.
+
+And how long a **run** it can take (``prefill_run``): a lane of a prefill
+call is a (slot, chunk) pair, and consecutive lanes may be consecutive
+chunks of ONE slot's prompt, because a call writes every lane's rows of a
+layer before that layer attends. A plain pool takes any run (lane k + 1
+finds lane k's rows under the same table); a ring takes as many chunks as
+it has room for beside its window; a kind that selects or reads a latent
+row by chunk, or writes a page tile at a time, answers 1 until it is
+shown to take more. The engine takes the least over a program's kinds.
 """
 
 from __future__ import annotations
@@ -247,6 +257,11 @@ def _add_attr(span, name: str, n: int):
         span.set_attrs(**{name: span.attrs.get(name, 0) + n})
 
 
+def _run_heads(starts, heads):
+    """The starts of the lanes that open their slot's run."""
+    return starts if heads is None else np.asarray(starts)[heads]
+
+
 def _attended(lens, n):
     """Tokens ``n`` decode token steps attend over, a layer: step j of a
     slot holding L tokens attends over L + j + 1."""
@@ -273,6 +288,9 @@ class Paged:
 
     quantized = False       # pages carry scale rows
     by_slot = False         # a prefill lane's placement needs its slot
+    #: consecutive chunks of one slot a prefill call may carry as lanes of
+    #: their own; None: as many as the call has lanes
+    prefill_run: Optional[int] = None
     stat_names: Tuple[str, ...] = ()    # counts its steps add on the device
     groups = None           # a :class:`_Groups` where its decode folds
     _c_resident = None      # bound where the program's pool is split by kind
@@ -289,6 +307,11 @@ class Paged:
         self.pools = self._kv_pools(geo.num_pages, (None, None, "tp")) \
             + tuple(((geo.num_pages, width, geo.page_size), geo.dtype, ())
                     for _name, width in extra_rows)
+        if extra_rows:
+            # a pool with the tokens along its lanes is written a page
+            # tile at a time: two lanes of one slot in one page would
+            # each write the tile back whole
+            self.prefill_run = 1
 
     def _kv_pools(self, pages: int, axes, dtype=None):
         """The K pool and the V pool of ``pages`` pages: a token's heads
@@ -456,8 +479,8 @@ class Paged:
                       for shape, dtype, _ in self.pools), layers=self.label)
 
     def _count_resident(self, before):
-        """What the slots hold going in (``before`` tokens each), where
-        the program's pool is split by kind."""
+        """What the slots hold going in (``before`` tokens each, one entry
+        a slot), where the program's pool is split by kind."""
         if self._c_resident is not None:
             pages = -(-np.asarray(before, np.int64) // self.geo.page_size)
             self._c_resident.inc(
@@ -490,9 +513,12 @@ class Paged:
         """What the kind alone counts of a decode round attending over
         ``live`` tokens a layer."""
 
-    def count_prefill(self, span, starts, ns):
-        """One prefill call: lanes at ``starts`` computing ``ns`` tokens."""
-        self._count_resident(starts)
+    def count_prefill(self, span, starts, ns, heads=None):
+        """One prefill call: lanes at ``starts`` computing ``ns`` tokens.
+        ``heads``: which lanes open their slot's run (a mask; None: every
+        lane is a slot's only one): what a slot holds going in is counted
+        once a slot, at its first lane."""
+        self._count_resident(_run_heads(starts, heads))
         self._count_prefill_attention(span, starts, ns)
 
 
@@ -506,6 +532,7 @@ class PagedInt8(Paged):
     heads: the abs-max is completed over the shards)."""
 
     quantized = True
+    prefill_run = 1         # not shown to take a run yet
 
     def __init__(self, geo, layers):
         super().__init__(geo, layers)
@@ -537,25 +564,40 @@ class PagedInt8(Paged):
 class Ring(Paged):
     """K and V of a layer whose queries attend to the last ``window``
     tokens (themselves counted): a **ring** of ``ring_pages`` =
-    ``pages_for(window) + 1`` pages a slot, in a pool of its own,
+    ``pages_for(window) + room`` pages a slot, in a pool of its own,
     ``(num_slots * ring + 1, page_size, lanes)`` (page 0 the null page).
     Token ``t`` of slot ``s`` lives in ring page ``1 + s * ring + (t //
     page_size) % ring``, row ``t % page_size``, so the page a slot writes
     next is the one whose tokens have all fallen behind the window
     (**recycled**), whatever the slot's length. No table, no allocation,
-    no free: the ring is the slot's, and a call that writes at most a page
-    of tokens a slot before it attends never writes over a token a query
-    of the same call still reads (:func:`build` holds ``prefill_chunk`` to
-    that). Admission reckons without it (``page_bytes`` 0). Never shared,
+    no free: the ring is the slot's.
+
+    ``room`` is the length of a run (``prefill_run``): a call writes
+    every lane's rows before the layer attends, so a slot that gives the
+    call ``room`` consecutive chunks of at most a page each (:func:`build`
+    holds ``prefill_chunk`` to that) writes, and its queries read, a span
+    of fewer than ``window + room * page_size`` tokens: under
+    ``ring * page_size``, so no token of it lands on a row another
+    query of the same call still reads. A run one chunk longer could
+    (``check_run`` says so by name). Each lane still attends through a
+    table of its own window's span, ``pages_for(window) + 2`` columns
+    from its own first page, and a decode token through
+    ``pages_for(window) + 1``: the room widens the pool, not a table.
+    Admission reckons without the ring (``page_bytes`` 0). Never shared,
     copied on write, published, spilled or shipped."""
 
     by_slot = True
 
-    def __init__(self, geo, layers, window: int, sink: bool = False):
+    def __init__(self, geo, layers, window: int, sink: bool = False,
+                 room: int = 1):
         super().__init__(geo, layers, "window", sink=sink)
         self.window = window
-        #: the window's own pages and one more, the page being written
-        self.ring_pages = -(-window // geo.page_size) + 1
+        #: chunks of one slot a call may carry: pages of room in the ring
+        self.prefill_run = room
+        #: the pages a window spans where it ends with its last page
+        self.window_pages = -(-window // geo.page_size)
+        #: the window's own pages and those a call's run writes
+        self.ring_pages = self.window_pages + room
         self.pools = self._kv_pools(
             geo.num_slots * self.ring_pages + 1, ())
 
@@ -594,6 +636,19 @@ class Ring(Paged):
                 slot * ring < p <= (slot + 1) * ring for p in mine), \
                 "a window's pages collide or leave the slot's ring"
 
+    def check_run(self, start: int, tokens: int):
+        """A slot at ``start`` tokens may give one call a run of
+        ``tokens`` more: what the run writes and what its queries read,
+        ``[start - window + 1, start + tokens)``, fits the ring without
+        a token landing on another's row. Raises ValueError by name."""
+        span = start + tokens - max(start - self.window + 1, 0)
+        if span > self.ring_pages * self.geo.page_size:
+            raise ValueError(
+                f"a run of {tokens} tokens at {start} spans {span} tokens "
+                f"with its window of {self.window}: more than the ring's "
+                f"{self.ring_pages} pages of {self.geo.page_size} "
+                f"(prefill_run={self.prefill_run}) hold at once")
+
     def _pages(self, slots, first_page, width):
         """-> (pages (S,) or (S, C) of the tokens of sequence page
         ``first_page`` (same shape), the table (S, ``width``) of the pages
@@ -614,7 +669,7 @@ class Ring(Paged):
         _, off, _, lengths = under
         ps = self.geo.page_size
         first = jnp.maximum(lengths + 1 - self.window, 0) // ps
-        pages, table = self._pages(slot_ids, first, self.ring_pages)
+        pages, table = self._pages(slot_ids, first, self.window_pages + 1)
         return (jnp.where(writable, pages(lengths // ps), 0), off, table,
                 lengths + 1 - first * ps, lengths)
 
@@ -622,16 +677,19 @@ class Ring(Paged):
         """-> (the ring pages the chunk's tokens are written to (S, C),
         their rows, the table of the pages the chunk's windows span, the
         chunk's start in them). A chunk of at most a page of tokens spans
-        the ring and, where it starts inside a page, that page's next
-        lap: one column more than the ring, the stale rows of either lap
-        outside every query's window or past it. A lane's ring is its
-        slot's: pool row less one (a pad lane writes nothing and attends
-        to nothing)."""
+        its window's pages, one more and, where it starts inside a page,
+        one more again: ``window_pages + 2`` columns from the lane's own
+        first page, whatever the ring's room (a lane further on in its
+        slot's run starts further on). Where the first and the last of
+        them are one ring page on two laps, the stale rows of either lap
+        lie outside every query's window or past it. A lane's ring is
+        its slot's: pool row less one (a pad lane writes nothing and
+        attends to nothing)."""
         _, off, _, starts = under
         slots = jnp.maximum(lane_rows - 1, 0)
         ps = self.geo.page_size
         first = jnp.maximum(starts - self.window + 1, 0) // ps
-        pages, table = self._pages(slots, first, self.ring_pages + 1)
+        pages, table = self._pages(slots, first, self.window_pages + 2)
         return (jnp.where(valid, pages(positions // ps), 0), off, table,
                 starts - first * ps)
 
@@ -659,25 +717,26 @@ class Ring(Paged):
     def _held(self, pages):
         return np.minimum(pages, self.ring_pages)
 
-    def _count(self, span, before, after):
-        self._count_resident(before)
+    def _count(self, span, before, after, held=None):
+        """Slots advancing from ``before`` to ``after`` tokens (a prefill
+        lane each, where a slot gives a call a run; ``held``: what each
+        slot holds going in, once a slot)."""
+        self._count_resident(before if held is None else held)
         recycled = self.recycled(before, after)
         self._c_recycled.inc(recycled)
-        if span is not None:
-            span.set_attrs(window_pages=span.attrs.get("window_pages", 0)
-                           + recycled)
+        _add_attr(span, "window_pages", recycled)
 
     def count_decode(self, span, block_tables, lengths, dslots, keeps,
                      n, width):
         # a window layer's read is its window's: token step j of a slot
         # holding L tokens attends over min(L + j + 1, window), from a
-        # table as wide as its ring
+        # table as wide as its window's span
         lens = lengths[dslots]
         self._count(span, lens, lens + keeps)
         self._count_rows(span, n * len(lens))
         return self._kv_bytes(
             int(sum(np.minimum(lens + j + 1, self.window).sum()
-                    for j in range(n))), self.ring_pages)
+                    for j in range(n))), self.window_pages + 1)
 
     def _seen_prefill(self, starts, ns):
         # chunk token j of a lane at ``start`` sees the last ``window``
@@ -688,8 +747,8 @@ class Ring(Paged):
         return (int(pairs.sum()), int(
             (starts + ns - np.maximum(starts - w + 1, 0))[ns > 0].sum()))
 
-    def count_prefill(self, span, starts, ns):
-        self._count(span, starts, starts + ns)
+    def count_prefill(self, span, starts, ns, heads=None):
+        self._count(span, starts, starts + ns, _run_heads(starts, heads))
         self._count_prefill_attention(span, starts, ns)
 
 
@@ -704,6 +763,8 @@ class Latent(Paged):
     refcounted, published, copied on write and freed under one page id.
     Its decode reads the pages that a group of slots' tables open with
     once for the group. Not quantized, sharded, spilled or shipped yet."""
+
+    prefill_run = 1         # the rotary pool is written a page tile a lane
 
     def __init__(self, geo, layers, latent_row):
         super().__init__(geo, layers, "latent")
@@ -768,7 +829,7 @@ class Latent(Paged):
         self._count(span, "decode", live - n * twice, live,
                     live - n * spared)
 
-    def count_prefill(self, span, starts, ns):
+    def count_prefill(self, span, starts, ns, heads=None):
         # chunk token j of a lane sees its context and the chunk's tokens
         # up to itself
         rows = int(starts.sum() + ns.sum())
@@ -787,6 +848,7 @@ class Selecting(Paged):
     the group."""
 
     stat_names = ("attn_context_tokens", "attn_selected_tokens")
+    prefill_run = 1         # selects by chunk; index rows a page tile a lane
 
     def __init__(self, geo, layers, label, extra_rows, topk: int):
         super().__init__(geo, layers, label, extra_rows)
@@ -843,14 +905,22 @@ class Selecting(Paged):
 
 def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
           share_prefix: bool, tp: int = 1, impl: str = "auto",
-          prefill_chunk: Optional[int] = None) -> Tuple[Paged, ...]:
+          prefill_chunk: Optional[int] = None,
+          prefill_room: int = 1) -> Tuple[Paged, ...]:
     """One kind a layer of the program ``spec`` describes, in a cache of
     this geometry (layers alike share one object: the same window, KV
     heads and sink). The only reader of ``spec.extra_rows``,
     ``spec.select_topk``, ``spec.layer_windows``, ``spec.latent_row``,
     ``spec.layer_kv_heads``, ``spec.value_dim``, ``spec.sink_layers`` and
     the cache's dtype, and the one place where what does not combine yet
-    is refused. ``prefill_chunk``: the engine's, where an engine asks."""
+    is refused. ``prefill_chunk``: the engine's, where an engine asks;
+    ``prefill_room``: the pages of room a ring gets, which is the
+    longest run of one slot's chunks a prefill call may carry through
+    the window layers (the engine's to choose: ``ServingEngine``'s
+    ``_LANE_STEP`` or the lanes its budget buys, whichever is less);
+    left out: one page, a call that carries one chunk a slot. Each page
+    of room is ``num_slots`` x window layers x a page of K and V
+    (``Ring.slot_bytes``)."""
     def geo(heads):
         return Geometry(num_slots=num_slots, page_size=page_size,
                         num_pages=num_pages, heads=heads,
@@ -897,8 +967,8 @@ def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
             raise ValueError(
                 f"prefill_chunk={prefill_chunk} > page_size="
                 f"{page_size}: a window layer's ring holds its window and "
-                "one page more, so a call writes at most a page of tokens "
-                "a slot before it attends")
+                "a page of room for each chunk of a call's run, so a "
+                "chunk is at most a page of tokens")
     if spec.extra_rows and int8:
         raise ValueError("an int8 pool carries no extra rows yet")
     if spec.slot_state and int8:
@@ -919,7 +989,7 @@ def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
 
     def kind(window, heads, sink, layers):
         if window is not None:
-            return Ring(geo(heads), layers, window, sink)
+            return Ring(geo(heads), layers, window, sink, prefill_room)
         if spec.latent_row is not None:
             return Latent(geo(heads), layers, spec.latent_row)
         if int8:

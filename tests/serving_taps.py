@@ -103,24 +103,70 @@ def serve_alone(eng, sink, prompt, n_new):
     del sink[:]
     rid = eng.submit(prompt, n_new)
     slot = None
-    while not eng.scheduler.idle():
-        eng.step()
-        eng.cache.check_invariants()
-        for i in eng.scheduler.active_slots():
-            slot = i
+    call, calls_made = eng._prefill_call, []
+
+    def noted(lanes):
+        calls_made.append(len(lanes))
+        return call(lanes)
+    eng._prefill_call = noted
+    try:
+        while not eng.scheduler.idle():
+            eng.step()
+            eng.cache.check_invariants()
+            for i in eng.scheduler.active_slots():
+                slot = i
+    finally:
+        del eng._prefill_call
     jax.effects_barrier()
     out = eng.result(rid)
-    # prefill calls hand (lanes, V): the lone request is lane 0, and the
-    # call that finished the prompt is the last of them; decode token
-    # steps hand (slots, V)
-    s_tot = eng.scheduler.num_slots
+    # prefill calls hand (lanes, V): the lone request's chunks are the
+    # call's live lanes in order (a run of them where the engine forms
+    # runs), the call that finished the prompt is the last prefill call
+    # and the lane that ended it the last live one; decode token steps
+    # hand (slots, V)
     calls = list(sink)
-    last_prefill = max(i for i, a in enumerate(calls)
-                       if a.shape[0] != s_tot or i == 0)
-    logits = [calls[last_prefill][0]]
+    last_prefill = len(calls_made) - 1
+    logits = [calls[last_prefill][calls_made[-1] - 1]]
     logits += [a[slot if slot is not None else 0]
                for a in calls[last_prefill + 1:]]
     return out, np.stack(logits[:n_new])
+
+
+def serve_noting_calls(eng, prompts, n_new=5):
+    """``prompts`` submitted at once and served to the end, the cache's
+    invariants (every ring's among them) checked after every step: ->
+    (the requests' tokens in order, ``[[lanes_live, lanes, width, tokens,
+    longest run] a call]`` a step that made any)."""
+    n0 = eng.anatomy.summary()["steps"]
+    rids = [eng.submit(p, n_new) for p in prompts]
+    out = {}
+    while not eng.scheduler.idle():
+        out.update(eng.step())
+        eng.cache.check_invariants()
+    recs = eng.anatomy.records()[-(eng.anatomy.summary()["steps"] - n0):]
+    return ([np.asarray(out[r]).tolist() for r in rids],
+            [[c[:4] + c[5:] for c in r["prefill_calls"]] for r in recs
+             if r.get("prefill_calls")])
+
+
+def runs_against_one_chunk_a_slot(eng, prompts, n_new=5):
+    """Serve ``prompts`` on ``eng`` as it forms its calls, then again
+    held to a run of 1 (the call of one chunk a slot), on the same
+    compiled programs: -> ((tokens, calls) with runs, (tokens, calls)
+    without), the step's budget held to on both sides."""
+    with_runs = serve_noting_calls(eng, prompts, n_new)
+    limit, eng._run_limit = eng._run_limit, 1
+    try:
+        without = serve_noting_calls(eng, prompts, n_new)
+    finally:
+        eng._run_limit = limit
+    most = max(eng.prefill_budget, eng.prefill_chunk)
+    for _, calls in (with_runs, without):
+        assert all(sum(c[3] for c in step) <= most for step in calls)
+        assert all(c[0] <= c[1] and c[4] <= limit for step in calls
+                   for c in step)
+    assert all(c[4] == 1 for step in without[1] for c in step)
+    return with_runs, without
 
 
 def prompt(n, seed=None, vocab=96):

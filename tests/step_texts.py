@@ -34,9 +34,12 @@ def step_hashes(name, impl):
     from paddle_tpu import observability as obs
     model = _model(name)
     params = model.init(jax.random.PRNGKey(0))
+    # a budget of one chunk a step: a call of one lane, so a window
+    # layer's ring has the one page of room it had before calls carried
+    # runs (PR 54), and the pools the shapes they had
     eng = inference.make_serving_engine(
         model, params, num_slots=2, page_size=4, prefill_chunk=4,
-        max_tokens_per_slot=32, attn_impl=impl,
+        prefill_budget=4, max_tokens_per_slot=32, attn_impl=impl,
         registry=obs.MetricsRegistry())
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     width = eng.cache.config.max_pages_per_slot

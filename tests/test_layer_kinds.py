@@ -70,7 +70,8 @@ class Negated(layer_kinds.Paged):
 
 
 def _toy_build(spec, *, num_slots, page_size, num_pages, dtype,
-               share_prefix, tp=1, impl="auto", prefill_chunk=None):
+               share_prefix, tp=1, impl="auto", prefill_chunk=None,
+               prefill_room=1):
     geo = layer_kinds.Geometry(num_slots, page_size, num_pages,
                                spec.kv_heads, spec.head_dim, dtype, tp, impl)
     return (Negated(geo, spec.num_layers),) * spec.num_layers
@@ -266,6 +267,140 @@ def test_prefill_pairs_and_rows_are_what_the_masks_admit(window):
             seen.update(range(lo, t + 1))
         rows += len(seen)
     assert kind._seen_prefill(starts, ns) == (pairs, rows)
+
+
+# -- a run of one slot's chunks in one call (ISSUE 54) ---------------------------
+
+def _ring(window, page, room, slots=2):
+    spec = ServingSpec(**{**_SPEC, "num_layers": 1}, kv_heads=2, head_dim=8,
+                       layer_windows=(window,))
+    kind, = layer_kinds.build(
+        spec, dtype=jnp.float32, share_prefix=False, num_slots=slots,
+        page_size=page, num_pages=9, prefill_chunk=page,
+        **({} if room is None else {"prefill_room": room}))
+    return kind
+
+
+@pytest.mark.parametrize("window, page, chunk, room", [
+    (8, 4, 4, 1), (8, 4, 4, 3), (8, 4, 4, 8), (6, 4, 4, 2), (9, 4, 3, 4),
+    (128, 128, 128, 8), (16, 8, 5, 3), (3, 4, 4, 2)])
+def test_a_run_as_long_as_the_rings_room_laps_no_row_it_still_reads(
+        window, page, chunk, room):
+    """The ring's mapping played out token by token: a slot at any length
+    gives one call ``room`` chunks; every token the run writes and every
+    token its queries read (the window behind each) has a row of the ring
+    to itself, so no write of the call lands on a row another query of the
+    same call still reads. ``check_run`` says the same, and refuses a run
+    one chunk longer somewhere, by name."""
+    ring = _ring(window, page, room)
+    assert ring.prefill_run == room
+    assert ring.ring_pages == -(-window // page) + room
+    assert ring.slot_bytes == ring.ring_pages * ring.row_bytes
+    for start in range(0, 3 * ring.ring_pages * page, chunk):
+        tokens = room * chunk
+        ring.check_run(start, tokens)
+        span = range(max(start - window + 1, 0), start + tokens)
+        rows = {(ring.page_of(1, t // page), t % page) for t in span}
+        assert len(rows) == len(span), (start, "two tokens on one row")
+        assert all(1 + ring.ring_pages <= p <= 2 * ring.ring_pages
+                   for p, _ in rows), (start, "left the slot's ring")
+    if chunk == page:
+        # one page more than the room: refused wherever the run starts
+        # on a page edge, with the numbers that do not fit
+        with pytest.raises(ValueError, match=rf"prefill_run={room}\b"):
+            ring.check_run(4 * page, (room + 1) * page)
+
+
+def test_the_rings_tables_are_as_wide_as_a_window_whatever_the_room():
+    """Room widens the pool: a prefill lane's table is its own window's
+    span from its own first page, a decode token's its window's."""
+    for room in (1, 3, 8):
+        ring = _ring(8, 4, room)
+        lanes = jnp.asarray([1, 1, 2])          # two lanes of slot 0
+        starts = jnp.asarray([8, 12, 0])
+        positions = starts[:, None] + jnp.arange(4)
+        valid = jnp.ones((3, 4), bool)
+        under = layer_kinds.under_table(
+            jnp.zeros((3, 1), jnp.int32), positions, valid,
+            jnp.arange(3)[:, None], 4, starts)
+        pages, _off, table, base = ring.place_prefill(
+            under, positions, valid, lanes)
+        assert table.shape == (3, ring.window_pages + 2)
+        # lane 1 goes on where lane 0 ends, in the next page of the ring
+        first = np.maximum(np.asarray(starts) - 8 + 1, 0) // 4
+        np.testing.assert_array_equal(
+            np.asarray(table[:2]),
+            1 + (first[:2, None] + np.arange(4)) % ring.ring_pages)
+        np.testing.assert_array_equal(np.asarray(base),
+                                      np.asarray(starts) - first * 4)
+        assert int(pages[1, 0]) == 1 + 3 % ring.ring_pages
+        place = ring.place_decode(
+            (None, jnp.zeros((2,), jnp.int32), None,
+             jnp.asarray([5, 21])), jnp.ones((2,), bool), jnp.arange(2))
+        assert place[2].shape == (2, ring.window_pages + 1)
+
+
+@pytest.mark.parametrize("lanes, room", [
+    (None, 1), (1, 1), (2, 2), (4, 4), (8, 8), (32, 8), (16, 8)])
+def test_build_gives_a_ring_the_room_the_budget_can_use(lanes, room):
+    """The engine owns the one constant: it asks for a step of its lane
+    buckets, or the lanes its budget buys if fewer, and ``build`` gives
+    the ring what it is asked for (left out: the one page it had)."""
+    from paddle_tpu.serving import engine as E
+    ring = _ring(8, 4, None if lanes is None else min(lanes, E._LANE_STEP))
+    assert (ring.prefill_run, ring.ring_pages) == (room, 2 + room)
+    assert not hasattr(layer_kinds, "RING_ROOM") and E._LANE_STEP == 8
+    # the pool is the slots' rings and the null page
+    assert ring.pools[0][0][0] == 2 * ring.ring_pages + 1
+
+
+@pytest.mark.parametrize("fields, dtype, run", [
+    (dict(), jnp.float32, None),
+    (dict(), jnp.int8, 1),
+    (dict(kv_heads=1, head_dim=24, latent_row=(16, 8)), jnp.float32, 1),
+    (dict(select_topk=8, extra_rows=(("idx", 4),)), jnp.float32, 1),
+    (dict(extra_rows=(("idx", 4),)), jnp.float32, 1),
+    (dict(layer_windows=(8, None)), jnp.float32, 4)],
+    ids=["paged", "int8", "latent", "selecting", "extra_rows", "ring"])
+def test_each_kind_says_how_long_a_run_it_takes(fields, dtype, run):
+    spec = ServingSpec(**{**_SPEC, **dict(kv_heads=2, head_dim=8), **fields})
+    kinds = layer_kinds.build(spec, dtype=dtype, share_prefix=False,
+                              num_slots=2, page_size=4, num_pages=9,
+                              prefill_chunk=4, prefill_room=4)
+    assert kinds[0].prefill_run == run
+    assert kinds[-1].prefill_run in (run, None)
+
+
+def _gpt_engine(**kw):
+    model = GPT(GPTConfig.tiny(num_heads=2, hidden_size=16,
+                               max_position=64))
+    return serving.ServingEngine(
+        model, model.init(jax.random.PRNGKey(0)), **{**dict(
+            num_slots=4, page_size=4, prefill_chunk=4, prefill_budget=12,
+            attn_impl="lax", registry=obs.MetricsRegistry()), **kw})
+
+
+@pytest.mark.parametrize("how, limit", [
+    ("plain", 3), ("prefix_sharing_off", 3), ("int8", 1), ("tp", 1),
+    ("prefill_tier", 1), ("speculative", 1), ("one_lane_budget", 1)])
+def test_the_engine_takes_the_least_run_of_what_it_is_built_from(how, limit):
+    """The run limit follows the program's kinds and the engine's options,
+    never an argument: an option not shown to take runs answers 1 and the
+    round is the loop it was."""
+    kw = {"plain": {}, "prefix_sharing_off": dict(prefix_sharing=False),
+          "int8": dict(cache_dtype=jnp.int8), "tp": dict(tp=2),
+          "prefill_tier": dict(tier="prefill"),
+          "one_lane_budget": dict(prefill_budget=4)}.get(how)
+    if how == "speculative":
+        draft = GPT(GPTConfig.tiny(num_layers=1, num_heads=2, hidden_size=16,
+                                   max_position=64))
+        kw = dict(draft_model=draft,
+                  draft_params=draft.init(jax.random.PRNGKey(1)), spec_k=2)
+    if how == "tp" and len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    eng = _gpt_engine(**kw)
+    assert eng._run_limit == limit
+    assert eng._lane_cap == (1 if how == "one_lane_budget" else 3)
 
 
 #: sha256 (16 hex digits) of the lowered text of each tiny program's decode

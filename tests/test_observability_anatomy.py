@@ -135,6 +135,18 @@ class TestStepAnatomy:
         assert rec["host_gap_s"] < 0.002
         assert a.summary()["steps"] == 2
 
+    @pytest.mark.parametrize("call, kept", [
+        ((2, 2, 4, 100, 0.003), [2, 2, 4, 100, 0.003]),
+        ((6, 8, 4, 100, 0.003, 3), [6, 8, 4, 100, 0.003, 3])])
+    def test_a_steps_record_keeps_each_calls_longest_run(self, call, kept):
+        """``end_step(prefill_calls=)`` takes a call with or without its
+        longest run and keeps what it was given."""
+        a = obs.StepAnatomy()
+        a.begin_step(1)
+        a.end_step(parts={anat.OTHER_PART: 0.0}, prefill_calls=[call])
+        rec = a.last()
+        assert rec["prefill_calls"] == [kept]
+
     def test_validators_reject_malformed(self, tmp_path):
         a = obs.StepAnatomy()
         a.begin_step(5)
@@ -149,6 +161,16 @@ class TestStepAnatomy:
             anat.validate_anatomy_record(overfull)
         with pytest.raises(ValueError, match="negative|nonneg|>= 0"):
             anat.validate_anatomy_record(dict(good, host_gap_s=-1.0))
+        # a prefill call of the step's own record: five fields, or six
+        # with the call's longest run (PR 54), which lies in 1..lanes_live
+        for call in ([3, 4, 2, 12, 0.001], [3, 4, 2, 12, 0.001, 1],
+                     [3, 4, 2, 12, 0.001, 3]):
+            anat.validate_anatomy_record(dict(good, prefill_calls=[call]))
+        for call in ([3, 4, 2, 12, 0.001, 4], [3, 4, 2, 12, 0.001, 0],
+                     [5, 4, 2, 12, 0.001, 1], [3, 4, 2, 12, 0.001, 1, 1]):
+            with pytest.raises(ValueError, match="prefill_calls"):
+                anat.validate_anatomy_record(
+                    dict(good, prefill_calls=[call]))
         p = tmp_path / "anat.jsonl"
         a.export_jsonl(str(p))
         assert anat.validate_anatomy_log(str(p), require_steps=1) == 1

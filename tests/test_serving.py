@@ -271,6 +271,112 @@ class TestServingObservability:
             assert steps < 500
 
 
+    @pytest.mark.parametrize("case", [
+        "a_budget_of_three_lanes", "a_budget_under_a_chunk",
+        "partial_chunks_leave_no_narrow_second_call",
+        "prompts_admitted_behind_a_call_wait_for_the_next_step"])
+    def test_a_round_that_carries_runs_keeps_to_the_steps_budget(self, case):
+        """ISSUE 54, a plain pool (a run as long as the call): a step
+        spends at most ``max(prefill_budget, prefill_chunk)`` prompt
+        tokens, in ONE call; under a chunk of budget the liveness lane
+        still moves a slot a step. Tokens are the dense reference's."""
+        from serving_taps import serve_noting_calls
+        slots, budget, lengths, n_new = {
+            "a_budget_of_three_lanes": (4, 12, [30, 29, 27, 25], 2),
+            "a_budget_under_a_chunk": (4, 2, [9, 6], 2),
+            # three prompts of 4 + 1 tokens: a chunk each and the nearest's
+            # second are four lanes and 13 tokens; the 3 left buy no lane,
+            # and the two last tokens wait for the next step
+            "partial_chunks_leave_no_narrow_second_call":
+                (4, 16, [5, 5, 5], 2),
+            # 24 slots of 2-token prompts that end on their first token:
+            # the first call's 24 lanes spend 48 of 96 tokens and their
+            # slots are free at once; the 12 prompts admitted behind them
+            # lead the next step's call
+            "prompts_admitted_behind_a_call_wait_for_the_next_step":
+                (24, 96, [2] * 36, 1),
+        }[case]
+        model, params = _model()
+        eng = serving.ServingEngine(
+            model, params, num_slots=slots, page_size=4, prefill_chunk=4,
+            prefill_budget=budget, attn_impl="lax",
+            registry=obs.MetricsRegistry())
+        prompts = _prompts(np.random.default_rng(54), lengths)
+        tokens, steps = serve_noting_calls(eng, prompts, n_new=n_new)
+        for p, toks in zip(prompts, tokens):
+            assert np.array_equal(
+                toks, _dense_reference(model, params, p, n_new))
+        assert all(sum(c[3] for c in step) <= max(budget, 4)
+                   for step in steps)
+        assert sum(c[3] for step in steps for c in step) == sum(lengths)
+        assert all(len(step) == 1 for step in steps)
+        if case == "a_budget_of_three_lanes":
+            # four prompts for three lanes: the three nearest, a chunk
+            # each; runs form once fewer prompts than lanes are left
+            assert steps[0] == [[3, 4, 1, 12, 1]]
+            assert max(c[4] for step in steps for c in step) == 3
+        elif case == "a_budget_under_a_chunk":
+            assert eng._lane_cap == eng._run_limit == 1
+            assert all([c[:2] for c in step] == [[1, 1]] for step in steps)
+            assert len(steps) == 3 + 2              # a chunk a step
+        elif case.startswith("partial"):
+            assert [[c[0] for c in step] for step in steps] == [[4], [2]]
+            assert steps[0][0][3] == 13 and steps[0][0][4] == 2
+        else:
+            assert [[c[:2] for c in step] for step in steps] \
+                == [[[24, 24]], [[12, 16]]]
+
+
+    @pytest.mark.parametrize("case, break_even, calls", [
+        ("the_surplus_of_a_burst_goes_in_the_same_step", 2, [[8, 4]]),
+        ("a_surplus_under_the_break_even_waits_a_step", 5, [[8], [4]]),
+        ("held_to_one_chunk_a_slot_the_loop_is_as_it_was", None, [[8, 4]])])
+    def test_a_further_call_is_for_the_slots_a_call_had_no_lane_for(
+            self, case, break_even, calls):
+        """ISSUE 54, the round's rule on a step's further calls, on a
+        plain pool: 12 prompts of half a chunk for a call of 8 lanes and
+        a budget of 8 chunks. The first call spends half the budget and
+        its 8 slots decode from this step on; the 4 it had no lane for
+        go in a second call of the same step where they reach the
+        break-even in lanes (set by hand: this toy's own, float32 at a
+        chunk of 4, is 120) and wait for the next step's where not.
+        With the break-even of a bf16 stage at a chunk of 128 that is
+        the call sequence of the loop of one chunk a slot."""
+        from serving_taps import serve_noting_calls
+        from paddle_tpu.serving import engine as E
+        model, params = _model()
+        eng = serving.ServingEngine(
+            model, params, num_slots=12, page_size=4, prefill_chunk=4,
+            prefill_budget=32, decode_block=2, attn_impl="lax",
+            registry=obs.MetricsRegistry())
+        assert eng._lane_cap == eng._run_limit == 8
+        assert eng._second_call_lanes == E._break_even_lanes(4, 4) == 120
+        if break_even is None:
+            eng._run_limit = 1
+        else:
+            eng._second_call_lanes = break_even
+        prompts = _prompts(np.random.default_rng(54), [2] * 12)
+        tokens, steps = serve_noting_calls(eng, prompts, n_new=3)
+        for p, toks in zip(prompts, tokens):
+            assert np.array_equal(
+                toks, _dense_reference(model, params, p, 3))
+        assert [[c[0] for c in step] for step in steps] == calls
+        assert all(sum(c[3] for c in step) <= 32 for step in steps)
+
+    @pytest.mark.parametrize("dtype, chunk, lanes", [
+        ("bfloat16", 128, 2), ("bfloat16", 64, 4), ("bfloat16", 32, 8),
+        ("float32", 128, 4), ("float32", 4, 120), ("int8", 128, 1)])
+    def test_the_break_even_follows_the_weights_bytes_and_the_chunk(
+            self, dtype, chunk, lanes):
+        """240 flops a byte: a bf16 weight's read is paid at 240 rows, so
+        at 2 lanes of 128 (both window cells) and 8 of 32 (gpt2)."""
+        from paddle_tpu.serving import engine as E
+        assert E._FLOPS_PER_HBM_BYTE == 240
+        import jax.numpy as jnp
+        assert E._break_even_lanes(
+            jnp.dtype(dtype).itemsize, chunk) == lanes
+
+
 class TestPrefixSharing:
     """ISSUE 6: refcounted copy-on-write prefix/page sharing."""
 

@@ -83,3 +83,51 @@ def test_overlapped_loop_goldens_are_the_parents(served, kind):
     assert tokens == PARENT_TOKENS[kind]
     assert eng._reg.snapshot()["serving_decode_blocks_overlapped_total"] > 0
     assert eng._pending is None
+
+
+#: the prefill calls ``[lanes_live, lanes, width, tokens]`` of each step of
+#: ``tests/prefill_call_sequences.py``'s seeded run AT THE PARENT OF PR 54
+#: (run on a checkout of ad2c7ff), for the programs whose calls carry one
+#: chunk a slot whatever the engine: a mixer's or a projection's state
+#: carried from chunk to chunk outside the pages, a kind that selects by
+#: chunk, a latent row's rotary pool written a page tile a lane
+CALLS_BEFORE_A_CALL_CARRIED_RUNS = {
+    'hybrid_ssm': [
+        [[3, 4, 2, 21]],
+        [[2, 2, 4, 16], [1, 1, 8, 1]],
+        [[1, 1, 4, 8], [1, 1, 8, 8], [1, 1, 8, 5]],
+        [[1, 1, 2, 8], [1, 1, 4, 8], [1, 1, 8, 8]],
+        [[1, 1, 8, 8], [1, 1, 16, 8]],
+    ],
+    'latent_conv_moe': [
+        [[3, 4, 2, 21]],
+        [[2, 2, 4, 16], [1, 1, 8, 1]],
+        [[1, 1, 4, 8], [1, 1, 8, 8], [1, 1, 8, 5]],
+        [[1, 1, 2, 8], [1, 1, 4, 8], [1, 1, 8, 8]],
+        [[1, 1, 8, 8], [1, 1, 16, 8]],
+    ],
+    'sparse_moe': [
+        [[3, 4, 4, 25]],
+        [[2, 2, 8, 17], [1, 1, 8, 12]],
+        [[1, 1, 8, 5]],
+        [[1, 1, 4, 12], [1, 1, 8, 12], [1, 1, 16, 12]],
+        [[1, 1, 16, 4]],
+    ],
+    'mla_moe': [
+        [[3, 4, 1, 21]],
+        [[2, 2, 2, 16], [1, 1, 4, 1]],
+        [[1, 1, 2, 8], [1, 1, 4, 8], [1, 1, 4, 5]],
+        [[1, 1, 1, 8], [1, 1, 2, 8], [1, 1, 4, 8]],
+        [[1, 1, 4, 8], [1, 1, 8, 8]],
+    ],
+}
+
+
+@pytest.mark.parametrize("program", sorted(CALLS_BEFORE_A_CALL_CARRIED_RUNS))
+def test_a_program_whose_run_limit_is_one_forms_the_calls_it_formed(program):
+    """ISSUE 54: the round of an engine whose program answers a run of 1
+    is the loop it was, call for call (regenerate on the PARENT commit with
+    ``tests/prefill_call_sequences.py``, never on the change)."""
+    import prefill_call_sequences
+    assert prefill_call_sequences.call_sequence(program) \
+        == CALLS_BEFORE_A_CALL_CARRIED_RUNS[program]

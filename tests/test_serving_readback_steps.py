@@ -25,7 +25,8 @@ def model_params():
 
 @pytest.fixture(scope="module")
 def four_chunk_steps(model_params):
-    """The two steps of a 29-token prompt whose four prefill calls and
+    """The two steps of a 29-token prompt whose four chunks (one prefill
+    call that carries them as a run of four lanes, since PR 54) and
     decode block go out in ONE step, made once for the two tests below:
     -> the engine and what the host held after each step."""
     eng = _engine(model_params, prefill_budget=32)
@@ -49,12 +50,15 @@ def four_chunk_steps(model_params):
 
 def test_a_four_chunk_prompt_waits_once_for_the_step_that_takes_it_whole(
         four_chunk_steps):
-    """Four prefill calls and the decode block of one step: one wait (the
-    parent of ISSUE 31 waited five times there), made by the next step,
-    which is when the host learns the first token and stamps TTFT."""
+    """The prompt's four chunks, one call's run, and the decode block of
+    one step: one wait (the parent of ISSUE 31 waited five times there,
+    once a chunk's call), made by the next step, which is when the host
+    learns the first token and stamps TTFT."""
     eng, (first, second) = four_chunk_steps
-    assert first.out == {} and first.calls == 4 and first.rounds == 1
-    # four prefill calls and a block went out, nothing was waited for
+    assert first.out == {} and first.calls == 1 and first.rounds == 1
+    assert eng.anatomy.records()[0]["prefill_calls"][0][:4] + \
+        eng.anatomy.records()[0]["prefill_calls"][0][5:] == [4, 4, 4, 29, 4]
+    # the prefill call and a block went out, nothing was waited for
     assert first.readbacks == 0 and first.owed == []
     assert first.prefill_done and first.generated == [] \
         and first.first_token_at is None
@@ -141,6 +145,8 @@ def test_eos_and_one_token_requests_are_read_in_the_parents_step(
         k += 1
         for rid, toks in eng.step().items():
             came[rid] = (k, np.asarray(toks).tolist())
+    # (the parent's tokens in the parent's steps: a call that carries runs
+    # gives each prompt of a step what the calls of one chunk a slot did)
     assert [came[r] for r in rids] == [
         (2, [89]), (4, [36]), (5, [49, 42, 49, 124, 39, 124]),
         (4, [39, 49, 120])]

@@ -326,7 +326,7 @@ def eng(model_params):
     return e
 
 
-def _served_with_a_sleep(eng, attr, seconds=0.2, at_call=3):
+def _served_with_a_sleep(eng, attr, seconds=0.2, at_call=2):
     """Serve three requests with ONE call of ``eng.<attr>`` sleeping
     first; -> (registry delta, the records of those steps, the seconds
     it slept)."""
@@ -445,9 +445,11 @@ class TestEngineSlowSteps:
 
     def test_prefill_histograms_against_a_scripted_arrival_pattern(
             self, eng):
-        """Budget 16, chunk 4, four slots: two prompts of 8 are two calls
-        of two lanes in ONE step; three prompts of 4 one call of three
-        lanes in a bucket of four; one prompt of 12 three calls of one."""
+        """Budget 16, chunk 4, four slots, a plain pool (a run as long as
+        the call): two prompts of 8 are one call of two runs of two; three
+        prompts of 4 one call of three lanes in a bucket of four; one
+        prompt of 12 one call whose three lanes are one run. The lanes, the
+        runs and the pad are what a hand count gives (PR 54)."""
         rng = np.random.default_rng(2)
         before, n0 = eng._reg.snapshot(), eng.anatomy.summary()["steps"]
         for lengths in ((8, 8), (4, 4, 4), (12,)):
@@ -456,21 +458,29 @@ class TestEngineSlowSteps:
         d = {k: after[k] - before[k] for k in after
              if k.startswith(("serving_prefill_call_lanes",
                               "serving_step_prefill_calls",
-                              "serving_prefill_calls_total"))}
-        assert d["serving_prefill_calls_total"] == 6
-        assert d["serving_prefill_call_lanes_count"] == 6
-        assert d["serving_prefill_call_lanes_sum"] == 2 + 2 + 3 + 1 + 1 + 1
+                              "serving_prefill_calls_total",
+                              "serving_prefill_run_chunks",
+                              "serving_prefill_lanes_total"))}
+        assert d["serving_prefill_calls_total"] == 3
+        assert d["serving_prefill_call_lanes_count"] == 3
+        assert d["serving_prefill_call_lanes_sum"] == 4 + 3 + 3
         assert d["serving_step_prefill_calls_count"] == 3
-        assert d["serving_step_prefill_calls_sum"] == 6
+        assert d["serving_step_prefill_calls_sum"] == 3
+        # a run is observed once a slot and call: 2 2, 1 1 1, 3
+        assert d["serving_prefill_run_chunks_count"] == 6
+        assert d["serving_prefill_run_chunks_sum"] == 2 + 2 + 1 + 1 + 1 + 3
+        assert d['serving_prefill_lanes_total{kind="live"}'] == 4 + 3 + 3
+        assert d['serving_prefill_lanes_total{kind="bucket"}'] == 4 + 4 + 4
         recs = eng.anatomy.records()[-(eng.anatomy.summary()["steps"] - n0):]
-        calls = [[c[:4] for c in r["prefill_calls"]] for r in recs
+        # [lanes_live, lanes, width, tokens, (seconds,) longest run]
+        calls = [[c[:4] + c[5:] for c in r["prefill_calls"]] for r in recs
                  if r["prefill_calls"]]
-        assert calls == [[[2, 2, 1, 8], [2, 2, 2, 8]],
-                         [[3, 4, 1, 12]],
-                         [[1, 1, 1, 4], [1, 1, 2, 4], [1, 1, 4, 4]]]
+        assert calls == [[[4, 4, 2, 16, 2]],
+                         [[3, 4, 1, 12, 1]],
+                         [[3, 4, 4, 12, 3]]]
         # the lanes' fill is in the records: live lanes over the buckets'
         assert sum(c[0] for cs in calls for c in cs) == 10
-        assert sum(c[1] for cs in calls for c in cs) == 11
+        assert sum(c[1] for cs in calls for c in cs) == 12
 
     def test_a_slow_step_is_one_annotation_after_its_phase_closed(
             self, eng, monkeypatch):

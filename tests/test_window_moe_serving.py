@@ -5,7 +5,9 @@ through the paged serving engine, against the plain float32 reference
 Sizes: hidden 64, 4 query heads over 2 KV heads of 16, five layers
 (window, window, window, full, window; the first MLP dense, then 8 routed
 experts of 32 with 3 a token, of which 2 are held, beside a shared one),
-window 8, page 4, chunk 4: a window layer's ring is 3 pages a slot.
+window 8, page 4, chunk 4: a window layer's ring is its window's 2 pages
+and 2 of room a slot (two slots, so a budget of two chunks a step: one
+prompt alone gives a call a run of two).
 Weights are seeded float32 as ``init`` draws them, so what separates the
 engine from the reference is the order of float32 sums (the paged
 kernels' page folds, the grouped expert kernel's tiles) and nothing else.
@@ -34,8 +36,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 import window_moe_reference as ref  # noqa: E402
 from serving_taps import (assert_close, assert_refused,  # noqa: E402
                           FEATURE_OPTIONS, moved,
-                          reference_rows, serve_alone,
-                          shared_engines, tapped_engine, traced)
+                          reference_rows, runs_against_one_chunk_a_slot,
+                          serve_alone, shared_engines, tapped_engine, traced)
 from serving_taps import prompt as _prompt  # noqa: E402
 
 #: float32 on both sides, sums in another order: 2e-5 OF THE LARGEST
@@ -85,8 +87,8 @@ CASES = {
     # a prompt of one page and a token: the second chunk reads the first's
     # page; decode crosses a page boundary
     "crosses_a_page": (PAGE + 1, 6),
-    # 10 + 9 tokens: the ring's 3 pages hold 12, so decode writes over
-    # the page of tokens 0-3 and then 4-7 (recycled pages)
+    # 10 + 9 tokens: the ring's 4 pages (2 of room: two lanes a call) hold
+    # 16, so decode writes over the page of tokens 0-3 (a recycled page)
     "decode_recycles_pages": (10, 9),
     # the prompt itself laps the ring twice (29 tokens, 8 chunks), ends
     # inside a page; 11 new tokens lap it again
@@ -162,8 +164,8 @@ def test_two_requests_side_by_side_keep_to_their_own_rings(
 
 def test_engine_decodes_window_layers_through_the_body_that_walks_pages():
     """Pages of whole tiles (128 lanes, 8 float32 rows): the window
-    layers' decode goes through the walking body, a ring of 3 pages that
-    40 tokens lap. (The one case where an engine's ring places what that
+    layers' decode goes through the walking body, a ring of 4 pages (2 of
+    room) that 40 tokens lap. (The one case where an engine's ring places what that
     body walks: ``tests/test_window_moe_kernels.py`` holds the body alone
     to the reference under a window, ``tests/test_kernels_registry.py`` an
     engine to it without one; the engines above, with pages of 4 rows,
@@ -183,6 +185,80 @@ def test_engine_decodes_window_layers_through_the_body_that_walks_pages():
     with jax.default_matmul_precision("highest"):
         want = np.asarray(ref.reference_logits(params, ids, ref.sizes_of(cfg)))
     assert (want[18:18 + 21].argmax(-1) == out).all()
+
+
+# -- a call that carries runs (ISSUE 54) -----------------------------------------
+
+#: prompt lengths, served together in four slots under a budget of three
+#: chunks a step: a ring of the window's 2 pages and 3 of room
+RUN_CASES = {
+    # 19 tokens: a run of three chunks, then one of two that ends inside
+    # a page (the lane that ends the prompt gives the first token)
+    "ends_inside_a_page": (4 * PAGE + 3,),
+    # a prompt shorter than a page beside one of two runs
+    "shorter_than_a_page": (3, 5 * PAGE + 1),
+    # four prompts for three lanes: the nearest its first token first
+    "more_slots_than_lanes": (9, 14, 6, 21),
+    # 43 tokens lap the ring of 20 twice, a run at a time
+    "laps_the_ring": (43, 2),
+}
+
+
+@pytest.fixture(scope="module")
+def run_engine(model_and_params):
+    model, params = model_and_params
+    return inference.make_serving_engine(
+        model, params, num_slots=4, page_size=PAGE, prefill_chunk=CHUNK,
+        prefill_budget=3 * CHUNK, max_tokens_per_slot=64, decode_block=2,
+        attn_impl="lax", registry=obs.MetricsRegistry())
+
+
+@pytest.mark.parametrize("case", sorted(RUN_CASES))
+def test_calls_that_carry_runs_give_the_tokens_of_one_chunk_a_slot(
+        case, run_engine):
+    """The same requests on the same programs, calls formed as the engine
+    forms them and held to one chunk a slot: token for token the same,
+    every ring sound after every step (``Ring.check``)."""
+    eng = run_engine
+    ring = next(k for k in eng.cache.config.kinds if k.by_slot)
+    assert (ring.prefill_run, ring.ring_pages, eng._run_limit) == (3, 5, 3)
+    prompts = [_prompt(n, seed=540 + n) for n in RUN_CASES[case]]
+    (got, calls), (want, plain) = runs_against_one_chunk_a_slot(eng, prompts)
+    assert got == want and all(len(t) == 5 for t in got)
+    longest = max(c[4] for step in calls for c in step)
+    assert longest == min(3, -(-max(RUN_CASES[case]) // CHUNK))
+    # fewer calls for the same prompt tokens, and never two in a step
+    # where the second would be a few lanes beside the first
+    assert sum(c[3] for step in calls for c in step) \
+        == sum(c[3] for step in plain for c in step) == sum(RUN_CASES[case])
+    assert sum(map(len, calls)) <= sum(map(len, plain))
+    assert all(len(step) == 1 for step in calls)
+
+
+def test_warmed_buckets_cover_every_call_a_round_can_form(run_engine):
+    """After ``warmup()`` a served run whose calls carry runs compiles
+    nothing: every (width, lanes) a round forms is in the plan."""
+    from paddle_tpu.analysis.hlo_lint import serving_bucket_coverage
+    model, params = run_engine.model, run_engine.params
+    eng = inference.make_serving_engine(
+        model, params, num_slots=4, page_size=PAGE, prefill_chunk=CHUNK,
+        prefill_budget=3 * CHUNK, max_tokens_per_slot=32, decode_block=2,
+        attn_impl="lax", registry=obs.MetricsRegistry())
+    assert serving_bucket_coverage(eng) == []
+    assert sorted({sig[2] for sig in eng.warmup_plan()
+                   if sig[0] == "prefill"}) == [1, 2, 4]
+    eng.warmup(cost_gauges=False)
+    det = obs.RecompileDetector("runs", warmup=0,
+                                registry=obs.MetricsRegistry())
+    outs = eng.generate_many([_prompt(n, seed=n) for n in (21, 3, 14, 9, 17)],
+                             max_new_tokens=6)
+    det.check()
+    assert det.recompiles == 0 and all(len(o) == 6 for o in outs)
+    served = {("prefill", c[2], c[1]) for r in eng.anatomy.records()
+              for c in r.get("prefill_calls", ())}
+    assert served and served <= eng.warmed_signatures
+    assert max(c[5] for r in eng.anatomy.records()
+               for c in r.get("prefill_calls", ())) == 3
 
 
 # -- the engine ---------------------------------------------------------------
@@ -214,19 +290,20 @@ def test_counters_and_spans_of_the_two_layer_kinds(engines):
     snap, gauges = moved(reg, before), reg.snapshot()
     row = 2 * 2 * 16 * 4                     # K and V of a token, a layer
     page = PAGE * row
-    # pools: 4 window layers of 2 x 3 + 1 pages, one full of 49
-    assert gauges['serving_kv_pool_bytes{layers="window"}'] == 4 * 7 * page
+    # pools: 4 window layers of 2 x 4 + 1 pages, one full of 49
+    assert gauges['serving_kv_pool_bytes{layers="window"}'] == 4 * 9 * page
     assert gauges['serving_kv_pool_bytes{layers="full"}'] \
         == eng.cache.config.num_pages * page
-    # prefill calls at 0, 4, 8 tokens held; decode blocks of 2 from 10 on
-    held = [0, 4, 8] + [10, 12, 14, 16]
+    # prefill calls at 0 (a run of two chunks) and 8 tokens held, what
+    # a slot holds counted once a call; decode blocks of 2 from 10 on
+    held = [0, 8] + [10, 12, 14, 16]
     pages = [-(-n // PAGE) for n in held]
     assert snap['serving_kv_resident_bytes_total{layers="full"}'] \
         == sum(pages) * page
     assert snap['serving_kv_resident_bytes_total{layers="window"}'] \
-        == sum(min(p, 3) for p in pages) * 4 * page
-    # the ring's first lap is 12 tokens: tokens 12-18 enter 2 more pages
-    assert snap["serving_window_pages_recycled_total"] == 2 * 4
+        == sum(min(p, 4) for p in pages) * 4 * page
+    # the ring's first lap is 16 tokens: tokens 16-18 enter 1 more page
+    assert snap["serving_window_pages_recycled_total"] == 1 * 4
     # a decode token step at L tokens held reads L + 1 rows of the full
     # layer and min(L + 1, 8) of each window layer
     steps = range(10, 18)
@@ -243,7 +320,9 @@ def test_counters_and_spans_of_the_two_layer_kinds(engines):
     rounds = [s for s in spans if s.name == "serving.decode_round"
               and s.attrs.get("slots_live")]
     calls = [s for s in spans if s.name == "serving.prefill_call"]
-    assert sum(s.attrs["window_pages"] for s in rounds + calls) == 8
+    assert sum(s.attrs["window_pages"] for s in rounds + calls) == 4
+    assert [(s.attrs["lanes_live"], s.attrs["slots"]) for s in calls] \
+        == [(2, 1), (1, 1)]
     assert all("pairs_held" in s.attrs for s in rounds)
 
 

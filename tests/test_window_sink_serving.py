@@ -8,8 +8,8 @@ of which 2 are held, no shared expert); a full layer caches 1 KV head and
 a window layer 2; keys of 24 a head of which the first ``int(24 x 0.334)``
 = 8 entries are rotated (base 5e6 on a full layer, 1e4 on a window layer),
 values of 16 scaled by 0.707, a learned sink a query head in the window
-layers' softmax; window 8, page 4, chunk 4: a window layer's ring is 3
-pages a slot. Weights are seeded float32 as ``init`` draws them (the sinks
+layers' softmax; window 8, page 4, chunk 4: a window layer's ring is 4
+pages a slot (its window's 2 and 2 of room: two slots, two lanes a call). Weights are seeded float32 as ``init`` draws them (the sinks
 so that they take about a quarter of a window's mass), so what separates
 the engine from the reference is the order of float32 sums and nothing
 else. ONE engine an ``impl`` serves the cases (``engines``, module-scoped).
@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu import inference
 from paddle_tpu import observability as obs
 from paddle_tpu.models import WindowMoELM, WindowMoELMConfig
 from paddle_tpu.serving import layer_kinds
@@ -34,7 +35,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 import mimo_v2_flash_reference as ref  # noqa: E402
 from serving_taps import (assert_close, assert_refused,  # noqa: E402
                           FEATURE_OPTIONS, moved, reference_rows,
-                          serve_alone, shared_engines, tapped_engine, traced)
+                          runs_against_one_chunk_a_slot, serve_alone,
+                          shared_engines, tapped_engine, traced)
 from serving_taps import prompt as _prompt  # noqa: E402
 
 #: float32 on both sides, sums in another order: 2e-5 OF THE LARGEST
@@ -147,8 +149,8 @@ CASES = {
     "inside_the_window": (3, 4),
     # the prompt ends a token short of the window; decode crosses it
     "decode_crosses_the_window": (WINDOW - 1, 5),
-    # 10 + 9 tokens: the ring's 3 pages hold 12, so decode writes over
-    # the page of tokens 0-3 and then 4-7 (recycled pages)
+    # 10 + 9 tokens: the ring's 4 pages (2 of room: two lanes a call) hold
+    # 16, so decode writes over the page of tokens 0-3 (a recycled page)
     "decode_recycles_pages": (10, 9),
     # the prompt itself laps the ring twice (29 tokens, 8 chunks), ends
     # inside a page; 11 new tokens lap it again
@@ -211,6 +213,145 @@ def test_two_requests_side_by_side_keep_to_their_own_rings(
         assert (want.argmax(-1) == out).all()
 
 
+# -- a call that carries runs (ISSUE 54) -----------------------------------------
+
+#: prompt lengths, served together in four slots under a budget of four
+#: chunks a step (the rehearsal's): rings of the window's 2 pages and 4 of
+#: room beside full layers that take any run
+RUN_CASES = {
+    # 22 tokens: a run of four chunks, then one of two that ends inside a
+    # page (the lane that ends the prompt gives the first token)
+    "ends_inside_a_page": (5 * PAGE + 2,),
+    # a prompt shorter than a page beside one of several runs
+    "shorter_than_a_page": (2, 9 * PAGE + 1),
+    # five prompts for four slots and four lanes
+    "more_slots_than_lanes": (9, 14, 6, 21, 11),
+    # 51 tokens lap the ring of 24 twice, a run at a time
+    "laps_the_ring": (51, 3),
+}
+
+
+@pytest.fixture(scope="module")
+def run_engine(model_and_params):
+    model, params = model_and_params
+    return inference.make_serving_engine(
+        model, params, num_slots=4, page_size=PAGE, prefill_chunk=CHUNK,
+        prefill_budget=4 * CHUNK, max_tokens_per_slot=64, decode_block=2,
+        attn_impl="lax", registry=obs.MetricsRegistry())
+
+
+@pytest.mark.parametrize("case", sorted(RUN_CASES))
+def test_calls_that_carry_runs_give_the_tokens_of_one_chunk_a_slot(
+        case, run_engine):
+    """Layers that differ in KV heads, key and value width and sinks: the
+    same requests on the same programs, calls formed as the engine forms
+    them and held to one chunk a slot, give the same tokens, and every
+    ring is sound after every step (``Ring.check``)."""
+    eng = run_engine
+    full, ring = eng.cache.config.kinds[0], eng.cache.config.kinds[1]
+    assert (full.prefill_run, ring.prefill_run, ring.ring_pages,
+            eng._run_limit) == (None, 4, 6, 4)
+    prompts = [_prompt(n, seed=549 + n) for n in RUN_CASES[case]]
+    (got, calls), (want, plain) = runs_against_one_chunk_a_slot(eng, prompts)
+    assert got == want and all(len(t) == 5 for t in got)
+    # (with more slots than lanes the nearest its first token leaves the
+    # others fewer lanes than their runs)
+    assert 1 < max(c[4] for step in calls for c in step) \
+        <= min(4, -(-max(RUN_CASES[case]) // CHUNK))
+    assert sum(c[3] for step in calls for c in step) \
+        == sum(c[3] for step in plain for c in step) == sum(RUN_CASES[case])
+    assert sum(map(len, calls)) <= sum(map(len, plain))
+    assert all(len(step) == 1 for step in calls)
+
+
+@pytest.mark.parametrize("case", [
+    "alone_on_an_idle_engine_it_advances_a_budget_a_step",
+    "beside_a_slot_that_decodes_it_gives_a_step_one_run",
+    "held_to_one_chunk_a_slot_it_advances_a_budget_a_step"])
+def test_a_lone_long_prompt_and_the_steps_further_calls(
+        case, model_and_params):
+    """A ring's room (8 pages: ``engine._LANE_STEP``) under a call of 12
+    lanes and a budget of 16 chunks, and one prompt of 15 chunks. Where
+    no slot decodes nothing waits behind a call, and the step spends its
+    budget in calls of one run (8 + 7 lanes: the first token after one
+    step, as with one chunk a slot a call); where a slot decodes the
+    prompt gives a step one run, and what it could still give leads the
+    next step's call (the break-even is set by hand to a bf16 stage's at
+    a chunk of 128: this toy's own is 120 lanes)."""
+    model, params = model_and_params
+    eng = inference.make_serving_engine(
+        model, params, num_slots=12, page_size=PAGE, prefill_chunk=CHUNK,
+        prefill_budget=16 * CHUNK, max_tokens_per_slot=72, decode_block=2,
+        attn_impl="lax", registry=obs.MetricsRegistry())
+    ring = eng.cache.config.kinds[1]
+    assert (eng._lane_cap, ring.prefill_run, eng._run_limit) == (12, 8, 8)
+    eng._second_call_lanes = 2
+    if case.startswith("held"):
+        eng._run_limit = 1
+    long = _prompt(15 * CHUNK, seed=541)
+    beside = case.startswith("beside")
+    if beside:
+        # a short request that decodes all the while
+        eng.submit(_prompt(3, seed=542), 40)
+        eng.step()
+    n0 = eng.anatomy.summary()["steps"]
+    rid = eng.submit(long, 2)
+    out = {}
+    while rid not in out:
+        out.update(eng.step())
+        eng.cache.check_invariants()
+    recs = eng.anatomy.records()[-(eng.anatomy.summary()["steps"] - n0):]
+    calls = [[c[0] for c in r["prefill_calls"]] for r in recs
+             if r.get("prefill_calls")]
+    assert calls == {"alone": [[8, 7]], "besid": [[8], [7]],
+                     "held_": [[1] * 15]}[case[:5]]
+    assert all(sum(c[3] for c in r.get("prefill_calls", ()))
+               <= 16 * CHUNK for r in recs)
+    got = np.asarray(out[rid])
+    assert (_reference_rows(model, params, long, got).argmax(-1) == got).all()
+    while not eng.scheduler.idle():
+        eng.step()
+
+
+def _rehearsal_engine(config):
+    """The engine ``benchmark/run.py --rehearse`` builds for a
+    configuration file: its rehearsal sizes and geometry, nothing run."""
+    import importlib
+    import json
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    family = importlib.import_module(f"families.{cfg['family']}")
+    model = family.build({**cfg["sizes"], **cfg["rehearsal"]["sizes"]},
+                         interpret=True)
+    ekw = {**cfg["engine"], **cfg["rehearsal"]["engine"]}
+    ekw["cache_dtype"] = jnp.dtype(ekw["cache_dtype"])
+    return inference.make_serving_engine(
+        model, model.init(jax.random.PRNGKey(0), dtype=jnp.bfloat16),
+        attn_impl="lax", registry=obs.MetricsRegistry(), **ekw)
+
+
+@pytest.mark.parametrize("config", ["mimo_v2_flash", "k_exaone_236b_a23b"])
+def test_rehearsal_engines_warm_every_call_a_round_can_form(config):
+    """Both window models' rehearsal engines (4 slots, a budget of four
+    chunks): the plan covers what the step side can ask for, lane counts
+    up to what the budget buys and no further, and the round's runs are
+    as long as the rings have room."""
+    from paddle_tpu.analysis.hlo_lint import serving_bucket_coverage
+    eng = _rehearsal_engine(config)
+    assert serving_bucket_coverage(eng) == []
+    assert set(eng.warmup_plan()) == eng.reachable_signatures()
+    lanes = sorted({sig[2] for sig in eng.warmup_plan()
+                    if sig[0] == "prefill"})
+    assert lanes == [1, 2, 4] == sorted(
+        {eng._pow2_count(n) for n in range(1, eng._lane_cap + 1)})
+    rings = [k for k in eng.cache.config.kinds if k.by_slot]
+    assert rings and {k.prefill_run for k in rings} == {4}
+    assert eng._run_limit == 4 and eng._lane_cap == 4
+
+
 # -- the engine ----------------------------------------------------------------
 
 def test_each_kind_of_layer_has_its_own_geometry(engines):
@@ -230,11 +371,14 @@ def test_each_kind_of_layer_has_its_own_geometry(engines):
     assert [shape for shape, _, _ in full.pools] == [
         (pages, PAGE, KV_FULL * DK), (pages, PAGE, KV_FULL * DV)]
     assert [shape for shape, _, _ in ring.pools] == [
-        (2 * 3 + 1, PAGE, KV_WINDOW * DK), (2 * 3 + 1, PAGE, KV_WINDOW * DV)]
+        (2 * 4 + 1, PAGE, KV_WINDOW * DK), (2 * 4 + 1, PAGE, KV_WINDOW * DV)]
+    # the window's 2 pages and 2 of room: two lanes a call
+    assert (ring.window_pages, ring.prefill_run, ring.ring_pages) == (2, 2, 4)
+    assert full.prefill_run is None and eng._run_limit == 2
     assert full.token_bytes == KV_FULL * (DK + DV) * 4
     assert ring.token_bytes == KV_WINDOW * (DK + DV) * 4
     assert eng.cache.bytes_per_page() == 2 * PAGE * full.token_bytes
-    assert eng.cache.bytes_per_slot() == 5 * 3 * PAGE * ring.token_bytes
+    assert eng.cache.bytes_per_slot() == 5 * 4 * PAGE * ring.token_bytes
     eng.cache.check_invariants()
 
 
@@ -259,16 +403,17 @@ def test_counters_and_spans_of_layers_of_two_widths(engines):
     full_row = KV_FULL * (DK + DV) * 4          # K and V of a token, a layer
     ring_row = KV_WINDOW * (DK + DV) * 4
     assert gauges['serving_kv_pool_bytes{layers="window"}'] \
-        == 5 * 7 * PAGE * ring_row
+        == 5 * 9 * PAGE * ring_row
     assert gauges['serving_kv_pool_bytes{layers="full"}'] \
         == 2 * eng.cache.config.num_pages * PAGE * full_row
-    # prefill calls at 0, 4, 8 tokens held; decode blocks of 2 from 10 on
-    held = [0, 4, 8] + [10, 12, 14, 16]
+    # prefill calls at 0 (a run of two chunks) and 8 tokens held, what
+    # a slot holds counted once a call; decode blocks of 2 from 10 on
+    held = [0, 8] + [10, 12, 14, 16]
     pages = [-(-n // PAGE) for n in held]
     assert snap['serving_kv_resident_bytes_total{layers="full"}'] \
         == sum(pages) * 2 * PAGE * full_row
     assert snap['serving_kv_resident_bytes_total{layers="window"}'] \
-        == sum(min(p, 3) for p in pages) * 5 * PAGE * ring_row
+        == sum(min(p, 4) for p in pages) * 5 * PAGE * ring_row
     # a decode token step at L tokens held reads L + 1 rows of each full
     # layer and min(L + 1, 8) of each window layer
     steps = range(10, 18)

@@ -22,6 +22,7 @@ import argparse
 import base64
 import hashlib
 import importlib
+import inspect
 import json
 import os
 import re
@@ -66,6 +67,7 @@ def cell_programs(root: str, config: dict, device):
     import jax
     import jax.numpy as jnp
     from paddle_tpu import inference
+    from paddle_tpu.serving import engine as serving_engine
     from paddle_tpu.serving import layer_kinds
     family = importlib.import_module(f"families.{config['family']}")
     model = family.build(config["sizes"])
@@ -86,9 +88,19 @@ def cell_programs(root: str, config: dict, device):
     sds = jax.ShapeDtypeStruct
     # the cell's pools: what each layer's kind lays out at the cell's
     # geometry, then the program's state a slot
+    lanes = min(max(eng.prefill_budget // chunk, 1), slots)
+    # (a ring's room follows the lanes of a call since PR 54; a checkout
+    # from before takes no such argument and gives a ring one page)
+    room = {"prefill_room": min(lanes, serving_engine._LANE_STEP)} \
+        if "prefill_room" in inspect.signature(
+            layer_kinds.build).parameters else {}
     kinds = layer_kinds.build(
         eng.program.spec, num_slots=slots, page_size=c.page_size,
-        num_pages=num_pages, dtype=c.dtype, share_prefix=c.share_prefix)
+        num_pages=num_pages, dtype=c.dtype, share_prefix=c.share_prefix,
+        prefill_chunk=chunk, **room)
+    # the steps place rows and attend as the CELL's kinds do (a ring's
+    # pages follow its slots and its room), not the stand-in's
+    eng.cache.config.kinds = kinds
     weights = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype, sharding=device), params)
     pages = [tuple(sds(shape, dtype, sharding=device)
@@ -100,7 +112,6 @@ def cell_programs(root: str, config: dict, device):
     def i32(*shape):
         return sds(shape, jnp.int32, sharding=device)
 
-    lanes = min(max(eng.prefill_budget // chunk, 1), slots)
     yield "decode", slots, width, eng.decode_step.lower(
         weights, pages, i32(slots, width), i32(slots), i32(slots),
         i32(slots)).as_text()
