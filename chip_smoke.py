@@ -137,6 +137,10 @@ class Sizes:
     #: keys wider than values: (query heads, key width, value width, page
     #: size, window, (KV heads of a full layer, of a window layer))
     wide_keys: tuple = (64, 192, 128, 128, 128, (4, 8))
+    #: a selection over a latent cache: (query heads, latent width, rotary
+    #: width, indexer heads, indexer head width, tokens selected, page
+    #: size, prefill chunk, pages a slot)
+    selecting_latent: tuple = (128, 512, 64, 64, 128, 2048, 128, 256, 40)
     interpret: bool = False
 
     @classmethod
@@ -186,7 +190,9 @@ class Sizes:
                                max_position_embeddings=256, num_experts=8,
                                moe_intermediate_size=32,
                                router_hidden_size=16),
-                   wide_keys=(4, 24, 16, 4, 8, (1, 2)), interpret=True)
+                   wide_keys=(4, 24, 16, 4, 8, (1, 2)),
+                   selecting_latent=(4, 16, 8, 2, 16, 16, 8, 16, 6),
+                   interpret=True)
 
     @property
     def prefill_steps(self):
@@ -902,6 +908,113 @@ def phase_latent_family(sizes, seed):
                              sizes, seed, 3, LATENT_TIE_MARGIN)
 
 
+# ---------------------------------------------------------------------------
+# phase 8: a selection over a latent cache, at DeepSeek-V3.2's widths
+# ---------------------------------------------------------------------------
+
+def phase_selecting_latent_kernels(sizes, seed):
+    """The kernels of ``layer_kinds.SelectingLatent`` against their ``lax``
+    forms, and the selection's positions against ``lax.top_k`` element for
+    element: the fold over gathered rows for decode and for a chunk, the
+    indexer where a decode step's few rows meet a block's key pages in one
+    product and where a chunk's heads are summed a few queries at a
+    time."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import kernels
+    from paddle_tpu.serving import sparse_attention as SA
+
+    h, dl, dr, j, di, topk, ps, c, mp = sizes.selecting_latent
+    impl = sizes.kernel_impl
+    dt = jnp.float32 if sizes.interpret else jnp.bfloat16
+    s = 8
+    num_pages = s * mp + 1
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape),
+                           jnp.float32).astype(dt)
+
+    tables = (1 + rng.permutation(num_pages - 1)[:s * mp]).reshape(
+        s, mp).astype(np.int32)
+    shared = mp - 4
+    tables[:5, :shared] = tables[0, :shared]      # five slots, one document
+    lengths = rng.integers(shared * ps, mp * ps + 1, s).astype(np.int32)
+    lengths[-1] = topk // 2                       # sees fewer than it takes
+    bt, lens = jnp.asarray(tables), jnp.asarray(lengths)
+    c_pages = normal(num_pages, ps, dl)
+    r_pages = normal(num_pages, ps, dr + -dr % 128)
+    ik_pages = normal(num_pages, di, ps)
+    errs = {}
+
+    def close(name, got, want, atol):
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        assert got.shape == want.shape and np.isfinite(got).all(), name
+        np.testing.assert_allclose(got, want, atol=atol, rtol=2e-2,
+                                   err_msg=f"{name} {impl} vs lax")
+        errs[name] = float(np.max(np.abs(got - want)))
+
+    # -- the indexer: a decode step's few rows against a block's pages in
+    # one product, a wide chunk summed a few queries at a time
+    q_idx, w_idx = normal(s, 1, j, di), jnp.asarray(
+        rng.standard_normal((s, 1, j)), jnp.float32)
+    alone = jax.jit(lambda *a: SA.lightning_index_scores(*a, impl=impl))(
+        q_idx, w_idx, ik_pages, bt, lens)[:, 0]
+    close("indexer decode", alone, jax.jit(
+        lambda *a: SA.lightning_index_scores(*a, impl="lax"))(
+            q_idx, w_idx, ik_pages, bt, lens)[:, 0], 2e-2 * di ** 0.5)
+    starts = jnp.asarray(np.maximum(lengths - c, 0))
+    n_valid = jnp.asarray(rng.integers(1, c + 1, s), jnp.int32)
+    qc, wc = normal(s, c, j, di), jnp.asarray(
+        rng.standard_normal((s, c, j)), jnp.float32)
+    close("indexer chunk", *(jax.jit(
+        lambda *a, i=i: SA.lightning_index_scores(*a, impl=i))(
+            qc, wc, ik_pages, bt, starts + n_valid)
+        for i in (impl, "lax")), 2e-2 * di ** 0.5)
+
+    # -- the selection: the mask read out as positions, against the sort
+    pos, n_sel = jax.jit(lambda a, n: SA.select_positions(
+        a, n, topk, impl=impl))(alone, lens)
+    want = np.asarray(SA.selected_by_sort(alone, lens, topk))
+    got = np.zeros_like(want)
+    for row, (p, n) in enumerate(zip(np.asarray(pos), np.asarray(n_sel))):
+        got[row, p[:n]] = 1
+    assert (got == want).all() and int(n_sel[-1]) == topk // 2
+    log(f"selection positions vs lax.top_k: {int(want.sum())} of "
+        f"{want.size} marked, 0 differ")
+
+    # -- the folds over gathered rows
+    scale = (dl + dr) ** -0.5 / 4
+    q = normal(s, h, dl + dr, scale=scale)
+    close("sparse_latent_decode", *(jax.jit(
+        lambda *a, i=i: SA.sparse_latent_decode_attention(*a, impl=i))(
+            q, c_pages, r_pages, bt, pos, n_sel) for i in (impl, "lax")),
+        2 ** -6)
+    lanes = 2
+    qp = normal(lanes, c, h, dl + dr, scale=scale)
+    scores = jax.jit(lambda *a: SA.lightning_index_scores(*a, impl=impl))(
+        qc[:lanes], wc[:lanes], ik_pages, bt[:lanes],
+        (starts + n_valid)[:lanes])
+    seen = starts[:lanes, None] + jnp.arange(1, c + 1)
+    live = jnp.arange(c)[None, :] < n_valid[:lanes, None]
+    if mp * ps > topk:
+        cpos, cn = SA._select_rows(
+            scores.reshape(lanes * c, -1), seen.reshape(-1), topk,
+            live.reshape(-1), impl)
+    else:
+        cpos, cn = (SA._every_position((lanes * c,), mp * ps),
+                    jnp.where(live, seen, 0).reshape(-1))
+    cpos, cn = cpos.reshape(lanes, c, -1), cn.reshape(lanes, c)
+    close("sparse_latent_prefill", *(jax.jit(
+        lambda *a, i=i: SA.sparse_latent_prefill_attention(*a, impl=i))(
+            qp, c_pages, r_pages, bt[:lanes], cpos, cn)
+        for i in (impl, "lax")), 2 ** -6)
+    for name in ("sparse_latent_decode", "sparse_latent_prefill",
+                 "lightning_indexer"):
+        assert _dispatched(name, impl) > 0
+    log("selecting latent kernels vs lax max|err|: " + json.dumps(errs))
+
+
 def _sparse_kernel_args(name, cfg, sizes, seed):
     """One call's arguments of kernel ``name`` at ``cfg``'s widths: bf16
     pools of 2 slots x 20 pages under float32 queries (as phase 1: the
@@ -1054,6 +1167,7 @@ def run_one_chip(sizes, seed=0):
     phase_hybrid_family(sizes, seed)
     phase_latent_family(sizes, seed)
     phase_wide_key_kernels(sizes, seed)
+    phase_selecting_latent_kernels(sizes, seed)
 
 
 def run_four_chips(sizes, seed=0, devices=None):
@@ -1105,7 +1219,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--only", choices=("wide_keys",), default=None,
+    ap.add_argument("--only", choices=("wide_keys", "selecting_latent"),
+                    default=None,
                     help="one chip: this phase alone")
     args = ap.parse_args(argv)
 
@@ -1131,7 +1246,9 @@ def main(argv=None) -> int:
     if args.chips == 4:
         run_four_chips(Sizes.real(), args.seed, devices)
     elif args.only:
-        phase_wide_key_kernels(Sizes.real(), args.seed)
+        {"wide_keys": phase_wide_key_kernels,
+         "selecting_latent": phase_selecting_latent_kernels}[args.only](
+             Sizes.real(), args.seed)
     else:
         run_one_chip(Sizes.real(), args.seed)
     log(f"all phases passed in {time.perf_counter() - t0:.1f}s; compile "

@@ -42,6 +42,37 @@ into the output (the absorbed form, the same mathematics)::
 ``attn_in`` returns ``qt`` and the row's two parts, the engine's latent
 kernels return ``u``, ``attn_out`` applies ``W_UV`` and ``W_o``.
 
+**A token selection over the latent cache** (``index_topk``: DeepSeek-V3.2's
+lightning indexer). Each layer also caches an index key a token and every
+query attends to the ``index_topk`` cached tokens it scores best::
+
+    qI[t, j] = [rope(u_j[:d_r], p_t) | u_j[d_r:]],  u = c_q,t W_qI   j < J
+    kI[s]    = [rope(z[:d_r], p_s) | z[d_r:]],      z = layernorm(h_s W_kI)
+    w[t]     = J^(-1/2) h_t W_w                     J head weights, float32
+    I[t, s]  = Di^(-1/2) sum_j w[t, j] relu(qI[t, j] . kI[s])      s <= t
+    S_t      = every s <= t while t + 1 <= index_topk, else the
+               index_topk largest I[t, s], ties to the lower position
+
+and the softmax above runs over ``s in S_t``. The indexer's rotary part is
+its first ``d_r`` lanes, the same adjacent pairs and YaRN frequencies as
+the rotary key's. The row a token caches is then ``[c | k_rope]`` plus
+``kI``; ``attn_in`` hands ``(qI, w)`` back as ``index``.
+
+**Leading dense layers and the group-limited sigmoid router**
+(``first_k_dense_replace``, ``scoring_func="sigmoid"``, ``n_group``,
+``topk_group``: DeepSeek-V3's ``noaux_tc``). The first
+``first_k_dense_replace`` layers' FFN is one SwiGLU of
+``intermediate_size``. A sparse layer under the sigmoid rule::
+
+    s    = sigmoid(h2 W_r)                        float32, all routed experts
+    g_k  = sum of the 2 largest of (s + b) in group k       n_group groups
+    G    = the topk_group groups of largest g_k   ties to the lower index
+    S    = the K largest of (s + b) over the experts of G
+    w_e  = scale * s_e / sum_{j in S} s_j
+
+``b`` the selection bias (``router_bias``, float32): it picks, it does not
+weigh.
+
 The router is as wide as the model's routed experts and picks
 ``num_experts_per_tok`` of them; the layer holds ``n_routed_experts`` of
 them from ``expert_offset`` on and computes their part of the sum (the
@@ -106,6 +137,19 @@ class MLAMoELMConfig:
     n_shared_experts: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    #: leading layers whose FFN is one dense SwiGLU ``intermediate_size``
+    #: wide
+    first_k_dense_replace: int = 0
+    intermediate_size: Optional[int] = None
+    #: "softmax", or "sigmoid" with a selection bias, picked inside the
+    #: ``topk_group`` best of ``n_group`` groups of experts
+    scoring_func: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    #: the lightning indexer: None, no selection
+    index_topk: Optional[int] = None
+    index_n_heads: int = 0
+    index_head_dim: int = 0
     #: which body the expert kernel runs: "auto" (Pallas on a TPU, XLA
     #: elsewhere), "pallas", "pallas_interpret", "lax"
     kernel_impl: str = "auto"
@@ -118,6 +162,26 @@ class MLAMoELMConfig:
             raise ValueError("the experts held lie within the router's")
         if self.qk_rope_head_dim % 2:
             raise ValueError("the rotary key is rotated in pairs")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func={self.scoring_func!r}: the "
+                             "router scores by 'softmax' or 'sigmoid'")
+        if self.num_routed_experts % self.n_group or not \
+                1 <= self.topk_group <= self.n_group:
+            raise ValueError("the routed experts fall into n_group equal "
+                             "groups of which topk_group are kept")
+        if self.n_group > 1 and (
+                self.scoring_func != "sigmoid"
+                or self.num_experts_per_tok > self.topk_group
+                * (self.num_routed_experts // self.n_group)):
+            raise ValueError("the group limit goes with the sigmoid rule "
+                             "and leaves num_experts_per_tok to pick")
+        if self.first_k_dense_replace and not self.intermediate_size:
+            raise ValueError("a dense layer is intermediate_size wide")
+        if self.index_topk is not None and (
+                self.index_n_heads < 1
+                or self.index_head_dim < self.qk_rope_head_dim):
+            raise ValueError("the indexer has heads of at least the "
+                             "rotary key's width")
 
     @property
     def holds_all_experts(self) -> bool:
@@ -127,6 +191,24 @@ class MLAMoELMConfig:
     def row_dim(self) -> int:
         """Values a token caches a layer: the latent and the rotary key."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def is_dense(self, i: int) -> bool:
+        return i < self.first_k_dense_replace
+
+    @classmethod
+    def tiny_selecting(cls, **kw):
+        """:meth:`tiny` with the indexer (16 of up to 64 cached tokens),
+        one leading dense layer of three and the group-limited sigmoid
+        router (4 of 16 experts a token inside 2 of 4 groups; 4 held, from
+        4 on)."""
+        for k, v in dict(num_hidden_layers=3, first_k_dense_replace=1,
+                         intermediate_size=48, scoring_func="sigmoid",
+                         n_group=4, topk_group=2, n_routed_experts=4,
+                         expert_offset=4, routed_scaling_factor=2.5,
+                         llama_4_scaling_beta=0.0, index_topk=16,
+                         index_n_heads=2, index_head_dim=16).items():
+            kw.setdefault(k, v)
+        return cls.tiny(**kw)
 
     @classmethod
     def tiny(cls, **kw):
@@ -169,6 +251,14 @@ def yarn_frequencies(c: MLAMoELMConfig):
     phi = theta ** (-2.0 * j / d)
     ramp = jnp.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
     return phi * (1.0 - ramp) + phi / c.rope_factor * ramp
+
+
+def _layer_norm(u, p, eps):
+    """LayerNorm with a scale and a bias over the last axis, float32."""
+    mu = u.mean(-1, keepdims=True)
+    var = ((u - mu) ** 2).mean(-1, keepdims=True)
+    return (u - mu) * jax.lax.rsqrt(var + eps) * _f32(p["scale"]) \
+        + _f32(p["bias"])
 
 
 def _swiglu(h, p):
@@ -214,7 +304,7 @@ class MLAMoELM:
         layers = {}
         for i in range(c.num_hidden_layers):
             k = jax.random.split(keys[i], 10)
-            layers[str(i)] = {
+            layer = layers[str(i)] = {
                 "attn_norm": ones(d),
                 "q_a_proj": lin(k[0], d, c.q_lora_rank),
                 "q_a_norm": ones(c.q_lora_rank),
@@ -234,6 +324,22 @@ class MLAMoELM:
                             "down": normal_init(k[8], (e, f, d), dtype)},
                 "shared": mlp(k[9], f * c.n_shared_experts),
             }
+            if c.is_dense(i):
+                for name in ("router", "experts", "shared"):
+                    del layer[name]
+                layer["mlp"] = mlp(k[9], c.intermediate_size)
+            elif c.scoring_func == "sigmoid":
+                layer["router_bias"] = jnp.zeros((c.num_routed_experts,),
+                                                 jnp.float32)
+            if c.index_topk is not None:
+                ki = jax.random.split(jax.random.fold_in(keys[i], 1), 3)
+                di = c.index_head_dim
+                layer.update(
+                    idx_q=lin(ki[0], c.q_lora_rank, c.index_n_heads * di),
+                    idx_k=lin(ki[1], d, di),
+                    idx_k_norm={"scale": jnp.ones((di,), dtype),
+                                "bias": jnp.zeros((di,), dtype)},
+                    idx_w=lin(ki[2], d, c.index_n_heads))
         return {"embed": lin(keys[-2], c.vocab_size, d),
                 "layers": layers, "final_norm": ones(d),
                 "head": lin(keys[-1], c.vocab_size, d)}
@@ -273,7 +379,11 @@ class MLAMoELM:
 
     def attn_in(self, params, i, x, positions):
         """-> (qt (S, H, C, d_c + d_r) scaled, (c (S, C, d_c), k_rope (S,
-        C, d_r)), None): the absorbed queries and the row to cache."""
+        C, d_r)), None): the absorbed queries and the row to cache; where
+        the model selects, the rows end with ``kI`` (S, C, Di) and the
+        third result is ``(qI (S, C, J, Di), h W_w (S, C, J) float32)``,
+        the indexer's queries and head weights (``J^(-1/2) Di^(-1/2)`` is
+        the scale their scores are given)."""
         c, lp = self.cfg, params["layers"][str(i)]
         s, n, _ = x.shape
         dn, dc = c.qk_nope_head_dim, c.kv_lora_rank
@@ -294,8 +404,25 @@ class MLAMoELM:
         qt = jnp.concatenate(
             [q_latent, self.rope(q[..., dn:], positions)], -1) \
             * self.query_scale(positions)[:, :, None, None]
+        if c.index_topk is None:
+            return (qt.astype(w_uk.dtype).transpose(0, 2, 1, 3),
+                    (latent, k_rope), None)
+        dr = c.qk_rope_head_dim
+
+        def partly_rotated(u):
+            return jnp.concatenate(
+                [self.rope(u[..., :dr], positions), u[..., dr:]], -1)
+
+        q_idx = partly_rotated(project(c_q, lp["idx_q"]["weight"]).reshape(
+            s, n, c.index_n_heads, c.index_head_dim))
+        k_idx = partly_rotated(_layer_norm(
+            project(a, lp["idx_k"]["weight"]), lp["idx_k_norm"],
+            c.rms_norm_eps))
+        w_idx = jnp.matmul(_f32(a), _f32(lp["idx_w"]["weight"]),
+                           precision=_HI)
         return (qt.astype(w_uk.dtype).transpose(0, 2, 1, 3),
-                (latent, k_rope), None)
+                (latent, k_rope, k_idx),
+                (q_idx.astype(w_uk.dtype), w_idx))
 
     def attn_out(self, params, i, x, att):
         """``att`` (S, C, H, d_c): each head's weighted sum of latents."""
@@ -312,6 +439,8 @@ class MLAMoELM:
         (ids (T, K) int32 of all the routed experts, weights (T, K)
         float32)."""
         c = self.cfg
+        if c.scoring_func == "sigmoid":
+            return self._route_in_groups(params["layers"][str(i)], flat)
         score = jax.nn.softmax(jnp.matmul(
             flat, _f32(params["layers"][str(i)]["router"]["weight"]),
             precision=_HI), axis=-1)
@@ -320,10 +449,36 @@ class MLAMoELM:
             top = top / top.sum(-1, keepdims=True)
         return ids.astype(jnp.int32), c.routed_scaling_factor * top
 
+    def _route_in_groups(self, lp, flat):
+        """The sigmoid rule: scores with the selection bias pick, inside
+        the ``topk_group`` groups whose two best add up highest; the
+        scores themselves weigh."""
+        c = self.cfg
+        t = flat.shape[0]
+        score = jax.nn.sigmoid(jnp.matmul(
+            flat, _f32(lp["router"]["weight"]), precision=_HI))
+        pick = score + lp["router_bias"]
+        if c.n_group > 1:
+            grouped = pick.reshape(t, c.n_group, -1)
+            best = jax.lax.top_k(grouped, 2)[0].sum(-1)         # (T, groups)
+            _, kept = jax.lax.top_k(best, c.topk_group)
+            allowed = jnp.zeros((t, c.n_group), bool).at[
+                jnp.arange(t)[:, None], kept].set(True)
+            pick = jnp.where(allowed[:, :, None], grouped,
+                             -jnp.inf).reshape(t, -1)
+        _, ids = jax.lax.top_k(pick, c.num_experts_per_tok)
+        top = jnp.take_along_axis(score, ids, axis=1)
+        if c.norm_topk_prob:
+            top = top / top.sum(-1, keepdims=True)
+        return ids.astype(jnp.int32), c.routed_scaling_factor * top
+
     def ffn(self, params, i, x, valid):
         c, lp = self.cfg, params["layers"][str(i)]
         s, n, d = x.shape
         b = rms_norm(x, lp["ffn_norm"]["scale"], c.rms_norm_eps)
+        if c.is_dense(i):
+            zero = jnp.zeros((), jnp.int32)
+            return x + _swiglu(b, lp["mlp"]), dict.fromkeys(_STATS, zero)
         flat = b.reshape(s * n, d)
         ids, coef = self.route(params, i, flat)
         ex = lp["experts"]
@@ -359,15 +514,31 @@ class MLAMoELM:
         t = jnp.arange(n)
         causal = t[None, :] <= t[:, None]
         for i in range(self.cfg.num_hidden_layers):
-            qt, (latent, k_rope), _ = self.attn_in(params, i, x, pos)
-            row = jnp.concatenate([latent, k_rope], -1)
+            qt, rows, index = self.attn_in(params, i, x, pos)
+            row = jnp.concatenate(rows[:2], -1)
             att = jnp.einsum("bhqd,bkd->bhqk", _f32(qt), row, precision=_HI)
-            att = jax.nn.softmax(jnp.where(causal, att, NEG_INF), axis=-1)
+            seen = causal
+            if index is not None:
+                seen = self._selected(index, rows[2], t + 1)[:, None] > 0
+            att = jax.nn.softmax(jnp.where(seen, att, NEG_INF), axis=-1)
             u = jnp.einsum("bhqk,bkl->bqhl", att, row[..., :dc],
                            precision=_HI)
             x = self.attn_out(params, i, x, u)
             x, _ = self.ffn(params, i, x, valid)
         return self.head(params, x)
+
+    def _selected(self, index, k_idx, n):
+        """(B, S, S) float32, 1 where query ``t`` attends to token ``s``:
+        the rule by sorting, over index scores made in float32."""
+        from paddle_tpu.serving.sparse_attention import selected_by_sort
+        q_idx, w_idx = index
+        dots = jnp.einsum("bqjd,bkd->bqjk", _f32(q_idx), _f32(k_idx),
+                          precision=_HI)
+        c = self.cfg
+        scores = (c.index_n_heads * c.index_head_dim) ** -0.5 * jnp.einsum(
+            "bqj,bqjk->bqk", w_idx, jnp.maximum(dots, 0.0), precision=_HI)
+        return selected_by_sort(scores, jnp.broadcast_to(n, scores.shape[:2]),
+                                c.index_topk)
 
     # -- the paged serving engine's view ------------------------------------
 
@@ -395,13 +566,16 @@ class MLAMoEServing:
         self.embed, self.attn_in = model.embed, model.attn_in
         self.attn_out, self.ffn, self.head = (model.attn_out, model.ffn,
                                               model.head)
+        selects = {} if c.index_topk is None else dict(
+            extra_rows=(("index_k", c.index_head_dim),),
+            select_topk=c.index_topk)
         self.spec = ServingSpec(
             num_layers=c.num_hidden_layers,
             num_heads=c.num_attention_heads, kv_heads=1,
             head_dim=c.row_dim, vocab_size=c.vocab_size,
             max_position=c.max_position_embeddings, stats=_STATS,
             latent_row=(c.kv_lora_rank, c.qk_rope_head_dim),
-            supports=frozenset({"prefix_sharing"}))
+            supports=frozenset({"prefix_sharing"}), **selects)
 
     def param_dtype(self, params):
         return params["embed"]["weight"].dtype

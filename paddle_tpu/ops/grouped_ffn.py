@@ -68,7 +68,9 @@ def _block_f(f: int, d: int, itemsize: int) -> int:
     for tf in _BLOCK_F:
         if f % tf == 0 and 2 * 3 * tf * d * itemsize <= _WEIGHT_VMEM_BUDGET:
             return tf
-    return f
+    # none fits (rows of 7168: 128 of them are 11 MB): the least that
+    # divides, never the whole expert (88 MB a matrix at those widths)
+    return min((tf for tf in _BLOCK_F if f % tf == 0), default=f)
 
 
 def route_tiles(expert_ids, valid, n_experts: int, tm: int,

@@ -459,6 +459,8 @@ class ServingEngine:
         #: pages (pool row slot + 1): whose state row, whose ring
         self._lane_slot_column = bool(spec.slot_state) or any(
             kind.by_slot for kind in self._kinds)
+        #: the step programs take a slot's whole table and no narrower one
+        self._whole_table = any(kind.whole_table for kind in self._kinds)
         #: consecutive chunks of ONE slot a prefill call may carry: the
         #: least over what the program is built from. Its layers' kinds
         #: say theirs; a program that carries state from chunk to chunk
@@ -2265,11 +2267,16 @@ class ServingEngine:
         """Pow2 page count covering ``need`` pages — the gathers (and
         the Pallas grids) then scale with the LIVE high-water mark, not
         full slot capacity, while the set of compiled shapes stays
-        log-sized; :meth:`warmup` precompiles them all."""
+        log-sized; :meth:`warmup` precompiles them all. ONE width, the
+        slot's whole table, where a layer's kind says so
+        (``layer_kinds.Paged.whole_table``)."""
+        widest = self.cache.config.max_pages_per_slot
+        if self._whole_table:
+            return widest
         w = 1
         while w < need:
             w *= 2
-        return min(w, self.cache.config.max_pages_per_slot)
+        return min(w, widest)
 
     def _pow2_count(self, need: int) -> int:
         """The lane bucket of a prefill call with ``need`` live lanes: a
@@ -2305,7 +2312,8 @@ class ServingEngine:
                 out.append(n)
                 n = n * 2 if step is None or n < step else n + step
             return out + [min(n, limit)]
-        widths = covering(c.max_pages_per_slot, c.max_pages_per_slot)
+        widths = [c.max_pages_per_slot] if self._whole_table \
+            else covering(c.max_pages_per_slot, c.max_pages_per_slot)
         # up to the lanes the budget buys a call: more are never asked for
         counts = covering(self._lane_cap, self.scheduler.num_slots,
                           _LANE_STEP)

@@ -25,6 +25,9 @@ The kinds, and what selects each:
 :class:`Selecting`  K and V plus index rows, each query attending to its
                     ``select_topk`` best tokens once it sees more
                     (``spec.select_topk``, ``spec.extra_rows``)
+:class:`SelectingLatent`  a latent row plus an index row a token, each
+                    query's heads reading the ``select_topk`` latent rows
+                    it selects (``spec.latent_row`` with the two above)
 ==================  =====================================================
 
 A kind's **geometry** is its own: :func:`build` hands each the KV heads of
@@ -291,6 +294,10 @@ class Paged:
     #: consecutive chunks of one slot a prefill call may carry as lanes of
     #: their own; None: as many as the call has lanes
     prefill_run: Optional[int] = None
+    #: its step programs are compiled at ONE table width, a slot's whole
+    #: table, and not at every power of two below it (what a narrow table
+    #: saves its calls is not worth a compile of both programs a width)
+    whole_table = False
     stat_names: Tuple[str, ...] = ()    # counts its steps add on the device
     groups = None           # a :class:`_Groups` where its decode folds
     _c_resident = None      # bound where the program's pool is split by kind
@@ -901,6 +908,125 @@ class Selecting(Paged):
             self._c_held.inc(live * self.layers)
 
 
+class SelectingLatent(Latent):
+    """A latent row a token that every head reads AND an index row beside
+    it, each query attending to the ``topk`` cached tokens its index
+    scores best (DeepSeek-V3.2's selection over multi-head latent
+    attention). The entry is three pools under one page id, allocated,
+    published, borrowed, copied on write and freed together: ``(c_pages
+    (num_pages, page_size, latent_dim), r_pages (num_pages, page_size,
+    rope_lanes), ik_pages (num_pages, index_dim, page_size))``. The
+    latent and the rotary key are BOTH token-major, because the attention
+    gathers the selected tokens' rows out of them and folds only those
+    (``sparse_attention.sparse_latent_decode`` / ``_prefill``: a row read
+    by 128 heads costs as much under a mask as selected), and the rotary
+    key lies in the first lanes of a row of whole lane tiles
+    (``rope_lanes``: 128 for a key of 64; a token-major pool of 64 lanes
+    the chip's compiler keeps page-minor and re-lays whole before every
+    gather); the index keys lie tokens along the lanes, as
+    :class:`Selecting`'s. One path for every bucket: a table of at most
+    ``topk`` tokens selects all a query sees, through the same kernel.
+    No group of slots is folded: what a document's slots share is the
+    pages, each gathers rows of its own, and the indexer's walk of a
+    slot's keys waits for its products, not for their bytes."""
+
+    stat_names = Selecting.stat_names
+    #: a call attends to ``topk`` rows whatever the table's width and the
+    #: indexer skips the pages past a lane's tokens: a narrow table saves
+    #: the selection's pass over the scores and nothing else, a few
+    #: microseconds a query row (PERF.md section 6, PR 55)
+    whole_table = True
+
+    def __init__(self, geo, layers, latent_row, extra_rows, topk: int):
+        super().__init__(geo, layers, latent_row)
+        latent, rope = latent_row
+        (_name, index_dim), = extra_rows
+        self.topk = topk
+        self.groups = None          # (Latent's are its decode kernel's)
+        self.pools = (
+            ((geo.num_pages, geo.page_size, latent), geo.dtype, ()),
+            ((geo.num_pages, geo.page_size, rope + -rope % 128), geo.dtype,
+             ()),
+            ((geo.num_pages, index_dim, geo.page_size), geo.dtype, ()))
+        #: what a token caches a layer, the rotary key's padding counted
+        self.token_bytes = self.row_bytes // geo.page_size
+
+    _selects = Selecting._selects
+
+    def write(self, ent, rows, place):
+        c, k_rope, k_idx = rows
+        pad = ent[1].shape[-1] - k_rope.shape[-1]
+        k_rope = jnp.pad(k_rope, ((0, 0),) * (k_rope.ndim - 1) + ((0, pad),))
+        return _write_rows(ent, (c, k_rope, k_idx), place, 2)
+
+    def attend_decode(self, q, ent, place, index, groups):
+        return SA.latent_indexed_decode_attention(
+            q, *ent, place[2], place[3] + 1, index[0][:, 0], index[1][:, 0],
+            self.topk, impl=self.geo.impl)
+
+    def attend_prefill(self, q, ent, place, n_valid, index):
+        return SA.latent_indexed_prefill_attention(
+            q, *ent, place[2], place[3], n_valid, index[0], index[1],
+            self.topk, impl=self.geo.impl)
+
+    attends_prefill = Selecting.attends_prefill
+    step_counts = Selecting.step_counts
+
+    def bind(self, reg):
+        super().bind(reg)
+        held = reg.counter(
+            "serving_latent_rows_held_total",
+            "live cached latent rows x layers of the queries attended for, "
+            "a query at a time: a decode token step every live row of "
+            "every decoding slot, a prefill call what each chunk token "
+            "sees (a selecting latent program only: what its kernels "
+            "would read without the selection)")
+        self._c_held = {ph: held.child(phase=ph)
+                        for ph in ("decode", "prefill")}
+        self._c_index = tuple(reg.counter(name, text).child() for name, text
+                              in (
+            ("serving_index_rows_scored_total",
+             "cached index-key rows x layers the indexer HAD to score, a "
+             "call of a bucket that selects: a decode token step every "
+             "live row of every decoding slot (each slot's query scores "
+             "them all), a prefill call each lane's context and chunk"),
+            ("serving_index_rows_fetched_total",
+             "cached index-key rows x layers the indexer's walks copied: "
+             "a slot or lane at a time, so what it has to score (a walk "
+             "that read a document's pages once for its slots would copy "
+             "fewer)")))
+
+    def _count_index(self, rows: int):
+        for child in self._c_index:
+            child.inc(rows * self.layers)
+
+    def _count_own(self, span, block_tables, lengths, dslots, live, n,
+                   width):
+        # token step j of a slot holding L tokens reads, scores and
+        # fetches min(L + j + 1, topk) rows, each gathered for that slot
+        lens = lengths[dslots]
+        rows = int(sum(np.minimum(lens + j + 1, self.topk).sum()
+                       for j in range(n)))
+        self._count(span, "decode", rows, rows, rows)
+        self._c_held["decode"].inc(live * self.layers)
+        if self._selects(width):
+            self._count_index(live)
+
+    def count_prefill(self, span, starts, ns, heads=None):
+        # chunk token j of a lane sees start + j + 1 rows and reads the
+        # topk it selects of them
+        starts, ns = (np.asarray(a, np.int64) for a in (starts, ns))
+        j = np.arange(int(ns.max()) if len(ns) else 0)
+        rows = int(np.where(j[None, :] < ns[:, None], np.minimum(
+            starts[:, None] + j[None, :] + 1, self.topk), 0).sum())
+        self._count(span, "prefill", rows, rows, rows)
+        self._c_held["prefill"].inc(
+            int((starts * ns + ns * (ns + 1) // 2).sum()) * self.layers)
+        if len(starts) and self._selects(
+                -(-int((starts + ns).max()) // self.geo.page_size)):
+            self._count_index(int((starts + ns)[ns > 0].sum()))
+
+
 # -- the one decision --------------------------------------------------------
 
 def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
@@ -956,7 +1082,7 @@ def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
     if spec.latent_row is not None and int8:
         raise ValueError(
             "a pool of latent rows is not quantized and carries no "
-            "extra rows, slot state or window layers yet")
+            "slot state or window layers yet")
     if ringed:
         if int8 or share_prefix:
             raise ValueError(
@@ -990,6 +1116,9 @@ def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
     def kind(window, heads, sink, layers):
         if window is not None:
             return Ring(geo(heads), layers, window, sink, prefill_room)
+        if spec.latent_row is not None and spec.select_topk is not None:
+            return SelectingLatent(geo(heads), layers, spec.latent_row,
+                                   spec.extra_rows, spec.select_topk)
         if spec.latent_row is not None:
             return Latent(geo(heads), layers, spec.latent_row)
         if int8:

@@ -27,6 +27,10 @@ object with a :class:`ServingSpec` as ``spec`` and the hooks below.
 ``select_topk``,    each query attends to its ``select_topk`` best tokens
 ``extra_rows``      once it sees more, scored against the index rows
                     cached beside K and V: ``Selecting``
+``latent_row`` with the selection over the latent rows, one index row a
+both of those       token cached beside them: ``SelectingLatent``. ``rows
+                    = (c, k_rope, k_index)`` and ``index`` as a selecting
+                    program's
 ==================  ======================================================
 
 A layer's **geometry** is its own too. Three fields say where a layer's
@@ -170,14 +174,21 @@ class ServingSpec:
                     f"latent_row={self.latent_row!r}: one row a token as "
                     "wide as a query (kv_heads 1, head_dim latent_dim + "
                     "rope_dim)")
-            mixed = [name for name in ("extra_rows", "select_topk",
-                                       "slot_state", "layer_windows",
+            mixed = [name for name in ("slot_state", "layer_windows",
                                        "layer_kv_heads", "value_dim",
                                        "sink_layers")
                      if getattr(self, name)]
             if mixed:
-                raise ValueError(f"a latent row is cached alone, without "
+                raise ValueError(f"a latent row is cached alone or beside "
+                                 f"the index rows of a selection, without "
                                  f"{mixed}")
+            if bool(self.extra_rows) != (self.select_topk is not None) \
+                    or len(self.extra_rows) > 1:
+                raise ValueError(
+                    f"latent_row with extra_rows={self.extra_rows!r}, "
+                    f"select_topk={self.select_topk!r}: beside a latent "
+                    "row goes ONE index row a token and the selection "
+                    "that reads it, both or neither")
         if self.layer_windows and all(w is None for w in self.layer_windows):
             object.__setattr__(self, "layer_windows", ())    # all full
         if self.layer_windows and (

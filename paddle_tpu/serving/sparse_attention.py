@@ -82,15 +82,34 @@ def _dot_precision(dtype):
 # lightning indexer
 # ---------------------------------------------------------------------------
 
-def _index_weights(w_idx, scale):
+def _index_weights(w_idx, scale, block=None):
     """``(S, C, J)`` head weights -> ``(S, C, C*J)`` block-diagonal rows:
     row ``c`` holds ``scale * w[c, :]`` at columns ``c*J .. (c+1)*J``, so
     the weighted head sum of every query is ONE matmul against the
-    ``(C*J, page_size)`` relu'd products."""
+    ``(C*J, page_size)`` relu'd products. ``block``: the same for every
+    ``block`` queries of their own, ``(S, C, block*J)``, row ``c``'s
+    weights at columns ``(c % block)*J ..``."""
     s, c, j = w_idx.shape
-    eye = jnp.eye(c, dtype=jnp.float32)
     w = w_idx.astype(jnp.float32) * scale
-    return (eye[None, :, :, None] * w[:, None, :, :]).reshape(s, c, c * j)
+    if block is None:
+        eye = jnp.eye(c, dtype=jnp.float32)
+        return (eye[None, :, :, None] * w[:, None, :, :]).reshape(
+            s, c, c * j)
+    eye = jnp.eye(block, dtype=jnp.float32)
+    return (eye[None, None, :, :, None]
+            * w.reshape(s, c // block, 1, block, j)).reshape(
+                s, c, block * j)
+
+
+#: rows of one product of the indexer, ``C * J``: a chunk with more sums
+#: its heads ``_QUERY_BLOCK`` queries at a time (256 queries of 64 heads
+#: against ONE block-diagonal would be a 16.8 MB float32 operand a lane
+#: and 256 times the useful products)
+_ONE_PRODUCT_ROWS = 4096
+_QUERY_BLOCK = 8
+#: rows of a product up to which a block's key pages are joined into one
+#: (the rows of the matrix unit)
+_JOINED_ROWS = 128
 
 
 def _indexer_lax(q_idx, w_idx, ik_pages, block_tables, extent, scale):
@@ -110,16 +129,62 @@ def _indexer_lax(q_idx, w_idx, ik_pages, block_tables, extent, scale):
 
 
 def _indexer_kernel(bt_ref, ext_ref, q_ref, w_ref, *refs, page_size,
-                    pages_per_block):
+                    pages_per_block, query_block=None, joined=False):
     pb = pages_per_block
     k_refs, o_ref = refs[:pb], refs[pb]
     sl, pj = pl.program_id(0), pl.program_id(1)
     extent = ext_ref[sl]
     rows = o_ref.shape[1]
 
+    def scores(q, w, keys):
+        dots = jax.lax.dot_general(
+            q, keys, (((1,), (0,)), ((), ())),
+            precision=_dot_precision(q.dtype),
+            preferred_element_type=jnp.float32)
+        return jax.lax.dot_general(
+            w, jnp.maximum(dots, 0.0), (((1,), (0,)), ((), ())),
+            precision=_FP32_DOT, preferred_element_type=jnp.float32)
+
+    def blocked():
+        """``query_block`` queries at a time: their heads' products with a
+        page, the head sum against their own small block-diagonal."""
+        qb = query_block
+        hj = w_ref.shape[2]                           # query_block * J
+        for t in range(pb):
+            keys = k_refs[t][0]
+
+            def some(b, _, t=t, keys=keys):
+                at = pl.multiple_of(b * qb, qb)
+                sc = scores(q_ref[0, pl.ds(pl.multiple_of(b * hj, hj), hj)],
+                            w_ref[0, pl.ds(at, qb)], keys)
+                tok = (pj * pb + t) * page_size \
+                    + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+                o_ref[0, pl.ds(at, qb),
+                      t * page_size:(t + 1) * page_size] = jnp.where(
+                          tok < extent, sc, 0.0)
+
+            jax.lax.fori_loop(0, rows // qb, some, None)
+
     @pl.when(pj * pb * page_size >= extent)
     def _dead():
         o_ref[...] = jnp.zeros_like(o_ref)
+
+    def one_product():
+        """The block's key pages side by side, ONE product for all of
+        them: a decode step's few rows (64 heads of one query) leave the
+        matrix unit idle through a product a page, and it is the count of
+        products, not the bytes, that the walk then waits for (4.4 ms a
+        layer of 64 slots x 261 pages at 9 pages a step; PR 55)."""
+        keys = jnp.concatenate([k_refs[t][0] for t in range(pb)], axis=1)
+        sc = scores(q_ref[0], w_ref[0], keys)
+        tok = pj * pb * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, sc.shape, 1)
+        o_ref[0] = jnp.where(tok < extent, sc, 0.0)
+
+    if query_block is not None or joined:
+        pl.when(pj * pb * page_size < extent)(
+            one_product if joined else blocked)
+        return
 
     @pl.when(pj * pb * page_size < extent)
     def _live():
@@ -146,10 +211,21 @@ def _indexer_pallas(q_idx, w_idx, ik_pages, block_tables, extent, scale,
     mp = block_tables.shape[1]
     ps = ik_pages.shape[-1]
     pb = max(1, min(int(pages_per_block), mp))
-    while mp % pb:                      # whole blocks only: widths are pow2
-        pb -= 1
+    # whole blocks only: a width that is no multiple (the engine's widest
+    # bucket, a slot's own page count) is padded with the null page, whose
+    # tokens lie past every extent
+    width = mp
+    block_tables = jnp.pad(block_tables, ((0, 0), (0, -mp % pb)))
+    mp = block_tables.shape[1]
     q2 = q_idx.reshape(s, c * j, di)
-    wmat = _index_weights(w_idx, scale)
+    qb = _QUERY_BLOCK if c * j > _ONE_PRODUCT_ROWS and c % _QUERY_BLOCK == 0 \
+        else None
+    wmat = _index_weights(w_idx, scale, qb)
+    # few rows against key pages that are whole lane tiles: the pages of
+    # a block joined into one product (a chunk's many rows fill the
+    # matrix unit a page at a time as they are)
+    joined = qb is None and pb > 1 and c * j <= _JOINED_ROWS \
+        and di % 128 == 0 and ps % 128 == 0
 
     def k_spec(t):
         def index(si, pj, bt, _ext):
@@ -163,22 +239,25 @@ def _indexer_pallas(q_idx, w_idx, ik_pages, block_tables, extent, scale,
         num_scalar_prefetch=2,
         grid=(s, mp // pb),
         in_specs=[pl.BlockSpec((1, c * j, di), row_index),
-                  pl.BlockSpec((1, c, c * j), row_index),
+                  pl.BlockSpec((1, c, wmat.shape[2]), row_index),
                   *[k_spec(t) for t in range(pb)]],
         out_specs=pl.BlockSpec((1, c, pb * ps),
                                lambda si, pj, *_prefetch: (si, 0, pj)),
     )
-    kernel = functools.partial(_indexer_kernel, page_size=ps,
-                               pages_per_block=pb)
+    kernel = functools.partial(
+        _indexer_kernel, page_size=ps, pages_per_block=pb,
+        **({} if qb is None else {"query_block": qb}),
+        **({"joined": True} if joined else {}))
+    limit = {} if qb is None else {"vmem_limit_bytes": DA._WIDE_VMEM_LIMIT}
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, c, mp * ps), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"), **limit,
         ) if not interpret else None,
         interpret=interpret, name="lightning_indexer",
     )(block_tables.astype(jnp.int32), extent.astype(jnp.int32), q2, wmat,
-      *([ik_pages] * pb))
+      *([ik_pages] * pb))[:, :, :width * ps]
 
 
 def lightning_index_scores(q_idx, w_idx, ik_pages, block_tables, extent, *,
@@ -257,9 +336,13 @@ def _indexer_vmem_estimate(args, kwargs, blocks):
     pad = lambda n, m: -(-n // m) * m                       # noqa: E731
     keys = pb * pad(di, 16) * pad(ps, 128) * ik.dtype.itemsize
     out = pad(c, 8) * pad(pb * ps, 128) * 4
+    # the heads' products of one block-diagonal: all the chunk's, or
+    # ``_QUERY_BLOCK`` queries' where the chunk is summed in blocks
+    rows = c * j if c * j <= _ONE_PRODUCT_ROWS or c % _QUERY_BLOCK \
+        else _QUERY_BLOCK * j
     qw = pad(c * j, 16) * pad(di, 128) * q.dtype.itemsize \
-        + pad(c, 8) * pad(c * j, 128) * 4
-    temps = 2 * pad(c * j, 8) * pad(ps, 128) * 4
+        + pad(c, 8) * pad(rows, 128) * 4
+    temps = 2 * pad(rows, 8) * pad(ps, 128) * 4
     return 2 * (keys + out + qw) + temps
 
 
@@ -897,7 +980,17 @@ def sparse_paged_decode_attention(q, k_pages, v_pages, block_tables,
     for whoever holds a selection as indices (the engine's own path hands
     the kernel a mask: :func:`indexed_decode_attention`). ``sel_idx`` (S,
     topk) cache positions, the first ``n_sel[s]`` live. ``groups`` as
-    :func:`selected_decode_attention` takes them. Returns (S, H, Dh)."""
+    :func:`selected_decode_attention` takes them. Returns (S, H, Dh).
+
+    A LATENT entry's first two pools (the latent and the rotary key, both
+    token-major: ``layer_kinds.SelectingLatent``) are taken here too, and
+    told from K and V by one rule: the queries are wider than the first
+    pool's rows (``Dl + Dr > Dl``; a K pool's ``G x Dh`` lanes are never
+    narrower than a query head). They go to ``sparse_latent_decode``,
+    ``q`` the absorbed queries already scaled; returns (S, H, Dl)."""
+    if q.shape[-1] > k_pages.shape[-1]:
+        return sparse_latent_decode_attention(
+            q, k_pages, v_pages, block_tables, sel_idx, n_sel, impl=impl)
     t = block_tables.shape[1] * k_pages.shape[1]
     live = jnp.arange(sel_idx.shape[1])[None, :] < n_sel[:, None]
     slot = jnp.arange(sel_idx.shape[0])[:, None]
@@ -1021,6 +1114,259 @@ def sparse_paged_prefill_attention(q, k_pages, v_pages, block_tables,
 
 
 # ---------------------------------------------------------------------------
+# selection over a latent cache: gather the selected rows, fold only those
+# ---------------------------------------------------------------------------
+#
+# A latent row is read by EVERY head (128 of them at the published widths),
+# so a row folded under a mask costs as much as a selected one: walking
+# whole pages as ``sparse_paged_decode`` does would push 33k rows through
+# 128 heads to keep 2048 (16 times the products; PERF.md section 6, PR 55).
+# Here the selection is a list of cache positions (``select_decode``: the
+# rule by ``lax.top_k``), the selected rows of the latent pool and of the
+# rotary-key pool, BOTH token-major, are gathered into ``(rows, topk, .)``
+# (XLA's gather: a row of 1 KB and one of 256 bytes a token; the rotary
+# pool's rows are whole lane tiles, the key in their first ``Dr`` lanes: a
+# token-major pool of 64 lanes the chip's compiler keeps page-minor and
+# re-lays whole, 40 MB a layer, before every gather), and the
+# Pallas body folds one query row's ``topk`` gathered rows against all its
+# heads in one grid step: scores ``(H, topk)``, one softmax, one ``P C``.
+# Decode hands it one query a slot, chunked prefill one a chunk token (each
+# has a selection of its own), ``q_rows`` rows a call so that the gathered
+# copy stays small; a block of rows none of which is live is skipped.
+
+#: query rows gathered and folded a call (a row's copy is ``topk`` x the
+#: cache row: 2.4 MB at the published widths)
+_LATENT_ROWS_A_CALL = 64
+
+
+def _gather_selected(c_pages, r_pages, block_tables, sel_idx):
+    """``sel_idx`` (R, K) cache positions under ``block_tables`` (R, mp)
+    -> the rows ``(R, K, Dl)``, ``(R, K, Dr)`` out of the two token-major
+    pools. A position past the table reads its last row (dead entries:
+    the fold masks them)."""
+    ps = c_pages.shape[1]
+    idx = jnp.clip(sel_idx, 0, block_tables.shape[1] * ps - 1)
+    flat = jnp.take_along_axis(block_tables, idx // ps, axis=1) * ps \
+        + idx % ps
+    return (c_pages.reshape(-1, c_pages.shape[-1])[flat],
+            r_pages.reshape(-1, r_pages.shape[-1])[flat])
+
+
+def _sparse_latent_kernel(n_ref, qc_ref, qr_ref, c_ref, r_ref, o_ref):
+    """One query row: ``qc_ref`` (1, H, Dl) / ``qr_ref`` (1, H, Dr) its
+    heads' absorbed queries, already scaled; ``c_ref`` (1, K, Dl) /
+    ``r_ref`` (1, K, Dr) its gathered rows, the first ``n_ref[row]`` of
+    them live; ``o_ref`` (1, H, Dl) each head's weighted sum of latents.
+    Scores, softmax and sums float32; the weights meet the latents in the
+    pool's type (one bf16 pass; a float32 pool at ``HIGHEST``)."""
+    n = n_ref[pl.program_id(0)]
+    c, r = c_ref[0], r_ref[0]
+    s = DA._pool_dot(qc_ref[0], c, 1) + DA._pool_dot(qr_ref[0], r, 1)
+    live = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < n
+    s = jnp.where(live, s, DA.NEG_INF)
+    m = jnp.max(s, axis=1, keepdims=True)
+    p = jnp.where(live, jnp.exp(s - m), 0.0)
+    denom = jnp.sum(p, axis=1, keepdims=True)
+    o = DA._pool_dot(p, c, 0) / jnp.where(denom == 0.0, 1.0, denom)
+    o_ref[0] = o.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _sparse_latent_fold(q, cg, rg, n_sel, interpret, name):
+    """``q`` (R, H, Dl + Dr) over the gathered rows ``cg`` (R, K, Dl) /
+    ``rg`` (R, K, Dr) -> (R, H, Dl): grid ``(R,)``, a row's gathered rows
+    one block. Jitted, as the sparse decode's call is."""
+    r, h, _ = q.shape
+    k, dl = cg.shape[1:]
+    dr = rg.shape[-1]
+
+    def row(*shape):
+        return pl.BlockSpec((1,) + shape, lambda i, _n: (i, 0, 0))
+
+    return pl.pallas_call(
+        _sparse_latent_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(r,),
+            in_specs=[row(h, dl), row(h, dr), row(k, dl), row(k, dr)],
+            out_specs=row(h, dl)),
+        out_shape=jax.ShapeDtypeStruct((r, h, dl), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=DA._WIDE_VMEM_LIMIT) if not interpret else None,
+        interpret=interpret, name=name,
+    )(n_sel.astype(jnp.int32), q[..., :dl],
+      jnp.pad(q[..., dl:], ((0, 0), (0, 0), (0, dr - (q.shape[-1] - dl)))),
+      cg, rg)
+
+
+def _fold_lax(q, cg, rg, n_sel):
+    dl = cg.shape[-1]
+    qf, cf = q.astype(jnp.float32), cg.astype(jnp.float32)
+    s = jnp.einsum("rhd,rkd->rhk", qf[..., :dl], cf, precision=_FP32_DOT) \
+        + jnp.einsum("rhd,rkd->rhk", qf[..., dl:],
+                     rg[..., :q.shape[-1] - dl].astype(jnp.float32),
+                     precision=_FP32_DOT)
+    live = jnp.arange(cg.shape[1])[None, None, :] < n_sel[:, None, None]
+    return jnp.einsum("rhk,rkd->rhd", DA._latent_softmax(s, live), cf,
+                      precision=_FP32_DOT).astype(q.dtype)
+
+
+def _sparse_latent_rows(q, c_pages, r_pages, tables, sel_idx, n_sel, fold,
+                        rows_a_call):
+    """``q`` (R, H, D) query rows, each with its table row, selection and
+    live count -> (R, H, Dl): ``rows_a_call`` rows gathered and folded at
+    a time, a block with no live row skipped. (Decode's form as it is:
+    a slot is a query row.)"""
+    r = q.shape[0]
+    blk = min(rows_a_call, r)
+    dl = c_pages.shape[-1]
+
+    def attend(qb, tb, ib, nb):
+        return fold(qb, *_gather_selected(c_pages, r_pages, tb, ib), nb)
+
+    if r <= blk:
+        return attend(q, tables, sel_idx, n_sel)
+    pad = -r % blk
+    blocks = tuple(
+        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
+            (-1, blk) + a.shape[1:]) for a in (q, tables, sel_idx, n_sel))
+    out = jax.lax.map(lambda b: jax.lax.cond(
+        jnp.any(b[3] > 0), lambda: attend(*b),
+        lambda: jnp.zeros(b[0].shape[:2] + (dl,), q.dtype)), blocks)
+    return out.reshape((-1,) + q.shape[1:-1] + (dl,))[:r]
+
+
+def _sparse_latent_prefill(q, c_pages, r_pages, block_tables, sel_idx, n_sel,
+                           fold, rows_a_call):
+    s, c = q.shape[:2]
+    tables = jnp.repeat(block_tables, c, axis=0)
+    out = _sparse_latent_rows(
+        q.reshape((s * c,) + q.shape[2:]), c_pages, r_pages, tables,
+        sel_idx.reshape(s * c, -1), n_sel.reshape(s * c), fold, rows_a_call)
+    return out.reshape((s, c) + out.shape[1:])
+
+
+def _sparse_latent_pallas(form, name):
+    def run(q, c_pages, r_pages, block_tables, sel_idx, n_sel, *,
+            block_sizes, interpret):
+        def fold(qb, cg, rg, nb):
+            return _sparse_latent_fold(qb, cg, rg, nb, interpret, name)
+        return form(q, c_pages, r_pages, block_tables, sel_idx, n_sel, fold,
+                    block_sizes.get("q_rows", _LATENT_ROWS_A_CALL))
+    return run
+
+
+def _sparse_latent_lax(form):
+    def run(q, c_pages, r_pages, block_tables, sel_idx, n_sel):
+        return form(q, c_pages, r_pages, block_tables, sel_idx, n_sel,
+                    _fold_lax, _LATENT_ROWS_A_CALL)
+    return run
+
+
+def _sparse_latent_reference(q, c_pages, r_pages, block_tables, sel_idx,
+                             n_sel):
+    """NumPy, a query row at a time over its live selected positions."""
+    import numpy as np
+    qn = np.asarray(q, np.float64)
+    chunked = qn.ndim == 4
+    if not chunked:
+        qn = qn[:, None]
+    s, c, h, _ = qn.shape
+    cp, rp = np.asarray(c_pages, np.float64), np.asarray(r_pages, np.float64)
+    ps, dl = cp.shape[1:]
+    bt = np.asarray(block_tables)
+    idx = np.asarray(sel_idx).reshape(s, c, -1)
+    n = np.asarray(n_sel).reshape(s, c)
+    out = np.zeros((s, c, h, dl))
+    for sl in range(s):
+        for t in range(c):
+            toks = idx[sl, t, :n[sl, t]]
+            if not len(toks):
+                continue
+            rows = np.concatenate(
+                [cp[bt[sl, toks // ps], toks % ps],
+                 rp[bt[sl, toks // ps], toks % ps][:, :qn.shape[-1] - dl]],
+                1)
+            sc = qn[sl, t] @ rows.T
+            pr = np.exp(sc - sc.max(-1, keepdims=True))
+            out[sl, t] = (pr / pr.sum(-1, keepdims=True)) @ rows[:, :dl]
+    out = out if chunked else out[:, 0]
+    return jnp.asarray(out).astype(q.dtype)
+
+
+def _make_sparse_latent_sample(seed, *, chunked):
+    """Three shapes by ``seed % 3``: float32 pools of pages scattered over
+    the pool; selections of distinct live positions in no order, rows that
+    select nothing, fewer than ``K`` and all ``K``; more rows than one
+    call folds, so that the blocks and the skipped block are driven."""
+    import numpy as np
+    s, c, h, dl, dr, ps, mp, k = (
+        ((5, 1, 2, 16, 8, 8, 6, 8), (70, 1, 4, 32, 8, 8, 4, 16),
+         (9, 1, 4, 64, 16, 16, 5, 24)) if not chunked else
+        ((3, 4, 2, 16, 8, 8, 6, 8), (9, 8, 4, 32, 8, 8, 4, 16),
+         (2, 8, 4, 64, 16, 16, 5, 24)))[seed % 3]
+    rng = np.random.default_rng(seed)
+    num_pages = s * mp + 1
+    scale = (dl + dr) ** -0.5
+    q = jnp.asarray(scale * rng.standard_normal((s, c, h, dl + dr)),
+                    jnp.float32)
+    c_pages = jnp.asarray(rng.standard_normal((num_pages, ps, dl)),
+                          jnp.float32)
+    # the rotary keys lie in rows of whole lane tiles (seed 2: as wide)
+    r_pages = jnp.asarray(rng.standard_normal(
+        (num_pages, ps, dr if seed % 3 == 2 else 2 * dr)), jnp.float32)
+    bt = jnp.asarray((rng.permutation(num_pages - 1)[:s * mp] + 1)
+                     .reshape(s, mp), jnp.int32)
+    idx = np.stack([rng.permutation(mp * ps)[:k] for _ in range(s * c)]
+                   ).reshape(s, c, k).astype(np.int32)
+    n = rng.integers(0, k + 1, (s, c)).astype(np.int32)
+    n.reshape(-1)[:3] = (0, k, 1)
+    if chunked and seed % 3 == 1:
+        n[2:] = 0                       # whole blocks of rows with none
+    if not chunked:
+        q, idx, n = q[:, 0], idx[:, 0], n[:, 0]
+    return (q, c_pages, r_pages, bt, jnp.asarray(idx), jnp.asarray(n)), {}
+
+
+def _sparse_latent_vmem_estimate(args, kwargs, blocks):
+    """One grid step: a row's gathered latents and rotary keys and its
+    queries and output double-buffered by the pipeline, the float32
+    scores and weights, the weights in the pool's type."""
+    q, c_pages, r_pages, _bt, sel_idx = args[:5]
+    h, k = q.shape[-2], sel_idx.shape[-1]
+    dl, dr = c_pages.shape[-1], r_pages.shape[-1]
+    isz = c_pages.dtype.itemsize
+    pad = lambda n, m: -(-n // m) * m                       # noqa: E731
+    rows = pad(k, 16) * (pad(dl, 128) + pad(dr, 128)) * isz
+    heads = pad(h, 16) * (2 * pad(dl, 128) + pad(dr, 128)) * isz
+    scores = pad(h, 8) * pad(k, 128) * (4 + 4 + isz)
+    return 2 * (rows + heads) + scores
+
+
+def sparse_latent_decode_attention(q, c_pages, r_pages, block_tables,
+                                   sel_idx, n_sel, *, impl: str = "auto"):
+    """One decode step of latent attention over each slot's SELECTED
+    rows: ``q`` (S, H, Dl + Dr) absorbed queries, already scaled,
+    ``c_pages`` (P, ps, Dl) and ``r_pages`` (P, ps, >= Dr, the key in the
+    first ``Dr`` lanes of a row) the token-major latent and rotary-key
+    pools, ``sel_idx`` (S, K) cache positions of
+    which the first ``n_sel[s]`` are live. Returns (S, H, Dl)."""
+    from paddle_tpu import kernels
+    return kernels.dispatch("sparse_latent_decode", q, c_pages, r_pages,
+                            block_tables, sel_idx, n_sel, impl=impl)
+
+
+def sparse_latent_prefill_attention(q, c_pages, r_pages, block_tables,
+                                    sel_idx, n_sel, *, impl: str = "auto"):
+    """The same for a chunk of queries a lane, each with a selection of
+    its own: ``q`` (S, C, H, Dl + Dr), ``sel_idx`` (S, C, K), ``n_sel``
+    (S, C), 0 for a pad token. Returns (S, C, H, Dl)."""
+    from paddle_tpu import kernels
+    return kernels.dispatch("sparse_latent_prefill", q, c_pages, r_pages,
+                            block_tables, sel_idx, n_sel, impl=impl)
+
+
+# ---------------------------------------------------------------------------
 # what the engine calls
 # ---------------------------------------------------------------------------
 
@@ -1065,6 +1411,125 @@ def indexed_prefill_attention(q, k_pages, v_pages, ik_pages, block_tables,
     return sparse_paged_prefill_attention(
         q, k_pages, v_pages, block_tables, chunk_starts, n_valid, selected,
         impl=impl)
+
+
+def _mask_positions(mask, topk):
+    """The positions a selection mask marks, in order: ``mask`` (R, T)
+    float32 of at most ``topk`` ones a row -> (R, topk) int32, slot ``k``
+    the position of the row's ``k``-th one; a slot past the row's count
+    reads past ``T``. No sort and no scatter: counts within blocks of
+    lanes by one product with a triangle, the block of slot ``k`` by
+    counting the blocks that end before it, the block's running counts
+    brought to the slot by a one-hot product, its lane by counting those
+    at or under its rank (every product exact in bfloat16: counts of at
+    most 128)."""
+    r, t = mask.shape
+    b = next(w for w in (128, 64, 32, 16, 8, 4, 2, 1) if t % w == 0)
+    nb = t // b
+    tri = (jnp.arange(b)[:, None] <= jnp.arange(b)[None, :]).astype(
+        jnp.bfloat16)
+    running = jnp.einsum("rnb,bc->rnc",
+                         mask.reshape(r, nb, b).astype(jnp.bfloat16), tri,
+                         preferred_element_type=jnp.float32)
+    count = running[..., -1]                               # (R, nb)
+    ends = jnp.cumsum(count, axis=1)
+    slot = jnp.arange(topk, dtype=jnp.float32)
+    before = ends[:, None, :] <= slot[None, :, None]       # (R, K, nb)
+    block = before.sum(-1, dtype=jnp.int32)
+    rank = slot[None, :] - jnp.sum(before * count[:, None, :], -1)
+    mine = (block[..., None] == jnp.arange(nb)).astype(jnp.bfloat16)
+    counts = jnp.einsum("rkn,rnc->rkc", mine, running.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.bfloat16)
+    lane = jnp.sum(counts.astype(jnp.float32) <= rank[..., None], -1,
+                   dtype=jnp.int32)
+    return block * b + lane
+
+
+def select_positions(scores, n, topk, *, impl: str = "auto"):
+    """The rule as a list of cache positions, what a kernel that GATHERS
+    its rows takes: ``scores`` (R, T), ``n`` (R,) visible -> (positions
+    (R, topk) in ascending order, how many of them are live (R,)): the
+    engine's mask (:func:`select_decode_mask`, by counting) read out by
+    :func:`_mask_positions`. The positions :func:`select_decode` returns
+    best first, as a set."""
+    mask = select_decode_mask(scores, n, topk, impl=impl)
+    return _mask_positions(mask, topk), jnp.minimum(n, topk)
+
+
+def _select_rows(scores, n, topk, live, impl,
+                 rows_a_call=_LATENT_ROWS_A_CALL):
+    """:func:`select_positions` of ``scores`` (R, T), ``rows_a_call`` rows
+    at a time, a block none of whose rows is ``live`` (R,) skipped:
+    (positions (R, topk), how many of them are live (R,), 0 for a dead
+    row)."""
+    r, t = scores.shape
+    n = jnp.where(live, n, 0)
+    blk = min(rows_a_call, r)
+    if r <= blk:
+        return select_positions(scores, n, topk, impl=impl)
+    pad = -r % blk
+
+    def one(args):
+        sc, nb = args
+        return jax.lax.cond(
+            jnp.any(nb > 0),
+            lambda: select_positions(sc, nb, topk, impl=impl)[0],
+            lambda: jnp.zeros((blk, topk), jnp.int32))
+
+    idx = jax.lax.map(one, (
+        jnp.pad(scores, ((0, pad), (0, 0))).reshape(-1, blk, t),
+        jnp.pad(n, (0, pad)).reshape(-1, blk)))
+    return idx.reshape(-1, topk)[:r], jnp.minimum(n, topk)
+
+
+def _every_position(shape, t):
+    """The selection of a bucket no wider than ``topk``: every position
+    of the table, in order (the live count says how many a query sees)."""
+    return jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), shape + (t,))
+
+
+def latent_indexed_decode_attention(q, c_pages, r_pages, ik_pages,
+                                    block_tables, lengths, q_idx, w_idx,
+                                    topk, *, impl: str = "auto"):
+    """Score, select, gather, attend for one decode token a slot over a
+    LATENT cache: ``q`` (S, H, Dl + Dr) absorbed and scaled, ``lengths``
+    the live tokens INCLUDING this one. A table of at most ``topk`` tokens
+    selects every live one (no scores are made). Returns (attention (S,
+    H, Dl), tokens attended a slot (S,))."""
+    t = block_tables.shape[1] * c_pages.shape[1]
+    if t <= topk:
+        idx, n_sel = _every_position(lengths.shape, t), lengths
+    else:
+        scores = lightning_index_scores(
+            q_idx[:, None], w_idx[:, None], ik_pages, block_tables, lengths,
+            impl=impl)[:, 0]
+        idx, n_sel = select_positions(scores, lengths, topk, impl=impl)
+    att = sparse_latent_decode_attention(q, c_pages, r_pages, block_tables,
+                                         idx, n_sel, impl=impl)
+    return att, n_sel
+
+
+def latent_indexed_prefill_attention(q, c_pages, r_pages, ik_pages,
+                                     block_tables, chunk_starts, n_valid,
+                                     q_idx, w_idx, topk, *,
+                                     impl: str = "auto"):
+    """The same for a chunk of queries a lane, ``q`` (S, C, H, Dl + Dr):
+    query ``c`` of lane ``s`` sees ``chunk_starts[s] + c + 1`` tokens and
+    selects among them. Returns (S, C, H, Dl)."""
+    s, c = q.shape[:2]
+    t = block_tables.shape[1] * c_pages.shape[1]
+    n = chunk_starts[:, None] + jnp.arange(1, c + 1, dtype=jnp.int32)
+    live = jnp.arange(c)[None, :] < n_valid[:, None]
+    if t <= topk:
+        idx, n_sel = _every_position((s, c), t), jnp.where(live, n, 0)
+    else:
+        scores = lightning_index_scores(q_idx, w_idx, ik_pages, block_tables,
+                                        chunk_starts + n_valid, impl=impl)
+        idx, n_sel = _select_rows(scores.reshape(s * c, t), n.reshape(s * c),
+                                  topk, live.reshape(s * c), impl)
+        idx, n_sel = idx.reshape(s, c, topk), n_sel.reshape(s, c)
+    return sparse_latent_prefill_attention(q, c_pages, r_pages, block_tables,
+                                           idx, n_sel, impl=impl)
 
 
 # ---------------------------------------------------------------------------
@@ -1214,6 +1679,40 @@ def _register():
         tune_signature=lambda args, kwargs: DA._paged_sig(
             args[0], args[1], args[3]),
         vmem_estimate=DA._paged_vmem_estimate))
+    for chunked, name in ((False, "sparse_latent_decode"),
+                          (True, "sparse_latent_prefill")):
+        form = _sparse_latent_prefill if chunked else _sparse_latent_rows
+        lead = "(S,C," if chunked else "(S,"
+        kernels.register(kernels.KernelSpec(
+            name=name,
+            contract=kernels.KernelContract(
+                version=1,
+                arg_layouts={"q": lead + "H,Dl+Dr)",
+                             "c_pages": "(P,ps,Dl)", "r_pages": "(P,ps,>=Dr)",
+                             "block_tables": "(S,mp) i32",
+                             "sel_idx": lead + "K) i32",
+                             "n_sel": lead.rstrip(",") + ") i32"},
+                out_layout=lead + "H,Dl)",
+                grid="the selected rows of both token-major pools gathered "
+                     "by XLA, q_rows query rows a call (a block of rows "
+                     "with none live skipped), then (q_rows,) one step a "
+                     "query row: its K gathered rows one block against all "
+                     "its heads, scores (H,K), one softmax, one P C",
+                block_candidates={"q_rows": (64, 32, 128)},
+                atol=2e-5, rtol=2e-5),
+            pallas_fn=_sparse_latent_pallas(form, name),
+            lax_fn=_sparse_latent_lax(form),
+            reference_fn=_sparse_latent_reference,
+            sample_inputs=functools.partial(_make_sparse_latent_sample,
+                                            chunked=chunked),
+            pallas_sites=(
+                "paddle_tpu.serving.sparse_attention:_sparse_latent_fold",),
+            tune_signature=lambda args, kwargs: (
+                ("r", math.prod(args[0].shape[:-2])),
+                ("h", args[0].shape[-2]), ("dl", args[1].shape[-1]),
+                ("dr", args[0].shape[-1] - args[1].shape[-1]),
+                ("k", args[4].shape[-1])),
+            vmem_estimate=_sparse_latent_vmem_estimate))
 
 
 _register()

@@ -1,0 +1,108 @@
+"""The selecting latent family's Pallas bodies compiled for the chip, here:
+for a ``v5e:2x2`` topology description, which refuses what the interpreter
+hides (block shapes off the tiling, VMEM, a layout the compiler re-lays),
+at DeepSeek-V3.2's published widths and the long-document cell's geometry
+(64 slots, 261 pages of 128, 2048 of 33408 tokens selected). A file of its
+own (ROADMAP Design 16): a family's compile cases cost about a minute of a
+worker, and ``tests/test_chip_compile.py`` is already the longest file.
+
+Everything that touches ``jax.experimental.topologies`` lives in the
+module-scoped fixture below, never at import.
+"""
+
+import functools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu import kernels
+from paddle_tpu.kernels import autotune
+from paddle_tpu.serving import sparse_attention as SA
+
+S, H, DL, DR, PS, MP, P, K, J, DI, C = (64, 128, 512, 64, 128, 261, 2433,
+                                        2048, 64, 128, 256)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(desc.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compiled(fn, one_chip, *args):
+    args = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                 for shape, dtype in args)
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _custom_calls(text):
+    return sorted(set(re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)))
+
+
+BF, I32, F32 = jnp.bfloat16, jnp.int32, jnp.float32
+POOLS = (((P, PS, DL), BF), ((P, PS, 128), BF))
+
+
+@pytest.mark.parametrize("name, lead", [("sparse_latent_decode", (S,)),
+                                        ("sparse_latent_prefill", (2, C))])
+def test_selecting_latent_kernels_compile_and_copy_no_pool(name, lead,
+                                                           one_chip):
+    """The gather reads both token-major pools where they lie (a rotary
+    pool of 64 lanes would be re-laid whole before every gather: the
+    reason its rows are 128 lanes wide), and the fold is the one custom
+    call, under the kernel's own name."""
+    spec = kernels.get(name)
+    args = (((*lead, H, DL + DR), BF),) + POOLS + (
+        ((lead[0], MP), I32), ((*lead, K), I32), (lead, I32))
+    blocks = autotune.static_prior(
+        spec, tuple(jax.ShapeDtypeStruct(*a) for a in args), {})
+    compiled = _compiled(functools.partial(
+        spec.pallas_fn, block_sizes=blocks, interpret=False), one_chip,
+        *args)
+    text = compiled.as_text()
+    assert _custom_calls(text) == [name]
+    assert not re.findall(r"= bf16\[%d,%d,\d+\]\S* copy\(" % (P, PS), text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("chunk", [1, C], ids=["decode", "chunk_of_256"])
+def test_the_indexer_compiles_at_64_heads_of_128(chunk, one_chip):
+    """One query a slot against ONE block-diagonal; a chunk of 256 queries
+    x 64 heads summed 8 queries at a time (one block-diagonal for all of
+    them would be a 16.8 MB float32 operand a lane); a table of 261 pages,
+    no multiple of the page block, padded with the null page."""
+    lanes = S if chunk == 1 else 8
+    text = _compiled(
+        lambda *a: SA.lightning_index_scores(*a, impl="pallas"), one_chip,
+        ((lanes, chunk, J, DI), BF), ((lanes, chunk, J), F32),
+        ((P, DI, PS), BF), ((lanes, MP), I32), ((lanes,), I32)).as_text()
+    assert _custom_calls(text) == ["lightning_indexer"]
+    assert f"f32[{lanes},{chunk},{MP * PS}]" in text
+
+
+def test_the_selection_compiles_over_rows_of_33408(one_chip):
+    """The counting mask over a slot's whole table (261 chunks of 128
+    lanes a pass) and its read-out as positions, no sort and no scatter
+    in the program."""
+    text = _compiled(
+        lambda a, n: SA.select_positions(a, n, K, impl="pallas"), one_chip,
+        ((S, MP * PS), F32), ((S,), I32)).as_text()
+    assert _custom_calls(text) == ["topk_selection_mask"]
+    assert not re.findall(r" sort\(| scatter\(", text)

@@ -30,6 +30,9 @@ ONE_CHIP_PHASES = {
     "phase_wide_key_kernels": ["wide-key paged kernels vs lax",
                                "ragged_paged_prefill[kv2,float32]",
                                "wide-key prefill lowerings"],
+    "phase_selecting_latent_kernels": [
+        "selection positions vs lax.top_k", "0 differ",
+        "selecting latent kernels vs lax", "indexer chunk"],
 }
 
 

@@ -373,6 +373,11 @@ def test_a_latent_page_is_one_row_a_token(engines):
 
 
 def test_a_latent_pool_is_alone_in_its_entry():
+    """A latent row goes with nothing but ONE index row and the selection
+    that reads it (``tests/test_deepseek_v32_serving.py``): slot state,
+    window layers, a layer's own KV heads, values narrower than keys and
+    a sink in the softmax are still refused beside it, by name; so are
+    int8 pages; and without the index row the entry is the two pools."""
     spec = dict(num_layers=1, num_heads=4, vocab_size=8, max_position=8)
     latent = dict(spec, kv_heads=1, head_dim=24, latent_row=(16, 8))
     geo = dict(num_slots=2, page_size=8, num_pages=5)
@@ -383,20 +388,26 @@ def test_a_latent_pool_is_alone_in_its_entry():
     cache = PagedKVCache(PagedCacheConfig(
         num_layers=1, num_heads=1, head_dim=24, kinds=kinds(), **geo))
     cache.check_invariants()
+    assert type(cache.config.kinds[0]) is layer_kinds.Latent
     assert len(cache.config.kinds[0].pools) == len(cache.pages[0]) == 2
     with pytest.raises(ValueError, match="latent rows"):
         kinds(dtype=jnp.int8)
     # (what a spec may not declare beside a latent row, the spec refuses)
-    for extra in (dict(extra_rows=(("idx", 4),)),
-                  dict(slot_state=(("s", (2,)),), share_prefix=False),
-                  dict(layer_windows=(8,), share_prefix=False)):
-        with pytest.raises(ValueError, match="cached alone"):
+    for name, extra in (
+            ("slot_state", dict(slot_state=(("s", (2,)),),
+                                share_prefix=False)),
+            ("layer_windows", dict(layer_windows=(8,), share_prefix=False)),
+            ("layer_kv_heads", dict(layer_kv_heads=(2,))),
+            ("value_dim", dict(value_dim=8)),
+            ("sink_layers", dict(sink_layers=(True,)))):
+        with pytest.raises(ValueError, match=f"cached alone.*{name}"):
             kinds(**extra)
     with pytest.raises(ValueError, match="one row a token"):
         ServingSpec(**spec, kv_heads=4, head_dim=24, latent_row=(16, 8))
-    with pytest.raises(ValueError, match="cached alone"):
-        ServingSpec(**spec, kv_heads=1, head_dim=24, latent_row=(16, 8),
-                    select_topk=8)
+    # an index row without its selection, or the other way round
+    for half in (dict(select_topk=8), dict(extra_rows=(("idx", 4),))):
+        with pytest.raises(ValueError, match="both or neither"):
+            ServingSpec(**latent, **half)
 
 
 def test_a_borrower_of_published_pages_reads_what_a_fresh_prefill_writes(
