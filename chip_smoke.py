@@ -914,11 +914,13 @@ def phase_latent_family(sizes, seed):
 
 def phase_selecting_latent_kernels(sizes, seed):
     """The kernels of ``layer_kinds.SelectingLatent`` against their ``lax``
-    forms, and the selection's positions against ``lax.top_k`` element for
-    element: the fold over gathered rows for decode and for a chunk, the
-    indexer where a decode step's few rows meet a block's key pages in one
-    product and where a chunk's heads are summed a few queries at a
-    time."""
+    forms, and the selection's positions against ``lax.top_k`` and its mask
+    against the positions' set, element for element: decode's grouped walk
+    that compacts the selected rows out of whole pages and folds them (a
+    shared document, slots walked alone, whole pages selected), the fold
+    over gathered rows for a chunk, the indexer where a decode step's few
+    rows meet a block's key pages in one product and where a chunk's
+    heads are summed a few queries at a time."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu import kernels
@@ -983,13 +985,39 @@ def phase_selecting_latent_kernels(sizes, seed):
     log(f"selection positions vs lax.top_k: {int(want.sum())} of "
         f"{want.size} marked, 0 differ")
 
-    # -- the folds over gathered rows
+    # -- decode takes the mask: the positions' set, element for element
+    mask = jax.jit(lambda a, n: SA.select_decode_mask(
+        a, n, topk, impl=impl))(alone, lens)
+    assert ((np.asarray(mask) > 0) == (got > 0)).all()
+
+    # -- the grouped decode: five slots over one document's pages walked
+    # once, three walked alone (the last selects whole pages: 8 passes of
+    # 16 rows each), compacted and folded on the chip, against the ``lax``
+    # form, the NumPy reference and the same slots walked alone
     scale = (dl + dr) ** -0.5 / 4
     q = normal(s, h, dl + dr, scale=scale)
-    close("sparse_latent_decode", *(jax.jit(
-        lambda *a, i=i: SA.sparse_latent_decode_attention(*a, impl=i))(
-            q, c_pages, r_pages, bt, pos, n_sel) for i in (impl, "lax")),
-        2 ** -6)
+    groups = SA.DA.decode_groups(tables, lengths, np.arange(s), ps)
+    folded = shared // SA.DA.GROUP_SHARED_PAGES * SA.DA.GROUP_SHARED_PAGES
+    assert (groups[2][:5] == folded).all() and not groups[2][5:].any()
+    a_page = np.asarray(mask).reshape(s, mp, ps).sum(-1)
+    assert a_page[-1].max() == ps and a_page[-1].sum() == topk // 2
+
+    def decode(i, groups):
+        groups = None if groups is None else tuple(map(jnp.asarray, groups))
+        return jax.jit(lambda *a: SA.selected_latent_decode_attention(
+            *a, groups, impl=i))(q, c_pages, r_pages, bt, mask, lens)
+
+    grouped = decode(impl, groups)
+    close("sparse_latent_decode", grouped, decode("lax", groups), 2 ** -6)
+    close("sparse_latent_decode, every slot alone", decode(impl, None),
+          grouped, 2 ** -6)
+    probe = np.asarray([0, 4, 5, s - 1])       # members, a lone slot, whole pages
+    close("sparse_latent_decode vs NumPy", grouped[probe],
+          SA._sparse_latent_decode_reference(
+              *(jnp.asarray(a, jnp.float32)[probe] if a.shape[0] == s
+                else jnp.asarray(a, jnp.float32)
+                for a in (q, c_pages, r_pages)), bt[probe], mask[probe],
+              lens[probe]), 2 ** -6)
     lanes = 2
     qp = normal(lanes, c, h, dl + dr, scale=scale)
     scores = jax.jit(lambda *a: SA.lightning_index_scores(*a, impl=impl))(

@@ -917,18 +917,20 @@ class SelectingLatent(Latent):
     (num_pages, page_size, latent_dim), r_pages (num_pages, page_size,
     rope_lanes), ik_pages (num_pages, index_dim, page_size))``. The
     latent and the rotary key are BOTH token-major, because the attention
-    gathers the selected tokens' rows out of them and folds only those
-    (``sparse_attention.sparse_latent_decode`` / ``_prefill``: a row read
-    by 128 heads costs as much under a mask as selected), and the rotary
-    key lies in the first lanes of a row of whole lane tiles
+    folds the selected tokens' rows only (a row read by 128 heads costs
+    as much under a mask as selected): prefill gathers them
+    (``sparse_attention.sparse_latent_prefill``), decode compacts them
+    out of whole pages on the chip (``sparse_latent_decode``), and the
+    rotary key lies in the first lanes of a row of whole lane tiles
     (``rope_lanes``: 128 for a key of 64; a token-major pool of 64 lanes
     the chip's compiler keeps page-minor and re-lays whole before every
     gather); the index keys lie tokens along the lanes, as
     :class:`Selecting`'s. One path for every bucket: a table of at most
     ``topk`` tokens selects all a query sees, through the same kernel.
-    No group of slots is folded: what a document's slots share is the
-    pages, each gathers rows of its own, and the indexer's walk of a
-    slot's keys waits for its products, not for their bytes."""
+    Its decode reads the pages that a group of slots' tables open with
+    once for the group, as :class:`Selecting`'s does (the indexer's walk
+    of a slot's keys does not: it waits for its products, not for their
+    bytes)."""
 
     stat_names = Selecting.stat_names
     #: a call attends to ``topk`` rows whatever the table's width and the
@@ -942,7 +944,7 @@ class SelectingLatent(Latent):
         latent, rope = latent_row
         (_name, index_dim), = extra_rows
         self.topk = topk
-        self.groups = None          # (Latent's are its decode kernel's)
+        self.groups = _Groups(geo, counts_twice=False)
         self.pools = (
             ((geo.num_pages, geo.page_size, latent), geo.dtype, ()),
             ((geo.num_pages, geo.page_size, rope + -rope % 128), geo.dtype,
@@ -962,7 +964,7 @@ class SelectingLatent(Latent):
     def attend_decode(self, q, ent, place, index, groups):
         return SA.latent_indexed_decode_attention(
             q, *ent, place[2], place[3] + 1, index[0][:, 0], index[1][:, 0],
-            self.topk, impl=self.geo.impl)
+            self.topk, groups=groups, impl=self.geo.impl)
 
     def attend_prefill(self, q, ent, place, n_valid, index):
         return SA.latent_indexed_prefill_attention(
@@ -1002,12 +1004,14 @@ class SelectingLatent(Latent):
 
     def _count_own(self, span, block_tables, lengths, dslots, live, n,
                    width):
-        # token step j of a slot holding L tokens reads, scores and
-        # fetches min(L + j + 1, topk) rows, each gathered for that slot
+        # token step j of a slot holding L tokens reads and scores the
+        # min(L + j + 1, topk) rows it selects; the walks copy every live
+        # row, a group's shared ones once a group
         lens = lengths[dslots]
         rows = int(sum(np.minimum(lens + j + 1, self.topk).sum()
                        for j in range(n)))
-        self._count(span, "decode", rows, rows, rows)
+        _, _, spared = self.groups.of(block_tables, lengths, dslots)
+        self._count(span, "decode", rows, rows, live - n * spared)
         self._c_held["decode"].inc(live * self.layers)
         if self._selects(width):
             self._count_index(live)

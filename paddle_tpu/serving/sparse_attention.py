@@ -20,7 +20,7 @@ Index score of query ``t`` against cached token ``s``::
 
     I[t, s] = scale * sum_j w[t, j] * relu(qI[t, j] . kI[s])
 
-Four kernels register with the shared kernel layer:
+Six kernels register with the shared kernel layer:
 
 ``lightning_indexer`` — the scores of ``C`` queries a slot against every
   cached token of the slot's pages, ``(S, C, mp * page_size)`` float32
@@ -42,6 +42,13 @@ Four kernels register with the shared kernel layer:
   selection: the paged prefill body with the selection as one more
   streamed input, applied beside the causal test inside the fold.
 
+``sparse_latent_decode`` / ``sparse_latent_prefill`` — the same over a
+  LATENT cache (one row a token that every head reads, so only selected
+  rows are folded): prefill gathers them by position; decode walks whole
+  pages as ``sparse_paged_decode`` does, a group's shared ones once, and
+  compacts each member's selected rows out of a page in VMEM by a
+  one-hot product before it folds them (further down).
+
 ``topk_selection_mask`` — the selection, one rule for decode and
   prefill, as the mask both attention kernels take: ``(R, T)`` scores and
   how many of them each row sees -> ``(R, T)`` float32, 1 at the
@@ -60,6 +67,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -991,13 +999,8 @@ def sparse_paged_decode_attention(q, k_pages, v_pages, block_tables,
     if q.shape[-1] > k_pages.shape[-1]:
         return sparse_latent_decode_attention(
             q, k_pages, v_pages, block_tables, sel_idx, n_sel, impl=impl)
-    t = block_tables.shape[1] * k_pages.shape[1]
-    live = jnp.arange(sel_idx.shape[1])[None, :] < n_sel[:, None]
-    slot = jnp.arange(sel_idx.shape[0])[:, None]
-    # a dead entry lands past the row's end and is dropped
-    selected = jnp.zeros(sel_idx.shape[:1] + (t,), jnp.float32).at[
-        slot, jnp.where(live, sel_idx, t)].set(1.0, mode="drop")
-    extent = jnp.max(jnp.where(live, sel_idx + 1, 0), axis=1)
+    selected, extent = _mask_of_positions(
+        sel_idx, n_sel, block_tables.shape[1] * k_pages.shape[1])
     return selected_decode_attention(q, k_pages, v_pages, block_tables,
                                      selected, extent, groups, scale=scale,
                                      impl=impl)
@@ -1114,25 +1117,26 @@ def sparse_paged_prefill_attention(q, k_pages, v_pages, block_tables,
 
 
 # ---------------------------------------------------------------------------
-# selection over a latent cache: gather the selected rows, fold only those
+# selection over a latent cache: only the selected rows are folded
 # ---------------------------------------------------------------------------
 #
 # A latent row is read by EVERY head (128 of them at the published widths),
 # so a row folded under a mask costs as much as a selected one: walking
 # whole pages as ``sparse_paged_decode`` does would push 33k rows through
 # 128 heads to keep 2048 (16 times the products; PERF.md section 6, PR 55).
-# Here the selection is a list of cache positions (``select_decode``: the
-# rule by ``lax.top_k``), the selected rows of the latent pool and of the
-# rotary-key pool, BOTH token-major, are gathered into ``(rows, topk, .)``
-# (XLA's gather: a row of 1 KB and one of 256 bytes a token; the rotary
-# pool's rows are whole lane tiles, the key in their first ``Dr`` lanes: a
-# token-major pool of 64 lanes the chip's compiler keeps page-minor and
-# re-lays whole, 40 MB a layer, before every gather), and the
-# Pallas body folds one query row's ``topk`` gathered rows against all its
-# heads in one grid step: scores ``(H, topk)``, one softmax, one ``P C``.
-# Decode hands it one query a slot, chunked prefill one a chunk token (each
-# has a selection of its own), ``q_rows`` rows a call so that the gathered
-# copy stays small; a block of rows none of which is live is skipped.
+# So both phases fold the selected rows of the latent pool and of the
+# rotary-key pool only, BOTH token-major (a row of 1 KB and one of 256
+# bytes a token; the rotary pool's rows are whole lane tiles, the key in
+# their first ``Dr`` lanes: a token-major pool of 64 lanes the chip's
+# compiler keeps page-minor and re-lays whole, 40 MB a layer, before every
+# gather). Chunked prefill, below, takes the selection as a list of cache
+# positions (``select_positions``), gathers the rows into ``(rows, topk,
+# .)`` (XLA's gather) and folds one query row's ``topk`` gathered rows
+# against all its heads in one grid step: scores ``(H, topk)``, one
+# softmax, one ``P C``; a chunk token is a query row, ``q_rows`` rows a
+# call so that the gathered copy stays small, a block of rows none of
+# which is live skipped. Decode, further down, gathers nothing: it walks
+# whole pages and compacts the selected rows out of them on the chip.
 
 #: query rows gathered and folded a call (a row's copy is ``topk`` x the
 #: cache row: 2.4 MB at the published widths)
@@ -1215,8 +1219,7 @@ def _sparse_latent_rows(q, c_pages, r_pages, tables, sel_idx, n_sel, fold,
                         rows_a_call):
     """``q`` (R, H, D) query rows, each with its table row, selection and
     live count -> (R, H, Dl): ``rows_a_call`` rows gathered and folded at
-    a time, a block with no live row skipped. (Decode's form as it is:
-    a slot is a query row.)"""
+    a time, a block with no live row skipped."""
     r = q.shape[0]
     blk = min(rows_a_call, r)
     dl = c_pages.shape[-1]
@@ -1246,21 +1249,20 @@ def _sparse_latent_prefill(q, c_pages, r_pages, block_tables, sel_idx, n_sel,
     return out.reshape((s, c) + out.shape[1:])
 
 
-def _sparse_latent_pallas(form, name):
-    def run(q, c_pages, r_pages, block_tables, sel_idx, n_sel, *,
-            block_sizes, interpret):
-        def fold(qb, cg, rg, nb):
-            return _sparse_latent_fold(qb, cg, rg, nb, interpret, name)
-        return form(q, c_pages, r_pages, block_tables, sel_idx, n_sel, fold,
-                    block_sizes.get("q_rows", _LATENT_ROWS_A_CALL))
-    return run
+def _sparse_latent_prefill_pallas(q, c_pages, r_pages, block_tables, sel_idx,
+                                  n_sel, *, block_sizes, interpret):
+    def fold(qb, cg, rg, nb):
+        return _sparse_latent_fold(qb, cg, rg, nb, interpret,
+                                   "sparse_latent_prefill")
+    return _sparse_latent_prefill(
+        q, c_pages, r_pages, block_tables, sel_idx, n_sel, fold,
+        block_sizes.get("q_rows", _LATENT_ROWS_A_CALL))
 
 
-def _sparse_latent_lax(form):
-    def run(q, c_pages, r_pages, block_tables, sel_idx, n_sel):
-        return form(q, c_pages, r_pages, block_tables, sel_idx, n_sel,
-                    _fold_lax, _LATENT_ROWS_A_CALL)
-    return run
+def _sparse_latent_prefill_lax(q, c_pages, r_pages, block_tables, sel_idx,
+                               n_sel):
+    return _sparse_latent_prefill(q, c_pages, r_pages, block_tables, sel_idx,
+                                  n_sel, _fold_lax, _LATENT_ROWS_A_CALL)
 
 
 def _sparse_latent_reference(q, c_pages, r_pages, block_tables, sel_idx,
@@ -1294,17 +1296,15 @@ def _sparse_latent_reference(q, c_pages, r_pages, block_tables, sel_idx,
     return jnp.asarray(out).astype(q.dtype)
 
 
-def _make_sparse_latent_sample(seed, *, chunked):
+def _make_sparse_latent_sample(seed):
     """Three shapes by ``seed % 3``: float32 pools of pages scattered over
     the pool; selections of distinct live positions in no order, rows that
     select nothing, fewer than ``K`` and all ``K``; more rows than one
     call folds, so that the blocks and the skipped block are driven."""
     import numpy as np
     s, c, h, dl, dr, ps, mp, k = (
-        ((5, 1, 2, 16, 8, 8, 6, 8), (70, 1, 4, 32, 8, 8, 4, 16),
-         (9, 1, 4, 64, 16, 16, 5, 24)) if not chunked else
-        ((3, 4, 2, 16, 8, 8, 6, 8), (9, 8, 4, 32, 8, 8, 4, 16),
-         (2, 8, 4, 64, 16, 16, 5, 24)))[seed % 3]
+        (3, 4, 2, 16, 8, 8, 6, 8), (9, 8, 4, 32, 8, 8, 4, 16),
+        (2, 8, 4, 64, 16, 16, 5, 24))[seed % 3]
     rng = np.random.default_rng(seed)
     num_pages = s * mp + 1
     scale = (dl + dr) ** -0.5
@@ -1321,10 +1321,8 @@ def _make_sparse_latent_sample(seed, *, chunked):
                    ).reshape(s, c, k).astype(np.int32)
     n = rng.integers(0, k + 1, (s, c)).astype(np.int32)
     n.reshape(-1)[:3] = (0, k, 1)
-    if chunked and seed % 3 == 1:
+    if seed % 3 == 1:
         n[2:] = 0                       # whole blocks of rows with none
-    if not chunked:
-        q, idx, n = q[:, 0], idx[:, 0], n[:, 0]
     return (q, c_pages, r_pages, bt, jnp.asarray(idx), jnp.asarray(n)), {}
 
 
@@ -1343,17 +1341,638 @@ def _sparse_latent_vmem_estimate(args, kwargs, blocks):
     return 2 * (rows + heads) + scores
 
 
+# ---------------------------------------------------------------------------
+# selecting latent decode: whole pages read once a group of slots, each
+# member's selected rows compacted in VMEM and folded there
+# ---------------------------------------------------------------------------
+#
+# Decode takes the selection as the MASK (``select_decode_mask``) and makes
+# no copy of the selected rows in HBM: XLA's gather of them is bound by rows
+# (30 ns a row of 1 KB, 4% of the HBM peak: PERF.md section 6, PR 55), whole
+# pages by bytes, and the slots that ask about one published document read
+# the same pages. So the walk is ``sparse_paged_decode``'s (Part A a group
+# of slots over the pages their tables open with, Part B a slot over its
+# own from the state Part A left it, ``decode_attention._page_walk``), and
+# what is new is between the copy and the fold: a page in VMEM is not
+# folded under the mask (every one of its 128 rows would meet all 128
+# heads to keep 8) but COMPACTED first. A member's mask of the page gives
+# each selected row its rank among the page's selected rows (one product
+# with a triangle a block), the one-hot ``(members x width, page_size)``
+# of ranks ``first .. first + width`` times the page ``(page_size, Dl |
+# Dr)`` is every member's selected rows of those ranks, bit for bit (a row
+# times 1, the others times 0), and a page where some member selects more
+# than ``width`` rows takes further passes of the same product over the
+# next ranks, so nothing is dropped. A block's first passes (one a page,
+# straight-line code) fill a member's ``pages_per_block x width`` compacted
+# rows, which are folded against all its heads in one softmax update
+# (scores, maximum, sums float32; the weights meet the latents in the
+# pool's type, as ``_sparse_latent_kernel``'s do), the rows past a page's
+# count masked; the further passes collect in a second block of the same
+# shape, from block to block, folded when it is full and at the walk's end.
+
+#: rows a member's selection of one page is compacted into a pass: a bf16
+#: tile (2048 of 33k tokens select a mean of 7.85 of a page's 128)
+_COMPACT_ROWS = 16
+
+
+def _page_ranks(sel):
+    """``sel`` (n, ps) float32, 1 at a page's selected tokens -> ((n, ps)
+    float32: a selected token's 1-based rank among its page's selected
+    ones, 0 at every other; (n, 1) how many the page has). One product
+    with a triangle, exact (counts of at most ``ps`` in float32)."""
+    ps = sel.shape[1]
+    upto = (jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 0)
+            <= jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 1))
+    running = DA._exact_page_dot(sel.astype(jnp.bfloat16),
+                                 upto.astype(jnp.bfloat16), 0)
+    return running * sel, running[:, ps - 1:]
+
+
+def _one_hot_of_ranks(rank_rows, first, width, dtype):
+    """``rank_rows``: a member's ranks of one page each, (1, ps) as
+    :func:`_page_ranks` gives them -> (members * width, ps) in ``dtype``:
+    row ``m * width + r`` is 1 at member ``m``'s selected token of rank
+    ``first + r + 1`` and 0 elsewhere (all 0 where it has no such)."""
+    ps = rank_rows[0].shape[1]
+    want = (jax.lax.broadcasted_iota(jnp.int32, (width, ps), 0)
+            + first + 1).astype(jnp.float32)
+    return jnp.concatenate(
+        [(jnp.broadcast_to(row, (width, ps)) == want).astype(jnp.float32)
+         for row in rank_rows], axis=0).astype(dtype)
+
+
+def _compact(one_hot, page):
+    """The rows of ``page`` (ps, D) that ``one_hot`` (n, ps) marks, one a
+    row, exactly: a product in the page's type whose every sum has one
+    term."""
+    return DA._exact_page_dot(one_hot, page, 0).astype(page.dtype)
+
+
+class _Compaction(NamedTuple):
+    """The scratch between a walk's copies and its folds, ``g`` members
+    of ``H`` heads, a row ``D = Dl + Drl`` lanes (the latent, then the
+    rotary key's): a block's FIRST pass of every page lands in ``rows``
+    (page ``t`` of the block in rows ``t * width ..``) and is folded with
+    the block; the further passes of the pages that need them collect in
+    ``more``, as many passes a fold, from block to block (``n = pb *
+    width`` rows a member either way)."""
+    ranks: object       # (g * pb, ps) f32: the block's ranks, rows (m, t)
+    counts: object      # (g * pb, n) f32: a page's count, a lane
+    most: object        # (pb, 128) f32: the most any member selects of it
+    rows: object        # (g, n, D): the first passes' rows
+    live: object        # (g, n) f32: a page's count, a lane
+    more: object        # (g, n, D): the further passes' rows
+    more_live: object   # (g, n) f32: rows a pass holds, a lane
+    filled: object      # SMEM (1,): further passes since their last fold
+    scores: object      # (g, H, n) f32: a fold's scores, then
+    weights: object     # (g, H, n): its weights in the pool's type, and
+    decay: object       # (g, H, 128) f32: what its maxima wipe of the state
+
+
+def _compaction_scratch(g, h, pb, ps, d, dtype, width):
+    n = pb * width
+    return [pltpu.VMEM((g * pb, ps), jnp.float32),
+            pltpu.VMEM((g * pb, n), jnp.float32),
+            pltpu.VMEM((pb, 128), jnp.float32),
+            pltpu.VMEM((g, n, d), dtype),
+            pltpu.VMEM((g, n), jnp.float32),
+            pltpu.VMEM((g, n, d), dtype),
+            pltpu.VMEM((g, n), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((g, h, n), jnp.float32),
+            pltpu.VMEM((g, h, n), dtype),
+            pltpu.VMEM((g, h, 128), jnp.float32)]
+
+
+def _clear_compaction(cm):
+    """Before a call's first walk: no further pass waiting, and no row of
+    the compacted blocks left as the scratch came (a dead row meets a
+    weight of 0, which does not wipe a NaN)."""
+    for ref in (cm.rows, cm.more, cm.more_live):
+        ref[...] = jnp.zeros_like(ref)
+    cm.filled[0] = 0
+
+
+def _across(x, lanes):
+    """A ``(rows, 128)`` statistic, every lane its row's value, against
+    ``lanes`` columns: itself side by side where those are whole lane
+    tiles (no lane moves: ``decode_attention._row_values``), else its
+    first column."""
+    if lanes % x.shape[1]:
+        return x[:, :1]
+    return jnp.concatenate([x] * (lanes // x.shape[1]), axis=1)
+
+
+def _fold_compacted(q_ref, rows, live, cm, m_scr, l_scr, acc_scr, *, width):
+    """One softmax update of every member's heads with its compacted
+    rows ``rows`` (g, n, D): ``q_ref`` (g * H, D) the members' absorbed
+    queries, the state ``(g * H, .)``, ``acc_scr`` ``Dl`` wide; row ``j``
+    of a member is live where ``j % width`` is under ``live[m, j]``.
+    Three passes over the members in straight-line code, each member's
+    step independent of the others': every member's scores, then every
+    member's softmax, then every member's weighted sum, so that one
+    member's products run under another's softmax (a member alone is a
+    chain of product, reduction, exponential, product; a member the group
+    lacks folds some slot's queries, which nobody reads)."""
+    g, n, _ = rows.shape
+    h, dl = q_ref.shape[0] // g, acc_scr.shape[1]
+    rank = (jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) % width).astype(
+        jnp.float32)
+    of = [slice(m * h, (m + 1) * h) for m in range(g)]
+    for m in range(g):
+        s = DA._pool_dot(q_ref[of[m]], rows[m], 1)               # (H, n)
+        cm.scores[m] = jnp.where(rank < live[m:m + 1, :], s,
+                                        DA.NEG_INF)
+    for m in range(g):
+        s = cm.scores[m]
+        m_prev = m_scr[of[m]]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)                         # (H, 128)
+        p = jnp.where(rank < live[m:m + 1, :],
+                      jnp.exp(s - _across(m_next, n)), 0.0)
+        l_scr[of[m]] = l_scr[of[m]] * alpha \
+            + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[of[m]] = m_next
+        cm.decay[m] = alpha
+        cm.weights[m] = p.astype(cm.weights.dtype)
+    for m in range(g):
+        acc_scr[of[m]] = acc_scr[of[m]] * _across(cm.decay[m], dl) \
+            + DA._exact_page_dot(cm.weights[m], rows[m, :, :dl], 0)
+
+
+def _compact_block(sel_refs, first, pages, buf, kv_buf, cm, fold, *,
+                   page_size, pages_per_block, width, whole):
+    """Block ``buf`` of a walk, ``pages`` pages from table place ``first``
+    on (``whole``: always a whole block). Every member's selected rows of
+    ranks up to ``width`` of every page are compacted and folded, in
+    straight-line code (``fold(rows, live)``); where a member selects
+    more of some page, that page's further ranks are compacted ``width``
+    a pass into the passes waiting, which are folded whenever a block's
+    worth of them are. ``sel_refs``: a member's selection each, (1, mp, ps)."""
+    g, ps, pb = len(sel_refs), page_size, pages_per_block
+    n = pb * width
+    page_of_row = jax.lax.broadcasted_iota(jnp.int32, (pb, 1), 0)
+    unit_of = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) // width
+    most = None
+    for m, sel in enumerate(sel_refs):
+        marks = sel[0, pl.ds(pl.multiple_of(first, pb), pb), :]
+        if not whole:       # past the walk's end lies no page's selection
+            marks = jnp.where(page_of_row < pages, marks, 0.0)
+        ranks, counts = _page_ranks(marks)
+        cm.ranks[m * pb:(m + 1) * pb] = ranks
+        cm.counts[m * pb:(m + 1) * pb] = jnp.broadcast_to(counts, (pb, n))
+        # page t's count along the lanes of its rows
+        cm.live[m:m + 1] = jnp.sum(
+            jnp.where(unit_of == page_of_row, counts, 0.0), axis=0,
+            keepdims=True)
+        most = counts if most is None else jnp.maximum(most, counts)
+    cm.most[...] = jnp.broadcast_to(most, cm.most.shape)
+
+    def compacted(t, k):
+        marks = _one_hot_of_ranks(
+            [cm.ranks[pl.ds(m * pb + t, 1), :] for m in range(g)],
+            k * width, width, kv_buf.dtype)
+        return _compact(
+            marks, kv_buf[buf, pl.ds(pl.multiple_of(t * ps, ps), ps)])
+
+    def first_pass(t):
+        x = compacted(t, 0)
+        for m in range(g):
+            cm.rows[m, t * width:(t + 1) * width] = \
+                x[m * width:(m + 1) * width]
+
+    for t in range(pb):
+        if whole:
+            first_pass(t)
+        else:
+            pl.when(t < pages)(functools.partial(first_pass, t))
+    fold(cm.rows, cm.live)
+
+    def further(t, _):
+        need = jnp.max(cm.most[pl.ds(t, 1), :]).astype(jnp.int32)
+
+        def one_pass(k, _):
+            u = cm.filled[0]
+            x = compacted(t, k)
+            at = pl.ds(pl.multiple_of(u * width, width), width)
+            for m in range(g):
+                cm.more[m, at] = x[m * width:(m + 1) * width]
+                cm.more_live[pl.ds(m, 1), :] = jnp.where(
+                    unit_of == u,
+                    cm.counts[pl.ds(m * pb + t, 1), :]
+                    - (k * width).astype(jnp.float32),
+                    cm.more_live[pl.ds(m, 1), :])
+            cm.filled[0] = u + 1
+            pl.when(u + 1 == pb)(
+                functools.partial(_fold_further, cm, fold))
+
+        jax.lax.fori_loop(1, (need + width - 1) // width, one_pass, None)
+
+    pl.when(jnp.max(most) > width)(
+        lambda: jax.lax.fori_loop(0, pages, further, None))
+
+
+def _fold_further(cm, fold):
+    """The passes waiting folded, and none waiting any more."""
+    fold(cm.more, cm.more_live)
+    cm.more_live[...] = jnp.zeros_like(cm.more_live)
+    cm.filled[0] = 0
+
+
+def _row_moves(c_hbm, r_hbm, kv_buf, page_size):
+    """A page of each pool into ONE buffer, the rotary key's lanes behind
+    the latent's: a token's row is then one row of the block."""
+    dl = c_hbm.shape[-1]
+
+    def moves(page, buf, t):
+        rows = pl.ds(t * page_size, page_size)
+        return ((c_hbm.at[page], kv_buf.at[buf, rows, pl.ds(0, dl)]),
+                (r_hbm.at[page],
+                 kv_buf.at[buf, rows, pl.ds(dl, r_hbm.shape[-1])]))
+    return moves
+
+
+def _sparse_latent_shared_kernel(bt_ref, gs_ref, gp_ref, *refs, page_size,
+                                 pages_per_block, members, width):
+    """Part A: grid ``(groups,)``, one step a group of up to ``members``
+    slots whose tables open with the same ``gp_ref[g]`` pages, whole
+    blocks of them, walked ONCE. ``refs`` opens with the members' queries
+    (``members`` blocks (1, H, D)) and their selections ((1, mp, ps)
+    each), found by ``gs_ref``; a member the group lacks reads some
+    slot's, which is compacted and folded with the others' and read by
+    nobody. The members' unnormalised float32 states ``[acc | m | l]``
+    leave as the group's block (1, members * H, Dl + 256). A group
+    without pages does nothing."""
+    g, ps, pb = members, page_size, pages_per_block
+    q_refs, sel_refs = refs[:g], refs[g:2 * g]
+    (c_hbm, r_hbm, st_ref, kv_buf, sems, first_buf, q_scr, m_scr, l_scr,
+     acc_scr) = refs[2 * g:2 * g + 10]
+    cm = _Compaction(*refs[2 * g + 10:])
+    h, dl = q_refs[0].shape[1], c_hbm.shape[-1]
+    grp = pl.program_id(0)
+
+    def walk_of(of):
+        return jnp.maximum(gs_ref[of * g], 0), 0, gp_ref[of]
+
+    def fold(rows, live):
+        _fold_compacted(q_scr, rows, live, cm, m_scr, l_scr, acc_scr,
+                        width=width)
+
+    def block(b, buf, pages):
+        _compact_block(sel_refs, b * pb, pages, buf, kv_buf, cm, fold,
+                       page_size=ps, pages_per_block=pb, width=width,
+                       whole=True)
+
+    pl.when(grp == 0)(functools.partial(_clear_compaction, cm))
+
+    @pl.when(gp_ref[grp] > 0)
+    def _stack():
+        for j in range(g):
+            q_scr[j * h:(j + 1) * h] = q_refs[j][0]
+        _reset(m_scr, l_scr, acc_scr)
+
+    DA._page_walk(walk_of, block, bt_ref,
+                  _row_moves(c_hbm, r_hbm, kv_buf, ps), sems, first_buf,
+                  pages_per_block=pb)
+    pl.when(cm.filled[0] > 0)(functools.partial(_fold_further, cm, fold))
+
+    @pl.when(gp_ref[grp] > 0)
+    def _hand_over():
+        st_ref[0, :, :dl] = acc_scr[...]
+        st_ref[0, :, dl:dl + 128] = m_scr[...]
+        st_ref[0, :, dl + 128:] = l_scr[...]
+
+
+def _sparse_latent_own_kernel(bt_ref, ext_ref, sp_ref, row_ref, q_ref,
+                              sel_ref, st_ref, c_hbm, r_hbm, o_ref, kv_buf,
+                              sems, first_buf, m_scr, l_scr, acc_scr,
+                              *compaction, page_size, pages_per_block, width,
+                              spare):
+    """Part B: grid ``(S,)``, one step a slot, the same compaction and
+    fold with one member. The state starts from what Part A left the slot
+    (``st_ref`` (1, H, Dl + 256), block ``row_ref[slot]`` of the states)
+    where ``sp_ref[slot]`` of its pages were walked with its group's,
+    from nothing where its block is the ``spare`` one (a slot in no group:
+    all its pages are walked here); the walk goes over its own pages from
+    there to the one that holds token ``ext_ref[slot] - 1``, the current
+    token's row among them, and the state is normalised into ``o_ref``
+    (1, H, Dl). The selection marks no token at or past the slot's
+    extent, so it is the whole mask."""
+    ps, pb = page_size, pages_per_block
+    cm = _Compaction(*compaction)
+    sl = pl.program_id(0)
+    dl = o_ref.shape[2]
+
+    def walk_of(of):
+        n = (ext_ref[of] + ps - 1) // ps
+        shared = jnp.minimum(sp_ref[of], n)
+        return of, shared, n - shared
+
+    def fold(rows, live):
+        _fold_compacted(q_ref.at[0], rows, live, cm, m_scr, l_scr, acc_scr,
+                        width=width)
+
+    def block(b, buf, pages):
+        _compact_block((sel_ref,), walk_of(sl)[1] + b * pb, pages, buf,
+                       kv_buf, cm, fold, page_size=ps, pages_per_block=pb,
+                       width=width, whole=False)
+
+    pl.when(sl == 0)(functools.partial(_clear_compaction, cm))
+    _reset(m_scr, l_scr, acc_scr)
+
+    @pl.when(row_ref[sl] != spare)
+    def _from_the_group():
+        acc_scr[...] = st_ref[0, :, :dl]
+        m_scr[...] = st_ref[0, :, dl:dl + 128]
+        l_scr[...] = st_ref[0, :, dl + 128:]
+
+    DA._page_walk(walk_of, block, bt_ref,
+                  _row_moves(c_hbm, r_hbm, kv_buf, ps), sems, first_buf,
+                  pages_per_block=pb)
+    pl.when(cm.filled[0] > 0)(functools.partial(_fold_further, cm, fold))
+    denom = l_scr[...][:, :1]
+    denom = jnp.where(denom == 0.0, 1.0, denom)
+    alive = m_scr[...][:, :1] > DA.NEG_INF / 2
+    o_ref[0] = jnp.where(alive, acc_scr[...] / denom, 0.0).astype(
+        o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10, 11))
+def _sparse_latent_decode_pallas(q, c_pages, r_pages, block_tables,
+                                 selected, extent, group_slots, group_pages,
+                                 shared_pages, interpret, pages_per_block,
+                                 width):
+    """The two ``pallas_call``s of ``sparse_latent_decode``, Part A a
+    group and Part B a slot, both under the kernel's one name. ``q`` is
+    absorbed and scaled. Jitted, so that a step program traces and lowers
+    the bodies once and calls them from every layer."""
+    s_slots, h, _ = q.shape
+    ps, dl = c_pages.shape[1:]
+    drl = r_pages.shape[-1]
+    d = dl + drl
+    mp = block_tables.shape[1]
+    n_groups, g = group_slots.shape
+    # the bodies copy a page out of each pool as it lies and compact it by
+    # products whose rows are bf16 tiles, which the chip's compiler takes
+    # only where every piece is whole tiles
+    if not interpret and (ps % 128 or dl % 128 or drl % 128
+                          or width % (32 // c_pages.dtype.itemsize)):
+        raise ValueError(
+            f"sparse_latent_decode copies whole pages out of the pools: "
+            f"pages of {ps} tokens, rows of {dl} + {drl} lanes, {width} "
+            f"compacted rows a pass are not whole tiles")
+    pb = max(1, min(int(pages_per_block), mp))
+    rows = h + -h % DA._HEAD_ROWS
+    # a head's query over a cached row's lanes: the latent's, the rotary
+    # key's, and zeros over the lanes that pad the key
+    q = jnp.pad(q.astype(c_pages.dtype),
+                ((0, 0), (0, rows - h), (0, d - q.shape[-1])))
+    block_tables = block_tables.astype(jnp.int32)
+    extent = extent.astype(jnp.int32)
+    group_slots = group_slots.astype(jnp.int32).reshape(-1)
+    # Part A walks whole blocks: what is left of a group's pages is walked
+    # a slot (nothing, where the groups are ``decode_groups``')
+    group_pages = group_pages.astype(jnp.int32) // pb * pb
+    shared_pages = shared_pages.astype(jnp.int32) // pb * pb
+    # a block of the selection is whole blocks of pages: the rows past the
+    # table's width are no page's (nothing reads what lies there)
+    selected = selected.astype(jnp.float32).reshape(s_slots, mp, ps)
+    sel_pages = mp + -mp % pb
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=DA._WIDE_VMEM_LIMIT) if not interpret else None
+
+    def scratch(members):
+        return [pltpu.VMEM((2, pb * ps, d), c_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32)], [
+                pltpu.VMEM((members * rows, 128), jnp.float32),
+                pltpu.VMEM((members * rows, 128), jnp.float32),
+                pltpu.VMEM((members * rows, dl), jnp.float32)
+                ] + _compaction_scratch(members, rows, pb, ps, d,
+                                        c_pages.dtype, width)
+
+    pools = [pl.BlockSpec(memory_space=pl.ANY),
+             pl.BlockSpec(memory_space=pl.ANY)]
+    state = dl + DA._STATE_LANES
+
+    # Part A. A member's queries and selection come from its slot's block
+    # (a member a group lacks reads slot 0's); a group's states go to the
+    # group's block, those of every group without pages to one spare block
+    def member(j, *shape):
+        return pl.BlockSpec(
+            (1,) + shape,
+            lambda grp, _bt, gs, _gp: (jnp.maximum(gs[grp * g + j], 0), 0, 0))
+
+    walk, fold = scratch(g)
+    states = pl.pallas_call(
+        functools.partial(_sparse_latent_shared_kernel, page_size=ps,
+                          pages_per_block=pb, members=g, width=width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_groups,),
+            in_specs=[member(j, rows, d) for j in range(g)]
+            + [member(j, sel_pages, ps) for j in range(g)] + pools,
+            out_specs=pl.BlockSpec(
+                (1, g * rows, state),
+                lambda grp, _bt, _gs, gp: (
+                    jnp.where(gp[grp] > 0, grp, n_groups), 0, 0)),
+            scratch_shapes=walk + [
+                pltpu.VMEM((g * rows, d), c_pages.dtype)] + fold),
+        out_shape=jax.ShapeDtypeStruct((n_groups + 1, g * rows, state),
+                                       jnp.float32),
+        compiler_params=params,
+        interpret=interpret,
+        name="sparse_latent_decode",
+    )(block_tables, group_slots, group_pages, *[q] * g, *[selected] * g,
+      c_pages, r_pages)
+
+    # Part B, from the state rows Part A left
+    spare = n_groups * g
+    state_rows = DA._group_state_rows(group_slots, shared_pages, extent,
+                                      spare)
+
+    def slot_block(*shape):
+        return pl.BlockSpec((1,) + shape, lambda s, *_prefetch: (s, 0, 0))
+
+    walk, fold = scratch(1)
+    out = pl.pallas_call(
+        functools.partial(_sparse_latent_own_kernel, page_size=ps,
+                          pages_per_block=pb, width=width, spare=spare),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(s_slots,),
+            in_specs=[slot_block(rows, d), slot_block(sel_pages, ps),
+                      pl.BlockSpec(
+                          (1, rows, state),
+                          lambda s, _bt, _ext, _sp, row: (row[s], 0, 0))]
+            + pools,
+            out_specs=slot_block(rows, dl),
+            scratch_shapes=walk + fold),
+        out_shape=jax.ShapeDtypeStruct((s_slots, rows, dl), q.dtype),
+        compiler_params=params,
+        interpret=interpret,
+        name="sparse_latent_decode",
+    )(block_tables, extent, shared_pages, state_rows, q, selected,
+      states.reshape(spare + g, rows, state), c_pages, r_pages)
+    return out[:, :h]
+
+
+def _sparse_latent_decode_kernel_pallas(q, c_pages, r_pages, block_tables,
+                                        selected, extent, group_slots,
+                                        group_pages, shared_pages, *,
+                                        block_sizes, interpret):
+    return _sparse_latent_decode_pallas(
+        q, c_pages, r_pages, block_tables, selected, extent, group_slots,
+        group_pages, shared_pages, interpret,
+        block_sizes.get("pages_per_block", DA.GROUP_SHARED_PAGES),
+        block_sizes.get("rows_a_pass", _COMPACT_ROWS))
+
+
+def _sparse_latent_decode_lax(q, c_pages, r_pages, block_tables, selected,
+                              extent, *_groups):
+    """Attention a slot over the rows its selection marks, every row of
+    its table scored; which slots share pages changes no result."""
+    s, mp = block_tables.shape
+    dl = c_pages.shape[-1]
+    cg = c_pages[block_tables].reshape(s, mp * c_pages.shape[1], dl)
+    rg = r_pages[block_tables].reshape(s, cg.shape[1], -1)
+    qf, cf = q.astype(jnp.float32), cg.astype(jnp.float32)
+    scores = jnp.einsum("shd,std->sht", qf[..., :dl], cf,
+                        precision=_FP32_DOT) \
+        + jnp.einsum("shd,std->sht", qf[..., dl:],
+                     rg[..., :q.shape[-1] - dl].astype(jnp.float32),
+                     precision=_FP32_DOT)
+    p = DA._latent_softmax(scores, selected[:, None, :] > 0)
+    return jnp.einsum("sht,std->shd", p, cf,
+                      precision=_FP32_DOT).astype(q.dtype)
+
+
+def _mask_of_positions(sel_idx, n_sel, t):
+    """A selection as positions, the first ``n_sel[s]`` of ``sel_idx``
+    (S, K) live -> (the mask (S, t) float32, the extent (S,) one past the
+    last position marked)."""
+    live = jnp.arange(sel_idx.shape[1])[None, :] < n_sel[:, None]
+    slot = jnp.arange(sel_idx.shape[0])[:, None]
+    # a dead entry lands past the row's end and is dropped
+    selected = jnp.zeros(sel_idx.shape[:1] + (t,), jnp.float32).at[
+        slot, jnp.where(live, sel_idx, t)].set(1.0, mode="drop")
+    return selected, jnp.max(jnp.where(live, sel_idx + 1, 0), axis=1)
+
+
+def _sparse_latent_decode_reference(q, c_pages, r_pages, block_tables,
+                                    selected, extent, *_groups):
+    """:func:`_sparse_latent_reference` over the positions the mask
+    marks: independent of both impls and of which slots share pages."""
+    import numpy as np
+    sel = np.asarray(selected) > 0
+    idx = np.zeros((sel.shape[0], max(int(sel.sum(1).max()), 1)), np.int32)
+    for sl, row in enumerate(sel):
+        idx[sl, :row.sum()] = np.flatnonzero(row)
+    return _sparse_latent_reference(q, c_pages, r_pages, block_tables, idx,
+                                    sel.sum(1))
+
+
+def _make_sparse_latent_decode_sample(seed):
+    """Three shapes by ``seed % 3``: float32 pools of pages scattered over
+    the pool, the rotary keys in rows wider than the key (seed 2: as
+    wide); slots of every length from empty to full, some of them opening
+    with the same pages (a pair; three and a pair; a whole group of eight
+    and a slot alone), grouped as the engine groups them; selections by
+    the engine's rule, of a few rows a page, of none and of whole pages."""
+    import numpy as np
+    s, h, dl, dr, ps, mp, topk, sharers = (
+        (3, 2, 16, 8, 8, 12, 24, (([0, 1], 8),)),
+        (6, 4, 32, 8, 8, 10, 40, (([0, 1, 2], 9), ([3, 5], 8))),
+        (9, 4, 64, 16, 16, 18, 96, ((list(range(8)), 16),)))[seed % 3]
+    rng = np.random.default_rng(seed)
+    num_pages = s * mp + 1
+    q = jnp.asarray((dl + dr) ** -0.5 * rng.standard_normal(
+        (s, h, dl + dr)), jnp.float32)
+    c_pages = jnp.asarray(rng.standard_normal((num_pages, ps, dl)),
+                          jnp.float32)
+    r_pages = jnp.asarray(rng.standard_normal(
+        (num_pages, ps, dr if seed % 3 == 2 else 2 * dr)), jnp.float32)
+    tables = (rng.permutation(num_pages - 1)[:s * mp] + 1).reshape(
+        s, mp).astype(np.int32)
+    lengths = rng.integers(0, mp * ps + 1, s).astype(np.int32)
+    for slots, k in sharers:
+        tables[slots, :k] = tables[slots[0], :k]
+        lengths[slots] = rng.integers(k * ps, mp * ps + 1, len(slots))
+    # scores that crowd a slot's selection into a few pages, whole ones
+    # among them, and leave others with none
+    scores = rng.standard_normal((s, mp * ps)) \
+        + 3.0 * np.repeat(rng.standard_normal((s, mp)), ps, axis=1)
+    selected = select_decode_mask(jnp.asarray(scores, jnp.float32),
+                                  jnp.asarray(lengths), topk)
+    groups = DA.decode_groups(tables, lengths, np.arange(s), ps)
+    return (q, c_pages, r_pages, jnp.asarray(tables), selected,
+            jnp.asarray(lengths)) + tuple(map(jnp.asarray, groups)), {}
+
+
+def _sparse_latent_decode_vmem_estimate(args, kwargs, blocks):
+    """VMEM working set of one grid step of the selecting latent decode,
+    the larger of its two parts, a group's: two buffers of ``pb`` pages
+    (a row the latent's lanes and the rotary key's), the members' queries
+    and selections and the group's state block double-buffered by the
+    pipeline, the queries stacked, the state, the compacted rows of the
+    first and of the further passes, a fold's scores, weights and decay,
+    and one pass's one-hot and product."""
+    q, c_pages, r_pages, bt = args[:4]
+    ps, dl = c_pages.shape[1:]
+    g, mp = args[6].shape[1], bt.shape[1]
+    isz = c_pages.dtype.itemsize
+    pb = min(blocks.get("pages_per_block", DA.GROUP_SHARED_PAGES), mp)
+    width = blocks.get("rows_a_pass", _COMPACT_ROWS)
+    pad = lambda n, m: -(-n // m) * m                       # noqa: E731
+    h = pad(q.shape[-2], 16)
+    rows = pad(pb * width, 16)
+    n = pad(rows, 128)
+    lanes = pad(dl, 128) + pad(r_pages.shape[-1], 128)
+    pages = 2 * pb * pad(ps, 16) * lanes * isz
+    queries = g * h * lanes * isz
+    state = g * h * (pad(dl, 128) + 256) * 4
+    io = 2 * (queries + g * pad(mp, pb) * pad(ps, 128) * 4 + state)
+    compacted = 2 * g * rows * lanes * isz \
+        + (2 * g * pb + pb + 2 * g) * pad(max(ps, n), 128) * 4
+    fold = g * h * (n * (4 + isz) + 128 * 4)
+    one_pass = g * pad(width, 16) * (pad(ps, 128) * (4 + isz)
+                                     + lanes * (4 + isz))
+    return pages + io + queries + state + compacted + fold + one_pass
+
+
 def sparse_latent_decode_attention(q, c_pages, r_pages, block_tables,
                                    sel_idx, n_sel, *, impl: str = "auto"):
     """One decode step of latent attention over each slot's SELECTED
-    rows: ``q`` (S, H, Dl + Dr) absorbed queries, already scaled,
-    ``c_pages`` (P, ps, Dl) and ``r_pages`` (P, ps, >= Dr, the key in the
-    first ``Dr`` lanes of a row) the token-major latent and rotary-key
-    pools, ``sel_idx`` (S, K) cache positions of
-    which the first ``n_sel[s]`` are live. Returns (S, H, Dl)."""
+    rows, for whoever holds a selection as indices (the engine's own path
+    hands the kernel a mask: :func:`selected_latent_decode_attention`):
+    ``sel_idx`` (S, K) cache positions of which the first ``n_sel[s]``
+    are live. Returns (S, H, Dl)."""
+    selected, extent = _mask_of_positions(
+        sel_idx, n_sel, block_tables.shape[1] * c_pages.shape[1])
+    return selected_latent_decode_attention(
+        q, c_pages, r_pages, block_tables, selected, extent, impl=impl)
+
+
+def selected_latent_decode_attention(q, c_pages, r_pages, block_tables,
+                                     selected, extent, groups=None, *,
+                                     impl: str = "auto"):
+    """One decode step of latent attention over the rows ``selected`` (S,
+    mp * page_size) marks, 1 for a row the slot's query attends to and 0
+    for every other, none at or past ``extent[s]`` (S,): ``q`` (S, H, Dl
+    + Dr) absorbed queries, already scaled, ``c_pages`` (P, ps, Dl) and
+    ``r_pages`` (P, ps, >= Dr, the key in the first ``Dr`` lanes of a
+    row) the token-major latent and rotary-key pools. ``groups`` as
+    :func:`selected_decode_attention` takes them: the kernel reads the
+    pages a group's tables open with once for the group, and compacts
+    each member's selected rows out of them on the chip; None: every slot
+    walked alone, by the same code. Returns (S, H, Dl)."""
     from paddle_tpu import kernels
+    if groups is None:      # the shapes of a table that groups no slot
+        groups = DA.decode_groups(block_tables, extent, (), 1)
     return kernels.dispatch("sparse_latent_decode", q, c_pages, r_pages,
-                            block_tables, sel_idx, n_sel, impl=impl)
+                            block_tables, selected, extent, *groups,
+                            impl=impl)
 
 
 def sparse_latent_prefill_attention(q, c_pages, r_pages, block_tables,
@@ -1490,23 +2109,29 @@ def _every_position(shape, t):
 
 def latent_indexed_decode_attention(q, c_pages, r_pages, ik_pages,
                                     block_tables, lengths, q_idx, w_idx,
-                                    topk, *, impl: str = "auto"):
-    """Score, select, gather, attend for one decode token a slot over a
-    LATENT cache: ``q`` (S, H, Dl + Dr) absorbed and scaled, ``lengths``
-    the live tokens INCLUDING this one. A table of at most ``topk`` tokens
-    selects every live one (no scores are made). Returns (attention (S,
-    H, Dl), tokens attended a slot (S,))."""
+                                    topk, *, groups=None,
+                                    impl: str = "auto"):
+    """Score, select, attend for one decode token a slot over a LATENT
+    cache: ``q`` (S, H, Dl + Dr) absorbed and scaled, ``lengths`` the
+    live tokens INCLUDING this one, ``groups`` which slots' tables open
+    with the same pages (:func:`selected_latent_decode_attention`). The
+    selection reaches the kernel as a mask (:func:`select_decode_mask`);
+    a table of at most ``topk`` tokens selects every live one (no scores
+    are made). Returns (attention (S, H, Dl), tokens attended a slot
+    (S,))."""
     t = block_tables.shape[1] * c_pages.shape[1]
     if t <= topk:
-        idx, n_sel = _every_position(lengths.shape, t), lengths
+        selected = (jnp.arange(t, dtype=jnp.int32)[None, :]
+                    < lengths[:, None]).astype(jnp.float32)
     else:
         scores = lightning_index_scores(
             q_idx[:, None], w_idx[:, None], ik_pages, block_tables, lengths,
             impl=impl)[:, 0]
-        idx, n_sel = select_positions(scores, lengths, topk, impl=impl)
-    att = sparse_latent_decode_attention(q, c_pages, r_pages, block_tables,
-                                         idx, n_sel, impl=impl)
-    return att, n_sel
+        selected = select_decode_mask(scores, lengths, topk, impl=impl)
+    att = selected_latent_decode_attention(
+        q, c_pages, r_pages, block_tables, selected, lengths, groups,
+        impl=impl)
+    return att, jnp.minimum(lengths, topk)
 
 
 def latent_indexed_prefill_attention(q, c_pages, r_pages, ik_pages,
@@ -1679,40 +2304,81 @@ def _register():
         tune_signature=lambda args, kwargs: DA._paged_sig(
             args[0], args[1], args[3]),
         vmem_estimate=DA._paged_vmem_estimate))
-    for chunked, name in ((False, "sparse_latent_decode"),
-                          (True, "sparse_latent_prefill")):
-        form = _sparse_latent_prefill if chunked else _sparse_latent_rows
-        lead = "(S,C," if chunked else "(S,"
-        kernels.register(kernels.KernelSpec(
-            name=name,
-            contract=kernels.KernelContract(
-                version=1,
-                arg_layouts={"q": lead + "H,Dl+Dr)",
-                             "c_pages": "(P,ps,Dl)", "r_pages": "(P,ps,>=Dr)",
-                             "block_tables": "(S,mp) i32",
-                             "sel_idx": lead + "K) i32",
-                             "n_sel": lead.rstrip(",") + ") i32"},
-                out_layout=lead + "H,Dl)",
-                grid="the selected rows of both token-major pools gathered "
-                     "by XLA, q_rows query rows a call (a block of rows "
-                     "with none live skipped), then (q_rows,) one step a "
-                     "query row: its K gathered rows one block against all "
-                     "its heads, scores (H,K), one softmax, one P C",
-                block_candidates={"q_rows": (64, 32, 128)},
-                atol=2e-5, rtol=2e-5),
-            pallas_fn=_sparse_latent_pallas(form, name),
-            lax_fn=_sparse_latent_lax(form),
-            reference_fn=_sparse_latent_reference,
-            sample_inputs=functools.partial(_make_sparse_latent_sample,
-                                            chunked=chunked),
-            pallas_sites=(
-                "paddle_tpu.serving.sparse_attention:_sparse_latent_fold",),
-            tune_signature=lambda args, kwargs: (
-                ("r", math.prod(args[0].shape[:-2])),
-                ("h", args[0].shape[-2]), ("dl", args[1].shape[-1]),
-                ("dr", args[0].shape[-1] - args[1].shape[-1]),
-                ("k", args[4].shape[-1])),
-            vmem_estimate=_sparse_latent_vmem_estimate))
+    kernels.register(kernels.KernelSpec(
+        name="sparse_latent_decode",
+        contract=kernels.KernelContract(
+            version=2,
+            arg_layouts={"q": "(S,H,Dl+Dr)", "c_pages": "(P,ps,Dl)",
+                         "r_pages": "(P,ps,>=Dr)",
+                         "block_tables": "(S,mp) i32",
+                         "selected": "(S,mp*ps) f32",
+                         "extent": "(S,) i32",
+                         "group_slots": "(S//2,G) i32",
+                         "group_pages": "(S//2,) i32",
+                         "shared_pages": "(S,) i32"},
+            out_layout="(S,H,Dl)",
+            grid="two calls, pools left in HBM. (S//2,) one step a group "
+                 "of up to G slots whose tables open with the same "
+                 "group_pages pages (group_slots, -1 for no member), "
+                 "walked once; then (S,) one step a slot, from the state "
+                 "its group left it over its own pages from shared_pages "
+                 "on (0: the slot is walked alone). Either body copies "
+                 "whole pages of both token-major pools itself, "
+                 "pages_per_block side by side into one of two VMEM "
+                 "buffers while it works on the other, COMPACTS each "
+                 "member's selected rows of a page by a one-hot product "
+                 "(rows_a_pass ranks a pass, as many passes as the page's "
+                 "fullest member needs) and folds 8 passes' rows a member "
+                 "against all its heads in one softmax update; no copy "
+                 "of the selected rows in HBM",
+            # (32 rows a pass read 1.96 ms a layer for 1.46 at the long-
+            # document cell's geometry, selections spread evenly, and 3.63
+            # for 3.79 crowded into a fifth of the pages: PERF.md, PR 56)
+            block_candidates={"pages_per_block": (8,),
+                              "rows_a_pass": (16,)},
+            atol=2e-5, rtol=2e-5),
+        pallas_fn=_sparse_latent_decode_kernel_pallas,
+        lax_fn=_sparse_latent_decode_lax,
+        reference_fn=_sparse_latent_decode_reference,
+        sample_inputs=_make_sparse_latent_decode_sample,
+        pallas_sites=("paddle_tpu.serving.sparse_attention:"
+                      "_sparse_latent_decode_pallas",),
+        tune_signature=lambda args, kwargs: (
+            ("s", args[0].shape[0]), ("h", args[0].shape[1]),
+            ("dl", args[1].shape[-1]),
+            ("dr", args[0].shape[-1] - args[1].shape[-1]),
+            ("ps", args[1].shape[1]), ("mp", args[3].shape[1]),
+            ("g", args[6].shape[1])),
+        vmem_estimate=_sparse_latent_decode_vmem_estimate))
+    kernels.register(kernels.KernelSpec(
+        name="sparse_latent_prefill",
+        contract=kernels.KernelContract(
+            version=1,
+            arg_layouts={"q": "(S,C,H,Dl+Dr)",
+                         "c_pages": "(P,ps,Dl)", "r_pages": "(P,ps,>=Dr)",
+                         "block_tables": "(S,mp) i32",
+                         "sel_idx": "(S,C,K) i32",
+                         "n_sel": "(S,C) i32"},
+            out_layout="(S,C,H,Dl)",
+            grid="the selected rows of both token-major pools gathered "
+                 "by XLA, q_rows query rows a call (a block of rows "
+                 "with none live skipped), then (q_rows,) one step a "
+                 "query row: its K gathered rows one block against all "
+                 "its heads, scores (H,K), one softmax, one P C",
+            block_candidates={"q_rows": (64, 32, 128)},
+            atol=2e-5, rtol=2e-5),
+        pallas_fn=_sparse_latent_prefill_pallas,
+        lax_fn=_sparse_latent_prefill_lax,
+        reference_fn=_sparse_latent_reference,
+        sample_inputs=_make_sparse_latent_sample,
+        pallas_sites=(
+            "paddle_tpu.serving.sparse_attention:_sparse_latent_fold",),
+        tune_signature=lambda args, kwargs: (
+            ("r", math.prod(args[0].shape[:-2])),
+            ("h", args[0].shape[-2]), ("dl", args[1].shape[-1]),
+            ("dr", args[0].shape[-1] - args[1].shape[-1]),
+            ("k", args[4].shape[-1])),
+        vmem_estimate=_sparse_latent_vmem_estimate))
 
 
 _register()

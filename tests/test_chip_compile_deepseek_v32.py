@@ -22,8 +22,8 @@ from paddle_tpu import kernels
 from paddle_tpu.kernels import autotune
 from paddle_tpu.serving import sparse_attention as SA
 
-S, H, DL, DR, PS, MP, P, K, J, DI, C = (64, 128, 512, 64, 128, 261, 2433,
-                                        2048, 64, 128, 256)
+S, H, DL, DR, PS, MP, P, K, J, DI, C, G = (64, 128, 512, 64, 128, 261,
+                                           2433, 2048, 64, 128, 256, 8)
 
 
 @pytest.fixture(scope="module")
@@ -60,17 +60,26 @@ BF, I32, F32 = jnp.bfloat16, jnp.int32, jnp.float32
 POOLS = (((P, PS, DL), BF), ((P, PS, 128), BF))
 
 
-@pytest.mark.parametrize("name, lead", [("sparse_latent_decode", (S,)),
-                                        ("sparse_latent_prefill", (2, C))])
-def test_selecting_latent_kernels_compile_and_copy_no_pool(name, lead,
+DECODE = ((((S, H, DL + DR), BF),) + POOLS + (
+    ((S, MP), I32), ((S, MP * PS), F32), ((S,), I32), ((S // 2, G), I32),
+    ((S // 2,), I32), ((S,), I32)))
+PREFILL = ((((2, C, H, DL + DR), BF),) + POOLS + (
+    ((2, MP), I32), ((2, C, K), I32), ((2, C), I32)))
+
+
+@pytest.mark.parametrize("name, args", [("sparse_latent_decode", DECODE),
+                                        ("sparse_latent_prefill", PREFILL)])
+def test_selecting_latent_kernels_compile_and_copy_no_pool(name, args,
                                                            one_chip):
-    """The gather reads both token-major pools where they lie (a rotary
-    pool of 64 lanes would be re-laid whole before every gather: the
-    reason its rows are 128 lanes wide), and the fold is the one custom
-    call, under the kernel's own name."""
+    """Decode, groups of 8 over tables of 261 pages: the two parts that
+    walk whole pages of both token-major pools where they lie, compact
+    the selected rows in VMEM and fold them are the only custom calls,
+    both under the kernel's own name, and no gathered copy is left in the
+    program. Prefill: the gather reads both pools where they lie (a
+    rotary pool of 64 lanes would be re-laid whole before every gather:
+    the reason its rows are 128 lanes wide), and the fold is the one
+    custom call."""
     spec = kernels.get(name)
-    args = (((*lead, H, DL + DR), BF),) + POOLS + (
-        ((lead[0], MP), I32), ((*lead, K), I32), (lead, I32))
     blocks = autotune.static_prior(
         spec, tuple(jax.ShapeDtypeStruct(*a) for a in args), {})
     compiled = _compiled(functools.partial(
@@ -80,6 +89,27 @@ def test_selecting_latent_kernels_compile_and_copy_no_pool(name, lead,
     assert _custom_calls(text) == [name]
     assert not re.findall(r"= bf16\[%d,%d,\d+\]\S* copy\(" % (P, PS), text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    if name == "sparse_latent_decode":
+        assert not re.findall(r" gather\(", text)
+        assert f"bf16[{S},{K}," not in text
+
+
+def test_the_decode_of_a_layer_compiles_from_the_mask(one_chip):
+    """Indexer, counting mask and the grouped decode as the engine's step
+    runs them: three kernels' custom calls, no sort, no scatter and no
+    gather in the program (the mask's read-out as positions is
+    prefill's)."""
+    text = _compiled(
+        lambda q, c, r, ik, bt, n, qi, wi, *groups:
+        SA.latent_indexed_decode_attention(
+            q, c, r, ik, bt, n, qi, wi, K, groups=groups, impl="pallas")[0],
+        one_chip, ((S, H, DL + DR), BF), *POOLS, ((P, DI, PS), BF),
+        ((S, MP), I32), ((S,), I32), ((S, J, DI), BF), ((S, J), F32),
+        ((S // 2, G), I32), ((S // 2,), I32), ((S,), I32)).as_text()
+    assert _custom_calls(text) == ["lightning_indexer",
+                                   "sparse_latent_decode",
+                                   "topk_selection_mask"]
+    assert not re.findall(r" sort\(| scatter\(| gather\(", text)
 
 
 @pytest.mark.parametrize("chunk", [1, C], ids=["decode", "chunk_of_256"])

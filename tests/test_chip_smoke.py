@@ -32,7 +32,9 @@ ONE_CHIP_PHASES = {
                                "wide-key prefill lowerings"],
     "phase_selecting_latent_kernels": [
         "selection positions vs lax.top_k", "0 differ",
-        "selecting latent kernels vs lax", "indexer chunk"],
+        "selecting latent kernels vs lax", "indexer chunk",
+        "sparse_latent_decode, every slot alone",
+        "sparse_latent_decode vs NumPy"],
 }
 
 

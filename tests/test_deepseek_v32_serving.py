@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu import kernels
+from paddle_tpu.kernels import autotune
 from paddle_tpu import observability as obs
 from paddle_tpu.models.mla_moe_lm import MLAMoELM, MLAMoELMConfig
 from paddle_tpu.serving import layer_kinds
@@ -364,9 +365,11 @@ def test_a_borrower_of_published_pages_reads_what_a_fresh_prefill_writes(
 
 
 def test_counters_of_the_selected_rows(model_and_params, engines):
-    """One request of 21 + 7 tokens alone: rows read, pairs and rows
-    fetched are the SELECTED rows (min(seen, 16) a query), held what the
-    queries see, the index rows those of the buckets that select."""
+    """One request of 21 + 7 tokens alone: rows read and pairs are the
+    SELECTED rows (min(seen, 16) a query), held what the queries see, the
+    index rows those of the buckets that select; prefill fetches the rows
+    it selects (it gathers them), decode the rows its walk copies: every
+    live row of a slot walked alone."""
     eng, _sink, reg = engines("lax")
     before = reg.snapshot()
     with traced(eng) as tracer:
@@ -383,16 +386,19 @@ def test_counters_of_the_selected_rows(model_and_params, engines):
     # decode blocks of 2 from 21 tokens on: the first token is prefill's,
     # so 6 more; step j of a block at L held sees L + j + 1
     steps = range(21, 27)
-    assert snap['serving_latent_rows_read_total{phase="decode"}'] \
-        == len(steps) * TOPK * LAYERS
-    assert snap['serving_latent_rows_held_total{phase="decode"}'] \
-        == sum(n + 1 for n in steps) * LAYERS
+    held = sum(n + 1 for n in steps) * LAYERS
+    for name in ("rows_read", "pairs"):
+        assert snap[f'serving_latent_{name}_total{{phase="decode"}}'] \
+            == len(steps) * TOPK * LAYERS
+    assert snap['serving_latent_rows_held_total{phase="decode"}'] == held
+    assert snap['serving_latent_rows_fetched_total{phase="decode"}'] == held
     # the third chunk's table is 3 pages wide (bucket 4: 32 > 16 selects)
     # and every decode bucket selects: a lone slot fetches what it scores
     scored = (21 + sum(n + 1 for n in steps)) * LAYERS
     assert snap["serving_index_rows_scored_total"] == scored
     assert snap["serving_index_rows_fetched_total"] == scored
-    assert eng._shared_groups(np.arange(2)) == ()    # no slots are folded
+    (groups,) = eng._shared_groups(np.arange(2))
+    assert not np.asarray(groups[1]).any()           # no slots are folded
     assert snap["serving_attn_context_tokens_total"] \
         == (sum(seen) + sum(n + 1 for n in steps)) * LAYERS
     assert snap["serving_attn_selected_tokens_total"] \
@@ -403,15 +409,67 @@ def test_counters_of_the_selected_rows(model_and_params, engines):
         == len(steps) * TOPK * LAYERS
 
 
+def test_requests_over_a_published_document_decode_folded(model_and_params,
+                                                          engines):
+    """Two requests that open with the 64 tokens a third published decode
+    as one group over ONE copy of its eight pages (the Pallas bodies,
+    interpreted), each compacting its own selection out of them, and emit
+    the tokens they emit when the cache shares nothing (the same engine,
+    its cache told not to share). The counters, from the tables and
+    lengths the host holds: the pairs are the selected rows either way;
+    the walks copy the group's 64 shared rows once a token step and layer
+    where the slots hold them twice, and what the slots hold where
+    nothing is shared."""
+    eng, _sink, reg = engines("pallas_interpret")
+    document = _prompt(64, seed=910)
+    eng.generate_many([np.concatenate([document, _prompt(2, 911)])],
+                      max_new_tokens=2)
+    asks = [np.concatenate([document, _prompt(n, 912 + n)]) for n in (3, 6)]
+    names = [f'serving_latent_{name}_total{{phase="decode"}}'
+             for name in ("rows_fetched", "rows_held", "pairs")]
+
+    def serve(sharing):
+        shares = eng.cache.config
+        eng.cache.config = dataclasses.replace(shares, share_prefix=sharing)
+        before = reg.snapshot()
+        try:
+            outs = eng.generate_many(asks, max_new_tokens=5)
+        finally:
+            eng.cache.config = shares
+        snap = reg.snapshot()
+        return [list(o) for o in outs], [
+            int(snap[k] - before.get(k, 0)) for k in names]
+
+    folded, (fetched, held, pairs) = serve(True)
+    kept = eng.cache.config.kinds[0].groups
+    _slots, _tables, groups, _twice, spared = kept.kept
+    assert np.asarray(groups[1]).tolist() == [8] and spared == 8 * PAGE
+    assert sorted(np.asarray(groups[0])[0, :2]) == [0, 1]
+    # blocks of 2 token steps, 3 layers: 64 rows spared each
+    assert held > fetched > 0 and (held - fetched) % (64 * 2 * LAYERS) == 0
+    alone, (fetched, held, pairs_alone) = serve(False)
+    assert alone == folded
+    assert fetched == held > 0
+    assert pairs == pairs_alone and pairs % (TOPK * LAYERS) == 0
+    assert not np.asarray(kept.kept[2][1]).any()
+
+
 # -- the kernels ----------------------------------------------------------------
 
-@pytest.mark.parametrize("blocks", [dict(q_rows=4), dict(q_rows=64)], ids=str)
-@pytest.mark.parametrize("name", ["sparse_latent_prefill",
-                                  "sparse_latent_decode"])
+@pytest.mark.parametrize("name, blocks", [
+    ("sparse_latent_prefill", dict(q_rows=4)),
+    ("sparse_latent_prefill", dict(q_rows=64)),
+    ("sparse_latent_decode", dict(pages_per_block=8, rows_a_pass=16)),
+    ("sparse_latent_decode", dict(pages_per_block=2, rows_a_pass=4)),
+    ("sparse_latent_decode", dict(pages_per_block=4, rows_a_pass=1)),
+], ids=str)
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_selecting_latent_kernels_at_every_block_size(name, blocks, seed):
-    """Rows folded four at a time (blocks of rows none of which is live
-    are skipped) and all in one call."""
+    """Prefill: rows folded four at a time (blocks of rows none of which
+    is live are skipped) and all in one call. Decode: blocks of 8, 2 and 4
+    pages (tables of 12, 10 and 18: ragged last blocks), 16, 4 and 1
+    compacted rows a pass, so that pages of 8 and 16 tokens take further
+    passes; a pair, three and a pair, a whole group and a slot alone."""
     spec = kernels.get(name)
     args, kw = spec.sample_inputs(seed)
     got = kernels.dispatch(name, *args, impl="pallas_interpret",
@@ -419,6 +477,141 @@ def test_selecting_latent_kernels_at_every_block_size(name, blocks, seed):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(spec.reference_fn(*args, **kw)),
         atol=spec.contract.atol, rtol=spec.contract.rtol)
+
+
+#: the grouped decode's cases below, one geometry (so one interpreted
+#: program): 10 slots, 19 pages of 16 a table (16 a document's, in blocks
+#: of 8, then 3 of a slot's own: a ragged last block), 4 compacted rows a
+#: pass, so that a page's fifth selected row takes a second pass
+GS, GH, GDL, GDR, GPS, GMP, GROWS = 10, 4, 32, 8, 16, 19, 4
+
+
+def _grouped_case(case):
+    """-> (q, c_pages, r_pages, tables, selected, extent, groups | None)."""
+    rng = np.random.default_rng(7)
+    t = GMP * GPS
+    pages = GS * GMP + 1
+    f = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa
+    q = (GDL + GDR) ** -0.5 * f(GS, GH, GDL + GDR)
+    c_pages, r_pages = f(pages, GPS, GDL), f(pages, GPS, 2 * GDR)
+    tables = (1 + rng.permutation(pages - 1)[:GS * GMP]).reshape(
+        GS, GMP).astype(np.int32)
+    lengths = rng.integers(16 * GPS + 1, t + 1, GS).astype(np.int32)
+    sharers = {"no_group": (), "a_slot_walked_alone": (),
+               "groups_of_2": ([0, 1], [4, 7]),
+               "a_group_of_8": (list(range(8)),),
+               "a_missing_member": ([1, 2, 3, 5, 8],)}.get(
+                   case, ([0, 1, 2],))
+    for slots in sharers:
+        tables[slots, :16] = tables[slots[0], :16]
+    scores = f(GS, t)
+    if case == "a_slot_walked_alone":
+        lengths[3] = t                      # every page of its table
+    selected = np.asarray(SA.select_decode_mask(
+        jnp.asarray(scores), jnp.asarray(lengths), 40)).copy()
+    if case == "a_member_selects_nothing":
+        selected[1] = 0.0
+    elif case == "an_overflowing_page":
+        # 7 rows of shared page 2 and 13 of own page 17, for widths of 4
+        selected[0, 2 * GPS:2 * GPS + 7] = 1.0
+        lengths[2] = t
+        selected[2, 17 * GPS + 3:18 * GPS] = 1.0
+    elif case == "a_whole_page":
+        selected[1, 5 * GPS:6 * GPS] = 1.0          # a shared one
+        lengths[0] = 18 * GPS
+        selected[0, 17 * GPS:18 * GPS] = 1.0        # and an own one
+    elif case == "the_current_tokens_row":
+        # the last live row, alone in a page of the slot's own
+        lengths[:] = 17 * GPS + 1
+        selected[:, 16 * GPS:] = 0.0
+        selected[:, 17 * GPS] = 1.0
+    groups = None if case == "no_group" else SA.DA.decode_groups(
+        tables, lengths, np.arange(GS), GPS)
+    return tuple(map(jnp.asarray, (q, c_pages, r_pages, tables, selected,
+                                   lengths))) + (groups,)
+
+
+@pytest.mark.parametrize("case", [
+    "no_group", "groups_of_2", "a_group_of_8", "a_missing_member",
+    "a_slot_walked_alone", "a_member_selects_nothing",
+    "an_overflowing_page", "a_whole_page", "the_current_tokens_row"])
+def test_grouped_decode_compacts_and_folds(case):
+    """The two parts of ``sparse_latent_decode`` against the NumPy
+    reference over the positions the mask marks, whoever shares what."""
+    *args, groups = _grouped_case(case)
+    if groups is not None:
+        members = (np.asarray(groups[0]) >= 0).sum(1)
+        want = {"groups_of_2": [2, 2], "a_group_of_8": [8],
+                "a_missing_member": [5], "a_slot_walked_alone": []}.get(
+                    case, [3])
+        assert members[members > 0].tolist() == want
+        assert set(np.asarray(groups[1])[:len(want)]) <= {16}
+        groups = tuple(map(jnp.asarray, groups))
+    got = kernels.dispatch(
+        "sparse_latent_decode", *args,
+        *(groups or SA.DA.decode_groups(args[3], args[5], (), 1)),
+        impl="pallas_interpret",
+        block_sizes=dict(pages_per_block=8, rows_a_pass=GROWS))
+    want = SA._sparse_latent_decode_reference(*args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    if case == "a_member_selects_nothing":
+        assert not np.asarray(got)[1].any()
+
+
+@pytest.mark.parametrize("impl", ["lax", "pallas_interpret"])
+def test_a_table_no_wider_than_topk_selects_every_live_row(impl):
+    """No scores are made: the mask is every position under the slot's
+    length, whole pages of it, through the same kernel."""
+    rng = np.random.default_rng(3)
+    s, h, dl, dr, ps, mp = 3, 2, 16, 8, 8, 4
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape),  # noqa: E731
+                                   jnp.float32)
+    q, c_pages, r_pages = 0.2 * f(s, h, dl + dr), f(s * mp + 1, ps, dl), \
+        f(s * mp + 1, ps, 2 * dr)
+    tables = jnp.asarray((1 + rng.permutation(s * mp)).reshape(s, mp),
+                         jnp.int32)
+    lengths = jnp.asarray([mp * ps, 11, 1], jnp.int32)
+    got, n_sel = SA.latent_indexed_decode_attention(
+        q, c_pages, r_pages, None, tables, lengths, None, None, mp * ps,
+        impl=impl)
+    np.testing.assert_array_equal(np.asarray(n_sel), np.asarray(lengths))
+    idx = np.broadcast_to(np.arange(mp * ps, dtype=np.int32), (s, mp * ps))
+    want = SA._sparse_latent_reference(q, c_pages, r_pages, tables, idx,
+                                       lengths)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=str)
+def test_the_compacted_rows_are_the_gathered_rows_bit_for_bit(dtype):
+    """A page's selected rows by the one-hot product, 16 ranks a pass,
+    equal the rows a gather takes, in order, every bit; the rows past a
+    pass's count are zeros."""
+    rng = np.random.default_rng(11)
+    ps, d, width = 128, 640, 16
+    page = jnp.asarray(rng.standard_normal((ps, d)) * 37.0, dtype)
+    marks = np.zeros((3, ps), np.float32)
+    marks[0, rng.permutation(ps)[:7]] = 1       # under a pass
+    marks[1, rng.permutation(ps)[:41]] = 1      # three passes
+    marks[2] = 1                                # the whole page: eight
+    ranks, counts = SA._page_ranks(jnp.asarray(marks))
+    np.testing.assert_array_equal(np.asarray(counts)[:, 0], [7, 41, 128])
+    got = [[] for _ in marks]
+    for k in range(ps // width):
+        one_hot = SA._one_hot_of_ranks(
+            [ranks[m:m + 1] for m in range(len(marks))], k * width, width,
+            dtype)
+        rows = np.asarray(SA._compact(one_hot, page).astype(jnp.float32))
+        for m in range(len(marks)):
+            got[m].append(rows[m * width:(m + 1) * width])
+    for m, row in enumerate(marks):
+        mine = np.concatenate(got[m])
+        n = int(row.sum())
+        np.testing.assert_array_equal(
+            mine[:n], np.asarray(page.astype(jnp.float32))[
+                np.flatnonzero(row)])
+        assert not mine[n:].any()
 
 
 @pytest.mark.parametrize("impl", ["lax", "pallas_interpret"])
@@ -533,21 +726,30 @@ def test_a_wide_chunk_sums_its_heads_a_few_queries_at_a_time():
 
 def test_vmem_estimates_at_the_published_widths():
     """A query row's 2048 gathered rows of 512 + 128 lanes against 128
-    heads fit the 64 MiB the fold asks for four times over; the indexer's
-    block of 16 key pages beside 256 x 64 query rows, summed 8 queries at
-    a time, fits 16 MiB; so does the selection's block of 8 rows of
-    33408."""
+    heads fit the 64 MiB prefill's fold asks for four times over; a group
+    of 8 slots' queries, states, selections and compacted rows beside two
+    blocks of 8 pages fit the 64 MiB decode asks for (more than the two
+    blocks and the states alone); the indexer's block of 16 key pages
+    beside 256 x 64 query rows, summed 8 queries at a time, fits 16 MiB;
+    so does the selection's block of 8 rows of 33408."""
     sds = jax.ShapeDtypeStruct
     pools = (sds((2433, 128, 512), jnp.bfloat16),
              sds((2433, 128, 128), jnp.bfloat16))
-    for name, lead in (("sparse_latent_decode", (64,)),
-                       ("sparse_latent_prefill", (8, 256))):
-        est = kernels.get(name).vmem_estimate(
-            (sds(lead + (128, 576), jnp.bfloat16),) + pools
-            + (sds((lead[0], 261), jnp.int32),
-               sds(lead + (2048,), jnp.int32), sds(lead, jnp.int32)), {},
-            {"q_rows": 64})
-        assert 2 * 2048 * 640 * 2 < est < 16 << 20
+    est = kernels.get("sparse_latent_prefill").vmem_estimate(
+        (sds((8, 256, 128, 576), jnp.bfloat16),) + pools
+        + (sds((8, 261), jnp.int32), sds((8, 256, 2048), jnp.int32),
+           sds((8, 256), jnp.int32)), {}, {"q_rows": 64})
+    assert 2 * 2048 * 640 * 2 < est < 16 << 20
+    decode = kernels.get("sparse_latent_decode")
+    args = (sds((64, 128, 576), jnp.bfloat16),) + pools + (
+        sds((64, 261), jnp.int32), sds((64, 261 * 128), jnp.float32),
+        sds((64,), jnp.int32), sds((32, 8), jnp.int32),
+        sds((32,), jnp.int32), sds((64,), jnp.int32))
+    blocks = autotune.static_prior(decode, args, {})
+    assert blocks == {"pages_per_block": 8, "rows_a_pass": 16}
+    est = decode.vmem_estimate(args, {}, blocks)
+    assert 2 * 8 * 128 * 640 * 2 + 2 * 8 * 128 * 768 * 4 < est \
+        < SA.DA._WIDE_VMEM_LIMIT
     indexer = kernels.get("lightning_indexer").vmem_estimate(
         (sds((8, 256, 64, 128), jnp.bfloat16),
          sds((8, 256, 64), jnp.float32),
