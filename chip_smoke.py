@@ -54,6 +54,15 @@ configured; weights random from ``--seed``), in ONE process:
    the softmax) against their ``lax_fn``, bf16 and fp32 pages, within
    each kernel contract's tolerance. ``--only wide_keys`` runs this phase
    alone.
+9. **gated delta** — the two kernels of a state layer
+   (``ops/gated_delta.py``) at Qwen3-Next's widths (16 key and 32 value
+   heads of 128, chunks of 128 = two tiles of 64; four lanes: one fresh,
+   one ragged, one a pad lane on the null row) against their ``lax_fn``,
+   rows no lane holds bit for bit; the dense paged decode and prefill
+   bodies at heads of 256 (a pool of 2 KV heads x 256 lanes, query groups
+   of 8) against theirs; and 16 chained one-token updates over 256 slots
+   timed against the state tiles' bytes. ``--only gated_delta`` runs this
+   phase alone.
 
 ``--chips 4`` runs INSTEAD (no one-chip phase): BERT-base under
 ``shard_train_step`` on a dp2 x tp2 mesh against the same steps on one
@@ -141,6 +150,10 @@ class Sizes:
     #: width, indexer heads, indexer head width, tokens selected, page
     #: size, prefill chunk, pages a slot)
     selecting_latent: tuple = (128, 512, 64, 64, 128, 2048, 128, 256, 40)
+    #: a state layer and the full layer beside it: (key heads, value heads,
+    #: key width, value width, chunk, slots timed; query heads, KV heads,
+    #: head width, page size, pages a slot)
+    gated_delta: tuple = (16, 32, 128, 128, 128, 256, 16, 2, 256, 128, 8)
     interpret: bool = False
 
     @classmethod
@@ -192,6 +205,7 @@ class Sizes:
                                router_hidden_size=16),
                    wide_keys=(4, 24, 16, 4, 8, (1, 2)),
                    selecting_latent=(4, 16, 8, 2, 16, 16, 8, 16, 6),
+                   gated_delta=(2, 4, 16, 16, 8, 3, 4, 2, 16, 4, 3),
                    interpret=True)
 
     @property
@@ -1043,6 +1057,126 @@ def phase_selecting_latent_kernels(sizes, seed):
     log("selecting latent kernels vs lax max|err|: " + json.dumps(errs))
 
 
+# ---------------------------------------------------------------------------
+# phase 9: a state layer's two kernels and the full layer beside it, at
+# Qwen3-Next's widths
+# ---------------------------------------------------------------------------
+
+def phase_gated_delta_kernels(sizes, seed):
+    """``gated_delta_chunk_scan`` and ``gated_delta_decode_update`` against
+    their ``lax`` forms at the published tile sizes, the dense paged
+    kernels at heads of 256 against theirs, and the one-token update over
+    every slot of the cell timed against its state tiles' bytes."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import kernels
+    from paddle_tpu.serving import decode_attention as DA
+
+    hk, hv, dk, dv, c, slots, hq, kv, dh, ps, mp = sizes.gated_delta
+    impl = sizes.kernel_impl
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def unit(a):
+        return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+    # -- the two kernels: four lanes, one fresh, one ragged, one a pad
+    # lane on the null row; neighbouring keys share a direction
+    s = 4
+    g = -np.exp(rng.standard_normal(hv)) * 0.05 * np.log1p(np.exp(
+        rng.standard_normal((s, c, hv))))
+    beta = 1.0 / (1.0 + np.exp(-rng.standard_normal((s, c, hv))))
+    g[2, c // 2:], beta[2, c // 2:] = 0.0, 0.0
+    g[3], beta[3] = 0.0, 0.0
+    scan = (unit(normal(s, c, hk, dk)) * dk ** -0.5,
+            unit(normal(s, c, hk, dk) + 0.7 * normal(s, 1, hk, dk)),
+            normal(s, c, hv, dv), jnp.asarray(g, jnp.float32),
+            jnp.asarray(beta, jnp.float32), normal(s + 2, hv, dk, dv),
+            jnp.asarray([2, 5, 3, 0], jnp.int32),
+            jnp.asarray([0, 1, 0, 0], jnp.int32))
+    one = tuple(a[:, 0] for a in scan[:3]) + (
+        jnp.exp(scan[3][:, 0]), scan[4][:, 0]) + scan[5:7]
+    errs = {}
+    for name, args in (("gated_delta_chunk_scan", scan),
+                       ("gated_delta_decode_update", one)):
+        spec = kernels.get(name)
+        out = jax.jit(lambda *a, _n=name: kernels.dispatch(
+            _n, *a, impl=impl))(*args)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a, _n=name: kernels.dispatch(
+                _n, *a, impl="lax"))(*args)
+        for got, want in zip(out, ref):
+            got, want = np.asarray(got), np.asarray(want)
+            assert got.shape == want.shape and np.isfinite(got).all(), name
+            np.testing.assert_allclose(
+                got, want, atol=spec.contract.atol * max(
+                    1.0, float(np.abs(want).max())),
+                rtol=spec.contract.rtol, err_msg=f"{name} {impl} vs lax")
+            errs[name] = max(errs.get(name, 0.0),
+                             float(np.max(np.abs(got - want))))
+        idle = [r for r in range(1, s + 2) if r not in (2, 5, 3)]
+        assert (np.asarray(out[1])[idle] == np.asarray(args[5])[idle]).all(), \
+            f"{name} touched a row no lane holds"
+        assert _dispatched(name, impl) > 0
+
+    # -- the full layer beside them: heads of 256, 8 query heads a KV head
+    dt = jnp.float32 if sizes.interpret else jnp.bfloat16
+    n_slots = 8
+    num_pages = n_slots * mp + 1
+    tables = jnp.asarray((1 + rng.permutation(num_pages - 1)[:n_slots * mp]
+                          ).reshape(n_slots, mp), jnp.int32)
+    k_pages = normal(num_pages, ps, kv * dh).astype(dt)
+    v_pages = normal(num_pages, ps, kv * dh).astype(dt)
+    lengths = jnp.asarray(rng.integers(1, mp * ps + 1, n_slots), jnp.int32)
+    q = (normal(n_slots, hq, dh) * dh ** -0.25).astype(dt)
+    qc = (normal(n_slots, ps, hq, dh) * dh ** -0.25).astype(dt)
+    starts = jnp.maximum(lengths - ps, 0)
+    n_valid = jnp.asarray(rng.integers(1, ps + 1, n_slots), jnp.int32)
+    for name, fn, args in (
+            ("ragged_paged_decode", DA.ragged_paged_decode_attention,
+             (q, k_pages, v_pages, tables, lengths)),
+            ("ragged_paged_prefill", DA.ragged_paged_prefill_attention,
+             (qc, k_pages, v_pages, tables, starts, n_valid))):
+        got, want = (np.asarray(jax.jit(
+            lambda *a, i=i: fn(*a, impl=i))(*args), np.float32)
+            for i in (impl, "lax"))
+        assert np.isfinite(got).all(), name
+        np.testing.assert_allclose(got, want, atol=2 ** -6, rtol=2e-2,
+                                   err_msg=f"{name} {impl} vs lax")
+        errs[f"{name}[kv{kv}x{dh}]"] = float(np.max(np.abs(got - want)))
+        assert _dispatched(name, impl) > 0
+    log("gated delta kernels vs lax max|err|: " + json.dumps(errs))
+
+    # -- 16 chained one-token updates over every slot, least of 5
+    chain = 16
+    rows = jnp.arange(1, slots + 1, dtype=jnp.int32)
+    wide = tuple(jnp.broadcast_to(a[:1], (slots,) + a.shape[1:])
+                 + 0.01 * normal(slots, *a.shape[1:]) for a in one[:5])
+
+    @jax.jit
+    def steps(pool, *args):
+        total = 0.0
+        for _ in range(chain):
+            o, pool = kernels.dispatch("gated_delta_decode_update", *args,
+                                       pool, rows, impl=impl)
+            total = total + o.sum()
+        return total, pool
+
+    pool = normal(slots + 1, hv, dk, dv)
+    jax.block_until_ready(steps(pool, *wide))
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(steps(pool, *wide))
+        best = min(best, time.perf_counter() - t0)
+    moved = 2.0 * 4 * slots * hv * dk * dv * chain
+    log(f"gated_delta_decode_update alone, {slots} slots x {chain} calls: "
+        f"{best / chain / slots * 1e6:.2f} us a slot and layer, "
+        f"{moved / best / 1e9:.0f} GB/s of state read and written")
+
+
 def _sparse_kernel_args(name, cfg, sizes, seed):
     """One call's arguments of kernel ``name`` at ``cfg``'s widths: bf16
     pools of 2 slots x 20 pages under float32 queries (as phase 1: the
@@ -1196,6 +1330,7 @@ def run_one_chip(sizes, seed=0):
     phase_latent_family(sizes, seed)
     phase_wide_key_kernels(sizes, seed)
     phase_selecting_latent_kernels(sizes, seed)
+    phase_gated_delta_kernels(sizes, seed)
 
 
 def run_four_chips(sizes, seed=0, devices=None):
@@ -1247,7 +1382,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--only", choices=("wide_keys", "selecting_latent"),
+    ap.add_argument("--only", choices=("wide_keys", "selecting_latent",
+                                       "gated_delta"),
                     default=None,
                     help="one chip: this phase alone")
     args = ap.parse_args(argv)
@@ -1275,7 +1411,8 @@ def main(argv=None) -> int:
         run_four_chips(Sizes.real(), args.seed, devices)
     elif args.only:
         {"wide_keys": phase_wide_key_kernels,
-         "selecting_latent": phase_selecting_latent_kernels}[args.only](
+         "selecting_latent": phase_selecting_latent_kernels,
+         "gated_delta": phase_gated_delta_kernels}[args.only](
              Sizes.real(), args.seed)
     else:
         run_one_chip(Sizes.real(), args.seed)

@@ -129,6 +129,7 @@ _HOME_MODULES = (
     "paddle_tpu.serving.sparse_attention",
     "paddle_tpu.ops.grouped_ffn",
     "paddle_tpu.ops.ssm_scan",
+    "paddle_tpu.ops.gated_delta",
     "paddle_tpu.parallel.ring_attention",
 )
 

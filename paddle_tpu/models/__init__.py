@@ -33,6 +33,8 @@ from paddle_tpu.models.latent_conv_moe_lm import (LatentConvMoELM,
                                                   LatentConvMoELMConfig)
 from paddle_tpu.models.window_moe_lm import WindowMoELM, WindowMoELMConfig
 from paddle_tpu.models.mla_moe_lm import MLAMoELM, MLAMoELMConfig
+from paddle_tpu.models.gated_delta_moe_lm import (GatedDeltaMoELM,
+                                                  GatedDeltaMoELMConfig)
 
 __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "ResNet", "ResNet50", "DeepFM", "Transformer",
@@ -42,4 +44,5 @@ __all__ = ["LeNet", "BertConfig", "BertModel", "BertForPretraining",
            "SEResNeXt50", "AlexNet", "DarkNet53", "DenseNet121", "GoogLeNet", "ShuffleNetV2", "SqueezeNet", "SSD", "SSDConfig", "FasterRCNN", "FasterRCNNConfig", "MaskRCNN", "C3D", "TSN", "YOLOv3", "YOLOv3Config", "CRNN", "DCGANGenerator", "DCGANDiscriminator", "gan_step",
            "SparseMoELM", "SparseMoELMConfig", "HybridSSMLM",
            "HybridSSMLMConfig", "LatentConvMoELM", "LatentConvMoELMConfig",
-           "WindowMoELM", "WindowMoELMConfig", "MLAMoELM", "MLAMoELMConfig"]
+           "WindowMoELM", "WindowMoELMConfig", "MLAMoELM", "MLAMoELMConfig",
+           "GatedDeltaMoELM", "GatedDeltaMoELMConfig"]

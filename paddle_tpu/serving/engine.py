@@ -4,13 +4,15 @@ The serving loop is TWO jit-compiled fixed-shape steps, written against
 what a model supplies (:mod:`~paddle_tpu.serving.program`) and what its
 layers cache (:mod:`~paddle_tpu.serving.layer_kinds`: one kind a layer,
 built once from the program's spec and the cache's geometry; the loops are
-straight-line code over ``cache.config.kinds[i]``):
+straight-line code over ``cache.config.kinds[i]``, a layer whose kind
+caches no rows (``layer_kinds.State``) running the program's ``mixer`` alone
+over its slot state and touching no page):
 
 - a **batched chunked-prefill step**: one call advances the admitted
   requests' next prompt chunks at once — tokens (lanes, C), ragged
   per-lane valid counts, causal paged attention. A lane is a (slot,
   chunk) pair: where the program's layer kinds take it
-  (``layer_kinds.Paged.prefill_run``) consecutive lanes carry a **run** of
+  (``layer_kinds.Kind.prefill_run``) consecutive lanes carry a **run** of
   consecutive chunks of ONE prompt, so that a step's prompt tokens read
   the weights in one call however few prompts are in prefill;
 - a **decode step**: every slot advances a BLOCK of ``decode_block``
@@ -459,8 +461,14 @@ class ServingEngine:
         #: pages (pool row slot + 1): whose state row, whose ring
         self._lane_slot_column = bool(spec.slot_state) or any(
             kind.by_slot for kind in self._kinds)
-        #: the step programs take a slot's whole table and no narrower one
-        self._whole_table = any(kind.whole_table for kind in self._kinds)
+        #: the step programs take a slot's whole table and no narrower
+        #: one: where a kind says so, and where the program has state
+        #: layers (they never see the table, and the dense paged kernels of
+        #: the attention layers beside them skip the pages past a lane's
+        #: tokens: a narrow table saves a few dead grid steps, not worth a
+        #: compile of both step programs a width)
+        self._whole_table = bool(spec.state_layers) or any(
+            kind.whole_table for kind in self._kinds)
         #: consecutive chunks of ONE slot a prefill call may carry: the
         #: least over what the program is built from. Its layers' kinds
         #: say theirs; a program that carries state from chunk to chunk
@@ -896,7 +904,7 @@ class ServingEngine:
         span's ``state_slots``."""
         if not self._state_slot_bytes:
             return
-        layers = self.cache.config.num_layers
+        layers = self.cache.state_layers()
         self._c_ssm_decode.inc(decoding * token_steps * layers)
         self._c_ssm_prefill.inc(tokens * layers)
         self._c_ssm_resets.inc(fresh)
@@ -2269,7 +2277,8 @@ class ServingEngine:
         full slot capacity, while the set of compiled shapes stays
         log-sized; :meth:`warmup` precompiles them all. ONE width, the
         slot's whole table, where a layer's kind says so
-        (``layer_kinds.Paged.whole_table``)."""
+        (``layer_kinds.Kind.whole_table``) or the program has state
+        layers."""
         widest = self.cache.config.max_pages_per_slot
         if self._whole_table:
             return widest
@@ -3048,9 +3057,8 @@ class ServingEngine:
         s_tot = tokens.shape[0]
         slot_ids = jnp.arange(s_tot)
         n_stats = len(self._stat_names(spec, kinds))
-        n_paged = len(pages[0]) - len(spec.slot_state)
         mixes = bool(spec.slot_state) and spec.slot_state_reader == "mixer"
-        distinct = tuple(dict.fromkeys(kinds))
+        distinct = tuple(kind for kind in dict.fromkeys(kinds) if kind.paged)
 
         def one_token(j, pages, lengths, tokens):
             pos = jnp.minimum(lengths, spec.max_position - 1)
@@ -3073,27 +3081,34 @@ class ServingEngine:
             new_pages, counts = [], 0
             carry = self._carry_start(spec, s_tot, 1)
             for i, kind in enumerate(kinds):
-                with jax.named_scope("attn_in"):
-                    q, rows, index, state = self._attn_in(
-                        program, params, i, x, pos[:, None],
-                        pages[i][n_paged:], state_rows, None, writable)
-                with jax.named_scope("write_rows"):
-                    ent = kind.write(pages[i][:n_paged],
-                                     tuple(r[:, 0] for r in rows),
-                                     places[kind])
-                with jax.named_scope("attend"):
-                    att, attended = kind.attend_decode(
-                        q[:, :, 0, :], ent, places[kind], index,
-                        groups)                                 # (S,H,Dh)
-                with jax.named_scope("attn_out"):
-                    x_in, x = x, program.attn_out(params, i, x, att[:, None])
-                if mixes:
+                n_paged = len(kind.pools)
+                if kind.paged:
+                    with jax.named_scope("attn_in"):
+                        q, rows, index, state = self._attn_in(
+                            program, params, i, x, pos[:, None],
+                            pages[i][n_paged:], state_rows, None, writable)
+                    with jax.named_scope("write_rows"):
+                        ent = kind.write(pages[i][:n_paged],
+                                         tuple(r[:, 0] for r in rows),
+                                         places[kind])
+                    with jax.named_scope("attend"):
+                        att, attended = kind.attend_decode(
+                            q[:, :, 0, :], ent, places[kind], index,
+                            groups)                             # (S,H,Dh)
+                    with jax.named_scope("attn_out"):
+                        x_in, x = x, program.attn_out(params, i, x,
+                                                      att[:, None])
+                else:
+                    # a state layer: no rows, no queries, no page; its
+                    # mixer below is the block's token mixer
+                    ent, x_in, attended = (), x, 0
+                if mixes and kind.state:
                     with jax.named_scope("mixer"):
                         mixed, state = program.mixer(
                             params, i, x_in, pages[i][n_paged:], state_rows,
                             jnp.zeros_like(state_rows), writable[:, None])
                         x = x + mixed
-                if spec.slot_state:
+                if spec.slot_state and kind.state:
                     ent = ent + tuple(state)
                 new_pages.append(ent)
                 with jax.named_scope("ffn"):
@@ -3180,9 +3195,8 @@ class ServingEngine:
         spec = program.spec
         ps = self.cache.config.page_size
         s_tot, c = tokens.shape
-        n_paged = len(pages[0]) - len(spec.slot_state)
         mixes = bool(spec.slot_state) and spec.slot_state_reader == "mixer"
-        distinct = tuple(dict.fromkeys(kinds))
+        distinct = tuple(kind for kind in dict.fromkeys(kinds) if kind.paged)
         state_rows = fresh = None
         if spec.slot_state or any(kind.by_slot for kind in distinct):
             # the lanes' slots (pool row slot + 1) ride the tables' last
@@ -3213,25 +3227,29 @@ class ServingEngine:
         new_pages, counts = [], 0
         carry = self._carry_start(spec, s_tot, c)
         for i, kind in enumerate(kinds):
-            with jax.named_scope("attn_in"):
-                q, rows, index, state = self._attn_in(
-                    program, params, i, x, pos_e, pages[i][n_paged:],
-                    state_rows, fresh, valid)
-            with jax.named_scope("write_rows"):
-                ent = kind.write(pages[i][:n_paged], rows, places[kind])
-            with jax.named_scope("attend"):
-                att = kind.attend_prefill(
-                    q.transpose(0, 2, 1, 3), ent, places[kind], n_valid,
-                    index)                                      # (S,C,H,Dh)
-            with jax.named_scope("attn_out"):
-                x_in, x = x, program.attn_out(params, i, x, att)
-            if mixes:
+            n_paged = len(kind.pools)
+            if kind.paged:
+                with jax.named_scope("attn_in"):
+                    q, rows, index, state = self._attn_in(
+                        program, params, i, x, pos_e, pages[i][n_paged:],
+                        state_rows, fresh, valid)
+                with jax.named_scope("write_rows"):
+                    ent = kind.write(pages[i][:n_paged], rows, places[kind])
+                with jax.named_scope("attend"):
+                    att = kind.attend_prefill(
+                        q.transpose(0, 2, 1, 3), ent, places[kind], n_valid,
+                        index)                                  # (S,C,H,Dh)
+                with jax.named_scope("attn_out"):
+                    x_in, x = x, program.attn_out(params, i, x, att)
+            else:
+                ent, x_in = (), x           # a state layer: its mixer alone
+            if mixes and kind.state:
                 with jax.named_scope("mixer"):
                     mixed, state = program.mixer(
                         params, i, x_in, pages[i][n_paged:], state_rows,
                         fresh, valid)
                     x = x + mixed
-            if spec.slot_state:
+            if spec.slot_state and kind.state:
                 ent = ent + tuple(state)
             new_pages.append(ent)
             with jax.named_scope("ffn"):
@@ -3240,7 +3258,7 @@ class ServingEngine:
             if counting:
                 with jax.named_scope("stats"):
                     counts = counts + self._step_stat_vector(
-                        spec, kind, ffn_stats, seen, attended[kind])
+                        spec, kind, ffn_stats, seen, attended.get(kind, 0))
         with jax.named_scope("head"):
             if all_positions:
                 logits = program.head(params, x)                # (S,C,V)

@@ -28,6 +28,10 @@ The kinds, and what selects each:
 :class:`SelectingLatent`  a latent row plus an index row a token, each
                     query's heads reading the ``select_topk`` latent rows
                     it selects (``spec.latent_row`` with the two above)
+:class:`State`      NO rows a token: the layer's whole memory is the
+                    program's slot state, its ``mixer`` the token mixer;
+                    no pool, no page, never asked to place, write or
+                    attend (``spec.state_layers[i]``)
 ==================  =====================================================
 
 A kind's **geometry** is its own: :func:`build` hands each the KV heads of
@@ -273,22 +277,20 @@ def _attended(lens, n):
 
 # -- the kinds ---------------------------------------------------------------
 
-class Paged:
-    """One kind of attention layer, for ``layers`` layers of one program
-    in one cache; this one is the plain kind, and the others subclass it
-    for what they answer differently. K and V a token, each ``(num_pages,
-    page_size, heads * head_dim)`` (a token's heads folded head-major into
-    the lanes), pages of the shared pool mapped by the slot's block table;
-    then one pool ``(num_pages, width, page_size)`` for each of the
-    program's ``extra_rows`` (tokens along the lanes), allocated, shared,
-    copied on write and freed with its page. ``pools``: ``(shape, dtype,
-    the axes a tp mesh shards as a PartitionSpec's entries)`` a pool array
-    (K and V: the folded head axis). ``label``: the kind's name in the
-    series that split a pool by kind, None where the program's layers are
-    all of one kind (:class:`Latent` always names itself). ``sink``: the
-    layers' softmax carries a learned logit a query head, which ``attn_in``
-    hands over as ``index``."""
+class Kind:
+    """What the cache and the engine ask of every layer's kind, whether
+    it caches rows (:class:`Paged` and its subclasses) or none
+    (:class:`State`): ``layers`` layers of one program in one cache of
+    geometry ``geo``. ``label``: the kind's name in the series that split
+    a pool by kind, None where the program's layers are all of one kind.
+    ``state``: the layers keep the program's slot state, where it declares
+    one (False for the attention layers of a program that names its state
+    layers). ``pools``: ``(shape, dtype, the axes a tp mesh shards as a
+    PartitionSpec's entries)`` a pool array of a layer's entry, before its
+    slot state."""
 
+    #: the layer caches rows: the engine has it place, write and attend
+    paged: bool
     quantized = False       # pages carry scale rows
     by_slot = False         # a prefill lane's placement needs its slot
     #: consecutive chunks of one slot a prefill call may carry as lanes of
@@ -299,14 +301,79 @@ class Paged:
     #: saves its calls is not worth a compile of both programs a width)
     whole_table = False
     stat_names: Tuple[str, ...] = ()    # counts its steps add on the device
+    pools: tuple = ()
+    page_bytes = 0          # bytes one page id commits in a layer
+    slot_bytes = 0          # a layer's bytes a slot holds at any length
+
+    def __init__(self, geo: Geometry, layers: int,
+                 label: Optional[str] = None, state: bool = True):
+        self.geo, self.layers, self.label = geo, layers, label
+        self.state = state
+
+    def check(self, ent, lengths):
+        """The kind's part of the cache's self-check (tests): ``ent``,
+        the layer's pool arrays, are what the kind lays out."""
+        assert [(a.shape, a.dtype) for a in ent] == [
+            (shape, jnp.dtype(dtype)) for shape, dtype, _ in self.pools], \
+            f"a layer's entry is not what {type(self).__name__} lays out"
+
+    def step_counts(self, context, selected):
+        """The values of ``stat_names`` for one layer-call."""
+        return ()
+
+    def copy_page(self, ent, src, dst):
+        """Page ``src`` of the entry duplicated into ``dst`` (the
+        copy-on-write of a borrowed tail page)."""
+        return tuple(a.at[dst].set(a[src]) for a in ent)
+
+    # -- host --
+
+    def decode_groups(self, block_tables, lengths, dslots) -> tuple:
+        """``(groups,)`` for the decode step where the kind's decode
+        folds the pages that several tables open with; ``()``: it walks
+        every slot alone and its step takes none."""
+        return ()
+
+    def bind(self, reg):
+        """Bind the kind's series in ``reg``, once."""
+
+    def count_decode(self, span, block_tables, lengths, dslots, keeps,
+                     n: int, width: int):
+        """One decode round of ``n`` token steps at gather width ``width``
+        over ``dslots``, from the tables and lengths BEFORE it (``keeps``:
+        the tokens each slot keeps). Feeds the kind's series and the
+        span's attribute; returns its share of
+        ``serving_decode_kv_bytes_total`` (live, a row of its table)."""
+        return 0, 0
+
+    def count_prefill(self, span, starts, ns, heads=None):
+        """One prefill call: lanes at ``starts`` computing ``ns`` tokens.
+        ``heads``: which lanes open their slot's run (a mask; None: every
+        lane is a slot's only one)."""
+
+
+class Paged(Kind):
+    """One kind of attention layer; this one is the plain kind, and the
+    others subclass it for what they answer differently. K and V a token,
+    each ``(num_pages, page_size, heads * head_dim)`` (a token's heads
+    folded head-major into the lanes), pages of the shared pool mapped by
+    the slot's block table; then one pool ``(num_pages, width,
+    page_size)`` for each of the program's ``extra_rows`` (tokens along
+    the lanes), allocated, shared, copied on write and freed with its
+    page (K and V's sharded axis: the folded head axis). :class:`Latent`
+    always names itself (``label``). ``sink``: the layers' softmax carries
+    a learned logit a query head, which ``attn_in`` hands over as
+    ``index``."""
+
+    paged = True
     groups = None           # a :class:`_Groups` where its decode folds
     _c_resident = None      # bound where the program's pool is split by kind
     _c_rows = _c_sink_rows = None   # bound where the program has sinks
 
     def __init__(self, geo: Geometry, layers: int,
                  label: Optional[str] = None, extra_rows=(),
-                 sink: bool = False):
-        self.geo, self.layers, self.label = geo, layers, label
+                 sink: bool = False, state: bool = True):
+        super().__init__(geo, layers, label, state)
         self.sink = sink
         #: K and V of one token in one layer, each at its own width
         self.token_bytes = (geo.k_lanes + geo.v_lanes) \
@@ -343,15 +410,6 @@ class Paged:
     def page_bytes(self) -> int:
         """Bytes one page id of the shared pool commits in a layer."""
         return self.row_bytes
-
-    slot_bytes = 0          # a layer's bytes a slot holds at any length
-
-    def check(self, ent, lengths):
-        """The kind's part of the cache's self-check (tests): ``ent``,
-        the layer's pool arrays, are what the kind lays out."""
-        assert [(a.shape, a.dtype) for a in ent] == [
-            (shape, jnp.dtype(dtype)) for shape, dtype, _ in self.pools], \
-            f"a layer's entry is not what {type(self).__name__} lays out"
 
     # -- traced --
 
@@ -390,27 +448,14 @@ class Paged:
         it can see, in a bucket as wide as ``block_tables``."""
         return seen
 
-    def step_counts(self, context, selected):
-        """The values of ``stat_names`` for one layer-call."""
-        return ()
-
-    def copy_page(self, ent, src, dst):
-        """Page ``src`` of the entry duplicated into ``dst`` (the
-        copy-on-write of a borrowed tail page)."""
-        return tuple(a.at[dst].set(a[src]) for a in ent)
-
     # -- host --
 
     def decode_groups(self, block_tables, lengths, dslots) -> tuple:
-        """``(groups,)`` for the decode step where the kind's decode
-        folds the pages that several tables open with; ``()``: it walks
-        every slot alone and its step takes none."""
         if self.groups is None:
             return ()
         return (self.groups.of(block_tables, lengths, dslots)[0],)
 
     def bind(self, reg):
-        """Bind the kind's series in ``reg``, once."""
         if self.label is not None:
             self._bind_by_kind(reg)
         if self.geo.query_heads:
@@ -503,11 +548,6 @@ class Paged:
 
     def count_decode(self, span, block_tables, lengths, dslots, keeps,
                      n: int, width: int):
-        """One decode round of ``n`` token steps at gather width ``width``
-        over ``dslots``, from the tables and lengths BEFORE it (``keeps``:
-        the tokens each slot keeps). Feeds the kind's series and the
-        span's attribute; returns its share of
-        ``serving_decode_kv_bytes_total`` (live, a row of its table)."""
         lens = lengths[dslots]
         live = _attended(lens, n)
         self._count_own(span, block_tables, lengths, dslots, live, n, width)
@@ -521,10 +561,8 @@ class Paged:
         ``live`` tokens a layer."""
 
     def count_prefill(self, span, starts, ns, heads=None):
-        """One prefill call: lanes at ``starts`` computing ``ns`` tokens.
-        ``heads``: which lanes open their slot's run (a mask; None: every
-        lane is a slot's only one): what a slot holds going in is counted
-        once a slot, at its first lane."""
+        # what a slot holds going in is counted once a slot, at its first
+        # lane
         self._count_resident(_run_heads(starts, heads))
         self._count_prefill_attention(span, starts, ns)
 
@@ -1031,20 +1069,54 @@ class SelectingLatent(Latent):
             self._count_index(int((starts + ns)[ns > 0].sum()))
 
 
+class State(Kind):
+    """A layer that caches nothing a token (a linear-attention layer: a
+    gated or delta rule over a matrix state a head): its whole memory is
+    the program's slot state, the same bytes a slot and layer whatever
+    the length. No pool, and a page id commits no byte of it: the
+    engine's loops never have it place, write or attend, they call the
+    program's ``mixer`` as the block's token mixer and thread the state
+    through (what the state kernels move is the engine's to count, as for
+    every program with slot state). ``page_layers``: the program's other
+    layers, for the spans."""
+
+    paged = False
+    prefill_run = 1         # a slot's state row is one lane's to advance
+
+    def __init__(self, geo: Geometry, layers: int, page_layers: int):
+        super().__init__(geo, layers, "state")
+        self.page_layers = page_layers
+
+    def _layers_touched(self, span):
+        """How many of the step's layers touched state, and how many pages."""
+        _add_attr(span, "state_layers", self.layers)
+        _add_attr(span, "page_layers", self.page_layers)
+
+    def count_decode(self, span, block_tables, lengths, dslots, keeps,
+                     n: int, width: int):
+        self._layers_touched(span)
+        return 0, 0
+
+    def count_prefill(self, span, starts, ns, heads=None):
+        self._layers_touched(span)
+
+
 # -- the one decision --------------------------------------------------------
 
 def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
           share_prefix: bool, tp: int = 1, impl: str = "auto",
           prefill_chunk: Optional[int] = None,
-          prefill_room: int = 1) -> Tuple[Paged, ...]:
+          prefill_room: int = 1) -> Tuple[Kind, ...]:
     """One kind a layer of the program ``spec`` describes, in a cache of
     this geometry (layers alike share one object: the same window, KV
     heads and sink). The only reader of ``spec.extra_rows``,
     ``spec.select_topk``, ``spec.layer_windows``, ``spec.latent_row``,
-    ``spec.layer_kv_heads``, ``spec.value_dim``, ``spec.sink_layers`` and
-    the cache's dtype, and the one place where what does not combine yet
-    is refused. ``prefill_chunk``: the engine's, where an engine asks;
-    ``prefill_room``: the pages of room a ring gets, which is the
+    ``spec.layer_kv_heads``, ``spec.value_dim``, ``spec.sink_layers``,
+    ``spec.state_layers`` and the cache's dtype, and the one place where
+    what does not combine yet is refused (what a ``ServingSpec`` refuses
+    of itself, state layers beside window, selecting or latent layers
+    among it, never gets here). ``prefill_chunk``: the engine's, where an
+    engine asks; ``prefill_room``: the pages of room a ring gets, which is the
     longest run of one slot's chunks a prefill call may carry through
     the window layers (the engine's to choose: ``ServingEngine``'s
     ``_LANE_STEP`` or the lanes its budget buys, whichever is less);
@@ -1115,9 +1187,12 @@ def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
             raise ValueError("a tp-sharded pool carries no extra "
                              "rows, no slot state, no window layers "
                              "and no latent rows yet")
-    label = "full" if ringed else None
+    label = "full" if ringed or spec.state_layers else None
+    stateful = spec.state_layers or (False,) * spec.num_layers
 
-    def kind(window, heads, sink, layers):
+    def kind(window, heads, sink, is_state, layers):
+        if is_state:
+            return State(geo(heads), layers, spec.num_layers - layers)
         if window is not None:
             return Ring(geo(heads), layers, window, sink, prefill_room)
         if spec.latent_row is not None and spec.select_topk is not None:
@@ -1130,11 +1205,13 @@ def build(spec, *, num_slots: int, page_size: int, num_pages: int, dtype,
         if spec.select_topk is not None:
             return Selecting(geo(heads), layers, label, spec.extra_rows,
                              spec.select_topk)
-        return Paged(geo(heads), layers, label, spec.extra_rows, sink)
+        # beside state layers an attention layer keeps no slot state
+        return Paged(geo(heads), layers, label, spec.extra_rows, sink,
+                     state=not spec.state_layers)
 
     alike = list(zip(
         windows, spec.layer_kv_heads or (spec.kv_heads,) * spec.num_layers,
-        spec.sink_layers or (False,) * spec.num_layers))
+        spec.sink_layers or (False,) * spec.num_layers, stateful))
     kinds = {key: kind(*key, alike.count(key))
              for key in dict.fromkeys(alike)}
     return tuple(kinds[key] for key in alike)

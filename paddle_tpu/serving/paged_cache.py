@@ -54,7 +54,9 @@ Per-slot state (ISSUE 32): a program whose layers carry a recurrence
 declares ``slot_state`` and the one manager then holds a second kind of
 cache: per layer and entry an array ``(num_slots + 1,) + shape`` beside
 the layer's page pools, in the same ``pages[layer]`` tuple (so it threads
-through, and is donated into, the same jitted steps). It is indexed by
+through, and is donated into, the same jitted steps); where the program
+names its state layers, in those layers' tuples only, which then hold
+nothing else (``layer_kinds.State``: no page pool, no page). It is indexed by
 SLOT, not by page: row ``slot + 1`` is the slot's, row 0 the null row that
 pad lanes and non-decoding slots point at. Fixed size whatever the
 sequence length; never shared, copied on write, published, spilled or
@@ -111,7 +113,7 @@ class PagedCacheConfig:
     #: each layer's kind (:func:`layer_kinds.build`), which lays out its
     #: pool entry; empty: K and V of ``num_heads`` x ``head_dim`` in
     #: ``dtype`` under the block table, every layer
-    kinds: Tuple[layer_kinds.Paged, ...] = ()
+    kinds: Tuple[layer_kinds.Kind, ...] = ()
 
     def __post_init__(self):
         if self.page_size < 1 or self.num_pages < 2:
@@ -342,7 +344,7 @@ class PagedKVCache:
             (*(jnp.zeros(shape, dtype) for shape, dtype, _ in kind.pools),
              *(jnp.zeros((c.num_slots + 1,) + tuple(shape),
                          c.slot_state_dtype)
-               for _name, shape in c.slot_state))
+               for _name, shape in (c.slot_state if kind.state else ())))
             for kind in c.kinds]
         if self.mesh is not None:
             from jax.sharding import NamedSharding
@@ -420,7 +422,8 @@ class PagedKVCache:
         Drops straight into ``shard_map`` in/out specs."""
         from jax.sharding import PartitionSpec as P
         state = (P(),) * len(self.config.slot_state)
-        return [tuple(P(*axes) for _, _, axes in kind.pools) + state
+        return [tuple(P(*axes) for _, _, axes in kind.pools)
+                + (state if kind.state else ())
                 for kind in self.config.kinds]
 
     def bytes_per_page(self) -> int:
@@ -433,12 +436,18 @@ class PagedKVCache:
         """HBM bytes a slot holds whatever its length, state not counted."""
         return self._bytes[1]
 
+    def state_layers(self) -> int:
+        """The layers that keep slot state (0 for a pool without)."""
+        return sum(kind.state for kind in self.config.kinds) \
+            if self.config.slot_state else 0
+
     def state_bytes_per_slot(self) -> int:
-        """Bytes of slot state one slot holds across every layer (0 for
-        a pool without)."""
+        """Bytes of slot state one slot holds across the layers that keep
+        it (0 for a pool without)."""
         c = self.config
-        return c.num_layers * np.dtype(c.slot_state_dtype).itemsize * sum(
-            int(np.prod(shape)) for _name, shape in c.slot_state)
+        return self.state_layers() * np.dtype(
+            c.slot_state_dtype).itemsize * sum(
+                int(np.prod(shape)) for _name, shape in c.slot_state)
 
     def capacity_bytes(self) -> int:
         """HBM bytes of the allocatable pool (null page excluded) and of

@@ -31,6 +31,16 @@ object with a :class:`ServingSpec` as ``spec`` and the hooks below.
 both of those       token cached beside them: ``SelectingLatent``. ``rows
                     = (c, k_rope, k_index)`` and ``index`` as a selecting
                     program's
+``state_layers``    one bool a layer: True, the layer is a **state layer**
+                    (``State``): it caches NO rows a token, holds no page
+                    and is never asked for queries; its whole memory is
+                    the program's ``slot_state``, and ``mixer`` is the
+                    block's only token mixer (``attn_in`` / ``attn_out``
+                    are not called for it). False: an attention layer of
+                    the kind the other fields select, which then keeps
+                    NO slot state. Needs ``slot_state`` read by
+                    ``"mixer"``. Empty: every layer attends, and keeps
+                    the state too where ``slot_state`` is declared
 ==================  ======================================================
 
 A layer's **geometry** is its own too. Three fields say where a layer's
@@ -94,7 +104,9 @@ dict of scalar counts (names from ``spec.stats``) about the tokens
 ``valid`` marks, or None.
 
 ``slot_state`` is state of a fixed size a layer keeps a SLOT, not a token
-(a recurrence's state, a conv window): one pool array a layer and entry,
+(a recurrence's state, a conv window): one pool array a layer and entry
+(a STATE layer and entry where the program declares ``state_layers``: its
+attention layers then have none),
 ``(num_slots + 1,) + shape``, row 0 the null row, read and advanced by
 ``mixer``, or by ``attn_in`` where ``slot_state_reader`` says the state is
 the attention projections' own. ``state`` is the layer's pools, ``rows``
@@ -103,7 +115,7 @@ decoding), ``fresh`` where a lane's prompt starts in this call (its row's
 content is another request's: start from zeros) and ``valid`` marks a
 lane's real tokens, which come first. ``mixer`` gets the layer's input
 ``x`` and returns what the block adds to the residual stream beside
-``attn_out``'s; either reader returns the pools with every lane's row
+``attn_out``'s (alone, in a state layer); either reader returns the pools with every lane's row
 advanced past its valid tokens, rows of other slots as they were, bit for
 bit.
 
@@ -165,6 +177,9 @@ class ServingSpec:
     value_dim: Optional[int] = None
     #: one bool a layer, its softmax carries a sink; empty: none does
     sink_layers: Tuple[bool, ...] = ()
+    #: one bool a layer, it caches no rows and ``mixer`` is its token
+    #: mixer; empty: every layer attends
+    state_layers: Tuple[bool, ...] = ()
     supports: FrozenSet[str] = FEATURES
 
     def __post_init__(self):
@@ -176,7 +191,7 @@ class ServingSpec:
                     "rope_dim)")
             mixed = [name for name in ("slot_state", "layer_windows",
                                        "layer_kv_heads", "value_dim",
-                                       "sink_layers")
+                                       "sink_layers", "state_layers")
                      if getattr(self, name)]
             if mixed:
                 raise ValueError(f"a latent row is cached alone or beside "
@@ -218,6 +233,28 @@ class ServingSpec:
                 f"({self.num_layers})")
         if self.value_dim is not None and self.value_dim < 1:
             raise ValueError(f"value_dim={self.value_dim!r}: at least 1")
+        if self.state_layers and not any(self.state_layers):
+            object.__setattr__(self, "state_layers", ())
+        if self.state_layers:
+            if len(self.state_layers) != self.num_layers:
+                raise ValueError(
+                    f"state_layers={self.state_layers!r}: one bool a layer "
+                    f"({self.num_layers})")
+            if not self.slot_state or self.slot_state_reader != "mixer":
+                raise ValueError(
+                    "state_layers: a state layer's whole memory is the "
+                    "program's slot_state, read and advanced by 'mixer'")
+            mixed = [name for name in ("layer_windows", "select_topk",
+                                       "extra_rows", "layer_kv_heads",
+                                       "value_dim", "sink_layers",
+                                       "layer_carry")
+                     if getattr(self, name)]
+            if mixed:
+                raise ValueError(
+                    f"state layers beside layers that declare {mixed} are "
+                    "not served yet: window, selecting or latent layers, "
+                    "a layer's own geometry or a carry beside state layers "
+                    "each wait for an architecture that has them")
         if self.slot_state_reader not in ("mixer", "attn_in"):
             raise ValueError(
                 f"slot_state_reader={self.slot_state_reader!r}: the engine "

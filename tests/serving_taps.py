@@ -218,12 +218,13 @@ def serve_into_a_used_slot_and_alone(eng, sink, reg, first, second):
     gives alone. -> its tokens and logits."""
     before = reg.snapshot()
     serve_alone(eng, sink, first, 5)
-    for pool in eng.cache.pages[0][2:]:
+    state0 = len(eng.cache.config.kinds[0].pools)   # past the page pools
+    for pool in eng.cache.pages[0][state0:]:
         assert np.asarray(pool[1]).any()        # what the first left
     out, got = serve_alone(eng, sink, second, 6)
     assert moved(reg, before)["serving_ssm_state_resets_total"] == 2
     wipe(eng)
-    assert not any(np.asarray(a).any() for a in eng.cache.pages[0][2:])
+    assert not any(np.asarray(a).any() for a in eng.cache.pages[0][state0:])
     out2, got2 = serve_alone(eng, sink, second, 6)
     assert (out == out2).all() and (got == got2).all()
     return out, got
@@ -239,9 +240,11 @@ def serve_staggered_watching_state_rows(eng, prompts, n_new=6):
     seen = {"decode_kept": 0, "prefill_kept": 0, "pad_lanes": 0,
             "mid_prefill_during_decode": 0}
 
+    paged = [len(kind.pools) for kind in eng.cache.config.kinds]
+
     def state_rows(pages):      # {(layer, entry): array}, on the host
         return {(i, k): np.asarray(a) for i, ent in enumerate(pages)
-                for k, a in enumerate(ent[2:])}
+                for k, a in enumerate(ent[paged[i]:])}
 
     def watch(step, rows_of, kind):
         def run(params_, pages, *args):
@@ -249,7 +252,7 @@ def serve_staggered_watching_state_rows(eng, prompts, n_new=6):
             touched = set(rows_of(*args)) | {0}
             out, new_pages = step(params_, pages, *args)
             for key, was in before.items():
-                now = np.asarray(new_pages[key[0]][2 + key[1]])
+                now = np.asarray(new_pages[key[0]][paged[key[0]] + key[1]])
                 for r in range(was.shape[0]):
                     if r not in touched:
                         assert (now[r] == was[r]).all(), (kind, key, r)

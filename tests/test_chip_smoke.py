@@ -35,6 +35,9 @@ ONE_CHIP_PHASES = {
         "selecting latent kernels vs lax", "indexer chunk",
         "sparse_latent_decode, every slot alone",
         "sparse_latent_decode vs NumPy"],
+    "phase_gated_delta_kernels": [
+        "gated delta kernels vs lax", "ragged_paged_prefill[kv2x16]",
+        "gated_delta_decode_update alone, 3 slots x 16 calls"],
 }
 
 

@@ -97,7 +97,7 @@ def cell_programs(root: str, config: dict, device):
     kinds = layer_kinds.build(
         eng.program.spec, num_slots=slots, page_size=c.page_size,
         num_pages=num_pages, dtype=c.dtype, share_prefix=c.share_prefix,
-        prefill_chunk=chunk, **room)
+        impl=eng.attn_impl, prefill_chunk=chunk, **room)
     # the steps place rows and attend as the CELL's kinds do (a ring's
     # pages follow its slots and its room), not the stand-in's
     eng.cache.config.kinds = kinds
