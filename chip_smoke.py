@@ -928,13 +928,13 @@ def phase_latent_family(sizes, seed):
 
 def phase_selecting_latent_kernels(sizes, seed):
     """The kernels of ``layer_kinds.SelectingLatent`` against their ``lax``
-    forms, and the selection's positions against ``lax.top_k`` and its mask
-    against the positions' set, element for element: decode's grouped walk
-    that compacts the selected rows out of whole pages and folds them (a
-    shared document, slots walked alone, whole pages selected), the fold
-    over gathered rows for a chunk, the indexer where a decode step's few
-    rows meet a block's key pages in one product and where a chunk's
-    heads are summed a few queries at a time."""
+    forms, and the selection's mask against ``lax.top_k``, element for
+    element: decode's grouped walk that compacts the selected rows out of
+    whole pages and folds them (a shared document, slots walked alone,
+    whole pages selected), the same two parts for a chunk's tokens eight
+    of a lane a walk, the indexer where a decode step's few rows meet a
+    block's key pages in one product and where a chunk's heads are
+    summed a few queries at a time."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu import kernels
@@ -988,21 +988,15 @@ def phase_selecting_latent_kernels(sizes, seed):
             qc, wc, ik_pages, bt, starts + n_valid)
         for i in (impl, "lax")), 2e-2 * di ** 0.5)
 
-    # -- the selection: the mask read out as positions, against the sort
-    pos, n_sel = jax.jit(lambda a, n: SA.select_positions(
-        a, n, topk, impl=impl))(alone, lens)
-    want = np.asarray(SA.selected_by_sort(alone, lens, topk))
-    got = np.zeros_like(want)
-    for row, (p, n) in enumerate(zip(np.asarray(pos), np.asarray(n_sel))):
-        got[row, p[:n]] = 1
-    assert (got == want).all() and int(n_sel[-1]) == topk // 2
-    log(f"selection positions vs lax.top_k: {int(want.sum())} of "
-        f"{want.size} marked, 0 differ")
-
-    # -- decode takes the mask: the positions' set, element for element
+    # -- the selection: the counting mask both kernels take, against the
+    # sort, element for element
     mask = jax.jit(lambda a, n: SA.select_decode_mask(
         a, n, topk, impl=impl))(alone, lens)
-    assert ((np.asarray(mask) > 0) == (got > 0)).all()
+    want = np.asarray(SA.selected_by_sort(alone, lens, topk))
+    assert ((np.asarray(mask) > 0) == (want > 0)).all()
+    assert int(want[-1].sum()) == topk // 2
+    log(f"selection mask vs lax.top_k: {int(want.sum())} of "
+        f"{want.size} marked, 0 differ")
 
     # -- the grouped decode: five slots over one document's pages walked
     # once, three walked alone (the last selects whole pages: 8 passes of
@@ -1037,19 +1031,20 @@ def phase_selecting_latent_kernels(sizes, seed):
     scores = jax.jit(lambda *a: SA.lightning_index_scores(*a, impl=impl))(
         qc[:lanes], wc[:lanes], ik_pages, bt[:lanes],
         (starts + n_valid)[:lanes])
-    seen = starts[:lanes, None] + jnp.arange(1, c + 1)
-    live = jnp.arange(c)[None, :] < n_valid[:lanes, None]
+    # -- the prefill: a chunk's tokens through the same two parts under
+    # the prefill's name, the second lane part dead
+    seen = SA._chunk_extents(starts[:lanes], n_valid[:lanes], c)
     if mp * ps > topk:
-        cpos, cn = SA._select_rows(
-            scores.reshape(lanes * c, -1), seen.reshape(-1), topk,
-            live.reshape(-1), impl)
+        marks = jax.jit(lambda a, n: SA.select_decode_mask(
+            a, n, topk, impl=impl))(scores.reshape(lanes * c, -1),
+                                    seen.reshape(-1))
     else:
-        cpos, cn = (SA._every_position((lanes * c,), mp * ps),
-                    jnp.where(live, seen, 0).reshape(-1))
-    cpos, cn = cpos.reshape(lanes, c, -1), cn.reshape(lanes, c)
+        marks = (jnp.arange(mp * ps)[None, :]
+                 < seen.reshape(-1, 1)).astype(jnp.float32)
     close("sparse_latent_prefill", *(jax.jit(
         lambda *a, i=i: SA.sparse_latent_prefill_attention(*a, impl=i))(
-            qp, c_pages, r_pages, bt[:lanes], cpos, cn)
+            qp, c_pages, r_pages, bt[:lanes], starts[:lanes],
+            n_valid[:lanes], marks.reshape(lanes, c, -1))
         for i in (impl, "lax")), 2 ** -6)
     for name in ("sparse_latent_decode", "sparse_latent_prefill",
                  "lightning_indexer"):

@@ -848,7 +848,9 @@ class Latent(Paged):
             "cached latent rows x layers the latent kernels' walks "
             "copied: a decode token step a group's shared rows once a "
             "group and every slot's own rows, a prefill call what it "
-            "has to read")
+            "has to read (a selecting program: the rows every chunk "
+            "token sees, the whole blocks of pages under the first of "
+            "each eight tokens of a lane once for the eight)")
         pairs = reg.counter(
             "serving_latent_pairs_total",
             "(query token, cached row) pairs x layers the latent kernels "
@@ -956,19 +958,20 @@ class SelectingLatent(Latent):
     rope_lanes), ik_pages (num_pages, index_dim, page_size))``. The
     latent and the rotary key are BOTH token-major, because the attention
     folds the selected tokens' rows only (a row read by 128 heads costs
-    as much under a mask as selected): prefill gathers them
-    (``sparse_attention.sparse_latent_prefill``), decode compacts them
-    out of whole pages on the chip (``sparse_latent_decode``), and the
-    rotary key lies in the first lanes of a row of whole lane tiles
-    (``rope_lanes``: 128 for a key of 64; a token-major pool of 64 lanes
-    the chip's compiler keeps page-minor and re-lays whole before every
-    gather); the index keys lie tokens along the lanes, as
-    :class:`Selecting`'s. One path for every bucket: a table of at most
-    ``topk`` tokens selects all a query sees, through the same kernel.
-    Its decode reads the pages that a group of slots' tables open with
-    once for the group, as :class:`Selecting`'s does (the indexer's walk
-    of a slot's keys does not: it waits for its products, not for their
-    bytes)."""
+    as much under a mask as selected): both phases take the selection as
+    a mask and compact the selected rows out of whole pages on the chip
+    (``sparse_attention.sparse_latent_decode`` and, a chunk token a slot,
+    ``sparse_latent_prefill``), and the rotary key lies in the first
+    lanes of a row of whole lane tiles (``rope_lanes``: 128 for a key of
+    64; a token-major pool of 64 lanes the chip's compiler keeps
+    page-minor and re-lays whole); the index keys lie tokens along the
+    lanes, as :class:`Selecting`'s. One path for every bucket: a table
+    of at most ``topk`` tokens selects all a query sees, through the
+    same kernel. Its decode reads the pages that a group of slots'
+    tables open with once for the group, as :class:`Selecting`'s does,
+    and its prefill the pages under a lane's chunk once for eight of the
+    chunk's tokens (the indexer's walk of a slot's keys does not: it
+    waits for its products, not for their bytes)."""
 
     stat_names = Selecting.stat_names
     #: a call attends to ``topk`` rows whatever the table's width and the
@@ -1056,14 +1059,22 @@ class SelectingLatent(Latent):
 
     def count_prefill(self, span, starts, ns, heads=None):
         # chunk token j of a lane sees start + j + 1 rows and reads the
-        # topk it selects of them
+        # topk it selects of them; the walks copy every row it sees, the
+        # whole blocks of pages under the first token of its group of
+        # ``DECODE_GROUP`` once for the group
         starts, ns = (np.asarray(a, np.int64) for a in (starts, ns))
-        j = np.arange(int(ns.max()) if len(ns) else 0)
-        rows = int(np.where(j[None, :] < ns[:, None], np.minimum(
-            starts[:, None] + j[None, :] + 1, self.topk), 0).sum())
-        self._count(span, "prefill", rows, rows, rows)
-        self._c_held["prefill"].inc(
-            int((starts * ns + ns * (ns + 1) // 2).sum()) * self.layers)
+        j = np.arange(int(ns.max()) if len(ns) else 0)[None, :]
+        live = j < ns[:, None]
+        seen = starts[:, None] + j + 1
+        rows = int(np.where(live, np.minimum(seen, self.topk), 0).sum())
+        held = int(np.where(live, seen, 0).sum())
+        block = DA.GROUP_SHARED_PAGES * self.geo.page_size
+        first = j % DA.DECODE_GROUP == 0
+        shared = (starts[:, None] + j - j % DA.DECODE_GROUP + 1) \
+            // block * block
+        self._count(span, "prefill", rows, rows, held - int(
+            np.where(live & ~first, shared, 0).sum()))
+        self._c_held["prefill"].inc(held * self.layers)
         if len(starts) and self._selects(
                 -(-int((starts + ns).max()) // self.geo.page_size)):
             self._count_index(int((starts + ns)[ns > 0].sum()))

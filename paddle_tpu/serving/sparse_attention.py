@@ -44,10 +44,11 @@ Six kernels register with the shared kernel layer:
 
 ``sparse_latent_decode`` / ``sparse_latent_prefill`` — the same over a
   LATENT cache (one row a token that every head reads, so only selected
-  rows are folded): prefill gathers them by position; decode walks whole
-  pages as ``sparse_paged_decode`` does, a group's shared ones once, and
-  compacts each member's selected rows out of a page in VMEM by a
-  one-hot product before it folds them (further down).
+  rows are folded): both walk whole pages as ``sparse_paged_decode``
+  does, a group's shared ones once (a group: decoding slots over one
+  document, or eight chunk tokens of a prefill lane), and compact each
+  member's selected rows out of a page in VMEM by a one-hot product
+  before they fold them (further down).
 
 ``topk_selection_mask`` — the selection, one rule for decode and
   prefill, as the mask both attention kernels take: ``(R, T)`` scores and
@@ -1117,7 +1118,8 @@ def sparse_paged_prefill_attention(q, k_pages, v_pages, block_tables,
 
 
 # ---------------------------------------------------------------------------
-# selection over a latent cache: only the selected rows are folded
+# selection over a latent cache: whole pages read once a group of queries,
+# each member's selected rows compacted in VMEM and folded there
 # ---------------------------------------------------------------------------
 #
 # A latent row is read by EVERY head (128 of them at the published widths),
@@ -1128,247 +1130,33 @@ def sparse_paged_prefill_attention(q, k_pages, v_pages, block_tables,
 # rotary-key pool only, BOTH token-major (a row of 1 KB and one of 256
 # bytes a token; the rotary pool's rows are whole lane tiles, the key in
 # their first ``Dr`` lanes: a token-major pool of 64 lanes the chip's
-# compiler keeps page-minor and re-lays whole, 40 MB a layer, before every
-# gather). Chunked prefill, below, takes the selection as a list of cache
-# positions (``select_positions``), gathers the rows into ``(rows, topk,
-# .)`` (XLA's gather) and folds one query row's ``topk`` gathered rows
-# against all its heads in one grid step: scores ``(H, topk)``, one
-# softmax, one ``P C``; a chunk token is a query row, ``q_rows`` rows a
-# call so that the gathered copy stays small, a block of rows none of
-# which is live skipped. Decode, further down, gathers nothing: it walks
-# whole pages and compacts the selected rows out of them on the chip.
-
-#: query rows gathered and folded a call (a row's copy is ``topk`` x the
-#: cache row: 2.4 MB at the published widths)
-_LATENT_ROWS_A_CALL = 64
-
-
-def _gather_selected(c_pages, r_pages, block_tables, sel_idx):
-    """``sel_idx`` (R, K) cache positions under ``block_tables`` (R, mp)
-    -> the rows ``(R, K, Dl)``, ``(R, K, Dr)`` out of the two token-major
-    pools. A position past the table reads its last row (dead entries:
-    the fold masks them)."""
-    ps = c_pages.shape[1]
-    idx = jnp.clip(sel_idx, 0, block_tables.shape[1] * ps - 1)
-    flat = jnp.take_along_axis(block_tables, idx // ps, axis=1) * ps \
-        + idx % ps
-    return (c_pages.reshape(-1, c_pages.shape[-1])[flat],
-            r_pages.reshape(-1, r_pages.shape[-1])[flat])
-
-
-def _sparse_latent_kernel(n_ref, qc_ref, qr_ref, c_ref, r_ref, o_ref):
-    """One query row: ``qc_ref`` (1, H, Dl) / ``qr_ref`` (1, H, Dr) its
-    heads' absorbed queries, already scaled; ``c_ref`` (1, K, Dl) /
-    ``r_ref`` (1, K, Dr) its gathered rows, the first ``n_ref[row]`` of
-    them live; ``o_ref`` (1, H, Dl) each head's weighted sum of latents.
-    Scores, softmax and sums float32; the weights meet the latents in the
-    pool's type (one bf16 pass; a float32 pool at ``HIGHEST``)."""
-    n = n_ref[pl.program_id(0)]
-    c, r = c_ref[0], r_ref[0]
-    s = DA._pool_dot(qc_ref[0], c, 1) + DA._pool_dot(qr_ref[0], r, 1)
-    live = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < n
-    s = jnp.where(live, s, DA.NEG_INF)
-    m = jnp.max(s, axis=1, keepdims=True)
-    p = jnp.where(live, jnp.exp(s - m), 0.0)
-    denom = jnp.sum(p, axis=1, keepdims=True)
-    o = DA._pool_dot(p, c, 0) / jnp.where(denom == 0.0, 1.0, denom)
-    o_ref[0] = o.astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _sparse_latent_fold(q, cg, rg, n_sel, interpret, name):
-    """``q`` (R, H, Dl + Dr) over the gathered rows ``cg`` (R, K, Dl) /
-    ``rg`` (R, K, Dr) -> (R, H, Dl): grid ``(R,)``, a row's gathered rows
-    one block. Jitted, as the sparse decode's call is."""
-    r, h, _ = q.shape
-    k, dl = cg.shape[1:]
-    dr = rg.shape[-1]
-
-    def row(*shape):
-        return pl.BlockSpec((1,) + shape, lambda i, _n: (i, 0, 0))
-
-    return pl.pallas_call(
-        _sparse_latent_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(r,),
-            in_specs=[row(h, dl), row(h, dr), row(k, dl), row(k, dr)],
-            out_specs=row(h, dl)),
-        out_shape=jax.ShapeDtypeStruct((r, h, dl), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=DA._WIDE_VMEM_LIMIT) if not interpret else None,
-        interpret=interpret, name=name,
-    )(n_sel.astype(jnp.int32), q[..., :dl],
-      jnp.pad(q[..., dl:], ((0, 0), (0, 0), (0, dr - (q.shape[-1] - dl)))),
-      cg, rg)
-
-
-def _fold_lax(q, cg, rg, n_sel):
-    dl = cg.shape[-1]
-    qf, cf = q.astype(jnp.float32), cg.astype(jnp.float32)
-    s = jnp.einsum("rhd,rkd->rhk", qf[..., :dl], cf, precision=_FP32_DOT) \
-        + jnp.einsum("rhd,rkd->rhk", qf[..., dl:],
-                     rg[..., :q.shape[-1] - dl].astype(jnp.float32),
-                     precision=_FP32_DOT)
-    live = jnp.arange(cg.shape[1])[None, None, :] < n_sel[:, None, None]
-    return jnp.einsum("rhk,rkd->rhd", DA._latent_softmax(s, live), cf,
-                      precision=_FP32_DOT).astype(q.dtype)
-
-
-def _sparse_latent_rows(q, c_pages, r_pages, tables, sel_idx, n_sel, fold,
-                        rows_a_call):
-    """``q`` (R, H, D) query rows, each with its table row, selection and
-    live count -> (R, H, Dl): ``rows_a_call`` rows gathered and folded at
-    a time, a block with no live row skipped."""
-    r = q.shape[0]
-    blk = min(rows_a_call, r)
-    dl = c_pages.shape[-1]
-
-    def attend(qb, tb, ib, nb):
-        return fold(qb, *_gather_selected(c_pages, r_pages, tb, ib), nb)
-
-    if r <= blk:
-        return attend(q, tables, sel_idx, n_sel)
-    pad = -r % blk
-    blocks = tuple(
-        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
-            (-1, blk) + a.shape[1:]) for a in (q, tables, sel_idx, n_sel))
-    out = jax.lax.map(lambda b: jax.lax.cond(
-        jnp.any(b[3] > 0), lambda: attend(*b),
-        lambda: jnp.zeros(b[0].shape[:2] + (dl,), q.dtype)), blocks)
-    return out.reshape((-1,) + q.shape[1:-1] + (dl,))[:r]
-
-
-def _sparse_latent_prefill(q, c_pages, r_pages, block_tables, sel_idx, n_sel,
-                           fold, rows_a_call):
-    s, c = q.shape[:2]
-    tables = jnp.repeat(block_tables, c, axis=0)
-    out = _sparse_latent_rows(
-        q.reshape((s * c,) + q.shape[2:]), c_pages, r_pages, tables,
-        sel_idx.reshape(s * c, -1), n_sel.reshape(s * c), fold, rows_a_call)
-    return out.reshape((s, c) + out.shape[1:])
-
-
-def _sparse_latent_prefill_pallas(q, c_pages, r_pages, block_tables, sel_idx,
-                                  n_sel, *, block_sizes, interpret):
-    def fold(qb, cg, rg, nb):
-        return _sparse_latent_fold(qb, cg, rg, nb, interpret,
-                                   "sparse_latent_prefill")
-    return _sparse_latent_prefill(
-        q, c_pages, r_pages, block_tables, sel_idx, n_sel, fold,
-        block_sizes.get("q_rows", _LATENT_ROWS_A_CALL))
-
-
-def _sparse_latent_prefill_lax(q, c_pages, r_pages, block_tables, sel_idx,
-                               n_sel):
-    return _sparse_latent_prefill(q, c_pages, r_pages, block_tables, sel_idx,
-                                  n_sel, _fold_lax, _LATENT_ROWS_A_CALL)
-
-
-def _sparse_latent_reference(q, c_pages, r_pages, block_tables, sel_idx,
-                             n_sel):
-    """NumPy, a query row at a time over its live selected positions."""
-    import numpy as np
-    qn = np.asarray(q, np.float64)
-    chunked = qn.ndim == 4
-    if not chunked:
-        qn = qn[:, None]
-    s, c, h, _ = qn.shape
-    cp, rp = np.asarray(c_pages, np.float64), np.asarray(r_pages, np.float64)
-    ps, dl = cp.shape[1:]
-    bt = np.asarray(block_tables)
-    idx = np.asarray(sel_idx).reshape(s, c, -1)
-    n = np.asarray(n_sel).reshape(s, c)
-    out = np.zeros((s, c, h, dl))
-    for sl in range(s):
-        for t in range(c):
-            toks = idx[sl, t, :n[sl, t]]
-            if not len(toks):
-                continue
-            rows = np.concatenate(
-                [cp[bt[sl, toks // ps], toks % ps],
-                 rp[bt[sl, toks // ps], toks % ps][:, :qn.shape[-1] - dl]],
-                1)
-            sc = qn[sl, t] @ rows.T
-            pr = np.exp(sc - sc.max(-1, keepdims=True))
-            out[sl, t] = (pr / pr.sum(-1, keepdims=True)) @ rows[:, :dl]
-    out = out if chunked else out[:, 0]
-    return jnp.asarray(out).astype(q.dtype)
-
-
-def _make_sparse_latent_sample(seed):
-    """Three shapes by ``seed % 3``: float32 pools of pages scattered over
-    the pool; selections of distinct live positions in no order, rows that
-    select nothing, fewer than ``K`` and all ``K``; more rows than one
-    call folds, so that the blocks and the skipped block are driven."""
-    import numpy as np
-    s, c, h, dl, dr, ps, mp, k = (
-        (3, 4, 2, 16, 8, 8, 6, 8), (9, 8, 4, 32, 8, 8, 4, 16),
-        (2, 8, 4, 64, 16, 16, 5, 24))[seed % 3]
-    rng = np.random.default_rng(seed)
-    num_pages = s * mp + 1
-    scale = (dl + dr) ** -0.5
-    q = jnp.asarray(scale * rng.standard_normal((s, c, h, dl + dr)),
-                    jnp.float32)
-    c_pages = jnp.asarray(rng.standard_normal((num_pages, ps, dl)),
-                          jnp.float32)
-    # the rotary keys lie in rows of whole lane tiles (seed 2: as wide)
-    r_pages = jnp.asarray(rng.standard_normal(
-        (num_pages, ps, dr if seed % 3 == 2 else 2 * dr)), jnp.float32)
-    bt = jnp.asarray((rng.permutation(num_pages - 1)[:s * mp] + 1)
-                     .reshape(s, mp), jnp.int32)
-    idx = np.stack([rng.permutation(mp * ps)[:k] for _ in range(s * c)]
-                   ).reshape(s, c, k).astype(np.int32)
-    n = rng.integers(0, k + 1, (s, c)).astype(np.int32)
-    n.reshape(-1)[:3] = (0, k, 1)
-    if seed % 3 == 1:
-        n[2:] = 0                       # whole blocks of rows with none
-    return (q, c_pages, r_pages, bt, jnp.asarray(idx), jnp.asarray(n)), {}
-
-
-def _sparse_latent_vmem_estimate(args, kwargs, blocks):
-    """One grid step: a row's gathered latents and rotary keys and its
-    queries and output double-buffered by the pipeline, the float32
-    scores and weights, the weights in the pool's type."""
-    q, c_pages, r_pages, _bt, sel_idx = args[:5]
-    h, k = q.shape[-2], sel_idx.shape[-1]
-    dl, dr = c_pages.shape[-1], r_pages.shape[-1]
-    isz = c_pages.dtype.itemsize
-    pad = lambda n, m: -(-n // m) * m                       # noqa: E731
-    rows = pad(k, 16) * (pad(dl, 128) + pad(dr, 128)) * isz
-    heads = pad(h, 16) * (2 * pad(dl, 128) + pad(dr, 128)) * isz
-    scores = pad(h, 8) * pad(k, 128) * (4 + 4 + isz)
-    return 2 * (rows + heads) + scores
-
-
-# ---------------------------------------------------------------------------
-# selecting latent decode: whole pages read once a group of slots, each
-# member's selected rows compacted in VMEM and folded there
-# ---------------------------------------------------------------------------
+# compiler keeps page-minor and re-lays whole, 40 MB a layer).
 #
-# Decode takes the selection as the MASK (``select_decode_mask``) and makes
-# no copy of the selected rows in HBM: XLA's gather of them is bound by rows
-# (30 ns a row of 1 KB, 4% of the HBM peak: PERF.md section 6, PR 55), whole
-# pages by bytes, and the slots that ask about one published document read
-# the same pages. So the walk is ``sparse_paged_decode``'s (Part A a group
-# of slots over the pages their tables open with, Part B a slot over its
-# own from the state Part A left it, ``decode_attention._page_walk``), and
-# what is new is between the copy and the fold: a page in VMEM is not
-# folded under the mask (every one of its 128 rows would meet all 128
-# heads to keep 8) but COMPACTED first. A member's mask of the page gives
-# each selected row its rank among the page's selected rows (one product
-# with a triangle a block), the one-hot ``(members x width, page_size)``
-# of ranks ``first .. first + width`` times the page ``(page_size, Dl |
-# Dr)`` is every member's selected rows of those ranks, bit for bit (a row
-# times 1, the others times 0), and a page where some member selects more
-# than ``width`` rows takes further passes of the same product over the
-# next ranks, so nothing is dropped. A block's first passes (one a page,
-# straight-line code) fill a member's ``pages_per_block x width`` compacted
-# rows, which are folded against all its heads in one softmax update
-# (scores, maximum, sums float32; the weights meet the latents in the
-# pool's type, as ``_sparse_latent_kernel``'s do), the rows past a page's
-# count masked; the further passes collect in a second block of the same
-# shape, from block to block, folded when it is full and at the walk's end.
+# Both phases take the selection as the MASK (``select_decode_mask``) and
+# make no copy of the selected rows in HBM: XLA's gather of them is bound by
+# rows (30 ns a row of 1 KB, 4% of the HBM peak: PERF.md section 6, PR 55),
+# whole pages by bytes, and the queries that ask about one document (the
+# slots over a published one in decode, the tokens of a lane's chunk in
+# prefill) read the same pages. So the walk is ``sparse_paged_decode``'s
+# (Part A a group of queries over the pages their tables open with, Part B
+# a query over its own from the state Part A left it,
+# ``decode_attention._page_walk``), and what is new is between the copy and
+# the fold: a page in VMEM is not folded under the mask (every one of its
+# 128 rows would meet all 128 heads to keep 8) but COMPACTED first. A
+# member's mask of the page gives each selected row its rank among the
+# page's selected rows (one product with a triangle a block), the one-hot
+# ``(members x width, page_size)`` of ranks ``first .. first + width``
+# times the page ``(page_size, Dl | Dr)`` is every member's selected rows
+# of those ranks, bit for bit (a row times 1, the others times 0), and a
+# page where some member selects more than ``width`` rows takes further
+# passes of the same product over the next ranks, so nothing is dropped. A
+# block's first passes (one a page, straight-line code) fill a member's
+# ``pages_per_block x width`` compacted rows, which are folded against all
+# its heads in one softmax update (scores, maximum, sums float32; the
+# weights meet the latents in the pool's type: one bf16 pass, a float32
+# pool at ``HIGHEST``), the rows past a page's count masked; the further
+# passes collect in a second block of the same shape, from block to block,
+# folded when it is full and at the walk's end.
 
 #: rows a member's selection of one page is compacted into a pass: a bf16
 #: tile (2048 of 33k tokens select a mean of 7.85 of a page's 128)
@@ -1697,13 +1485,14 @@ def _sparse_latent_own_kernel(bt_ref, ext_ref, sp_ref, row_ref, q_ref,
         o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12))
 def _sparse_latent_decode_pallas(q, c_pages, r_pages, block_tables,
                                  selected, extent, group_slots, group_pages,
                                  shared_pages, interpret, pages_per_block,
-                                 width):
+                                 width, name):
     """The two ``pallas_call``s of ``sparse_latent_decode``, Part A a
-    group and Part B a slot, both under the kernel's one name. ``q`` is
+    group and Part B a slot, both under the one ``name`` (prefill, whose
+    chunk tokens are this kernel's slots, gives its own). ``q`` is
     absorbed and scaled. Jitted, so that a step program traces and lowers
     the bodies once and calls them from every layer."""
     s_slots, h, _ = q.shape
@@ -1718,7 +1507,7 @@ def _sparse_latent_decode_pallas(q, c_pages, r_pages, block_tables,
     if not interpret and (ps % 128 or dl % 128 or drl % 128
                           or width % (32 // c_pages.dtype.itemsize)):
         raise ValueError(
-            f"sparse_latent_decode copies whole pages out of the pools: "
+            f"{name} copies whole pages out of the pools: "
             f"pages of {ps} tokens, rows of {dl} + {drl} lanes, {width} "
             f"compacted rows a pass are not whole tiles")
     pb = max(1, min(int(pages_per_block), mp))
@@ -1783,7 +1572,7 @@ def _sparse_latent_decode_pallas(q, c_pages, r_pages, block_tables,
                                        jnp.float32),
         compiler_params=params,
         interpret=interpret,
-        name="sparse_latent_decode",
+        name=name,
     )(block_tables, group_slots, group_pages, *[q] * g, *[selected] * g,
       c_pages, r_pages)
 
@@ -1812,7 +1601,7 @@ def _sparse_latent_decode_pallas(q, c_pages, r_pages, block_tables,
         out_shape=jax.ShapeDtypeStruct((s_slots, rows, dl), q.dtype),
         compiler_params=params,
         interpret=interpret,
-        name="sparse_latent_decode",
+        name=name,
     )(block_tables, extent, shared_pages, state_rows, q, selected,
       states.reshape(spare + g, rows, state), c_pages, r_pages)
     return out[:, :h]
@@ -1826,7 +1615,7 @@ def _sparse_latent_decode_kernel_pallas(q, c_pages, r_pages, block_tables,
         q, c_pages, r_pages, block_tables, selected, extent, group_slots,
         group_pages, shared_pages, interpret,
         block_sizes.get("pages_per_block", DA.GROUP_SHARED_PAGES),
-        block_sizes.get("rows_a_pass", _COMPACT_ROWS))
+        block_sizes.get("rows_a_pass", _COMPACT_ROWS), "sparse_latent_decode")
 
 
 def _sparse_latent_decode_lax(q, c_pages, r_pages, block_tables, selected,
@@ -1860,17 +1649,43 @@ def _mask_of_positions(sel_idx, n_sel, t):
     return selected, jnp.max(jnp.where(live, sel_idx + 1, 0), axis=1)
 
 
+def _sparse_latent_reference(q, c_pages, r_pages, block_tables, selected):
+    """NumPy, a query row at a time over the rows its mask marks: ``q``
+    (S, H, D) with ``selected`` (S, T), or a chunk a lane, (S, C, H, D)
+    with (S, C, T)."""
+    import numpy as np
+    qn = np.asarray(q, np.float64)
+    chunked = qn.ndim == 4
+    if not chunked:
+        qn = qn[:, None]
+    s, c, h, _ = qn.shape
+    cp, rp = np.asarray(c_pages, np.float64), np.asarray(r_pages, np.float64)
+    ps, dl = cp.shape[1:]
+    bt = np.asarray(block_tables)
+    sel = (np.asarray(selected) > 0).reshape(s, c, -1)
+    out = np.zeros((s, c, h, dl))
+    for sl in range(s):
+        for t in range(c):
+            toks = np.flatnonzero(sel[sl, t])
+            if not len(toks):
+                continue
+            rows = np.concatenate(
+                [cp[bt[sl, toks // ps], toks % ps],
+                 rp[bt[sl, toks // ps], toks % ps][:, :qn.shape[-1] - dl]],
+                1)
+            sc = qn[sl, t] @ rows.T
+            pr = np.exp(sc - sc.max(-1, keepdims=True))
+            out[sl, t] = (pr / pr.sum(-1, keepdims=True)) @ rows[:, :dl]
+    out = out if chunked else out[:, 0]
+    return jnp.asarray(out).astype(q.dtype)
+
+
 def _sparse_latent_decode_reference(q, c_pages, r_pages, block_tables,
                                     selected, extent, *_groups):
-    """:func:`_sparse_latent_reference` over the positions the mask
-    marks: independent of both impls and of which slots share pages."""
-    import numpy as np
-    sel = np.asarray(selected) > 0
-    idx = np.zeros((sel.shape[0], max(int(sel.sum(1).max()), 1)), np.int32)
-    for sl, row in enumerate(sel):
-        idx[sl, :row.sum()] = np.flatnonzero(row)
-    return _sparse_latent_reference(q, c_pages, r_pages, block_tables, idx,
-                                    sel.sum(1))
+    """:func:`_sparse_latent_reference`: independent of both impls and of
+    which slots share pages."""
+    return _sparse_latent_reference(q, c_pages, r_pages, block_tables,
+                                    selected)
 
 
 def _make_sparse_latent_decode_sample(seed):
@@ -1910,22 +1725,21 @@ def _make_sparse_latent_decode_sample(seed):
             jnp.asarray(lengths)) + tuple(map(jnp.asarray, groups)), {}
 
 
-def _sparse_latent_decode_vmem_estimate(args, kwargs, blocks):
-    """VMEM working set of one grid step of the selecting latent decode,
-    the larger of its two parts, a group's: two buffers of ``pb`` pages
-    (a row the latent's lanes and the rotary key's), the members' queries
-    and selections and the group's state block double-buffered by the
-    pipeline, the queries stacked, the state, the compacted rows of the
-    first and of the further passes, a fold's scores, weights and decay,
-    and one pass's one-hot and product."""
-    q, c_pages, r_pages, bt = args[:4]
+def _compacting_vmem(heads, c_pages, r_pages, mp, g, blocks):
+    """VMEM working set of one grid step of the selecting latent kernels,
+    the larger of their two parts, a group's of ``g`` members of
+    ``heads`` heads: two buffers of ``pb`` pages (a row the latent's
+    lanes and the rotary key's), the members' queries and selections and
+    the group's state block double-buffered by the pipeline, the queries
+    stacked, the state, the compacted rows of the first and of the
+    further passes, a fold's scores, weights and decay, and one pass's
+    one-hot and product."""
     ps, dl = c_pages.shape[1:]
-    g, mp = args[6].shape[1], bt.shape[1]
     isz = c_pages.dtype.itemsize
     pb = min(blocks.get("pages_per_block", DA.GROUP_SHARED_PAGES), mp)
     width = blocks.get("rows_a_pass", _COMPACT_ROWS)
     pad = lambda n, m: -(-n // m) * m                       # noqa: E731
-    h = pad(q.shape[-2], 16)
+    h = pad(heads, 16)
     rows = pad(pb * width, 16)
     n = pad(rows, 128)
     lanes = pad(dl, 128) + pad(r_pages.shape[-1], 128)
@@ -1939,6 +1753,12 @@ def _sparse_latent_decode_vmem_estimate(args, kwargs, blocks):
     one_pass = g * pad(width, 16) * (pad(ps, 128) * (4 + isz)
                                      + lanes * (4 + isz))
     return pages + io + queries + state + compacted + fold + one_pass
+
+
+def _sparse_latent_decode_vmem_estimate(args, kwargs, blocks):
+    q, c_pages, r_pages, bt = args[:4]
+    return _compacting_vmem(q.shape[-2], c_pages, r_pages, bt.shape[1],
+                            args[6].shape[1], blocks)
 
 
 def sparse_latent_decode_attention(q, c_pages, r_pages, block_tables,
@@ -1975,14 +1795,186 @@ def selected_latent_decode_attention(q, c_pages, r_pages, block_tables,
                             impl=impl)
 
 
+# ---------------------------------------------------------------------------
+# selecting latent prefill: a chunk's tokens through the same two parts,
+# eight rows of a lane a walk
+# ---------------------------------------------------------------------------
+#
+# A chunk token is to the kernel above what a decoding slot is: a query row
+# ``(H, Dl + Dr)`` with a mask of its own, its lane's table and an extent
+# (``chunk_starts[s] + c + 1`` rows, itself the last; 0 for a pad token,
+# whose walk is empty). Eight rows of a lane in a row are a group whose
+# members share EVERY page under the first one's extent, so Part A walks
+# those once for the eight (whole blocks of ``GROUP_SHARED_PAGES``) and
+# Part B each row's tail from there to its own extent: a page or two over
+# a published document, up to nine pages while a document is published.
+# A lane's rows are padded to whole groups, so that a group's members are
+# one lane's (one table) and its dead rows its last; ``q_rows`` rows are
+# one call of the two parts, a call none of whose rows is live skipped.
+
+#: query rows a call of the two parts: 8 groups of ``DECODE_GROUP`` (a
+#: call's masks are 8.5 MB at the published widths)
+_LATENT_ROWS_A_CALL = 64
+
+
+def _chunk_extents(chunk_starts, n_valid, c):
+    """(S, c) int32: the rows chunk token ``c`` of lane ``s`` sees, itself
+    the last (``chunk_starts[s] + c + 1``); 0 for a pad token."""
+    tok = jnp.arange(c, dtype=jnp.int32)[None, :]
+    return jnp.where(tok < n_valid[:, None], chunk_starts[:, None] + tok + 1,
+                     0)
+
+
+def _by_live_blocks(fn, n, arrays, row, rows_a_call=_LATENT_ROWS_A_CALL):
+    """``fn(n, *arrays)`` of ``n`` (R,) and ``arrays`` (R, ...),
+    ``rows_a_call`` rows at a time, a row of the result as ``row``
+    (``ShapeDtypeStruct``) describes it; a block in which every ``n`` is
+    0 (pad tokens) is skipped and reads zeros."""
+    r = n.shape[0]
+    if r <= rows_a_call:
+        return fn(n, *arrays)
+    pad = -r % rows_a_call
+    blocks = tuple(
+        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
+            (-1, rows_a_call) + a.shape[1:]) for a in (n,) + tuple(arrays))
+    out = jax.lax.map(lambda b: jax.lax.cond(
+        jnp.any(b[0] > 0), lambda: fn(*b),
+        lambda: jnp.zeros((rows_a_call,) + row.shape, row.dtype)), blocks)
+    return out.reshape((-1,) + row.shape)[:r]
+
+
+def _sparse_latent_prefill(q, c_pages, block_tables, chunk_starts, n_valid,
+                           selected, attend, rows_a_call):
+    """The chunk tokens as rows, a lane's padded to whole groups:
+    ``attend(extent (R,), q (R, H, D), tables (R, mp), selected (R, T))``
+    -> (R, H, Dl), ``rows_a_call`` rows at a time."""
+    s, c = q.shape[:2]
+    g = DA.DECODE_GROUP
+    if rows_a_call % g:
+        raise ValueError(f"sparse_latent_prefill walks groups of {g} rows: "
+                         f"{rows_a_call} rows a call are no whole groups")
+    pad = ((0, 0), (0, -c % g))
+    extent = _chunk_extents(chunk_starts, n_valid, c + pad[1][1])
+    rows = tuple(a.reshape((-1,) + a.shape[2:]) for a in (
+        extent, jnp.pad(q, pad + ((0, 0), (0, 0))),
+        jnp.repeat(block_tables[:, None], extent.shape[1], axis=1),
+        jnp.pad(selected, pad + ((0, 0),))))
+    out = _by_live_blocks(
+        attend, rows[0], rows[1:],
+        jax.ShapeDtypeStruct((q.shape[2], c_pages.shape[-1]), q.dtype),
+        rows_a_call)
+    return out.reshape((s, -1) + out.shape[1:])[:, :c]
+
+
+def _sparse_latent_prefill_pallas(q, c_pages, r_pages, block_tables,
+                                  chunk_starts, n_valid, selected, *,
+                                  block_sizes, interpret):
+    g, ps = DA.DECODE_GROUP, c_pages.shape[1]
+    pb = block_sizes.get("pages_per_block", DA.GROUP_SHARED_PAGES)
+
+    def attend(extent, qb, tables, marks):
+        # a group's shared pages: the whole blocks under its first row's
+        # extent (none where that row is dead: the whole group is)
+        shared = extent.reshape(-1, g)[:, 0] // ps \
+            // DA.GROUP_SHARED_PAGES * DA.GROUP_SHARED_PAGES
+        return _sparse_latent_decode_pallas(
+            qb, c_pages, r_pages, tables, marks, extent,
+            jnp.arange(extent.shape[0], dtype=jnp.int32).reshape(-1, g),
+            shared, jnp.repeat(shared, g), interpret, pb,
+            block_sizes.get("rows_a_pass", _COMPACT_ROWS),
+            "sparse_latent_prefill").astype(q.dtype)
+
+    return _sparse_latent_prefill(
+        q, c_pages, block_tables, chunk_starts, n_valid, selected, attend,
+        block_sizes.get("q_rows", _LATENT_ROWS_A_CALL))
+
+
+def _sparse_latent_prefill_lax(q, c_pages, r_pages, block_tables,
+                               chunk_starts, n_valid, selected):
+    """The masked form :func:`_sparse_latent_decode_lax` is, a chunk token
+    a row: every row of its lane's table scored."""
+    def attend(extent, qb, tables, marks):
+        seen = jnp.arange(marks.shape[1])[None, :] < extent[:, None]
+        return _sparse_latent_decode_lax(qb, c_pages, r_pages, tables,
+                                         jnp.where(seen, marks, 0.0), extent)
+
+    return _sparse_latent_prefill(
+        q, c_pages, block_tables, chunk_starts, n_valid, selected, attend,
+        _LATENT_ROWS_A_CALL)
+
+
+def _sparse_latent_prefill_reference(q, c_pages, r_pages, block_tables,
+                                     chunk_starts, n_valid, selected):
+    """:func:`_sparse_latent_reference` over what each live chunk token's
+    mask marks of the rows it sees."""
+    import numpy as np
+    c = q.shape[1]
+    tok = np.arange(c)[None, :]
+    extent = np.where(tok < np.asarray(n_valid)[:, None],
+                      np.asarray(chunk_starts)[:, None] + tok + 1, 0)
+    sel = np.asarray(selected) > 0
+    seen = np.arange(sel.shape[-1])[None, None, :] < extent[..., None]
+    return _sparse_latent_reference(q, c_pages, r_pages, block_tables,
+                                    sel & seen)
+
+
+def _make_sparse_latent_prefill_sample(seed):
+    """Three shapes by ``seed % 3``: float32 pools of pages scattered over
+    the pool, the rotary keys in rows wider than the key (seed 2: as
+    wide); chunks of 4, 8 and 24 tokens (half a group, one, three); lanes
+    that start at 0 (no shared page), deep in their tables (whole blocks
+    shared) and not at all (dead: seed 1 has more rows than a call takes,
+    whole calls of them dead); selections by the engine's rule, crowded
+    into a few pages, the pad tokens' rows marked like any other (the
+    kernel reads none of them)."""
+    import numpy as np
+    s, c, h, dl, dr, ps, mp, topk = (
+        (3, 4, 2, 16, 8, 8, 12, 12), (11, 8, 4, 32, 8, 8, 11, 20),
+        (2, 24, 4, 64, 16, 16, 10, 48))[seed % 3]
+    rng = np.random.default_rng(seed)
+    num_pages = s * mp + 1
+    q = jnp.asarray((dl + dr) ** -0.5 * rng.standard_normal(
+        (s, c, h, dl + dr)), jnp.float32)
+    c_pages = jnp.asarray(rng.standard_normal((num_pages, ps, dl)),
+                          jnp.float32)
+    r_pages = jnp.asarray(rng.standard_normal(
+        (num_pages, ps, dr if seed % 3 == 2 else 2 * dr)), jnp.float32)
+    bt = jnp.asarray((rng.permutation(num_pages - 1)[:s * mp] + 1)
+                     .reshape(s, mp), jnp.int32)
+    starts = rng.integers(0, mp * ps - c + 1, s).astype(np.int32)
+    n_valid = rng.integers(1, c + 1, s).astype(np.int32)
+    starts[:2] = (0, mp * ps - c)
+    n_valid[:2] = (c, c - 1)
+    if seed % 3 == 1:
+        n_valid[2:] = 0
+        n_valid[-1] = 3                 # dead calls between live ones
+    scores = rng.standard_normal((s, c, mp * ps)) \
+        + 3.0 * np.repeat(rng.standard_normal((s, 1, mp)), ps, axis=2)
+    starts, n_valid = jnp.asarray(starts), jnp.asarray(n_valid)
+    selected = select_prefill(jnp.asarray(scores, jnp.float32), starts,
+                              n_valid, topk)
+    return (q, c_pages, r_pages, bt, starts, n_valid, selected), {}
+
+
+def _sparse_latent_prefill_vmem_estimate(args, kwargs, blocks):
+    q, c_pages, r_pages, bt = args[:4]
+    return _compacting_vmem(q.shape[-2], c_pages, r_pages, bt.shape[1],
+                            DA.DECODE_GROUP, blocks)
+
+
 def sparse_latent_prefill_attention(q, c_pages, r_pages, block_tables,
-                                    sel_idx, n_sel, *, impl: str = "auto"):
+                                    chunk_starts, n_valid, selected, *,
+                                    impl: str = "auto"):
     """The same for a chunk of queries a lane, each with a selection of
-    its own: ``q`` (S, C, H, Dl + Dr), ``sel_idx`` (S, C, K), ``n_sel``
-    (S, C), 0 for a pad token. Returns (S, C, H, Dl)."""
+    its own: ``q`` (S, C, H, Dl + Dr), ``selected`` (S, C, mp *
+    page_size), query ``c`` of lane ``s`` attending to the rows its mask
+    marks, none at or past ``chunk_starts[s] + c + 1``; the first
+    ``n_valid[s]`` queries of a lane are live, the others read zeros.
+    Returns (S, C, H, Dl)."""
     from paddle_tpu import kernels
     return kernels.dispatch("sparse_latent_prefill", q, c_pages, r_pages,
-                            block_tables, sel_idx, n_sel, impl=impl)
+                            block_tables, chunk_starts, n_valid, selected,
+                            impl=impl)
 
 
 # ---------------------------------------------------------------------------
@@ -2032,81 +2024,6 @@ def indexed_prefill_attention(q, k_pages, v_pages, ik_pages, block_tables,
         impl=impl)
 
 
-def _mask_positions(mask, topk):
-    """The positions a selection mask marks, in order: ``mask`` (R, T)
-    float32 of at most ``topk`` ones a row -> (R, topk) int32, slot ``k``
-    the position of the row's ``k``-th one; a slot past the row's count
-    reads past ``T``. No sort and no scatter: counts within blocks of
-    lanes by one product with a triangle, the block of slot ``k`` by
-    counting the blocks that end before it, the block's running counts
-    brought to the slot by a one-hot product, its lane by counting those
-    at or under its rank (every product exact in bfloat16: counts of at
-    most 128)."""
-    r, t = mask.shape
-    b = next(w for w in (128, 64, 32, 16, 8, 4, 2, 1) if t % w == 0)
-    nb = t // b
-    tri = (jnp.arange(b)[:, None] <= jnp.arange(b)[None, :]).astype(
-        jnp.bfloat16)
-    running = jnp.einsum("rnb,bc->rnc",
-                         mask.reshape(r, nb, b).astype(jnp.bfloat16), tri,
-                         preferred_element_type=jnp.float32)
-    count = running[..., -1]                               # (R, nb)
-    ends = jnp.cumsum(count, axis=1)
-    slot = jnp.arange(topk, dtype=jnp.float32)
-    before = ends[:, None, :] <= slot[None, :, None]       # (R, K, nb)
-    block = before.sum(-1, dtype=jnp.int32)
-    rank = slot[None, :] - jnp.sum(before * count[:, None, :], -1)
-    mine = (block[..., None] == jnp.arange(nb)).astype(jnp.bfloat16)
-    counts = jnp.einsum("rkn,rnc->rkc", mine, running.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.bfloat16)
-    lane = jnp.sum(counts.astype(jnp.float32) <= rank[..., None], -1,
-                   dtype=jnp.int32)
-    return block * b + lane
-
-
-def select_positions(scores, n, topk, *, impl: str = "auto"):
-    """The rule as a list of cache positions, what a kernel that GATHERS
-    its rows takes: ``scores`` (R, T), ``n`` (R,) visible -> (positions
-    (R, topk) in ascending order, how many of them are live (R,)): the
-    engine's mask (:func:`select_decode_mask`, by counting) read out by
-    :func:`_mask_positions`. The positions :func:`select_decode` returns
-    best first, as a set."""
-    mask = select_decode_mask(scores, n, topk, impl=impl)
-    return _mask_positions(mask, topk), jnp.minimum(n, topk)
-
-
-def _select_rows(scores, n, topk, live, impl,
-                 rows_a_call=_LATENT_ROWS_A_CALL):
-    """:func:`select_positions` of ``scores`` (R, T), ``rows_a_call`` rows
-    at a time, a block none of whose rows is ``live`` (R,) skipped:
-    (positions (R, topk), how many of them are live (R,), 0 for a dead
-    row)."""
-    r, t = scores.shape
-    n = jnp.where(live, n, 0)
-    blk = min(rows_a_call, r)
-    if r <= blk:
-        return select_positions(scores, n, topk, impl=impl)
-    pad = -r % blk
-
-    def one(args):
-        sc, nb = args
-        return jax.lax.cond(
-            jnp.any(nb > 0),
-            lambda: select_positions(sc, nb, topk, impl=impl)[0],
-            lambda: jnp.zeros((blk, topk), jnp.int32))
-
-    idx = jax.lax.map(one, (
-        jnp.pad(scores, ((0, pad), (0, 0))).reshape(-1, blk, t),
-        jnp.pad(n, (0, pad)).reshape(-1, blk)))
-    return idx.reshape(-1, topk)[:r], jnp.minimum(n, topk)
-
-
-def _every_position(shape, t):
-    """The selection of a bucket no wider than ``topk``: every position
-    of the table, in order (the live count says how many a query sees)."""
-    return jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), shape + (t,))
-
-
 def latent_indexed_decode_attention(q, c_pages, r_pages, ik_pages,
                                     block_tables, lengths, q_idx, w_idx,
                                     topk, *, groups=None,
@@ -2140,21 +2057,25 @@ def latent_indexed_prefill_attention(q, c_pages, r_pages, ik_pages,
                                      impl: str = "auto"):
     """The same for a chunk of queries a lane, ``q`` (S, C, H, Dl + Dr):
     query ``c`` of lane ``s`` sees ``chunk_starts[s] + c + 1`` tokens and
-    selects among them. Returns (S, C, H, Dl)."""
+    selects among them; the selection reaches the kernel as a mask too,
+    made ``_LATENT_ROWS_A_CALL`` rows at a time where any of them is live
+    (a pad token sees nothing and marks nothing). Returns (S, C, H, Dl)."""
     s, c = q.shape[:2]
     t = block_tables.shape[1] * c_pages.shape[1]
-    n = chunk_starts[:, None] + jnp.arange(1, c + 1, dtype=jnp.int32)
-    live = jnp.arange(c)[None, :] < n_valid[:, None]
+    n = _chunk_extents(chunk_starts, n_valid, c)
     if t <= topk:
-        idx, n_sel = _every_position((s, c), t), jnp.where(live, n, 0)
+        selected = (jnp.arange(t, dtype=jnp.int32) < n[..., None]).astype(
+            jnp.float32)
     else:
         scores = lightning_index_scores(q_idx, w_idx, ik_pages, block_tables,
                                         chunk_starts + n_valid, impl=impl)
-        idx, n_sel = _select_rows(scores.reshape(s * c, t), n.reshape(s * c),
-                                  topk, live.reshape(s * c), impl)
-        idx, n_sel = idx.reshape(s, c, topk), n_sel.reshape(s, c)
-    return sparse_latent_prefill_attention(q, c_pages, r_pages, block_tables,
-                                           idx, n_sel, impl=impl)
+        selected = _by_live_blocks(
+            lambda nb, sc: select_decode_mask(sc, nb, topk, impl=impl),
+            n.reshape(s * c), (scores.reshape(s * c, t),),
+            jax.ShapeDtypeStruct((t,), jnp.float32)).reshape(s, c, t)
+    return sparse_latent_prefill_attention(
+        q, c_pages, r_pages, block_tables, chunk_starts, n_valid, selected,
+        impl=impl)
 
 
 # ---------------------------------------------------------------------------
@@ -2353,32 +2274,41 @@ def _register():
     kernels.register(kernels.KernelSpec(
         name="sparse_latent_prefill",
         contract=kernels.KernelContract(
-            version=1,
+            version=2,
             arg_layouts={"q": "(S,C,H,Dl+Dr)",
                          "c_pages": "(P,ps,Dl)", "r_pages": "(P,ps,>=Dr)",
                          "block_tables": "(S,mp) i32",
-                         "sel_idx": "(S,C,K) i32",
-                         "n_sel": "(S,C) i32"},
+                         "chunk_starts": "(S,) i32",
+                         "n_valid": "(S,) i32",
+                         "selected": "(S,C,mp*ps) f32"},
             out_layout="(S,C,H,Dl)",
-            grid="the selected rows of both token-major pools gathered "
-                 "by XLA, q_rows query rows a call (a block of rows "
-                 "with none live skipped), then (q_rows,) one step a "
-                 "query row: its K gathered rows one block against all "
-                 "its heads, scores (H,K), one softmax, one P C",
-            block_candidates={"q_rows": (64, 32, 128)},
+            grid="sparse_latent_decode's two calls under this kernel's "
+                 "name, a chunk token a slot (its lane's table, its own "
+                 "mask, extent chunk_starts + c + 1; 0 for a pad token), "
+                 "q_rows rows a pair of calls, a pair with no live row "
+                 "skipped: (q_rows/8,) one step a group of 8 rows of a "
+                 "lane over the whole blocks of pages under the first "
+                 "one's extent, walked once; then (q_rows,) one step a "
+                 "row over its own pages from there to its extent. The "
+                 "selected rows compacted out of whole pages in VMEM and "
+                 "folded there, no positions and no copy of them in HBM",
+            # (the members a walk are decode_attention.DECODE_GROUP, as
+            # decode's: PERF.md section 6, PR 58)
+            block_candidates={"q_rows": (64,), "pages_per_block": (8,),
+                              "rows_a_pass": (16,)},
             atol=2e-5, rtol=2e-5),
         pallas_fn=_sparse_latent_prefill_pallas,
         lax_fn=_sparse_latent_prefill_lax,
-        reference_fn=_sparse_latent_reference,
-        sample_inputs=_make_sparse_latent_sample,
-        pallas_sites=(
-            "paddle_tpu.serving.sparse_attention:_sparse_latent_fold",),
+        reference_fn=_sparse_latent_prefill_reference,
+        sample_inputs=_make_sparse_latent_prefill_sample,
+        pallas_sites=("paddle_tpu.serving.sparse_attention:"
+                      "_sparse_latent_decode_pallas",),
         tune_signature=lambda args, kwargs: (
-            ("r", math.prod(args[0].shape[:-2])),
-            ("h", args[0].shape[-2]), ("dl", args[1].shape[-1]),
+            ("s", args[0].shape[0]), ("c", args[0].shape[1]),
+            ("h", args[0].shape[2]), ("dl", args[1].shape[-1]),
             ("dr", args[0].shape[-1] - args[1].shape[-1]),
-            ("k", args[4].shape[-1])),
-        vmem_estimate=_sparse_latent_vmem_estimate))
+            ("ps", args[1].shape[1]), ("mp", args[3].shape[1])),
+        vmem_estimate=_sparse_latent_prefill_vmem_estimate))
 
 
 _register()

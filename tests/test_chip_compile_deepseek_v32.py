@@ -64,7 +64,7 @@ DECODE = ((((S, H, DL + DR), BF),) + POOLS + (
     ((S, MP), I32), ((S, MP * PS), F32), ((S,), I32), ((S // 2, G), I32),
     ((S // 2,), I32), ((S,), I32)))
 PREFILL = ((((2, C, H, DL + DR), BF),) + POOLS + (
-    ((2, MP), I32), ((2, C, K), I32), ((2, C), I32)))
+    ((2, MP), I32), ((2,), I32), ((2,), I32), ((2, C, MP * PS), F32)))
 
 
 @pytest.mark.parametrize("name, args", [("sparse_latent_decode", DECODE),
@@ -75,10 +75,10 @@ def test_selecting_latent_kernels_compile_and_copy_no_pool(name, args,
     walk whole pages of both token-major pools where they lie, compact
     the selected rows in VMEM and fold them are the only custom calls,
     both under the kernel's own name, and no gathered copy is left in the
-    program. Prefill: the gather reads both pools where they lie (a
-    rotary pool of 64 lanes would be re-laid whole before every gather:
-    the reason its rows are 128 lanes wide), and the fold is the one
-    custom call."""
+    program. Prefill, two lanes of a chunk of 256 from the mask: the same
+    two parts under ITS name, 64 rows a pair of calls, no gather and no
+    copy of a pool (a rotary pool of 64 lanes would be re-laid whole:
+    the reason its rows are 128 lanes wide)."""
     spec = kernels.get(name)
     blocks = autotune.static_prior(
         spec, tuple(jax.ShapeDtypeStruct(*a) for a in args), {})
@@ -89,16 +89,14 @@ def test_selecting_latent_kernels_compile_and_copy_no_pool(name, args,
     assert _custom_calls(text) == [name]
     assert not re.findall(r"= bf16\[%d,%d,\d+\]\S* copy\(" % (P, PS), text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
-    if name == "sparse_latent_decode":
-        assert not re.findall(r" gather\(", text)
-        assert f"bf16[{S},{K}," not in text
+    assert not re.findall(r" gather\(", text)
+    assert f",{K}," not in text
 
 
 def test_the_decode_of_a_layer_compiles_from_the_mask(one_chip):
     """Indexer, counting mask and the grouped decode as the engine's step
     runs them: three kernels' custom calls, no sort, no scatter and no
-    gather in the program (the mask's read-out as positions is
-    prefill's)."""
+    gather in the program."""
     text = _compiled(
         lambda q, c, r, ik, bt, n, qi, wi, *groups:
         SA.latent_indexed_decode_attention(
@@ -110,6 +108,25 @@ def test_the_decode_of_a_layer_compiles_from_the_mask(one_chip):
                                    "sparse_latent_decode",
                                    "topk_selection_mask"]
     assert not re.findall(r" sort\(| scatter\(| gather\(", text)
+
+
+def test_the_prefill_of_a_layer_compiles_from_the_mask(one_chip):
+    """The same for a prefill call of two lanes of 256 over 33,408 rows:
+    the indexer, the mask 64 rows at a time and the two parts under the
+    prefill's name; no positions are read out of the mask and no row is
+    gathered (no sort, scatter or gather), and no pool is copied."""
+    text = _compiled(
+        lambda q, c, r, ik, bt, st, nv, qi, wi:
+        SA.latent_indexed_prefill_attention(
+            q, c, r, ik, bt, st, nv, qi, wi, K, impl="pallas"),
+        one_chip, ((2, C, H, DL + DR), BF), *POOLS, ((P, DI, PS), BF),
+        ((2, MP), I32), ((2,), I32), ((2,), I32), ((2, C, J, DI), BF),
+        ((2, C, J), F32)).as_text()
+    assert _custom_calls(text) == ["lightning_indexer",
+                                   "sparse_latent_prefill",
+                                   "topk_selection_mask"]
+    assert not re.findall(r" sort\(| scatter\(| gather\(", text)
+    assert not re.findall(r"= bf16\[%d,%d,\d+\]\S* copy\(" % (P, PS), text)
 
 
 @pytest.mark.parametrize("chunk", [1, C], ids=["decode", "chunk_of_256"])
@@ -129,10 +146,10 @@ def test_the_indexer_compiles_at_64_heads_of_128(chunk, one_chip):
 
 def test_the_selection_compiles_over_rows_of_33408(one_chip):
     """The counting mask over a slot's whole table (261 chunks of 128
-    lanes a pass) and its read-out as positions, no sort and no scatter
-    in the program."""
+    lanes a pass), what both attention kernels take: no sort and no
+    scatter in the program."""
     text = _compiled(
-        lambda a, n: SA.select_positions(a, n, K, impl="pallas"), one_chip,
+        lambda a, n: SA.select_decode_mask(a, n, K, impl="pallas"), one_chip,
         ((S, MP * PS), F32), ((S,), I32)).as_text()
     assert _custom_calls(text) == ["topk_selection_mask"]
     assert not re.findall(r" sort\(| scatter\(", text)

@@ -31,7 +31,7 @@ ONE_CHIP_PHASES = {
                                "ragged_paged_prefill[kv2,float32]",
                                "wide-key prefill lowerings"],
     "phase_selecting_latent_kernels": [
-        "selection positions vs lax.top_k", "0 differ",
+        "selection mask vs lax.top_k", "0 differ",
         "selecting latent kernels vs lax", "indexer chunk",
         "sparse_latent_decode, every slot alone",
         "sparse_latent_decode vs NumPy"],

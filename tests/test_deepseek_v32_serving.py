@@ -367,9 +367,10 @@ def test_a_borrower_of_published_pages_reads_what_a_fresh_prefill_writes(
 def test_counters_of_the_selected_rows(model_and_params, engines):
     """One request of 21 + 7 tokens alone: rows read and pairs are the
     SELECTED rows (min(seen, 16) a query), held what the queries see, the
-    index rows those of the buckets that select; prefill fetches the rows
-    it selects (it gathers them), decode the rows its walk copies: every
-    live row of a slot walked alone."""
+    index rows those of the buckets that select; either phase fetches the
+    rows its walks copy: every row a query sees, where no whole block of
+    8 pages lies under a group of chunk tokens and the slot decodes
+    alone."""
     eng, _sink, reg = engines("lax")
     before = reg.snapshot()
     with traced(eng) as tracer:
@@ -378,11 +379,12 @@ def test_counters_of_the_selected_rows(model_and_params, engines):
     # prefill calls of 8, 8 and 5 tokens at 0, 8 and 16 held
     seen = list(range(1, 22))
     read = sum(min(n, TOPK) for n in seen) * LAYERS
-    for name in ("rows_read", "pairs", "rows_fetched"):
+    for name in ("rows_read", "pairs"):
         assert snap[f'serving_latent_{name}_total{{phase="prefill"}}'] \
             == read
-    assert snap['serving_latent_rows_held_total{phase="prefill"}'] \
-        == sum(seen) * LAYERS
+    for name in ("rows_held", "rows_fetched"):
+        assert snap[f'serving_latent_{name}_total{{phase="prefill"}}'] \
+            == sum(seen) * LAYERS
     # decode blocks of 2 from 21 tokens on: the first token is prefill's,
     # so 6 more; step j of a block at L held sees L + j + 1
     steps = range(21, 27)
@@ -454,19 +456,72 @@ def test_requests_over_a_published_document_decode_folded(model_and_params,
     assert not np.asarray(kept.kept[2][1]).any()
 
 
+def test_prefill_counts_what_its_walks_copy(engines):
+    """``serving_latent_rows_fetched_total{phase="prefill"}`` from the
+    ``starts`` and ``ns`` the host holds: eight chunk tokens of a lane are
+    a group, the whole blocks of 8 pages under its first token copied
+    once for the group and every token's rows from there to itself once
+    for the token; held is what the tokens see."""
+    eng, _sink, reg = engines("lax")
+    kind = eng.cache.config.kinds[0]
+    starts, ns = np.asarray([0, 70, 200, 127, 300]), \
+        np.asarray([8, 5, 16, 3, 0])
+    before = reg.snapshot()
+    kind.count_prefill(None, starts, ns)
+    snap = moved(reg, before)
+    block = 8 * PAGE
+    fetched = held = 0
+    for start, n in zip(starts, ns):
+        for first in range(0, n, 8):
+            shared = (start + first + 1) // block * block
+            seen = [start + j + 1 for j in range(first, min(first + 8, n))]
+            fetched += shared + sum(e - shared for e in seen)
+            held += sum(seen)
+    assert (200 + 9) // block * block == 192 and held > fetched
+    assert snap['serving_latent_rows_fetched_total{phase="prefill"}'] \
+        == fetched * LAYERS
+    assert snap['serving_latent_rows_held_total{phase="prefill"}'] \
+        == held * LAYERS
+
+
 # -- the kernels ----------------------------------------------------------------
 
-@pytest.mark.parametrize("name, blocks", [
-    ("sparse_latent_prefill", dict(q_rows=4)),
-    ("sparse_latent_prefill", dict(q_rows=64)),
-    ("sparse_latent_decode", dict(pages_per_block=8, rows_a_pass=16)),
-    ("sparse_latent_decode", dict(pages_per_block=2, rows_a_pass=4)),
-    ("sparse_latent_decode", dict(pages_per_block=4, rows_a_pass=1)),
-], ids=str)
-@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.fixture
+def released():
+    """An interpreted walk-and-compact program maps about 1,500 memory
+    regions of the 65,530 a process may hold (``vm.max_map_count``), and
+    a test worker keeps every program it compiled: a case that compiles
+    one of its own gives it back."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _programs_released_after_the_file():
+    yield
+    jax.clear_caches()
+
+
+
+_WALK = dict(pages_per_block=8, rows_a_pass=16)
+
+
+@pytest.mark.parametrize("name, blocks, seed", [
+    (name, blocks, seed) for name, blocks, seeds in (
+        ("sparse_latent_prefill",
+         dict(q_rows=8, pages_per_block=2, rows_a_pass=4), (1, 2)),
+        # (88 rows: the one sample that is more than a pair of calls of 64)
+        ("sparse_latent_prefill", dict(q_rows=64, **_WALK), (1,)),
+        ("sparse_latent_decode", _WALK, (0, 1, 2)),
+        ("sparse_latent_decode", dict(pages_per_block=2, rows_a_pass=4),
+         (0, 1, 2)),
+        ("sparse_latent_decode", dict(pages_per_block=4, rows_a_pass=1),
+         (0, 1, 2))) for seed in seeds], ids=str)
+@pytest.mark.usefixtures("released")
 def test_selecting_latent_kernels_at_every_block_size(name, blocks, seed):
-    """Prefill: rows folded four at a time (blocks of rows none of which
-    is live are skipped) and all in one call. Decode: blocks of 8, 2 and 4
+    """Prefill: a group of rows a pair of calls (pairs none of whose rows
+    is live are skipped) and up to 64, under blocks of 2 and 8 pages and 4
+    and 16 compacted rows a pass. Decode: blocks of 8, 2 and 4
     pages (tables of 12, 10 and 18: ragged last blocks), 16, 4 and 1
     compacted rows a pass, so that pages of 8 and 16 tokens take further
     passes; a pair, three and a pair, a whole group and a slot alone."""
@@ -576,9 +631,9 @@ def test_a_table_no_wider_than_topk_selects_every_live_row(impl):
         q, c_pages, r_pages, None, tables, lengths, None, None, mp * ps,
         impl=impl)
     np.testing.assert_array_equal(np.asarray(n_sel), np.asarray(lengths))
-    idx = np.broadcast_to(np.arange(mp * ps, dtype=np.int32), (s, mp * ps))
-    want = SA._sparse_latent_reference(q, c_pages, r_pages, tables, idx,
-                                       lengths)
+    want = SA._sparse_latent_reference(
+        q, c_pages, r_pages, tables,
+        np.arange(mp * ps)[None, :] < np.asarray(lengths)[:, None])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -656,26 +711,78 @@ def test_gathered_absorbed_decode_is_expanded_attention_over_the_selection(
         np.testing.assert_allclose(got[sl], want, atol=2e-5)
 
 
-@pytest.mark.parametrize("seed", range(6))
-def test_the_masks_positions_are_the_sorts_selection(seed):
-    """``select_positions`` (the counting mask read out with no sort and
-    no scatter) lists, in order, exactly the positions ``lax.top_k``
-    takes: ties across the threshold, zeros of both signs, rows that see
-    none, fewer than ``topk``, all."""
-    (scores, n), kw = SA._make_selection_sample(seed)
-    topk = kw["topk"]
-    for impl in ("lax", "pallas_interpret"):
-        pos, n_sel = (np.asarray(a) for a in SA.select_positions(
-            scores, n, topk, impl=impl))
-        want = np.asarray(SA.selected_by_sort(scores, n, topk))
-        best, n_best = (np.asarray(a) for a in SA.select_decode(
-            scores, n, topk))
-        np.testing.assert_array_equal(n_sel, n_best)
-        for r in range(len(pos)):
-            live = pos[r, :n_sel[r]]
-            assert (np.diff(live) > 0).all()
-            np.testing.assert_array_equal(np.flatnonzero(want[r]), live)
-            assert set(best[r, :n_best[r]]) == set(live)
+#: the prefill's cases below, one geometry: 2 lanes of a chunk of 16 over
+#: tables of 12 pages of 16, 16 rows a pair of calls (a lane a pair),
+#: blocks of 4 pages, 4 compacted rows a pass, so that a page's fifth
+#: selected row takes a second pass; (chunk_starts, n_valid) a case
+PS_, PC, PH, PDL, PDR, PPS, PMP, PTOPK = 2, 16, 4, 32, 8, 16, 12, 40
+PREFILL_CASES = {
+    # tokens 150-165: the chunk's own rows lie on both sides of a page edge
+    "a_chunk_straddles_a_page": ((150, 8), (16, 16)),
+    # 11 and 5 live tokens: the second group of lane 0 and the one of
+    # lane 1 are part dead, their dead rows last
+    "a_partly_dead_group": ((130, 140), (11, 5)),
+    # lane 0 carries nothing: its pair of calls is skipped, zeros
+    "a_dead_lane_beside_a_live_one": ((96, 40), (0, 16)),
+    # no page lies whole under a fresh lane's tokens: all Part B
+    "no_shared_page": ((0, 0), (16, 9)),
+    # the table's 192 tokens are no more than ``topk``: no scores made
+    "every_visible_row": ((100, 176), (16, 16)),
+    # a whole block of 8 pages under each lane, out of its own table
+    "two_lanes_two_tables": ((130, 160), (16, 16)),
+    # 7 rows of a shared page and 13 of an own one, for passes of 4
+    "an_overflowing_page": ((144, 170), (16, 16)),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _prefill_through(impl):
+    """One jitted call an ``impl`` for every case (an eager call traces
+    its ``lax.map`` anew and compiles it again)."""
+    blocks = {} if impl == "lax" else {"block_sizes": dict(
+        q_rows=16, pages_per_block=4, rows_a_pass=4)}
+    return jax.jit(lambda *args: kernels.dispatch(
+        "sparse_latent_prefill", *args, impl=impl, **blocks))
+
+
+@pytest.mark.parametrize("impl", ["lax", "pallas_interpret"])
+@pytest.mark.parametrize("case", list(PREFILL_CASES))
+def test_prefill_walks_and_compacts(case, impl):
+    """``sparse_latent_prefill`` against the NumPy reference over the rows
+    each live token's mask marks of those it sees; a pad token reads
+    zeros."""
+    rng = np.random.default_rng(13)
+    t = PMP * PPS
+    pages = PS_ * PMP + 1
+    f = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa
+    q = jnp.asarray((PDL + PDR) ** -0.5 * f(PS_, PC, PH, PDL + PDR))
+    c_pages, r_pages = jnp.asarray(f(pages, PPS, PDL)), \
+        jnp.asarray(f(pages, PPS, 2 * PDR))
+    tables = jnp.asarray((1 + rng.permutation(pages - 1)).reshape(
+        PS_, PMP), jnp.int32)
+    starts, n_valid = (jnp.asarray(a, jnp.int32)
+                       for a in PREFILL_CASES[case])
+    if case == "every_visible_row":
+        got = jax.jit(lambda *a: SA.latent_indexed_prefill_attention(
+            *a[:3], None, *a[3:], None, None, t, impl=impl))(
+                q, c_pages, r_pages, tables, starts, n_valid)
+        selected = jnp.ones((PS_, PC, t), jnp.float32)
+    else:
+        selected = np.asarray(SA.select_prefill(
+            jnp.asarray(f(PS_, PC, t)), starts, n_valid, PTOPK)).copy()
+        if case == "an_overflowing_page":
+            selected[0, 3, 2 * PPS:2 * PPS + 7] = 1.0
+            selected[1, 12, 10 * PPS + 3:11 * PPS] = 1.0
+        selected = jnp.asarray(selected)
+        got = _prefill_through(impl)(q, c_pages, r_pages, tables, starts,
+                                     n_valid, selected)
+    want = SA._sparse_latent_prefill_reference(
+        q, c_pages, r_pages, tables, starts, n_valid, selected)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    dead = np.arange(PC)[None, :] >= np.asarray(n_valid)[:, None]
+    assert not np.asarray(got)[dead].any()
+    assert np.abs(np.asarray(got)[~dead]).min(-1).max() > 0
 
 
 def test_few_rows_meet_a_blocks_key_pages_in_one_product():
@@ -725,21 +832,25 @@ def test_a_wide_chunk_sums_its_heads_a_few_queries_at_a_time():
 
 
 def test_vmem_estimates_at_the_published_widths():
-    """A query row's 2048 gathered rows of 512 + 128 lanes against 128
-    heads fit the 64 MiB prefill's fold asks for four times over; a group
-    of 8 slots' queries, states, selections and compacted rows beside two
-    blocks of 8 pages fit the 64 MiB decode asks for (more than the two
-    blocks and the states alone); the indexer's block of 16 key pages
+    """A group of 8 queries' (decoding slots, or chunk tokens of a lane),
+    states, selections and compacted rows beside two blocks of 8 pages
+    fit the 64 MiB both selecting latent kernels ask for (more than the
+    two blocks and the states alone), and the static prior is the one
+    candidate each contract commits; the indexer's block of 16 key pages
     beside 256 x 64 query rows, summed 8 queries at a time, fits 16 MiB;
     so does the selection's block of 8 rows of 33408."""
     sds = jax.ShapeDtypeStruct
     pools = (sds((2433, 128, 512), jnp.bfloat16),
              sds((2433, 128, 128), jnp.bfloat16))
-    est = kernels.get("sparse_latent_prefill").vmem_estimate(
-        (sds((8, 256, 128, 576), jnp.bfloat16),) + pools
-        + (sds((8, 261), jnp.int32), sds((8, 256, 2048), jnp.int32),
-           sds((8, 256), jnp.int32)), {}, {"q_rows": 64})
-    assert 2 * 2048 * 640 * 2 < est < 16 << 20
+    prefill = kernels.get("sparse_latent_prefill")
+    args = (sds((8, 256, 128, 576), jnp.bfloat16),) + pools + (
+        sds((8, 261), jnp.int32), sds((8,), jnp.int32), sds((8,), jnp.int32),
+        sds((8, 256, 261 * 128), jnp.float32))
+    blocks = autotune.static_prior(prefill, args, {})
+    assert blocks == {"q_rows": 64, "pages_per_block": 8, "rows_a_pass": 16}
+    est = prefill.vmem_estimate(args, {}, blocks)
+    assert 2 * 8 * 128 * 640 * 2 + 2 * 8 * 128 * 768 * 4 < est \
+        < SA.DA._WIDE_VMEM_LIMIT
     decode = kernels.get("sparse_latent_decode")
     args = (sds((64, 128, 576), jnp.bfloat16),) + pools + (
         sds((64, 261), jnp.int32), sds((64, 261 * 128), jnp.float32),
@@ -747,9 +858,7 @@ def test_vmem_estimates_at_the_published_widths():
         sds((32,), jnp.int32), sds((64,), jnp.int32))
     blocks = autotune.static_prior(decode, args, {})
     assert blocks == {"pages_per_block": 8, "rows_a_pass": 16}
-    est = decode.vmem_estimate(args, {}, blocks)
-    assert 2 * 8 * 128 * 640 * 2 + 2 * 8 * 128 * 768 * 4 < est \
-        < SA.DA._WIDE_VMEM_LIMIT
+    assert decode.vmem_estimate(args, {}, blocks) == est
     indexer = kernels.get("lightning_indexer").vmem_estimate(
         (sds((8, 256, 64, 128), jnp.bfloat16),
          sds((8, 256, 64), jnp.float32),

@@ -23,6 +23,17 @@ from paddle_tpu.kernels import autotune
 KERNEL_NAMES = kernels.load_all()
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _programs_released_after_the_file():
+    """The battery's interpreted programs map about 20,000 memory
+    regions of the 65,530 a process may hold (``vm.max_map_count``), and
+    a test worker keeps what it compiled: give them back for the files
+    the worker runs next."""
+    import jax
+    yield
+    jax.clear_caches()
+
+
 # ---------------------------------------------------------------------------
 # parity battery — every registered kernel, one harness
 # ---------------------------------------------------------------------------
